@@ -1,9 +1,10 @@
 """RLlib throughput harness: env-steps/sec, dynamic loop vs Podracer.
 
-Three sections, one JSON record line each (bench.py artifact shape,
-stamped with the PR-6 TPU-probe provenance fields — `tpu_lost`,
-`tpu_probe_ok`, `tpu_probe_attempts`, `device` — so a CPU-container run
-is distinguishable from a regression):
+Three sections, one JSON record line each (bench.py artifact shape),
+each stamped with the device of the process that ran its model: this
+process for PPO's local learner and for Anakin; the CPU backend for the
+Podracer arms, whose learner and runner actors lease no chip and are
+therefore held to `JAX_PLATFORMS=cpu` by their supervisor:
 
   * `ppo_atari_env_steps_per_sec` — the BASELINE "PPO-Atari
     env-steps/sec/chip" row: PPO + Nature-CNN over 84x84x4 uint8 frames
@@ -28,24 +29,7 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
-import sys
 import time
-
-
-def _probe_provenance(log) -> dict:
-    """bench.py's shared provenance helper (one definition for every
-    harness; a missing bench.py still yields an honest tpu_lost record)."""
-    try:
-        sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-        from bench import probe_provenance
-
-        return probe_provenance(log)
-    except Exception as e:
-        log(f"provenance helper unavailable ({e!r}); treating as lost")
-        return {"tpu_probe_ok": False, "tpu_probe_attempts": 0,
-                "tpu_lost": True, "forced_cpu": False,
-                "device": "unknown", "device_kind": "unknown"}
 
 
 def run(env: str = "SyntheticAtari-v0", iters: int = 5,
@@ -205,7 +189,6 @@ if __name__ == "__main__":
     ap.add_argument("--anakin-envs", type=int, default=32)
     ns = ap.parse_args()
 
-    prov = _probe_provenance(lambda m: print(m, file=sys.stderr))
     records = []
     if not ns.skip_ppo:
         records.append(run(ns.env, ns.iters, ns.runners, ns.envs,
@@ -215,6 +198,12 @@ if __name__ == "__main__":
                                     iters=ns.podracer_iters))
     if not ns.skip_anakin:
         records.append(run_anakin(num_envs=ns.anakin_envs))
+    import jax
+
+    here = {"device": jax.devices()[0].platform,
+            "device_kind": jax.devices()[0].device_kind}
+    actors = {"device": "cpu", "device_kind": "cpu"}
     for rec in records:
-        rec.update(prov)
+        rec.update(actors if rec["metric"].startswith(("rl_", "podracer_"))
+                   else here)
         print(json.dumps(rec))

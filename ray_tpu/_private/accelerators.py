@@ -115,6 +115,85 @@ def tpu_pod_resources() -> Dict[str, float]:
     return out
 
 
+# ---------------------------------------------------------------------------
+# who owns the chips of this host
+#
+# A chip belongs to one process at a time: the first process whose JAX
+# client opens it holds it until that process exits. Which process that is
+# gets decided by the settings stock JAX and libtpu honour, and by nothing
+# else — JAX_PLATFORMS for "may this process open an accelerator at all",
+# and TPU_VISIBLE_CHIPS with its bounds for "which ones".
+
+# chips in one process -> TPU_CHIPS_PER_HOST_BOUNDS (reference
+# accelerators/tpu.py; libtpu refuses a second process on the host's
+# default bounds, and takes these for 1 and 2 chips on a v5e 2x2 host)
+_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1"}
+
+
+def count_local_chips() -> int:
+    """TPU chips attached to this host, from the device files the TPU
+    driver creates — no JAX backend is initialised, so counting claims
+    nothing, and it works on a VM that sets no TPU variable at all.
+    Older runtimes expose ``/dev/accel<N>``, newer ones one VFIO group per
+    chip (``/dev/vfio/<N>`` next to the ``/dev/vfio/vfio`` control node)."""
+    import glob
+
+    chips = glob.glob("/dev/accel[0-9]*")
+    if not chips:
+        chips = [p for p in glob.glob("/dev/vfio/*")
+                 if os.path.basename(p).isdigit()]
+    return len(chips)
+
+
+def host_chip_ids(num_chips: int) -> list:
+    """The ids libtpu knows this node's ``num_chips`` chips by: the node's
+    own ``TPU_VISIBLE_CHIPS`` where it was started on a subset of its
+    host, else 0..n-1."""
+    visible = [c.strip() for c in
+               os.environ.get("TPU_VISIBLE_CHIPS", "").split(",") if c.strip()]
+    if len(visible) == num_chips and all(c.isdigit() for c in visible):
+        return [int(c) for c in visible]
+    return list(range(num_chips))
+
+
+def keep_off_accelerators() -> Optional[str]:
+    """Pin THIS process, and whatever inherits its environment, to JAX's
+    CPU backend; returns the ``JAX_PLATFORMS`` it was launched with (None
+    if unset). Control daemons call it first thing: they must never open
+    a chip that a worker will need."""
+    launched_with = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    return launched_with
+
+
+def worker_env(base, chips, host_chips: int,
+               launch_platforms: Optional[str]) -> Dict[str, str]:
+    """Environment of a worker process spawned from ``base``.
+
+    Without ``chips`` the worker runs JAX on the CPU backend whatever the
+    host has. With chips it gets back the ``JAX_PLATFORMS`` the node was
+    launched with (so the driver's setting decides: unset or ``tpu`` on a
+    TPU host, ``cpu`` in the test suite) and, unless it leases the whole
+    host, is pinned to exactly its chips."""
+    env = dict(base)
+    if not chips:
+        env["JAX_PLATFORMS"] = "cpu"
+        return env
+    if launch_platforms is None:
+        env.pop("JAX_PLATFORMS", None)
+    else:
+        env["JAX_PLATFORMS"] = launch_platforms
+    if len(chips) < host_chips:
+        if len(chips) not in _CHIP_BOUNDS:
+            raise ValueError(
+                f"a worker can hold {sorted(_CHIP_BOUNDS)} chips or all "
+                f"{host_chips} of its host, not {len(chips)}")
+        env["TPU_VISIBLE_CHIPS"] = ",".join(str(c) for c in chips)
+        env["TPU_CHIPS_PER_HOST_BOUNDS"] = _CHIP_BOUNDS[len(chips)]
+        env["TPU_HOST_BOUNDS"] = "1,1,1"
+    return env
+
+
 def chips_from_accelerator_type(accel: str) -> int:
     """Per-host chip count implied by the pod type (fallback when the
     runtime env vars are absent)."""

@@ -279,8 +279,13 @@ class Config:
     # (≈ src/ray/gcs/store_client/redis_store_client.h)
     controller_store_uri: str = ""
     # ---- TPU ----
-    tpu_chips_per_host: int = 0  # 0 = autodetect via jax
-    tpu_topology: str = ""  # e.g. "v5p-64"; "" = autodetect
+    # neither field has a reader. A node's chip count comes from
+    # ray_tpu.init(num_tpus=...) or, without it, from
+    # resources._detect_tpu_chips, which never touches jax:
+    # TPU_VISIBLE_CHIPS, then the chips' device files, then the TPU
+    # topology variables; the pod type from accelerators.py.
+    tpu_chips_per_host: int = 0
+    tpu_topology: str = ""
     # ---- fault injection (chaos.py; every knob defaults OFF) ----
     # seed for the deterministic fault schedule; < 0 disables chaos
     # entirely (the rpc hot path then pays one None-check)

@@ -1766,6 +1766,9 @@ def main() -> None:
     from ray_tpu._private.watchdog import start_owner_watchdog_from_env
 
     start_owner_watchdog_from_env("controller")
+    from ray_tpu._private.accelerators import keep_off_accelerators
+
+    keep_off_accelerators()
 
     async def run():
         snapshot = args.snapshot_path

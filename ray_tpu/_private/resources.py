@@ -80,9 +80,10 @@ def detect_node_resources(
 ) -> ResourceSet:
     """Detect this host's schedulable resources.
 
-    TPU detection avoids initializing a jax backend (which would claim the
-    chips): we trust explicit args, then the TPU_CHIPS / TPU topology env vars
-    the TPU VM runtime sets, and only count; we never touch the devices.
+    TPU detection never initializes a jax backend (which would claim the
+    chips): explicit args win, then TPU_VISIBLE_CHIPS isolation, then the
+    device files of the chips really attached, and only where there are
+    none the TPU topology env vars a TPU runtime sets. We only count.
     """
     rs = ResourceSet()
     rs[CPU] = float(num_cpus if num_cpus is not None else (os.cpu_count() or 1))
@@ -113,6 +114,18 @@ def _detect_tpu_chips() -> int:
     visible = os.environ.get("TPU_VISIBLE_CHIPS")
     if visible:
         return len([c for c in visible.split(",") if c.strip()])
+    if os.environ.get("RAY_TPU_FORCE_TPU_CHIPS"):
+        return int(os.environ["RAY_TPU_FORCE_TPU_CHIPS"])
+    # the chips that are really attached: the environment may describe a
+    # larger host than this VM was given (a one-chip machine cut from a
+    # 2x2 host still says TPU_CHIPS_PER_HOST_BOUNDS=2,2,1), and a bare VM
+    # may set nothing at all
+    from ray_tpu._private.accelerators import (chips_from_accelerator_type,
+                                               count_local_chips)
+
+    attached = count_local_chips()
+    if attached:
+        return attached
     chips = os.environ.get("TPU_CHIPS_PER_HOST_BOUNDS")
     if chips:
         try:
@@ -123,15 +136,10 @@ def _detect_tpu_chips() -> int:
             return n
         except ValueError:
             pass
-    if os.environ.get("RAY_TPU_FORCE_TPU_CHIPS"):
-        return int(os.environ["RAY_TPU_FORCE_TPU_CHIPS"])
     # GKE sets the pod accelerator type but not per-host chip bounds:
     # derive chips/host from the topology (accelerators.py discovery)
     accel = os.environ.get("TPU_ACCELERATOR_TYPE")
     if accel:
-        from ray_tpu._private.accelerators import (
-            chips_from_accelerator_type)
-
         return chips_from_accelerator_type(accel)
     return 0
 

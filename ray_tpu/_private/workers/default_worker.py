@@ -15,9 +15,10 @@ Execution model:
   * async actors: methods that are coroutines run on a dedicated asyncio loop
     with a ``max_concurrency`` semaphore (≈ fiber.h's fibers).
 
-TPU specifics: before the first TPU task runs, the worker pins itself to its
-assigned chips via ``TPU_VISIBLE_CHIPS`` (reference accelerators/tpu.py:30) —
-jax then initializes only those chips when user code first touches it.
+TPU specifics: the supervisor spawns a chip-holding worker with its chips
+already pinned in the environment (``accelerators.worker_env``), so jax
+opens only those chips when user code first touches it, and the compile
+cache is placed before that (``compile_cache.enable``).
 """
 
 from __future__ import annotations
@@ -68,7 +69,6 @@ class Executor:
         # append/popleft are thread-safe)
         self._done_outbox: deque = deque()
         self._done_flushing = False
-        self._tpu_env_set = False
         self._lock = threading.Lock()
 
     # -- entry from the IO loop (RPC handler) --
@@ -175,21 +175,6 @@ class Executor:
             ]
         return plain_args, kwargs
 
-    def _maybe_setup_tpu(self, spec: TaskSpec) -> None:
-        if self._tpu_env_set or spec.required_resources().get("TPU", 0) <= 0:
-            return
-        try:
-            chips = self.core._run(
-                self.core.clients.get(self.core.supervisor_addr).call(
-                    "tpu_visible_chips", {"worker_id_hex": self.core.worker_id.hex()}
-                )
-            )
-            if chips and "TPU_VISIBLE_CHIPS" not in os.environ:
-                os.environ["TPU_VISIBLE_CHIPS"] = ",".join(str(c) for c in chips)
-        except Exception:
-            pass
-        self._tpu_env_set = True
-
     def _get_callable(self, spec: TaskSpec):
         if spec.kind == TaskKind.ACTOR_TASK:
             if self.actor_instance is None:
@@ -218,7 +203,6 @@ class Executor:
 
             self._report_error(spec, TaskCancelledError(spec.name), retryable=False)
             return
-        self._maybe_setup_tpu(spec)
         try:
             args, kwargs = self._resolve_args(spec)
             fn = self._get_callable(spec)
@@ -676,6 +660,13 @@ def main() -> None:
         format="[worker %(process)d] %(asctime)s %(levelname)s %(message)s",
     )
 
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        # not held to the CPU backend: this worker leased chips and is
+        # about to become a device process
+        from ray_tpu._private import compile_cache
+
+        compile_cache.enable()
+
     def parse_addr(s):
         host, port = s.rsplit(":", 1)
         return (host, int(port))
@@ -745,7 +736,11 @@ def main() -> None:
             },
         )
     )
-    asyncio.run_coroutine_threadsafe(
+    # held for the life of the process: the loop keeps tasks weakly and
+    # the bond's only other referrers form a cycle with it, so without
+    # this the first gc destroys the bond ("Task was destroyed but it is
+    # pending!") and leaves the ppid poll as the only liveness check
+    core.liveness_bond = asyncio.run_coroutine_threadsafe(
         _liveness_bond(parse_addr(args.supervisor)), core.loop
     )
     # SIGTERM (supervisor shutdown/kill): drain the IO loop before dying

@@ -208,8 +208,10 @@ def slot_decode_step(cfg: TransformerConfig, params, tokens, active, caches):
 
 # ---------------------------------------------------------------------------
 # paged KV arena — the slot arena rebuilt as a pool of fixed-size pages
-# (ISSUE 13). KV storage is [num_pages, page_tokens, Hkv, D] per layer; a
-# slot owns a PAGE TABLE ([pages_per_slot] int32 of physical page ids)
+# (ISSUE 13). KV storage is [num_pages, page_tokens, Hkv * D] per layer (a
+# token's kv heads joined on the minor axis: the layout whose pages the
+# TPU's DMA engine can slice — see ops/paged_attention.py); a slot owns a
+# PAGE TABLE ([pages_per_slot] int32 of physical page ids)
 # instead of a contiguous worst-case range, so long/idle sequences stop
 # reserving memory they don't use and read-only pages can be SHARED between
 # slots (the prefix cache). The two compiled programs gather a slot's
@@ -232,7 +234,7 @@ def slot_decode_step(cfg: TransformerConfig, params, tokens, active, caches):
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class PagedKVCache:
-    """One layer's page pool. k/v: [num_pages, page_tokens, Hkv, D];
+    """One layer's page pool. k/v: [num_pages, page_tokens, Hkv * D];
     lengths: [slots] int32 — per-slot write cursors in LOGICAL tokens."""
 
     k: Any
@@ -244,8 +246,8 @@ class PagedKVCache:
               kv_heads: int, head_dim: int,
               dtype=jnp.bfloat16) -> "PagedKVCache":
         return cls(
-            k=jnp.zeros((num_pages, page_tokens, kv_heads, head_dim), dtype),
-            v=jnp.zeros((num_pages, page_tokens, kv_heads, head_dim), dtype),
+            k=jnp.zeros((num_pages, page_tokens, kv_heads * head_dim), dtype),
+            v=jnp.zeros((num_pages, page_tokens, kv_heads * head_dim), dtype),
             lengths=jnp.zeros((slots,), jnp.int32),
         )
 
@@ -283,12 +285,10 @@ def paged_reset_slot(caches: List[PagedKVCache], slot: int,
         for c in caches]
 
 
-def _gather_row(c: PagedKVCache, table):
+def _gather_row(cfg: TransformerConfig, c: PagedKVCache, table):
     """[P] page table -> one slot's logical [1, P*T, Hkv, D] k/v view."""
-    P = table.shape[0]
-    T, H, D = c.k.shape[1:]
-    return (c.k[table].reshape(1, P * T, H, D),
-            c.v[table].reshape(1, P * T, H, D))
+    view = (1, table.shape[0] * c.k.shape[1], cfg.kv_heads, cfg.head_dim)
+    return c.k[table].reshape(view), c.v[table].reshape(view)
 
 
 # attention lanes for the paged programs (ISSUE 20). "gather" is the
@@ -357,8 +357,10 @@ def _paged_forward_inplace(cfg: TransformerConfig, params, tokens, positions,
             cos, sin = rope
             q = apply_rotary(q, cos, sin, positions)
             k = apply_rotary(k, cos, sin, positions)
-        ck = c.k.at[pages, offs].set(k.astype(c.k.dtype))
-        cv = c.v.at[pages, offs].set(v.astype(c.v.dtype))
+        ck = c.k.at[pages, offs].set(
+            k.reshape(*k.shape[:2], -1).astype(c.k.dtype))
+        cv = c.v.at[pages, offs].set(
+            v.reshape(*v.shape[:2], -1).astype(c.v.dtype))
         o = paged_attention(q, ck, cv, read_tables, lengths, impl=impl)
         x = x + jnp.einsum("bshk,hkd->bsd", o, ap["wo"].astype(cfg.dtype))
         m, _ = _mlp(cfg, p["mlp"], _norm(cfg, p["ln2"], x))
@@ -406,18 +408,17 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
         last = lax.dynamic_index_in_dim(logits[0], real_len - 1,
                                         keepdims=False)
         return last, new_caches
-    T = caches[0].k.shape[1]
+    T, HD = caches[0].k.shape[1:]
     P = read_row.shape[0]
     rows = []
     for c in caches:
-        k, v = _gather_row(c, read_row)
+        k, v = _gather_row(cfg, c, read_row)
         rows.append(LayerKVCache(
             k=k, v=v, length=lax.dynamic_slice(c.lengths, (slot,), (1,))[0]))
     positions = jnp.arange(tokens.shape[1])[None, :] + rows[0].length
     logits, new_rows = forward(cfg, params, tokens, positions=positions,
                                kv_caches=rows)
     last = lax.dynamic_index_in_dim(logits[0], real_len - 1, keepdims=False)
-    H, D = caches[0].k.shape[2:]
     # windowed scatter-back: the chunk writes only [cursor, cursor + C),
     # which spans at most ceil(C/T)+1 pages — persisting just that window
     # (instead of the whole P-page view) keeps the paged program's write
@@ -433,8 +434,8 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
     new_caches = []
     for c, r in zip(caches, new_rows):
         new_caches.append(PagedKVCache(
-            k=c.k.at[dest].set(r.k.reshape(P, T, H, D)[widx]),
-            v=c.v.at[dest].set(r.v.reshape(P, T, H, D)[widx]),
+            k=c.k.at[dest].set(r.k.reshape(P, T, HD)[widx]),
+            v=c.v.at[dest].set(r.v.reshape(P, T, HD)[widx]),
             lengths=c.lengths.at[slot].add(real_len)))
     return last, new_caches
 
@@ -465,14 +466,13 @@ def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
             read_tables, write_tables, caches, attn,
             lambda l: l + active)
         return logits[:, 0], new_caches
-    T = caches[0].k.shape[1]
+    T, HD = caches[0].k.shape[1:]
     slots, P = read_tables.shape
-    H, D = caches[0].k.shape[2:]
 
     def one(tok, length, read_row, write_row):
         rows = []
         for c in caches:
-            k, v = _gather_row(c, read_row)
+            k, v = _gather_row(cfg, c, read_row)
             rows.append(LayerKVCache(k=k, v=v, length=length))
         positions = rows[0].length + jnp.zeros((1, 1), jnp.int32)
         logits, new_rows = forward(cfg, params, tok[None, None],
@@ -483,10 +483,10 @@ def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
         pidx = jnp.clip(length // T, 0, P - 1)
         dest = write_row[pidx]
         outs_k = [lax.dynamic_index_in_dim(
-            r.k[0].reshape(P, T, H, D), pidx, keepdims=False)
+            r.k[0].reshape(P, T, HD), pidx, keepdims=False)
             for r in new_rows]
         outs_v = [lax.dynamic_index_in_dim(
-            r.v[0].reshape(P, T, H, D), pidx, keepdims=False)
+            r.v[0].reshape(P, T, HD), pidx, keepdims=False)
             for r in new_rows]
         return logits[0, -1], dest, (outs_k, outs_v)
 
@@ -539,15 +539,14 @@ def paged_verify_step(cfg: TransformerConfig, params, tokens,
             cfg, params, tokens, positions, lengths,
             read_tables, write_tables, caches, attn, lambda l: l)
         return logits, new_caches
-    T = caches[0].k.shape[1]
+    T, HD = caches[0].k.shape[1:]
     slots, P = read_tables.shape
-    H, D = caches[0].k.shape[2:]
     K = tokens.shape[1]
 
     def one(toks, length, read_row, write_row):
         rows = []
         for c in caches:
-            k, v = _gather_row(c, read_row)
+            k, v = _gather_row(cfg, c, read_row)
             rows.append(LayerKVCache(k=k, v=v, length=length))
         positions = jnp.arange(K)[None, :] + rows[0].length
         logits, new_rows = forward(cfg, params, toks[None, :],
@@ -559,8 +558,8 @@ def paged_verify_step(cfg: TransformerConfig, params, tokens,
         w0 = rows[0].length // T
         widx = jnp.clip(w0 + jnp.arange(W), 0, P - 1)
         dest = write_row[widx]
-        outs_k = [r.k[0].reshape(P, T, H, D)[widx] for r in new_rows]
-        outs_v = [r.v[0].reshape(P, T, H, D)[widx] for r in new_rows]
+        outs_k = [r.k[0].reshape(P, T, HD)[widx] for r in new_rows]
+        outs_v = [r.v[0].reshape(P, T, HD)[widx] for r in new_rows]
         return logits[0], dest, (outs_k, outs_v)
 
     lengths = caches[0].lengths

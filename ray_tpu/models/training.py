@@ -145,10 +145,18 @@ def make_train_step(cfg: TransformerConfig, tx, mesh=None,
     if batch_sharding is None:
         batch_sharding = data_sharding(mesh)
     repl = NamedSharding(mesh, PartitionSpec())
+
+    def step_on_mesh(state: TrainState, batch):
+        # the mesh is in scope while the step traces, so ops that GSPMD
+        # cannot partition (the Pallas flash kernel, ops/attention.py)
+        # can shard_map themselves over it
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return step_fn(state, batch)
+
     # pytree-prefix shardings: every batch leaf is batch-sharded; state keeps
     # its existing (init-time) shardings; metrics come back replicated.
     return jax.jit(
-        step_fn,
+        step_on_mesh,
         in_shardings=(None, batch_sharding),
         out_shardings=(None, repl),
         donate_argnums=(0,) if donate else (),

@@ -7,6 +7,10 @@ forces interpret mode even on TPU (bisecting Mosaic lowering issues vs
 kernel-math bugs). The knob is one-way: it can force interpretation ON,
 never force a non-TPU backend to attempt a Mosaic compile (which would
 just crash), so falsy values simply defer to backend detection.
+
+On a TPU backend without the knob a kernel is always compiled, never
+interpreted and never swapped for a reference; `chip_smoke.py` fails when
+interpretation is in force for any reason.
 """
 
 from __future__ import annotations

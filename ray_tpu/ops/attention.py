@@ -9,12 +9,16 @@ rejects unknown/falsy values identically and loudly."""
 
 from __future__ import annotations
 
+import logging
 import math
 from typing import Optional
 
 import jax
 
+from ray_tpu.ops._pallas import should_interpret
 from ray_tpu.ops.flash_attention import flash_attention, reference_attention
+
+logger = logging.getLogger(__name__)
 
 ATTN_IMPLS = ("auto", "flash", "reference")
 
@@ -44,15 +48,55 @@ def attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] = None,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if bias is not None:
-        impl = "reference"
+        if impl == "flash":  # never give way to the reference silently
+            raise ValueError("the flash kernel takes no bias; call with "
+                             "impl='reference' (or 'auto')")
+        impl = "reference"  # shape rule: only the reference adds a bias
     if impl == "auto":
         impl = "flash" if jax.default_backend() == "tpu" else "reference"
     if impl == "flash":
-        return flash_attention(q, k, v, sm_scale, causal)
+        return _flash_on_mesh(q, k, v, sm_scale, causal)
     return reference_attention(q, k, v, sm_scale, causal, bias=bias)
 
 
-def resolve_paged_attn_lane(choice: Optional[str] = None) -> str:
+def _flash_on_mesh(q, k, v, sm_scale, causal):
+    """The flash kernel, partitioned by hand when a mesh is in scope.
+
+    GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map"), so under a mesh — ``make_train_step`` puts its mesh in
+    scope — the kernel runs per shard: batch over the axes the sharding
+    rules give ``batch``, heads over the axis they give ``heads`` (only
+    when it divides H and Hkv alike, so every shard keeps whole GQA
+    groups); sequence and head_dim stay whole, which is all the kernel
+    needs. Axes that are already manual (a caller's own shard_map) are
+    left alone. With no mesh in scope the call is bare, as before."""
+    mesh = jax.sharding.get_abstract_mesh()
+    free = [a for a in mesh.axis_names if a not in mesh.manual_axes]
+    if not free:
+        return flash_attention(q, k, v, sm_scale, causal)
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.parallel.sharding import DEFAULT_RULES
+
+    def axes_for(logical, *dims):
+        target = DEFAULT_RULES[logical]
+        names = tuple(a for a in ((target,) if isinstance(target, str)
+                                  else target) if a in free)
+        size = math.prod(mesh.shape[a] for a in names)
+        if not names or any(d % size for d in dims):
+            return None
+        return names if len(names) > 1 else names[0]
+
+    spec = P(axes_for("batch", q.shape[0]), None,
+             axes_for("heads", q.shape[2], k.shape[2]), None)
+    return jax.shard_map(
+        lambda q, k, v: flash_attention(q, k, v, sm_scale, causal),
+        in_specs=(spec, spec, spec), out_specs=spec,
+        axis_names=set(free), check_vma=False)(q, k, v)
+
+
+def resolve_paged_attn_lane(choice: Optional[str] = None,
+                            cfg=None) -> str:
     """Resolve the serve paged-attention lane to a concrete program lane.
 
     choice=None reads the ``serve_paged_attn`` config flag
@@ -60,6 +104,13 @@ def resolve_paged_attn_lane(choice: Optional[str] = None) -> str:
     falsy spellings like "0"/"" — are rejected loudly (the falsy-zero
     lesson: 0 never silently means a default lane). Returns one of
     'pallas' | 'reference' | 'gather'.
+
+    Given the model's ``cfg`` (its ``kv_heads`` and ``head_dim``), a
+    ``pallas`` lane that Mosaic would have to compile is checked against
+    ``pallas_shape_problem``: an explicit
+    'pallas' raises here, at scheduler build; 'auto' takes 'reference' by
+    that same stated shape rule (logged, and named in the scheduler's
+    stats). Interpreted kernels (off-TPU) take any shape.
     """
     if choice is None:
         from ray_tpu._private.config import global_config
@@ -70,6 +121,20 @@ def resolve_paged_attn_lane(choice: Optional[str] = None) -> str:
             f"unknown paged attention lane {choice!r} (serve_paged_attn / "
             f"RAY_TPU_SERVE_PAGED_ATTN); expected one of "
             f"{list(PAGED_ATTN_CHOICES)}")
+    lane = choice
     if choice == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "reference"
-    return choice
+        lane = "pallas" if jax.default_backend() == "tpu" else "reference"
+    if lane == "pallas" and cfg is not None and not should_interpret():
+        from ray_tpu.ops.paged_attention import pallas_shape_problem
+
+        problem = pallas_shape_problem(cfg.kv_heads, cfg.head_dim)
+        if problem and choice == "pallas":
+            raise ValueError(
+                f"paged attention lane 'pallas' cannot compile for this "
+                f"model on a TPU: {problem}")
+        if problem:
+            logger.warning("paged attention: auto takes the 'reference' "
+                           "lane, the Pallas kernel cannot compile here "
+                           "(%s)", problem)
+            lane = "reference"
+    return lane

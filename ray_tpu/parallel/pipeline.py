@@ -17,6 +17,7 @@ groups.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable
 
 import jax
@@ -58,18 +59,6 @@ def pipeline_apply(stage_fn: Callable[[Any, Any], Any], stacked_params,
     num_micro = x.shape[0]
     steps = num_micro + num_stages - 1
 
-    import functools
-
-    try:
-        from jax import shard_map as _sm
-
-        # new API spells the replication check 'check_vma'
-        shard_map = functools.partial(_sm, check_vma=False)
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map as _sme
-
-        shard_map = functools.partial(_sme, check_rep=False)
-
     param_specs = jax.tree.map(
         lambda v: P(axis, *([None] * (v.ndim - 1))), stacked_params)
 
@@ -99,25 +88,15 @@ def pipeline_apply(stage_fn: Callable[[Any, Any], Any], stacked_params,
         return lax.psum(outs, axis)
 
     in_x_spec = P(*([None] * x.ndim))
-    return shard_map(
+    return _shard_map(mesh)(
         local,
-        mesh=mesh,
         in_specs=(param_specs, in_x_spec),
         out_specs=P(*([None] * x.ndim)),
     )(stacked_params, x)
 
 
 def _shard_map(mesh):
-    import functools
-
-    try:
-        from jax import shard_map as _sm
-
-        return functools.partial(_sm, mesh=mesh, check_vma=False)
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map as _sme
-
-        return functools.partial(_sme, mesh=mesh, check_rep=False)
+    return functools.partial(jax.shard_map, mesh=mesh, check_vma=False)
 
 
 def pipeline_1f1b(stage_fn: Callable[[Any, Any], Any],

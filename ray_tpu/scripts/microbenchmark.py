@@ -1383,6 +1383,10 @@ def main(argv=None) -> None:
 
     import ray_tpu
 
+    # one process, one chip: THIS process runs every jax probe itself
+    # (serve probes instantiate LLMServerImpl here, not build_app), and
+    # no task or actor of this suite leases a TPU, so its workers are
+    # all held to the CPU backend and never contend for the device
     ray_tpu.init(num_cpus=args.num_cpus,
                  object_store_memory=512 * 1024 * 1024)
     try:

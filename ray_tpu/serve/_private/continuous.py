@@ -271,7 +271,7 @@ class ContinuousScheduler:
             # RAY_TPU_SERVE_PAGED_ATTN fails the constructor, not some
             # later decode step, and stats() always names the real lane
             self.attn_lane = resolve_paged_attn_lane(
-                conf.serve_paged_attn if attn is None else attn)
+                conf.serve_paged_attn if attn is None else attn, cfg)
             # donated caches: the pool mutates in place across iterations;
             # the tables are tiny per-call host->device uploads
             self._prefill = jax.jit(

@@ -182,6 +182,13 @@ class Router:
         aid = getattr(rep, "_actor_id", None)
         return aid.hex() if aid is not None else repr(rep)
 
+    def _polling(self) -> bool:
+        """Whether the background loops go on. They end with the cluster
+        connection too: after ray_tpu.shutdown() their next API call would
+        start a NEW cluster through the API's implicit init — from a
+        daemon thread of a driver that is on its way out."""
+        return not self._stopped and ray_tpu.is_initialized()
+
     def _ensure_polling(self) -> None:
         if self._poll_thread is None:
             with self._lock:
@@ -199,7 +206,7 @@ class Router:
         If the controller stays unreachable (serve.shutdown), the thread
         retires itself; the next assign_request restarts polling."""
         failures = 0
-        while not self._stopped:
+        while self._polling():
             try:
                 info = ray_tpu.get(
                     self._controller.listen_for_change.remote(
@@ -250,7 +257,7 @@ class Router:
         Retires itself if the controller stays unreachable; the next
         affinity-eligible request restarts it."""
         failures = 0
-        while not self._stopped:
+        while self._polling():
             with self._lock:
                 known = self._affinity.version
             try:
@@ -466,7 +473,7 @@ class Router:
         unreachable replicas are merged in, not wiped — a model mid-load
         (or one slow poll) must not bounce the next request to a cold
         replica. The thread retires itself once mux traffic stops."""
-        while not self._stopped:
+        while self._polling():
             time.sleep(1.0)
             now = time.monotonic()
             if now - self._mux_last_request > self.MUX_IDLE_EXIT_S:

@@ -173,7 +173,7 @@ class Drafter:
         idx = jnp.asarray(np.asarray(read_row, np.int32))
         out = []
         for dc, tc in zip(self._caches, target_caches):
-            H, D = tc.k.shape[2:]
+            H, D = dc.k.shape[2:]  # target pages keep heads joined
             vk = tc.k[idx].reshape(-1, H, D)[:length]
             vv = tc.v[idx].reshape(-1, H, D)[:length]
             out.append(dataclasses.replace(

@@ -118,6 +118,16 @@ def get_or_publish(key: str, loader: Callable[[], Any], *,
                                                   timeout_s)
                     host = _tree_to_host(params)
                     del params
+                    need = tree_nbytes(host)
+                    if need > core.arena.size:
+                        # loud, before the put: a store that cannot hold
+                        # the weights must not be papered over by spills
+                        raise RuntimeError(
+                            f"shared weights {key!r} need {need} bytes but "
+                            f"this node's object store holds "
+                            f"{core.arena.size}; start the cluster with a "
+                            f"larger object_store_memory or deploy with "
+                            f"share_weights=False")
                     ref = ray_tpu.put(host)
                     del host  # the loader copy dies; the arena copy stays
                     packed = _pack_ref(ref)

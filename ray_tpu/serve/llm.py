@@ -40,9 +40,10 @@ from functools import partial
 from typing import Any, Dict, List, Optional
 
 import ray_tpu.serve as serve
-from ray_tpu.models import presets
-from ray_tpu.models.decode import (decode_step, init_caches, prefill,
-                                   sample_token)
+
+# ray_tpu.models (and with it jax) is imported where a replica is built,
+# never at module level: the driver that calls build_app() stays off JAX,
+# so the chip is free for the replica's own process.
 
 
 def _byte_tokenize(text: str, vocab_size: int) -> List[int]:
@@ -86,6 +87,8 @@ class LLMServerImpl:
         import jax
         import jax.numpy as jnp
 
+        from ray_tpu.models import presets
+        from ray_tpu.models.decode import decode_step, prefill
         from ray_tpu.models.transformer import init_params
 
         if scheduler not in ("continuous", "batch"):
@@ -198,6 +201,7 @@ class LLMServerImpl:
         import jax
 
         from ray_tpu._private.config import global_config
+        from ray_tpu.models import presets
         from ray_tpu.models.transformer import init_params
 
         conf = global_config()
@@ -318,6 +322,8 @@ class LLMServerImpl:
     def _generate_group(self, prompts: List[List[int]],
                         new_tokens: int) -> List[List[int]]:
         """One batched decode program over same-length prompts."""
+        from ray_tpu.models.decode import init_caches, sample_token
+
         jnp = self._jnp
         batch = len(prompts)
         length = len(prompts[0])
@@ -341,6 +347,8 @@ class LLMServerImpl:
         executor thread, never the event loop — but each live stream still
         monopolizes one whole decode program; the continuous path replaces
         this with a queue consumer over the shared slot arena."""
+        from ray_tpu.models.decode import init_caches, sample_token
+
         jnp = self._jnp
         tokens = jnp.asarray([prompt_ids], dtype=jnp.int32)
         caches = init_caches(self.cfg, 1, len(prompt_ids) + new_tokens)
@@ -405,8 +413,18 @@ class LLMServerImpl:
 
     def scheduler_stats(self) -> Dict[str, Any]:
         if self._sched is not None:
-            return self._sched.stats()
-        return {"mode": "batch", "max_batch_size": self._max_batch}
+            out = self._sched.stats()
+        else:
+            out = {"mode": "batch", "max_batch_size": self._max_batch}
+        # where the model really runs: a replica that was not given a
+        # chip runs on the CPU, and the record has to say so
+        devices = self._jax.devices()
+        out["platform"] = devices[0].platform
+        out["device_kind"] = devices[0].device_kind
+        out["device_count"] = len(devices)
+        out["peak_bytes_in_use"] = (devices[0].memory_stats() or {}).get(
+            "peak_bytes_in_use")
+        return out
 
     def queue_depth(self) -> int:
         """Admitted-but-unscheduled sequences (the replica relays this
@@ -479,6 +497,17 @@ def build_app(preset: str = "llama_debug", num_replicas: int = 1,
               **kwargs) -> "serve.Application":
     """`serve.run(build_app(...), route_prefix="/llm")` — the deployable
     LLM decode application."""
-    dep = LLMServer.options(num_replicas=num_replicas)
+    import ray_tpu
+
+    # one replica process for each chip: where the cluster shows TPU
+    # chips a replica leases one (its worker is then pinned to it and the
+    # model runs there); on a cluster without chips the same call runs on
+    # the CPU. scheduler_stats() names the platform either way.
+    actor_options = {}
+    if (ray_tpu.is_initialized()
+            and ray_tpu.cluster_resources().get("TPU", 0) >= 1):
+        actor_options["num_tpus"] = 1
+    dep = LLMServer.options(num_replicas=num_replicas,
+                            ray_actor_options=actor_options)
     return dep.bind(preset=preset, max_new_tokens=max_new_tokens,
                     temperature=temperature, **kwargs)

@@ -75,6 +75,29 @@ class TestShardedTraining:
         assert losses[-1] < losses[0] * 0.9, losses
         assert int(state.step) == 8
 
+    def test_flash_kernel_inside_the_sharded_step(self):
+        """make_train_step puts its mesh in scope, so attn_impl='flash'
+        (what 'auto' resolves to on a TPU) runs per shard inside the
+        GSPMD step: same losses as the XLA reference attention."""
+        mesh = build_mesh(MeshSpec.of(fsdp=2, tp=2),
+                          devices=jax.devices()[:4])
+        ocfg = OptimizerConfig(learning_rate=1e-2, warmup_steps=1,
+                               decay_steps=100)
+        tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, 256)
+        losses = {}
+        for impl in ("flash", "reference"):
+            cfg = llama_debug(attn_impl=impl)
+            state, tx = init_train_state(cfg, ocfg, jax.random.PRNGKey(0),
+                                         mesh)
+            step = make_train_step(cfg, tx, mesh)
+            losses[impl] = []
+            for _ in range(3):
+                state, metrics = step(state, {"tokens": tokens})
+                losses[impl].append(float(metrics["loss"]))
+        np.testing.assert_allclose(losses["flash"], losses["reference"],
+                                   rtol=1e-4)
+        assert losses["flash"][-1] < losses["flash"][0]
+
     def test_param_shardings_applied(self):
         cfg = llama_debug()
         mesh = build_mesh(MeshSpec.of(fsdp=4, tp=2))

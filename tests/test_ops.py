@@ -72,6 +72,77 @@ class TestFlashAttention:
         np.testing.assert_allclose(out, ref, atol=1e-6)
 
 
+class TestFlashUnderMesh:
+    """GSPMD cannot partition a Mosaic kernel, so with a mesh in scope the
+    dispatcher shard_maps the kernel over the axes the sharding rules
+    give batch and heads (ISSUE 21). Interpreted here; the chip's compiler
+    is asked in tests/test_tpu_compile.py."""
+
+    @staticmethod
+    def _run(mesh, q, k, v, grad=False):
+        def fn(q, k, v):
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                o = attention(q, k, v, causal=True, impl="flash")
+            return o.sum() if grad else o
+
+        fn = jax.grad(fn, argnums=(0, 1, 2)) if grad else fn
+        on_mesh = jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec())
+        return jax.jit(fn)(*jax.device_put((q, k, v), on_mesh))
+
+    @pytest.mark.parametrize("axes,hkv", [
+        (dict(fsdp=2, tp=2), 4),   # batch over fsdp, heads over tp
+        (dict(dp=2, fsdp=2), 4),   # batch over both data axes
+        (dict(fsdp=2, tp=2), 1),   # tp does not divide Hkv: heads whole
+        (dict(sp=4), 4),           # nothing shards batch or heads
+    ], ids=["fsdp_tp", "dp_fsdp", "gqa_heads_whole", "sp_only"])
+    def test_matches_reference_forward_and_grad(self, axes, hkv):
+        from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+        mesh = build_mesh(MeshSpec.of(**axes), devices=jax.devices()[:4])
+        q, k, v = _qkv(b=4, s=64, h=4, hkv=hkv)
+        ref = reference_attention(q, k, v, causal=True)
+        np.testing.assert_allclose(self._run(mesh, q, k, v), ref,
+                                   atol=2e-5, rtol=2e-5)
+        g_ref = jax.grad(
+            lambda q, k, v: reference_attention(q, k, v, causal=True).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(self._run(mesh, q, k, v, grad=True), g_ref):
+            np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
+
+    def test_shards_batch_and_heads_not_the_whole_arrays(self):
+        """The kernel sees its shard: [B/fsdp, S, H/tp, D]."""
+        import sys
+
+        from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+        # (the package re-exports the function under the module's name)
+        attn_mod = sys.modules["ray_tpu.ops.attention"]
+        seen = []
+        real = attn_mod.flash_attention
+
+        def spy(q, k, v, *a):
+            seen.append((q.shape, k.shape))
+            return real(q, k, v, *a)
+
+        mesh = build_mesh(MeshSpec.of(fsdp=2, tp=2), devices=jax.devices()[:4])
+        q, k, v = _qkv(b=4, s=64, h=4, hkv=2)
+        attn_mod.flash_attention = spy
+        try:
+            self._run(mesh, q, k, v)
+        finally:
+            attn_mod.flash_attention = real
+        assert seen == [((2, 64, 2, 32), (2, 64, 1, 32))]
+
+    def test_no_mesh_in_scope_calls_the_kernel_bare(self):
+        q, k, v = _qkv(s=64)
+        out = jax.jit(lambda q, k, v: attention(
+            q, k, v, causal=True, impl="flash"))(q, k, v)
+        np.testing.assert_allclose(
+            out, reference_attention(q, k, v, causal=True),
+            atol=2e-5, rtol=2e-5)
+
+
 class TestRingAttention:
     @pytest.mark.parametrize("causal", [True, False])
     def test_matches_full(self, causal):

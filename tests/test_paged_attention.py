@@ -52,7 +52,9 @@ def _mk_pools(rng, S, K, H, Hkv, D, T, P, lengths, garbage_fill=0.0):
             tables[s, j] = pid
             pid += 1
     q = rng.standard_normal((S, K, H, D)).astype(np.float32)
-    return (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+    # the arena's pool layout: one token's kv heads joined on the minor axis
+    return (jnp.asarray(q), jnp.asarray(kp.reshape(N, T, Hkv * D)),
+            jnp.asarray(vp.reshape(N, T, Hkv * D)),
             jnp.asarray(tables), jnp.asarray(np.asarray(lengths, np.int32)))
 
 
@@ -63,7 +65,7 @@ def _full_softmax_oracle(q, kp, vp, tables, lengths):
     q, kp, vp = np.asarray(q), np.asarray(kp), np.asarray(vp)
     tables, lengths = np.asarray(tables), np.asarray(lengths)
     S, K, H, D = q.shape
-    N, T, Hkv, _ = kp.shape
+    N, T, Hkv = kp.shape[0], kp.shape[1], kp.shape[2] // D
     P = tables.shape[1]
     G = H // Hkv
     sm = 1.0 / np.sqrt(D)
@@ -159,7 +161,7 @@ class TestPagedAttentionOp:
         for impl in ("reference", "pallas"):
             out = np.asarray(paged_attention(q, kp, vp, tables, lengths,
                                              impl=impl))
-            want = np.asarray(vp)[np.asarray(tables)[0, 0], 0]  # [Hkv, D]
+            want = np.asarray(vp)[np.asarray(tables)[0, 0], 0].reshape(2, 8)
             for h in range(4):
                 assert np.array_equal(out[0, 0, h], want[h // 2])
 

@@ -1,0 +1,273 @@
+"""What the chip's compiler accepts (ISSUE 21) — asked here, without the chip.
+
+libtpu compiles for a TPU that is described and not attached
+(``jax.experimental.topologies``), so the kernels and programs of the main
+path are compiled at their real widths against a ``v5e:2x2`` description:
+flash attention forward and forward+backward, paged attention (decode K=1,
+verify K=4), the GPT-2 small train step on one chip and sharded over four,
+and the three serve programs. Interpret mode cannot see what these see: a
+slice not aligned to the tiling, a kernel GSPMD cannot partition, a program
+that does not fit 16 GB. Nothing runs — a compile that passes is not a chip
+run.
+
+``jax.default_backend()`` says "cpu" here, so the fixture steers the code
+under test the way a TPU process would go: kernels compiled (not
+interpreted), ``auto`` lanes resolved for a TPU.
+"""
+
+import functools
+import os
+import re
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+
+HBM_BYTES = 16 * 1024**3
+GPT2S = dict(H=12, Hkv=12, D=64)
+GQA128 = dict(H=32, Hkv=8, D=128)  # llama3_8b's head shape
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+
+
+@pytest.fixture(autouse=True)
+def as_a_tpu_process(monkeypatch):
+    """Compile for the described chip: kernels go through Mosaic and
+    ``auto`` picks the TPU lanes. The persistent compile cache is off — a
+    described-device executable can be written but never read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _on(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _fits(compiled) -> int:
+    ma = compiled.memory_analysis()
+    total = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+             + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    assert total < HBM_BYTES, f"{total / 2**30:.1f} GiB does not fit 16 GB"
+    return total
+
+
+# ------------------------------------------------------------------ kernels
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("shape", [dict(B=16, S=1024, **GPT2S),
+                                   dict(B=2, S=2048, **GQA128)],
+                         ids=["gpt2s", "gqa_d128"])
+def test_flash_attention_compiles(v5e, shape, grad):
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    q = _on(chip, (shape["B"], shape["S"], shape["H"], shape["D"]))
+    kv = _on(chip, (shape["B"], shape["S"], shape["Hkv"], shape["D"]))
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, None, True)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    text = jax.jit(fn).lower(q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") >= (3 if grad else 1)
+
+
+@pytest.mark.parametrize("K", [1, 4], ids=["decode_k1", "verify_k4"])
+@pytest.mark.parametrize("shape", [GPT2S, GQA128], ids=["gpt2s", "gqa_d128"])
+def test_paged_attention_compiles(v5e, shape, K):
+    from ray_tpu.ops.paged_attention import (paged_attention,
+                                             pallas_shape_problem)
+
+    S, T, pages = 8, 16, 64
+    assert pallas_shape_problem(shape["Hkv"], shape["D"]) is None
+    chip = SingleDeviceSharding(v5e.devices[0])
+    pool = _on(chip, (S * pages + 1, T, shape["Hkv"] * shape["D"]))
+    text = jax.jit(functools.partial(paged_attention, impl="pallas")).lower(
+        _on(chip, (S, K, shape["H"], shape["D"])), pool, pool,
+        _on(chip, (S, pages), jnp.int32), _on(chip, (S,), jnp.int32),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+class TestPagedShapeRule:
+    """A pool the compiled kernel cannot take (llama_debug: 2 kv heads of
+    16 lanes) is refused where the lane is chosen, not at the first decode
+    step — and only where Mosaic would really have to compile it."""
+
+    def test_rule_names_the_problem(self):
+        from ray_tpu.ops.paged_attention import pallas_shape_problem
+
+        assert "128" in pallas_shape_problem(2, 16)      # llama_debug
+        assert "128" in pallas_shape_problem(3, 64)      # no pairing of 3
+        assert pallas_shape_problem(12, 64) is None      # 2 heads a window
+        assert pallas_shape_problem(4, 96) is None       # 4 heads a window
+        assert pallas_shape_problem(3, 128) is None      # 1 head a window
+
+    def test_uncompilable_pool_is_refused_at_trace_time(self, v5e):
+        from ray_tpu.ops.paged_attention import paged_attention
+
+        chip = SingleDeviceSharding(v5e.devices[0])
+        pool = _on(chip, (33, 16, 2 * 16), jnp.float32)
+        with pytest.raises(ValueError, match="cannot compile"):
+            jax.jit(functools.partial(paged_attention, impl="pallas")).lower(
+                _on(chip, (4, 1, 4, 16), jnp.float32), pool, pool,
+                _on(chip, (4, 8), jnp.int32), _on(chip, (4,), jnp.int32))
+
+    def test_explicit_pallas_raises_and_auto_takes_reference(self):
+        from ray_tpu.models import gpt2_small, llama_debug
+        from ray_tpu.ops.attention import resolve_paged_attn_lane
+
+        with pytest.raises(ValueError, match="cannot compile"):
+            resolve_paged_attn_lane("pallas", llama_debug())
+        assert resolve_paged_attn_lane("auto", llama_debug()) == "reference"
+        assert resolve_paged_attn_lane("auto", gpt2_small()) == "pallas"
+        assert resolve_paged_attn_lane("pallas", gpt2_small()) == "pallas"
+
+    def test_interpreted_kernel_takes_any_shape(self, monkeypatch):
+        """Off-TPU the kernel is interpreted: the PR 20 tests drive
+        llama_debug through the explicit 'pallas' lane on the CPU."""
+        from ray_tpu.models import llama_debug
+        from ray_tpu.ops.attention import resolve_paged_attn_lane
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        assert resolve_paged_attn_lane("pallas", llama_debug()) == "pallas"
+
+
+# ----------------------------------------------------------- train programs
+
+
+def _abstract_train_state(cfg, tx, mesh):
+    from ray_tpu.models.training import TrainState, _state_specs
+    from ray_tpu.models.transformer import init_params
+
+    def init(key):
+        params = init_params(cfg, key)
+        return TrainState(params=params, opt_state=tx.init(params),
+                          step=jnp.zeros((), jnp.int32))
+
+    abstract = jax.eval_shape(init, jax.random.PRNGKey(0))
+    if isinstance(mesh, Mesh):
+        shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                                 _state_specs(cfg, abstract, mesh, None))
+    else:
+        shardings = jax.tree.map(lambda _: mesh, abstract)
+    return jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        abstract, shardings)
+
+
+def test_gpt2s_train_step_fits_one_chip(v5e):
+    from ray_tpu.models import gpt2_small
+    from ray_tpu.models.training import (OptimizerConfig, make_optimizer,
+                                         make_train_step)
+
+    cfg, tx = gpt2_small(), make_optimizer(OptimizerConfig())
+    chip = SingleDeviceSharding(v5e.devices[0])
+    compiled = make_train_step(cfg, tx).lower(
+        _abstract_train_state(cfg, tx, chip),
+        {"tokens": _on(chip, (16, 1024), jnp.int32)}).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+def test_gpt2s_sharded_train_step_lowers_with_the_kernel_in_it(v5e):
+    """fsdp=2 x tp=2 over four described chips. GSPMD cannot partition a
+    Mosaic kernel; the dispatcher shard_maps it over the mesh in scope, so
+    the step lowers with the kernel inside and q/k/v are never gathered
+    to full batch or full heads in front of it."""
+    from ray_tpu.models import gpt2_small
+    from ray_tpu.models.training import (OptimizerConfig, make_optimizer,
+                                         make_train_step)
+    from ray_tpu.parallel.mesh import data_sharding
+
+    cfg, tx = gpt2_small(), make_optimizer(OptimizerConfig())
+    mesh = Mesh(np.array(v5e.devices[:4]).reshape(2, 2), ("fsdp", "tp"))
+    compiled = make_train_step(cfg, tx, mesh).lower(
+        _abstract_train_state(cfg, tx, mesh),
+        {"tokens": _on(data_sharding(mesh), (16, 1024), jnp.int32)}).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    gathers = re.findall(
+        r"= \(?bf16\[([0-9,]+)\][^=\n]* all-gather(?:-start)?\(", text)
+    # per shard q/k/v are [8, 1024, 6, 64] (or head-major): a gather back
+    # to batch 16 or to 12 heads would show one of these
+    whole = {"16,1024,6,64", "8,1024,12,64", "16,1024,12,64",
+             "16,6,1024,64", "8,12,1024,64", "16,12,1024,64"}
+    assert gathers and not whole & set(gathers), whole & set(gathers)
+    _fits(compiled)
+
+
+# ----------------------------------------------------------- serve programs
+
+
+def test_gpt2s_serve_programs_compile_and_fit(v5e):
+    """Prefill chunk, decode and verify at the scheduler's defaults for
+    GPT-2 small (8 slots, 32-token chunks, 16-token pages, K=4), on the
+    lane a TPU replica resolves."""
+    from ray_tpu._private.config import Config
+    from ray_tpu.models import gpt2_small
+    from ray_tpu.models.decode import (init_paged_caches, paged_decode_step,
+                                       paged_prefill_into_slot,
+                                       paged_verify_step)
+    from ray_tpu.models.transformer import init_params
+    from ray_tpu.ops.attention import resolve_paged_attn_lane
+
+    cfg, conf = gpt2_small(), Config()
+    slots, chunk, T = conf.serve_slots, conf.serve_prefill_chunk, \
+        conf.serve_page_tokens
+    pages = cfg.max_seq_len // T
+    lane = resolve_paged_attn_lane("auto", cfg)
+    assert lane == "pallas"
+    chip = SingleDeviceSharding(v5e.devices[0])
+
+    def place(tree):
+        return jax.tree.map(lambda a: _on(chip, a.shape, a.dtype), tree)
+
+    params = place(jax.eval_shape(
+        functools.partial(init_params, cfg), jax.random.PRNGKey(0)))
+    caches = place(jax.eval_shape(functools.partial(
+        init_paged_caches, cfg, slots, slots * pages + 1, T, pages)))
+    table = _on(chip, (slots, pages), jnp.int32)
+    row = _on(chip, (pages,), jnp.int32)
+    ids = functools.partial(_on, chip, dtype=jnp.int32)
+    programs = {
+        "prefill": (paged_prefill_into_slot,
+                    (params, ids((1, chunk)), ids(()), ids(()), row, row,
+                     caches), 6),
+        "decode": (paged_decode_step,
+                   (params, ids((slots,)), ids((slots,)), table, table,
+                    caches), 5),
+        "verify": (paged_verify_step,
+                   (params, ids((slots, conf.serve_spec_k)), table, table,
+                    caches), 4),
+    }
+    for name, (program, args, donated) in programs.items():
+        compiled = jax.jit(functools.partial(program, cfg, attn=lane),
+                           donate_argnums=(donated,)).lower(*args).compile()
+        assert "tpu_custom_call" in compiled.as_text(), name
+        _fits(compiled)
