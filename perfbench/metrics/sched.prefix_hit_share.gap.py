@@ -1,0 +1,8 @@
+"""sched.prefix_hit_share.gap: ``sched.prefix_hit_share`` in the cells that report ``gap_p95_ms`` and not
+``serve_tokens_per_s`` (the same reader; see ``sched.prefix_hit_share.py``). Moves gap_p95_ms."""
+
+from perfbench.lib import manifest
+
+
+def read(ctx):
+    return manifest.metric_reader("sched.prefix_hit_share")(ctx)
