@@ -1,0 +1,8 @@
+"""step.decode_ms.gap: ``step.decode_ms`` in the cells that report ``gap_p95_ms`` and not
+``serve_tokens_per_s`` (the same reader; see ``step.decode_ms.py``). Moves gap_p95_ms."""
+
+from perfbench.lib import manifest
+
+
+def read(ctx):
+    return manifest.metric_reader("step.decode_ms")(ctx)

@@ -1,0 +1,8 @@
+"""step.prefill_share.gap: ``step.prefill_share`` in the cells that report ``gap_p95_ms`` and not
+``serve_tokens_per_s`` (the same reader; see ``step.prefill_share.py``). Moves gap_p95_ms."""
+
+from perfbench.lib import manifest
+
+
+def read(ctx):
+    return manifest.metric_reader("step.prefill_share")(ctx)

@@ -31,6 +31,14 @@ Kinds: BEGIN/END (nesting duration events), INSTANT (point + arg),
 SPAN (t_ns = end, arg = duration ns — one record per completed wait),
 COUNTER (arg = value; rendered as a Perfetto counter track).
 
+Regions (``PhaseClock``) cut ONE thread's whole time into named leaf
+phases: a transition closes the open phase and opens the next with one
+clock read, and feeds three sinks — a SPAN record in the ring, the same
+interval as a host event of the JAX profiler's own trace (so it shares the
+device trace's clock while a ``jax.profiler`` session is open; only in a
+process that has imported JAX already — this module never imports it), and
+cumulative nanoseconds per phase for ``stats()``-style counters.
+
 Knobs: ``RAY_TPU_FLIGHT_ENABLED`` (default on), and
 ``RAY_TPU_FLIGHT_BUFFER_RECORDS`` (per-thread ring capacity).
 """
@@ -39,9 +47,10 @@ from __future__ import annotations
 
 import os
 import struct
+import sys
 import threading
 import time
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 _REC = struct.Struct("<QQHBB")
 REC_SIZE = _REC.size  # 20
@@ -261,6 +270,95 @@ def span(name: str) -> _Span:
     """``with flight.span("phase"):`` convenience (interns per call — hot
     loops should hold the id and use ``now()``/``span_since`` instead)."""
     return _Span(intern(name))
+
+
+# ---------------------------------------------------------------- regions
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` if THIS process has imported JAX,
+    else None: the controller, the supervisor and CPU workers stay off JAX,
+    and a region there is a ring record only. With no profiler session open
+    an annotation is a flag test in C++ (measured 0.3-0.4 us a region)."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    return getattr(profiler, "TraceAnnotation", None)
+
+
+class PhaseClock:
+    """One thread's time as a sequence of named leaf phases.
+
+    ``switch(i)`` closes the open phase and opens ``names[i]`` with ONE
+    clock read; phases never nest and never overlap, so between ``start``
+    and ``stop`` their durations sum to the thread's wall time. Only the
+    owning thread calls ``switch``/``lap``/``stop``; ``seconds()`` may be
+    read from any thread (it counts the open phase up to now). With the
+    recorder off nothing is stamped and every total stays 0.
+    """
+
+    __slots__ = ("names", "_ids", "_ns", "_cur", "_t0", "_annotate",
+                 "_event", "_lap_ns", "_lap_phase")
+
+    def __init__(self, names: Sequence[str]):
+        self.names = tuple(names)
+        self._ids = [intern(n) for n in self.names]
+        self._ns = [0] * len(self.names)
+        self._cur = -1
+        self._t0 = 0
+        self._annotate = _profiler_annotation()
+        self._event = None
+        self._lap_ns = 0
+        self._lap_phase = -1
+
+    def switch(self, phase: int, _pcn=time.perf_counter_ns) -> None:
+        cur = self._cur
+        if phase == cur:
+            return
+        if not _enabled:
+            if cur >= 0:  # switched off mid-run: drop the open phase
+                self._close_event()
+                self._cur = -1
+            return
+        t = _pcn()
+        if cur >= 0:
+            dur = t - self._t0
+            self._ns[cur] += dur
+            if dur > self._lap_ns:
+                self._lap_ns, self._lap_phase = dur, cur
+            _record(self._ids[cur], SPAN, t, dur)
+            self._close_event()
+        self._t0 = t
+        self._cur = phase
+        if phase >= 0 and self._annotate is not None:
+            event = self._annotate(self.names[phase])
+            event.__enter__()
+            self._event = event
+
+    def _close_event(self) -> None:
+        event, self._event = self._event, None
+        if event is not None:
+            event.__exit__(None, None, None)
+
+    def stop(self) -> None:
+        """Close the open phase; the clock stands until the next switch."""
+        self.switch(-1)
+
+    def lap(self) -> Tuple[int, int, int]:
+        """``(t_ns, phase, ns)``: when the last transition happened, and the
+        longest single phase closed since the previous ``lap`` (-1, 0 if
+        none). A loop calls it once a turn, right after a ``switch``."""
+        out = (self._t0, self._lap_phase, self._lap_ns)
+        self._lap_ns, self._lap_phase = 0, -1
+        return out
+
+    def seconds(self) -> List[float]:
+        """Cumulative seconds per phase, the open one counted up to now."""
+        # a racy read from another thread is off by at most the phase a
+        # concurrent transition just closed
+        t0, cur = self._t0, self._cur
+        ns = list(self._ns)
+        if cur >= 0 and _enabled:
+            ns[cur] += max(time.perf_counter_ns() - t0, 0)
+        return [n / 1e9 for n in ns]
 
 
 # ------------------------------------------------------------------ drain

@@ -192,6 +192,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, block_h,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
+        name="flash_attention_fwd",
         interpret=interpret,
     )(q, k, v)
     return out, lse
@@ -324,6 +325,7 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
+        name="flash_attention_bwd_dq",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
@@ -351,6 +353,7 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
+        name="flash_attention_bwd_dkv",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

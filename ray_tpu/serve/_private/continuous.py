@@ -65,24 +65,50 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from functools import partial
 from queue import Empty as _QueueEmpty
 from queue import Queue as _Queue
 from typing import Any, Dict, List, Optional
 
 from ray_tpu._private import flight
-from ray_tpu._private.metrics import Counter, Gauge
+from ray_tpu._private.metrics import Counter, Gauge, Histogram
 
-# flight-recorder span ids: the per-iteration admit/prefill/decode/retire
-# phases the aggregate counters can't localize (per-thread ring records,
-# no locks/RPCs — safe at decode-iteration rates)
+# The scheduler thread's time, cut into leaf phases by ONE clock
+# (``flight.PhaseClock``): a transition closes the open phase and opens the
+# next. Each phase is a SPAN in the flight ring, a host event of an open
+# ``jax.profiler`` session (so an idle gap of the device trace is labelled
+# by the phase that overlaps it) and cumulative seconds in ``stats()``
+# (``phase_<name>_s``). No phase encloses another and none adds a device
+# wait: the two ``*.wait`` phases stand directly before a host read that
+# would block anyway. The transitions of a loop turn do not grow with the
+# number of slots.
+PHASES = (
+    "serve.admit",           # commands, migrations, admission, slot reset
+    "serve.prefill",         # build, upload and dispatch of one chunk
+    "serve.prefill.wait",    # last chunk only: its logits are sampled from
+    "serve.decode.prepare",  # arrays, pages, table upload, dispatch
+    "serve.decode.wait",     # the device is working on the step
+    "serve.decode.fetch",    # the step's logits to the host
+    "serve.sample",          # sampling and per-sequence bookkeeping
+    "serve.emit",            # hand-off to the consumers' event loop, retire
+    "serve.park",            # nothing to do: waiting to be woken
+    "serve.verify",          # speculative path: the verify call and fetch
+    "serve.migrate",         # fleet path: splicing pulled prefix pages
+)
+(_P_ADMIT, _P_PREFILL, _P_PREFILL_WAIT, _P_PREPARE, _P_WAIT, _P_FETCH,
+ _P_SAMPLE, _P_EMIT, _P_PARK, _P_VERIFY, _P_MIGRATE) = range(len(PHASES))
+_PHASE_KEYS = tuple("phase_" + n[len("serve."):].replace(".", "_") + "_s"
+                    for n in PHASES)
+
+# a request's life, as instants that carry the request's id
+_F_QUEUED = flight.intern("serve.req.queued")
 _F_ADMIT = flight.intern("serve.admit")
-_F_PREFILL = flight.intern("serve.prefill")
-_F_DECODE = flight.intern("serve.decode")
+_F_FIRST_TOKEN = flight.intern("serve.first_token")
 _F_RETIRE = flight.intern("serve.retire")
-_F_VERIFY = flight.intern("serve.verify")
-_F_MIGRATE = flight.intern("serve.migrate")
-_F_ATTN = flight.intern("serve.attn")
+# a loop turn that took longer than _STALL_NS while a slot was live; the
+# instant's argument is ``microseconds << 8 | index into PHASES`` of the
+# phase that held most of it
+_F_STALL = flight.intern("serve.stall")
+_STALL_NS = 1_000_000_000  # four slow turns of 0.16-0.27 s (PERF.md)
 
 _m_steps = Counter(
     "ray_tpu_serve_decode_steps_total",
@@ -111,6 +137,9 @@ _m_attn_bytes = Counter(
 _m_queue_depth = Gauge(
     "ray_tpu_serve_queue_depth",
     "Requests waiting for a free KV arena slot")
+_m_queue_wait = Histogram(
+    "ray_tpu_serve_queue_wait_seconds",
+    "Time a request waited from submit to admission into a slot")
 
 # sequence states
 _QUEUED = "queued"
@@ -123,18 +152,30 @@ class SchedulerClosedError(RuntimeError):
     pass
 
 
+def _program(fn, name: str, cfg, **keywords):
+    """``fn(cfg, *args, **keywords)`` as a function called ``name``: what
+    ``jax.jit`` compiles is the XLA module ``jit_<name>``, which is how a
+    profiler trace tells the scheduler's programs apart (a
+    ``functools.partial`` has no name: ``jit__unknown``)."""
+    def program(*args):
+        return fn(cfg, *args, **keywords)
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
 class _Seq:
     """One in-flight generation request and its consumer-side queue."""
 
     __slots__ = ("prompt", "remaining_prompt", "max_new", "temperature",
                  "seed", "slot", "state", "n_generated", "next_token",
-                 "queue", "loop", "cancelled", "t_submit", "t_first_token",
-                 "rng", "cached_len", "cursor", "owned_pages", "radix_node",
+                 "queue", "loop", "cancelled", "rid", "t_submit", "t_admit",
+                 "t_first_token", "rng", "cached_len", "cursor", "owned_pages", "radix_node",
                  "table_fill", "fleet_hint", "migration_node",
                  "drafter_len", "drafter_pending")
 
     def __init__(self, prompt: List[int], max_new: int, temperature: float,
                  seed: int, loop, queue):
+        self.rid = 0  # set at submit; carried by this request's instants
         self.prompt = prompt
         self.remaining_prompt = list(prompt)
         self.max_new = max_new
@@ -148,6 +189,7 @@ class _Seq:
         self.loop = loop
         self.cancelled = False
         self.t_submit = time.monotonic()
+        self.t_admit: Optional[float] = None
         self.t_first_token: Optional[float] = None
         self.rng = None  # lazily created numpy Generator for temperature > 0
         # ---- paged-arena bookkeeping (host mirrors of device state) ----
@@ -275,11 +317,11 @@ class ContinuousScheduler:
             # donated caches: the pool mutates in place across iterations;
             # the tables are tiny per-call host->device uploads
             self._prefill = jax.jit(
-                partial(paged_prefill_into_slot, cfg, attn=self.attn_lane),
-                donate_argnums=(6,))
+                _program(paged_prefill_into_slot, "paged_prefill_chunk", cfg,
+                         attn=self.attn_lane), donate_argnums=(6,))
             self._step = jax.jit(
-                partial(paged_decode_step, cfg, attn=self.attn_lane),
-                donate_argnums=(5,))
+                _program(paged_decode_step, "paged_decode_step", cfg,
+                         attn=self.attn_lane), donate_argnums=(5,))
             self._caches = init_paged_caches(
                 cfg, self.slots, self.num_pages, self.page_tokens,
                 self._pages_per_slot, cache_dtype)
@@ -310,10 +352,12 @@ class ContinuousScheduler:
             self._pages_per_slot = 0
             self.num_pages = 0
             # donated caches: the arena mutates in place across iterations
-            self._prefill = jax.jit(partial(prefill_into_slot, cfg),
-                                    donate_argnums=(4,))
-            self._step = jax.jit(partial(slot_decode_step, cfg),
-                                 donate_argnums=(3,))
+            self._prefill = jax.jit(
+                _program(prefill_into_slot, "slot_prefill_chunk", cfg),
+                donate_argnums=(4,))
+            self._step = jax.jit(
+                _program(slot_decode_step, "slot_decode_step", cfg),
+                donate_argnums=(3,))
             self._caches = init_slot_caches(cfg, self.slots, self.arena_len,
                                             cache_dtype)
         # ---- speculative decoding (ISSUE 18): the drafter proposes, one
@@ -344,8 +388,8 @@ class ContinuousScheduler:
             from ray_tpu.models.decode import paged_verify_step
 
             self._verify = jax.jit(
-                partial(paged_verify_step, cfg, attn=self.attn_lane),
-                donate_argnums=(4,))
+                _program(paged_verify_step, "paged_verify_step", cfg,
+                         attn=self.attn_lane), donate_argnums=(4,))
         # ---- cross-replica page migration (ISSUE 18): a dedicated
         # worker thread does the blocking peer pull; the scheduler thread
         # only splices finished results between iterations. _commands
@@ -381,6 +425,15 @@ class ContinuousScheduler:
         self._admitted_mid_flight = 0
         self._max_active_slots = 0
         self._peak_queue_depth = 0
+        # a request's life and the loop's own time (README "Observability")
+        self._n_submitted = 0
+        self._queue_wait_s = 0.0
+        self._first_token_wait_s = 0.0
+        self._n_first_tokens = 0
+        self._stall_s = 0.0
+        self._n_stalls = 0
+        self._stall_phase = ""
+        self._clock = flight.PhaseClock(PHASES)
         self._thread = threading.Thread(
             target=self._run, name="serve-continuous-scheduler", daemon=True)
         self._thread.start()
@@ -409,10 +462,18 @@ class ContinuousScheduler:
 
     def submit(self, prompt_ids: List[int], *, max_new_tokens: int,
                temperature: float = 0.0, seed: int = 0,
-               loop=None, queue=None, fleet_hint=None) -> _Seq:
+               loop=None, queue=None, fleet_hint=None,
+               request_id: Optional[int] = None) -> _Seq:
         """Enqueue a generation. Tokens/end/error events arrive on ``queue``
-        via ``loop.call_soon_threadsafe`` as ``("tok", id)``, ``("end",
-        reason)`` or ``("err", message)`` tuples. Thread/loop-safe.
+        via ``loop.call_soon_threadsafe`` as ``("tok", id, stamp)``,
+        ``("end", reason, stamp)`` or ``("err", message, stamp)`` tuples;
+        ``stamp`` is the recorder's clock (``perf_counter_ns``) at the
+        hand-off, 0 with the recorder off. Thread/loop-safe.
+
+        ``request_id`` is what the request's flight instants
+        (``serve.req.queued/admit/first_token/retire``) carry; the replica
+        passes its own request counter, and without one the scheduler
+        counts submissions.
 
         ``fleet_hint`` (router-attached): ``{"handle": holder_replica,
         "tokens": matched_depth}`` — before admission the scheduler pulls
@@ -437,10 +498,14 @@ class ContinuousScheduler:
                 raise SchedulerClosedError(
                     "scheduler is shut down" if self._error is None
                     else f"scheduler failed: {self._error!r}")
+            self._n_submitted += 1
+            seq.rid = (self._n_submitted if request_id is None
+                       else int(request_id))
             self._pending.append(seq)
             self._peak_queue_depth = max(self._peak_queue_depth,
                                          len(self._pending))
             _m_queue_depth.set(float(len(self._pending)))
+        flight.instant(_F_QUEUED, seq.rid)
         self._wake.set()
         return seq
 
@@ -452,11 +517,15 @@ class ContinuousScheduler:
 
     # -------------------------------------------------------------- loop
 
-    def _emit(self, seq: _Seq, item) -> None:
+    def _emit(self, seq: _Seq, kind: str, value) -> None:
+        """Hand one item to the consumer's event loop, stamped with the
+        recorder's clock so the receiving side can count how long it lay
+        between the two threads (``stream_lag_s`` in the replica)."""
         if seq.loop is None or seq.queue is None:
             return
         try:
-            seq.loop.call_soon_threadsafe(seq.queue.put_nowait, item)
+            seq.loop.call_soon_threadsafe(
+                seq.queue.put_nowait, (kind, value, flight.now()))
         except RuntimeError:
             # consumer's loop is gone — nobody is listening; retire quietly
             seq.cancelled = True
@@ -490,13 +559,13 @@ class ContinuousScheduler:
         self._release_migration_ref(seq)
         self._release_slot_resources(seq)
         if seq.slot is not None:
-            flight.instant(_F_RETIRE, seq.slot)
             self._slot_seqs[seq.slot] = None
             seq.slot = None
+        flight.instant(_F_RETIRE, seq.rid)
         seq.state = _DONE
         self._n_retired += 1
         _m_retired.inc()
-        self._emit(seq, ("end", reason))
+        self._emit(seq, "end", reason)
 
     def _fail(self, seq: _Seq, msg: str) -> None:
         self._release_migration_ref(seq)
@@ -504,10 +573,11 @@ class ContinuousScheduler:
         if seq.slot is not None:
             self._slot_seqs[seq.slot] = None
             seq.slot = None
+        flight.instant(_F_RETIRE, seq.rid)
         seq.state = _DONE
         self._n_retired += 1
         _m_retired.inc()
-        self._emit(seq, ("err", msg))
+        self._emit(seq, "err", msg)
 
     def _ensure_pages(self, seq: _Seq, upto: int) -> bool:
         """Grow the slot's page table so its logical view covers
@@ -563,7 +633,10 @@ class ContinuousScheduler:
         _m_tokens.inc()
         if seq.t_first_token is None:
             seq.t_first_token = time.monotonic()
-        self._emit(seq, ("tok", tok))
+            self._first_token_wait_s += seq.t_first_token - seq.t_admit
+            self._n_first_tokens += 1
+            flight.instant(_F_FIRST_TOKEN, seq.rid)
+        self._emit(seq, "tok", tok)
         if self.eos_id is not None and tok == self.eos_id:
             return True
         return seq.n_generated >= seq.max_new
@@ -641,17 +714,22 @@ class ContinuousScheduler:
             else:
                 self._caches = reset_slot(self._caches, free)
             self._n_admitted += 1
-            flight.instant(_F_ADMIT, free)
+            seq.t_admit = time.monotonic()
+            waited = seq.t_admit - seq.t_submit
+            self._queue_wait_s += waited
+            _m_queue_wait.observe(waited)
+            flight.instant(_F_ADMIT, seq.rid)
             _m_admitted.inc()
             if in_flight:
                 # the signal request-level flush-and-drain cannot produce:
                 # an admission while other sequences are mid-generation
                 self._admitted_mid_flight += 1
 
-    def _record_attn(self, t0: int, qk: int, n_slots: int,
+    def _record_attn(self, qk: int, n_slots: int,
                      longest: Optional[int] = None) -> None:
-        """Stamp the ``serve.attn`` span and account the KV bytes the
-        attention lane streamed for one attention-bearing program call.
+        """Account the KV bytes the attention lane streamed for one
+        attention-bearing program call (its device time is read from a
+        profiler trace, by the program's name and the kernel's).
         Pure host-side mirror arithmetic (cursors, table shapes) — no
         device readback on the hot loop. The gather lane materializes a
         contiguous ``[pages_per_slot * page_tokens]`` view per slot per
@@ -659,7 +737,6 @@ class ContinuousScheduler:
         stream only pages covering the longest live sequence."""
         if not self._paged:
             return
-        flight.span_since(_F_ATTN, t0)
         cfg = self.cfg
         T = self.page_tokens
         row = cfg.kv_heads * cfg.head_dim * self._kv_itemsize
@@ -686,12 +763,14 @@ class ContinuousScheduler:
         import jax.numpy as jnp
         import numpy as np
 
+        switch = self._clock.switch
         start = self._prefill_rr
         for off in range(self.slots):
             i = (start + off) % self.slots
             seq = self._slot_seqs[i]
             if seq is None or seq.state != _PREFILL:
                 continue
+            switch(_P_PREFILL)
             self._prefill_rr = (i + 1) % self.slots
             if seq.cancelled:
                 self._retire(seq, "cancelled")
@@ -710,27 +789,26 @@ class ContinuousScheduler:
             real = len(chunk)
             padded = chunk + [0] * (self.prefill_chunk - real)
             tokens = jnp.asarray([padded], jnp.int32)
-            t0 = flight.now()
             if self._paged:
+                # the rows are uploaded as COPIES: dispatch is async and an
+                # upload may alias (CPU) or still be reading (TPU) the host
+                # buffer, while _offer_prompt_pages and _ensure_pages write
+                # to these rows before anything waits for this chunk
                 logits, self._caches = self._prefill(
                     self.params, tokens, np.int32(real), np.int32(seq.slot),
-                    jnp.asarray(self._read_tables[seq.slot]),
-                    jnp.asarray(self._write_tables[seq.slot]),
+                    jnp.asarray(self._read_tables[seq.slot].copy()),
+                    jnp.asarray(self._write_tables[seq.slot].copy()),
                     self._caches)
                 seq.cursor += real
             else:
                 logits, self._caches = self._prefill(
                     self.params, tokens, np.int32(real), np.int32(seq.slot),
                     self._caches)
-            if t0:
-                # jax dispatch is async: without a sync the span would
-                # time the DISPATCH and smear the real prefill compute
-                # into the next decode region (the decode span gets its
-                # sync from the np.asarray below)
-                jax.block_until_ready(logits)
-            flight.span_since(_F_PREFILL, t0)
+            # dispatch is async and stays so: the chunk's device time is
+            # read from a profiler trace by the program's name, and the
+            # wait for it falls into the next phase that reads a result
             if self._paged:
-                self._record_attn(t0, self.prefill_chunk, 1,
+                self._record_attn(self.prefill_chunk, 1,
                                   longest=seq.cursor - real)
             self._n_prefill_chunks += 1
             _m_prefill_chunks.inc()
@@ -739,9 +817,15 @@ class ContinuousScheduler:
                 self._offer_prompt_pages(seq)
             if not seq.remaining_prompt:
                 # prompt fully resident: sample the first token NOW — this
-                # is the time-to-first-token moment
+                # is the time-to-first-token moment. The host read below
+                # blocks until the chunk is done, so the wait for the
+                # device gets a phase of its own and adds no sync
+                switch(_P_PREFILL_WAIT)
+                jax.block_until_ready(logits)
+                switch(_P_SAMPLE)
                 tok = self._sample(seq, logits)
                 seq.state = _DECODE
+                switch(_P_EMIT)
                 if self._emit_token(seq, tok):
                     self._retire(seq, "length" if self.eos_id is None
                                  or tok != self.eos_id else "eos")
@@ -878,10 +962,12 @@ class ContinuousScheduler:
                   and int(res.get("matched_len") or 0) > 0
                   and int(res.get("page_tokens") or 0) == self.page_tokens)
             if ok:
+                self._clock.switch(_P_MIGRATE)
                 try:
                     self._splice_migrated(seq, res)
                 except Exception:  # noqa: BLE001 — abandon to cold prefill
                     self._n_migration_failures += 1
+                self._clock.switch(_P_ADMIT)
             else:
                 self._n_migration_failures += 1
             self._requeue(seq)
@@ -907,7 +993,6 @@ class ContinuousScheduler:
         n = matched // T
         if n <= 0:
             raise ValueError("empty migration payload")
-        t0 = flight.now()
         try:
             pages = self._arena.alloc(n)
         except OutOfPagesError:
@@ -936,7 +1021,6 @@ class ContinuousScheduler:
         self._n_migrated_pages += n - len(dups)
         m_migrations.inc()
         m_migrated_pages.inc(n - len(dups))
-        flight.span_since(_F_MIGRATE, t0)
 
     # -------------------------------------------------- prefix export
 
@@ -1053,6 +1137,8 @@ class ContinuousScheduler:
 
         k = self.spec_k
         K = k + 1
+        switch = self._clock.switch
+        switch(_P_PREPARE)  # drafting is this path's preparation
         live: List[_Seq] = []
         for seq in self._slot_seqs:
             if seq is None or seq.state != _DECODE:
@@ -1102,14 +1188,14 @@ class ContinuousScheduler:
         for s in live:
             row = [s.next_token] + drafts[s.slot]
             vt[s.slot, :len(row)] = row
-        t0 = flight.now()
+        switch(_P_VERIFY)
         vlogits, self._caches = self._verify(
             self.params, jnp.asarray(vt),
             jnp.asarray(self._read_tables),
             jnp.asarray(self._write_tables), self._caches)
         va = np.asarray(vlogits)
-        flight.span_since(_F_VERIFY, t0)
-        self._record_attn(t0, K, self.slots)
+        switch(_P_EMIT)  # acceptance, emission and the cursor rewind
+        self._record_attn(K, self.slots)
         self._n_steps += 1
         _m_steps.inc()
         self._n_spec_rounds += 1
@@ -1168,6 +1254,8 @@ class ContinuousScheduler:
         import jax.numpy as jnp
         import numpy as np
 
+        switch = self._clock.switch
+        switch(_P_PREPARE)
         toks = np.zeros(self.slots, np.int32)
         active = np.zeros(self.slots, np.int32)
         live: List[_Seq] = []
@@ -1184,7 +1272,6 @@ class ContinuousScheduler:
             live.append(seq)
         if not live:
             return False
-        t0 = flight.now()
         if self._paged:
             logits, self._caches = self._step(
                 self.params, jnp.asarray(toks), jnp.asarray(active),
@@ -1194,15 +1281,25 @@ class ContinuousScheduler:
             logits, self._caches = self._step(
                 self.params, jnp.asarray(toks), jnp.asarray(active),
                 self._caches)
-        la = np.asarray(logits)
-        flight.span_since(_F_DECODE, t0)
-        self._record_attn(t0, 1, self.slots)
+        self._record_attn(1, self.slots)
         self._n_steps += 1
         _m_steps.inc()
         self._max_active_slots = max(self._max_active_slots, len(live))
+        # the fetch below would block until the step is done: the wait for
+        # the device and the copy to the host are told apart, no sync added
+        switch(_P_WAIT)
+        self._jax.block_until_ready(logits)
+        switch(_P_FETCH)
+        la = np.asarray(logits)
+        # sample every live sequence, then emit: two transitions a step
+        # however many slots are live
+        switch(_P_SAMPLE)
+        sampled = []
         for seq in live:
             seq.cursor += 1
-            tok = self._sample(seq, la[seq.slot])
+            sampled.append(self._sample(seq, la[seq.slot]))
+        switch(_P_EMIT)
+        for seq, tok in zip(live, sampled):
             if self._emit_token(seq, tok):
                 self._retire(seq, "eos" if self.eos_id is not None
                              and tok == self.eos_id else "length")
@@ -1211,8 +1308,16 @@ class ContinuousScheduler:
         return True
 
     def _run(self) -> None:
+        clock = self._clock
+        t_turn = 0  # when the previous turn ended (0: no turn to compare)
         try:
             while True:
+                clock.switch(_P_ADMIT)
+                t_now, held_by, _ = clock.lap()
+                if t_turn and t_now - t_turn > _STALL_NS and any(
+                        s is not None for s in self._slot_seqs):
+                    self._note_stall(t_now - t_turn, held_by)
+                t_turn = t_now
                 with self._lock:
                     if self._closed:
                         break
@@ -1229,6 +1334,7 @@ class ContinuousScheduler:
                 _m_active.set(float(sum(
                     1 for s in self._slot_seqs if s is not None)))
                 if not did:
+                    clock.switch(_P_PARK)
                     with self._lock:
                         idle = (not self._pending and not self._commands
                                 and not self._migrating and all(
@@ -1254,9 +1360,19 @@ class ContinuousScheduler:
             self._migrating.clear()
             self._drain_commands("scheduler crashed")
         finally:
+            clock.stop()
             with self._lock:
                 self._closed = True
             _m_active.set(0.0)
+
+    def _note_stall(self, ns: int, phase: int) -> None:
+        """A loop turn, end to end, took longer than ``_STALL_NS`` while a
+        slot was live: count the time beyond the limit and which phase
+        held most of the turn."""
+        self._stall_s += (ns - _STALL_NS) / 1e9
+        self._n_stalls += 1
+        self._stall_phase = PHASES[phase]
+        flight.instant(_F_STALL, (ns // 1000) << 8 | phase)
 
     # --------------------------------------------------------- lifecycle
 
@@ -1336,7 +1452,19 @@ class ContinuousScheduler:
             "queue_depth": q,
             "active_slots": sum(1 for s in self._slot_seqs if s is not None),
             "compiled_programs": self.compiled_programs(),
+            # a request's life: submit -> admit (count: admitted) and
+            # admit -> first token (count: first_tokens), summed
+            "queue_wait_s": self._queue_wait_s,
+            "first_token_wait_s": self._first_token_wait_s,
+            "first_tokens": self._n_first_tokens,
+            # loop turns longer than 1 s while a slot was live: the time
+            # beyond it, how many, and the phase that held the last one
+            "stall_s": self._stall_s,
+            "stalls": self._n_stalls,
+            "stall_phase": self._stall_phase,
         }
+        # the scheduler thread's own time by phase (0 with the recorder off)
+        out.update(zip(_PHASE_KEYS, self._clock.seconds()))
         if self._paged:
             out["page_tokens"] = self.page_tokens
             out["pages_per_slot"] = self._pages_per_slot

@@ -36,6 +36,7 @@ loader for the real thing.
 from __future__ import annotations
 
 import asyncio
+import time
 from functools import partial
 from typing import Any, Dict, List, Optional
 
@@ -107,6 +108,10 @@ class LLMServerImpl:
         self._scheduler_mode = scheduler
         self._eos_id = eos_id
         self._seq_counter = 0
+        # the scheduler thread -> event loop hand-off: how long the items
+        # received so far lay between the two (scheduler_stats())
+        self._stream_lag_ns = 0
+        self._stream_tokens = 0
 
         # ---- weights: one arena copy per node (ISSUE 9 tentpole) ----
         from ray_tpu.serve._private import weights as _weights
@@ -247,8 +252,18 @@ class LLMServerImpl:
         seq = self._sched.submit(
             ids, max_new_tokens=max_new, temperature=temperature,
             seed=self._seq_counter, loop=loop, queue=q,
-            fleet_hint=fleet_hint)
+            fleet_hint=fleet_hint, request_id=self._seq_counter)
         return seq, q
+
+    async def _next_item(self, q: asyncio.Queue):
+        """The next ``(kind, value)`` the scheduler handed to this request;
+        for a token, the time since the scheduler stamped it is counted
+        (stamp 0: recorder off, nothing is counted)."""
+        kind, val, stamp = await q.get()
+        if stamp and kind == "tok":
+            self._stream_lag_ns += time.perf_counter_ns() - stamp
+            self._stream_tokens += 1
+        return kind, val
 
     async def _run_continuous(self, ids: List[int], max_new: int,
                               temperature: float,
@@ -257,7 +272,7 @@ class LLMServerImpl:
         toks: List[int] = []
         try:
             while True:
-                kind, val = await q.get()
+                kind, val = await self._next_item(q)
                 if kind == "tok":
                     toks.append(val)
                 elif kind == "end":
@@ -276,7 +291,7 @@ class LLMServerImpl:
         seq, q = self._submit(ids, max_new, temperature, fleet_hint)
         try:
             while True:
-                kind, val = await q.get()
+                kind, val = await self._next_item(q)
                 if kind == "tok":
                     yield self._detokenize([val])
                 elif kind == "end":
@@ -414,6 +429,8 @@ class LLMServerImpl:
     def scheduler_stats(self) -> Dict[str, Any]:
         if self._sched is not None:
             out = self._sched.stats()
+            out["stream_lag_s"] = self._stream_lag_ns / 1e9
+            out["stream_tokens"] = self._stream_tokens
         else:
             out = {"mode": "batch", "max_batch_size": self._max_batch}
         # where the model really runs: a replica that was not given a

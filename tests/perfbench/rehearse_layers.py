@@ -1,0 +1,22 @@
+"""``rehearse.py`` over the second tiny manifest (``BENCHMARK_layers.json``:
+the tiny manifest plus the per-layer metrics that read the phase clock, the
+named programs and the named flash kernel), so the CPU rehearsal runs those
+readers too. Same tiny tree, same stand-in peak, counts only."""
+
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, os.path.dirname(os.path.dirname(HERE)))
+
+from perfbench import run  # noqa: E402
+from perfbench.lib import peaks  # noqa: E402
+
+LAYERS_MANIFEST = os.path.join(HERE, "tiny", "BENCHMARK_layers.json")
+
+if __name__ == "__main__":
+    peaks.PEAKS.setdefault("cpu", {"flops_bf16": 1e12,
+                                   "hbm_bytes_per_s": 1e11,
+                                   "hbm_bytes": 1e10})
+    sys.exit(run.main(sys.argv[1:], manifest_path=LAYERS_MANIFEST,
+                      require_tpu=False))

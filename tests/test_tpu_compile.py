@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
 HBM_BYTES = 16 * 1024**3
+FLASH_KERNELS = ("flash_attention_fwd", "flash_attention_bwd_dq",
+                 "flash_attention_bwd_dkv")
 GPT2S = dict(H=12, Hkv=12, D=64)
 GQA128 = dict(H=32, Hkv=8, D=128)  # llama3_8b's head shape
 
@@ -64,6 +66,14 @@ def _on(sharding, shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _kernel_names(text: str) -> set:
+    """The names the compiled kernels carry: a ``pallas_call``'s ``name=``
+    becomes part of the custom call's instruction name (``%jvp_<name>_.1``,
+    ``%<name>.1``), which is what a profiler trace shows as the op."""
+    return set(re.findall(
+        r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text))
+
+
 def _fits(compiled) -> int:
     ma = compiled.memory_analysis()
     total = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
@@ -95,6 +105,9 @@ def test_flash_attention_compiles(v5e, shape, grad):
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
     text = jax.jit(fn).lower(q, kv, kv).compile().as_text()
     assert text.count("tpu_custom_call") >= (3 if grad else 1)
+    names = _kernel_names(text)
+    for kernel in FLASH_KERNELS if grad else FLASH_KERNELS[:1]:
+        assert [n for n in names if kernel in n], (kernel, names)
 
 
 @pytest.mark.parametrize("K", [1, 4], ids=["decode_k1", "verify_k4"])
@@ -112,6 +125,7 @@ def test_paged_attention_compiles(v5e, shape, K):
         _on(chip, (S, pages), jnp.int32), _on(chip, (S,), jnp.int32),
     ).compile().as_text()
     assert "tpu_custom_call" in text
+    assert [n for n in _kernel_names(text) if "paged_attention" in n]
 
 
 class TestPagedShapeRule:
@@ -191,7 +205,12 @@ def test_gpt2s_train_step_fits_one_chip(v5e):
     compiled = make_train_step(cfg, tx).lower(
         _abstract_train_state(cfg, tx, chip),
         {"tokens": _on(chip, (16, 1024), jnp.int32)}).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # under the layer scan and full remat every kernel keeps its name, the
+    # recomputed forward too (PERF.md: kernel.flash_roofline finds them)
+    for kernel in FLASH_KERNELS:
+        assert [n for n in _kernel_names(text) if kernel in n], kernel
     _fits(compiled)
 
 
@@ -212,6 +231,8 @@ def test_gpt2s_sharded_train_step_lowers_with_the_kernel_in_it(v5e):
         {"tokens": _on(data_sharding(mesh), (16, 1024), jnp.int32)}).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    for kernel in FLASH_KERNELS:  # named inside the shard_map as well
+        assert [n for n in _kernel_names(text) if kernel in n], kernel
     gathers = re.findall(
         r"= \(?bf16\[([0-9,]+)\][^=\n]* all-gather(?:-start)?\(", text)
     # per shard q/k/v are [8, 1024, 6, 64] (or head-major): a gather back
