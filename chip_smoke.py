@@ -7,8 +7,9 @@ weights from ``--seed``):
   train    ray_tpu.init() finds the chip; JaxTrainer(...).fit() takes 6 steps
            (0..5) at batch 16 x 1024 with the flash kernel in the program
   kernels  a @ray_tpu.remote(num_tpus=1) task checks flash attention (fwd,
-           grad) and paged attention (K=1, K=4) against their jax.numpy
-           references on the device
+           grad) and paged attention (K=1, 4 and 5; and at Mistral-7B's widths
+           K=1 and K=512 over contexts 16 to 8192) against their jax.numpy
+           references on the device, and prints the largest errors
   serve    serve.run(build_app(preset="gpt2_small")) answers 8 concurrent
            requests: six through the handle, one streamed, one over HTTP
 
@@ -134,7 +135,8 @@ def train_loop(config: dict) -> None:
 
 
 def kernels_task(seed: int) -> dict:
-    """Flash and paged attention against their references, GPT-2s shapes."""
+    """Flash and paged attention against their references: GPT-2s shapes,
+    and the paged kernel at Mistral-7B's widths too."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -176,20 +178,43 @@ def kernels_task(seed: int) -> dict:
                            first_call(graded(ref), q, k, v))
     errors["flash_grad"] = max(max_err(g, r) for g, r in zip(got, want))
 
-    # ---- paged attention through a page table: decode (K=1), verify (K=4)
+    def paged_err(qk, k_pool, v_pool, tables, lengths):
+        """The kernel against its reference lane, largest relative error."""
+        return max_err(*(first_call(
+            jax.jit(lambda *a, impl=impl: paged_attention(*a, impl=impl)),
+            qk, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(lengths))
+            for impl in ("pallas", "reference")))
+
+    # ---- paged attention through a page table: decode (K=1), verify (K=4,
+    # and the scheduler's default window of serve_spec_k + 1 = 5)
     lengths = np.asarray([0, 15, 16, 100, 333, 511, 777, 1000], np.int32)
     S = len(lengths)
     tables = (1 + np.arange(S * P, dtype=np.int32)).reshape(S, P)
     pool_shape = (S * P + 1, T, H * D)
     k_pool, v_pool = (jax.random.normal(next(keys), pool_shape, jnp.bfloat16)
                       for _ in range(2))
-    for K in (1, 4):
+    for K in (1, 4, 5):
         qk = jax.random.normal(next(keys), (S, K, H, D), jnp.bfloat16)
-        outs = [first_call(
-            jax.jit(lambda *a, impl=impl: paged_attention(*a, impl=impl)),
-            qk, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(lengths))
-            for impl in ("pallas", "reference")]
-        errors[f"paged_k{K}"] = max_err(*outs)
+        errors[f"paged_k{K}"] = paged_err(qk, k_pool, v_pool, tables, lengths)
+
+    # ---- the same at Mistral-7B's widths (32 heads over 8 kv heads of 128),
+    # contexts 16 to 8192: the decode step's K=1 (with a row that holds no
+    # sequence, length -1, among the others) and a prefill chunk's K=512
+    H, Hkv, D = 32, 8, 128
+    for K, lengths in ((1, [16, 100, 511, -1, 512, 513, 2000, 4096, 8192]),
+                       (512, [16, 1000, 4096, 7680])):
+        lengths = np.asarray(lengths, np.int32)
+        need = -(-(lengths + K) // T)
+        tables = np.zeros((len(lengths), int(need.max())), np.int32)
+        for s, n in enumerate(need):   # the tail stays the garbage page 0
+            tables[s, :n] = 1 + need[:s].sum() + np.arange(n)
+        pool_shape = (int(need.sum()) + 1, T, Hkv * D)
+        k_pool, v_pool = (jax.random.normal(next(keys), pool_shape,
+                                            jnp.bfloat16) for _ in range(2))
+        qk = jax.random.normal(next(keys), (len(lengths), K, H, D),
+                               jnp.bfloat16)
+        errors[f"paged_mistral_k{K}"] = paged_err(qk, k_pool, v_pool, tables,
+                                                  lengths)
 
     bad = {name: e for name, e in errors.items()
            if not (math.isfinite(e) and e < 2e-2)}

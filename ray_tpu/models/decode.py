@@ -209,8 +209,10 @@ def slot_decode_step(cfg: TransformerConfig, params, tokens, active, caches):
 # ---------------------------------------------------------------------------
 # paged KV arena — the slot arena rebuilt as a pool of fixed-size pages
 # (ISSUE 13). KV storage is [num_pages, page_tokens, Hkv * D] per layer (a
-# token's kv heads joined on the minor axis: the layout whose pages the
-# TPU's DMA engine can slice — see ops/paged_attention.py); a slot owns a
+# token's kv heads joined on the minor axis: a page is then ONE contiguous
+# run of whole 128-lane rows, which the paged kernel streams in
+# double-buffered blocks of whole rows and serves every kv head from — see
+# ops/paged_attention.py); a slot owns a
 # PAGE TABLE ([pages_per_slot] int32 of physical page ids)
 # instead of a contiguous worst-case range, so long/idle sequences stop
 # reserving memory they don't use and read-only pages can be SHARED between
@@ -461,8 +463,11 @@ def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
     _check_attn_lane(attn)
     if attn != "gather":
         lengths = caches[0].lengths
+        # a slot the step marks inactive attends nothing (its logits are
+        # dropped): a retired slot's stale cursor streams no page
         logits, new_caches = _paged_forward_inplace(
-            cfg, params, tokens[:, None], lengths[:, None], lengths,
+            cfg, params, tokens[:, None], lengths[:, None],
+            jnp.where(active > 0, lengths, -1),
             read_tables, write_tables, caches, attn,
             lambda l: l + active)
         return logits[:, 0], new_caches
@@ -502,11 +507,14 @@ def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
     return logits, new_caches
 
 
-def paged_verify_step(cfg: TransformerConfig, params, tokens,
+def paged_verify_step(cfg: TransformerConfig, params, tokens, active,
                       read_tables, write_tables,
                       caches: List[PagedKVCache], *, attn: str = "gather"):
     """Speculative-decoding verify: score K candidate tokens per slot in
-    ONE fixed-shape call over the slots axis (ISSUE 18). tokens:
+    ONE fixed-shape call over the slots axis (ISSUE 18). active: [slots]
+    int32, 0 for a row without a live sequence (the in-place lanes attend
+    nothing there, the gather lane takes no notice: such a row's logits
+    are dropped either way). tokens:
     [slots, K] int32 — each slot's [next_token, d_1..d_{K-1}] placed at
     logical positions [cursor, cursor + K); logits[s, j] is the target
     model's distribution over the token FOLLOWING position cursor + j,
@@ -536,7 +544,8 @@ def paged_verify_step(cfg: TransformerConfig, params, tokens,
         lengths = caches[0].lengths
         positions = lengths[:, None] + jnp.arange(K, dtype=jnp.int32)[None]
         logits, new_caches = _paged_forward_inplace(
-            cfg, params, tokens, positions, lengths,
+            cfg, params, tokens, positions,
+            jnp.where(active > 0, lengths, -K),
             read_tables, write_tables, caches, attn, lambda l: l)
         return logits, new_caches
     T, HD = caches[0].k.shape[1:]

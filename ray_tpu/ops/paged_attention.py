@@ -7,44 +7,70 @@ original decode/verify programs materialize every slot's full logical
 ``[pages_per_slot * page_tokens]`` view with a gather before attending — an
 O(arena_len)·layers·slots copy per single-token step, so decode cost scales
 with pool PROVISIONING rather than the tokens actually attended. This module
-computes attention directly against the pool:
+computes attention directly against the pool, in BLOCKS of whole page rows:
 
+  * A block is ``B`` consecutive entries of a slot's page table, ``B * T``
+    tokens; scores and the online-softmax update are per block
+    (``[K*G, D] x [D, B*T]``, then ``[K*G, B*T] x [B*T, D]``), with the
+    operands in the pool's dtype and f32 accumulation; m, l and the
+    accumulator stay f32. ``tile_sizes`` derives ``B`` (and the query tile
+    for a long window) from the static shapes alone: up to two megabytes of
+    K and V rows and 512 tokens a block — one megabyte covers the HBM's
+    latency, but a block's fixed cost (a softmax chain a kv head) is only
+    paid off by more, as measured on the v5e (PERF.md 6, PR 25).
   * ``paged_attention(..., impl='pallas')`` — a Pallas TPU kernel, one grid
-    cell per (slot, kv-head window). A window is the fewest kv heads whose
-    joined lanes fill whole 128-lane tiles (2 heads at D=64, 1 at D=128):
-    Mosaic only copies tile-aligned slices out of HBM, which is also why
-    the pool keeps ``[page_tokens, Hkv * D]`` as its minor dims. The page
-    table and slot lengths ride in as scalar-prefetch operands (SMEM), the
-    K/V pools stay in HBM (``memory_space=ANY``), and the kernel
-    async-copies ONE page window at a time into VMEM scratch — only
-    ``ceil((length+K)/page_tokens)`` pages per slot, a dynamic trip count.
-    No contiguous view ever exists. ``pallas_shape_problem`` names the
-    shapes the compiled kernel cannot take.
+    cell per (slot, query tile). The page table and slot lengths ride in as
+    scalar-prefetch operands (SMEM), the K/V pools stay in HBM
+    (``memory_space=ANY``). A page copy moves the WHOLE row
+    ``[page_tokens, Hkv * D]`` (contiguous in HBM; every kv head of the
+    slot is then served from VMEM by static lane slices); all of a block's
+    copies are started before any is waited for, into one of two VMEM
+    buffers, and block n+1's copies — or, behind a cell's last block, the
+    NEXT grid cell's first block — are started before block n is computed
+    on. Only the blocks up to a query tile's last position are fetched, a
+    dynamic trip count, and the slots that attend something come first in
+    the grid, so a slot without a live sequence is fetched nothing for and
+    handed no buffer. No contiguous view ever exists.
+    ``pallas_shape_problem`` names the shapes the compiled kernel cannot
+    take.
   * ``paged_attention(..., impl='reference')`` — pure JAX with IDENTICAL
-    math (same page order, same online-softmax update, same -1e30 mask):
-    one fori_loop over pages, trip count = the batch max of allocated
-    pages. This is the parity oracle for the kernel and the production
-    lane off-TPU.
+    math (same blocks in the same order, same operand dtypes, same
+    online-softmax update, same -1e30 mask): one fori_loop over blocks,
+    trip count = the batch max of blocks. This is the parity oracle for
+    the kernel and the production lane off-TPU.
 
 Mask semantics match ``LayerKVCache.mask_bias``: query row ``i`` of slot
 ``s`` sits at logical position ``lengths[s] + i`` and may attend logical
-position ``j`` iff ``j <= lengths[s] + i``. Page-table entries past a slot's
+position ``j`` iff ``j <= lengths[s] + i``. A slot whose whole window lies
+before position 0 (``lengths[s] + K <= 0``: how a caller marks a row without
+a live sequence) attends nothing, reads no page and returns zeros.
+Page-table entries past a slot's
 allocation point at the reserved garbage page 0; every position they cover
 is ``> lengths[s] + i``, so the mask zeroes them EXACTLY (exp(-1e30 - m)
 underflows to 0.0f) — garbage content can never leak into an attended
-value, and masked pages contribute bit-exact zeros to the online
+value, and masked positions contribute bit-exact zeros to the online
 accumulator (the same invariant the gathered-view lane relies on).
 
-Decode is the K=1 case; the fixed-K verify window shares the same kernel —
-each query row reduces over pages in ascending order with a full-width
-mask, so per-row reduction order matches K sequential decode steps.
+The partly filled last block: a masked score gives p = 0, and 0 times
+uninitialised VMEM (NaN) would be NaN. So EVERY entry of a block is fetched
+through the table, whatever the slot's length: the tail entries are the
+table's own garbage-page entries (finite by the arena's standing
+invariant), and entries past the table's end, where ``B`` does not divide
+its width, read page 0 too. No part of a buffer is ever computed on before
+a copy has defined it. What the tail costs is counted by the scheduler
+(``attn_tokens_fetched`` against ``attn_tokens_attended``).
+
+Decode is the K=1 case; the fixed-K verify window and the prefill chunk
+share the same kernel — each query row reduces over blocks in ascending
+order with a full-width mask, so per-row reduction order matches K
+sequential decode steps.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -65,7 +91,8 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
 
     q: [S, K, H, D] queries (K = 1 decode, K > 1 verify/prefill window).
     k_pool/v_pool: [N, T, Hkv * D] page pools (page 0 = garbage page).
-    tables: [S, P] int32 page tables; lengths: [S] int32 slot cursors.
+    tables: [S, P] int32 page tables; lengths: [S] int32 slot cursors
+    (``-K`` for a row without a live sequence: zeros, no page read).
     Returns [S, K, H, D] in q.dtype.
 
     The new tokens' k/v must already be WRITTEN into their pages (write-
@@ -90,187 +117,311 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
             "[N, T, Hkv * D]; H must be a multiple of Hkv, D must match)")
     if impl == "pallas":
         return _paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
-                                       sm_scale)
+                                       sm_scale, should_interpret())
     return _paged_attention_reference(q, k_pool, v_pool, tables, lengths,
                                       sm_scale)
+
+
+# ------------------------------------------------------------ tile sizes
+
+_LANES = 128
+_BLOCK_BYTES = 2 << 20   # K and V rows one block's copies keep in flight
+_MAX_BLOCK_TOKENS = 512  # lanes of a score tile
+_MAX_Q_ROWS = 512        # query rows (tokens x group) of one tile
+_SUB_ROWS = 256          # of which one matmul takes so many: the f32 score
+#                          tile is [_SUB_ROWS, block tokens], 512 KB at most
+
+
+def tile_sizes(qk: int, group: int, page_tokens: int, pages_per_slot: int,
+               row_bytes: int) -> Tuple[int, int]:
+    """(pages per block, query tokens per tile) for a K = ``qk`` window over
+    a pool whose token rows hold ``row_bytes`` (all kv heads of K or of V).
+
+    Both lanes and the scheduler's counters read the blocking from here, and
+    it follows from static shapes alone: a long window is cut into tiles of
+    about ``_MAX_Q_ROWS`` query rows (whole multiples of the ``_SUB_ROWS``
+    one matmul takes); a block is the largest power of two of pages that
+    keeps its K and V rows within ``_BLOCK_BYTES``, its tokens within
+    ``_MAX_BLOCK_TOKENS`` and itself within the page table."""
+    n_tiles = -(-qk * group // _MAX_Q_ROWS)
+    q_tile = -(-qk // n_tiles)
+    if q_tile * group > _SUB_ROWS:  # whole matmuls of _SUB_ROWS rows
+        step = _SUB_ROWS // math.gcd(group, _SUB_ROWS)
+        q_tile = -(-q_tile // step) * step
+    pages = min(_BLOCK_BYTES // (2 * page_tokens * row_bytes),
+                _MAX_BLOCK_TOKENS // page_tokens, pages_per_slot)
+    return 1 << (max(pages, 1).bit_length() - 1), q_tile
+
+
+def _vmem_bytes(shape, dtype) -> int:
+    """What an array takes in VMEM: its minor axis in whole 128-lane rows,
+    the axis before it in whole tiles of eight 32-bit sublanes."""
+    *lead, rows, lanes = shape
+    item = jnp.dtype(dtype).itemsize
+    sub = 8 * 4 // item
+    return (math.prod(lead) * -(-rows // sub) * sub
+            * -(-lanes // _LANES) * _LANES * item)
+
+
+def _shapes(q, k_pool, tables):
+    S, K, H, D = q.shape
+    T = k_pool.shape[1]
+    Hkv = k_pool.shape[2] // D
+    P = tables.shape[1]
+    G = H // Hkv
+    B, q_tile = tile_sizes(K, G, T, P, k_pool.shape[2] * k_pool.dtype.itemsize)
+    return S, K, D, T, Hkv, P, G, B, q_tile
 
 
 # ------------------------------------------------------------- reference
 
 
 def _paged_attention_reference(q, k_pool, v_pool, tables, lengths, sm_scale):
-    """Pure-JAX twin of the kernel: one fori_loop over pages, all slots
-    batched per iteration. Trip count is the BATCH MAX of pages any slot
-    needs — pages past a slot's own need hit its garbage-page table tail
-    and contribute exact zeros, so each slot's result is bit-identical to
-    looping only its own pages."""
-    S, K, H, D = q.shape
-    T = k_pool.shape[1]
-    Hkv = k_pool.shape[2] // D
-    P = tables.shape[1]
-    G = H // Hkv
-    # [S, K, Hkv, G, D] f32 — kv-head-major grouping, like the flash kernel
-    qf = q.reshape(S, K, Hkv, G, D).astype(jnp.float32)
+    """Pure-JAX twin of the kernel: one fori_loop over blocks of pages, all
+    slots batched per iteration. Trip count is the BATCH MAX of blocks any
+    slot needs — blocks past a slot's own need hit its garbage-page table
+    tail and contribute exact zeros, so each slot's result is bit-identical
+    to looping only its own blocks."""
+    S, K, D, T, Hkv, P, G, B, _ = _shapes(q, k_pool, tables)
+    BT = B * T
+    n_table_blocks = -(-P // B)
+    # entries past the table's end read the garbage page, like its tail
+    tables = jnp.pad(tables, ((0, 0), (0, n_table_blocks * B - P)))
+    # the kernel's operands: rows [K*G, D] a kv head, row i*G+g = (token i,
+    # group g), against that head's [BT, D] block, batched over (slot, head)
+    qh = q.reshape(S, K, Hkv, G, D).transpose(0, 2, 1, 3, 4)
+    qh = qh.reshape(S, Hkv, K * G, D).astype(k_pool.dtype)
     qpos = lengths[:, None] + jnp.arange(K, dtype=jnp.int32)[None, :]  # [S,K]
-    n_pages = lax.div(jnp.max(lengths) + K + T - 1, jnp.int32(T))
-    n_pages = jnp.minimum(n_pages, jnp.int32(P))
+    qpos = jnp.repeat(qpos, G, axis=1)[:, None, :, None]      # [S,1,K*G,1]
+    n_blocks = lax.div(jnp.max(lengths) + K + BT - 1, jnp.int32(BT))
+    n_blocks = jnp.minimum(n_blocks, jnp.int32(n_table_blocks))
+    batch = ((0, 1), (0, 1))
 
-    def body(p, carry):
+    def body(b, carry):
         m, l, acc = carry
-        pids = lax.dynamic_index_in_dim(tables, p, axis=1, keepdims=False)
-        kpg = k_pool[pids].reshape(S, T, Hkv, D).astype(jnp.float32)
-        vpg = v_pool[pids].reshape(S, T, Hkv, D).astype(jnp.float32)
-        s_ = jnp.einsum("skhgd,sthd->skhgt", qf, kpg) * sm_scale
-        kpos = p * T + jnp.arange(T, dtype=jnp.int32)            # [T]
-        allowed = kpos[None, None, :] <= qpos[:, :, None]        # [S, K, T]
-        s_ = jnp.where(allowed[:, :, None, None, :], s_, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s_, axis=-1))
+        pids = lax.dynamic_slice_in_dim(tables, b * B, B, axis=1)   # [S, B]
+        kb = k_pool[pids].reshape(S, BT, Hkv, D).transpose(0, 2, 1, 3)
+        vb = v_pool[pids].reshape(S, BT, Hkv, D).transpose(0, 2, 1, 3)
+        s_ = lax.dot_general(qh, kb, (((3,), (3,)), batch),
+                             preferred_element_type=jnp.float32)
+        kpos = b * BT + jnp.arange(BT, dtype=jnp.int32)          # [BT]
+        s_ = jnp.where(kpos <= qpos, s_ * sm_scale, NEG_INF)  # [S,Hkv,K*G,BT]
+        m_new = jnp.maximum(m, jnp.max(s_, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
-        pr = jnp.exp(s_ - m_new[..., None])
-        l_new = l * alpha + jnp.sum(pr, axis=-1)
-        acc_new = (acc * alpha[..., None]
-                   + jnp.einsum("skhgt,sthd->skhgd", pr, vpg))
+        pr = jnp.exp(s_ - m_new)
+        l_new = l * alpha + jnp.sum(pr, axis=-1, keepdims=True)
+        acc_new = acc * alpha + lax.dot_general(
+            pr.astype(vb.dtype), vb, (((3,), (2,)), batch),
+            preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
-    m0 = jnp.full((S, K, Hkv, G), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((S, K, Hkv, G), jnp.float32)
-    a0 = jnp.zeros((S, K, Hkv, G, D), jnp.float32)
-    _, l, acc = lax.fori_loop(0, n_pages, body, (m0, l0, a0))
-    l = jnp.where(l == 0.0, 1.0, l)  # fully-masked row (can't happen: j=0
-    #                                  is always allowed) -> 0, not NaN
-    out = acc / l[..., None]
-    return out.reshape(S, K, H, D).astype(q.dtype)
+    m0 = jnp.full((S, Hkv, K * G, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((S, Hkv, K * G, 1), jnp.float32)
+    a0 = jnp.zeros((S, Hkv, K * G, D), jnp.float32)
+    _, l, acc = lax.fori_loop(0, n_blocks, body, (m0, l0, a0))
+    l = jnp.where(l == 0.0, 1.0, l)  # no block at all -> 0, not NaN
+    out = (acc / l).reshape(S, Hkv, K, G, D).transpose(0, 2, 1, 3, 4)
+    # a slot that attends nothing went through the others' blocks, all
+    # masked, which leaves the mean of what they held: zeros instead
+    out = jnp.where((lengths + K > 0)[:, None, None, None, None], out, 0.0)
+    return out.reshape(S, K, Hkv * G, D).astype(q.dtype)
 
 
 # ---------------------------------------------------------------- kernel
-
-_LANES = 128
-
-
-def _heads_per_window(kv_heads: int, head_dim: int) -> Optional[int]:
-    """Fewest kv heads (a divisor of ``kv_heads``) whose joined lanes fill
-    whole 128-lane tiles — the unit one page copy moves. None when no
-    grouping does (``kv_heads * head_dim`` is not a multiple of 128)."""
-    for hp in range(1, kv_heads + 1):
-        if kv_heads % hp == 0 and (hp * head_dim) % _LANES == 0:
-            return hp
-    return None
 
 
 def pallas_shape_problem(kv_heads: int, head_dim: int) -> Optional[str]:
     """Why Mosaic cannot compile the kernel for this pool shape, or None.
 
-    A page window is DMA'd out of HBM as ``[page_tokens, hp * head_dim]``,
-    and the chip's compiler only slices the lane axis of an HBM array on
-    128-lane tile boundaries (any page_tokens compiles). The interpreter
+    A page row is DMA'd out of HBM whole, ``[page_tokens, kv_heads *
+    head_dim]``, and the chip's compiler only moves whole 128-lane tiles
+    (any page_tokens the pool's dtype tiles compiles). The interpreter
     has no such rule, which is why every shape runs off-TPU."""
-    if _heads_per_window(kv_heads, head_dim) is None:
+    if (kv_heads * head_dim) % _LANES:
         return (f"kv_heads * head_dim = {kv_heads * head_dim} is not a "
                 f"multiple of {_LANES} lanes")
     return None
 
 
-def _paged_kernel(lengths_ref, tables_ref,          # scalar prefetch (SMEM)
-                  q_ref,                            # [1, 1, hp, K*G, D] VMEM
-                  k_pool_ref, v_pool_ref,           # [N, T, Hkv*D] HBM/ANY
-                  o_ref,                            # [1, 1, hp, K*G, D] VMEM
-                  k_scr, v_scr, sem_k, sem_v,       # [T, hp*D] VMEM + DMA sems
-                  *, page_tokens, qk, group, heads, head_dim, sm_scale):
-    s = pl.program_id(0)
-    w = pl.program_id(1)
-    T, D = page_tokens, head_dim
-    W = heads * D
-    length = lengths_ref[s]
-    n_pages = lax.div(length + qk + T - 1, jnp.int32(T))
-    qs = [q_ref[0, 0, i].astype(jnp.float32) for i in range(heads)]
-    # row r = i * group + g is query token i: position length + i
-    row_pos = length + lax.broadcasted_iota(jnp.int32, (qk * group, 1),
-                                            0) // group
+def _paged_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
+                  order_ref, n_live_ref,        # the same: see below
+                  q_ref,                        # [1, 1, Hkv, R, D] VMEM
+                  k_pool_ref, v_pool_ref,       # [N, T, Hkv*D] HBM/ANY
+                  o_ref,                        # [1, 1, Hkv, R, D] VMEM
+                  k_buf, v_buf,                 # [2, B*T, Hkv*D] VMEM
+                  sems,                         # DMA [2 buffers, k|v]
+                  first_buf,                    # SMEM [1]: see below
+                  m_scr, l_scr, acc_scr,        # [Hkv, R, 1|1|D] f32 VMEM
+                  *, page_tokens, pages, qk, q_tile, group, kv_heads,
+                  head_dim, sm_scale):
+    """One (slot, query tile) cell: R = q_tile * group query rows of every
+    kv head over the slot's blocks 0 .. the tile's last position.
 
-    def body(p, carry):
-        pid = tables_ref[s, p]
-        lanes = pl.ds(pl.multiple_of(w * W, W), W)
-        cp_k = pltpu.make_async_copy(k_pool_ref.at[pid, :, lanes], k_scr,
-                                     sem_k)
-        cp_v = pltpu.make_async_copy(v_pool_ref.at[pid, :, lanes], v_scr,
-                                     sem_v)
-        cp_k.start()
-        cp_v.start()
-        cp_k.wait()
-        cp_v.wait()
-        kpos = p * T + lax.broadcasted_iota(jnp.int32, (1, T), 1)
-        out = []
-        for i, (m, l, acc) in enumerate(carry):     # heads of this window
-            kpg = k_scr[:, i * D:(i + 1) * D].astype(jnp.float32)  # [T, D]
-            vpg = v_scr[:, i * D:(i + 1) * D].astype(jnp.float32)
-            s_ = jax.lax.dot_general(qs[i], kpg, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            s_ = s_ * sm_scale                      # [K*G, T]
-            s_ = jnp.where(kpos <= row_pos, s_, NEG_INF)
+    Cell c of the grid's first axis serves slot ``order[c]``: the
+    ``n_live`` slots that attend something first, the others after them,
+    where they only write their zeros. The grid runs in order, and the two
+    buffers are handed from cell to cell: ``first_buf`` names the buffer
+    that holds this cell's block 0, whose copies the PREVIOUS cell started
+    behind its own last block (the first cell starts its own). So a slot of
+    two or three blocks does not pay a cold start (1.4-2.1 us a cell on the
+    v5e, PERF.md 6), and nothing is ever started for a slot that attends
+    nothing."""
+    c, t = pl.program_id(0), pl.program_id(1)
+    n_tiles, n_live = pl.num_programs(1), n_live_ref[0]
+    s = order_ref[c]
+    T, B, D, R = page_tokens, pages, head_dim, q_tile * group
+    BT = B * T
+    # rows one matmul takes: all of a small tile's (whatever K * group is),
+    # else _SUB_ROWS, which tile_sizes makes divide R
+    RS = R if R <= _SUB_ROWS else _SUB_ROWS
+    P = tables_ref.shape[1]
+
+    def block_copies(s, b, buf, wait=False):
+        """Start (or wait for) the 2 * B whole-row copies of block b."""
+        for i in range(B):
+            j = b * B + i
+            if wait:
+                pid = 0                  # a wait reads the sizes only
+            elif P % B == 0:
+                pid = tables_ref[s, j]
+            else:                        # past the table's end: page 0
+                pid = jnp.where(j < P, tables_ref[s, jnp.minimum(j, P - 1)],
+                                0)
+            rows = pl.ds(i * T, T)
+            for kv, (pool, dst) in enumerate(((k_pool_ref, k_buf),
+                                              (v_pool_ref, v_buf))):
+                cp = pltpu.make_async_copy(pool.at[pid], dst.at[buf, rows],
+                                           sems.at[buf, kv])
+                cp.wait() if wait else cp.start()
+
+    @pl.when(jnp.logical_and(c == 0, t == 0))
+    def _():
+        first_buf[0] = 0
+
+        @pl.when(n_live > 0)
+        def _():
+            block_copies(s, 0, 0)
+
+    base = first_buf[0]
+    # the blocks up to the tile's last position, within the table
+    upto = lengths_ref[s] + jnp.minimum((t + 1) * q_tile, qk)
+    nb = jnp.clip(lax.div(upto + BT - 1, jnp.int32(BT)), 1, -(-P // B))
+    nb = jnp.where(c < n_live, nb, 0)  # a live cell uses what it was handed
+    c_next = jnp.where(t + 1 == n_tiles, c + 1, c)  # whose block 0 is next
+    s_next = order_ref[jnp.minimum(c_next, pl.num_programs(0) - 1)]
+
+    m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    def body(b, _):
+        buf = lax.rem(base + b, 2)
+        more = b + 1 < nb
+
+        @pl.when(jnp.logical_or(more, c_next < n_live))
+        def _():
+            block_copies(jnp.where(more, s, s_next),
+                         jnp.where(more, b + 1, 0), 1 - buf)
+
+        block_copies(s, b, buf, wait=True)
+        kpos = b * BT + lax.broadcasted_iota(jnp.int32, (1, BT), 1)
+
+        def update(h, r0):
+            """Rows [r0, r0 + RS) of kv head h against this block."""
+            rows, lanes = pl.ds(r0, RS), slice(h * D, (h + 1) * D)
+            s_ = lax.dot_general(q_ref[0, 0, h, rows], k_buf[buf, :, lanes],
+                                 (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            # row r = i * group + g is query token i of the tile
+            row_pos = lengths_ref[s] + t * q_tile + (
+                r0 + lax.broadcasted_iota(jnp.int32, (RS, 1), 0)) // group
+            s_ = jnp.where(kpos <= row_pos, s_ * sm_scale, NEG_INF)  # [RS,BT]
+            m = m_scr[h, rows]
             m_new = jnp.maximum(m, jnp.max(s_, axis=-1, keepdims=True))
             alpha = jnp.exp(m - m_new)
             pr = jnp.exp(s_ - m_new)
-            l_new = l * alpha + jnp.sum(pr, axis=-1, keepdims=True)
-            acc_new = acc * alpha + jax.lax.dot_general(
-                pr, vpg, (((1,), (0,)), ((), ())),
+            l_scr[h, rows] = (l_scr[h, rows] * alpha
+                              + jnp.sum(pr, axis=-1, keepdims=True))
+            acc_scr[h, rows] = acc_scr[h, rows] * alpha + lax.dot_general(
+                pr.astype(v_buf.dtype), v_buf[buf, :, lanes],
+                (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            out.append((m_new, l_new, acc_new))
-        return tuple(out)
+            m_scr[h, rows] = m_new
 
-    init = tuple((jnp.full((qk * group, 1), NEG_INF, jnp.float32),
-                  jnp.zeros((qk * group, 1), jnp.float32),
-                  jnp.zeros((qk * group, D), jnp.float32))
-                 for _ in range(heads))
-    for i, (_, l, acc) in enumerate(lax.fori_loop(0, n_pages, body, init)):
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, i] = (acc / l).astype(o_ref.dtype)
+        for h in range(kv_heads):
+            if R == RS:
+                update(h, 0)
+            else:   # a loop, not R / RS unrolled copies: compile time
+                lax.fori_loop(0, R // RS, lambda i, _, h=h: update(
+                    h, pl.multiple_of(i * RS, RS)), None)
+
+    lax.fori_loop(0, nb, body, None)
+    first_buf[0] = lax.rem(base + nb, 2)
+    l = l_scr[...]
+    l = jnp.where(l == 0.0, 1.0, l)
+    o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths, sm_scale):
-    S, K, H, D = q.shape
-    T = k_pool.shape[1]
-    Hkv = k_pool.shape[2] // D
-    G = H // Hkv
-    interpret = should_interpret()
-    hp = _heads_per_window(Hkv, D)
-    if hp is None:
-        if not interpret:
-            raise ValueError(
-                "paged attention kernel cannot compile for this pool: "
-                + pallas_shape_problem(Hkv, D))
-        hp = Hkv  # interpreted: one window spanning the whole page row
-    nw = Hkv // hp
-    # window-major rows: [S, nw, hp, K*G, D]; row i*G+g = (token i, group g)
-    qr = q.reshape(S, K, nw, hp, G, D).transpose(0, 2, 3, 1, 4, 5)
-    qr = qr.reshape(S, nw, hp, K * G, D)
-    kernel = functools.partial(_paged_kernel, page_tokens=T, qk=K, group=G,
-                               heads=hp, head_dim=D, sm_scale=sm_scale)
-    block = (1, 1, hp, K * G, D)
+# jitted on its own: a program calls the op once a layer with the same
+# shapes, and the kernel's body is then traced and lowered once, not once a
+# layer (16 layers cost 14 s of every process's start otherwise)
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths, sm_scale,
+                            interpret):
+    S, K, D, T, Hkv, P, G, B, q_tile = _shapes(q, k_pool, tables)
+    problem = None if interpret else pallas_shape_problem(Hkv, D)
+    if problem:
+        raise ValueError(
+            f"paged attention kernel cannot compile for this pool: {problem}")
+    n_tiles = -(-K // q_tile)
+    R = q_tile * G
+    # tile-major rows: [S, tiles, Hkv, R, D]; row i*G+g = (token i, group g)
+    qr = jnp.pad(q.astype(k_pool.dtype),
+                 ((0, 0), (0, n_tiles * q_tile - K), (0, 0), (0, 0)))
+    qr = qr.reshape(S, n_tiles, q_tile, Hkv, G, D).transpose(0, 1, 3, 2, 4, 5)
+    qr = qr.reshape(S, n_tiles, Hkv, R, D)
+    kernel = functools.partial(_paged_kernel, page_tokens=T, pages=B, qk=K,
+                               q_tile=q_tile, group=G, kv_heads=Hkv,
+                               head_dim=D, sm_scale=sm_scale)
+    block = (1, 1, Hkv, R, D)
+    live = lengths + K > 0
+    order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
+    bufs = [pltpu.VMEM((2, B * T, Hkv * D), pool.dtype)
+            for pool in (k_pool, v_pool)]
+    stats = [pltpu.VMEM((Hkv, R, width), jnp.float32) for width in (1, 1, D)]
+    # the scratch, the query and output blocks (the pipeline keeps two of
+    # each) and four score tiles of one matmul, and half as much again
+    vmem = (sum(_vmem_bytes(a.shape, a.dtype) for a in bufs + stats)
+            + 2 * (_vmem_bytes(block, k_pool.dtype)
+                   + _vmem_bytes(block, q.dtype))
+            + 4 * _vmem_bytes((min(R, _SUB_ROWS), B * T), jnp.float32))
+
+    def cell(c, t, lengths_ref, tables_ref, order_ref, n_live_ref):
+        return order_ref[c], t, 0, 0, 0
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, nw),
+        num_scalar_prefetch=4,
+        grid=(S, n_tiles),
         in_specs=[
-            pl.BlockSpec(block, lambda s, w, *_: (s, w, 0, 0, 0)),
+            pl.BlockSpec(block, cell),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(block, lambda s, w, *_: (s, w, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((T, hp * D), k_pool.dtype),
-            pltpu.VMEM((T, hp * D), v_pool.dtype),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ],
+        out_specs=pl.BlockSpec(block, cell),
+        scratch_shapes=bufs + [pltpu.SemaphoreType.DMA((2, 2)),
+                               pltpu.SMEM((1,), jnp.int32)] + stats,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qr.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem + vmem // 2),
         name="paged_attention",
         interpret=interpret,
-    )(lengths.astype(jnp.int32), tables.astype(jnp.int32),
-      qr, k_pool, v_pool)
-    out = out.reshape(S, nw, hp, K, G, D).transpose(0, 3, 1, 2, 4, 5)
-    return out.reshape(S, K, H, D)
+    )(lengths.astype(jnp.int32), tables.astype(jnp.int32), order,
+      jnp.sum(live, dtype=jnp.int32)[None], qr, k_pool, v_pool)
+    out = out.reshape(S, n_tiles, Hkv, q_tile, G, D).transpose(
+        0, 1, 3, 2, 4, 5)
+    return out.reshape(S, n_tiles * q_tile, Hkv * G, D)[:, :K]

@@ -132,8 +132,8 @@ _m_attn_bytes = Counter(
     "ray_tpu_serve_attn_bytes_moved_total",
     "KV-cache bytes the paged attention lane streamed per program call "
     "(host-side mirror arithmetic, labelled by lane: the gather lane "
-    "materializes the full provisioned arena, the in-place lanes only "
-    "pages covering live tokens)")
+    "materializes the full provisioned arena, the in-place lanes whole "
+    "blocks of pages up to each sequence's cursor)")
 _m_queue_depth = Gauge(
     "ray_tpu_serve_queue_depth",
     "Requests waiting for a free KV arena slot")
@@ -389,7 +389,7 @@ class ContinuousScheduler:
 
             self._verify = jax.jit(
                 _program(paged_verify_step, "paged_verify_step", cfg,
-                         attn=self.attn_lane), donate_argnums=(4,))
+                         attn=self.attn_lane), donate_argnums=(5,))
         # ---- cross-replica page migration (ISSUE 18): a dedicated
         # worker thread does the blocking peer pull; the scheduler thread
         # only splices finished results between iterations. _commands
@@ -421,6 +421,8 @@ class ContinuousScheduler:
         self._n_retired = 0
         self._n_tokens = 0
         self._n_attn_bytes = 0
+        self._n_attn_attended = 0
+        self._n_attn_fetched = 0
         self._n_prefix_hit_tokens = 0
         self._admitted_mid_flight = 0
         self._max_active_slots = 0
@@ -725,32 +727,56 @@ class ContinuousScheduler:
                 # an admission while other sequences are mid-generation
                 self._admitted_mid_flight += 1
 
-    def _record_attn(self, qk: int, n_slots: int,
-                     longest: Optional[int] = None) -> None:
-        """Account the KV bytes the attention lane streamed for one
+    def _record_attn(self, qk: int, cursors: List[int],
+                     idle_rows: int = 0) -> None:
+        """Account what the attention lane streamed for one
         attention-bearing program call (its device time is read from a
         profiler trace, by the program's name and the kernel's).
-        Pure host-side mirror arithmetic (cursors, table shapes) — no
-        device readback on the hot loop. The gather lane materializes a
-        contiguous ``[pages_per_slot * page_tokens]`` view per slot per
-        layer regardless of how little of it is live; the in-place lanes
-        stream only pages covering the longest live sequence."""
+        ``cursors``: the attention cursor of every slot row that attends a
+        K = ``qk`` window; ``idle_rows``: the call's other rows, which the
+        program marks as attending nothing. Pure host-side mirror arithmetic
+        (cursors, table shapes, the op's own ``tile_sizes``) — no device
+        readback on the hot loop. The gather lane materializes a
+        contiguous ``[pages_per_slot * page_tokens]`` view per slot
+        regardless of how little of it is live. The in-place lanes stream
+        whole BLOCKS of pages: the kernel each slot's own blocks, once per
+        query tile, up to the tile's last position (an idle row none);
+        the reference every row, idle ones too, over the longest row's
+        blocks.
+        ``attn_tokens_attended`` (the positions a row, or a query tile of
+        the kernel, may attend) over ``attn_tokens_fetched`` is the block
+        fill share (per layer: every layer repeats the same fetches)."""
         if not self._paged:
             return
+        from ray_tpu.ops.paged_attention import tile_sizes
+
         cfg = self.cfg
-        T = self.page_tokens
+        T, P = self.page_tokens, self._pages_per_slot
         row = cfg.kv_heads * cfg.head_dim * self._kv_itemsize
+        rows = len(cursors) + idle_rows
+        attended = sum(c + qk for c in cursors)
         if self.attn_lane == "gather":
-            pages = n_slots * self._pages_per_slot
+            fetched = rows * P * T
         else:
-            if longest is None:
-                longest = max((s.cursor for s in self._slot_seqs
-                               if s is not None), default=0)
-            pages = n_slots * min(-(-(longest + qk) // T),
-                                  self._pages_per_slot)
-        # k + v pools, every layer: pages read through the table plus the
-        # qk freshly-written rows per slot
-        moved = 2 * cfg.num_layers * row * (pages * T + n_slots * qk)
+            pages, q_tile = tile_sizes(qk, cfg.num_heads // cfg.kv_heads,
+                                       T, P, row)
+
+            def blocks(upto: int) -> int:
+                return min(-(-upto // (pages * T)), -(-P // pages))
+
+            if self.attn_lane == "reference":
+                fetched = rows * blocks(max(cursors) + qk) if cursors else 0
+            else:  # each query tile streams the blocks up to its own end
+                ends = [min(e, qk) for e in range(q_tile, qk + q_tile,
+                                                  q_tile)]
+                attended = sum(c + e for e in ends for c in cursors)
+                fetched = sum(blocks(c + e) for e in ends for c in cursors)
+            fetched *= pages * T
+        self._n_attn_attended += attended
+        self._n_attn_fetched += fetched
+        # k + v pools, every layer: the rows read through the table plus
+        # the qk freshly-written rows per slot
+        moved = 2 * cfg.num_layers * row * (fetched + rows * qk)
         self._n_attn_bytes += moved
         _m_attn_bytes.inc(moved, labels={"lane": self.attn_lane})
 
@@ -808,8 +834,7 @@ class ContinuousScheduler:
             # read from a profiler trace by the program's name, and the
             # wait for it falls into the next phase that reads a result
             if self._paged:
-                self._record_attn(self.prefill_chunk, 1,
-                                  longest=seq.cursor - real)
+                self._record_attn(self.prefill_chunk, [seq.cursor - real])
             self._n_prefill_chunks += 1
             _m_prefill_chunks.inc()
             if self._paged and self._radix is not None \
@@ -1190,12 +1215,13 @@ class ContinuousScheduler:
             vt[s.slot, :len(row)] = row
         switch(_P_VERIFY)
         vlogits, self._caches = self._verify(
-            self.params, jnp.asarray(vt),
+            self.params, jnp.asarray(vt), jnp.asarray(active),
             jnp.asarray(self._read_tables),
             jnp.asarray(self._write_tables), self._caches)
         va = np.asarray(vlogits)
         switch(_P_EMIT)  # acceptance, emission and the cursor rewind
-        self._record_attn(K, self.slots)
+        self._record_attn(K, [s.cursor for s in live],
+                          self.slots - len(live))
         self._n_steps += 1
         _m_steps.inc()
         self._n_spec_rounds += 1
@@ -1281,7 +1307,8 @@ class ContinuousScheduler:
             logits, self._caches = self._step(
                 self.params, jnp.asarray(toks), jnp.asarray(active),
                 self._caches)
-        self._record_attn(1, self.slots)
+        self._record_attn(1, [s.cursor for s in live],
+                          self.slots - len(live))
         self._n_steps += 1
         _m_steps.inc()
         self._max_active_slots = max(self._max_active_slots, len(live))
@@ -1470,6 +1497,8 @@ class ContinuousScheduler:
             out["pages_per_slot"] = self._pages_per_slot
             out["attn_lane"] = self.attn_lane
             out["attn_bytes_moved"] = self._n_attn_bytes
+            out["attn_tokens_attended"] = self._n_attn_attended
+            out["attn_tokens_fetched"] = self._n_attn_fetched
             out.update(self._arena.stats())
             if self._radix is not None:
                 out.update(self._radix.stats())

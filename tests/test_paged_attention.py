@@ -85,40 +85,120 @@ def _full_softmax_oracle(q, kp, vp, tables, lengths):
 
 
 class TestPagedAttentionOp:
-    def test_reference_matches_full_softmax_oracle(self):
-        """Mixed lengths — including 0 and an exact page-boundary multiple
-        — for both the decode (K=1) and verify (K=3) windows, with the
-        garbage page stuffed with huge values: the online-softmax
-        page-streaming reference must equal the materialized-view
-        softmax."""
+    @pytest.mark.parametrize("impl", ["reference", "pallas"])
+    @pytest.mark.parametrize("K,T,P,lengths", [
+        (1, 4, 6, [0, 5, 8, 13]),       # 8 = exactly two full pages (T=4)
+        (3, 4, 6, [0, 5, 8, 13]),
+        # around a block (B * T = 512 tokens): B*T - 1, B*T, B*T + 1 tokens
+        # attended by the K = 1 row, and several blocks plus a part
+        (1, 16, 96, [510, 511, 512, 1300]),
+        # a length-0 slot beside a long one
+        (1, 16, 96, [0, 1500]),
+        (4, 16, 96, [0, 509, 1100]),
+    ], ids=["k1_pages", "k3_pages", "k1_block_edges", "k1_empty_beside_long",
+            "k4_blocks"])
+    def test_lanes_match_full_softmax_oracle(self, impl, K, T, P, lengths):
+        """Mixed lengths — 0, exact page and block multiples and one to
+        either side — for the decode (K=1) and verify (K>1) windows, with
+        the garbage page stuffed with huge values behind every partly
+        filled last block: the online-softmax block-streaming lanes must
+        equal the materialized-view softmax."""
+        from ray_tpu.ops.paged_attention import paged_attention, tile_sizes
+
+        if T == 16:  # the cases are written for blocks of 512 tokens
+            assert tile_sizes(K, 2, T, P, 2 * 8 * 4) == (32, K)
+        rng = np.random.default_rng(0)
+        args = _mk_pools(rng, S=len(lengths), K=K, H=4, Hkv=2, D=8, T=T,
+                         P=P, lengths=lengths, garbage_fill=1e4)
+        got = np.asarray(paged_attention(*args, impl=impl))
+        want = _full_softmax_oracle(*args)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+
+    @pytest.mark.parametrize("impl", ["reference", "pallas"])
+    def test_chunk_sized_window_with_group_of_four(self, impl):
+        """A prefill-chunk-sized window (K = 160, G = 4: 640 query rows,
+        two query tiles of two matmuls' rows each) over contexts that end
+        inside, at and past a block edge: tiles re-stream the blocks up to
+        their own last position."""
+        from ray_tpu.ops.paged_attention import paged_attention, tile_sizes
+
+        pages, q_tile = tile_sizes(160, 4, 16, 64, 2 * 8 * 4)
+        assert (pages, q_tile) == (32, 128)
+        rng = np.random.default_rng(6)
+        args = _mk_pools(rng, S=3, K=160, H=8, Hkv=2, D=8, T=16, P=64,
+                         lengths=[0, 352, 700], garbage_fill=1e4)
+        got = np.asarray(paged_attention(*args, impl=impl))
+        want = _full_softmax_oracle(*args)
+        np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+
+    def test_bf16_pool_lanes_agree_and_track_the_oracle(self):
+        """bf16 pools (the deployed dtype): operands go to the matmuls in
+        bf16 with f32 accumulation, p rounded to bf16 before p.v as the
+        flash kernel does. The lanes agree to 1e-6 (same math; measured 0)
+        and sit within 2e-2 of the f32 oracle on the rounded pool
+        (measured 4e-3: the rounding of p)."""
         from ray_tpu.ops.paged_attention import paged_attention
 
-        rng = np.random.default_rng(0)
-        for K in (1, 3):
-            lengths = [0, 5, 8, 13]  # 8 = exactly two full pages (T=4)
-            args = _mk_pools(rng, S=4, K=K, H=4, Hkv=2, D=8, T=4, P=6,
-                             lengths=lengths, garbage_fill=1e4)
-            got = np.asarray(paged_attention(*args, impl="reference"))
-            want = _full_softmax_oracle(*args)
-            np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+        rng = np.random.default_rng(7)
+        q, kp, vp, tables, lengths = _mk_pools(
+            rng, S=3, K=2, H=4, Hkv=2, D=8, T=16, P=96,
+            lengths=[0, 511, 1200], garbage_fill=1e4)
+        q, kp, vp = (x.astype(jnp.bfloat16) for x in (q, kp, vp))
+        outs = {impl: np.asarray(paged_attention(
+            q, kp, vp, tables, lengths, impl=impl), np.float32)
+            for impl in ("reference", "pallas")}
+        np.testing.assert_allclose(outs["pallas"], outs["reference"],
+                                   atol=1e-6, rtol=0)
+        want = _full_softmax_oracle(*(np.asarray(x, np.float32)
+                                      for x in (q, kp, vp)), tables, lengths)
+        np.testing.assert_allclose(outs["pallas"], want, atol=2e-2, rtol=0)
 
-    def test_pallas_interpret_bitwise_equals_reference(self):
+    @pytest.mark.parametrize("qk,group,T,P,row_bytes,want", [
+        (1, 4, 16, 256, 2048, (32, 1)),      # Mistral decode: 2 MB a block
+        (512, 4, 16, 1024, 2048, (32, 128)),  # its chunk: 4 tiles of 512 rows
+        (1, 1, 16, 64, 1536, (32, 1)),       # GPT-2 small
+        (512, 1, 16, 64, 1536, (32, 512)),
+        (1, 2, 4, 6, 64, (4, 1)),            # never wider than the table
+        (7, 4, 1024, 8, 64, (1, 7)),         # a page past the token cap
+    ])
+    def test_tile_sizes_follow_static_shapes(self, qk, group, T, P,
+                                             row_bytes, want):
+        from ray_tpu.ops.paged_attention import tile_sizes
+
+        assert tile_sizes(qk, group, T, P, row_bytes) == want
+
+    @pytest.mark.parametrize("K,G,T,P,lengths", [
+        (1, 2, 4, 8, [0, 7, 16]),
+        (4, 2, 4, 8, [0, 7, 16]),
+        # a table no block divides (P = 6, blocks of 4 pages): the last
+        # block's entries past the table read the garbage page
+        (1, 2, 4, 6, [0, 9, 20]),
+        (3, 2, 4, 6, [1, 13, 21]),
+        # blocks of 512 tokens: one short, exact, one over, several + a part
+        (1, 4, 16, 96, [510, 511, 512, 1300]),
+        (4, 4, 16, 96, [507, 508, 509, 1300]),
+    ], ids=["k1", "k4", "k1_ragged_table", "k3_ragged_table", "k1_blocks",
+            "k4_blocks"])
+    def test_pallas_interpret_bitwise_equals_reference(self, K, G, T, P,
+                                                       lengths):
         """The kernel (interpret mode on CPU) and the pure-JAX reference
-        share page order, mask constant and online-softmax update — their
-        outputs must match BITWISE, not just to tolerance."""
+        share block order, operand dtypes, mask constant and online-softmax
+        update — their outputs must match BITWISE, not just to tolerance."""
         from ray_tpu.ops.paged_attention import paged_attention
 
         rng = np.random.default_rng(1)
-        for K in (1, 4):
-            args = _mk_pools(rng, S=3, K=K, H=4, Hkv=2, D=8, T=4, P=8,
-                             lengths=[0, 7, 16], garbage_fill=123.0)
-            ref = np.asarray(paged_attention(*args, impl="reference"))
-            pal = np.asarray(paged_attention(*args, impl="pallas"))
-            assert np.array_equal(ref, pal), \
-                f"pallas diverged from reference (max |d| = " \
-                f"{np.abs(ref - pal).max()})"
+        args = _mk_pools(rng, S=len(lengths), K=K, H=2 * G, Hkv=2, D=8, T=T,
+                         P=P, lengths=lengths, garbage_fill=123.0)
+        ref = np.asarray(paged_attention(*args, impl="reference"))
+        pal = np.asarray(paged_attention(*args, impl="pallas"))
+        assert np.array_equal(ref, pal), \
+            f"pallas diverged from reference (max |d| = " \
+            f"{np.abs(ref - pal).max()})"
 
-    def test_shared_prefix_pages_between_slots(self):
+    @pytest.mark.parametrize("T,P,cursor", [(4, 4, 9), (16, 96, 700)],
+                             ids=["pages", "blocks"])
+    def test_shared_prefix_pages_between_slots(self, T, P, cursor):
         """Two slots whose tables point at the SAME physical pages (a
         radix prefix hit) with equal cursors must produce identical rows —
         paging relocates bytes, never values."""
@@ -126,7 +206,7 @@ class TestPagedAttentionOp:
 
         rng = np.random.default_rng(2)
         q, kp, vp, tables, lengths = _mk_pools(
-            rng, S=2, K=1, H=4, Hkv=2, D=8, T=4, P=4, lengths=[9, 9])
+            rng, S=2, K=1, H=4, Hkv=2, D=8, T=T, P=P, lengths=[cursor] * 2)
         q = jnp.concatenate([q[:1], q[:1]])          # same query both slots
         tables = jnp.concatenate([tables[:1], tables[:1]])  # shared pages
         for impl in ("reference", "pallas"):
@@ -134,7 +214,10 @@ class TestPagedAttentionOp:
                                              impl=impl))
             assert np.array_equal(out[0], out[1])
 
-    def test_garbage_page_content_never_leaks(self):
+    @pytest.mark.parametrize("T,P,lengths", [
+        (4, 8, [2, 6, 11]), (16, 96, [3, 515, 1030])],
+        ids=["pages", "partly_filled_last_block"])
+    def test_garbage_page_content_never_leaks(self, T, P, lengths):
         """Masked pages must contribute bit-exact zeros to the online
         accumulator: stuffing the garbage page with huge values cannot
         change a single output bit."""
@@ -144,8 +227,8 @@ class TestPagedAttentionOp:
             outs = []
             for fill in (0.0, 1e4):
                 rng = np.random.default_rng(3)  # same content both times
-                args = _mk_pools(rng, S=3, K=2, H=4, Hkv=2, D=8, T=4, P=8,
-                                 lengths=[2, 6, 11], garbage_fill=fill)
+                args = _mk_pools(rng, S=3, K=2, H=4, Hkv=2, D=8, T=T, P=P,
+                                 lengths=lengths, garbage_fill=fill)
                 outs.append(np.asarray(paged_attention(*args, impl=impl)))
             assert np.array_equal(outs[0], outs[1]), impl
 
@@ -164,6 +247,33 @@ class TestPagedAttentionOp:
             want = np.asarray(vp)[np.asarray(tables)[0, 0], 0].reshape(2, 8)
             for h in range(4):
                 assert np.array_equal(out[0, 0, h], want[h // 2])
+
+    @pytest.mark.parametrize("K", [1, 3])
+    @pytest.mark.parametrize("idle", [[0], [1, 2], [3], [0, 1, 2, 3]],
+                             ids=["first", "middle", "last", "all"])
+    def test_slots_without_a_sequence_attend_nothing(self, K, idle):
+        """A row a caller marks with length -K (no live sequence) returns
+        zeros in both lanes, whatever its table holds and wherever it sits
+        among the live rows (the kernel serves those first and hands its
+        buffers from one to the next), and the live rows are what they are
+        without it: the lanes stay bitwise equal to each other."""
+        from ray_tpu.ops.paged_attention import paged_attention
+
+        rng = np.random.default_rng(8)
+        lengths = np.asarray([600, 30, 1100, 511], np.int32)
+        q, kp, vp, tables, _ = _mk_pools(
+            rng, S=4, K=K, H=4, Hkv=2, D=8, T=16, P=96, lengths=lengths,
+            garbage_fill=1e4)
+        want = _full_softmax_oracle(q, kp, vp, tables, lengths)
+        want[idle] = 0.0
+        lengths[idle] = -K   # the tables still name the stale pages
+        outs = {impl: np.asarray(paged_attention(
+            q, kp, vp, tables, jnp.asarray(lengths), impl=impl))
+            for impl in ("reference", "pallas")}
+        assert np.array_equal(outs["reference"], outs["pallas"])
+        assert not outs["pallas"][idle].any()
+        np.testing.assert_allclose(outs["pallas"], want, atol=1e-5,
+                                   rtol=1e-5)
 
     def test_unknown_impl_and_shape_mismatches_rejected(self):
         from ray_tpu.ops.paged_attention import paged_attention
@@ -284,9 +394,17 @@ class TestInPlaceLanes:
                 cfg, params, attn, self.PROMPT_IDS, 1)
             vt = np.asarray([[t[-1], 1, 2] for t in toks], np.int32)
             verify = jax.jit(partial(paged_verify_step, cfg, attn=attn))
-            logits, _ = verify(params, jnp.asarray(vt), tables, tables,
-                               caches)
+            active = np.ones(len(toks), np.int32)
+            logits, _ = verify(params, jnp.asarray(vt), jnp.asarray(active),
+                               tables, tables, caches)
             outs[attn] = np.asarray(logits)
+            # a row marked inactive (a retired slot's stale cursor and
+            # pages) changes nothing for the live rows
+            active[1] = 0
+            logits, _ = verify(params, jnp.asarray(vt), jnp.asarray(active),
+                               tables, tables, caches)
+            assert np.array_equal(np.asarray(logits)[[0, 2]],
+                                  outs[attn][[0, 2]]), attn
         assert np.array_equal(outs["gather"].argmax(-1),
                               outs["reference"].argmax(-1))
         assert np.array_equal(outs["reference"], outs["pallas"])
@@ -297,7 +415,7 @@ class TestInPlaceLanes:
                                            paged_verify_step)
 
         for fn, nargs in ((paged_decode_step, 6),
-                          (paged_verify_step, 5),
+                          (paged_verify_step, 6),
                           (paged_prefill_into_slot, 7)):
             with pytest.raises(ValueError, match="unknown paged attention"):
                 fn(None, *([None] * nargs), attn="turbo")
@@ -370,6 +488,56 @@ class TestLaneResolution:
                           attn="reference")
 
 
+# ---------------------------------------------------------- the counters
+
+
+class TestAttnCounters:
+    """``_record_attn`` mirrors on the host what each lane fetches, in
+    whole blocks of ``tile_sizes`` pages, against what is attended."""
+
+    @staticmethod
+    def _scheduler(lane):
+        import types
+
+        from ray_tpu.serve._private.continuous import ContinuousScheduler
+
+        sch = object.__new__(ContinuousScheduler)  # the counters only
+        sch.cfg = types.SimpleNamespace(num_heads=32, kv_heads=8,
+                                        head_dim=128, num_layers=2)
+        sch._paged, sch.attn_lane = True, lane
+        sch.page_tokens, sch._pages_per_slot, sch._kv_itemsize = 16, 256, 2
+        sch._n_attn_bytes = sch._n_attn_attended = sch._n_attn_fetched = 0
+        return sch
+
+    @pytest.mark.parametrize("lane,attended,fetched", [
+        # blocks of 32 pages = 512 tokens: 1 + 2 + 2 of the live rows' own
+        # and none for an idle row
+        ("pallas", 11 + 601 + 1024, (1 + 2 + 2) * 512),
+        # every row, idle ones too, over the longest row's two blocks
+        ("reference", 11 + 601 + 1024, 5 * 2 * 512),
+        # every row's whole provisioned view
+        ("gather", 11 + 601 + 1024, 5 * 256 * 16),
+    ])
+    def test_decode_step(self, lane, attended, fetched):
+        sch = self._scheduler(lane)
+        sch._record_attn(1, [10, 600, 1023], idle_rows=2)
+        assert sch._n_attn_attended == attended
+        assert sch._n_attn_fetched == fetched
+        # K and V, both layers, 2048 bytes a token row; + the 5 new rows
+        assert sch._n_attn_bytes == 2 * 2 * 2048 * (fetched + 5)
+
+    def test_prefill_chunk_streams_once_per_query_tile(self):
+        """A 512-token chunk at G = 4 is four query tiles of 128 tokens;
+        each streams the blocks up to its own last position."""
+        sch = self._scheduler("pallas")
+        sch._record_attn(512, [700])
+        assert sch._n_attn_attended == 828 + 956 + 1084 + 1212
+        assert sch._n_attn_fetched == (2 + 2 + 3 + 3) * 512
+        ref = self._scheduler("reference")
+        ref._record_attn(512, [700])
+        assert (ref._n_attn_attended, ref._n_attn_fetched) == (1212, 3 * 512)
+
+
 # ------------------------------------------------------------- end to end
 
 
@@ -393,9 +561,12 @@ class TestSchedulerLanes:
     def _drive(self, attn):
         from ray_tpu.serve.llm import LLMServerImpl
 
+        # an arena of several blocks (a block is at most 512 tokens), so
+        # that provisioning and live tokens can be told apart
         srv = LLMServerImpl(max_new_tokens=NEW, slots=SLOTS,
                             prefill_chunk=CHUNK, page_tokens=PAGE,
-                            share_weights=False, attn=attn)
+                            share_weights=False, attn=attn,
+                            preset_overrides={"max_seq_len": 2048})
         try:
             async def go():
                 reqs = [{"prompt": p} for p in PROMPTS * 3]  # > slots
@@ -421,10 +592,17 @@ class TestSchedulerLanes:
             assert stats[lane]["compiled_programs"] == 2, stats[lane]
             assert stats[lane]["prefix_hits"] > 0
             assert stats[lane]["attn_bytes_moved"] > 0
+            # the block fill share: what was attended of what was fetched
+            assert 0 < stats[lane]["attn_tokens_attended"] \
+                <= stats[lane]["attn_tokens_fetched"]
         assert texts["reference"] == texts["gather"]
         assert texts["pallas"] == texts["gather"]
         assert stats["gather"]["attn_bytes_moved"] > \
             2 * stats["reference"]["attn_bytes_moved"]
+        # the kernel fetches each slot's own blocks, the reference every
+        # row over the longest row's: never more
+        assert stats["pallas"]["attn_tokens_fetched"] \
+            <= stats["reference"]["attn_tokens_fetched"]
 
     def test_spec_decode_acceptance_unchanged_on_inplace_lane(self):
         """Speculative decoding rides the in-place verify lane unchanged:

@@ -4,8 +4,9 @@ libtpu compiles for a TPU that is described and not attached
 (``jax.experimental.topologies``), so the kernels and programs of the main
 path are compiled at their real widths against a ``v5e:2x2`` description:
 flash attention forward and forward+backward, paged attention (decode K=1,
-verify K=4), the GPT-2 small train step on one chip and sharded over four,
-and the three serve programs. Interpret mode cannot see what these see: a
+verify K=3, 4 and 5 and a prefill chunk K=512, at GPT-2 small's and
+Mistral-7B's widths), the GPT-2 small train step on one chip and sharded over
+four, and the three serve programs. Interpret mode cannot see what these see: a
 slice not aligned to the tiling, a kernel GSPMD cannot partition, a program
 that does not fit 16 GB. Nothing runs — a compile that passes is not a chip
 run.
@@ -110,16 +111,29 @@ def test_flash_attention_compiles(v5e, shape, grad):
         assert [n for n in names if kernel in n], (kernel, names)
 
 
-@pytest.mark.parametrize("K", [1, 4], ids=["decode_k1", "verify_k4"])
-@pytest.mark.parametrize("shape", [GPT2S, GQA128], ids=["gpt2s", "gqa_d128"])
-def test_paged_attention_compiles(v5e, shape, K):
+@pytest.mark.parametrize("shape,S,K,pages", [
+    (GPT2S, 8, 1, 64), (GPT2S, 8, 4, 64), (GQA128, 8, 1, 64),
+    (GQA128, 8, 4, 64),
+    # windows whose rows (K x group) are no power of two: the scheduler's
+    # verify window is serve_spec_k + 1 = 5
+    (GPT2S, 8, 3, 64), (GPT2S, 8, 5, 64), (GQA128, 8, 3, 64),
+    (GQA128, 8, 5, 64),
+    # Mistral-7B widths as the serving cells run them: the decode step over
+    # 32 slots of a 16384-token context, and one prefill chunk
+    (GQA128, 32, 1, 1024), (GQA128, 1, 512, 1024),
+    (GPT2S, 1, 512, 64)],
+    ids=["gpt2s-decode_k1", "gpt2s-verify_k4", "gqa_d128-decode_k1",
+         "gqa_d128-verify_k4", "gpt2s-verify_k3", "gpt2s-verify_k5",
+         "gqa_d128-verify_k3", "gqa_d128-verify_k5", "mistral-decode_32slots",
+         "mistral-chunk_k512", "gpt2s-chunk_k512"])
+def test_paged_attention_compiles(v5e, shape, S, K, pages):
     from ray_tpu.ops.paged_attention import (paged_attention,
                                              pallas_shape_problem)
 
-    S, T, pages = 8, 16, 64
+    T = 16
     assert pallas_shape_problem(shape["Hkv"], shape["D"]) is None
     chip = SingleDeviceSharding(v5e.devices[0])
-    pool = _on(chip, (S * pages + 1, T, shape["Hkv"] * shape["D"]))
+    pool = _on(chip, (min(S * pages, 6144) + 1, T, shape["Hkv"] * shape["D"]))
     text = jax.jit(functools.partial(paged_attention, impl="pallas")).lower(
         _on(chip, (S, K, shape["H"], shape["D"])), pool, pool,
         _on(chip, (S, pages), jnp.int32), _on(chip, (S,), jnp.int32),
@@ -248,8 +262,8 @@ def test_gpt2s_sharded_train_step_lowers_with_the_kernel_in_it(v5e):
 
 def test_gpt2s_serve_programs_compile_and_fit(v5e):
     """Prefill chunk, decode and verify at the scheduler's defaults for
-    GPT-2 small (8 slots, 32-token chunks, 16-token pages, K=4), on the
-    lane a TPU replica resolves."""
+    GPT-2 small (8 slots, 32-token chunks, 16-token pages, a verify window
+    of serve_spec_k + 1 = 5), on the lane a TPU replica resolves."""
     from ray_tpu._private.config import Config
     from ray_tpu.models import gpt2_small
     from ray_tpu.models.decode import (init_paged_caches, paged_decode_step,
@@ -284,8 +298,8 @@ def test_gpt2s_serve_programs_compile_and_fit(v5e):
                    (params, ids((slots,)), ids((slots,)), table, table,
                     caches), 5),
         "verify": (paged_verify_step,
-                   (params, ids((slots, conf.serve_spec_k)), table, table,
-                    caches), 4),
+                   (params, ids((slots, conf.serve_spec_k + 1)), ids((slots,)),
+                    table, table, caches), 5),
     }
     for name, (program, args, donated) in programs.items():
         compiled = jax.jit(functools.partial(program, cfg, attn=lane),
