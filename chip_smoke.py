@@ -10,6 +10,14 @@ weights from ``--seed``):
            grad) and paged attention (K=1, 4 and 5; and at Mistral-7B's widths
            K=1 and K=512 over contexts 16 to 8192) against their jax.numpy
            references on the device, and prints the largest errors
+  olmoe    a @ray_tpu.remote(num_tpus=1) task runs the benchmark's
+           OLMoE-1B-7B configuration (published widths, 8 layers, bf16): one
+           expert layer, then two prompts through the paged prefill chunks and
+           decode steps, against perfbench/reference/olmoe.py on the experts
+           the system chose (routed error), with the share of (token, layer)
+           pairs whose set of experts differs from the reference's own and
+           how far those were from it (routing margin); expert counts equal
+           live rows x 8
   serve    serve.run(build_app(preset="gpt2_small")) answers 8 concurrent
            requests: six through the handle, one streamed, one over HTTP
 
@@ -225,6 +233,143 @@ def kernels_task(seed: int) -> dict:
             **device_report()}
 
 
+def olmoe_task(seed: int) -> dict:
+    """The OLMoE-width checks (ISSUE 26): the system in bf16 against the
+    plain float32 reference given the system's own routes."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from perfbench.lib import configs, weights
+    from perfbench.lib import manifest as manifest_lib
+    from perfbench.reference import olmoe as ref
+    from ray_tpu.models.decode import (init_paged_caches, paged_decode_step,
+                                       paged_prefill_into_slot)
+    from ray_tpu.ops.moe import moe_layer
+
+    require_chip()
+    manifest = manifest_lib.load()
+    hp = manifest_lib.config(manifest, "olmoe_1b_7b_l8")
+    cfg = configs.build_program_config(*configs.program_overrides(
+        hp, manifest_lib.read_json_from_bench("families", hp["model_type"])))
+    L, E, k = cfg.num_layers, cfg.moe_num_experts, cfg.moe_top_k
+    params = weights.make_params(cfg, seed)
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 8))
+    out = {}
+
+    def rel(got, want):
+        got, want = (np.asarray(x, np.float32) for x in (got, want))
+        return {"max": float(np.abs(got - want).max() / np.abs(want).max()),
+                "rms": float(np.sqrt(((got - want) ** 2).mean()
+                                     / (want ** 2).mean()))}
+
+    # ---- one expert layer (layer 0's weights, as they lie: in the stack
+    # or apart), a chunk's rows of which the last 112 are padding
+    stacked = "mlp" in params["blocks"]
+    mlp = params["blocks"]["mlp" if stacked else "0"]
+    mlp = mlp if stacked else mlp["mlp"]
+    x = jax.random.normal(next(keys), (1, 512, cfg.embed_dim), cfg.dtype)
+    valid = jnp.arange(512)[None] < 400
+    y, _, counts, routes = jax.jit(lambda p, x, v: moe_layer(
+        p, x, num_experts=E, top_k=k, renormalize=cfg.moe_renormalize,
+        dtype=cfg.dtype, valid=v, layer=0 if stacked else None))(
+        mlp, x, valid)
+    with jax.default_matmul_precision("highest"):
+        h = x.astype(jnp.float32)
+        router = mlp["w_router"][0] if stacked else mlp["w_router"]
+        probs = jax.nn.softmax(h @ router.astype(jnp.float32), -1)
+        w = ref.token_weights(probs, routes, k, cfg.moe_renormalize)
+        want = jnp.zeros_like(h)
+        add = jax.jit(ref.add_expert)
+        for e in range(E):
+            want = add(want, h, w, mlp, (0, e) if stacked else (e,))
+    flips, margin = ref.routing_margin(probs[None, :, :400],
+                                       routes[None, :, :400])
+    out["layer"] = {"routed_err": rel(y[:, :400], want[:, :400]),
+                    "padding_is_zero": bool((y[:, 400:] == 0).all()),
+                    "rows_routed": int(counts.sum()), "live_rows_x_k": 400 * k,
+                    "flip_share": flips, "margin": margin}
+
+    # ---- the 8-layer model through the paged programs: two prompts (one
+    # of two chunks) prefilled into slots 0 and 5 of 8, then 6 decode steps
+    S, C, T, P = 8, 512, 16, 64
+    caches = init_paged_caches(cfg, S, S * P + 1, T, P)
+    tables = (1 + np.arange(S * P, dtype=np.int32)).reshape(S, P)
+    prefill = jax.jit(lambda *a: paged_prefill_into_slot(
+        cfg, *a, attn="pallas", moe_info=True), donate_argnums=(6,))
+    step = jax.jit(lambda *a: paged_decode_step(
+        cfg, *a, attn="pallas", moe_info=True), donate_argnums=(5,))
+    rng = np.random.default_rng(seed)
+    prompts = {0: rng.integers(1, cfg.vocab_size, 320).tolist(),
+               5: rng.integers(1, cfg.vocab_size, 700).tolist()}
+    got = {s: [] for s in prompts}      # logits at the served positions
+    taken = {s: [] for s in prompts}    # routes [L, tokens, k]
+    rows_routed = live_rows = 0
+    for s, prompt in prompts.items():
+        for c0 in range(0, len(prompt), C):
+            chunk = prompt[c0:c0 + C]
+            real = len(chunk)
+            logits, caches, moe = prefill(
+                params, jnp.asarray([chunk + [0] * (C - real)], jnp.int32),
+                np.int32(real), np.int32(s), jnp.asarray(tables[s]),
+                jnp.asarray(tables[s]), caches)
+            taken[s].append(np.asarray(moe["routes"])[:, 0, :real])
+            rows_routed += int(np.asarray(moe["counts"]).sum())
+            live_rows += real
+        got[s].append(np.asarray(logits, np.float32))
+    active = np.zeros(S, np.int32)
+    active[list(prompts)] = 1
+    fed = {s: [] for s in prompts}
+    for _ in range(6):
+        toks = np.zeros(S, np.int32)
+        for s in prompts:
+            fed[s].append(int(got[s][-1].argmax()))
+            toks[s] = fed[s][-1]
+        logits, caches, moe = step(params, jnp.asarray(toks),
+                                   jnp.asarray(active), jnp.asarray(tables),
+                                   jnp.asarray(tables), caches)
+        rows_routed += int(np.asarray(moe["counts"]).sum())
+        live_rows += len(prompts)
+        for s in prompts:
+            got[s].append(np.asarray(logits[s], np.float32))
+            taken[s].append(np.asarray(moe["routes"])[:, s])
+    errs, flip_share, margins = [], [], []
+    for s, prompt in prompts.items():
+        tokens = jnp.asarray([prompt + fed[s]], jnp.int32)
+        routes = jnp.asarray(np.concatenate(taken[s], axis=1))[:, None]
+        want, probs = ref.forward_and_router(params, tokens, hp, routes)
+        errs.append(rel(np.stack(got[s][:-1]),
+                        np.asarray(want[0])[len(prompt) - 1:-1]))
+        f, m = ref.routing_margin(probs, routes)
+        flip_share.append(f)
+        margins.append(m)
+    out["model"] = {"routed_err_max": max(e["max"] for e in errs),
+                    "routed_err_rms": max(e["rms"] for e in errs),
+                    "flip_share": flip_share, "margin": margins,
+                    "rows_routed": rows_routed,
+                    "live_rows_x_k_x_layers": live_rows * k * L}
+    bad = []
+    if out["layer"]["rows_routed"] != out["layer"]["live_rows_x_k"] \
+            or rows_routed != live_rows * k * L:
+        bad.append("a row was dropped or a dead row counted")
+    if not out["layer"]["padding_is_zero"]:
+        bad.append("a padded row came out of the expert layer non-zero")
+    # the dense serving cells' tolerance (bf16 through the layers: 8% of
+    # the largest logit, 6% root-mean-square; read there at most 4.3 / 3.8)
+    if out["layer"]["routed_err"]["max"] > 0.03 \
+            or out["model"]["routed_err_max"] > 0.08 \
+            or out["model"]["routed_err_rms"] > 0.06:
+        bad.append("routed error above the dense cells' tolerance")
+    # another expert only where rounding can: bf16 hidden states move a
+    # router probability (about 1/64 to 1/10) by well under 0.004
+    if max(margins + [margin]) > 4e-3:
+        bad.append("an expert was taken that the reference scores far "
+                   "below its 8th")
+    if bad:
+        raise RuntimeError(f"olmoe: {bad}: {out}")
+    return {**out, **device_report()}
+
+
 class ChipProbe:
     """One-chip actor for the four-replica check: sees one device, works."""
 
@@ -275,6 +420,15 @@ def kernels_phase(seed: int) -> None:
     out = ray_tpu.get(
         ray_tpu.remote(num_tpus=1)(kernels_task).remote(seed), timeout=900)
     emit("kernels", seconds=round(time.perf_counter() - t0, 1), **out)
+
+
+def olmoe_phase(seed: int) -> None:
+    import ray_tpu
+
+    t0 = time.perf_counter()
+    out = ray_tpu.get(
+        ray_tpu.remote(num_tpus=1)(olmoe_task).remote(seed), timeout=1500)
+    emit("olmoe", seconds=round(time.perf_counter() - t0, 1), **out)
 
 
 def serve_phase(seed: int) -> None:
@@ -382,6 +536,7 @@ def serve_phase(seed: int) -> None:
 def one_chip(seed: int) -> dict:
     out = fit("train", seed, steps=6, mesh=None, chips=1)
     kernels_phase(seed)
+    olmoe_phase(seed)
     serve_phase(seed)
     return out["device"]
 

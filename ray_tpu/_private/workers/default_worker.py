@@ -580,10 +580,14 @@ class Executor:
         )
 
     async def _notify_creation_failed(self, spec: TaskSpec, err) -> None:
+        # the head says which task, the tail which exception: keep both
+        reason = str(err)
+        if len(reason) > 1500:
+            reason = reason[:300] + "\n...\n" + reason[-1200:]
         try:
             await self.core.clients.get(self.core.controller_addr).call(
                 "actor_creation_failed",
-                {"actor_id_hex": spec.actor_id.hex(), "reason": str(err)[:500]},
+                {"actor_id_hex": spec.actor_id.hex(), "reason": reason},
             )
         except Exception:
             pass

@@ -19,9 +19,9 @@ import numpy as np
 from jax import lax
 
 from ray_tpu.models.transformer import (TransformerConfig, _mlp, _norm,
-                                        forward)
+                                        _qkv, forward, stacked_mlp)
 from ray_tpu.ops.paged_attention import paged_attention
-from ray_tpu.ops.rotary import apply_rotary, rope_frequencies
+from ray_tpu.ops.rotary import rope_frequencies
 
 
 @jax.tree_util.register_dataclass
@@ -318,7 +318,7 @@ def _layer_params(cfg: TransformerConfig, params, i: int):
 
 def _paged_forward_inplace(cfg: TransformerConfig, params, tokens, positions,
                            lengths, read_tables, write_tables, caches, impl,
-                           advance):
+                           advance, valid):
     """The in-place twin of the gathered-view programs: one K-token-window
     forward over all S slots where each layer (1) writes the window's k/v
     DIRECTLY into its pages — ``pool.at[page, offset].set`` through the
@@ -333,7 +333,13 @@ def _paged_forward_inplace(cfg: TransformerConfig, params, tokens, positions,
     its OWN buffer — the callers donate caches, and a shared buffer would
     be donated once per layer). Positions on unallocated/shared pages
     redirect to the garbage page through the write table, same contract
-    as the scatter-back lane. Returns (logits [S, K, vocab], caches)."""
+    as the scatter-back lane. ``valid``: bool [S, K], the rows that carry a
+    live token (not a slot without a sequence, not a chunk's padding): the
+    expert layer routes the others nowhere. Returns (logits [S, K, vocab],
+    caches, moe): moe is None for a dense model, else ``{"counts": [L, E],
+    "routes": [L, S, K, k]}`` — the rows each layer's experts received
+    (they sum to valid rows x k a layer: no row is dropped) and the experts
+    each row chose."""
     T = caches[0].k.shape[1]
     P = read_tables.shape[1]
     x = params["embed"]["table"].astype(cfg.dtype)[tokens]
@@ -347,28 +353,26 @@ def _paged_forward_inplace(cfg: TransformerConfig, params, tokens, positions,
         write_tables, jnp.clip(positions // T, 0, P - 1), axis=1)
     offs = positions % T
     new_caches = []
+    moe_layers = []
     for i in range(cfg.num_layers):
         p = _layer_params(cfg, params, i)
         c = caches[i]
-        h = _norm(cfg, p["ln1"], x)
         ap = p["attn"]
-        q = jnp.einsum("bsd,dhk->bshk", h, ap["wq"].astype(cfg.dtype))
-        k = jnp.einsum("bsd,dhk->bshk", h, ap["wk"].astype(cfg.dtype))
-        v = jnp.einsum("bsd,dhk->bshk", h, ap["wv"].astype(cfg.dtype))
-        if rope is not None:
-            cos, sin = rope
-            q = apply_rotary(q, cos, sin, positions)
-            k = apply_rotary(k, cos, sin, positions)
+        q, k, v = _qkv(cfg, ap, _norm(cfg, p["ln1"], x), rope, positions)
         ck = c.k.at[pages, offs].set(
             k.reshape(*k.shape[:2], -1).astype(c.k.dtype))
         cv = c.v.at[pages, offs].set(
             v.reshape(*v.shape[:2], -1).astype(c.v.dtype))
         o = paged_attention(q, ck, cv, read_tables, lengths, impl=impl)
         x = x + jnp.einsum("bshk,hkd->bsd", o, ap["wo"].astype(cfg.dtype))
-        m, _ = _mlp(cfg, p["mlp"], _norm(cfg, p["ln2"], x))
+        mlp_p, layer = stacked_mlp(cfg, params, p, i)
+        m, _, moe = _mlp(cfg, mlp_p, _norm(cfg, p["ln2"], x), valid, layer)
         x = x + m
+        moe_layers.append(moe)
         new_caches.append(PagedKVCache(k=ck, v=cv,
                                        lengths=advance(c.lengths)))
+    moe = (jax.tree.map(lambda *a: jnp.stack(a), *moe_layers)
+           if cfg.mlp == "moe" else None)
     x = _norm(cfg, params["final_norm"], x)
     if cfg.tie_embeddings:
         logits = jnp.einsum("bsd,vd->bsv", x,
@@ -376,13 +380,27 @@ def _paged_forward_inplace(cfg: TransformerConfig, params, tokens, positions,
     else:
         logits = jnp.einsum("bsd,dv->bsv", x,
                             params["lm_head"]["kernel"].astype(cfg.dtype))
-    return logits, new_caches
+    return logits, new_caches, moe
+
+
+def _paged_outputs(first, caches, moe, moe_info: bool):
+    """What a paged program returns: ``(first, caches)``, and with
+    ``moe_info`` the expert layers' counts and routes as a third."""
+    return (first, caches, moe) if moe_info else (first, caches)
+
+
+def _check_moe_info(cfg: TransformerConfig, attn: str, moe_info: bool):
+    if moe_info and (cfg.mlp != "moe" or attn == "gather"):
+        raise ValueError(
+            "moe_info needs mlp='moe' and an in-place attention lane "
+            "('reference' or 'pallas'): the gathered-view lane runs rows "
+            "without a live sequence through the experts and counts nothing")
 
 
 def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
                             slot, read_row, write_row,
                             caches: List[PagedKVCache], *,
-                            attn: str = "gather"):
+                            attn: str = "gather", moe_info: bool = False):
     """``prefill_into_slot`` through a page table. read_row/write_row: [P]
     int32 — shared (prefix-cache) pages appear in read_row but are
     redirected to the garbage page in write_row, so their content is
@@ -398,18 +416,23 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
     tokens [cursor, cursor + real_len) is allocated and OWNED (write_row
     == read_row there); pad positions beyond real_len may fall on
     unallocated entries — their writes redirect to the garbage page and
-    their reads are causally masked. cursor + C fits the logical view."""
+    their reads are causally masked. cursor + C fits the logical view.
+
+    ``moe_info`` (in-place lanes, mlp='moe'): return a third value, the
+    expert layers' ``{"counts": [L, E], "routes": [L, 1, C, k]}``; the
+    chunk's padding past ``real_len`` is routed nowhere and not counted."""
     _check_attn_lane(attn)
+    _check_moe_info(cfg, attn, moe_info)
     if attn != "gather":
         lengths = lax.dynamic_slice(caches[0].lengths, (slot,), (1,))
-        positions = jnp.arange(tokens.shape[1])[None, :] + lengths[:, None]
-        logits, new_caches = _paged_forward_inplace(
-            cfg, params, tokens, positions, lengths, read_row[None],
-            write_row[None], caches, attn,
-            lambda l: l.at[slot].add(real_len))
+        steps = jnp.arange(tokens.shape[1])[None, :]
+        logits, new_caches, moe = _paged_forward_inplace(
+            cfg, params, tokens, steps + lengths[:, None], lengths,
+            read_row[None], write_row[None], caches, attn,
+            lambda l: l.at[slot].add(real_len), steps < real_len)
         last = lax.dynamic_index_in_dim(logits[0], real_len - 1,
                                         keepdims=False)
-        return last, new_caches
+        return _paged_outputs(last, new_caches, moe, moe_info)
     T, HD = caches[0].k.shape[1:]
     P = read_row.shape[0]
     rows = []
@@ -444,7 +467,8 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
 
 def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
                       read_tables, write_tables,
-                      caches: List[PagedKVCache], *, attn: str = "gather"):
+                      caches: List[PagedKVCache], *, attn: str = "gather",
+                      moe_info: bool = False):
     """``slot_decode_step`` through page tables: one fixed-shape program
     over the whole arena. tokens/active: [slots] int32; read_tables/
     write_tables: [slots, P] int32.
@@ -459,18 +483,22 @@ def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
     the page table, never materializing the view (temperature-0 token
     parity with the gather lane, asserted in tests/test_paged_attention).
 
-    Returns (logits [slots, vocab], caches)."""
+    Returns (logits [slots, vocab], caches); with ``moe_info`` (in-place
+    lanes, mlp='moe') a third value, the expert layers' ``{"counts":
+    [L, E], "routes": [L, slots, 1, k]}`` over the active rows."""
     _check_attn_lane(attn)
+    _check_moe_info(cfg, attn, moe_info)
     if attn != "gather":
         lengths = caches[0].lengths
         # a slot the step marks inactive attends nothing (its logits are
-        # dropped): a retired slot's stale cursor streams no page
-        logits, new_caches = _paged_forward_inplace(
+        # dropped): a retired slot's stale cursor streams no page, and the
+        # expert layer routes its row nowhere
+        logits, new_caches, moe = _paged_forward_inplace(
             cfg, params, tokens[:, None], lengths[:, None],
             jnp.where(active > 0, lengths, -1),
             read_tables, write_tables, caches, attn,
-            lambda l: l + active)
-        return logits[:, 0], new_caches
+            lambda l: l + active, active[:, None] > 0)
+        return _paged_outputs(logits[:, 0], new_caches, moe, moe_info)
     T, HD = caches[0].k.shape[1:]
     slots, P = read_tables.shape
 
@@ -509,7 +537,8 @@ def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
 
 def paged_verify_step(cfg: TransformerConfig, params, tokens, active,
                       read_tables, write_tables,
-                      caches: List[PagedKVCache], *, attn: str = "gather"):
+                      caches: List[PagedKVCache], *, attn: str = "gather",
+                      moe_info: bool = False):
     """Speculative-decoding verify: score K candidate tokens per slot in
     ONE fixed-shape call over the slots axis (ISSUE 18). active: [slots]
     int32, 0 for a row without a live sequence (the in-place lanes attend
@@ -537,17 +566,21 @@ def paged_verify_step(cfg: TransformerConfig, params, tokens, active,
     unallocated write entries redirect to the garbage page, so a verify
     can never scribble on prefix-cache pages.
 
-    Returns (logits [slots, K, vocab], caches)."""
+    Returns (logits [slots, K, vocab], caches); with ``moe_info`` (in-place
+    lanes, mlp='moe') a third value, the expert layers' ``{"counts":
+    [L, E], "routes": [L, slots, K, k]}`` over the used rows."""
     _check_attn_lane(attn)
+    _check_moe_info(cfg, attn, moe_info)
     if attn != "gather":
         K = tokens.shape[1]
         lengths = caches[0].lengths
-        positions = lengths[:, None] + jnp.arange(K, dtype=jnp.int32)[None]
-        logits, new_caches = _paged_forward_inplace(
-            cfg, params, tokens, positions,
+        steps = jnp.arange(K, dtype=jnp.int32)[None]
+        logits, new_caches, moe = _paged_forward_inplace(
+            cfg, params, tokens, lengths[:, None] + steps,
             jnp.where(active > 0, lengths, -K),
-            read_tables, write_tables, caches, attn, lambda l: l)
-        return logits, new_caches
+            read_tables, write_tables, caches, attn, lambda l: l,
+            steps < active[:, None])
+        return _paged_outputs(logits, new_caches, moe, moe_info)
     T, HD = caches[0].k.shape[1:]
     slots, P = read_tables.shape
     K = tokens.shape[1]

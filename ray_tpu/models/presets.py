@@ -69,13 +69,15 @@ def llama_debug(**overrides) -> TransformerConfig:
 
 
 def moe_debug(**overrides) -> TransformerConfig:
-    """Tiny MoE config (SwiGLU experts, top-2 routing) for tests and
-    expert-parallel dry runs."""
+    """Tiny OLMoE-shaped config (allenai/OLMoE-1B-7B: SwiGLU experts,
+    dropless top-k routing with weights that are not renormalized, RMSNorm
+    on the whole projected q and k) for tests and expert-parallel dry
+    runs: top-3 of 8 experts."""
     kw = dict(
         vocab_size=256, num_layers=2, embed_dim=64, num_heads=4,
-        num_kv_heads=2, mlp="moe", mlp_dim=128, moe_num_experts=4,
-        moe_top_k=2, max_seq_len=128, norm="rmsnorm", pos="rope",
-        tie_embeddings=False, dtype=jnp.float32,
+        num_kv_heads=2, mlp="moe", mlp_dim=128, moe_num_experts=8,
+        moe_top_k=3, moe_renormalize=False, qk_norm=True, max_seq_len=128,
+        norm="rmsnorm", pos="rope", tie_embeddings=False, dtype=jnp.float32,
     )
     kw.update(overrides)
     return TransformerConfig(**kw)
@@ -395,12 +397,13 @@ def _apply_blocks(cfg, blocks, h, n_local: int):
             policy=policies[cfg.remat_policy])
     if cfg.scan_layers:
         def body(carry, layer_params):
-            hh, _, _ = block_fn(cfg, layer_params, carry, rope, None, None)
+            hh, _, _, _ = block_fn(cfg, layer_params, carry, rope, None,
+                                   None)
             return hh, None
         h, _ = lax.scan(body, h, blocks)
     else:
         for i in range(n_local):
-            h, _, _ = block_fn(cfg, blocks[str(i)], h, rope, None, None)
+            h, _, _, _ = block_fn(cfg, blocks[str(i)], h, rope, None, None)
     return h
 
 

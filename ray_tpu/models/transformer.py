@@ -10,8 +10,11 @@ Config switches:
   * norm: 'rmsnorm' (LLaMA) | 'layernorm' (GPT-2)
   * pos:  'rope' (LLaMA) | 'learned' (GPT-2)
   * mlp:  'swiglu' (LLaMA) | 'gelu' (GPT-2) | 'moe' (SwiGLU experts,
-          top-k routing, expert-parallel over the `ep` mesh axis)
+          dropless top-k routing over grouped matmuls, ops/moe.py)
   * GQA via num_kv_heads; tied embeddings via tie_embeddings.
+  * qk_norm: RMSNorm over the whole projected q and k (OLMoE);
+    moe_renormalize: top-k router weights divided by their sum (Mixtral)
+    or taken as they are (OLMoE).
 """
 
 from __future__ import annotations
@@ -38,11 +41,14 @@ class TransformerConfig:
     num_heads: int = 12
     num_kv_heads: Optional[int] = None        # None => MHA
     mlp_dim: Optional[int] = None             # None => 4x (gelu) / 8/3x (swiglu)
-    # MoE (mlp='moe'): SwiGLU experts, top-k routing, EP-sharded experts
+    # MoE (mlp='moe'): SwiGLU experts, dropless top-k routing
     moe_num_experts: int = 0
     moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
+    moe_renormalize: bool = True              # False: OLMoE (norm_topk_prob)
     moe_aux_weight: float = 0.01
+    # RMSNorm over ALL H*D (resp. Hkv*D) projected values of q and k, before
+    # the split into heads and before RoPE (OLMoE's q_norm / k_norm)
+    qk_norm: bool = False
     max_seq_len: int = 2048
     norm: str = "rmsnorm"                     # 'rmsnorm' | 'layernorm'
     pos: str = "rope"                         # 'rope' | 'learned'
@@ -104,6 +110,9 @@ def _block_params(cfg: TransformerConfig, key) -> Dict[str, Any]:
         "ln1": _norm_params(cfg, d),
         "ln2": _norm_params(cfg, d),
     }
+    if cfg.qk_norm:
+        p["attn"]["q_norm"] = jnp.ones((h * hd,), cfg.param_dtype)
+        p["attn"]["k_norm"] = jnp.ones((kvh * hd,), cfg.param_dtype)
     if cfg.mlp == "moe":
         from ray_tpu.ops.moe import init_moe_params
 
@@ -175,6 +184,9 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
         "ln1": norm_axes(),
         "ln2": norm_axes(),
     }
+    if cfg.qk_norm:
+        block["attn"]["q_norm"] = L + (None,)
+        block["attn"]["k_norm"] = L + (None,)
     if cfg.mlp == "moe":
         from ray_tpu.ops.moe import moe_logical_axes
 
@@ -217,18 +229,32 @@ def _norm(cfg, p, x):
     return layer_norm(x, p["scale"], p["bias"], cfg.norm_eps)
 
 
-def _attn(cfg, p, x, rope, positions, sp_axis, kv_cache=None):
-    b, s, d = x.shape
+def _qkv(cfg, p, x, rope, positions):
+    """The q/k/v projection of every forward (training, tensor-parallel,
+    cached and paged decode): x [B, S, d] -> q [B, S, H, D], k and v
+    [B, S, Hkv, D], q and k normalized (``cfg.qk_norm``) and rotated."""
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(cfg.dtype))
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(cfg.dtype))
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(cfg.dtype))
+    if cfg.qk_norm:
+        # one scale vector over all heads' values: the norm sees the
+        # projection whole, before it is split into heads
+        q = rms_norm(q.reshape(*q.shape[:2], -1), p["q_norm"],
+                     cfg.norm_eps).reshape(q.shape)
+        k = rms_norm(k.reshape(*k.shape[:2], -1), p["k_norm"],
+                     cfg.norm_eps).reshape(k.shape)
     if rope is not None:
         cos, sin = rope
         q = apply_rotary(q, cos, sin, positions)
         k = apply_rotary(k, cos, sin, positions)
+    return q, k, v
+
+
+def _attn(cfg, p, x, rope, positions, sp_axis, kv_cache=None):
+    q, k, v = _qkv(cfg, p, x, rope, positions)
     if kv_cache is not None:
         # decode: append to cache, attend over the full prefix
-        bias = kv_cache.mask_bias(s)
+        bias = kv_cache.mask_bias(x.shape[1])
         new_cache, k_all, v_all = kv_cache.update(k, v)
         o = attention(q, k_all, v_all, causal=False, impl="reference",
                       bias=bias)
@@ -243,42 +269,66 @@ def _attn(cfg, p, x, rope, positions, sp_axis, kv_cache=None):
     return out, new_cache
 
 
-def _mlp(cfg, p, x):
-    """Returns (y, aux_loss) — aux is 0 except for MoE routing."""
+def _mlp(cfg, p, x, valid=None, layer=None):
+    """Returns (y, aux_loss, moe): aux is 0 and moe None except for MoE
+    routing, where moe is ``{"counts": [E], "routes": [B, S, k]}`` (rows
+    each expert received; the experts each row chose). ``valid``: bool
+    [B, S], rows that are not live (the expert layer routes them nowhere; a
+    dense mlp takes no notice). ``layer`` (experts only): ``p`` is every
+    layer's weights stacked and this is the one to apply (``stacked_mlp``)."""
     if cfg.mlp == "moe":
         from ray_tpu.ops.moe import moe_layer
 
-        return moe_layer(p, x, num_experts=cfg.moe_num_experts,
-                         top_k=cfg.moe_top_k,
-                         capacity_factor=cfg.moe_capacity_factor,
-                         dtype=cfg.dtype)
+        y, aux, counts, routes = moe_layer(
+            p, x, num_experts=cfg.moe_num_experts, top_k=cfg.moe_top_k,
+            renormalize=cfg.moe_renormalize, dtype=cfg.dtype, valid=valid,
+            layer=layer)
+        return y, aux, {"counts": counts, "routes": routes}
     if cfg.mlp == "swiglu":
         gate = jnp.einsum("bsd,df->bsf", x, p["w_gate"].astype(cfg.dtype))
         up = jnp.einsum("bsd,df->bsf", x, p["w_up"].astype(cfg.dtype))
         return jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
-                          p["w_down"].astype(cfg.dtype)), 0.0
+                          p["w_down"].astype(cfg.dtype)), 0.0, None
     h = jnp.einsum("bsd,df->bsf", x, p["w_in"].astype(cfg.dtype))
     h = jax.nn.gelu(h + p["b_in"].astype(cfg.dtype), approximate=True)
     return (jnp.einsum("bsf,fd->bsd", h, p["w_out"].astype(cfg.dtype))
-            + p["b_out"].astype(cfg.dtype)), 0.0
+            + p["b_out"].astype(cfg.dtype)), 0.0, None
 
 
-def _block(cfg, p, x, rope, positions, sp_axis, kv_cache=None):
+def stacked_mlp(cfg, params, layer_params, i):
+    """``(mlp weights, layer)`` for ``_mlp``: a layer's own weights and
+    None, but for experts stacked over layers (``scan_layers``) outside a
+    scan the whole stack and ``i`` — the grouped matmuls cannot fuse the
+    slice as a dense matmul does, and would copy the layer's experts."""
+    if cfg.mlp == "moe" and cfg.scan_layers:
+        return params["blocks"]["mlp"], i
+    return layer_params["mlp"], None
+
+
+def _block(cfg, p, x, rope, positions, sp_axis, kv_cache=None, mlp=None):
+    """``mlp``: ``stacked_mlp``'s pair where the caller walks stacked
+    layers one by one; None for ``(p["mlp"], None)``."""
     a, new_cache = _attn(cfg, p["attn"], _norm(cfg, p["ln1"], x), rope,
                          positions, sp_axis, kv_cache)
     x = x + a
-    m, aux = _mlp(cfg, p["mlp"], _norm(cfg, p["ln2"], x))
+    mlp_p, layer = mlp or (p["mlp"], None)
+    m, aux, moe = _mlp(cfg, mlp_p, _norm(cfg, p["ln2"], x), layer=layer)
     x = x + m
-    return x, new_cache, aux
+    return x, new_cache, aux, moe
 
 
 def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
             sp_axis: Optional[str] = None, kv_caches=None,
-            return_aux: bool = False, return_hidden: bool = False):
+            return_aux: bool = False, return_hidden: bool = False,
+            return_routes: bool = False):
     """tokens [B, S] int32 -> logits [B, S, vocab].
 
     return_hidden: skip the vocab projection and return the post-final-norm
     hidden states [B, S, D] (with aux) — used by the fused-CE loss path.
+    return_routes (debug, mlp='moe' without kv_caches): also return the
+    experts every token chose in every layer, int32 [L, B, S, k] — top-k is
+    discontinuous, so a comparison with another implementation has to be
+    made on the same choices.
 
     sp_axis: when running inside shard_map with sequence sharded over that
     axis, attention goes through the ring kernel and `positions` must be the
@@ -308,32 +358,43 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
         block_fn = jax.checkpoint(
             _block, static_argnums=(0, 5), policy=policy)
 
+    if return_routes and (cfg.mlp != "moe" or kv_caches is not None):
+        raise ValueError("return_routes needs mlp='moe' and no kv_caches")
     new_caches = None
     aux_total = 0.0
+    routes = None
     if cfg.scan_layers and kv_caches is None:
         def body(carry, layer_params):
             h, aux_acc = carry
-            h, _, aux = block_fn(cfg, layer_params, h, rope, positions,
-                                 sp_axis)
-            return (h, aux_acc + aux), None
-        (x, aux_total), _ = jax.lax.scan(body, (x, 0.0), params["blocks"])
+            h, _, aux, moe = block_fn(cfg, layer_params, h, rope, positions,
+                                      sp_axis)
+            return (h, aux_acc + aux), (moe["routes"] if return_routes
+                                        else None)
+        (x, aux_total), routes = jax.lax.scan(body, (x, 0.0),
+                                              params["blocks"])
     elif cfg.scan_layers:
         new_caches = []
         for i in range(cfg.num_layers):
             layer_p = jax.tree.map(lambda a, i=i: a[i], params["blocks"])
-            x, c, aux = _block(cfg, layer_p, x, rope, positions, sp_axis,
-                               kv_caches[i])
+            x, c, aux, _ = _block(cfg, layer_p, x, rope, positions, sp_axis,
+                                  kv_caches[i],
+                                  stacked_mlp(cfg, params, layer_p, i))
             aux_total = aux_total + aux
             new_caches.append(c)
     else:
         new_caches = [] if kv_caches is not None else None
+        per_layer = []
         for i in range(cfg.num_layers):
             cache = kv_caches[i] if kv_caches is not None else None
-            x, c, aux = block_fn(cfg, params["blocks"][str(i)], x, rope,
-                                 positions, sp_axis, cache)
+            x, c, aux, moe = block_fn(cfg, params["blocks"][str(i)], x, rope,
+                                      positions, sp_axis, cache)
             aux_total = aux_total + aux
             if new_caches is not None:
                 new_caches.append(c)
+            if return_routes:
+                per_layer.append(moe["routes"])
+        if return_routes:
+            routes = jnp.stack(per_layer)
 
     x = _norm(cfg, params["final_norm"], x)
     if return_hidden:
@@ -346,6 +407,8 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
                             params["lm_head"]["kernel"].astype(cfg.dtype))
     if kv_caches is not None:
         return logits, new_caches
+    if return_routes:
+        return logits, routes
     if return_aux:
         return logits, aux_total
     return logits
@@ -371,6 +434,10 @@ def tp_block_shard_spec(cfg: TransformerConfig) -> Dict[str, Dict[str, int]]:
     gelu's post-reduce b_out) are replicated. For scan-stacked blocks add 1
     to every axis (the leading layers axis).
     """
+    if cfg.qk_norm:
+        raise ValueError(
+            "tensor parallelism does not support cfg.qk_norm=True — the "
+            "norm spans all heads' values, which tp splits over ranks")
     spec: Dict[str, Dict[str, int]] = {
         "attn": {"wq": 1, "wk": 1, "wv": 1,   # (d, heads, hd) — heads
                  "wo": 0},                     # (heads, hd, d) — heads
@@ -443,13 +510,7 @@ def merge_tp_block_params(cfg: TransformerConfig, shards, *,
 def _tp_attn_partial(cfg, p, x, rope, positions=None):
     """Attention over this rank's local heads; returns the PARTIAL output
     projection (sum over local heads only — g completes it)."""
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(cfg.dtype))
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(cfg.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(cfg.dtype))
-    if rope is not None:
-        cos, sin = rope
-        q = apply_rotary(q, cos, sin, positions)
-        k = apply_rotary(k, cos, sin, positions)
+    q, k, v = _qkv(cfg, p, x, rope, positions)
     o = attention(q, k, v, causal=True,
                   impl=cfg.attn_impl if cfg.attn_impl != "ring" else "auto")
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(cfg.dtype))

@@ -181,6 +181,12 @@ def run(app: Application, *, name: str = "default",
             st = ray_tpu.get(controller.status.remote()).get(name, {})
             if st and all(d["status"] == "RUNNING" for d in st.values()):
                 break
+            failed = {n: d["error"] for n, d in st.items() if d.get("error")}
+            if failed:
+                raise RuntimeError(
+                    f"application {name!r} failed to deploy: " + "; ".join(
+                        f"a replica of {n!r} died in its constructor: {e}"
+                        for n, e in failed.items()))
             time.sleep(0.1)
         else:
             raise TimeoutError(f"application {name!r} not RUNNING: {st}")

@@ -314,14 +314,21 @@ class ContinuousScheduler:
             # later decode step, and stats() always names the real lane
             self.attn_lane = resolve_paged_attn_lane(
                 conf.serve_paged_attn if attn is None else attn, cfg)
+            # an expert layer's programs hand the rows each expert received
+            # back with the logits (the in-place lanes: they know which
+            # rows are live)
+            self._moe = cfg.mlp == "moe" and self.attn_lane != "gather"
+            self._lane_kw = {"attn": self.attn_lane}
+            if self._moe:
+                self._lane_kw["moe_info"] = True
             # donated caches: the pool mutates in place across iterations;
             # the tables are tiny per-call host->device uploads
             self._prefill = jax.jit(
                 _program(paged_prefill_into_slot, "paged_prefill_chunk", cfg,
-                         attn=self.attn_lane), donate_argnums=(6,))
+                         **self._lane_kw), donate_argnums=(6,))
             self._step = jax.jit(
                 _program(paged_decode_step, "paged_decode_step", cfg,
-                         attn=self.attn_lane), donate_argnums=(5,))
+                         **self._lane_kw), donate_argnums=(5,))
             self._caches = init_paged_caches(
                 cfg, self.slots, self.num_pages, self.page_tokens,
                 self._pages_per_slot, cache_dtype)
@@ -337,6 +344,7 @@ class ContinuousScheduler:
                     "attn lane selection requires kv_layout='paged' "
                     "(the contiguous arena has no page tables)")
             self.attn_lane = None
+            self._moe = False
             env_on = env_flag_explicit("serve_prefix_cache")
             if prefix_cache or (prefix_cache is None and env_on):
                 # explicit intent conflicts loudly. "Explicit" means the
@@ -389,7 +397,7 @@ class ContinuousScheduler:
 
             self._verify = jax.jit(
                 _program(paged_verify_step, "paged_verify_step", cfg,
-                         attn=self.attn_lane), donate_argnums=(5,))
+                         **self._lane_kw), donate_argnums=(5,))
         # ---- cross-replica page migration (ISSUE 18): a dedicated
         # worker thread does the blocking peer pull; the scheduler thread
         # only splices finished results between iterations. _commands
@@ -423,6 +431,14 @@ class ContinuousScheduler:
         self._n_attn_bytes = 0
         self._n_attn_attended = 0
         self._n_attn_fetched = 0
+        # expert layers (mlp='moe'): what the device's counts add up to,
+        # beside the live rows the host handed it
+        self._moe_pending: List[Any] = []
+        self._n_moe_live_rows = 0
+        self._n_moe_layer_calls = 0
+        self._n_moe_rows_routed = 0
+        self._n_moe_experts_hit = 0
+        self._n_moe_max_expert_rows = 0
         self._n_prefix_hit_tokens = 0
         self._admitted_mid_flight = 0
         self._max_active_slots = 0
@@ -780,6 +796,29 @@ class ContinuousScheduler:
         self._n_attn_bytes += moved
         _m_attn_bytes.inc(moved, labels={"lane": self.attn_lane})
 
+    def _moe_note(self, out, live_rows: int):
+        """Split a paged program's result. An expert model's third value
+        (``moe_info``) is kept on the device until ``_moe_count`` reads
+        it behind a wait the loop makes anyway."""
+        if self._moe:
+            self._moe_pending.append((out[2]["counts"], live_rows))
+        return out[0], out[1]
+
+    def _moe_count(self) -> None:
+        """Add up the expert counts of every program that has finished
+        (call after a wait on the newest program's logits: the device
+        runs programs in order, so the copies below wait for nothing)."""
+        import numpy as np
+
+        for counts, live_rows in self._moe_pending:
+            c = np.asarray(counts)  # [layers, experts]
+            self._n_moe_live_rows += live_rows
+            self._n_moe_layer_calls += c.shape[0]
+            self._n_moe_rows_routed += int(c.sum())
+            self._n_moe_experts_hit += int((c > 0).sum())
+            self._n_moe_max_expert_rows += int(c.max(axis=1).sum())
+        self._moe_pending.clear()
+
     def _prefill_one(self) -> bool:
         """Advance ONE prefilling sequence by one chunk, round-robin over
         slots — concurrent prompts interleave their chunks, so one long
@@ -820,11 +859,11 @@ class ContinuousScheduler:
                 # upload may alias (CPU) or still be reading (TPU) the host
                 # buffer, while _offer_prompt_pages and _ensure_pages write
                 # to these rows before anything waits for this chunk
-                logits, self._caches = self._prefill(
+                logits, self._caches = self._moe_note(self._prefill(
                     self.params, tokens, np.int32(real), np.int32(seq.slot),
                     jnp.asarray(self._read_tables[seq.slot].copy()),
                     jnp.asarray(self._write_tables[seq.slot].copy()),
-                    self._caches)
+                    self._caches), real)
                 seq.cursor += real
             else:
                 logits, self._caches = self._prefill(
@@ -848,6 +887,7 @@ class ContinuousScheduler:
                 switch(_P_PREFILL_WAIT)
                 jax.block_until_ready(logits)
                 switch(_P_SAMPLE)
+                self._moe_count()
                 tok = self._sample(seq, logits)
                 seq.state = _DECODE
                 switch(_P_EMIT)
@@ -1210,15 +1250,18 @@ class ContinuousScheduler:
                 drafts[sl].append(d)
         # ---- verify: ONE fixed-shape K-token target call --------------
         vt = np.zeros((self.slots, K), np.int32)
+        used = np.zeros(self.slots, np.int32)  # window rows with a token
         for s in live:
             row = [s.next_token] + drafts[s.slot]
             vt[s.slot, :len(row)] = row
+            used[s.slot] = len(row)
         switch(_P_VERIFY)
-        vlogits, self._caches = self._verify(
-            self.params, jnp.asarray(vt), jnp.asarray(active),
+        vlogits, self._caches = self._moe_note(self._verify(
+            self.params, jnp.asarray(vt), jnp.asarray(used),
             jnp.asarray(self._read_tables),
-            jnp.asarray(self._write_tables), self._caches)
+            jnp.asarray(self._write_tables), self._caches), int(used.sum()))
         va = np.asarray(vlogits)
+        self._moe_count()
         switch(_P_EMIT)  # acceptance, emission and the cursor rewind
         self._record_attn(K, [s.cursor for s in live],
                           self.slots - len(live))
@@ -1299,10 +1342,10 @@ class ContinuousScheduler:
         if not live:
             return False
         if self._paged:
-            logits, self._caches = self._step(
+            logits, self._caches = self._moe_note(self._step(
                 self.params, jnp.asarray(toks), jnp.asarray(active),
                 jnp.asarray(self._read_tables),
-                jnp.asarray(self._write_tables), self._caches)
+                jnp.asarray(self._write_tables), self._caches), len(live))
         else:
             logits, self._caches = self._step(
                 self.params, jnp.asarray(toks), jnp.asarray(active),
@@ -1318,6 +1361,7 @@ class ContinuousScheduler:
         self._jax.block_until_ready(logits)
         switch(_P_FETCH)
         la = np.asarray(logits)
+        self._moe_count()
         # sample every live sequence, then emit: two transitions a step
         # however many slots are live
         switch(_P_SAMPLE)
@@ -1499,6 +1543,16 @@ class ContinuousScheduler:
             out["attn_bytes_moved"] = self._n_attn_bytes
             out["attn_tokens_attended"] = self._n_attn_attended
             out["attn_tokens_fetched"] = self._n_attn_fetched
+            if self._moe:
+                # the device's per-expert row counts, summed a layer-call
+                # (one expert layer in one program run) as of the last
+                # fetch; rows_routed == live_rows x top_k x layers exactly:
+                # no row is dropped
+                out["moe_live_rows"] = self._n_moe_live_rows
+                out["moe_layer_calls"] = self._n_moe_layer_calls
+                out["moe_rows_routed"] = self._n_moe_rows_routed
+                out["moe_experts_hit"] = self._n_moe_experts_hit
+                out["moe_max_expert_rows"] = self._n_moe_max_expert_rows
             out.update(self._arena.stats())
             if self._radix is not None:
                 out.update(self._radix.stats())

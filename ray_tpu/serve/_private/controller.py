@@ -53,6 +53,10 @@ class _DeploymentState:
         self.version = 0
         self.target = spec["num_replicas"]
         self.status = "UPDATING"
+        # set when a replica died in its constructor: the same code would
+        # fail again, so the deployment stops (status DEPLOY_FAILED) until
+        # it is deployed anew, and ``serve.run`` raises this
+        self.error: Optional[str] = None
         self.deleted = False
         # prefix-affinity digests (ISSUE 18): replica key -> the digest
         # its stats last reported; version bumps wake listen_for_digests
@@ -117,6 +121,7 @@ class ServeController:
             if name_state := app.get(spec["name"]):
                 name_state.spec = spec
                 name_state.target = spec["num_replicas"]
+                name_state.error = None  # new code gets a new try
                 name_state.version += 1
                 self._notify_change()
             else:
@@ -193,6 +198,9 @@ class ServeController:
                 if st.deleted:
                     continue
                 await self._health_sweep(st)
+                if st.error is not None:
+                    st.status = "DEPLOY_FAILED"
+                    continue
                 await self._scale_to(st, st.target)
                 ready = sum(1 for h in st.replicas if h.ready)
                 st.status = "RUNNING" if ready == st.target else "UPDATING"
@@ -238,6 +246,15 @@ class ServeController:
                             "consecutive, %s); replacing", st.name,
                             holder.health_failures, type(e).__name__)
                         dead.append(holder)
+                elif isinstance(e, ActorDiedError) \
+                        and "__init__ failed" in e.reason:
+                    # its constructor raised (the core's reason for a
+                    # failed creation task, remote traceback included); a
+                    # starting replica that was killed is replaced below
+                    logger.warning("replica of %s died in its constructor: "
+                                   "%s", st.name, e)
+                    st.error = str(e)
+                    dead.append(holder)
                 elif self._init_expired(holder):
                     logger.warning(
                         "replica of %s never became ready in %.0fs; replacing",
@@ -477,7 +494,8 @@ class ServeController:
         for app_name, app in self._apps.items():
             out[app_name] = {
                 name: {"status": st.status, "replicas": len(st.replicas),
-                       "target": st.target, "version": st.version}
+                       "target": st.target, "version": st.version,
+                       "error": st.error}
                 for name, st in app.items()
             }
         return out

@@ -20,26 +20,22 @@ class TestMoELayer:
     def test_shapes_and_aux(self):
         p = init_moe_params(jax.random.PRNGKey(0), 32, 64, 4)
         x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 32))
-        y, aux = moe_layer(p, x, num_experts=4, dtype=jnp.float32)
+        y, aux, counts, routes = moe_layer(p, x, num_experts=4,
+                                           dtype=jnp.float32)
         assert y.shape == x.shape
         assert jnp.isfinite(y).all()
         # Switch aux loss is ~1 for near-uniform routing, >= 1 in general
         assert 0.5 < float(aux) < 4.0
-
-    def test_capacity_drops_dont_nan(self):
-        p = init_moe_params(jax.random.PRNGKey(0), 16, 32, 2)
-        x = jax.random.normal(jax.random.PRNGKey(1), (1, 32, 16))
-        # capacity_factor so small most tokens overflow
-        y, _ = moe_layer(p, x, num_experts=2, capacity_factor=0.1,
-                         dtype=jnp.float32)
-        assert jnp.isfinite(y).all()
+        # dropless: every row reaches its top-2 experts
+        assert int(counts.sum()) == 2 * 8 * 2
+        assert routes.shape == (2, 8, 2)
 
     def test_gradients_flow_to_all_parts(self):
         p = init_moe_params(jax.random.PRNGKey(0), 16, 32, 4)
         x = jax.random.normal(jax.random.PRNGKey(1), (2, 4, 16))
 
         def loss(p):
-            y, aux = moe_layer(p, x, num_experts=4, dtype=jnp.float32)
+            y, aux, _, _ = moe_layer(p, x, num_experts=4, dtype=jnp.float32)
             return jnp.sum(y**2) + 0.01 * aux
 
         g = jax.grad(loss)(p)
@@ -242,7 +238,7 @@ class TestPipeline:
 
         def stage_fn(stage_params, h):
             def body(carry, layer_params):
-                out, _, _ = _block(cfg, layer_params, carry, rope, None,
+                out, _, _, _ = _block(cfg, layer_params, carry, rope, None,
                                    None)
                 return out, None
             h, _ = jax.lax.scan(body, h, stage_params)

@@ -1,0 +1,462 @@
+"""The OLMoE-shaped block (ISSUE 26: dropless top-k experts over grouped
+matmuls, router weights not renormalized, RMSNorm on the whole projected q
+and k) against the plain reference ``perfbench/reference/olmoe.py``, at a
+toy size on the CPU in float32 on seeded weights.
+
+Logits are compared, never sampled tokens. Top-k is discontinuous, so the
+reference is given the system's own routes (*routed*) and its own choice is
+compared by margin. Tolerance 1e-4 of the largest logit: both sides compute
+in float32 and differ by the order of their sums (grouped matmuls over
+sorted rows against one dense matmul an expert), which reads about 3e-7
+here; a forward pass in bfloat16 reads about 1e-2, a dropped row, weights
+that are renormalized or a missing q/k norm far more (asserted below).
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from perfbench.reference import olmoe as ref
+from ray_tpu.models import (forward, init_params, llama_debug, loss_fn,
+                            moe_debug)
+from ray_tpu.models.decode import (decode_step, init_caches,
+                                   init_paged_caches, paged_decode_step,
+                                   paged_prefill_into_slot,
+                                   paged_verify_step, prefill)
+from ray_tpu.models.transformer import _qkv
+from ray_tpu.ops.moe import init_moe_params, moe_layer
+from ray_tpu.ops.rotary import apply_rotary, rope_frequencies
+
+TOL = 1e-4
+
+
+def hp_of(cfg):
+    """The reference's view of a program config (the source's keys)."""
+    return {"rms_norm_eps": cfg.norm_eps, "rope_theta": cfg.rope_theta,
+            "num_experts": cfg.moe_num_experts,
+            "num_experts_per_tok": cfg.moe_top_k,
+            "norm_topk_prob": cfg.moe_renormalize,
+            "num_hidden_layers": cfg.num_layers}
+
+
+def seeded(cfg, seed=0):
+    """Seeded weights whose norm scales are not all ones (so a norm that
+    is left out shows)."""
+    params = init_params(cfg, jax.random.PRNGKey(seed))
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
+
+    def jitter(path, a):
+        name = jax.tree_util.keystr(path)
+        if "norm" in name or "ln" in name:
+            return a + 0.2 * jax.random.normal(next(keys), a.shape, a.dtype)
+        return a
+    return jax.tree_util.tree_map_with_path(jitter, params)
+
+
+def rel_err(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+def sys_forward(cfg, params, tokens):
+    with jax.default_matmul_precision("highest"):
+        return forward(cfg, params, tokens, return_routes=True)
+
+
+@pytest.fixture(scope="module")
+def toy():
+    cfg = moe_debug(max_seq_len=256)
+    params = seeded(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(9), (2, 48), 0,
+                                cfg.vocab_size)
+    return cfg, params, tokens
+
+
+# ------------------------------------------------------- the full forward
+
+
+@pytest.mark.parametrize("scan_layers", [True, False],
+                         ids=["stacked", "layers_apart"])
+def test_forward_logits_match_the_reference(scan_layers):
+    cfg = moe_debug(scan_layers=scan_layers)
+    params = seeded(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(9), (2, 40), 0,
+                                cfg.vocab_size)
+    logits, routes = sys_forward(cfg, params, tokens)
+    assert routes.shape == (cfg.num_layers, 2, 40, cfg.moe_top_k)
+    want, probs = ref.forward_and_router(params, tokens, hp_of(cfg), routes)
+    assert rel_err(logits, want) < TOL
+    # in float32 the system takes the experts the reference would
+    flips, margin = ref.routing_margin(probs, routes)
+    assert margin < 1e-6 and flips < 0.01
+    assert rel_err(logits, ref.forward(params, tokens, hp_of(cfg))) < TOL
+
+
+@pytest.mark.parametrize("fused_ce", [False, True], ids=["plain", "fused"])
+def test_loss_and_gradients_match_the_reference(toy, fused_ce):
+    """Same tolerance, on the loss and on every leaf's gradient (as a share
+    of the leaf's largest entry), with the Switch auxiliary term in both."""
+    cfg, params, tokens = toy
+    cfg = dataclasses.replace(cfg, fused_ce=fused_ce)
+    with jax.default_matmul_precision("highest"):
+        (loss, metrics), grads = jax.value_and_grad(
+            lambda p: loss_fn(cfg, p, {"tokens": tokens}),
+            has_aux=True)(params)
+    want, want_grads = jax.value_and_grad(
+        lambda p: ref.loss(p, tokens, hp_of(cfg), cfg.moe_aux_weight))(params)
+    assert abs(float(loss) - float(want)) < TOL * abs(float(want))
+    assert float(metrics["moe_aux"]) > 0
+    errs = jax.tree.map(rel_err, grads, want_grads)
+    assert max(jax.tree.leaves(errs)) < TOL, errs
+
+
+VARIANTS = {
+    # what the routed tolerance has to refuse
+    "bfloat16_for_float32": lambda cfg: dataclasses.replace(
+        cfg, dtype=jnp.bfloat16),
+    "renormalized_weights": lambda cfg: dataclasses.replace(
+        cfg, moe_renormalize=True),
+}
+
+
+@pytest.mark.parametrize("variant", sorted(VARIANTS))
+def test_the_routed_tolerance_refuses(toy, variant):
+    cfg, params, tokens = toy
+    logits, routes = sys_forward(VARIANTS[variant](cfg), params, tokens)
+    want = ref.forward(params, tokens, hp_of(cfg), routes)
+    assert rel_err(logits, want) > 10 * TOL
+
+
+def test_the_routed_tolerance_refuses_a_missing_qk_norm(toy):
+    cfg, params, tokens = toy
+    bare = dataclasses.replace(cfg, qk_norm=False)
+    logits, routes = sys_forward(bare, params, tokens)
+    want = ref.forward(params, tokens, hp_of(cfg), routes)
+    assert rel_err(logits, want) > 10 * TOL
+
+
+def test_the_routed_tolerance_refuses_a_dropped_row(toy):
+    """One (token, expert) pair of one layer left out of the reference's
+    sum: the system, which drops nothing, is then far from it."""
+    cfg, params, tokens = toy
+    logits, routes = sys_forward(cfg, params, tokens)
+    dropped = routes.at[0, 0, 5, 0].set(routes[0, 0, 5, 1])  # a pair twice
+    want = ref.forward(params, tokens, hp_of(cfg), dropped)
+    assert rel_err(logits, want) > 10 * TOL
+
+
+# ------------------------------------------------ decoding through caches
+
+
+def test_prefill_and_decode_step_match_the_full_forward(toy):
+    cfg, params, tokens = toy
+    n = 30
+    want = ref.forward(params, tokens, hp_of(cfg))
+    with jax.default_matmul_precision("highest"):
+        caches = init_caches(cfg, tokens.shape[0], tokens.shape[1])
+        logits, caches = prefill(cfg, params, tokens[:, :n], caches)
+        got = [logits]
+        for t in range(n, tokens.shape[1] - 1):
+            logits, caches = decode_step(cfg, params, tokens[:, t:t + 1],
+                                         caches)
+            got.append(logits)
+    assert rel_err(jnp.stack(got, 1), want[:, n - 1:-1]) < TOL
+
+
+def paged_setup(cfg, slots, T=8, P=16):
+    caches = init_paged_caches(cfg, slots, slots * P + 1, T, P)
+    tables = (1 + np.arange(slots * P, dtype=np.int32)).reshape(slots, P)
+    return caches, jnp.asarray(tables)
+
+
+@pytest.fixture(scope="module")
+def paged_run(toy):
+    """Two prompts through the paged programs in the in-place reference
+    lane: prefill chunks of 16 into slots 1 and 2 of 4 (slots 0 and 3 hold
+    no sequence), then decode steps, collecting logits, routes and counts."""
+    cfg, params, tokens = toy
+    C, slots, n = 16, 4, [21, 32]
+    caches, tables = paged_setup(cfg, slots)
+    got = {s: [] for s in (1, 2)}
+    taken = {s: [] for s in (1, 2)}
+    counted = live = 0
+    with jax.default_matmul_precision("highest"):
+        for b, s in enumerate((1, 2)):
+            prompt = np.asarray(tokens[b, :n[b]])
+            for c0 in range(0, n[b], C):
+                chunk = prompt[c0:c0 + C]
+                real = len(chunk)
+                padded = np.zeros((1, C), np.int32)
+                padded[0, :real] = chunk
+                logits, caches, moe = paged_prefill_into_slot(
+                    cfg, params, jnp.asarray(padded), real, s, tables[s],
+                    tables[s], caches, attn="reference", moe_info=True)
+                taken[s].append(np.asarray(moe["routes"])[:, 0, :real])
+                counted += int(moe["counts"].sum())
+                live += real
+            got[s].append(logits)
+        active = jnp.asarray([0, 1, 1, 0], jnp.int32)
+        for step in range(6):
+            toks = np.zeros(slots, np.int32)
+            for b, s in enumerate((1, 2)):
+                toks[s] = tokens[b, n[b] + step]
+            logits, caches, moe = paged_decode_step(
+                cfg, params, jnp.asarray(toks), active, tables, tables,
+                caches, attn="reference", moe_info=True)
+            counted += int(moe["counts"].sum())
+            live += 2
+            for s in (1, 2):
+                got[s].append(logits[s])
+                taken[s].append(np.asarray(moe["routes"])[:, s])
+    return {"got": got, "taken": taken, "counted": counted, "live": live,
+            "n": n, "caches": caches, "tables": tables}
+
+
+@pytest.mark.parametrize("b,slot", [(0, 1), (1, 2)])
+def test_paged_chunks_and_decode_match_the_full_forward(toy, paged_run, b,
+                                                        slot):
+    cfg, params, tokens = toy
+    n = paged_run["n"][b]
+    seq = tokens[b:b + 1, :n + 6]
+    routes = jnp.asarray(np.concatenate(paged_run["taken"][slot], 1))[:, None]
+    want = ref.forward(params, seq, hp_of(cfg), routes)[0]
+    got = jnp.stack(paged_run["got"][slot])
+    assert rel_err(got, want[n - 1:n + 6]) < TOL
+    assert rel_err(got, ref.forward(params, seq, hp_of(cfg))[0][n - 1:]) < TOL
+
+
+def test_paged_programs_count_live_rows_only(toy, paged_run):
+    """Rows of slots without a sequence and a chunk's padding reach no
+    expert: the counts are live rows x k x layers, exactly."""
+    cfg = toy[0]
+    assert paged_run["counted"] == (paged_run["live"] * cfg.moe_top_k
+                                    * cfg.num_layers)
+
+
+def test_paged_verify_step_matches_the_full_forward(toy, paged_run):
+    """A K = 4 window for slot 1 (3 of its rows used) and slot 2 (all 4),
+    called directly: logits against the reference's full forward, unused
+    rows and rows of slots without a sequence not counted."""
+    cfg, params, tokens = toy
+    K, used = 4, np.asarray([0, 3, 4, 0], np.int32)
+    window = np.zeros((4, K), np.int32)
+    starts = {1: paged_run["n"][0] + 6, 2: paged_run["n"][1] + 6}
+    for b, s in enumerate((1, 2)):
+        window[s, :used[s]] = tokens[b, starts[s]:starts[s] + used[s]]
+    with jax.default_matmul_precision("highest"):
+        logits, _, moe = paged_verify_step(
+            cfg, params, jnp.asarray(window), jnp.asarray(used),
+            paged_run["tables"], paged_run["tables"], paged_run["caches"],
+            attn="reference", moe_info=True)
+    assert int(moe["counts"].sum()) == 7 * cfg.moe_top_k * cfg.num_layers
+    for b, s in enumerate((1, 2)):
+        seq = tokens[b:b + 1, :starts[s] + used[s]]
+        want = ref.forward(params, seq, hp_of(cfg))[0][starts[s]:]
+        assert rel_err(logits[s, :used[s]], want) < TOL
+
+
+def test_gather_lane_refuses_moe_info(toy):
+    cfg, params, _ = toy
+    caches, tables = paged_setup(cfg, 2)
+    with pytest.raises(ValueError, match="in-place"):
+        paged_decode_step(cfg, params, jnp.zeros(2, jnp.int32),
+                          jnp.ones(2, jnp.int32), tables, tables, caches,
+                          attn="gather", moe_info=True)
+
+
+def test_scheduler_serves_the_expert_model_and_drops_no_row(toy):
+    """Through ``ContinuousScheduler`` (prefill chunks + paged decode, some
+    slots idle, padded chunks): every served token is the reference's
+    choice or within TOL of it, and the device's expert counts add up to
+    live rows x k x layers."""
+    import asyncio
+
+    from ray_tpu.serve._private.continuous import ContinuousScheduler
+
+    cfg, params, tokens = toy
+    sched = ContinuousScheduler(cfg, params, slots=4, prefill_chunk=16,
+                                arena_len=128, page_tokens=8, kv_pages=65,
+                                attn="reference")
+    prompts = [np.asarray(tokens[0, :21]).tolist(),
+               np.asarray(tokens[1, :37]).tolist(),
+               np.asarray(tokens[0, 5:14]).tolist()]
+
+    async def one(prompt):
+        queue = asyncio.Queue()
+        sched.submit(prompt, max_new_tokens=5, temperature=0.0,
+                     loop=asyncio.get_running_loop(), queue=queue)
+        out = []
+        while True:
+            kind, value, _ = await queue.get()
+            if kind == "tok":
+                out.append(value)
+            elif kind == "end":
+                return out
+            else:
+                raise RuntimeError(f"{kind}: {value}")
+
+    async def drive():
+        return await asyncio.gather(*(one(p) for p in prompts))
+
+    try:
+        with jax.default_matmul_precision("highest"):
+            served = asyncio.run(drive())
+        stats = sched.stats()
+    finally:
+        sched.shutdown()
+    for prompt, out in zip(prompts, served):
+        assert len(out) == 5
+        seq = jnp.asarray([prompt + out[:-1]], jnp.int32)
+        want = np.asarray(ref.forward(params, seq, hp_of(cfg))[0])
+        want = want[len(prompt) - 1:]
+        for logits, tok in zip(want, out):
+            assert logits.max() - logits[tok] <= TOL * np.abs(want).max()
+    live = sum(len(p) for p in prompts) + 4 * len(prompts)
+    assert stats["moe_live_rows"] == live
+    assert stats["moe_rows_routed"] == live * cfg.moe_top_k * cfg.num_layers
+    assert stats["moe_layer_calls"] == cfg.num_layers * (
+        stats["decode_steps"] + stats["prefill_chunks"])
+    assert 0 < stats["moe_experts_hit"] <= (stats["moe_layer_calls"]
+                                            * cfg.moe_num_experts)
+    assert stats["moe_max_expert_rows"] >= (stats["moe_rows_routed"]
+                                            / cfg.moe_num_experts)
+
+
+# ------------------------------------------------------ the expert layer
+
+
+def layer_reference(p, x, top_k, renormalize, routes=None):
+    """One expert layer by the reference's own functions (float32)."""
+    with jax.default_matmul_precision("highest"):
+        h = x.astype(jnp.float32)
+        probs = jax.nn.softmax(h @ p["w_router"], axis=-1)
+        w = ref.token_weights(probs, routes, top_k, renormalize)
+        y = jnp.zeros_like(h)
+        for e in range(p["w_router"].shape[1]):
+            y = ref.add_expert(y, h, w, p, (e,))
+    return y
+
+
+def run_layer(p, x, **kw):
+    with jax.default_matmul_precision("highest"):
+        return moe_layer(p, x, num_experts=p["w_router"].shape[1],
+                         dtype=jnp.float32, **kw)
+
+
+@pytest.mark.parametrize("renormalize", [False, True],
+                         ids=["olmoe", "renormalized"])
+def test_layer_matches_the_reference(renormalize):
+    p = init_moe_params(jax.random.PRNGKey(0), 32, 48, 8)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, 32))
+    y, _, counts, routes = run_layer(p, x, top_k=3, renormalize=renormalize)
+    assert rel_err(y, layer_reference(p, x, 3, renormalize, routes)) < 1e-5
+    assert int(counts.sum()) == 2 * 24 * 3
+
+
+@pytest.mark.parametrize("case", ["all_rows_to_the_same_experts",
+                                  "an_expert_with_no_row"])
+def test_forced_imbalance_loses_no_row(case):
+    """No capacity: experts that take every row, and experts that take
+    none, both give the reference's output."""
+    E, k, n = 8, 2, 40
+    p = init_moe_params(jax.random.PRNGKey(0), 16, 24, E)
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (1, n, 16))) + 0.1
+    if case == "all_rows_to_the_same_experts":
+        # positive inputs: a large positive column wins every row
+        router = p["w_router"].at[:, 5].set(3.0).at[:, 2].set(2.0)
+        want_counts = [0, 0, n, 0, 0, n, 0, 0]
+    else:
+        router = p["w_router"].at[:, 4].set(-5.0)
+        want_counts = None
+    p = {**p, "w_router": router}
+    y, _, counts, routes = run_layer(p, x, top_k=k, renormalize=False)
+    assert int(counts.sum()) == n * k
+    if want_counts is not None:
+        assert counts.tolist() == want_counts
+    else:
+        assert int(counts[4]) == 0 and int((counts > 0).sum()) > 2
+    assert rel_err(y, layer_reference(p, x, k, False, routes)) < 1e-5
+
+
+def test_rows_masked_by_valid_change_no_output_and_no_count():
+    p = init_moe_params(jax.random.PRNGKey(0), 32, 48, 8)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 32))
+    valid = jnp.arange(16)[None] < jnp.asarray([[16], [9]])
+    y, aux, counts, _ = run_layer(p, x, top_k=3, renormalize=False,
+                                  valid=valid)
+    # the live rows alone, as a batch of their own
+    y0, _, c0, _ = run_layer(p, x[:1], top_k=3, renormalize=False)
+    y1, _, c1, _ = run_layer(p, x[1:, :9], top_k=3, renormalize=False)
+    assert counts.tolist() == (c0 + c1).tolist()
+    assert int(counts.sum()) == 25 * 3
+    np.testing.assert_allclose(y[0], y0[0], rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(y[1, :9], y1[0], rtol=1e-6, atol=1e-7)
+    assert not np.asarray(y[1, 9:]).any()
+    # garbage in the masked rows (a retired slot's stale state) is inert
+    noisy = x.at[1, 9:].set(jnp.nan)
+    y2, aux2, counts2, _ = run_layer(p, noisy, top_k=3, renormalize=False,
+                                     valid=valid)
+    assert counts2.tolist() == counts.tolist()
+    np.testing.assert_array_equal(np.asarray(y2[0]), np.asarray(y[0]))
+    np.testing.assert_array_equal(np.asarray(y2[1, :9]), np.asarray(y[1, :9]))
+
+
+# --------------------------------------- what the dense models keep
+
+
+@pytest.mark.parametrize("preset,overrides", [
+    ("llama_debug", {}),
+    ("gpt2_small", dict(num_layers=1, embed_dim=64, num_heads=4,
+                        vocab_size=256, max_seq_len=64))],
+    ids=["llama_shaped", "gpt2_shaped"])
+def test_one_projection_reproduces_the_dense_block_bit_for_bit(preset,
+                                                               overrides):
+    """With qk_norm off the shared projection is the three einsums and the
+    rotation every forward wrote out before, to the bit."""
+    from ray_tpu.models import presets
+
+    cfg = getattr(presets, preset)(**overrides)
+    assert not cfg.qk_norm and cfg.moe_renormalize
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    p = jax.tree.map(lambda a: a[0], params["blocks"])["attn"]
+    assert "q_norm" not in p
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 12, cfg.embed_dim),
+                          cfg.dtype)
+    rope = (rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
+            if cfg.pos == "rope" else None)
+    got = _qkv(cfg, p, x, rope, None)
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(cfg.dtype))
+    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(cfg.dtype))
+    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(cfg.dtype))
+    if rope is not None:
+        q = apply_rotary(q, *rope, None)
+        k = apply_rotary(k, *rope, None)
+    for a, b in zip(got, (q, k, v)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_llama_forward_matches_the_mistral_reference():
+    """The dense Llama-shaped path through the shared projection, against
+    the benchmark's dense reference."""
+    from perfbench.reference import mistral
+
+    cfg = llama_debug()
+    params = seeded(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 24), 0,
+                                cfg.vocab_size)
+    with jax.default_matmul_precision("highest"):
+        logits = forward(cfg, params, tokens)
+    want = mistral.forward(params, tokens, {
+        "rms_norm_eps": cfg.norm_eps, "rope_theta": cfg.rope_theta,
+        "num_hidden_layers": cfg.num_layers})
+    assert rel_err(logits, want) < TOL
+
+
+def test_tensor_parallel_refuses_qk_norm():
+    from ray_tpu.models.transformer import tp_block_shard_spec
+
+    with pytest.raises(ValueError, match="qk_norm"):
+        tp_block_shard_spec(llama_debug(qk_norm=True))

@@ -51,6 +51,34 @@ class TestDeployments:
                       route_prefix="/d3")
         assert h.remote(5).result(timeout=10) == 115
 
+    def test_a_constructor_that_raises_fails_the_run_at_once(
+            self, serve_shutdown):
+        """A replica whose constructor raises would raise again: the
+        deployment stops and ``serve.run`` raises the cause within
+        seconds, not ``TimeoutError`` after ``timeout_s``."""
+        @serve.deployment
+        class Broken:
+            def __init__(self):
+                raise ValueError("no such preset field: 'qk_norm'")
+
+            def __call__(self, x):
+                return x
+
+        t0 = time.monotonic()
+        with pytest.raises(RuntimeError) as ei:
+            serve.run(Broken.bind(), name="d_broken", route_prefix="/dbr",
+                      timeout_s=300)
+        assert time.monotonic() - t0 < 60
+        assert "died in its constructor" in str(ei.value)
+        assert "no such preset field: 'qk_norm'" in str(ei.value)
+        status = serve.status()["d_broken"]["Broken"]
+        assert status["status"] == "DEPLOY_FAILED" and status["error"]
+        assert status["replicas"] == 0
+        serve.delete("d_broken")
+        # the controller still deploys what works
+        h = serve.run(Doubler.bind(), name="d_after", route_prefix="/daf")
+        assert h.remote(4).result(timeout=10) == 8
+
     def test_method_call(self, serve_shutdown):
         @serve.deployment
         class Multi:

@@ -306,3 +306,61 @@ def test_gpt2s_serve_programs_compile_and_fit(v5e):
                            donate_argnums=(donated,)).lower(*args).compile()
         assert "tpu_custom_call" in compiled.as_text(), name
         _fits(compiled)
+
+
+def test_olmoe_serve_programs_compile_and_fit(v5e):
+    """The benchmark's OLMoE-1B-7B configuration (published widths, 8
+    layers, bf16) under its cell's deployment: the prefill chunk and the
+    decode step with the expert layer's grouped matmuls
+    (``jax.lax.ragged_dot``) and the paged kernel at its second shape (page
+    rows of 16 kv heads x 128, group size 1), weights and the 6.4 GB pool
+    beside the programs' own memory on one 16 GB chip."""
+    from perfbench.lib import configs
+    from perfbench.lib import manifest as manifest_lib
+    from ray_tpu.models.decode import (init_paged_caches, paged_decode_step,
+                                       paged_prefill_into_slot)
+    from ray_tpu.models.transformer import init_params
+    from ray_tpu.ops.attention import resolve_paged_attn_lane
+
+    manifest = manifest_lib.load()
+    hp = manifest_lib.config(manifest, "olmoe_1b_7b_l8")
+    cfg = configs.build_program_config(*configs.program_overrides(
+        hp, manifest_lib.read_json_from_bench("families", hp["model_type"])))
+    dep = manifest_lib.read_json(manifest, "cells",
+                                 "olmoe_reason")["deployment"]
+    slots, chunk, T = dep["slots"], dep["prefill_chunk"], dep["page_tokens"]
+    pages = dep["arena_len"] // T
+    lane = resolve_paged_attn_lane("auto", cfg)
+    assert lane == "pallas"
+    chip = SingleDeviceSharding(v5e.devices[0])
+
+    def place(tree):
+        return jax.tree.map(lambda a: _on(chip, a.shape, a.dtype), tree)
+
+    params = place(jax.eval_shape(
+        functools.partial(init_params, cfg), jax.random.PRNGKey(0)))
+    caches = place(jax.eval_shape(functools.partial(
+        init_paged_caches, cfg, slots, dep["kv_pages"], T, pages)))
+    held = sum(a.size * a.dtype.itemsize
+               for a in jax.tree.leaves((params, caches)))
+    assert 13.4e9 < held < 13.7e9  # 7.13 GB of weights + 6.4 GB of pool
+    table = _on(chip, (slots, pages), jnp.int32)
+    row = _on(chip, (pages,), jnp.int32)
+    ids = functools.partial(_on, chip, dtype=jnp.int32)
+    programs = {
+        "prefill": (paged_prefill_into_slot,
+                    (params, ids((1, chunk)), ids(()), ids(()), row, row,
+                     caches), 6),
+        "decode": (paged_decode_step,
+                   (params, ids((slots,)), ids((slots,)), table, table,
+                    caches), 5),
+    }
+    for name, (program, args, donated) in programs.items():
+        compiled = jax.jit(
+            functools.partial(program, cfg, attn=lane, moe_info=True),
+            donate_argnums=(donated,)).lower(*args).compile()
+        assert "tpu_custom_call" in compiled.as_text(), name
+        _fits(compiled)
+        # no layer's experts (805 MB) are copied off the stacked weights
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 600e6, f"{name}: {temp / 1e6:.0f} MB of temporaries"
