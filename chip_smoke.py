@@ -293,12 +293,12 @@ def olmoe_task(seed: int) -> dict:
     # ---- the 8-layer model through the paged programs: two prompts (one
     # of two chunks) prefilled into slots 0 and 5 of 8, then 6 decode steps
     S, C, T, P = 8, 512, 16, 64
-    caches = init_paged_caches(cfg, S, S * P + 1, T, P)
+    caches = init_paged_caches(cfg, S * P + 1, T, P)
     tables = (1 + np.arange(S * P, dtype=np.int32)).reshape(S, P)
     prefill = jax.jit(lambda *a: paged_prefill_into_slot(
         cfg, *a, attn="pallas", moe_info=True), donate_argnums=(6,))
     step = jax.jit(lambda *a: paged_decode_step(
-        cfg, *a, attn="pallas", moe_info=True), donate_argnums=(5,))
+        cfg, *a, attn="pallas", moe_info=True), donate_argnums=(6,))
     rng = np.random.default_rng(seed)
     prompts = {0: rng.integers(1, cfg.vocab_size, 320).tolist(),
                5: rng.integers(1, cfg.vocab_size, 700).tolist()}
@@ -311,7 +311,7 @@ def olmoe_task(seed: int) -> dict:
             real = len(chunk)
             logits, caches, moe = prefill(
                 params, jnp.asarray([chunk + [0] * (C - real)], jnp.int32),
-                np.int32(real), np.int32(s), jnp.asarray(tables[s]),
+                np.int32(real), np.int32(c0), jnp.asarray(tables[s]),
                 jnp.asarray(tables[s]), caches)
             taken[s].append(np.asarray(moe["routes"])[:, 0, :real])
             rows_routed += int(np.asarray(moe["counts"]).sum())
@@ -319,6 +319,9 @@ def olmoe_task(seed: int) -> dict:
         got[s].append(np.asarray(logits, np.float32))
     active = np.zeros(S, np.int32)
     active[list(prompts)] = 1
+    cursors = np.zeros(S, np.int32)  # the caller's: the pool keeps none
+    for s, prompt in prompts.items():
+        cursors[s] = len(prompt)
     fed = {s: [] for s in prompts}
     for _ in range(6):
         toks = np.zeros(S, np.int32)
@@ -326,8 +329,10 @@ def olmoe_task(seed: int) -> dict:
             fed[s].append(int(got[s][-1].argmax()))
             toks[s] = fed[s][-1]
         logits, caches, moe = step(params, jnp.asarray(toks),
-                                   jnp.asarray(active), jnp.asarray(tables),
-                                   jnp.asarray(tables), caches)
+                                   jnp.asarray(active), cursors,
+                                   jnp.asarray(tables), jnp.asarray(tables),
+                                   caches)
+        cursors = cursors + active
         rows_routed += int(np.asarray(moe["counts"]).sum())
         live_rows += len(prompts)
         for s in prompts:

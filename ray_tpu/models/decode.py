@@ -15,7 +15,6 @@ from typing import Any, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
 from ray_tpu.models.transformer import (TransformerConfig, _mlp, _norm,
@@ -236,25 +235,24 @@ def slot_decode_step(cfg: TransformerConfig, params, tokens, active, caches):
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class PagedKVCache:
-    """One layer's page pool. k/v: [num_pages, page_tokens, Hkv * D];
-    lengths: [slots] int32 — per-slot write cursors in LOGICAL tokens."""
+    """One layer's page pool. k/v: [num_pages, page_tokens, Hkv * D]. The
+    pool holds pages and nothing else: which slot is where in its sequence
+    (the cursor, in LOGICAL tokens) is the caller's state, handed to every
+    program as an argument like the page tables."""
 
     k: Any
     v: Any
-    lengths: Any
 
     @classmethod
-    def zeros(cls, slots: int, num_pages: int, page_tokens: int,
-              kv_heads: int, head_dim: int,
-              dtype=jnp.bfloat16) -> "PagedKVCache":
+    def zeros(cls, num_pages: int, page_tokens: int, kv_heads: int,
+              head_dim: int, dtype=jnp.bfloat16) -> "PagedKVCache":
         return cls(
             k=jnp.zeros((num_pages, page_tokens, kv_heads * head_dim), dtype),
             v=jnp.zeros((num_pages, page_tokens, kv_heads * head_dim), dtype),
-            lengths=jnp.zeros((slots,), jnp.int32),
         )
 
 
-def init_paged_caches(cfg: TransformerConfig, slots: int, num_pages: int,
+def init_paged_caches(cfg: TransformerConfig, num_pages: int,
                       page_tokens: int, pages_per_slot: int,
                       dtype=None) -> List[PagedKVCache]:
     if page_tokens < 1:
@@ -271,20 +269,9 @@ def init_paged_caches(cfg: TransformerConfig, slots: int, num_pages: int,
             f"pages_per_slot * page_tokens ({pages_per_slot * page_tokens}) "
             f"exceeds cfg.max_seq_len ({cfg.max_seq_len})")
     dtype = dtype or cfg.dtype
-    return [PagedKVCache.zeros(slots, num_pages, page_tokens, cfg.kv_heads,
+    return [PagedKVCache.zeros(num_pages, page_tokens, cfg.kv_heads,
                                cfg.head_dim, dtype)
             for _ in range(cfg.num_layers)]
-
-
-def paged_reset_slot(caches: List[PagedKVCache], slot: int,
-                     length: int = 0) -> List[PagedKVCache]:
-    """Point a slot's cursor at ``length`` (0 for a cold admit; the cached
-    prefix length for a prefix-cache hit, whose pages the read table
-    splices in). No scrub, same contiguous-write/update-before-attend
-    invariant as ``reset_slot``."""
-    return [dataclasses.replace(
-        c, lengths=c.lengths.at[slot].set(jnp.int32(length)))
-        for c in caches]
 
 
 def _gather_row(cfg: TransformerConfig, c: PagedKVCache, table):
@@ -318,7 +305,7 @@ def _layer_params(cfg: TransformerConfig, params, i: int):
 
 def _paged_forward_inplace(cfg: TransformerConfig, params, tokens, positions,
                            lengths, read_tables, write_tables, caches, impl,
-                           advance, valid):
+                           valid):
     """The in-place twin of the gathered-view programs: one K-token-window
     forward over all S slots where each layer (1) writes the window's k/v
     DIRECTLY into its pages — ``pool.at[page, offset].set`` through the
@@ -328,10 +315,7 @@ def _paged_forward_inplace(cfg: TransformerConfig, params, tokens, positions,
     scatter-back). Layer math mirrors ``transformer._block`` exactly.
 
     tokens/positions: [S, K]; lengths: [S] attention cursors;
-    read_tables/write_tables: [S, P]. ``advance(lengths)`` maps one
-    layer's cursor buffer to its updated value (each layer must return
-    its OWN buffer — the callers donate caches, and a shared buffer would
-    be donated once per layer). Positions on unallocated/shared pages
+    read_tables/write_tables: [S, P]. Positions on unallocated/shared pages
     redirect to the garbage page through the write table, same contract
     as the scatter-back lane. ``valid``: bool [S, K], the rows that carry a
     live token (not a slot without a sequence, not a chunk's padding): the
@@ -369,8 +353,7 @@ def _paged_forward_inplace(cfg: TransformerConfig, params, tokens, positions,
         m, _, moe = _mlp(cfg, mlp_p, _norm(cfg, p["ln2"], x), valid, layer)
         x = x + m
         moe_layers.append(moe)
-        new_caches.append(PagedKVCache(k=ck, v=cv,
-                                       lengths=advance(c.lengths)))
+        new_caches.append(PagedKVCache(k=ck, v=cv))
     moe = (jax.tree.map(lambda *a: jnp.stack(a), *moe_layers)
            if cfg.mlp == "moe" else None)
     x = _norm(cfg, params["final_norm"], x)
@@ -398,13 +381,16 @@ def _check_moe_info(cfg: TransformerConfig, attn: str, moe_info: bool):
 
 
 def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
-                            slot, read_row, write_row,
+                            cursor, read_row, write_row,
                             caches: List[PagedKVCache], *,
                             attn: str = "gather", moe_info: bool = False):
-    """``prefill_into_slot`` through a page table. read_row/write_row: [P]
-    int32 — shared (prefix-cache) pages appear in read_row but are
-    redirected to the garbage page in write_row, so their content is
-    immutable here.
+    """``prefill_into_slot`` through a page table: the chunk lands at
+    logical positions [cursor, cursor + C) of the slot whose two rows these
+    are. cursor: int32 scalar, the tokens already resident (0 cold, the
+    spliced length after a prefix-cache hit); the caller advances it by
+    ``real_len``. read_row/write_row: [P] int32 — shared (prefix-cache)
+    pages appear in read_row but are redirected to the garbage page in
+    write_row, so their content is immutable here.
 
     attn="gather" (the measured baseline): gather the slot's logical view
     from the pool, run the identical chunk forward, scatter the view back
@@ -424,12 +410,11 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
     _check_attn_lane(attn)
     _check_moe_info(cfg, attn, moe_info)
     if attn != "gather":
-        lengths = lax.dynamic_slice(caches[0].lengths, (slot,), (1,))
-        steps = jnp.arange(tokens.shape[1])[None, :]
+        steps = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
         logits, new_caches, moe = _paged_forward_inplace(
-            cfg, params, tokens, steps + lengths[:, None], lengths,
+            cfg, params, tokens, steps + cursor, jnp.reshape(cursor, (1,)),
             read_row[None], write_row[None], caches, attn,
-            lambda l: l.at[slot].add(real_len), steps < real_len)
+            steps < real_len)
         last = lax.dynamic_index_in_dim(logits[0], real_len - 1,
                                         keepdims=False)
         return _paged_outputs(last, new_caches, moe, moe_info)
@@ -438,9 +423,8 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
     rows = []
     for c in caches:
         k, v = _gather_row(cfg, c, read_row)
-        rows.append(LayerKVCache(
-            k=k, v=v, length=lax.dynamic_slice(c.lengths, (slot,), (1,))[0]))
-    positions = jnp.arange(tokens.shape[1])[None, :] + rows[0].length
+        rows.append(LayerKVCache(k=k, v=v, length=cursor))
+    positions = jnp.arange(tokens.shape[1])[None, :] + cursor
     logits, new_rows = forward(cfg, params, tokens, positions=positions,
                                kv_caches=rows)
     last = lax.dynamic_index_in_dim(logits[0], real_len - 1, keepdims=False)
@@ -453,25 +437,27 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
     # unallocated entries redirect to the garbage page.
     C = tokens.shape[1]
     W = min(P, (C + T - 1) // T + 1)
-    w0 = rows[0].length // T
-    widx = jnp.clip(w0 + jnp.arange(W), 0, P - 1)
+    widx = jnp.clip(cursor // T + jnp.arange(W), 0, P - 1)
     dest = write_row[widx]
-    new_caches = []
-    for c, r in zip(caches, new_rows):
-        new_caches.append(PagedKVCache(
-            k=c.k.at[dest].set(r.k.reshape(P, T, HD)[widx]),
-            v=c.v.at[dest].set(r.v.reshape(P, T, HD)[widx]),
-            lengths=c.lengths.at[slot].add(real_len)))
+    new_caches = [
+        PagedKVCache(k=c.k.at[dest].set(r.k.reshape(P, T, HD)[widx]),
+                     v=c.v.at[dest].set(r.v.reshape(P, T, HD)[widx]))
+        for c, r in zip(caches, new_rows)]
     return last, new_caches
 
 
 def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
-                      read_tables, write_tables,
+                      cursors, read_tables, write_tables,
                       caches: List[PagedKVCache], *, attn: str = "gather",
                       moe_info: bool = False):
     """``slot_decode_step`` through page tables: one fixed-shape program
-    over the whole arena. tokens/active: [slots] int32; read_tables/
-    write_tables: [slots, P] int32.
+    over the whole arena. tokens/active/cursors: [slots] int32; read_tables/
+    write_tables: [slots, P] int32. Row s's token is written at logical
+    position cursors[s] and attends [0, cursors[s]]; the caller advances
+    the cursors of its active rows by one. An inactive row attends nothing
+    and its logits are dropped, but it WRITES at its cursor like any other:
+    the caller's tables send that write to the garbage page, or to a
+    position the row's own sequence writes again before attending it.
 
     attn="gather" (the measured baseline): the per-slot math is the
     contiguous path's vmapped single-sequence forward over the GATHERED
@@ -489,15 +475,13 @@ def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
     _check_attn_lane(attn)
     _check_moe_info(cfg, attn, moe_info)
     if attn != "gather":
-        lengths = caches[0].lengths
         # a slot the step marks inactive attends nothing (its logits are
-        # dropped): a retired slot's stale cursor streams no page, and the
-        # expert layer routes its row nowhere
+        # dropped): it streams no page, and the expert layer routes its
+        # row nowhere
         logits, new_caches, moe = _paged_forward_inplace(
-            cfg, params, tokens[:, None], lengths[:, None],
-            jnp.where(active > 0, lengths, -1),
-            read_tables, write_tables, caches, attn,
-            lambda l: l + active, active[:, None] > 0)
+            cfg, params, tokens[:, None], cursors[:, None],
+            jnp.where(active > 0, cursors, -1),
+            read_tables, write_tables, caches, attn, active[:, None] > 0)
         return _paged_outputs(logits[:, 0], new_caches, moe, moe_info)
     T, HD = caches[0].k.shape[1:]
     slots, P = read_tables.shape
@@ -523,27 +507,23 @@ def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
             for r in new_rows]
         return logits[0, -1], dest, (outs_k, outs_v)
 
-    lengths = caches[0].lengths
     logits, dest, (new_k, new_v) = jax.vmap(one, in_axes=(0, 0, 0, 0))(
-        tokens, lengths, read_tables, write_tables)
-    new_caches = []
-    for c, nk, nv in zip(caches, new_k, new_v):
-        new_caches.append(PagedKVCache(
-            k=c.k.at[dest].set(nk),
-            v=c.v.at[dest].set(nv),
-            lengths=c.lengths + active))
+        tokens, cursors, read_tables, write_tables)
+    new_caches = [PagedKVCache(k=c.k.at[dest].set(nk), v=c.v.at[dest].set(nv))
+                  for c, nk, nv in zip(caches, new_k, new_v)]
     return logits, new_caches
 
 
 def paged_verify_step(cfg: TransformerConfig, params, tokens, active,
-                      read_tables, write_tables,
+                      cursors, read_tables, write_tables,
                       caches: List[PagedKVCache], *, attn: str = "gather",
                       moe_info: bool = False):
     """Speculative-decoding verify: score K candidate tokens per slot in
     ONE fixed-shape call over the slots axis (ISSUE 18). active: [slots]
     int32, 0 for a row without a live sequence (the in-place lanes attend
     nothing there, the gather lane takes no notice: such a row's logits
-    are dropped either way). tokens:
+    are dropped either way). cursors: [slots] int32, as in
+    ``paged_decode_step``. tokens:
     [slots, K] int32 — each slot's [next_token, d_1..d_{K-1}] placed at
     logical positions [cursor, cursor + K); logits[s, j] is the target
     model's distribution over the token FOLLOWING position cursor + j,
@@ -557,9 +537,10 @@ def paged_verify_step(cfg: TransformerConfig, params, tokens, active,
     query row reduces over pages in ascending order under a full-width
     mask, exactly the reduction a K=1 in-place decode performs.
 
-    Slot cursors are NOT advanced here: acceptance length is a host-side
-    decision (accept-prefix + corrected resample), applied afterwards via
-    ``paged_rewind_slots``. KV for all K positions IS written through the
+    How far a cursor advances is the caller's decision, made afterwards
+    (accept-prefix + corrected resample): accepted slots move to cursor +
+    accepted + 1, rejected tails are rewound by simply not advancing past
+    them. KV for all K positions IS written through the
     windowed scatter — rejected positions hold stale values that the next
     round's writes overwrite before anything attends to them (the same
     update-before-attend invariant the arena already relies on); shared /
@@ -573,12 +554,11 @@ def paged_verify_step(cfg: TransformerConfig, params, tokens, active,
     _check_moe_info(cfg, attn, moe_info)
     if attn != "gather":
         K = tokens.shape[1]
-        lengths = caches[0].lengths
         steps = jnp.arange(K, dtype=jnp.int32)[None]
         logits, new_caches, moe = _paged_forward_inplace(
-            cfg, params, tokens, lengths[:, None] + steps,
-            jnp.where(active > 0, lengths, -K),
-            read_tables, write_tables, caches, attn, lambda l: l,
+            cfg, params, tokens, cursors[:, None] + steps,
+            jnp.where(active > 0, cursors, -K),
+            read_tables, write_tables, caches, attn,
             steps < active[:, None])
         return _paged_outputs(logits, new_caches, moe, moe_info)
     T, HD = caches[0].k.shape[1:]
@@ -604,33 +584,11 @@ def paged_verify_step(cfg: TransformerConfig, params, tokens, active,
         outs_v = [r.v[0].reshape(P, T, HD)[widx] for r in new_rows]
         return logits[0], dest, (outs_k, outs_v)
 
-    lengths = caches[0].lengths
     logits, dest, (new_k, new_v) = jax.vmap(one, in_axes=(0, 0, 0, 0))(
-        tokens, lengths, read_tables, write_tables)
-    new_caches = []
-    for c, nk, nv in zip(caches, new_k, new_v):
-        new_caches.append(PagedKVCache(
-            k=c.k.at[dest].set(nk),
-            v=c.v.at[dest].set(nv),
-            lengths=c.lengths))
+        tokens, cursors, read_tables, write_tables)
+    new_caches = [PagedKVCache(k=c.k.at[dest].set(nk), v=c.v.at[dest].set(nv))
+                  for c, nk, nv in zip(caches, new_k, new_v)]
     return logits, new_caches
-
-
-def paged_rewind_slots(caches: List[PagedKVCache],
-                       new_lengths) -> List[PagedKVCache]:
-    """Set every slot's cursor after a verify round's host-side
-    acceptance: accepted slots advance to cursor + accepted + 1, rejected
-    tails rewind by simply NOT advancing past them. Stale KV beyond a
-    slot's new cursor is causally masked until overwritten (update-before-
-    attend), and shared pages are untouched — rewinding never frees or
-    mutates a page. new_lengths: [slots] int.
-
-    Each layer gets its OWN device buffer — the decode/verify programs
-    donate their caches, and a buffer shared across layers would be
-    donated once per layer (XLA rejects the duplicate)."""
-    host = np.asarray(new_lengths, np.int32)
-    return [dataclasses.replace(c, lengths=jnp.asarray(host))
-            for c in caches]
 
 
 @partial(jax.jit, static_argnums=(0, 4, 5, 6))

@@ -1227,12 +1227,11 @@ def run_all(budget_s: float = 2.0) -> List[Dict[str, float]]:
 
     def pa_step_ms(lane, act_pages, pages_per_slot, check_hlo=None):
         kv_pages = PA_S * pages_per_slot + 1  # the serve auto-sizing rule
-        caches = init_paged_caches(pa_cfg, PA_S, kv_pages, PA_T,
-                                   pages_per_slot)
-        lens = [act_pages * PA_T - 1 - (s % 3) for s in range(PA_S)]
-        caches = [type(c)(k=c.k, v=c.v,
-                          lengths=jnp.asarray(lens, jnp.int32))
-                  for c in caches]
+        caches = init_paged_caches(pa_cfg, kv_pages, PA_T, pages_per_slot)
+        # every step at the same cursors: the time of a step at a fixed
+        # number of live tokens
+        cur = jnp.asarray([act_pages * PA_T - 1 - (s % 3)
+                           for s in range(PA_S)], jnp.int32)
         tables = np.zeros((PA_S, pages_per_slot), np.int32)
         pid = 1
         for s in range(PA_S):
@@ -1242,22 +1241,22 @@ def run_all(budget_s: float = 2.0) -> List[Dict[str, float]]:
         tj = jnp.asarray(tables)
         step = jax.jit(_functools.partial(paged_decode_step, pa_cfg,
                                           attn=lane),
-                       donate_argnums=(5,))
+                       donate_argnums=(6,))
         toks = jnp.zeros(PA_S, jnp.int32)
         act = jnp.ones(PA_S, jnp.int32)
         if check_hlo is not None:
             # unoptimized lowered text: enough to prove the arms trace
             # different programs, without paying a second XLA compile
             check_hlo[lane] = step.lower(
-                pa_params, toks, act, tj, tj, caches).as_text()
-        lg, caches = step(pa_params, toks, act, tj, tj, caches)
+                pa_params, toks, act, cur, tj, tj, caches).as_text()
+        lg, caches = step(pa_params, toks, act, cur, tj, tj, caches)
         jax.block_until_ready(lg)
         first = np.asarray(lg).argmax(-1)
         best = float("inf")
         for _ in range(3 if full else 1):
             t0 = time.perf_counter()
             for _ in range(pa_iters):
-                lg, caches = step(pa_params, toks, act, tj, tj, caches)
+                lg, caches = step(pa_params, toks, act, cur, tj, tj, caches)
             jax.block_until_ready(lg)
             best = min(best, (time.perf_counter() - t0) / pa_iters * 1e3)
         return best, first

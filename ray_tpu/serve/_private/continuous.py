@@ -27,12 +27,16 @@ default): KV storage is a pool of ``page_tokens``-sized pages
 contiguous worst-case ``arena_len`` range, and the same two compiled
 programs gather/scatter through the tables at fixed shapes — so long/idle
 sequences stop reserving memory they never use and a replica admits far
-more concurrent sequences at the same arena bytes. On top of paging a
-PREFIX/RADIX CACHE (``serve/_private/paging.RadixCache``) makes admitting
-a request whose prompt shares a cached prefix a page-table splice + cursor
-jump instead of a re-prefill; eviction is LRU over refcount-0 nodes under
-arena pressure. ``kv_layout="contiguous"`` keeps the PR-9 arena as the
-measured baseline (the collective layer's ``algo="kv"`` idiom).
+more concurrent sequences at the same arena bytes. The device holds the
+pages and nothing else: each slot's cursor is ``_Seq.cursor`` here on the
+host, handed to every program as an argument beside the tables, so an
+admission, a retirement or a rejected draft runs no device program. On top
+of paging a PREFIX/RADIX CACHE (``serve/_private/paging.RadixCache``) makes
+admitting a request whose prompt shares a cached prefix a page-table
+splice + cursor jump instead of a re-prefill; eviction is LRU over
+refcount-0 nodes under arena pressure. ``kv_layout="contiguous"`` keeps the
+PR-9 arena as the measured baseline (the collective layer's ``algo="kv"``
+idiom).
 
 ISSUE 18 adds the FLEET phase on top: (1) the radix cache's chain-hash
 digest is exported through ``prefix_digest()`` so the router can steer
@@ -192,9 +196,9 @@ class _Seq:
         self.t_admit: Optional[float] = None
         self.t_first_token: Optional[float] = None
         self.rng = None  # lazily created numpy Generator for temperature > 0
-        # ---- paged-arena bookkeeping (host mirrors of device state) ----
+        # ---- paged-arena bookkeeping (the device holds pages only) ----
         self.cached_len = 0            # spliced prefix tokens (page-aligned)
-        self.cursor = 0                # mirrors the slot's device cursor
+        self.cursor = 0                # tokens resident: THE slot's cursor
         self.owned_pages: List[int] = []  # pages this slot must free
         self.radix_node = None         # ref-counted prefix-cache node
         self.table_fill = 0            # logical pages present in the table
@@ -211,9 +215,13 @@ class ContinuousScheduler:
 
     ``params`` are the (device-resident) model parameters shared by every
     program; the scheduler owns the KV arena and two jitted programs —
-    ``prefill_into_slot`` (one compiled shape: [1, prefill_chunk]) and
-    ``slot_decode_step`` ([slots]) — both with donated caches so the arena
-    updates in place instead of being copied per iteration.
+    a prefill chunk (one compiled shape: [1, prefill_chunk]) and a decode
+    step ([slots]) — both with donated caches so the arena updates in
+    place instead of being copied per iteration. In the paged
+    layout (``paged_prefill_into_slot``, ``paged_decode_step``) the
+    scheduler also owns every slot's cursor and passes it with each call;
+    the contiguous arena (``prefill_into_slot``, ``slot_decode_step``)
+    still keeps its cursors on the device.
     """
 
     def __init__(self, cfg, params, *, slots: Optional[int] = None,
@@ -328,9 +336,9 @@ class ContinuousScheduler:
                          **self._lane_kw), donate_argnums=(6,))
             self._step = jax.jit(
                 _program(paged_decode_step, "paged_decode_step", cfg,
-                         **self._lane_kw), donate_argnums=(5,))
+                         **self._lane_kw), donate_argnums=(6,))
             self._caches = init_paged_caches(
-                cfg, self.slots, self.num_pages, self.page_tokens,
+                cfg, self.num_pages, self.page_tokens,
                 self._pages_per_slot, cache_dtype)
             self._kv_itemsize = int(self._caches[0].k.dtype.itemsize)
         else:
@@ -397,7 +405,7 @@ class ContinuousScheduler:
 
             self._verify = jax.jit(
                 _program(paged_verify_step, "paged_verify_step", cfg,
-                         **self._lane_kw), donate_argnums=(5,))
+                         **self._lane_kw), donate_argnums=(6,))
         # ---- cross-replica page migration (ISSUE 18): a dedicated
         # worker thread does the blocking peer pull; the scheduler thread
         # only splices finished results between iterations. _commands
@@ -694,7 +702,11 @@ class ContinuousScheduler:
         self._n_prefix_hit_tokens += keep
 
     def _admit(self) -> None:
-        from ray_tpu.models.decode import paged_reset_slot, reset_slot
+        """Seat pending requests in free slots. In the paged layout this is
+        host bookkeeping alone (tables, the prefix splice, the cursor): the
+        programs take the cursor as an argument, so no device program runs
+        and no live slot waits for one."""
+        from ray_tpu.models.decode import reset_slot
 
         while True:
             with self._lock:
@@ -727,8 +739,6 @@ class ContinuousScheduler:
                     self._release_migration_ref(seq)
                 seq.cursor = seq.cached_len
                 seq.remaining_prompt = seq.prompt[seq.cached_len:]
-                self._caches = paged_reset_slot(self._caches, free,
-                                                seq.cached_len)
             else:
                 self._caches = reset_slot(self._caches, free)
             self._n_admitted += 1
@@ -796,6 +806,23 @@ class ContinuousScheduler:
         self._n_attn_bytes += moved
         _m_attn_bytes.inc(moved, labels={"lane": self.attn_lane})
 
+    def _cursors(self):
+        """``[slots]`` int32 for a decode or verify call: every seated
+        sequence's ``cursor``, 0 for a free slot. The host's count is the
+        only one; the device keeps none to reset or read back.
+
+        The rows the call marks inactive rely on this: such a row attends
+        nothing (the program masks it by ``active``) but still writes at
+        its cursor. A PREFILLING slot passes its true cursor, so the write
+        lands on the position its next chunk writes before anything
+        attends it (or, page not yet allocated, on the garbage page); a
+        FREE slot's table rows are zero, so whatever it passes lands on
+        the garbage page."""
+        import numpy as np
+
+        return np.fromiter((0 if s is None else s.cursor
+                            for s in self._slot_seqs), np.int32, self.slots)
+
     def _moe_note(self, out, live_rows: int):
         """Split a paged program's result. An expert model's third value
         (``moe_info``) is kept on the device until ``_moe_count`` reads
@@ -853,14 +880,16 @@ class ContinuousScheduler:
             seq.remaining_prompt = seq.remaining_prompt[self.prefill_chunk:]
             real = len(chunk)
             padded = chunk + [0] * (self.prefill_chunk - real)
-            tokens = jnp.asarray([padded], jnp.int32)
+            # NumPy, uploaded with the call: jnp.asarray of a list with a
+            # dtype would run an eager convert program of its own a chunk
+            tokens = np.asarray([padded], np.int32)
             if self._paged:
                 # the rows are uploaded as COPIES: dispatch is async and an
                 # upload may alias (CPU) or still be reading (TPU) the host
                 # buffer, while _offer_prompt_pages and _ensure_pages write
                 # to these rows before anything waits for this chunk
                 logits, self._caches = self._moe_note(self._prefill(
-                    self.params, tokens, np.int32(real), np.int32(seq.slot),
+                    self.params, tokens, np.int32(real), np.int32(seq.cursor),
                     jnp.asarray(self._read_tables[seq.slot].copy()),
                     jnp.asarray(self._write_tables[seq.slot].copy()),
                     self._caches), real)
@@ -1181,9 +1210,9 @@ class ContinuousScheduler:
         ``spec_k`` batched drafter steps propose tokens, ONE fixed-shape
         ``paged_verify_step`` scores every proposal, and exact
         accept-prefix + corrected-resample emits 1..spec_k+1 tokens per
-        live sequence. Rejections rewind CURSORS only (host-side) — pages
-        are never freed or mutated by a rejection; stale KV past a cursor
-        is causally masked until overwritten.
+        live sequence. A rejection just does not advance ``seq.cursor``
+        past it — pages are never freed or mutated by one; stale KV past a
+        cursor is causally masked until overwritten.
 
         Drafter sync: the drafter always steps ``spec_k`` times (fixed
         program shapes), but after a fully-accepted round it first
@@ -1193,7 +1222,6 @@ class ContinuousScheduler:
 
         import jax.numpy as jnp
 
-        from ray_tpu.models.decode import paged_rewind_slots
         from ray_tpu.serve._private.speculative import (_softmax,
                                                         accept_greedy,
                                                         accept_sample,
@@ -1257,20 +1285,19 @@ class ContinuousScheduler:
             used[s.slot] = len(row)
         switch(_P_VERIFY)
         vlogits, self._caches = self._moe_note(self._verify(
-            self.params, jnp.asarray(vt), jnp.asarray(used),
+            self.params, jnp.asarray(vt), jnp.asarray(used), self._cursors(),
             jnp.asarray(self._read_tables),
             jnp.asarray(self._write_tables), self._caches), int(used.sum()))
         va = np.asarray(vlogits)
         self._moe_count()
-        switch(_P_EMIT)  # acceptance, emission and the cursor rewind
+        switch(_P_EMIT)  # acceptance and emission
         self._record_attn(K, [s.cursor for s in live],
                           self.slots - len(live))
         self._n_steps += 1
         _m_steps.inc()
         self._n_spec_rounds += 1
         self._max_active_slots = max(self._max_active_slots, len(live))
-        # ---- exact acceptance + host-side cursor rewind ---------------
-        new_lengths = np.asarray(self._caches[0].lengths, np.int32).copy()
+        # ---- exact acceptance: the cursor moves past what was accepted -
         dlen = self._drafter.lengths().copy()
         for s in live:
             sl = s.slot
@@ -1293,7 +1320,6 @@ class ContinuousScheduler:
                 m_spec_accepted.inc(a)
             new_cursor = old + a + 1
             s.cursor = new_cursor
-            new_lengths[sl] = new_cursor
             # drafter sync: positions [L0, L0 + k) were consumed this
             # round; the valid prefix stops at the last accepted position,
             # and whatever accepted tokens the drafter missed become next
@@ -1314,7 +1340,6 @@ class ContinuousScheduler:
             if finished:
                 self._retire(s, "eos" if self.eos_id is not None
                              and s.next_token == self.eos_id else "length")
-        self._caches = paged_rewind_slots(self._caches, new_lengths)
         self._drafter.set_lengths(dlen)
         return True
 
@@ -1344,7 +1369,7 @@ class ContinuousScheduler:
         if self._paged:
             logits, self._caches = self._moe_note(self._step(
                 self.params, jnp.asarray(toks), jnp.asarray(active),
-                jnp.asarray(self._read_tables),
+                self._cursors(), jnp.asarray(self._read_tables),
                 jnp.asarray(self._write_tables), self._caches), len(live))
         else:
             logits, self._caches = self._step(

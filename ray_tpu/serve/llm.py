@@ -6,9 +6,11 @@ and the batching is CONTINUOUS (iteration-level, ISSUE 9):
 
   * a PAGED KV arena (`models.decode.PagedKVCache`, ISSUE 13) plus ONE
     fixed-shape jitted decode step over all slots per iteration; slots
-    own page tables instead of worst-case `max_seq_len` ranges, a radix
-    prefix cache turns shared system-prompt/few-shot preambles into a
-    page-table splice + cursor jump at admission, new requests are
+    own page tables instead of worst-case `max_seq_len` ranges (tables
+    and cursors are the scheduler's host state, passed to each call: the
+    device holds pages only), a radix prefix cache turns shared
+    system-prompt/few-shot preambles into a page-table splice + cursor
+    jump at admission, new requests are
     admitted into free slots between iterations (chunked prefill),
     finished/EOS/cancelled sequences retire their slot (and pages)
     immediately — ≈ vLLM's PagedAttention + SGLang's RadixAttention

@@ -167,7 +167,7 @@ def test_prefill_and_decode_step_match_the_full_forward(toy):
 
 
 def paged_setup(cfg, slots, T=8, P=16):
-    caches = init_paged_caches(cfg, slots, slots * P + 1, T, P)
+    caches = init_paged_caches(cfg, slots * P + 1, T, P)
     tables = (1 + np.arange(slots * P, dtype=np.int32)).reshape(slots, P)
     return caches, jnp.asarray(tables)
 
@@ -192,20 +192,22 @@ def paged_run(toy):
                 padded = np.zeros((1, C), np.int32)
                 padded[0, :real] = chunk
                 logits, caches, moe = paged_prefill_into_slot(
-                    cfg, params, jnp.asarray(padded), real, s, tables[s],
-                    tables[s], caches, attn="reference", moe_info=True)
+                    cfg, params, jnp.asarray(padded), real, np.int32(c0),
+                    tables[s], tables[s], caches, attn="reference",
+                    moe_info=True)
                 taken[s].append(np.asarray(moe["routes"])[:, 0, :real])
                 counted += int(moe["counts"].sum())
                 live += real
             got[s].append(logits)
         active = jnp.asarray([0, 1, 1, 0], jnp.int32)
+        cursors = np.asarray([0, n[0], n[1], 0], np.int32)
         for step in range(6):
             toks = np.zeros(slots, np.int32)
             for b, s in enumerate((1, 2)):
                 toks[s] = tokens[b, n[b] + step]
             logits, caches, moe = paged_decode_step(
-                cfg, params, jnp.asarray(toks), active, tables, tables,
-                caches, attn="reference", moe_info=True)
+                cfg, params, jnp.asarray(toks), active, cursors + step,
+                tables, tables, caches, attn="reference", moe_info=True)
             counted += int(moe["counts"].sum())
             live += 2
             for s in (1, 2):
@@ -249,6 +251,7 @@ def test_paged_verify_step_matches_the_full_forward(toy, paged_run):
     with jax.default_matmul_precision("highest"):
         logits, _, moe = paged_verify_step(
             cfg, params, jnp.asarray(window), jnp.asarray(used),
+            np.asarray([0, starts[1], starts[2], 0], np.int32),
             paged_run["tables"], paged_run["tables"], paged_run["caches"],
             attn="reference", moe_info=True)
     assert int(moe["counts"].sum()) == 7 * cfg.moe_top_k * cfg.num_layers
@@ -263,8 +266,9 @@ def test_gather_lane_refuses_moe_info(toy):
     caches, tables = paged_setup(cfg, 2)
     with pytest.raises(ValueError, match="in-place"):
         paged_decode_step(cfg, params, jnp.zeros(2, jnp.int32),
-                          jnp.ones(2, jnp.int32), tables, tables, caches,
-                          attn="gather", moe_info=True)
+                          jnp.ones(2, jnp.int32), jnp.zeros(2, jnp.int32),
+                          tables, tables, caches, attn="gather",
+                          moe_info=True)
 
 
 def test_scheduler_serves_the_expert_model_and_drops_no_row(toy):

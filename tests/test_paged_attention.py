@@ -315,7 +315,7 @@ def tiny_model():
 def _arena(cfg, S, T, P):
     from ray_tpu.models.decode import init_paged_caches
 
-    caches = init_paged_caches(cfg, S, S * P + 1, T, P, jnp.float32)
+    caches = init_paged_caches(cfg, S * P + 1, T, P, jnp.float32)
     tables = np.zeros((S, P), np.int32)
     pid = 1
     for s in range(S):
@@ -327,7 +327,8 @@ def _arena(cfg, S, T, P):
 
 def _drive_lane(cfg, params, attn, prompts, new_tokens, T=4, P=8):
     """Prefill mixed-length prompts into slots, then greedy-decode
-    ``new_tokens`` steps. Returns (tokens per slot, stacked logits)."""
+    ``new_tokens`` steps. Returns (tokens per slot, stacked logits, caches,
+    tables, cursors): the cursors are the caller's, the pool keeps none."""
     from functools import partial
 
     from ray_tpu.models.decode import (paged_decode_step,
@@ -342,21 +343,23 @@ def _drive_lane(cfg, params, attn, prompts, new_tokens, T=4, P=8):
     for s, ids in enumerate(prompts):
         padded = list(ids) + [0] * (CHUNK - len(ids))
         last, caches = prefill(params, jnp.asarray([padded], jnp.int32),
-                               np.int32(len(ids)), np.int32(s),
+                               np.int32(len(ids)), np.int32(0),
                                tables[s], tables[s], caches)
         next_tok.append(int(np.asarray(last).argmax()))
     toks, active = np.asarray(next_tok, np.int32), np.ones(S, np.int32)
+    cursors = np.asarray([len(ids) for ids in prompts], np.int32)
     out = [[t] for t in next_tok]
     traces = []
     for _ in range(new_tokens):
-        logits, caches = step(params, jnp.asarray(toks),
-                              jnp.asarray(active), tables, tables, caches)
+        logits, caches = step(params, jnp.asarray(toks), jnp.asarray(active),
+                              cursors, tables, tables, caches)
+        cursors = cursors + 1
         la = np.asarray(logits)
         traces.append(la)
         toks = la.argmax(-1).astype(np.int32)
         for s in range(S):
             out[s].append(int(toks[s]))
-    return out, np.stack(traces), caches, tables
+    return out, np.stack(traces), caches, tables, cursors
 
 
 class TestInPlaceLanes:
@@ -368,12 +371,12 @@ class TestInPlaceLanes:
         the pallas lane's logits must equal the reference lane's BITWISE
         at every step."""
         cfg, params = tiny_model
-        gather, _, _, _ = _drive_lane(cfg, params, "gather",
+        gather, *_ = _drive_lane(cfg, params, "gather",
+                                 self.PROMPT_IDS, NEW)
+        ref, ref_tr, *_ = _drive_lane(cfg, params, "reference",
                                       self.PROMPT_IDS, NEW)
-        ref, ref_tr, _, _ = _drive_lane(cfg, params, "reference",
-                                        self.PROMPT_IDS, NEW)
-        pal, pal_tr, _, _ = _drive_lane(cfg, params, "pallas",
-                                        self.PROMPT_IDS, NEW)
+        pal, pal_tr, *_ = _drive_lane(cfg, params, "pallas",
+                                      self.PROMPT_IDS, NEW)
         assert ref == gather, "in-place lane token stream diverged"
         assert pal == gather
         assert np.array_equal(ref_tr, pal_tr), \
@@ -390,19 +393,20 @@ class TestInPlaceLanes:
         cfg, params = tiny_model
         outs = {}
         for attn in ("gather", "reference", "pallas"):
-            toks, _, caches, tables = _drive_lane(
+            toks, _, caches, tables, cursors = _drive_lane(
                 cfg, params, attn, self.PROMPT_IDS, 1)
             vt = np.asarray([[t[-1], 1, 2] for t in toks], np.int32)
             verify = jax.jit(partial(paged_verify_step, cfg, attn=attn))
             active = np.ones(len(toks), np.int32)
             logits, _ = verify(params, jnp.asarray(vt), jnp.asarray(active),
-                               tables, tables, caches)
+                               cursors, tables, tables, caches)
             outs[attn] = np.asarray(logits)
-            # a row marked inactive (a retired slot's stale cursor and
-            # pages) changes nothing for the live rows
+            # a row marked inactive (a retired slot's pages still in the
+            # tables, whatever cursor it passes) changes nothing for the
+            # live rows
             active[1] = 0
             logits, _ = verify(params, jnp.asarray(vt), jnp.asarray(active),
-                               tables, tables, caches)
+                               cursors, tables, tables, caches)
             assert np.array_equal(np.asarray(logits)[[0, 2]],
                                   outs[attn][[0, 2]]), attn
         assert np.array_equal(outs["gather"].argmax(-1),
@@ -414,8 +418,8 @@ class TestInPlaceLanes:
                                            paged_prefill_into_slot,
                                            paged_verify_step)
 
-        for fn, nargs in ((paged_decode_step, 6),
-                          (paged_verify_step, 6),
+        for fn, nargs in ((paged_decode_step, 7),
+                          (paged_verify_step, 7),
                           (paged_prefill_into_slot, 7)):
             with pytest.raises(ValueError, match="unknown paged attention"):
                 fn(None, *([None] * nargs), attn="turbo")

@@ -516,6 +516,15 @@ def _sequential_reference(srv, prompt, new_tokens):
     return srv._detokenize(out)
 
 
+def _assert_the_pool_keeps_no_cursor(srv):
+    """``_Seq.cursor`` is the only cursor: the device holds pages, so a
+    speculative round has nothing to read back or upload but its logits."""
+    import dataclasses
+
+    assert [f.name for f in dataclasses.fields(srv._sched._caches[0])] == [
+        "k", "v"]
+
+
 @pytest.fixture(scope="module")
 def spec_server():
     srv = _mk_server(drafter="self", spec_k=4)
@@ -546,6 +555,38 @@ class TestSpeculativeParity:
         # self-drafter at temperature 0: every draft must be accepted
         assert st["spec_accept_rate"] == 1.0
         assert st["spec_tokens_per_step"] > 1.0
+        _assert_the_pool_keeps_no_cursor(srv)
+
+    def test_rejected_drafts_move_only_the_hosts_cursor(self):
+        """A drafter that is wrong every other step: each rejection leaves
+        ``seq.cursor`` short of the window the verify call wrote, there is
+        no device cursor to rewind or read back, and the stream is still
+        the sequential greedy one — slots reused mid-speculation."""
+        srv = _mk_server(drafter="self", spec_k=3)
+        try:
+            drafter = srv._sched._drafter
+            step, calls = drafter.step, [0]
+
+            def wrong_every_other_step(tokens, active):
+                calls[0] += 1
+                logits = step(tokens, active)
+                return -logits if calls[0] % 2 else logits
+
+            drafter.step = wrong_every_other_step
+            refs = {p: _sequential_reference(srv, p, NEW) for p in PROMPTS}
+
+            async def drive():
+                reqs = [{"prompt": p} for p in PROMPTS * 3]
+                return await asyncio.gather(*[srv(r) for r in reqs])
+
+            for o in asyncio.run(drive()):
+                assert o["text"] == refs[o["prompt"]], o["prompt"]
+            st = srv.scheduler_stats()
+            assert 0.0 < st["spec_accept_rate"] < 1.0
+            assert st["compiled_programs"] == 2
+            _assert_the_pool_keeps_no_cursor(srv)
+        finally:
+            srv.shutdown()
 
     def test_slot_reuse_stays_exact(self, spec_server):
         """> slots requests force retire/reuse mid-speculation; rewound

@@ -69,6 +69,24 @@ class _SlowStep:
         return self._step._cache_size()
 
 
+class _CancelMidPrefill:
+    """Test double of the prefill chunk: once ``target`` has a chunk
+    resident, cancels it (on the scheduler's thread, so before its next
+    chunk), then runs the real program."""
+
+    def __init__(self, sched):
+        self._sched, self._prefill, self.target = sched, sched._prefill, None
+
+    def __call__(self, *args):
+        seq = self.target
+        if seq is not None and seq.cursor > 0:
+            self._sched.cancel(seq)
+        return self._prefill(*args)
+
+    def _cache_size(self):
+        return self._prefill._cache_size()
+
+
 # ------------------------------------------------------------- the clock
 
 
@@ -203,6 +221,62 @@ def test_phases_and_programs_are_named_in_a_profiler_trace(warm, tmp_path):
     labels = [label for label, _ in summary["breakdown"]["idle_gaps"]]
     assert not [l for l in labels if "jit__unknown" in l], labels
     assert [l for l in labels if "| host: serve." in l], labels
+
+
+def test_an_admission_runs_no_device_program(tmp_path):
+    """The cursors are the scheduler's (ISSUE 27): whatever an admission
+    finds in its slot — a longer sequence's pages and position, a cached
+    prefix to start behind, a prompt cancelled between two chunks — the
+    device runs the two named programs and nothing else, and serves what a
+    fresh scheduler serves."""
+    import dataclasses
+
+    from perfbench.lib import trace
+
+    long_a = "a long first occupant: forty-eight tokens, three pages"[:48]
+    hit = long_a[:32] + " and another tail"
+    gone = "cancelled between its chunks " * 3
+    srv = _server()
+    try:
+        sched = srv._sched
+        _drive(srv, ["warm a", "warm b"])
+        cancel = sched._prefill = _CancelMidPrefill(sched)
+        before = srv.scheduler_stats()
+        trace.start(str(tmp_path))
+        served = dict(zip((long_a, "b"), _drive(srv, [long_a, "b"])))
+        # both slots held longer sequences: the cursors start over
+        served.update(zip(("cd", "e"), _drive(srv, ["cd", "e"])))
+        served[hit] = _drive(srv, [hit])[0]  # starts at cached_len = 32
+
+        async def cancelled_mid_prefill():
+            seq, q = srv._submit(srv._tokenize(gone), 6, 0.0)
+            cancel.target = seq
+            live = asyncio.ensure_future(srv({"prompt": "beside it"}))
+            assert (await srv._next_item(q)) == ("end", "cancelled")
+            assert 0 < seq.cursor < len(seq.prompt)
+            return (await live)["text"]
+
+        served["beside it"] = asyncio.run(cancelled_mid_prefill())
+        cancel.target = None
+        served["after"] = _drive(srv, ["after"])[0]  # the cancelled slot
+        path = trace.stop(str(tmp_path))
+        ran = _delta(srv.scheduler_stats(), before,
+                     ("admitted", "retired", "prefix_hit_tokens"))
+        assert ran == {"admitted": 8, "retired": 8, "prefix_hit_tokens": 32}
+        programs = trace.summarize(trace.load(path))["programs"]
+        assert sorted(programs) == ["jit_paged_decode_step",
+                                    "jit_paged_prefill_chunk"], programs
+        assert sched.compiled_programs() == 2
+        assert [f.name for f in dataclasses.fields(sched._caches[0])] == [
+            "k", "v"]
+    finally:
+        srv.shutdown()
+    fresh = _server(prefix_cache=False)
+    try:
+        for prompt, text in served.items():
+            assert _drive(fresh, [prompt]) == [text], prompt
+    finally:
+        fresh.shutdown()
 
 
 @pytest.mark.parametrize("layout,names", [

@@ -286,7 +286,7 @@ def test_gpt2s_serve_programs_compile_and_fit(v5e):
     params = place(jax.eval_shape(
         functools.partial(init_params, cfg), jax.random.PRNGKey(0)))
     caches = place(jax.eval_shape(functools.partial(
-        init_paged_caches, cfg, slots, slots * pages + 1, T, pages)))
+        init_paged_caches, cfg, slots * pages + 1, T, pages)))
     table = _on(chip, (slots, pages), jnp.int32)
     row = _on(chip, (pages,), jnp.int32)
     ids = functools.partial(_on, chip, dtype=jnp.int32)
@@ -295,11 +295,11 @@ def test_gpt2s_serve_programs_compile_and_fit(v5e):
                     (params, ids((1, chunk)), ids(()), ids(()), row, row,
                      caches), 6),
         "decode": (paged_decode_step,
-                   (params, ids((slots,)), ids((slots,)), table, table,
-                    caches), 5),
+                   (params, ids((slots,)), ids((slots,)), ids((slots,)), table,
+                    table, caches), 6),
         "verify": (paged_verify_step,
                    (params, ids((slots, conf.serve_spec_k + 1)), ids((slots,)),
-                    table, table, caches), 5),
+                    ids((slots,)), table, table, caches), 6),
     }
     for name, (program, args, donated) in programs.items():
         compiled = jax.jit(functools.partial(program, cfg, attn=lane),
@@ -340,7 +340,7 @@ def test_olmoe_serve_programs_compile_and_fit(v5e):
     params = place(jax.eval_shape(
         functools.partial(init_params, cfg), jax.random.PRNGKey(0)))
     caches = place(jax.eval_shape(functools.partial(
-        init_paged_caches, cfg, slots, dep["kv_pages"], T, pages)))
+        init_paged_caches, cfg, dep["kv_pages"], T, pages)))
     held = sum(a.size * a.dtype.itemsize
                for a in jax.tree.leaves((params, caches)))
     assert 13.4e9 < held < 13.7e9  # 7.13 GB of weights + 6.4 GB of pool
@@ -352,8 +352,8 @@ def test_olmoe_serve_programs_compile_and_fit(v5e):
                     (params, ids((1, chunk)), ids(()), ids(()), row, row,
                      caches), 6),
         "decode": (paged_decode_step,
-                   (params, ids((slots,)), ids((slots,)), table, table,
-                    caches), 5),
+                   (params, ids((slots,)), ids((slots,)), ids((slots,)), table,
+                    table, caches), 6),
     }
     for name, (program, args, donated) in programs.items():
         compiled = jax.jit(
