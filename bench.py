@@ -1,5 +1,5 @@
-"""Benchmark: causal-LM training MFU on the local chip, a 1B-class second
-config, and the serve decode capacity.
+"""Benchmark: causal-LM training MFU on the local chip and a 1B-class
+second config.
 
 One process that measures on the chip it finds. It fails — prints no
 record, exits non-zero — when JAX finds no TPU (a CPU number is never
@@ -118,11 +118,10 @@ def _mfu_record(metric, dt, n_params, cfg, batch, seq, peak,
 
 
 def main() -> None:
-    """gpt2s train MFU, then the 1B config, then serve decode capacity.
-    A phase that fails fails the run."""
+    """gpt2s train MFU, then the 1B config. A phase that fails fails the
+    run."""
     device, peak, stamp = _chip()
 
-    from bench_serve import bench_decode
     from ray_tpu.models import gpt2_small, gpt_1b
 
     batch, seq = 16, 1024
@@ -139,17 +138,6 @@ def main() -> None:
     dt1, n1 = _run_config(cfg1, b1, s1, steps=10)
     rec["detail"]["gpt1b_mfu"] = _mfu_record(
         "gpt1b_train_mfu", dt1, n1, cfg1, b1, s1, peak)
-
-    # third perf point: the batched prefill+decode program a Serve LLM
-    # replica runs per @serve.batch flush, peak tokens/s over batch sizes
-    d = bench_decode("gpt2_small", prompt_len=128, new_tokens=64)
-    best = max(d["per_batch"], key=lambda r: r["decode_tokens_per_sec"])
-    rec["detail"]["serve_decode"] = {
-        "metric": "llm_decode_tokens_per_sec",
-        "value": best["decode_tokens_per_sec"],
-        "unit": "tokens/s",
-        "per_batch": d["per_batch"],
-    }
     print(json.dumps(rec))
 
 

@@ -129,33 +129,20 @@ class Config:
     # many tokens between decode iterations, so a long prompt cannot stall
     # the in-flight decodes of other slots
     serve_prefill_chunk: int = 32
-    # KV arena layout: "paged" (pool of page_tokens-sized pages, per-slot
-    # page tables, prefix sharing — ISSUE 13) or "contiguous" (PR-9
-    # worst-case range per slot, kept as the measured baseline)
-    serve_kv_layout: str = "paged"
-    # tokens per KV page. Explicit 0 (env or argument) RAISES at scheduler
+    # tokens per KV page (the pool is pages of this many tokens, a page
+    # table a slot). Explicit 0 (env or argument) RAISES at scheduler
     # build — it never silently becomes this default (the PR-8/PR-9
     # falsy-zero lesson)
     serve_page_tokens: int = 16
     # total pages in the paged pool (page 0 is the reserved garbage page).
-    # 0 = auto: size for the contiguous worst case, slots * arena_len /
-    # page_tokens + 1 — same arena bytes as the PR-9 layout, but slots
-    # only consume what they actually use, so capacity can be raised
-    # ~10x at the same bytes by raising `serve_slots`
+    # 0 = auto: size for every slot's worst case, slots * arena_len /
+    # page_tokens + 1; slots only consume what they actually use, so
+    # capacity can be raised at the same bytes by raising `serve_slots`
     serve_kv_pages: int = 0
     # radix prefix cache over prompt tokens: admit a request whose prompt
     # shares a cached prefix by page-table splice + cursor jump instead of
-    # re-prefilling. Requires the paged layout
+    # re-prefilling
     serve_prefix_cache: bool = True
-    # paged-attention lane of the decode/verify/prefill programs (paged
-    # layout only): "auto" = the in-place lane (Pallas kernel on TPU, its
-    # pure-JAX twin elsewhere — attention reads KV pages straight from the
-    # pool, no gathered view); "pallas"/"reference" force one in-place
-    # impl; "gather" keeps the original gathered-view + scatter-back
-    # programs (the measured baseline, selectable like
-    # collective_algo="kv"). Unknown/falsy values ("0", "") are REJECTED
-    # at scheduler build — never a silent fallback
-    serve_paged_attn: str = "auto"
     # ---- serve: fleet phase 2 (ISSUE 18) ----
     # prefix-affinity routing: replicas advertise a digest of their radix
     # cache's page-boundary prefix hashes through the controller's stats
@@ -359,18 +346,6 @@ def _render(val) -> str:
     if isinstance(val, (dict, list)):
         return json.dumps(val)
     return str(val)
-
-
-def env_flag_explicit(field_name: str) -> bool | None:
-    """True/False iff the ``RAY_TPU_<FIELD_NAME>`` env var is explicitly
-    set — parsed by the SAME bool rule ``Config.from_env`` uses — else
-    None. For callers that must distinguish an operator's explicit env
-    intent from a config-field default (e.g. loud knob-conflict
-    rejection) without re-implementing the parser."""
-    raw = os.environ.get(_ENV_PREFIX + field_name.upper())
-    if raw is None:
-        return None
-    return bool(_parse(raw, bool, False))
 
 
 _global_config: Config | None = None

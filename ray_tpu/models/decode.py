@@ -98,11 +98,11 @@ def sample_token(logits, key, temperature: float = 0.0, top_k: int = 0):
 
 
 # ---------------------------------------------------------------------------
-# slotted KV arena — the continuous-batching substrate (serve/_private/
-# continuous.py). One fixed-shape decode program steps EVERY slot each
-# iteration; sequences are admitted into free slots (chunked prefill) and
-# retire their slot the moment they finish, so the program shape never
-# changes while the active set churns.
+# slotted KV arena — a contiguous [slots, max_len] cache whose cursors live
+# on the device. The speculative DRAFTER (serve/_private/speculative.py) is
+# its only user: the serving scheduler runs the paged programs below. One
+# fixed-shape decode program steps EVERY slot each iteration, so the program
+# shape never changes while the active set churns.
 
 
 @jax.tree_util.register_dataclass
@@ -206,28 +206,29 @@ def slot_decode_step(cfg: TransformerConfig, params, tokens, active, caches):
 
 
 # ---------------------------------------------------------------------------
-# paged KV arena — the slot arena rebuilt as a pool of fixed-size pages
-# (ISSUE 13). KV storage is [num_pages, page_tokens, Hkv * D] per layer (a
+# paged KV arena — what the serving scheduler (serve/_private/continuous.py)
+# runs. KV storage is a pool [num_pages, page_tokens, Hkv * D] per layer (a
 # token's kv heads joined on the minor axis: a page is then ONE contiguous
 # run of whole 128-lane rows, which the paged kernel streams in
 # double-buffered blocks of whole rows and serves every kv head from — see
-# ops/paged_attention.py); a slot owns a
-# PAGE TABLE ([pages_per_slot] int32 of physical page ids)
-# instead of a contiguous worst-case range, so long/idle sequences stop
-# reserving memory they don't use and read-only pages can be SHARED between
-# slots (the prefix cache). The two compiled programs gather a slot's
-# logical view out of the pool, run the exact same per-row math as the
-# contiguous SlotKVCache path, and scatter the view back through a WRITE
-# table — so paging relocates bytes but never changes a single attended
-# value (temperature-0 parity with the contiguous arena is bit-exact).
+# ops/paged_attention.py); a slot owns a PAGE TABLE ([pages_per_slot] int32
+# of physical page ids) instead of a contiguous worst-case range, so
+# long/idle sequences reserve no memory they don't use and read-only pages
+# can be SHARED between slots (the prefix cache). Every program is one
+# forward, ``_paged_forward_inplace``: each layer writes the new tokens' k/v
+# straight into their pages through a WRITE table and attends THROUGH the
+# READ table (``ops.paged_attention``), so no contiguous view of a slot ever
+# exists and a step's cost follows the pages a sequence holds. ``attn``
+# names the op's implementation ('reference' | 'pallas') and has no default:
+# ``ops.paged_attention.resolve_impl`` picks it from the platform and the
+# model's shapes. The tests' oracle is the sequential cache above
+# (``prefill`` + ``decode_step``).
 #
 # Page 0 is RESERVED as the garbage page: read-table entries for logical
 # pages a slot has not allocated point at it (their positions are >= the
-# slot's cursor, so the causal mask zeroes them exactly — the same
-# masked-garbage invariant the contiguous arena already relies on for
-# stale slot content), and write-table entries for SHARED or unallocated
-# pages redirect there so a slot can never scribble on a page it does not
-# own. The scheduler (serve/_private/continuous.py) maintains the tables
+# slot's cursor, so the causal mask zeroes them exactly), and write-table
+# entries for SHARED or unallocated pages redirect there so a slot can never
+# scribble on a page it does not own. The scheduler maintains the tables
 # host-side and guarantees the page covering every position written by a
 # program is allocated and owned before the call.
 
@@ -274,29 +275,6 @@ def init_paged_caches(cfg: TransformerConfig, num_pages: int,
             for _ in range(cfg.num_layers)]
 
 
-def _gather_row(cfg: TransformerConfig, c: PagedKVCache, table):
-    """[P] page table -> one slot's logical [1, P*T, Hkv, D] k/v view."""
-    view = (1, table.shape[0] * c.k.shape[1], cfg.kv_heads, cfg.head_dim)
-    return c.k[table].reshape(view), c.v[table].reshape(view)
-
-
-# attention lanes for the paged programs (ISSUE 20). "gather" is the
-# measured-baseline gathered-view path (the original ISSUE-13 programs,
-# kept selectable like collective_algo="kv" — never a silent fallback);
-# "reference"/"pallas" are the in-place lanes: each layer writes the new
-# tokens' k/v straight into their pages and attends THROUGH the page table
-# (ops/paged_attention.py), so no contiguous [arena_len] view ever exists
-# and step cost tracks allocated pages, not pool provisioning.
-PAGED_ATTN_LANES = ("gather", "reference", "pallas")
-
-
-def _check_attn_lane(attn: str) -> None:
-    if attn not in PAGED_ATTN_LANES:
-        raise ValueError(
-            f"unknown paged attention lane {attn!r}; expected one of "
-            f"{list(PAGED_ATTN_LANES)}")
-
-
 def _layer_params(cfg: TransformerConfig, params, i: int):
     if cfg.scan_layers:
         return jax.tree.map(lambda a, i=i: a[i], params["blocks"])
@@ -306,24 +284,22 @@ def _layer_params(cfg: TransformerConfig, params, i: int):
 def _paged_forward_inplace(cfg: TransformerConfig, params, tokens, positions,
                            lengths, read_tables, write_tables, caches, impl,
                            valid):
-    """The in-place twin of the gathered-view programs: one K-token-window
-    forward over all S slots where each layer (1) writes the window's k/v
-    DIRECTLY into its pages — ``pool.at[page, offset].set`` through the
-    write table, write-before-attend, so XLA updates the donated pool in
-    place — and (2) attends through the page table via
-    ``ops.paged_attention`` (no ``_gather_row`` view, no whole-page
-    scatter-back). Layer math mirrors ``transformer._block`` exactly.
+    """The serving forward: one K-token-window pass over all S slots where
+    each layer (1) writes the window's k/v DIRECTLY into its pages —
+    ``pool.at[page, offset].set`` through the write table,
+    write-before-attend, so XLA updates the donated pool in place — and (2)
+    attends through the read table via ``ops.paged_attention(impl=)``.
+    Layer math mirrors ``transformer._block`` exactly.
 
     tokens/positions: [S, K]; lengths: [S] attention cursors;
     read_tables/write_tables: [S, P]. Positions on unallocated/shared pages
-    redirect to the garbage page through the write table, same contract
-    as the scatter-back lane. ``valid``: bool [S, K], the rows that carry a
-    live token (not a slot without a sequence, not a chunk's padding): the
-    expert layer routes the others nowhere. Returns (logits [S, K, vocab],
-    caches, moe): moe is None for a dense model, else ``{"counts": [L, E],
-    "routes": [L, S, K, k]}`` — the rows each layer's experts received
-    (they sum to valid rows x k a layer: no row is dropped) and the experts
-    each row chose."""
+    redirect to the garbage page through the write table. ``valid``: bool
+    [S, K], the rows that carry a live token (not a slot without a sequence,
+    not a chunk's padding): the expert layer routes the others nowhere.
+    Returns (logits [S, K, vocab], caches, moe): moe is None for a dense
+    model, else ``{"counts": [L, E], "routes": [L, S, K, k]}`` — the rows
+    each layer's experts received (they sum to valid rows x k a layer: no
+    row is dropped) and the experts each row chose."""
     T = caches[0].k.shape[1]
     P = read_tables.shape[1]
     x = params["embed"]["table"].astype(cfg.dtype)[tokens]
@@ -372,31 +348,28 @@ def _paged_outputs(first, caches, moe, moe_info: bool):
     return (first, caches, moe) if moe_info else (first, caches)
 
 
-def _check_moe_info(cfg: TransformerConfig, attn: str, moe_info: bool):
-    if moe_info and (cfg.mlp != "moe" or attn == "gather"):
-        raise ValueError(
-            "moe_info needs mlp='moe' and an in-place attention lane "
-            "('reference' or 'pallas'): the gathered-view lane runs rows "
-            "without a live sequence through the experts and counts nothing")
+def _check_moe_info(cfg: TransformerConfig, moe_info: bool):
+    if moe_info and cfg.mlp != "moe":
+        raise ValueError("moe_info needs mlp='moe': a dense model has no "
+                         "expert layer to count")
 
 
 def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
                             cursor, read_row, write_row,
                             caches: List[PagedKVCache], *,
-                            attn: str = "gather", moe_info: bool = False):
-    """``prefill_into_slot`` through a page table: the chunk lands at
+                            attn: str, moe_info: bool = False):
+    """One prefill chunk into ONE slot, through its page table. tokens:
+    [1, C] — the next C prompt tokens, zero-padded past ``real_len`` (so
+    every chunk size compiles to the same program). The chunk lands at
     logical positions [cursor, cursor + C) of the slot whose two rows these
-    are. cursor: int32 scalar, the tokens already resident (0 cold, the
-    spliced length after a prefix-cache hit); the caller advances it by
-    ``real_len``. read_row/write_row: [P] int32 — shared (prefix-cache)
-    pages appear in read_row but are redirected to the garbage page in
-    write_row, so their content is immutable here.
-
-    attn="gather" (the measured baseline): gather the slot's logical view
-    from the pool, run the identical chunk forward, scatter the view back
-    through ``write_row``. attn="reference"/"pallas": the in-place lane —
-    chunk k/v written straight into their pages, attention through the
-    page table (see ``_paged_forward_inplace``).
+    are: its k/v written straight into their pages, attention through the
+    read table (``_paged_forward_inplace``). cursor: int32 scalar, the
+    tokens already resident (0 cold, the spliced length after a
+    prefix-cache hit); the caller advances it by ``real_len``.
+    read_row/write_row: [P] int32 — shared (prefix-cache) pages appear in
+    read_row but are redirected to the garbage page in write_row, so their
+    content is immutable here. ``attn``: the implementation
+    ``ops.paged_attention`` runs ('reference' | 'pallas').
 
     Caller contract (scheduler-enforced): every page covering the REAL
     tokens [cursor, cursor + real_len) is allocated and OWNED (write_row
@@ -404,191 +377,85 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
     unallocated entries — their writes redirect to the garbage page and
     their reads are causally masked. cursor + C fits the logical view.
 
-    ``moe_info`` (in-place lanes, mlp='moe'): return a third value, the
-    expert layers' ``{"counts": [L, E], "routes": [L, 1, C, k]}``; the
-    chunk's padding past ``real_len`` is routed nowhere and not counted."""
-    _check_attn_lane(attn)
-    _check_moe_info(cfg, attn, moe_info)
-    if attn != "gather":
-        steps = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
-        logits, new_caches, moe = _paged_forward_inplace(
-            cfg, params, tokens, steps + cursor, jnp.reshape(cursor, (1,)),
-            read_row[None], write_row[None], caches, attn,
-            steps < real_len)
-        last = lax.dynamic_index_in_dim(logits[0], real_len - 1,
-                                        keepdims=False)
-        return _paged_outputs(last, new_caches, moe, moe_info)
-    T, HD = caches[0].k.shape[1:]
-    P = read_row.shape[0]
-    rows = []
-    for c in caches:
-        k, v = _gather_row(cfg, c, read_row)
-        rows.append(LayerKVCache(k=k, v=v, length=cursor))
-    positions = jnp.arange(tokens.shape[1])[None, :] + cursor
-    logits, new_rows = forward(cfg, params, tokens, positions=positions,
-                               kv_caches=rows)
+    Returns (logits [vocab] at the last REAL token, caches) — only the final
+    chunk's logits are meaningful; with ``moe_info`` (mlp='moe') a third
+    value, the expert layers' ``{"counts": [L, E], "routes": [L, 1, C,
+    k]}``; the chunk's padding past ``real_len`` is routed nowhere and not
+    counted."""
+    _check_moe_info(cfg, moe_info)
+    steps = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
+    logits, new_caches, moe = _paged_forward_inplace(
+        cfg, params, tokens, steps + cursor, jnp.reshape(cursor, (1,)),
+        read_row[None], write_row[None], caches, attn, steps < real_len)
     last = lax.dynamic_index_in_dim(logits[0], real_len - 1, keepdims=False)
-    # windowed scatter-back: the chunk writes only [cursor, cursor + C),
-    # which spans at most ceil(C/T)+1 pages — persisting just that window
-    # (instead of the whole P-page view) keeps the paged program's write
-    # traffic proportional to the chunk, like the contiguous arena's
-    # in-place dynamic_update_slice. Clipped window tails land on
-    # already-in-window pages (same content, harmless) and shared /
-    # unallocated entries redirect to the garbage page.
-    C = tokens.shape[1]
-    W = min(P, (C + T - 1) // T + 1)
-    widx = jnp.clip(cursor // T + jnp.arange(W), 0, P - 1)
-    dest = write_row[widx]
-    new_caches = [
-        PagedKVCache(k=c.k.at[dest].set(r.k.reshape(P, T, HD)[widx]),
-                     v=c.v.at[dest].set(r.v.reshape(P, T, HD)[widx]))
-        for c, r in zip(caches, new_rows)]
-    return last, new_caches
+    return _paged_outputs(last, new_caches, moe, moe_info)
 
 
 def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
                       cursors, read_tables, write_tables,
-                      caches: List[PagedKVCache], *, attn: str = "gather",
+                      caches: List[PagedKVCache], *, attn: str,
                       moe_info: bool = False):
-    """``slot_decode_step`` through page tables: one fixed-shape program
-    over the whole arena. tokens/active/cursors: [slots] int32; read_tables/
-    write_tables: [slots, P] int32. Row s's token is written at logical
-    position cursors[s] and attends [0, cursors[s]]; the caller advances
-    the cursors of its active rows by one. An inactive row attends nothing
-    and its logits are dropped, but it WRITES at its cursor like any other:
-    the caller's tables send that write to the garbage page, or to a
-    position the row's own sequence writes again before attending it.
+    """One fixed-shape decode step over the whole arena, through page
+    tables. tokens/active/cursors: [slots] int32; read_tables/write_tables:
+    [slots, P] int32. Row s's token is written at ``pool[page, offset]`` of
+    logical position cursors[s] and attends [0, cursors[s]] through the
+    read table; the caller advances the cursors of its active rows by one.
+    An inactive row attends nothing (it streams no page, and the expert
+    layer routes it nowhere) and its logits are dropped, but it WRITES at
+    its cursor like any other: the caller's tables send that write to the
+    garbage page, or to a position the row's own sequence writes again
+    before attending it. ``attn``: the implementation
+    ``ops.paged_attention`` runs ('reference' | 'pallas').
 
-    attn="gather" (the measured baseline): the per-slot math is the
-    contiguous path's vmapped single-sequence forward over the GATHERED
-    view, so an attended value can never differ from the contiguous
-    arena; the scatter through write_tables persists each slot's view
-    back into the pool (shared + unallocated entries land on the garbage
-    page). attn="reference"/"pallas": the in-place lane — each layer
-    writes the token's k/v at ``pool[page, offset]`` and attends through
-    the page table, never materializing the view (temperature-0 token
-    parity with the gather lane, asserted in tests/test_paged_attention).
-
-    Returns (logits [slots, vocab], caches); with ``moe_info`` (in-place
-    lanes, mlp='moe') a third value, the expert layers' ``{"counts":
-    [L, E], "routes": [L, slots, 1, k]}`` over the active rows."""
-    _check_attn_lane(attn)
-    _check_moe_info(cfg, attn, moe_info)
-    if attn != "gather":
-        # a slot the step marks inactive attends nothing (its logits are
-        # dropped): it streams no page, and the expert layer routes its
-        # row nowhere
-        logits, new_caches, moe = _paged_forward_inplace(
-            cfg, params, tokens[:, None], cursors[:, None],
-            jnp.where(active > 0, cursors, -1),
-            read_tables, write_tables, caches, attn, active[:, None] > 0)
-        return _paged_outputs(logits[:, 0], new_caches, moe, moe_info)
-    T, HD = caches[0].k.shape[1:]
-    slots, P = read_tables.shape
-
-    def one(tok, length, read_row, write_row):
-        rows = []
-        for c in caches:
-            k, v = _gather_row(cfg, c, read_row)
-            rows.append(LayerKVCache(k=k, v=v, length=length))
-        positions = rows[0].length + jnp.zeros((1, 1), jnp.int32)
-        logits, new_rows = forward(cfg, params, tok[None, None],
-                                   positions=positions, kv_caches=rows)
-        # windowed scatter-back: a decode step writes exactly ONE
-        # position (``length``), so only the page containing it needs to
-        # persist — inactive/shared entries redirect to the garbage page
-        pidx = jnp.clip(length // T, 0, P - 1)
-        dest = write_row[pidx]
-        outs_k = [lax.dynamic_index_in_dim(
-            r.k[0].reshape(P, T, HD), pidx, keepdims=False)
-            for r in new_rows]
-        outs_v = [lax.dynamic_index_in_dim(
-            r.v[0].reshape(P, T, HD), pidx, keepdims=False)
-            for r in new_rows]
-        return logits[0, -1], dest, (outs_k, outs_v)
-
-    logits, dest, (new_k, new_v) = jax.vmap(one, in_axes=(0, 0, 0, 0))(
-        tokens, cursors, read_tables, write_tables)
-    new_caches = [PagedKVCache(k=c.k.at[dest].set(nk), v=c.v.at[dest].set(nv))
-                  for c, nk, nv in zip(caches, new_k, new_v)]
-    return logits, new_caches
+    Returns (logits [slots, vocab], caches); with ``moe_info`` (mlp='moe') a
+    third value, the expert layers' ``{"counts": [L, E], "routes": [L,
+    slots, 1, k]}`` over the active rows."""
+    _check_moe_info(cfg, moe_info)
+    logits, new_caches, moe = _paged_forward_inplace(
+        cfg, params, tokens[:, None], cursors[:, None],
+        jnp.where(active > 0, cursors, -1),
+        read_tables, write_tables, caches, attn, active[:, None] > 0)
+    return _paged_outputs(logits[:, 0], new_caches, moe, moe_info)
 
 
 def paged_verify_step(cfg: TransformerConfig, params, tokens, active,
                       cursors, read_tables, write_tables,
-                      caches: List[PagedKVCache], *, attn: str = "gather",
+                      caches: List[PagedKVCache], *, attn: str,
                       moe_info: bool = False):
     """Speculative-decoding verify: score K candidate tokens per slot in
-    ONE fixed-shape call over the slots axis (ISSUE 18). active: [slots]
-    int32, 0 for a row without a live sequence (the in-place lanes attend
-    nothing there, the gather lane takes no notice: such a row's logits
-    are dropped either way). cursors: [slots] int32, as in
-    ``paged_decode_step``. tokens:
-    [slots, K] int32 — each slot's [next_token, d_1..d_{K-1}] placed at
-    logical positions [cursor, cursor + K); logits[s, j] is the target
-    model's distribution over the token FOLLOWING position cursor + j,
-    i.e. the exact distribution the sequential ``paged_decode_step`` loop
-    would produce after accepting d_1..d_j. The per-slot math is the same
-    gathered-view forward as the decode step with a K-token window —
-    mask_bias always spans the full fixed view width, so per-query
-    reduction order (and therefore every attended value) is bit-identical
-    to K sequential single-token steps. The in-place lanes
-    (attn="reference"/"pallas") keep that property within themselves: each
-    query row reduces over pages in ascending order under a full-width
-    mask, exactly the reduction a K=1 in-place decode performs.
+    ONE fixed-shape call over the slots axis. active: [slots] int32, the
+    window rows of each slot that carry a token (0 for a row without a live
+    sequence, which attends nothing and whose logits are dropped). cursors:
+    [slots] int32, as in ``paged_decode_step``. tokens: [slots, K] int32 —
+    each slot's [next_token, d_1..d_{K-1}] placed at logical positions
+    [cursor, cursor + K); logits[s, j] is the target model's distribution
+    over the token FOLLOWING position cursor + j, i.e. the distribution the
+    sequential ``paged_decode_step`` loop would produce after accepting
+    d_1..d_j: each query row reduces over blocks of pages in ascending order
+    under a full-width mask, exactly the reduction a K=1 decode performs.
+    ``attn``: the implementation ``ops.paged_attention`` runs ('reference' |
+    'pallas').
 
     How far a cursor advances is the caller's decision, made afterwards
     (accept-prefix + corrected resample): accepted slots move to cursor +
     accepted + 1, rejected tails are rewound by simply not advancing past
-    them. KV for all K positions IS written through the
-    windowed scatter — rejected positions hold stale values that the next
-    round's writes overwrite before anything attends to them (the same
-    update-before-attend invariant the arena already relies on); shared /
-    unallocated write entries redirect to the garbage page, so a verify
-    can never scribble on prefix-cache pages.
+    them. KV for all K positions IS written — rejected positions hold stale
+    values that the next round's writes overwrite before anything attends
+    to them (the update-before-attend invariant); shared / unallocated
+    write entries redirect to the garbage page, so a verify can never
+    scribble on prefix-cache pages.
 
-    Returns (logits [slots, K, vocab], caches); with ``moe_info`` (in-place
-    lanes, mlp='moe') a third value, the expert layers' ``{"counts":
-    [L, E], "routes": [L, slots, K, k]}`` over the used rows."""
-    _check_attn_lane(attn)
-    _check_moe_info(cfg, attn, moe_info)
-    if attn != "gather":
-        K = tokens.shape[1]
-        steps = jnp.arange(K, dtype=jnp.int32)[None]
-        logits, new_caches, moe = _paged_forward_inplace(
-            cfg, params, tokens, cursors[:, None] + steps,
-            jnp.where(active > 0, cursors, -K),
-            read_tables, write_tables, caches, attn,
-            steps < active[:, None])
-        return _paged_outputs(logits, new_caches, moe, moe_info)
-    T, HD = caches[0].k.shape[1:]
-    slots, P = read_tables.shape
+    Returns (logits [slots, K, vocab], caches); with ``moe_info``
+    (mlp='moe') a third value, the expert layers' ``{"counts": [L, E],
+    "routes": [L, slots, K, k]}`` over the used rows."""
+    _check_moe_info(cfg, moe_info)
     K = tokens.shape[1]
-
-    def one(toks, length, read_row, write_row):
-        rows = []
-        for c in caches:
-            k, v = _gather_row(cfg, c, read_row)
-            rows.append(LayerKVCache(k=k, v=v, length=length))
-        positions = jnp.arange(K)[None, :] + rows[0].length
-        logits, new_rows = forward(cfg, params, toks[None, :],
-                                   positions=positions, kv_caches=rows)
-        # windowed scatter-back: the K-token window writes
-        # [cursor, cursor + K), at most ceil(K/T)+1 pages — same idiom as
-        # the prefill chunk's scatter
-        W = min(P, (K + T - 1) // T + 1)
-        w0 = rows[0].length // T
-        widx = jnp.clip(w0 + jnp.arange(W), 0, P - 1)
-        dest = write_row[widx]
-        outs_k = [r.k[0].reshape(P, T, HD)[widx] for r in new_rows]
-        outs_v = [r.v[0].reshape(P, T, HD)[widx] for r in new_rows]
-        return logits[0], dest, (outs_k, outs_v)
-
-    logits, dest, (new_k, new_v) = jax.vmap(one, in_axes=(0, 0, 0, 0))(
-        tokens, cursors, read_tables, write_tables)
-    new_caches = [PagedKVCache(k=c.k.at[dest].set(nk), v=c.v.at[dest].set(nv))
-                  for c, nk, nv in zip(caches, new_k, new_v)]
-    return logits, new_caches
+    steps = jnp.arange(K, dtype=jnp.int32)[None]
+    logits, new_caches, moe = _paged_forward_inplace(
+        cfg, params, tokens, cursors[:, None] + steps,
+        jnp.where(active > 0, cursors, -K),
+        read_tables, write_tables, caches, attn, steps < active[:, None])
+    return _paged_outputs(logits, new_caches, moe, moe_info)
 
 
 @partial(jax.jit, static_argnums=(0, 4, 5, 6))

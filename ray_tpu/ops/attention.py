@@ -1,33 +1,16 @@
 """Attention dispatcher: picks the Pallas flash kernel on TPU (or when forced),
-the XLA reference otherwise. Single entry point for all models.
-
-Also home of the PAGED-attention lane resolver (ISSUE 20): the serve
-scheduler's decode/verify/prefill programs pick between the in-place paged
-lanes (``ops.paged_attention``) and the measured-baseline gathered-view
-path via ``RAY_TPU_SERVE_PAGED_ATTN`` — resolved here so every consumer
-rejects unknown/falsy values identically and loudly."""
+the XLA reference otherwise. Single entry point for all models."""
 
 from __future__ import annotations
 
-import logging
 import math
 from typing import Optional
 
 import jax
 
-from ray_tpu.ops._pallas import should_interpret
 from ray_tpu.ops.flash_attention import flash_attention, reference_attention
 
-logger = logging.getLogger(__name__)
-
 ATTN_IMPLS = ("auto", "flash", "reference")
-
-# "auto" -> the Pallas paged kernel on TPU, the pure-JAX in-place reference
-# elsewhere; "gather" keeps the original gathered-view programs (the
-# measured baseline — selectable like collective_algo="kv", never a silent
-# fallback). Resolution happens ONCE at scheduler build, so stats() always
-# names the real lane.
-PAGED_ATTN_CHOICES = ("auto", "pallas", "reference", "gather")
 
 
 def attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] = None,
@@ -93,48 +76,3 @@ def _flash_on_mesh(q, k, v, sm_scale, causal):
         lambda q, k, v: flash_attention(q, k, v, sm_scale, causal),
         in_specs=(spec, spec, spec), out_specs=spec,
         axis_names=set(free), check_vma=False)(q, k, v)
-
-
-def resolve_paged_attn_lane(choice: Optional[str] = None,
-                            cfg=None) -> str:
-    """Resolve the serve paged-attention lane to a concrete program lane.
-
-    choice=None reads the ``serve_paged_attn`` config flag
-    (``RAY_TPU_SERVE_PAGED_ATTN``). Unknown values — including explicit
-    falsy spellings like "0"/"" — are rejected loudly (the falsy-zero
-    lesson: 0 never silently means a default lane). Returns one of
-    'pallas' | 'reference' | 'gather'.
-
-    Given the model's ``cfg`` (its ``kv_heads`` and ``head_dim``), a
-    ``pallas`` lane that Mosaic would have to compile is checked against
-    ``pallas_shape_problem``: an explicit
-    'pallas' raises here, at scheduler build; 'auto' takes 'reference' by
-    that same stated shape rule (logged, and named in the scheduler's
-    stats). Interpreted kernels (off-TPU) take any shape.
-    """
-    if choice is None:
-        from ray_tpu._private.config import global_config
-
-        choice = global_config().serve_paged_attn
-    if choice not in PAGED_ATTN_CHOICES:
-        raise ValueError(
-            f"unknown paged attention lane {choice!r} (serve_paged_attn / "
-            f"RAY_TPU_SERVE_PAGED_ATTN); expected one of "
-            f"{list(PAGED_ATTN_CHOICES)}")
-    lane = choice
-    if choice == "auto":
-        lane = "pallas" if jax.default_backend() == "tpu" else "reference"
-    if lane == "pallas" and cfg is not None and not should_interpret():
-        from ray_tpu.ops.paged_attention import pallas_shape_problem
-
-        problem = pallas_shape_problem(cfg.kv_heads, cfg.head_dim)
-        if problem and choice == "pallas":
-            raise ValueError(
-                f"paged attention lane 'pallas' cannot compile for this "
-                f"model on a TPU: {problem}")
-        if problem:
-            logger.warning("paged attention: auto takes the 'reference' "
-                           "lane, the Pallas kernel cannot compile here "
-                           "(%s)", problem)
-            lane = "reference"
-    return lane

@@ -1,13 +1,13 @@
 """Paged attention: flash-style online-softmax THROUGH the page table.
 
-The paged KV arena (models/decode.py, ISSUE 13) stores each layer's cache as
-a pool ``[num_pages, page_tokens, Hkv * D]`` (one token's kv heads joined on
-the lane axis) plus per-slot page tables. The
-original decode/verify programs materialize every slot's full logical
-``[pages_per_slot * page_tokens]`` view with a gather before attending — an
-O(arena_len)·layers·slots copy per single-token step, so decode cost scales
-with pool PROVISIONING rather than the tokens actually attended. This module
-computes attention directly against the pool, in BLOCKS of whole page rows:
+The paged KV arena (models/decode.py) stores each layer's cache as a pool
+``[num_pages, page_tokens, Hkv * D]`` (one token's kv heads joined on the
+lane axis) plus per-slot page tables. This module computes attention
+directly against the pool, in BLOCKS of whole page rows, so a step's cost
+follows the tokens attended and not the pool's provisioning, and it owns the
+choice of implementation: ``resolve_impl`` picks one from the platform and
+the model's shapes, ``streamed_tokens`` says what each fetches for a call
+(the scheduler's counters).
 
   * A block is ``B`` consecutive entries of a slot's page table, ``B * T``
     tokens; scores and the online-softmax update are per block
@@ -37,7 +37,7 @@ computes attention directly against the pool, in BLOCKS of whole page rows:
     math (same blocks in the same order, same operand dtypes, same
     online-softmax update, same -1e30 mask): one fori_loop over blocks,
     trip count = the batch max of blocks. This is the parity oracle for
-    the kernel and the production lane off-TPU.
+    the kernel and what serves off-TPU.
 
 Mask semantics match ``LayerKVCache.mask_bias``: query row ``i`` of slot
 ``s`` sits at logical position ``lengths[s] + i`` and may attend logical
@@ -49,7 +49,7 @@ allocation point at the reserved garbage page 0; every position they cover
 is ``> lengths[s] + i``, so the mask zeroes them EXACTLY (exp(-1e30 - m)
 underflows to 0.0f) — garbage content can never leak into an attended
 value, and masked positions contribute bit-exact zeros to the online
-accumulator (the same invariant the gathered-view lane relies on).
+accumulator.
 
 The partly filled last block: a masked score gives p = 0, and 0 times
 uninitialised VMEM (NaN) would be NaN. So EVERY entry of a block is fetched
@@ -69,8 +69,9 @@ sequential decode steps.
 from __future__ import annotations
 
 import functools
+import logging
 import math
-from typing import Optional, Tuple
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -80,9 +81,45 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops._pallas import should_interpret
 
+logger = logging.getLogger(__name__)
+
 NEG_INF = -1e30
 
 PAGED_ATTN_IMPLS = ("pallas", "reference")
+
+
+def resolve_impl(cfg, impl: Optional[str] = None) -> str:
+    """The implementation the serving programs run for the model ``cfg``
+    (its ``kv_heads`` and ``head_dim``): the kernel on a TPU, the reference
+    elsewhere. On a TPU whose compiler cannot take the model's pool shape
+    (``pallas_shape_problem``) it is the reference by that stated rule,
+    logged. Resolved ONCE, at scheduler build, so ``stats()`` names what
+    really runs.
+
+    An explicit ``impl`` ('reference' | 'pallas') is taken as given: the
+    tests run the kernel interpreted on a CPU. Anything else — a falsy
+    spelling like "0" or "" too — is refused, and an explicit 'pallas' that
+    Mosaic would have to compile for a shape it cannot take raises here
+    instead of at the first decode step."""
+    if impl is not None and impl not in PAGED_ATTN_IMPLS:
+        raise ValueError(
+            f"unknown paged attention impl {impl!r}; expected one of "
+            f"{list(PAGED_ATTN_IMPLS)}, or None for the platform's")
+    chosen = impl
+    if chosen is None:
+        chosen = "pallas" if jax.default_backend() == "tpu" else "reference"
+    if chosen == "pallas" and not should_interpret():
+        problem = pallas_shape_problem(cfg.kv_heads, cfg.head_dim)
+        if problem and impl is not None:
+            raise ValueError(
+                f"paged attention impl 'pallas' cannot compile for this "
+                f"model on a TPU: {problem}")
+        if problem:
+            logger.warning("paged attention: the 'reference' implementation "
+                           "serves, the Pallas kernel cannot compile here "
+                           "(%s)", problem)
+            chosen = "reference"
+    return chosen
 
 
 def paged_attention(q, k_pool, v_pool, tables, lengths, *,
@@ -103,8 +140,7 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
     if impl not in PAGED_ATTN_IMPLS:
         raise ValueError(
             f"unknown paged attention impl {impl!r}; expected one of "
-            f"{list(PAGED_ATTN_IMPLS)} (the 'gather' lane is not an op — "
-            "models/decode.py dispatches it before reaching here)")
+            f"{list(PAGED_ATTN_IMPLS)}")
     if q.shape[0] != tables.shape[0] or q.shape[0] != lengths.shape[0]:
         raise ValueError(
             f"slot axis mismatch: q {q.shape}, tables {tables.shape}, "
@@ -137,12 +173,12 @@ def tile_sizes(qk: int, group: int, page_tokens: int, pages_per_slot: int,
     """(pages per block, query tokens per tile) for a K = ``qk`` window over
     a pool whose token rows hold ``row_bytes`` (all kv heads of K or of V).
 
-    Both lanes and the scheduler's counters read the blocking from here, and
-    it follows from static shapes alone: a long window is cut into tiles of
-    about ``_MAX_Q_ROWS`` query rows (whole multiples of the ``_SUB_ROWS``
-    one matmul takes); a block is the largest power of two of pages that
-    keeps its K and V rows within ``_BLOCK_BYTES``, its tokens within
-    ``_MAX_BLOCK_TOKENS`` and itself within the page table."""
+    Both implementations and ``streamed_tokens`` read the blocking from
+    here, and it follows from static shapes alone: a long window is cut into
+    tiles of about ``_MAX_Q_ROWS`` query rows (whole multiples of the
+    ``_SUB_ROWS`` one matmul takes); a block is the largest power of two of
+    pages that keeps its K and V rows within ``_BLOCK_BYTES``, its tokens
+    within ``_MAX_BLOCK_TOKENS`` and itself within the page table."""
     n_tiles = -(-qk * group // _MAX_Q_ROWS)
     q_tile = -(-qk // n_tiles)
     if q_tile * group > _SUB_ROWS:  # whole matmuls of _SUB_ROWS rows
@@ -151,6 +187,36 @@ def tile_sizes(qk: int, group: int, page_tokens: int, pages_per_slot: int,
     pages = min(_BLOCK_BYTES // (2 * page_tokens * row_bytes),
                 _MAX_BLOCK_TOKENS // page_tokens, pages_per_slot)
     return 1 << (max(pages, 1).bit_length() - 1), q_tile
+
+
+def streamed_tokens(impl: str, qk: int, cursors: List[int], idle_rows: int,
+                    group: int, page_tokens: int, pages_per_slot: int,
+                    row_bytes: int) -> Tuple[int, int]:
+    """(attended, fetched) token positions of one ``[S, K = qk]`` call, per
+    layer: ``tile_sizes``' twin on the host, for counters. ``cursors``: the
+    attention cursor of every row that attends its window; ``idle_rows``:
+    the call's other rows, which attend nothing. Both implementations
+    stream whole BLOCKS of pages: the kernel each row's own blocks, once per
+    query tile, up to the tile's last position (an idle row none); the
+    reference every row, idle ones too, over the longest row's blocks.
+    Attended (the positions a row, or a query tile of the kernel, may
+    attend) over fetched is the block fill share."""
+    pages, q_tile = tile_sizes(qk, group, page_tokens, pages_per_slot,
+                               row_bytes)
+
+    def blocks(upto: int) -> int:
+        return min(-(-upto // (pages * page_tokens)),
+                   -(-pages_per_slot // pages))
+
+    if impl == "reference":
+        attended = sum(c + qk for c in cursors)
+        fetched = ((len(cursors) + idle_rows) * blocks(max(cursors) + qk)
+                   if cursors else 0)
+    else:  # each query tile streams the blocks up to its own end
+        ends = [min(e, qk) for e in range(q_tile, qk + q_tile, q_tile)]
+        attended = sum(c + e for e in ends for c in cursors)
+        fetched = sum(blocks(c + e) for e in ends for c in cursors)
+    return attended, fetched * pages * page_tokens
 
 
 def _vmem_bytes(shape, dtype) -> int:

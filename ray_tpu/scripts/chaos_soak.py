@@ -1494,7 +1494,6 @@ def run_serve_chaos(
         while time.monotonic() < deadline and (clean < 4 or hits_seen == 0):
             st = h.scheduler_stats.remote().result(timeout=30)
             assert st["mode"] == "continuous", st
-            assert st["kv_layout"] == "paged", st
             hits_seen = max(hits_seen, st["prefix_hits"])
             attn_bytes_seen = max(attn_bytes_seen, st["attn_bytes_moved"])
             if (st["active_slots"] == 0 and st["radix_active_refs"] == 0
@@ -1509,7 +1508,7 @@ def run_serve_chaos(
         assert hits_seen > 0, (
             "the shared-prefix burst never hit the radix cache on any "
             f"sampled replica: {st}")
-        assert st["attn_lane"] in ("gather", "reference", "pallas"), st
+        assert st["attn_lane"] in ("reference", "pallas"), st
         assert attn_bytes_seen > 0, (
             "no sampled replica moved attention bytes — the paged "
             f"attention lane never engaged: {st}")

@@ -1115,180 +1115,6 @@ def run_all(budget_s: float = 2.0) -> List[Dict[str, float]]:
                     "value": round(serial_s / max(lap_s, 1e-9), 2),
                     "unit": "x"})
 
-    # -- serve: continuous (iteration-level) batching vs the request-level
-    # @serve.batch flush-and-drain baseline, same open-loop offered load
-    # (Poisson arrivals, mixed prompt lengths, heavy-tailed budgets). The
-    # guard asserts the iteration-level scheduler actually engaged — a
-    # silent fall-back to flush-and-drain can't vacuously pass.
-    import asyncio
-
-    from ray_tpu.serve.llm import LLMServerImpl
-
-    # budget-scaled (the test_core smoke runs budget_s=0.2: a handful of
-    # requests and few distinct prompt lengths so the request-level
-    # baseline's per-shape compiles don't dominate the smoke)
-    full = budget_s >= 1.0
-    sv_n = 48 if full else 10
-    sv_lens = [3, 9, 18, 30] if full else [3, 9]
-    sv_cap = 24 if full else 8
-    sv_slots = 4 if full else 2
-
-    def bench_serve_mode(mode):
-        rng = np.random.default_rng(0)
-        arrivals = np.cumsum(rng.exponential(1.0 / 60.0, size=sv_n))
-        lens = rng.choice(sv_lens, size=sv_n)
-        load = [(float(a), "x" * int(L),
-                 int(min(sv_cap, 1 + round(3 * rng.pareto(1.5)))))
-                for a, L in zip(arrivals, lens)]
-        srv = LLMServerImpl(preset="llama_debug", max_new_tokens=sv_cap,
-                            scheduler=mode, slots=sv_slots, prefill_chunk=8,
-                            share_weights=False, max_batch_size=sv_slots)
-        try:
-            stream = mode == "continuous"
-
-            async def drive():
-                loop = asyncio.get_running_loop()
-                t_start = loop.time()
-                out = {"tokens": 0, "ttfts": []}
-
-                async def one(at, prompt, budget):
-                    await asyncio.sleep(
-                        max(0.0, t_start + at - loop.time()))
-                    t0 = time.perf_counter()
-                    if stream:
-                        gen = await srv({"prompt": prompt, "stream": True,
-                                         "max_new_tokens": budget})
-                        first = None
-                        async for _ in gen:
-                            first = first or time.perf_counter()
-                            out["tokens"] += 1
-                    else:
-                        r = await srv({"prompt": prompt,
-                                       "max_new_tokens": budget})
-                        first = time.perf_counter()
-                        out["tokens"] += r["num_tokens"]
-                    out["ttfts"].append(first - t0)
-
-                t0 = time.perf_counter()
-                await asyncio.gather(*[one(*req) for req in load])
-                out["wall"] = time.perf_counter() - t0
-                return out
-
-            if full:
-                asyncio.run(drive())  # warm replay: compile every shape
-            out = asyncio.run(drive())
-            if mode == "continuous":
-                st = srv.scheduler_stats()
-                assert st["mode"] == "continuous", st
-                assert st["admitted_mid_flight"] > 0, (
-                    "iteration-level admission never engaged — the probe "
-                    f"measured flush-and-drain twice: {st}")
-                assert st["max_active_slots"] >= 2, st
-            return (out["tokens"] / out["wall"],
-                    float(np.percentile(out["ttfts"], 99)))
-        finally:
-            srv.shutdown()
-
-    cont_tps, cont_p99 = bench_serve_mode("continuous")
-    base_tps, base_p99 = bench_serve_mode("batch")
-    record("serve_continuous_tokens_per_sec", cont_tps, unit="tokens/s")
-    record("serve_request_batch_tokens_per_sec", base_tps,
-           unit="tokens/s")
-    results.append({"benchmark": "serve_continuous_vs_request_batching",
-                    "value": round(cont_tps / max(base_tps, 1e-9), 2),
-                    "unit": "x"})
-    results.append({"benchmark": "serve_continuous_p99_ttft_improvement",
-                    "value": round(base_p99 / max(cont_p99, 1e-9), 1),
-                    "unit": "x"})
-
-    # -- paged attention lanes (ISSUE 20): one fixed-shape decode step on
-    # an arena provisioned 4x beyond the live tokens — the gathered-view
-    # baseline materializes every slot's full logical view per layer per
-    # step (cost tracks PROVISIONING), the in-place lane attends through
-    # the page table (cost tracks live pages). Same params, same caches
-    # geometry, greedy parity asserted; the engagement guard compares the
-    # two arms' compiled HLO — a silently ignored lane kwarg would time
-    # the same program twice and record a vacuous ~1x.
-    import functools as _functools
-
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.models.decode import init_paged_caches, paged_decode_step
-    from ray_tpu.models.transformer import TransformerConfig, init_params
-
-    pa_cfg = TransformerConfig(
-        vocab_size=128, num_layers=4, embed_dim=128, num_heads=4,
-        num_kv_heads=2, mlp_dim=128, max_seq_len=2048, dtype=jnp.float32,
-        param_dtype=jnp.float32, scan_layers=False, remat=False)
-    pa_params = init_params(pa_cfg, jax.random.PRNGKey(0))
-    PA_S, PA_T = 8, 16
-    pa_iters = 50 if full else 4
-
-    def pa_step_ms(lane, act_pages, pages_per_slot, check_hlo=None):
-        kv_pages = PA_S * pages_per_slot + 1  # the serve auto-sizing rule
-        caches = init_paged_caches(pa_cfg, kv_pages, PA_T, pages_per_slot)
-        # every step at the same cursors: the time of a step at a fixed
-        # number of live tokens
-        cur = jnp.asarray([act_pages * PA_T - 1 - (s % 3)
-                           for s in range(PA_S)], jnp.int32)
-        tables = np.zeros((PA_S, pages_per_slot), np.int32)
-        pid = 1
-        for s in range(PA_S):
-            for j in range(min(act_pages + 1, pages_per_slot)):
-                tables[s, j] = pid
-                pid += 1
-        tj = jnp.asarray(tables)
-        step = jax.jit(_functools.partial(paged_decode_step, pa_cfg,
-                                          attn=lane),
-                       donate_argnums=(6,))
-        toks = jnp.zeros(PA_S, jnp.int32)
-        act = jnp.ones(PA_S, jnp.int32)
-        if check_hlo is not None:
-            # unoptimized lowered text: enough to prove the arms trace
-            # different programs, without paying a second XLA compile
-            check_hlo[lane] = step.lower(
-                pa_params, toks, act, cur, tj, tj, caches).as_text()
-        lg, caches = step(pa_params, toks, act, cur, tj, tj, caches)
-        jax.block_until_ready(lg)
-        first = np.asarray(lg).argmax(-1)
-        best = float("inf")
-        for _ in range(3 if full else 1):
-            t0 = time.perf_counter()
-            for _ in range(pa_iters):
-                lg, caches = step(pa_params, toks, act, cur, tj, tj, caches)
-            jax.block_until_ready(lg)
-            best = min(best, (time.perf_counter() - t0) / pa_iters * 1e3)
-        return best, first
-
-    hlo = {}
-    # 4x overprovision: 128 live tokens per slot on a 512-token arena
-    g_ms, g_tok = pa_step_ms("gather", 8, 32, check_hlo=hlo)
-    i_ms, i_tok = pa_step_ms("reference", 8, 32, check_hlo=hlo)
-    assert hlo["gather"] != hlo["reference"], (
-        "attn lane kwarg ignored — both arms compiled the same program")
-    assert np.array_equal(g_tok, i_tok), (
-        "paged attention lanes diverged at temperature 0")
-    record("serve_paged_attn_gather_step", g_ms, unit="ms")
-    record("serve_paged_attn_inplace_step", i_ms, unit="ms")
-    results.append({"benchmark": "paged_attn_speedup",
-                    "value": round(g_ms / max(i_ms, 1e-9), 2),
-                    "unit": "x"})
-    if full:
-        # pool-scaling probe: FIXED live tokens (2 pages/slot), arena
-        # provisioning swept 8 -> 128 pages/slot — the gather lane's step
-        # time must grow with provisioning while the in-place lane stays
-        # flat (growth ratio over the 16x sweep, ~1.0 = flat)
-        sweep = {}
-        for lane in ("gather", "reference"):
-            lo, _ = pa_step_ms(lane, 2, 8)
-            hi, _ = pa_step_ms(lane, 2, 128)
-            sweep[lane] = hi / max(lo, 1e-9)
-        results.append({"benchmark": "paged_attn_gather_pool_scaling",
-                        "value": round(sweep["gather"], 1), "unit": "x"})
-        results.append({"benchmark": "paged_attn_inplace_pool_scaling",
-                        "value": round(sweep["reference"], 1), "unit": "x"})
-
     # -- Podracer RL: R runner actors + 1 learner ACTOR in the dynamic
     # loop (every rollout an object-store put/get through the driver,
     # every update an actor round-trip, weights re-synced per interval)
@@ -1382,8 +1208,7 @@ def main(argv=None) -> None:
 
     import ray_tpu
 
-    # one process, one chip: THIS process runs every jax probe itself
-    # (serve probes instantiate LLMServerImpl here, not build_app), and
+    # one process, one chip: THIS process runs every jax probe itself, and
     # no task or actor of this suite leases a TPU, so its workers are
     # all held to the CPU backend and never contend for the device
     ray_tpu.init(num_cpus=args.num_cpus,

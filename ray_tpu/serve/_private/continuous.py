@@ -1,67 +1,60 @@
 """Continuous (iteration-level) batching scheduler for LLM serve replicas.
 
-Replaces the flush-and-drain loop of ``@serve.batch`` for the LLM path
-(ISSUE 9, ROADMAP item 4): instead of admitting a request batch, running
-prefill plus the ENTIRE ``max_new_tokens`` decode loop, and only then
-looking at the queue again, the scheduler owns a slotted KV-cache arena of
-``slots`` sequence slots (``models.decode.SlotKVCache``) and drives ONE
-fixed-shape jitted decode step over the whole arena per iteration:
+The scheduler owns ``slots`` sequence slots over a PAGED KV pool
+(``models.decode.PagedKVCache``: pages of ``page_tokens`` tokens, a page
+table a slot) and drives ONE fixed-shape jitted decode step over all slots
+per iteration:
 
   * new requests are admitted into free slots *between* decode iterations
     and prefilled in ``prefill_chunk``-token chunks (one chunk per
     iteration), so a long prompt can never stall in-flight decodes;
-  * finished / EOS / cancelled sequences retire their slot immediately —
-    the freed slot is re-admitted on the very next iteration;
+  * finished / EOS / cancelled sequences retire their slot (and pages)
+    immediately — the freed slot is re-admitted on the very next iteration;
   * every sampled token streams out to its request's asyncio queue the
     iteration it is produced, so streaming and non-streaming consumers ride
     the same batched program (no per-stream single-sequence decode loops).
 
-This is the serving analog of PR 8's 1F1B pipeline loop: the device-side
-program shape is compiled once and the host-side loop only decides *which*
-sequences occupy which slots. All jax work runs on the scheduler's own
+The device-side program shapes are compiled once (``paged_prefill_chunk``,
+``paged_decode_step``) and the host-side loop only decides *which* sequences
+occupy which slots and pages. All jax work runs on the scheduler's own
 thread — the replica's asyncio event loop only ever touches queues.
 
-ISSUE 13 rebuilds the arena as a PAGED pool (``kv_layout="paged"``, the
-default): KV storage is a pool of ``page_tokens``-sized pages
-(``models.decode.PagedKVCache``), each slot owns a page table instead of a
-contiguous worst-case ``arena_len`` range, and the same two compiled
-programs gather/scatter through the tables at fixed shapes — so long/idle
-sequences stop reserving memory they never use and a replica admits far
-more concurrent sequences at the same arena bytes. The device holds the
+There is one KV layout and one path to the kernel. A slot owns a page table
+instead of a contiguous worst-case ``arena_len`` range, so long/idle
+sequences reserve no memory they never use; each program writes the new
+tokens' k/v into their pages and attends through the table
+(``ops.paged_attention``, whose ``resolve_impl`` picks the kernel on a TPU
+and the pure-JAX reference elsewhere, once, at build). The device holds the
 pages and nothing else: each slot's cursor is ``_Seq.cursor`` here on the
 host, handed to every program as an argument beside the tables, so an
 admission, a retirement or a rejected draft runs no device program. On top
 of paging a PREFIX/RADIX CACHE (``serve/_private/paging.RadixCache``) makes
 admitting a request whose prompt shares a cached prefix a page-table
 splice + cursor jump instead of a re-prefill; eviction is LRU over
-refcount-0 nodes under arena pressure. ``kv_layout="contiguous"`` keeps the
-PR-9 arena as the measured baseline (the collective layer's ``algo="kv"``
-idiom).
+refcount-0 nodes under arena pressure.
 
-ISSUE 18 adds the FLEET phase on top: (1) the radix cache's chain-hash
-digest is exported through ``prefix_digest()`` so the router can steer
-prompts to the replica already holding their prefix; (2) a request that
-arrives with a ``fleet_hint`` (holder replica handle + matched depth)
-PULLS the matched prefix pages from the holder before admission — the
-pull runs on a dedicated worker thread (the scheduler thread never
-blocks on a peer), the pulled KV is spliced into the local arena +
-radix tree, and admission then hits it like any local prefix; a failed
-or timed-out pull falls back to a cold prefill, bit-identical by
-construction; (3) speculative decoding: a ``speculative.Drafter``
-proposes up to ``spec_k`` tokens per slot and ONE fixed-shape
-``paged_verify_step`` call (the third and only third compiled program)
-scores them all, with exact accept-prefix + corrected-resample
-semantics (temperature-0 output is the sequential greedy path's, token
-for token).
+The FLEET phase on top: (1) the radix cache's chain-hash digest is exported
+through ``prefix_digest()`` so the router can steer prompts to the replica
+already holding their prefix; (2) a request that arrives with a
+``fleet_hint`` (holder replica handle + matched depth) PULLS the matched
+prefix pages from the holder before admission — the pull runs on a
+dedicated worker thread (the scheduler thread never blocks on a peer), the
+pulled KV is spliced into the local arena + radix tree, and admission then
+hits it like any local prefix; a failed or timed-out pull falls back to a
+cold prefill, bit-identical by construction; (3) speculative decoding: a
+``speculative.Drafter`` proposes up to ``spec_k`` tokens per slot and ONE
+fixed-shape ``paged_verify_step`` call (the third and only third compiled
+program) scores them all, with exact accept-prefix + corrected-resample
+semantics (temperature-0 output is the sequential greedy path's, token for
+token).
 
-Knobs: ``RAY_TPU_SERVE_SLOTS`` (arena width), ``RAY_TPU_SERVE_PREFILL_CHUNK``
-(prefill chunk tokens), ``RAY_TPU_SERVE_KV_LAYOUT``,
-``RAY_TPU_SERVE_PAGE_TOKENS``, ``RAY_TPU_SERVE_KV_PAGES`` (0 = size the
-pool to the contiguous worst case), ``RAY_TPU_SERVE_PREFIX_CACHE``,
-``RAY_TPU_SERVE_MIGRATION_BUDGET`` (pages per cross-replica pull),
-``RAY_TPU_SERVE_SPEC_K`` (draft tokens per verify round),
-``RAY_TPU_SERVE_DRAFTER`` (drafter preset; ``"self"`` shares the target's
-weights); all overridable per-deployment via LLMServer init.
+Knobs: ``RAY_TPU_SERVE_SLOTS`` (slots), ``RAY_TPU_SERVE_PREFILL_CHUNK``
+(prefill chunk tokens), ``RAY_TPU_SERVE_PAGE_TOKENS``,
+``RAY_TPU_SERVE_KV_PAGES`` (0 = size the pool to every slot's worst case),
+``RAY_TPU_SERVE_PREFIX_CACHE``, ``RAY_TPU_SERVE_MIGRATION_BUDGET`` (pages
+per cross-replica pull), ``RAY_TPU_SERVE_SPEC_K`` (draft tokens per verify
+round), ``RAY_TPU_SERVE_DRAFTER`` (drafter preset; ``"self"`` shares the
+target's weights); all overridable per-deployment via LLMServer init.
 """
 
 from __future__ import annotations
@@ -135,8 +128,7 @@ _m_active = Gauge(
 _m_attn_bytes = Counter(
     "ray_tpu_serve_attn_bytes_moved_total",
     "KV-cache bytes the paged attention lane streamed per program call "
-    "(host-side mirror arithmetic, labelled by lane: the gather lane "
-    "materializes the full provisioned arena, the in-place lanes whole "
+    "(host-side mirror arithmetic, labelled by implementation: whole "
     "blocks of pages up to each sequence's cursor)")
 _m_queue_depth = Gauge(
     "ray_tpu_serve_queue_depth",
@@ -211,17 +203,17 @@ class _Seq:
 
 
 class ContinuousScheduler:
-    """Slotted-arena continuous-batching decode scheduler.
+    """Continuous-batching decode scheduler over a paged KV pool.
 
     ``params`` are the (device-resident) model parameters shared by every
-    program; the scheduler owns the KV arena and two jitted programs —
-    a prefill chunk (one compiled shape: [1, prefill_chunk]) and a decode
-    step ([slots]) — both with donated caches so the arena updates in
-    place instead of being copied per iteration. In the paged
-    layout (``paged_prefill_into_slot``, ``paged_decode_step``) the
-    scheduler also owns every slot's cursor and passes it with each call;
-    the contiguous arena (``prefill_into_slot``, ``slot_decode_step``)
-    still keeps its cursors on the device.
+    program; the scheduler owns the page pool and two jitted programs —
+    a prefill chunk (``paged_prefill_into_slot``, one compiled shape:
+    [1, prefill_chunk]) and a decode step (``paged_decode_step``, [slots])
+    — both with donated caches so the pool updates in place instead of
+    being copied per iteration. It also owns every slot's page table and
+    cursor and passes them with each call. ``attn``: the paged-attention
+    implementation, ``None`` for ``ops.paged_attention.resolve_impl``'s
+    answer (the kernel on a TPU, the reference elsewhere).
     """
 
     def __init__(self, cfg, params, *, slots: Optional[int] = None,
@@ -229,7 +221,6 @@ class ContinuousScheduler:
                  arena_len: Optional[int] = None,
                  eos_id: Optional[int] = None,
                  cache_dtype=None,
-                 kv_layout: Optional[str] = None,
                  page_tokens: Optional[int] = None,
                  kv_pages: Optional[int] = None,
                  prefix_cache: Optional[bool] = None,
@@ -242,11 +233,11 @@ class ContinuousScheduler:
 
         from ray_tpu._private.config import global_config
         from ray_tpu.models.decode import (init_paged_caches,
-                                           init_slot_caches,
                                            paged_decode_step,
                                            paged_prefill_into_slot,
-                                           prefill_into_slot,
-                                           slot_decode_step)
+                                           paged_verify_step)
+        from ray_tpu.ops.paged_attention import resolve_impl
+        from ray_tpu.serve._private.paging import PageArena, RadixCache
 
         conf = global_config()
         self.cfg = cfg
@@ -260,13 +251,6 @@ class ContinuousScheduler:
         self.arena_len = int(cfg.max_seq_len if arena_len is None
                              else arena_len)
         self.eos_id = eos_id
-        self.kv_layout = (conf.serve_kv_layout if kv_layout is None
-                          else kv_layout)
-        if self.kv_layout not in ("paged", "contiguous"):
-            raise ValueError(
-                f"kv_layout must be 'paged' or 'contiguous', got "
-                f"{self.kv_layout!r}")
-        self._paged = self.kv_layout == "paged"
         if self.slots < 1:
             raise ValueError(f"slots must be >= 1, got {self.slots}")
         if self.prefill_chunk < 1:
@@ -277,105 +261,60 @@ class ContinuousScheduler:
                 f"prefill_chunk ({self.prefill_chunk}) exceeds the arena "
                 f"length ({self.arena_len})")
         self._jax = jax
-        self._arena = None
-        self._radix = None
-        if self._paged:
-            from ray_tpu.serve._private.paging import PageArena, RadixCache
-
-            self.page_tokens = int(conf.serve_page_tokens
-                                   if page_tokens is None else page_tokens)
-            if self.page_tokens < 1:
-                # explicit 0 (arg or RAY_TPU_SERVE_PAGE_TOKENS=0) raises —
-                # never silently the config default through a falsy `or`
-                raise ValueError(
-                    f"page_tokens must be >= 1, got {self.page_tokens}")
-            if self.arena_len % self.page_tokens != 0:
-                raise ValueError(
-                    f"arena_len ({self.arena_len}) must be a multiple of "
-                    f"page_tokens ({self.page_tokens})")
-            self._pages_per_slot = self.arena_len // self.page_tokens
-            kvp = int(conf.serve_kv_pages if kv_pages is None else kv_pages)
-            if kvp < 0:
-                raise ValueError(f"kv_pages must be >= 0, got {kvp}")
-            if kvp == 0:
-                # auto: the contiguous worst case (every slot could fill
-                # its whole logical range) + the reserved garbage page
-                kvp = self.slots * self._pages_per_slot + 1
-            self.num_pages = kvp
-            self._arena = PageArena(self.num_pages, self.page_tokens)
-            use_prefix = (conf.serve_prefix_cache if prefix_cache is None
-                          else bool(prefix_cache))
-            if use_prefix:
-                self._radix = RadixCache(self._arena)
-            # host-side page tables: logical page j of slot s lives at
-            # physical page read_tables[s, j]; 0 = the garbage page
-            # (unallocated reads are causally masked, redirected writes
-            # are absorbed)
-            self._read_tables = np.zeros(
-                (self.slots, self._pages_per_slot), np.int32)
-            self._write_tables = np.zeros(
-                (self.slots, self._pages_per_slot), np.int32)
-            from ray_tpu.ops.attention import resolve_paged_attn_lane
-
-            # the attention lane resolves ONCE at build — a typo'd
-            # RAY_TPU_SERVE_PAGED_ATTN fails the constructor, not some
-            # later decode step, and stats() always names the real lane
-            self.attn_lane = resolve_paged_attn_lane(
-                conf.serve_paged_attn if attn is None else attn, cfg)
-            # an expert layer's programs hand the rows each expert received
-            # back with the logits (the in-place lanes: they know which
-            # rows are live)
-            self._moe = cfg.mlp == "moe" and self.attn_lane != "gather"
-            self._lane_kw = {"attn": self.attn_lane}
-            if self._moe:
-                self._lane_kw["moe_info"] = True
-            # donated caches: the pool mutates in place across iterations;
-            # the tables are tiny per-call host->device uploads
-            self._prefill = jax.jit(
-                _program(paged_prefill_into_slot, "paged_prefill_chunk", cfg,
-                         **self._lane_kw), donate_argnums=(6,))
-            self._step = jax.jit(
-                _program(paged_decode_step, "paged_decode_step", cfg,
-                         **self._lane_kw), donate_argnums=(6,))
-            self._caches = init_paged_caches(
-                cfg, self.num_pages, self.page_tokens,
-                self._pages_per_slot, cache_dtype)
-            self._kv_itemsize = int(self._caches[0].k.dtype.itemsize)
-        else:
-            from ray_tpu._private.config import env_flag_explicit
-
-            if attn is not None:
-                # the lane picks between paged attention programs; the
-                # contiguous arena has no page tables to attend through,
-                # so an explicit lane request here is a configuration bug
-                raise ValueError(
-                    "attn lane selection requires kv_layout='paged' "
-                    "(the contiguous arena has no page tables)")
-            self.attn_lane = None
-            self._moe = False
-            env_on = env_flag_explicit("serve_prefix_cache")
-            if prefix_cache or (prefix_cache is None and env_on):
-                # explicit intent conflicts loudly. "Explicit" means the
-                # constructor arg or the env var (parsed by the config
-                # layer's own bool rule); serve_prefix_cache=True arriving
-                # through config is indistinguishable from the default
-                # (which documents itself as paged-layout-only), so it
-                # simply does not apply to the contiguous baseline
-                raise ValueError(
-                    "prefix_cache requires kv_layout='paged' (the "
-                    "contiguous arena has no shareable pages)")
-            self.page_tokens = 0
-            self._pages_per_slot = 0
-            self.num_pages = 0
-            # donated caches: the arena mutates in place across iterations
-            self._prefill = jax.jit(
-                _program(prefill_into_slot, "slot_prefill_chunk", cfg),
-                donate_argnums=(4,))
-            self._step = jax.jit(
-                _program(slot_decode_step, "slot_decode_step", cfg),
-                donate_argnums=(3,))
-            self._caches = init_slot_caches(cfg, self.slots, self.arena_len,
-                                            cache_dtype)
+        self.page_tokens = int(conf.serve_page_tokens
+                               if page_tokens is None else page_tokens)
+        if self.page_tokens < 1:
+            # explicit 0 (arg or RAY_TPU_SERVE_PAGE_TOKENS=0) raises —
+            # never silently the config default through a falsy `or`
+            raise ValueError(
+                f"page_tokens must be >= 1, got {self.page_tokens}")
+        if self.arena_len % self.page_tokens != 0:
+            raise ValueError(
+                f"arena_len ({self.arena_len}) must be a multiple of "
+                f"page_tokens ({self.page_tokens})")
+        self._pages_per_slot = self.arena_len // self.page_tokens
+        kvp = int(conf.serve_kv_pages if kv_pages is None else kv_pages)
+        if kvp < 0:
+            raise ValueError(f"kv_pages must be >= 0, got {kvp}")
+        if kvp == 0:
+            # auto: the worst case (every slot could fill its whole
+            # logical range) + the reserved garbage page
+            kvp = self.slots * self._pages_per_slot + 1
+        self.num_pages = kvp
+        self._arena = PageArena(self.num_pages, self.page_tokens)
+        use_prefix = (conf.serve_prefix_cache if prefix_cache is None
+                      else bool(prefix_cache))
+        self._radix = RadixCache(self._arena) if use_prefix else None
+        # host-side page tables: logical page j of slot s lives at
+        # physical page read_tables[s, j]; 0 = the garbage page
+        # (unallocated reads are causally masked, redirected writes
+        # are absorbed)
+        self._read_tables = np.zeros(
+            (self.slots, self._pages_per_slot), np.int32)
+        self._write_tables = np.zeros(
+            (self.slots, self._pages_per_slot), np.int32)
+        # the implementation resolves ONCE at build — an unknown value
+        # fails the constructor, not some later decode step, and stats()
+        # always names what really runs
+        self.attn_lane = resolve_impl(cfg, attn)
+        # an expert layer's programs hand the rows each expert received
+        # back with the logits
+        self._moe = cfg.mlp == "moe"
+        program_kw = {"attn": self.attn_lane}
+        if self._moe:
+            program_kw["moe_info"] = True
+        # donated caches: the pool mutates in place across iterations;
+        # the tables are tiny per-call host->device uploads
+        self._prefill = jax.jit(
+            _program(paged_prefill_into_slot, "paged_prefill_chunk", cfg,
+                     **program_kw), donate_argnums=(6,))
+        self._step = jax.jit(
+            _program(paged_decode_step, "paged_decode_step", cfg,
+                     **program_kw), donate_argnums=(6,))
+        self._caches = init_paged_caches(
+            cfg, self.num_pages, self.page_tokens,
+            self._pages_per_slot, cache_dtype)
+        self._kv_itemsize = int(self._caches[0].k.dtype.itemsize)
         # ---- speculative decoding (ISSUE 18): the drafter proposes, one
         # extra fixed-shape verify program scores — the two-compiles
         # contract becomes exactly three with speculation on
@@ -393,19 +332,13 @@ class ContinuousScheduler:
         self._drafter = drafter
         self._verify = None
         if drafter is not None:
-            if not self._paged:
-                raise ValueError(
-                    "speculative decoding requires kv_layout='paged' (the "
-                    "verify step scores K tokens through page tables)")
             if drafter.slots != self.slots:
                 raise ValueError(
                     f"drafter has {drafter.slots} slots, scheduler has "
                     f"{self.slots} — they must share the slot numbering")
-            from ray_tpu.models.decode import paged_verify_step
-
             self._verify = jax.jit(
                 _program(paged_verify_step, "paged_verify_step", cfg,
-                         **self._lane_kw), donate_argnums=(6,))
+                         **program_kw), donate_argnums=(6,))
         # ---- cross-replica page migration (ISSUE 18): a dedicated
         # worker thread does the blocking peer pull; the scheduler thread
         # only splices finished results between iterations. _commands
@@ -474,13 +407,11 @@ class ContinuousScheduler:
         over-budget request is rejected loudly at submit, before any
         pages are allocated."""
         c = self.prefill_chunk
-        effective = self.arena_len
-        if self._paged:
-            effective = min(effective,
-                            self._arena.usable_pages * self.page_tokens)
+        effective = min(self.arena_len,
+                        self._arena.usable_pages * self.page_tokens)
         # with speculation on, a verify round near the end of generation
         # writes up to spec_k positions past the final cursor — reserve
-        # them so the windowed scatter can never clip onto the slot's
+        # them so the window's writes can never clip onto the slot's
         # last real page
         reserve = self.spec_k if self._drafter is not None else 0
         by_pad = (effective // c) * c
@@ -517,7 +448,7 @@ class ContinuousScheduler:
                 f"chunks)")
         seq = _Seq(list(prompt_ids), max_new_tokens, temperature, seed,
                    loop, queue)
-        if fleet_hint and self._paged and self._radix is not None:
+        if fleet_hint and self._radix is not None:
             seq.fleet_hint = dict(fleet_hint)
         with self._lock:
             if self._closed:
@@ -557,10 +488,10 @@ class ContinuousScheduler:
             seq.cancelled = True
 
     def _release_slot_resources(self, seq: _Seq) -> None:
-        """Paged-arena teardown for one slot: drop the prefix-cache ref,
-        free owned pages, and zero the page-table rows (so an inactive
-        slot's decode gather/scatter touches only the garbage page)."""
-        if not self._paged or seq.slot is None:
+        """Teardown for one slot: drop the prefix-cache ref, free owned
+        pages, and zero the page-table rows (so an inactive slot's decode
+        write touches only the garbage page)."""
+        if seq.slot is None:
             return
         slot = seq.slot
         if seq.radix_node is not None:
@@ -702,12 +633,10 @@ class ContinuousScheduler:
         self._n_prefix_hit_tokens += keep
 
     def _admit(self) -> None:
-        """Seat pending requests in free slots. In the paged layout this is
-        host bookkeeping alone (tables, the prefix splice, the cursor): the
-        programs take the cursor as an argument, so no device program runs
-        and no live slot waits for one."""
-        from ray_tpu.models.decode import reset_slot
-
+        """Seat pending requests in free slots. This is host bookkeeping
+        alone (tables, the prefix splice, the cursor): the programs take the
+        cursor as an argument, so no device program runs and no live slot
+        waits for one."""
         while True:
             with self._lock:
                 if not self._pending:
@@ -725,22 +654,19 @@ class ContinuousScheduler:
             seq.slot = free
             seq.state = _PREFILL
             self._slot_seqs[free] = seq
-            if self._paged:
-                seq.cached_len = 0
-                seq.owned_pages = []
-                seq.radix_node = None
-                seq.table_fill = 0
-                self._read_tables[free, :] = 0
-                self._write_tables[free, :] = 0
-                if self._radix is not None:
-                    self._splice_prefix(seq)
-                    # a migrated prefix was pinned only so eviction could
-                    # not race admission; the splice holds its own ref now
-                    self._release_migration_ref(seq)
-                seq.cursor = seq.cached_len
-                seq.remaining_prompt = seq.prompt[seq.cached_len:]
-            else:
-                self._caches = reset_slot(self._caches, free)
+            seq.cached_len = 0
+            seq.owned_pages = []
+            seq.radix_node = None
+            seq.table_fill = 0
+            self._read_tables[free, :] = 0
+            self._write_tables[free, :] = 0
+            if self._radix is not None:
+                self._splice_prefix(seq)
+                # a migrated prefix was pinned only so eviction could
+                # not race admission; the splice holds its own ref now
+                self._release_migration_ref(seq)
+            seq.cursor = seq.cached_len
+            seq.remaining_prompt = seq.prompt[seq.cached_len:]
             self._n_admitted += 1
             seq.t_admit = time.monotonic()
             waited = seq.t_admit - seq.t_submit
@@ -755,54 +681,30 @@ class ContinuousScheduler:
 
     def _record_attn(self, qk: int, cursors: List[int],
                      idle_rows: int = 0) -> None:
-        """Account what the attention lane streamed for one
+        """Account what the paged attention streamed for one
         attention-bearing program call (its device time is read from a
         profiler trace, by the program's name and the kernel's).
         ``cursors``: the attention cursor of every slot row that attends a
         K = ``qk`` window; ``idle_rows``: the call's other rows, which the
         program marks as attending nothing. Pure host-side mirror arithmetic
-        (cursors, table shapes, the op's own ``tile_sizes``) — no device
-        readback on the hot loop. The gather lane materializes a
-        contiguous ``[pages_per_slot * page_tokens]`` view per slot
-        regardless of how little of it is live. The in-place lanes stream
-        whole BLOCKS of pages: the kernel each slot's own blocks, once per
-        query tile, up to the tile's last position (an idle row none);
-        the reference every row, idle ones too, over the longest row's
-        blocks.
-        ``attn_tokens_attended`` (the positions a row, or a query tile of
-        the kernel, may attend) over ``attn_tokens_fetched`` is the block
-        fill share (per layer: every layer repeats the same fetches)."""
-        if not self._paged:
-            return
-        from ray_tpu.ops.paged_attention import tile_sizes
+        (``ops.paged_attention.streamed_tokens``) — no device readback on
+        the hot loop. ``attn_tokens_attended`` over ``attn_tokens_fetched``
+        is the block fill share (per layer: every layer repeats the same
+        fetches)."""
+        from ray_tpu.ops.paged_attention import streamed_tokens
 
         cfg = self.cfg
-        T, P = self.page_tokens, self._pages_per_slot
         row = cfg.kv_heads * cfg.head_dim * self._kv_itemsize
-        rows = len(cursors) + idle_rows
-        attended = sum(c + qk for c in cursors)
-        if self.attn_lane == "gather":
-            fetched = rows * P * T
-        else:
-            pages, q_tile = tile_sizes(qk, cfg.num_heads // cfg.kv_heads,
-                                       T, P, row)
-
-            def blocks(upto: int) -> int:
-                return min(-(-upto // (pages * T)), -(-P // pages))
-
-            if self.attn_lane == "reference":
-                fetched = rows * blocks(max(cursors) + qk) if cursors else 0
-            else:  # each query tile streams the blocks up to its own end
-                ends = [min(e, qk) for e in range(q_tile, qk + q_tile,
-                                                  q_tile)]
-                attended = sum(c + e for e in ends for c in cursors)
-                fetched = sum(blocks(c + e) for e in ends for c in cursors)
-            fetched *= pages * T
+        attended, fetched = streamed_tokens(
+            self.attn_lane, qk, cursors, idle_rows,
+            cfg.num_heads // cfg.kv_heads, self.page_tokens,
+            self._pages_per_slot, row)
         self._n_attn_attended += attended
         self._n_attn_fetched += fetched
         # k + v pools, every layer: the rows read through the table plus
         # the qk freshly-written rows per slot
-        moved = 2 * cfg.num_layers * row * (fetched + rows * qk)
+        moved = 2 * cfg.num_layers * row * (
+            fetched + (len(cursors) + idle_rows) * qk)
         self._n_attn_bytes += moved
         _m_attn_bytes.inc(moved, labels={"lane": self.attn_lane})
 
@@ -872,7 +774,7 @@ class ContinuousScheduler:
             # table entries, which the garbage-page write redirect
             # absorbs by design (don't fail a fitting sequence for
             # pad-only pages when the pool is tight)
-            if self._paged and not self._ensure_pages(
+            if not self._ensure_pages(
                     seq, seq.cursor + min(len(seq.remaining_prompt),
                                           self.prefill_chunk)):
                 continue  # failed cleanly; other slots keep running
@@ -883,30 +785,23 @@ class ContinuousScheduler:
             # NumPy, uploaded with the call: jnp.asarray of a list with a
             # dtype would run an eager convert program of its own a chunk
             tokens = np.asarray([padded], np.int32)
-            if self._paged:
-                # the rows are uploaded as COPIES: dispatch is async and an
-                # upload may alias (CPU) or still be reading (TPU) the host
-                # buffer, while _offer_prompt_pages and _ensure_pages write
-                # to these rows before anything waits for this chunk
-                logits, self._caches = self._moe_note(self._prefill(
-                    self.params, tokens, np.int32(real), np.int32(seq.cursor),
-                    jnp.asarray(self._read_tables[seq.slot].copy()),
-                    jnp.asarray(self._write_tables[seq.slot].copy()),
-                    self._caches), real)
-                seq.cursor += real
-            else:
-                logits, self._caches = self._prefill(
-                    self.params, tokens, np.int32(real), np.int32(seq.slot),
-                    self._caches)
+            # the rows are uploaded as COPIES: dispatch is async and an
+            # upload may alias (CPU) or still be reading (TPU) the host
+            # buffer, while _offer_prompt_pages and _ensure_pages write
+            # to these rows before anything waits for this chunk
+            logits, self._caches = self._moe_note(self._prefill(
+                self.params, tokens, np.int32(real), np.int32(seq.cursor),
+                jnp.asarray(self._read_tables[seq.slot].copy()),
+                jnp.asarray(self._write_tables[seq.slot].copy()),
+                self._caches), real)
+            seq.cursor += real
             # dispatch is async and stays so: the chunk's device time is
             # read from a profiler trace by the program's name, and the
             # wait for it falls into the next phase that reads a result
-            if self._paged:
-                self._record_attn(self.prefill_chunk, [seq.cursor - real])
+            self._record_attn(self.prefill_chunk, [seq.cursor - real])
             self._n_prefill_chunks += 1
             _m_prefill_chunks.inc()
-            if self._paged and self._radix is not None \
-                    and not seq.remaining_prompt:
+            if self._radix is not None and not seq.remaining_prompt:
                 self._offer_prompt_pages(seq)
             if not seq.remaining_prompt:
                 # prompt fully resident: sample the first token NOW — this
@@ -1000,7 +895,7 @@ class ContinuousScheduler:
         to what is NOT already cached locally, and bounded by the
         migration budget — a hint that buys nothing re-queues for normal
         (cold or locally-warm) admission immediately."""
-        if not self._paged or self._radix is None:
+        if self._radix is None:
             return
         with self._lock:
             flagged = [s for s in self._pending if s.fleet_hint is not None]
@@ -1036,8 +931,6 @@ class ContinuousScheduler:
         admission-time ``_splice_prefix`` now hits the migrated span, a
         failed pull means a plain cold prefill. Either way the OUTPUT is
         the same tokens; migration only moves where the KV comes from."""
-        if not self._paged:
-            return
         while True:
             try:
                 seq, res = self._mig_results.get_nowait()
@@ -1125,7 +1018,7 @@ class ContinuousScheduler:
         scheduler thread (sole owner of the tree and the donated caches),
         so this enqueues a command and waits. The matched node is pinned
         only for the duration of the gather."""
-        if not self._paged or self._radix is None:
+        if self._radix is None:
             return {"matched_len": 0, "page_tokens": self.page_tokens,
                     "k": [], "v": []}
         box: Dict[str, Any] = {}
@@ -1178,7 +1071,7 @@ class ContinuousScheduler:
         Probed OFF the scheduler thread (the stats path), so the rare
         mid-mutation dict iteration is retried rather than locked — the
         digest is advisory; a stale read costs one cold prefill at most."""
-        if not self._paged or self._radix is None:
+        if self._radix is None:
             return {}
         for _ in range(8):
             try:
@@ -1239,7 +1132,7 @@ class ContinuousScheduler:
             if seq.cancelled:
                 self._retire(seq, "cancelled")
                 continue
-            # the verify scatter writes positions [cursor, cursor + K)
+            # the verify window writes positions [cursor, cursor + K)
             if not self._ensure_pages(seq, seq.cursor + K):
                 continue
             live.append(seq)
@@ -1359,22 +1252,17 @@ class ContinuousScheduler:
             if seq.cancelled:
                 self._retire(seq, "cancelled")
                 continue
-            if self._paged and not self._ensure_pages(seq, seq.cursor + 1):
+            if not self._ensure_pages(seq, seq.cursor + 1):
                 continue  # this sequence failed cleanly; others continue
             toks[i] = seq.next_token
             active[i] = 1
             live.append(seq)
         if not live:
             return False
-        if self._paged:
-            logits, self._caches = self._moe_note(self._step(
-                self.params, jnp.asarray(toks), jnp.asarray(active),
-                self._cursors(), jnp.asarray(self._read_tables),
-                jnp.asarray(self._write_tables), self._caches), len(live))
-        else:
-            logits, self._caches = self._step(
-                self.params, jnp.asarray(toks), jnp.asarray(active),
-                self._caches)
+        logits, self._caches = self._moe_note(self._step(
+            self.params, jnp.asarray(toks), jnp.asarray(active),
+            self._cursors(), jnp.asarray(self._read_tables),
+            jnp.asarray(self._write_tables), self._caches), len(live))
         self._record_attn(1, [s.cursor for s in live],
                           self.slots - len(live))
         self._n_steps += 1
@@ -1417,10 +1305,9 @@ class ContinuousScheduler:
                 with self._lock:
                     if self._closed:
                         break
-                if self._paged:
-                    self._process_commands()
-                    self._finish_migrations()
-                    self._start_migrations()
+                self._process_commands()
+                self._finish_migrations()
+                self._start_migrations()
                 self._admit()
                 did = self._prefill_one()
                 if self._drafter is not None:
@@ -1530,7 +1417,6 @@ class ContinuousScheduler:
             q = len(self._pending)
         out = {
             "mode": "continuous",
-            "kv_layout": self.kv_layout,
             "slots": self.slots,
             "prefill_chunk": self.prefill_chunk,
             "arena_len": self.arena_len,
@@ -1561,31 +1447,30 @@ class ContinuousScheduler:
         }
         # the scheduler thread's own time by phase (0 with the recorder off)
         out.update(zip(_PHASE_KEYS, self._clock.seconds()))
-        if self._paged:
-            out["page_tokens"] = self.page_tokens
-            out["pages_per_slot"] = self._pages_per_slot
-            out["attn_lane"] = self.attn_lane
-            out["attn_bytes_moved"] = self._n_attn_bytes
-            out["attn_tokens_attended"] = self._n_attn_attended
-            out["attn_tokens_fetched"] = self._n_attn_fetched
-            if self._moe:
-                # the device's per-expert row counts, summed a layer-call
-                # (one expert layer in one program run) as of the last
-                # fetch; rows_routed == live_rows x top_k x layers exactly:
-                # no row is dropped
-                out["moe_live_rows"] = self._n_moe_live_rows
-                out["moe_layer_calls"] = self._n_moe_layer_calls
-                out["moe_rows_routed"] = self._n_moe_rows_routed
-                out["moe_experts_hit"] = self._n_moe_experts_hit
-                out["moe_max_expert_rows"] = self._n_moe_max_expert_rows
-            out.update(self._arena.stats())
-            if self._radix is not None:
-                out.update(self._radix.stats())
-                out["prefix_hit_tokens"] = self._n_prefix_hit_tokens
-                out["migrations"] = self._n_migrations
-                out["migrated_pages"] = self._n_migrated_pages
-                out["migration_failures"] = self._n_migration_failures
-                out["migrations_pending"] = len(self._migrating)
+        out["page_tokens"] = self.page_tokens
+        out["pages_per_slot"] = self._pages_per_slot
+        out["attn_lane"] = self.attn_lane
+        out["attn_bytes_moved"] = self._n_attn_bytes
+        out["attn_tokens_attended"] = self._n_attn_attended
+        out["attn_tokens_fetched"] = self._n_attn_fetched
+        if self._moe:
+            # the device's per-expert row counts, summed a layer-call
+            # (one expert layer in one program run) as of the last
+            # fetch; rows_routed == live_rows x top_k x layers exactly:
+            # no row is dropped
+            out["moe_live_rows"] = self._n_moe_live_rows
+            out["moe_layer_calls"] = self._n_moe_layer_calls
+            out["moe_rows_routed"] = self._n_moe_rows_routed
+            out["moe_experts_hit"] = self._n_moe_experts_hit
+            out["moe_max_expert_rows"] = self._n_moe_max_expert_rows
+        out.update(self._arena.stats())
+        if self._radix is not None:
+            out.update(self._radix.stats())
+            out["prefix_hit_tokens"] = self._n_prefix_hit_tokens
+            out["migrations"] = self._n_migrations
+            out["migrated_pages"] = self._n_migrated_pages
+            out["migration_failures"] = self._n_migration_failures
+            out["migrations_pending"] = len(self._migrating)
         if self._drafter is not None:
             out["spec_k"] = self.spec_k
             out["drafter"] = self._drafter.name

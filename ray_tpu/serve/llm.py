@@ -2,9 +2,9 @@
 
 The reference leaves model serving to torch/vLLM inside replicas (its
 `ray.serve.llm` wraps vLLM engines); here the decode loop is TPU-native
-and the batching is CONTINUOUS (iteration-level, ISSUE 9):
+and the batching is CONTINUOUS (iteration-level):
 
-  * a PAGED KV arena (`models.decode.PagedKVCache`, ISSUE 13) plus ONE
+  * a PAGED KV pool (`models.decode.PagedKVCache`) plus ONE
     fixed-shape jitted decode step over all slots per iteration; slots
     own page tables instead of worst-case `max_seq_len` ranges (tables
     and cursors are the scheduler's host state, passed to each call: the
@@ -14,9 +14,10 @@ and the batching is CONTINUOUS (iteration-level, ISSUE 9):
     admitted into free slots between iterations (chunked prefill),
     finished/EOS/cancelled sequences retire their slot (and pages)
     immediately — ≈ vLLM's PagedAttention + SGLang's RadixAttention
-    scheduling, not a flush-and-drain `@serve.batch` window (kept as
-    `scheduler="batch"`, the measured baseline; `kv_layout="contiguous"`
-    keeps the PR-9 arena);
+    scheduling, not a flush-and-drain `@serve.batch` window; the
+    paged-attention implementation (the Pallas kernel on a TPU, the
+    pure-JAX reference elsewhere) is `ops.paged_attention.resolve_impl`'s
+    choice;
   * token streaming: `{"prompt": ..., "stream": true}` returns an async
     generator consuming the scheduler's per-slot token queue — the stream
     rides the same batched program as everything else (no per-stream
@@ -60,22 +61,19 @@ def _byte_detokenize(ids: List[int]) -> str:
 
 
 class LLMServerImpl:
-    """One model replica: owns the jitted decode programs and (in
-    continuous mode) the slot-arena scheduler. Weights are shared per node
-    through the object arena unless ``share_weights=False``."""
+    """One model replica: owns the weights and the continuous-batching
+    scheduler (``serve/_private/continuous.py``). Weights are shared per
+    node through the object arena unless ``share_weights=False``."""
 
     def __init__(self, preset: str = "llama_debug",
                  preset_overrides: Optional[Dict[str, Any]] = None,
                  max_new_tokens: int = 16,
                  temperature: float = 0.0,
-                 max_batch_size: int = 8,
                  params_loader=None,
                  tokenize=None, detokenize=None,
-                 scheduler: str = "continuous",
                  slots: Optional[int] = None,
                  prefill_chunk: Optional[int] = None,
                  arena_len: Optional[int] = None,
-                 kv_layout: Optional[str] = None,
                  page_tokens: Optional[int] = None,
                  kv_pages: Optional[int] = None,
                  prefix_cache: Optional[bool] = None,
@@ -88,17 +86,11 @@ class LLMServerImpl:
                  migration_budget: Optional[int] = None,
                  attn: Optional[str] = None):
         import jax
-        import jax.numpy as jnp
 
         from ray_tpu.models import presets
         from ray_tpu.models.decode import decode_step, prefill
         from ray_tpu.models.transformer import init_params
 
-        if scheduler not in ("continuous", "batch"):
-            raise ValueError(
-                f"scheduler must be 'continuous' or 'batch', got "
-                f"{scheduler!r}")
-        self._jnp = jnp
         self._jax = jax
         # preset fields (e.g. a wider max_seq_len context window for long
         # few-shot preambles) are overridable per deployment; the KV arena
@@ -106,8 +98,6 @@ class LLMServerImpl:
         self.cfg = getattr(presets, preset)(**(preset_overrides or {}))
         self.max_new_tokens = max_new_tokens
         self.temperature = temperature
-        self._max_batch = max_batch_size
-        self._scheduler_mode = scheduler
         self._eos_id = eos_id
         self._seq_counter = 0
         # the scheduler thread -> event loop hand-off: how long the items
@@ -163,38 +153,22 @@ class LLMServerImpl:
         # tokenize itself — true for the reproducible byte tokenizer;
         # custom tokenizers need explicit prompt_ids in the request
         self._byte_tok = tokenize is None
-        # jitted programs for the request-level baseline + legacy streaming
+        # the sequential cache's two programs: the oracle a deployment
+        # checks its served tokens against (perfbench's reference check)
         self._prefill = jax.jit(partial(prefill, self.cfg))
         self._decode_step = jax.jit(partial(decode_step, self.cfg))
-        self._key = jax.random.PRNGKey(0)
-        import threading
 
-        self._key_lock = threading.Lock()  # batch flushes run on executor threads
-        # deploy-time batch size overrides the @serve.batch default
-        setattr(self, "__serve_batch_size__generate_batch", max_batch_size)
+        from ray_tpu.serve._private.continuous import ContinuousScheduler
 
-        self._sched = None
-        if scheduler == "continuous":
-            from ray_tpu.serve._private.continuous import ContinuousScheduler
-
-            drafter_obj = self._build_drafter(drafter, slots, arena_len,
-                                              _weights)
-            self._sched = ContinuousScheduler(
-                self.cfg, self.params, slots=slots,
-                prefill_chunk=prefill_chunk, arena_len=arena_len,
-                eos_id=eos_id, kv_layout=kv_layout,
-                page_tokens=page_tokens, kv_pages=kv_pages,
-                prefix_cache=prefix_cache, drafter=drafter_obj,
-                spec_k=spec_k, migration_budget=migration_budget,
-                attn=attn)
-        elif drafter:
-            raise ValueError(
-                "speculative decoding (drafter=...) requires "
-                "scheduler='continuous'")
-        elif attn is not None:
-            raise ValueError(
-                "attn lane selection (attn=...) requires "
-                "scheduler='continuous' with kv_layout='paged'")
+        drafter_obj = self._build_drafter(drafter, slots, arena_len,
+                                          _weights)
+        self._sched = ContinuousScheduler(
+            self.cfg, self.params, slots=slots,
+            prefill_chunk=prefill_chunk, arena_len=arena_len,
+            eos_id=eos_id, page_tokens=page_tokens, kv_pages=kv_pages,
+            prefix_cache=prefix_cache, drafter=drafter_obj,
+            spec_k=spec_k, migration_budget=migration_budget,
+            attn=attn)
 
     def _build_drafter(self, drafter: Optional[str], slots, arena_len,
                        _weights):
@@ -303,81 +277,6 @@ class LLMServerImpl:
         finally:
             self._sched.cancel(seq)
 
-    # ------------------------------------------------ request-level path
-    # (the measured flush-and-drain baseline: one @serve.batch window runs
-    # prefill + the FULL decode loop before any newly arrived request is
-    # admitted — scheduler="batch" keeps it selectable, exactly like the
-    # collective layer's algo="kv")
-
-    @serve.batch(max_batch_size=8, batch_wait_timeout_s=0.02)
-    async def _generate_batch(self, items) -> List[List[int]]:
-        """Request-level batching: the flush runs every request in it to
-        completion. The jax work runs on an executor thread — blocking the
-        replica's event loop would stall health checks and stream pulls."""
-        return await asyncio.get_running_loop().run_in_executor(
-            None, self._generate_batch_sync, items)
-
-    def _generate_batch_sync(self, items) -> List[List[int]]:
-        """Group prompts by exact length and run one decode program per
-        group. Padding mixed lengths into one program would let real tokens
-        attend to pad positions (the causal cache mask has no pad masking),
-        silently degrading shorter prompts; grouping keeps every program
-        exact while still batching the common same-shape case."""
-        by_len: Dict[int, List[int]] = {}
-        for i, (p, _new) in enumerate(items):
-            by_len.setdefault(len(p), []).append(i)
-        outs: List[List[int]] = [[] for _ in items]
-        for _length, indices in by_len.items():
-            group = [items[i][0] for i in indices]
-            # flush-and-drain: the whole group decodes until its LONGEST
-            # request is done; shorter requests are truncated after
-            steps = max(items[i][1] for i in indices)
-            for i, out in zip(indices, self._generate_group(group, steps)):
-                outs[i] = out[: items[i][1]]
-        return outs
-
-    def _generate_group(self, prompts: List[List[int]],
-                        new_tokens: int) -> List[List[int]]:
-        """One batched decode program over same-length prompts."""
-        from ray_tpu.models.decode import init_caches, sample_token
-
-        jnp = self._jnp
-        batch = len(prompts)
-        length = len(prompts[0])
-        tokens = jnp.asarray(prompts, dtype=jnp.int32)
-        caches = init_caches(self.cfg, batch, length + new_tokens)
-        logits, caches = self._prefill(self.params, tokens, caches)
-        outs: List[List[int]] = [[] for _ in range(batch)]
-        for _ in range(new_tokens):
-            with self._key_lock:
-                self._key, sub = self._jax.random.split(self._key)
-            tok = sample_token(logits, sub, self.temperature)
-            for i, t in enumerate(tok.tolist()):
-                outs[i].append(int(t))
-            logits, caches = self._decode_step(
-                self.params, tok[:, None].astype(jnp.int32), caches)
-        return outs
-
-    def _generate_stream(self, prompt_ids: List[int], new_tokens: int):
-        """Legacy streaming (scheduler="batch" only): a single-sequence
-        decode loop owning its own KV cache. The replica pumps it on an
-        executor thread, never the event loop — but each live stream still
-        monopolizes one whole decode program; the continuous path replaces
-        this with a queue consumer over the shared slot arena."""
-        from ray_tpu.models.decode import init_caches, sample_token
-
-        jnp = self._jnp
-        tokens = jnp.asarray([prompt_ids], dtype=jnp.int32)
-        caches = init_caches(self.cfg, 1, len(prompt_ids) + new_tokens)
-        logits, caches = self._prefill(self.params, tokens, caches)
-        key = self._jax.random.PRNGKey(len(prompt_ids))
-        for _ in range(new_tokens):
-            key, sub = self._jax.random.split(key)
-            tok = sample_token(logits, sub, self.temperature)
-            yield self._detokenize([int(tok[0])])
-            logits, caches = self._decode_step(
-                self.params, tok[:, None].astype(jnp.int32), caches)
-
     # ------------------------------------------------------------ entry
 
     async def __call__(self, request: Optional[Dict[str, Any]] = None):
@@ -395,46 +294,22 @@ class LLMServerImpl:
             raise ValueError("prompt must be non-empty")
         max_new = int(request.get("max_new_tokens", self.max_new_tokens))
         temperature = float(request.get("temperature", self.temperature))
-        # router-attached pull hint (fleet hit on another replica); only
-        # meaningful to the continuous scheduler
+        # router-attached pull hint (fleet hit on another replica)
         fleet_hint = request.get("_fleet_hint")
-        if self._sched is not None:
-            if request.get("stream"):
-                return self._stream_continuous(ids, max_new, temperature,
-                                               fleet_hint)
-            out_ids = await self._run_continuous(ids, max_new, temperature,
-                                                 fleet_hint)
-        else:
-            # the request-level path has no per-sequence cache bound of its
-            # own (the continuous scheduler validates at submit): guard the
-            # user-controlled budget before it sizes a KV cache, and refuse
-            # (rather than silently ignore) per-request temperatures its
-            # whole-batch sampler cannot honor
-            if max_new < 1:
-                raise ValueError("max_new_tokens must be >= 1")
-            if len(ids) + max_new > self.cfg.max_seq_len:
-                raise ValueError(
-                    f"prompt of {len(ids)} tokens + {max_new} new tokens "
-                    f"exceeds cfg.max_seq_len ({self.cfg.max_seq_len})")
-            if temperature != self.temperature:
-                raise ValueError(
-                    "per-request temperature requires the continuous "
-                    "scheduler (this replica runs scheduler='batch')")
-            if request.get("stream"):
-                return self._generate_stream(ids, max_new)
-            out_ids = await self._generate_batch((ids, max_new))
+        if request.get("stream"):
+            return self._stream_continuous(ids, max_new, temperature,
+                                           fleet_hint)
+        out_ids = await self._run_continuous(ids, max_new, temperature,
+                                             fleet_hint)
         return {"prompt": prompt, "text": self._detokenize(out_ids),
                 "num_tokens": len(out_ids)}
 
     # ------------------------------------------------------ introspection
 
     def scheduler_stats(self) -> Dict[str, Any]:
-        if self._sched is not None:
-            out = self._sched.stats()
-            out["stream_lag_s"] = self._stream_lag_ns / 1e9
-            out["stream_tokens"] = self._stream_tokens
-        else:
-            out = {"mode": "batch", "max_batch_size": self._max_batch}
+        out = self._sched.stats()
+        out["stream_lag_s"] = self._stream_lag_ns / 1e9
+        out["stream_tokens"] = self._stream_tokens
         # where the model really runs: a replica that was not given a
         # chip runs on the CPU, and the record has to say so
         devices = self._jax.devices()
@@ -449,19 +324,13 @@ class LLMServerImpl:
         """Admitted-but-unscheduled sequences (the replica relays this
         into its stats so the controller can autoscale on backlog, not
         just in-flight counts)."""
-        if self._sched is not None:
-            return int(self._sched.stats().get("queue_depth", 0))
-        return 0
+        return int(self._sched.stats().get("queue_depth", 0))
 
     def prefix_digest(self) -> Dict[str, Any]:
         """The radix cache's chain-hash digest plus what the router needs
         to hash prompts the same way (tokenizer kind + vocab). Empty when
-        there is nothing advertisable (batch scheduler, contiguous
-        layout, prefix cache off)."""
-        if self._sched is None:
-            return {}
-        probe = getattr(self._sched, "prefix_digest", None)
-        d = probe() if callable(probe) else {}
+        there is nothing advertisable (prefix cache off or empty)."""
+        d = self._sched.prefix_digest()
         if d:
             d = dict(d)
             d["vocab_size"] = self.cfg.vocab_size
@@ -473,8 +342,6 @@ class LLMServerImpl:
         """Peer-replica migration pull: the longest cached prefix of
         ``tokens`` as per-layer KV page arrays (replica→replica, never
         through the controller)."""
-        if self._sched is None:
-            return {"matched_len": 0, "page_tokens": 0, "k": [], "v": []}
         return self._sched.export_prefix(list(tokens), timeout_s=timeout_s)
 
     def weights_info(self) -> Dict[str, Any]:
@@ -492,13 +359,10 @@ class LLMServerImpl:
         return True
 
     def check_health(self) -> bool:
-        if self._sched is not None and self._sched.closed:
-            return False
-        return self.params is not None
+        return not self._sched.closed and self.params is not None
 
     def shutdown(self) -> None:
-        if self._sched is not None:
-            self._sched.shutdown()
+        self._sched.shutdown()
 
     def __del__(self):
         try:

@@ -261,13 +261,14 @@ def test_paged_verify_step_matches_the_full_forward(toy, paged_run):
         assert rel_err(logits[s, :used[s]], want) < TOL
 
 
-def test_gather_lane_refuses_moe_info(toy):
+def test_moe_info_refuses_a_dense_model(toy):
     cfg, params, _ = toy
     caches, tables = paged_setup(cfg, 2)
-    with pytest.raises(ValueError, match="in-place"):
-        paged_decode_step(cfg, params, jnp.zeros(2, jnp.int32),
+    dense = dataclasses.replace(cfg, mlp="swiglu")
+    with pytest.raises(ValueError, match="mlp='moe'"):
+        paged_decode_step(dense, params, jnp.zeros(2, jnp.int32),
                           jnp.ones(2, jnp.int32), jnp.zeros(2, jnp.int32),
-                          tables, tables, caches, attn="gather",
+                          tables, tables, caches, attn="reference",
                           moe_info=True)
 
 

@@ -1,19 +1,19 @@
-"""Paged attention lanes (ISSUE 20): gather-free decode/verify that reads
-KV pages in place.
+"""Paged attention: decode/verify/prefill that read KV pages in place.
 
 Covers the op-level contracts of ``ops.paged_attention`` (the pure-JAX
 reference against a full-softmax gathered-view oracle; the Pallas kernel —
 interpret mode on CPU — bitwise against the reference; garbage-page
 redirects, shared prefix pages, length-0 and page-boundary edges), the
-in-place model lanes in ``models.decode`` (temperature-0 token parity of
-the ``attn="reference"``/``"pallas"`` lanes against the measured-baseline
-``"gather"`` lane across prefill/decode/verify), the lane dispatcher
-(unknown/falsy spellings rejected loudly at every layer, satellite: the
-``ops.attention`` impl typo guard), and the scheduler end to end (token
-streams identical across lanes under mixed lengths, slot reuse and prefix
-hits; spec-decode acceptance unchanged; the two-compiles contract with the
-in-place lane on; ``attn_bytes_moved`` showing the gather lane's
-provisioning-proportional traffic).
+paged programs in ``models.decode`` (each program on each implementation
+against the SEQUENTIAL cache, ``decode.prefill`` + ``decode_step`` over a
+``LayerKVCache``: the same tokens at temperature 0, logits within a float32
+tolerance; ``attn="reference"`` against ``"pallas"`` bitwise), the
+implementation resolver (unknown/falsy spellings rejected loudly at every
+layer, satellite: the ``ops.attention`` impl typo guard), and the scheduler
+end to end (token streams equal to the sequential cache's on both
+implementations under mixed lengths, slot reuse and prefix hits;
+spec-decode acceptance unchanged; the two-compiles contract; the fetch
+counters).
 """
 
 import asyncio
@@ -281,8 +281,8 @@ class TestPagedAttentionOp:
         rng = np.random.default_rng(5)
         q, kp, vp, tables, lengths = _mk_pools(
             rng, S=2, K=1, H=4, Hkv=2, D=8, T=4, P=4, lengths=[3, 3])
-        # 'gather' is a models/decode.py lane, not an op impl — the error
-        # must say so instead of silently running the reference
+        # the gathered-view lane is gone: its name is refused like any
+        # other unknown impl, never silently the reference
         with pytest.raises(ValueError, match="gather"):
             paged_attention(q, kp, vp, tables, lengths, impl="gather")
         with pytest.raises(ValueError, match="slot axis"):
@@ -325,30 +325,43 @@ def _arena(cfg, S, T, P):
     return caches, jnp.asarray(tables)
 
 
+def _paged_prefill(cfg, params, attn, prompts, T=4, P=8):
+    """Prefill mixed-length prompts into one slot each, ``CHUNK`` tokens a
+    call. Returns (each slot's last logits, caches, tables, cursors): the
+    cursors are the caller's, the pool keeps none."""
+    from functools import partial
+
+    from ray_tpu.models.decode import paged_prefill_into_slot
+
+    caches, tables = _arena(cfg, len(prompts), T, P)
+    prefill = jax.jit(partial(paged_prefill_into_slot, cfg, attn=attn))
+    lasts = []
+    for s, ids in enumerate(prompts):
+        for at in range(0, len(ids), CHUNK):
+            chunk = list(ids[at:at + CHUNK])
+            padded = chunk + [0] * (CHUNK - len(chunk))
+            last, caches = prefill(params, jnp.asarray([padded], jnp.int32),
+                                   np.int32(len(chunk)), np.int32(at),
+                                   tables[s], tables[s], caches)
+        lasts.append(np.asarray(last))
+    cursors = np.asarray([len(ids) for ids in prompts], np.int32)
+    return np.stack(lasts), caches, tables, cursors
+
+
 def _drive_lane(cfg, params, attn, prompts, new_tokens, T=4, P=8):
     """Prefill mixed-length prompts into slots, then greedy-decode
     ``new_tokens`` steps. Returns (tokens per slot, stacked logits, caches,
-    tables, cursors): the cursors are the caller's, the pool keeps none."""
+    tables, cursors)."""
     from functools import partial
 
-    from ray_tpu.models.decode import (paged_decode_step,
-                                       paged_prefill_into_slot)
+    from ray_tpu.models.decode import paged_decode_step
 
     S = len(prompts)
-    caches, tables = _arena(cfg, S, T, P)
-    prefill = jax.jit(partial(paged_prefill_into_slot, cfg, attn=attn),
-                      static_argnames=())
+    lasts, caches, tables, cursors = _paged_prefill(cfg, params, attn,
+                                                    prompts, T, P)
     step = jax.jit(partial(paged_decode_step, cfg, attn=attn))
-    next_tok = []
-    for s, ids in enumerate(prompts):
-        padded = list(ids) + [0] * (CHUNK - len(ids))
-        last, caches = prefill(params, jnp.asarray([padded], jnp.int32),
-                               np.int32(len(ids)), np.int32(0),
-                               tables[s], tables[s], caches)
-        next_tok.append(int(np.asarray(last).argmax()))
-    toks, active = np.asarray(next_tok, np.int32), np.ones(S, np.int32)
-    cursors = np.asarray([len(ids) for ids in prompts], np.int32)
-    out = [[t] for t in next_tok]
+    toks, active = lasts.argmax(-1).astype(np.int32), np.ones(S, np.int32)
+    out = [[int(t)] for t in toks]
     traces = []
     for _ in range(new_tokens):
         logits, caches = step(params, jnp.asarray(toks), jnp.asarray(active),
@@ -362,37 +375,102 @@ def _drive_lane(cfg, params, attn, prompts, new_tokens, T=4, P=8):
     return out, np.stack(traces), caches, tables, cursors
 
 
+def _sequential_logits(cfg, params, ids, feed):
+    """The oracle: ``decode.prefill`` of ``ids`` then one ``decode_step``
+    a token of ``feed``, over a ``LayerKVCache`` of one sequence. Returns
+    the logits after the prompt and after each fed token, [1 + len(feed),
+    vocab]."""
+    from ray_tpu.models.decode import decode_step, init_caches, prefill
+
+    caches = init_caches(cfg, 1, cfg.max_seq_len, jnp.float32)
+    logits, caches = prefill(cfg, params, jnp.asarray([ids], jnp.int32),
+                             caches)
+    out = [np.asarray(logits[0])]
+    for t in feed:
+        logits, caches = decode_step(cfg, params,
+                                     jnp.asarray([[t]], jnp.int32), caches)
+        out.append(np.asarray(logits[0]))
+    return np.stack(out)
+
+
+PAGED_PROGRAMS = ("paged_prefill_into_slot", "paged_decode_step",
+                  "paged_verify_step")
+
+
 class TestInPlaceLanes:
-    PROMPT_IDS = [[1, 2, 3], [4, 5, 6, 7], [8] * 8, [9, 10, 11, 12, 13]]
+    # mixed lengths: one exactly two pages (8 = 2 x T), one of two chunks
+    PROMPT_IDS = [[1, 2, 3], [4, 5, 6, 7], [8] * 8,
+                  [9, 10, 11, 12, 13, 14, 15, 16, 17, 18, 19, 20, 21]]
+
+    @staticmethod
+    def _close(got, want):
+        """The same token at temperature 0 and logits within a float32
+        tolerance (the paged op reduces in blocks, the oracle at once)."""
+        assert np.array_equal(got.argmax(-1), want.argmax(-1))
+        np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
+
+    @pytest.mark.parametrize("attn", ["reference", "pallas"])
+    @pytest.mark.parametrize("program", PAGED_PROGRAMS)
+    def test_program_matches_sequential_cache(self, tiny_model, program,
+                                              attn):
+        """Each paged program on each implementation against the
+        sequential cache, which knows no page, table or block."""
+        from functools import partial
+
+        from ray_tpu.models.decode import paged_verify_step
+
+        cfg, params = tiny_model
+        if program == "paged_prefill_into_slot":
+            lasts, *_ = _paged_prefill(cfg, params, attn, self.PROMPT_IDS)
+            for ids, last in zip(self.PROMPT_IDS, lasts):
+                self._close(last, _sequential_logits(cfg, params, ids,
+                                                     [])[0])
+        elif program == "paged_decode_step":
+            toks, traces, *_ = _drive_lane(cfg, params, attn,
+                                           self.PROMPT_IDS, NEW)
+            for s, ids in enumerate(self.PROMPT_IDS):
+                want = _sequential_logits(cfg, params, ids, toks[s][:-1])
+                assert toks[s] == [int(t) for t in want.argmax(-1)]
+                self._close(traces[:, s], want[1:])
+        else:
+            toks, _, caches, tables, cursors = _drive_lane(
+                cfg, params, attn, self.PROMPT_IDS, 1)
+            vt = np.asarray([[t[-1], 1, 2] for t in toks], np.int32)
+            verify = jax.jit(partial(paged_verify_step, cfg, attn=attn))
+            logits, _ = verify(params, jnp.asarray(vt),
+                               jnp.full(len(toks), 3, jnp.int32), cursors,
+                               tables, tables, caches)
+            for s, ids in enumerate(self.PROMPT_IDS):
+                want = _sequential_logits(cfg, params, ids,
+                                          toks[s][:1] + list(vt[s]))
+                self._close(np.asarray(logits)[s], want[2:])
 
     def test_decode_token_parity_and_pallas_bitwise(self, tiny_model):
-        """Temperature-0 token streams must be identical across all three
-        lanes under mixed prompt lengths (one exactly page-aligned), and
-        the pallas lane's logits must equal the reference lane's BITWISE
-        at every step."""
+        """Temperature-0 token streams must be identical on both
+        implementations under mixed prompt lengths (one exactly
+        page-aligned), and the kernel's logits must equal the reference's
+        BITWISE at every step."""
         cfg, params = tiny_model
-        gather, *_ = _drive_lane(cfg, params, "gather",
-                                 self.PROMPT_IDS, NEW)
         ref, ref_tr, *_ = _drive_lane(cfg, params, "reference",
                                       self.PROMPT_IDS, NEW)
         pal, pal_tr, *_ = _drive_lane(cfg, params, "pallas",
                                       self.PROMPT_IDS, NEW)
-        assert ref == gather, "in-place lane token stream diverged"
-        assert pal == gather
+        assert pal == ref
         assert np.array_equal(ref_tr, pal_tr), \
             "pallas logits diverged from reference bitwise"
 
     def test_verify_window_parity(self, tiny_model):
-        """A K=3 verify window after mixed-length prefill: per-position
-        argmax must agree across lanes (so acceptance decisions are
-        unchanged), pallas bitwise equal to reference."""
+        """A K=3 verify window after mixed-length prefill: the kernel
+        bitwise equal to the reference (so acceptance decisions are
+        unchanged), and a row marked inactive changes nothing for the
+        live rows."""
         from functools import partial
 
         from ray_tpu.models.decode import paged_verify_step
 
         cfg, params = tiny_model
         outs = {}
-        for attn in ("gather", "reference", "pallas"):
+        for attn in ("reference", "pallas"):
             toks, _, caches, tables, cursors = _drive_lane(
                 cfg, params, attn, self.PROMPT_IDS, 1)
             vt = np.asarray([[t[-1], 1, 2] for t in toks], np.int32)
@@ -409,20 +487,38 @@ class TestInPlaceLanes:
                                cursors, tables, tables, caches)
             assert np.array_equal(np.asarray(logits)[[0, 2]],
                                   outs[attn][[0, 2]]), attn
-        assert np.array_equal(outs["gather"].argmax(-1),
-                              outs["reference"].argmax(-1))
         assert np.array_equal(outs["reference"], outs["pallas"])
 
-    def test_unknown_lane_rejected_before_any_math(self):
-        from ray_tpu.models.decode import (paged_decode_step,
-                                           paged_prefill_into_slot,
-                                           paged_verify_step)
+    @pytest.mark.parametrize("program", PAGED_PROGRAMS)
+    def test_unknown_lane_rejected_before_any_math(self, tiny_model,
+                                                   program):
+        """``attn`` goes to ``paged_attention(impl=)``, which refuses what
+        it does not know — the deleted gather lane among it — while the
+        program is traced: nothing runs."""
+        import ray_tpu.models.decode as decode
 
-        for fn, nargs in ((paged_decode_step, 7),
-                          (paged_verify_step, 7),
-                          (paged_prefill_into_slot, 7)):
+        cfg, params = tiny_model
+        caches, tables = _arena(cfg, 2, 4, 8)
+        if program == "paged_prefill_into_slot":
+            args = (jnp.zeros((1, CHUNK), jnp.int32), np.int32(3),
+                    np.int32(0), tables[0], tables[0], caches)
+        else:
+            k = (2, 3) if program == "paged_verify_step" else (2,)
+            args = (jnp.zeros(k, jnp.int32), jnp.ones(2, jnp.int32),
+                    jnp.zeros(2, jnp.int32), tables, tables, caches)
+        for bad in ("gather", "turbo", "auto", ""):
             with pytest.raises(ValueError, match="unknown paged attention"):
-                fn(None, *([None] * nargs), attn="turbo")
+                jax.eval_shape(lambda *a, bad=bad: getattr(decode, program)(
+                    cfg, params, *a, attn=bad), *args)
+
+    @pytest.mark.parametrize("program", PAGED_PROGRAMS)
+    def test_attn_is_a_required_keyword(self, program):
+        """No default: a caller that names no implementation lands on none
+        (at the parent it ran the gathered-view lane, silently)."""
+        import ray_tpu.models.decode as decode
+
+        with pytest.raises(TypeError, match="attn"):
+            getattr(decode, program)(None, *([None] * 7))
 
 
 # ------------------------------------------------------------- dispatchers
@@ -443,61 +539,26 @@ class TestLaneResolution:
         assert out.shape == q.shape
 
     def test_resolver_choices_and_falsy_rejection(self):
-        from ray_tpu.ops.attention import resolve_paged_attn_lane
+        from ray_tpu.ops.paged_attention import resolve_impl
 
-        # conftest pins the backend to CPU: auto means the in-place
-        # pure-JAX lane, never a silent gather fallback
-        assert resolve_paged_attn_lane("auto") == "reference"
-        assert resolve_paged_attn_lane("gather") == "gather"
-        assert resolve_paged_attn_lane("pallas") == "pallas"
-        for bad in ("0", "", "off", "turbo"):
-            with pytest.raises(ValueError, match="RAY_TPU_SERVE_PAGED_ATTN"):
-                resolve_paged_attn_lane(bad)
-
-    def test_env_falsy_lane_fails_scheduler_build(self, monkeypatch):
-        """RAY_TPU_SERVE_PAGED_ATTN=0 must fail the CONSTRUCTOR — lane
-        resolution happens once at build, not on some later decode step."""
-        import ray_tpu._private.config as config_mod
-        from ray_tpu._private.config import Config
-        from ray_tpu.serve._private.continuous import ContinuousScheduler
-
-        class _Cfg:  # never reaches jit — validation fires first
-            max_seq_len = 128
-
-        monkeypatch.setenv("RAY_TPU_SERVE_PAGED_ATTN", "0")
-        monkeypatch.setattr(config_mod, "_global_config",
-                            Config.from_env(), raising=False)
-        try:
-            with pytest.raises(ValueError, match="paged attention lane"):
-                ContinuousScheduler(_Cfg(), None)
-        finally:
-            monkeypatch.setattr(config_mod, "_global_config", None,
-                                raising=False)
-
-    def test_attn_requires_paged_layout(self):
-        from ray_tpu.serve._private.continuous import ContinuousScheduler
-
-        class _Cfg:
-            max_seq_len = 128
-
-        with pytest.raises(ValueError, match="paged"):
-            ContinuousScheduler(_Cfg(), None, kv_layout="contiguous",
-                                attn="reference")
-
-    def test_attn_requires_continuous_scheduler(self):
-        from ray_tpu.serve.llm import LLMServerImpl
-
-        with pytest.raises(ValueError, match="continuous"):
-            LLMServerImpl(scheduler="batch", share_weights=False,
-                          attn="reference")
+        cfg = _tiny_cfg()
+        # conftest pins the backend to CPU: the platform's choice is the
+        # pure-JAX reference
+        assert resolve_impl(cfg) == "reference"
+        assert resolve_impl(cfg, "reference") == "reference"
+        assert resolve_impl(cfg, "pallas") == "pallas"
+        for bad in ("0", "", "off", "turbo", "auto", "gather"):
+            with pytest.raises(ValueError, match="unknown paged attention"):
+                resolve_impl(cfg, bad)
 
 
 # ---------------------------------------------------------- the counters
 
 
 class TestAttnCounters:
-    """``_record_attn`` mirrors on the host what each lane fetches, in
-    whole blocks of ``tile_sizes`` pages, against what is attended."""
+    """``_record_attn`` adds up what ``streamed_tokens`` says each
+    implementation fetches, in whole blocks of ``tile_sizes`` pages,
+    against what is attended."""
 
     @staticmethod
     def _scheduler(lane):
@@ -508,7 +569,7 @@ class TestAttnCounters:
         sch = object.__new__(ContinuousScheduler)  # the counters only
         sch.cfg = types.SimpleNamespace(num_heads=32, kv_heads=8,
                                         head_dim=128, num_layers=2)
-        sch._paged, sch.attn_lane = True, lane
+        sch.attn_lane = lane
         sch.page_tokens, sch._pages_per_slot, sch._kv_itemsize = 16, 256, 2
         sch._n_attn_bytes = sch._n_attn_attended = sch._n_attn_fetched = 0
         return sch
@@ -519,8 +580,6 @@ class TestAttnCounters:
         ("pallas", 11 + 601 + 1024, (1 + 2 + 2) * 512),
         # every row, idle ones too, over the longest row's two blocks
         ("reference", 11 + 601 + 1024, 5 * 2 * 512),
-        # every row's whole provisioned view
-        ("gather", 11 + 601 + 1024, 5 * 256 * 16),
     ])
     def test_decode_step(self, lane, attended, fetched):
         sch = self._scheduler(lane)
@@ -577,20 +636,22 @@ class TestSchedulerLanes:
                 return await asyncio.gather(*[srv(r) for r in reqs])
 
             outs = asyncio.run(go())
+            if attn == "reference":  # the kernel is held to this lane
+                refs = {p: _sequential_reference(srv, p) for p in PROMPTS}
+                assert [o["text"] for o in outs] == [
+                    refs[p] for p in PROMPTS * 3]
             return [o["text"] for o in outs], srv.scheduler_stats()
         finally:
             srv.shutdown()
 
     def test_token_streams_identical_across_lanes(self):
-        """The acceptance bar: temperature-0 token streams from the
-        in-place lanes are identical to the gathered-view lane under mixed
-        lengths, slot reuse (3x slots) and prefix hits — and every lane
-        keeps the two-compiles contract. The gather lane's byte accounting
-        must dwarf the in-place lanes' (it materializes the full
-        provisioned view every step)."""
+        """The acceptance bar: temperature-0 token streams are identical on
+        both implementations — and equal to the sequential cache's — under
+        mixed lengths, slot reuse (3x slots) and prefix hits, and both
+        keep the two-compiles contract."""
         texts = {}
         stats = {}
-        for lane in ("gather", "reference", "pallas"):
+        for lane in ("reference", "pallas"):
             texts[lane], stats[lane] = self._drive(lane)
             assert stats[lane]["attn_lane"] == lane
             assert stats[lane]["compiled_programs"] == 2, stats[lane]
@@ -599,10 +660,7 @@ class TestSchedulerLanes:
             # the block fill share: what was attended of what was fetched
             assert 0 < stats[lane]["attn_tokens_attended"] \
                 <= stats[lane]["attn_tokens_fetched"]
-        assert texts["reference"] == texts["gather"]
-        assert texts["pallas"] == texts["gather"]
-        assert stats["gather"]["attn_bytes_moved"] > \
-            2 * stats["reference"]["attn_bytes_moved"]
+        assert texts["pallas"] == texts["reference"]
         # the kernel fetches each slot's own blocks, the reference every
         # row over the longest row's: never more
         assert stats["pallas"]["attn_tokens_fetched"] \

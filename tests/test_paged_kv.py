@@ -3,8 +3,8 @@ path (ISSUE 13; ROADMAP item 3).
 
 Covers: the page allocator and radix tree units (insert/match/refcount/
 evict, partial-prefix splice at page boundaries), temperature-0 parity of
-the paged scheduler against both the sequential single-request reference
-AND the PR-9 contiguous arena under mixed lengths + slot/page reuse, the
+the paged scheduler against the sequential single-request reference
+under mixed lengths + slot/page reuse, the
 ~10x-concurrency admission contract at fixed arena bytes, the two-compiles
 guard (compile counter unchanged across mixed paged workloads — shape
 churn would show up here), loud rejection of falsy-zero knobs and
@@ -245,32 +245,9 @@ class TestPagedParity:
             assert o["text"] == refs[o["prompt"]], \
                 f"paged output diverged for {o['prompt']!r}"
         st = server.scheduler_stats()
-        assert st["kv_layout"] == "paged"
         assert st["prefix_hits"] > 0, "repeats never hit the radix cache"
         assert st["admitted_mid_flight"] > 0
         assert st["max_active_slots"] >= 2
-
-    def test_paged_equals_contiguous_arena(self, server):
-        """Paging relocates KV bytes but must not change a single attended
-        value: the same prompts through the PR-9 contiguous arena yield
-        identical text."""
-        from ray_tpu.serve.llm import LLMServerImpl
-
-        base = LLMServerImpl(max_new_tokens=NEW, slots=SLOTS,
-                             prefill_chunk=CHUNK, kv_layout="contiguous",
-                             share_weights=False)
-        try:
-            async def drive(srv):
-                return await asyncio.gather(*[
-                    srv({"prompt": p}) for p in PROMPTS])
-
-            paged = asyncio.run(drive(server))
-            contig = asyncio.run(drive(base))
-            assert base.scheduler_stats()["kv_layout"] == "contiguous"
-            for a, b in zip(paged, contig):
-                assert a["text"] == b["text"]
-        finally:
-            base.shutdown()
 
     def test_two_compiles_contract_across_mixed_paged_workloads(
             self, server):
@@ -361,19 +338,6 @@ class TestKnobValidation:
         with pytest.raises(ValueError, match="multiple"):
             ContinuousScheduler(self._cfg(), None, arena_len=100,
                                 page_tokens=16)
-
-    def test_prefix_cache_requires_paged_layout(self, monkeypatch):
-        from ray_tpu.serve._private.continuous import ContinuousScheduler
-
-        with pytest.raises(ValueError, match="prefix_cache"):
-            ContinuousScheduler(self._cfg(), None, kv_layout="contiguous",
-                                prefix_cache=True)
-        # explicit ENV intent conflicts just as loudly as the argument
-        # (the config DEFAULT, by contrast, simply doesn't apply to the
-        # contiguous baseline)
-        monkeypatch.setenv("RAY_TPU_SERVE_PREFIX_CACHE", "1")
-        with pytest.raises(ValueError, match="prefix_cache"):
-            ContinuousScheduler(self._cfg(), None, kv_layout="contiguous")
 
     def test_negative_kv_pages_rejected(self):
         from ray_tpu.serve._private.continuous import ContinuousScheduler

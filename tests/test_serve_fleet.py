@@ -10,7 +10,7 @@ and a failed migration must degrade to a cold prefill with the same
 tokens.
 
 The end-to-end fleet path (4 replicas through the real control plane)
-is exercised by `bench_serve.py --fleet` and `chaos_soak --fleet`; this
+is exercised by `chaos_soak --fleet`; this
 suite covers the in-process contracts: chain-hash/digest construction,
 router steering + skew/fail fallback + hint injection, the migration
 splice's refcount/eviction hygiene, speculative parity and acceptance
@@ -702,10 +702,6 @@ class TestKnobValidation:
     def test_explicit_zero_migration_budget_rejected(self):
         with pytest.raises(ValueError, match="migration_budget"):
             _mk_server(migration_budget=0)
-
-    def test_drafter_requires_continuous(self):
-        with pytest.raises(ValueError, match="continuous"):
-            _mk_server(scheduler="batch", drafter="self")
 
     def test_unknown_drafter_preset_rejected(self):
         with pytest.raises(ValueError, match="drafter"):

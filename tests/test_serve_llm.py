@@ -10,10 +10,9 @@ item errors, deploy-time overrides), and the shared-weights pin
 accounting (second replica adds no arena bytes; replica death releases
 its pins).
 
-Since ISSUE 13 the scheduler's default KV layout is PAGED with the radix
-prefix cache on — this suite intentionally runs the defaults end to end;
-the paged/radix-specific contracts (parity vs the contiguous arena,
-capacity at fixed pool bytes, eviction, two-compiles guard) live in
+The scheduler's KV pool is PAGED with the radix prefix cache on — this
+suite runs the defaults end to end; the paged/radix-specific contracts
+(capacity at fixed pool bytes, eviction, two-compiles guard) live in
 tests/test_paged_kv.py.
 """
 
@@ -65,6 +64,15 @@ def _sequential_reference(srv, prompt: str, new_tokens: int):
         logits, caches = srv._decode_step(
             srv.params, jnp.asarray([[t]], jnp.int32), caches)
     return srv._detokenize(out)
+
+
+def _scheduler_on_a_stub(**kwargs):
+    from ray_tpu.serve._private.continuous import ContinuousScheduler
+
+    class _Cfg:  # never reaches jit — validation fires first
+        max_seq_len = 128
+
+    return ContinuousScheduler(_Cfg(), None, **kwargs)
 
 
 class TestContinuousParity:
@@ -197,32 +205,23 @@ class TestSlotLifecycle:
     def test_explicit_zero_knobs_rejected(self):
         """slots=0 / prefill_chunk=0 must raise, not silently take the
         config default (the PR-8 falsy-zero lesson)."""
-        from ray_tpu.serve._private.continuous import ContinuousScheduler
-
-        class _Cfg:  # never reaches jit — validation fires first
-            max_seq_len = 128
-
         with pytest.raises(ValueError, match="slots"):
-            ContinuousScheduler(_Cfg(), None, slots=0)
+            _scheduler_on_a_stub(slots=0)
         with pytest.raises(ValueError, match="prefill_chunk"):
-            ContinuousScheduler(_Cfg(), None, prefill_chunk=0)
+            _scheduler_on_a_stub(prefill_chunk=0)
 
-    def test_batch_mode_validates_request_knobs(self):
-        """The request-level baseline must guard the user-controlled
-        generation budget before it sizes a KV cache, and refuse (not
-        silently ignore) per-request temperatures it cannot honor."""
-        srv = LLMServerImpl(max_new_tokens=4, scheduler="batch",
-                            share_weights=False)
-
-        async def drive():
-            with pytest.raises(ValueError, match="max_seq_len"):
-                await srv({"prompt": "hi", "max_new_tokens": 10_000})
-            with pytest.raises(ValueError, match="temperature"):
-                await srv({"prompt": "hi", "temperature": 0.7})
-            out = await srv({"prompt": "hi", "max_new_tokens": 2})
-            assert out["num_tokens"] == 2
-
-        asyncio.run(drive())
+    @pytest.mark.parametrize("build,error", [
+        (lambda: LLMServerImpl(scheduler="batch", share_weights=False),
+         TypeError),
+        (lambda: LLMServerImpl(kv_layout="contiguous", share_weights=False),
+         TypeError),
+        (lambda: _scheduler_on_a_stub(attn="gather"), ValueError),
+    ], ids=["scheduler=batch", "kv_layout=contiguous", "attn=gather"])
+    def test_deleted_options_are_refused(self, build, error):
+        """There is one scheduler, one KV layout and no gathered-view lane:
+        asking for another raises, it is not ignored."""
+        with pytest.raises(error, match="scheduler|kv_layout|gather"):
+            build()
 
     def test_shutdown_fails_inflight_cleanly(self):
         srv = LLMServerImpl(max_new_tokens=NEW, slots=2, prefill_chunk=CHUNK,

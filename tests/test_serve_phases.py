@@ -279,14 +279,12 @@ def test_an_admission_runs_no_device_program(tmp_path):
         fresh.shutdown()
 
 
-@pytest.mark.parametrize("layout,names", [
-    ("paged", ("paged_prefill_chunk", "paged_decode_step")),
-    ("contiguous", ("slot_prefill_chunk", "slot_decode_step"))])
-def test_the_scheduler_jits_named_functions(layout, names):
-    srv = _server(kv_layout=layout)
+def test_the_scheduler_jits_named_functions():
+    srv = _server()
     try:
         sched = srv._sched
-        assert (sched._prefill.__name__, sched._step.__name__) == names
+        assert (sched._prefill.__name__, sched._step.__name__) == (
+            "paged_prefill_chunk", "paged_decode_step")
         assert _drive(srv, ["named"])[0]
         assert sched.compiled_programs() == 2
     finally:

@@ -168,22 +168,24 @@ class TestPagedShapeRule:
 
     def test_explicit_pallas_raises_and_auto_takes_reference(self):
         from ray_tpu.models import gpt2_small, llama_debug
-        from ray_tpu.ops.attention import resolve_paged_attn_lane
+        from ray_tpu.ops.paged_attention import resolve_impl
 
         with pytest.raises(ValueError, match="cannot compile"):
-            resolve_paged_attn_lane("pallas", llama_debug())
-        assert resolve_paged_attn_lane("auto", llama_debug()) == "reference"
-        assert resolve_paged_attn_lane("auto", gpt2_small()) == "pallas"
-        assert resolve_paged_attn_lane("pallas", gpt2_small()) == "pallas"
+            resolve_impl(llama_debug(), "pallas")
+        assert resolve_impl(llama_debug()) == "reference"
+        assert resolve_impl(gpt2_small()) == "pallas"
+        assert resolve_impl(gpt2_small(), "pallas") == "pallas"
+        with pytest.raises(ValueError, match="unknown paged attention"):
+            resolve_impl(gpt2_small(), "gather")
 
     def test_interpreted_kernel_takes_any_shape(self, monkeypatch):
         """Off-TPU the kernel is interpreted: the PR 20 tests drive
         llama_debug through the explicit 'pallas' lane on the CPU."""
         from ray_tpu.models import llama_debug
-        from ray_tpu.ops.attention import resolve_paged_attn_lane
+        from ray_tpu.ops.paged_attention import resolve_impl
 
         monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
-        assert resolve_paged_attn_lane("pallas", llama_debug()) == "pallas"
+        assert resolve_impl(llama_debug(), "pallas") == "pallas"
 
 
 # ----------------------------------------------------------- train programs
@@ -270,13 +272,13 @@ def test_gpt2s_serve_programs_compile_and_fit(v5e):
                                        paged_prefill_into_slot,
                                        paged_verify_step)
     from ray_tpu.models.transformer import init_params
-    from ray_tpu.ops.attention import resolve_paged_attn_lane
+    from ray_tpu.ops.paged_attention import resolve_impl
 
     cfg, conf = gpt2_small(), Config()
     slots, chunk, T = conf.serve_slots, conf.serve_prefill_chunk, \
         conf.serve_page_tokens
     pages = cfg.max_seq_len // T
-    lane = resolve_paged_attn_lane("auto", cfg)
+    lane = resolve_impl(cfg)
     assert lane == "pallas"
     chip = SingleDeviceSharding(v5e.devices[0])
 
@@ -320,7 +322,7 @@ def test_olmoe_serve_programs_compile_and_fit(v5e):
     from ray_tpu.models.decode import (init_paged_caches, paged_decode_step,
                                        paged_prefill_into_slot)
     from ray_tpu.models.transformer import init_params
-    from ray_tpu.ops.attention import resolve_paged_attn_lane
+    from ray_tpu.ops.paged_attention import resolve_impl
 
     manifest = manifest_lib.load()
     hp = manifest_lib.config(manifest, "olmoe_1b_7b_l8")
@@ -330,7 +332,7 @@ def test_olmoe_serve_programs_compile_and_fit(v5e):
                                  "olmoe_reason")["deployment"]
     slots, chunk, T = dep["slots"], dep["prefill_chunk"], dep["page_tokens"]
     pages = dep["arena_len"] // T
-    lane = resolve_paged_attn_lane("auto", cfg)
+    lane = resolve_impl(cfg)
     assert lane == "pallas"
     chip = SingleDeviceSharding(v5e.devices[0])
 
