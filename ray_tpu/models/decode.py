@@ -86,15 +86,35 @@ def decode_step(cfg: TransformerConfig, params, token, caches):
     return logits[:, -1], caches
 
 
-def sample_token(logits, key, temperature: float = 0.0, top_k: int = 0):
-    """Greedy (temperature 0) or temperature/top-k sampling. [B,V] -> [B]."""
-    if temperature == 0.0:
-        return jnp.argmax(logits, axis=-1)
-    logits = logits / temperature
-    if top_k > 0:
-        top = jax.lax.top_k(logits, top_k)[0][..., -1:]
-        logits = jnp.where(logits < top, -1e30, logits)
-    return jax.random.categorical(key, logits, axis=-1)
+def sample_token(logits, temperature, seeds, positions, top_k: int = 0):
+    """THE device sampler: logits [rows, vocab] -> ids [rows] int32, every
+    row by its own ``temperature`` [rows] float32. A row at 0 takes the
+    argmax of its float32 logits (the first maximum wins, as NumPy's does).
+    A row above 0 draws from ``softmax(logits / T)`` (of the ``top_k``
+    largest, if given) with a key folded from its entry of ``seeds``
+    [rows] uint32 and of ``positions`` [rows] int32, the index in its
+    sequence of the token being drawn: a request's stream follows from its
+    seed alone, whichever row or batch it rides in. A call all of whose
+    rows are at 0 draws no random bits."""
+    logits = logits.astype(jnp.float32)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def draw():
+        def one(row, t, seed, position):
+            row = row / t
+            if top_k > 0:
+                row = jnp.where(row < lax.top_k(row, top_k)[0][-1], -1e30,
+                                row)
+            key = jax.random.fold_in(
+                jax.random.fold_in(jax.random.key(0), seed), position)
+            return jax.random.categorical(key, row).astype(jnp.int32)
+
+        drawn = jax.vmap(one)(logits, jnp.where(temperature > 0,
+                                                temperature, 1.0),
+                              seeds, positions)
+        return jnp.where(temperature > 0, drawn, greedy)
+
+    return lax.cond(jnp.any(temperature > 0), draw, lambda: greedy)
 
 
 # ---------------------------------------------------------------------------
@@ -342,10 +362,13 @@ def _paged_forward_inplace(cfg: TransformerConfig, params, tokens, positions,
     return logits, new_caches, moe
 
 
-def _paged_outputs(first, caches, moe, moe_info: bool):
-    """What a paged program returns: ``(first, caches)``, and with
-    ``moe_info`` the expert layers' counts and routes as a third."""
-    return (first, caches, moe) if moe_info else (first, caches)
+def _paged_outputs(first, caches, moe, moe_info: bool, logits):
+    """What a paged program returns: ``(first, caches)``, with ``moe_info``
+    the expert layers' counts and routes next, and last the ``logits`` the
+    ids in ``first`` were sampled from where the caller asked for them
+    (else None)."""
+    return ((first, caches) + ((moe,) if moe_info else ())
+            + (() if logits is None else (logits,)))
 
 
 def _check_moe_info(cfg: TransformerConfig, moe_info: bool):
@@ -356,8 +379,9 @@ def _check_moe_info(cfg: TransformerConfig, moe_info: bool):
 
 def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
                             cursor, read_row, write_row,
-                            caches: List[PagedKVCache], *,
-                            attn: str, moe_info: bool = False):
+                            caches: List[PagedKVCache], ids, slot,
+                            temperature, seed, *, attn: str,
+                            moe_info: bool = False, logits: bool = False):
     """One prefill chunk into ONE slot, through its page table. tokens:
     [1, C] — the next C prompt tokens, zero-padded past ``real_len`` (so
     every chunk size compiles to the same program). The chunk lands at
@@ -371,51 +395,81 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
     content is immutable here. ``attn``: the implementation
     ``ops.paged_attention`` runs ('reference' | 'pallas').
 
+    The chunk samples (``sample_token``, by ``temperature`` and ``seed``,
+    scalars) the token that follows its last REAL row, and where the chunk
+    is the prompt's last puts it where the next decode step reads it: ids
+    [slots] int32 is the vector ``paged_decode_step`` takes as its tokens,
+    ``slot`` the row that takes the sampled id, -1 for a chunk that is not
+    the last (the vector is then returned as it came). The first token so
+    reaches the step without a visit to the host.
+
     Caller contract (scheduler-enforced): every page covering the REAL
     tokens [cursor, cursor + real_len) is allocated and OWNED (write_row
     == read_row there); pad positions beyond real_len may fall on
     unallocated entries — their writes redirect to the garbage page and
     their reads are causally masked. cursor + C fits the logical view.
 
-    Returns (logits [vocab] at the last REAL token, caches) — only the final
-    chunk's logits are meaningful; with ``moe_info`` (mlp='moe') a third
+    Returns (ids [slots], caches); with ``moe_info`` (mlp='moe') a third
     value, the expert layers' ``{"counts": [L, E], "routes": [L, 1, C,
-    k]}``; the chunk's padding past ``real_len`` is routed nowhere and not
-    counted."""
+    k]}`` (the chunk's padding past ``real_len`` is routed nowhere and not
+    counted); with ``logits`` the float32-castable logits [vocab] at the
+    last REAL token come last (tests compare them with an oracle; the
+    scheduler never asks)."""
     _check_moe_info(cfg, moe_info)
     steps = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
-    logits, new_caches, moe = _paged_forward_inplace(
+    all_logits, new_caches, moe = _paged_forward_inplace(
         cfg, params, tokens, steps + cursor, jnp.reshape(cursor, (1,)),
         read_row[None], write_row[None], caches, attn, steps < real_len)
-    last = lax.dynamic_index_in_dim(logits[0], real_len - 1, keepdims=False)
-    return _paged_outputs(last, new_caches, moe, moe_info)
+    last = lax.dynamic_index_in_dim(all_logits[0], real_len - 1,
+                                    keepdims=False)
+    first = sample_token(last[None], jnp.reshape(temperature, (1,)),
+                         jnp.reshape(seed, (1,)),
+                         jnp.reshape(cursor + real_len, (1,)))[0]
+    ids = jnp.where(jnp.arange(ids.shape[0]) == slot, first, ids)
+    return _paged_outputs(ids, new_caches, moe, moe_info,
+                          last if logits else None)
 
 
 def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
                       cursors, read_tables, write_tables,
-                      caches: List[PagedKVCache], *, attn: str,
-                      moe_info: bool = False):
+                      caches: List[PagedKVCache], temperature, seeds, *,
+                      attn: str, moe_info: bool = False,
+                      logits: bool = False):
     """One fixed-shape decode step over the whole arena, through page
     tables. tokens/active/cursors: [slots] int32; read_tables/write_tables:
     [slots, P] int32. Row s's token is written at ``pool[page, offset]`` of
     logical position cursors[s] and attends [0, cursors[s]] through the
     read table; the caller advances the cursors of its active rows by one.
     An inactive row attends nothing (it streams no page, and the expert
-    layer routes it nowhere) and its logits are dropped, but it WRITES at
-    its cursor like any other: the caller's tables send that write to the
-    garbage page, or to a position the row's own sequence writes again
-    before attending it. ``attn``: the implementation
-    ``ops.paged_attention`` runs ('reference' | 'pallas').
+    layer routes it nowhere), but it WRITES at its cursor like any other:
+    the caller's tables send that write to the garbage page, or to a
+    position the row's own sequence writes again before attending it.
+    ``attn``: the implementation ``ops.paged_attention`` runs ('reference'
+    | 'pallas').
 
-    Returns (logits [slots, vocab], caches); with ``moe_info`` (mlp='moe') a
-    third value, the expert layers' ``{"counts": [L, E], "routes": [L,
-    slots, 1, k]}`` over the active rows."""
+    The step samples (``sample_token``; temperature [slots] float32, seeds
+    [slots] uint32, the position of the new token cursors + 1) and returns
+    ids [slots] int32: an active row's next token, an inactive row's
+    ``tokens`` entry as it came. The result is the next step's ``tokens``
+    as it stands, so a token reaches the step that consumes it without a
+    visit to the host.
+
+    Returns (ids [slots], caches); with ``moe_info`` (mlp='moe') a third
+    value, the expert layers' ``{"counts": [L, E], "routes": [L, slots, 1,
+    k]}`` over the active rows; with ``logits`` the logits [slots, vocab]
+    the ids were sampled from come last (tests compare them with an
+    oracle; the scheduler never asks)."""
     _check_moe_info(cfg, moe_info)
-    logits, new_caches, moe = _paged_forward_inplace(
+    all_logits, new_caches, moe = _paged_forward_inplace(
         cfg, params, tokens[:, None], cursors[:, None],
         jnp.where(active > 0, cursors, -1),
         read_tables, write_tables, caches, attn, active[:, None] > 0)
-    return _paged_outputs(logits[:, 0], new_caches, moe, moe_info)
+    sampled = sample_token(all_logits[:, 0],
+                           jnp.where(active > 0, temperature, 0.0), seeds,
+                           cursors + 1)
+    ids = jnp.where(active > 0, sampled, tokens)
+    return _paged_outputs(ids, new_caches, moe, moe_info,
+                          all_logits[:, 0] if logits else None)
 
 
 def paged_verify_step(cfg: TransformerConfig, params, tokens, active,
@@ -455,7 +509,7 @@ def paged_verify_step(cfg: TransformerConfig, params, tokens, active,
         cfg, params, tokens, cursors[:, None] + steps,
         jnp.where(active > 0, cursors, -K),
         read_tables, write_tables, caches, attn, steps < active[:, None])
-    return _paged_outputs(logits, new_caches, moe, moe_info)
+    return _paged_outputs(logits, new_caches, moe, moe_info, None)
 
 
 @partial(jax.jit, static_argnums=(0, 4, 5, 6))
@@ -473,13 +527,16 @@ def generate(cfg: TransformerConfig, params, prompt, key,
         )
     caches = init_caches(cfg, batch, prompt_len + max_new_tokens)
     logits, caches = prefill(cfg, params, prompt, caches)
+    temperatures = jnp.full((batch,), temperature, jnp.float32)
+    seeds = jax.random.bits(key, (batch,), jnp.uint32)
 
-    def body(carry, step_key):
+    def body(carry, position):
         logits, caches = carry
-        tok = sample_token(logits, step_key, temperature, top_k)
+        tok = sample_token(logits, temperatures, seeds,
+                           jnp.full((batch,), position), top_k)
         logits, caches = decode_step(cfg, params, tok[:, None], caches)
         return (logits, caches), tok
 
-    keys = jax.random.split(key, max_new_tokens)
-    (_, _), toks = lax.scan(body, (logits, caches), keys)
+    (_, _), toks = lax.scan(body, (logits, caches),
+                            prompt_len + jnp.arange(max_new_tokens))
     return toks.T  # [B, T]

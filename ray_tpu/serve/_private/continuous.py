@@ -19,6 +19,22 @@ The device-side program shapes are compiled once (``paged_prefill_chunk``,
 occupy which slots and pages. All jax work runs on the scheduler's own
 thread — the replica's asyncio event loop only ever touches queues.
 
+The loop runs ONE STEP AHEAD of what it has read. Both programs sample
+(``decode.sample_token``: argmax at temperature 0, else a draw keyed by the
+request's seed and the token's position) and hand the ids on in a device
+vector ``[slots]`` that the next step takes as its tokens, so a token never
+visits the host on its way to the step that consumes it. A turn is: admit;
+dispatch a chunk if one is due; prepare and dispatch step n+1 from what the
+host knows without step n's result (cursors advance by one a live row; a
+row whose budget step n exhausts is not in n+1); THEN wait for step n's ids
+(4 bytes a slot), emit and retire. What the host learns only from an id —
+EOS — and what it learns between turns — a cancellation, an exhausted
+pool — therefore arrives one step late: the row may ride in one more step,
+whose id is discarded (``discarded_rows``) and never emitted
+(``_release_slot_resources`` says why its K/V write harms nobody). The
+speculative path needs whole logits on the host to accept and resample, so
+it reads before every dispatch.
+
 There is one KV layout and one path to the kernel. A slot owns a page table
 instead of a contiguous worst-case ``arena_len`` range, so long/idle
 sequences reserve no memory they never use; each program writes the new
@@ -76,16 +92,16 @@ from ray_tpu._private.metrics import Counter, Gauge, Histogram
 # by the phase that overlaps it) and cumulative seconds in ``stats()``
 # (``phase_<name>_s``). No phase encloses another and none adds a device
 # wait: the two ``*.wait`` phases stand directly before a host read that
-# would block anyway. The transitions of a loop turn do not grow with the
-# number of slots.
+# would block anyway, the read of a program dispatched a turn earlier. The
+# transitions of a loop turn do not grow with the number of slots.
 PHASES = (
     "serve.admit",           # commands, migrations, admission, slot reset
     "serve.prefill",         # build, upload and dispatch of one chunk
-    "serve.prefill.wait",    # last chunk only: its logits are sampled from
+    "serve.prefill.wait",    # the device is working on a chunk read from
     "serve.decode.prepare",  # arrays, pages, table upload, dispatch
-    "serve.decode.wait",     # the device is working on the step
-    "serve.decode.fetch",    # the step's logits to the host
-    "serve.sample",          # sampling and per-sequence bookkeeping
+    "serve.decode.wait",     # the device is working on the step read from
+    "serve.decode.fetch",    # a program's ids (4 bytes a slot) to the host
+    "serve.sample",          # whose token is whose; the rows to discard
     "serve.emit",            # hand-off to the consumers' event loop, retire
     "serve.park",            # nothing to do: waiting to be woken
     "serve.verify",          # speculative path: the verify call and fetch
@@ -105,6 +121,9 @@ _F_RETIRE = flight.intern("serve.retire")
 # instant's argument is ``microseconds << 8 | index into PHASES`` of the
 # phase that held most of it
 _F_STALL = flight.intern("serve.stall")
+# the thread read a result with no program queued behind it: the device
+# stands idle until the next dispatch (argument: the programs read)
+_F_DRAIN = flight.intern("serve.drain")
 _STALL_NS = 1_000_000_000  # four slow turns of 0.16-0.27 s (PERF.md)
 
 _m_steps = Counter(
@@ -163,9 +182,11 @@ class _Seq:
     """One in-flight generation request and its consumer-side queue."""
 
     __slots__ = ("prompt", "remaining_prompt", "max_new", "temperature",
-                 "seed", "slot", "state", "n_generated", "next_token",
+                 "seed", "slot", "state", "n_generated", "n_launched",
+                 "next_token",
                  "queue", "loop", "cancelled", "rid", "t_submit", "t_admit",
-                 "t_first_token", "rng", "cached_len", "cursor", "owned_pages", "radix_node",
+                 "t_first_token", "rng", "cached_len", "cursor",
+                 "owned_pages", "radix_node",
                  "table_fill", "fleet_hint", "migration_node",
                  "drafter_len", "drafter_pending")
 
@@ -176,18 +197,21 @@ class _Seq:
         self.remaining_prompt = list(prompt)
         self.max_new = max_new
         self.temperature = temperature
-        self.seed = seed
+        self.seed = seed & 0xFFFFFFFF  # a sampling key folds 32 bits in
         self.slot: Optional[int] = None
         self.state = _QUEUED
-        self.n_generated = 0
-        self.next_token: Optional[int] = None
+        self.n_generated = 0           # tokens read and emitted
+        # tokens whose program has been dispatched, read or not: the host
+        # knows a sequence's length a step before it knows its last token
+        self.n_launched = 0
+        self.next_token: Optional[int] = None  # newest token read
         self.queue = queue
         self.loop = loop
         self.cancelled = False
         self.t_submit = time.monotonic()
         self.t_admit: Optional[float] = None
         self.t_first_token: Optional[float] = None
-        self.rng = None  # lazily created numpy Generator for temperature > 0
+        self.rng = None  # the speculative path's numpy Generator (T > 0)
         # ---- paged-arena bookkeeping (the device holds pages only) ----
         self.cached_len = 0            # spliced prefix tokens (page-aligned)
         self.cursor = 0                # tokens resident: THE slot's cursor
@@ -202,6 +226,27 @@ class _Seq:
         self.drafter_pending: List[int] = []  # tokens drafter must catch up
 
 
+def _deliver(batch) -> None:
+    """On a consumers' event loop: one program's items into their queues."""
+    for seq, item in batch:
+        seq.queue.put_nowait(item)
+
+
+class _Launched:
+    """One dispatched program whose result the host has not read yet."""
+
+    __slots__ = ("serial", "step", "ids", "rows", "moe", "live_rows")
+
+    def __init__(self, serial: int, step: bool, ids, rows: List[_Seq], moe,
+                 live_rows: int):
+        self.serial = serial        # its number among the dispatched programs
+        self.step = step            # a decode step (else a prefill chunk)
+        self.ids = ids              # the program's [slots] ids, on the device
+        self.rows = rows            # the sequences it sampled a token for
+        self.moe = moe              # an expert model's counts, on the device
+        self.live_rows = live_rows  # the live rows the host handed it
+
+
 class ContinuousScheduler:
     """Continuous-batching decode scheduler over a paged KV pool.
 
@@ -211,7 +256,9 @@ class ContinuousScheduler:
     [1, prefill_chunk]) and a decode step (``paged_decode_step``, [slots])
     — both with donated caches so the pool updates in place instead of
     being copied per iteration. It also owns every slot's page table and
-    cursor and passes them with each call. ``attn``: the paged-attention
+    cursor and passes them with each call; the sampled ids stay on the
+    device (``_ids``, never donated: the host reads each vector one program
+    later). ``attn``: the paged-attention
     implementation, ``None`` for ``ops.paged_attention.resolve_impl``'s
     answer (the kernel on a TPU, the reference elsewhere).
     """
@@ -298,7 +345,7 @@ class ContinuousScheduler:
         # always names what really runs
         self.attn_lane = resolve_impl(cfg, attn)
         # an expert layer's programs hand the rows each expert received
-        # back with the logits
+        # back with the ids
         self._moe = cfg.mlp == "moe"
         program_kw = {"attn": self.attn_lane}
         if self._moe:
@@ -315,6 +362,19 @@ class ContinuousScheduler:
             cfg, self.num_pages, self.page_tokens,
             self._pages_per_slot, cache_dtype)
         self._kv_itemsize = int(self._caches[0].k.dtype.itemsize)
+        # the newest token of every slot, as the programs left it: a chunk
+        # that ends a prompt sets its row, a step replaces its active rows,
+        # and the next step takes the vector as its tokens
+        self._ids = jax.numpy.zeros((self.slots,), jax.numpy.int32)
+        # programs dispatched and not read yet, oldest first; the loop
+        # reads a turn behind what it dispatches
+        self._inflight: deque = deque()
+        self._serial = 0           # programs dispatched so far
+        self._steps_unread = 0     # decode steps among _inflight
+        self._outbox: Optional[Dict[Any, list]] = None  # see _emit
+        self._n_runahead = 0
+        self._n_drains = 0
+        self._n_discarded = 0
         # ---- speculative decoding (ISSUE 18): the drafter proposes, one
         # extra fixed-shape verify program scores — the two-compiles
         # contract becomes exactly three with speculation on
@@ -374,7 +434,6 @@ class ContinuousScheduler:
         self._n_attn_fetched = 0
         # expert layers (mlp='moe'): what the device's counts add up to,
         # beside the live rows the host handed it
-        self._moe_pending: List[Any] = []
         self._n_moe_live_rows = 0
         self._n_moe_layer_calls = 0
         self._n_moe_rows_routed = 0
@@ -480,17 +539,45 @@ class ContinuousScheduler:
         between the two threads (``stream_lag_s`` in the replica)."""
         if seq.loop is None or seq.queue is None:
             return
+        item = (kind, value, flight.now())
+        if self._outbox is not None:
+            # a program's tokens leave together (_collect): one wake-up of
+            # the consumers' loop a step, not one a token
+            self._outbox.setdefault(seq.loop, []).append((seq, item))
+            return
         try:
-            seq.loop.call_soon_threadsafe(
-                seq.queue.put_nowait, (kind, value, flight.now()))
+            seq.loop.call_soon_threadsafe(seq.queue.put_nowait, item)
         except RuntimeError:
             # consumer's loop is gone — nobody is listening; retire quietly
             seq.cancelled = True
 
+    def _hand_over(self) -> None:
+        """Send what ``_emit`` gathered while ``_outbox`` was open, in
+        order, with one call into each consumers' loop."""
+        outbox, self._outbox = self._outbox, None
+        for loop, batch in outbox.items():
+            try:
+                loop.call_soon_threadsafe(_deliver, batch)
+            except RuntimeError:  # that loop is gone: nobody listens
+                for seq, _ in batch:
+                    seq.cancelled = True
+
     def _release_slot_resources(self, seq: _Seq) -> None:
         """Teardown for one slot: drop the prefix-cache ref, free owned
         pages, and zero the page-table rows (so an inactive slot's decode
-        write touches only the garbage page)."""
+        write touches only the garbage page).
+
+        The sequence may still ride in ONE step in flight (EOS, a
+        cancellation and an exhausted pool reach the loop a step late).
+        That step took COPIES of the tables as they stood when it was
+        dispatched, so the stale row writes its k/v at its own ``cursor``,
+        on its own last page or the garbage page, never on a shared prefix
+        page (those are write-redirected). The device runs programs in
+        order: a page freed here and handed to another sequence is written
+        by the stale row BEFORE any program of the new owner, and the new
+        owner writes every position before it attends it (the
+        update-before-attend invariant of ``_cursors``). The stale row's id
+        is discarded when it is read (``_collect``)."""
         if seq.slot is None:
             return
         slot = seq.slot
@@ -568,19 +655,6 @@ class ContinuousScheduler:
         seq.owned_pages.extend(pages)
         seq.table_fill = need
         return True
-
-    def _sample(self, seq: _Seq, logits_row) -> int:
-        import numpy as np
-
-        if seq.temperature <= 0.0:
-            return int(np.asarray(logits_row).argmax())
-        if seq.rng is None:
-            seq.rng = np.random.default_rng(seq.seed)
-        x = np.asarray(logits_row, np.float64) / seq.temperature
-        x -= x.max()
-        p = np.exp(x)
-        p /= p.sum()
-        return int(seq.rng.choice(len(p), p=p))
 
     def _emit_token(self, seq: _Seq, tok: int) -> bool:
         """Record + stream one sampled token; returns True if the sequence
@@ -725,35 +799,88 @@ class ContinuousScheduler:
         return np.fromiter((0 if s is None else s.cursor
                             for s in self._slot_seqs), np.int32, self.slots)
 
-    def _moe_note(self, out, live_rows: int):
-        """Split a paged program's result. An expert model's third value
-        (``moe_info``) is kept on the device until ``_moe_count`` reads
-        it behind a wait the loop makes anyway."""
-        if self._moe:
-            self._moe_pending.append((out[2]["counts"], live_rows))
-        return out[0], out[1]
+    def _launch(self, out, *, step: bool, rows: List[_Seq],
+                live_rows: int) -> None:
+        """Take over what a paged program returned: the ids stay on the
+        device for the next program, the pool is the next program's, and
+        what the host has to read of it later (ids, an expert model's
+        counts) is queued for ``_collect``."""
+        self._ids, self._caches = out[0], out[1]
+        self._serial += 1
+        moe = out[2]["counts"] if self._moe else None
+        if rows or moe is not None:
+            self._inflight.append(_Launched(self._serial, step, out[0], rows,
+                                            moe, live_rows))
 
-    def _moe_count(self) -> None:
-        """Add up the expert counts of every program that has finished
-        (call after a wait on the newest program's logits: the device
-        runs programs in order, so the copies below wait for nothing)."""
+    def _moe_count(self, counts, live_rows: int) -> None:
+        """Add up one finished program's expert counts (call after a wait
+        on that program: the copy below then waits for nothing)."""
         import numpy as np
 
-        for counts, live_rows in self._moe_pending:
-            c = np.asarray(counts)  # [layers, experts]
-            self._n_moe_live_rows += live_rows
-            self._n_moe_layer_calls += c.shape[0]
-            self._n_moe_rows_routed += int(c.sum())
-            self._n_moe_experts_hit += int((c > 0).sum())
-            self._n_moe_max_expert_rows += int(c.max(axis=1).sum())
-        self._moe_pending.clear()
+        c = np.asarray(counts)  # [layers, experts]
+        self._n_moe_live_rows += live_rows
+        self._n_moe_layer_calls += c.shape[0]
+        self._n_moe_rows_routed += int(c.sum())
+        self._n_moe_experts_hit += int((c > 0).sum())
+        self._n_moe_max_expert_rows += int(c.max(axis=1).sum())
+
+    def _collect(self, n: int) -> bool:
+        """Read the ``n`` oldest programs in flight: wait for each (the
+        device runs them in order), fetch its ids, then emit every row's
+        token and retire what ended. A row whose sequence ended while its
+        program was in flight is discarded: never emitted, never counted in
+        ``tokens_generated``. Returns True if anything was read."""
+        import numpy as np
+
+        if n <= 0:
+            return False
+        switch = self._clock.switch
+        if self._inflight[n - 1].serial == self._serial:
+            # nothing is queued behind what is read: the device idles
+            # while the thread works
+            self._n_drains += 1
+            flight.instant(_F_DRAIN, n)
+        for _ in range(n):
+            rec = self._inflight.popleft()
+            switch(_P_WAIT if rec.step else _P_PREFILL_WAIT)
+            self._jax.block_until_ready(rec.ids)
+            switch(_P_FETCH)
+            ids = np.asarray(rec.ids)
+            if rec.moe is not None:
+                self._moe_count(rec.moe, rec.live_rows)
+            if rec.step:
+                self._steps_unread -= 1
+            if not rec.rows:
+                continue
+            switch(_P_SAMPLE)
+            arrived = [(seq, int(ids[seq.slot])) for seq in rec.rows
+                       if seq.state != _DONE]
+            self._n_discarded += len(rec.rows) - len(arrived)
+            switch(_P_EMIT)
+            self._outbox = {}
+            try:
+                for seq, tok in arrived:
+                    if seq.cancelled:  # nobody listens: no token past it
+                        self._n_discarded += 1
+                        self._retire(seq, "cancelled")
+                        continue
+                    seq.next_token = tok
+                    if self._emit_token(seq, tok):
+                        self._retire(seq, "eos" if self.eos_id is not None
+                                     and tok == self.eos_id else "length")
+            finally:
+                self._hand_over()
+        return True
 
     def _prefill_one(self) -> bool:
         """Advance ONE prefilling sequence by one chunk, round-robin over
         slots — concurrent prompts interleave their chunks, so one long
         prompt cannot monopolize prefill (and decode never waits more than
-        one chunk). Returns True if a chunk ran."""
-        import jax
+        one chunk). The chunk is dispatched and not waited for: a prompt's
+        last chunk samples the first token into ``_ids`` on the device, the
+        sequence decodes from the next step on, and the host reads the
+        token with the other ids (``_collect``). Returns True if a chunk
+        was dispatched."""
         import jax.numpy as jnp
         import numpy as np
 
@@ -789,37 +916,28 @@ class ContinuousScheduler:
             # upload may alias (CPU) or still be reading (TPU) the host
             # buffer, while _offer_prompt_pages and _ensure_pages write
             # to these rows before anything waits for this chunk
-            logits, self._caches = self._moe_note(self._prefill(
+            last = not seq.remaining_prompt
+            self._launch(self._prefill(
                 self.params, tokens, np.int32(real), np.int32(seq.cursor),
                 jnp.asarray(self._read_tables[seq.slot].copy()),
                 jnp.asarray(self._write_tables[seq.slot].copy()),
-                self._caches), real)
+                self._caches, self._ids, np.int32(seq.slot if last else -1),
+                np.float32(seq.temperature), np.uint32(seq.seed)),
+                step=False, rows=[seq] if last else [], live_rows=real)
             seq.cursor += real
             # dispatch is async and stays so: the chunk's device time is
             # read from a profiler trace by the program's name, and the
-            # wait for it falls into the next phase that reads a result
+            # wait for it falls into the phase that reads its result
             self._record_attn(self.prefill_chunk, [seq.cursor - real])
             self._n_prefill_chunks += 1
             _m_prefill_chunks.inc()
-            if self._radix is not None and not seq.remaining_prompt:
-                self._offer_prompt_pages(seq)
-            if not seq.remaining_prompt:
-                # prompt fully resident: sample the first token NOW — this
-                # is the time-to-first-token moment. The host read below
-                # blocks until the chunk is done, so the wait for the
-                # device gets a phase of its own and adds no sync
-                switch(_P_PREFILL_WAIT)
-                jax.block_until_ready(logits)
-                switch(_P_SAMPLE)
-                self._moe_count()
-                tok = self._sample(seq, logits)
+            if last:
+                # prompt fully resident and its first token on the way:
+                # the sequence rides in the next decode step already
+                if self._radix is not None:
+                    self._offer_prompt_pages(seq)
                 seq.state = _DECODE
-                switch(_P_EMIT)
-                if self._emit_token(seq, tok):
-                    self._retire(seq, "length" if self.eos_id is None
-                                 or tok != self.eos_id else "eos")
-                else:
-                    seq.next_token = tok
+                seq.n_launched = 1
             return True
         return False
 
@@ -1110,7 +1228,12 @@ class ContinuousScheduler:
         Drafter sync: the drafter always steps ``spec_k`` times (fixed
         program shapes), but after a fully-accepted round it first
         catches up on the accepted token it never consumed
-        (``drafter_pending``), producing one fewer draft that round."""
+        (``drafter_pending``), producing one fewer draft that round.
+
+        This is the one synchronous loop left: acceptance and the corrected
+        resample need the window's whole logits on the host, so the round
+        reads everything in flight (a prompt's first token among it) before
+        it drafts, and its own result before it returns."""
         import numpy as np
 
         import jax.numpy as jnp
@@ -1124,6 +1247,7 @@ class ContinuousScheduler:
         k = self.spec_k
         K = k + 1
         switch = self._clock.switch
+        self._collect(len(self._inflight))
         switch(_P_PREPARE)  # drafting is this path's preparation
         live: List[_Seq] = []
         for seq in self._slot_seqs:
@@ -1177,12 +1301,15 @@ class ContinuousScheduler:
             vt[s.slot, :len(row)] = row
             used[s.slot] = len(row)
         switch(_P_VERIFY)
-        vlogits, self._caches = self._moe_note(self._verify(
+        out = self._verify(
             self.params, jnp.asarray(vt), jnp.asarray(used), self._cursors(),
             jnp.asarray(self._read_tables),
-            jnp.asarray(self._write_tables), self._caches), int(used.sum()))
-        va = np.asarray(vlogits)
-        self._moe_count()
+            jnp.asarray(self._write_tables), self._caches)
+        self._caches = out[1]
+        va = np.asarray(out[0])
+        self._n_drains += 1  # read with nothing queued behind it
+        if self._moe:
+            self._moe_count(out[2]["counts"], int(used.sum()))
         switch(_P_EMIT)  # acceptance and emission
         self._record_attn(K, [s.cursor for s in live],
                           self.slots - len(live))
@@ -1236,15 +1363,19 @@ class ContinuousScheduler:
         self._drafter.set_lengths(dlen)
         return True
 
-    def _decode_once(self) -> bool:
-        """One batched decode iteration over every DECODE slot."""
-        import jax.numpy as jnp
+    def _decode_once(self, behind: int) -> bool:
+        """One turn of the decode loop, one step ahead: prepare and
+        dispatch the next step over every DECODE slot from what the host
+        knows without the previous step's result, THEN read the ``behind``
+        programs dispatched in earlier turns (``_collect``). The step's
+        tokens are ``_ids``, on the device since the programs that sampled
+        them. Returns True if a step was dispatched or a result read."""
         import numpy as np
 
-        switch = self._clock.switch
-        switch(_P_PREPARE)
-        toks = np.zeros(self.slots, np.int32)
+        self._clock.switch(_P_PREPARE)
         active = np.zeros(self.slots, np.int32)
+        temperature = np.zeros(self.slots, np.float32)
+        seeds = np.zeros(self.slots, np.uint32)
         live: List[_Seq] = []
         for i, seq in enumerate(self._slot_seqs):
             if seq is None or seq.state != _DECODE:
@@ -1252,44 +1383,34 @@ class ContinuousScheduler:
             if seq.cancelled:
                 self._retire(seq, "cancelled")
                 continue
+            if seq.n_launched >= seq.max_new:
+                continue  # its last token is in flight: nothing to add
             if not self._ensure_pages(seq, seq.cursor + 1):
                 continue  # this sequence failed cleanly; others continue
-            toks[i] = seq.next_token
             active[i] = 1
+            temperature[i] = seq.temperature
+            seeds[i] = seq.seed
             live.append(seq)
-        if not live:
-            return False
-        logits, self._caches = self._moe_note(self._step(
-            self.params, jnp.asarray(toks), jnp.asarray(active),
-            self._cursors(), jnp.asarray(self._read_tables),
-            jnp.asarray(self._write_tables), self._caches), len(live))
-        self._record_attn(1, [s.cursor for s in live],
-                          self.slots - len(live))
-        self._n_steps += 1
-        _m_steps.inc()
-        self._max_active_slots = max(self._max_active_slots, len(live))
-        # the fetch below would block until the step is done: the wait for
-        # the device and the copy to the host are told apart, no sync added
-        switch(_P_WAIT)
-        self._jax.block_until_ready(logits)
-        switch(_P_FETCH)
-        la = np.asarray(logits)
-        self._moe_count()
-        # sample every live sequence, then emit: two transitions a step
-        # however many slots are live
-        switch(_P_SAMPLE)
-        sampled = []
-        for seq in live:
-            seq.cursor += 1
-            sampled.append(self._sample(seq, la[seq.slot]))
-        switch(_P_EMIT)
-        for seq, tok in zip(live, sampled):
-            if self._emit_token(seq, tok):
-                self._retire(seq, "eos" if self.eos_id is not None
-                             and tok == self.eos_id else "length")
-            else:
-                seq.next_token = tok
-        return True
+        if live:
+            if self._steps_unread:
+                self._n_runahead += 1
+            # the tables go up as COPIES: the host frees and hands out
+            # pages while this step is in flight (see _prefill_one)
+            self._launch(self._step(
+                self.params, self._ids, active, self._cursors(),
+                self._read_tables.copy(), self._write_tables.copy(),
+                self._caches, temperature, seeds),
+                step=True, rows=live, live_rows=len(live))
+            self._steps_unread += 1
+            self._record_attn(1, [s.cursor for s in live],
+                              self.slots - len(live))
+            for seq in live:
+                seq.cursor += 1
+                seq.n_launched += 1
+            self._n_steps += 1
+            _m_steps.inc()
+            self._max_active_slots = max(self._max_active_slots, len(live))
+        return self._collect(behind) or bool(live)
 
     def _run(self) -> None:
         clock = self._clock
@@ -1309,11 +1430,12 @@ class ContinuousScheduler:
                 self._finish_migrations()
                 self._start_migrations()
                 self._admit()
+                behind = len(self._inflight)
                 did = self._prefill_one()
                 if self._drafter is not None:
                     did = self._decode_spec() or did
                 else:
-                    did = self._decode_once() or did
+                    did = self._decode_once(behind) or did
                 _m_active.set(float(sum(
                     1 for s in self._slot_seqs if s is not None)))
                 if not did:
@@ -1444,6 +1566,14 @@ class ContinuousScheduler:
             "stall_s": self._stall_s,
             "stalls": self._n_stalls,
             "stall_phase": self._stall_phase,
+            # the loop runs a step ahead: decode steps dispatched while the
+            # previous step's ids were unread (over decode_steps: the
+            # run-ahead share), reads with no program queued behind them
+            # (first step after park, every speculative round), and rows
+            # computed for a sequence that had already ended
+            "runahead_steps": self._n_runahead,
+            "pipeline_drains": self._n_drains,
+            "discarded_rows": self._n_discarded,
         }
         # the scheduler thread's own time by phase (0 with the recorder off)
         out.update(zip(_PHASE_KEYS, self._clock.seconds()))

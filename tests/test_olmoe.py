@@ -191,10 +191,12 @@ def paged_run(toy):
                 real = len(chunk)
                 padded = np.zeros((1, C), np.int32)
                 padded[0, :real] = chunk
-                logits, caches, moe = paged_prefill_into_slot(
+                _, caches, moe, logits = paged_prefill_into_slot(
                     cfg, params, jnp.asarray(padded), real, np.int32(c0),
-                    tables[s], tables[s], caches, attn="reference",
-                    moe_info=True)
+                    tables[s], tables[s], caches,
+                    jnp.zeros(slots, jnp.int32), np.int32(-1),
+                    np.float32(0), np.uint32(0), attn="reference",
+                    moe_info=True, logits=True)
                 taken[s].append(np.asarray(moe["routes"])[:, 0, :real])
                 counted += int(moe["counts"].sum())
                 live += real
@@ -205,9 +207,16 @@ def paged_run(toy):
             toks = np.zeros(slots, np.int32)
             for b, s in enumerate((1, 2)):
                 toks[s] = tokens[b, n[b] + step]
-            logits, caches, moe = paged_decode_step(
+            ids, caches, moe, logits = paged_decode_step(
                 cfg, params, jnp.asarray(toks), active, cursors + step,
-                tables, tables, caches, attn="reference", moe_info=True)
+                tables, tables, caches, jnp.zeros(slots, jnp.float32),
+                jnp.zeros(slots, jnp.uint32), attn="reference",
+                moe_info=True, logits=True)
+            # an active row's id is its argmax, an idle row's its token
+            assert np.array_equal(
+                np.asarray(ids), np.where(np.asarray(active) > 0,
+                                          np.asarray(logits).argmax(-1),
+                                          toks))
             counted += int(moe["counts"].sum())
             live += 2
             for s in (1, 2):
@@ -268,7 +277,8 @@ def test_moe_info_refuses_a_dense_model(toy):
     with pytest.raises(ValueError, match="mlp='moe'"):
         paged_decode_step(dense, params, jnp.zeros(2, jnp.int32),
                           jnp.ones(2, jnp.int32), jnp.zeros(2, jnp.int32),
-                          tables, tables, caches, attn="reference",
+                          tables, tables, caches, jnp.zeros(2, jnp.float32),
+                          jnp.zeros(2, jnp.uint32), attn="reference",
                           moe_info=True)
 
 

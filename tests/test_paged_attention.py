@@ -334,16 +334,24 @@ def _paged_prefill(cfg, params, attn, prompts, T=4, P=8):
     from ray_tpu.models.decode import paged_prefill_into_slot
 
     caches, tables = _arena(cfg, len(prompts), T, P)
-    prefill = jax.jit(partial(paged_prefill_into_slot, cfg, attn=attn))
+    prefill = jax.jit(partial(paged_prefill_into_slot, cfg, attn=attn,
+                              logits=True))
     lasts = []
+    first = jnp.zeros(len(prompts), jnp.int32)  # the programs' own ids
     for s, ids in enumerate(prompts):
         for at in range(0, len(ids), CHUNK):
             chunk = list(ids[at:at + CHUNK])
             padded = chunk + [0] * (CHUNK - len(chunk))
-            last, caches = prefill(params, jnp.asarray([padded], jnp.int32),
-                                   np.int32(len(chunk)), np.int32(at),
-                                   tables[s], tables[s], caches)
+            ends = at + CHUNK >= len(ids)
+            first, caches, last = prefill(
+                params, jnp.asarray([padded], jnp.int32),
+                np.int32(len(chunk)), np.int32(at), tables[s], tables[s],
+                caches, first, np.int32(s if ends else -1), np.float32(0),
+                np.uint32(0))
         lasts.append(np.asarray(last))
+    # temperature 0: the id a prompt's last chunk left in its row is the
+    # argmax of the logits it returned, and no other chunk touched the row
+    assert np.array_equal(np.asarray(first), np.stack(lasts).argmax(-1))
     cursors = np.asarray([len(ids) for ids in prompts], np.int32)
     return np.stack(lasts), caches, tables, cursors
 
@@ -359,17 +367,20 @@ def _drive_lane(cfg, params, attn, prompts, new_tokens, T=4, P=8):
     S = len(prompts)
     lasts, caches, tables, cursors = _paged_prefill(cfg, params, attn,
                                                     prompts, T, P)
-    step = jax.jit(partial(paged_decode_step, cfg, attn=attn))
+    step = jax.jit(partial(paged_decode_step, cfg, attn=attn, logits=True))
     toks, active = lasts.argmax(-1).astype(np.int32), np.ones(S, np.int32)
+    greedy = (np.zeros(S, np.float32), np.zeros(S, np.uint32))
     out = [[int(t)] for t in toks]
     traces = []
     for _ in range(new_tokens):
-        logits, caches = step(params, jnp.asarray(toks), jnp.asarray(active),
-                              cursors, tables, tables, caches)
+        ids, caches, logits = step(params, jnp.asarray(toks),
+                                   jnp.asarray(active), cursors, tables,
+                                   tables, caches, *greedy)
         cursors = cursors + 1
         la = np.asarray(logits)
         traces.append(la)
         toks = la.argmax(-1).astype(np.int32)
+        assert np.array_equal(np.asarray(ids), toks)  # the step's own ids
         for s in range(S):
             out[s].append(int(toks[s]))
     return out, np.stack(traces), caches, tables, cursors
@@ -501,11 +512,15 @@ class TestInPlaceLanes:
         caches, tables = _arena(cfg, 2, 4, 8)
         if program == "paged_prefill_into_slot":
             args = (jnp.zeros((1, CHUNK), jnp.int32), np.int32(3),
-                    np.int32(0), tables[0], tables[0], caches)
+                    np.int32(0), tables[0], tables[0], caches,
+                    jnp.zeros(2, jnp.int32), np.int32(0), np.float32(0),
+                    np.uint32(0))
         else:
             k = (2, 3) if program == "paged_verify_step" else (2,)
             args = (jnp.zeros(k, jnp.int32), jnp.ones(2, jnp.int32),
                     jnp.zeros(2, jnp.int32), tables, tables, caches)
+            if program == "paged_decode_step":
+                args += (jnp.zeros(2, jnp.float32), jnp.zeros(2, jnp.uint32))
         for bad in ("gather", "turbo", "auto", ""):
             with pytest.raises(ValueError, match="unknown paged attention"):
                 jax.eval_shape(lambda *a, bad=bad: getattr(decode, program)(
@@ -517,8 +532,10 @@ class TestInPlaceLanes:
         (at the parent it ran the gathered-view lane, silently)."""
         import ray_tpu.models.decode as decode
 
+        positional = {"paged_prefill_into_slot": 11, "paged_decode_step": 9,
+                      "paged_verify_step": 7}[program]
         with pytest.raises(TypeError, match="attn"):
-            getattr(decode, program)(None, *([None] * 7))
+            getattr(decode, program)(None, *([None] * positional))
 
 
 # ------------------------------------------------------------- dispatchers

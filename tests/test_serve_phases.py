@@ -279,6 +279,75 @@ def test_an_admission_runs_no_device_program(tmp_path):
         fresh.shutdown()
 
 
+def test_a_run_ahead_window_holds_the_two_programs_and_nothing_else(
+        tmp_path):
+    """The loop one step ahead (ISSUE 29), traced in its steady state with
+    everything that strikes a step in flight: slots reused, a sampling row
+    beside greedy ones (the draw is a branch INSIDE the step), a stream
+    abandoned after its first token (its rows in flight are discarded).
+    The device runs the two named programs and nothing else."""
+    from perfbench.lib import trace
+
+    srv = _server(slots=4)
+    try:
+        _drive(srv, ["warm a", "warm b"])
+        before = srv.scheduler_stats()
+        trace.start(str(tmp_path))
+
+        async def go():
+            async def abandoned():
+                out = await srv({"prompt": "nobody reads this to its end",
+                                 "max_new_tokens": 40, "stream": True})
+                async for _ in out:
+                    break
+                await out.aclose()
+
+            async def one(i):
+                return (await srv({"prompt": f"ahead {i}" * (1 + i % 3),
+                                   "max_new_tokens": 5 + i,
+                                   "temperature": 0.9 * (i % 2)}))["text"]
+
+            await asyncio.gather(abandoned(), *[one(i) for i in range(9)])
+
+        asyncio.run(go())
+        path = trace.stop(str(tmp_path))
+        ran = _delta(srv.scheduler_stats(), before,
+                     ("decode_steps", "prefill_chunks", "runahead_steps",
+                      "pipeline_drains", "discarded_rows", "retired"))
+        programs = trace.summarize(trace.load(path))["programs"]
+        assert sorted(programs) == ["jit_paged_decode_step",
+                                    "jit_paged_prefill_chunk"], programs
+        assert programs["jit_paged_decode_step"]["count"] == ran[
+            "decode_steps"]
+        assert srv._sched.compiled_programs() == 2
+        assert ran["retired"] == 10 and ran["discarded_rows"] >= 1
+        assert ran["runahead_steps"] >= ran["decode_steps"] - ran[
+            "pipeline_drains"] > 0
+    finally:
+        srv.shutdown()
+
+
+def test_a_drain_is_an_instant_and_a_count(warm):
+    """The thread reads a result with nothing queued behind it when a burst
+    ends (and only then, without a drafter): each such read is a
+    ``serve.drain`` instant and one of ``pipeline_drains``."""
+    def drains():
+        return len([e for e in flight.local_timeline()
+                    if e.get("ph") == "i" and e["name"] == "serve.drain"])
+
+    n0, before = drains(), warm.scheduler_stats()
+    _drive(warm, ["burst a", "burst b", "burst c"])
+    time.sleep(0.1)
+    _drive(warm, ["alone"])
+    d = _delta(warm.scheduler_stats(), before,
+               ("pipeline_drains", "decode_steps", "runahead_steps",
+                "discarded_rows"))
+    assert drains() - n0 == d["pipeline_drains"]
+    assert 2 <= d["pipeline_drains"] <= 4  # one an emptied arena, about
+    assert d["runahead_steps"] >= d["decode_steps"] - d["pipeline_drains"]
+    assert d["discarded_rows"] == 0
+
+
 def test_the_scheduler_jits_named_functions():
     srv = _server()
     try:

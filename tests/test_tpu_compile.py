@@ -295,10 +295,13 @@ def test_gpt2s_serve_programs_compile_and_fit(v5e):
     programs = {
         "prefill": (paged_prefill_into_slot,
                     (params, ids((1, chunk)), ids(()), ids(()), row, row,
-                     caches), 6),
+                     caches, ids((slots,)), ids(()),
+                     _on(chip, (), jnp.float32), _on(chip, (), jnp.uint32)),
+                    6),
         "decode": (paged_decode_step,
                    (params, ids((slots,)), ids((slots,)), ids((slots,)), table,
-                    table, caches), 6),
+                    table, caches, _on(chip, (slots,), jnp.float32),
+                    _on(chip, (slots,), jnp.uint32)), 6),
         "verify": (paged_verify_step,
                    (params, ids((slots, conf.serve_spec_k + 1)), ids((slots,)),
                     ids((slots,)), table, table, caches), 6),
@@ -352,10 +355,13 @@ def test_olmoe_serve_programs_compile_and_fit(v5e):
     programs = {
         "prefill": (paged_prefill_into_slot,
                     (params, ids((1, chunk)), ids(()), ids(()), row, row,
-                     caches), 6),
+                     caches, ids((slots,)), ids(()),
+                     _on(chip, (), jnp.float32), _on(chip, (), jnp.uint32)),
+                    6),
         "decode": (paged_decode_step,
                    (params, ids((slots,)), ids((slots,)), ids((slots,)), table,
-                    table, caches), 6),
+                    table, caches, _on(chip, (slots,), jnp.float32),
+                    _on(chip, (slots,), jnp.uint32)), 6),
     }
     for name, (program, args, donated) in programs.items():
         compiled = jax.jit(
