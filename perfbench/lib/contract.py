@@ -55,10 +55,14 @@ def _is_count(x: Any) -> bool:
 
 def build_line(*, correct: bool, attempted: int, failed: int,
                metrics: Dict[str, Dict[str, Any]], device: Dict[str, Any],
-               breakdown: Optional[Dict[str, list]] = None) -> Dict[str, Any]:
+               breakdown: Optional[Dict[str, list]] = None,
+               compared: Optional[Dict[str, Dict[str, float]]] = None
+               ) -> Dict[str, Any]:
     """The object a run prints. ``metrics`` maps name -> {"value", "unit"};
     a value a reader could not produce is simply absent, and the check then
-    names it."""
+    names it. ``compared`` (name -> {"value", "limit"}: each number the
+    run's ``correct`` was decided from, beside its limit) comes last; the
+    driver ignores it and keeps it where a run was not correct."""
     line: Dict[str, Any] = {
         "correct": bool(correct), "attempted": int(attempted),
         "failed": int(failed), "metrics": metrics, "device": device}
@@ -66,6 +70,8 @@ def build_line(*, correct: bool, attempted: int, failed: int,
         line["breakdown"] = {
             k: [[str(n), float(s)] for n, s in breakdown.get(k, [])
                 ][:BREAKDOWN_MAX] for k in BREAKDOWN_KEYS}
+    if compared is not None:
+        line["compared"] = compared
     return line
 
 

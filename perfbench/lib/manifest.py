@@ -12,7 +12,13 @@ manifest gives it:
     metrics/<metric>.py       reader of one per-layer metric
 
 so a later PR adds a cell, a configuration, a mix or a per-layer metric by
-adding files and manifest entries, and edits none that is there.
+adding files and manifest entries, and edits none that is there: an entry
+goes to the END of its list, and a cell joins a metric's ``workloads`` at
+the end. The tests hold it to that and leave it the room
+(``tests/perfbench/held.py``): what an earlier PR listed is held by name and
+index, a ``workloads`` list by its first entries, a count as ``>=``. A PR's
+own tests hold its entries the same way, never by ``[-1]``, a total length
+or a ``workloads`` list compared whole, or the next PR could add nothing.
 """
 
 from __future__ import annotations

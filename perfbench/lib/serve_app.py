@@ -31,6 +31,7 @@ class BenchLLMServer(LLMServerImpl):
         self._bench = bench
         self._bench_compiles = worker.CompileCounter()
         self._bench_stamp = worker.device_stamp(bench["require_tpu"])
+        self._bench_chip_open = time.time()
         super().__init__(
             preset=bench["preset"],
             preset_overrides=configs.with_dtypes(bench["overrides"], jnp),
@@ -39,9 +40,11 @@ class BenchLLMServer(LLMServerImpl):
         self._bench_ready = time.time()
 
     def bench_info(self) -> Dict[str, Any]:
-        from perfbench.lib import configs, worker
+        from perfbench.lib import configs, hostwatch, worker
 
         return {"first_line": self._bench_first_line,
+                "host": hostwatch.process_reading(),
+                "chip_open": self._bench_chip_open,
                 "ready": self._bench_ready,
                 "device": {**self._bench_stamp,
                            "memory_peak_bytes": worker.memory_peak_bytes()},

@@ -16,6 +16,7 @@ from __future__ import annotations
 import time
 from typing import Any, Callable, Dict, Tuple
 
+from perfbench.lib import hostwatch
 from perfbench.lib import load as load_lib
 from perfbench.lib import traffic
 
@@ -64,21 +65,29 @@ def measure(ctx: Dict[str, Any], send: Callable, call: Callable,
     load = load_lib.Load(traffic.RequestStream(mix, ctx["seed"], vocab), send)
     load.start()
     time.sleep(float(cell["warmup_s"]))
-    compiles_before = call("bench_info")["compiles"]
+    info_before = call("bench_info")
+    compiles_before = info_before["compiles"]
+    client_before = hostwatch.process_reading()
     before = call("scheduler_stats")
     window_start = time.time()
     t0 = time.perf_counter()
-    summary, traced_at = None, None
+    summary, traced_at, late = None, None, []
     if traced:
-        time.sleep(0.4 * seconds)
+        hostwatch.sleep_until(t0 + 0.4 * seconds, t0, late)
         call("trace_start")
         traced_at = [time.perf_counter()]
-        time.sleep(float(cell["trace_s"]))
+        hostwatch.sleep_until(traced_at[0] + float(cell["trace_s"]), t0, late)
         traced_at.append(time.perf_counter())
         summary = call("trace_stop")
-    time.sleep(max(t0 + seconds - time.perf_counter(), 0.0))
+    hostwatch.sleep_until(t0 + seconds, t0, late)
     t1 = time.perf_counter()
     after = call("scheduler_stats")
+    info_after = call("bench_info")
+    host = {"client_slices_late": late,
+            "client": hostwatch.delta(client_before,
+                                      hostwatch.process_reading()),
+            "replica": hostwatch.delta(info_before["host"],
+                                       info_after["host"])}
     load.stop(float(cell["grace_s"]))
     records = load.snapshot()
     seen = load_lib.window_results(records, t0, t1)
@@ -92,6 +101,7 @@ def measure(ctx: Dict[str, Any], send: Callable, call: Callable,
              and isinstance(before.get(k), (int, float))}
     return {"seen": seen, "delta": delta, "end": after, "trace": summary,
             "trace_window": trace_window, "window_start": window_start,
+            "host": host,
             "compiles_in_window":
                 call("bench_info")["compiles"] - compiles_before}
 
@@ -101,6 +111,7 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
 
     cell = ctx["cell"]
     send, call = deploy(ctx)
+    t_deployed = time.time()
 
     # ---- correct, part one: the served path against the plain reference
     info = call("bench_info")
@@ -109,8 +120,10 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
         1, vocab, size=int(cell["check_prompt_tokens"])).tolist()
     served = [t for chunk in send(traffic.Request(
         -1, ids, int(cell["check_new_tokens"]), None)) for t in chunk]
+    t_served = time.time()
     check = call("reference_check", ids, served, ctx["config"],
                  ctx["reference_path"])
+    t_checked = time.time()
 
     m = measure(ctx, send, call, ctx["traffic"], vocab, ctx["seconds"],
                 bool(ctx["trace"]))
@@ -133,6 +146,11 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
     e2e["setup_s"] = m["window_start"] - ctx["t_process_start"]
     return {
         "correct": all(checks.values()), "checks": checks,
+        "compared": {
+            **{k: {"value": check[k], "limit": tol[k]} for k in sorted(tol)},
+            "requests_failed": {"value": seen["failed"], "limit": 0},
+            "compiles_in_window": {"value": m["compiles_in_window"],
+                                   "limit": 0}},
         "attempted": seen["attempted"], "failed": seen["failed"],
         "e2e": e2e, "device": end["device"], "trace": m["trace"],
         "clock": {"worker_start_s": info["first_line"] - ctx["t_init"],
@@ -140,9 +158,15 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
         "sizes": info["sizes"],
         "counters": {"delta": m["delta"], "end": after, "window": seen,
                      "trace_window": m["trace_window"]},
+        "setup_phases": {
+            "open_chip": info["chip_open"] - info["first_line"],
+            "replica_build": info["ready"] - info["chip_open"],
+            "deployed": t_deployed - info["ready"],
+            "first_request": t_served - t_deployed,
+            "reference_check": t_checked - t_served,
+            "warmup_traffic": m["window_start"] - t_checked},
         "notes": {"reference_check": check, "window": seen,
                   "compiles_in_window": m["compiles_in_window"],
-                  "replica_ready_s": info["ready"] - info["first_line"],
                   "memory_stats": end["memory_stats"],
                   "scheduler": {k: after.get(k) for k in (
                       "attn_lane", "slots", "prefill_chunk", "arena_len",
@@ -150,5 +174,6 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
                       "peak_queue_depth", "compiled_programs",
                       "max_active_slots", "platform")},
                   "delta": {k: v for k, v in m["delta"].items() if v},
+                  "host": m["host"],
                   "program_runs": (m["trace"] or {}).get("program_runs")},
     }

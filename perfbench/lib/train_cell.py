@@ -16,8 +16,18 @@ from typing import Any, Dict
 
 def train_loop(config: Dict[str, Any]) -> None:
     first_line = time.time()
+    # where set-up's seconds go: one stamp after each phase, by name
+    phases, t_phase = {}, time.perf_counter()
+
+    def phase_done(name: str) -> None:
+        nonlocal t_phase
+        now = time.perf_counter()
+        phases[name] = now - t_phase
+        t_phase = now
+
     import jax
     import numpy as np
+    phase_done("import_jax")
 
     from perfbench.lib import configs, trace, traffic, weights, worker
     from perfbench.reference import common
@@ -26,9 +36,11 @@ def train_loop(config: Dict[str, Any]) -> None:
     from ray_tpu.models.training import (OptimizerConfig, init_train_state,
                                          make_train_step)
     from ray_tpu.models.transformer import loss_fn
+    phase_done("import_program")
 
     compiles = worker.CompileCounter()
     stamp = worker.device_stamp(config["require_tpu"])
+    phase_done("open_chip")
     cell, mix, seed = config["cell"], config["traffic"], config["seed"]
     cfg = configs.build_program_config(config["preset"], config["overrides"])
     sizes = configs.program_sizes(cfg)
@@ -45,6 +57,8 @@ def train_loop(config: Dict[str, Any]) -> None:
     ocfg = OptimizerConfig(**cell.get("optimizer", {}))
     state, tx = init_train_state(cfg, ocfg, weights.key_for(seed), mesh)
     step = make_train_step(cfg, tx, mesh)
+    jax.block_until_ready(state.params)
+    phase_done("state_init")
 
     # ---- correct: the system's loss on a seeded sample against the plain
     # reference on the same weights (set-up, outside the window)
@@ -65,6 +79,7 @@ def train_loop(config: Dict[str, Any]) -> None:
     loss_ref = float(common.next_token_loss(
         ref.forward(state.params, sample_dev, config["config"]), sample_dev))
     check_err = abs(loss_sys - loss_ref) / abs(loss_ref)
+    phase_done("reference_check")
 
     # ---- warm-up: compile the one shape the window uses, take two steps
     batches = traffic.token_batches(mix, seed, batch, cfg.vocab_size)
@@ -78,6 +93,7 @@ def train_loop(config: Dict[str, Any]) -> None:
     for _ in range(2):
         state, metrics = compiled(state, {"tokens": place(next(batches))})
     jax.block_until_ready(metrics["loss"])
+    phase_done("compile_and_warmup")
 
     # ---- the measured window: a new batch from the host every step, the
     # host one step ahead of the device and never further
@@ -134,7 +150,7 @@ def train_loop(config: Dict[str, Any]) -> None:
         "losses_finite": all(math.isfinite(x) for x in losses),
         "loss_system": loss_sys, "loss_reference": loss_ref,
         "check_rel_err": check_err,
-        "compile_s": compile_s,
+        "compile_s": compile_s, "setup_phases": phases,
         "compiles_in_window": compiles.count - compiles_before,
         "memory_analysis": str(compiled.memory_analysis()),
         "memory_stats": dict(jax.devices()[0].memory_stats() or {}),
@@ -176,12 +192,17 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
     rate = out["steps_timed"] * out["tokens_per_step"] / out["timed_s"]
     return {
         "correct": all(checks.values()), "checks": checks,
+        "compared": {
+            "loss_rel_err": {"value": out["check_rel_err"], "limit": tol},
+            "compiles_in_window": {"value": out["compiles_in_window"],
+                                   "limit": 0}},
         "attempted": out["steps"], "failed": 0,
         "e2e": {"train_tokens_per_s": rate,
                 "setup_s": out["window_start"] - ctx["t_process_start"]},
         "device": out["device"], "trace": out["trace"],
         "clock": {"worker_start_s": out["first_line"] - ctx["t_init"]},
         "sizes": out["sizes"], "counters": {},
+        "setup_phases": out["setup_phases"],
         "notes": {k: out[k] for k in (
             "steps", "steps_timed", "elapsed_s", "timed_s", "losses_head",
             "losses_tail", "loss_system", "loss_reference", "check_rel_err",

@@ -141,6 +141,13 @@ def main(argv=None, *, manifest_path=None, require_tpu: bool = True) -> int:
     import ray_tpu
     from ray_tpu._private import compile_cache
 
+    # A kind's module has ``run(ctx)``, which returns ``correct``, ``checks``,
+    # ``attempted``, ``failed``, ``e2e`` (name -> value), ``device``,
+    # ``trace`` (the traced window's summary or None), ``clock``
+    # (``worker_start_s``), ``sizes``, ``counters``, and may return
+    # ``notes``, ``setup_phases`` (name -> seconds, in order) and
+    # ``compared`` (name -> {"value", "limit"}: what ``correct`` was
+    # decided from).
     kind = ctx["cell"]["kind"]
     if kind == "train":
         from perfbench.lib import train_cell as cell_module
@@ -192,16 +199,25 @@ def main(argv=None, *, manifest_path=None, require_tpu: bool = True) -> int:
         device["window_s"] = summary["window_s"]
         device["busy_s"] = summary["busy_s"]
         breakdown = summary["breakdown"]
+    # where set-up's seconds went: the command's two phases, then the
+    # phases the process that holds the chip stamped, in order
+    setup_phases = {"command_to_init": ctx["t_init"] - T_PROCESS_START,
+                    "worker_start": result["clock"]["worker_start_s"],
+                    **result.get("setup_phases", {})}
     say(note="checks", correct=result["correct"], checks=result["checks"],
-        **result.get("notes", {}))
+        setup_phases=setup_phases, **result.get("notes", {}))
     say(note="end_to_end_in_this_run", **result["e2e"])
     say(note="cache", cache_entries=compile_cache.entries(cache_dir))
     line = contract.build_line(
         correct=result["correct"], attempted=result["attempted"],
         failed=result["failed"], metrics=metrics_of(manifest, ctx, result),
-        device=device, breakdown=breakdown)
+        device=device, breakdown=breakdown, compared=result.get("compared"))
     try:
         contract.emit(line, manifest, args.workload, bool(args.trace))
+        # the contract wants them twice: stderr's last lines, and above
+        for name, c in result.get("compared", {}).items():
+            print(f"perfbench: compared {name} = {c['value']} "
+                  f"(limit {c['limit']})", file=sys.stderr)
     except contract.ContractError as e:
         for p in e.problems:
             print(f"perfbench: contract: {p}", file=sys.stderr)

@@ -6,17 +6,14 @@ import copy
 import io
 import json
 import math
-import os
-import re
 
 import pytest
 
 from perfbench.lib import contract
 from perfbench.lib import manifest as manifest_lib
+from tests.perfbench import held
 
 MANIFEST = manifest_lib.load()
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
 
 def _serve_cell():
@@ -153,86 +150,18 @@ def test_cli_reads_the_last_line(tmp_path, monkeypatch, capsys):
 
 
 # ------------------------------------------------ the committed manifest
+# The rules themselves are functions of a manifest (``held.py``), so that
+# ``test_perfbench_additions.py`` can hold a manifest with a later PR's
+# additions to them too.
 
 
 def test_manifest_keys_names_and_units():
-    assert set(MANIFEST) - {"_dir"} == {
-        "command", "paths", "run_seconds", "configs", "workloads",
-        "end_to_end", "per_layer"}
-    assert 1 <= MANIFEST["run_seconds"] <= 51
-    rows = (MANIFEST["configs"] + MANIFEST["workloads"]
-            + MANIFEST["end_to_end"] + MANIFEST["per_layer"])
-    for row in rows:
-        assert NAME.match(row["name"]), row["name"]
-    for group in ("configs", "workloads"):
-        names = [r["name"] for r in MANIFEST[group]]
-        assert len(names) == len(set(names))
-    metrics = MANIFEST["end_to_end"] + MANIFEST["per_layer"]
-    assert len({m["name"] for m in metrics}) == len(metrics)
-    for m in metrics:
-        assert UNIT.match(m["unit"]), m
-        assert m["better"] in ("lower", "higher")
-        allowed = {"name", "unit", "better", "source", "workloads"}
-        allowed |= ({"bound"} if m in MANIFEST["end_to_end"]
-                    else {"layer", "moves"})
-        assert set(m) <= allowed, m
-    for m in MANIFEST["end_to_end"]:
-        assert m["source"] in ("host_clock", "device_trace")
-        assert 0.01 <= m["bound"] <= 0.1
-    assert any(m["name"] == "setup_s" and "workloads" not in m
-               for m in MANIFEST["end_to_end"])
-    for w in MANIFEST["workloads"]:
-        assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
-        assert NAME.match(w["traffic"])
-    for c in MANIFEST["configs"]:
-        assert set(c) == {"name", "source", "file", "reduced", "why"}
-        assert 1 <= len(c["why"]) <= 200 and len(c["reduced"]) <= 16
-    four = sum(w["chips"] == 4 for w in MANIFEST["workloads"])
-    assert four <= max(1, len(MANIFEST["workloads"]) // 4)
-    n = len(MANIFEST["workloads"])
-    assert (2 + 14 * 24) * (MANIFEST["run_seconds"] + 60) + 24 * 180 \
-        + 1200 <= 43200, "run_seconds must fit with the full 24 cells"
-    assert n <= 24
+    held.static_rules(MANIFEST)
 
 
 def test_every_cell_reports_what_the_contract_asks():
-    cells = {w["name"] for w in MANIFEST["workloads"]}
-    used = {w["config"] for w in MANIFEST["workloads"]}
-    assert used == {c["name"] for c in MANIFEST["configs"]}
-    pairs = [(w["config"], w["traffic"]) for w in MANIFEST["workloads"]]
-    assert len(pairs) == len(set(pairs))
-    e2e_names = {m["name"] for m in MANIFEST["end_to_end"]}
-    for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]:
-        assert set(m.get("workloads", cells)) <= cells, m["name"]
-    for cell in cells:
-        e2e = {m["name"] for m in manifest_lib.metrics_for(
-            MANIFEST, cell, False)}
-        assert "setup_s" in e2e and len(e2e) >= 2, cell
-        per_layer = manifest_lib.metrics_for(MANIFEST, cell, True)
-        assert per_layer, cell
-        for m in per_layer:
-            assert m["moves"] in e2e_names
-            assert m["moves"] in e2e, (m["name"], cell)
+    held.every_cell_reports(MANIFEST)
 
 
 def test_every_named_file_is_there():
-    root = MANIFEST["_dir"]
-    bench = manifest_lib.bench_dir(MANIFEST)
-    for c in MANIFEST["configs"]:
-        assert c["file"].startswith(MANIFEST["paths"][0] + "/")
-        cfg = manifest_lib.config(MANIFEST, c["name"])
-        fam = manifest_lib.read_json_from_bench("families",
-                                                cfg["model_type"])
-        assert os.path.isfile(os.path.join(
-            bench, "reference", fam["reference"] + ".py"))
-        for key in c["reduced"]:
-            assert key in cfg and key in cfg["reduced"], key
-    for w in MANIFEST["workloads"]:
-        assert manifest_lib.read_json(MANIFEST, "cells", w["name"])["kind"]
-        assert manifest_lib.read_json(MANIFEST, "traffic", w["traffic"])
-    for m in MANIFEST["per_layer"]:
-        assert callable(manifest_lib.metric_reader(m["name"]))
-    for word in MANIFEST["command"]:
-        assert not word.startswith("/") and ".." not in word
-    assert os.path.isfile(os.path.join(root, MANIFEST["command"][1]))
+    held.every_named_file_is_there(MANIFEST)

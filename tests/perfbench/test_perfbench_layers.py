@@ -15,15 +15,14 @@ import pytest
 
 from perfbench.lib import contract, layers
 from perfbench.lib import manifest as manifest_lib
+from tests.perfbench import held
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 TINY = manifest_lib.load(os.path.join(HERE, "tiny", "BENCHMARK.json"))
 LAYERS = manifest_lib.load(os.path.join(HERE, "tiny",
                                         "BENCHMARK_layers.json"))
-SERVE = ("step.decode_ms", "step.prefill_share", "sched.queue_wait_ms",
-         "sched.host_share", "sched.stall_share", "replica.stream_lag_ms")
-FLASH = "kernel.flash_roofline"
+SERVE, FLASH = held.SERVE, held.FLASH
 V5E = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
 
 
@@ -172,24 +171,7 @@ def test_flash_roofline_reads_zero_where_no_kernel_has_the_name():
 
 
 def test_the_benchmark_lists_the_thirteen_names_each_with_a_reader():
-    bench = manifest_lib.load()
-    rows = {m["name"]: m for m in bench["per_layer"]}
-    for name in SERVE:
-        assert rows[name]["workloads"] == ["mistral7b_docs"]
-        assert rows[name]["moves"] == "serve_tokens_per_s"
-        twin = rows[name + ".gap"]
-        assert twin["workloads"] == ["mistral7b_chat"]
-        assert twin["moves"] == "gap_p95_ms"
-        assert {k: v for k, v in twin.items()
-                if k not in ("name", "workloads", "moves")} == \
-            {k: v for k, v in rows[name].items()
-             if k not in ("name", "workloads", "moves")}
-    assert rows[FLASH]["workloads"] == ["gpt2s_train",
-                                        "mistral7b_train_4chip"]
-    for name in list(SERVE) + [n + ".gap" for n in SERVE] + [FLASH]:
-        assert callable(manifest_lib.metric_reader(name))
-    layers_of = {m["layer"] for m in bench["per_layer"][:18]}
-    assert {rows[n]["layer"] for n in list(SERVE) + [FLASH]} <= layers_of
+    held.pr24_entries(manifest_lib.load())
 
 
 def test_the_second_tiny_manifest_is_the_first_plus_the_new_names():
@@ -198,8 +180,7 @@ def test_the_second_tiny_manifest_is_the_first_plus_the_new_names():
     n = len(TINY["per_layer"])
     assert LAYERS["per_layer"][:n] == TINY["per_layer"]
     added = [m["name"] for m in LAYERS["per_layer"][n:]]
-    bench = [m["name"] for m in manifest_lib.load()["per_layer"][18:]]
-    assert added == bench and len(added) == 13
+    assert added == held.THIRTEEN  # BENCHMARK.json's [18:31], held there
 
 
 # -------------------------------------------------------------- rehearsal

@@ -5,10 +5,10 @@ of the cell at a toy size over the third tiny manifest
 (``tiny/BENCHMARK_olmoe.json``). Counts and structure only: no number here
 is a device number.
 
-The ``moe.*`` readers and the ``*.moe`` twins are listed in the tiny
-manifest only: ``test_perfbench_layers.py`` pins ``BENCHMARK.json``'s
-per-layer list at its 31 entries, so a ``benchmark`` PR has to free that
-pin before they can be appended there (PERF.md, section 7).
+What ``BENCHMARK.json`` lists for the configuration and the cell is held in
+``held.py`` (``pr26_config``, ``pr26_cell``, and since PR 31 ``pr31_entries``:
+the ``moe.*`` readers and the six lists of PR 24's the cell joined), by name
+and index, so that whatever a later PR appends passes.
 """
 
 import dataclasses
@@ -21,6 +21,7 @@ import pytest
 
 from perfbench.lib import configs, contract, moe_work, peaks
 from perfbench.lib import manifest as manifest_lib
+from tests.perfbench import held
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
@@ -31,12 +32,8 @@ OLMOE = manifest_lib.load(os.path.join(HERE, "tiny", "BENCHMARK_olmoe.json"))
 HP = manifest_lib.config(BENCH, "olmoe_1b_7b_l8")
 V5E = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
 SIZES = {"num_layers": 8, "num_kv_heads": 16, "head_dim": 128}  # as run
-NEW = ("step.decode_ms.moe", "step.prefill_share.moe", "sched.host_share.moe",
-       "moe.decode_step_roofline", "moe.max_expert_load")
-SHARED = ("client.tokens_per_s", "client.ttft_p50_ms.gap",
-          "client.ttft_p95_ms.gap", "sched.occupancy.gap",
-          "sched.prefix_hit_share.gap", "paging.peak_pages_in_use.gap",
-          "kernel.paged_attn_roofline", "device.idle_share.gap")
+NEW = held.MOE
+JOINED = tuple(n + ".gap" for n in held.SERVE)  # PR 24's six, since PR 31
 
 
 def read(metric, ctx):
@@ -58,10 +55,7 @@ def test_the_configuration_is_the_published_one_cut_in_depth_only():
         "tie_word_embeddings": False, "vocab_size": 50304}
     differs = {k for k, v in published.items() if HP.get(k, "absent") != v}
     assert differs == {"num_hidden_layers"} and HP["num_hidden_layers"] == 8
-    entry = [c for c in BENCH["configs"] if c["name"] == "olmoe_1b_7b_l8"][0]
-    assert entry["reduced"] == ["num_hidden_layers"]
-    assert entry["source"] == HP["source"] and "allenai" in entry["source"]
-    assert BENCH["configs"][-1] is entry  # appended, nothing moved
+    held.pr26_config(BENCH)  # fourth, cut in depth only, nothing moved
 
 
 def test_the_family_names_only_what_the_program_had_before_this_pr():
@@ -90,11 +84,8 @@ def test_the_family_names_only_what_the_program_had_before_this_pr():
 
 
 def test_the_cell_and_its_traffic_are_the_issues():
-    cell = [w for w in BENCH["workloads"] if w["name"] == "olmoe_reason"][0]
-    assert BENCH["workloads"][-1] is cell and len(BENCH["workloads"]) == 5
-    assert (cell["config"], cell["traffic"], cell["chips"]) == (
-        "olmoe_1b_7b_l8", "reason", 1)
-    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) == 1
+    held.pr26_cell(BENCH)  # fifth, one chip, in the lists chat is in
+    held.pr31_entries(BENCH)  # and, since PR 31, in PR 24's six and moe.*
     chat = manifest_lib.read_json(BENCH, "cells", "mistral7b_chat")
     mine = manifest_lib.read_json(BENCH, "cells", "olmoe_reason")
     for key in ("kind", "max_ongoing_requests", "deployment", "warmup_s",
@@ -107,31 +98,29 @@ def test_the_cell_and_its_traffic_are_the_issues():
     assert mix["output_tokens"] == {"min": 256, "max": 1024, "body_max": 768,
                                     "tail_share": 0.1, "tail_alpha": 1.5}
     assert (mix["block"], mix["shuffle"], mix["order_seed"]) == (32, 8, 23)
-    e2e = {m["name"] for m in manifest_lib.metrics_for(
-        BENCH, "olmoe_reason", False)}
-    assert e2e == {"gap_p95_ms", "setup_s"}
     per_layer = {m["name"] for m in manifest_lib.metrics_for(
         BENCH, "olmoe_reason", True)}
-    assert per_layer == set(SHARED) | {"own.worker_start_s"}
-    for m in BENCH["per_layer"]:
-        if m["name"] in SHARED:  # appended to, nothing else changed
-            assert m["workloads"] == ["mistral7b_chat", "olmoe_reason"]
+    assert per_layer >= set(held.SHARED) | set(JOINED) | set(NEW)
 
 
 def test_the_third_tiny_manifest_is_the_second_plus_the_expert_cell():
-    assert OLMOE["configs"][:-1] == LAYERS["configs"]
-    assert OLMOE["workloads"][:-1] == LAYERS["workloads"]
-    assert OLMOE["workloads"][-1]["name"] == "tiny_reason"
+    """As ``BENCHMARK.json`` took the cell: by appending, to the lists the
+    chat cell is in (PR 24's six among them) and behind everything."""
+    held.only_added(LAYERS, OLMOE)
+    cells = len(LAYERS["workloads"])
+    assert OLMOE["workloads"][cells]["name"] == "tiny_reason"
+    assert OLMOE["configs"][len(LAYERS["configs"])]["name"] == "tiny_olmoe"
     n = len(LAYERS["per_layer"])
-    for mine, theirs in zip(OLMOE["per_layer"][:n], LAYERS["per_layer"]):
-        if mine != theirs:  # the cell appended to the lists chat is in
-            assert mine["workloads"] == theirs["workloads"] + ["tiny_reason"]
-            assert {**mine, "workloads": 0} == {**theirs, "workloads": 0}
-            assert theirs["workloads"] == ["tiny_chat"]
-    assert [m["name"] for m in OLMOE["per_layer"][n:]] == list(NEW)
-    for m in OLMOE["per_layer"][n:]:
-        assert m["workloads"] == ["tiny_reason"]
-        assert m["moves"] == "gap_p95_ms"
+    for mine, theirs in zip(OLMOE["per_layer"], LAYERS["per_layer"]):
+        if "tiny_chat" in theirs.get("workloads", ()):
+            k = len(theirs["workloads"])  # joined right behind what was there
+            assert mine["workloads"][k:k + 1] == ["tiny_reason"], mine["name"]
+    assert set(JOINED) <= {m["name"] for m in manifest_lib.metrics_for(
+        OLMOE, "tiny_reason", True)}
+    assert [m["name"] for m in OLMOE["per_layer"][n:n + 2]] == list(NEW)
+    for m in OLMOE["per_layer"][n:n + 2]:
+        assert m["workloads"][:1] == ["tiny_reason"]
+        assert (m["moves"], m["layer"]) == ("gap_p95_ms", "experts")
         assert callable(manifest_lib.metric_reader(m["name"]))
 
 
@@ -215,19 +204,6 @@ def test_without_the_counters_a_reader_reads_zero_not_nothing(metric):
     assert read("moe.decode_step_roofline", moe_ctx(COUNTERS, {})) == 0.0
 
 
-@pytest.mark.parametrize("metric", ["step.decode_ms", "step.prefill_share",
-                                    "sched.host_share"])
-def test_the_moe_twin_reads_what_its_name_reads(metric):
-    programs = {"jit_paged_decode_step": {"count": 20, "sum_s": 0.4,
-                                          "median_s": 0.02},
-                "jit_paged_prefill_chunk": {"count": 3, "sum_s": 0.09,
-                                            "median_s": 0.03}}
-    delta = {"phase_admit_s": 1.0, "phase_decode_wait_s": 6.0,
-             "phase_emit_s": 2.0, "phase_park_s": 5.0}
-    ctx = moe_ctx(delta, programs)
-    assert read(metric + ".moe", ctx) == read(metric, ctx) is not None
-
-
 # ---------------------------------------------------------------- rehearsal
 
 
@@ -269,10 +245,13 @@ def test_rehearsal_of_the_expert_cell(trace, tmp_path):
     assert abs(runs - delta["decode_steps"] - delta["prefill_chunks"]) <= 4
     assert checks["reference_check"]["logit_err"] < 1e-4
     if trace:
-        value = {n: line["metrics"][n]["value"] for n in NEW}
-        assert value["step.decode_ms.moe"] > 0
-        assert 0 < value["step.prefill_share.moe"] < 100
-        assert 0 < value["sched.host_share.moe"] <= 100
+        value = {n: line["metrics"][n]["value"] for n in NEW + JOINED}
+        assert value["step.decode_ms.gap"] > 0
+        assert 0 < value["step.prefill_share.gap"] < 100
+        assert 0 < value["sched.host_share.gap"] <= 100
+        assert value["sched.queue_wait_ms.gap"] > 0
+        assert value["replica.stream_lag_ms.gap"] > 0
+        assert value["sched.stall_share.gap"] == 0
         assert value["moe.decode_step_roofline"] > 0
         assert 100 <= value["moe.max_expert_load"] <= 800
         assert "jit_paged_decode_step" in checks["program_runs"]

@@ -9,7 +9,7 @@ import statistics
 import numpy as np
 import pytest
 
-from perfbench.lib import peaks, stats, trace, traffic
+from perfbench.lib import hostwatch, peaks, stats, trace, traffic
 
 MS = 1_000_000  # ns
 
@@ -350,3 +350,29 @@ def test_tpu_op_events_are_whole_instructions():
     assert trace.op_family(kernel) == "closed_call [custom-call]"
     assert trace.is_collective(done) and not trace.is_collective(fusion)
     assert trace.parse_op("dot_general.1") == ("dot_general.1", "")
+
+
+# ---------------------------------------------------- the host's own pauses
+
+
+@pytest.mark.parametrize("pause_at,want", [(None, []), (3, [[0.06, 0.5]])],
+                         ids=["no_pause", "one_slice_half_a_second_late"])
+def test_the_waiting_client_keeps_the_slices_that_came_back_late(
+        monkeypatch, pause_at, want):
+    """A clock that moves only when slept on: the wait ends at its
+    deadline, and a slice that overslept is kept with its offset."""
+    now, slept = [100.0], []
+
+    def sleep(seconds):
+        slept.append(seconds)
+        now[0] += seconds + (0.5 if len(slept) - 1 == pause_at else 0.0)
+
+    monkeypatch.setattr(hostwatch.time, "perf_counter", lambda: now[0])
+    monkeypatch.setattr(hostwatch.time, "sleep", sleep)
+    late = []
+    hostwatch.sleep_until(101.0, 100.0, late)
+    assert now[0] == pytest.approx(101.0) and max(slept) <= hostwatch.SLICE_S
+    assert [[a, round(b, 3)] for a, b in late] == want
+    before = hostwatch.process_reading()
+    assert set(hostwatch.delta(before, hostwatch.process_reading())) == {
+        "cpu_s", "gc_full_collections"}
