@@ -21,6 +21,16 @@ weights from ``--seed``):
            through ContinuousScheduler at those widths: the run-ahead share
            of its decode steps, and the served tokens against decode_step
            on a sequential cache (share equal; each within 3% of its best)
+  sala     a @ray_tpu.remote(num_tpus=1) task runs the benchmark's
+           MiniCPM-SALA configuration (published widths, 16 layers of two
+           kinds, bf16): a prompt of 9300 tokens (past the selection's
+           dense_len) through the paged prefill chunks, then 6 decode steps,
+           against perfbench/reference/minicpm_sala.py on the blocks the
+           system chose (error given the choice, inside the dense cells' 8% /
+           6%), with the share of (query, K/V group, block) choices that
+           differ from the reference's own and how far below the reference's
+           cut the system's blocks scored; a slot without a sequence keeps a
+           zero state
   serve    serve.run(build_app(preset="gpt2_small")) answers 8 concurrent
            requests: six through the handle, one streamed, one over HTTP
 
@@ -397,6 +407,112 @@ def olmoe_task(seed: int) -> dict:
     return {**out, **device_report()}
 
 
+def sala_task(seed: int) -> dict:
+    """The MiniCPM-SALA-width checks (ISSUE 32): the system in bf16 — paged
+    prefill chunks, then decode steps, on a prompt past ``dense_len`` so
+    that the selection is at work — against the plain float32 reference
+    GIVEN the system's own choice of blocks, and how far that choice lies
+    from the reference's own."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from perfbench.lib import configs, weights
+    from perfbench.lib import manifest as manifest_lib
+    from perfbench.reference import minicpm_sala as ref
+    from ray_tpu.models.decode import (init_paged_caches, paged_decode_step,
+                                       paged_prefill_into_slot)
+
+    require_chip()
+    manifest = manifest_lib.load()
+    hp = manifest_lib.config(manifest, "minicpm_sala_l16")
+    cfg = configs.build_program_config(*configs.program_overrides(
+        hp, manifest_lib.read_json_from_bench("families", hp["model_type"])))
+    params = weights.make_params(cfg, seed)
+    S, C, T, P, slot, steps = 4, 512, 16, 640, 2, 6
+    caches = init_paged_caches(cfg, S * P + 1, T, P, slots=S)
+    tables = (1 + np.arange(S * P, dtype=np.int32)).reshape(S, P)
+    prefill = jax.jit(lambda *a: paged_prefill_into_slot(
+        cfg, *a, attn="pallas", logits=True, selected=True),
+        donate_argnums=(6,))
+    step = jax.jit(lambda *a: paged_decode_step(
+        cfg, *a, attn="pallas", logits=True, selected=True),
+        donate_argnums=(6,))
+    prompt = np.random.default_rng(seed).integers(
+        1, cfg.vocab_size, 9300).tolist()
+    ids = jnp.zeros(S, jnp.int32)
+    chose, got, chunk_s = [], [], []
+    for c0 in range(0, len(prompt), C):
+        chunk = prompt[c0:c0 + C]
+        t0 = time.perf_counter()
+        ids, caches, logits, picked = prefill(
+            params, jnp.asarray([chunk + [0] * (C - len(chunk))], jnp.int32),
+            np.int32(len(chunk)), np.int32(c0), jnp.asarray(tables[slot]),
+            jnp.asarray(tables[slot]), caches, ids,
+            np.int32(slot if c0 + C >= len(prompt) else -1), np.float32(0),
+            np.uint32(0), np.int32(slot))
+        chose.append(np.asarray(picked)[:, 0, :len(chunk)])
+        chunk_s.append(time.perf_counter() - t0)
+    got.append(np.asarray(logits, np.float32))
+    active = np.zeros(S, np.int32)
+    active[slot] = 1
+    cursors = np.zeros(S, np.int32)
+    cursors[slot] = len(prompt)
+    fed, step_s = [], []
+    for _ in range(steps):
+        fed.append(int(got[-1].argmax()))
+        t0 = time.perf_counter()
+        ids, caches, logits, picked = step(
+            params, ids, jnp.asarray(active), cursors, jnp.asarray(tables),
+            jnp.asarray(tables), caches, np.zeros(S, np.float32),
+            np.zeros(S, np.uint32))
+        chose.append(np.asarray(picked)[:, slot])
+        got.append(np.asarray(logits[slot], np.float32))
+        step_s.append(time.perf_counter() - t0)
+        cursors = cursors + active
+    # another slot's states were never touched
+    idle_states = float(max(np.abs(np.asarray(c.s[0])).max()
+                            for c in caches if hasattr(c, "s")))
+    del caches
+    tokens = jnp.asarray([prompt + fed], jnp.int32)
+    mine = np.concatenate(chose, axis=1)[:, None]   # [layers, 1, S, Hkv, NB]
+    want, own = ref.forward(params, tokens, hp, selected=mine,
+                            return_selected=True)
+    want = want[0, len(prompt) - 1:-1]
+    have = np.stack(got[:-1])
+    err = {"max": float(np.abs(have - want).max() / np.abs(want).max()),
+           "rms": float(np.sqrt(((have - want) ** 2).mean()
+                                / (want ** 2).mean()))}
+    # where the two choices differ, how far below the reference's cut the
+    # system's block scored (a score is a sum of 16 heads' probabilities)
+    differ = chosen = 0
+    margins = []
+    for layer, (theirs, score, cand) in enumerate(own):
+        ours = mine[layer][..., :theirs.shape[-1]]
+        differ += int((ours != theirs).sum())
+        chosen += int(theirs.sum())
+        ranked = np.where(np.logical_and(theirs, cand), score, np.inf)
+        cut = ranked.min(axis=-1, keepdims=True)
+        extra = np.logical_and(ours, np.logical_not(theirs))
+        if extra.any():
+            margins.append(float((cut - score)[extra].max()))
+    out = {"given_err": err, "choices_differing": differ,
+           "choices": chosen, "differing_share": differ / max(chosen, 1),
+           "worst_margin": max(margins, default=0.0),
+           "idle_slot_state": idle_states,
+           "chunk_ms_by_position": [round(1e3 * x, 1) for x in chunk_s],
+           "step_ms": [round(1e3 * x, 1) for x in step_s]}
+    bad = []
+    # the dense serving cells' tolerance (bf16 through 16 layers)
+    if err["max"] > 0.08 or err["rms"] > 0.06:
+        bad.append("error given the choice above the dense cells' tolerance")
+    if idle_states != 0.0:
+        bad.append("a slot without a sequence has a state")
+    if bad:
+        raise RuntimeError(f"sala: {bad}: {out}")
+    return {**out, **device_report()}
+
+
 def served_batch(cfg, params, seed: int) -> dict:
     """A short mixed batch through ``ContinuousScheduler`` at the widths
     ``params`` has (ISSUE 29): twelve requests over eight slots, the loop
@@ -535,6 +651,15 @@ def olmoe_phase(seed: int) -> None:
     emit("olmoe", seconds=round(time.perf_counter() - t0, 1), **out)
 
 
+def sala_phase(seed: int) -> None:
+    import ray_tpu
+
+    t0 = time.perf_counter()
+    out = ray_tpu.get(
+        ray_tpu.remote(num_tpus=1)(sala_task).remote(seed), timeout=1500)
+    emit("sala", seconds=round(time.perf_counter() - t0, 1), **out)
+
+
 def serve_phase(seed: int) -> None:
     import ray_tpu
     import ray_tpu.serve as serve
@@ -644,6 +769,7 @@ def one_chip(seed: int) -> dict:
     out = fit("train", seed, steps=6, mesh=None, chips=1)
     kernels_phase(seed)
     olmoe_phase(seed)
+    sala_phase(seed)
     serve_phase(seed)
     return out["device"]
 

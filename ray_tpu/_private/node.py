@@ -180,17 +180,22 @@ class NodeHandle:
         )
 
     def stop(self) -> None:
+        """SIGTERM both daemons and return when they have gone. The
+        supervisor goes once its workers have (``Supervisor.stop`` reaps
+        them, a killed chip worker in seconds, within its own 30 s), so it
+        is given longer than that before it is killed; the controller has
+        nothing to wait for."""
         for proc in (self.supervisor_proc, self.controller_proc):
             try:
                 proc.terminate()
             except Exception:
                 pass
-        deadline = time.monotonic() + 3
-        for proc in (self.supervisor_proc, self.controller_proc):
+        for proc, timeout in ((self.supervisor_proc, 40), (self.controller_proc, 3)):
             try:
-                proc.wait(timeout=max(0.1, deadline - time.monotonic()))
+                proc.wait(timeout=timeout)
             except Exception:
                 try:
                     proc.kill()
+                    proc.wait(timeout=3)
                 except Exception:
                     pass

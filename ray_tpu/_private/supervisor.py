@@ -26,6 +26,7 @@ import dataclasses
 import json
 import logging
 import os
+import signal
 import subprocess
 import sys
 import tempfile
@@ -265,6 +266,7 @@ class Supervisor:
         self._pin_client_addrs: Dict[str, Address] = {}
         self._pin_client_fails: Dict[str, int] = {}
         self._pin_sweep_task: Optional[asyncio.Task] = None
+        self._stopping = False  # stop() has begun: no worker is spawned
         # clients whose pins were just force/bulk-released: a straggler
         # unpin retry from them is a benign shutdown race, not the
         # protocol bug the strict unpin guards against
@@ -389,21 +391,55 @@ class Supervisor:
         return self.metrics_server.port if self.metrics_server else -1
 
     async def stop(self) -> None:
+        self._stopping = True
         for t in (self._sync_task, self._reap_task, self._monitor_task,
                   self._log_task, self._memory_task, self._pin_sweep_task):
             if t is not None:
                 t.cancel()
         if self.metrics_server is not None:
             await self.metrics_server.stop()
-        for w in self.workers.values():
-            if w.proc is not None:
-                try:
-                    w.proc.terminate()
-                except Exception:
-                    pass
+        await self._stop_workers()
         self.store.shutdown()
         await self.clients.close_all()
         await self.server.stop()
+
+    async def _stop_workers(self, grace_s: float = 2.0,
+                            timeout_s: float = 30.0) -> None:
+        """SIGTERM every worker, SIGKILL what is left after *grace_s*, and
+        return once this process has no child left (or *timeout_s* passed).
+
+        A node has stopped when its processes have: a killed chip worker
+        needs seconds to hand back its chip and its memory, and until it is
+        reaped it still runs. Reaping here, in the parent, also leaves no
+        zombie to init. The supervisor runs in a process of its own
+        (``main``), so every child is a worker, reaped by ``waitpid(-1)``
+        whether or not its Popen is still held."""
+        from ray_tpu._private.watchdog import _kill_children
+
+        for proc in [w.proc for w in self.workers.values()] + \
+                list(self._spawned_procs.values()):
+            if proc is not None:
+                try:
+                    proc.terminate()
+                except Exception:
+                    pass
+        start, killed = time.monotonic(), False
+        while True:
+            try:
+                pid, _ = os.waitpid(-1, os.WNOHANG)
+            except ChildProcessError:
+                return
+            if pid:
+                continue
+            waited = time.monotonic() - start
+            if waited > timeout_s:
+                logger.warning("workers still alive %.0fs after the kill",
+                               waited)
+                return
+            if not killed and waited > grace_s:
+                _kill_children(signal.SIGKILL)
+                killed = True
+            await asyncio.sleep(0.01)
 
     @idempotent
     async def rpc_ping(self, body=None) -> str:
@@ -876,6 +912,8 @@ class Supervisor:
                             chips: List[int]) -> WorkerHandle:
         from ray_tpu._private.watchdog import owner_env
 
+        if self._stopping:
+            raise RuntimeError("the supervisor is stopping")
         env = owner_env(self._worker_env(spec, chips))  # workers die with us
         env["RAY_TPU_WORKER_ENV_KEY"] = env_key
         env_spec = await self.runtime_envs.setup(spec.runtime_env)
@@ -1853,7 +1891,13 @@ def main() -> None:
             with open(tmp, "w") as f:
                 f.write(f"{addr[0]}:{addr[1]}")
             os.replace(tmp, args.address_file)
-        await asyncio.Event().wait()
+        # SIGTERM (the driver's shutdown): take the workers along and go
+        # when they have gone. A lost owner is the watchdog's, which exits hard
+        stopping = asyncio.Event()
+        asyncio.get_running_loop().add_signal_handler(
+            signal.SIGTERM, stopping.set)
+        await stopping.wait()
+        await sup.stop()
 
     asyncio.run(run())
 

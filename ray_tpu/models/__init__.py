@@ -16,4 +16,5 @@ from ray_tpu.models.presets import (  # noqa: F401
     llama3_8b,
     llama_debug,
     moe_debug,
+    minicpm_sala_debug,
 )

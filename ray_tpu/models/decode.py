@@ -17,10 +17,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.transformer import (TransformerConfig, _mlp, _norm,
-                                        _qkv, forward, stacked_mlp)
+from ray_tpu.models.transformer import (ATTENTION, LINEAR, SPARSE,
+                                        TransformerConfig, _mlp, _norm, _qkv,
+                                        _residual, embed, final_hidden,
+                                        forward, layer_params, linear_mixer,
+                                        project, rope_table, sparse_mixer,
+                                        sparse_pool_pages, stacked_mlp)
 from ray_tpu.ops.paged_attention import paged_attention
-from ray_tpu.ops.rotary import rope_frequencies
+from ray_tpu.ops.sparse_attention import check_pool
 
 
 @jax.tree_util.register_dataclass
@@ -62,20 +66,50 @@ class LayerKVCache:
         return bias[None, None, None, :, :]
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class LinearState:
+    """What a 'lightning-attn' layer keeps instead of keys and values: s
+    [rows, H, D, D] float32, one row a sequence (the contiguous cache) or a
+    slot (the serving pool, where the cursors are the caller's and
+    ``length`` is None)."""
+
+    s: Any
+    length: Any = None
+
+
 def init_caches(cfg: TransformerConfig, batch: int, max_len: int,
-                dtype=None) -> List[LayerKVCache]:
+                dtype=None) -> List[Any]:
+    """A contiguous cache a layer, by the layer's kind."""
     dtype = dtype or cfg.dtype
-    return [LayerKVCache.zeros(batch, max_len, cfg.kv_heads, cfg.head_dim,
-                               dtype) for _ in range(cfg.num_layers)]
+    zero = jnp.zeros((), jnp.int32)
+
+    def one(kind):
+        if kind == ATTENTION:
+            return LayerKVCache.zeros(batch, max_len, cfg.kv_heads,
+                                      cfg.head_dim, dtype)
+        if kind == LINEAR:
+            return LinearState(
+                s=jnp.zeros((batch, cfg.num_heads, cfg.head_dim,
+                             cfg.head_dim), jnp.float32), length=zero)
+        # a pool of its own: page 0 the garbage page, then a sequence's
+        # pages in order, so its page table is the identity
+        return dataclasses.replace(SparsePagedKVCache.zeros(
+            1 + batch * sparse_pool_pages(cfg, max_len),
+            cfg.sparse.kernel_stride, cfg.kv_heads, cfg.head_dim, dtype),
+            length=zero)
+
+    return [one(kind) for kind in cfg.kinds]
 
 
 def prefill(cfg: TransformerConfig, params, tokens, caches):
     """Run the prompt through the model, filling caches.
-    Returns (logits_last [B, vocab], caches)."""
+    Returns (logits_last [B, vocab], caches). The head sees the last
+    position alone: a long prompt's logits are never made whole."""
     positions = jnp.arange(tokens.shape[1])[None, :] + caches[0].length
-    logits, caches = forward(cfg, params, tokens, positions=positions,
-                             kv_caches=caches)
-    return logits[:, -1], caches
+    hidden, caches = forward(cfg, params, tokens, positions=positions,
+                             kv_caches=caches, return_hidden=True)
+    return project(cfg, params, hidden[:, -1:])[:, 0], caches
 
 
 def decode_step(cfg: TransformerConfig, params, token, caches):
@@ -273,9 +307,36 @@ class PagedKVCache:
         )
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class SparsePagedKVCache:
+    """A 'minicpm4' layer's page pool: k and v as ``PagedKVCache`` holds
+    them, and beside them ``means`` [num_pages, Hkv * D] float32, each
+    page's pooled key row (what the layer's selection scores). The same
+    layout is that layer's CONTIGUOUS cache (``init_caches``), which then
+    carries its ``length``; in the serving pool the cursors are the
+    caller's and it is None."""
+
+    k: Any
+    v: Any
+    means: Any
+    length: Any = None
+
+    @classmethod
+    def zeros(cls, num_pages: int, page_tokens: int, kv_heads: int,
+              head_dim: int, dtype=jnp.bfloat16) -> "SparsePagedKVCache":
+        pool = PagedKVCache.zeros(num_pages, page_tokens, kv_heads, head_dim,
+                                  dtype)
+        return cls(k=pool.k, v=pool.v, means=jnp.zeros(
+            (num_pages, kv_heads * head_dim), jnp.float32))
+
+
 def init_paged_caches(cfg: TransformerConfig, num_pages: int,
                       page_tokens: int, pages_per_slot: int,
-                      dtype=None) -> List[PagedKVCache]:
+                      dtype=None, slots: Optional[int] = None) -> List[Any]:
+    """The serving pool, a layer at a time and by the layer's kind: pages
+    for an attention layer (with pooled key rows for a 'minicpm4' one), a
+    state a slot (``slots`` of them) for a 'lightning-attn' one."""
     if page_tokens < 1:
         raise ValueError(f"page_tokens must be >= 1, got {page_tokens}")
     if num_pages < 2:
@@ -289,86 +350,114 @@ def init_paged_caches(cfg: TransformerConfig, num_pages: int,
         raise ValueError(
             f"pages_per_slot * page_tokens ({pages_per_slot * page_tokens}) "
             f"exceeds cfg.max_seq_len ({cfg.max_seq_len})")
+    if SPARSE in cfg.kinds:
+        check_pool(cfg.sparse, page_tokens, pages_per_slot)
+    if cfg.recurrent and not slots:
+        raise ValueError("a model with 'lightning-attn' layers keeps a state "
+                         "a slot: init_paged_caches needs slots")
     dtype = dtype or cfg.dtype
-    return [PagedKVCache.zeros(num_pages, page_tokens, cfg.kv_heads,
-                               cfg.head_dim, dtype)
-            for _ in range(cfg.num_layers)]
 
+    def one(kind):
+        if kind == LINEAR:
+            return LinearState(s=jnp.zeros(
+                (slots, cfg.num_heads, cfg.head_dim, cfg.head_dim),
+                jnp.float32))
+        pool = SparsePagedKVCache if kind == SPARSE else PagedKVCache
+        return pool.zeros(num_pages, page_tokens, cfg.kv_heads, cfg.head_dim,
+                          dtype)
 
-def _layer_params(cfg: TransformerConfig, params, i: int):
-    if cfg.scan_layers:
-        return jax.tree.map(lambda a, i=i: a[i], params["blocks"])
-    return params["blocks"][str(i)]
+    return [one(kind) for kind in cfg.kinds]
 
 
 def _paged_forward_inplace(cfg: TransformerConfig, params, tokens, positions,
                            lengths, read_tables, write_tables, caches, impl,
-                           valid):
+                           valid, *, slot=None, real_len=None, active=None,
+                           taps=None):
     """The serving forward: one K-token-window pass over all S slots where
-    each layer (1) writes the window's k/v DIRECTLY into its pages —
-    ``pool.at[page, offset].set`` through the write table,
+    each attention layer (1) writes the window's k/v DIRECTLY into its pages
+    — ``pool.at[page, offset].set`` through the write table,
     write-before-attend, so XLA updates the donated pool in place — and (2)
-    attends through the read table via ``ops.paged_attention(impl=)``.
-    Layer math mirrors ``transformer._block`` exactly.
+    attends through the read table via ``ops.paged_attention(impl=)``; a
+    'minicpm4' layer does the same through ``transformer.sparse_mixer`` and
+    attends the blocks it chooses; a 'lightning-attn' layer
+    (``transformer.linear_mixer``) reads and writes its states instead: all
+    slots' in a step, of which the rows not ``active`` [S] keep theirs
+    bitwise, or in a chunk (``slot`` given: the one row is that slot's) the
+    slot's own, taken as zero when the chunk starts at position 0 — a new
+    sequence needs no reset beforehand — and advanced by the chunk's
+    ``real_len`` real tokens. Layer math mirrors ``transformer._block``.
 
     tokens/positions: [S, K]; lengths: [S] attention cursors;
     read_tables/write_tables: [S, P]. Positions on unallocated/shared pages
     redirect to the garbage page through the write table. ``valid``: bool
     [S, K], the rows that carry a live token (not a slot without a sequence,
     not a chunk's padding): the expert layer routes the others nowhere.
+    ``taps``: a list that is given each 'minicpm4' layer's choice (debug).
     Returns (logits [S, K, vocab], caches, moe): moe is None for a dense
     model, else ``{"counts": [L, E], "routes": [L, S, K, k]}`` — the rows
     each layer's experts received (they sum to valid rows x k a layer: no
     row is dropped) and the experts each row chose."""
-    T = caches[0].k.shape[1]
-    P = read_tables.shape[1]
-    x = params["embed"]["table"].astype(cfg.dtype)[tokens]
+    x = embed(cfg, params, tokens)
     if cfg.pos == "learned":
         x = x + params["pos_embed"]["table"].astype(cfg.dtype)[positions]
-        rope = None
-    else:
-        rope = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
-                                cfg.rope_theta)
-    pages = jnp.take_along_axis(
-        write_tables, jnp.clip(positions // T, 0, P - 1), axis=1)
-    offs = positions % T
+    rope = rope_table(cfg)
+    if ATTENTION in cfg.kinds:
+        T = caches[cfg.kinds.index(ATTENTION)].k.shape[1]
+        P = read_tables.shape[1]
+        pages = jnp.take_along_axis(
+            write_tables, jnp.clip(positions // T, 0, P - 1), axis=1)
+        offs = positions % T
     new_caches = []
     moe_layers = []
-    for i in range(cfg.num_layers):
-        p = _layer_params(cfg, params, i)
+    for i, kind in enumerate(cfg.kinds):
+        p = layer_params(cfg, params, i)
         c = caches[i]
         ap = p["attn"]
-        q, k, v = _qkv(cfg, ap, _norm(cfg, p["ln1"], x), rope, positions)
-        ck = c.k.at[pages, offs].set(
-            k.reshape(*k.shape[:2], -1).astype(c.k.dtype))
-        cv = c.v.at[pages, offs].set(
-            v.reshape(*v.shape[:2], -1).astype(c.v.dtype))
-        o = paged_attention(q, ck, cv, read_tables, lengths, impl=impl)
-        x = x + jnp.einsum("bshk,hkd->bsd", o, ap["wo"].astype(cfg.dtype))
+        h = _norm(cfg, p["ln1"], x)
+        if kind == LINEAR and slot is None:
+            a, s = linear_mixer(cfg, ap, h, positions, c.s, active=active)
+            new_caches.append(LinearState(s=s))
+        elif kind == LINEAR:
+            mine = lax.dynamic_slice_in_dim(c.s, slot, 1, axis=0)
+            mine = jnp.where(positions[0, 0] == 0, 0.0, mine)
+            a, mine = linear_mixer(cfg, ap, h, positions, mine,
+                                   real_len=real_len)
+            new_caches.append(LinearState(
+                s=lax.dynamic_update_slice_in_dim(c.s, mine, slot, axis=0)))
+        elif kind == SPARSE:
+            a, pools = sparse_mixer(cfg, ap, h, positions, lengths,
+                                    (c.k, c.v, c.means), read_tables,
+                                    write_tables, impl=impl, taps=taps)
+            new_caches.append(SparsePagedKVCache(*pools))
+        else:
+            q, k, v = _qkv(cfg, ap, h, rope, positions)
+            ck = c.k.at[pages, offs].set(
+                k.reshape(*k.shape[:2], -1).astype(c.k.dtype))
+            cv = c.v.at[pages, offs].set(
+                v.reshape(*v.shape[:2], -1).astype(c.v.dtype))
+            o = paged_attention(q, ck, cv, read_tables, lengths, impl=impl)
+            a = jnp.einsum("bshk,hkd->bsd", o, ap["wo"].astype(cfg.dtype))
+            new_caches.append(PagedKVCache(k=ck, v=cv))
+        x = _residual(cfg, x, a)
         mlp_p, layer = stacked_mlp(cfg, params, p, i)
         m, _, moe = _mlp(cfg, mlp_p, _norm(cfg, p["ln2"], x), valid, layer)
-        x = x + m
+        x = _residual(cfg, x, m)
         moe_layers.append(moe)
-        new_caches.append(PagedKVCache(k=ck, v=cv))
     moe = (jax.tree.map(lambda *a: jnp.stack(a), *moe_layers)
            if cfg.mlp == "moe" else None)
-    x = _norm(cfg, params["final_norm"], x)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x,
-                            params["embed"]["table"].astype(cfg.dtype))
-    else:
-        logits = jnp.einsum("bsd,dv->bsv", x,
-                            params["lm_head"]["kernel"].astype(cfg.dtype))
+    logits = project(cfg, params, final_hidden(cfg, params, x))
     return logits, new_caches, moe
 
 
-def _paged_outputs(first, caches, moe, moe_info: bool, logits):
+def _paged_outputs(first, caches, moe, moe_info: bool, logits, taps=None):
     """What a paged program returns: ``(first, caches)``, with ``moe_info``
-    the expert layers' counts and routes next, and last the ``logits`` the
+    the expert layers' counts and routes next, then the ``logits`` the
     ids in ``first`` were sampled from where the caller asked for them
-    (else None)."""
+    (else None), and last what the 'minicpm4' layers chose (``taps``,
+    stacked) where it asked for that."""
     return ((first, caches) + ((moe,) if moe_info else ())
-            + (() if logits is None else (logits,)))
+            + (() if logits is None else (logits,))
+            + (() if taps is None else (jnp.stack(taps),)))
 
 
 def _check_moe_info(cfg: TransformerConfig, moe_info: bool):
@@ -379,9 +468,10 @@ def _check_moe_info(cfg: TransformerConfig, moe_info: bool):
 
 def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
                             cursor, read_row, write_row,
-                            caches: List[PagedKVCache], ids, slot,
-                            temperature, seed, *, attn: str,
-                            moe_info: bool = False, logits: bool = False):
+                            caches: List[Any], ids, slot,
+                            temperature, seed, state_slot=None, *, attn: str,
+                            moe_info: bool = False, logits: bool = False,
+                            selected: bool = False):
     """One prefill chunk into ONE slot, through its page table. tokens:
     [1, C] — the next C prompt tokens, zero-padded past ``real_len`` (so
     every chunk size compiles to the same program). The chunk lands at
@@ -401,7 +491,11 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
     [slots] int32 is the vector ``paged_decode_step`` takes as its tokens,
     ``slot`` the row that takes the sampled id, -1 for a chunk that is not
     the last (the vector is then returned as it came). The first token so
-    reaches the step without a visit to the host.
+    reaches the step without a visit to the host. ``state_slot``: the
+    slot itself, whichever chunk this is — where the model has
+    'lightning-attn' layers, their states of that slot are what the chunk
+    continues (from zero when ``cursor`` is 0) and leaves advanced by
+    ``real_len`` tokens.
 
     Caller contract (scheduler-enforced): every page covering the REAL
     tokens [cursor, cursor + real_len) is allocated and OWNED (write_row
@@ -413,13 +507,21 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
     value, the expert layers' ``{"counts": [L, E], "routes": [L, 1, C,
     k]}`` (the chunk's padding past ``real_len`` is routed nowhere and not
     counted); with ``logits`` the float32-castable logits [vocab] at the
-    last REAL token come last (tests compare them with an oracle; the
-    scheduler never asks)."""
+    last REAL token come next (tests compare them with an oracle; the
+    scheduler never asks); with ``selected`` the blocks the 'minicpm4'
+    layers chose, bool [layers of the kind, 1, C, Hkv, NB], last."""
     _check_moe_info(cfg, moe_info)
+    if cfg.recurrent and state_slot is None:
+        raise ValueError("a model with 'lightning-attn' layers needs "
+                         "state_slot: the slot whose states the chunk "
+                         "continues")
+    taps = [] if selected else None
     steps = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
     all_logits, new_caches, moe = _paged_forward_inplace(
         cfg, params, tokens, steps + cursor, jnp.reshape(cursor, (1,)),
-        read_row[None], write_row[None], caches, attn, steps < real_len)
+        read_row[None], write_row[None], caches, attn, steps < real_len,
+        slot=0 if state_slot is None else state_slot, real_len=real_len,
+        taps=taps)
     last = lax.dynamic_index_in_dim(all_logits[0], real_len - 1,
                                     keepdims=False)
     first = sample_token(last[None], jnp.reshape(temperature, (1,)),
@@ -427,14 +529,14 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
                          jnp.reshape(cursor + real_len, (1,)))[0]
     ids = jnp.where(jnp.arange(ids.shape[0]) == slot, first, ids)
     return _paged_outputs(ids, new_caches, moe, moe_info,
-                          last if logits else None)
+                          last if logits else None, taps)
 
 
 def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
                       cursors, read_tables, write_tables,
-                      caches: List[PagedKVCache], temperature, seeds, *,
+                      caches: List[Any], temperature, seeds, *,
                       attn: str, moe_info: bool = False,
-                      logits: bool = False):
+                      logits: bool = False, selected: bool = False):
     """One fixed-shape decode step over the whole arena, through page
     tables. tokens/active/cursors: [slots] int32; read_tables/write_tables:
     [slots, P] int32. Row s's token is written at ``pool[page, offset]`` of
@@ -443,7 +545,9 @@ def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
     An inactive row attends nothing (it streams no page, and the expert
     layer routes it nowhere), but it WRITES at its cursor like any other:
     the caller's tables send that write to the garbage page, or to a
-    position the row's own sequence writes again before attending it.
+    position the row's own sequence writes again before attending it. A
+    'lightning-attn' layer's state has no such second chance, so an
+    inactive row's state is left bitwise as it was.
     ``attn``: the implementation ``ops.paged_attention`` runs ('reference'
     | 'pallas').
 
@@ -457,19 +561,23 @@ def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
     Returns (ids [slots], caches); with ``moe_info`` (mlp='moe') a third
     value, the expert layers' ``{"counts": [L, E], "routes": [L, slots, 1,
     k]}`` over the active rows; with ``logits`` the logits [slots, vocab]
-    the ids were sampled from come last (tests compare them with an
-    oracle; the scheduler never asks)."""
+    the ids were sampled from come next (tests compare them with an
+    oracle; the scheduler never asks); with ``selected`` the blocks the
+    'minicpm4' layers chose, bool [layers of the kind, slots, 1, Hkv, NB],
+    last."""
     _check_moe_info(cfg, moe_info)
+    taps = [] if selected else None
     all_logits, new_caches, moe = _paged_forward_inplace(
         cfg, params, tokens[:, None], cursors[:, None],
         jnp.where(active > 0, cursors, -1),
-        read_tables, write_tables, caches, attn, active[:, None] > 0)
+        read_tables, write_tables, caches, attn, active[:, None] > 0,
+        active=active, taps=taps)
     sampled = sample_token(all_logits[:, 0],
                            jnp.where(active > 0, temperature, 0.0), seeds,
                            cursors + 1)
     ids = jnp.where(active > 0, sampled, tokens)
     return _paged_outputs(ids, new_caches, moe, moe_info,
-                          all_logits[:, 0] if logits else None)
+                          all_logits[:, 0] if logits else None, taps)
 
 
 def paged_verify_step(cfg: TransformerConfig, params, tokens, active,
@@ -503,6 +611,11 @@ def paged_verify_step(cfg: TransformerConfig, params, tokens, active,
     (mlp='moe') a third value, the expert layers' ``{"counts": [L, E],
     "routes": [L, slots, K, k]}`` over the used rows."""
     _check_moe_info(cfg, moe_info)
+    if cfg.recurrent:
+        raise ValueError(
+            "paged_verify_step cannot run a model with 'lightning-attn' "
+            "layers: a rejected draft would have to rewind their states, "
+            "and no snapshot is kept")
     K = tokens.shape[1]
     steps = jnp.arange(K, dtype=jnp.int32)[None]
     logits, new_caches, moe = _paged_forward_inplace(

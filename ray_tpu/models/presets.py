@@ -83,6 +83,29 @@ def moe_debug(**overrides) -> TransformerConfig:
     return TransformerConfig(**kw)
 
 
+def minicpm_sala_debug(**overrides) -> TransformerConfig:
+    """Tiny MiniCPM-SALA-shaped config (openbmb/MiniCPM-SALA: layers of two
+    kinds in the published 1:3, block-selected sparse attention and decayed
+    linear attention, q/k norm a head, output gates, the MiniCPM scales) for
+    tests: two periods, and a selection scaled down with the context so
+    that it is ACTIVE past 48 tokens."""
+    kw = dict(
+        vocab_size=256, num_layers=8, embed_dim=64, num_heads=4,
+        num_kv_heads=2, mlp="swiglu", mlp_dim=128, max_seq_len=1024,
+        layer_kinds=("minicpm4",) + ("lightning-attn",) * 3
+        + ("minicpm4",) + ("lightning-attn",) * 3,
+        sparse_config=dict(kernel_size=8, kernel_stride=4, block_size=16,
+                           topk=2, init_blocks=1, window_size=32,
+                           dense_len=48),
+        head_qk_norm=True, scale_emb=12.0, scale_depth=1.4,
+        scale_depth_layers=32, dim_model_base=16, norm="rmsnorm",
+        pos="rope", rope_theta=10000.0, norm_eps=1e-6, tie_embeddings=False,
+        dtype=jnp.float32,
+    )
+    kw.update(overrides)
+    return TransformerConfig(**kw)
+
+
 # ---------------------------------------------------------------------------
 # pipeline stage partition (MPMD train.PipelineTrainer shards)
 #
@@ -132,6 +155,10 @@ def _check_pipeline_cfg(cfg) -> None:
             "router's load-balancing aux loss would need summing across "
             "stages every microbatch. Use a dense mlp ('gelu'/'swiglu'), "
             "or train MoE configs with the SPMD expert-parallel path")
+    if cfg.layer_kinds and set(cfg.layer_kinds) != {"attention"}:
+        raise ValueError(
+            "pipeline_stage_defs: cfg.layer_kinds other than 'attention' "
+            "is unsupported — a stage's blocks are stacked as one kind")
 
 
 def _resolve_virtual_stages(virtual_stages, num_stages: int,

@@ -13,24 +13,47 @@ Config switches:
           dropless top-k routing over grouped matmuls, ops/moe.py)
   * GQA via num_kv_heads; tied embeddings via tie_embeddings.
   * qk_norm: RMSNorm over the whole projected q and k (OLMoE);
+    head_qk_norm: RMSNorm over each head's values, one scale of head_dim
+    shared by the heads (MiniCPM);
     moe_renormalize: top-k router weights divided by their sum (Mixtral)
     or taken as they are (OLMoE).
+  * layer_kinds: the mixer of each layer, a pattern over the depth —
+    'attention' (softmax attention over the whole context, the only kind
+    when the pattern is None), 'minicpm4' (block-selected sparse attention
+    with an output gate and no RoPE, ops/sparse_attention.py) and
+    'lightning-attn' (decayed linear attention on a [D, D] state a head,
+    with RoPE, an output norm and gate, ops/linear_attention.py). The two
+    are written once, state in and state out (``sparse_mixer``,
+    ``linear_mixer``), and called from every forward: this one with or
+    without caches, and the paged serving forward of models/decode.py.
+  * scale_emb, scale_depth (over scale_depth_layers), dim_model_base: the
+    MiniCPM scales of the embedding, of every residual branch and of the
+    hidden state before the output head.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.attention import attention
+from ray_tpu.ops.linear_attention import (linear_attention_chunk,
+                                          linear_attention_step, slopes)
 from ray_tpu.ops.losses import softmax_cross_entropy
 from ray_tpu.ops.norms import layer_norm, rms_norm
 from ray_tpu.ops.ring_attention import ring_attention_local
-from ray_tpu.ops.rotary import apply_rotary, rope_frequencies
+from ray_tpu.ops.rotary import (apply_rotary, apply_rotary_at,
+                                rope_frequencies)
+from ray_tpu.ops.sparse_attention import (SparseSizes, sparse_attention,
+                                          update_page_means)
+
+ATTENTION, SPARSE, LINEAR = "attention", "minicpm4", "lightning-attn"
+LAYER_KINDS = (ATTENTION, SPARSE, LINEAR)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,6 +72,23 @@ class TransformerConfig:
     # RMSNorm over ALL H*D (resp. Hkv*D) projected values of q and k, before
     # the split into heads and before RoPE (OLMoE's q_norm / k_norm)
     qk_norm: bool = False
+    # RMSNorm over each head's head_dim values of q and k, before RoPE
+    head_qk_norm: bool = False
+    # the mixer of every layer (LAYER_KINDS); None: 'attention' throughout
+    layer_kinds: Optional[Tuple[str, ...]] = None
+    # 'minicpm4': the sizes of the selection (ops.sparse_attention
+    # .SparseSizes' fields; a dict is taken and frozen)
+    sparse_config: Any = None
+    # 'lightning-attn': head h decays by exp(-2^(-e (h + 1) / H)) a token
+    linear_slope_exponent: float = 8.0
+    # MiniCPM's scales: the embedding times scale_emb; every residual
+    # branch times scale_depth / sqrt(scale_depth_layers or num_layers)
+    # (0: added as it is); the hidden state before the head divided by
+    # embed_dim / dim_model_base (0: as it is)
+    scale_emb: float = 1.0
+    scale_depth: float = 0.0
+    scale_depth_layers: int = 0
+    dim_model_base: int = 0
     max_seq_len: int = 2048
     norm: str = "rmsnorm"                     # 'rmsnorm' | 'layernorm'
     pos: str = "rope"                         # 'rope' | 'learned'
@@ -70,9 +110,51 @@ class TransformerConfig:
     fused_ce: bool = True
     ce_chunk: int = 2048
 
+    def __post_init__(self):
+        if self.layer_kinds is not None:
+            kinds = tuple(self.layer_kinds)
+            if len(kinds) != self.num_layers or set(kinds) - set(LAYER_KINDS):
+                raise ValueError(
+                    f"layer_kinds must name one of {LAYER_KINDS} for each of "
+                    f"the {self.num_layers} layers, got {kinds}")
+            object.__setattr__(self, "layer_kinds", kinds)
+        if isinstance(self.sparse_config, dict):
+            object.__setattr__(self, "sparse_config",
+                               tuple(sorted(self.sparse_config.items())))
+
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        return self.layer_kinds or (ATTENTION,) * self.num_layers
+
+    @property
+    def period(self) -> int:
+        """The pattern's period: the layers one scan step applies when
+        layers are stacked (1 for a model of one kind)."""
+        kinds = self.kinds
+        return next(p for p in range(1, len(kinds) + 1)
+                    if len(kinds) % p == 0
+                    and all(k == kinds[i % p] for i, k in enumerate(kinds)))
+
+    @property
+    def recurrent(self) -> bool:
+        """Some layer carries a state that is no K/V cache: what a token
+        leaves behind cannot be cut at a page boundary or rewound."""
+        return LINEAR in self.kinds
+
+    @property
+    def sparse(self) -> SparseSizes:
+        return SparseSizes(**dict(self.sparse_config or ()))
+
+    @property
+    def residual_scale(self) -> float:
+        if not self.scale_depth:
+            return 1.0
+        return self.scale_depth / math.sqrt(self.scale_depth_layers
+                                            or self.num_layers)
 
     @property
     def head_dim(self) -> int:
@@ -93,9 +175,12 @@ class TransformerConfig:
 # params
 
 
-def _block_params(cfg: TransformerConfig, key) -> Dict[str, Any]:
+def _block_params(cfg: TransformerConfig, key,
+                  kind: str = ATTENTION) -> Dict[str, Any]:
     d, h, kvh, hd, f = (cfg.embed_dim, cfg.num_heads, cfg.kv_heads,
                         cfg.head_dim, cfg.hidden_dim)
+    if kind == LINEAR:
+        kvh = h  # a key and a value head for every query head
     ks = jax.random.split(key, 8)
     init = jax.nn.initializers.normal(0.02, cfg.param_dtype)
     out_init = jax.nn.initializers.normal(
@@ -113,6 +198,13 @@ def _block_params(cfg: TransformerConfig, key) -> Dict[str, Any]:
     if cfg.qk_norm:
         p["attn"]["q_norm"] = jnp.ones((h * hd,), cfg.param_dtype)
         p["attn"]["k_norm"] = jnp.ones((kvh * hd,), cfg.param_dtype)
+    if cfg.head_qk_norm:
+        p["attn"]["q_norm"] = jnp.ones((hd,), cfg.param_dtype)
+        p["attn"]["k_norm"] = jnp.ones((hd,), cfg.param_dtype)
+    if kind != ATTENTION:
+        p["attn"]["wg"] = init(ks[7], (d, h, hd))   # the output gate
+    if kind == LINEAR:
+        p["attn"]["o_norm"] = jnp.ones((h * hd,), cfg.param_dtype)
     if cfg.mlp == "moe":
         from ray_tpu.ops.moe import init_moe_params
 
@@ -156,13 +248,30 @@ def init_params(cfg: TransformerConfig, key) -> Dict[str, Any]:
     if not cfg.tie_embeddings:
         params["lm_head"] = {
             "kernel": init(keys[2], (cfg.embed_dim, cfg.vocab_size))}
-    blocks = [_block_params(cfg, keys[3 + i]) for i in range(cfg.num_layers)]
-    if cfg.scan_layers:
-        params["blocks"] = jax.tree.map(
-            lambda *xs: jnp.stack(xs, axis=0), *blocks)
+    blocks = [_block_params(cfg, keys[3 + i], kind)
+              for i, kind in enumerate(cfg.kinds)]
+    stack = lambda layers: jax.tree.map(
+        lambda *xs: jnp.stack(xs, axis=0), *layers)
+    if cfg.scan_layers and cfg.period == 1:
+        params["blocks"] = stack(blocks)
+    elif cfg.scan_layers:
+        # layers of unequal kinds have unequal shapes: stacked by their
+        # place in the pattern's period, which is the scan's unit
+        params["blocks"] = {f"p{j}": stack(blocks[j::cfg.period])
+                            for j in range(cfg.period)}
     else:
         params["blocks"] = {str(i): b for i, b in enumerate(blocks)}
     return params
+
+
+def layer_params(cfg: TransformerConfig, params, i: int):
+    """Layer ``i``'s weights, whichever way ``init_params`` laid them out."""
+    if not cfg.scan_layers:
+        return params["blocks"][str(i)]
+    if cfg.period == 1:
+        return jax.tree.map(lambda a: a[i], params["blocks"])
+    return jax.tree.map(lambda a: a[i // cfg.period],
+                        params["blocks"][f"p{i % cfg.period}"])
 
 
 def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
@@ -174,39 +283,52 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
             return {"scale": L + ("embed_notp",)}
         return {"scale": L + ("embed_notp",), "bias": L + ("embed_notp",)}
 
-    block = {
-        "attn": {
-            "wq": L + ("embed", "heads", "head_dim"),
-            "wk": L + ("embed", "kv", "head_dim"),
-            "wv": L + ("embed", "kv", "head_dim"),
-            "wo": L + ("heads", "head_dim", "embed"),
-        },
-        "ln1": norm_axes(),
-        "ln2": norm_axes(),
-    }
-    if cfg.qk_norm:
-        block["attn"]["q_norm"] = L + (None,)
-        block["attn"]["k_norm"] = L + (None,)
-    if cfg.mlp == "moe":
-        from ray_tpu.ops.moe import moe_logical_axes
+    def block_axes(kind):
+        kv = "heads" if kind == LINEAR else "kv"
+        block = {
+            "attn": {
+                "wq": L + ("embed", "heads", "head_dim"),
+                "wk": L + ("embed", kv, "head_dim"),
+                "wv": L + ("embed", kv, "head_dim"),
+                "wo": L + ("heads", "head_dim", "embed"),
+            },
+            "ln1": norm_axes(),
+            "ln2": norm_axes(),
+        }
+        if cfg.qk_norm or cfg.head_qk_norm:
+            block["attn"]["q_norm"] = L + (None,)
+            block["attn"]["k_norm"] = L + (None,)
+        if kind != ATTENTION:
+            block["attn"]["wg"] = L + ("embed", "heads", "head_dim")
+        if kind == LINEAR:
+            block["attn"]["o_norm"] = L + (None,)
+        if cfg.mlp == "moe":
+            from ray_tpu.ops.moe import moe_logical_axes
 
-        block["mlp"] = {k: L + v for k, v in moe_logical_axes().items()}
-    elif cfg.mlp == "swiglu":
-        block["mlp"] = {"w_gate": L + ("embed", "mlp"),
-                        "w_up": L + ("embed", "mlp"),
-                        "w_down": L + ("mlp", "embed")}
+            block["mlp"] = {k: L + v for k, v in moe_logical_axes().items()}
+        elif cfg.mlp == "swiglu":
+            block["mlp"] = {"w_gate": L + ("embed", "mlp"),
+                            "w_up": L + ("embed", "mlp"),
+                            "w_down": L + ("mlp", "embed")}
+        else:
+            block["mlp"] = {"w_in": L + ("embed", "mlp"),
+                            "b_in": L + ("mlp",),
+                            "w_out": L + ("mlp", "embed"),
+                            "b_out": L + ("embed_notp",)}
+        return block
+
+    kinds = cfg.kinds
+    if cfg.scan_layers and cfg.period == 1:
+        blocks = block_axes(kinds[0])
+    elif cfg.scan_layers:
+        blocks = {f"p{j}": block_axes(kinds[j]) for j in range(cfg.period)}
     else:
-        block["mlp"] = {"w_in": L + ("embed", "mlp"),
-                        "b_in": L + ("mlp",),
-                        "w_out": L + ("mlp", "embed"),
-                        "b_out": L + ("embed_notp",)}
+        blocks = {str(i): block_axes(kind) for i, kind in enumerate(kinds)}
     axes: Dict[str, Any] = {
         "embed": {"table": ("vocab", "embed")},
         "final_norm": {"scale": ("embed_notp",)} if cfg.norm == "rmsnorm"
         else {"scale": ("embed_notp",), "bias": ("embed_notp",)},
-        "blocks": block if cfg.scan_layers
-        else {str(i): jax.tree.map(lambda a: a, block)
-              for i in range(cfg.num_layers)},
+        "blocks": blocks,
     }
     if cfg.pos == "learned":
         axes["pos_embed"] = {"table": (None, "embed")}
@@ -229,10 +351,15 @@ def _norm(cfg, p, x):
     return layer_norm(x, p["scale"], p["bias"], cfg.norm_eps)
 
 
+COMPUTED = "computed"  # ``rope``: angles from the positions, no table
+
+
 def _qkv(cfg, p, x, rope, positions):
     """The q/k/v projection of every forward (training, tensor-parallel,
     cached and paged decode): x [B, S, d] -> q [B, S, H, D], k and v
-    [B, S, Hkv, D], q and k normalized (``cfg.qk_norm``) and rotated."""
+    [B, S, Hkv, D], q and k normalized (``cfg.qk_norm``,
+    ``cfg.head_qk_norm``) and rotated (``rope``: the (cos, sin) tables,
+    ``COMPUTED`` or None)."""
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(cfg.dtype))
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(cfg.dtype))
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(cfg.dtype))
@@ -243,7 +370,13 @@ def _qkv(cfg, p, x, rope, positions):
                      cfg.norm_eps).reshape(q.shape)
         k = rms_norm(k.reshape(*k.shape[:2], -1), p["k_norm"],
                      cfg.norm_eps).reshape(k.shape)
-    if rope is not None:
+    if cfg.head_qk_norm:
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+    if rope is COMPUTED:
+        q = apply_rotary_at(q, positions, cfg.rope_theta)
+        k = apply_rotary_at(k, positions, cfg.rope_theta)
+    elif rope is not None:
         cos, sin = rope
         q = apply_rotary(q, cos, sin, positions)
         k = apply_rotary(k, cos, sin, positions)
@@ -267,6 +400,112 @@ def _attn(cfg, p, x, rope, positions, sp_axis, kv_cache=None):
         new_cache = None
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(cfg.dtype))
     return out, new_cache
+
+
+# The two mixers that are no plain attention, each written once: the state
+# comes in and goes out, and who holds it (nobody, a contiguous cache, the
+# serving pool) is the caller's business.
+
+
+def _gated_out(cfg, p, x, o):
+    """``(o * sigmoid(x Wg)) Wo``: the output gate both mixers end in."""
+    gate = jnp.einsum("bsd,dhk->bshk", x, p["wg"].astype(cfg.dtype))
+    return jnp.einsum("bshk,hkd->bsd", o * jax.nn.sigmoid(gate),
+                      p["wo"].astype(cfg.dtype))
+
+
+def linear_mixer(cfg, p, x, positions, state, *, real_len=None, active=None):
+    """'lightning-attn': x [B, S, d] at ``positions`` [B, S], state [B, H,
+    D, D] float32 -> (y [B, S, d], state). One token a row is a step, of
+    which a row that is not ``active`` ([B]; default: all are) keeps its
+    state bitwise; more are a chunk whose first ``real_len`` tokens (a
+    scalar; default: all) are real and the rest trailing padding."""
+    B, S = x.shape[:2]
+    q, k, v = _qkv(cfg, p, x, COMPUTED, positions)
+    slope = slopes(cfg.num_heads, cfg.linear_slope_exponent)
+    if S == 1:
+        o, state = linear_attention_step(
+            q[:, 0], k[:, 0], v[:, 0], state, slope,
+            jnp.ones((B,), jnp.int32) if active is None else active)
+        o = o[:, None]
+    else:
+        o, state = linear_attention_chunk(
+            q, k, v, state, slope, S if real_len is None else real_len)
+    o = rms_norm(o.reshape(B, S, -1), p["o_norm"], cfg.norm_eps)
+    return _gated_out(cfg, p, x, o.reshape(q.shape).astype(cfg.dtype)), state
+
+
+def sparse_mixer(cfg, p, x, positions, lengths, pools, read_tables,
+                 write_tables, *, impl: str, taps: Optional[List] = None):
+    """'minicpm4': x [B, S, d] at ``positions`` [B, S] over a paged pool —
+    ``pools`` = (k [N, T, Hkv * D], v, the pages' pooled key rows [N, Hkv *
+    D] float32), a row's pages through ``read_tables`` / ``write_tables``
+    [B, P], ``lengths`` [B] as ``ops.paged_attention`` takes them. The
+    window's keys and values are written, the pooled rows of the pages they
+    fell on recomputed, then the chosen blocks attended. Returns (y, pools);
+    ``taps`` (a list) is given the choice, bool [B, S, Hkv, NB]."""
+    k_pool, v_pool, means = pools
+    T, P = k_pool.shape[1], write_tables.shape[1]
+    q, k, v = _qkv(cfg, p, x, None, positions)
+    pages = jnp.take_along_axis(
+        write_tables, jnp.clip(positions // T, 0, P - 1), axis=1)
+    offs = positions % T
+    k_pool = k_pool.at[pages, offs].set(
+        k.reshape(*k.shape[:2], -1).astype(k_pool.dtype))
+    v_pool = v_pool.at[pages, offs].set(
+        v.reshape(*v.shape[:2], -1).astype(v_pool.dtype))
+    means = update_page_means(means, k_pool, write_tables, positions)
+    o, selected = sparse_attention(
+        q, k_pool, v_pool, means, read_tables, positions, lengths,
+        cfg.sparse, impl=impl, return_selected=True)
+    if taps is not None:
+        taps.append(selected)
+    return _gated_out(cfg, p, x, o), (k_pool, v_pool, means)
+
+
+def sparse_pool_pages(cfg, tokens: int) -> int:
+    """Pages (whole blocks of them) a run of ``tokens`` tokens takes."""
+    return -(-tokens // cfg.sparse.block_size) * cfg.sparse.pages_per_block
+
+
+def _mixer(cfg, kind, p, x, rope, positions, sp_axis, cache, taps):
+    """One layer's mixer over the normalized x -> (y, the cache after it).
+    ``cache``: None, or what ``models.decode.init_caches`` makes for the
+    kind (a contiguous cache is, for the sparse kind, a pool of its own
+    whose page table is the identity)."""
+    if kind == ATTENTION:
+        return _attn(cfg, p, x, rope, positions, sp_axis, cache)
+    B, S = x.shape[:2]
+    pos = jnp.broadcast_to(jnp.arange(S)[None] if positions is None
+                           else positions, (B, S)).astype(jnp.int32)
+    if kind == LINEAR:
+        state = (jnp.zeros((B, cfg.num_heads, cfg.head_dim, cfg.head_dim),
+                           jnp.float32) if cache is None else cache.s)
+        y, state = linear_mixer(cfg, p, x, pos, state)
+        return y, cache and dataclasses.replace(
+            cache, s=state, length=cache.length + S)
+    from ray_tpu.ops.paged_attention import resolve_impl
+
+    if cache is None:
+        n = 1 + B * sparse_pool_pages(cfg, S)
+        rows = (n, cfg.sparse.kernel_stride, cfg.kv_heads * cfg.head_dim)
+        pools = (jnp.zeros(rows, cfg.dtype), jnp.zeros(rows, cfg.dtype),
+                 jnp.zeros((n, rows[2]), jnp.float32))
+        length = jnp.zeros((), jnp.int32)
+    else:
+        pools, length = (cache.k, cache.v, cache.means), cache.length
+    P = (pools[0].shape[0] - 1) // B
+    tables = (1 + jnp.arange(B, dtype=jnp.int32)[:, None] * P
+              + jnp.arange(P, dtype=jnp.int32)[None])
+    y, pools = sparse_mixer(cfg, p, x, pos, jnp.broadcast_to(length, (B,)),
+                            pools, tables, tables,
+                            impl=resolve_impl(cfg), taps=taps)
+    return y, cache and dataclasses.replace(
+        cache, k=pools[0], v=pools[1], means=pools[2], length=length + S)
+
+
+def _residual(cfg, x, y):
+    return x + y * cfg.residual_scale if cfg.scale_depth else x + y
 
 
 def _mlp(cfg, p, x, valid=None, layer=None):
@@ -300,35 +539,72 @@ def stacked_mlp(cfg, params, layer_params, i):
     None, but for experts stacked over layers (``scan_layers``) outside a
     scan the whole stack and ``i`` — the grouped matmuls cannot fuse the
     slice as a dense matmul does, and would copy the layer's experts."""
-    if cfg.mlp == "moe" and cfg.scan_layers:
+    if cfg.mlp == "moe" and cfg.scan_layers and cfg.period == 1:
         return params["blocks"]["mlp"], i
     return layer_params["mlp"], None
 
 
-def _block(cfg, p, x, rope, positions, sp_axis, kv_cache=None, mlp=None):
+def _block(cfg, p, x, rope, positions, sp_axis, kv_cache=None, mlp=None,
+           kind=ATTENTION, taps=None):
     """``mlp``: ``stacked_mlp``'s pair where the caller walks stacked
-    layers one by one; None for ``(p["mlp"], None)``."""
-    a, new_cache = _attn(cfg, p["attn"], _norm(cfg, p["ln1"], x), rope,
-                         positions, sp_axis, kv_cache)
-    x = x + a
+    layers one by one; None for ``(p["mlp"], None)``. ``kind``: the layer's
+    mixer. ``taps``: a list that is given what a mixer chose (debug)."""
+    a, new_cache = _mixer(cfg, kind, p["attn"], _norm(cfg, p["ln1"], x),
+                          rope, positions, sp_axis, kv_cache, taps)
+    x = _residual(cfg, x, a)
     mlp_p, layer = mlp or (p["mlp"], None)
     m, aux, moe = _mlp(cfg, mlp_p, _norm(cfg, p["ln2"], x), layer=layer)
-    x = x + m
+    x = _residual(cfg, x, m)
     return x, new_cache, aux, moe
+
+
+def embed(cfg, params, tokens):
+    x = params["embed"]["table"].astype(cfg.dtype)[tokens]
+    return x * cfg.scale_emb if cfg.scale_emb != 1.0 else x
+
+
+def final_hidden(cfg, params, x):
+    """The last norm (and MiniCPM's division of what the head sees)."""
+    x = _norm(cfg, params["final_norm"], x)
+    if cfg.dim_model_base:
+        x = x / (cfg.embed_dim / cfg.dim_model_base)
+    return x
+
+
+def project(cfg, params, x):
+    """hidden [B, S, d] (after ``final_hidden``) -> logits [B, S, vocab]."""
+    if cfg.tie_embeddings:
+        return jnp.einsum("bsd,vd->bsv", x,
+                          params["embed"]["table"].astype(cfg.dtype))
+    return jnp.einsum("bsd,dv->bsv", x,
+                      params["lm_head"]["kernel"].astype(cfg.dtype))
+
+
+def rope_table(cfg):
+    """The (cos, sin) tables plain attention rotates by, None for a model
+    none of whose layers does (a table is as long as the context)."""
+    if cfg.pos == "learned" or ATTENTION not in cfg.kinds:
+        return None
+    return rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
 
 
 def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
             sp_axis: Optional[str] = None, kv_caches=None,
             return_aux: bool = False, return_hidden: bool = False,
-            return_routes: bool = False):
+            return_routes: bool = False, return_selected: bool = False):
     """tokens [B, S] int32 -> logits [B, S, vocab].
 
     return_hidden: skip the vocab projection and return the post-final-norm
-    hidden states [B, S, D] (with aux) — used by the fused-CE loss path.
+    hidden states [B, S, D] (with aux, or with the caches where there are
+    any) — used by the fused-CE loss path and by ``decode.prefill``, which
+    projects the last position alone.
     return_routes (debug, mlp='moe' without kv_caches): also return the
     experts every token chose in every layer, int32 [L, B, S, k] — top-k is
     discontinuous, so a comparison with another implementation has to be
     made on the same choices.
+    return_selected (debug, without kv_caches): also return the blocks every
+    query of every 'minicpm4' layer attended, bool [layers of the kind, B,
+    S, Hkv, NB], for the same reason.
 
     sp_axis: when running inside shard_map with sequence sharded over that
     axis, attention goes through the ring kernel and `positions` must be the
@@ -336,16 +612,15 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
     kv_caches: optional list/stack of per-layer decode caches (see
     ray_tpu.models.decode); when set, runs in incremental-decode mode.
     """
-    x = params["embed"]["table"].astype(cfg.dtype)[tokens]
+    x = embed(cfg, params, tokens)
     if cfg.pos == "learned":
         pos = positions if positions is not None else jnp.arange(tokens.shape[1])
         x = x + params["pos_embed"]["table"].astype(cfg.dtype)[pos]
-        rope = None
-    else:
-        rope = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
+    rope = rope_table(cfg)
+    kinds = cfg.kinds
 
-    block_fn = _block
-    if cfg.remat and kv_caches is None:
+    block_fn = lambda kind: functools.partial(_block, kind=kind)
+    if cfg.remat and kv_caches is None and not return_selected:
         policies = {
             "full": jax.checkpoint_policies.nothing_saveable,
             "dots": jax.checkpoint_policies.checkpoint_dots,
@@ -355,39 +630,47 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
                 f"unknown remat_policy {cfg.remat_policy!r}; "
                 f"expected one of {sorted(policies)}")
         policy = policies[cfg.remat_policy]
-        block_fn = jax.checkpoint(
-            _block, static_argnums=(0, 5), policy=policy)
+        block_fn = lambda kind: jax.checkpoint(
+            functools.partial(_block, kind=kind), static_argnums=(0, 5),
+            policy=policy)
 
     if return_routes and (cfg.mlp != "moe" or kv_caches is not None):
         raise ValueError("return_routes needs mlp='moe' and no kv_caches")
+    if return_selected and (SPARSE not in kinds or kv_caches is not None):
+        raise ValueError("return_selected needs a 'minicpm4' layer and no "
+                         "kv_caches")
     new_caches = None
     aux_total = 0.0
     routes = None
-    if cfg.scan_layers and kv_caches is None:
+    taps = [] if return_selected else None
+    if cfg.scan_layers and kv_caches is None and not return_selected:
+        period = cfg.period
+        fns = [block_fn(kind) for kind in kinds[:period]]
+
         def body(carry, layer_params):
             h, aux_acc = carry
-            h, _, aux, moe = block_fn(cfg, layer_params, h, rope, positions,
-                                      sp_axis)
-            return (h, aux_acc + aux), (moe["routes"] if return_routes
-                                        else None)
+            moe = None
+            for j, fn in enumerate(fns):
+                h, _, aux, moe = fn(
+                    cfg, layer_params if period == 1
+                    else layer_params[f"p{j}"], h, rope, positions, sp_axis)
+                aux_acc = aux_acc + aux
+            return (h, aux_acc), (moe["routes"] if return_routes else None)
         (x, aux_total), routes = jax.lax.scan(body, (x, 0.0),
                                               params["blocks"])
-    elif cfg.scan_layers:
-        new_caches = []
-        for i in range(cfg.num_layers):
-            layer_p = jax.tree.map(lambda a, i=i: a[i], params["blocks"])
-            x, c, aux, _ = _block(cfg, layer_p, x, rope, positions, sp_axis,
-                                  kv_caches[i],
-                                  stacked_mlp(cfg, params, layer_p, i))
-            aux_total = aux_total + aux
-            new_caches.append(c)
     else:
         new_caches = [] if kv_caches is not None else None
         per_layer = []
-        for i in range(cfg.num_layers):
-            cache = kv_caches[i] if kv_caches is not None else None
-            x, c, aux, moe = block_fn(cfg, params["blocks"][str(i)], x, rope,
-                                      positions, sp_axis, cache)
+        for i, kind in enumerate(kinds):
+            layer_p = layer_params(cfg, params, i)
+            if kv_caches is not None or return_selected:
+                x, c, aux, moe = _block(
+                    cfg, layer_p, x, rope, positions, sp_axis,
+                    kv_caches[i] if kv_caches is not None else None,
+                    stacked_mlp(cfg, params, layer_p, i), kind, taps)
+            else:
+                x, c, aux, moe = block_fn(kind)(cfg, layer_p, x, rope,
+                                                positions, sp_axis)
             aux_total = aux_total + aux
             if new_caches is not None:
                 new_caches.append(c)
@@ -396,19 +679,16 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
         if return_routes:
             routes = jnp.stack(per_layer)
 
-    x = _norm(cfg, params["final_norm"], x)
+    x = final_hidden(cfg, params, x)
     if return_hidden:
-        return x, aux_total
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x,
-                            params["embed"]["table"].astype(cfg.dtype))
-    else:
-        logits = jnp.einsum("bsd,dv->bsv", x,
-                            params["lm_head"]["kernel"].astype(cfg.dtype))
+        return x, (new_caches if kv_caches is not None else aux_total)
+    logits = project(cfg, params, x)
     if kv_caches is not None:
         return logits, new_caches
     if return_routes:
         return logits, routes
+    if return_selected:
+        return logits, jnp.stack(taps)
     if return_aux:
         return logits, aux_total
     return logits
@@ -438,6 +718,10 @@ def tp_block_shard_spec(cfg: TransformerConfig) -> Dict[str, Dict[str, int]]:
         raise ValueError(
             "tensor parallelism does not support cfg.qk_norm=True — the "
             "norm spans all heads' values, which tp splits over ranks")
+    if set(cfg.kinds) != {ATTENTION}:
+        raise ValueError(
+            "tensor parallelism knows plain attention layers alone, not "
+            f"cfg.layer_kinds={cfg.layer_kinds}")
     spec: Dict[str, Dict[str, int]] = {
         "attn": {"wq": 1, "wk": 1, "wv": 1,   # (d, heads, hd) — heads
                  "wo": 0},                     # (heads, hd, d) — heads

@@ -123,14 +123,17 @@ def resolve_impl(cfg, impl: Optional[str] = None) -> str:
 
 
 def paged_attention(q, k_pool, v_pool, tables, lengths, *,
-                    sm_scale: Optional[float] = None, impl: str = "reference"):
+                    sm_scale: Optional[float] = None, impl: str = "reference",
+                    name: str = "paged_attention"):
     """Attention for q at positions [lengths[s], lengths[s] + K) of each slot.
 
     q: [S, K, H, D] queries (K = 1 decode, K > 1 verify/prefill window).
     k_pool/v_pool: [N, T, Hkv * D] page pools (page 0 = garbage page).
     tables: [S, P] int32 page tables; lengths: [S] int32 slot cursors
     (``-K`` for a row without a live sequence: zeros, no page read).
-    Returns [S, K, H, D] in q.dtype.
+    Returns [S, K, H, D] in q.dtype. ``name``: what the kernel is called in
+    a profiler trace (``ops.sparse_attention`` runs it over tables of chosen
+    pages under a name of its own).
 
     The new tokens' k/v must already be WRITTEN into their pages (write-
     before-attend, the arena's standing invariant) — this op only reads.
@@ -153,7 +156,7 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
             "[N, T, Hkv * D]; H must be a multiple of Hkv, D must match)")
     if impl == "pallas":
         return _paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
-                                       sm_scale, should_interpret())
+                                       sm_scale, should_interpret(), name)
     return _paged_attention_reference(q, k_pool, v_pool, tables, lengths,
                                       sm_scale)
 
@@ -431,9 +434,10 @@ def _paged_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
 # jitted on its own: a program calls the op once a layer with the same
 # shapes, and the kernel's body is then traced and lowered once, not once a
 # layer (16 layers cost 14 s of every process's start otherwise)
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "interpret", "name"))
 def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths, sm_scale,
-                            interpret):
+                            interpret, name="paged_attention"):
     S, K, D, T, Hkv, P, G, B, q_tile = _shapes(q, k_pool, tables)
     problem = None if interpret else pallas_shape_problem(Hkv, D)
     if problem:
@@ -484,7 +488,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths, sm_scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=vmem + vmem // 2),
-        name="paged_attention",
+        name=name,
         interpret=interpret,
     )(lengths.astype(jnp.int32), tables.astype(jnp.int32), order,
       jnp.sum(live, dtype=jnp.int32)[None], qr, k_pool, v_pool)

@@ -24,9 +24,24 @@ def apply_rotary(x, cos, sin, positions=None):
     else:
         cos = cos[: x.shape[-3]]
         sin = sin[: x.shape[-3]]
-    # broadcast over heads: [..., seq, 1, hd//2]
+    return _rotate(x, cos, sin)
+
+
+def _rotate(x, cos, sin):
+    """x [..., seq, heads, hd] by cos/sin [..., seq, hd//2], broadcast over
+    heads; the first half of a head's values pairs with the second."""
     cos = jnp.expand_dims(cos, axis=-2)
     sin = jnp.expand_dims(sin, axis=-2)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def apply_rotary_at(x, positions, theta: float = 10000.0):
+    """Rotate q or k by angles computed from ``positions`` [..., seq] as
+    they come — no table, so a model's context length costs nothing. x:
+    [..., seq, heads, head_dim]; the same half-rotation layout."""
+    d = x.shape[-1]
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
+    return _rotate(x, jnp.cos(angles), jnp.sin(angles))

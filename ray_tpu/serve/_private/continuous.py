@@ -64,6 +64,17 @@ program) scores them all, with exact accept-prefix + corrected-resample
 semantics (temperature-0 output is the sequential greedy path's, token for
 token).
 
+LAYERS OF OTHER KINDS (``TransformerConfig.layer_kinds``). The pool is by
+kind: pages for an attention layer, pages plus a pooled key row a page for a
+'minicpm4' layer (which attends the blocks it chooses), a fixed float32 state
+a slot for a 'lightning-attn' layer. A chunk continues its slot's states and
+takes them as zero when it starts at position 0, so an admission still runs
+no device program; a step leaves the states of a row that is free or
+mid-prefill bitwise alone. What a token leaves in such a state cannot be cut
+at a page boundary or rewound, and no snapshot is kept: the prefix cache,
+prefix export / migration and the speculative programs refuse a model with
+such layers when the scheduler is built.
+
 Knobs: ``RAY_TPU_SERVE_SLOTS`` (slots), ``RAY_TPU_SERVE_PREFILL_CHUNK``
 (prefill chunk tokens), ``RAY_TPU_SERVE_PAGE_TOKENS``,
 ``RAY_TPU_SERVE_KV_PAGES`` (0 = size the pool to every slot's worst case),
@@ -283,6 +294,7 @@ class ContinuousScheduler:
                                            paged_decode_step,
                                            paged_prefill_into_slot,
                                            paged_verify_step)
+        from ray_tpu.models.transformer import LINEAR, SPARSE
         from ray_tpu.ops.paged_attention import resolve_impl
         from ray_tpu.serve._private.paging import PageArena, RadixCache
 
@@ -344,6 +356,20 @@ class ContinuousScheduler:
         # fails the constructor, not some later decode step, and stats()
         # always names what really runs
         self.attn_lane = resolve_impl(cfg, attn)
+        if cfg.recurrent:
+            # a spliced prefix would need the states as they stood at its
+            # last token, a rejected draft their rewind: no snapshot is kept
+            if prefix_cache:
+                raise ValueError(
+                    "prefix_cache=True cannot serve a model with "
+                    "'lightning-attn' layers: their state at a prefix's "
+                    "end is not kept")
+            if drafter is not None:
+                raise ValueError(
+                    "speculative decoding cannot serve a model with "
+                    "'lightning-attn' layers: a rejected draft would have "
+                    "to rewind their states")
+            self._radix = None  # the configured default cannot apply
         # an expert layer's programs hand the rows each expert received
         # back with the ids
         self._moe = cfg.mlp == "moe"
@@ -360,8 +386,27 @@ class ContinuousScheduler:
                      **program_kw), donate_argnums=(6,))
         self._caches = init_paged_caches(
             cfg, self.num_pages, self.page_tokens,
-            self._pages_per_slot, cache_dtype)
-        self._kv_itemsize = int(self._caches[0].k.dtype.itemsize)
+            self._pages_per_slot, cache_dtype, slots=self.slots)
+        self._kv_itemsize = int(jax.numpy.dtype(
+            cache_dtype or cfg.dtype).itemsize)
+        # the pool by kind: layers that hold pages, layers that hold a state
+        # a slot, and among the first those that attend chosen blocks
+        kinds = cfg.kinds
+        self._n_linear = kinds.count(LINEAR)
+        self._n_sparse = kinds.count(SPARSE)
+        self._n_paged = len(kinds) - self._n_linear
+        self._state_bytes = (self._n_linear * self.slots * cfg.num_heads
+                             * cfg.head_dim * cfg.head_dim * 4)
+        if self._n_sparse:
+            # tokens of one block of the step's kernel over a row's table
+            # of chosen pages (``sparse_attention._step_attention``)
+            from ray_tpu.ops.paged_attention import tile_sizes
+
+            sizes = cfg.sparse
+            self._sparse_step_block = self.page_tokens * tile_sizes(
+                1, cfg.num_heads // cfg.kv_heads, self.page_tokens,
+                sizes.max_chosen_blocks() * sizes.pages_per_block,
+                cfg.kv_heads * cfg.head_dim * self._kv_itemsize)[0]
         # the newest token of every slot, as the programs left it: a chunk
         # that ends a prompt sets its row, a step replaces its active rows,
         # and the next step takes the vector as its tokens
@@ -432,6 +477,15 @@ class ContinuousScheduler:
         self._n_attn_bytes = 0
         self._n_attn_attended = 0
         self._n_attn_fetched = 0
+        # layers of other kinds (a layer-call: one layer in one program run)
+        self._n_linear_chunk_calls = 0
+        self._n_linear_step_rows = 0
+        self._n_sparse_rows = 0
+        self._n_sparse_rows_dense = 0
+        self._n_sparse_attended = 0
+        self._n_sparse_context = 0
+        self._n_sparse_step_attended = 0
+        self._n_sparse_step_context = 0
         # expert layers (mlp='moe'): what the device's counts add up to,
         # beside the live rows the host handed it
         self._n_moe_live_rows = 0
@@ -754,33 +808,76 @@ class ContinuousScheduler:
                 self._admitted_mid_flight += 1
 
     def _record_attn(self, qk: int, cursors: List[int],
-                     idle_rows: int = 0) -> None:
+                     idle_rows: int = 0, real: Optional[int] = None) -> None:
         """Account what the paged attention streamed for one
         attention-bearing program call (its device time is read from a
         profiler trace, by the program's name and the kernel's).
         ``cursors``: the attention cursor of every slot row that attends a
         K = ``qk`` window; ``idle_rows``: the call's other rows, which the
-        program marks as attending nothing. Pure host-side mirror arithmetic
+        program marks as attending nothing; ``real``: the window's real
+        tokens (a chunk's; default all). Pure host-side mirror arithmetic
         (``ops.paged_attention.streamed_tokens``) — no device readback on
         the hot loop. ``attn_tokens_attended`` over ``attn_tokens_fetched``
-        is the block fill share (per layer: every layer repeats the same
-        fetches)."""
+        is the block fill share (per layer: every layer that holds pages
+        repeats the same fetches; a model all of whose such layers attend
+        chosen blocks is counted by ``_record_sparse``)."""
         from ray_tpu.ops.paged_attention import streamed_tokens
 
         cfg = self.cfg
+        rows = len(cursors)
+        if self._n_linear:
+            if qk == 1:
+                self._n_linear_step_rows += self._n_linear * rows
+            else:
+                self._n_linear_chunk_calls += self._n_linear * rows
         row = cfg.kv_heads * cfg.head_dim * self._kv_itemsize
-        attended, fetched = streamed_tokens(
-            self.attn_lane, qk, cursors, idle_rows,
-            cfg.num_heads // cfg.kv_heads, self.page_tokens,
-            self._pages_per_slot, row)
+        if self._n_sparse:
+            attended, fetched = self._record_sparse(
+                qk, cursors, qk if real is None else real)
+        else:
+            attended, fetched = streamed_tokens(
+                self.attn_lane, qk, cursors, idle_rows,
+                cfg.num_heads // cfg.kv_heads, self.page_tokens,
+                self._pages_per_slot, row)
         self._n_attn_attended += attended
         self._n_attn_fetched += fetched
-        # k + v pools, every layer: the rows read through the table plus
-        # the qk freshly-written rows per slot
-        moved = 2 * cfg.num_layers * row * (
-            fetched + (len(cursors) + idle_rows) * qk)
+        # k + v pools, every layer that holds pages: the rows read through
+        # the table plus the qk freshly-written rows per slot
+        moved = 2 * self._n_paged * row * (fetched + (rows + idle_rows) * qk)
         self._n_attn_bytes += moved
         _m_attn_bytes.inc(moved, labels={"lane": self.attn_lane})
+
+    def _record_sparse(self, qk: int, cursors: List[int], real: int):
+        """The block-selected layers' share of ``_record_attn``: what each
+        query attends is a function of its position alone
+        (``SparseSizes.attended_tokens``), so the host mirrors it. Returns
+        (attended, fetched) token positions a layer, as the paged kernel's
+        are counted (what a row or a query tile may attend over what is
+        streamed for it): a step streams each row's chosen blocks once a
+        K/V group, in whole kernel blocks of the compacted table; a chunk
+        (one row) streams its slot's context up to each tile of 32 queries,
+        which attend their mean choice of it."""
+        import numpy as np
+
+        cfg, sizes, layers = self.cfg, self.cfg.sparse, self._n_sparse
+        # positions [rows, real]: a step's rows, or a chunk's real queries
+        t = np.asarray(cursors)[:, None] + np.arange(real)
+        att = sizes.attended_tokens(t)
+        self._n_sparse_rows += layers * t.size
+        self._n_sparse_rows_dense += layers * int(
+            (t + 1 <= sizes.dense_len).sum())
+        self._n_sparse_attended += layers * int(att.sum())
+        self._n_sparse_context += layers * int((t + 1).sum())
+        if qk == 1:
+            self._n_sparse_step_attended += layers * int(att.sum())
+            self._n_sparse_step_context += layers * int((t + 1).sum())
+            block = self._sparse_step_block
+            return (cfg.kv_heads * int(att.sum()),
+                    cfg.kv_heads * int((-(-att // block)).sum()) * block)
+        starts = np.arange(0, real, 32)
+        ends = np.minimum(starts + 32, real)
+        return (sum(int(att[0, lo:hi].mean()) for lo, hi in zip(starts, ends)),
+                int((-(-(t[0, 0] + ends) // 512)).sum()) * 512)
 
     def _cursors(self):
         """``[slots]`` int32 for a decode or verify call: every seated
@@ -922,13 +1019,15 @@ class ContinuousScheduler:
                 jnp.asarray(self._read_tables[seq.slot].copy()),
                 jnp.asarray(self._write_tables[seq.slot].copy()),
                 self._caches, self._ids, np.int32(seq.slot if last else -1),
-                np.float32(seq.temperature), np.uint32(seq.seed)),
+                np.float32(seq.temperature), np.uint32(seq.seed),
+                np.int32(seq.slot)),
                 step=False, rows=[seq] if last else [], live_rows=real)
             seq.cursor += real
             # dispatch is async and stays so: the chunk's device time is
             # read from a profiler trace by the program's name, and the
             # wait for it falls into the phase that reads its result
-            self._record_attn(self.prefill_chunk, [seq.cursor - real])
+            self._record_attn(self.prefill_chunk, [seq.cursor - real],
+                              real=real)
             self._n_prefill_chunks += 1
             _m_prefill_chunks.inc()
             if last:
@@ -1136,6 +1235,10 @@ class ContinuousScheduler:
         scheduler thread (sole owner of the tree and the donated caches),
         so this enqueues a command and waits. The matched node is pinned
         only for the duration of the gather."""
+        if self.cfg.recurrent:
+            raise ValueError(
+                "a model with 'lightning-attn' layers exports no prefix: "
+                "the pages alone do not continue a sequence")
         if self._radix is None:
             return {"matched_len": 0, "page_tokens": self.page_tokens,
                     "k": [], "v": []}
@@ -1583,6 +1686,24 @@ class ContinuousScheduler:
         out["attn_bytes_moved"] = self._n_attn_bytes
         out["attn_tokens_attended"] = self._n_attn_attended
         out["attn_tokens_fetched"] = self._n_attn_fetched
+        if self._n_linear:
+            # a state a slot a 'lightning-attn' layer, float32; layer-calls
+            # of the chunked scan; live rows x layers of the one-row update
+            out["state_slots"] = self.slots
+            out["state_bytes"] = self._state_bytes
+            out["linear_chunk_calls"] = self._n_linear_chunk_calls
+            out["linear_step_rows"] = self._n_linear_step_rows
+        if self._n_sparse:
+            # query rows x 'minicpm4' layers (real tokens of a chunk, live
+            # rows of a step), those at or under dense_len, and the tokens
+            # of the blocks they attended over the tokens of their contexts
+            out["sparse_rows"] = self._n_sparse_rows
+            out["sparse_rows_dense"] = self._n_sparse_rows_dense
+            out["sparse_tokens_attended"] = self._n_sparse_attended
+            out["sparse_tokens_context"] = self._n_sparse_context
+            # of which a step's rows (the rest are a chunk's queries)
+            out["sparse_step_tokens_attended"] = self._n_sparse_step_attended
+            out["sparse_step_tokens_context"] = self._n_sparse_step_context
         if self._moe:
             # the device's per-expert row counts, summed a layer-call
             # (one expert layer in one program run) as of the last
@@ -1594,9 +1715,10 @@ class ContinuousScheduler:
             out["moe_experts_hit"] = self._n_moe_experts_hit
             out["moe_max_expert_rows"] = self._n_moe_max_expert_rows
         out.update(self._arena.stats())
+        # 0 without a prefix cache: no prompt token was served from one
+        out["prefix_hit_tokens"] = self._n_prefix_hit_tokens
         if self._radix is not None:
             out.update(self._radix.stats())
-            out["prefix_hit_tokens"] = self._n_prefix_hit_tokens
             out["migrations"] = self._n_migrations
             out["migrated_pages"] = self._n_migrated_pages
             out["migration_failures"] = self._n_migration_failures

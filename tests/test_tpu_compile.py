@@ -372,3 +372,70 @@ def test_olmoe_serve_programs_compile_and_fit(v5e):
         # no layer's experts (805 MB) are copied off the stacked weights
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < 600e6, f"{name}: {temp / 1e6:.0f} MB of temporaries"
+
+
+def test_minicpm_sala_serve_programs_compile_and_fit(v5e):
+    """The benchmark's MiniCPM-SALA configuration (published widths, 16
+    layers of two kinds, bf16) under its cell's deployment: the prefill
+    chunk and the decode step with the four kernels of the two mixers
+    (``linear_attention_chunk`` / ``_step``, ``sparse_select``,
+    ``sparse_paged_attention`` — the paged kernel over a table of chosen
+    pages in a step, the masked flash kernel in a chunk), 10.1 GB of
+    weights, the 2.2 GB pool of the four sparse layers and 0.4 GB of states
+    beside the programs' own memory on one 16 GB chip."""
+    from perfbench.lib import configs
+    from perfbench.lib import manifest as manifest_lib
+    from ray_tpu.models.decode import (init_paged_caches, paged_decode_step,
+                                       paged_prefill_into_slot)
+    from ray_tpu.models.transformer import init_params
+    from ray_tpu.ops.paged_attention import resolve_impl
+
+    manifest = manifest_lib.load()
+    hp = manifest_lib.config(manifest, "minicpm_sala_l16")
+    cfg = configs.build_program_config(*configs.program_overrides(
+        hp, manifest_lib.read_json_from_bench("families", hp["model_type"])))
+    dep = manifest_lib.read_json(manifest, "cells",
+                                 "minicpm_sala_longdoc")["deployment"]
+    slots, chunk, T = dep["slots"], dep["prefill_chunk"], dep["page_tokens"]
+    pages = dep["arena_len"] // T
+    lane = resolve_impl(cfg)
+    assert lane == "pallas"
+    chip = SingleDeviceSharding(v5e.devices[0])
+
+    def place(tree):
+        return jax.tree.map(lambda a: _on(chip, a.shape, a.dtype), tree)
+
+    params = place(jax.eval_shape(
+        functools.partial(init_params, cfg), jax.random.PRNGKey(0)))
+    caches = place(jax.eval_shape(functools.partial(
+        init_paged_caches, cfg, dep["kv_pages"], T, pages, slots=slots)))
+    held = sum(a.size * a.dtype.itemsize
+               for a in jax.tree.leaves((params, caches)))
+    assert 12.5e9 < held < 12.9e9  # 10.1 GB + 2.2 GB of pool + 0.4 of state
+    table = _on(chip, (slots, pages), jnp.int32)
+    row = _on(chip, (pages,), jnp.int32)
+    ids = functools.partial(_on, chip, dtype=jnp.int32)
+    programs = {
+        "prefill": (paged_prefill_into_slot,
+                    (params, ids((1, chunk)), ids(()), ids(()), row, row,
+                     caches, ids((slots,)), ids(()),
+                     _on(chip, (), jnp.float32), _on(chip, (), jnp.uint32),
+                     ids(())),
+                    {"linear_attention_chunk", "sparse_select",
+                     "sparse_paged_attention"}),
+        "decode": (paged_decode_step,
+                   (params, ids((slots,)), ids((slots,)), ids((slots,)), table,
+                    table, caches, _on(chip, (slots,), jnp.float32),
+                    _on(chip, (slots,), jnp.uint32)),
+                   {"linear_attention_step", "sparse_select",
+                    "sparse_paged_attention"}),
+    }
+    for name, (program, args, kernels) in programs.items():
+        compiled = jax.jit(functools.partial(program, cfg, attn=lane),
+                           donate_argnums=(6,)).lower(*args).compile()
+        found = {re.sub(r"[.\d]+$", "", k)
+                 for k in _kernel_names(compiled.as_text())}
+        assert found == kernels, (name, found)
+        total = _fits(compiled)
+        # the programs' own memory leaves room for the reference check
+        assert total < 14.5e9, f"{name}: {total / 1e9:.1f} GB"

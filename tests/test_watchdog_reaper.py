@@ -7,6 +7,7 @@ driver must not orphan daemons whose workers go on holding the host's
 chips (a chip belongs to one process at a time).
 """
 
+import json
 import os
 import signal
 import subprocess
@@ -174,3 +175,51 @@ def test_reaper_spares_daemon_with_live_owner(tmp_path):
     finally:
         proc.kill()
         proc.wait()
+
+
+@pytest.mark.parametrize("deaf", [False, True],
+                         ids=["workers_heed_sigterm", "a_worker_ignores_it"])
+def test_shutdown_returns_when_every_process_has_gone(deaf):
+    """``shutdown()`` ends the session's processes, it does not merely
+    signal them: when it returns no daemon and no worker is left, running
+    or as a zombie handed to init. A chip worker needs seconds to die and
+    holds its chip till then; a harness that starts its next run, or counts
+    what a run left behind, right after ``shutdown()`` must find nothing.
+    A worker deaf to SIGTERM is killed after the supervisor's grace."""
+    script = textwrap.dedent(f"""
+        import ctypes, json, os, time
+        import ray_tpu
+
+        info = ray_tpu.init(num_cpus=4, object_store_memory=64 * 1024 * 1024)
+
+        @ray_tpu.remote
+        class A:
+            def pid(self, deaf):
+                if deaf:  # SIG_IGN for the whole process, from any thread
+                    ctypes.CDLL(None).signal(15, 1)
+                return os.getpid()
+
+        @ray_tpu.remote
+        def f():
+            return os.getpid()
+
+        actors = [A.remote() for _ in range(2)]
+        pids = ray_tpu.get([a.pid.remote({deaf}) for a in actors])
+        pids += ray_tpu.get([f.remote() for _ in range(3)])
+        t = time.monotonic()
+        ray_tpu.shutdown()
+        took = time.monotonic() - t
+        left = [d for d in os.listdir("/proc") if d.isdigit() and
+                info["session_dir"].encode() in
+                open(f"/proc/{{d}}/cmdline", "rb").read()]
+        states = [open(f"/proc/{{p}}/stat").read().split(")")[-1].split()[0]
+                  for p in set(pids) if os.path.exists(f"/proc/{{p}}")]
+        print(json.dumps({{"left": left, "states": states, "took": took}}),
+              flush=True)
+    """)
+    out = subprocess.run([sys.executable, "-c", script], timeout=60,
+                         capture_output=True, text=True)
+    assert out.returncode == 0, out.stderr[-2000:]
+    seen = json.loads(out.stdout.splitlines()[-1])
+    assert seen["left"] == [] and seen["states"] == [], seen
+    assert (2.0 <= seen["took"] < 15.0) if deaf else seen["took"] < 5.0, seen
