@@ -7,31 +7,73 @@ import numpy as np
 import pytest
 
 from ray_tpu.ops import attention, ring_attention, rms_norm, layer_norm
-from ray_tpu.ops.flash_attention import flash_attention, reference_attention
+from ray_tpu.ops import flash_attention as fa
+from ray_tpu.ops.flash_attention import (Tiles, flash_attention,
+                                         reference_attention, tile_sizes)
 from ray_tpu.ops.losses import softmax_cross_entropy
 from ray_tpu.ops.rotary import apply_rotary, rope_frequencies
 
 
-def _qkv(b=2, s=128, h=4, hkv=None, d=32, dtype=jnp.float32, seed=0):
+def _qkv(b=2, s=128, h=4, hkv=None, d=32, dtype=jnp.float32, seed=0,
+         sq=None):
     hkv = hkv or h
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    q = jax.random.normal(ks[0], (b, s, h, d), dtype)
+    q = jax.random.normal(ks[0], (b, sq or s, h, d), dtype)
     k = jax.random.normal(ks[1], (b, s, hkv, d), dtype)
     v = jax.random.normal(ks[2], (b, s, hkv, d), dtype)
     return q, k, v
+
+
+def _grads(fn, q, k, v):
+    """Gradients of a weighted sum (a plain sum leaves dS = 0 rows)."""
+    w = jax.random.normal(jax.random.PRNGKey(7), q.shape, q.dtype)
+    return jax.grad(lambda q, k, v: (fn(q, k, v) * w).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+# (shape of _qkv, forced Tiles or None, causal): both head shapes of the
+# benchmark's cells (D 64 MHA: two heads a 128-lane window; D 128, group 4:
+# a head a window), resident and streamed, and the edges of the triangle walk
+_D64 = dict(b=2, s=128, h=4, d=64)
+_D128 = dict(b=1, s=128, h=4, hkv=1, d=128)
+SCHEDULES = {
+    "d64_mha-resident": (_D64, Tiles(2, 64, 32, 128), True),
+    "d64_mha-streamed": (_D64, Tiles(2, 64, 32, 64), True),
+    "d128_gqa4-resident": (_D128, Tiles(4, 64, 32, 128), True),
+    "d128_gqa4-streamed": (_D128, Tiles(4, 64, 32, 64), True),
+    "seq_is_one_sub_block": (dict(b=1, s=64, h=2, d=64),
+                             Tiles(2, 64, 64, 64), True),
+    "rows_span_four_sub_blocks": (dict(b=1, s=256, h=2, d=64),
+                                  Tiles(2, 128, 32, 256), True),
+    "sub_block_wider_than_rows": (_D64, Tiles(4, 32, 64, 128), True),
+    "streamed_sub_block_is_the_part": (_D64, Tiles(4, 64, 32, 32), True),
+    "seq_q_shorter": (dict(b=1, sq=64, s=128, h=2, d=64),
+                      Tiles(2, 32, 32, 128), True),
+    "seq_q_longer": (dict(b=1, sq=128, s=64, h=2, d=64),
+                     Tiles(2, 64, 32, 32), True),
+    "not_causal-resident": (_D64, Tiles(2, 64, 32, 128), False),
+    "not_causal-streamed": (_D128, Tiles(4, 32, 32, 64), False),
+    "tile_sizes_own_choice": (dict(b=1, s=512, h=4, d=64), None, True),
+    "tile_sizes_own_choice-d128_gqa4": (dict(b=1, s=512, h=4, hkv=1, d=128),
+                                        None, True),
+    "shared_window_gqa_rolls": (dict(b=1, s=128, h=8, hkv=4, d=32),
+                                Tiles(8, 64, 32, 64), True),
+    "head_is_no_window": (dict(b=1, s=64, h=8, hkv=2, d=32),
+                          Tiles(8, 32, 32, 64), True),
+}
 
 
 class TestFlashAttention:
     @pytest.mark.parametrize("causal", [True, False])
     def test_matches_reference(self, causal):
         q, k, v = _qkv()
-        out = flash_attention(q, k, v, None, causal, 64, 64)
+        out = flash_attention(q, k, v, None, causal, Tiles(4, 64, 64, 128))
         ref = reference_attention(q, k, v, causal=causal)
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
     def test_gqa(self):
         q, k, v = _qkv(h=8, hkv=2)
-        out = flash_attention(q, k, v, None, True, 64, 64)
+        out = flash_attention(q, k, v, None, True, Tiles(8, 64, 64, 128))
         ref = reference_attention(q, k, v, causal=True)
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
@@ -39,7 +81,8 @@ class TestFlashAttention:
         q, k, v = _qkv(s=64)
 
         def f_flash(q, k, v):
-            return flash_attention(q, k, v, None, True, 32, 32).sum()
+            return flash_attention(q, k, v, None, True,
+                                   Tiles(4, 32, 32, 64)).sum()
 
         def f_ref(q, k, v):
             return reference_attention(q, k, v, causal=True).sum()
@@ -50,12 +93,13 @@ class TestFlashAttention:
             np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
 
     def test_grad_matches_gqa(self):
-        """dK/dV accumulation over the query-head group (the
-        `hkv*g + j//nq` index maps in _dkv_kernel) vs the reference."""
+        """dK/dV accumulation over the query-head group (one accumulator
+        a kv head in _dkv_kernel) vs the reference."""
         q, k, v = _qkv(s=64, h=4, hkv=2)
 
         def f_flash(q, k, v):
-            return flash_attention(q, k, v, None, True, 32, 32).sum()
+            return flash_attention(q, k, v, None, True,
+                                   Tiles(4, 32, 32, 64)).sum()
 
         def f_ref(q, k, v):
             return reference_attention(q, k, v, causal=True).sum()
@@ -65,11 +109,76 @@ class TestFlashAttention:
         for a, b in zip(g1, g2):
             np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
 
+    @pytest.mark.parametrize("case", list(SCHEDULES), ids=list(SCHEDULES))
+    def test_schedule_matches_reference_forward_and_grads(self, case):
+        shape, tiles, causal = SCHEDULES[case]
+        q, k, v = _qkv(**shape)
+
+        def flash(q, k, v):
+            return flash_attention(q, k, v, None, causal, tiles)
+
+        def ref(q, k, v):
+            return reference_attention(q, k, v, causal=causal)
+
+        np.testing.assert_allclose(flash(q, k, v), ref(q, k, v),
+                                   atol=2e-5, rtol=2e-5)
+        for a, b in zip(_grads(flash, q, k, v), _grads(ref, q, k, v)):
+            np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
+
+    def test_forced_tiles_must_divide_the_sequences(self):
+        q, k, v = _qkv(s=96)
+        with pytest.raises(ValueError, match="does not divide"):
+            flash_attention(q, k, v, None, True, Tiles(4, 64, 32, 96))
+
     def test_dispatcher_on_cpu(self):
         q, k, v = _qkv(s=64)
         out = attention(q, k, v, causal=True)
         ref = reference_attention(q, k, v, causal=True)
         np.testing.assert_allclose(out, ref, atol=1e-6)
+
+
+# the benchmark's two training cells, as a shard sees them
+CELL_SHAPES = {
+    "gpt2s_train": dict(seq=1024, head_dim=64, num_heads=12, group=1),
+    "mistral7b_train_4chip": dict(seq=4096, head_dim=128, num_heads=16,
+                                  group=4),
+}
+
+
+class TestTileSizes:
+    @pytest.mark.parametrize("cell", list(CELL_SHAPES))
+    def test_blocks_fit_the_stated_budget_and_divide_the_shapes(self, cell):
+        c = CELL_SHAPES[cell]
+        schedule = tile_sizes(c["seq"], c["seq"], c["head_dim"],
+                              c["num_heads"], c["group"], 2)
+        for role, tiles in schedule._asdict().items():
+            assert fa.block_bytes(tiles, role, c["head_dim"], c["group"],
+                                  2) <= fa._VMEM_BUDGET < fa._VMEM_LIMIT
+            assert c["seq"] % tiles.rows == 0 and c["seq"] % tiles.major == 0
+            assert tiles.major % tiles.cols == 0
+            assert c["num_heads"] % tiles.heads == 0
+            assert tiles.heads % c["group"] == 0
+            # lane blocks are whole 128-lane windows of q and of k/v
+            assert tiles.heads * c["head_dim"] % 128 == 0
+            assert tiles.heads // c["group"] * c["head_dim"] % 128 == 0
+            # both cells' K/V (and Q/dO) fit: resident, no fourth grid step
+            assert tiles.major == c["seq"], (role, tiles)
+
+    def test_a_sequence_too_long_for_the_budget_is_streamed(self):
+        schedule = tile_sizes(131072, 131072, 128, 16, 4, 2)
+        for role, tiles in schedule._asdict().items():
+            assert tiles.major < 131072 and 131072 % tiles.major == 0
+            assert fa.block_bytes(tiles, role, 128, 4, 2) <= fa._VMEM_BUDGET
+
+    def test_heads_follow_the_lane_cap_and_edges_the_head_size(self):
+        """A cell takes whole 128-lane windows of heads up to the cap (every
+        head is unrolled in the kernel's body), tiles of 4 x head_dim."""
+        gpt2s = tile_sizes(1024, 1024, 64, 12, 1, 2).fwd
+        assert (gpt2s.heads, gpt2s.rows, gpt2s.cols) == (4, 256, 256)
+        shard = tile_sizes(4096, 4096, 128, 16, 4, 2).dkv
+        assert (shard.heads, shard.rows, shard.cols) == (4, 512, 512)
+        # no legal choice under the cap: the fewest heads that are legal
+        assert tile_sizes(1024, 1024, 128, 8, 8, 2).fwd.heads == 8
 
 
 class TestFlashUnderMesh:

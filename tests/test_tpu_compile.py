@@ -87,9 +87,12 @@ def _fits(compiled) -> int:
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
-@pytest.mark.parametrize("shape", [dict(B=16, S=1024, **GPT2S),
-                                   dict(B=2, S=2048, **GQA128)],
-                         ids=["gpt2s", "gqa_d128"])
+@pytest.mark.parametrize("shape", [
+    dict(B=16, S=1024, **GPT2S), dict(B=2, S=2048, **GQA128),
+    # the benchmark's training cells as a chip sees them: gpt2s_train whole,
+    # mistral7b_train_4chip's shard of fsdp=2 x tp=2
+    dict(B=128, S=1024, **GPT2S), dict(B=4, S=4096, H=16, Hkv=4, D=128)],
+    ids=["gpt2s", "gqa_d128", "gpt2s_train", "mistral7b_train_4chip"])
 def test_flash_attention_compiles(v5e, shape, grad):
     from ray_tpu.ops.flash_attention import flash_attention
 
@@ -104,11 +107,14 @@ def test_flash_attention_compiles(v5e, shape, grad):
         return fwd(q, k, v).astype(jnp.float32).sum()
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
-    text = jax.jit(fn).lower(q, kv, kv).compile().as_text()
+    compiled = jax.jit(fn).lower(q, kv, kv).compile()
+    text = compiled.as_text()
     assert text.count("tpu_custom_call") >= (3 if grad else 1)
     names = _kernel_names(text)
     for kernel in FLASH_KERNELS if grad else FLASH_KERNELS[:1]:
         assert [n for n in names if kernel in n], (kernel, names)
+    assert len(names) == (3 if grad else 1), names   # three kernels, no more
+    _fits(compiled)
 
 
 @pytest.mark.parametrize("shape,S,K,pages", [
