@@ -12,7 +12,10 @@ weights from ``--seed``):
            references on the device, and prints the largest errors
   olmoe    a @ray_tpu.remote(num_tpus=1) task runs the benchmark's
            OLMoE-1B-7B configuration (published widths, 8 layers, bf16): one
-           expert layer, then two prompts through the paged prefill chunks and
+           expert layer; the experts' kernel (moe_grouped_matmul) against
+           jax.lax.ragged_dot on the stacked weights at a decode step's 256
+           pairs and a chunk's 4096 (largest absolute difference printed);
+           then two prompts through the paged prefill chunks and
            decode steps, against perfbench/reference/olmoe.py on the experts
            the system chose (routed error), with the share of (token, layer)
            pairs whose set of experts differs from the reference's own and
@@ -258,7 +261,7 @@ def olmoe_task(seed: int) -> dict:
     from perfbench.reference import olmoe as ref
     from ray_tpu.models.decode import (init_paged_caches, paged_decode_step,
                                        paged_prefill_into_slot)
-    from ray_tpu.ops.moe import moe_layer
+    from ray_tpu.ops.moe import expert_mlp, moe_layer, tile_sizes
 
     require_chip()
     manifest = manifest_lib.load()
@@ -302,6 +305,43 @@ def olmoe_task(seed: int) -> dict:
                     "padding_is_zero": bool((y[:, 400:] == 0).all()),
                     "rows_routed": int(counts.sum()), "live_rows_x_k": 400 * k,
                     "flip_share": flips, "margin": margin}
+
+    # ---- the experts' kernel (moe_grouped_matmul) against the compiler's
+    # ragged_dot, on the model's own experts as they lie (the LAST layer of
+    # the stack, by its offset), at the pairs a decode step of 32 slots with
+    # 22 live and a full 512-token chunk bring
+    out["kernel"] = {}
+    if stacked:
+        first = (L - 1) * E
+        w = [mlp[name].reshape(-1, *mlp[name].shape[2:]).astype(cfg.dtype)
+             for name in ("w_gate", "w_up", "w_down")]
+        draw = np.random.default_rng(seed + 1)
+        # the stack is an ARGUMENT: closed over it would be a constant
+        kernel = jax.jit(lambda xs, wg, wu, wd, g: expert_mlp(
+            xs, wg, wu, wd, g, first))
+
+        def plain(xs, wg, wu, wd, g):
+            sl = [a[first:first + E] for a in (wg, wu, wd)]
+            hidden = jax.nn.silu(jax.lax.ragged_dot(xs, sl[0], g)) * (
+                jax.lax.ragged_dot(xs, sl[1], g))
+            return jax.lax.ragged_dot(hidden, sl[2], g)
+
+        for name, slots, live in (("decode", 32, 22), ("chunk", 512, 512)):
+            groups = draw.multinomial(live * k,
+                                      draw.dirichlet(np.full(E, 2.0)))
+            xs = jax.random.normal(next(keys), (slots * k, cfg.embed_dim),
+                                   cfg.dtype)
+            g = jnp.asarray(groups, jnp.int32)
+            if "moe_grouped_matmul" not in kernel.lower(xs, *w, g).as_text():
+                raise RuntimeError("expert_mlp is not the kernel on the chip")
+            got_k, want_k = (np.asarray(a[:live * k], np.float32) for a in (
+                kernel(xs, *w, g), jax.jit(plain)(xs, *w, g)))
+            out["kernel"][name] = {
+                "pairs": slots * k, "experts_hit": int((groups > 0).sum()),
+                "tiles": list(tile_sizes(slots * k, E, cfg.embed_dim,
+                                         cfg.mlp_dim, 2)),
+                "max_abs_diff": float(np.abs(got_k - want_k).max()),
+                "largest_value": float(np.abs(want_k).max())}
 
     # ---- the 8-layer model through the paged programs: two prompts (one
     # of two chunks) prefilled into slots 0 and 5 of 8, then 6 decode steps
@@ -382,6 +422,13 @@ def olmoe_task(seed: int) -> dict:
         bad.append("a row was dropped or a dead row counted")
     if not out["layer"]["padding_is_zero"]:
         bad.append("a padded row came out of the expert layer non-zero")
+    # both sum bf16 products in float32; the kernel rounds silu(gate) * up
+    # once where XLA rounds gate, up and their product: a few roundings of
+    # the largest value, 2^-8 each
+    for name, read in out["kernel"].items():
+        if not read["max_abs_diff"] <= 2.0 ** -5 * read["largest_value"]:
+            bad.append(f"the experts' kernel at the {name} pairs is not "
+                       "ragged_dot's")
     # the dense serving cells' tolerance (bf16 through the layers: 8% of
     # the largest logit, 6% root-mean-square; read there at most 4.3 / 3.8)
     if out["layer"]["routed_err"]["max"] > 0.03 \

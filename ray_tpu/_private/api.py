@@ -13,7 +13,8 @@ import inspect
 import logging
 import os
 import threading
-from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
+from typing import (Any, Dict, Iterator, List, Optional, Sequence, Tuple,
+                    Union)
 
 from ray_tpu._private import serialization
 from ray_tpu._private.config import Config
@@ -122,13 +123,33 @@ class ObjectRefGenerator:
     def __next__(self) -> ObjectRef:
         return self._next(timeout=None)
 
-    def _next(self, timeout: Optional[float] = None) -> ObjectRef:
-        core = _require_core()
-        oid = core.stream_next(self._task_id, self._cursor, timeout)
+    def _fetch(self, fetch, timeout: Optional[float] = None):
+        """The ONE place an item is waited for (``fetch``: the core's
+        ``stream_next`` or ``stream_next_value``): a subclass that watches
+        the stream's end or its error overrides this."""
+        out = fetch(self._task_id, self._cursor, timeout)
         self._cursor += 1
-        return ObjectRef(oid, self._owner_addr)
+        return out
+
+    def _next(self, timeout: Optional[float] = None) -> ObjectRef:
+        return ObjectRef(self._fetch(_require_core().stream_next, timeout),
+                         self._owner_addr)
 
     next = _next  # explicit-timeout spelling: gen.next(timeout=...)
+
+    def values(self) -> Iterator[Any]:
+        """Iterate the items' VALUES, in order, where ``for ref in gen:
+        get(ref)`` would do. Every item is still an object of its own from
+        its report until it is read; an inline one is read and freed in the
+        same entry into the IO loop that waited for it (``next`` then
+        ``get`` make two), any other takes the ref's path."""
+        core = _require_core()
+        while True:
+            try:
+                inline, out = self._fetch(core.stream_next_value)
+            except StopIteration:
+                return
+            yield out if inline else get(ObjectRef(out, self._owner_addr))
 
     def __aiter__(self):
         return self

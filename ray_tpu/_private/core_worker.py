@@ -384,6 +384,10 @@ class CoreWorker:
         self._lineage_bytes = 0
         # streaming generator tasks: task_id -> owner-side stream state
         self._streams: Dict[TaskID, _StreamState] = {}
+        # executor side, process-wide: `stream_items` reports this worker
+        # sent and the yielded items they carried (IO loop only)
+        self.stream_reports = 0
+        self.stream_items_reported = 0
         # dedupe of retried completion reports (bounded LRU)
         self._seen_reports: "OrderedDict[bytes, bool]" = OrderedDict()
 
@@ -1071,14 +1075,27 @@ class CoreWorker:
     # ----------------------------------------------------------- streaming
 
     @idempotent  # replayed indices refresh the same entry in place
-    async def rpc_stream_item(self, body) -> dict:
-        """Executor reports one yielded item of a streaming generator task
-        (≈ ReportGeneratorItemReturns, core_worker.cc:3260). The item
-        becomes an owned object immediately — ownership rests with the
-        caller from the moment of the report, which is the worker→owner
-        transfer the reference does for dynamically created returns.
-        Returns the consumption watermark (executor-side backpressure)."""
-        task_id = TaskID(body["task_id"])
+    async def rpc_stream_items(self, body) -> dict:
+        """An executor's report: yielded items of streaming generator
+        tasks this process owns, as ``(task id, index, kind, payload)`` in
+        yield order a stream; items of several streams may share a report
+        (an async actor sends what one turn of its loop yielded), a sync
+        generator sends a list of one (≈ ReportGeneratorItemReturns,
+        core_worker.cc:3260). Each item is taken on its own, see
+        ``_stream_item``; the answer carries, a task id, the consumption
+        watermark (executor-side backpressure) and ``stop``."""
+        streams = {}
+        for task_id, index, kind, payload in body["items"]:
+            streams[task_id] = self._stream_item(
+                TaskID(task_id), index, kind, payload)
+        return {"streams": streams}
+
+    def _stream_item(self, task_id: TaskID, index: int, kind: str,
+                     payload) -> dict:
+        """One yielded item becomes an owned object immediately —
+        ownership rests with the caller from the moment of the report,
+        which is the worker→owner transfer the reference does for
+        dynamically created returns."""
         stream = self._streams.get(task_id)
         if stream is None:
             # consumer released the stream (lineage reconstruction always
@@ -1087,21 +1104,7 @@ class CoreWorker:
             return {"consumed": 0, "stop": True}
         if stream.finished and stream.error is not None:
             return {"consumed": stream.consumed, "stop": True}
-        index = body["index"]
-        oid = ObjectID(body["object_id"])
-        entry = self._ensure_entry(oid)
-        if body["kind"] == "inline":
-            self.in_process.put(oid, body["payload"])
-            entry.state = INLINE
-            entry.size = len(body["payload"])
-        else:
-            entry.state = SHARED
-            entry.size = body["payload"]["size"]
-            entry.location = tuple(body["payload"]["node_addr"])
-        self._wake(entry)
-        if index == len(stream.items):
-            stream.items.append(oid)
-        elif index > len(stream.items):
+        if index > len(stream.items):
             # executor reports strictly in order; a gap means a protocol
             # bug — fail loudly rather than hand out wrong items, and
             # stop the producer
@@ -1111,6 +1114,19 @@ class CoreWorker:
             stream.finished = True
             stream.event.set()
             return {"consumed": stream.consumed, "stop": True}
+        oid = ObjectID.for_task_return(task_id, index)
+        entry = self._ensure_entry(oid)
+        if kind == "inline":
+            self.in_process.put(oid, payload)
+            entry.state = INLINE
+            entry.size = len(payload)
+        else:
+            entry.state = SHARED
+            entry.size = payload["size"]
+            entry.location = tuple(payload["node_addr"])
+        self._wake(entry)
+        if index == len(stream.items):
+            stream.items.append(oid)
         # index < len(items): re-execution replay after a worker death —
         # same deterministic id, entry refreshed above
         stream.event.set()
@@ -1175,12 +1191,36 @@ class CoreWorker:
         """Blocking fetch of the index-th item's ObjectID; raises
         StopIteration at end-of-stream, the task's error after its last
         yielded item, or TimeoutError."""
+        return self._stream_blocking(self._async_stream_next, task_id,
+                                     index, timeout)
+
+    def _stream_blocking(self, fetch, task_id: TaskID, index: int,
+                         timeout: Optional[float]):
         deadline = None if timeout is None else time.monotonic() + timeout
         try:
-            return self._run(
-                self._async_stream_next(task_id, index, deadline))
+            return self._run(fetch(task_id, index, deadline))
         except _StreamEnd:
             raise StopIteration from None
+
+    async def _async_stream_next_value(self, task_id: TaskID, index: int,
+                                       deadline: Optional[float]):
+        oid = await self._async_stream_next(task_id, index, deadline)
+        entry = self.objects.get(oid)
+        if entry is None or entry.state != INLINE:
+            return False, oid
+        value = serialization.unpack(self.in_process.get(oid))
+        self._maybe_free(entry)  # no ref was handed out: read is release
+        return True, value
+
+    def stream_next_value(self, task_id: TaskID, index: int,
+                          timeout: Optional[float] = None):
+        """``stream_next`` and the ``get`` of its item in ONE entry into
+        the IO loop, for a consumer that wants the values and no refs:
+        ``(True, value)`` for an inline item, which is read and freed here;
+        ``(False, object id)`` for any other, which takes the ref's path.
+        Raises as ``stream_next`` does."""
+        return self._stream_blocking(self._async_stream_next_value, task_id,
+                                     index, timeout)
 
     def stream_released(self, task_id: TaskID) -> None:
         """Consumer dropped the generator: free unconsumed items and the
@@ -1201,7 +1241,7 @@ class CoreWorker:
     def _drop_sentinel_stream(self, task_id: TaskID) -> None:
         """Tear down a reconstruction-replay stream (consumed=1<<31
         sentinel, no live consumer). Every replayed item was re-stored by
-        rpc_stream_item as an owned entry; sweep them through refcounted
+        rpc_stream_items as an owned entry; sweep them through refcounted
         _maybe_free so ref-less replicas are released while the object
         that triggered the reconstruction (held by a waiter/borrower)
         survives — otherwise each reconstruction leaks the rest of the

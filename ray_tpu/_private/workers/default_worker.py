@@ -15,6 +15,20 @@ Execution model:
   * async actors: methods that are coroutines run on a dedicated asyncio loop
     with a ``max_concurrency`` semaphore (≈ fiber.h's fibers).
 
+Streaming generators (``num_returns="streaming"``): every yielded item is
+packed where it was yielded and becomes an object of its own at the owner,
+under the deterministic id (task id, yield index). What crosses the wire is
+a REPORT, one ``stream_items`` call: a list of ``(task id, index, kind,
+payload)`` entries in yield order a stream (``inline``: the packed bytes;
+``shared``: size and node of an item over ``max_direct_call_object_size``,
+already sealed in the shared store), answered by ``consumed`` / ``stop`` a
+task id. An async actor's generators append to an outbox a owner and one
+sender a owner keeps at most one report in flight, so what the actor's loop
+yields in one turn, over ALL its streams, leaves in one report and what is
+yielded meanwhile rides the next; a sync generator reports a list of one
+and waits for the answer. A stream's completion (``stream_count``) is sent
+only after its last item's report was acknowledged.
+
 TPU specifics: the supervisor spawns a chip-holding worker with its chips
 already pinned in the environment (``accelerators.worker_env``), so jax
 opens only those chips when user code first touches it, and the compile
@@ -44,6 +58,37 @@ from ray_tpu._private.task_spec import ArgKind, TaskKind, TaskSpec
 logger = logging.getLogger(__name__)
 
 
+class _OutStream:
+    """Executor-side state of one stream being produced. Written by the
+    thread that reads a report's answer, read by the producer."""
+
+    __slots__ = ("acked", "consumed", "failed", "wake")
+
+    def __init__(self):
+        self.acked = 0      # items whose report was answered
+        self.consumed = 0   # the owner's consumption watermark
+        self.failed: Optional[Exception] = None  # a report was not delivered
+        self.wake = None    # set by a producer that waits for an answer
+
+
+class _StreamOutbox:
+    """Yielded items of every stream one owner consumes, in yield order,
+    until that owner's sender takes them (deque append/popleft are
+    thread-safe: producers' loops append, the IO loop drains)."""
+
+    __slots__ = ("queue", "kicked", "sending")
+
+    def __init__(self):
+        self.queue: deque = deque()
+        self.kicked = False   # a flush is scheduled and has not drained yet
+        self.sending = False  # IO loop only: the sender is running
+
+
+def _resolve(fut) -> None:
+    if not fut.done():
+        fut.set_result(None)
+
+
 class Executor:
     """Executes task specs pushed to this worker."""
 
@@ -62,8 +107,9 @@ class Executor:
         # and retry elsewhere/again — a task id must execute at most once
         # here (bounded LRU)
         self._seen_pushes: "OrderedDict[TaskID, bool]" = OrderedDict()
-        # streaming: last consumption watermark the owner told us, per task
-        self._stream_consumed: Dict[TaskID, int] = {}
+        # streaming: one outbox of yielded items per owner address (async
+        # generators; see _queue_stream_item)
+        self._stream_boxes: Dict[tuple, _StreamOutbox] = {}
         # completion-report outbox (batched reply path, see _send_done);
         # appended from executor threads, drained on the IO loop (deque
         # append/popleft are thread-safe)
@@ -310,6 +356,7 @@ class Executor:
                 f"returned {type(gen).__name__}, not a generator")
         from ray_tpu._private.exceptions import TaskCancelledError
 
+        out = _OutStream()
         index = 0
         any_shared = False
         try:
@@ -318,9 +365,16 @@ class Executor:
                     self._report_error(
                         spec, TaskCancelledError(spec.name), retryable=False)
                     return
-                any_shared |= self._report_stream_item(spec, index, item)
+                entry = self._stream_entry(spec, index,
+                                           serialization.pack(item))
+                any_shared |= entry[2] == "shared"
+                # a report of one, answered before the next item is made
+                self._stream_replied(
+                    [(out, entry)],
+                    self.core._run(self._send_stream_report(
+                        tuple(spec.owner), [entry])))
                 index += 1
-                self._stream_backpressure(spec, index)
+                self._stream_backpressure(spec, out, index)
         except Exception as e:  # noqa: BLE001 — user generator raised
             self._report_error(spec, TaskError.from_exception(spec.name, e),
                                spec.retry_exceptions)
@@ -335,12 +389,14 @@ class Executor:
         """Per-stream executor state must not outlive the stream — a
         long-lived replica serves millions of them (the adjacent
         _seen_pushes cache is bounded for the same reason)."""
-        self._stream_consumed.pop(spec.task_id, None)
         self._cancelled.discard(spec.task_id)
 
     async def _run_async_generator(self, spec: TaskSpec, agen) -> None:
         """Async-actor variant: drive an async generator on the actor's
-        event loop (items interleave with other concurrent methods)."""
+        event loop (items interleave with other concurrent methods). An
+        item is packed here and queued for its owner's sender; the
+        generator goes on without waiting for the report's answer, unless
+        ``spec.backpressure`` holds it."""
         if not hasattr(agen, "__anext__"):
             # plain generator from an async actor: drive it OFF the actor
             # loop — per-item report RPCs and backpressure sleeps would
@@ -350,79 +406,199 @@ class Executor:
             return
         from ray_tpu._private.exceptions import TaskCancelledError
 
+        out = _OutStream()
         index = 0
         any_shared = False
+        failure = None  # (error, retryable) once the stream has failed
         loop = asyncio.get_running_loop()
         try:
             async for item in agen:
                 if spec.task_id in self._cancelled:
-                    self._report_error(
-                        spec, TaskCancelledError(spec.name), retryable=False)
-                    return
-                any_shared |= await loop.run_in_executor(
-                    None, self._report_stream_item, spec, index, item)
+                    failure = (TaskCancelledError(spec.name), False)
+                    break
+                if out.failed is not None:
+                    raise out.failed  # a report did not reach the owner
+                packed = serialization.pack(item)
+                if len(packed) > self.core.config.max_direct_call_object_size:
+                    # the shared store's round trips stay off the loop
+                    entry = await loop.run_in_executor(
+                        None, self._stream_entry, spec, index, packed)
+                    any_shared = True
+                else:
+                    entry = self._stream_entry(spec, index, packed)
+                self._queue_stream_item(loop, tuple(spec.owner), out, entry)
                 index += 1
-                await loop.run_in_executor(
-                    None, self._stream_backpressure, spec, index)
+                if spec.backpressure > 0:
+                    await self._stream_backpressure_on_loop(
+                        loop, spec, out, index)
         except Exception as e:  # noqa: BLE001
-            self._report_error(spec, TaskError.from_exception(spec.name, e),
-                               spec.retry_exceptions)
+            failure = (TaskError.from_exception(spec.name, e),
+                       spec.retry_exceptions)
+        # the completion, an error's included, never overtakes an item:
+        # it is queued once every report of this stream was answered
+        await self._stream_wait(
+            loop, out, lambda: out.acked >= index or out.failed is not None)
+        self._stream_cleanup(spec)
+        if failure is not None:
+            self._report_error(spec, *failure)
             return
-        finally:
-            self._stream_cleanup(spec)
         self._send_done(spec, {
             "task_id": spec.task_id.binary(), "results": [],
             "stream_count": index, "stream_any_shared": any_shared})
 
-    def _report_stream_item(self, spec: TaskSpec, index: int, item) -> bool:
-        """Ship one yielded item to the owner; returns True if it went to
-        the shared arena (size-routed exactly like normal returns)."""
+    def _stream_entry(self, spec: TaskSpec, index: int,
+                      packed: bytes) -> tuple:
+        """One yielded (packed) item as a report carries it: ``(task id,
+        index, kind, payload)``. Size-routed exactly like normal returns:
+        an item over ``max_direct_call_object_size`` is sealed in the
+        shared store first (blocking: never call that lane on an event
+        loop)."""
+        if len(packed) <= self.core.config.max_direct_call_object_size:
+            return (spec.task_id.binary(), index, "inline", packed)
         oid = ObjectID.for_task_return(spec.task_id, index)
-        packed = serialization.pack(item)
-        body = {"task_id": spec.task_id.binary(), "index": index,
-                "object_id": oid.binary()}
-        shared = len(packed) > self.core.config.max_direct_call_object_size
-        if shared:
-            self.core._run(self._store_shared(oid, packed))
-            body["kind"] = "shared"
-            body["payload"] = {"size": len(packed),
-                               "node_addr": self.core.supervisor_addr}
-        else:
-            body["kind"] = "inline"
-            body["payload"] = packed
-        reply = self.core._run(
-            self.core.clients.get(tuple(spec.owner)).call("stream_item", body))
-        self._stream_consumed[spec.task_id] = reply.get("consumed", 0)
-        if reply.get("stop"):
-            self._cancelled.add(spec.task_id)  # consumer released the stream
-        return shared
+        self.core._run(self._store_shared(oid, packed))
+        return (spec.task_id.binary(), index, "shared",
+                {"size": len(packed), "node_addr": self.core.supervisor_addr})
 
-    def _stream_backpressure(self, spec: TaskSpec, produced: int) -> None:
+    def _queue_stream_item(self, loop, owner: tuple, out: "_OutStream",
+                           entry: tuple) -> None:
+        """Append to the owner's outbox; the first item of a loop turn
+        schedules ONE kick at the turn's end (``call_soon``: behind every
+        generator already woken), so a turn's items leave together."""
+        box = self._stream_boxes.get(owner)
+        if box is None:
+            box = self._stream_boxes.setdefault(owner, _StreamOutbox())
+        box.queue.append((out, entry))
+        if not box.kicked:
+            box.kicked = True
+            loop.call_soon(self._kick_stream_flush, owner, box)
+
+    def _kick_stream_flush(self, owner: tuple, box: "_StreamOutbox") -> None:
+        self.core._run_nowait(self._flush_stream_items(owner, box))
+
+    async def _flush_stream_items(self, owner: tuple,
+                                  box: "_StreamOutbox") -> None:
+        """The owner's one sender, on the IO loop: everything queued goes
+        as one report; what is queued while it is in flight is the next."""
+        if box.sending:
+            return  # the sender drains what was just queued
+        box.sending = True
+        try:
+            while box.queue:
+                # cleared BEFORE the drain: an item appended after it
+                # finds the flag down and kicks again
+                box.kicked = False
+                batch = []
+                while box.queue:
+                    batch.append(box.queue.popleft())
+                try:
+                    reply = await self._send_stream_report(
+                        owner, [entry for _, entry in batch])
+                except Exception as e:  # noqa: BLE001 — owner unreachable
+                    for out, _ in batch:
+                        out.failed = e
+                    reply = None
+                self._stream_replied(batch, reply)
+        finally:
+            box.sending = False
+
+    async def _send_stream_report(self, owner: tuple, entries: list) -> dict:
+        """One ``stream_items`` call (on the IO loop), counted."""
+        reply = await self.core.clients.get(owner).call(
+            "stream_items", {"items": entries})
+        self.core.stream_reports += 1
+        self.core.stream_items_reported += len(entries)
+        return reply["streams"]
+
+    def _stream_replied(self, batch: list, reply: Optional[dict]) -> None:
+        """Bring a report's answer to its streams, a task id each, then
+        wake the producers that wait for it."""
+        for out, (task_id, index, _, _) in batch:
+            out.acked = max(out.acked, index + 1)
+            if reply and task_id in reply:
+                self._stream_answered(out, task_id, reply[task_id])
+            wake, out.wake = out.wake, None
+            if wake is not None:
+                wake()
+
+    def _stream_answered(self, out: "_OutStream", task_id: bytes,
+                         state: dict) -> None:
+        """The owner's word on one stream: its consumption watermark, and
+        ``stop`` once the consumer released THAT stream."""
+        out.consumed = state.get("consumed", 0)
+        if state.get("stop"):
+            self._cancelled.add(TaskID(task_id))
+
+    @staticmethod
+    async def _stream_wait(loop, out: "_OutStream", ready) -> None:
+        """Park the producer on ITS loop until ``ready()``; the sender's
+        thread wakes it after each answer that names the stream."""
+        while not ready():
+            fut = loop.create_future()
+
+            def wake(fut=fut):
+                try:
+                    loop.call_soon_threadsafe(_resolve, fut)
+                except RuntimeError:
+                    pass  # the producer's loop is gone
+
+            out.wake = wake
+            if ready():  # the answer landed before the waker was set
+                out.wake = None
+                return
+            await fut
+
+    def _stream_backpressure(self, spec: TaskSpec, out: "_OutStream",
+                             produced: int) -> None:
         """Pause when the owner's consumer lags more than the configured
         window (spec.backpressure, 0 = unbounded) — ≈ the reference's
         _generator_backpressure_num_objects."""
-        if spec.backpressure <= 0:
-            return
-        while (produced - self._stream_consumed.get(spec.task_id, 0)
-               >= spec.backpressure
-               and spec.task_id not in self._cancelled):
-            # owner-side long-poll: ONE rpc blocks until the consumer
-            # reaches the watermark (or 5s passes) instead of hammering
-            # the owner's IO loop with 20ms polls
-            wait_for = produced - spec.backpressure + 1
+        while self._stream_over_window(spec, out, produced):
             try:
                 reply = self.core._run(
-                    self.core.clients.get(tuple(spec.owner)).call(
-                        "stream_state",
-                        {"task_id": spec.task_id.binary(),
-                         "wait_for": wait_for, "timeout": 5.0},
-                        timeout=30.0))
+                    self._stream_state(spec, produced), timeout=40.0)
             except Exception:
                 return  # owner gone: stop pausing, let the report fail
-            self._stream_consumed[spec.task_id] = reply.get("consumed", 0)
-            if reply.get("stop"):
-                self._cancelled.add(spec.task_id)
+            self._stream_answered(out, spec.task_id.binary(), reply)
+
+    async def _stream_backpressure_on_loop(self, loop, spec: TaskSpec,
+                                           out: "_OutStream",
+                                           produced: int) -> None:
+        """The same pause, awaited on the producer's loop: first for the
+        answers to its own reports (they carry the watermark), then, with
+        everything reported and the consumer still behind, for the owner's
+        long-poll."""
+        while self._stream_over_window(spec, out, produced):
+            if out.acked < produced:
+                await self._stream_wait(
+                    loop, out,
+                    lambda: out.acked >= produced or out.failed is not None)
+                if out.failed is not None:
+                    return  # the next item raises it
+                continue
+            try:
+                reply = await asyncio.wrap_future(
+                    asyncio.run_coroutine_threadsafe(
+                        self._stream_state(spec, produced), self.core.loop))
+            except Exception:
                 return
+            self._stream_answered(out, spec.task_id.binary(), reply)
+
+    def _stream_over_window(self, spec: TaskSpec, out: "_OutStream",
+                            produced: int) -> bool:
+        return (spec.backpressure > 0
+                and produced - out.consumed >= spec.backpressure
+                and spec.task_id not in self._cancelled)
+
+    async def _stream_state(self, spec: TaskSpec, produced: int) -> dict:
+        """Owner-side long-poll: ONE rpc blocks until the consumer
+        reaches the watermark (or 5s pass) instead of hammering the
+        owner's IO loop with 20ms polls."""
+        return await self.core.clients.get(tuple(spec.owner)).call(
+            "stream_state",
+            {"task_id": spec.task_id.binary(),
+             "wait_for": produced - spec.backpressure + 1, "timeout": 5.0},
+            timeout=30.0)
 
     # -- result reporting (owner is the submitter) --
 

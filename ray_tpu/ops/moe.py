@@ -1,12 +1,35 @@
 """Mixture-of-Experts layer: dropless top-k routing over grouped matmuls.
 
-One implementation for training and serving (OLMoE / Mixtral style, the
+One routing for training and serving (OLMoE / Mixtral style, the
 reference has no MoE at all — SURVEY §5): the router scores every row in
 float32, each row takes its top-k experts, the (row, expert) pairs are
 sorted by expert and the three SwiGLU matmuls run as GROUPED matmuls over
-the ragged per-expert groups (``jax.lax.ragged_dot``), then the pairs are
-un-sorted and summed under the router's weights. No capacity, so no row is
-ever dropped, and nothing of size [rows, experts, capacity] exists.
+the ragged per-expert groups, then the pairs are un-sorted and summed
+under the router's weights. No capacity, so no row is ever dropped, and
+nothing of size [rows, experts, capacity] exists.
+
+Which call runs which grouped matmul (two paths below the sort, because
+the needs conflict; the input says which, no option does):
+
+  * a call WITHOUT ``layer`` holds one layer's experts (training, under
+    ``scan``): three ``jax.lax.ragged_dot``, which has a backward and which
+    GSPMD partitions over ``ep``;
+  * a call that passes the STACK of every layer's experts and a ``layer``
+    index (the paged serving programs and ``decode_step``, whose layers are
+    a Python loop) runs ``expert_mlp``: the Pallas kernel
+    ``moe_grouped_matmul`` (that name in a trace; interpreted off a TPU).
+    One call a layer does all three products. Its grid walks the VISITS
+    (expert, row tile) in the order of the sorted pairs, a map computed from
+    the counts and handed in by scalar prefetch; the ``BlockSpec``s index
+    the stacked weights ``[layers x experts, ...]`` in place, so no layer is
+    sliced off the stack (805 MB copied a call at OLMoE's widths), an expert
+    that received rows is read from HBM exactly once (consecutive row tiles
+    of one expert keep its weights in VMEM, the pipeline fetches the next
+    visit's while this one's are multiplied) and an expert without rows is
+    never fetched. Gate and up read the row tile once, ``silu(gate) * up``
+    stays in VMEM and is rounded once, then down; operands in the model's
+    dtype, float32 accumulation. Row tiles follow the STATIC pair count
+    (``tile_sizes``). No backward: nothing trains on this path.
 
 Rows that are not live (a slot without a sequence, a chunk's padding) are
 marked by ``valid``: they sort behind every group, cost no expert a row,
@@ -19,10 +42,16 @@ fit. How experts exchange rows across chips is not decided here.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+import functools
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops._pallas import should_interpret
 
 
 def init_moe_params(key, embed_dim: int, hidden_dim: int, num_experts: int,
@@ -46,6 +75,177 @@ def moe_logical_axes() -> Dict[str, Tuple[Optional[str], ...]]:
         "w_down": ("expert", "mlp", "embed"),
     }
 
+# ------------------------------------------- the serving path's experts
+
+_LANES = 128
+_VMEM_BUDGET = 48 << 20   # a grid cell: every block twice + its products
+_MAX_ROWS = 128           # up to here a visit's products hide behind the
+#                           next expert's copy (PERF.md 6, PR 36 / 37)
+_GROUPS_A_TILE = 16       # a row tile spans so many groups of mean size
+
+
+class Tiles(NamedTuple):
+    rows: int  # pairs a row tile holds
+    cols: int  # columns of the experts' hidden width a grid cell takes
+
+
+def _vmem_bytes(t: Tiles, d: int, itemsize: int) -> int:
+    """VMEM a grid cell holds: the row tile, the three weight tiles and
+    the out tile twice (two buffers each), the float32 products before
+    they are rounded (gate, up, down) and the down product's accumulator."""
+    blocks = 2 * t.rows * d + 3 * d * t.cols
+    return 2 * blocks * itemsize + (2 * t.rows * t.cols
+                                    + 2 * t.rows * d) * 4
+
+
+def tile_sizes(pairs: int, groups: int, d: int, f: int, itemsize: int,
+               vmem_bytes: int = _VMEM_BUDGET) -> Tiles:
+    """The kernel's tiles, from static shapes alone.
+
+    rows: ``_GROUPS_A_TILE`` groups of mean size, as a power of two between
+    the dtype's sublane tile (16 rows of bf16) and ``_MAX_ROWS``, never
+    more than the pairs there are: 64 for a decode step's 256 pairs over 64
+    experts, 128 for a 512-token chunk's 4,096. What a tile costs is its
+    VISITS (one a group that shares a row with it: a grid step, and the
+    copy engine idle while a group's second tile is multiplied), not its
+    rows: a visit's products run beside the next expert's copy and take
+    less than it up to 128 rows (measured on the v5e at OLMoE's widths,
+    PERF.md 6). cols: all of the hidden width ``f`` where a whole expert
+    fits ``vmem_bytes`` twice over (OLMoE: 12.6 MB, three contiguous
+    copies), else the widest whole-lane divisor of ``f`` that does; the
+    down product then accumulates over the column tiles."""
+    sublanes = 8 * 4 // itemsize
+    want = _GROUPS_A_TILE * max(pairs // max(groups, 1), 1)
+    rows = max(sublanes, min(_MAX_ROWS, 1 << (want - 1).bit_length()))
+    rows = min(rows, -(-pairs // sublanes) * sublanes)
+    lanes = f // _LANES if f % _LANES == 0 else 0
+    for m in range(lanes, 0, -1):
+        t = Tiles(rows, m * _LANES)
+        if lanes % m == 0 and _vmem_bytes(t, d, itemsize) <= vmem_bytes:
+            return t
+    return Tiles(rows, f if not lanes else _LANES)
+
+
+def _visits(counts, first_group, n_tiles: int, rows: int):
+    """The grid's walk: one VISIT for every (group, row tile) pair that
+    shares a row, in the order of the rows. Consecutive groups share at
+    most one tile, so there are at most ``n_tiles + G - 1``; the walk is
+    padded to that by repeating the last visit, which fetches nothing anew
+    and is not computed. Returns int32 arrays a visit (its group in the
+    weight stack, its row tile, the group's first row and its end) and the
+    number of visits."""
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    first = starts // rows
+    n = jnp.where(counts > 0, (ends - 1) // rows - first + 1, 0)
+    upto = jnp.cumsum(n)
+    total = upto[-1]
+    i = jnp.minimum(jnp.arange(n_tiles + counts.shape[0] - 1),
+                    jnp.maximum(total - 1, 0))
+    g = jnp.minimum((i[:, None] >= upto[None, :]).sum(1),
+                    counts.shape[0] - 1)
+    tile = jnp.clip(first[g] + i - (upto - n)[g], 0, n_tiles - 1)
+    return first_group + g, tile, starts[g], ends[g], total[None]
+
+
+def _expert_kernel(group_ref, tile_ref, start_ref, end_ref, total_ref,
+                   x_ref, gate_ref, up_ref, down_ref, o_ref, *acc, rows):
+    """One visit (and one column tile of the hidden width): the row tile
+    through the group's SwiGLU, stored into the rows of the tile that are
+    the group's. The out block stays in VMEM while consecutive visits share
+    its tile, and goes back to HBM once."""
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(i < total_ref[0])
+    def _():
+        x = x_ref[...]
+        gate = jnp.dot(x, gate_ref[...], preferred_element_type=jnp.float32)
+        up = jnp.dot(x, up_ref[...], preferred_element_type=jnp.float32)
+        hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+        y = jnp.dot(hidden, down_ref[...],
+                    preferred_element_type=jnp.float32)
+
+        def store(y):
+            row = tile_ref[i] * rows + lax.broadcasted_iota(
+                jnp.int32, (rows, 1), 0)
+            mine = jnp.logical_and(row >= start_ref[i], row < end_ref[i])
+            o_ref[...] = jnp.where(mine, y, o_ref[...].astype(jnp.float32)
+                                   ).astype(o_ref.dtype)
+
+        if not acc:  # the whole hidden width in one cell
+            store(y)
+            return
+        acc_ref, = acc
+
+        @pl.when(j == 0)
+        def _():
+            acc_ref[...] = y
+
+        @pl.when(j > 0)
+        def _():
+            acc_ref[...] += y
+
+        @pl.when(j == pl.num_programs(1) - 1)
+        def _():
+            store(acc_ref[...])
+
+
+# jitted on its own: a program calls it once a layer with the same shapes,
+# and the kernel's body is then traced and lowered once a shape, not once a
+# layer (first_group is an argument, not a constant)
+@functools.partial(jax.jit, static_argnames=("tiles",))
+def expert_mlp(xs, w_gate, w_up, w_down, counts, first_group, tiles=None):
+    """``silu(xs @ gate_g) * (xs @ up_g) @ down_g`` for every row's group.
+
+    xs: [pairs, d], sorted by group; counts: [G] int32 rows of each group;
+    the weights are a STACK ``[>= first_group + G, d, f]`` (gate, up) and
+    ``[.., f, d]`` (down) of which the G groups start at ``first_group``
+    (layer x experts; an int32 scalar). Rows past the last group are
+    undefined. ``tiles``: ``tile_sizes``' unless a test names its own."""
+    pairs, d = xs.shape
+    f = w_gate.shape[2]
+    t = tiles or tile_sizes(pairs, counts.shape[0], d, f, xs.dtype.itemsize)
+    if f % t.cols:
+        raise ValueError(f"{t} does not divide the hidden width {f}")
+    n_tiles, f_tiles = -(-pairs // t.rows), f // t.cols
+    if n_tiles * t.rows != pairs:   # whole tiles; the rows added are dead
+        xs = jnp.pad(xs, ((0, n_tiles * t.rows - pairs), (0, 0)))
+    visits = _visits(counts.astype(jnp.int32),
+                     jnp.asarray(first_group, jnp.int32), n_tiles, t.rows)
+
+    def rows_map(i, j, group, tile, *_):
+        return tile[i], 0
+
+    def into_hidden(i, j, group, *_):  # gate and up: [d, cols] of a group
+        return group[i], 0, j
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(visits),
+        grid=(visits[0].shape[0], f_tiles),
+        in_specs=[
+            pl.BlockSpec((t.rows, d), rows_map),
+            pl.BlockSpec((None, d, t.cols), into_hidden),
+            pl.BlockSpec((None, d, t.cols), into_hidden),
+            pl.BlockSpec((None, t.cols, d),
+                         lambda i, j, group, *_: (group[i], j, 0)),
+        ],
+        out_specs=pl.BlockSpec((t.rows, d), rows_map),
+        scratch_shapes=([pltpu.VMEM((t.rows, d), jnp.float32)]
+                        if f_tiles > 1 else []),
+    )
+    out = pl.pallas_call(
+        functools.partial(_expert_kernel, rows=t.rows),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_tiles * t.rows, d), xs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_bytes(t, d, xs.dtype.itemsize) * 5 // 4
+            + (4 << 20)),
+        name="moe_grouped_matmul",
+        interpret=should_interpret(),
+    )(*visits, xs, w_gate, w_up, w_down)
+    return out[:pairs]
+
 
 def moe_layer(p: Dict[str, Any], x, *, num_experts: int, top_k: int = 2,
               renormalize: bool = True, dtype=jnp.bfloat16, valid=None,
@@ -59,10 +259,11 @@ def moe_layer(p: Dict[str, Any], x, *, num_experts: int, top_k: int = 2,
     to valid rows x k: the dropless witness). ``routes``: the experts each
     row chose, best first (also for rows that are not valid).
     ``layer``: ``p`` holds ALL layers' weights stacked on a leading axis and
-    this is the layer to apply. The grouped matmuls then take the stack
-    whole, as layers x experts groups of which only this layer's have rows:
-    a layer sliced off the stack would first be copied (on the v5e 805 MB a
-    call at OLMoE's widths, 4.2 ms against 1.8; empty groups cost 0.03).
+    this is the layer to apply: the serving path, whose grouped matmuls are
+    the kernel (``expert_mlp``), which takes the stack whole and the layer
+    as an offset into it; a layer sliced off the stack would first be
+    copied (on the v5e 805 MB a call at OLMoE's widths). Without ``layer``
+    the three products are ``jax.lax.ragged_dot`` (module docstring).
 
     aux_loss is the Switch load-balancing loss over the valid rows
     (E * sum_e fraction_of_rows_whose_first_choice_is_e * mean_prob_e); add
@@ -89,16 +290,16 @@ def moe_layer(p: Dict[str, Any], x, *, num_experts: int, top_k: int = 2,
     counts = jnp.zeros((num_experts,), jnp.int32).at[pair_expert].add(
         1, mode="drop")
     xs = xt[order // top_k]  # [N*k, d]
-    groups, w = counts, {k: p[k] for k in ("w_gate", "w_up", "w_down")}
-    if layer is not None:
-        stack = p["w_gate"].shape[0] * num_experts
-        groups = jnp.zeros((stack,), jnp.int32).at[
-            layer * num_experts:(layer + 1) * num_experts].set(counts)
-        w = {k: a.reshape(stack, *a.shape[2:]) for k, a in w.items()}
-    gate = jax.lax.ragged_dot(xs, w["w_gate"].astype(dtype), groups)
-    up = jax.lax.ragged_dot(xs, w["w_up"].astype(dtype), groups)
-    out = jax.lax.ragged_dot(jax.nn.silu(gate) * up,
-                             w["w_down"].astype(dtype), groups)
+    w_gate, w_up, w_down = (p[k].astype(dtype)
+                            for k in ("w_gate", "w_up", "w_down"))
+    if layer is None:
+        gate = jax.lax.ragged_dot(xs, w_gate, counts)
+        up = jax.lax.ragged_dot(xs, w_up, counts)
+        out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_down, counts)
+    else:  # [layers, experts, ...] seen as layers x experts groups
+        out = expert_mlp(xs, *(a.reshape(-1, *a.shape[2:])
+                               for a in (w_gate, w_up, w_down)),
+                         counts, layer * num_experts)
     # back to (row, choice) order; the weighted sum over a row's k experts
     # accumulates in float32. Pairs past the last group hold nothing
     # defined: they are masked, not multiplied by 0

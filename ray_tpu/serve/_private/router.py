@@ -75,12 +75,12 @@ class _WatchedStream(ray_tpu.ObjectRefGenerator):
         if ok is not None:
             r._note_result(self._replica_key, ok=ok, mux_id=self._mux_id)
 
-    def _next(self, timeout=None):
+    def _fetch(self, fetch, timeout=None):
         import asyncio
         import concurrent.futures
 
         try:
-            return super()._next(timeout)
+            return super()._fetch(fetch, timeout)
         except StopIteration:
             self._settle(ok=True)
             raise
@@ -95,8 +95,6 @@ class _WatchedStream(ray_tpu.ObjectRefGenerator):
         except BaseException:
             self._settle(ok=False)
             raise
-
-    next = _next  # re-bind: the base class aliases its own _next
 
     def __del__(self):
         # consumer dropped the stream mid-iteration: release the count

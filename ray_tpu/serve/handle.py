@@ -61,9 +61,9 @@ class DeploymentResponse:
         """Stream the response. Non-streaming results yield once."""
         if isinstance(self._ref, ray_tpu.ObjectRefGenerator):
             # native generator transport (handle.options(stream=True)):
-            # chunks are owner-owned refs arriving as produced
-            for chunk_ref in self._ref:
-                yield ray_tpu.get(chunk_ref)
+            # chunks are owner-owned objects arriving as produced, read
+            # (and released) one by one
+            yield from self._ref.values()
             return
         out = self.result()
         if not (isinstance(out, dict) and STREAM_MARKER in out):

@@ -310,6 +310,13 @@ class LLMServerImpl:
         out = self._sched.stats()
         out["stream_lag_s"] = self._stream_lag_ns / 1e9
         out["stream_tokens"] = self._stream_tokens
+        # how this worker's streams left it: items over reports is what one
+        # turn of the replica's loop sent its callers in one call
+        from ray_tpu._private import api
+
+        if api._core is not None:
+            out["stream_reports"] = api._core.stream_reports
+            out["stream_items_reported"] = api._core.stream_items_reported
         # where the model really runs: a replica that was not given a
         # chip runs on the CPU, and the record has to say so
         devices = self._jax.devices()

@@ -27,7 +27,8 @@ from ray_tpu.models.decode import (decode_step, init_caches,
                                    paged_prefill_into_slot,
                                    paged_verify_step, prefill)
 from ray_tpu.models.transformer import _qkv
-from ray_tpu.ops.moe import init_moe_params, moe_layer
+from ray_tpu.ops import moe as moe_ops
+from ray_tpu.ops.moe import Tiles, init_moe_params, moe_layer, tile_sizes
 from ray_tpu.ops.rotary import apply_rotary, rope_frequencies
 
 TOL = 1e-4
@@ -357,7 +358,7 @@ def layer_reference(p, x, top_k, renormalize, routes=None):
 
 def run_layer(p, x, **kw):
     with jax.default_matmul_precision("highest"):
-        return moe_layer(p, x, num_experts=p["w_router"].shape[1],
+        return moe_layer(p, x, num_experts=p["w_router"].shape[-1],
                          dtype=jnp.float32, **kw)
 
 
@@ -417,6 +418,151 @@ def test_rows_masked_by_valid_change_no_output_and_no_count():
     assert counts2.tolist() == counts.tolist()
     np.testing.assert_array_equal(np.asarray(y2[0]), np.asarray(y[0]))
     np.testing.assert_array_equal(np.asarray(y2[1, :9]), np.asarray(y[1, :9]))
+
+
+# ------------------- the serving path's experts: the kernel (ISSUE 37)
+#
+# A call that passes the stack and a layer runs ``expert_mlp`` (the Pallas
+# kernel ``moe_grouped_matmul``, interpreted here); the call without runs
+# ``jax.lax.ragged_dot``. Both against each other and against the reference
+# given the routes, in float32 at 1e-4.
+
+# name -> (rows, experts, top_k, layers in the stack, layer, router, live)
+KERNEL_CASES = {
+    "8_pairs": (1, 16, 8, 1, 0, None, None),
+    "256_pairs": (32, 64, 8, 1, 0, None, None),
+    "4096_pairs": (512, 64, 8, 1, 0, None, None),
+    "a_layer_in_the_middle_of_a_stack": (32, 64, 8, 3, 1, None, None),
+    "the_last_layer_of_a_stack": (24, 8, 3, 4, 3, None, None),
+    "experts_without_rows": (32, 64, 8, 2, 1, "most_empty", None),
+    "one_expert_takes_every_row": (40, 8, 2, 2, 0, "one_wins", None),
+    "rows_that_are_not_live": (32, 64, 8, 2, 1, None, 19),
+    "no_row_is_live": (16, 8, 3, 2, 1, None, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_the_kernel_matches_ragged_dot_and_the_reference(case):
+    rows, E, k, layers, layer, router, live = KERNEL_CASES[case]
+    d, f = 32, 48
+    stack = [init_moe_params(jax.random.PRNGKey(7 + i), d, f, E)
+             for i in range(layers)]
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (1, rows, d))) + 0.1
+    if router == "most_empty":   # positive inputs: 52 experts never win
+        stack[layer]["w_router"] = stack[layer]["w_router"].at[:, 12:].set(
+            -5.0)
+    if router == "one_wins":
+        stack[layer]["w_router"] = stack[layer]["w_router"].at[:, 5].set(3.0)
+    stacked = jax.tree.map(lambda *a: jnp.stack(a), *stack)
+    valid = None if live is None else (jnp.arange(rows) < live)[None]
+    kw = dict(top_k=k, renormalize=False, valid=valid)
+    y, _, counts, routes = run_layer(stacked, x, layer=layer, **kw)
+    y_ragged, _, c_ragged, r_ragged = run_layer(stack[layer], x, **kw)
+    n_live = rows if live is None else live
+    assert counts.tolist() == c_ragged.tolist()
+    assert int(counts.sum()) == n_live * k      # dropless, live rows only
+    assert routes.tolist() == r_ragged.tolist()
+    if router == "most_empty":
+        assert int((counts > 0).sum()) <= 12
+    if router == "one_wins":
+        assert int(counts[5]) == rows
+    assert not np.asarray(y[0, n_live:]).any()  # zeros out
+    if n_live == 0:
+        return
+    want = layer_reference(stack[layer], x, k, False, routes)[0, :n_live]
+    assert float(jnp.abs(want).max()) > 1e-5  # it compares something
+    assert rel_err(y[0, :n_live], want) < TOL
+    assert rel_err(y[0, :n_live], y_ragged[0, :n_live]) < TOL
+    # a fault the tolerance refuses: bf16 operands through the same kernel
+    y16 = moe_layer(stacked, x, num_experts=E, dtype=jnp.bfloat16,
+                    layer=layer, **kw)[0]
+    if rows * k >= 256:
+        assert rel_err(y16[0, :n_live], want) > 10 * TOL
+
+
+@pytest.mark.parametrize("dtype,tiles", [
+    (jnp.float32, None), (jnp.bfloat16, None),
+    (jnp.float32, Tiles(8, 128)), (jnp.bfloat16, Tiles(32, 128))],
+    ids=["f32", "bf16", "f32_column_tiles", "bf16_column_tiles"])
+def test_expert_mlp_is_ragged_dot_thrice(dtype, tiles):
+    """The op alone, on a stack with an offset; with the hidden width cut
+    into column tiles the down product accumulates in float32. Rows past
+    the last group are undefined and touch no live row."""
+    G, d, f, pairs, first = 8, 128, 256, 80, 8
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    xs = jax.random.normal(ks[0], (pairs, d), jnp.float32).astype(dtype)
+    w = [(0.1 * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+         for key, shape in zip(ks[1:], [(3 * G, d, f), (3 * G, d, f),
+                                        (3 * G, f, d)])]
+    counts = jnp.asarray([0, 10, 0, 33, 1, 0, 0, 5], jnp.int32)
+    live = int(counts.sum())
+    with jax.default_matmul_precision("highest"):
+        got = moe_ops.expert_mlp(xs, *w, counts, first, tiles=tiles)
+        sl = [a[first:first + G] for a in w]
+        hidden = jax.nn.silu(jax.lax.ragged_dot(xs, sl[0], counts)) * (
+            jax.lax.ragged_dot(xs, sl[1], counts))
+        want = jax.lax.ragged_dot(hidden, sl[2], counts)
+        again = moe_ops.expert_mlp(xs.at[live:].set(jnp.nan), *w, counts,
+                                   first, tiles=tiles)
+    assert got.shape == (pairs, d) and got.dtype == dtype
+    # bf16: the kernel rounds silu(gate) * up once where XLA rounds thrice
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    assert rel_err(got[:live], want[:live]) < tol
+    assert float(jnp.abs(want[:live].astype(jnp.float32)).max()) > 0.1
+    np.testing.assert_array_equal(np.asarray(again[:live], np.float32),
+                                  np.asarray(got[:live], np.float32))
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_the_walk_reads_every_hit_expert_once_and_no_other(seed):
+    """The grid's walk, replayed on the host: every row of every group is
+    stored exactly once, an expert's visits are consecutive (the pipeline
+    keeps its weights: one read), an expert without rows is not visited,
+    and the padding repeats the last visit."""
+    rng = np.random.default_rng(seed)
+    E, rows, n_tiles, first_group = 64, 16, 16, 64 * seed
+    bias = rng.normal(0, 0.6, E)
+    counts = np.zeros(E, np.int32)
+    for _ in range(int(rng.integers(1, 33))):
+        counts[np.argsort(-(bias + rng.gumbel(0, 1, E)))[:8]] += 1
+    group, tile, start, end, total = (np.asarray(a) for a in moe_ops._visits(
+        jnp.asarray(counts), jnp.int32(first_group), n_tiles, rows))
+    total = int(total[0])
+    assert group.shape == (n_tiles + E - 1,) and total <= group.shape[0]
+    stored = np.zeros(n_tiles * rows, np.int32)
+    for g, t, s, e in zip(group[:total], tile[:total], start[:total],
+                          end[:total]):
+        lo, hi = max(s, t * rows), min(e, (t + 1) * rows)
+        assert lo < hi                       # a visit has a row to store
+        stored[lo:hi] += 1
+    assert (stored[:counts.sum()] == 1).all()
+    assert not stored[counts.sum():].any()
+    visited = group[:total] - first_group
+    assert set(visited) == set(np.flatnonzero(counts))
+    assert int((np.diff(visited) != 0).sum()) + 1 == (counts > 0).sum()
+    assert (group[total:] == group[total - 1]).all()
+    assert (tile[total:] == tile[total - 1]).all()
+
+
+def test_tile_sizes_is_a_function_of_static_shapes():
+    """Row tiles from the pair count; the whole hidden width where an
+    expert fits the VMEM it is given, whole-lane column tiles else."""
+    decode = tile_sizes(256, 64, 2048, 1024, 2)
+    chunk = tile_sizes(4096, 64, 2048, 1024, 2)
+    assert decode == tile_sizes(256, 64, 2048, 1024, 2) == Tiles(64, 1024)
+    assert chunk == Tiles(128, 1024)
+    for budget in (12 << 20, 24 << 20, 48 << 20):
+        t = tile_sizes(4096, 64, 2048, 1024, 2, vmem_bytes=budget)
+        assert moe_ops._vmem_bytes(t, 2048, 2) <= budget, (budget, t)
+        assert 1024 % t.cols == 0 and t.cols % 128 == 0
+    # Mixtral's experts (4096 x 14336) do not fit whole: column tiles
+    big = tile_sizes(4096, 8, 4096, 14336, 2)
+    assert 14336 % big.cols == 0 and big.cols < 14336
+    assert moe_ops._vmem_bytes(big, 4096, 2) <= 48 << 20
+    # never more rows than there are pairs, whole sublane tiles of the dtype
+    assert tile_sizes(24, 8, 64, 128, 2).rows == 32
+    assert tile_sizes(24, 8, 64, 128, 4).rows == 24
+    assert tile_sizes(8, 16, 32, 48, 4) == Tiles(8, 48)
 
 
 # --------------------------------------- what the dense models keep

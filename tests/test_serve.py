@@ -360,6 +360,42 @@ class TestStreaming:
                        route_prefix="/ngen2")
         assert list(h2.options(stream=True).remote(3)) == [0, 1, 2]
 
+    def test_streamed_response_settles_its_replica_when_it_ends(
+            self, serve_shutdown):
+        """A streamed handle response read to its end (or to its error)
+        gives its replica's in-flight count back THEN, and feeds the
+        router's failure accounting, while the response is still
+        referenced: nothing is left for ``__del__`` to settle from inside
+        a collection (which can run under the router's lock)."""
+        @serve.deployment
+        class Streamer:
+            def __call__(self, n):
+                def gen():
+                    for i in range(abs(n)):
+                        yield i
+                    if n < 0:
+                        raise ValueError("stream boom")
+                return gen()
+
+        h = serve.run(Streamer.bind(), name="settle", route_prefix="/settle")
+        sh = h.options(stream=True)
+        router = sh._get_router()
+        kept = []
+        for n in (3, 1):
+            resp = sh.remote(n)
+            kept.append(resp)
+            assert sum(router._inflight.values()) == 1
+            assert list(resp) == list(range(n))
+            assert resp.ref._settled
+            assert sum(router._inflight.values()) == 0
+        resp = sh.remote(-2)
+        kept.append(resp)
+        with pytest.raises(Exception, match="stream boom"):
+            list(resp)
+        assert resp.ref._settled
+        assert sum(router._inflight.values()) == 0
+        assert resp.ref._replica_key in router._fail_marks
+
     def test_busy_replica_survives_missed_health_probes(self,
                                                         serve_shutdown):
         """A replica that blocks its loop longer than one probe timeout

@@ -268,24 +268,23 @@ def test_gpt2s_sharded_train_step_lowers_with_the_kernel_in_it(v5e):
 # ----------------------------------------------------------- serve programs
 
 
-def test_gpt2s_serve_programs_compile_and_fit(v5e):
-    """Prefill chunk, decode and verify at the scheduler's defaults for
-    GPT-2 small (8 slots, 32-token chunks, 16-token pages, a verify window
-    of serve_spec_k + 1 = 5), on the lane a TPU replica resolves."""
+def _serve_programs_at_the_defaults(cfg, v5e, **program_kw):
+    """{name: compiled} for the prefill chunk, the decode step and the
+    verify step at the scheduler's defaults (8 slots, 32-token chunks,
+    16-token pages, a verify window of serve_spec_k + 1 = 5), on the lane a
+    TPU replica resolves."""
     from ray_tpu._private.config import Config
-    from ray_tpu.models import gpt2_small
     from ray_tpu.models.decode import (init_paged_caches, paged_decode_step,
                                        paged_prefill_into_slot,
                                        paged_verify_step)
     from ray_tpu.models.transformer import init_params
     from ray_tpu.ops.paged_attention import resolve_impl
 
-    cfg, conf = gpt2_small(), Config()
+    conf = Config()
     slots, chunk, T = conf.serve_slots, conf.serve_prefill_chunk, \
         conf.serve_page_tokens
     pages = cfg.max_seq_len // T
     lane = resolve_impl(cfg)
-    assert lane == "pallas"
     chip = SingleDeviceSharding(v5e.devices[0])
 
     def place(tree):
@@ -312,18 +311,88 @@ def test_gpt2s_serve_programs_compile_and_fit(v5e):
                    (params, ids((slots, conf.serve_spec_k + 1)), ids((slots,)),
                     ids((slots,)), table, table, caches), 6),
     }
-    for name, (program, args, donated) in programs.items():
-        compiled = jax.jit(functools.partial(program, cfg, attn=lane),
-                           donate_argnums=(donated,)).lower(*args).compile()
-        assert "tpu_custom_call" in compiled.as_text(), name
-        _fits(compiled)
+    return lane, {
+        name: jax.jit(functools.partial(program, cfg, attn=lane,
+                                        **program_kw),
+                      donate_argnums=(donated,)).lower(*args).compile()
+        for name, (program, args, donated) in programs.items()}
+
+
+def test_gpt2s_serve_programs_compile_and_fit(v5e):
+    """Prefill chunk, decode and verify at the scheduler's defaults for
+    GPT-2 small (8 slots, 32-token chunks, 16-token pages, a verify window
+    of serve_spec_k + 1 = 5), on the lane a TPU replica resolves."""
+    from ray_tpu.models import gpt2_small
+
+    lane, compiled = _serve_programs_at_the_defaults(gpt2_small(), v5e)
+    assert lane == "pallas"
+    for name, program in compiled.items():
+        assert "tpu_custom_call" in program.as_text(), name
+        _fits(program)
+
+
+def _names(compiled) -> set:
+    return {re.sub(r"[.\d]+$", "", name)
+            for name in _kernel_names(compiled.as_text())}
+
+
+def test_moe_debug_serve_programs_lower_with_the_experts_kernel_inside(v5e):
+    """The three paged programs of the toy expert model (float32, 8 experts
+    of 64 x 128, top-3: 24 pairs a decode step, 96 a chunk) pass the stack
+    and a layer, so their grouped matmuls are the kernel
+    ``moe_grouped_matmul``, once a layer, and nothing of the compiler's own
+    ``ragged-dot``; the same model's training step keeps ``ragged_dot``."""
+    from ray_tpu.models import loss_fn, moe_debug
+    from ray_tpu.models.transformer import init_params
+
+    cfg = moe_debug()
+    _, compiled = _serve_programs_at_the_defaults(cfg, v5e)
+    for name, program in compiled.items():
+        text = program.as_text()
+        assert "moe_grouped_matmul" in _names(program), (name, _names(program))
+        assert "ragged-dot" not in text, name
+        _fits(program)
+    chip = SingleDeviceSharding(v5e.devices[0])
+    params = jax.tree.map(
+        lambda a: _on(chip, a.shape, a.dtype),
+        jax.eval_shape(functools.partial(init_params, cfg),
+                       jax.random.PRNGKey(0)))
+    batch = {"tokens": _on(chip, (2, 128), jnp.int32)}
+    train = jax.jit(jax.grad(functools.partial(
+        loss_fn, moe_debug(attn_impl="reference")),
+                             has_aux=True)).lower(
+        params, batch).compile()
+    assert "ragged-dot" in train.as_text()
+    assert "moe_grouped_matmul" not in _names(train)
+
+
+@pytest.mark.parametrize("pairs", [256, 4096], ids=["decode", "chunk"])
+def test_moe_grouped_matmul_compiles(v5e, pairs):
+    """The experts' kernel at OLMoE's widths over the benchmark's stack of
+    8 x 64 experts of 2048 x 1024, at the pairs a decode step of 32 slots
+    and a 512-token chunk bring (8 experts a row): it compiles, under its
+    name, within the VMEM ``tile_sizes`` reckons, and nothing of the stack
+    is copied."""
+    from ray_tpu.ops import moe
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    assert moe.tile_sizes(pairs, 64, 2048, 1024, 2) == (
+        (64, 1024) if pairs == 256 else (128, 1024))
+    args = (_on(chip, (pairs, 2048)), _on(chip, (8 * 64, 2048, 1024)),
+            _on(chip, (8 * 64, 2048, 1024)), _on(chip, (8 * 64, 1024, 2048)),
+            _on(chip, (64,), jnp.int32), _on(chip, (), jnp.int32))
+    compiled = jax.jit(moe.expert_mlp).lower(*args).compile()
+    assert _names(compiled) == {"moe_grouped_matmul"}
+    assert "ragged-dot" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8e6
 
 
 def test_olmoe_serve_programs_compile_and_fit(v5e):
     """The benchmark's OLMoE-1B-7B configuration (published widths, 8
     layers, bf16) under its cell's deployment: the prefill chunk and the
-    decode step with the expert layer's grouped matmuls
-    (``jax.lax.ragged_dot``) and the paged kernel at its second shape (page
+    decode step with the expert layer's grouped matmuls (the kernel
+    ``moe_grouped_matmul``, once a layer, and nothing of the compiler's
+    own ``ragged-dot``) and the paged kernel at its second shape (page
     rows of 16 kv heads x 128, group size 1), weights and the 6.4 GB pool
     beside the programs' own memory on one 16 GB chip."""
     from perfbench.lib import configs
@@ -373,7 +442,9 @@ def test_olmoe_serve_programs_compile_and_fit(v5e):
         compiled = jax.jit(
             functools.partial(program, cfg, attn=lane, moe_info=True),
             donate_argnums=(donated,)).lower(*args).compile()
-        assert "tpu_custom_call" in compiled.as_text(), name
+        assert _names(compiled) == {"paged_attention",
+                                    "moe_grouped_matmul"}, name
+        assert "ragged-dot" not in compiled.as_text(), name
         _fits(compiled)
         # no layer's experts (805 MB) are copied off the stacked weights
         temp = compiled.memory_analysis().temp_size_in_bytes
