@@ -243,6 +243,14 @@ def span_since(name_id: int, t0_ns: int, _pcn=time.perf_counter_ns,
     ring.count = i + 1
 
 
+def span_between(name_id: int, t0_ns: int, t1_ns: int) -> None:
+    """Record a completed span between two stamps of ``now()`` the caller
+    already holds (no clock read). A 0 stamp (recorder off when it was
+    taken, or no start yet) records nothing."""
+    if _enabled and t0_ns and t1_ns:
+        _record(name_id, SPAN, t1_ns, t1_ns - t0_ns)
+
+
 def record_span(name: str, duration_ns: int) -> None:
     """A just-finished span by name (the ``util/tracing.py`` bridge: user
     spans land on the same merged timeline)."""

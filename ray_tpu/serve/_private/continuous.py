@@ -35,6 +35,18 @@ whose id is discarded (``discarded_rows``) and never emitted
 speculative path needs whole logits on the host to accept and resample, so
 it reads before every dispatch.
 
+The scheduler measures the gap it makes. A sampled token becomes an emitted
+one at the READ of its program (``_collect``; a speculative round's tokens
+in ``_decode_spec``): the tokens of one read take ONE stamp and leave in one
+call. Every token after its sequence's first has a gap, this read's stamp
+less the stamp of the read that emitted the one before it, counted by what
+the device was given in between: the scheduler keeps the prompt tokens it
+has dispatched (``prefill_tokens``), notes the count on every launch, and a
+gap is beside prefill if the count rose between the two programs that
+sampled the two tokens (``gap_prefill_*``), else plain (``gap_plain_*``).
+By work, never by a program's name: one program that carries prompt rows
+and decode rows counts the same way.
+
 There is one KV layout and one path to the kernel. A slot owns a page table
 instead of a contiguous worst-case ``arena_len`` range, so long/idle
 sequences reserve no memory they never use; each program writes the new
@@ -135,6 +147,11 @@ _F_STALL = flight.intern("serve.stall")
 # the thread read a result with no program queued behind it: the device
 # stands idle until the next dispatch (argument: the programs read)
 _F_DRAIN = flight.intern("serve.drain")
+# one read that emitted tokens: a SPAN from the previous emitting read's stamp
+# to this one's, and an instant whose argument packs what the device was
+# given in between (``unpack_turn``). Ring only, never a profiler host event:
+# a span that encloses the phases would take every idle-gap label from them
+_F_TURN = flight.intern("serve.turn")
 _STALL_NS = 1_000_000_000  # four slow turns of 0.16-0.27 s (PERF.md)
 
 _m_steps = Counter(
@@ -178,6 +195,15 @@ class SchedulerClosedError(RuntimeError):
     pass
 
 
+def unpack_turn(arg: int) -> Dict[str, Any]:
+    """What a ``serve.turn`` instant's argument packs: the turn's kind
+    ("prefill" if prompt tokens were dispatched since the previous emitting
+    read's program, else "plain"), the tokens the read emitted, and those
+    prompt tokens."""
+    return {"kind": "prefill" if arg & 0xFF else "plain",
+            "rows": (arg >> 8) & 0xFFFF, "prompt_tokens": arg >> 24}
+
+
 def _program(fn, name: str, cfg, **keywords):
     """``fn(cfg, *args, **keywords)`` as a function called ``name``: what
     ``jax.jit`` compiles is the XLA module ``jit_<name>``, which is how a
@@ -196,7 +222,8 @@ class _Seq:
                  "seed", "slot", "state", "n_generated", "n_launched",
                  "next_token",
                  "queue", "loop", "cancelled", "rid", "t_submit", "t_admit",
-                 "t_first_token", "rng", "cached_len", "cursor",
+                 "t_first_token", "t_emit", "prefill_mark", "rng",
+                 "cached_len", "cursor",
                  "owned_pages", "radix_node",
                  "table_fill", "fleet_hint", "migration_node",
                  "drafter_len", "drafter_pending")
@@ -222,6 +249,11 @@ class _Seq:
         self.t_submit = time.monotonic()
         self.t_admit: Optional[float] = None
         self.t_first_token: Optional[float] = None
+        # the read that emitted the newest token: its stamp (the recorder's
+        # clock, 0 with the recorder off) and ``prefill_tokens`` as the
+        # program that sampled the token was dispatched
+        self.t_emit = 0
+        self.prefill_mark = 0
         self.rng = None  # the speculative path's numpy Generator (T > 0)
         # ---- paged-arena bookkeeping (the device holds pages only) ----
         self.cached_len = 0            # spliced prefix tokens (page-aligned)
@@ -246,11 +278,14 @@ def _deliver(batch) -> None:
 class _Launched:
     """One dispatched program whose result the host has not read yet."""
 
-    __slots__ = ("serial", "step", "ids", "rows", "moe", "live_rows")
+    __slots__ = ("serial", "prefill_mark", "step", "ids", "rows", "moe",
+                 "live_rows")
 
-    def __init__(self, serial: int, step: bool, ids, rows: List[_Seq], moe,
-                 live_rows: int):
+    def __init__(self, serial: int, prefill_mark: int, step: bool, ids,
+                 rows: List[_Seq], moe, live_rows: int):
         self.serial = serial        # its number among the dispatched programs
+        # prompt tokens dispatched so far, this program's own among them
+        self.prefill_mark = prefill_mark
         self.step = step            # a decode step (else a prefill chunk)
         self.ids = ids              # the program's [slots] ids, on the device
         self.rows = rows            # the sequences it sampled a token for
@@ -417,6 +452,18 @@ class ContinuousScheduler:
         self._serial = 0           # programs dispatched so far
         self._steps_unread = 0     # decode steps among _inflight
         self._outbox: Optional[Dict[Any, list]] = None  # see _emit
+        # the read whose tokens are being emitted (_begin_read): its stamp
+        # and its program's prefill_mark; and the stamp and mark of the
+        # read that emitted before it
+        self._read_stamp = 0
+        self._read_mark = 0
+        self._turn_stamp = 0
+        self._turn_mark = 0
+        # what stats() shows of the counts below, as of the last read: one
+        # tuple, replaced whole, so a reader on another thread never sees a
+        # token counted and its gap not yet (tokens, first tokens, plain,
+        # plain ns, prefill, prefill ns)
+        self._emitted = (0, 0, 0, 0, 0, 0)
         self._n_runahead = 0
         self._n_drains = 0
         self._n_discarded = 0
@@ -471,6 +518,17 @@ class ContinuousScheduler:
         # stats (host-side; mirrored into the process metric registry)
         self._n_steps = 0
         self._n_prefill_chunks = 0
+        # prompt tokens dispatched (a chunk's real tokens), and every token
+        # after a sequence's first by what the device was given since the
+        # program that sampled the one before it: decode work only
+        # (plain), or prompt tokens too (prefill); nanoseconds between the
+        # two reads that emitted them
+        self._n_prefill_tokens = 0
+        self._n_gap_plain = 0
+        self._gap_plain_ns = 0
+        self._n_gap_prefill = 0
+        self._gap_prefill_ns = 0
+        self._n_turns = 0  # loop turns that dispatched or read a program
         self._n_admitted = 0
         self._n_retired = 0
         self._n_tokens = 0
@@ -537,8 +595,9 @@ class ContinuousScheduler:
         """Enqueue a generation. Tokens/end/error events arrive on ``queue``
         via ``loop.call_soon_threadsafe`` as ``("tok", id, stamp)``,
         ``("end", reason, stamp)`` or ``("err", message, stamp)`` tuples;
-        ``stamp`` is the recorder's clock (``perf_counter_ns``) at the
-        hand-off, 0 with the recorder off. Thread/loop-safe.
+        ``stamp`` is the recorder's clock (``perf_counter_ns``) at the read
+        that emitted the item (one stamp for all the items of one read), 0
+        with the recorder off. Thread/loop-safe.
 
         ``request_id`` is what the request's flight instants
         (``serve.req.queued/admit/first_token/retire``) carry; the replica
@@ -590,24 +649,52 @@ class ContinuousScheduler:
     def _emit(self, seq: _Seq, kind: str, value) -> None:
         """Hand one item to the consumer's event loop, stamped with the
         recorder's clock so the receiving side can count how long it lay
-        between the two threads (``stream_lag_s`` in the replica)."""
+        between the two threads (``stream_lag_s`` in the replica). The
+        items of one read carry the read's ONE stamp and leave together."""
         if seq.loop is None or seq.queue is None:
             return
-        item = (kind, value, flight.now())
         if self._outbox is not None:
-            # a program's tokens leave together (_collect): one wake-up of
-            # the consumers' loop a step, not one a token
-            self._outbox.setdefault(seq.loop, []).append((seq, item))
+            # a program's tokens leave together (_end_read): one wake-up of
+            # the consumers' loop a read, not one a token
+            self._outbox.setdefault(seq.loop, []).append(
+                (seq, (kind, value, self._read_stamp)))
             return
         try:
-            seq.loop.call_soon_threadsafe(seq.queue.put_nowait, item)
+            seq.loop.call_soon_threadsafe(seq.queue.put_nowait,
+                                          (kind, value, flight.now()))
         except RuntimeError:
             # consumer's loop is gone — nobody is listening; retire quietly
             seq.cancelled = True
 
-    def _hand_over(self) -> None:
-        """Send what ``_emit`` gathered while ``_outbox`` was open, in
-        order, with one call into each consumers' loop."""
+    def _begin_read(self, prefill_mark: int) -> None:
+        """Open the emission of one read (a dispatched program's ids, or a
+        speculative round's accepted tokens): the one place a sampled token
+        becomes an emitted one. Everything emitted until ``_end_read``
+        carries ONE stamp, the end of all its gaps, and ``prefill_mark``,
+        the prompt tokens dispatched up to and including the program that
+        sampled it: what decides each gap's kind."""
+        self._outbox = {}
+        self._read_stamp = flight.now()
+        self._read_mark = prefill_mark
+
+    def _end_read(self) -> None:
+        """Count the read's tokens once and put the turn down in the flight
+        ring; then send what ``_emit`` gathered since ``_begin_read``, in
+        order, with one call into each consumers' loop. The hand-over stays
+        the phase's LAST act: it wakes a thread that wants the interpreter
+        lock, and what follows it here would wait for that thread."""
+        emitted = self._n_tokens - self._emitted[0]
+        if emitted:
+            _m_tokens.inc(emitted)
+            stamp, mark = self._read_stamp, self._read_mark
+            prompt_tokens = mark - self._turn_mark
+            flight.span_between(_F_TURN, self._turn_stamp, stamp)
+            flight.instant(_F_TURN, prompt_tokens << 24
+                           | min(emitted, 0xFFFF) << 8 | (prompt_tokens > 0))
+            self._turn_stamp, self._turn_mark = stamp, mark
+            self._emitted = (self._n_tokens, self._n_first_tokens,
+                             self._n_gap_plain, self._gap_plain_ns,
+                             self._n_gap_prefill, self._gap_prefill_ns)
         outbox, self._outbox = self._outbox, None
         for loop, batch in outbox.items():
             try:
@@ -711,16 +798,33 @@ class ContinuousScheduler:
         return True
 
     def _emit_token(self, seq: _Seq, tok: int) -> bool:
-        """Record + stream one sampled token; returns True if the sequence
-        is finished (budget exhausted or EOS)."""
+        """Record + stream one sampled token of the open read
+        (``_begin_read``); returns True if the sequence is finished (budget
+        exhausted or EOS). A token that is not its sequence's first has a
+        GAP, this read's stamp less the stamp of the read that emitted the
+        one before it, counted beside prefill if any prompt token was
+        dispatched after the program that sampled that one, up to and
+        including the program that sampled this one (by the scheduler's own
+        count at dispatch, never by which program ran), else plain."""
         seq.n_generated += 1
         self._n_tokens += 1
-        _m_tokens.inc()
+        stamp = self._read_stamp
         if seq.t_first_token is None:
             seq.t_first_token = time.monotonic()
             self._first_token_wait_s += seq.t_first_token - seq.t_admit
             self._n_first_tokens += 1
             flight.instant(_F_FIRST_TOKEN, seq.rid)
+        else:
+            # a stamp is 0 while the recorder is off: counted, not timed
+            gap = stamp - seq.t_emit if stamp and seq.t_emit else 0
+            if self._read_mark > seq.prefill_mark:
+                self._n_gap_prefill += 1
+                self._gap_prefill_ns += gap
+            else:
+                self._n_gap_plain += 1
+                self._gap_plain_ns += gap
+        seq.t_emit = stamp
+        seq.prefill_mark = self._read_mark
         self._emit(seq, "tok", tok)
         if self.eos_id is not None and tok == self.eos_id:
             return True
@@ -906,8 +1010,9 @@ class ContinuousScheduler:
         self._serial += 1
         moe = out[2]["counts"] if self._moe else None
         if rows or moe is not None:
-            self._inflight.append(_Launched(self._serial, step, out[0], rows,
-                                            moe, live_rows))
+            self._inflight.append(_Launched(
+                self._serial, self._n_prefill_tokens, step, out[0], rows,
+                moe, live_rows))
 
     def _moe_count(self, counts, live_rows: int) -> None:
         """Add up one finished program's expert counts (call after a wait
@@ -954,7 +1059,7 @@ class ContinuousScheduler:
                        if seq.state != _DONE]
             self._n_discarded += len(rec.rows) - len(arrived)
             switch(_P_EMIT)
-            self._outbox = {}
+            self._begin_read(rec.prefill_mark)
             try:
                 for seq, tok in arrived:
                     if seq.cancelled:  # nobody listens: no token past it
@@ -966,7 +1071,7 @@ class ContinuousScheduler:
                         self._retire(seq, "eos" if self.eos_id is not None
                                      and tok == self.eos_id else "length")
             finally:
-                self._hand_over()
+                self._end_read()
         return True
 
     def _prefill_one(self) -> bool:
@@ -1014,6 +1119,7 @@ class ContinuousScheduler:
             # buffer, while _offer_prompt_pages and _ensure_pages write
             # to these rows before anything waits for this chunk
             last = not seq.remaining_prompt
+            self._n_prefill_tokens += real
             self._launch(self._prefill(
                 self.params, tokens, np.int32(real), np.int32(seq.cursor),
                 jnp.asarray(self._read_tables[seq.slot].copy()),
@@ -1422,47 +1528,53 @@ class ContinuousScheduler:
         self._max_active_slots = max(self._max_active_slots, len(live))
         # ---- exact acceptance: the cursor moves past what was accepted -
         dlen = self._drafter.lengths().copy()
-        for s in live:
-            sl = s.slot
-            ds = drafts[sl]
-            old = s.cursor
-            nxt = s.next_token
-            if s.temperature <= 0.0:
-                a, emitted = accept_greedy(ds, va[sl])
-            else:
-                if s.rng is None:
-                    s.rng = np.random.default_rng(s.seed)
-                pt = [_softmax(va[sl, j], s.temperature)
-                      for j in range(len(ds) + 1)]
-                a, emitted = accept_sample(ds, dprobs[sl], pt, s.rng)
-            self._n_drafted += len(ds)
-            self._n_accepted += a
-            if ds:
-                m_spec_drafted.inc(len(ds))
-            if a:
-                m_spec_accepted.inc(a)
-            new_cursor = old + a + 1
-            s.cursor = new_cursor
-            # drafter sync: positions [L0, L0 + k) were consumed this
-            # round; the valid prefix stops at the last accepted position,
-            # and whatever accepted tokens the drafter missed become next
-            # round's catch-up feed
-            L0 = s.drafter_len
-            valid = min(L0 + k, new_cursor)
-            hist = pend0[sl] + [nxt] + list(ds[:a])
-            s.drafter_pending = hist[valid - L0:new_cursor - L0]
-            s.drafter_len = valid
-            dlen[sl] = valid
-            finished = False
-            for tok in emitted:
-                s.next_token = tok
-                self._n_spec_emitted += 1
-                if self._emit_token(s, tok):
-                    finished = True
-                    break
-            if finished:
-                self._retire(s, "eos" if self.eos_id is not None
-                             and s.next_token == self.eos_id else "length")
+        # the round's accepted tokens are ONE read: one stamp, one hand-over;
+        # the verify call came after every chunk dispatched so far
+        self._begin_read(self._n_prefill_tokens)
+        try:
+            for s in live:
+                sl = s.slot
+                ds = drafts[sl]
+                old = s.cursor
+                nxt = s.next_token
+                if s.temperature <= 0.0:
+                    a, emitted = accept_greedy(ds, va[sl])
+                else:
+                    if s.rng is None:
+                        s.rng = np.random.default_rng(s.seed)
+                    pt = [_softmax(va[sl, j], s.temperature)
+                          for j in range(len(ds) + 1)]
+                    a, emitted = accept_sample(ds, dprobs[sl], pt, s.rng)
+                self._n_drafted += len(ds)
+                self._n_accepted += a
+                if ds:
+                    m_spec_drafted.inc(len(ds))
+                if a:
+                    m_spec_accepted.inc(a)
+                new_cursor = old + a + 1
+                s.cursor = new_cursor
+                # drafter sync: positions [L0, L0 + k) were consumed this
+                # round; the valid prefix stops at the last accepted position,
+                # and whatever accepted tokens the drafter missed become next
+                # round's catch-up feed
+                L0 = s.drafter_len
+                valid = min(L0 + k, new_cursor)
+                hist = pend0[sl] + [nxt] + list(ds[:a])
+                s.drafter_pending = hist[valid - L0:new_cursor - L0]
+                s.drafter_len = valid
+                dlen[sl] = valid
+                finished = False
+                for tok in emitted:
+                    s.next_token = tok
+                    self._n_spec_emitted += 1
+                    if self._emit_token(s, tok):
+                        finished = True
+                        break
+                if finished:
+                    self._retire(s, "eos" if self.eos_id is not None
+                                 and s.next_token == self.eos_id else "length")
+        finally:
+            self._end_read()
         self._drafter.set_lengths(dlen)
         return True
 
@@ -1541,8 +1653,11 @@ class ContinuousScheduler:
                     did = self._decode_once(behind) or did
                 _m_active.set(float(sum(
                     1 for s in self._slot_seqs if s is not None)))
-                if not did:
+                if did:
+                    self._n_turns += 1
+                else:
                     clock.switch(_P_PARK)
+                    self._turn_stamp = 0  # no turn spans a pause
                     with self._lock:
                         idle = (not self._pending and not self._commands
                                 and not self._migrating and all(
@@ -1640,6 +1755,8 @@ class ContinuousScheduler:
     def stats(self) -> Dict[str, Any]:
         with self._lock:
             q = len(self._pending)
+        (tokens, first_tokens, gap_plain, gap_plain_ns, gap_prefill,
+         gap_prefill_ns) = self._emitted
         out = {
             "mode": "continuous",
             "slots": self.slots,
@@ -1647,9 +1764,13 @@ class ContinuousScheduler:
             "arena_len": self.arena_len,
             "decode_steps": self._n_steps,
             "prefill_chunks": self._n_prefill_chunks,
+            # prompt tokens dispatched (the chunks' real tokens), and loop
+            # turns that dispatched or read a program
+            "prefill_tokens": self._n_prefill_tokens,
+            "turns": self._n_turns,
             "admitted": self._n_admitted,
             "retired": self._n_retired,
-            "tokens_generated": self._n_tokens,
+            "tokens_generated": tokens,
             # iteration-level proof signals: > 0 means a request was
             # admitted while others were mid-generation, which a
             # flush-and-drain batcher can never do
@@ -1663,7 +1784,17 @@ class ContinuousScheduler:
             # admit -> first token (count: first_tokens), summed
             "queue_wait_s": self._queue_wait_s,
             "first_token_wait_s": self._first_token_wait_s,
-            "first_tokens": self._n_first_tokens,
+            "first_tokens": first_tokens,
+            # every emitted token after its sequence's first, by what the
+            # device was given between the programs that sampled it and
+            # the one before it: decode work only (plain) or prompt tokens
+            # too (prefill); seconds between the two reads, 0 with the
+            # recorder off. plain + prefill + first_tokens ==
+            # tokens_generated, exactly, in every snapshot (_emitted)
+            "gap_plain_tokens": gap_plain,
+            "gap_plain_s": gap_plain_ns / 1e9,
+            "gap_prefill_tokens": gap_prefill,
+            "gap_prefill_s": gap_prefill_ns / 1e9,
             # loop turns longer than 1 s while a slot was live: the time
             # beyond it, how many, and the phase that held the last one
             "stall_s": self._stall_s,
