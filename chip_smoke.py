@@ -259,7 +259,8 @@ def olmoe_task(seed: int) -> dict:
     from perfbench.lib import configs, weights
     from perfbench.lib import manifest as manifest_lib
     from perfbench.reference import olmoe as ref
-    from ray_tpu.models.decode import (init_paged_caches, paged_decode_step,
+    from ray_tpu.models.decode import (StepRows, init_paged_caches,
+                                       paged_decode_step,
                                        paged_prefill_into_slot)
     from ray_tpu.ops.moe import expert_mlp, moe_layer, tile_sizes
 
@@ -344,7 +345,9 @@ def olmoe_task(seed: int) -> dict:
                 "largest_value": float(np.abs(want_k).max())}
 
     # ---- the 8-layer model through the paged programs: two prompts (one
-    # of two chunks) prefilled into slots 0 and 5 of 8, then 6 decode steps
+    # of two chunks) prefilled into slots 0 and 5 of 8 — slot 5's two chunks
+    # TAKE SLOT 0'S DECODE ROW ALONG, as a turn of the scheduler does
+    # (ISSUE 40) — then 6 decode steps of both
     S, C, T, P = 8, 512, 16, 64
     caches = init_paged_caches(cfg, S * P + 1, T, P)
     tables = (1 + np.arange(S * P, dtype=np.int32)).reshape(S, P)
@@ -360,38 +363,51 @@ def olmoe_task(seed: int) -> dict:
                5: rng.integers(1, cfg.vocab_size, 700).tolist()}
     got = {s: [] for s in prompts}      # logits at the served positions
     taken = {s: [] for s in prompts}    # routes [L, tokens, k]
+    fed = {s: [] for s in prompts}
     rows_routed = live_rows = 0
+    active = np.zeros(S, np.int32)
+    cursors = np.zeros(S, np.int32)  # the caller's: the pool keeps none
+    greedy = (np.zeros(S, np.float32), np.zeros(S, np.uint32))
+    ids_are_argmax = True  # what a program hands the next is what the host fed
+
+    def feed():
+        """The decoding rows' next tokens as the host would feed them, and
+        whether the device's own vector holds just those."""
+        toks = np.zeros(S, np.int32)
+        for s in np.flatnonzero(active):
+            fed[s].append(int(got[s][-1].argmax()))
+            toks[s] = fed[s][-1]
+        return [int(t) for t in np.asarray(ids)] == list(toks)
+
     for s, prompt in prompts.items():
         for c0 in range(0, len(prompt), C):
             chunk = prompt[c0:c0 + C]
             real = len(chunk)
+            ids_are_argmax &= feed()
             ids, caches, moe, logits = prefill(
                 params, jnp.asarray([chunk + [0] * (C - real)], jnp.int32),
                 np.int32(real), np.int32(c0), jnp.asarray(tables[s]),
                 jnp.asarray(tables[s]), caches, ids,
                 np.int32(s if c0 + C >= len(prompt) else -1), np.float32(0),
-                np.uint32(0))
-            taken[s].append(np.asarray(moe["routes"])[:, 0, :real])
+                np.uint32(0), StepRows(active.copy(), cursors.copy(), tables,
+                                       tables, *greedy))
+            routes = np.asarray(moe["routes"])[:, 0]  # chunk rows, then step
+            taken[s].append(routes[:, :real])
             rows_routed += int(np.asarray(moe["counts"]).sum())
-            live_rows += real
-        got[s].append(np.asarray(logits, np.float32))
-    active = np.zeros(S, np.int32)
-    active[list(prompts)] = 1
-    cursors = np.zeros(S, np.int32)  # the caller's: the pool keeps none
-    for s, prompt in prompts.items():
-        cursors[s] = len(prompt)
-    fed = {s: [] for s in prompts}
-    ids_are_argmax = True  # what a step hands the next is what the host fed
+            live_rows += real + int(active.sum())
+            for row in np.flatnonzero(active):
+                got[row].append(np.asarray(logits[1 + row], np.float32))
+                taken[row].append(routes[:, C + row:C + row + 1])
+            cursors = cursors + active
+            cursors[s] += real
+        got[s].append(np.asarray(logits[0], np.float32))
+        active[s] = 1
+    assert len(fed[0]) == 2 and not fed[5]  # slot 0 decoded beside 5's chunks
     for _ in range(6):
-        toks = np.zeros(S, np.int32)
-        for s in prompts:
-            fed[s].append(int(got[s][-1].argmax()))
-            toks[s] = fed[s][-1]
-        ids_are_argmax &= [int(t) for t in np.asarray(ids)] == list(toks)
+        ids_are_argmax &= feed()
         ids, caches, moe, logits = step(
             params, ids, jnp.asarray(active), cursors, jnp.asarray(tables),
-            jnp.asarray(tables), caches, np.zeros(S, np.float32),
-            np.zeros(S, np.uint32))
+            jnp.asarray(tables), caches, *greedy)
         cursors = cursors + active
         rows_routed += int(np.asarray(moe["counts"]).sum())
         live_rows += len(prompts)
@@ -443,9 +459,10 @@ def olmoe_task(seed: int) -> dict:
     out["scheduler"] = served_batch(cfg, params, seed)
     if out["scheduler"]["runahead_share"] < 0.8 \
             or out["scheduler"]["discarded_rows"] \
-            or out["scheduler"]["compiled_programs"] != 2:
-        bad.append("the decode loop did not run a step ahead, or paid "
-                   "for it")
+            or out["scheduler"]["compiled_programs"] != 2 \
+            or not out["scheduler"]["fused_turns"]:
+        bad.append("the decode loop did not run a step ahead, paid for it, "
+                   "or no chunk took a decode row along")
     if out["scheduler"]["worst_served_gap"] > 0.10:
         bad.append("a served token is not among the sequential cache's "
                    "best")
@@ -497,7 +514,7 @@ def sala_task(seed: int) -> dict:
             np.int32(len(chunk)), np.int32(c0), jnp.asarray(tables[slot]),
             jnp.asarray(tables[slot]), caches, ids,
             np.int32(slot if c0 + C >= len(prompt) else -1), np.float32(0),
-            np.uint32(0), np.int32(slot))
+            np.uint32(0), None, np.int32(slot))
         chose.append(np.asarray(picked)[:, 0, :len(chunk)])
         chunk_s.append(time.perf_counter() - t0)
     got.append(np.asarray(logits, np.float32))
@@ -632,6 +649,9 @@ def served_batch(cfg, params, seed: int) -> dict:
             "runahead_share": round(stats["runahead_steps"]
                                     / stats["decode_steps"], 4),
             "decode_steps": stats["decode_steps"],
+            "prefill_chunks": stats["prefill_chunks"],
+            "fused_turns": stats["fused_turns"],
+            "fused_step_rows": stats["fused_step_rows"],
             "pipeline_drains": stats["pipeline_drains"],
             "discarded_rows": stats["discarded_rows"],
             "compiled_programs": stats["compiled_programs"]}
@@ -805,6 +825,8 @@ def serve_phase(seed: int) -> None:
          pipeline_drains=stats["pipeline_drains"],
          discarded_rows=stats["discarded_rows"],
          prefill_chunks=stats["prefill_chunks"],
+         fused_turns=stats["fused_turns"],
+         fused_step_rows=stats["fused_step_rows"],
          prefix_hit_tokens=stats.get("prefix_hit_tokens"),
          weights={k: weights.get(k) for k in ("mode", "nbytes")},
          store_capacity=store["capacity"],

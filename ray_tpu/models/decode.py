@@ -10,8 +10,9 @@ any prompt length up to max_len.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from functools import partial
-from typing import Any, List, Optional, Tuple
+from typing import Any, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -369,15 +370,48 @@ def init_paged_caches(cfg: TransformerConfig, num_pages: int,
     return [one(kind) for kind in cfg.kinds]
 
 
-def _paged_forward_inplace(cfg: TransformerConfig, params, tokens, positions,
-                           lengths, read_tables, write_tables, caches, impl,
-                           valid, *, slot=None, real_len=None, active=None,
-                           taps=None):
-    """The serving forward: one K-token-window pass over all S slots where
-    each attention layer (1) writes the window's k/v DIRECTLY into its pages
-    — ``pool.at[page, offset].set`` through the write table,
+class _Rows(NamedTuple):
+    """One group of rows of a paged program: what attends at ONE shape.
+    tokens/positions/valid: [S, K]; lengths: [S] attention cursors;
+    read_tables/write_tables: [S, P]."""
+
+    tokens: Any
+    positions: Any
+    lengths: Any
+    read_tables: Any
+    write_tables: Any
+    valid: Any
+
+
+class StepRows(NamedTuple):
+    """The decode step's rows a prefill chunk's program takes along
+    (``paged_prefill_into_slot``): ``paged_decode_step``'s arrays of the
+    same names over ``[slots]``; the rows' tokens are the chunk's ``ids``."""
+
+    active: Any
+    cursors: Any
+    read_tables: Any
+    write_tables: Any
+    temperature: Any
+    seeds: Any
+
+
+def step_rides_chunk(cfg: TransformerConfig) -> bool:
+    """Whether a prefill chunk's program can take the live decode rows
+    along: where every layer holds pages through ``ops.paged_attention``. A
+    'minicpm4' or 'lightning-attn' layer has chunk and step kernels of its
+    own shapes, and such a model's turn stays two programs."""
+    return all(kind == ATTENTION for kind in cfg.kinds)
+
+
+def _paged_forward_inplace(cfg: TransformerConfig, params,
+                           groups: List[_Rows], caches, impl, *, slot=None,
+                           real_len=None, active=None, taps=None):
+    """The serving forward: one pass over the rows of ``groups`` where each
+    attention layer (1) writes the rows' k/v DIRECTLY into their pages —
+    ``pool.at[page, offset].set`` through the write tables,
     write-before-attend, so XLA updates the donated pool in place — and (2)
-    attends through the read table via ``ops.paged_attention(impl=)``; a
+    attends through the read tables via ``ops.paged_attention(impl=)``; a
     'minicpm4' layer does the same through ``transformer.sparse_mixer`` and
     attends the blocks it chooses; a 'lightning-attn' layer
     (``transformer.linear_mixer``) reads and writes its states instead: all
@@ -387,25 +421,59 @@ def _paged_forward_inplace(cfg: TransformerConfig, params, tokens, positions,
     sequence needs no reset beforehand — and advanced by the chunk's
     ``real_len`` real tokens. Layer math mirrors ``transformer._block``.
 
-    tokens/positions: [S, K]; lengths: [S] attention cursors;
-    read_tables/write_tables: [S, P]. Positions on unallocated/shared pages
-    redirect to the garbage page through the write table. ``valid``: bool
-    [S, K], the rows that carry a live token (not a slot without a sequence,
-    not a chunk's padding): the expert layer routes the others nowhere.
-    ``taps``: a list that is given each 'minicpm4' layer's choice (debug).
-    Returns (logits [S, K, vocab], caches, moe): moe is None for a dense
-    model, else ``{"counts": [L, E], "routes": [L, S, K, k]}`` — the rows
-    each layer's experts received (they sum to valid rows x k a layer: no
-    row is dropped) and the experts each row chose."""
+    One group (``_Rows``: a step's or a verify's [S, K] window over all
+    slots, a chunk's [1, C]) is the whole batch as it stands. SEVERAL — a
+    chunk and the step's rows it takes along, plain attention layers only —
+    go through every norm, projection and the MLP or expert layer as ONE
+    batch ``[1, sum of S x K]``, read the weights once, and their k/v land in
+    the pool in ONE write; only attention is called a group, each at its
+    own shape. No two rows that matter may share a position: the caller
+    sends every row whose write must not land to the garbage page through
+    its group's write table. Positions on unallocated/shared pages redirect
+    there the same way. ``valid`` marks the rows that carry a live token
+    (not a slot without a sequence, not a chunk's padding): the expert layer
+    routes the others nowhere. ``taps``: a list that is given each
+    'minicpm4' layer's choice (debug).
+    Returns (hidden, caches, moe): the rows after the last layer, BEFORE the
+    final norm, [S, K, d] (several groups: [1, rows, d], group after
+    group) — the caller norms and projects the rows it samples (``_head``);
+    moe is None for a dense model, else ``{"counts": [L, E], "routes": [L,
+    *rows, k]}`` — the rows each layer's experts received (they sum to valid
+    rows x k a layer: no row is dropped; several groups: [L, groups, E], the
+    rows each GROUP sent them) and the experts each row chose."""
+    first = groups[0]
+    if len(groups) > 1 and not step_rides_chunk(cfg):
+        raise ValueError("several groups of rows in one paged program need "
+                         "plain attention layers throughout")
+    lead = first.tokens.shape if len(groups) == 1 else (1, -1)
+
+    def batch(parts):
+        """The groups' rows as the one batch: a lone group as it stands."""
+        parts = [p.reshape(lead + p.shape[2:]) for p in parts]
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+    ends = list(itertools.accumulate(g.tokens.size for g in groups))
+
+    def split(rows):
+        """The batch's rows group by group, each in its group's shape."""
+        if len(groups) == 1:
+            return [rows]
+        return [rows[:, end - g.tokens.size:end].reshape(
+            g.tokens.shape + rows.shape[2:]) for g, end in zip(groups, ends)]
+
+    tokens = batch([g.tokens for g in groups])
+    positions = batch([g.positions for g in groups])
+    valid = batch([g.valid for g in groups])
     x = embed(cfg, params, tokens)
     if cfg.pos == "learned":
         x = x + params["pos_embed"]["table"].astype(cfg.dtype)[positions]
     rope = rope_table(cfg)
     if ATTENTION in cfg.kinds:
         T = caches[cfg.kinds.index(ATTENTION)].k.shape[1]
-        P = read_tables.shape[1]
-        pages = jnp.take_along_axis(
-            write_tables, jnp.clip(positions // T, 0, P - 1), axis=1)
+        pages = batch([jnp.take_along_axis(
+            g.write_tables,
+            jnp.clip(g.positions // T, 0, g.write_tables.shape[1] - 1),
+            axis=1) for g in groups])
         offs = positions % T
     new_caches = []
     moe_layers = []
@@ -425,9 +493,9 @@ def _paged_forward_inplace(cfg: TransformerConfig, params, tokens, positions,
             new_caches.append(LinearState(
                 s=lax.dynamic_update_slice_in_dim(c.s, mine, slot, axis=0)))
         elif kind == SPARSE:
-            a, pools = sparse_mixer(cfg, ap, h, positions, lengths,
-                                    (c.k, c.v, c.means), read_tables,
-                                    write_tables, impl=impl, taps=taps)
+            a, pools = sparse_mixer(cfg, ap, h, positions, first.lengths,
+                                    (c.k, c.v, c.means), first.read_tables,
+                                    first.write_tables, impl=impl, taps=taps)
             new_caches.append(SparsePagedKVCache(*pools))
         else:
             q, k, v = _qkv(cfg, ap, h, rope, positions)
@@ -435,18 +503,34 @@ def _paged_forward_inplace(cfg: TransformerConfig, params, tokens, positions,
                 k.reshape(*k.shape[:2], -1).astype(c.k.dtype))
             cv = c.v.at[pages, offs].set(
                 v.reshape(*v.shape[:2], -1).astype(c.v.dtype))
-            o = paged_attention(q, ck, cv, read_tables, lengths, impl=impl)
+            o = batch([paged_attention(q_g, ck, cv, g.read_tables, g.lengths,
+                                       impl=impl)
+                       for g, q_g in zip(groups, split(q))])
             a = jnp.einsum("bshk,hkd->bsd", o, ap["wo"].astype(cfg.dtype))
             new_caches.append(PagedKVCache(k=ck, v=cv))
         x = _residual(cfg, x, a)
         mlp_p, layer = stacked_mlp(cfg, params, p, i)
         m, _, moe = _mlp(cfg, mlp_p, _norm(cfg, p["ln2"], x), valid, layer)
         x = _residual(cfg, x, m)
+        if moe is not None and len(groups) > 1:
+            # the experts saw one batch; who sent them which rows is still
+            # told a group, as if each group had been a program of its own
+            moe["counts"] = jnp.stack([
+                jnp.zeros_like(moe["counts"]).at[jnp.where(
+                    live[..., None], routes, moe["counts"].shape[0])].add(
+                        1, mode="drop")
+                for live, routes in zip(split(valid), split(moe["routes"]))])
         moe_layers.append(moe)
     moe = (jax.tree.map(lambda *a: jnp.stack(a), *moe_layers)
            if cfg.mlp == "moe" else None)
-    logits = project(cfg, params, final_hidden(cfg, params, x))
-    return logits, new_caches, moe
+    return x, new_caches, moe
+
+
+def _head(cfg: TransformerConfig, params, hidden):
+    """hidden [B, S, d] as ``_paged_forward_inplace`` returns it -> logits
+    [B, S, vocab]: the final norm and the output projection, of the rows a
+    program samples or scores and of no other."""
+    return project(cfg, params, final_hidden(cfg, params, hidden))
 
 
 def _paged_outputs(first, caches, moe, moe_info: bool, logits, taps=None):
@@ -469,67 +553,109 @@ def _check_moe_info(cfg: TransformerConfig, moe_info: bool):
 def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
                             cursor, read_row, write_row,
                             caches: List[Any], ids, slot,
-                            temperature, seed, state_slot=None, *, attn: str,
+                            temperature, seed, step: Optional[StepRows],
+                            state_slot=None, *, attn: str,
                             moe_info: bool = False, logits: bool = False,
                             selected: bool = False):
-    """One prefill chunk into ONE slot, through its page table. tokens:
-    [1, C] — the next C prompt tokens, zero-padded past ``real_len`` (so
-    every chunk size compiles to the same program). The chunk lands at
-    logical positions [cursor, cursor + C) of the slot whose two rows these
-    are: its k/v written straight into their pages, attention through the
-    read table (``_paged_forward_inplace``). cursor: int32 scalar, the
-    tokens already resident (0 cold, the spliced length after a
-    prefix-cache hit); the caller advances it by ``real_len``.
-    read_row/write_row: [P] int32 — shared (prefix-cache) pages appear in
-    read_row but are redirected to the garbage page in write_row, so their
-    content is immutable here. ``attn``: the implementation
-    ``ops.paged_attention`` runs ('reference' | 'pallas').
+    """One prefill chunk into ONE slot, through its page table, and with it
+    the decode step of the rows that are live (``step``): a turn that holds
+    a chunk reads the weights once. tokens: [1, C] — the next C prompt
+    tokens, zero-padded past ``real_len`` (so every chunk size compiles to
+    the same program). The chunk lands at logical positions [cursor, cursor
+    + C) of the slot whose two rows these are: its k/v written straight
+    into their pages, attention through the read table
+    (``_paged_forward_inplace``). cursor: int32 scalar, the tokens already
+    resident (0 cold, the spliced length after a prefix-cache hit); the
+    caller advances it by ``real_len``. read_row/write_row: [P] int32 —
+    shared (prefix-cache) pages appear in read_row but are redirected to the
+    garbage page in write_row, so their content is immutable here. ``attn``:
+    the implementation ``ops.paged_attention`` runs ('reference' |
+    'pallas').
 
     The chunk samples (``sample_token``, by ``temperature`` and ``seed``,
     scalars) the token that follows its last REAL row, and where the chunk
     is the prompt's last puts it where the next decode step reads it: ids
     [slots] int32 is the vector ``paged_decode_step`` takes as its tokens,
     ``slot`` the row that takes the sampled id, -1 for a chunk that is not
-    the last (the vector is then returned as it came). The first token so
-    reaches the step without a visit to the host. ``state_slot``: the
-    slot itself, whichever chunk this is — where the model has
-    'lightning-attn' layers, their states of that slot are what the chunk
-    continues (from zero when ``cursor`` is 0) and leaves advanced by
-    ``real_len`` tokens.
+    the last. The first token so reaches the step without a visit to the
+    host.
+
+    ``step`` (``StepRows``, or None: the chunk goes alone, and the ids
+    vector comes back as it came but for ``slot``): what
+    ``paged_decode_step`` would be given next, its tokens being ``ids``.
+    Where ``step_rides_chunk(cfg)`` the C chunk rows and the [slots] step
+    rows are ONE batch through every projection, the MLP or expert layer
+    and ONE write of k/v; attention alone is two calls a layer, ``[1, C]``
+    through the slot's row and ``[slots, 1]`` through all tables; the head
+    sees the chunk's last real row and the step rows, not the C. An active
+    row's next token replaces its entry of ids (sampled at position cursors
+    + 1) exactly as the step alone would have it, and the caller advances
+    its cursor by one. A row that is not active — the chunk's own slot
+    among them, whose cursor IS the chunk's first position — attends
+    nothing, is routed to no expert and writes to the garbage page, whatever
+    its tables hold: the chunk's positions see one write, the chunk's. A
+    model with a 'minicpm4' or 'lightning-attn' layer takes None.
+
+    ``state_slot``: the slot itself, whichever chunk this is — where the
+    model has 'lightning-attn' layers, their states of that slot are what
+    the chunk continues (from zero when ``cursor`` is 0) and leaves advanced
+    by ``real_len`` tokens.
 
     Caller contract (scheduler-enforced): every page covering the REAL
     tokens [cursor, cursor + real_len) is allocated and OWNED (write_row
     == read_row there); pad positions beyond real_len may fall on
     unallocated entries — their writes redirect to the garbage page and
-    their reads are causally masked. cursor + C fits the logical view.
+    their reads are causally masked. cursor + C fits the logical view. No
+    active step row is the chunk's slot.
 
     Returns (ids [slots], caches); with ``moe_info`` (mlp='moe') a third
-    value, the expert layers' ``{"counts": [L, E], "routes": [L, 1, C,
-    k]}`` (the chunk's padding past ``real_len`` is routed nowhere and not
-    counted); with ``logits`` the float32-castable logits [vocab] at the
-    last REAL token come next (tests compare them with an oracle; the
-    scheduler never asks); with ``selected`` the blocks the 'minicpm4'
-    layers chose, bool [layers of the kind, 1, C, Hkv, NB], last."""
+    value, the expert layers' ``{"counts": [L, 2, E], "routes": [L, 1, rows,
+    k]}``: the rows the experts received from the chunk and from the step
+    (the kernel ran once a layer over both; without ``step`` [L, E]), rows
+    the C of the chunk and then the step's (the chunk's padding past
+    ``real_len`` and the rows not active are routed nowhere and not
+    counted); with ``logits`` the float32-castable logits the ids were
+    sampled from come next, [vocab] at the chunk's last REAL token, and
+    with ``step`` [1 + slots, vocab]: that row, then the step's (tests
+    compare them with an oracle; the scheduler never asks); with
+    ``selected`` the blocks the 'minicpm4' layers chose, bool [layers of
+    the kind, 1, C, Hkv, NB], last."""
     _check_moe_info(cfg, moe_info)
     if cfg.recurrent and state_slot is None:
         raise ValueError("a model with 'lightning-attn' layers needs "
                          "state_slot: the slot whose states the chunk "
                          "continues")
     taps = [] if selected else None
-    steps = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
-    all_logits, new_caches, moe = _paged_forward_inplace(
-        cfg, params, tokens, steps + cursor, jnp.reshape(cursor, (1,)),
-        read_row[None], write_row[None], caches, attn, steps < real_len,
+    C = tokens.shape[1]
+    steps = jnp.arange(C, dtype=jnp.int32)[None, :]
+    groups = [_Rows(tokens, steps + cursor, jnp.reshape(cursor, (1,)),
+                    read_row[None], write_row[None], steps < real_len)]
+    sample = (jnp.reshape(temperature, (1,)), jnp.reshape(seed, (1,)),
+              jnp.reshape(cursor + real_len, (1,)))
+    if step is not None:
+        live = step.active > 0
+        groups.append(_Rows(
+            ids[:, None], step.cursors[:, None],
+            jnp.where(live, step.cursors, -1), step.read_tables,
+            jnp.where(live[:, None], step.write_tables, 0), live[:, None]))
+        sample = tuple(jnp.concatenate(pair) for pair in zip(sample, (
+            jnp.where(live, step.temperature, 0.0), step.seeds,
+            step.cursors + 1)))
+    hidden, new_caches, moe = _paged_forward_inplace(
+        cfg, params, groups, caches, attn,
         slot=0 if state_slot is None else state_slot, real_len=real_len,
         taps=taps)
-    last = lax.dynamic_index_in_dim(all_logits[0], real_len - 1,
-                                    keepdims=False)
-    first = sample_token(last[None], jnp.reshape(temperature, (1,)),
-                         jnp.reshape(seed, (1,)),
-                         jnp.reshape(cursor + real_len, (1,)))[0]
-    ids = jnp.where(jnp.arange(ids.shape[0]) == slot, first, ids)
+    # the rows that are sampled: the chunk's last real one, then the step's
+    last = lax.dynamic_slice_in_dim(hidden, real_len - 1, 1, axis=1)
+    sampled_logits = _head(cfg, params, jnp.concatenate(
+        [last, hidden[:, C:]], axis=1))[0]
+    sampled = sample_token(sampled_logits, *sample)
+    if step is not None:
+        ids = jnp.where(live, sampled[1:], ids)
+    ids = jnp.where(jnp.arange(ids.shape[0]) == slot, sampled[0], ids)
+    shown = sampled_logits[0] if step is None else sampled_logits
     return _paged_outputs(ids, new_caches, moe, moe_info,
-                          last if logits else None, taps)
+                          shown if logits else None, taps)
 
 
 def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
@@ -567,17 +693,18 @@ def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
     last."""
     _check_moe_info(cfg, moe_info)
     taps = [] if selected else None
-    all_logits, new_caches, moe = _paged_forward_inplace(
-        cfg, params, tokens[:, None], cursors[:, None],
-        jnp.where(active > 0, cursors, -1),
-        read_tables, write_tables, caches, attn, active[:, None] > 0,
-        active=active, taps=taps)
-    sampled = sample_token(all_logits[:, 0],
+    hidden, new_caches, moe = _paged_forward_inplace(
+        cfg, params, [_Rows(tokens[:, None], cursors[:, None],
+                            jnp.where(active > 0, cursors, -1), read_tables,
+                            write_tables, active[:, None] > 0)],
+        caches, attn, active=active, taps=taps)
+    all_logits = _head(cfg, params, hidden)[:, 0]
+    sampled = sample_token(all_logits,
                            jnp.where(active > 0, temperature, 0.0), seeds,
                            cursors + 1)
     ids = jnp.where(active > 0, sampled, tokens)
     return _paged_outputs(ids, new_caches, moe, moe_info,
-                          all_logits[:, 0] if logits else None, taps)
+                          all_logits if logits else None, taps)
 
 
 def paged_verify_step(cfg: TransformerConfig, params, tokens, active,
@@ -618,11 +745,13 @@ def paged_verify_step(cfg: TransformerConfig, params, tokens, active,
             "and no snapshot is kept")
     K = tokens.shape[1]
     steps = jnp.arange(K, dtype=jnp.int32)[None]
-    logits, new_caches, moe = _paged_forward_inplace(
-        cfg, params, tokens, cursors[:, None] + steps,
-        jnp.where(active > 0, cursors, -K),
-        read_tables, write_tables, caches, attn, steps < active[:, None])
-    return _paged_outputs(logits, new_caches, moe, moe_info, None)
+    hidden, new_caches, moe = _paged_forward_inplace(
+        cfg, params, [_Rows(tokens, cursors[:, None] + steps,
+                            jnp.where(active > 0, cursors, -K), read_tables,
+                            write_tables, steps < active[:, None])],
+        caches, attn)
+    return _paged_outputs(_head(cfg, params, hidden), new_caches, moe,
+                          moe_info, None)
 
 
 @partial(jax.jit, static_argnums=(0, 4, 5, 6))

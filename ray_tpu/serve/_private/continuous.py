@@ -22,18 +22,34 @@ thread — the replica's asyncio event loop only ever touches queues.
 The loop runs ONE STEP AHEAD of what it has read. Both programs sample
 (``decode.sample_token``: argmax at temperature 0, else a draw keyed by the
 request's seed and the token's position) and hand the ids on in a device
-vector ``[slots]`` that the next step takes as its tokens, so a token never
-visits the host on its way to the step that consumes it. A turn is: admit;
-dispatch a chunk if one is due; prepare and dispatch step n+1 from what the
-host knows without step n's result (cursors advance by one a live row; a
-row whose budget step n exhausts is not in n+1); THEN wait for step n's ids
-(4 bytes a slot), emit and retire. What the host learns only from an id —
-EOS — and what it learns between turns — a cancellation, an exhausted
-pool — therefore arrives one step late: the row may ride in one more step,
+vector ``[slots]`` that the next program takes as its tokens, so a token
+never visits the host on its way to the step that consumes it. A turn is:
+admit; pick the chunk that is due, if one is; build the decode rows of step
+n+1 from what the host knows without step n's result (cursors advance by
+one a live row; a row whose budget step n exhausts is not in n+1); dispatch
+ONE program; THEN wait for the ids of the program of the turn before (4
+bytes a slot), emit and retire. What the host learns only from an id — EOS
+— and what it learns between turns — a cancellation, an exhausted pool —
+therefore arrives one step late: the row may ride in one more program,
 whose id is discarded (``discarded_rows``) and never emitted
 (``_release_slot_resources`` says why its K/V write harms nobody). The
 speculative path needs whole logits on the host to accept and resample, so
 it reads before every dispatch.
+
+A TURN READS THE WEIGHTS ONCE. Where there is a chunk the one program is the
+chunk's (``paged_prefill_chunk``), and it takes the turn's decode rows
+along: chunk rows and step rows are one batch through every projection and
+the MLP or expert layer, and only attention is called a group, at the
+chunk's shape and at the step's (``decode.paged_prefill_into_slot``). Where
+there is none it is the plain step (``paged_decode_step``). Such a program
+counts for what it carried: in ``prefill_chunks`` and ``prefill_tokens``,
+and, if a row was live, in ``decode_steps`` too (``fused_turns`` counts
+those, ``fused_step_rows`` their rows). A prompt whose last chunk rides in
+it decodes from the NEXT turn on: its first token exists only at this
+program's end. Which models: those all of whose layers hold pages through
+``ops.paged_attention`` (``decode.step_rides_chunk``, read off
+``cfg.kinds``); a model with a layer of another kind keeps two programs a
+turn with a chunk, the chunk alone and then the step.
 
 The scheduler measures the gap it makes. A sampled token becomes an emitted
 one at the READ of its program (``_collect``; a speculative round's tokens
@@ -44,8 +60,8 @@ the device was given in between: the scheduler keeps the prompt tokens it
 has dispatched (``prefill_tokens``), notes the count on every launch, and a
 gap is beside prefill if the count rose between the two programs that
 sampled the two tokens (``gap_prefill_*``), else plain (``gap_plain_*``).
-By work, never by a program's name: one program that carries prompt rows
-and decode rows counts the same way.
+By work, never by a program's name: the program that carries prompt rows
+and decode rows counts the same way as the two it replaced.
 
 There is one KV layout and one path to the kernel. A slot owns a page table
 instead of a contiguous worst-case ``arena_len`` range, so long/idle
@@ -119,10 +135,10 @@ from ray_tpu._private.metrics import Counter, Gauge, Histogram
 # transitions of a loop turn do not grow with the number of slots.
 PHASES = (
     "serve.admit",           # commands, migrations, admission, slot reset
-    "serve.prefill",         # build, upload and dispatch of one chunk
-    "serve.prefill.wait",    # the device is working on a chunk read from
-    "serve.decode.prepare",  # arrays, pages, table upload, dispatch
-    "serve.decode.wait",     # the device is working on the step read from
+    "serve.prefill",         # one chunk picked and built (dispatched, alone)
+    "serve.prefill.wait",    # the device works on the chunk's program read
+    "serve.decode.prepare",  # the rows' arrays, pages, tables; the dispatch
+    "serve.decode.wait",     # the device works on the plain step read from
     "serve.decode.fetch",    # a program's ids (4 bytes a slot) to the host
     "serve.sample",          # whose token is whose; the rows to discard
     "serve.emit",            # hand-off to the consumers' event loop, retire
@@ -278,19 +294,37 @@ def _deliver(batch) -> None:
 class _Launched:
     """One dispatched program whose result the host has not read yet."""
 
-    __slots__ = ("serial", "prefill_mark", "step", "ids", "rows", "moe",
-                 "live_rows")
+    __slots__ = ("serial", "prefill_mark", "step", "chunk", "ids", "rows",
+                 "moe", "live_rows")
 
-    def __init__(self, serial: int, prefill_mark: int, step: bool, ids,
-                 rows: List[_Seq], moe, live_rows: int):
+    def __init__(self, serial: int, prefill_mark: int, step: bool,
+                 chunk: bool, ids, rows: List[_Seq], moe, live_rows: int):
         self.serial = serial        # its number among the dispatched programs
         # prompt tokens dispatched so far, this program's own among them
         self.prefill_mark = prefill_mark
-        self.step = step            # a decode step (else a prefill chunk)
+        self.step = step            # it advanced decode rows
+        self.chunk = chunk          # it carried a prefill chunk (or both)
         self.ids = ids              # the program's [slots] ids, on the device
-        self.rows = rows            # the sequences it sampled a token for
+        # the sequences it sampled a token for: the decode rows and, behind
+        # them, the prompt whose last chunk it carried
+        self.rows = rows
         self.moe = moe              # an expert model's counts, on the device
         self.live_rows = live_rows  # the live rows the host handed it
+
+
+class _LiveRows:
+    """The decode rows of one turn, as the host built them: the sequences
+    that take a token (``live``) and the step's arrays over ``[slots]``."""
+
+    __slots__ = ("active", "temperature", "seeds", "live")
+
+    def __init__(self, slots: int):
+        import numpy as np
+
+        self.active = np.zeros(slots, np.int32)
+        self.temperature = np.zeros(slots, np.float32)
+        self.seeds = np.zeros(slots, np.uint32)
+        self.live: List[_Seq] = []
 
 
 class ContinuousScheduler:
@@ -299,9 +333,11 @@ class ContinuousScheduler:
     ``params`` are the (device-resident) model parameters shared by every
     program; the scheduler owns the page pool and two jitted programs —
     a prefill chunk (``paged_prefill_into_slot``, one compiled shape:
-    [1, prefill_chunk]) and a decode step (``paged_decode_step``, [slots])
-    — both with donated caches so the pool updates in place instead of
-    being copied per iteration. It also owns every slot's page table and
+    [1, prefill_chunk], with the [slots] decode rows along where the
+    model's layer kinds allow) and a decode step (``paged_decode_step``,
+    [slots]), which runs the turns that hold no chunk — both with donated
+    caches so the pool updates in place instead of being copied per
+    iteration. It also owns every slot's page table and
     cursor and passes them with each call; the sampled ids stay on the
     device (``_ids``, never donated: the host reads each vector one program
     later). ``attn``: the paged-attention
@@ -328,7 +364,8 @@ class ContinuousScheduler:
         from ray_tpu.models.decode import (init_paged_caches,
                                            paged_decode_step,
                                            paged_prefill_into_slot,
-                                           paged_verify_step)
+                                           paged_verify_step,
+                                           step_rides_chunk)
         from ray_tpu.models.transformer import LINEAR, SPARSE
         from ray_tpu.ops.paged_attention import resolve_impl
         from ray_tpu.serve._private.paging import PageArena, RadixCache
@@ -411,6 +448,11 @@ class ContinuousScheduler:
         program_kw = {"attn": self.attn_lane}
         if self._moe:
             program_kw["moe_info"] = True
+        # a chunk's program takes the turn's decode rows along where every
+        # layer holds pages through the paged kernel: read off the layer
+        # kinds, the one thing that decides it
+        self._fused = step_rides_chunk(cfg)
+        self._no_rows = _LiveRows(self.slots)  # for a chunk that takes none
         # donated caches: the pool mutates in place across iterations;
         # the tables are tiny per-call host->device uploads
         self._prefill = jax.jit(
@@ -529,6 +571,10 @@ class ContinuousScheduler:
         self._n_gap_prefill = 0
         self._gap_prefill_ns = 0
         self._n_turns = 0  # loop turns that dispatched or read a program
+        # chunk programs that carried at least one live decode row, and the
+        # rows they carried
+        self._n_fused_turns = 0
+        self._n_fused_step_rows = 0
         self._n_admitted = 0
         self._n_retired = 0
         self._n_tokens = 0
@@ -988,38 +1034,47 @@ class ContinuousScheduler:
         sequence's ``cursor``, 0 for a free slot. The host's count is the
         only one; the device keeps none to reset or read back.
 
-        The rows the call marks inactive rely on this: such a row attends
-        nothing (the program masks it by ``active``) but still writes at
-        its cursor. A PREFILLING slot passes its true cursor, so the write
-        lands on the position its next chunk writes before anything
-        attends it (or, page not yet allocated, on the garbage page); a
-        FREE slot's table rows are zero, so whatever it passes lands on
-        the garbage page."""
+        The rows the plain step and the verify call mark inactive rely on
+        this: such a row attends nothing (the program masks it by
+        ``active``) but still writes at its cursor. A PREFILLING slot
+        passes its true cursor, so the write lands on the position its next
+        chunk writes before anything attends it (or, page not yet
+        allocated, on the garbage page); a FREE slot's table rows are zero,
+        so whatever it passes lands on the garbage page. The chunk's
+        program, where the prefilling slot's cursor IS a position the same
+        scatter writes, relies on nothing: it sends every row that is not
+        active to the garbage page itself."""
         import numpy as np
 
         return np.fromiter((0 if s is None else s.cursor
                             for s in self._slot_seqs), np.int32, self.slots)
 
-    def _launch(self, out, *, step: bool, rows: List[_Seq],
+    def _launch(self, out, *, step: bool, chunk: bool, rows: List[_Seq],
                 live_rows: int) -> None:
         """Take over what a paged program returned: the ids stay on the
         device for the next program, the pool is the next program's, and
         what the host has to read of it later (ids, an expert model's
-        counts) is queued for ``_collect``."""
+        counts) is queued for ``_collect``: one record a program."""
         self._ids, self._caches = out[0], out[1]
         self._serial += 1
         moe = out[2]["counts"] if self._moe else None
         if rows or moe is not None:
             self._inflight.append(_Launched(
-                self._serial, self._n_prefill_tokens, step, out[0], rows,
-                moe, live_rows))
+                self._serial, self._n_prefill_tokens, step, chunk, out[0],
+                rows, moe, live_rows))
 
-    def _moe_count(self, counts, live_rows: int) -> None:
+    def _moe_count(self, counts, live_rows: int, step: bool = True) -> None:
         """Add up one finished program's expert counts (call after a wait
-        on that program: the copy below then waits for nothing)."""
+        on that program: the copy below then waits for nothing). A chunk's
+        program that took the step along tells the two groups' rows apart
+        ([layers, 2, experts]): each is a layer-call of its own, as when
+        they were two programs, the step's only where a row was live
+        (``step``)."""
         import numpy as np
 
         c = np.asarray(counts)  # [layers, experts]
+        if c.ndim == 3:
+            c = c[:, :1 + step].reshape(-1, c.shape[-1])
         self._n_moe_live_rows += live_rows
         self._n_moe_layer_calls += c.shape[0]
         self._n_moe_rows_routed += int(c.sum())
@@ -1044,12 +1099,12 @@ class ContinuousScheduler:
             flight.instant(_F_DRAIN, n)
         for _ in range(n):
             rec = self._inflight.popleft()
-            switch(_P_WAIT if rec.step else _P_PREFILL_WAIT)
+            switch(_P_PREFILL_WAIT if rec.chunk else _P_WAIT)
             self._jax.block_until_ready(rec.ids)
             switch(_P_FETCH)
             ids = np.asarray(rec.ids)
             if rec.moe is not None:
-                self._moe_count(rec.moe, rec.live_rows)
+                self._moe_count(rec.moe, rec.live_rows, rec.step)
             if rec.step:
                 self._steps_unread -= 1
             if not rec.rows:
@@ -1074,26 +1129,22 @@ class ContinuousScheduler:
                 self._end_read()
         return True
 
-    def _prefill_one(self) -> bool:
-        """Advance ONE prefilling sequence by one chunk, round-robin over
+    def _next_chunk(self):
+        """Pick ONE prefilling sequence's next chunk, round-robin over
         slots — concurrent prompts interleave their chunks, so one long
         prompt cannot monopolize prefill (and decode never waits more than
-        one chunk). The chunk is dispatched and not waited for: a prompt's
-        last chunk samples the first token into ``_ids`` on the device, the
-        sequence decodes from the next step on, and the host reads the
-        token with the other ids (``_collect``). Returns True if a chunk
-        was dispatched."""
-        import jax.numpy as jnp
+        one chunk) — and see to its pages. Nothing is dispatched here.
+        Returns (the sequence, the chunk's tokens [1, prefill_chunk], how
+        many of them are real), or None where no prompt is pending."""
         import numpy as np
 
-        switch = self._clock.switch
         start = self._prefill_rr
         for off in range(self.slots):
             i = (start + off) % self.slots
             seq = self._slot_seqs[i]
             if seq is None or seq.state != _PREFILL:
                 continue
-            switch(_P_PREFILL)
+            self._clock.switch(_P_PREFILL)
             self._prefill_rr = (i + 1) % self.slots
             if seq.cancelled:
                 self._retire(seq, "cancelled")
@@ -1109,42 +1160,71 @@ class ContinuousScheduler:
                 continue  # failed cleanly; other slots keep running
             chunk = seq.remaining_prompt[:self.prefill_chunk]
             seq.remaining_prompt = seq.remaining_prompt[self.prefill_chunk:]
-            real = len(chunk)
-            padded = chunk + [0] * (self.prefill_chunk - real)
             # NumPy, uploaded with the call: jnp.asarray of a list with a
             # dtype would run an eager convert program of its own a chunk
-            tokens = np.asarray([padded], np.int32)
-            # the rows are uploaded as COPIES: dispatch is async and an
-            # upload may alias (CPU) or still be reading (TPU) the host
-            # buffer, while _offer_prompt_pages and _ensure_pages write
-            # to these rows before anything waits for this chunk
-            last = not seq.remaining_prompt
-            self._n_prefill_tokens += real
-            self._launch(self._prefill(
-                self.params, tokens, np.int32(real), np.int32(seq.cursor),
-                jnp.asarray(self._read_tables[seq.slot].copy()),
-                jnp.asarray(self._write_tables[seq.slot].copy()),
-                self._caches, self._ids, np.int32(seq.slot if last else -1),
-                np.float32(seq.temperature), np.uint32(seq.seed),
-                np.int32(seq.slot)),
-                step=False, rows=[seq] if last else [], live_rows=real)
-            seq.cursor += real
-            # dispatch is async and stays so: the chunk's device time is
-            # read from a profiler trace by the program's name, and the
-            # wait for it falls into the phase that reads its result
-            self._record_attn(self.prefill_chunk, [seq.cursor - real],
-                              real=real)
-            self._n_prefill_chunks += 1
-            _m_prefill_chunks.inc()
-            if last:
-                # prompt fully resident and its first token on the way:
-                # the sequence rides in the next decode step already
-                if self._radix is not None:
-                    self._offer_prompt_pages(seq)
-                seq.state = _DECODE
-                seq.n_launched = 1
-            return True
-        return False
+            return seq, np.asarray(
+                [chunk + [0] * (self.prefill_chunk - len(chunk))],
+                np.int32), len(chunk)
+        return None
+
+    def _dispatch_chunk(self, seq: _Seq, tokens, real: int,
+                        rows: Optional[_LiveRows]) -> None:
+        """Dispatch the program of the chunk ``_next_chunk`` picked. Where
+        every layer of the model holds pages (``_fused``) it takes the decode
+        step along, over ONE read of the weights: ``rows``, the turn's live
+        decode rows (``_step_rows``), or with None no row active (the
+        speculative loop, whose rows go through the verify program).
+        Elsewhere the chunk goes alone and the step is the next program.
+
+        Nothing is waited for: a prompt's last chunk samples the first token
+        into ``_ids`` on the device, the sequence joins the decode rows of
+        the NEXT program, and the host reads the token with the other ids
+        (``_collect``). The program's device time is read from a profiler
+        trace by its name, and the wait for it falls into the phase that
+        reads its result."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from ray_tpu.models.decode import StepRows
+
+        last = not seq.remaining_prompt
+        live = rows.live if rows is not None else []
+        step = None
+        if self._fused:
+            r = rows or self._no_rows
+            step = StepRows(r.active, self._cursors(),
+                            self._read_tables.copy(),
+                            self._write_tables.copy(), r.temperature, r.seeds)
+        self._n_prefill_tokens += real
+        # the rows are uploaded as COPIES: dispatch is async and an
+        # upload may alias (CPU) or still be reading (TPU) the host
+        # buffer, while _offer_prompt_pages and _ensure_pages write
+        # to these rows before anything waits for this chunk
+        self._launch(self._prefill(
+            self.params, tokens, np.int32(real), np.int32(seq.cursor),
+            jnp.asarray(self._read_tables[seq.slot].copy()),
+            jnp.asarray(self._write_tables[seq.slot].copy()),
+            self._caches, self._ids, np.int32(seq.slot if last else -1),
+            np.float32(seq.temperature), np.uint32(seq.seed), step,
+            np.int32(seq.slot)),
+            step=bool(live), chunk=True,
+            rows=live + [seq] if last else live, live_rows=real + len(live))
+        self._record_attn(self.prefill_chunk, [seq.cursor], real=real)
+        seq.cursor += real
+        self._n_prefill_chunks += 1
+        _m_prefill_chunks.inc()
+        if step is not None:
+            self._stepped(live)
+        if live:
+            self._n_fused_turns += 1
+            self._n_fused_step_rows += len(live)
+        if last:
+            # prompt fully resident and its first token on the way: the
+            # sequence rides in the next program's decode rows already
+            if self._radix is not None:
+                self._offer_prompt_pages(seq)
+            seq.state = _DECODE
+            seq.n_launched = 1
 
     def _offer_prompt_pages(self, seq: _Seq) -> None:
         """Prompt fully resident: offer its full pages to the radix cache
@@ -1578,20 +1658,12 @@ class ContinuousScheduler:
         self._drafter.set_lengths(dlen)
         return True
 
-    def _decode_once(self, behind: int) -> bool:
-        """One turn of the decode loop, one step ahead: prepare and
-        dispatch the next step over every DECODE slot from what the host
-        knows without the previous step's result, THEN read the ``behind``
-        programs dispatched in earlier turns (``_collect``). The step's
-        tokens are ``_ids``, on the device since the programs that sampled
-        them. Returns True if a step was dispatched or a result read."""
-        import numpy as np
-
+    def _step_rows(self) -> _LiveRows:
+        """The turn's decode rows, from what the host knows without the
+        previous program's result: every DECODE slot that still has a token
+        to take and a page to write it on."""
         self._clock.switch(_P_PREPARE)
-        active = np.zeros(self.slots, np.int32)
-        temperature = np.zeros(self.slots, np.float32)
-        seeds = np.zeros(self.slots, np.uint32)
-        live: List[_Seq] = []
+        rows = _LiveRows(self.slots)
         for i, seq in enumerate(self._slot_seqs):
             if seq is None or seq.state != _DECODE:
                 continue
@@ -1602,30 +1674,59 @@ class ContinuousScheduler:
                 continue  # its last token is in flight: nothing to add
             if not self._ensure_pages(seq, seq.cursor + 1):
                 continue  # this sequence failed cleanly; others continue
-            active[i] = 1
-            temperature[i] = seq.temperature
-            seeds[i] = seq.seed
-            live.append(seq)
-        if live:
-            if self._steps_unread:
-                self._n_runahead += 1
+            rows.active[i] = 1
+            rows.temperature[i] = seq.temperature
+            rows.seeds[i] = seq.seed
+            rows.live.append(seq)
+        return rows
+
+    def _stepped(self, live: List[_Seq]) -> None:
+        """Behind the dispatch of a program that ran the step's attention
+        call over ``live`` (the plain step, or a chunk's program that took
+        the rows along): count the call, and move the live rows on by the
+        token that is now on its way."""
+        self._record_attn(1, [s.cursor for s in live], self.slots - len(live))
+        if not live:
+            return
+        if self._steps_unread:
+            self._n_runahead += 1
+        self._steps_unread += 1
+        for seq in live:
+            seq.cursor += 1
+            seq.n_launched += 1
+        self._n_steps += 1
+        _m_steps.inc()
+        self._max_active_slots = max(self._max_active_slots, len(live))
+
+    def _turn(self, behind: int) -> bool:
+        """One turn of the loop, one step ahead: pick the chunk that is due,
+        build the decode rows, dispatch ONE program — the chunk's, with the
+        rows, where there is a chunk, else the plain step over the rows —
+        and THEN read the ``behind`` programs dispatched in earlier turns
+        (``_collect``). The rows' tokens are ``_ids``, on the device since
+        the programs that sampled them. A model with a layer that holds no
+        pages, or chooses among them, keeps two programs a turn with a
+        chunk: the chunk alone, then the step (in which a prompt that the
+        chunk ended already rides). Returns True if a program was dispatched
+        or a result read."""
+        chunk = self._next_chunk()
+        if chunk is not None and not self._fused:
+            self._dispatch_chunk(*chunk, None)
+        rows = self._step_rows()
+        if chunk is not None and self._fused:
+            self._dispatch_chunk(*chunk, rows)
+        elif rows.live:
             # the tables go up as COPIES: the host frees and hands out
-            # pages while this step is in flight (see _prefill_one)
+            # pages while this step is in flight (see _dispatch_chunk)
             self._launch(self._step(
-                self.params, self._ids, active, self._cursors(),
+                self.params, self._ids, rows.active, self._cursors(),
                 self._read_tables.copy(), self._write_tables.copy(),
-                self._caches, temperature, seeds),
-                step=True, rows=live, live_rows=len(live))
-            self._steps_unread += 1
-            self._record_attn(1, [s.cursor for s in live],
-                              self.slots - len(live))
-            for seq in live:
-                seq.cursor += 1
-                seq.n_launched += 1
-            self._n_steps += 1
-            _m_steps.inc()
-            self._max_active_slots = max(self._max_active_slots, len(live))
-        return self._collect(behind) or bool(live)
+                self._caches, rows.temperature, rows.seeds),
+                step=True, chunk=False, rows=rows.live,
+                live_rows=len(rows.live))
+            self._stepped(rows.live)
+        return (self._collect(behind) or chunk is not None
+                or bool(rows.live))
 
     def _run(self) -> None:
         clock = self._clock
@@ -1646,11 +1747,13 @@ class ContinuousScheduler:
                 self._start_migrations()
                 self._admit()
                 behind = len(self._inflight)
-                did = self._prefill_one()
                 if self._drafter is not None:
-                    did = self._decode_spec() or did
+                    chunk = self._next_chunk()
+                    if chunk is not None:
+                        self._dispatch_chunk(*chunk, None)
+                    did = self._decode_spec() or chunk is not None
                 else:
-                    did = self._decode_once(behind) or did
+                    did = self._turn(behind)
                 _m_active.set(float(sum(
                     1 for s in self._slot_seqs if s is not None)))
                 if did:
@@ -1743,10 +1846,12 @@ class ContinuousScheduler:
         """Total compiled program count across the scheduler's jitted
         entry points — the two-compiles contract says this is exactly 2
         (one prefill shape + one decode shape) no matter how lengths,
-        pages and prefix hits churn; speculative decoding adds the verify
-        program as the only new shape (and the plain decode step, never
-        driven in spec mode, stays uncompiled — the total remains 2; the
-        drafter's own programs are reported separately in stats)."""
+        pages and prefix hits churn, and whether or not a chunk takes decode
+        rows along (it always takes the step's arrays, none active where
+        none is live); speculative decoding adds the verify program as the
+        only new shape (and the plain decode step, never driven in spec
+        mode, stays uncompiled — the total remains 2; the drafter's own
+        programs are reported separately in stats)."""
         n = self._prefill._cache_size() + self._step._cache_size()
         if self._verify is not None:
             n += self._verify._cache_size()
@@ -1768,6 +1873,12 @@ class ContinuousScheduler:
             # turns that dispatched or read a program
             "prefill_tokens": self._n_prefill_tokens,
             "turns": self._n_turns,
+            # chunk programs that took live decode rows along (they count in
+            # prefill_chunks AND in decode_steps), and those rows;
+            # fused_turns / prefill_chunks is how often a turn with a chunk
+            # read the weights once. 0 for a model whose turn is two programs
+            "fused_turns": self._n_fused_turns,
+            "fused_step_rows": self._n_fused_step_rows,
             "admitted": self._n_admitted,
             "retired": self._n_retired,
             "tokens_generated": tokens,

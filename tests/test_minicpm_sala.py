@@ -193,7 +193,7 @@ def chunks_into(cfg, params, prompt, slot, caches, tables, chunk, slots,
             cfg, params, jnp.asarray(padded), np.int32(len(part)),
             np.int32(c0),
             tables[slot], tables[slot], caches, jnp.zeros(slots, jnp.int32),
-            np.int32(-1), np.float32(0), np.uint32(0), np.int32(slot),
+            np.int32(-1), np.float32(0), np.uint32(0), None, np.int32(slot),
             attn="reference", logits=True)
         if between is not None and c0 + chunk < len(prompt):
             caches = between(caches, c0 + len(part))
@@ -387,6 +387,8 @@ def test_scheduler_serves_both_kinds_and_a_reused_slot_starts_from_zero(toy):
         want = ref.forward(params, seq, hp_of(cfg))[0][len(prompt) - 1:]
         for logits, tok in zip(want, out):
             assert logits.max() - logits[tok] <= TOL * np.abs(want).max()
+    # layers of other kinds: a chunk goes alone, the step is its own program
+    assert stats["fused_turns"] == stats["fused_step_rows"] == 0
     assert stats["state_slots"] == 2
     assert stats["state_bytes"] == 6 * 2 * 4 * 16 * 16 * 4
     assert stats["linear_chunk_calls"] == 6 * stats["prefill_chunks"]
@@ -450,7 +452,8 @@ def test_what_cannot_continue_a_state_is_refused(toy):
         paged_prefill_into_slot(
             cfg, params, jnp.zeros((1, 32), jnp.int32), 32, np.int32(0),
             tables[0], tables[0], caches, jnp.zeros(2, jnp.int32),
-            np.int32(-1), np.float32(0), np.uint32(0), attn="reference")
+            np.int32(-1), np.float32(0), np.uint32(0), None,
+            attn="reference")
     sched = ContinuousScheduler(cfg, params, **kw)  # the default: cache off
     try:
         assert "radix_nodes" not in sched.stats()

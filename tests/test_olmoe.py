@@ -22,7 +22,7 @@ import pytest
 from perfbench.reference import olmoe as ref
 from ray_tpu.models import (forward, init_params, llama_debug, loss_fn,
                             moe_debug)
-from ray_tpu.models.decode import (decode_step, init_caches,
+from ray_tpu.models.decode import (StepRows, decode_step, init_caches,
                                    init_paged_caches, paged_decode_step,
                                    paged_prefill_into_slot,
                                    paged_verify_step, prefill)
@@ -184,6 +184,11 @@ def paged_run(toy):
     got = {s: [] for s in (1, 2)}
     taken = {s: [] for s in (1, 2)}
     counted = live = 0
+    # the step's rows a chunk's program takes along: none decodes yet, so
+    # none is active, none is routed to an expert and none is counted
+    idle = StepRows(np.zeros(slots, np.int32), np.zeros(slots, np.int32),
+                    tables, tables, np.zeros(slots, np.float32),
+                    np.zeros(slots, np.uint32))
     with jax.default_matmul_precision("highest"):
         for b, s in enumerate((1, 2)):
             prompt = np.asarray(tokens[b, :n[b]])
@@ -196,12 +201,13 @@ def paged_run(toy):
                     cfg, params, jnp.asarray(padded), real, np.int32(c0),
                     tables[s], tables[s], caches,
                     jnp.zeros(slots, jnp.int32), np.int32(-1),
-                    np.float32(0), np.uint32(0), attn="reference",
+                    np.float32(0), np.uint32(0), idle, attn="reference",
                     moe_info=True, logits=True)
+                assert moe["routes"].shape[2] == C + slots
                 taken[s].append(np.asarray(moe["routes"])[:, 0, :real])
                 counted += int(moe["counts"].sum())
                 live += real
-            got[s].append(logits)
+            got[s].append(logits[0])  # behind it the idle step rows'
         active = jnp.asarray([0, 1, 1, 0], jnp.int32)
         cursors = np.asarray([0, n[0], n[1], 0], np.int32)
         for step in range(6):

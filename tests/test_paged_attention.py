@@ -331,13 +331,18 @@ def _paged_prefill(cfg, params, attn, prompts, T=4, P=8):
     cursors are the caller's, the pool keeps none."""
     from functools import partial
 
-    from ray_tpu.models.decode import paged_prefill_into_slot
+    from ray_tpu.models.decode import StepRows, paged_prefill_into_slot
 
-    caches, tables = _arena(cfg, len(prompts), T, P)
+    S = len(prompts)
+    caches, tables = _arena(cfg, S, T, P)
     prefill = jax.jit(partial(paged_prefill_into_slot, cfg, attn=attn,
                               logits=True))
     lasts = []
-    first = jnp.zeros(len(prompts), jnp.int32)  # the programs' own ids
+    first = jnp.zeros(S, jnp.int32)  # the programs' own ids
+    # the step's rows the chunk's program takes along: none decodes yet,
+    # and a row that is not active leaves the pool and the ids alone
+    cursors = np.zeros(S, np.int32)
+    idle = (np.zeros(S, np.float32), np.zeros(S, np.uint32))
     for s, ids in enumerate(prompts):
         for at in range(0, len(ids), CHUNK):
             chunk = list(ids[at:at + CHUNK])
@@ -347,12 +352,14 @@ def _paged_prefill(cfg, params, attn, prompts, T=4, P=8):
                 params, jnp.asarray([padded], jnp.int32),
                 np.int32(len(chunk)), np.int32(at), tables[s], tables[s],
                 caches, first, np.int32(s if ends else -1), np.float32(0),
-                np.uint32(0))
-        lasts.append(np.asarray(last))
+                np.uint32(0), StepRows(np.zeros(S, np.int32), cursors,
+                                       tables, tables, *idle))
+            cursors[s] = at + len(chunk)
+        lasts.append(np.asarray(last)[0])  # then the step's rows', unused
     # temperature 0: the id a prompt's last chunk left in its row is the
     # argmax of the logits it returned, and no other chunk touched the row
     assert np.array_equal(np.asarray(first), np.stack(lasts).argmax(-1))
-    cursors = np.asarray([len(ids) for ids in prompts], np.int32)
+    assert cursors.tolist() == [len(ids) for ids in prompts]
     return np.stack(lasts), caches, tables, cursors
 
 
@@ -514,7 +521,7 @@ class TestInPlaceLanes:
             args = (jnp.zeros((1, CHUNK), jnp.int32), np.int32(3),
                     np.int32(0), tables[0], tables[0], caches,
                     jnp.zeros(2, jnp.int32), np.int32(0), np.float32(0),
-                    np.uint32(0))
+                    np.uint32(0), None)
         else:
             k = (2, 3) if program == "paged_verify_step" else (2,)
             args = (jnp.zeros(k, jnp.int32), jnp.ones(2, jnp.int32),
@@ -532,7 +539,7 @@ class TestInPlaceLanes:
         (at the parent it ran the gathered-view lane, silently)."""
         import ray_tpu.models.decode as decode
 
-        positional = {"paged_prefill_into_slot": 11, "paged_decode_step": 9,
+        positional = {"paged_prefill_into_slot": 12, "paged_decode_step": 9,
                       "paged_verify_step": 7}[program]
         with pytest.raises(TypeError, match="attn"):
             getattr(decode, program)(None, *([None] * positional))

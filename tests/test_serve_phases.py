@@ -189,10 +189,15 @@ def test_transitions_of_a_turn_do_not_grow_with_the_slots():
         n1 = len([e for e in flight.local_timeline()
                   if e.get("ph") == "X" and e["name"] == "serve.sample"])
         d = _delta(after, before, ("decode_steps", "prefill_chunks",
-                                   "tokens_generated", "first_tokens"))
+                                   "tokens_generated", "first_tokens",
+                                   "fused_turns"))
         assert d["tokens_generated"] == 24 > d["decode_steps"]
-        # one per decode step, one per prompt's last chunk
-        assert n1 - n0 == d["decode_steps"] + d["first_tokens"]
+        # one per program read that sampled for a row: a decode step, or a
+        # prompt's last chunk, which are ONE program where the chunk took
+        # the live rows along (ISSUE 40) and two reads where it went alone
+        assert d["first_tokens"] == 4 and 0 < d["fused_turns"]
+        assert (d["decode_steps"] < n1 - n0
+                <= d["decode_steps"] + d["first_tokens"])
     finally:
         srv.shutdown()
 
@@ -207,7 +212,7 @@ def test_phases_and_programs_are_named_in_a_profiler_trace(warm, tmp_path):
     _drive(warm, ["traced 4"])
     path = trace.stop(str(tmp_path))
     ran = _delta(warm.scheduler_stats(), before,
-                 ("decode_steps", "prefill_chunks"))
+                 ("decode_steps", "prefill_chunks", "fused_turns"))
     loaded = trace.load(path)
     host_names = {name for name, _, _ in loaded["host"]}
     for phase in continuous.PHASES[:9]:  # all but verify and migrate
@@ -215,7 +220,11 @@ def test_phases_and_programs_are_named_in_a_profiler_trace(warm, tmp_path):
     summary = trace.summarize(loaded)
     programs = summary["programs"]
     assert not [p for p in programs if "unknown" in p], programs
-    assert programs["jit_paged_decode_step"]["count"] == ran["decode_steps"]
+    # a chunk's program that took decode rows along is a decode step too,
+    # under the chunk's name: the plain step ran every other step
+    assert (programs["jit_paged_decode_step"]["count"]
+            == ran["decode_steps"] - ran["fused_turns"])
+    assert ran["fused_turns"] > 0
     assert (programs["jit_paged_prefill_chunk"]["count"]
             == ran["prefill_chunks"] == 5)
     labels = [label for label, _ in summary["breakdown"]["idle_gaps"]]
@@ -313,16 +322,22 @@ def test_a_run_ahead_window_holds_the_two_programs_and_nothing_else(
         path = trace.stop(str(tmp_path))
         ran = _delta(srv.scheduler_stats(), before,
                      ("decode_steps", "prefill_chunks", "runahead_steps",
-                      "pipeline_drains", "discarded_rows", "retired"))
+                      "pipeline_drains", "discarded_rows", "retired",
+                      "fused_turns"))
         programs = trace.summarize(trace.load(path))["programs"]
         assert sorted(programs) == ["jit_paged_decode_step",
                                     "jit_paged_prefill_chunk"], programs
         assert programs["jit_paged_decode_step"]["count"] == ran[
-            "decode_steps"]
+            "decode_steps"] - ran["fused_turns"]
+        assert programs["jit_paged_prefill_chunk"]["count"] == ran[
+            "prefill_chunks"] >= ran["fused_turns"] > 0
         assert srv._sched.compiled_programs() == 2
         assert ran["retired"] == 10 and ran["discarded_rows"] >= 1
+        # a step is ahead of the read before it unless it follows a drain
+        # or a chunk that went alone (no row was live to take along)
+        alone = ran["prefill_chunks"] - ran["fused_turns"]
         assert ran["runahead_steps"] >= ran["decode_steps"] - ran[
-            "pipeline_drains"] > 0
+            "pipeline_drains"] - alone > 0
     finally:
         srv.shutdown()
 
@@ -341,10 +356,13 @@ def test_a_drain_is_an_instant_and_a_count(warm):
     _drive(warm, ["alone"])
     d = _delta(warm.scheduler_stats(), before,
                ("pipeline_drains", "decode_steps", "runahead_steps",
-                "discarded_rows"))
+                "discarded_rows", "prefill_chunks", "fused_turns"))
     assert drains() - n0 == d["pipeline_drains"]
     assert 2 <= d["pipeline_drains"] <= 4  # one an emptied arena, about
-    assert d["runahead_steps"] >= d["decode_steps"] - d["pipeline_drains"]
+    # every step ahead but the one behind a drain or a chunk that went alone
+    assert d["runahead_steps"] >= (
+        d["decode_steps"] - d["pipeline_drains"]
+        - (d["prefill_chunks"] - d["fused_turns"]))
     assert d["discarded_rows"] == 0
 
 

@@ -125,11 +125,13 @@ def test_every_token_is_a_first_token_or_has_a_gap_of_one_kind(scripted):
     assert d["gap_plain_tokens"] == TOKENS - 2 - 5  # decode-only stretches
 
 
-def test_a_chunk_that_is_never_read_still_turns_the_gap_to_prefill(
+def test_a_chunk_turns_the_gap_of_the_row_it_takes_along_to_prefill(
         monkeypatch):
-    """A dense model's non-last chunks are dispatched and never queued for a
-    read: the kind follows what was DISPATCHED, so the row that decodes
-    beside them still counts a prefill gap a chunk."""
+    """Since ISSUE 40 a dense model's chunk takes the live row along: each
+    of the late prompt's chunks is ONE record that is both a chunk and a
+    step, read once, and the row's token it carries counts a prefill gap —
+    by what was DISPATCHED, as for the model whose chunk goes alone and is
+    never read (``test_serve_fused_turn.py``, the two-program model)."""
     queued = []
 
     class Spy(continuous._Launched):
@@ -137,7 +139,7 @@ def test_a_chunk_that_is_never_read_still_turns_the_gap_to_prefill(
 
         def __init__(self, *args):
             super().__init__(*args)
-            queued.append(self.step)
+            queued.append((self.chunk, self.step))
 
     monkeypatch.setattr(continuous, "_Launched", Spy)
     srv = _server()
@@ -150,7 +152,10 @@ def test_a_chunk_that_is_never_read_still_turns_the_gap_to_prefill(
         _admission_in_mid_decode(sched)
         d = _delta(sched.stats(), before)
         assert d["prefill_chunks"] == 6
-        assert queued.count(False) == 2  # one chunk a prompt was ever read
+        # the first prompt's chunk found no row live; the late prompt's five
+        # each carried the first's
+        assert queued.count((True, False)) == 1
+        assert queued.count((True, True)) == 5
         assert d["gap_prefill_tokens"] == 5
     finally:
         srv.shutdown()
@@ -274,10 +279,11 @@ def test_the_items_of_one_read_carry_one_stamp(scripted):
     a, b = ({s for kind, _, s in seq if kind == "tok"}
             for seq in scripted["items"])
     assert len(a) == NEW and len(b) == LATE_NEW
-    # the late row's first token came from its last chunk, alone
-    assert len(a & b) == LATE_NEW - 1
+    # the late row's first token came from its last chunk, whose program
+    # took the first row along (ISSUE 40): one read, one stamp for both
+    assert len(a & b) == LATE_NEW
     turns, _ = _turns_of(scripted["sched"])
-    assert len(a | b) == NEW + 1 <= len(turns)
+    assert len(a | b) == NEW <= len(turns)
 
 
 def test_the_registrys_counter_still_equals_tokens_generated(scripted):

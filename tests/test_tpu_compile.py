@@ -274,7 +274,8 @@ def _serve_programs_at_the_defaults(cfg, v5e, **program_kw):
     16-token pages, a verify window of serve_spec_k + 1 = 5), on the lane a
     TPU replica resolves."""
     from ray_tpu._private.config import Config
-    from ray_tpu.models.decode import (init_paged_caches, paged_decode_step,
+    from ray_tpu.models.decode import (StepRows, init_paged_caches,
+                                       paged_decode_step,
                                        paged_prefill_into_slot,
                                        paged_verify_step)
     from ray_tpu.models.transformer import init_params
@@ -297,16 +298,17 @@ def _serve_programs_at_the_defaults(cfg, v5e, **program_kw):
     table = _on(chip, (slots, pages), jnp.int32)
     row = _on(chip, (pages,), jnp.int32)
     ids = functools.partial(_on, chip, dtype=jnp.int32)
+    step = (ids((slots,)), ids((slots,)), table, table,
+            _on(chip, (slots,), jnp.float32), _on(chip, (slots,), jnp.uint32))
     programs = {
+        # the chunk's program as the scheduler calls it: with the step's rows
         "prefill": (paged_prefill_into_slot,
                     (params, ids((1, chunk)), ids(()), ids(()), row, row,
                      caches, ids((slots,)), ids(()),
-                     _on(chip, (), jnp.float32), _on(chip, (), jnp.uint32)),
-                    6),
+                     _on(chip, (), jnp.float32), _on(chip, (), jnp.uint32),
+                     StepRows(*step)), 6),
         "decode": (paged_decode_step,
-                   (params, ids((slots,)), ids((slots,)), ids((slots,)), table,
-                    table, caches, _on(chip, (slots,), jnp.float32),
-                    _on(chip, (slots,), jnp.uint32)), 6),
+                   (params, ids((slots,)), *step[:4], caches, *step[4:]), 6),
         "verify": (paged_verify_step,
                    (params, ids((slots, conf.serve_spec_k + 1)), ids((slots,)),
                     ids((slots,)), table, table, caches), 6),
@@ -387,31 +389,27 @@ def test_moe_grouped_matmul_compiles(v5e, pairs):
     assert compiled.memory_analysis().temp_size_in_bytes < 8e6
 
 
-def test_olmoe_serve_programs_compile_and_fit(v5e):
-    """The benchmark's OLMoE-1B-7B configuration (published widths, 8
-    layers, bf16) under its cell's deployment: the prefill chunk and the
-    decode step with the expert layer's grouped matmuls (the kernel
-    ``moe_grouped_matmul``, once a layer, and nothing of the compiler's
-    own ``ragged-dot``) and the paged kernel at its second shape (page
-    rows of 16 kv heads x 128, group size 1), weights and the 6.4 GB pool
-    beside the programs' own memory on one 16 GB chip."""
+def _cell_programs(v5e, config: str, cell: str):
+    """A benchmark cell's two serving programs as its scheduler calls them,
+    at the configuration's published widths under the cell's deployment:
+    (cfg, bytes held by weights and pool, {name: (program, arguments)}). The
+    chunk's program takes the step's rows where the model's layer kinds let
+    it (``step_rides_chunk``), else None."""
     from perfbench.lib import configs
     from perfbench.lib import manifest as manifest_lib
-    from ray_tpu.models.decode import (init_paged_caches, paged_decode_step,
-                                       paged_prefill_into_slot)
+    from ray_tpu.models.decode import (StepRows, init_paged_caches,
+                                       paged_decode_step,
+                                       paged_prefill_into_slot,
+                                       step_rides_chunk)
     from ray_tpu.models.transformer import init_params
-    from ray_tpu.ops.paged_attention import resolve_impl
 
     manifest = manifest_lib.load()
-    hp = manifest_lib.config(manifest, "olmoe_1b_7b_l8")
+    hp = manifest_lib.config(manifest, config)
     cfg = configs.build_program_config(*configs.program_overrides(
         hp, manifest_lib.read_json_from_bench("families", hp["model_type"])))
-    dep = manifest_lib.read_json(manifest, "cells",
-                                 "olmoe_reason")["deployment"]
+    dep = manifest_lib.read_json(manifest, "cells", cell)["deployment"]
     slots, chunk, T = dep["slots"], dep["prefill_chunk"], dep["page_tokens"]
     pages = dep["arena_len"] // T
-    lane = resolve_impl(cfg)
-    assert lane == "pallas"
     chip = SingleDeviceSharding(v5e.devices[0])
 
     def place(tree):
@@ -420,28 +418,102 @@ def test_olmoe_serve_programs_compile_and_fit(v5e):
     params = place(jax.eval_shape(
         functools.partial(init_params, cfg), jax.random.PRNGKey(0)))
     caches = place(jax.eval_shape(functools.partial(
-        init_paged_caches, cfg, dep["kv_pages"], T, pages)))
+        init_paged_caches, cfg, dep["kv_pages"], T, pages, slots=slots)))
     held = sum(a.size * a.dtype.itemsize
                for a in jax.tree.leaves((params, caches)))
-    assert 13.4e9 < held < 13.7e9  # 7.13 GB of weights + 6.4 GB of pool
     table = _on(chip, (slots, pages), jnp.int32)
     row = _on(chip, (pages,), jnp.int32)
     ids = functools.partial(_on, chip, dtype=jnp.int32)
-    programs = {
+    step = (ids((slots,)), ids((slots,)), table, table,
+            _on(chip, (slots,), jnp.float32), _on(chip, (slots,), jnp.uint32))
+    return cfg, held, {
         "prefill": (paged_prefill_into_slot,
                     (params, ids((1, chunk)), ids(()), ids(()), row, row,
                      caches, ids((slots,)), ids(()),
-                     _on(chip, (), jnp.float32), _on(chip, (), jnp.uint32)),
-                    6),
+                     _on(chip, (), jnp.float32), _on(chip, (), jnp.uint32),
+                     StepRows(*step) if step_rides_chunk(cfg) else None,
+                     ids(()))),
         "decode": (paged_decode_step,
-                   (params, ids((slots,)), ids((slots,)), ids((slots,)), table,
-                    table, caches, _on(chip, (slots,), jnp.float32),
-                    _on(chip, (slots,), jnp.uint32)), 6),
+                   (params, ids((slots,)), *step[:4], caches, *step[4:])),
     }
-    for name, (program, args, donated) in programs.items():
+
+
+def _kernel_calls(compiled) -> dict:
+    """How many custom calls of each kernel name the compiled text holds."""
+    names = [re.sub(r"[.\d]+$", "", k)
+             for k in _kernel_names(compiled.as_text())]
+    return {k: names.count(k) for k in set(names)}
+
+
+@pytest.mark.parametrize("config,cell,held_gb", [
+    ("mistral7b_v03_l16", "mistral7b_chat", (13.7, 14.2)),
+    ("olmoe_1b_7b_l8", "olmoe_reason", (13.4, 13.7))])
+def test_a_turn_with_a_chunk_is_one_program_at_the_cells_shapes(
+        v5e, config, cell, held_gb):
+    """ISSUE 40: the chunk's program with the step's rows along, at the
+    cells' real shapes (512 + 32 rows through every projection): TWO
+    ``paged_attention`` calls a layer, at the chunk's shape and the step's,
+    ONE ``moe_grouped_matmul`` a layer over (512 + 32) x 8 pairs (the tiles
+    of a chunk's 4,096), the head over 33 rows and not 512, and temporaries
+    no larger than the chunk's program alone holds: what chat and docs stand
+    at (15.3-15.4 GB of 16) leaves it no room to add."""
+    from ray_tpu.models.decode import StepRows
+    from ray_tpu.ops import moe
+    from ray_tpu.ops.paged_attention import resolve_impl
+
+    cfg, held, programs = _cell_programs(v5e, config, cell)
+    assert held_gb[0] * 1e9 < held < held_gb[1] * 1e9
+    program, args = programs["prefill"]
+    assert isinstance(args[11], StepRows)
+    kw = {"attn": resolve_impl(cfg)}
+    if cfg.mlp == "moe":
+        kw["moe_info"] = True
+        assert moe.tile_sizes((512 + 32) * 8, 64, 2048, 1024, 2) == \
+            moe.tile_sizes(512 * 8, 64, 2048, 1024, 2) == (128, 1024)
+
+    def compiled(arguments):
+        return jax.jit(functools.partial(program, cfg, **kw),
+                       donate_argnums=(6,)).lower(*arguments).compile()
+
+    fused = compiled(args)
+    calls = _kernel_calls(fused)
+    assert calls.pop("paged_attention") == 2 * cfg.num_layers
+    if cfg.mlp == "moe":
+        assert calls.pop("moe_grouped_matmul") == cfg.num_layers
+        assert "ragged-dot" not in fused.as_text()
+    assert not calls
+    _fits(fused)
+    # no logits over the chunk's 512 rows: the head sees the sampled rows
+    vocab = cfg.vocab_size
+    assert not re.search(rf"\[(1,)?512,{vocab}\]", fused.as_text())
+    assert re.search(rf"\[(1,)?33,{vocab}\]", fused.as_text())
+    alone = compiled(args[:11] + (None,) + args[12:])
+    assert _kernel_calls(alone)["paged_attention"] == cfg.num_layers
+    temp, temp_alone = (c.memory_analysis().temp_size_in_bytes
+                        for c in (fused, alone))
+    assert temp < 1.1 * temp_alone + 16e6, (temp, temp_alone)
+
+
+def test_olmoe_serve_programs_compile_and_fit(v5e):
+    """The benchmark's OLMoE-1B-7B configuration (published widths, 8
+    layers, bf16) under its cell's deployment: the prefill chunk (with the
+    step's rows along) and the decode step with the expert layer's grouped
+    matmuls (the kernel ``moe_grouped_matmul``, once a layer, and nothing
+    of the compiler's own ``ragged-dot``) and the paged kernel at its
+    second shape (page rows of 16 kv heads x 128, group size 1), weights
+    and the 6.4 GB pool beside the programs' own memory on one 16 GB
+    chip."""
+    from ray_tpu.ops.paged_attention import resolve_impl
+
+    cfg, held, programs = _cell_programs(v5e, "olmoe_1b_7b_l8",
+                                         "olmoe_reason")
+    lane = resolve_impl(cfg)
+    assert lane == "pallas"
+    assert 13.4e9 < held < 13.7e9  # 7.13 GB of weights + 6.4 GB of pool
+    for name, (program, args) in programs.items():
         compiled = jax.jit(
             functools.partial(program, cfg, attn=lane, moe_info=True),
-            donate_argnums=(donated,)).lower(*args).compile()
+            donate_argnums=(6,)).lower(*args).compile()
         assert _names(compiled) == {"paged_attention",
                                     "moe_grouped_matmul"}, name
         assert "ragged-dot" not in compiled.as_text(), name
@@ -460,59 +532,24 @@ def test_minicpm_sala_serve_programs_compile_and_fit(v5e):
     pages in a step, the masked flash kernel in a chunk), 10.1 GB of
     weights, the 2.2 GB pool of the four sparse layers and 0.4 GB of states
     beside the programs' own memory on one 16 GB chip."""
-    from perfbench.lib import configs
-    from perfbench.lib import manifest as manifest_lib
-    from ray_tpu.models.decode import (init_paged_caches, paged_decode_step,
-                                       paged_prefill_into_slot)
-    from ray_tpu.models.transformer import init_params
     from ray_tpu.ops.paged_attention import resolve_impl
 
-    manifest = manifest_lib.load()
-    hp = manifest_lib.config(manifest, "minicpm_sala_l16")
-    cfg = configs.build_program_config(*configs.program_overrides(
-        hp, manifest_lib.read_json_from_bench("families", hp["model_type"])))
-    dep = manifest_lib.read_json(manifest, "cells",
-                                 "minicpm_sala_longdoc")["deployment"]
-    slots, chunk, T = dep["slots"], dep["prefill_chunk"], dep["page_tokens"]
-    pages = dep["arena_len"] // T
+    cfg, held, programs = _cell_programs(v5e, "minicpm_sala_l16",
+                                         "minicpm_sala_longdoc")
     lane = resolve_impl(cfg)
     assert lane == "pallas"
-    chip = SingleDeviceSharding(v5e.devices[0])
-
-    def place(tree):
-        return jax.tree.map(lambda a: _on(chip, a.shape, a.dtype), tree)
-
-    params = place(jax.eval_shape(
-        functools.partial(init_params, cfg), jax.random.PRNGKey(0)))
-    caches = place(jax.eval_shape(functools.partial(
-        init_paged_caches, cfg, dep["kv_pages"], T, pages, slots=slots)))
-    held = sum(a.size * a.dtype.itemsize
-               for a in jax.tree.leaves((params, caches)))
     assert 12.5e9 < held < 12.9e9  # 10.1 GB + 2.2 GB of pool + 0.4 of state
-    table = _on(chip, (slots, pages), jnp.int32)
-    row = _on(chip, (pages,), jnp.int32)
-    ids = functools.partial(_on, chip, dtype=jnp.int32)
-    programs = {
-        "prefill": (paged_prefill_into_slot,
-                    (params, ids((1, chunk)), ids(()), ids(()), row, row,
-                     caches, ids((slots,)), ids(()),
-                     _on(chip, (), jnp.float32), _on(chip, (), jnp.uint32),
-                     ids(())),
-                    {"linear_attention_chunk", "sparse_select",
-                     "sparse_paged_attention"}),
-        "decode": (paged_decode_step,
-                   (params, ids((slots,)), ids((slots,)), ids((slots,)), table,
-                    table, caches, _on(chip, (slots,), jnp.float32),
-                    _on(chip, (slots,), jnp.uint32)),
-                   {"linear_attention_step", "sparse_select",
-                    "sparse_paged_attention"}),
-    }
-    for name, (program, args, kernels) in programs.items():
+    assert programs["prefill"][1][11] is None  # the chunk goes alone
+    kernels = {"prefill": {"linear_attention_chunk", "sparse_select",
+                           "sparse_paged_attention"},
+               "decode": {"linear_attention_step", "sparse_select",
+                          "sparse_paged_attention"}}
+    for name, (program, args) in programs.items():
         compiled = jax.jit(functools.partial(program, cfg, attn=lane),
                            donate_argnums=(6,)).lower(*args).compile()
         found = {re.sub(r"[.\d]+$", "", k)
                  for k in _kernel_names(compiled.as_text())}
-        assert found == kernels, (name, found)
+        assert found == kernels[name], (name, found)
         total = _fits(compiled)
         # the programs' own memory leaves room for the reference check
         assert total < 14.5e9, f"{name}: {total / 1e9:.1f} GB"
