@@ -34,6 +34,16 @@ weights from ``--seed``):
            differ from the reference's own and how far below the reference's
            cut the system's blocks scored; a slot without a sequence keeps a
            zero state
+  brumby   a @ray_tpu.remote(num_tpus=1) task runs the benchmark's
+           Brumby-14B configuration (published widths, 8 layers, every
+           mixer power retention, bf16, no page anywhere): the two kernels
+           in float32 at the published head shape (40 query heads on 8
+           states) against the attention form token against token (tight:
+           1e-3 of the largest output); then a prompt of 2177 tokens through
+           the paged prefill chunks (the last one padded) and 6 decode steps
+           beside a second live row, against perfbench/reference/brumby.py
+           (inside the cell's limits); a slot without a sequence keeps a
+           zero state
   serve    serve.run(build_app(preset="gpt2_small")) answers 8 concurrent
            requests: six through the handle, one streamed, one over HTTP
 
@@ -577,6 +587,150 @@ def sala_task(seed: int) -> dict:
     return {**out, **device_report()}
 
 
+def brumby_task(seed: int) -> dict:
+    """The Brumby-width checks (ISSUE 43): the two retention kernels in
+    float32 against the attention form, then the system in bf16 — paged
+    prefill chunks and decode steps, no page table — against the plain
+    float32 reference."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from perfbench.lib import configs, weights
+    from perfbench.lib import manifest as manifest_lib
+    from perfbench.reference import brumby as ref
+    from ray_tpu.models.decode import (init_paged_caches, paged_decode_step,
+                                       paged_prefill_into_slot)
+    from ray_tpu.ops import power_retention as pr
+
+    require_chip()
+    manifest = manifest_lib.load()
+    hp = manifest_lib.config(manifest, "brumby_14b_l8")
+    tol = manifest_lib.read_json(manifest, "cells",
+                                 "brumby_longgen")["check_tolerance"]
+    cfg = configs.build_program_config(*configs.program_overrides(
+        hp, manifest_lib.read_json_from_bench("families", hp["model_type"])))
+    # ---- the kernels, float32, against the attention form
+    H, G, D, n = cfg.num_heads, cfg.kv_heads, cfg.head_dim, 300
+    ks = jax.random.split(jax.random.key(seed & 0x7FFFFFFF), 4)
+    q = jax.random.normal(ks[0], (1, n, H, D))
+    k = jax.random.normal(ks[1], (1, n, G, D))
+    v = jax.random.normal(ks[2], (1, n, G, D))
+    gate = jax.nn.log_sigmoid(jax.random.normal(ks[3], (1, n, G)) + 2.0)
+
+    @jax.jit
+    def attention_form(q, k, v, gate):
+        with jax.default_matmul_precision("highest"):
+            kk, vv = (jnp.repeat(x, H // G, axis=2) for x in (k, v))
+            run = jnp.cumsum(jnp.repeat(gate, H // G, axis=2), axis=1)[0].T
+            score = jnp.einsum("qhd,khd->hqk", q[0], kk[0]) / math.sqrt(D)
+            seen = jnp.tril(jnp.ones((n, n), bool))
+            a = score ** 2 * jnp.exp(jnp.where(
+                seen, run[:, :, None] - run[:, None, :], -jnp.inf))
+            return (jnp.einsum("hqk,khd->qhd", a, vv[0])
+                    / (a.sum(-1).T[..., None] + pr.EPS))
+
+    want = np.asarray(attention_form(q, k, v, gate))
+    zero = [jnp.zeros(shape, jnp.float32)
+            for shape in pr.state_shapes(1, G, D).values()]
+    head = 256  # a chunk of two blocks, then steps on the state it left
+    o, s, z = pr.power_retention_chunk(q[:, :head], k[:, :head], v[:, :head],
+                                       gate[:, :head], *zero, head - 7)
+    rel = lambda a, b: float(np.abs(np.asarray(a) - b).max()
+                             / np.abs(want).max())
+    kernel_err = {"chunk": rel(o[0, :head - 7], want[:head - 7])}
+    stepped = []
+    for t in range(head - 7, n):
+        o1, s, z = pr.power_retention_step(
+            q[:, t], k[:, t], v[:, t], gate[:, t], s, z,
+            jnp.ones((1,), jnp.int32))
+        stepped.append(np.asarray(o1[0]))
+    kernel_err["steps_behind_it"] = rel(np.stack(stepped), want[head - 7:])
+    del q, k, v, o, s, z, zero
+    # ---- the paged programs, bf16, against the reference
+    params = weights.make_params(cfg, seed)
+    S, C, slot, other, steps = 4, 512, 2, 1, 6
+    caches = init_paged_caches(cfg, 1, C, 1, slots=S)
+    prefill = jax.jit(lambda *a: paged_prefill_into_slot(
+        cfg, *a, attn="pallas", logits=True), donate_argnums=(6,))
+    step = jax.jit(lambda *a: paged_decode_step(
+        cfg, *a, attn="pallas", logits=True), donate_argnums=(6,))
+    rng = np.random.default_rng(seed)
+    # lengths of whole blocks of 128 and one token: each prompt's last
+    # token is a block's first, which reads the state ACROSS blocks (the
+    # chunk kernel's one product on rounded pairs) at its largest weight
+    prompts = {slot: rng.integers(1, cfg.vocab_size, 2177).tolist(),
+               other: rng.integers(1, cfg.vocab_size, 641).tolist()}
+    ids = jnp.zeros(S, jnp.int32)
+    first, chunk_s = {}, []
+    for row, prompt in prompts.items():
+        for c0 in range(0, len(prompt), C):
+            chunk = prompt[c0:c0 + C]
+            t0 = time.perf_counter()
+            ids, caches, logits = prefill(
+                params, jnp.asarray([chunk + [0] * (C - len(chunk))],
+                                    jnp.int32),
+                np.int32(len(chunk)), np.int32(c0), None, None, caches, ids,
+                np.int32(row if c0 + C >= len(prompt) else -1),
+                np.float32(0), np.uint32(0), None, np.int32(row))
+            first[row] = np.asarray(logits, np.float32)
+            chunk_s.append(time.perf_counter() - t0)
+    got = {row: [first[row]] for row in prompts}
+    fed = {row: [] for row in prompts}
+    active = np.zeros(S, np.int32)
+    active[[slot, other]] = 1
+    cursors = np.zeros(S, np.int32)
+    for row, prompt in prompts.items():
+        cursors[row] = len(prompt)
+    step_s = []
+    for _ in range(steps):
+        for row in prompts:
+            fed[row].append(int(got[row][-1].argmax()))
+        t0 = time.perf_counter()
+        ids, caches, logits = step(
+            params, ids, jnp.asarray(active), cursors, None, None, caches,
+            np.zeros(S, np.float32), np.zeros(S, np.uint32))
+        for row in prompts:
+            got[row].append(np.asarray(logits[row], np.float32))
+        step_s.append(time.perf_counter() - t0)
+        cursors = cursors + active
+    served_on_device = np.asarray(ids)
+    idle_states = float(max(np.abs(np.asarray(a[0])).max() for c in caches
+                            for a in c.arrays().values()))
+    del caches
+    err = {}
+    for row, prompt in prompts.items():
+        want = ref.forward(params, jnp.asarray([prompt + fed[row]],
+                                               jnp.int32),
+                           hp)[0, len(prompt) - 1:-1]
+        have = np.stack(got[row][:-1])
+        err[f"slot{row}"] = {
+            "max": float(np.abs(have - want).max() / np.abs(want).max()),
+            "rms": float(np.sqrt(((have - want) ** 2).mean()
+                                 / (want ** 2).mean())),
+            "margin": max(float((w.max() - w[t]) / np.abs(want).max())
+                          for w, t in zip(want, fed[row]))}
+        del want
+    out = {"kernel_err_f32": kernel_err, "paged_err": err,
+           "idle_slot_state": idle_states,
+           "ids_on_device": [int(served_on_device[r]) for r in prompts],
+           "chunk_ms": [round(1e3 * x, 1) for x in chunk_s],
+           "step_ms": [round(1e3 * x, 1) for x in step_s]}
+    bad = []
+    if max(kernel_err.values()) > 1e-3:
+        bad.append("a retention kernel is off the attention form in float32")
+    for row, e in err.items():
+        if (e["max"] > tol["logit_err"] or e["rms"] > tol["logit_rms_err"]
+                or e["margin"] > tol["served_margin"]):
+            bad.append(f"{row}: the paged programs are outside the cell's "
+                       "limits")
+    if idle_states != 0.0:
+        bad.append("a slot without a sequence has a state")
+    if bad:
+        raise RuntimeError(f"brumby: {bad}: {out}")
+    return {**out, **device_report()}
+
+
 def served_batch(cfg, params, seed: int) -> dict:
     """A short mixed batch through ``ContinuousScheduler`` at the widths
     ``params`` has (ISSUE 29): twelve requests over eight slots, the loop
@@ -727,6 +881,15 @@ def sala_phase(seed: int) -> None:
     emit("sala", seconds=round(time.perf_counter() - t0, 1), **out)
 
 
+def brumby_phase(seed: int) -> None:
+    import ray_tpu
+
+    t0 = time.perf_counter()
+    out = ray_tpu.get(
+        ray_tpu.remote(num_tpus=1)(brumby_task).remote(seed), timeout=1500)
+    emit("brumby", seconds=round(time.perf_counter() - t0, 1), **out)
+
+
 def serve_phase(seed: int) -> None:
     import ray_tpu
     import ray_tpu.serve as serve
@@ -839,6 +1002,7 @@ def one_chip(seed: int) -> dict:
     kernels_phase(seed)
     olmoe_phase(seed)
     sala_phase(seed)
+    brumby_phase(seed)
     serve_phase(seed)
     return out["device"]
 

@@ -17,4 +17,5 @@ from ray_tpu.models.presets import (  # noqa: F401
     llama_debug,
     moe_debug,
     minicpm_sala_debug,
+    brumby_debug,
 )

@@ -18,12 +18,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.transformer import (ATTENTION, LINEAR, SPARSE,
-                                        TransformerConfig, _mlp, _norm, _qkv,
-                                        _residual, embed, final_hidden,
-                                        forward, layer_params, linear_mixer,
-                                        project, rope_table, sparse_mixer,
-                                        sparse_pool_pages, stacked_mlp)
+from ray_tpu.models.transformer import (ATTENTION, LINEAR, RETENTION, SPARSE,
+                                        STATE_KINDS, TransformerConfig, _mlp,
+                                        _norm, _qkv, _residual, embed,
+                                        final_hidden, forward, layer_params,
+                                        linear_mixer, project,
+                                        retention_mixer, rope_table,
+                                        sparse_mixer, sparse_pool_pages,
+                                        stacked_mlp, state_shapes)
 from ray_tpu.ops.paged_attention import paged_attention
 from ray_tpu.ops.sparse_attention import check_pool
 
@@ -78,6 +80,36 @@ class LinearState:
     s: Any
     length: Any = None
 
+    def arrays(self):
+        return {"s": self.s}
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class RetentionState:
+    """What a 'power-retention' layer keeps: s [rows, Hkv, tiles, D, lanes]
+    and the normaliser z [rows, Hkv, tiles, 1, lanes], float32
+    (``ops.power_retention.state_shapes``), one row a sequence or a slot as
+    ``LinearState`` has it."""
+
+    s: Any
+    z: Any
+    length: Any = None
+
+    def arrays(self):
+        return {"s": self.s, "z": self.z}
+
+
+_STATES = {LINEAR: LinearState, RETENTION: RetentionState}
+
+
+def init_state(cfg: TransformerConfig, kind: str, rows: int, length=None):
+    """The zero state of a layer of a kind in ``STATE_KINDS`` for ``rows``
+    sequences (``transformer.state_shapes`` says what that is)."""
+    return _STATES[kind](length=length, **{
+        name: jnp.zeros(shape, jnp.float32)
+        for name, shape in state_shapes(cfg, kind, rows).items()})
+
 
 def init_caches(cfg: TransformerConfig, batch: int, max_len: int,
                 dtype=None) -> List[Any]:
@@ -89,10 +121,8 @@ def init_caches(cfg: TransformerConfig, batch: int, max_len: int,
         if kind == ATTENTION:
             return LayerKVCache.zeros(batch, max_len, cfg.kv_heads,
                                       cfg.head_dim, dtype)
-        if kind == LINEAR:
-            return LinearState(
-                s=jnp.zeros((batch, cfg.num_heads, cfg.head_dim,
-                             cfg.head_dim), jnp.float32), length=zero)
+        if kind in STATE_KINDS:
+            return init_state(cfg, kind, batch, zero)
         # a pool of its own: page 0 the garbage page, then a sequence's
         # pages in order, so its page table is the identity
         return dataclasses.replace(SparsePagedKVCache.zeros(
@@ -337,10 +367,12 @@ def init_paged_caches(cfg: TransformerConfig, num_pages: int,
                       dtype=None, slots: Optional[int] = None) -> List[Any]:
     """The serving pool, a layer at a time and by the layer's kind: pages
     for an attention layer (with pooled key rows for a 'minicpm4' one), a
-    state a slot (``slots`` of them) for a 'lightning-attn' one."""
+    state a slot (``slots`` of them) for a layer of a kind in
+    ``STATE_KINDS``. A model none of whose layers holds a page has no pool:
+    ``num_pages`` may then be anything, and nothing is made of it."""
     if page_tokens < 1:
         raise ValueError(f"page_tokens must be >= 1, got {page_tokens}")
-    if num_pages < 2:
+    if num_pages < 2 and cfg.holds_pages:
         # page 0 is the reserved garbage page; an arena with no
         # allocatable page cannot hold any sequence
         raise ValueError(f"num_pages must be >= 2, got {num_pages}")
@@ -354,15 +386,14 @@ def init_paged_caches(cfg: TransformerConfig, num_pages: int,
     if SPARSE in cfg.kinds:
         check_pool(cfg.sparse, page_tokens, pages_per_slot)
     if cfg.recurrent and not slots:
-        raise ValueError("a model with 'lightning-attn' layers keeps a state "
-                         "a slot: init_paged_caches needs slots")
+        raise ValueError("a model with layers that keep a state a slot "
+                         f"({_state_kinds(cfg)}): init_paged_caches needs "
+                         "slots")
     dtype = dtype or cfg.dtype
 
     def one(kind):
-        if kind == LINEAR:
-            return LinearState(s=jnp.zeros(
-                (slots, cfg.num_heads, cfg.head_dim, cfg.head_dim),
-                jnp.float32))
+        if kind in STATE_KINDS:
+            return init_state(cfg, kind, slots)
         pool = SparsePagedKVCache if kind == SPARSE else PagedKVCache
         return pool.zeros(num_pages, page_tokens, cfg.kv_heads, cfg.head_dim,
                           dtype)
@@ -370,10 +401,16 @@ def init_paged_caches(cfg: TransformerConfig, num_pages: int,
     return [one(kind) for kind in cfg.kinds]
 
 
+def _state_kinds(cfg: TransformerConfig) -> str:
+    """The model's kinds that keep a state, for a message."""
+    return ", ".join(repr(k) for k in STATE_KINDS if k in cfg.kinds)
+
+
 class _Rows(NamedTuple):
     """One group of rows of a paged program: what attends at ONE shape.
     tokens/positions/valid: [S, K]; lengths: [S] attention cursors;
-    read_tables/write_tables: [S, P]."""
+    read_tables/write_tables: [S, P] (None for a model that holds no
+    page)."""
 
     tokens: Any
     positions: Any
@@ -400,7 +437,8 @@ def step_rides_chunk(cfg: TransformerConfig) -> bool:
     """Whether a prefill chunk's program can take the live decode rows
     along: where every layer holds pages through ``ops.paged_attention``. A
     'minicpm4' or 'lightning-attn' layer has chunk and step kernels of its
-    own shapes, and such a model's turn stays two programs."""
+    own shapes, as a 'power-retention' layer has, and such a model's turn
+    stays two programs."""
     return all(kind == ATTENTION for kind in cfg.kinds)
 
 
@@ -413,9 +451,10 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
     write-before-attend, so XLA updates the donated pool in place — and (2)
     attends through the read tables via ``ops.paged_attention(impl=)``; a
     'minicpm4' layer does the same through ``transformer.sparse_mixer`` and
-    attends the blocks it chooses; a 'lightning-attn' layer
-    (``transformer.linear_mixer``) reads and writes its states instead: all
-    slots' in a step, of which the rows not ``active`` [S] keep theirs
+    attends the blocks it chooses; a 'lightning-attn' or 'power-retention'
+    layer (``transformer.linear_mixer``, ``retention_mixer``) reads and
+    writes its states instead: all slots' in a step, of which the rows not
+    ``active`` [S] keep theirs
     bitwise, or in a chunk (``slot`` given: the one row is that slot's) the
     slot's own, taken as zero when the chunk starts at position 0 — a new
     sequence needs no reset beforehand — and advanced by the chunk's
@@ -482,16 +521,26 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
         c = caches[i]
         ap = p["attn"]
         h = _norm(cfg, p["ln1"], x)
-        if kind == LINEAR and slot is None:
-            a, s = linear_mixer(cfg, ap, h, positions, c.s, active=active)
-            new_caches.append(LinearState(s=s))
-        elif kind == LINEAR:
-            mine = lax.dynamic_slice_in_dim(c.s, slot, 1, axis=0)
-            mine = jnp.where(positions[0, 0] == 0, 0.0, mine)
-            a, mine = linear_mixer(cfg, ap, h, positions, mine,
-                                   real_len=real_len)
-            new_caches.append(LinearState(
-                s=lax.dynamic_update_slice_in_dim(c.s, mine, slot, axis=0)))
+        if kind in STATE_KINDS:
+            # a step: every slot's state; a chunk: the slot's own, zero
+            # where the chunk starts a sequence
+            state, how = c.arrays(), {"active": active}
+            if slot is not None:
+                state = {n: jnp.where(
+                    positions[0, 0] == 0, 0.0,
+                    lax.dynamic_slice_in_dim(s, slot, 1, axis=0))
+                    for n, s in state.items()}
+                how = {"real_len": real_len}
+            if kind == LINEAR:
+                a, state["s"] = linear_mixer(cfg, ap, h, positions,
+                                             state["s"], **how)
+            else:
+                a, state = retention_mixer(cfg, ap, h, positions, state,
+                                           **how)
+            if slot is not None:
+                state = {n: lax.dynamic_update_slice_in_dim(
+                    getattr(c, n), s, slot, axis=0) for n, s in state.items()}
+            new_caches.append(_STATES[kind](**state))
         elif kind == SPARSE:
             a, pools = sparse_mixer(cfg, ap, h, positions, first.lengths,
                                     (c.k, c.v, c.means), first.read_tables,
@@ -566,7 +615,8 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
     into their pages, attention through the read table
     (``_paged_forward_inplace``). cursor: int32 scalar, the tokens already
     resident (0 cold, the spliced length after a prefix-cache hit); the
-    caller advances it by ``real_len``. read_row/write_row: [P] int32 —
+    caller advances it by ``real_len``. read_row/write_row: [P] int32
+    (None for a model that holds no page) —
     shared (prefix-cache) pages appear in read_row but are redirected to the
     garbage page in write_row, so their content is immutable here. ``attn``:
     the implementation ``ops.paged_attention`` runs ('reference' |
@@ -597,7 +647,8 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
     model with a 'minicpm4' or 'lightning-attn' layer takes None.
 
     ``state_slot``: the slot itself, whichever chunk this is — where the
-    model has 'lightning-attn' layers, their states of that slot are what
+    model has layers that keep a state (``STATE_KINDS``), their states of
+    that slot are what
     the chunk continues (from zero when ``cursor`` is 0) and leaves advanced
     by ``real_len`` tokens.
 
@@ -622,14 +673,15 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
     the kind, 1, C, Hkv, NB], last."""
     _check_moe_info(cfg, moe_info)
     if cfg.recurrent and state_slot is None:
-        raise ValueError("a model with 'lightning-attn' layers needs "
+        raise ValueError(f"a model with {_state_kinds(cfg)} layers needs "
                          "state_slot: the slot whose states the chunk "
                          "continues")
     taps = [] if selected else None
     C = tokens.shape[1]
     steps = jnp.arange(C, dtype=jnp.int32)[None, :]
+    row = lambda table: None if table is None else table[None]
     groups = [_Rows(tokens, steps + cursor, jnp.reshape(cursor, (1,)),
-                    read_row[None], write_row[None], steps < real_len)]
+                    row(read_row), row(write_row), steps < real_len)]
     sample = (jnp.reshape(temperature, (1,)), jnp.reshape(seed, (1,)),
               jnp.reshape(cursor + real_len, (1,)))
     if step is not None:
@@ -672,8 +724,9 @@ def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
     layer routes it nowhere), but it WRITES at its cursor like any other:
     the caller's tables send that write to the garbage page, or to a
     position the row's own sequence writes again before attending it. A
-    'lightning-attn' layer's state has no such second chance, so an
-    inactive row's state is left bitwise as it was.
+    'lightning-attn' or 'power-retention' layer's state has no such second
+    chance, so an inactive row's state is left bitwise as it was. For a
+    model that holds no page the two tables are None.
     ``attn``: the implementation ``ops.paged_attention`` runs ('reference'
     | 'pallas').
 
@@ -740,7 +793,7 @@ def paged_verify_step(cfg: TransformerConfig, params, tokens, active,
     _check_moe_info(cfg, moe_info)
     if cfg.recurrent:
         raise ValueError(
-            "paged_verify_step cannot run a model with 'lightning-attn' "
+            f"paged_verify_step cannot run a model with {_state_kinds(cfg)} "
             "layers: a rejected draft would have to rewind their states, "
             "and no snapshot is kept")
     K = tokens.shape[1]

@@ -106,6 +106,24 @@ def minicpm_sala_debug(**overrides) -> TransformerConfig:
     return TransformerConfig(**kw)
 
 
+def brumby_debug(**overrides) -> TransformerConfig:
+    """Tiny Brumby-shaped config (manifestai/Brumby-14B-Base: the dense
+    Llama-shaped block with q/k norm a head and RoPE, whose every mixer is
+    power retention of degree 2 on the state of a K/V head that a group of
+    query heads shares) for tests: five query heads over each of two K/V
+    heads, as the published 40 over 8. Every layer is 'power-retention',
+    however many ``num_layers`` says."""
+    kw = dict(
+        vocab_size=256, num_layers=2, embed_dim=320, num_heads=10,
+        num_kv_heads=2, mlp="swiglu", mlp_dim=256, max_seq_len=1024,
+        head_qk_norm=True, norm="rmsnorm", pos="rope", rope_theta=1000000.0,
+        norm_eps=1e-6, tie_embeddings=False, dtype=jnp.float32,
+    )
+    kw.update(overrides)
+    kw.setdefault("layer_kinds", ("power-retention",) * kw["num_layers"])
+    return TransformerConfig(**kw)
+
+
 # ---------------------------------------------------------------------------
 # pipeline stage partition (MPMD train.PipelineTrainer shards)
 #

@@ -22,10 +22,15 @@ Config switches:
     when the pattern is None), 'minicpm4' (block-selected sparse attention
     with an output gate and no RoPE, ops/sparse_attention.py) and
     'lightning-attn' (decayed linear attention on a [D, D] state a head,
-    with RoPE, an output norm and gate, ops/linear_attention.py). The two
-    are written once, state in and state out (``sparse_mixer``,
-    ``linear_mixer``), and called from every forward: this one with or
-    without caches, and the paged serving forward of models/decode.py.
+    with RoPE, an output norm and gate, ops/linear_attention.py) and
+    'power-retention' (degree 2, gated and normalised, on the symmetric
+    half of the outer product as the state of a K/V head, shared by its
+    query heads; q/k norm and RoPE as the block has them, no output gate;
+    ops/power_retention.py). Each is written once, state in and state out
+    (``sparse_mixer``, ``linear_mixer``, ``retention_mixer``), and called
+    from every forward: this one with or without caches, and the paged
+    serving forward of models/decode.py. What a kind that keeps a state a
+    sequence keeps is said in ONE place, ``state_shapes``.
   * scale_emb, scale_depth (over scale_depth_layers), dim_model_base: the
     MiniCPM scales of the embedding, of every residual branch and of the
     hidden state before the output head.
@@ -46,6 +51,9 @@ from ray_tpu.ops.linear_attention import (linear_attention_chunk,
                                           linear_attention_step, slopes)
 from ray_tpu.ops.losses import softmax_cross_entropy
 from ray_tpu.ops.norms import layer_norm, rms_norm
+from ray_tpu.ops import power_retention
+from ray_tpu.ops.power_retention import (power_retention_chunk,
+                                         power_retention_step)
 from ray_tpu.ops.ring_attention import ring_attention_local
 from ray_tpu.ops.rotary import (apply_rotary, apply_rotary_at,
                                 rope_frequencies)
@@ -53,7 +61,11 @@ from ray_tpu.ops.sparse_attention import (SparseSizes, sparse_attention,
                                           update_page_means)
 
 ATTENTION, SPARSE, LINEAR = "attention", "minicpm4", "lightning-attn"
-LAYER_KINDS = (ATTENTION, SPARSE, LINEAR)
+RETENTION = "power-retention"
+LAYER_KINDS = (ATTENTION, SPARSE, LINEAR, RETENTION)
+# the kinds that keep a fixed state a sequence and no keys or values: no
+# page of the serving pool is theirs
+STATE_KINDS = (LINEAR, RETENTION)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,7 +155,12 @@ class TransformerConfig:
     def recurrent(self) -> bool:
         """Some layer carries a state that is no K/V cache: what a token
         leaves behind cannot be cut at a page boundary or rewound."""
-        return LINEAR in self.kinds
+        return any(kind in STATE_KINDS for kind in self.kinds)
+
+    @property
+    def holds_pages(self) -> bool:
+        """Some layer keeps keys and values: the serving pool has pages."""
+        return any(kind not in STATE_KINDS for kind in self.kinds)
 
     @property
     def sparse(self) -> SparseSizes:
@@ -201,10 +218,12 @@ def _block_params(cfg: TransformerConfig, key,
     if cfg.head_qk_norm:
         p["attn"]["q_norm"] = jnp.ones((hd,), cfg.param_dtype)
         p["attn"]["k_norm"] = jnp.ones((hd,), cfg.param_dtype)
-    if kind != ATTENTION:
+    if kind in (SPARSE, LINEAR):
         p["attn"]["wg"] = init(ks[7], (d, h, hd))   # the output gate
     if kind == LINEAR:
         p["attn"]["o_norm"] = jnp.ones((h * hd,), cfg.param_dtype)
+    if kind == RETENTION:
+        p["attn"]["wc"] = init(ks[7], (d, kvh))     # a log-gate a K/V head
     if cfg.mlp == "moe":
         from ray_tpu.ops.moe import init_moe_params
 
@@ -298,10 +317,12 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
         if cfg.qk_norm or cfg.head_qk_norm:
             block["attn"]["q_norm"] = L + (None,)
             block["attn"]["k_norm"] = L + (None,)
-        if kind != ATTENTION:
+        if kind in (SPARSE, LINEAR):
             block["attn"]["wg"] = L + ("embed", "heads", "head_dim")
         if kind == LINEAR:
             block["attn"]["o_norm"] = L + (None,)
+        if kind == RETENTION:
+            block["attn"]["wc"] = L + ("embed", "kv")
         if cfg.mlp == "moe":
             from ray_tpu.ops.moe import moe_logical_axes
 
@@ -402,9 +423,22 @@ def _attn(cfg, p, x, rope, positions, sp_axis, kv_cache=None):
     return out, new_cache
 
 
-# The two mixers that are no plain attention, each written once: the state
-# comes in and goes out, and who holds it (nobody, a contiguous cache, the
-# serving pool) is the caller's business.
+# The mixers that are no plain attention, each written once: the state comes
+# in and goes out, and who holds it (nobody, a contiguous cache, the serving
+# pool) is the caller's business.
+
+
+def state_shapes(cfg, kind: str, rows: int) -> Dict[str, tuple]:
+    """What a layer of a kind in ``STATE_KINDS`` keeps for ``rows``
+    sequences: the float32 arrays by name, a row a sequence. Every holder (a
+    forward without caches, ``decode.init_caches``, the serving pool, the
+    scheduler's count of bytes) reads the shapes here."""
+    if kind == LINEAR:
+        return {"s": (rows, cfg.num_heads, cfg.head_dim, cfg.head_dim)}
+    if kind == RETENTION:
+        return power_retention.state_shapes(rows, cfg.kv_heads,
+                                            cfg.head_dim)
+    raise ValueError(f"a {kind!r} layer keeps no state")
 
 
 def _gated_out(cfg, p, x, o):
@@ -433,6 +467,33 @@ def linear_mixer(cfg, p, x, positions, state, *, real_len=None, active=None):
             q, k, v, state, slope, S if real_len is None else real_len)
     o = rms_norm(o.reshape(B, S, -1), p["o_norm"], cfg.norm_eps)
     return _gated_out(cfg, p, x, o.reshape(q.shape).astype(cfg.dtype)), state
+
+
+def retention_mixer(cfg, p, x, positions, state, *, real_len=None,
+                    active=None):
+    """'power-retention': x [B, S, d] at ``positions`` [B, S], state the
+    dict ``state_shapes`` describes (``s`` and the normaliser ``z``, a K/V
+    head each, float32) -> (y [B, S, d], state). The log-gate is ``log
+    sigmoid(x Wc)``, one a K/V head, float32. One token a row is a step, of
+    which a row that is not ``active`` ([B]; default: all are) keeps its
+    state bitwise; more are a chunk whose first ``real_len`` tokens (a
+    scalar; default: all) are real and the rest trailing padding."""
+    B, S = x.shape[:2]
+    q, k, v = _qkv(cfg, p, x, COMPUTED, positions)
+    gate = jax.nn.log_sigmoid(jnp.einsum(
+        "bsd,dg->bsg", x, p["wc"].astype(cfg.dtype),
+        preferred_element_type=jnp.float32))
+    if S == 1:
+        o, s, z = power_retention_step(
+            q[:, 0], k[:, 0], v[:, 0], gate[:, 0], state["s"], state["z"],
+            jnp.ones((B,), jnp.int32) if active is None else active)
+        o = o[:, None]
+    else:
+        o, s, z = power_retention_chunk(
+            q, k, v, gate, state["s"], state["z"],
+            S if real_len is None else real_len)
+    return jnp.einsum("bshk,hkd->bsd", o.astype(cfg.dtype),
+                      p["wo"].astype(cfg.dtype)), {"s": s, "z": z}
 
 
 def sparse_mixer(cfg, p, x, positions, lengths, pools, read_tables,
@@ -478,12 +539,16 @@ def _mixer(cfg, kind, p, x, rope, positions, sp_axis, cache, taps):
     B, S = x.shape[:2]
     pos = jnp.broadcast_to(jnp.arange(S)[None] if positions is None
                            else positions, (B, S)).astype(jnp.int32)
-    if kind == LINEAR:
-        state = (jnp.zeros((B, cfg.num_heads, cfg.head_dim, cfg.head_dim),
-                           jnp.float32) if cache is None else cache.s)
-        y, state = linear_mixer(cfg, p, x, pos, state)
+    if kind in STATE_KINDS:
+        state = ({name: jnp.zeros(shape, jnp.float32) for name, shape
+                  in state_shapes(cfg, kind, B).items()} if cache is None
+                 else cache.arrays())
+        if kind == LINEAR:
+            y, state["s"] = linear_mixer(cfg, p, x, pos, state["s"])
+        else:
+            y, state = retention_mixer(cfg, p, x, pos, state)
         return y, cache and dataclasses.replace(
-            cache, s=state, length=cache.length + S)
+            cache, **state, length=cache.length + S)
     from ray_tpu.ops.paged_attention import resolve_impl
 
     if cache is None:

@@ -114,6 +114,7 @@ target's weights); all overridable per-deployment via LLMServer init.
 
 from __future__ import annotations
 
+import math
 import threading
 import time
 from collections import deque
@@ -366,7 +367,8 @@ class ContinuousScheduler:
                                            paged_prefill_into_slot,
                                            paged_verify_step,
                                            step_rides_chunk)
-        from ray_tpu.models.transformer import LINEAR, SPARSE
+        from ray_tpu.models.transformer import (LINEAR, RETENTION, SPARSE,
+                                                STATE_KINDS, state_shapes)
         from ray_tpu.ops.paged_attention import resolve_impl
         from ray_tpu.serve._private.paging import PageArena, RadixCache
 
@@ -428,19 +430,33 @@ class ContinuousScheduler:
         # fails the constructor, not some later decode step, and stats()
         # always names what really runs
         self.attn_lane = resolve_impl(cfg, attn)
+        # a model none of whose layers holds a page: memory is a state a
+        # slot and a request is bounded by arena_len alone. Of the pages
+        # above (the knobs were checked as given) one a slot is left that is
+        # never handed out: no pool on the device, no table uploaded, no
+        # allocator work a turn
+        self._paged = cfg.holds_pages
+        if not self._paged:
+            self.page_tokens, self._pages_per_slot = self.arena_len, 1
+            self.num_pages = 1
+            self._arena = PageArena(1, self.arena_len, pageless=True)
+            self._read_tables = np.zeros((self.slots, 1), np.int32)
+            self._write_tables = np.zeros((self.slots, 1), np.int32)
         if cfg.recurrent:
             # a spliced prefix would need the states as they stood at its
             # last token, a rejected draft their rewind: no snapshot is kept
             if prefix_cache:
                 raise ValueError(
-                    "prefix_cache=True cannot serve a model with "
-                    "'lightning-attn' layers: their state at a prefix's "
-                    "end is not kept")
+                    "prefix_cache=True cannot serve a model with layers "
+                    "that keep a state a slot ('lightning-attn', "
+                    "'power-retention'): their state at a prefix's end is "
+                    "not kept")
             if drafter is not None:
                 raise ValueError(
-                    "speculative decoding cannot serve a model with "
-                    "'lightning-attn' layers: a rejected draft would have "
-                    "to rewind their states")
+                    "speculative decoding cannot serve a model with layers "
+                    "that keep a state a slot ('lightning-attn', "
+                    "'power-retention'): a rejected draft would have to "
+                    "rewind their states")
             self._radix = None  # the configured default cannot apply
         # an expert layer's programs hand the rows each expert received
         # back with the ids
@@ -470,10 +486,12 @@ class ContinuousScheduler:
         # a slot, and among the first those that attend chosen blocks
         kinds = cfg.kinds
         self._n_linear = kinds.count(LINEAR)
+        self._n_retention = kinds.count(RETENTION)
         self._n_sparse = kinds.count(SPARSE)
-        self._n_paged = len(kinds) - self._n_linear
-        self._state_bytes = (self._n_linear * self.slots * cfg.num_heads
-                             * cfg.head_dim * cfg.head_dim * 4)
+        self._n_paged = sum(kind not in STATE_KINDS for kind in kinds)
+        self._state_bytes = 4 * sum(
+            math.prod(shape) for kind in kinds if kind in STATE_KINDS
+            for shape in state_shapes(cfg, kind, self.slots).values())
         if self._n_sparse:
             # tokens of one block of the step's kernel over a row's table
             # of chosen pages (``sparse_attention._step_attention``)
@@ -584,6 +602,9 @@ class ContinuousScheduler:
         # layers of other kinds (a layer-call: one layer in one program run)
         self._n_linear_chunk_calls = 0
         self._n_linear_step_rows = 0
+        self._n_retention_chunk_calls = 0
+        self._n_retention_chunk_tokens = 0
+        self._n_retention_step_rows = 0
         self._n_sparse_rows = 0
         self._n_sparse_rows_dense = 0
         self._n_sparse_attended = 0
@@ -624,8 +645,10 @@ class ContinuousScheduler:
         over-budget request is rejected loudly at submit, before any
         pages are allocated."""
         c = self.prefill_chunk
-        effective = min(self.arena_len,
-                        self._arena.usable_pages * self.page_tokens)
+        effective = self.arena_len
+        if self._paged:
+            effective = min(effective,
+                            self._arena.usable_pages * self.page_tokens)
         # with speculation on, a verify round near the end of generation
         # writes up to spec_k positions past the final cursor — reserve
         # them so the window's writes can never clip onto the slot's
@@ -818,6 +841,8 @@ class ContinuousScheduler:
         pages are present."""
         from ray_tpu.serve._private.paging import OutOfPagesError
 
+        if not self._paged:
+            return True  # arena_len bounded the request at submit
         need = -(-upto // self.page_tokens)
         missing = need - seq.table_fill
         if missing <= 0:
@@ -970,7 +995,12 @@ class ContinuousScheduler:
         the hot loop. ``attn_tokens_attended`` over ``attn_tokens_fetched``
         is the block fill share (per layer: every layer that holds pages
         repeats the same fetches; a model all of whose such layers attend
-        chosen blocks is counted by ``_record_sparse``)."""
+        chosen blocks is counted by ``_record_sparse``). The layers that
+        keep a state are counted by kind, a layer-call one layer in one
+        program run: live rows x layers of the one-row update, layer-calls
+        of the chunked scan (and, for 'power-retention', the real tokens
+        they carried). For a model that holds no page the ``attn_*`` counts
+        stay 0: they count pages."""
         from ray_tpu.ops.paged_attention import streamed_tokens
 
         cfg = self.cfg
@@ -980,6 +1010,16 @@ class ContinuousScheduler:
                 self._n_linear_step_rows += self._n_linear * rows
             else:
                 self._n_linear_chunk_calls += self._n_linear * rows
+        if self._n_retention:
+            if qk == 1:
+                self._n_retention_step_rows += self._n_retention * rows
+            else:
+                calls = self._n_retention * rows
+                self._n_retention_chunk_calls += calls
+                self._n_retention_chunk_tokens += calls * (
+                    qk if real is None else real)
+        if not self._n_paged:
+            return
         row = cfg.kv_heads * cfg.head_dim * self._kv_itemsize
         if self._n_sparse:
             attended, fetched = self._record_sparse(
@@ -1048,6 +1088,19 @@ class ContinuousScheduler:
 
         return np.fromiter((0 if s is None else s.cursor
                             for s in self._slot_seqs), np.int32, self.slots)
+
+    def _tables(self, slot: Optional[int] = None):
+        """(read, write) page tables for a program: all slots' or one
+        ``slot``'s rows, as COPIES — dispatch is async and an upload may
+        alias (CPU) or still be reading (TPU) the host buffer, while the
+        host frees and hands out pages before anything waits for the
+        program. (None, None) for a model that holds no page: nothing is
+        uploaded."""
+        if not self._paged:
+            return None, None
+        rows = slice(None) if slot is None else slot
+        return (self._read_tables[rows].copy(),
+                self._write_tables[rows].copy())
 
     def _launch(self, out, *, step: bool, chunk: bool, rows: List[_Seq],
                 live_rows: int) -> None:
@@ -1182,7 +1235,6 @@ class ContinuousScheduler:
         (``_collect``). The program's device time is read from a profiler
         trace by its name, and the wait for it falls into the phase that
         reads its result."""
-        import jax.numpy as jnp
         import numpy as np
 
         from ray_tpu.models.decode import StepRows
@@ -1192,19 +1244,13 @@ class ContinuousScheduler:
         step = None
         if self._fused:
             r = rows or self._no_rows
-            step = StepRows(r.active, self._cursors(),
-                            self._read_tables.copy(),
-                            self._write_tables.copy(), r.temperature, r.seeds)
+            step = StepRows(r.active, self._cursors(), *self._tables(),
+                            r.temperature, r.seeds)
         self._n_prefill_tokens += real
-        # the rows are uploaded as COPIES: dispatch is async and an
-        # upload may alias (CPU) or still be reading (TPU) the host
-        # buffer, while _offer_prompt_pages and _ensure_pages write
-        # to these rows before anything waits for this chunk
         self._launch(self._prefill(
             self.params, tokens, np.int32(real), np.int32(seq.cursor),
-            jnp.asarray(self._read_tables[seq.slot].copy()),
-            jnp.asarray(self._write_tables[seq.slot].copy()),
-            self._caches, self._ids, np.int32(seq.slot if last else -1),
+            *self._tables(seq.slot), self._caches, self._ids,
+            np.int32(seq.slot if last else -1),
             np.float32(seq.temperature), np.uint32(seq.seed), step,
             np.int32(seq.slot)),
             step=bool(live), chunk=True,
@@ -1423,8 +1469,9 @@ class ContinuousScheduler:
         only for the duration of the gather."""
         if self.cfg.recurrent:
             raise ValueError(
-                "a model with 'lightning-attn' layers exports no prefix: "
-                "the pages alone do not continue a sequence")
+                "a model with layers that keep a state a slot exports no "
+                "prefix: pages alone, if it holds any, do not continue a "
+                "sequence")
         if self._radix is None:
             return {"matched_len": 0, "page_tokens": self.page_tokens,
                     "k": [], "v": []}
@@ -1716,12 +1763,10 @@ class ContinuousScheduler:
         if chunk is not None and self._fused:
             self._dispatch_chunk(*chunk, rows)
         elif rows.live:
-            # the tables go up as COPIES: the host frees and hands out
-            # pages while this step is in flight (see _dispatch_chunk)
+            # the tables go up as COPIES (see _tables)
             self._launch(self._step(
                 self.params, self._ids, rows.active, self._cursors(),
-                self._read_tables.copy(), self._write_tables.copy(),
-                self._caches, rows.temperature, rows.seeds),
+                *self._tables(), self._caches, rows.temperature, rows.seeds),
                 step=True, chunk=False, rows=rows.live,
                 live_rows=len(rows.live))
             self._stepped(rows.live)
@@ -1928,13 +1973,22 @@ class ContinuousScheduler:
         out["attn_bytes_moved"] = self._n_attn_bytes
         out["attn_tokens_attended"] = self._n_attn_attended
         out["attn_tokens_fetched"] = self._n_attn_fetched
-        if self._n_linear:
-            # a state a slot a 'lightning-attn' layer, float32; layer-calls
-            # of the chunked scan; live rows x layers of the one-row update
+        if self._state_bytes:
+            # a float32 state a slot a layer that keeps one, by kind
+            # (``transformer.state_shapes``)
             out["state_slots"] = self.slots
             out["state_bytes"] = self._state_bytes
+        if self._n_linear:
+            # layer-calls of the chunked scan; live rows x layers of the
+            # one-row update
             out["linear_chunk_calls"] = self._n_linear_chunk_calls
             out["linear_step_rows"] = self._n_linear_step_rows
+        if self._n_retention:
+            # the same of the 'power-retention' layers, and the real tokens
+            # their chunk calls carried
+            out["retention_chunk_calls"] = self._n_retention_chunk_calls
+            out["retention_chunk_tokens"] = self._n_retention_chunk_tokens
+            out["retention_step_rows"] = self._n_retention_step_rows
         if self._n_sparse:
             # query rows x 'minicpm4' layers (real tokens of a chunk, live
             # rows of a step), those at or under dense_len, and the tokens

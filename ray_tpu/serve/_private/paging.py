@@ -63,15 +63,18 @@ class OutOfPagesError(RuntimeError):
 class PageArena:
     """Free-list allocator over the paged KV pool. Page ids are indices
     into the device-side ``PagedKVCache`` pools; page 0 never leaves the
-    allocator (it is the shared garbage page)."""
+    allocator (it is the shared garbage page). ``pageless``: the arena of a
+    model none of whose layers holds a page — it may be the reserved page
+    alone, hands nothing out and reads 0 wherever pages are counted."""
 
-    def __init__(self, num_pages: int, page_tokens: int):
+    def __init__(self, num_pages: int, page_tokens: int,
+                 pageless: bool = False):
         if page_tokens < 1:
             # the PR-8/PR-9 falsy-zero lesson: an explicit 0 must raise
             # here, never silently become some default upstream
             raise ValueError(
                 f"page_tokens must be >= 1, got {page_tokens}")
-        if num_pages < 2:
+        if num_pages < 2 and not pageless:
             raise ValueError(
                 f"kv arena needs >= 2 pages (page 0 is reserved), "
                 f"got {num_pages}")

@@ -51,7 +51,13 @@ def v5e():
 def as_a_tpu_process(monkeypatch):
     """Compile for the described chip: kernels go through Mosaic and
     ``auto`` picks the TPU lanes. The persistent compile cache is off — a
-    described-device executable can be written but never read back."""
+    described-device executable can be written but never read back. On the
+    way out JAX's own caches are cleared: a kernel's jitted wrapper traced
+    here holds a Mosaic call, and a later test of the same process that
+    calls it at the same shapes on the CPU would be handed that trace
+    ("Only interpret mode is supported on CPU backend": six cases of
+    ``tests/test_serve_fused_turn.py[moe_debug]`` whenever xdist paired the
+    two files, PR 41)."""
     from jax.experimental.compilation_cache import compilation_cache
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -61,6 +67,7 @@ def as_a_tpu_process(monkeypatch):
     yield
     jax.config.update("jax_enable_compilation_cache", enabled)
     compilation_cache.reset_cache()
+    jax.clear_caches()
 
 
 def _on(sharding, shape, dtype=jnp.bfloat16):
@@ -553,3 +560,88 @@ def test_minicpm_sala_serve_programs_compile_and_fit(v5e):
         total = _fits(compiled)
         # the programs' own memory leaves room for the reference check
         assert total < 14.5e9, f"{name}: {total / 1e9:.1f} GB"
+
+
+def _pageless_programs(v5e, cfg, slots: int, chunk: int):
+    """The scheduler's two programs for a model none of whose layers holds a
+    page, as it calls them: no page table (None), states a slot, the chunk
+    alone. (cfg's bytes held by weights and states, {name: (program,
+    arguments)})."""
+    from ray_tpu.models.decode import (init_paged_caches, paged_decode_step,
+                                       paged_prefill_into_slot,
+                                       step_rides_chunk)
+    from ray_tpu.models.transformer import init_params
+
+    assert not cfg.holds_pages and not step_rides_chunk(cfg)
+    chip = SingleDeviceSharding(v5e.devices[0])
+    place = lambda tree: jax.tree.map(
+        lambda a: _on(chip, a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(
+        functools.partial(init_params, cfg), jax.random.PRNGKey(0)))
+    caches = place(jax.eval_shape(functools.partial(
+        init_paged_caches, cfg, 1, chunk, 1, slots=slots)))
+    held = sum(a.size * a.dtype.itemsize
+               for a in jax.tree.leaves((params, caches)))
+    ids = functools.partial(_on, chip, dtype=jnp.int32)
+    rows = (_on(chip, (slots,), jnp.float32), _on(chip, (slots,), jnp.uint32))
+    return held, {
+        "prefill": (paged_prefill_into_slot,
+                    (params, ids((1, chunk)), ids(()), ids(()), None, None,
+                     caches, ids((slots,)), ids(()),
+                     _on(chip, (), jnp.float32), _on(chip, (), jnp.uint32),
+                     None, ids(()))),
+        "decode": (paged_decode_step,
+                   (params, ids((slots,)), ids((slots,)), ids((slots,)),
+                    None, None, caches, *rows)),
+    }
+
+
+RETENTION_KERNELS = {"prefill": {"power_retention_chunk"},
+                     "decode": {"power_retention_step"}}
+
+
+def test_brumby_debug_serve_programs_lower_with_the_retention_kernels(v5e):
+    """The two serve programs of the toy Brumby (float32, heads of 32, five
+    query heads on each of two states, no page anywhere) go through Mosaic:
+    the chunk's program holds ``power_retention_chunk`` and the step's
+    ``power_retention_step``, once a layer, and no other kernel."""
+    from ray_tpu.models.presets import brumby_debug
+
+    cfg = brumby_debug()
+    _, programs = _pageless_programs(v5e, cfg, slots=4, chunk=64)
+    for name, (program, args) in programs.items():
+        compiled = jax.jit(functools.partial(program, cfg, attn="pallas"),
+                           donate_argnums=(6,)).lower(*args).compile()
+        calls = _kernel_calls(compiled)
+        assert set(calls) == RETENTION_KERNELS[name], (name, calls)
+        _fits(compiled)
+
+
+def test_brumby_serve_programs_compile_and_fit(v5e):
+    """The benchmark's Brumby-14B configuration (published widths, 8 layers,
+    bf16) under its cell's deployment: the prefill chunk and the decode
+    step with their retention kernel inside, 8.4 GB of weights and 4.4 GB of
+    states (16 slots x 8 layers x 34.35 MB) beside the programs' own memory
+    on one 16 GB chip."""
+    from perfbench.lib import configs
+    from perfbench.lib import manifest as manifest_lib
+
+    manifest = manifest_lib.load()
+    hp = manifest_lib.config(manifest, "brumby_14b_l8")
+    cfg = configs.build_program_config(*configs.program_overrides(
+        hp, manifest_lib.read_json_from_bench("families", hp["model_type"])))
+    dep = manifest_lib.read_json(manifest, "cells",
+                                 "brumby_longgen")["deployment"]
+    assert "page_tokens" not in dep and "kv_pages" not in dep
+    held, programs = _pageless_programs(v5e, cfg, dep["slots"],
+                                        dep["prefill_chunk"])
+    assert 12.6e9 < held < 13.0e9
+    for name, (program, args) in programs.items():
+        compiled = jax.jit(functools.partial(program, cfg, attn="pallas"),
+                           donate_argnums=(6,)).lower(*args).compile()
+        found = {re.sub(r"[.\d]+$", "", k)
+                 for k in _kernel_names(compiled.as_text())}
+        assert found == RETENTION_KERNELS[name], (name, found)
+        total = _fits(compiled)
+        # the programs' own memory leaves room for the reference check
+        assert total < 14.6e9, f"{name}: {total / 1e9:.1f} GB"
