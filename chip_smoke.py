@@ -33,7 +33,10 @@ weights from ``--seed``):
            6%), with the share of (query, K/V group, block) choices that
            differ from the reference's own and how far below the reference's
            cut the system's blocks scored; a slot without a sequence keeps a
-           zero state
+           zero state; then a turn with a chunk as ONE program (a new
+           prompt's chunk with the decoding row along) against the chunk
+           alone and then the step, inside the cell's limits, and once with
+           every row idle (the other slots' states bitwise as they were)
   brumby   a @ray_tpu.remote(num_tpus=1) task runs the benchmark's
            Brumby-14B configuration (published widths, 8 layers, every
            mixer power retention, bf16, no page anywhere): the two kernels
@@ -43,7 +46,8 @@ weights from ``--seed``):
            the paged prefill chunks (the last one padded) and 6 decode steps
            beside a second live row, against perfbench/reference/brumby.py
            (inside the cell's limits); a slot without a sequence keeps a
-           zero state
+           zero state; then the turn as one program against the two, and
+           with every row idle, as in sala
   serve    serve.run(build_app(preset="gpt2_small")) answers 8 concurrent
            requests: six through the handle, one streamed, one over HTTP
 
@@ -481,6 +485,87 @@ def olmoe_task(seed: int) -> dict:
     return {**out, **device_report()}
 
 
+def fused_turn(prefill, step, params, caches, ids, tables, cursors, active,
+               into: int, vocab: int, chunk: int, tol: dict,
+               seed: int) -> dict:
+    """A turn with a chunk as ONE program against the two it replaced (ISSUE
+    44), on the chip and on the same inputs: a new prompt's first chunk
+    into the free slot ``into`` while the rows of ``active`` decode — the
+    chunk's program with the step's rows along against the chunk alone and
+    then the step — and once more with EVERY row idle, which must leave
+    every other slot's states bitwise alone. ``prefill`` / ``step``: the
+    task's jitted programs (donated caches, ``logits=True``); ``tables``
+    None for a model without pages. Raises outside ``tol`` (a cell's
+    ``check_tolerance``); returns what it read."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models.decode import StepRows
+
+    S = len(active)
+    copy = lambda: jax.tree.map(jnp.copy, caches)
+    rows = lambda t: (None, None) if tables is None else (jnp.asarray(t),) * 2
+    states = lambda cs: [a for c in cs if hasattr(c, "arrays")
+                         for a in c.arrays().values()]
+    tokens = np.random.default_rng(seed).integers(
+        1, vocab, (1, chunk)).astype(np.int32)
+    args = (params, tokens, np.int32(chunk), np.int32(0),
+            *rows(None if tables is None else tables[into]))
+    tail = (np.int32(into), np.float32(0), np.uint32(0))
+    greedy = (np.zeros(S, np.float32), np.zeros(S, np.uint32))
+    alone = prefill(*args, copy(), ids, *tail, None, np.int32(into))
+    first = np.asarray(alone[2], np.float32)
+    two = step(params, alone[0], jnp.asarray(active), cursors, *rows(tables),
+               alone[1], *greedy)
+
+    def one(live):
+        return prefill(*args, copy(), ids, *tail, StepRows(
+            jnp.asarray(live), cursors, *rows(tables), *greedy),
+            np.int32(into))
+
+    def off(got, want):
+        got, want = (np.asarray(x, np.float32) for x in (got, want))
+        scale = np.abs(want).max()
+        return {"max": float(np.abs(got - want).max() / scale),
+                "rms": float(np.sqrt(((got - want) ** 2).mean()
+                                     / (want ** 2).mean())),
+                "margin": float(max(
+                    (g.max() - g[w.argmax()]) / scale
+                    for g, w in zip(got.reshape(-1, got.shape[-1]),
+                                    want.reshape(-1, want.shape[-1]))))}
+
+    live = np.flatnonzero(active)
+    fused = one(active)
+    out = {"first_token": off(fused[2][0], first),
+           "live_rows": off(fused[2][1 + live], two[2][live]),
+           "ids_differing": int((np.asarray(fused[0])
+                                 != np.asarray(two[0])).sum()),
+           "states": max(float(np.abs(np.asarray(a) - np.asarray(b)).max()
+                               / max(np.abs(np.asarray(b)).max(), 1e-30))
+                         for a, b in zip(states(fused[1]), states(two[1])))}
+    del fused
+    idle = one(np.zeros_like(active))
+    out["idle_first_token"] = off(idle[2][0], first)
+    others = [r for r in range(S) if r != into]
+    bad = []
+    if not all(np.array_equal(np.asarray(a)[others], np.asarray(b)[others])
+               for a, b in zip(states(idle[1]), states(caches))):
+        bad.append("a row that is not live did not keep its state bitwise")
+    if not np.array_equal(np.asarray(idle[0])[others],
+                          np.asarray(ids)[others]):
+        bad.append("a row that is not live was given a token")
+    for name in ("first_token", "live_rows", "idle_first_token"):
+        e = out[name]
+        if (e["max"] > tol["logit_err"] or e["rms"] > tol["logit_rms_err"]
+                or e["margin"] > tol["served_margin"]):
+            bad.append(f"{name}: one program is outside the cell's limits "
+                       "of the two")
+    if bad:
+        raise RuntimeError(f"fused turn: {bad}: {out}")
+    return out
+
+
 def sala_task(seed: int) -> dict:
     """The MiniCPM-SALA-width checks (ISSUE 32): the system in bf16 — paged
     prefill chunks, then decode steps, on a prompt past ``dense_len`` so
@@ -500,6 +585,8 @@ def sala_task(seed: int) -> dict:
     require_chip()
     manifest = manifest_lib.load()
     hp = manifest_lib.config(manifest, "minicpm_sala_l16")
+    tol = manifest_lib.read_json(manifest, "cells",
+                                 "minicpm_sala_longdoc")["check_tolerance"]
     cfg = configs.build_program_config(*configs.program_overrides(
         hp, manifest_lib.read_json_from_bench("families", hp["model_type"])))
     params = weights.make_params(cfg, seed)
@@ -547,6 +634,10 @@ def sala_task(seed: int) -> dict:
     # another slot's states were never touched
     idle_states = float(max(np.abs(np.asarray(c.s[0])).max()
                             for c in caches if hasattr(c, "s")))
+    # the turn as one program: a new prompt's chunk into slot 0 beside the
+    # decoding row, against the two programs above
+    turn = fused_turn(prefill, step, params, caches, ids, tables, cursors,
+                      active, 0, cfg.vocab_size, C, tol, seed)
     del caches
     tokens = jnp.asarray([prompt + fed], jnp.int32)
     mine = np.concatenate(chose, axis=1)[:, None]   # [layers, 1, S, Hkv, NB]
@@ -573,7 +664,7 @@ def sala_task(seed: int) -> dict:
     out = {"given_err": err, "choices_differing": differ,
            "choices": chosen, "differing_share": differ / max(chosen, 1),
            "worst_margin": max(margins, default=0.0),
-           "idle_slot_state": idle_states,
+           "idle_slot_state": idle_states, "fused_turn": turn,
            "chunk_ms_by_position": [round(1e3 * x, 1) for x in chunk_s],
            "step_ms": [round(1e3 * x, 1) for x in step_s]}
     bad = []
@@ -697,6 +788,10 @@ def brumby_task(seed: int) -> dict:
     served_on_device = np.asarray(ids)
     idle_states = float(max(np.abs(np.asarray(a[0])).max() for c in caches
                             for a in c.arrays().values()))
+    # the turn as one program: a new prompt's chunk into slot 0 beside the
+    # two decoding rows, against the two programs above
+    turn = fused_turn(prefill, step, params, caches, ids, None, cursors,
+                      active, 0, cfg.vocab_size, C, tol, seed)
     del caches
     err = {}
     for row, prompt in prompts.items():
@@ -712,7 +807,7 @@ def brumby_task(seed: int) -> dict:
                           for w, t in zip(want, fed[row]))}
         del want
     out = {"kernel_err_f32": kernel_err, "paged_err": err,
-           "idle_slot_state": idle_states,
+           "idle_slot_state": idle_states, "fused_turn": turn,
            "ids_on_device": [int(served_on_device[r]) for r in prompts],
            "chunk_ms": [round(1e3 * x, 1) for x in chunk_s],
            "step_ms": [round(1e3 * x, 1) for x in step_s]}

@@ -19,15 +19,16 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models.transformer import (ATTENTION, LINEAR, RETENTION, SPARSE,
-                                        STATE_KINDS, TransformerConfig, _mlp,
+                                        STATE_KINDS, STATE_MIXERS,
+                                        TransformerConfig, _gated_out, _mlp,
                                         _norm, _qkv, _residual, embed,
                                         final_hidden, forward, layer_params,
-                                        linear_mixer, project,
-                                        retention_mixer, rope_table,
-                                        sparse_mixer, sparse_pool_pages,
-                                        stacked_mlp, state_shapes)
+                                        project, rope_table,
+                                        sparse_mix, sparse_pool_pages,
+                                        stacked_mlp, state_shapes,
+                                        write_pages, written_pages)
 from ray_tpu.ops.paged_attention import paged_attention
-from ray_tpu.ops.sparse_attention import check_pool
+from ray_tpu.ops.sparse_attention import check_pool, update_page_means
 
 
 @jax.tree_util.register_dataclass
@@ -407,10 +408,15 @@ def _state_kinds(cfg: TransformerConfig) -> str:
 
 
 class _Rows(NamedTuple):
-    """One group of rows of a paged program: what attends at ONE shape.
-    tokens/positions/valid: [S, K]; lengths: [S] attention cursors;
-    read_tables/write_tables: [S, P] (None for a model that holds no
-    page)."""
+    """One group of rows of a paged program: what meets a layer's pages or
+    states at ONE shape. tokens/positions/valid: [S, K]; lengths: [S]
+    attention cursors; read_tables/write_tables: [S, P] (None for a model
+    that holds no page). For the layers that keep a state a slot
+    (``STATE_KINDS``) the group is a step's — a row a slot over ALL slots'
+    states, of which the rows not ``active`` [S] keep theirs bitwise — or,
+    with ``slot``, a chunk's: its one row continues that slot's own states,
+    taken as zero when the chunk starts at position 0 (a new sequence needs
+    no reset beforehand), by its ``real_len`` real tokens."""
 
     tokens: Any
     positions: Any
@@ -418,6 +424,9 @@ class _Rows(NamedTuple):
     read_tables: Any
     write_tables: Any
     valid: Any
+    active: Any = None
+    slot: Any = None
+    real_len: Any = None
 
 
 class StepRows(NamedTuple):
@@ -433,46 +442,70 @@ class StepRows(NamedTuple):
     seeds: Any
 
 
-def step_rides_chunk(cfg: TransformerConfig) -> bool:
-    """Whether a prefill chunk's program can take the live decode rows
-    along: where every layer holds pages through ``ops.paged_attention``. A
-    'minicpm4' or 'lightning-attn' layer has chunk and step kernels of its
-    own shapes, as a 'power-retention' layer has, and such a model's turn
-    stays two programs."""
-    return all(kind == ATTENTION for kind in cfg.kinds)
+def _unless_idle(group: _Rows, several: bool, call, kept):
+    """``call(kept) -> (made, kept)``: what a layer does with ONE group's
+    rows. A step's rows that ride in a chunk's program (``several`` groups)
+    may have none live — a first prompt's chunks, the whole time to the
+    first token at low load — and the call is then skipped inside the
+    program: ``made`` comes back as zeros, ``kept`` as it came, and a chunk
+    pays nothing for the rows it did not take along."""
+    if not several or group.active is None:
+        return call(kept)
+    made = jax.eval_shape(call, kept)[0]
+    return lax.cond(
+        jnp.any(group.active > 0), call,
+        lambda kept: (jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype),
+                                   made), kept), kept)
+
+
+def _mix_states(cfg, mix, group: _Rows, rows, state, several: bool):
+    """One group's rows (``rows``: what the kind's projection made of them)
+    through a state layer's ``state`` ({name: [slots, ...]}, every slot's)
+    -> (o, state). A chunk's group cuts its slot's states out and puts them
+    back; a step's goes over all of them in place."""
+    if group.slot is None:
+        return _unless_idle(
+            group, several,
+            lambda state: mix(cfg, rows, state, active=group.active), state)
+    own = {n: jnp.where(group.positions[0, 0] == 0, 0.0,
+                        lax.dynamic_slice_in_dim(s, group.slot, 1, axis=0))
+           for n, s in state.items()}
+    o, own = mix(cfg, rows, own, real_len=group.real_len)
+    return o, {n: lax.dynamic_update_slice_in_dim(state[n], s, group.slot,
+                                                  axis=0)
+               for n, s in own.items()}
 
 
 def _paged_forward_inplace(cfg: TransformerConfig, params,
-                           groups: List[_Rows], caches, impl, *, slot=None,
-                           real_len=None, active=None, taps=None):
-    """The serving forward: one pass over the rows of ``groups`` where each
-    attention layer (1) writes the rows' k/v DIRECTLY into their pages —
-    ``pool.at[page, offset].set`` through the write tables,
+                           groups: List[_Rows], caches, impl, *, taps=None):
+    """The serving forward: one pass over the rows of ``groups``. ONE RULE
+    for every kind of layer: the rows go through the norms, the projections
+    (q/k/v, gates, ``wo``) and the MLP or expert layer as one batch, and
+    meet what the layer KEEPS a group at a time, each group at its own
+    shape. An attention layer (1) writes the rows' k/v DIRECTLY into their
+    pages — ``pool.at[page, offset].set`` through the write tables,
     write-before-attend, so XLA updates the donated pool in place — and (2)
-    attends through the read tables via ``ops.paged_attention(impl=)``; a
-    'minicpm4' layer does the same through ``transformer.sparse_mixer`` and
-    attends the blocks it chooses; a 'lightning-attn' or 'power-retention'
-    layer (``transformer.linear_mixer``, ``retention_mixer``) reads and
-    writes its states instead: all slots' in a step, of which the rows not
-    ``active`` [S] keep theirs
-    bitwise, or in a chunk (``slot`` given: the one row is that slot's) the
-    slot's own, taken as zero when the chunk starts at position 0 — a new
-    sequence needs no reset beforehand — and advanced by the chunk's
-    ``real_len`` real tokens. Layer math mirrors ``transformer._block``.
+    attends through the read tables via ``ops.paged_attention(impl=)``, a
+    call a group; a 'minicpm4' layer writes the same way and recomputes the
+    pooled rows of the pages written, then attends the blocks it chooses, a
+    call a group (``transformer.sparse_mix``); a
+    'lightning-attn' or 'power-retention' layer (``transformer
+    .STATE_MIXERS``) reads and writes its states instead, a kernel call a
+    group (``_Rows`` says whose states a group's rows meet). Layer math
+    mirrors ``transformer._block``.
 
     One group (``_Rows``: a step's or a verify's [S, K] window over all
     slots, a chunk's [1, C]) is the whole batch as it stands. SEVERAL — a
-    chunk and the step's rows it takes along, plain attention layers only —
-    go through every norm, projection and the MLP or expert layer as ONE
-    batch ``[1, sum of S x K]``, read the weights once, and their k/v land in
-    the pool in ONE write; only attention is called a group, each at its
-    own shape. No two rows that matter may share a position: the caller
-    sends every row whose write must not land to the garbage page through
-    its group's write table. Positions on unallocated/shared pages redirect
-    there the same way. ``valid`` marks the rows that carry a live token
-    (not a slot without a sequence, not a chunk's padding): the expert layer
-    routes the others nowhere. ``taps``: a list that is given each
-    'minicpm4' layer's choice (debug).
+    chunk and the step's rows it takes along — are ONE batch ``[1, sum of S
+    x K]`` outside those calls, read the weights once, and their k/v land in
+    the pool in ONE write. No two rows that matter may share a position or
+    a state: the caller sends every row whose write must not land to the
+    garbage page through its group's write table and marks it not active.
+    Positions on unallocated/shared pages redirect there the same way.
+    ``valid`` marks the rows that carry a live token (not a slot without a
+    sequence, not a chunk's padding): the expert layer routes the others
+    nowhere. ``taps``: a list that is given each 'minicpm4' layer's choice,
+    a group at a time (debug).
     Returns (hidden, caches, moe): the rows after the last layer, BEFORE the
     final norm, [S, K, d] (several groups: [1, rows, d], group after
     group) — the caller norms and projects the rows it samples (``_head``);
@@ -480,11 +513,8 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
     *rows, k]}`` — the rows each layer's experts received (they sum to valid
     rows x k a layer: no row is dropped; several groups: [L, groups, E], the
     rows each GROUP sent them) and the experts each row chose."""
-    first = groups[0]
-    if len(groups) > 1 and not step_rides_chunk(cfg):
-        raise ValueError("several groups of rows in one paged program need "
-                         "plain attention layers throughout")
-    lead = first.tokens.shape if len(groups) == 1 else (1, -1)
+    several = len(groups) > 1
+    lead = (1, -1) if several else groups[0].tokens.shape
 
     def batch(parts):
         """The groups' rows as the one batch: a lone group as it stands."""
@@ -495,7 +525,7 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
 
     def split(rows):
         """The batch's rows group by group, each in its group's shape."""
-        if len(groups) == 1:
+        if not several:
             return [rows]
         return [rows[:, end - g.tokens.size:end].reshape(
             g.tokens.shape + rows.shape[2:]) for g, end in zip(groups, ends)]
@@ -507,12 +537,11 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
     if cfg.pos == "learned":
         x = x + params["pos_embed"]["table"].astype(cfg.dtype)[positions]
     rope = rope_table(cfg)
-    if ATTENTION in cfg.kinds:
-        T = caches[cfg.kinds.index(ATTENTION)].k.shape[1]
-        pages = batch([jnp.take_along_axis(
-            g.write_tables,
-            jnp.clip(g.positions // T, 0, g.write_tables.shape[1] - 1),
-            axis=1) for g in groups])
+    if cfg.holds_pages:
+        T = next(c.k.shape[1] for c, kind in zip(caches, cfg.kinds)
+                 if kind not in STATE_KINDS)
+        pages = batch([written_pages(g.write_tables, g.positions, T)
+                       for g in groups])
         offs = positions % T
     new_caches = []
     moe_layers = []
@@ -522,46 +551,41 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
         ap = p["attn"]
         h = _norm(cfg, p["ln1"], x)
         if kind in STATE_KINDS:
-            # a step: every slot's state; a chunk: the slot's own, zero
-            # where the chunk starts a sequence
-            state, how = c.arrays(), {"active": active}
-            if slot is not None:
-                state = {n: jnp.where(
-                    positions[0, 0] == 0, 0.0,
-                    lax.dynamic_slice_in_dim(s, slot, 1, axis=0))
-                    for n, s in state.items()}
-                how = {"real_len": real_len}
-            if kind == LINEAR:
-                a, state["s"] = linear_mixer(cfg, ap, h, positions,
-                                             state["s"], **how)
-            else:
-                a, state = retention_mixer(cfg, ap, h, positions, state,
-                                           **how)
-            if slot is not None:
-                state = {n: lax.dynamic_update_slice_in_dim(
-                    getattr(c, n), s, slot, axis=0) for n, s in state.items()}
+            project, mix, finish = STATE_MIXERS[kind]
+            state, outs = c.arrays(), []
+            for g, *rows in zip(groups, *map(
+                    split, project(cfg, ap, h, positions))):
+                o, state = _mix_states(cfg, mix, g, rows, state, several)
+                outs.append(o)
+            a = finish(cfg, ap, h, batch(outs))
             new_caches.append(_STATES[kind](**state))
-        elif kind == SPARSE:
-            a, pools = sparse_mixer(cfg, ap, h, positions, first.lengths,
-                                    (c.k, c.v, c.means), first.read_tables,
-                                    first.write_tables, impl=impl, taps=taps)
-            new_caches.append(SparsePagedKVCache(*pools))
         else:
-            q, k, v = _qkv(cfg, ap, h, rope, positions)
-            ck = c.k.at[pages, offs].set(
-                k.reshape(*k.shape[:2], -1).astype(c.k.dtype))
-            cv = c.v.at[pages, offs].set(
-                v.reshape(*v.shape[:2], -1).astype(c.v.dtype))
-            o = batch([paged_attention(q_g, ck, cv, g.read_tables, g.lengths,
-                                       impl=impl)
-                       for g, q_g in zip(groups, split(q))])
-            a = jnp.einsum("bshk,hkd->bsd", o, ap["wo"].astype(cfg.dtype))
-            new_caches.append(PagedKVCache(k=ck, v=cv))
+            q, k, v = _qkv(cfg, ap, h, rope if kind == ATTENTION else None,
+                           positions)
+            ck = write_pages(c.k, k, pages, offs)
+            cv = write_pages(c.v, v, pages, offs)
+            if kind == SPARSE:
+                pools = (ck, cv, update_page_means(c.means, ck, *(
+                    (g.write_tables, g.positions) for g in groups)))
+                outs, chosen = zip(*(_unless_idle(g, several, lambda _: (
+                    sparse_mix(cfg, q_g, pools, g.read_tables, g.positions,
+                               g.lengths, impl=impl), ()), ())[0]
+                    for g, q_g in zip(groups, split(q))))
+                if taps is not None:
+                    taps.extend(chosen)
+                a = _gated_out(cfg, ap, h, batch(outs))
+                new_caches.append(SparsePagedKVCache(*pools))
+            else:
+                o = batch([paged_attention(q_g, ck, cv, g.read_tables,
+                                           g.lengths, impl=impl)
+                           for g, q_g in zip(groups, split(q))])
+                a = jnp.einsum("bshk,hkd->bsd", o, ap["wo"].astype(cfg.dtype))
+                new_caches.append(PagedKVCache(k=ck, v=cv))
         x = _residual(cfg, x, a)
         mlp_p, layer = stacked_mlp(cfg, params, p, i)
         m, _, moe = _mlp(cfg, mlp_p, _norm(cfg, p["ln2"], x), valid, layer)
         x = _residual(cfg, x, m)
-        if moe is not None and len(groups) > 1:
+        if moe is not None and several:
             # the experts saw one batch; who sent them which rows is still
             # told a group, as if each group had been a program of its own
             moe["counts"] = jnp.stack([
@@ -582,15 +606,20 @@ def _head(cfg: TransformerConfig, params, hidden):
     return project(cfg, params, final_hidden(cfg, params, hidden))
 
 
-def _paged_outputs(first, caches, moe, moe_info: bool, logits, taps=None):
+def _paged_outputs(first, caches, moe, moe_info: bool, logits, taps=None,
+                   groups: int = 1):
     """What a paged program returns: ``(first, caches)``, with ``moe_info``
     the expert layers' counts and routes next, then the ``logits`` the
     ids in ``first`` were sampled from where the caller asked for them
     (else None), and last what the 'minicpm4' layers chose (``taps``,
-    stacked) where it asked for that."""
+    stacked; ``groups`` > 1: a tuple, a stack a group of rows) where it
+    asked for that."""
+    if taps is not None:
+        taps = (jnp.stack(taps) if groups == 1 else tuple(
+            jnp.stack(taps[g::groups]) for g in range(groups)))
     return ((first, caches) + ((moe,) if moe_info else ())
             + (() if logits is None else (logits,))
-            + (() if taps is None else (jnp.stack(taps),)))
+            + (() if taps is None else (taps,)))
 
 
 def _check_moe_info(cfg: TransformerConfig, moe_info: bool):
@@ -633,18 +662,22 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
     ``step`` (``StepRows``, or None: the chunk goes alone, and the ids
     vector comes back as it came but for ``slot``): what
     ``paged_decode_step`` would be given next, its tokens being ``ids``.
-    Where ``step_rides_chunk(cfg)`` the C chunk rows and the [slots] step
-    rows are ONE batch through every projection, the MLP or expert layer
-    and ONE write of k/v; attention alone is two calls a layer, ``[1, C]``
-    through the slot's row and ``[slots, 1]`` through all tables; the head
-    sees the chunk's last real row and the step rows, not the C. An active
-    row's next token replaces its entry of ids (sampled at position cursors
-    + 1) exactly as the step alone would have it, and the caller advances
-    its cursor by one. A row that is not active — the chunk's own slot
-    among them, whose cursor IS the chunk's first position — attends
-    nothing, is routed to no expert and writes to the garbage page, whatever
-    its tables hold: the chunk's positions see one write, the chunk's. A
-    model with a 'minicpm4' or 'lightning-attn' layer takes None.
+    Whatever the kinds of the model's layers, the C chunk rows and the
+    [slots] step rows are ONE batch through every projection, the MLP or
+    expert layer and ONE write of k/v; what a layer keeps is met in two
+    calls a layer, ``[1, C]`` through the slot's row of the tables or on
+    the slot's own states and ``[slots, 1]`` through all tables or over all
+    slots' states (``_paged_forward_inplace``); the head sees the chunk's
+    last real row and the step rows, not the C. An active row's next token
+    replaces its entry of ids (sampled at position cursors + 1) exactly as
+    the step alone would have it, and the caller advances its cursor by
+    one. A row that is not active — the chunk's own slot among them, whose
+    cursor IS the chunk's first position — attends nothing, is routed to no
+    expert, writes to the garbage page, whatever its tables hold, and keeps
+    its states bitwise: the chunk's positions see one write, the chunk's,
+    and its slot's states one update. Where NO row is active, what a
+    layer does with the step's rows alone (the pass over every slot's
+    states, the choice of blocks) is skipped inside the program.
 
     ``state_slot``: the slot itself, whichever chunk this is — where the
     model has layers that keep a state (``STATE_KINDS``), their states of
@@ -670,7 +703,8 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
     with ``step`` [1 + slots, vocab]: that row, then the step's (tests
     compare them with an oracle; the scheduler never asks); with
     ``selected`` the blocks the 'minicpm4' layers chose, bool [layers of
-    the kind, 1, C, Hkv, NB], last."""
+    the kind, 1, C, Hkv, NB], last (with ``step`` a pair: the chunk's, and
+    the step rows' [layers of the kind, slots, 1, Hkv, NB])."""
     _check_moe_info(cfg, moe_info)
     if cfg.recurrent and state_slot is None:
         raise ValueError(f"a model with {_state_kinds(cfg)} layers needs "
@@ -681,7 +715,8 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
     steps = jnp.arange(C, dtype=jnp.int32)[None, :]
     row = lambda table: None if table is None else table[None]
     groups = [_Rows(tokens, steps + cursor, jnp.reshape(cursor, (1,)),
-                    row(read_row), row(write_row), steps < real_len)]
+                    row(read_row), row(write_row), steps < real_len,
+                    slot=state_slot, real_len=real_len)]
     sample = (jnp.reshape(temperature, (1,)), jnp.reshape(seed, (1,)),
               jnp.reshape(cursor + real_len, (1,)))
     if step is not None:
@@ -689,14 +724,14 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
         groups.append(_Rows(
             ids[:, None], step.cursors[:, None],
             jnp.where(live, step.cursors, -1), step.read_tables,
-            jnp.where(live[:, None], step.write_tables, 0), live[:, None]))
+            None if step.write_tables is None
+            else jnp.where(live[:, None], step.write_tables, 0),
+            live[:, None], active=step.active))
         sample = tuple(jnp.concatenate(pair) for pair in zip(sample, (
             jnp.where(live, step.temperature, 0.0), step.seeds,
             step.cursors + 1)))
     hidden, new_caches, moe = _paged_forward_inplace(
-        cfg, params, groups, caches, attn,
-        slot=0 if state_slot is None else state_slot, real_len=real_len,
-        taps=taps)
+        cfg, params, groups, caches, attn, taps=taps)
     # the rows that are sampled: the chunk's last real one, then the step's
     last = lax.dynamic_slice_in_dim(hidden, real_len - 1, 1, axis=1)
     sampled_logits = _head(cfg, params, jnp.concatenate(
@@ -707,7 +742,7 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
     ids = jnp.where(jnp.arange(ids.shape[0]) == slot, sampled[0], ids)
     shown = sampled_logits[0] if step is None else sampled_logits
     return _paged_outputs(ids, new_caches, moe, moe_info,
-                          shown if logits else None, taps)
+                          shown if logits else None, taps, len(groups))
 
 
 def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
@@ -749,8 +784,9 @@ def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
     hidden, new_caches, moe = _paged_forward_inplace(
         cfg, params, [_Rows(tokens[:, None], cursors[:, None],
                             jnp.where(active > 0, cursors, -1), read_tables,
-                            write_tables, active[:, None] > 0)],
-        caches, attn, active=active, taps=taps)
+                            write_tables, active[:, None] > 0,
+                            active=active)],
+        caches, attn, taps=taps)
     all_logits = _head(cfg, params, hidden)[:, 0]
     sampled = sample_token(all_logits,
                            jnp.where(active > 0, temperature, 0.0), seeds,

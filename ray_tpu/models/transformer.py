@@ -26,11 +26,13 @@ Config switches:
     'power-retention' (degree 2, gated and normalised, on the symmetric
     half of the outer product as the state of a K/V head, shared by its
     query heads; q/k norm and RoPE as the block has them, no output gate;
-    ops/power_retention.py). Each is written once, state in and state out
-    (``sparse_mixer``, ``linear_mixer``, ``retention_mixer``), and called
-    from every forward: this one with or without caches, and the paged
-    serving forward of models/decode.py. What a kind that keeps a state a
-    sequence keeps is said in ONE place, ``state_shapes``.
+    ops/power_retention.py). Each is written once, state in and state out,
+    as three pieces — project the rows, mix one group of them, finish the
+    rows (``STATE_MIXERS``, ``sparse_mix``) — that every forward calls: this
+    one with or without caches (``state_mixer``, ``sparse_mixer``), and the
+    paged serving forward of models/decode.py, which mixes a group of rows
+    at a time. What a kind that keeps a state a sequence keeps is said in
+    ONE place, ``state_shapes``.
   * scale_emb, scale_depth (over scale_depth_layers), dim_model_base: the
     MiniCPM scales of the embedding, of every residual branch and of the
     hidden state before the output head.
@@ -423,9 +425,17 @@ def _attn(cfg, p, x, rope, positions, sp_axis, kv_cache=None):
     return out, new_cache
 
 
-# The mixers that are no plain attention, each written once: the state comes
-# in and goes out, and who holds it (nobody, a contiguous cache, the serving
-# pool) is the caller's business.
+# The mixers that are no plain attention, each written once and in three
+# pieces: PROJECT the rows (q, k, v and a gate: the weights, whatever the
+# rows are), MIX one group of rows — rows that meet the state or the pool at
+# ONE shape, a step's one token a row or a chunk's run — and FINISH the rows
+# (output norm, gate, ``wo``: weights again). A forward with one group
+# (this file's, with or without caches; the serving step) calls the three in
+# a row (``state_mixer``, ``sparse_mixer``); the serving program that takes a
+# chunk's rows and a step's through a layer together (models/decode.py)
+# projects and finishes them as ONE batch and mixes a group at a time. The
+# state comes in and goes out, and who holds it (nobody, a contiguous cache,
+# the serving pool) is the caller's business.
 
 
 def state_shapes(cfg, kind: str, rows: int) -> Dict[str, tuple]:
@@ -448,41 +458,51 @@ def _gated_out(cfg, p, x, o):
                       p["wo"].astype(cfg.dtype))
 
 
-def linear_mixer(cfg, p, x, positions, state, *, real_len=None, active=None):
-    """'lightning-attn': x [B, S, d] at ``positions`` [B, S], state [B, H,
-    D, D] float32 -> (y [B, S, d], state). One token a row is a step, of
-    which a row that is not ``active`` ([B]; default: all are) keeps its
-    state bitwise; more are a chunk whose first ``real_len`` tokens (a
-    scalar; default: all) are real and the rest trailing padding."""
-    B, S = x.shape[:2]
-    q, k, v = _qkv(cfg, p, x, COMPUTED, positions)
+def _linear_project(cfg, p, x, positions):
+    return _qkv(cfg, p, x, COMPUTED, positions)
+
+
+def _linear_mix(cfg, rows, state, *, real_len=None, active=None):
+    """'lightning-attn', one group: q, k, v [B, S, H, D] on the state
+    ``{"s": [B, H, D, D]}`` float32 -> (o [B, S, H, D] float32, state)."""
+    q, k, v = rows
+    B, S = q.shape[:2]
     slope = slopes(cfg.num_heads, cfg.linear_slope_exponent)
     if S == 1:
-        o, state = linear_attention_step(
-            q[:, 0], k[:, 0], v[:, 0], state, slope,
+        o, s = linear_attention_step(
+            q[:, 0], k[:, 0], v[:, 0], state["s"], slope,
             jnp.ones((B,), jnp.int32) if active is None else active)
         o = o[:, None]
     else:
-        o, state = linear_attention_chunk(
-            q, k, v, state, slope, S if real_len is None else real_len)
-    o = rms_norm(o.reshape(B, S, -1), p["o_norm"], cfg.norm_eps)
-    return _gated_out(cfg, p, x, o.reshape(q.shape).astype(cfg.dtype)), state
+        o, s = linear_attention_chunk(
+            q, k, v, state["s"], slope, S if real_len is None else real_len)
+    return o.astype(jnp.float32), {"s": s}
 
 
-def retention_mixer(cfg, p, x, positions, state, *, real_len=None,
-                    active=None):
-    """'power-retention': x [B, S, d] at ``positions`` [B, S], state the
-    dict ``state_shapes`` describes (``s`` and the normaliser ``z``, a K/V
-    head each, float32) -> (y [B, S, d], state). The log-gate is ``log
-    sigmoid(x Wc)``, one a K/V head, float32. One token a row is a step, of
-    which a row that is not ``active`` ([B]; default: all are) keeps its
-    state bitwise; more are a chunk whose first ``real_len`` tokens (a
-    scalar; default: all) are real and the rest trailing padding."""
+def _linear_finish(cfg, p, x, o):
     B, S = x.shape[:2]
+    o = rms_norm(o.reshape(B, S, -1), p["o_norm"], cfg.norm_eps)
+    return _gated_out(cfg, p, x, o.reshape(
+        B, S, cfg.num_heads, cfg.head_dim).astype(cfg.dtype))
+
+
+def _retention_project(cfg, p, x, positions):
+    """q, k, v and the log-gate ``log sigmoid(x Wc)`` [B, S, Hkv], one a
+    K/V head, float32."""
     q, k, v = _qkv(cfg, p, x, COMPUTED, positions)
     gate = jax.nn.log_sigmoid(jnp.einsum(
         "bsd,dg->bsg", x, p["wc"].astype(cfg.dtype),
         preferred_element_type=jnp.float32))
+    return q, k, v, gate
+
+
+def _retention_mix(cfg, rows, state, *, real_len=None, active=None):
+    """'power-retention', one group: q [B, S, H, D], k, v [B, S, Hkv, D]
+    and the log-gate on the state the dict ``state_shapes`` describes (``s``
+    and the normaliser ``z``, a K/V head each, float32) -> (o [B, S, H, D],
+    state)."""
+    q, k, v, gate = rows
+    B, S = q.shape[:2]
     if S == 1:
         o, s, z = power_retention_step(
             q[:, 0], k[:, 0], v[:, 0], gate[:, 0], state["s"], state["z"],
@@ -492,36 +512,84 @@ def retention_mixer(cfg, p, x, positions, state, *, real_len=None,
         o, s, z = power_retention_chunk(
             q, k, v, gate, state["s"], state["z"],
             S if real_len is None else real_len)
-    return jnp.einsum("bshk,hkd->bsd", o.astype(cfg.dtype),
-                      p["wo"].astype(cfg.dtype)), {"s": s, "z": z}
+    return o.astype(cfg.dtype), {"s": s, "z": z}
+
+
+def _retention_finish(cfg, p, x, o):
+    return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(cfg.dtype))
+
+
+# kind -> (project(cfg, p, x, positions) -> the rows' arrays [B, S, ...],
+#          mix(cfg, rows, state, *, real_len, active) -> (o, state),
+#          finish(cfg, p, x, o) -> y [B, S, d])
+STATE_MIXERS = {
+    LINEAR: (_linear_project, _linear_mix, _linear_finish),
+    RETENTION: (_retention_project, _retention_mix, _retention_finish),
+}
+
+
+def state_mixer(cfg, kind, p, x, positions, state):
+    """A layer of a kind in ``STATE_KINDS`` over ONE group of rows, all of
+    them live and real: x [B, S, d] at ``positions`` [B, S], state the dict
+    of float32 arrays ``state_shapes`` describes -> (y [B, S, d], state).
+    One token a row is a step, more are a chunk. (The serving forward calls
+    the three pieces itself: its steps have rows that are not ``active``,
+    which keep their state bitwise, and its chunks trailing padding past
+    ``real_len``.)"""
+    project, mix, finish = STATE_MIXERS[kind]
+    o, state = mix(cfg, project(cfg, p, x, positions), state)
+    return finish(cfg, p, x, o), state
+
+
+def written_pages(write_tables, positions, page_tokens: int):
+    """The pages [B, S] of a paged pool that the rows at ``positions`` [B,
+    S] land on through ``write_tables`` [B, P]; a row's offset in its page
+    is ``positions % page_tokens``."""
+    return jnp.take_along_axis(
+        write_tables,
+        jnp.clip(positions // page_tokens, 0, write_tables.shape[1] - 1),
+        axis=1)
+
+
+def write_pages(pool, rows, pages, offs):
+    """rows [B, S, Hkv, D] into pool [N, T, Hkv * D] at (pages, offs) [B,
+    S]."""
+    return pool.at[pages, offs].set(
+        rows.reshape(*rows.shape[:2], -1).astype(pool.dtype))
+
+
+def sparse_mix(cfg, q, pools, read_tables, positions, lengths, *, impl: str):
+    """'minicpm4', one group, its keys and values and the pooled rows of
+    their pages already in the pool: q [B, S, H, D] at ``positions`` [B, S]
+    attends the blocks it chooses. ``pools`` = (k [N, T, Hkv * D], v, the
+    pages' pooled key rows [N, Hkv * D] float32), a row's pages through
+    ``read_tables`` [B, P], ``lengths`` [B] as ``ops.paged_attention`` takes
+    them. Returns (o [B, S, H, D], the choice, bool [B, S, Hkv, NB])."""
+    return sparse_attention(q, *pools, read_tables, positions, lengths,
+                            cfg.sparse, impl=impl, return_selected=True)
 
 
 def sparse_mixer(cfg, p, x, positions, lengths, pools, read_tables,
                  write_tables, *, impl: str, taps: Optional[List] = None):
-    """'minicpm4': x [B, S, d] at ``positions`` [B, S] over a paged pool —
-    ``pools`` = (k [N, T, Hkv * D], v, the pages' pooled key rows [N, Hkv *
-    D] float32), a row's pages through ``read_tables`` / ``write_tables``
-    [B, P], ``lengths`` [B] as ``ops.paged_attention`` takes them. The
-    window's keys and values are written, the pooled rows of the pages they
-    fell on recomputed, then the chosen blocks attended. Returns (y, pools);
-    ``taps`` (a list) is given the choice, bool [B, S, Hkv, NB]."""
+    """'minicpm4' over ONE group of rows: x [B, S, d] at ``positions`` [B,
+    S] over a paged pool (``sparse_mix`` says what the arguments are; a
+    row's pages are written through ``write_tables`` [B, P]). The window's
+    keys and values are written, the pooled rows of the pages they fell on
+    recomputed, then the chosen blocks attended. Returns (y, pools);
+    ``taps`` (a list) is given the choice."""
     k_pool, v_pool, means = pools
-    T, P = k_pool.shape[1], write_tables.shape[1]
+    T = k_pool.shape[1]
     q, k, v = _qkv(cfg, p, x, None, positions)
-    pages = jnp.take_along_axis(
-        write_tables, jnp.clip(positions // T, 0, P - 1), axis=1)
-    offs = positions % T
-    k_pool = k_pool.at[pages, offs].set(
-        k.reshape(*k.shape[:2], -1).astype(k_pool.dtype))
-    v_pool = v_pool.at[pages, offs].set(
-        v.reshape(*v.shape[:2], -1).astype(v_pool.dtype))
-    means = update_page_means(means, k_pool, write_tables, positions)
-    o, selected = sparse_attention(
-        q, k_pool, v_pool, means, read_tables, positions, lengths,
-        cfg.sparse, impl=impl, return_selected=True)
+    cells = written_pages(write_tables, positions, T), positions % T
+    k_pool = write_pages(k_pool, k, *cells)
+    v_pool = write_pages(v_pool, v, *cells)
+    pools = (k_pool, v_pool,
+             update_page_means(means, k_pool, (write_tables, positions)))
+    o, selected = sparse_mix(cfg, q, pools, read_tables, positions, lengths,
+                             impl=impl)
     if taps is not None:
         taps.append(selected)
-    return _gated_out(cfg, p, x, o), (k_pool, v_pool, means)
+    return _gated_out(cfg, p, x, o), pools
 
 
 def sparse_pool_pages(cfg, tokens: int) -> int:
@@ -543,10 +611,7 @@ def _mixer(cfg, kind, p, x, rope, positions, sp_axis, cache, taps):
         state = ({name: jnp.zeros(shape, jnp.float32) for name, shape
                   in state_shapes(cfg, kind, B).items()} if cache is None
                  else cache.arrays())
-        if kind == LINEAR:
-            y, state["s"] = linear_mixer(cfg, p, x, pos, state["s"])
-        else:
-            y, state = retention_mixer(cfg, p, x, pos, state)
+        y, state = state_mixer(cfg, kind, p, x, pos, state)
         return y, cache and dataclasses.replace(
             cache, **state, length=cache.length + S)
     from ray_tpu.ops.paged_attention import resolve_impl

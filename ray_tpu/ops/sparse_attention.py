@@ -113,19 +113,25 @@ def check_pool(sizes: SparseSizes, page_tokens: int, pages_per_slot: int):
 # --------------------------------------------------------- pooled key rows
 
 
-def update_page_means(means, k_pool, write_tables, positions):
-    """Recompute the pooled row of every page the window ``positions`` [B,
-    S] (consecutive a row) was just written to: means [N, Hkv * D] float32.
-    A page that is not full yet gets the mean of what it holds; no query
-    sees it before the write that fills it recomputes it."""
+def update_page_means(means, k_pool, *windows):
+    """Recompute the pooled row of every page that ``windows`` — each a pair
+    (write_tables [B, P], positions [B, S], consecutive a row), the groups
+    of rows one write put into ``k_pool`` — were just written to, in ONE
+    scatter: means [N, Hkv * D] float32. A page that is not full yet gets
+    the mean of what it holds; no query sees it before the write that fills
+    it recomputes it."""
     T = k_pool.shape[1]
-    P = write_tables.shape[1]
-    n = (positions.shape[1] + T - 2) // T + 1
-    logical = positions[:, :1] // T + jnp.arange(n, dtype=jnp.int32)[None]
-    phys = jnp.take_along_axis(write_tables, jnp.clip(logical, 0, P - 1),
-                               axis=1)
-    phys = jnp.where(logical < P, phys, 0)
-    return means.at[phys].set(k_pool[phys].astype(jnp.float32).mean(axis=2))
+    phys = []
+    for write_tables, positions in windows:
+        P = write_tables.shape[1]
+        n = (positions.shape[1] + T - 2) // T + 1
+        logical = positions[:, :1] // T + jnp.arange(n, dtype=jnp.int32)[None]
+        pages = jnp.take_along_axis(
+            write_tables, jnp.clip(logical, 0, P - 1), axis=1)
+        phys.append(jnp.where(logical < P, pages, 0))
+    phys = phys[0] if len(phys) == 1 else jnp.concatenate(
+        [pages.reshape(-1) for pages in phys])
+    return means.at[phys].set(k_pool[phys].astype(jnp.float32).mean(axis=-2))
 
 
 # --------------------------------------------------------------- selection
@@ -388,11 +394,22 @@ def sparse_attention(q, k_pool, v_pool, means, tables, positions, lengths,
     before position 0 attends nothing). ``impl``: what the paged kernel runs
     as for a step ('reference' | 'pallas'). Returns [B, S, H, D], and with
     ``return_selected`` the choice, bool [B, S, Hkv, NB]."""
+    return _attention(q, k_pool, v_pool, means, tables, positions, lengths,
+                      sizes, impl, return_selected, should_interpret())
+
+
+# jitted as the kernels' own wrappers are: a model's layers of the kind and
+# a program's groups of one shape are traced and lowered ONCE (the choice of
+# blocks is many small ops; a replica pays their tracing before its first
+# request)
+@functools.partial(jax.jit, static_argnames=(
+    "sizes", "impl", "return_selected", "interpret"))
+def _attention(q, k_pool, v_pool, means, tables, positions, lengths, sizes,
+               impl, return_selected, interpret):
     B, S, H, D = q.shape
     Hkv = k_pool.shape[2] // D
     P = tables.shape[1]
     check_pool(sizes, k_pool.shape[1], P)
-    interpret = should_interpret()
     rows = means[tables]                                   # [B, P, Hkv * D]
     kc = 0.5 * (rows[:, :-1] + rows[:, 1:])
     padded = -(-(P - 1) // 128) * 128

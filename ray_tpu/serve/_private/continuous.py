@@ -36,20 +36,18 @@ whose id is discarded (``discarded_rows``) and never emitted
 speculative path needs whole logits on the host to accept and resample, so
 it reads before every dispatch.
 
-A TURN READS THE WEIGHTS ONCE. Where there is a chunk the one program is the
-chunk's (``paged_prefill_chunk``), and it takes the turn's decode rows
-along: chunk rows and step rows are one batch through every projection and
-the MLP or expert layer, and only attention is called a group, at the
-chunk's shape and at the step's (``decode.paged_prefill_into_slot``). Where
-there is none it is the plain step (``paged_decode_step``). Such a program
-counts for what it carried: in ``prefill_chunks`` and ``prefill_tokens``,
-and, if a row was live, in ``decode_steps`` too (``fused_turns`` counts
-those, ``fused_step_rows`` their rows). A prompt whose last chunk rides in
-it decodes from the NEXT turn on: its first token exists only at this
-program's end. Which models: those all of whose layers hold pages through
-``ops.paged_attention`` (``decode.step_rides_chunk``, read off
-``cfg.kinds``); a model with a layer of another kind keeps two programs a
-turn with a chunk, the chunk alone and then the step.
+A TURN READS THE WEIGHTS ONCE, whatever the kinds of the model's layers.
+Where there is a chunk the one program is the chunk's
+(``paged_prefill_chunk``), and it takes the turn's decode rows along: chunk
+rows and step rows are one batch through every projection and the MLP or
+expert layer, and only what a layer keeps — its pages, or its states a
+slot — is met a group at a time, at the chunk's shape and at the step's
+(``decode.paged_prefill_into_slot``). Where there is none it is the plain
+step (``paged_decode_step``). Such a program counts for what it carried: in
+``prefill_chunks`` and ``prefill_tokens``, and, if a row was live, in
+``decode_steps`` too (``fused_turns`` counts those, ``fused_step_rows``
+their rows). A prompt whose last chunk rides in it decodes from the NEXT
+turn on: its first token exists only at this program's end.
 
 The scheduler measures the gap it makes. A sampled token becomes an emitted
 one at the READ of its program (``_collect``; a speculative round's tokens
@@ -95,10 +93,11 @@ token).
 LAYERS OF OTHER KINDS (``TransformerConfig.layer_kinds``). The pool is by
 kind: pages for an attention layer, pages plus a pooled key row a page for a
 'minicpm4' layer (which attends the blocks it chooses), a fixed float32 state
-a slot for a 'lightning-attn' layer. A chunk continues its slot's states and
-takes them as zero when it starts at position 0, so an admission still runs
-no device program; a step leaves the states of a row that is free or
-mid-prefill bitwise alone. What a token leaves in such a state cannot be cut
+a slot for a 'lightning-attn' or 'power-retention' layer. A chunk continues
+its slot's states and takes them as zero when it starts at position 0, so an
+admission still runs no device program; a step, alone or along in a chunk's
+program, leaves the states of a row that is free or mid-prefill bitwise
+alone. What a token leaves in such a state cannot be cut
 at a page boundary or rewound, and no snapshot is kept: the prefix cache,
 prefix export / migration and the speculative programs refuse a model with
 such layers when the scheduler is built.
@@ -334,8 +333,8 @@ class ContinuousScheduler:
     ``params`` are the (device-resident) model parameters shared by every
     program; the scheduler owns the page pool and two jitted programs —
     a prefill chunk (``paged_prefill_into_slot``, one compiled shape:
-    [1, prefill_chunk], with the [slots] decode rows along where the
-    model's layer kinds allow) and a decode step (``paged_decode_step``,
+    [1, prefill_chunk], with the [slots] decode rows along) and a decode
+    step (``paged_decode_step``,
     [slots]), which runs the turns that hold no chunk — both with donated
     caches so the pool updates in place instead of being copied per
     iteration. It also owns every slot's page table and
@@ -365,8 +364,7 @@ class ContinuousScheduler:
         from ray_tpu.models.decode import (init_paged_caches,
                                            paged_decode_step,
                                            paged_prefill_into_slot,
-                                           paged_verify_step,
-                                           step_rides_chunk)
+                                           paged_verify_step)
         from ray_tpu.models.transformer import (LINEAR, RETENTION, SPARSE,
                                                 STATE_KINDS, state_shapes)
         from ray_tpu.ops.paged_attention import resolve_impl
@@ -464,10 +462,6 @@ class ContinuousScheduler:
         program_kw = {"attn": self.attn_lane}
         if self._moe:
             program_kw["moe_info"] = True
-        # a chunk's program takes the turn's decode rows along where every
-        # layer holds pages through the paged kernel: read off the layer
-        # kinds, the one thing that decides it
-        self._fused = step_rides_chunk(cfg)
         self._no_rows = _LiveRows(self.slots)  # for a chunk that takes none
         # donated caches: the pool mutates in place across iterations;
         # the tables are tiny per-call host->device uploads
@@ -1222,12 +1216,13 @@ class ContinuousScheduler:
 
     def _dispatch_chunk(self, seq: _Seq, tokens, real: int,
                         rows: Optional[_LiveRows]) -> None:
-        """Dispatch the program of the chunk ``_next_chunk`` picked. Where
-        every layer of the model holds pages (``_fused``) it takes the decode
-        step along, over ONE read of the weights: ``rows``, the turn's live
-        decode rows (``_step_rows``), or with None no row active (the
-        speculative loop, whose rows go through the verify program).
-        Elsewhere the chunk goes alone and the step is the next program.
+        """Dispatch the program of the chunk ``_next_chunk`` picked. It
+        takes the decode step along, over ONE read of the weights, for every
+        model (the program meets each layer's pages or states a group of
+        rows at a time: ``decode._paged_forward_inplace``): ``rows``,
+        the turn's live decode rows (``_step_rows``), or with None no row
+        active (the speculative loop, whose rows go through the verify
+        program).
 
         Nothing is waited for: a prompt's last chunk samples the first token
         into ``_ids`` on the device, the sequence joins the decode rows of
@@ -1240,12 +1235,10 @@ class ContinuousScheduler:
         from ray_tpu.models.decode import StepRows
 
         last = not seq.remaining_prompt
-        live = rows.live if rows is not None else []
-        step = None
-        if self._fused:
-            r = rows or self._no_rows
-            step = StepRows(r.active, self._cursors(), *self._tables(),
-                            r.temperature, r.seeds)
+        rows = rows or self._no_rows
+        live = rows.live
+        step = StepRows(rows.active, self._cursors(), *self._tables(),
+                        rows.temperature, rows.seeds)
         self._n_prefill_tokens += real
         self._launch(self._prefill(
             self.params, tokens, np.int32(real), np.int32(seq.cursor),
@@ -1259,8 +1252,7 @@ class ContinuousScheduler:
         seq.cursor += real
         self._n_prefill_chunks += 1
         _m_prefill_chunks.inc()
-        if step is not None:
-            self._stepped(live)
+        self._stepped(live)
         if live:
             self._n_fused_turns += 1
             self._n_fused_step_rows += len(live)
@@ -1748,19 +1740,15 @@ class ContinuousScheduler:
     def _turn(self, behind: int) -> bool:
         """One turn of the loop, one step ahead: pick the chunk that is due,
         build the decode rows, dispatch ONE program — the chunk's, with the
-        rows, where there is a chunk, else the plain step over the rows —
-        and THEN read the ``behind`` programs dispatched in earlier turns
+        rows, where there is a chunk, else the plain step over the rows: one
+        rule for every model, whatever the kinds of its layers — and THEN
+        read the ``behind`` programs dispatched in earlier turns
         (``_collect``). The rows' tokens are ``_ids``, on the device since
-        the programs that sampled them. A model with a layer that holds no
-        pages, or chooses among them, keeps two programs a turn with a
-        chunk: the chunk alone, then the step (in which a prompt that the
-        chunk ended already rides). Returns True if a program was dispatched
-        or a result read."""
+        the programs that sampled them. Returns True if a program was
+        dispatched or a result read."""
         chunk = self._next_chunk()
-        if chunk is not None and not self._fused:
-            self._dispatch_chunk(*chunk, None)
         rows = self._step_rows()
-        if chunk is not None and self._fused:
+        if chunk is not None:
             self._dispatch_chunk(*chunk, rows)
         elif rows.live:
             # the tables go up as COPIES (see _tables)
@@ -1921,7 +1909,7 @@ class ContinuousScheduler:
             # chunk programs that took live decode rows along (they count in
             # prefill_chunks AND in decode_steps), and those rows;
             # fused_turns / prefill_chunks is how often a turn with a chunk
-            # read the weights once. 0 for a model whose turn is two programs
+            # carried a live row
             "fused_turns": self._n_fused_turns,
             "fused_step_rows": self._n_fused_step_rows,
             "admitted": self._n_admitted,

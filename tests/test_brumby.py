@@ -426,6 +426,12 @@ def test_the_scheduler_serves_a_model_without_pages(toy):
     new = 6
     sched = ContinuousScheduler(cfg, params, slots=3, prefill_chunk=16,
                                 arena_len=128)
+    carried, plain = [], []  # live rows a chunk's program took; plain steps
+    dispatch, step = sched._dispatch_chunk, sched._step
+    sched._dispatch_chunk = lambda seq, tokens, real, rows: (
+        carried.append(len(rows.live)), dispatch(seq, tokens, real, rows))[1]
+    sched._step = lambda *args: (plain.append(1), step(*args))[1]
+    sched._step._cache_size = step._cache_size
 
     async def main():
         loop = asyncio.get_running_loop()
@@ -451,7 +457,11 @@ def test_the_scheduler_serves_a_model_without_pages(toy):
         assert (kind, value) == ("end", "length")
         assert tokens == greedy(cfg, params, prompt, new)
     assert st["compiled_programs"] == 2
-    assert st["fused_turns"] == 0          # the turn stays two programs
+    # a chunk's program takes the live rows along: one program a turn
+    assert st["fused_turns"] == sum(1 for n in carried if n) > 0
+    assert st["fused_step_rows"] == sum(carried)
+    assert len(carried) == st["prefill_chunks"]
+    assert len(plain) == st["decode_steps"] - st["fused_turns"] > 0
     # admitted by slot and arena_len alone: nothing of pages moved
     assert sched.max_prompt_len(28) == 100  # arena_len less the answer
     for key in ("usable_pages", "peak_pages_in_use", "pages_allocated_total",

@@ -376,6 +376,12 @@ def test_scheduler_serves_both_kinds_and_a_reused_slot_starts_from_zero(toy):
                np.asarray(tokens[1, :70]).tolist(),
                np.asarray(tokens[0, 5:45]).tolist()]
     sched = scheduler(cfg, params, 2)
+    carried, plain = [], []  # live rows a chunk's program took; plain steps
+    dispatch, step = sched._dispatch_chunk, sched._step
+    sched._dispatch_chunk = lambda seq, tokens, real, rows: (
+        carried.append(len(rows.live)), dispatch(seq, tokens, real, rows))[1]
+    sched._step = lambda *args: (plain.append(1), step(*args))[1]
+    sched._step._cache_size = step._cache_size
     try:
         served = serve(sched, prompts)
         stats = sched.stats()
@@ -387,8 +393,11 @@ def test_scheduler_serves_both_kinds_and_a_reused_slot_starts_from_zero(toy):
         want = ref.forward(params, seq, hp_of(cfg))[0][len(prompt) - 1:]
         for logits, tok in zip(want, out):
             assert logits.max() - logits[tok] <= TOL * np.abs(want).max()
-    # layers of other kinds: a chunk goes alone, the step is its own program
-    assert stats["fused_turns"] == stats["fused_step_rows"] == 0
+    # layers of other kinds too: a chunk's program takes the live rows along
+    assert stats["fused_turns"] == sum(1 for n in carried if n) > 0
+    assert stats["fused_step_rows"] == sum(carried)
+    assert len(carried) == stats["prefill_chunks"]
+    assert len(plain) == stats["decode_steps"] - stats["fused_turns"] > 0
     assert stats["state_slots"] == 2
     assert stats["state_bytes"] == 6 * 2 * 4 * 16 * 16 * 4
     assert stats["linear_chunk_calls"] == 6 * stats["prefill_chunks"]

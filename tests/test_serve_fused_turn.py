@@ -1,16 +1,19 @@
-"""A turn reads the weights once (ISSUE 40, ROADMAP S3): where every layer of
-a model holds pages through ``ops.paged_attention`` the prefill chunk's program
-(``paged_prefill_into_slot``, jitted as ``paged_prefill_chunk``) takes the live
-decode rows along, and a loop turn that holds a chunk dispatches ONE program.
+"""A turn reads the weights once (ISSUE 40 and 44, ROADMAP S3): the prefill
+chunk's program (``paged_prefill_into_slot``, jitted as
+``paged_prefill_chunk``) takes the live decode rows along, and a loop turn
+that holds a chunk dispatches ONE program — whatever the kinds of the model's
+layers: pages through ``ops.paged_attention``, chosen blocks ('minicpm4'), a
+state a slot ('lightning-attn', 'power-retention'), or all four in one model.
 
 Program level: the chunk's program with live step rows against the chunk's
 program alone and then ``paged_decode_step``, on the same inputs. Scheduler
 level: under churn every stream is the sequential cache's, token for token,
 one program a turn, two compiled, and the counters keep their meaning. CPU,
-float32, toy models: tokens, pools and counts, never a time."""
+float32, toy models: tokens, pools, states and counts, never a time."""
 
 import asyncio
-import dataclasses
+import re
+import time
 from functools import partial
 
 import jax
@@ -18,16 +21,29 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import llama_debug, minicpm_sala_debug, moe_debug
+from ray_tpu.models import (brumby_debug, llama_debug, minicpm_sala_debug,
+                            moe_debug)
 from ray_tpu.models.decode import (StepRows, decode_step, init_caches,
                                    init_paged_caches, paged_decode_step,
-                                   paged_prefill_into_slot, prefill,
-                                   step_rides_chunk)
-from ray_tpu.models.transformer import init_params
+                                   paged_prefill_into_slot, prefill)
+from ray_tpu.models.transformer import (ATTENTION, LINEAR, RETENTION, SPARSE,
+                                        STATE_KINDS, init_params)
 from ray_tpu.serve._private.continuous import ContinuousScheduler
 
-PRESETS = {"llama_debug": llama_debug, "moe_debug": moe_debug}
-SLOTS, T, P, C = 4, 4, 16, 8  # slots, page tokens, pages a slot, chunk
+PRESETS = {
+    "llama_debug": llama_debug, "moe_debug": moe_debug,
+    # block-selected and linear attention, 1:3; the linear kind alone; power
+    # retention alone (no page anywhere)
+    "minicpm_sala_debug": minicpm_sala_debug,
+    "lightning_debug": partial(minicpm_sala_debug, num_layers=2,
+                               layer_kinds=(LINEAR,) * 2),
+    "brumby_debug": brumby_debug,
+    # every kind of layer in one model
+    "four_kinds_debug": partial(
+        minicpm_sala_debug, num_layers=4,
+        layer_kinds=(SPARSE, LINEAR, ATTENTION, RETENTION)),
+}
+SLOTS, T, P, C = 4, 4, 32, 8  # slots, page tokens, pages a slot, chunk
 
 
 @pytest.fixture(scope="module", params=sorted(PRESETS))
@@ -40,11 +56,24 @@ def model(request):
 
 
 def _programs(cfg):
+    """The two programs; a model with 'minicpm4' layers also hands back the
+    blocks they chose (last of what a program returns)."""
     kw = {"attn": "reference"}
     if cfg.mlp == "moe":
         kw["moe_info"] = True
+    if SPARSE in cfg.kinds:
+        kw["selected"] = True
     return (jax.jit(partial(paged_prefill_into_slot, cfg, **kw)),
             jax.jit(partial(paged_decode_step, cfg, **kw)))
+
+
+def _rows(cfg, tables, slot=None):
+    """The (read, write) tables of all slots or of one; None, None for a
+    model none of whose layers holds a page."""
+    if not cfg.holds_pages:
+        return None, None
+    rows = tables if slot is None else tables[slot]
+    return rows, rows
 
 
 def _prompt(n, start):
@@ -56,7 +85,8 @@ def _state(cfg, params, lengths):
     by the chunk's program alone), their tables (a slot's pages in order,
     never page 0), cursors and the ids vector with each one's first token."""
     chunk, _ = _programs(cfg)
-    caches = init_paged_caches(cfg, SLOTS * P + 1, T, P, jnp.float32)
+    caches = init_paged_caches(cfg, SLOTS * P + 1, T, P, jnp.float32,
+                               slots=SLOTS)
     tables = (1 + np.arange(SLOTS * P, dtype=np.int32)).reshape(SLOTS, P)
     ids = jnp.zeros(SLOTS, jnp.int32)
     cursors = np.zeros(SLOTS, np.int32)
@@ -65,9 +95,9 @@ def _state(cfg, params, lengths):
         for at in range(0, n, C):
             part = prompt[at:at + C]
             out = chunk(params, _padded(part), np.int32(len(part)),
-                        np.int32(at), tables[slot], tables[slot], caches, ids,
+                        np.int32(at), *_rows(cfg, tables, slot), caches, ids,
                         np.int32(slot if at + C >= n else -1), np.float32(0),
-                        np.uint32(0), None)
+                        np.uint32(0), None, np.int32(slot))
             ids, caches = out[0], out[1]
         cursors[slot] = n
     return caches, tables, ids, cursors
@@ -84,13 +114,48 @@ def _real_positions(tables, cursors):
     return tuple(np.asarray(x) for x in zip(*at))
 
 
+def _same_caches(cfg, got, want, tables, cursors, untouched=()):
+    """What two runs left in the pool, a layer at a time and by its kind:
+    keys and values on every position a slot holds (and a 'minicpm4'
+    layer's pooled row of every page they fill: the row of a page that is
+    not full yet is recomputed by the write that fills it, before any query
+    sees it), every slot's states; the
+    states of the slots in ``untouched`` ([(slot, the caches they must
+    still equal)]) bitwise."""
+    where = _real_positions(tables, cursors)
+    close = partial(np.testing.assert_allclose, rtol=1e-5, atol=1e-5)
+    for kind, a, b in zip(cfg.kinds, got, want):
+        if kind in STATE_KINDS:
+            for name, x in a.arrays().items():
+                close(np.asarray(x), np.asarray(b.arrays()[name]))
+            continue
+        close(np.asarray(a.k)[where], np.asarray(b.k)[where])
+        close(np.asarray(a.v)[where], np.asarray(b.v)[where])
+        if kind == SPARSE:
+            full = np.concatenate([tables[s, :int(cursors[s]) // T]
+                                   for s in range(SLOTS)])
+            close(np.asarray(a.means)[full], np.asarray(b.means)[full])
+    for slot, before in untouched:
+        for a, b in zip(got, before):
+            if hasattr(a, "arrays"):
+                for name, x in a.arrays().items():
+                    assert np.array_equal(np.asarray(x)[slot],
+                                          np.asarray(b.arrays()[name])[slot])
+
+
+# slots 0 and 2 decode (slot 2 so far past the 48 tokens from which a
+# 'minicpm4' layer chooses that it leaves blocks out), slot 1 is mid-prompt,
+# slot 3 holds a sequence that takes no token this turn
+HELD = {0: 5, 1: C, 2: 101, 3: 6}
+
+
 @pytest.mark.parametrize("last", [False, True], ids=["mid_prompt", "last"])
 def test_the_chunk_with_live_rows_is_the_chunk_and_then_the_step(model, last):
     """Slots 0 and 2 decode, slot 1 is mid-prompt with its cursor on a
-    page's first position, slot 3 is free: ONE program against two."""
+    page's first position, slot 3 is not active: ONE program against two."""
     cfg, params = model
     chunk, step = _programs(cfg)
-    caches, tables, ids, cursors = _state(cfg, params, {0: 5, 1: C, 2: 13})
+    caches, tables, ids, cursors = _state(cfg, params, HELD)
     assert cursors[1] % T == 0
     # slot 1's next chunk; as a decode row it is NOT active, its tables hold
     # its real pages and its cursor is where the chunk writes first
@@ -99,18 +164,19 @@ def test_the_chunk_with_live_rows_is_the_chunk_and_then_the_step(model, last):
     active = np.asarray([1, 0, 1, 0], np.int32)
     greedy = (np.zeros(SLOTS, np.float32), np.zeros(SLOTS, np.uint32))
     args = (params, _padded(part), np.int32(real), np.int32(cursors[1]),
-            tables[1], tables[1])
+            *_rows(cfg, tables, 1))
     tail = (np.int32(1 if last else -1), np.float32(0), np.uint32(0))
 
     # two programs, as the scheduler ran them: the slot's cursor moves past
     # the chunk before the step, whose idle row 1 writes at its cursor
-    two = chunk(*args, caches, ids, *tail, None)
+    two = chunk(*args, caches, ids, *tail, None, np.int32(1))
     moved = cursors.copy()
     moved[1] += real
-    two_step = step(params, two[0], active, moved, tables, tables, two[1],
-                    *greedy)
+    two_step = step(params, two[0], active, moved, *_rows(cfg, tables),
+                    two[1], *greedy)
     one = chunk(*args, caches, ids, *tail,
-                StepRows(active, cursors, tables, tables, *greedy))
+                StepRows(active, cursors, *_rows(cfg, tables), *greedy),
+                np.int32(1))
 
     want, got = np.asarray(two_step[0]), np.asarray(one[0])
     # the live rows' next tokens, the free slot's entry as it came
@@ -122,12 +188,17 @@ def test_the_chunk_with_live_rows_is_the_chunk_and_then_the_step(model, last):
         assert got[1] == np.asarray(two[0])[1] == want[1]
     else:
         assert got[1] == np.asarray(ids)[1]
-    where = _real_positions(tables, moved + active)
-    for a, b in zip(one[1], two_step[1]):
-        np.testing.assert_allclose(np.asarray(a.k)[where],
-                                   np.asarray(b.k)[where], atol=1e-5)
-        np.testing.assert_allclose(np.asarray(a.v)[where],
-                                   np.asarray(b.v)[where], atol=1e-5)
+    # pools, pooled rows and states; slot 3 took no token: its states are
+    # bitwise what they were before either
+    _same_caches(cfg, one[1], two_step[1], tables, moved + active,
+                 untouched=[(3, caches)])
+    if SPARSE in cfg.kinds:
+        # the blocks chosen, a group of rows: the chunk's, the live rows'
+        of_chunk, of_step = (np.asarray(x) for x in one[-1])
+        assert np.array_equal(of_chunk, np.asarray(two[-1]))
+        assert np.array_equal(of_step[:, [0, 2]],
+                              np.asarray(two_step[-1])[:, [0, 2]])
+        assert not of_step[:, 2].all()  # a choice: not every block
     if cfg.mlp == "moe":
         # the experts ran once a layer over both groups' rows; the counts
         # still say which group sent which, as the two programs did
@@ -148,29 +219,35 @@ def test_a_row_that_is_not_active_writes_nothing_a_sequence_reads(model):
     row carries, and so does every other slot's last position."""
     cfg, params = model
     chunk, _ = _programs(cfg)
-    caches, tables, ids, cursors = _state(cfg, params, {0: 5, 1: C, 2: 13})
+    caches, tables, ids, cursors = _state(cfg, params, HELD)
     part = _prompt(C, 50)
     args = (params, _padded(part), np.int32(C), np.int32(cursors[1]),
-            tables[1], tables[1])
+            *_rows(cfg, tables, 1))
     tail = (np.int32(-1), np.float32(0), np.uint32(0))
     greedy = (np.zeros(SLOTS, np.float32), np.zeros(SLOTS, np.uint32))
-    alone = chunk(*args, caches, ids, *tail, None)
+    alone = chunk(*args, caches, ids, *tail, None, np.int32(1))
     # no row active; rows 0 and 2 point at their newest REAL position
     stale = cursors.copy()
     stale[[0, 2]] -= 1
     for token in (7, 201):
         idle = chunk(*args, caches, ids.at[:].set(token), *tail,
-                     StepRows(np.zeros(SLOTS, np.int32), stale, tables,
-                              tables, *greedy))
+                     StepRows(np.zeros(SLOTS, np.int32), stale,
+                              *_rows(cfg, tables), *greedy), np.int32(1))
         assert np.array_equal(np.asarray(idle[0]), np.full(SLOTS, token))
         after = cursors.copy()
         after[1] += C
         where = _real_positions(tables, after)
-        for a, b in zip(idle[1], alone[1]):
+        for kind, a, b in zip(cfg.kinds, idle[1], alone[1]):
+            if kind in STATE_KINDS:
+                continue
             assert np.array_equal(np.asarray(a.k)[where],
                                   np.asarray(b.k)[where])
             assert np.array_equal(np.asarray(a.v)[where],
                                   np.asarray(b.v)[where])
+        # a state has no second chance: every other slot's is bitwise what
+        # it was, the chunk's own slot's what the chunk alone left
+        _same_caches(cfg, idle[1], alone[1], tables, after,
+                     untouched=[(s, caches) for s in (0, 2, 3)])
         if cfg.mlp == "moe":
             counts = np.asarray(idle[2]["counts"])
             assert np.array_equal(counts[:, 0],
@@ -178,25 +255,42 @@ def test_a_row_that_is_not_active_writes_nothing_a_sequence_reads(model):
             assert not counts[:, 1].any()
 
 
-def test_which_models_take_the_rows_along_is_read_off_the_layer_kinds():
-    assert step_rides_chunk(llama_debug()) and step_rides_chunk(moe_debug())
-    sala = minicpm_sala_debug()
-    assert not step_rides_chunk(sala)
-    assert not step_rides_chunk(dataclasses.replace(
-        sala, layer_kinds=("lightning-attn",) * sala.num_layers))
-    params = jax.eval_shape(lambda: init_params(sala, jax.random.PRNGKey(0)))
-    caches = jax.eval_shape(lambda: init_paged_caches(sala, 65, 4, 16,
-                                                      slots=SLOTS))
+def test_a_model_of_all_four_kinds_of_layer_takes_the_rows_along():
+    """One rule, read off nothing but each layer's kind: in a model that
+    mixes plain attention, chosen blocks, linear attention and power
+    retention the chunk's program calls every layer's kernels a group of
+    rows — the chunk's and the step's of each kind, once a layer — and the
+    plain step holds none of the chunk's."""
+    cfg = PRESETS["four_kinds_debug"]()
+    assert set(cfg.kinds) == {ATTENTION, SPARSE, LINEAR, RETENTION}
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    caches = jax.eval_shape(lambda: init_paged_caches(
+        cfg, SLOTS * P + 1, T, P, jnp.float32, slots=SLOTS))
     rows = jnp.zeros(SLOTS, jnp.int32)
-    tables = jnp.zeros((SLOTS, 16), jnp.int32)
-    with pytest.raises(ValueError, match="plain attention layers"):
-        jax.eval_shape(
-            lambda p, c: paged_prefill_into_slot(
-                sala, p, jnp.zeros((1, C), jnp.int32), 3, jnp.int32(0),
-                tables[0], tables[0], c, rows, 0, jnp.float32(0),
-                jnp.uint32(0), StepRows(rows, rows, tables, tables,
-                                        jnp.zeros(SLOTS), rows), 0,
-                attn="reference"), params, caches)
+    tables = jnp.zeros((SLOTS, P), jnp.int32)
+    step = (rows, rows, tables, tables, jnp.zeros(SLOTS), rows)
+
+    def kernels(program, *args):
+        text = str(jax.make_jaxpr(partial(program, cfg, attn="reference"))(
+            *args))
+        names = re.findall(r"name=(\w+)", text)
+        return {k: names.count(k) for k in (
+            "linear_attention_chunk", "linear_attention_step",
+            "power_retention_chunk", "power_retention_step",
+            "sparse_select", "sparse_paged_attention") if k in names}
+
+    # off a TPU the step's chosen blocks go through the paged reference
+    assert kernels(
+        paged_prefill_into_slot, params, jnp.zeros((1, C), jnp.int32), 3,
+        jnp.int32(0), tables[0], tables[0], caches, rows, 0, jnp.float32(0),
+        jnp.uint32(0), StepRows(*step), 0) == {
+            "linear_attention_chunk": 1, "linear_attention_step": 1,
+            "power_retention_chunk": 1, "power_retention_step": 1,
+            "sparse_select": 2, "sparse_paged_attention": 1}
+    assert kernels(paged_decode_step, params, rows, *step[:4], caches,
+                   *step[4:]) == {
+        "linear_attention_step": 1, "power_retention_step": 1,
+        "sparse_select": 1}
 
 
 # --------------------------------------------------------- scheduler level
@@ -266,15 +360,17 @@ async def _stream(sched, prompt, new, cancel_after=None, gate=None):
 
 # prompt lengths of one chunk and of several, budgets short and long: with
 # four slots the later ones are admitted while the earlier ones decode
+# (the last one long enough that a 'minicpm4' layer leaves blocks out)
 CHURN = [(5, 24), (21, 10), (8, 30), (13, 6), (30, 12), (3, 40), (17, 8),
-         (9, 16), (26, 5), (12, 20)]
+         (9, 16), (26, 5), (12, 20), (70, 36)]
 CANCELLED, CANCEL_AFTER = 2, 3  # (8, 30) is cancelled behind its third token
 REPEATED = 4                    # (30, 12) comes again once it has ended
 
 
 @pytest.fixture(scope="module")
 def churn(model):
-    """Ten requests through four slots with the prefix cache on, an EOS id
+    """Eleven requests through four slots with the prefix cache on (where
+    the model has no layer that keeps a state), an EOS id
     that cuts some streams short, one cancellation in mid-decode and one
     prompt sent again after it ended (the prefix hit)."""
     cfg, params = model
@@ -285,9 +381,11 @@ def churn(model):
     later = [t for s in free for t in s[2:]]
     eos = max(set(later) - {s[0] for s in free}, key=later.count)
     want = [_upto_eos(s[:new], eos) for s, (_, new) in zip(free, CHURN)]
+    # a state cannot be cut at a page boundary: no prefix cache for a model
+    # with a layer that keeps one
     sched = Watched(cfg, params, slots=SLOTS, prefill_chunk=C,
                     arena_len=P * T, page_tokens=T, eos_id=eos,
-                    prefix_cache=True, attn="reference")
+                    prefix_cache=not cfg.recurrent, attn="reference")
 
     async def drive():
         ended = asyncio.Event()
@@ -307,6 +405,12 @@ def churn(model):
 
     try:
         got = asyncio.run(drive())
+        # the last stream's end is emitted from inside the read of its
+        # program: let the loop finish that turn
+        patience = time.monotonic() + 10
+        while (sched._inflight or sched._steps_unread) and (
+                time.monotonic() < patience):
+            time.sleep(0.01)
         stats = sched.stats()
         left = (len(sched._inflight), sched._steps_unread)
     finally:
@@ -331,7 +435,8 @@ def test_under_churn_every_stream_is_the_oracles_token_for_token(churn):
         assert end == ("end", "eos" if tokens[-1] == churn["eos"]
                        else "length")
     stats = churn["stats"]
-    assert stats["admitted_mid_flight"] > 0 and stats["prefix_hit_tokens"] > 0
+    assert stats["admitted_mid_flight"] > 0
+    assert stats["prefix_hit_tokens"] > 0 or churn["cfg"].recurrent
     # EOS and the cancellation reached the loop a program late: their rows
     # rode once more and were dropped, never emitted
     assert stats["discarded_rows"] > 0
@@ -395,6 +500,8 @@ def test_an_exhausted_pool_fails_one_stream_and_the_rest_are_the_oracles(
     program, and is dropped), a third prompt then takes its slot and fits
     beside the survivor, and what every stream holds is the oracle's."""
     cfg, params = model
+    if not cfg.holds_pages:
+        pytest.skip("no layer of the model holds a page")
     asks = [(10, 14), (9, 14), (7, 4)]
     prompts = [_prompt(n, 31 * i + 5) for i, (n, _) in enumerate(asks)]
     want = [_oracle(cfg, params, p, new)
@@ -451,44 +558,3 @@ def test_the_speculative_loop_calls_the_chunks_program_with_no_row_active():
     assert stats["spec_rounds"] > 0 and stats["prefill_chunks"] == 4
     assert stats["fused_turns"] == stats["fused_step_rows"] == 0
     assert sched._step._cache_size() == 0 and stats["compiled_programs"] == 2
-
-
-def test_a_model_with_layers_of_other_kinds_keeps_two_programs_a_turn():
-    """``minicpm_sala_debug`` (block-selected and linear attention): a turn
-    with a chunk and a live row dispatches the chunk alone and then the
-    step, as before ISSUE 40, and no fused turn is counted."""
-    cfg = minicpm_sala_debug()
-    params = init_params(cfg, jax.random.PRNGKey(2))
-    sched = Watched(cfg, params, slots=2, prefill_chunk=32, arena_len=256,
-                    page_tokens=4, prefix_cache=False, attn="reference")
-    assert not sched._fused
-
-    async def drive():
-        started = asyncio.Event()
-
-        async def first():
-            queue = asyncio.Queue()
-            sched.submit(_prompt(20, 3), max_new_tokens=12,
-                         loop=asyncio.get_running_loop(), queue=queue)
-            n = 0
-            while (await queue.get())[0] == "tok":
-                n += 1
-                if n == 2:
-                    started.set()
-            return n
-
-        return await asyncio.gather(first(), _stream(
-            sched, _prompt(70, 9), 3, gate=started))
-
-    try:
-        n, (late, end) = asyncio.run(drive())
-        stats = sched.stats()
-    finally:
-        sched.shutdown()
-    assert n == 12 and len(late) == 3 and end == ("end", "length")
-    assert stats["fused_turns"] == stats["fused_step_rows"] == 0
-    both = [t for t in sched.per_turn if t[1] and t[2]]
-    assert len(both) >= 2 and all(t[0] == 2 and t[3] == 0 for t in both)
-    assert sum(t[0] for t in sched.per_turn) == (
-        stats["prefill_chunks"] + stats["decode_steps"])
-    assert stats["compiled_programs"] == 2

@@ -400,14 +400,12 @@ def _cell_programs(v5e, config: str, cell: str):
     """A benchmark cell's two serving programs as its scheduler calls them,
     at the configuration's published widths under the cell's deployment:
     (cfg, bytes held by weights and pool, {name: (program, arguments)}). The
-    chunk's program takes the step's rows where the model's layer kinds let
-    it (``step_rides_chunk``), else None."""
+    chunk's program takes the step's rows along."""
     from perfbench.lib import configs
     from perfbench.lib import manifest as manifest_lib
     from ray_tpu.models.decode import (StepRows, init_paged_caches,
                                        paged_decode_step,
-                                       paged_prefill_into_slot,
-                                       step_rides_chunk)
+                                       paged_prefill_into_slot)
     from ray_tpu.models.transformer import init_params
 
     manifest = manifest_lib.load()
@@ -438,8 +436,7 @@ def _cell_programs(v5e, config: str, cell: str):
                     (params, ids((1, chunk)), ids(()), ids(()), row, row,
                      caches, ids((slots,)), ids(()),
                      _on(chip, (), jnp.float32), _on(chip, (), jnp.uint32),
-                     StepRows(*step) if step_rides_chunk(cfg) else None,
-                     ids(()))),
+                     StepRows(*step), ids(()))),
         "decode": (paged_decode_step,
                    (params, ids((slots,)), *step[:4], caches, *step[4:])),
     }
@@ -533,7 +530,8 @@ def test_olmoe_serve_programs_compile_and_fit(v5e):
 def test_minicpm_sala_serve_programs_compile_and_fit(v5e):
     """The benchmark's MiniCPM-SALA configuration (published widths, 16
     layers of two kinds, bf16) under its cell's deployment: the prefill
-    chunk and the decode step with the four kernels of the two mixers
+    chunk (with the step's rows along: both kernels of the linear mixer)
+    and the decode step with the four kernels of the two mixers
     (``linear_attention_chunk`` / ``_step``, ``sparse_select``,
     ``sparse_paged_attention`` — the paged kernel over a table of chosen
     pages in a step, the masked flash kernel in a chunk), 10.1 GB of
@@ -546,9 +544,8 @@ def test_minicpm_sala_serve_programs_compile_and_fit(v5e):
     lane = resolve_impl(cfg)
     assert lane == "pallas"
     assert 12.5e9 < held < 12.9e9  # 10.1 GB + 2.2 GB of pool + 0.4 of state
-    assert programs["prefill"][1][11] is None  # the chunk goes alone
-    kernels = {"prefill": {"linear_attention_chunk", "sparse_select",
-                           "sparse_paged_attention"},
+    kernels = {"prefill": {"linear_attention_chunk", "linear_attention_step",
+                           "sparse_select", "sparse_paged_attention"},
                "decode": {"linear_attention_step", "sparse_select",
                           "sparse_paged_attention"}}
     for name, (program, args) in programs.items():
@@ -564,15 +561,15 @@ def test_minicpm_sala_serve_programs_compile_and_fit(v5e):
 
 def _pageless_programs(v5e, cfg, slots: int, chunk: int):
     """The scheduler's two programs for a model none of whose layers holds a
-    page, as it calls them: no page table (None), states a slot, the chunk
-    alone. (cfg's bytes held by weights and states, {name: (program,
-    arguments)})."""
-    from ray_tpu.models.decode import (init_paged_caches, paged_decode_step,
-                                       paged_prefill_into_slot,
-                                       step_rides_chunk)
+    page, as it calls them: no page table (None), states a slot, the
+    step's rows along in the chunk's program. (cfg's bytes held by weights
+    and states, {name: (program, arguments)})."""
+    from ray_tpu.models.decode import (StepRows, init_paged_caches,
+                                       paged_decode_step,
+                                       paged_prefill_into_slot)
     from ray_tpu.models.transformer import init_params
 
-    assert not cfg.holds_pages and not step_rides_chunk(cfg)
+    assert not cfg.holds_pages
     chip = SingleDeviceSharding(v5e.devices[0])
     place = lambda tree: jax.tree.map(
         lambda a: _on(chip, a.shape, a.dtype), tree)
@@ -589,22 +586,25 @@ def _pageless_programs(v5e, cfg, slots: int, chunk: int):
                     (params, ids((1, chunk)), ids(()), ids(()), None, None,
                      caches, ids((slots,)), ids(()),
                      _on(chip, (), jnp.float32), _on(chip, (), jnp.uint32),
-                     None, ids(()))),
+                     StepRows(ids((slots,)), ids((slots,)), None, None,
+                              *rows), ids(()))),
         "decode": (paged_decode_step,
                    (params, ids((slots,)), ids((slots,)), ids((slots,)),
                     None, None, caches, *rows)),
     }
 
 
-RETENTION_KERNELS = {"prefill": {"power_retention_chunk"},
+RETENTION_KERNELS = {"prefill": {"power_retention_chunk",
+                                 "power_retention_step"},
                      "decode": {"power_retention_step"}}
 
 
 def test_brumby_debug_serve_programs_lower_with_the_retention_kernels(v5e):
     """The two serve programs of the toy Brumby (float32, heads of 32, five
     query heads on each of two states, no page anywhere) go through Mosaic:
-    the chunk's program holds ``power_retention_chunk`` and the step's
-    ``power_retention_step``, once a layer, and no other kernel."""
+    the chunk's program holds ``power_retention_chunk`` and, for the
+    step's rows it takes along, ``power_retention_step``, the step's the
+    latter alone, each once a layer, and no other kernel."""
     from ray_tpu.models.presets import brumby_debug
 
     cfg = brumby_debug()
@@ -612,9 +612,112 @@ def test_brumby_debug_serve_programs_lower_with_the_retention_kernels(v5e):
     for name, (program, args) in programs.items():
         compiled = jax.jit(functools.partial(program, cfg, attn="pallas"),
                            donate_argnums=(6,)).lower(*args).compile()
-        calls = _kernel_calls(compiled)
-        assert set(calls) == RETENTION_KERNELS[name], (name, calls)
+        assert _kernel_calls(compiled) == dict.fromkeys(
+            RETENTION_KERNELS[name], cfg.num_layers), name
         _fits(compiled)
+
+
+def test_minicpm_sala_debug_chunk_program_lowers_with_the_steps_rows(v5e):
+    """The toy of two layer kinds (float32): the chunk's program with the
+    step's rows along goes through Mosaic with each kind's kernels a group
+    of rows — the linear layers' chunk and step kernels once a layer, the
+    block-selected layers' selection twice and their chunk's attention once
+    (a pool row of 32 lanes is too narrow for the paged kernel, which the
+    step's chosen blocks go through: ``resolve_impl`` says 'reference')."""
+    from ray_tpu.models.decode import (StepRows, init_paged_caches,
+                                       paged_prefill_into_slot)
+    from ray_tpu.models.presets import minicpm_sala_debug
+    from ray_tpu.models.transformer import LINEAR, SPARSE, init_params
+    from ray_tpu.ops.paged_attention import resolve_impl
+
+    cfg = minicpm_sala_debug()
+    assert resolve_impl(cfg) == "reference"
+    slots, chunk, T, pages = 4, 64, 4, 64
+    chip = SingleDeviceSharding(v5e.devices[0])
+    place = lambda tree: jax.tree.map(
+        lambda a: _on(chip, a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(
+        functools.partial(init_params, cfg), jax.random.PRNGKey(0)))
+    caches = place(jax.eval_shape(functools.partial(
+        init_paged_caches, cfg, slots * pages + 1, T, pages, slots=slots)))
+    ids = functools.partial(_on, chip, dtype=jnp.int32)
+    table = ids((slots, pages))
+    step = StepRows(ids((slots,)), ids((slots,)), table, table,
+                    _on(chip, (slots,), jnp.float32),
+                    _on(chip, (slots,), jnp.uint32))
+    compiled = jax.jit(
+        functools.partial(paged_prefill_into_slot, cfg, attn="reference"),
+        donate_argnums=(6,)).lower(
+            params, ids((1, chunk)), ids(()), ids(()), ids((pages,)),
+            ids((pages,)), caches, ids((slots,)), ids(()),
+            _on(chip, (), jnp.float32), _on(chip, (), jnp.uint32), step,
+            ids(())).compile()
+    linear, sparse = cfg.kinds.count(LINEAR), cfg.kinds.count(SPARSE)
+    assert _kernel_calls(compiled) == {
+        "linear_attention_chunk": linear, "linear_attention_step": linear,
+        "sparse_select": 2 * sparse, "sparse_paged_attention": sparse}
+    _fits(compiled)
+
+
+def _copied_shapes(compiled) -> set:
+    """(element type, dimensions) of every copy in a compiled program's
+    text, as the text writes them: ``("f32", "16,8,13,128,640")``."""
+    return set(re.findall(r"= (\w+)\[([\d,]+)\]\S* copy(?:-start)?\(",
+                          compiled.as_text()))
+
+
+@pytest.mark.parametrize("cell", ["minicpm_sala_longdoc", "brumby_longgen"])
+def test_the_state_kinds_chunk_program_takes_the_rows_along_in_place(v5e,
+                                                                     cell):
+    """ISSUE 44, at the cells' real shapes: the chunk's program with the
+    step's rows along against the chunk alone. What it adds is the step's
+    kernels, a group of rows a layer; the states (Brumby: 4.4 GB beside 8.4
+    of weights on 16) and the pools are still updated in place — the same
+    bytes aliased, no copy of the shape of a state or a pool of 30 MB or
+    more, temporaries within 128 MB — and
+    what a layer does with the step's rows alone (the pass over every slot's
+    states, the choice of blocks) stands under a conditional, one a layer,
+    that a program none of whose rows is live does not enter."""
+    from ray_tpu.models.transformer import STATE_KINDS
+
+    if cell == "brumby_longgen":
+        from perfbench.lib import configs
+        from perfbench.lib import manifest as manifest_lib
+
+        manifest = manifest_lib.load()
+        hp = manifest_lib.config(manifest, "brumby_14b_l8")
+        cfg = configs.build_program_config(*configs.program_overrides(
+            hp, manifest_lib.read_json_from_bench("families",
+                                                  hp["model_type"])))
+        dep = manifest_lib.read_json(manifest, "cells", cell)["deployment"]
+        _, programs = _pageless_programs(v5e, cfg, dep["slots"],
+                                         dep["prefill_chunk"])
+    else:
+        cfg, _, programs = _cell_programs(v5e, "minicpm_sala_l16", cell)
+    program, args = programs["prefill"]
+
+    def compiled(arguments):
+        return jax.jit(functools.partial(program, cfg, attn="pallas"),
+                       donate_argnums=(6,)).lower(*arguments).compile()
+
+    fused, alone = compiled(args), compiled(args[:11] + (None,) + args[12:])
+    stateful = sum(kind in STATE_KINDS for kind in cfg.kinds)
+    step_kernel = ("power_retention_step" if cell == "brumby_longgen"
+                   else "linear_attention_step")
+    assert _kernel_calls(fused)[step_kernel] == stateful
+    assert step_kernel not in _kernel_calls(alone)
+    conditionals = lambda c: c.as_text().count(" conditional(")
+    assert conditionals(fused) - conditionals(alone) == cfg.num_layers
+    own, base = fused.memory_analysis(), alone.memory_analysis()
+    assert own.alias_size_in_bytes == base.alias_size_in_bytes > 2e9
+    assert own.temp_size_in_bytes < base.temp_size_in_bytes + 128e6
+    names = {"float32": "f32", "bfloat16": "bf16"}
+    # (the 4 MB normaliser beside a retention state moves between memories)
+    held = {(names[a.dtype.name], ",".join(map(str, a.shape)))
+            for a in jax.tree.leaves(args[6])
+            if a.size * a.dtype.itemsize > 30e6}
+    assert held and not held & _copied_shapes(fused)
+    _fits(fused)
 
 
 def test_brumby_serve_programs_compile_and_fit(v5e):
