@@ -48,6 +48,24 @@ weights from ``--seed``):
            (inside the cell's limits); a slot without a sequence keeps a
            zero state; then the turn as one program against the two, and
            with every row idle, as in sala
+  mellum   a @ray_tpu.remote(num_tpus=1) task runs the benchmark's
+           Mellum2-12B-A2.5B configuration (published widths: hidden 2304,
+           32 query heads over 4 K/V heads of 128, 64 experts of 896 top-8
+           renormalized; 8 layers, six with a window of 1024 and a plain
+           RoPE, two full with YaRN; bf16) through the paged programs with a
+           page pool a kind: a prompt of 2300 tokens (past the window, its
+           last chunk padded) and one of 1020 (below it) whose two chunks
+           take the first's decode row along, then 6 decode steps of both
+           (the second crosses the window), against
+           perfbench/reference/mellum.py GIVEN the system's own routes
+           (inside the dense cells' 8% / 6%); every window-pool page wholly
+           behind a row's window is off its table and FILLED WITH NaN before
+           the program that follows, so whatever read it would show; the
+           window pool holds at most window + chunk tokens and a page a slot;
+           then the benchmark's own comparison (reference_check with the
+           routes given, under the limits of cells/mellum2_shortlong.json) on
+           the float8 CONTROL (the reference's forward with every weight
+           rounded to float8 e4m3), which has to come out NOT correct
   serve    serve.run(build_app(preset="gpt2_small")) answers 8 concurrent
            requests: six through the handle, one streamed, one over HTTP
 
@@ -826,6 +844,305 @@ def brumby_task(seed: int) -> dict:
     return {**out, **device_report()}
 
 
+def mellum_task(seed: int) -> dict:
+    """The Mellum-width checks (ISSUE 46): the paged chunk, step and fused
+    turn in bf16, a page pool a kind of layer, against the plain float32
+    reference given the system's own routes, with what lies behind a window
+    poisoned."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from perfbench.lib import configs, weights
+    from perfbench.lib import manifest as manifest_lib
+    from perfbench.reference import mellum as ref
+    from perfbench.reference.olmoe import routing_margin
+    from ray_tpu.models.decode import (StepRows, init_paged_caches,
+                                       paged_decode_step,
+                                       paged_prefill_into_slot)
+    from ray_tpu.models.transformer import ATTENTION, SLIDING
+
+    require_chip()
+    manifest = manifest_lib.load()
+    hp = manifest_lib.config(manifest, "mellum2_12b_l8")
+    cfg = configs.build_program_config(*configs.program_overrides(
+        hp, manifest_lib.read_json_from_bench("families", hp["model_type"])))
+    W, L, k = cfg.sliding_window, cfg.num_layers, cfg.moe_top_k
+    params = weights.make_params(cfg, seed)
+    S, C, T, P = 4, 512, 16, 192
+    # the window pool is handed out once and never again: a released page
+    # stays poisoned to the end
+    caches = init_paged_caches(cfg, S * P + 1, T, P, window_pages=300)
+    full = np.zeros((S, P), np.int32)
+    window = np.zeros((S, P), np.int32)
+    held = {s: [] for s in range(S)}
+    handed = [0]
+    peak_held = released = 0
+
+    def ensure(caches, s, cursor, upto):
+        """Slot ``s``'s pages for a program whose first row is at
+        ``cursor`` and whose last real row is before ``upto``, as the
+        scheduler sees to them."""
+        nonlocal peak_held, released
+        need = -(-upto // T)
+        full[s, :need] = 1 + s * P + np.arange(need)
+        first_kept = max(cursor - W + 1, 0) // T
+        gone = [j for j in held[s] if j < first_kept]
+        if gone:
+            pages = window[s, gone].copy()
+            window[s, gone] = 0
+            held[s] = [j for j in held[s] if j >= first_kept]
+            released += len(gone)
+            caches = [dataclasses.replace(
+                c, k=c.k.at[pages].set(jnp.nan), v=c.v.at[pages].set(jnp.nan))
+                if kind == SLIDING else c for c, kind in zip(caches,
+                                                             cfg.kinds)]
+        for j in range(max(held[s], default=-1) + 1, need):
+            if j >= first_kept:
+                handed[0] += 1
+                window[s, j] = handed[0]
+                held[s].append(j)
+        peak_held = max(peak_held, len(held[s]))
+        return caches
+
+    def tables(s=None):
+        rows = slice(None) if s is None else s
+        both = {ATTENTION: jnp.asarray(full[rows]),
+                SLIDING: jnp.asarray(window[rows])}
+        return both, both
+
+    prefill = jax.jit(lambda *a: paged_prefill_into_slot(
+        cfg, *a, attn="pallas", moe_info=True, logits=True),
+        donate_argnums=(6,))
+    step = jax.jit(lambda *a: paged_decode_step(
+        cfg, *a, attn="pallas", moe_info=True, logits=True),
+        donate_argnums=(6,))
+    ids = jnp.zeros(S, jnp.int32)
+    rng = np.random.default_rng(seed)
+    prompts = {0: rng.integers(1, cfg.vocab_size, 2300).tolist(),
+               2: rng.integers(1, cfg.vocab_size, 1020).tolist()}
+    got = {s: [] for s in prompts}
+    taken = {s: [] for s in prompts}
+    fed = {s: [] for s in prompts}
+    active = np.zeros(S, np.int32)
+    cursors = np.zeros(S, np.int32)
+    greedy = (np.zeros(S, np.float32), np.zeros(S, np.uint32))
+    rows_routed = live_rows = 0
+
+    def feed():
+        for s in np.flatnonzero(active):
+            fed[s].append(int(got[s][-1].argmax()))
+
+    for s, prompt in prompts.items():
+        for c0 in range(0, len(prompt), C):
+            chunk = prompt[c0:c0 + C]
+            real = len(chunk)
+            feed()
+            caches = ensure(caches, s, c0, c0 + real)
+            for row in np.flatnonzero(active):
+                caches = ensure(caches, row, int(cursors[row]),
+                                int(cursors[row]) + 1)
+            ids, caches, moe, logits = prefill(
+                params, jnp.asarray([chunk + [0] * (C - real)], jnp.int32),
+                np.int32(real), np.int32(c0), *tables(s), caches, ids,
+                np.int32(s if c0 + C >= len(prompt) else -1), np.float32(0),
+                np.uint32(0), StepRows(active.copy(), cursors.copy(),
+                                       *tables(), *greedy))
+            routes = np.asarray(moe["routes"])[:, 0]
+            taken[s].append(routes[:, :real])
+            rows_routed += int(np.asarray(moe["counts"]).sum())
+            live_rows += real + int(active.sum())
+            for row in np.flatnonzero(active):
+                got[row].append(np.asarray(logits[1 + row], np.float32))
+                taken[row].append(routes[:, C + row:C + row + 1])
+            cursors = cursors + active
+            cursors[s] += real
+        got[s].append(np.asarray(logits[0], np.float32))
+        active[s] = 1
+    assert len(fed[0]) == 2 and not fed[2]  # slot 0 decoded beside 2's chunks
+    for _ in range(6):
+        feed()
+        for row in prompts:
+            caches = ensure(caches, row, int(cursors[row]),
+                            int(cursors[row]) + 1)
+        ids, caches, moe, logits = step(
+            params, ids, jnp.asarray(active), cursors, *tables(), caches,
+            *greedy)
+        cursors = cursors + active
+        rows_routed += int(np.asarray(moe["counts"]).sum())
+        live_rows += len(prompts)
+        for s in prompts:
+            got[s].append(np.asarray(logits[s], np.float32))
+            taken[s].append(np.asarray(moe["routes"])[:, s])
+    assert cursors[2] > W > len(prompts[2])  # slot 2 crossed the window
+
+    def rel(got, want):
+        return {"max": float(np.abs(got - want).max() / np.abs(want).max()),
+                "rms": float(np.sqrt(((got - want) ** 2).mean()
+                                     / (want ** 2).mean()))}
+
+    errs, flip_share, margins = {}, [], []
+    for s, prompt in prompts.items():
+        tokens = jnp.asarray([prompt + fed[s]], jnp.int32)
+        routes = jnp.asarray(np.concatenate(taken[s], axis=1))[:, None]
+        want, probs = ref.forward_and_router(params, tokens, hp, routes)
+        errs[s] = rel(np.stack(got[s][:-1]), want[0][len(prompt) - 1:-1])
+        f, m = routing_margin(probs, routes)
+        flip_share.append(f)
+        margins.append(m)
+    poisoned = [bool(jnp.isnan(c.k).any()) for c in caches]
+    out = {"routed_err": errs, "flip_share": flip_share, "margin": margins,
+           "rows_routed": rows_routed,
+           "live_rows_x_k_x_layers": live_rows * k * L,
+           "window_pages_released": released,
+           "peak_window_pages_a_slot": peak_held,
+           "bound_window_pages_a_slot": -(-(W + C) // T) + 1,
+           "full_pages_slot_0": int((full[0] > 0).sum()),
+           "pools_poisoned": poisoned}
+    bad = []
+    if not all(np.isfinite(g).all() for rows in got.values() for g in rows):
+        bad.append("a logit is not finite: a released page was read")
+    if poisoned != [kind == SLIDING for kind in cfg.kinds] or not released:
+        bad.append("the poison is not in the window layers' pools alone")
+    if peak_held > out["bound_window_pages_a_slot"]:
+        bad.append("a slot held more of the window pool than window + chunk")
+    if rows_routed != live_rows * k * L:
+        bad.append("a row was dropped or a dead row counted")
+    if max(e["max"] for e in errs.values()) > 0.08 \
+            or max(e["rms"] for e in errs.values()) > 0.06:
+        bad.append("routed error above the dense cells' tolerance")
+    if max(margins) > 4e-3:
+        bad.append("an expert was taken that the reference scores far "
+                   "below its 8th")
+    if bad:
+        raise RuntimeError(f"mellum: {bad}: {out}")
+    del caches
+    control = out["float8_control"] = mellum_float8_control(cfg, hp, params,
+                                                            seed)
+    # NOT correct, by the comparison without routes (its root mean square)
+    # and by the one given them; the two statistics that read ONE position's
+    # worst cannot tell a flipped position from float8 (the cell's file)
+    if control["checks"]["reference_logits"] \
+            or control["checks"]["reference_logits_given_choices"]:
+        raise RuntimeError(f"mellum: the float8 control passes a comparison "
+                           f"that has to refuse it: {control}")
+    return {**out, **device_report()}
+
+
+class Float8Control:
+    """Stands where the replica stands in ``BenchLLMServer.reference_check``:
+    the plain reference's own forward pass with every weight rounded to
+    float8 e4m3 (the nearest type below the configuration's bf16) answers for
+    the program. ``_prefill`` and ``_decode_step`` hand out its logits row by
+    row, ``_forward_with_choices`` its logits GIVEN the routes the program's
+    own uncached forward took on the true weights, so the harness's own
+    comparison reads the control. The rounded weights stay float8 arrays (the
+    reference casts every weight it touches to float32): nothing can fold
+    the rounding away, and they fit beside the true ones."""
+
+    def __init__(self, cfg, params, ref, hp, fed):
+        import jax
+        import jax.numpy as jnp
+
+        self.cfg, self.params, self.ref, self.hp, self.fed = (
+            cfg, params, ref, hp, fed)
+        self._jax = jax
+        self.rounded = jax.tree.map(
+            jax.jit(lambda a: a.astype(jnp.float8_e4m3fn)), params)
+
+    def _prefill(self, params, prompt, caches):
+        import jax.numpy as jnp
+        import numpy as np
+
+        tokens = jnp.concatenate(
+            [prompt, jnp.asarray([self.fed], jnp.int32)], axis=1)
+        self.rows = np.asarray(self.ref.forward(
+            self.rounded, tokens, self.hp)[0], np.float32)[
+                prompt.shape[1] - 1:]
+        self._row = iter(self.rows)
+        return next(self._row)[None], caches
+
+    def _decode_step(self, params, token, caches):
+        return next(self._row)[None], caches
+
+    def _forward_with_choices(self, keyword, tokens, first, end):
+        import numpy as np
+
+        from perfbench.lib.serve_app import BenchLLMServer
+
+        _, choice = BenchLLMServer._forward_with_choices(
+            self, keyword, tokens, first, end)
+        mine = self.ref.forward(self.rounded, tokens, self.hp,
+                                **{keyword: choice})
+        return np.asarray(mine[0], np.float32)[first:end], choice
+
+
+def mellum_float8_control(cfg, hp, params, seed: int) -> dict:
+    """The cell's own comparison (``BenchLLMServer.reference_check`` with
+    the routes given too, then the limits of
+    ``cells/mellum2_shortlong.json`` as ``serve_cell.run`` applies them) on
+    the float8 control, which has to come out NOT correct: on the cell's
+    check prompt from ``seed``, fed the tokens the program's own greedy pass
+    answers it with (``prefill`` + ``decode_step``, what the check itself
+    drives). The control's tokens are its logits' best at each of those
+    positions. Returns the readings, the verdicts, and which limits the
+    control passes."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from perfbench.lib import manifest as manifest_lib
+    from perfbench.lib import traffic
+    from perfbench.lib.serve_app import BenchLLMServer
+    from perfbench.reference import mellum as ref
+    from ray_tpu.models.decode import decode_step, init_caches, prefill
+
+    cell = manifest_lib.read_json(manifest_lib.load(), "cells",
+                                  "mellum2_shortlong")
+    tol = cell["check_tolerance"]
+    n, new = int(cell["check_prompt_tokens"]), int(cell["check_new_tokens"])
+    ids = traffic.rng_for(seed, 9).integers(1, cfg.vocab_size,
+                                            size=n).tolist()
+    pre = jax.jit(lambda p, t, c: prefill(cfg, p, t, c))
+    dec = jax.jit(lambda p, t, c: decode_step(cfg, p, t, c))
+    logits, caches = pre(params, jnp.asarray([ids], jnp.int32),
+                         init_caches(cfg, 1, n + new))
+    served = [int(logits[0].argmax())]
+    for _ in range(new - 1):
+        logits, caches = dec(params, jnp.asarray([[served[-1]]], jnp.int32),
+                             caches)
+        served.append(int(logits[0].argmax()))
+    del caches, logits
+    control = Float8Control(cfg, params, ref, hp, served[:-1])
+    path = os.path.join(manifest_lib.BENCH_DIR, "reference", "mellum.py")
+    check = BenchLLMServer.reference_check(control, ids, served, hp, path,
+                                           given=True)
+    # the harness read the PROGRAM's tokens' margin; the control's own
+    # tokens by the same expression
+    want = np.asarray(ref.forward(
+        params, jnp.asarray([ids + served[:-1]], jnp.int32), hp)[0],
+        np.float32)[n - 1:]
+    scale = float(np.abs(want).max())
+    check["served_margin"] = max(
+        float((want[i].max() - want[i][int(row.argmax())]) / scale)
+        for i, row in enumerate(control.rows))
+    given = [k for k in tol if k.startswith("given_")]
+    verdicts = {
+        "reference_logits": check["logit_err"] <= tol["logit_err"]
+        and check["logit_rms_err"] <= tol["logit_rms_err"],
+        "served_tokens_near_argmax":
+            check["served_margin"] <= tol["served_margin"],
+        "reference_logits_given_choices": all(
+            check[k] <= tol[k] for k in given)}
+    return {"seed": seed, "positions": new,
+            "compared": {k: {"value": check[k], "limit": tol[k]}
+                         for k in sorted(tol)},
+            "checks": verdicts,
+            "limits_passed": sorted(k for k in tol if check[k] <= tol[k])}
+
+
 def served_batch(cfg, params, seed: int) -> dict:
     """A short mixed batch through ``ContinuousScheduler`` at the widths
     ``params`` has (ISSUE 29): twelve requests over eight slots, the loop
@@ -985,6 +1302,15 @@ def brumby_phase(seed: int) -> None:
     emit("brumby", seconds=round(time.perf_counter() - t0, 1), **out)
 
 
+def mellum_phase(seed: int) -> None:
+    import ray_tpu
+
+    t0 = time.perf_counter()
+    out = ray_tpu.get(
+        ray_tpu.remote(num_tpus=1)(mellum_task).remote(seed), timeout=1500)
+    emit("mellum", seconds=round(time.perf_counter() - t0, 1), **out)
+
+
 def serve_phase(seed: int) -> None:
     import ray_tpu
     import ray_tpu.serve as serve
@@ -1098,6 +1424,7 @@ def one_chip(seed: int) -> dict:
     olmoe_phase(seed)
     sala_phase(seed)
     brumby_phase(seed)
+    mellum_phase(seed)
     serve_phase(seed)
     return out["device"]
 

@@ -18,4 +18,5 @@ from ray_tpu.models.presets import (  # noqa: F401
     moe_debug,
     minicpm_sala_debug,
     brumby_debug,
+    mellum_debug,
 )

@@ -18,8 +18,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.transformer import (ATTENTION, LINEAR, RETENTION, SPARSE,
-                                        STATE_KINDS, STATE_MIXERS,
+from ray_tpu.models.transformer import (ATTENTION, LINEAR, RETENTION,
+                                        SLIDING, SPARSE, STATE_KINDS,
+                                        STATE_MIXERS,
                                         TransformerConfig, _gated_out, _mlp,
                                         _norm, _qkv, _residual, embed,
                                         final_hidden, forward, layer_params,
@@ -59,13 +60,16 @@ class LayerKVCache:
         new = LayerKVCache(k=k, v=v, length=self.length + k_new.shape[1])
         return new, k, v
 
-    def mask_bias(self, q_len: int):
+    def mask_bias(self, q_len: int, window: Optional[int] = None, first=0):
         """Additive bias [1,1,1,q_len,max_len]: query i (global position
-        length+i) may attend to cache slot j iff j <= length+i."""
+        length+first+i) may attend to cache slot j iff j <= its position
+        (and, with a ``window``, j > its position - window)."""
         max_len = self.k.shape[1]
-        qpos = self.length + jnp.arange(q_len)[:, None]
+        qpos = self.length + first + jnp.arange(q_len)[:, None]
         jpos = jnp.arange(max_len)[None, :]
         allowed = jpos <= qpos
+        if window is not None:
+            allowed = jnp.logical_and(allowed, jpos > qpos - window)
         bias = jnp.where(allowed, 0.0, -1e30).astype(jnp.float32)
         return bias[None, None, None, :, :]
 
@@ -119,7 +123,9 @@ def init_caches(cfg: TransformerConfig, batch: int, max_len: int,
     zero = jnp.zeros((), jnp.int32)
 
     def one(kind):
-        if kind == ATTENTION:
+        if kind in (ATTENTION, SLIDING):
+            # a window layer's contiguous cache keeps every position too:
+            # the window is in the mask (the serving pool is what forgets)
             return LayerKVCache.zeros(batch, max_len, cfg.kv_heads,
                                       cfg.head_dim, dtype)
         if kind in STATE_KINDS:
@@ -365,12 +371,17 @@ class SparsePagedKVCache:
 
 def init_paged_caches(cfg: TransformerConfig, num_pages: int,
                       page_tokens: int, pages_per_slot: int,
-                      dtype=None, slots: Optional[int] = None) -> List[Any]:
+                      dtype=None, slots: Optional[int] = None,
+                      window_pages: Optional[int] = None) -> List[Any]:
     """The serving pool, a layer at a time and by the layer's kind: pages
     for an attention layer (with pooled key rows for a 'minicpm4' one), a
     state a slot (``slots`` of them) for a layer of a kind in
     ``STATE_KINDS``. A model none of whose layers holds a page has no pool:
-    ``num_pages`` may then be anything, and nothing is made of it."""
+    ``num_pages`` may then be anything, and nothing is made of it. A
+    'sliding_attention' layer's pool has a page count of its own,
+    ``window_pages`` (its page 0 the garbage page too): its pages go
+    through tables of their own (``pool_of``), and how many a slot keeps is
+    the caller's business."""
     if page_tokens < 1:
         raise ValueError(f"page_tokens must be >= 1, got {page_tokens}")
     if num_pages < 2 and cfg.holds_pages:
@@ -390,14 +401,18 @@ def init_paged_caches(cfg: TransformerConfig, num_pages: int,
         raise ValueError("a model with layers that keep a state a slot "
                          f"({_state_kinds(cfg)}): init_paged_caches needs "
                          "slots")
+    if SLIDING in cfg.kinds and (window_pages is None or window_pages < 2):
+        raise ValueError("a model with 'sliding_attention' layers: "
+                         "init_paged_caches needs window_pages >= 2, got "
+                         f"{window_pages}")
     dtype = dtype or cfg.dtype
 
     def one(kind):
         if kind in STATE_KINDS:
             return init_state(cfg, kind, slots)
         pool = SparsePagedKVCache if kind == SPARSE else PagedKVCache
-        return pool.zeros(num_pages, page_tokens, cfg.kv_heads, cfg.head_dim,
-                          dtype)
+        return pool.zeros(window_pages if kind == SLIDING else num_pages,
+                          page_tokens, cfg.kv_heads, cfg.head_dim, dtype)
 
     return [one(kind) for kind in cfg.kinds]
 
@@ -407,11 +422,25 @@ def _state_kinds(cfg: TransformerConfig) -> str:
     return ", ".join(repr(k) for k in STATE_KINDS if k in cfg.kinds)
 
 
+def pool_of(kind: str) -> str:
+    """The pool a page-holding layer's pages are in, by the name its tables
+    go under: the window layers' own, or the one every other kind shares."""
+    return SLIDING if kind == SLIDING else ATTENTION
+
+
+def pool_tables(tables, kind: str):
+    """A layer's page tables out of a program's: an array as it stands (a
+    model of one pool), else the entry of the layer's pool in a dict by
+    ``pool_of`` (a model with 'sliding_attention' layers beside others)."""
+    return tables[pool_of(kind)] if isinstance(tables, dict) else tables
+
+
 class _Rows(NamedTuple):
     """One group of rows of a paged program: what meets a layer's pages or
     states at ONE shape. tokens/positions/valid: [S, K]; lengths: [S]
     attention cursors; read_tables/write_tables: [S, P] (None for a model
-    that holds no page). For the layers that keep a state a slot
+    that holds no page; a dict of them by pool, ``pool_tables``, for one
+    whose layers hold pages in two). For the layers that keep a state a slot
     (``STATE_KINDS``) the group is a step's — a row a slot over ALL slots'
     states, of which the rows not ``active`` [S] keep theirs bitwise — or,
     with ``slot``, a chunk's: its one row continues that slot's own states,
@@ -540,8 +569,13 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
     if cfg.holds_pages:
         T = next(c.k.shape[1] for c, kind in zip(caches, cfg.kinds)
                  if kind not in STATE_KINDS)
-        pages = batch([written_pages(g.write_tables, g.positions, T)
-                       for g in groups])
+        # the pages the rows land on, a pool at a time (one, but for a
+        # model with window layers)
+        pages = {pool: batch([written_pages(pool_tables(g.write_tables, pool),
+                                            g.positions, T) for g in groups])
+                 for pool in dict.fromkeys(
+                     pool_of(kind) for kind in cfg.kinds
+                     if kind not in STATE_KINDS)}
         offs = positions % T
     new_caches = []
     moe_layers = []
@@ -560,10 +594,10 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
             a = finish(cfg, ap, h, batch(outs))
             new_caches.append(_STATES[kind](**state))
         else:
-            q, k, v = _qkv(cfg, ap, h, rope if kind == ATTENTION else None,
-                           positions)
-            ck = write_pages(c.k, k, pages, offs)
-            cv = write_pages(c.v, v, pages, offs)
+            q, k, v = _qkv(cfg, ap, h, None if kind == SPARSE else rope,
+                           positions, kind)
+            ck = write_pages(c.k, k, pages[pool_of(kind)], offs)
+            cv = write_pages(c.v, v, pages[pool_of(kind)], offs)
             if kind == SPARSE:
                 pools = (ck, cv, update_page_means(c.means, ck, *(
                     (g.write_tables, g.positions) for g in groups)))
@@ -576,9 +610,14 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
                 a = _gated_out(cfg, ap, h, batch(outs))
                 new_caches.append(SparsePagedKVCache(*pools))
             else:
-                o = batch([paged_attention(q_g, ck, cv, g.read_tables,
-                                           g.lengths, impl=impl)
-                           for g, q_g in zip(groups, split(q))])
+                # a window layer's call has a name of its own, so a trace
+                # tells its kernel from the full layers'
+                o = batch([paged_attention(
+                    q_g, ck, cv, pool_tables(g.read_tables, kind), g.lengths,
+                    impl=impl, window=cfg.window(kind),
+                    name=("window_attention" if kind == SLIDING
+                          else "paged_attention"))
+                    for g, q_g in zip(groups, split(q))])
                 a = jnp.einsum("bshk,hkd->bsd", o, ap["wo"].astype(cfg.dtype))
                 new_caches.append(PagedKVCache(k=ck, v=cv))
         x = _residual(cfg, x, a)
@@ -713,7 +752,7 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
     taps = [] if selected else None
     C = tokens.shape[1]
     steps = jnp.arange(C, dtype=jnp.int32)[None, :]
-    row = lambda table: None if table is None else table[None]
+    row = lambda table: jax.tree.map(lambda t: t[None], table)
     groups = [_Rows(tokens, steps + cursor, jnp.reshape(cursor, (1,)),
                     row(read_row), row(write_row), steps < real_len,
                     slot=state_slot, real_len=real_len)]
@@ -724,8 +763,8 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
         groups.append(_Rows(
             ids[:, None], step.cursors[:, None],
             jnp.where(live, step.cursors, -1), step.read_tables,
-            None if step.write_tables is None
-            else jnp.where(live[:, None], step.write_tables, 0),
+            jax.tree.map(lambda t: jnp.where(live[:, None], t, 0),
+                         step.write_tables),
             live[:, None], active=step.active))
         sample = tuple(jnp.concatenate(pair) for pair in zip(sample, (
             jnp.where(live, step.temperature, 0.0), step.seeds,

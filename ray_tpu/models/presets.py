@@ -124,6 +124,37 @@ def brumby_debug(**overrides) -> TransformerConfig:
     return TransformerConfig(**kw)
 
 
+def mellum_debug(**overrides) -> TransformerConfig:
+    """Tiny Mellum-2-shaped config (JetBrains/Mellum2-12B-A2.5B-Instruct:
+    three 'sliding_attention' layers to every 'full_attention' one, each
+    kind with a RoPE rule of its own — plain for the window layers, YaRN
+    for the full ones — a head size that is a field and not ``embed_dim //
+    num_heads``, grouped K/V heads, no q/k norm, and dropless top-k experts
+    whose weights are renormalized) for tests: a window of 24 tokens, YaRN
+    over an original context of 32. The kinds follow the published pattern
+    for however many layers ``num_layers`` says, unless ``layer_kinds`` is
+    given."""
+    kw = dict(
+        vocab_size=256, num_layers=8, embed_dim=64, num_heads=4,
+        num_kv_heads=2, head_dim=32, mlp="moe", mlp_dim=64,
+        moe_num_experts=8, moe_top_k=3, moe_renormalize=True,
+        sliding_window=24, max_seq_len=256, norm="rmsnorm", pos="rope",
+        norm_eps=1e-6, tie_embeddings=False, dtype=jnp.float32,
+        rope_parameters={
+            "full_attention": {
+                "rope_type": "yarn", "rope_theta": 10000.0, "factor": 4.0,
+                "original_max_position_embeddings": 32, "beta_fast": 4.0,
+                "beta_slow": 1.0, "attention_factor": 1.1386294361119891},
+            "sliding_attention": {"rope_type": "default",
+                                  "rope_theta": 10000.0}},
+    )
+    kw.update(overrides)
+    kw.setdefault("layer_kinds", tuple(
+        "full_attention" if i % 4 == 3 else "sliding_attention"
+        for i in range(kw["num_layers"])))
+    return TransformerConfig(**kw)
+
+
 # ---------------------------------------------------------------------------
 # pipeline stage partition (MPMD train.PipelineTrainer shards)
 #

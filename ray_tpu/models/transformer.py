@@ -11,7 +11,11 @@ Config switches:
   * pos:  'rope' (LLaMA) | 'learned' (GPT-2)
   * mlp:  'swiglu' (LLaMA) | 'gelu' (GPT-2) | 'moe' (SwiGLU experts,
           dropless top-k routing over grouped matmuls, ops/moe.py)
-  * GQA via num_kv_heads; tied embeddings via tie_embeddings.
+  * GQA via num_kv_heads; tied embeddings via tie_embeddings; head_dim a
+    field where it is not embed_dim // num_heads.
+  * rope_parameters: a RoPE rule a kind of layer, keyed as the source keys
+    them (``ops.rotary.rule_frequencies``: 'default' or 'yarn'); the angles
+    are then computed from the positions, no table.
   * qk_norm: RMSNorm over the whole projected q and k (OLMoE);
     head_qk_norm: RMSNorm over each head's values, one scale of head_dim
     shared by the heads (MiniCPM);
@@ -26,7 +30,13 @@ Config switches:
     'power-retention' (degree 2, gated and normalised, on the symmetric
     half of the outer product as the state of a K/V head, shared by its
     query heads; q/k norm and RoPE as the block has them, no output gate;
-    ops/power_retention.py). Each is written once, state in and state out,
+    ops/power_retention.py) and 'sliding_attention' (softmax attention
+    over the last ``sliding_window`` positions, the query's own among them:
+    the attention block as it stands, K/V heads and all, with a window in
+    the mask; in the serving pool its pages are a pool of their own, of
+    which a slot holds those the window still covers). 'full_attention' is
+    taken for 'attention', so a source's ``layer_types`` map straight onto
+    ``layer_kinds``. Each state kind is written once, state in and state out,
     as three pieces — project the rows, mix one group of them, finish the
     rows (``STATE_MIXERS``, ``sparse_mix``) — that every forward calls: this
     one with or without caches (``state_mixer``, ``sparse_mixer``), and the
@@ -64,7 +74,11 @@ from ray_tpu.ops.sparse_attention import (SparseSizes, sparse_attention,
 
 ATTENTION, SPARSE, LINEAR = "attention", "minicpm4", "lightning-attn"
 RETENTION = "power-retention"
-LAYER_KINDS = (ATTENTION, SPARSE, LINEAR, RETENTION)
+SLIDING = "sliding_attention"
+LAYER_KINDS = (ATTENTION, SPARSE, LINEAR, RETENTION, SLIDING)
+# what a source calls the kind this file calls 'attention': its name in
+# ``layer_kinds`` as given and in ``rope_parameters``
+FULL_ATTENTION = "full_attention"
 # the kinds that keep a fixed state a sequence and no keys or values: no
 # page of the serving pool is theirs
 STATE_KINDS = (LINEAR, RETENTION)
@@ -77,6 +91,7 @@ class TransformerConfig:
     embed_dim: int = 768
     num_heads: int = 12
     num_kv_heads: Optional[int] = None        # None => MHA
+    head_dim: Optional[int] = None            # None => embed_dim // num_heads
     mlp_dim: Optional[int] = None             # None => 4x (gelu) / 8/3x (swiglu)
     # MoE (mlp='moe'): SwiGLU experts, dropless top-k routing
     moe_num_experts: int = 0
@@ -93,6 +108,12 @@ class TransformerConfig:
     # 'minicpm4': the sizes of the selection (ops.sparse_attention
     # .SparseSizes' fields; a dict is taken and frozen)
     sparse_config: Any = None
+    # 'sliding_attention': the positions a query attends, its own among them
+    sliding_window: int = 0
+    # a RoPE rule a kind of layer, {'full_attention': {...},
+    # 'sliding_attention': {...}} as the source's config has it (a dict is
+    # taken and frozen); None: ``rope_theta`` for every layer that rotates
+    rope_parameters: Any = None
     # 'lightning-attn': head h decays by exp(-2^(-e (h + 1) / H)) a token
     linear_slope_exponent: float = 8.0
     # MiniCPM's scales: the embedding times scale_emb; every residual
@@ -125,8 +146,12 @@ class TransformerConfig:
     ce_chunk: int = 2048
 
     def __post_init__(self):
+        if self.head_dim is None:
+            object.__setattr__(self, "head_dim",
+                               self.embed_dim // self.num_heads)
         if self.layer_kinds is not None:
-            kinds = tuple(self.layer_kinds)
+            kinds = tuple(ATTENTION if kind == FULL_ATTENTION else kind
+                          for kind in self.layer_kinds)
             if len(kinds) != self.num_layers or set(kinds) - set(LAYER_KINDS):
                 raise ValueError(
                     f"layer_kinds must name one of {LAYER_KINDS} for each of "
@@ -135,6 +160,13 @@ class TransformerConfig:
         if isinstance(self.sparse_config, dict):
             object.__setattr__(self, "sparse_config",
                                tuple(sorted(self.sparse_config.items())))
+        if isinstance(self.rope_parameters, dict):
+            object.__setattr__(self, "rope_parameters", tuple(sorted(
+                (kind, tuple(sorted(rule.items())))
+                for kind, rule in self.rope_parameters.items())))
+        if SLIDING in self.kinds and self.sliding_window < 1:
+            raise ValueError("a 'sliding_attention' layer needs "
+                             f"sliding_window >= 1, got {self.sliding_window}")
 
     @property
     def kv_heads(self) -> int:
@@ -175,9 +207,18 @@ class TransformerConfig:
         return self.scale_depth / math.sqrt(self.scale_depth_layers
                                             or self.num_layers)
 
-    @property
-    def head_dim(self) -> int:
-        return self.embed_dim // self.num_heads
+    def rope_rule(self, kind: str):
+        """The rule (``ops.rotary.rule_frequencies``) the layers of ``kind``
+        rotate by, None where ``rope_theta`` says it all."""
+        if self.rope_parameters is None:
+            return None
+        rules = dict(self.rope_parameters)
+        return dict(rules[FULL_ATTENTION if kind == ATTENTION else kind])
+
+    def window(self, kind: str) -> Optional[int]:
+        """The positions a query of a layer of ``kind`` attends, its own
+        among them; None for the whole context."""
+        return self.sliding_window if kind == SLIDING else None
 
     @property
     def hidden_dim(self) -> int:
@@ -377,12 +418,13 @@ def _norm(cfg, p, x):
 COMPUTED = "computed"  # ``rope``: angles from the positions, no table
 
 
-def _qkv(cfg, p, x, rope, positions):
+def _qkv(cfg, p, x, rope, positions, kind=ATTENTION):
     """The q/k/v projection of every forward (training, tensor-parallel,
     cached and paged decode): x [B, S, d] -> q [B, S, H, D], k and v
     [B, S, Hkv, D], q and k normalized (``cfg.qk_norm``,
     ``cfg.head_qk_norm``) and rotated (``rope``: the (cos, sin) tables,
-    ``COMPUTED`` or None)."""
+    ``COMPUTED`` — by ``kind``'s own rule where ``cfg.rope_parameters``
+    gives one — or None)."""
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(cfg.dtype))
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(cfg.dtype))
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(cfg.dtype))
@@ -397,8 +439,11 @@ def _qkv(cfg, p, x, rope, positions):
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     if rope is COMPUTED:
-        q = apply_rotary_at(q, positions, cfg.rope_theta)
-        k = apply_rotary_at(k, positions, cfg.rope_theta)
+        if positions is None:
+            positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None]
+        rule = cfg.rope_rule(kind)
+        q = apply_rotary_at(q, positions, cfg.rope_theta, rule)
+        k = apply_rotary_at(k, positions, cfg.rope_theta, rule)
     elif rope is not None:
         cos, sin = rope
         q = apply_rotary(q, cos, sin, positions)
@@ -406,14 +451,90 @@ def _qkv(cfg, p, x, rope, positions):
     return q, k, v
 
 
-def _attn(cfg, p, x, rope, positions, sp_axis, kv_cache=None):
-    q, k, v = _qkv(cfg, p, x, rope, positions)
+# more query rows than this attend a block of them at a time (a cached
+# prefill, and a window layer without a cache): the reference's [heads, rows,
+# keys] scores of a long prompt are gigabytes otherwise (a window layer's
+# check prompt passes its window)
+_CACHED_QUERY_BLOCK = 512
+
+
+def _by_query_blocks(attend_block, q, block):
+    """``attend_block(rows [B, block, H, D], the block's index)`` over q
+    [B, S, H, D] a block of rows at a time; the last block's padding rows
+    are cut off again."""
+    S = q.shape[1]
+    n = -(-S // block)
+    blocks = jnp.pad(q, ((0, 0), (0, n * block - S), (0, 0), (0, 0)))
+    blocks = blocks.reshape(q.shape[0], n, block, *q.shape[2:])
+    out = jax.lax.map(lambda args: attend_block(*args),
+                      (jnp.moveaxis(blocks, 1, 0), jnp.arange(n)))
+    return jnp.moveaxis(out, 0, 1).reshape(
+        q.shape[0], n * block, *q.shape[2:])[:, :S]
+
+
+def _cached_attention(q, k_all, v_all, kv_cache, window):
+    """q [B, S, H, D] at the cache's positions ``length`` .. against the
+    whole fixed-size cache, masked by ``kv_cache.mask_bias``."""
+    S = q.shape[1]
+    attend = lambda q, bias: attention(q, k_all, v_all, causal=False,
+                                       impl="reference", bias=bias)
+    block = _CACHED_QUERY_BLOCK
+    if S <= block:
+        return attend(q, kv_cache.mask_bias(S, window=window))
+    return _by_query_blocks(
+        lambda rows, b: attend(rows, kv_cache.mask_bias(
+            block, window=window, first=b * block)), q, block)
+
+
+def _window_bias(rows: int, keys: int, window: int, first=0, key0=0):
+    """Additive bias [1, 1, 1, rows, keys]: query ``first + r`` attends key
+    ``key0 + c`` iff ``0 <= j`` and ``i - window < j <= i``."""
+    i = first + jnp.arange(rows)[:, None]
+    j = key0 + jnp.arange(keys)[None, :]
+    allowed = (j >= 0) & (j <= i) & (j > i - window)
+    return jnp.where(allowed, 0.0, -1e30).astype(
+        jnp.float32)[None, None, None]
+
+
+def _window_attention(q, k, v, window):
+    """Without a cache: q, k, v [B, S, ., D] at positions 0 .. S-1, query i
+    over the keys ``i - window < j <= i``. The masked reference (the flash
+    kernels take no window: ROADMAP R4); a long sequence a block of query
+    rows at a time against the keys that block's windows reach and no
+    others, so the scores are [heads, block, window + block] whatever S."""
+    S = q.shape[1]
+    block = _CACHED_QUERY_BLOCK
+    attend = functools.partial(attention, causal=False, impl="reference")
+    if S <= block:
+        return attend(q, k, v, bias=_window_bias(S, S, window))
+    back = min(window - 1, S)  # keys before a block's first row in reach
+    n = -(-S // block)
+    pad = ((0, 0), (back, n * block - S), (0, 0), (0, 0))
+    k, v = jnp.pad(k, pad), jnp.pad(v, pad)
+
+    def attend_block(rows, b):
+        # the padded arrays' row t is key t - back: this block's keys start
+        # at its first row less ``back``
+        keys = [jax.lax.dynamic_slice_in_dim(a, b * block, back + block, 1)
+                for a in (k, v)]
+        return attend(rows, *keys, bias=_window_bias(
+            block, back + block, window, first=b * block,
+            key0=b * block - back))
+
+    return _by_query_blocks(attend_block, q, block)
+
+
+def _attn(cfg, p, x, rope, positions, sp_axis, kv_cache=None,
+          kind=ATTENTION):
+    q, k, v = _qkv(cfg, p, x, rope, positions, kind)
+    window = cfg.window(kind)
     if kv_cache is not None:
         # decode: append to cache, attend over the full prefix
-        bias = kv_cache.mask_bias(x.shape[1])
         new_cache, k_all, v_all = kv_cache.update(k, v)
-        o = attention(q, k_all, v_all, causal=False, impl="reference",
-                      bias=bias)
+        o = _cached_attention(q, k_all, v_all, kv_cache, window)
+    elif window is not None:
+        o = _window_attention(q, k, v, window)
+        new_cache = None
     elif cfg.attn_impl == "ring" and sp_axis is not None:
         o = ring_attention_local(q, k, v, sp_axis, causal=True)
         new_cache = None
@@ -602,8 +723,8 @@ def _mixer(cfg, kind, p, x, rope, positions, sp_axis, cache, taps):
     ``cache``: None, or what ``models.decode.init_caches`` makes for the
     kind (a contiguous cache is, for the sparse kind, a pool of its own
     whose page table is the identity)."""
-    if kind == ATTENTION:
-        return _attn(cfg, p, x, rope, positions, sp_axis, cache)
+    if kind in (ATTENTION, SLIDING):
+        return _attn(cfg, p, x, rope, positions, sp_axis, cache, kind)
     B, S = x.shape[:2]
     pos = jnp.broadcast_to(jnp.arange(S)[None] if positions is None
                            else positions, (B, S)).astype(jnp.int32)
@@ -667,10 +788,15 @@ def _mlp(cfg, p, x, valid=None, layer=None):
 def stacked_mlp(cfg, params, layer_params, i):
     """``(mlp weights, layer)`` for ``_mlp``: a layer's own weights and
     None, but for experts stacked over layers (``scan_layers``) outside a
-    scan the whole stack and ``i`` — the grouped matmuls cannot fuse the
-    slice as a dense matmul does, and would copy the layer's experts."""
+    scan the whole stack that holds the layer and its index there — the
+    grouped matmuls cannot fuse the slice as a dense matmul does, and would
+    copy the layer's experts."""
     if cfg.mlp == "moe" and cfg.scan_layers and cfg.period == 1:
         return params["blocks"]["mlp"], i
+    if cfg.mlp == "moe" and cfg.scan_layers:
+        # a pattern of kinds: a stack a place in the period (``init_params``)
+        return (params["blocks"][f"p{i % cfg.period}"]["mlp"],
+                i // cfg.period)
     return layer_params["mlp"], None
 
 
@@ -713,8 +839,10 @@ def project(cfg, params, x):
 def rope_table(cfg):
     """The (cos, sin) tables plain attention rotates by, None for a model
     none of whose layers does (a table is as long as the context)."""
-    if cfg.pos == "learned" or ATTENTION not in cfg.kinds:
+    if cfg.pos == "learned" or not {ATTENTION, SLIDING} & set(cfg.kinds):
         return None
+    if cfg.rope_parameters is not None:
+        return COMPUTED  # a rule a kind: angles from the positions
     return rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
 
 
@@ -760,8 +888,10 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
                 f"unknown remat_policy {cfg.remat_policy!r}; "
                 f"expected one of {sorted(policies)}")
         policy = policies[cfg.remat_policy]
+        # ``COMPUTED`` is a name, not an array: static like the config
+        static = (0, 3, 5) if rope is COMPUTED else (0, 5)
         block_fn = lambda kind: jax.checkpoint(
-            functools.partial(_block, kind=kind), static_argnums=(0, 5),
+            functools.partial(_block, kind=kind), static_argnums=static,
             policy=policy)
 
     if return_routes and (cfg.mlp != "moe" or kv_caches is not None):
@@ -779,15 +909,19 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
 
         def body(carry, layer_params):
             h, aux_acc = carry
-            moe = None
+            chosen = []
             for j, fn in enumerate(fns):
                 h, _, aux, moe = fn(
                     cfg, layer_params if period == 1
                     else layer_params[f"p{j}"], h, rope, positions, sp_axis)
                 aux_acc = aux_acc + aux
-            return (h, aux_acc), (moe["routes"] if return_routes else None)
+                chosen.append(moe["routes"] if return_routes else None)
+            return (h, aux_acc), (jnp.stack(chosen) if return_routes
+                                  else None)
         (x, aux_total), routes = jax.lax.scan(body, (x, 0.0),
                                               params["blocks"])
+        if return_routes:  # [steps, layers a step, ...] -> a layer a row
+            routes = routes.reshape(-1, *routes.shape[2:])
     else:
         new_caches = [] if kv_caches is not None else None
         per_layer = []
@@ -848,7 +982,7 @@ def tp_block_shard_spec(cfg: TransformerConfig) -> Dict[str, Dict[str, int]]:
         raise ValueError(
             "tensor parallelism does not support cfg.qk_norm=True — the "
             "norm spans all heads' values, which tp splits over ranks")
-    if set(cfg.kinds) != {ATTENTION}:
+    if set(cfg.kinds) != {ATTENTION} or cfg.rope_parameters is not None:
         raise ValueError(
             "tensor parallelism knows plain attention layers alone, not "
             f"cfg.layer_kinds={cfg.layer_kinds}")

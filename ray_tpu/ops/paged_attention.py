@@ -60,6 +60,18 @@ its width, read page 0 too. No part of a buffer is ever computed on before
 a copy has defined it. What the tail costs is counted by the scheduler
 (``attn_tokens_fetched`` against ``attn_tokens_attended``).
 
+A WINDOW (``window=W``, static; a 'sliding_attention' layer's call): row
+``i`` attends ``j`` iff ``lengths[s] + i - W < j <= lengths[s] + i``. Both
+implementations then START at the block that holds the first position the
+query tile's first row may attend and fetch nothing before it, so a call's
+cost follows the window and not the context, and the pages wholly behind the
+window are never read (their table entries may point at the garbage page:
+the scheduler releases them). A block that lies wholly before a LATER row's
+window gives that row p = 1 on every masked score for a while; the first
+block that holds a position it may attend (its own, if no other) rescales
+that by exp(-1e30 - m) = 0 exactly, as the online softmax does for any
+stale maximum. Without a window the traced program is what it was.
+
 Decode is the K=1 case; the fixed-K verify window and the prefill chunk
 share the same kernel — each query row reduces over blocks in ascending
 order with a full-width mask, so per-row reduction order matches K
@@ -124,7 +136,8 @@ def resolve_impl(cfg, impl: Optional[str] = None) -> str:
 
 def paged_attention(q, k_pool, v_pool, tables, lengths, *,
                     sm_scale: Optional[float] = None, impl: str = "reference",
-                    name: str = "paged_attention"):
+                    name: str = "paged_attention",
+                    window: Optional[int] = None):
     """Attention for q at positions [lengths[s], lengths[s] + K) of each slot.
 
     q: [S, K, H, D] queries (K = 1 decode, K > 1 verify/prefill window).
@@ -133,7 +146,8 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
     (``-K`` for a row without a live sequence: zeros, no page read).
     Returns [S, K, H, D] in q.dtype. ``name``: what the kernel is called in
     a profiler trace (``ops.sparse_attention`` runs it over tables of chosen
-    pages under a name of its own).
+    pages under a name of its own). ``window``: the positions a row
+    attends, its own among them (static; None: all up to its own).
 
     The new tokens' k/v must already be WRITTEN into their pages (write-
     before-attend, the arena's standing invariant) — this op only reads.
@@ -154,11 +168,14 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
         raise ValueError(
             f"head mismatch: q {q.shape} vs pool {k_pool.shape} (pool is "
             "[N, T, Hkv * D]; H must be a multiple of Hkv, D must match)")
+    if window is not None and window < 1:
+        raise ValueError(f"window must be >= 1, got {window}")
     if impl == "pallas":
         return _paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
-                                       sm_scale, should_interpret(), name)
+                                       sm_scale, should_interpret(), name,
+                                       window)
     return _paged_attention_reference(q, k_pool, v_pool, tables, lengths,
-                                      sm_scale)
+                                      sm_scale, window)
 
 
 # ------------------------------------------------------------ tile sizes
@@ -172,7 +189,8 @@ _SUB_ROWS = 256          # of which one matmul takes so many: the f32 score
 
 
 def tile_sizes(qk: int, group: int, page_tokens: int, pages_per_slot: int,
-               row_bytes: int) -> Tuple[int, int]:
+               row_bytes: int, window: Optional[int] = None
+               ) -> Tuple[int, int]:
     """(pages per block, query tokens per tile) for a K = ``qk`` window over
     a pool whose token rows hold ``row_bytes`` (all kv heads of K or of V).
 
@@ -181,7 +199,10 @@ def tile_sizes(qk: int, group: int, page_tokens: int, pages_per_slot: int,
     tiles of about ``_MAX_Q_ROWS`` query rows (whole multiples of the
     ``_SUB_ROWS`` one matmul takes); a block is the largest power of two of
     pages that keeps its K and V rows within ``_BLOCK_BYTES``, its tokens
-    within ``_MAX_BLOCK_TOKENS`` and itself within the page table."""
+    within ``_MAX_BLOCK_TOKENS`` and itself within the page table — and,
+    under a ``window``, within half of it: a row's window then spans three
+    blocks at most, of which two are full (at 1024 tokens and pages of 16
+    the 512-token block as it is)."""
     n_tiles = -(-qk * group // _MAX_Q_ROWS)
     q_tile = -(-qk // n_tiles)
     if q_tile * group > _SUB_ROWS:  # whole matmuls of _SUB_ROWS rows
@@ -189,12 +210,15 @@ def tile_sizes(qk: int, group: int, page_tokens: int, pages_per_slot: int,
         q_tile = -(-q_tile // step) * step
     pages = min(_BLOCK_BYTES // (2 * page_tokens * row_bytes),
                 _MAX_BLOCK_TOKENS // page_tokens, pages_per_slot)
+    if window is not None:
+        pages = min(pages, window // (2 * page_tokens))
     return 1 << (max(pages, 1).bit_length() - 1), q_tile
 
 
 def streamed_tokens(impl: str, qk: int, cursors: List[int], idle_rows: int,
                     group: int, page_tokens: int, pages_per_slot: int,
-                    row_bytes: int) -> Tuple[int, int]:
+                    row_bytes: int, window: Optional[int] = None
+                    ) -> Tuple[int, int]:
     """(attended, fetched) token positions of one ``[S, K = qk]`` call, per
     layer: ``tile_sizes``' twin on the host, for counters. ``cursors``: the
     attention cursor of every row that attends its window; ``idle_rows``:
@@ -203,23 +227,35 @@ def streamed_tokens(impl: str, qk: int, cursors: List[int], idle_rows: int,
     query tile, up to the tile's last position (an idle row none); the
     reference every row, idle ones too, over the longest row's blocks.
     Attended (the positions a row, or a query tile of the kernel, may
-    attend) over fetched is the block fill share."""
+    attend) over fetched is the block fill share. Under a ``window`` both
+    start at the block that holds the first position the window (of the
+    row, or of the tile's first row) lets in."""
     pages, q_tile = tile_sizes(qk, group, page_tokens, pages_per_slot,
-                               row_bytes)
+                               row_bytes, window)
+    block = pages * page_tokens
 
     def blocks(upto: int) -> int:
-        return min(-(-upto // (pages * page_tokens)),
-                   -(-pages_per_slot // pages))
+        return min(-(-upto // block), -(-pages_per_slot // pages))
+
+    def behind(start: int) -> int:
+        """The positions before a window whose first query is at
+        ``start``: never attended, and fetched only where they share the
+        window's first block."""
+        return 0 if window is None else max(start - window + 1, 0)
 
     if impl == "reference":
-        attended = sum(c + qk for c in cursors)
-        fetched = ((len(cursors) + idle_rows) * blocks(max(cursors) + qk)
+        attended = sum(c + qk - behind(c) for c in cursors)
+        fetched = ((len(cursors) + idle_rows) * max(
+            blocks(c + qk) - behind(c) // block for c in cursors)
                    if cursors else 0)
     else:  # each query tile streams the blocks up to its own end
-        ends = [min(e, qk) for e in range(q_tile, qk + q_tile, q_tile)]
-        attended = sum(c + e for e in ends for c in cursors)
-        fetched = sum(blocks(c + e) for e in ends for c in cursors)
-    return attended, fetched * pages * page_tokens
+        tiles = [(e - q_tile, min(e, qk))
+                 for e in range(q_tile, qk + q_tile, q_tile)]
+        attended = sum(c + e - behind(c + b) for b, e in tiles
+                       for c in cursors)
+        fetched = sum(blocks(c + e) - behind(c + b) // block
+                      for b, e in tiles for c in cursors)
+    return attended, fetched * block
 
 
 def _vmem_bytes(shape, dtype) -> int:
@@ -232,26 +268,30 @@ def _vmem_bytes(shape, dtype) -> int:
             * -(-lanes // _LANES) * _LANES * item)
 
 
-def _shapes(q, k_pool, tables):
+def _shapes(q, k_pool, tables, window=None):
     S, K, H, D = q.shape
     T = k_pool.shape[1]
     Hkv = k_pool.shape[2] // D
     P = tables.shape[1]
     G = H // Hkv
-    B, q_tile = tile_sizes(K, G, T, P, k_pool.shape[2] * k_pool.dtype.itemsize)
+    B, q_tile = tile_sizes(K, G, T, P,
+                           k_pool.shape[2] * k_pool.dtype.itemsize, window)
     return S, K, D, T, Hkv, P, G, B, q_tile
 
 
 # ------------------------------------------------------------- reference
 
 
-def _paged_attention_reference(q, k_pool, v_pool, tables, lengths, sm_scale):
+def _paged_attention_reference(q, k_pool, v_pool, tables, lengths, sm_scale,
+                               window=None):
     """Pure-JAX twin of the kernel: one fori_loop over blocks of pages, all
     slots batched per iteration. Trip count is the BATCH MAX of blocks any
     slot needs — blocks past a slot's own need hit its garbage-page table
     tail and contribute exact zeros, so each slot's result is bit-identical
-    to looping only its own blocks."""
-    S, K, D, T, Hkv, P, G, B, _ = _shapes(q, k_pool, tables)
+    to looping only its own blocks. Under a ``window`` iteration ``b`` reads
+    every slot's OWN block ``first[s] + b``, its first being the one that
+    holds the first position its first row may attend."""
+    S, K, D, T, Hkv, P, G, B, _ = _shapes(q, k_pool, tables, window)
     BT = B * T
     n_table_blocks = -(-P // B)
     # entries past the table's end read the garbage page, like its tail
@@ -265,16 +305,33 @@ def _paged_attention_reference(q, k_pool, v_pool, tables, lengths, sm_scale):
     n_blocks = lax.div(jnp.max(lengths) + K + BT - 1, jnp.int32(BT))
     n_blocks = jnp.minimum(n_blocks, jnp.int32(n_table_blocks))
     batch = ((0, 1), (0, 1))
+    if window is not None:
+        first = jnp.maximum(lengths - window + 1, 0) // BT           # [S]
+        own = jnp.clip(lax.div(lengths + K + BT - 1, jnp.int32(BT)), 0,
+                       n_table_blocks)
+        n_blocks = jnp.max(jnp.maximum(own - first, 0))
 
     def body(b, carry):
         m, l, acc = carry
-        pids = lax.dynamic_slice_in_dim(tables, b * B, B, axis=1)   # [S, B]
+        if window is None:
+            pids = lax.dynamic_slice_in_dim(tables, b * B, B, axis=1)  # [S, B]
+        else:  # a block a slot; past the table's end it is masked whole
+            at = (first + b)[:, None]
+            pids = jnp.take_along_axis(
+                tables, jnp.minimum(at, n_table_blocks - 1) * B
+                + jnp.arange(B, dtype=jnp.int32)[None], axis=1)
         kb = k_pool[pids].reshape(S, BT, Hkv, D).transpose(0, 2, 1, 3)
         vb = v_pool[pids].reshape(S, BT, Hkv, D).transpose(0, 2, 1, 3)
         s_ = lax.dot_general(qh, kb, (((3,), (3,)), batch),
                              preferred_element_type=jnp.float32)
-        kpos = b * BT + jnp.arange(BT, dtype=jnp.int32)          # [BT]
-        s_ = jnp.where(kpos <= qpos, s_ * sm_scale, NEG_INF)  # [S,Hkv,K*G,BT]
+        if window is None:
+            kpos = b * BT + jnp.arange(BT, dtype=jnp.int32)         # [BT]
+            seen = kpos <= qpos
+        else:
+            kpos = (at * BT + jnp.arange(BT, dtype=jnp.int32)[None]
+                    )[:, None, None, :]                       # [S,1,1,BT]
+            seen = jnp.logical_and(kpos <= qpos, kpos > qpos - window)
+        s_ = jnp.where(seen, s_ * sm_scale, NEG_INF)    # [S,Hkv,K*G,BT]
         m_new = jnp.maximum(m, jnp.max(s_, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         pr = jnp.exp(s_ - m_new)
@@ -322,7 +379,7 @@ def _paged_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
                   first_buf,                    # SMEM [1]: see below
                   m_scr, l_scr, acc_scr,        # [Hkv, R, 1|1|D] f32 VMEM
                   *, page_tokens, pages, qk, q_tile, group, kv_heads,
-                  head_dim, sm_scale):
+                  head_dim, sm_scale, window=None):
     """One (slot, query tile) cell: R = q_tile * group query rows of every
     kv head over the slot's blocks 0 .. the tile's last position.
 
@@ -334,7 +391,10 @@ def _paged_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
     behind its own last block (the first cell starts its own). So a slot of
     two or three blocks does not pay a cold start (1.4-2.1 us a cell on the
     v5e, PERF.md 6), and nothing is ever started for a slot that attends
-    nothing."""
+    nothing. Under a ``window`` a cell's walk starts at its FIRST block, the
+    one that holds the first position its first row may attend
+    (``first_block``), and that is the block the cell before it starts for
+    it."""
     c, t = pl.program_id(0), pl.program_id(1)
     n_tiles, n_live = pl.num_programs(1), n_live_ref[0]
     s = order_ref[c]
@@ -363,13 +423,22 @@ def _paged_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
                                            sems.at[buf, kv])
                 cp.wait() if wait else cp.start()
 
+    def first_block(s, t):
+        """The block that holds the first position the first row of query
+        tile ``t`` of slot ``s`` may attend under the window: never past
+        the tile's last block, which holds that row's own position."""
+        seen = jnp.maximum(lengths_ref[s] + t * q_tile - window + 1, 0)
+        return lax.div(seen, jnp.int32(BT))
+
+    b0 = 0 if window is None else first_block(s, t)
+
     @pl.when(jnp.logical_and(c == 0, t == 0))
     def _():
         first_buf[0] = 0
 
         @pl.when(n_live > 0)
         def _():
-            block_copies(s, 0, 0)
+            block_copies(s, b0, 0)
 
     base = first_buf[0]
     # the blocks up to the tile's last position, within the table
@@ -378,19 +447,24 @@ def _paged_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
     nb = jnp.where(c < n_live, nb, 0)  # a live cell uses what it was handed
     c_next = jnp.where(t + 1 == n_tiles, c + 1, c)  # whose block 0 is next
     s_next = order_ref[jnp.minimum(c_next, pl.num_programs(0) - 1)]
+    if window is None:
+        b0_next = 0
+    else:
+        b0 = jnp.minimum(b0, nb)  # a cell that is not live walks nothing
+        b0_next = first_block(s_next, jnp.where(t + 1 == n_tiles, 0, t + 1))
 
     m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
     l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
     acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
     def body(b, _):
-        buf = lax.rem(base + b, 2)
+        buf = lax.rem(base + b if window is None else base + b - b0, 2)
         more = b + 1 < nb
 
         @pl.when(jnp.logical_or(more, c_next < n_live))
         def _():
             block_copies(jnp.where(more, s, s_next),
-                         jnp.where(more, b + 1, 0), 1 - buf)
+                         jnp.where(more, b + 1, b0_next), 1 - buf)
 
         block_copies(s, b, buf, wait=True)
         kpos = b * BT + lax.broadcasted_iota(jnp.int32, (1, BT), 1)
@@ -404,7 +478,10 @@ def _paged_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
             # row r = i * group + g is query token i of the tile
             row_pos = lengths_ref[s] + t * q_tile + (
                 r0 + lax.broadcasted_iota(jnp.int32, (RS, 1), 0)) // group
-            s_ = jnp.where(kpos <= row_pos, s_ * sm_scale, NEG_INF)  # [RS,BT]
+            seen = kpos <= row_pos
+            if window is not None:
+                seen = jnp.logical_and(seen, kpos > row_pos - window)
+            s_ = jnp.where(seen, s_ * sm_scale, NEG_INF)            # [RS,BT]
             m = m_scr[h, rows]
             m_new = jnp.maximum(m, jnp.max(s_, axis=-1, keepdims=True))
             alpha = jnp.exp(m - m_new)
@@ -424,8 +501,9 @@ def _paged_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
                 lax.fori_loop(0, R // RS, lambda i, _, h=h: update(
                     h, pl.multiple_of(i * RS, RS)), None)
 
-    lax.fori_loop(0, nb, body, None)
-    first_buf[0] = lax.rem(base + nb, 2)
+    lax.fori_loop(b0, nb, body, None)
+    first_buf[0] = lax.rem(base + nb if window is None else base + nb - b0,
+                           2)
     l = l_scr[...]
     l = jnp.where(l == 0.0, 1.0, l)
     o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
@@ -434,11 +512,11 @@ def _paged_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
 # jitted on its own: a program calls the op once a layer with the same
 # shapes, and the kernel's body is then traced and lowered once, not once a
 # layer (16 layers cost 14 s of every process's start otherwise)
-@functools.partial(jax.jit,
-                   static_argnames=("sm_scale", "interpret", "name"))
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret", "name",
+                                             "window"))
 def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths, sm_scale,
-                            interpret, name="paged_attention"):
-    S, K, D, T, Hkv, P, G, B, q_tile = _shapes(q, k_pool, tables)
+                            interpret, name="paged_attention", window=None):
+    S, K, D, T, Hkv, P, G, B, q_tile = _shapes(q, k_pool, tables, window)
     problem = None if interpret else pallas_shape_problem(Hkv, D)
     if problem:
         raise ValueError(
@@ -452,7 +530,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths, sm_scale,
     qr = qr.reshape(S, n_tiles, Hkv, R, D)
     kernel = functools.partial(_paged_kernel, page_tokens=T, pages=B, qk=K,
                                q_tile=q_tile, group=G, kv_heads=Hkv,
-                               head_dim=D, sm_scale=sm_scale)
+                               head_dim=D, sm_scale=sm_scale, window=window)
     block = (1, 1, Hkv, R, D)
     live = lengths + K > 0
     order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)

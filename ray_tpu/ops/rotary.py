@@ -2,7 +2,11 @@
 
 from __future__ import annotations
 
+import math
+from typing import Any, Mapping, Optional, Tuple
+
 import jax.numpy as jnp
+import numpy as np
 
 
 def rope_frequencies(head_dim: int, max_len: int, theta: float = 10000.0,
@@ -37,11 +41,65 @@ def _rotate(x, cos, sin):
     return out.astype(x.dtype)
 
 
-def apply_rotary_at(x, positions, theta: float = 10000.0):
+def apply_rotary_at(x, positions, theta: float = 10000.0,
+                    rule: Optional[Mapping[str, Any]] = None):
     """Rotate q or k by angles computed from ``positions`` [..., seq] as
     they come — no table, so a model's context length costs nothing. x:
-    [..., seq, heads, head_dim]; the same half-rotation layout."""
+    [..., seq, heads, head_dim]; the same half-rotation layout. ``rule``:
+    one entry of a source's ``rope_parameters`` (``rule_frequencies``), in
+    place of ``theta``."""
     d = x.shape[-1]
+    if rule is not None:
+        inv_freq, factor = rule_frequencies(d, rule)
+        angles = positions[..., None].astype(jnp.float32) * inv_freq
+        return _rotate(x, jnp.cos(angles) * factor, jnp.sin(angles) * factor)
     inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     angles = positions[..., None].astype(jnp.float32) * inv_freq
     return _rotate(x, jnp.cos(angles), jnp.sin(angles))
+
+
+ROPE_TYPES = ("default", "yarn")
+
+
+def rule_frequencies(head_dim: int,
+                     rule: Mapping[str, Any]) -> Tuple[np.ndarray, float]:
+    """(inverse frequencies [head_dim // 2] float32, the factor cos and sin
+    are multiplied by) of one RoPE rule, keyed as the public ``transformers``
+    configs key it: ``rope_type`` 'default' (``rope_theta`` alone) or 'yarn'
+    (Peng et al. 2023, arXiv:2309.00071): the frequencies that turn fewer
+    than ``beta_slow`` times over ``original_max_position_embeddings`` are
+    divided by ``factor``, those that turn more than ``beta_fast`` times are
+    kept, with a linear ramp over the pair indices between (their bounds
+    rounded outwards: ``truncate``, true unless the rule says otherwise), and
+    cos and sin are scaled by ``attention_factor`` (``0.1 ln(factor) + 1``
+    where the rule gives none)."""
+    kind = rule.get("rope_type", "default")
+    if kind not in ROPE_TYPES:
+        raise ValueError(f"unknown rope_type {kind!r}; expected one of "
+                         f"{list(ROPE_TYPES)}")
+    theta = float(rule["rope_theta"])
+    half = head_dim // 2
+    base = theta ** (-np.arange(half, dtype=np.float64) * 2.0 / head_dim)
+    if kind == "default":
+        return base.astype(np.float32), 1.0
+    factor = float(rule["factor"])
+    original = float(rule["original_max_position_embeddings"])
+
+    def pair_index(turns: float) -> float:
+        """The pair whose frequency turns ``turns`` times over the original
+        context."""
+        return (head_dim * math.log(original / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = pair_index(float(rule.get("beta_fast", 32)))
+    high = pair_index(float(rule.get("beta_slow", 1)))
+    if rule.get("truncate", True):
+        low, high = math.floor(low), math.ceil(high)
+    low, high = max(low, 0), min(high, head_dim - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    inv_freq = (1.0 - ramp) * base + ramp * base / factor
+    attention_factor = rule.get("attention_factor")
+    if attention_factor is None:
+        attention_factor = 0.1 * math.log(factor) + 1.0
+    return inv_freq.astype(np.float32), float(attention_factor)

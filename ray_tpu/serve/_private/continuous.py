@@ -102,6 +102,22 @@ at a page boundary or rewound, and no snapshot is kept: the prefix cache,
 prefix export / migration and the speculative programs refuse a model with
 such layers when the scheduler is built.
 
+WINDOW LAYERS ('sliding_attention'). Their pages are a pool and an arena of
+their own beside the full layers', through tables of their own (every paged
+program takes both pairs, ``_tables``). A slot holds there the pages its
+window still covers and no more, whatever the context: before a turn
+allocates what its rows will write (``_ensure_pages``) the pages wholly
+behind ``cursor - sliding_window + 1`` go back to the arena, their table
+entries point at the garbage page, and the kernel's walk starts behind them
+(``ops.paged_attention``), so nothing ever reads them. The pool's size
+follows from ``slots``, the window, ``prefill_chunk`` and ``page_tokens``
+(``window + chunk`` tokens and a page a slot): no option sets it, and
+``kv_pages`` keeps meaning the full layers' pool. A spliced prefix would
+need the window layers' last window of it kept too, and a rejected draft's
+released pages back: the prefix cache, prefix export / migration and the
+speculative programs refuse a model with such layers when the scheduler is
+built.
+
 Knobs: ``RAY_TPU_SERVE_SLOTS`` (slots), ``RAY_TPU_SERVE_PREFILL_CHUNK``
 (prefill chunk tokens), ``RAY_TPU_SERVE_PAGE_TOKENS``,
 ``RAY_TPU_SERVE_KV_PAGES`` (0 = size the pool to every slot's worst case),
@@ -241,7 +257,8 @@ class _Seq:
                  "t_first_token", "t_emit", "prefill_mark", "rng",
                  "cached_len", "cursor",
                  "owned_pages", "radix_node",
-                 "table_fill", "fleet_hint", "migration_node",
+                 "table_fill", "window_pages", "window_fill",
+                 "fleet_hint", "migration_node",
                  "drafter_len", "drafter_pending")
 
     def __init__(self, prompt: List[int], max_new: int, temperature: float,
@@ -277,6 +294,10 @@ class _Seq:
         self.owned_pages: List[int] = []  # pages this slot must free
         self.radix_node = None         # ref-counted prefix-cache node
         self.table_fill = 0            # logical pages present in the table
+        # the window layers' pool: the pages held, oldest first (the logical
+        # pages [window_fill - len(window_pages), window_fill))
+        self.window_pages: deque = deque()
+        self.window_fill = 0
         # ---- fleet phase (ISSUE 18) ----
         self.fleet_hint = None         # {"handle", "tokens"} from the router
         self.migration_node = None     # pin on a just-migrated prefix span
@@ -364,9 +385,10 @@ class ContinuousScheduler:
         from ray_tpu.models.decode import (init_paged_caches,
                                            paged_decode_step,
                                            paged_prefill_into_slot,
-                                           paged_verify_step)
-        from ray_tpu.models.transformer import (LINEAR, RETENTION, SPARSE,
-                                                STATE_KINDS, state_shapes)
+                                           paged_verify_step, pool_of)
+        from ray_tpu.models.transformer import (ATTENTION, LINEAR, RETENTION,
+                                                SLIDING, SPARSE, STATE_KINDS,
+                                                state_shapes)
         from ray_tpu.ops.paged_attention import resolve_impl
         from ray_tpu.serve._private.paging import PageArena, RadixCache
 
@@ -456,6 +478,33 @@ class ContinuousScheduler:
                     "'power-retention'): a rejected draft would have to "
                     "rewind their states")
             self._radix = None  # the configured default cannot apply
+        # window layers: a pool and an arena of their own, sized by what a
+        # slot can hold of it at once — the window behind a chunk's first
+        # row, the chunk, and a page for where the two begin inside one
+        self._window = cfg.sliding_window if SLIDING in cfg.kinds else 0
+        self._window_arena = None
+        if self._window:
+            if prefix_cache:
+                raise ValueError(
+                    "prefix_cache=True cannot serve a model with "
+                    "'sliding_attention' layers: a spliced prefix would need "
+                    "their last window of it kept too, and they release it")
+            if drafter is not None:
+                raise ValueError(
+                    "speculative decoding cannot serve a model with "
+                    "'sliding_attention' layers: a rejected draft would "
+                    "need the pages released behind it back")
+            self._radix = None  # the configured default cannot apply
+            slot_pages = min(
+                self._pages_per_slot,
+                -(-(self._window + self.prefill_chunk) // self.page_tokens)
+                + 1)
+            self._window_arena = PageArena(
+                self.slots * slot_pages + 1, self.page_tokens, pool="window")
+            self._window_read_tables = np.zeros_like(self._read_tables)
+            self._window_write_tables = np.zeros_like(self._write_tables)
+            # the tables' names: the full layers' pool, the window layers'
+            self._pools = (pool_of(ATTENTION), pool_of(SLIDING))
         # an expert layer's programs hand the rows each expert received
         # back with the ids
         self._moe = cfg.mlp == "moe"
@@ -473,7 +522,9 @@ class ContinuousScheduler:
                      **program_kw), donate_argnums=(6,))
         self._caches = init_paged_caches(
             cfg, self.num_pages, self.page_tokens,
-            self._pages_per_slot, cache_dtype, slots=self.slots)
+            self._pages_per_slot, cache_dtype, slots=self.slots,
+            window_pages=(self._window_arena.num_pages if self._window
+                          else None))
         self._kv_itemsize = int(jax.numpy.dtype(
             cache_dtype or cfg.dtype).itemsize)
         # the pool by kind: layers that hold pages, layers that hold a state
@@ -483,6 +534,7 @@ class ContinuousScheduler:
         self._n_retention = kinds.count(RETENTION)
         self._n_sparse = kinds.count(SPARSE)
         self._n_paged = sum(kind not in STATE_KINDS for kind in kinds)
+        self._n_window = kinds.count(SLIDING)
         self._state_bytes = 4 * sum(
             math.prod(shape) for kind in kinds if kind in STATE_KINDS
             for shape in state_shapes(cfg, kind, self.slots).values())
@@ -593,6 +645,18 @@ class ContinuousScheduler:
         self._n_attn_bytes = 0
         self._n_attn_attended = 0
         self._n_attn_fetched = 0
+        # window layers beside full ones: what the mask admits by kind and
+        # by work (keys a decode row reads, (query, key) pairs of a chunk's
+        # real rows; summed over rows and layers), the pages released from
+        # behind a window, and, a turn, the tokens their pool holds beside
+        # those it would hold without release
+        self._n_window_step_keys = 0
+        self._n_full_step_keys = 0
+        self._n_window_chunk_pairs = 0
+        self._n_full_chunk_pairs = 0
+        self._n_window_released = 0
+        self._n_window_tokens_held = 0
+        self._n_window_tokens_unreleased = 0
         # layers of other kinds (a layer-call: one layer in one program run)
         self._n_linear_chunk_calls = 0
         self._n_linear_step_rows = 0
@@ -794,6 +858,12 @@ class ContinuousScheduler:
         seq.table_fill = 0
         self._read_tables[slot, :] = 0
         self._write_tables[slot, :] = 0
+        if self._window:
+            self._window_arena.free(list(seq.window_pages))
+            seq.window_pages.clear()
+            seq.window_fill = 0
+            self._window_read_tables[slot, :] = 0
+            self._window_write_tables[slot, :] = 0
 
     def _release_migration_ref(self, seq: _Seq) -> None:
         """A migrated-prefix pin must drop no matter how the sequence
@@ -838,6 +908,8 @@ class ContinuousScheduler:
         if not self._paged:
             return True  # arena_len bounded the request at submit
         need = -(-upto // self.page_tokens)
+        if self._window and not self._ensure_window_pages(seq, need):
+            return False
         missing = need - seq.table_fill
         if missing <= 0:
             return True
@@ -860,6 +932,47 @@ class ContinuousScheduler:
             self._write_tables[slot, j] = p
         seq.owned_pages.extend(pages)
         seq.table_fill = need
+        return True
+
+    def _ensure_window_pages(self, seq: _Seq, need: int) -> bool:
+        """The window layers' half of ``_ensure_pages``: give back the pages
+        wholly behind the window of the next row the slot's programs run
+        (``seq.cursor``: every program dispatched so far took COPIES of the
+        tables, and the device runs them before whatever is dispatched
+        next), then grow the slot's window table to ``need`` logical pages.
+        A released page's entries point at the garbage page; the kernel's
+        walk starts behind it. Returns True if the pages are present."""
+        from ray_tpu.serve._private.paging import (F_WINDOW_RELEASE,
+                                                   OutOfPagesError,
+                                                   m_window_pages_released)
+
+        slot, held = seq.slot, seq.window_pages
+        first_kept = max(seq.cursor - self._window + 1, 0) // self.page_tokens
+        front = seq.window_fill - len(held)
+        if first_kept > front:
+            n = min(first_kept - front, len(held))
+            self._window_arena.free([held.popleft() for _ in range(n)])
+            self._window_read_tables[slot, front:front + n] = 0
+            self._window_write_tables[slot, front:front + n] = 0
+            self._n_window_released += n
+            m_window_pages_released.inc(n)
+            flight.instant(F_WINDOW_RELEASE, n)
+        missing = need - seq.window_fill
+        if missing <= 0:
+            return True
+        try:
+            pages = self._window_arena.alloc(missing)
+        except OutOfPagesError:
+            arena = self._window_arena
+            self._fail(seq, f"window kv arena out of pages (need {missing} "
+                            f"more, {arena.free_pages} free of "
+                            f"{arena.usable_pages})")
+            return False
+        fill = seq.window_fill
+        self._window_read_tables[slot, fill:need] = pages
+        self._window_write_tables[slot, fill:need] = pages
+        held.extend(pages)
+        seq.window_fill = need
         return True
 
     def _emit_token(self, seq: _Seq, tok: int) -> bool:
@@ -957,6 +1070,11 @@ class ContinuousScheduler:
             seq.table_fill = 0
             self._read_tables[free, :] = 0
             self._write_tables[free, :] = 0
+            if self._window:
+                seq.window_pages = deque()
+                seq.window_fill = 0
+                self._window_read_tables[free, :] = 0
+                self._window_write_tables[free, :] = 0
             if self._radix is not None:
                 self._splice_prefix(seq)
                 # a migrated prefix was pinned only so eviction could
@@ -1015,21 +1133,50 @@ class ContinuousScheduler:
         if not self._n_paged:
             return
         row = cfg.kv_heads * cfg.head_dim * self._kv_itemsize
+        streamed = lambda window: streamed_tokens(
+            self.attn_lane, qk, cursors, idle_rows,
+            cfg.num_heads // cfg.kv_heads, self.page_tokens,
+            self._pages_per_slot, row, window)
         if self._n_sparse:
             attended, fetched = self._record_sparse(
                 qk, cursors, qk if real is None else real)
         else:
-            attended, fetched = streamed_tokens(
-                self.attn_lane, qk, cursors, idle_rows,
-                cfg.num_heads // cfg.kv_heads, self.page_tokens,
-                self._pages_per_slot, row)
+            attended, fetched = streamed(None)
         self._n_attn_attended += attended
         self._n_attn_fetched += fetched
         # k + v pools, every layer that holds pages: the rows read through
         # the table plus the qk freshly-written rows per slot
-        moved = 2 * self._n_paged * row * (fetched + (rows + idle_rows) * qk)
+        written = (rows + idle_rows) * qk
+        moved = 2 * (self._n_paged - self._n_window) * row * (
+            fetched + written)
+        if self._n_window:
+            self._record_window(qk, cursors, real)
+            moved += 2 * self._n_window * row * (
+                streamed(self._window)[1] + written)
         self._n_attn_bytes += moved
         _m_attn_bytes.inc(moved, labels={"lane": self.attn_lane})
+
+    def _record_window(self, qk: int, cursors: List[int],
+                       real: Optional[int]) -> None:
+        """A model with window layers, one attention-bearing call: what the
+        mask admits, by kind of layer and by work, summed over rows and
+        layers — the keys a decode row reads (``*_step_keys``), the (query,
+        key) pairs of a chunk's ``real`` rows (``*_chunk_pairs``). Host
+        arithmetic on the cursors, no readback (``attn_tokens_*`` stay the
+        full layers' streamed tokens)."""
+        import numpy as np
+
+        w, n_full = self._window, self._n_paged - self._n_window
+        # the position of every real query row: a step's one a row
+        t = (np.asarray(cursors, np.int64)[:, None]
+             + np.arange(1 if qk == 1 else qk if real is None else real))
+        full, seen = int((t + 1).sum()), int(np.minimum(t + 1, w).sum())
+        if qk == 1:
+            self._n_full_step_keys += n_full * full
+            self._n_window_step_keys += self._n_window * seen
+        else:
+            self._n_full_chunk_pairs += n_full * full
+            self._n_window_chunk_pairs += self._n_window * seen
 
     def _record_sparse(self, qk: int, cursors: List[int], real: int):
         """The block-selected layers' share of ``_record_attn``: what each
@@ -1089,10 +1236,18 @@ class ContinuousScheduler:
         alias (CPU) or still be reading (TPU) the host buffer, while the
         host frees and hands out pages before anything waits for the
         program. (None, None) for a model that holds no page: nothing is
-        uploaded."""
+        uploaded. For a model with window layers each of the two is a dict,
+        the full layers' table and the window layers' by the pool's name."""
         if not self._paged:
             return None, None
         rows = slice(None) if slot is None else slot
+        if self._window:  # a pair a pool (``decode.pool_tables``)
+            return tuple(
+                {self._pools[0]: full[rows].copy(),
+                 self._pools[1]: window[rows].copy()}
+                for full, window in (
+                    (self._read_tables, self._window_read_tables),
+                    (self._write_tables, self._window_write_tables)))
         return (self._read_tables[rows].copy(),
                 self._write_tables[rows].copy())
 
@@ -1464,6 +1619,11 @@ class ContinuousScheduler:
                 "a model with layers that keep a state a slot exports no "
                 "prefix: pages alone, if it holds any, do not continue a "
                 "sequence")
+        if self._window:
+            raise ValueError(
+                "a model with 'sliding_attention' layers exports no prefix: "
+                "the full layers' pages alone do not continue a sequence, "
+                "and the window layers' are released behind the window")
         if self._radix is None:
             return {"matched_len": 0, "page_tokens": self.page_tokens,
                     "k": [], "v": []}
@@ -1748,6 +1908,14 @@ class ContinuousScheduler:
         dispatched or a result read."""
         chunk = self._next_chunk()
         rows = self._step_rows()
+        if self._window and (chunk is not None or rows.live):
+            # a turn's sample, behind its releases and allocations: what the
+            # window layers' pool holds, beside what it would hold of the
+            # same sequences had nothing been released
+            T = self.page_tokens
+            self._n_window_tokens_held += T * self._window_arena.pages_in_use
+            self._n_window_tokens_unreleased += T * sum(
+                s.window_fill for s in self._slot_seqs if s is not None)
         if chunk is not None:
             self._dispatch_chunk(*chunk, rows)
         elif rows.live:
@@ -1999,6 +2167,22 @@ class ContinuousScheduler:
             out["moe_experts_hit"] = self._n_moe_experts_hit
             out["moe_max_expert_rows"] = self._n_moe_max_expert_rows
         out.update(self._arena.stats())
+        if self._window:
+            arena = self._window_arena.stats()
+            # by pool: the full layers' pages (``kv_pages``: the keys above
+            # too) and the window layers', whose pool the scheduler sized
+            out["kv_pages_in_use_full"] = out["pages_in_use"]
+            out["kv_peak_pages_in_use_full"] = out["peak_pages_in_use"]
+            out["kv_pages_in_use_window"] = arena["pages_in_use"]
+            out["kv_peak_pages_in_use_window"] = arena["peak_pages_in_use"]
+            out["window_pages_released"] = self._n_window_released
+            out["window_tokens_held"] = self._n_window_tokens_held
+            out["window_tokens_unreleased"] = self._n_window_tokens_unreleased
+            # what the mask admits, by kind and by work (``_record_window``)
+            out["window_attn_step_keys"] = self._n_window_step_keys
+            out["full_attn_step_keys"] = self._n_full_step_keys
+            out["window_attn_chunk_pairs"] = self._n_window_chunk_pairs
+            out["full_attn_chunk_pairs"] = self._n_full_chunk_pairs
         # 0 without a prefix cache: no prompt token was served from one
         out["prefix_hit_tokens"] = self._n_prefix_hit_tokens
         if self._radix is not None:

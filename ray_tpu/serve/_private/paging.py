@@ -36,6 +36,8 @@ from ray_tpu.serve._private.affinity import CHAIN_SEED, chain_hashes
 F_PREFIX_HIT = flight.intern("serve.prefix_hit")
 F_PAGE_ALLOC = flight.intern("serve.page_alloc")
 F_EVICT = flight.intern("serve.evict")
+# pages a window layers' arena took back from behind a slot's window
+F_WINDOW_RELEASE = flight.intern("serve.window_release")
 
 m_prefix_hits = Counter(
     "ray_tpu_serve_prefix_hits_total",
@@ -52,6 +54,10 @@ m_pages_freed = Counter(
 m_pages_in_use = Gauge(
     "ray_tpu_serve_kv_pages_in_use",
     "KV pages currently allocated (slot-owned + prefix-cache resident)")
+m_window_pages_released = Counter(
+    "ray_tpu_serve_kv_window_pages_released_total",
+    "KV pages of sliding-window layers released from behind a live "
+    "sequence's window")
 
 GARBAGE_PAGE = 0
 
@@ -65,10 +71,13 @@ class PageArena:
     into the device-side ``PagedKVCache`` pools; page 0 never leaves the
     allocator (it is the shared garbage page). ``pageless``: the arena of a
     model none of whose layers holds a page — it may be the reserved page
-    alone, hands nothing out and reads 0 wherever pages are counted."""
+    alone, hands nothing out and reads 0 wherever pages are counted.
+    ``pool``: the name of a SECOND arena of one scheduler (the window
+    layers' pool, 'window'), which labels what it puts in the process's
+    metrics; the one arena every scheduler has carries no label, as ever."""
 
     def __init__(self, num_pages: int, page_tokens: int,
-                 pageless: bool = False):
+                 pageless: bool = False, pool: Optional[str] = None):
         if page_tokens < 1:
             # the PR-8/PR-9 falsy-zero lesson: an explicit 0 must raise
             # here, never silently become some default upstream
@@ -80,6 +89,7 @@ class PageArena:
                 f"got {num_pages}")
         self.num_pages = num_pages
         self.page_tokens = page_tokens
+        self._labels = {"pool": pool} if pool else None
         # LIFO free list: recently-freed pages are re-used first (their
         # content is dead by construction — cursors never read past a
         # slot's own writes)
@@ -118,8 +128,8 @@ class PageArena:
         self._outstanding.update(pages)
         self._allocated_total += n
         self._peak_in_use = max(self._peak_in_use, self.pages_in_use)
-        m_pages_allocated.inc(n)
-        m_pages_in_use.set(float(self.pages_in_use))
+        m_pages_allocated.inc(n, self._labels)
+        m_pages_in_use.set(float(self.pages_in_use), self._labels)
         flight.instant(F_PAGE_ALLOC, n)
         return pages
 
@@ -135,8 +145,8 @@ class PageArena:
             self._free.append(p)
         if pages:
             self._freed_total += len(pages)
-            m_pages_freed.inc(len(pages))
-            m_pages_in_use.set(float(self.pages_in_use))
+            m_pages_freed.inc(len(pages), self._labels)
+            m_pages_in_use.set(float(self.pages_in_use), self._labels)
 
     def stats(self) -> Dict[str, int]:
         return {

@@ -597,7 +597,7 @@ class TestAttnCounters:
         sch.page_tokens, sch._pages_per_slot, sch._kv_itemsize = 16, 256, 2
         sch._n_attn_bytes = sch._n_attn_attended = sch._n_attn_fetched = 0
         sch._n_linear, sch._n_sparse, sch._n_paged = 0, 0, 2  # by kind
-        sch._n_retention = 0
+        sch._n_retention = sch._n_window = 0
         return sch
 
     @pytest.mark.parametrize("lane,attended,fetched", [

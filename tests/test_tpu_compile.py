@@ -406,7 +406,7 @@ def _cell_programs(v5e, config: str, cell: str):
     from ray_tpu.models.decode import (StepRows, init_paged_caches,
                                        paged_decode_step,
                                        paged_prefill_into_slot)
-    from ray_tpu.models.transformer import init_params
+    from ray_tpu.models.transformer import ATTENTION, SLIDING, init_params
 
     manifest = manifest_lib.load()
     hp = manifest_lib.config(manifest, config)
@@ -422,12 +422,20 @@ def _cell_programs(v5e, config: str, cell: str):
 
     params = place(jax.eval_shape(
         functools.partial(init_params, cfg), jax.random.PRNGKey(0)))
+    # a model with window layers: their pool as the scheduler sizes it, and
+    # a pair of tables a pool
+    window = {}
+    if SLIDING in cfg.kinds:
+        window["window_pages"] = 1 + slots * min(
+            pages, -(-(cfg.sliding_window + chunk) // T) + 1)
     caches = place(jax.eval_shape(functools.partial(
-        init_paged_caches, cfg, dep["kv_pages"], T, pages, slots=slots)))
+        init_paged_caches, cfg, dep["kv_pages"], T, pages, slots=slots,
+        **window)))
     held = sum(a.size * a.dtype.itemsize
                for a in jax.tree.leaves((params, caches)))
-    table = _on(chip, (slots, pages), jnp.int32)
-    row = _on(chip, (pages,), jnp.int32)
+    by_pool = lambda t: {ATTENTION: t, SLIDING: t} if window else t
+    table = by_pool(_on(chip, (slots, pages), jnp.int32))
+    row = by_pool(_on(chip, (pages,), jnp.int32))
     ids = functools.partial(_on, chip, dtype=jnp.int32)
     step = (ids((slots,)), ids((slots,)), table, table,
             _on(chip, (slots,), jnp.float32), _on(chip, (slots,), jnp.uint32))
@@ -525,6 +533,81 @@ def test_olmoe_serve_programs_compile_and_fit(v5e):
         # no layer's experts (805 MB) are copied off the stacked weights
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < 600e6, f"{name}: {temp / 1e6:.0f} MB of temporaries"
+
+
+def test_mellum_serve_programs_compile_and_fit(v5e):
+    """The benchmark's Mellum2-12B-A2.5B configuration (published widths:
+    hidden 2304 = 18 lanes, experts of 896 = 7 lanes, 32 query heads over 4
+    K/V heads of 128, so a group of 8; 8 layers of two kinds, bf16) under its
+    cell's deployment: the prefill chunk with the step's rows along and the
+    decode step, the paged kernel under BOTH names — ``window_attention``
+    for the six window layers (a first block as well as a last one in its
+    walk), ``paged_attention`` for the two full ones — and the experts'
+    kernel once a layer, fed from the stacks of a pattern's period and not
+    from a copy of a layer's experts (793 MB); 7.59 GB of weights, the
+    full layers' 4.43 GB pool and the window layers' 0.62 GB beside the
+    programs' own memory on one 16 GB chip."""
+    from ray_tpu.ops import moe
+    from ray_tpu.ops.paged_attention import resolve_impl
+
+    cfg, held, programs = _cell_programs(v5e, "mellum2_12b_l8",
+                                         "mellum2_shortlong")
+    assert (cfg.embed_dim, cfg.head_dim, cfg.hidden_dim) == (2304, 128, 896)
+    assert cfg.num_heads // cfg.kv_heads == 8 and cfg.period == 4
+    lane = resolve_impl(cfg)
+    assert lane == "pallas"
+    assert 12.5e9 < held < 12.8e9
+    # a whole expert in one grid cell at both programs' pair counts
+    assert moe.tile_sizes(32 * 8, 64, 2304, 896, 2) == (64, 896)
+    assert moe.tile_sizes((512 + 32) * 8, 64, 2304, 896, 2) == (128, 896)
+    calls = {"prefill": {"window_attention": 12, "paged_attention": 4,
+                         "moe_grouped_matmul": 8},
+             "decode": {"window_attention": 6, "paged_attention": 2,
+                        "moe_grouped_matmul": 8}}
+    for name, (program, args) in programs.items():
+        compiled = jax.jit(
+            functools.partial(program, cfg, attn=lane, moe_info=True),
+            donate_argnums=(6,)).lower(*args).compile()
+        assert _kernel_calls(compiled) == calls[name], name
+        assert "ragged-dot" not in compiled.as_text(), name
+        total = _fits(compiled)
+        assert total < 14.6e9, f"{name}: {total / 1e9:.1f} GB"
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 600e6, f"{name}: {temp / 1e6:.0f} MB of temporaries"
+
+
+def test_mellum_forward_given_the_routes_fits_beside_the_pools(v5e):
+    """The cell states limits GIVEN the routes, so ``reference_check`` runs
+    the program's uncached whole-sequence ``forward`` (``return_routes``)
+    over the check prompt and the tokens served behind it, up to whole
+    tiles, in the replica, beside the weights and both pools: its window
+    layers attend a block of query rows at a time
+    (``transformer._window_attention``), not through ``[32, S, S]`` scores
+    (2.6 GB a layer in float32 at 4480 tokens)."""
+    from perfbench.lib import manifest as manifest_lib
+    from perfbench.lib.serve_app import GIVEN_PAD
+    from ray_tpu.models.transformer import forward
+
+    cfg, held, programs = _cell_programs(v5e, "mellum2_12b_l8",
+                                         "mellum2_shortlong")
+    cell = manifest_lib.read_json(manifest_lib.load(), "cells",
+                                  "mellum2_shortlong")
+    assert {"given_logit_err", "given_logit_rms_err"} <= set(
+        cell["check_tolerance"])
+    first = cell["check_prompt_tokens"] - 1
+    n = first + cell["check_new_tokens"]
+    params = programs["decode"][1][0]
+
+    def run(params, tokens):
+        logits, routes = forward(cfg, params, tokens, return_routes=True)
+        return logits[0, first:n].astype(jnp.float32), routes
+
+    tokens = _on(params["embed"]["table"].sharding,
+                 (1, n + -n % GIVEN_PAD), jnp.int32)
+    compiled = jax.jit(run).lower(params, tokens).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1.2e9, f"{temp / 1e9:.2f} GB of temporaries"
+    assert held + temp < 14.6e9
 
 
 def test_minicpm_sala_serve_programs_compile_and_fit(v5e):
