@@ -57,6 +57,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import attention
 from ray_tpu.ops.linear_attention import (linear_attention_chunk,
@@ -71,6 +72,7 @@ from ray_tpu.ops.rotary import (apply_rotary, apply_rotary_at,
                                 rope_frequencies)
 from ray_tpu.ops.sparse_attention import (SparseSizes, sparse_attention,
                                           update_page_means)
+from ray_tpu.parallel.sharding import free_axes
 
 ATTENTION, SPARSE, LINEAR = "attention", "minicpm4", "lightning-attn"
 RETENTION = "power-retention"
@@ -417,6 +419,18 @@ def _norm(cfg, p, x):
 
 COMPUTED = "computed"  # ``rope``: angles from the positions, no table
 
+# Under a mesh whose tensor-parallel axis is the compiler's to place
+# (``make_train_step(mesh)``; never a decode program, which sees no mesh) a
+# block's recompute pays no reduce again: ``_attn`` gives ``wo``'s result,
+# the partial sums already reduced over that axis, this name, and
+# ``forward``'s policy keeps it. ``w_down``'s is not needed backward and is
+# not recomputed. What is left is Megatron's four: the two row-parallel
+# dots' sums forward, the gradient of each norm's output backward (the
+# chip's compiler sums the partial gradients of ``wq``, ``wk`` and ``wv``,
+# and of ``w_gate`` and ``w_up``, before it reduces; the CPU's reduces each
+# where it stands).
+ATTN_OUT = "attn_out"
+
 
 def _qkv(cfg, p, x, rope, positions, kind=ATTENTION):
     """The q/k/v projection of every forward (training, tensor-parallel,
@@ -543,6 +557,8 @@ def _attn(cfg, p, x, rope, positions, sp_axis, kv_cache=None,
                       if cfg.attn_impl != "ring" else "auto")
         new_cache = None
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(cfg.dtype))
+    if kv_cache is None and free_axes("heads", p["wo"].shape[0]) is not None:
+        out = checkpoint_name(out, ATTN_OUT)
     return out, new_cache
 
 
@@ -814,6 +830,21 @@ def _block(cfg, p, x, rope, positions, sp_axis, kv_cache=None, mlp=None,
     return x, new_cache, aux, moe
 
 
+def _residual_layout(x):
+    """x [B, S, d] where the residual lives under a mesh in scope: the
+    batch over the axes the rule table gives it, d whole. Said at the
+    embedding's output and at every layer's, or the partitioner takes the
+    layout from whatever stands nearest — the embedding table's split of d
+    over ``fsdp``, unless a kernel's ``shard_map`` happens to pin the batch
+    — and reduces activations over ``fsdp`` that it need only have gathered
+    weights for. No mesh in scope: x as it is."""
+    batch = free_axes("batch", x.shape[0])
+    if batch is None:
+        return x
+    return jax.lax.with_sharding_constraint(
+        x, jax.sharding.PartitionSpec(batch, None, None))
+
+
 def embed(cfg, params, tokens):
     x = params["embed"]["table"].astype(cfg.dtype)[tokens]
     return x * cfg.scale_emb if cfg.scale_emb != 1.0 else x
@@ -874,13 +905,15 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
     if cfg.pos == "learned":
         pos = positions if positions is not None else jnp.arange(tokens.shape[1])
         x = x + params["pos_embed"]["table"].astype(cfg.dtype)[pos]
+    x = _residual_layout(x)
     rope = rope_table(cfg)
     kinds = cfg.kinds
 
     block_fn = lambda kind: functools.partial(_block, kind=kind)
     if cfg.remat and kv_caches is None and not return_selected:
         policies = {
-            "full": jax.checkpoint_policies.nothing_saveable,
+            # ``ATTN_OUT`` is named only where keeping it saves a reduce
+            "full": jax.checkpoint_policies.save_only_these_names(ATTN_OUT),
             "dots": jax.checkpoint_policies.checkpoint_dots,
         }
         if cfg.remat_policy not in policies:
@@ -916,8 +949,8 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
                     else layer_params[f"p{j}"], h, rope, positions, sp_axis)
                 aux_acc = aux_acc + aux
                 chosen.append(moe["routes"] if return_routes else None)
-            return (h, aux_acc), (jnp.stack(chosen) if return_routes
-                                  else None)
+            return (_residual_layout(h), aux_acc), (
+                jnp.stack(chosen) if return_routes else None)
         (x, aux_total), routes = jax.lax.scan(body, (x, 0.0),
                                               params["blocks"])
         if return_routes:  # [steps, layers a step, ...] -> a layer a row

@@ -59,19 +59,10 @@ def _flash_on_mesh(q, k, v, sm_scale, causal):
         return flash_attention(q, k, v, sm_scale, causal)
     from jax.sharding import PartitionSpec as P
 
-    from ray_tpu.parallel.sharding import DEFAULT_RULES
+    from ray_tpu.parallel.sharding import free_axes
 
-    def axes_for(logical, *dims):
-        target = DEFAULT_RULES[logical]
-        names = tuple(a for a in ((target,) if isinstance(target, str)
-                                  else target) if a in free)
-        size = math.prod(mesh.shape[a] for a in names)
-        if not names or any(d % size for d in dims):
-            return None
-        return names if len(names) > 1 else names[0]
-
-    spec = P(axes_for("batch", q.shape[0]), None,
-             axes_for("heads", q.shape[2], k.shape[2]), None)
+    spec = P(free_axes("batch", q.shape[0]), None,
+             free_axes("heads", q.shape[2], k.shape[2]), None)
     return jax.shard_map(
         lambda q, k, v: flash_attention(q, k, v, sm_scale, causal),
         in_specs=(spec, spec, spec), out_specs=spec,

@@ -13,6 +13,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ray_tpu.parallel.sharding import free_axes
 
 
 def softmax_cross_entropy(logits, labels, mask=None, z_loss: float = 0.0):
@@ -92,6 +95,19 @@ def fused_softmax_cross_entropy(hidden, table, labels, mask=None, *,
     xc = x.reshape(-1, chunk, D)
     lc = labels.reshape(-1, chunk)
     mc = m.reshape(-1, chunk)
+    rows = free_axes("batch", chunk)
+    if rows is not None:
+        # under a mesh (``make_train_step``) a chunk is data-parallel like
+        # the batch it was cut from: ITS ROWS over the batch's axes, the
+        # hidden dimension whole, the table whole but for the vocabulary's
+        # split. Left to the partitioner, the scanned axis takes the batch's
+        # split with it and each chunk's logits, [chunk, vocab] in float32,
+        # are all-reduced as partial sums over a split hidden dimension —
+        # twice a chunk, the recompute's too
+        constrain = jax.lax.with_sharding_constraint
+        xc = constrain(xc, P(None, rows, None))
+        lc, mc = constrain(lc, P(None, rows)), constrain(mc, P(None, rows))
+        w = constrain(w, P(free_axes("vocab", V + pad_v), None))
 
     def body(acc, args):
         return acc + chunk_loss(*args), None

@@ -9,7 +9,17 @@ Canonical axis names (used by sharding rules and the trainer):
   * ``dp``   — pure data parallel (gradient all-reduce over ICI/DCN)
   * ``fsdp`` — data parallel with parameter/optimizer sharding (ZeRO-3-style,
                all-gather params forward, reduce-scatter grads)
-  * ``tp``   — tensor (megatron) parallelism within attention/MLP blocks
+  * ``tp``   — tensor (megatron) parallelism within attention/MLP blocks.
+               A training block pays four reduces of a residual-sized array
+               over it: two forward (``wo``'s and the mlp's last dot's
+               partial sums), two backward (the gradient of each norm's
+               output), none again in the recompute, which keeps ``wo``'s
+               reduced result (``models/transformer.py: ATTN_OUT``).
+               ``forward`` states where the residual lives (batch over
+               ``dp``/``fsdp``, the hidden dimension whole) and the fused
+               cross-entropy where a chunk's rows do, so no activation is
+               reduced over ``fsdp``; ``collective_census`` below counts
+               what a compiled step holds, by op and axis
   * ``sp``   — sequence/context parallelism (ring attention over this axis)
   * ``ep``   — expert parallelism for MoE layers
   * ``pp``   — pipeline stages (usually over DCN between slices)
@@ -23,6 +33,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
 AXIS_ORDER = ("pp", "dp", "fsdp", "ep", "sp", "tp")
@@ -108,3 +119,127 @@ def replicated(mesh):
     from jax.sharding import NamedSharding, PartitionSpec
 
     return NamedSharding(mesh, PartitionSpec())
+
+
+# ---------------------------------------------------------------------------
+# what the compiler put on the interconnect: a count from the program's text
+
+_COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+                "collective-permute")
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
+                "s16": 2, "u16": 2, "f16": 2, "bf16": 2, "s32": 4, "u32": 4,
+                "f32": 4, "s64": 8, "u64": 8, "f64": 8}
+_COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(?P<type>\(.*?\)|\S+)\s+"
+    r"(?P<op>" + "|".join(_COLLECTIVES) + r")(?P<start>-start)?\(")
+_ARRAY = re.compile(r"(\w+)\[([\d,]*)\]")
+_CALLED = re.compile(
+    r"(?:body|condition|calls|to_apply|true_computation|false_computation)"
+    r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
+
+
+def _group_axes(line: str, op: str, shape: Dict[str, int]) -> Tuple[str, ...]:
+    """The mesh axes one group of the instruction spans: its devices'
+    places in the mesh (a device's number is its place in ``mesh.devices``,
+    row-major, which is how ``jit`` numbers the partitions), and the axes
+    along which they differ."""
+    import numpy as np
+
+    sizes = tuple(shape.values())
+    n = math.prod(sizes)
+    if op == "collective-permute":
+        pairs = re.search(r"source_target_pairs=\{(.*?)\}\}", line)
+        groups = [[int(i) for i in pair.split(",")] for pair in
+                  re.findall(r"\{(\d+,\d+)", pairs.group(1) + "}")]
+    elif (iota := re.search(r"replica_groups=\[([\d,]+)\]<=\[([\d,]+)\]"
+                            r"(?:T\(([\d,]+)\))?", line)):
+        dims, reshape, perm = (tuple(int(i) for i in g.split(","))
+                               if g else None for g in iota.groups())
+        ids = np.arange(math.prod(reshape)).reshape(reshape)
+        groups = ids.transpose(perm).reshape(dims).tolist()
+    else:
+        listed = re.search(r"replica_groups=\{(.*?)\}\}", line)
+        groups = [[int(i) for i in g.split(",")] for g in
+                  re.findall(r"\{([\d,]+)", (listed.group(1) + "}")
+                             if listed else "")]
+    if not groups:  # no groups named: every device is in the one group
+        groups = [list(range(n))]
+    differ = set()
+    for group in groups:
+        places = np.array(np.unravel_index(group, sizes))
+        differ |= {i for i, row in enumerate(places) if len(set(row)) > 1}
+    return tuple(name for i, name in enumerate(shape) if i in differ)
+
+
+def collectives(hlo_text: str, mesh) -> List[Dict[str, object]]:
+    """Every collective instruction of a compiled program's text
+    (``compiled.as_text()``), one row each: ``op`` (``all-reduce-scatter``
+    for an all-reduce inside the chip compiler's fusion of that name);
+    ``axes``, the mesh axes its groups span; ``shapes`` and ``bytes`` of
+    what ONE device holds of its result (an ``-start`` counts once, by what
+    its ``-done`` yields); ``loop``, whether a ``while`` body reaches it (a
+    layer scan's, forward or backward, or the cross-entropy's chunks') or
+    only the entry does; and ``op_name``, the source op it serves, as the
+    compiler's metadata has it (``transpose(`` the backward pass,
+    ``rematted_computation`` a recompute). It counts INSTRUCTIONS: the chip's
+    compiler writes a layer's weight gathers three to five times in a body
+    it has pipelined, so their bytes are an upper bound there; a reduce of
+    an activation stands once. Text in, rows out: nothing is compiled or
+    run here."""
+    shape = dict(mesh.shape)
+    computation, rows, homes, calls, bodies = None, [], [], {}, set()
+    for line in hlo_text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            computation = head.group(2)
+            continue
+        for one, many in _CALLED.findall(line):
+            names = [one] if one else re.findall(r"[\w.\-]+", many)
+            calls.setdefault(computation, set()).update(names)
+        bodies.update(re.findall(r"\bbody=%?([\w.\-]+)", line))
+        found = _INSTRUCTION.match(line)
+        if not found:
+            continue
+        arrays = [(dtype, tuple(int(d) for d in dims.split(",") if d))
+                  for dtype, dims in _ARRAY.findall(found.group("type"))
+                  if dtype in _DTYPE_BYTES]
+        op = found.group("op")
+        if computation.startswith("all-reduce-scatter"):
+            # the chip's compiler fuses the reduce with the slice that
+            # keeps a device's share: a reduce-scatter by another name
+            op = "all-reduce-scatter"
+        if found.group("start") and op in ("all-gather",
+                                           "collective-permute"):
+            # (operands..., results..., [two counters of a permute])
+            arrays = [a for a in arrays if a[1] or op == "all-gather"]
+            arrays = arrays[len(arrays) // 2:]
+        name = re.search(r'op_name="([^"]*)"', line)
+        rows.append({
+            "op": op, "axes": _group_axes(line, op, shape),
+            "shapes": [dims for _, dims in arrays],
+            "bytes": sum(_DTYPE_BYTES[dtype] * math.prod(dims)
+                         for dtype, dims in arrays),
+            "op_name": name.group(1) if name else ""})
+        homes.append(computation)
+    in_loop, stack = set(), list(bodies)
+    while stack:
+        name = stack.pop()
+        if name not in in_loop:
+            in_loop.add(name)
+            stack.extend(calls.get(name, ()))
+    for row, home in zip(rows, homes):
+        row["loop"] = home in in_loop
+    return rows
+
+
+def collective_census(hlo_text: str, mesh) -> Dict[tuple, Dict[str, int]]:
+    """``collectives`` added up: ``(where, op, axes) -> {"calls", "bytes"}``,
+    ``where`` "loop" (the scan bodies: a layer-step) or "entry"."""
+    census: Dict[tuple, Dict[str, int]] = {}
+    for row in collectives(hlo_text, mesh):
+        key = ("loop" if row["loop"] else "entry", row["op"], row["axes"])
+        tally = census.setdefault(key, {"calls": 0, "bytes": 0})
+        tally["calls"] += 1
+        tally["bytes"] += row["bytes"]
+    return census

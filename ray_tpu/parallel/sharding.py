@@ -35,6 +35,27 @@ DEFAULT_RULES: Dict[str, Optional[Union[str, Tuple[str, ...]]]] = {
 }
 
 
+def free_axes(logical: str, *dims: int):
+    """The axes of the mesh in scope (``jax.sharding.get_abstract_mesh()``)
+    that ``DEFAULT_RULES`` give ``logical`` and that are still the
+    compiler's to place — not manual under a caller's ``shard_map`` — as a
+    ``PartitionSpec`` entry: one name, a tuple of names, or None where there
+    is none or their size does not divide every one of ``dims``. What code
+    that traces under ``make_train_step``'s mesh asks before it states a
+    layout; with no mesh in scope the answer is None."""
+    import jax
+
+    mesh = jax.sharding.get_abstract_mesh()
+    target = DEFAULT_RULES[logical]
+    names = tuple(a for a in ((target,) if isinstance(target, str)
+                              else target or ())
+                  if a in mesh.axis_names and a not in mesh.manual_axes)
+    size = math.prod(mesh.shape[a] for a in names)
+    if size == 1 or any(d % size for d in dims):
+        return None
+    return names if len(names) > 1 else names[0]
+
+
 @dataclasses.dataclass
 class ShardingRules:
     rules: Dict[str, Optional[Union[str, Tuple[str, ...]]]] = dataclasses.field(

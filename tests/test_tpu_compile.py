@@ -243,21 +243,41 @@ def test_gpt2s_train_step_fits_one_chip(v5e):
     _fits(compiled)
 
 
-def test_gpt2s_sharded_train_step_lowers_with_the_kernel_in_it(v5e):
+def _sharded_shapes():
+    from ray_tpu.models import gpt2_small, llama3_8b
+
+    return {
+        "gpt2s": (gpt2_small, 16),
+        # the Llama-shaped block (GQA 8 over 4, SwiGLU, untied head) at a
+        # width that compiles in seconds; no weight has a dimension of the
+        # sequence's or a cross-entropy chunk's length
+        "llama_narrow": (lambda: llama3_8b(
+            vocab_size=32768, num_layers=2, embed_dim=1536, num_heads=8,
+            num_kv_heads=4, head_dim=128, mlp_dim=3072, max_seq_len=1024), 8),
+    }
+
+
+@pytest.mark.parametrize("shape", ["gpt2s", "llama_narrow"])
+def test_sharded_train_step_lowers_with_the_kernel_in_it(v5e, shape):
     """fsdp=2 x tp=2 over four described chips. GSPMD cannot partition a
     Mosaic kernel; the dispatcher shard_maps it over the mesh in scope, so
     the step lowers with the kernel inside and q/k/v are never gathered
-    to full batch or full heads in front of it."""
-    from ray_tpu.models import gpt2_small
+    to full batch or full heads in front of it. And what the chip's
+    compiler puts on the interconnect (ISSUE 47; ``collectives``): a block
+    reduces each activation once over ``tp`` — two residual-sized arrays
+    forward, two backward, none in the recompute — and nothing that carries
+    tokens over ``fsdp`` inside a scan, the cross-entropy's among them."""
     from ray_tpu.models.training import (OptimizerConfig, make_optimizer,
                                          make_train_step)
-    from ray_tpu.parallel.mesh import data_sharding
+    from ray_tpu.parallel.mesh import collectives, data_sharding
 
-    cfg, tx = gpt2_small(), make_optimizer(OptimizerConfig())
+    make, batch = _sharded_shapes()[shape]
+    cfg, tx = make(), make_optimizer(OptimizerConfig())
     mesh = Mesh(np.array(v5e.devices[:4]).reshape(2, 2), ("fsdp", "tp"))
     compiled = make_train_step(cfg, tx, mesh).lower(
         _abstract_train_state(cfg, tx, mesh),
-        {"tokens": _on(data_sharding(mesh), (16, 1024), jnp.int32)}).compile()
+        {"tokens": _on(data_sharding(mesh), (batch, 1024), jnp.int32)}
+    ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     for kernel in FLASH_KERNELS:  # named inside the shard_map as well
@@ -270,6 +290,23 @@ def test_gpt2s_sharded_train_step_lowers_with_the_kernel_in_it(v5e):
              "16,6,1024,64", "8,12,1024,64", "16,12,1024,64"}
     assert gathers and not whole & set(gathers), whole & set(gathers)
     _fits(compiled)
+
+    residual = (batch // 2, 1024, cfg.embed_dim)
+    rows = [row for row in collectives(text, mesh) if row["loop"]]
+    reduced = [row["op_name"] for row in rows for s in row["shapes"]
+               if s == residual and row["op"] == "all-reduce"
+               and row["axes"] == ("tp",)]
+    backward = [name for name in reduced if "transpose(" in name]
+    assert len(reduced) == 4 and len(backward) == 2, reduced
+    assert not [n for n in reduced if "rematted_computation" in n], reduced
+    # tokens over fsdp: a residual, or a chunk of the cross-entropy's rows
+    # ([2048 or its share, ...]; the parent all-reduced [2048, vocab / tp]
+    # float32 logits, twice a chunk)
+    over_fsdp = [row for row in rows if "fsdp" in row["axes"] and any(
+        s[:2] == residual[:2] or s[:1] in ((cfg.ce_chunk,),
+                                           (cfg.ce_chunk // 2,))
+        for s in row["shapes"])]
+    assert not over_fsdp, over_fsdp
 
 
 # ----------------------------------------------------------- serve programs
