@@ -457,20 +457,16 @@ def _apply_blocks(cfg, blocks, h, n_local: int):
     import jax
     from jax import lax
 
-    from ray_tpu.models.transformer import _block
+    from ray_tpu.models.transformer import _block, remat_policy
     from ray_tpu.ops.rotary import rope_frequencies
 
     rope = None if cfg.pos == "learned" else rope_frequencies(
         cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
     block_fn = _block
     if cfg.remat:
-        policies = {
-            "full": jax.checkpoint_policies.nothing_saveable,
-            "dots": jax.checkpoint_policies.checkpoint_dots,
-        }
         block_fn = jax.checkpoint(
             _block, static_argnums=(0, 5),
-            policy=policies[cfg.remat_policy])
+            policy=remat_policy(cfg.remat_policy))
     if cfg.scan_layers:
         def body(carry, layer_params):
             hh, _, _, _ = block_fn(cfg, layer_params, carry, rope, None,
@@ -577,7 +573,8 @@ def _tp_apply_blocks(cfg, blocks, h, n_local: int, tp_ops,
     import jax
     from jax import lax
 
-    from ray_tpu.models.transformer import _tp_block, _tp_block_tail
+    from ray_tpu.models.transformer import (_tp_block, _tp_block_tail,
+                                            remat_policy)
     from ray_tpu.ops.rotary import rope_frequencies
 
     g, f = tp_ops
@@ -591,14 +588,9 @@ def _tp_apply_blocks(cfg, blocks, h, n_local: int, tp_ops,
         return _tp_block_tail(cfg, p, x, rope, g, f)
 
     if cfg.remat:
-        policies = {
-            "full": jax.checkpoint_policies.nothing_saveable,
-            "dots": jax.checkpoint_policies.checkpoint_dots,
-        }
-        one_block = jax.checkpoint(
-            one_block, policy=policies[cfg.remat_policy])
-        tail_block = jax.checkpoint(
-            tail_block, policy=policies[cfg.remat_policy])
+        policy = remat_policy(cfg.remat_policy)
+        one_block = jax.checkpoint(one_block, policy=policy)
+        tail_block = jax.checkpoint(tail_block, policy=policy)
 
     n_chain = n_local - 1 if split_tail else n_local
     if cfg.scan_layers:

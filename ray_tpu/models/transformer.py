@@ -60,6 +60,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import attention
+from ray_tpu.ops.flash_attention import FLASH_LSE, FLASH_OUT
 from ray_tpu.ops.linear_attention import (linear_attention_chunk,
                                           linear_attention_step, slopes)
 from ray_tpu.ops.losses import softmax_cross_entropy
@@ -136,9 +137,15 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16                 # activation/compute dtype
     param_dtype: Any = jnp.float32
     remat: bool = True                        # checkpoint each block
-    # 'full': recompute everything in backward (min memory, ~+2N flops/tok);
-    # 'dots': save matmul outputs, recompute elementwise only (near-full
-    # memory, tiny recompute) — the right trade when HBM allows
+    # What a block keeps for its backward (``remat_policy`` below, the one
+    # table). 'full': its input, the attention kernel's output and
+    # log-sum-exp and, under ``tp``, the reduced ``wo`` result; all else is
+    # recomputed (min memory, ~+2N flops/tok). 'dots': those and every
+    # matmul's output, recomputing elementwise only (near-full memory, tiny
+    # recompute) — the right trade when HBM allows. The kernel's two stay
+    # under both because its recompute is the one part of a block that
+    # grows with S squared, and what keeping them costs is as small as the
+    # block's input ([B, S, H*D], the float32 log-sum-exp 2/D of it).
     remat_policy: str = "full"
     scan_layers: bool = True                  # stack layers, lax.scan over them
     attn_impl: str = "auto"                   # 'auto'|'flash'|'reference'|'ring'
@@ -430,6 +437,26 @@ COMPUTED = "computed"  # ``rope``: angles from the positions, no table
 # and of ``w_gate`` and ``w_up``, before it reduces; the CPU's reduces each
 # where it stands).
 ATTN_OUT = "attn_out"
+
+
+def remat_policy(name: str):
+    """``TransformerConfig.remat_policy`` as a ``jax.checkpoint`` policy:
+    the one table, for ``forward`` and the pipeline stages' block slices
+    (``presets._apply_blocks``, ``_tp_apply_blocks``). Both policies keep
+    the three names (``ATTN_OUT`` is named only where keeping it saves a
+    reduce); ``checkpoint_dots`` alone does not see a Pallas call and would
+    run the forward kernel a second time."""
+    named = jax.checkpoint_policies.save_only_these_names(
+        ATTN_OUT, FLASH_OUT, FLASH_LSE)
+    policies = {
+        "full": named,
+        "dots": jax.checkpoint_policies.save_from_both_policies(
+            jax.checkpoint_policies.checkpoint_dots, named),
+    }
+    if name not in policies:
+        raise ValueError(f"unknown remat_policy {name!r}; "
+                         f"expected one of {sorted(policies)}")
+    return policies[name]
 
 
 def _qkv(cfg, p, x, rope, positions, kind=ATTENTION):
@@ -911,16 +938,7 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
 
     block_fn = lambda kind: functools.partial(_block, kind=kind)
     if cfg.remat and kv_caches is None and not return_selected:
-        policies = {
-            # ``ATTN_OUT`` is named only where keeping it saves a reduce
-            "full": jax.checkpoint_policies.save_only_these_names(ATTN_OUT),
-            "dots": jax.checkpoint_policies.checkpoint_dots,
-        }
-        if cfg.remat_policy not in policies:
-            raise ValueError(
-                f"unknown remat_policy {cfg.remat_policy!r}; "
-                f"expected one of {sorted(policies)}")
-        policy = policies[cfg.remat_policy]
+        policy = remat_policy(cfg.remat_policy)
         # ``COMPUTED`` is a name, not an array: static like the config
         static = (0, 3, 5) if rope is COMPUTED else (0, 5)
         block_fn = lambda kind: jax.checkpoint(

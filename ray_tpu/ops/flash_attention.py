@@ -46,6 +46,17 @@ dK/dV is the mirror image (the cell's own block is keys, the GQA group's
 query heads sum into their kv head's accumulator in-kernel). delta =
 rowsum(dO * O) is computed in XLA (cheap elementwise) and fed in.
 
+Under a ``jax.checkpoint`` the forward kernel runs ONCE (PR 48): the forward
+rule names the two arrays the backward kernels read beside q, k and v — the
+output (`FLASH_OUT`) and the log-sum-exp (`FLASH_LSE`) — and both of the
+models' remat policies keep them (``models/transformer.py: remat_policy``:
+'full' keeps a block's input, these two and under ``tp`` the reduced ``wo``
+result; 'dots' those and every matmul's output, for ``checkpoint_dots`` does
+not see a Pallas call). It is the one part of a block whose recompute grows
+with S squared, and what is kept is as small as the block's input ([B, S,
+H*D], the float32 log-sum-exp 2/D of it). With no checkpoint around the call —
+the serving programs, the reference checks — a name is an identity.
+
 What it measures (my chip runs, PR 33, one v5e; PERF.md sections 5 and 6):
 B 128, H 12, S 1024, D 64 (`gpt2s_train`): forward 12.81 -> 6.08 ms a call,
 dQ 10.49 -> 6.53, dK/dV 10.49 -> 9.15; B 4, H 16 over 4 kv heads, S 4096,
@@ -66,6 +77,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -73,6 +85,12 @@ from ray_tpu.ops._pallas import should_interpret
 
 NEG_INF = -1e30
 _LANES = 128
+
+# What the forward kernel hands the two backward kernels, by name, so that a
+# ``jax.checkpoint`` around the caller can keep them (module docstring;
+# ``models/transformer.py: remat_policy``). Outside one a name is an identity.
+FLASH_OUT = "flash_attn_out"
+FLASH_LSE = "flash_attn_lse"
 
 # What a cell's pipelined blocks may take of VMEM (bytes, both buffers of
 # each counted, `block_bytes`): the rule that decides resident against
@@ -737,6 +755,9 @@ def _fwd_rule(q, k, v, sm_scale, causal, tiles):
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     out, lse = _flash_fwd(q, k, v, sm_scale, causal,
                           _schedule(q, k, tiles).fwd, should_interpret())
+    # the residuals are the NAMED values: a recompute that finds both kept
+    # has no use left for the call
+    out, lse = checkpoint_name(out, FLASH_OUT), checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, out, lse)
 
 

@@ -82,6 +82,13 @@ def _kernel_names(text: str) -> set:
         r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text))
 
 
+def _kernel_calls(compiled) -> dict:
+    """How many custom calls of each kernel name the compiled text holds."""
+    names = [re.sub(r"[.\d]+$", "", k)
+             for k in _kernel_names(compiled.as_text())]
+    return {k: names.count(k) for k in set(names)}
+
+
 def _fits(compiled) -> int:
     ma = compiled.memory_analysis()
     total = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
@@ -224,7 +231,18 @@ def _abstract_train_state(cfg, tx, mesh):
         abstract, shardings)
 
 
-def test_gpt2s_train_step_fits_one_chip(v5e):
+# a training step runs each flash kernel once a layer (ISSUE 48): under the
+# layer scan a kernel's call stands once in the text, and the block's
+# recompute holds no second forward (the parent's step: two)
+ONCE_A_LAYER = dict.fromkeys(FLASH_KERNELS, 1)
+
+
+@pytest.mark.parametrize("batch", [16, 128], ids=["b16", "the_cells_b128"])
+def test_gpt2s_train_step_fits_one_chip(v5e, batch, capsys):
+    """``gpt2s_train``'s step, at a batch that compiles in seconds and at the
+    cell's own (128 x 1024, 23 s): what the block keeps across its remat
+    boundary is paid for at the cell's size, so a change to the block that
+    costs memory there is seen here, without a chip."""
     from ray_tpu.models import gpt2_small
     from ray_tpu.models.training import (OptimizerConfig, make_optimizer,
                                          make_train_step)
@@ -233,14 +251,16 @@ def test_gpt2s_train_step_fits_one_chip(v5e):
     chip = SingleDeviceSharding(v5e.devices[0])
     compiled = make_train_step(cfg, tx).lower(
         _abstract_train_state(cfg, tx, chip),
-        {"tokens": _on(chip, (16, 1024), jnp.int32)}).compile()
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    # under the layer scan and full remat every kernel keeps its name, the
-    # recomputed forward too (PERF.md: kernel.flash_roofline finds them)
-    for kernel in FLASH_KERNELS:
-        assert [n for n in _kernel_names(text) if kernel in n], kernel
-    _fits(compiled)
+        {"tokens": _on(chip, (batch, 1024), jnp.int32)}).compile()
+    # every kernel keeps its name under the layer scan and the remat
+    # (PERF.md: kernel.flash_roofline finds them by it)
+    assert _kernel_calls(compiled) == ONCE_A_LAYER
+    total, ma = _fits(compiled), compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\ngpt2s train step, batch {batch} x 1024: temporaries "
+              f"{ma.temp_size_in_bytes / 1e9:.2f} GB, arguments "
+              f"{ma.argument_size_in_bytes / 1e9:.2f} GB, in all "
+              f"{total / 1e9:.2f} GB of {HBM_BYTES / 1e9:.2f}")
 
 
 def _sharded_shapes():
@@ -279,9 +299,8 @@ def test_sharded_train_step_lowers_with_the_kernel_in_it(v5e, shape):
         {"tokens": _on(data_sharding(mesh), (batch, 1024), jnp.int32)}
     ).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    for kernel in FLASH_KERNELS:  # named inside the shard_map as well
-        assert [n for n in _kernel_names(text) if kernel in n], kernel
+    # named inside the shard_map as well, and the forward not run again
+    assert _kernel_calls(compiled) == ONCE_A_LAYER
     gathers = re.findall(
         r"= \(?bf16\[([0-9,]+)\][^=\n]* all-gather(?:-start)?\(", text)
     # per shard q/k/v are [8, 1024, 6, 64] (or head-major): a gather back
@@ -485,13 +504,6 @@ def _cell_programs(v5e, config: str, cell: str):
         "decode": (paged_decode_step,
                    (params, ids((slots,)), *step[:4], caches, *step[4:])),
     }
-
-
-def _kernel_calls(compiled) -> dict:
-    """How many custom calls of each kernel name the compiled text holds."""
-    names = [re.sub(r"[.\d]+$", "", k)
-             for k in _kernel_names(compiled.as_text())]
-    return {k: names.count(k) for k in set(names)}
 
 
 @pytest.mark.parametrize("config,cell,held_gb", [
