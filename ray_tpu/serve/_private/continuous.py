@@ -90,33 +90,16 @@ program) scores them all, with exact accept-prefix + corrected-resample
 semantics (temperature-0 output is the sequential greedy path's, token for
 token).
 
-LAYERS OF OTHER KINDS (``TransformerConfig.layer_kinds``). The pool is by
-kind: pages for an attention layer, pages plus a pooled key row a page for a
-'minicpm4' layer (which attends the blocks it chooses), a fixed float32 state
-a slot for a 'lightning-attn' or 'power-retention' layer. A chunk continues
-its slot's states and takes them as zero when it starts at position 0, so an
-admission still runs no device program; a step, alone or along in a chunk's
-program, leaves the states of a row that is free or mid-prefill bitwise
-alone. What a token leaves in such a state cannot be cut
-at a page boundary or rewound, and no snapshot is kept: the prefix cache,
-prefix export / migration and the speculative programs refuse a model with
-such layers when the scheduler is built.
-
-WINDOW LAYERS ('sliding_attention'). Their pages are a pool and an arena of
-their own beside the full layers', through tables of their own (every paged
-program takes both pairs, ``_tables``). A slot holds there the pages its
-window still covers and no more, whatever the context: before a turn
-allocates what its rows will write (``_ensure_pages``) the pages wholly
-behind ``cursor - sliding_window + 1`` go back to the arena, their table
-entries point at the garbage page, and the kernel's walk starts behind them
-(``ops.paged_attention``), so nothing ever reads them. The pool's size
-follows from ``slots``, the window, ``prefill_chunk`` and ``page_tokens``
-(``window + chunk`` tokens and a page a slot): no option sets it, and
-``kv_pages`` keeps meaning the full layers' pool. A spliced prefix would
-need the window layers' last window of it kept too, and a rejected draft's
-released pages back: the prefix cache, prefix export / migration and the
-speculative programs refuse a model with such layers when the scheduler is
-built.
+THE KINDS OF THE MODEL'S LAYERS (``TransformerConfig.layer_kinds``) are not
+named here. What they hold of pages is ``_pools`` (``paging.build_pools``:
+none, one or several ``PagePool`` — an arena, every slot's tables, perhaps a
+window behind which a slot's pages go back before a turn allocates); a state
+a slot is part of ``_caches``, zero at position 0 and bitwise alone where a
+row is not live, so an admission runs no device program for any model. What
+that forbids where pages alone do not continue a sequence (the prefix cache,
+prefix export / migration, the speculative programs) is
+``paging.cannot_continue``, asked when the scheduler is built. What a call
+did, by kind, is ``_work`` (``work.Work``), merged into ``stats()`` unread.
 
 Knobs: ``RAY_TPU_SERVE_SLOTS`` (slots), ``RAY_TPU_SERVE_PREFILL_CHUNK``
 (prefill chunk tokens), ``RAY_TPU_SERVE_PAGE_TOKENS``,
@@ -129,7 +112,6 @@ target's weights); all overridable per-deployment via LLMServer init.
 
 from __future__ import annotations
 
-import math
 import threading
 import time
 from collections import deque
@@ -139,6 +121,10 @@ from typing import Any, Dict, List, Optional
 
 from ray_tpu._private import flight
 from ray_tpu._private.metrics import Counter, Gauge, Histogram
+from ray_tpu.serve._private.paging import (OutOfPagesError, RadixCache,
+                                           SlotPages, build_pools,
+                                           cannot_continue, pool_stats,
+                                           pool_tables)
 
 # The scheduler thread's time, cut into leaf phases by ONE clock
 # (``flight.PhaseClock``): a transition closes the open phase and opens the
@@ -204,11 +190,6 @@ _m_retired = Counter(
 _m_active = Gauge(
     "ray_tpu_serve_slots_active",
     "KV arena slots currently holding a live sequence")
-_m_attn_bytes = Counter(
-    "ray_tpu_serve_attn_bytes_moved_total",
-    "KV-cache bytes the paged attention lane streamed per program call "
-    "(host-side mirror arithmetic, labelled by implementation: whole "
-    "blocks of pages up to each sequence's cursor)")
 _m_queue_depth = Gauge(
     "ray_tpu_serve_queue_depth",
     "Requests waiting for a free KV arena slot")
@@ -255,9 +236,7 @@ class _Seq:
                  "next_token",
                  "queue", "loop", "cancelled", "rid", "t_submit", "t_admit",
                  "t_first_token", "t_emit", "prefill_mark", "rng",
-                 "cached_len", "cursor",
-                 "owned_pages", "radix_node",
-                 "table_fill", "window_pages", "window_fill",
+                 "cached_len", "cursor", "held", "radix_node",
                  "fleet_hint", "migration_node",
                  "drafter_len", "drafter_pending")
 
@@ -291,13 +270,8 @@ class _Seq:
         # ---- paged-arena bookkeeping (the device holds pages only) ----
         self.cached_len = 0            # spliced prefix tokens (page-aligned)
         self.cursor = 0                # tokens resident: THE slot's cursor
-        self.owned_pages: List[int] = []  # pages this slot must free
+        self.held: tuple = ()  # of each pool, once seated (``SlotPages``)
         self.radix_node = None         # ref-counted prefix-cache node
-        self.table_fill = 0            # logical pages present in the table
-        # the window layers' pool: the pages held, oldest first (the logical
-        # pages [window_fill - len(window_pages), window_fill))
-        self.window_pages: deque = deque()
-        self.window_fill = 0
         # ---- fleet phase (ISSUE 18) ----
         self.fleet_hint = None         # {"handle", "tokens"} from the router
         self.migration_node = None     # pin on a just-migrated prefix span
@@ -316,10 +290,11 @@ class _Launched:
     """One dispatched program whose result the host has not read yet."""
 
     __slots__ = ("serial", "prefill_mark", "step", "chunk", "ids", "rows",
-                 "moe", "live_rows")
+                 "returned", "live_rows")
 
     def __init__(self, serial: int, prefill_mark: int, step: bool,
-                 chunk: bool, ids, rows: List[_Seq], moe, live_rows: int):
+                 chunk: bool, ids, rows: List[_Seq], returned: tuple,
+                 live_rows: int):
         self.serial = serial        # its number among the dispatched programs
         # prompt tokens dispatched so far, this program's own among them
         self.prefill_mark = prefill_mark
@@ -329,7 +304,7 @@ class _Launched:
         # the sequences it sampled a token for: the decode rows and, behind
         # them, the prompt whose last chunk it carried
         self.rows = rows
-        self.moe = moe              # an expert model's counts, on the device
+        self.returned = returned    # beside ids and caches: ``_work``'s
         self.live_rows = live_rows  # the live rows the host handed it
 
 
@@ -378,19 +353,15 @@ class ContinuousScheduler:
                  spec_k: Optional[int] = None,
                  migration_budget: Optional[int] = None,
                  attn: Optional[str] = None):
-        import numpy as np
         import jax
 
         from ray_tpu._private.config import global_config
         from ray_tpu.models.decode import (init_paged_caches,
                                            paged_decode_step,
                                            paged_prefill_into_slot,
-                                           paged_verify_step, pool_of)
-        from ray_tpu.models.transformer import (ATTENTION, LINEAR, RETENTION,
-                                                SLIDING, SPARSE, STATE_KINDS,
-                                                state_shapes)
+                                           paged_verify_step)
         from ray_tpu.ops.paged_attention import resolve_impl
-        from ray_tpu.serve._private.paging import PageArena, RadixCache
+        from ray_tpu.serve._private.work import Work
 
         conf = global_config()
         self.cfg = cfg
@@ -433,83 +404,40 @@ class ContinuousScheduler:
             # auto: the worst case (every slot could fill its whole
             # logical range) + the reserved garbage page
             kvp = self.slots * self._pages_per_slot + 1
-        self.num_pages = kvp
-        self._arena = PageArena(self.num_pages, self.page_tokens)
-        use_prefix = (conf.serve_prefix_cache if prefix_cache is None
-                      else bool(prefix_cache))
-        self._radix = RadixCache(self._arena) if use_prefix else None
-        # host-side page tables: logical page j of slot s lives at
-        # physical page read_tables[s, j]; 0 = the garbage page
-        # (unallocated reads are causally masked, redirected writes
-        # are absorbed)
-        self._read_tables = np.zeros(
-            (self.slots, self._pages_per_slot), np.int32)
-        self._write_tables = np.zeros(
-            (self.slots, self._pages_per_slot), np.int32)
         # the implementation resolves ONCE at build — an unknown value
         # fails the constructor, not some later decode step, and stats()
         # always names what really runs
         self.attn_lane = resolve_impl(cfg, attn)
-        # a model none of whose layers holds a page: memory is a state a
-        # slot and a request is bounded by arena_len alone. Of the pages
-        # above (the knobs were checked as given) one a slot is left that is
-        # never handed out: no pool on the device, no table uploaded, no
-        # allocator work a turn
-        self._paged = cfg.holds_pages
-        if not self._paged:
+        self._pools = build_pools(
+            cfg, slots=self.slots, page_tokens=self.page_tokens,
+            pages_per_slot=self._pages_per_slot, num_pages=kvp,
+            prefill_chunk=self.prefill_chunk)
+        if not self._pools:
+            # no layer holds a page: memory is a state a slot and a request
+            # is bounded by arena_len alone (the knobs above were checked as
+            # given): no pool on the device, no table uploaded
             self.page_tokens, self._pages_per_slot = self.arena_len, 1
-            self.num_pages = 1
-            self._arena = PageArena(1, self.arena_len, pageless=True)
-            self._read_tables = np.zeros((self.slots, 1), np.int32)
-            self._write_tables = np.zeros((self.slots, 1), np.int32)
-        if cfg.recurrent:
-            # a spliced prefix would need the states as they stood at its
-            # last token, a rejected draft their rewind: no snapshot is kept
+        use_prefix = (conf.serve_prefix_cache if prefix_cache is None
+                      else bool(prefix_cache))
+        self._refused = cannot_continue(cfg, self._pools)
+        if self._refused:
             if prefix_cache:
-                raise ValueError(
-                    "prefix_cache=True cannot serve a model with layers "
-                    "that keep a state a slot ('lightning-attn', "
-                    "'power-retention'): their state at a prefix's end is "
-                    "not kept")
+                raise ValueError(self._refused["prefix_cache"])
             if drafter is not None:
-                raise ValueError(
-                    "speculative decoding cannot serve a model with layers "
-                    "that keep a state a slot ('lightning-attn', "
-                    "'power-retention'): a rejected draft would have to "
-                    "rewind their states")
-            self._radix = None  # the configured default cannot apply
-        # window layers: a pool and an arena of their own, sized by what a
-        # slot can hold of it at once — the window behind a chunk's first
-        # row, the chunk, and a page for where the two begin inside one
-        self._window = cfg.sliding_window if SLIDING in cfg.kinds else 0
-        self._window_arena = None
-        if self._window:
-            if prefix_cache:
-                raise ValueError(
-                    "prefix_cache=True cannot serve a model with "
-                    "'sliding_attention' layers: a spliced prefix would need "
-                    "their last window of it kept too, and they release it")
-            if drafter is not None:
-                raise ValueError(
-                    "speculative decoding cannot serve a model with "
-                    "'sliding_attention' layers: a rejected draft would "
-                    "need the pages released behind it back")
-            self._radix = None  # the configured default cannot apply
-            slot_pages = min(
-                self._pages_per_slot,
-                -(-(self._window + self.prefill_chunk) // self.page_tokens)
-                + 1)
-            self._window_arena = PageArena(
-                self.slots * slot_pages + 1, self.page_tokens, pool="window")
-            self._window_read_tables = np.zeros_like(self._read_tables)
-            self._window_write_tables = np.zeros_like(self._write_tables)
-            # the tables' names: the full layers' pool, the window layers'
-            self._pools = (pool_of(ATTENTION), pool_of(SLIDING))
-        # an expert layer's programs hand the rows each expert received
-        # back with the ids
-        self._moe = cfg.mlp == "moe"
+                raise ValueError(self._refused["drafter"])
+            use_prefix = False  # the configured default cannot apply
+        # the prefix cache is the first pool's: it splices and adopts that
+        # pool's pages, and the pool asks it for pages before it fails
+        self._radix = None
+        if use_prefix:
+            first = self._pools[0]
+            first.radix = self._radix = RadixCache(first.arena)
+        self._work = Work(
+            cfg, slots=self.slots, page_tokens=self.page_tokens,
+            pages_per_slot=self._pages_per_slot, lane=self.attn_lane,
+            itemsize=int(jax.numpy.dtype(cache_dtype or cfg.dtype).itemsize))
         program_kw = {"attn": self.attn_lane}
-        if self._moe:
+        if self._work.counts_experts:
             program_kw["moe_info"] = True
         self._no_rows = _LiveRows(self.slots)  # for a chunk that takes none
         # donated caches: the pool mutates in place across iterations;
@@ -521,33 +449,10 @@ class ContinuousScheduler:
             _program(paged_decode_step, "paged_decode_step", cfg,
                      **program_kw), donate_argnums=(6,))
         self._caches = init_paged_caches(
-            cfg, self.num_pages, self.page_tokens,
-            self._pages_per_slot, cache_dtype, slots=self.slots,
-            window_pages=(self._window_arena.num_pages if self._window
-                          else None))
-        self._kv_itemsize = int(jax.numpy.dtype(
-            cache_dtype or cfg.dtype).itemsize)
-        # the pool by kind: layers that hold pages, layers that hold a state
-        # a slot, and among the first those that attend chosen blocks
-        kinds = cfg.kinds
-        self._n_linear = kinds.count(LINEAR)
-        self._n_retention = kinds.count(RETENTION)
-        self._n_sparse = kinds.count(SPARSE)
-        self._n_paged = sum(kind not in STATE_KINDS for kind in kinds)
-        self._n_window = kinds.count(SLIDING)
-        self._state_bytes = 4 * sum(
-            math.prod(shape) for kind in kinds if kind in STATE_KINDS
-            for shape in state_shapes(cfg, kind, self.slots).values())
-        if self._n_sparse:
-            # tokens of one block of the step's kernel over a row's table
-            # of chosen pages (``sparse_attention._step_attention``)
-            from ray_tpu.ops.paged_attention import tile_sizes
-
-            sizes = cfg.sparse
-            self._sparse_step_block = self.page_tokens * tile_sizes(
-                1, cfg.num_heads // cfg.kv_heads, self.page_tokens,
-                sizes.max_chosen_blocks() * sizes.pages_per_block,
-                cfg.kv_heads * cfg.head_dim * self._kv_itemsize)[0]
+            cfg, kvp, self.page_tokens, self._pages_per_slot, cache_dtype,
+            slots=self.slots,
+            window_pages=next((pool.arena.num_pages for pool in self._pools
+                               if pool.window is not None), None))
         # the newest token of every slot, as the programs left it: a chunk
         # that ends a prompt sets its row, a step replaces its active rows,
         # and the next step takes the vector as its tokens
@@ -642,40 +547,6 @@ class ContinuousScheduler:
         self._n_admitted = 0
         self._n_retired = 0
         self._n_tokens = 0
-        self._n_attn_bytes = 0
-        self._n_attn_attended = 0
-        self._n_attn_fetched = 0
-        # window layers beside full ones: what the mask admits by kind and
-        # by work (keys a decode row reads, (query, key) pairs of a chunk's
-        # real rows; summed over rows and layers), the pages released from
-        # behind a window, and, a turn, the tokens their pool holds beside
-        # those it would hold without release
-        self._n_window_step_keys = 0
-        self._n_full_step_keys = 0
-        self._n_window_chunk_pairs = 0
-        self._n_full_chunk_pairs = 0
-        self._n_window_released = 0
-        self._n_window_tokens_held = 0
-        self._n_window_tokens_unreleased = 0
-        # layers of other kinds (a layer-call: one layer in one program run)
-        self._n_linear_chunk_calls = 0
-        self._n_linear_step_rows = 0
-        self._n_retention_chunk_calls = 0
-        self._n_retention_chunk_tokens = 0
-        self._n_retention_step_rows = 0
-        self._n_sparse_rows = 0
-        self._n_sparse_rows_dense = 0
-        self._n_sparse_attended = 0
-        self._n_sparse_context = 0
-        self._n_sparse_step_attended = 0
-        self._n_sparse_step_context = 0
-        # expert layers (mlp='moe'): what the device's counts add up to,
-        # beside the live rows the host handed it
-        self._n_moe_live_rows = 0
-        self._n_moe_layer_calls = 0
-        self._n_moe_rows_routed = 0
-        self._n_moe_experts_hit = 0
-        self._n_moe_max_expert_rows = 0
         self._n_prefix_hit_tokens = 0
         self._admitted_mid_flight = 0
         self._max_active_slots = 0
@@ -703,10 +574,8 @@ class ContinuousScheduler:
         over-budget request is rejected loudly at submit, before any
         pages are allocated."""
         c = self.prefill_chunk
-        effective = self.arena_len
-        if self._paged:
-            effective = min(effective,
-                            self._arena.usable_pages * self.page_tokens)
+        effective = min([self.arena_len]
+                        + [pool.longest for pool in self._pools])
         # with speculation on, a verify round near the end of generation
         # writes up to spec_k positions past the final cursor — reserve
         # them so the window's writes can never clip onto the slot's
@@ -848,22 +717,11 @@ class ContinuousScheduler:
         is discarded when it is read (``_collect``)."""
         if seq.slot is None:
             return
-        slot = seq.slot
         if seq.radix_node is not None:
             self._radix.release(seq.radix_node)
             seq.radix_node = None
-        if seq.owned_pages:
-            self._arena.free(seq.owned_pages)
-            seq.owned_pages = []
-        seq.table_fill = 0
-        self._read_tables[slot, :] = 0
-        self._write_tables[slot, :] = 0
-        if self._window:
-            self._window_arena.free(list(seq.window_pages))
-            seq.window_pages.clear()
-            seq.window_fill = 0
-            self._window_read_tables[slot, :] = 0
-            self._window_write_tables[slot, :] = 0
+        for pool, held in zip(self._pools, seq.held):
+            pool.free(seq.slot, held)
 
     def _release_migration_ref(self, seq: _Seq) -> None:
         """A migrated-prefix pin must drop no matter how the sequence
@@ -873,7 +731,9 @@ class ContinuousScheduler:
             self._radix.release(seq.migration_node)
             seq.migration_node = None
 
-    def _retire(self, seq: _Seq, reason: str) -> None:
+    def _end(self, seq: _Seq, event: str, payload: str) -> None:
+        """The one way out of the scheduler: ``("end", reason)`` for a
+        sequence that retires, ``("err", message)`` for one that fails."""
         self._release_migration_ref(seq)
         self._release_slot_resources(seq)
         if seq.slot is not None:
@@ -883,96 +743,26 @@ class ContinuousScheduler:
         seq.state = _DONE
         self._n_retired += 1
         _m_retired.inc()
-        self._emit(seq, "end", reason)
+        self._emit(seq, event, payload)
+
+    def _retire(self, seq: _Seq, reason: str) -> None:
+        self._end(seq, "end", reason)
 
     def _fail(self, seq: _Seq, msg: str) -> None:
-        self._release_migration_ref(seq)
-        self._release_slot_resources(seq)
-        if seq.slot is not None:
-            self._slot_seqs[seq.slot] = None
-            seq.slot = None
-        flight.instant(_F_RETIRE, seq.rid)
-        seq.state = _DONE
-        self._n_retired += 1
-        _m_retired.inc()
-        self._emit(seq, "err", msg)
+        self._end(seq, "err", msg)
 
     def _ensure_pages(self, seq: _Seq, upto: int) -> bool:
-        """Grow the slot's page table so its logical view covers
-        [0, upto) tokens, evicting LRU unreferenced prefix-cache nodes
-        under pressure. On exhaustion the SEQUENCE fails cleanly (the
+        """Grow the slot's view of every pool to cover [0, upto) tokens
+        (``PagePool.grow``). On exhaustion the SEQUENCE fails cleanly (the
         scheduler and its other slots keep running). Returns True if the
         pages are present."""
-        from ray_tpu.serve._private.paging import OutOfPagesError
-
-        if not self._paged:
-            return True  # arena_len bounded the request at submit
         need = -(-upto // self.page_tokens)
-        if self._window and not self._ensure_window_pages(seq, need):
-            return False
-        missing = need - seq.table_fill
-        if missing <= 0:
-            return True
         try:
-            pages = self._arena.alloc(missing)
-        except OutOfPagesError:
-            if self._radix is not None:
-                self._radix.evict(missing - self._arena.free_pages)
-            try:
-                pages = self._arena.alloc(missing)
-            except OutOfPagesError:
-                self._fail(seq, f"kv arena out of pages (need {missing} "
-                                f"more, {self._arena.free_pages} free of "
-                                f"{self._arena.usable_pages}; nothing "
-                                f"evictable)")
-                return False
-        slot = seq.slot
-        for j, p in enumerate(pages, start=seq.table_fill):
-            self._read_tables[slot, j] = p
-            self._write_tables[slot, j] = p
-        seq.owned_pages.extend(pages)
-        seq.table_fill = need
-        return True
-
-    def _ensure_window_pages(self, seq: _Seq, need: int) -> bool:
-        """The window layers' half of ``_ensure_pages``: give back the pages
-        wholly behind the window of the next row the slot's programs run
-        (``seq.cursor``: every program dispatched so far took COPIES of the
-        tables, and the device runs them before whatever is dispatched
-        next), then grow the slot's window table to ``need`` logical pages.
-        A released page's entries point at the garbage page; the kernel's
-        walk starts behind it. Returns True if the pages are present."""
-        from ray_tpu.serve._private.paging import (F_WINDOW_RELEASE,
-                                                   OutOfPagesError,
-                                                   m_window_pages_released)
-
-        slot, held = seq.slot, seq.window_pages
-        first_kept = max(seq.cursor - self._window + 1, 0) // self.page_tokens
-        front = seq.window_fill - len(held)
-        if first_kept > front:
-            n = min(first_kept - front, len(held))
-            self._window_arena.free([held.popleft() for _ in range(n)])
-            self._window_read_tables[slot, front:front + n] = 0
-            self._window_write_tables[slot, front:front + n] = 0
-            self._n_window_released += n
-            m_window_pages_released.inc(n)
-            flight.instant(F_WINDOW_RELEASE, n)
-        missing = need - seq.window_fill
-        if missing <= 0:
-            return True
-        try:
-            pages = self._window_arena.alloc(missing)
-        except OutOfPagesError:
-            arena = self._window_arena
-            self._fail(seq, f"window kv arena out of pages (need {missing} "
-                            f"more, {arena.free_pages} free of "
-                            f"{arena.usable_pages})")
+            for pool, held in zip(self._pools, seq.held):
+                pool.grow(seq.slot, held, need, seq.cursor)
+        except OutOfPagesError as e:
+            self._fail(seq, str(e))
             return False
-        fill = seq.window_fill
-        self._window_read_tables[slot, fill:need] = pages
-        self._window_write_tables[slot, fill:need] = pages
-        held.extend(pages)
-        seq.window_fill = need
         return True
 
     def _emit_token(self, seq: _Seq, tok: int) -> bool:
@@ -1035,10 +825,8 @@ class ContinuousScheduler:
             self._radix.note_miss()
             return
         self._radix.note_hit(keep)
-        n = keep // T
-        self._read_tables[seq.slot, :n] = pages[:n]
+        self._pools[0].splice(seq.slot, seq.held[0], pages[:keep // T])
         seq.cached_len = keep
-        seq.table_fill = n
         seq.radix_node = node
         self._n_prefix_hit_tokens += keep
 
@@ -1065,16 +853,8 @@ class ContinuousScheduler:
             seq.state = _PREFILL
             self._slot_seqs[free] = seq
             seq.cached_len = 0
-            seq.owned_pages = []
             seq.radix_node = None
-            seq.table_fill = 0
-            self._read_tables[free, :] = 0
-            self._write_tables[free, :] = 0
-            if self._window:
-                seq.window_pages = deque()
-                seq.window_fill = 0
-                self._window_read_tables[free, :] = 0
-                self._window_write_tables[free, :] = 0
+            seq.held = tuple(SlotPages() for _ in self._pools)
             if self._radix is not None:
                 self._splice_prefix(seq)
                 # a migrated prefix was pinned only so eviction could
@@ -1093,122 +873,6 @@ class ContinuousScheduler:
                 # the signal request-level flush-and-drain cannot produce:
                 # an admission while other sequences are mid-generation
                 self._admitted_mid_flight += 1
-
-    def _record_attn(self, qk: int, cursors: List[int],
-                     idle_rows: int = 0, real: Optional[int] = None) -> None:
-        """Account what the paged attention streamed for one
-        attention-bearing program call (its device time is read from a
-        profiler trace, by the program's name and the kernel's).
-        ``cursors``: the attention cursor of every slot row that attends a
-        K = ``qk`` window; ``idle_rows``: the call's other rows, which the
-        program marks as attending nothing; ``real``: the window's real
-        tokens (a chunk's; default all). Pure host-side mirror arithmetic
-        (``ops.paged_attention.streamed_tokens``) — no device readback on
-        the hot loop. ``attn_tokens_attended`` over ``attn_tokens_fetched``
-        is the block fill share (per layer: every layer that holds pages
-        repeats the same fetches; a model all of whose such layers attend
-        chosen blocks is counted by ``_record_sparse``). The layers that
-        keep a state are counted by kind, a layer-call one layer in one
-        program run: live rows x layers of the one-row update, layer-calls
-        of the chunked scan (and, for 'power-retention', the real tokens
-        they carried). For a model that holds no page the ``attn_*`` counts
-        stay 0: they count pages."""
-        from ray_tpu.ops.paged_attention import streamed_tokens
-
-        cfg = self.cfg
-        rows = len(cursors)
-        if self._n_linear:
-            if qk == 1:
-                self._n_linear_step_rows += self._n_linear * rows
-            else:
-                self._n_linear_chunk_calls += self._n_linear * rows
-        if self._n_retention:
-            if qk == 1:
-                self._n_retention_step_rows += self._n_retention * rows
-            else:
-                calls = self._n_retention * rows
-                self._n_retention_chunk_calls += calls
-                self._n_retention_chunk_tokens += calls * (
-                    qk if real is None else real)
-        if not self._n_paged:
-            return
-        row = cfg.kv_heads * cfg.head_dim * self._kv_itemsize
-        streamed = lambda window: streamed_tokens(
-            self.attn_lane, qk, cursors, idle_rows,
-            cfg.num_heads // cfg.kv_heads, self.page_tokens,
-            self._pages_per_slot, row, window)
-        if self._n_sparse:
-            attended, fetched = self._record_sparse(
-                qk, cursors, qk if real is None else real)
-        else:
-            attended, fetched = streamed(None)
-        self._n_attn_attended += attended
-        self._n_attn_fetched += fetched
-        # k + v pools, every layer that holds pages: the rows read through
-        # the table plus the qk freshly-written rows per slot
-        written = (rows + idle_rows) * qk
-        moved = 2 * (self._n_paged - self._n_window) * row * (
-            fetched + written)
-        if self._n_window:
-            self._record_window(qk, cursors, real)
-            moved += 2 * self._n_window * row * (
-                streamed(self._window)[1] + written)
-        self._n_attn_bytes += moved
-        _m_attn_bytes.inc(moved, labels={"lane": self.attn_lane})
-
-    def _record_window(self, qk: int, cursors: List[int],
-                       real: Optional[int]) -> None:
-        """A model with window layers, one attention-bearing call: what the
-        mask admits, by kind of layer and by work, summed over rows and
-        layers — the keys a decode row reads (``*_step_keys``), the (query,
-        key) pairs of a chunk's ``real`` rows (``*_chunk_pairs``). Host
-        arithmetic on the cursors, no readback (``attn_tokens_*`` stay the
-        full layers' streamed tokens)."""
-        import numpy as np
-
-        w, n_full = self._window, self._n_paged - self._n_window
-        # the position of every real query row: a step's one a row
-        t = (np.asarray(cursors, np.int64)[:, None]
-             + np.arange(1 if qk == 1 else qk if real is None else real))
-        full, seen = int((t + 1).sum()), int(np.minimum(t + 1, w).sum())
-        if qk == 1:
-            self._n_full_step_keys += n_full * full
-            self._n_window_step_keys += self._n_window * seen
-        else:
-            self._n_full_chunk_pairs += n_full * full
-            self._n_window_chunk_pairs += self._n_window * seen
-
-    def _record_sparse(self, qk: int, cursors: List[int], real: int):
-        """The block-selected layers' share of ``_record_attn``: what each
-        query attends is a function of its position alone
-        (``SparseSizes.attended_tokens``), so the host mirrors it. Returns
-        (attended, fetched) token positions a layer, as the paged kernel's
-        are counted (what a row or a query tile may attend over what is
-        streamed for it): a step streams each row's chosen blocks once a
-        K/V group, in whole kernel blocks of the compacted table; a chunk
-        (one row) streams its slot's context up to each tile of 32 queries,
-        which attend their mean choice of it."""
-        import numpy as np
-
-        cfg, sizes, layers = self.cfg, self.cfg.sparse, self._n_sparse
-        # positions [rows, real]: a step's rows, or a chunk's real queries
-        t = np.asarray(cursors)[:, None] + np.arange(real)
-        att = sizes.attended_tokens(t)
-        self._n_sparse_rows += layers * t.size
-        self._n_sparse_rows_dense += layers * int(
-            (t + 1 <= sizes.dense_len).sum())
-        self._n_sparse_attended += layers * int(att.sum())
-        self._n_sparse_context += layers * int((t + 1).sum())
-        if qk == 1:
-            self._n_sparse_step_attended += layers * int(att.sum())
-            self._n_sparse_step_context += layers * int((t + 1).sum())
-            block = self._sparse_step_block
-            return (cfg.kv_heads * int(att.sum()),
-                    cfg.kv_heads * int((-(-att // block)).sum()) * block)
-        starts = np.arange(0, real, 32)
-        ends = np.minimum(starts + 32, real)
-        return (sum(int(att[0, lo:hi].mean()) for lo, hi in zip(starts, ends)),
-                int((-(-(t[0, 0] + ends) // 512)).sum()) * 512)
 
     def _cursors(self):
         """``[slots]`` int32 for a decode or verify call: every seated
@@ -1230,58 +894,19 @@ class ContinuousScheduler:
         return np.fromiter((0 if s is None else s.cursor
                             for s in self._slot_seqs), np.int32, self.slots)
 
-    def _tables(self, slot: Optional[int] = None):
-        """(read, write) page tables for a program: all slots' or one
-        ``slot``'s rows, as COPIES — dispatch is async and an upload may
-        alias (CPU) or still be reading (TPU) the host buffer, while the
-        host frees and hands out pages before anything waits for the
-        program. (None, None) for a model that holds no page: nothing is
-        uploaded. For a model with window layers each of the two is a dict,
-        the full layers' table and the window layers' by the pool's name."""
-        if not self._paged:
-            return None, None
-        rows = slice(None) if slot is None else slot
-        if self._window:  # a pair a pool (``decode.pool_tables``)
-            return tuple(
-                {self._pools[0]: full[rows].copy(),
-                 self._pools[1]: window[rows].copy()}
-                for full, window in (
-                    (self._read_tables, self._window_read_tables),
-                    (self._write_tables, self._window_write_tables)))
-        return (self._read_tables[rows].copy(),
-                self._write_tables[rows].copy())
-
     def _launch(self, out, *, step: bool, chunk: bool, rows: List[_Seq],
                 live_rows: int) -> None:
         """Take over what a paged program returned: the ids stay on the
         device for the next program, the pool is the next program's, and
-        what the host has to read of it later (ids, an expert model's
-        counts) is queued for ``_collect``: one record a program."""
+        what the host has to read of it later (ids, and whatever else it
+        returned: ``_work`` asked for it) is queued for ``_collect``: one
+        record a program."""
         self._ids, self._caches = out[0], out[1]
         self._serial += 1
-        moe = out[2]["counts"] if self._moe else None
-        if rows or moe is not None:
+        if rows or out[2:]:
             self._inflight.append(_Launched(
                 self._serial, self._n_prefill_tokens, step, chunk, out[0],
-                rows, moe, live_rows))
-
-    def _moe_count(self, counts, live_rows: int, step: bool = True) -> None:
-        """Add up one finished program's expert counts (call after a wait
-        on that program: the copy below then waits for nothing). A chunk's
-        program that took the step along tells the two groups' rows apart
-        ([layers, 2, experts]): each is a layer-call of its own, as when
-        they were two programs, the step's only where a row was live
-        (``step``)."""
-        import numpy as np
-
-        c = np.asarray(counts)  # [layers, experts]
-        if c.ndim == 3:
-            c = c[:, :1 + step].reshape(-1, c.shape[-1])
-        self._n_moe_live_rows += live_rows
-        self._n_moe_layer_calls += c.shape[0]
-        self._n_moe_rows_routed += int(c.sum())
-        self._n_moe_experts_hit += int((c > 0).sum())
-        self._n_moe_max_expert_rows += int(c.max(axis=1).sum())
+                rows, out[2:], live_rows))
 
     def _collect(self, n: int) -> bool:
         """Read the ``n`` oldest programs in flight: wait for each (the
@@ -1305,8 +930,8 @@ class ContinuousScheduler:
             self._jax.block_until_ready(rec.ids)
             switch(_P_FETCH)
             ids = np.asarray(rec.ids)
-            if rec.moe is not None:
-                self._moe_count(rec.moe, rec.live_rows, rec.step)
+            if rec.returned:
+                self._work.routed(rec.returned, rec.live_rows, rec.step)
             if rec.step:
                 self._steps_unread -= 1
             if not rec.rows:
@@ -1392,18 +1017,19 @@ class ContinuousScheduler:
         last = not seq.remaining_prompt
         rows = rows or self._no_rows
         live = rows.live
-        step = StepRows(rows.active, self._cursors(), *self._tables(),
-                        rows.temperature, rows.seeds)
+        step = StepRows(rows.active, self._cursors(),
+                        *pool_tables(self._pools), rows.temperature,
+                        rows.seeds)
         self._n_prefill_tokens += real
         self._launch(self._prefill(
             self.params, tokens, np.int32(real), np.int32(seq.cursor),
-            *self._tables(seq.slot), self._caches, self._ids,
+            *pool_tables(self._pools, seq.slot), self._caches, self._ids,
             np.int32(seq.slot if last else -1),
             np.float32(seq.temperature), np.uint32(seq.seed), step,
             np.int32(seq.slot)),
             step=bool(live), chunk=True,
             rows=live + [seq] if last else live, live_rows=real + len(live))
-        self._record_attn(self.prefill_chunk, [seq.cursor], real=real)
+        self._work.record(self.prefill_chunk, [seq.cursor], real=real)
         seq.cursor += real
         self._n_prefill_chunks += 1
         _m_prefill_chunks.inc()
@@ -1434,17 +1060,12 @@ class ContinuousScheduler:
         ins_len = (len(seq.prompt) // T) * T
         if ins_len <= seq.cached_len:
             return
-        n = ins_len // T
-        slot = seq.slot
-        offered = [int(x) for x in self._read_tables[slot, :n]]
+        pool = self._pools[0]
+        offered = [int(x) for x in pool.read[seq.slot, :ins_len // T]]
         dups, node = self._radix.insert(seq.prompt[:ins_len], offered)
         adopted = set(offered) - set(dups)
         if adopted:
-            seq.owned_pages = [p for p in seq.owned_pages
-                               if p not in adopted]
-            for j in range(n):
-                if int(self._write_tables[slot, j]) in adopted:
-                    self._write_tables[slot, j] = 0
+            pool.share(seq.slot, seq.held[0], adopted)
         if node is not None:
             if seq.radix_node is not None:
                 self._radix.release(seq.radix_node)
@@ -1569,18 +1190,12 @@ class ContinuousScheduler:
 
         from ray_tpu.serve._private.affinity import (m_migrated_pages,
                                                      m_migrations)
-        from ray_tpu.serve._private.paging import OutOfPagesError
-
-        T = self.page_tokens
+        T, arena = self.page_tokens, self._radix.arena
         matched = (int(res["matched_len"]) // T) * T
         n = matched // T
         if n <= 0:
             raise ValueError("empty migration payload")
-        try:
-            pages = self._arena.alloc(n)
-        except OutOfPagesError:
-            self._radix.evict(n - self._arena.free_pages)
-            pages = self._arena.alloc(n)
+        pages = self._pools[0].take(n)
         try:
             idx = jnp.asarray(np.asarray(pages, np.int32))
             out = []
@@ -1593,11 +1208,11 @@ class ContinuousScheduler:
             self._caches = out
             dups, node = self._radix.insert(seq.prompt[:matched], pages)
         except BaseException:
-            self._arena.free(pages)
+            arena.free(pages)
             raise
         if dups:
             # spans another sequence cached while we pulled: keep theirs
-            self._arena.free(dups)
+            arena.free(dups)
         if node is not None:
             seq.migration_node = node
         self._n_migrations += 1
@@ -1614,16 +1229,8 @@ class ContinuousScheduler:
         scheduler thread (sole owner of the tree and the donated caches),
         so this enqueues a command and waits. The matched node is pinned
         only for the duration of the gather."""
-        if self.cfg.recurrent:
-            raise ValueError(
-                "a model with layers that keep a state a slot exports no "
-                "prefix: pages alone, if it holds any, do not continue a "
-                "sequence")
-        if self._window:
-            raise ValueError(
-                "a model with 'sliding_attention' layers exports no prefix: "
-                "the full layers' pages alone do not continue a sequence, "
-                "and the window layers' are released behind the window")
+        if self._refused:
+            raise ValueError(self._refused["export"])
         if self._radix is None:
             return {"matched_len": 0, "page_tokens": self.page_tokens,
                     "k": [], "v": []}
@@ -1696,7 +1303,7 @@ class ContinuousScheduler:
         must run the prompt through its own model."""
         if self._drafter.shares_target:
             self._drafter.adopt_from_paged(
-                seq.slot, self._caches, self._read_tables[seq.slot],
+                seq.slot, self._caches, self._pools[0].read[seq.slot],
                 int(seq.cursor), self.page_tokens)
         else:
             self._drafter.prefill_prompt(seq.slot, seq.prompt,
@@ -1791,15 +1398,14 @@ class ContinuousScheduler:
         switch(_P_VERIFY)
         out = self._verify(
             self.params, jnp.asarray(vt), jnp.asarray(used), self._cursors(),
-            jnp.asarray(self._read_tables),
-            jnp.asarray(self._write_tables), self._caches)
+            *pool_tables(self._pools), self._caches)
         self._caches = out[1]
         va = np.asarray(out[0])
         self._n_drains += 1  # read with nothing queued behind it
-        if self._moe:
-            self._moe_count(out[2]["counts"], int(used.sum()))
+        if out[2:]:
+            self._work.routed(out[2:], int(used.sum()))
         switch(_P_EMIT)  # acceptance and emission
-        self._record_attn(K, [s.cursor for s in live],
+        self._work.record(K, [s.cursor for s in live],
                           self.slots - len(live))
         self._n_steps += 1
         _m_steps.inc()
@@ -1884,7 +1490,7 @@ class ContinuousScheduler:
         call over ``live`` (the plain step, or a chunk's program that took
         the rows along): count the call, and move the live rows on by the
         token that is now on its way."""
-        self._record_attn(1, [s.cursor for s in live], self.slots - len(live))
+        self._work.record(1, [s.cursor for s in live], self.slots - len(live))
         if not live:
             return
         if self._steps_unread:
@@ -1908,21 +1514,16 @@ class ContinuousScheduler:
         dispatched or a result read."""
         chunk = self._next_chunk()
         rows = self._step_rows()
-        if self._window and (chunk is not None or rows.live):
-            # a turn's sample, behind its releases and allocations: what the
-            # window layers' pool holds, beside what it would hold of the
-            # same sequences had nothing been released
-            T = self.page_tokens
-            self._n_window_tokens_held += T * self._window_arena.pages_in_use
-            self._n_window_tokens_unreleased += T * sum(
-                s.window_fill for s in self._slot_seqs if s is not None)
+        if chunk is not None or rows.live:
+            self._work.sample(self._pools)
         if chunk is not None:
             self._dispatch_chunk(*chunk, rows)
         elif rows.live:
-            # the tables go up as COPIES (see _tables)
+            # the tables go up as COPIES (``paging.pool_tables``)
             self._launch(self._step(
                 self.params, self._ids, rows.active, self._cursors(),
-                *self._tables(), self._caches, rows.temperature, rows.seeds),
+                *pool_tables(self._pools), self._caches, rows.temperature,
+                rows.seeds),
                 step=True, chunk=False, rows=rows.live,
                 live_rows=len(rows.live))
             self._stepped(rows.live)
@@ -2126,63 +1727,9 @@ class ContinuousScheduler:
         out["page_tokens"] = self.page_tokens
         out["pages_per_slot"] = self._pages_per_slot
         out["attn_lane"] = self.attn_lane
-        out["attn_bytes_moved"] = self._n_attn_bytes
-        out["attn_tokens_attended"] = self._n_attn_attended
-        out["attn_tokens_fetched"] = self._n_attn_fetched
-        if self._state_bytes:
-            # a float32 state a slot a layer that keeps one, by kind
-            # (``transformer.state_shapes``)
-            out["state_slots"] = self.slots
-            out["state_bytes"] = self._state_bytes
-        if self._n_linear:
-            # layer-calls of the chunked scan; live rows x layers of the
-            # one-row update
-            out["linear_chunk_calls"] = self._n_linear_chunk_calls
-            out["linear_step_rows"] = self._n_linear_step_rows
-        if self._n_retention:
-            # the same of the 'power-retention' layers, and the real tokens
-            # their chunk calls carried
-            out["retention_chunk_calls"] = self._n_retention_chunk_calls
-            out["retention_chunk_tokens"] = self._n_retention_chunk_tokens
-            out["retention_step_rows"] = self._n_retention_step_rows
-        if self._n_sparse:
-            # query rows x 'minicpm4' layers (real tokens of a chunk, live
-            # rows of a step), those at or under dense_len, and the tokens
-            # of the blocks they attended over the tokens of their contexts
-            out["sparse_rows"] = self._n_sparse_rows
-            out["sparse_rows_dense"] = self._n_sparse_rows_dense
-            out["sparse_tokens_attended"] = self._n_sparse_attended
-            out["sparse_tokens_context"] = self._n_sparse_context
-            # of which a step's rows (the rest are a chunk's queries)
-            out["sparse_step_tokens_attended"] = self._n_sparse_step_attended
-            out["sparse_step_tokens_context"] = self._n_sparse_step_context
-        if self._moe:
-            # the device's per-expert row counts, summed a layer-call
-            # (one expert layer in one program run) as of the last
-            # fetch; rows_routed == live_rows x top_k x layers exactly:
-            # no row is dropped
-            out["moe_live_rows"] = self._n_moe_live_rows
-            out["moe_layer_calls"] = self._n_moe_layer_calls
-            out["moe_rows_routed"] = self._n_moe_rows_routed
-            out["moe_experts_hit"] = self._n_moe_experts_hit
-            out["moe_max_expert_rows"] = self._n_moe_max_expert_rows
-        out.update(self._arena.stats())
-        if self._window:
-            arena = self._window_arena.stats()
-            # by pool: the full layers' pages (``kv_pages``: the keys above
-            # too) and the window layers', whose pool the scheduler sized
-            out["kv_pages_in_use_full"] = out["pages_in_use"]
-            out["kv_peak_pages_in_use_full"] = out["peak_pages_in_use"]
-            out["kv_pages_in_use_window"] = arena["pages_in_use"]
-            out["kv_peak_pages_in_use_window"] = arena["peak_pages_in_use"]
-            out["window_pages_released"] = self._n_window_released
-            out["window_tokens_held"] = self._n_window_tokens_held
-            out["window_tokens_unreleased"] = self._n_window_tokens_unreleased
-            # what the mask admits, by kind and by work (``_record_window``)
-            out["window_attn_step_keys"] = self._n_window_step_keys
-            out["full_attn_step_keys"] = self._n_full_step_keys
-            out["window_attn_chunk_pairs"] = self._n_window_chunk_pairs
-            out["full_attn_chunk_pairs"] = self._n_full_chunk_pairs
+        # the kinds' counts and the pools' pages: merged unread
+        out.update(self._work.stats())
+        out.update(pool_stats(self._pools))
         # 0 without a prefix cache: no prompt token was served from one
         out["prefix_hit_tokens"] = self._n_prefix_hit_tokens
         if self._radix is not None:

@@ -1,4 +1,4 @@
-"""Paged KV arena allocator + prefix/radix cache (ISSUE 13).
+"""Paged KV arena allocator, a model's page pools + prefix/radix cache.
 
 Host-side bookkeeping for the paged slot arena in ``models.decode``:
 
@@ -19,7 +19,12 @@ Host-side bookkeeping for the paged slot arena in ``models.decode``:
     unreachable-from-root once evicted, so leaves go first and parents
     become evictable as their subtrees drain).
 
-Both are single-thread structures: the continuous scheduler owns them and
+  * ``PagePool`` — one pool as the scheduler drives it: an arena, every
+    slot's host page tables, the window the pool keeps. ``build_pools``
+    makes a model's pools from its layers' kinds and ``cannot_continue``
+    says what those kinds forbid.
+
+All are single-thread structures: the continuous scheduler owns them and
 touches them only from its own loop thread (admission validation in
 ``submit`` is pure arithmetic and reads no allocator state).
 """
@@ -27,7 +32,8 @@ touches them only from its own loop thread (admission validation in
 from __future__ import annotations
 
 import time
-from typing import Dict, List, Optional, Tuple
+from collections import deque
+from typing import Collection, Dict, List, Optional, Tuple
 
 from ray_tpu._private import flight
 from ray_tpu._private.metrics import Counter, Gauge
@@ -69,21 +75,19 @@ class OutOfPagesError(RuntimeError):
 class PageArena:
     """Free-list allocator over the paged KV pool. Page ids are indices
     into the device-side ``PagedKVCache`` pools; page 0 never leaves the
-    allocator (it is the shared garbage page). ``pageless``: the arena of a
-    model none of whose layers holds a page — it may be the reserved page
-    alone, hands nothing out and reads 0 wherever pages are counted.
-    ``pool``: the name of a SECOND arena of one scheduler (the window
-    layers' pool, 'window'), which labels what it puts in the process's
-    metrics; the one arena every scheduler has carries no label, as ever."""
+    allocator (it is the shared garbage page). ``pool``: the name of a
+    SECOND arena of one scheduler (the window layers' pool, 'window'),
+    which labels what it puts in the process's metrics; the one arena every
+    scheduler has carries no label, as ever."""
 
     def __init__(self, num_pages: int, page_tokens: int,
-                 pageless: bool = False, pool: Optional[str] = None):
+                 pool: Optional[str] = None):
         if page_tokens < 1:
             # the PR-8/PR-9 falsy-zero lesson: an explicit 0 must raise
             # here, never silently become some default upstream
             raise ValueError(
                 f"page_tokens must be >= 1, got {page_tokens}")
-        if num_pages < 2 and not pageless:
+        if num_pages < 2:
             raise ValueError(
                 f"kv arena needs >= 2 pages (page 0 is reserved), "
                 f"got {num_pages}")
@@ -133,7 +137,7 @@ class PageArena:
         flight.instant(F_PAGE_ALLOC, n)
         return pages
 
-    def free(self, pages: List[int]) -> None:
+    def free(self, pages: Collection[int]) -> None:
         for p in pages:
             if p == GARBAGE_PAGE:
                 raise ValueError("page 0 is reserved and never allocated")
@@ -158,6 +162,233 @@ class PageArena:
             "pages_freed_total": self._freed_total,
             "peak_pages_in_use": self._peak_in_use,
         }
+
+
+class SlotPages:
+    """What one seated sequence holds of one pool: the pages it must free,
+    oldest first, and the logical pages its view covers (``fill``). Under a
+    window the pages are the logical pages ``[fill - len(pages), fill)``;
+    under the prefix cache the view may begin with shared pages. Made when
+    the sequence takes its slot, whose rows are zero since ``free``."""
+
+    __slots__ = ("pages", "fill")
+
+    def __init__(self):
+        self.pages: deque = deque()
+        self.fill = 0
+
+
+class PagePool:
+    """One pool of pages as the scheduler drives it: an arena; every slot's
+    host page tables (logical page j of slot s lives at physical page
+    ``read[s, j]``; 0 is the garbage page: unallocated reads are causally
+    masked, redirected writes are absorbed); the ``name`` its tables go
+    under in a program's arguments (``decode.pool_of``); the ``window`` it
+    keeps (None: everything; else a slot holds the pages its window still
+    covers and no more, whatever the context); ``radix``, the prefix cache
+    over this arena, asked for pages before a sequence fails (or None)."""
+
+    def __init__(self, name: str, num_pages: int, page_tokens: int,
+                 slots: int, pages_per_slot: int,
+                 window: Optional[int] = None):
+        import numpy as np
+
+        self.name = name
+        self.window = window
+        self.label = "full" if window is None else "window"
+        self.arena = PageArena(num_pages, page_tokens,
+                               pool=None if window is None else self.label)
+        self.radix: Optional["RadixCache"] = None
+        self.read = np.zeros((slots, pages_per_slot), np.int32)
+        self.write = np.zeros_like(self.read)
+        self.released = 0  # pages given back from behind a window
+        # logical pages in the seated slots' views, released ones among them
+        self.filled = 0
+        # tokens of the longest sequence the pool can hold alone: all its
+        # pages', or, releasing behind a window, its table's whole range
+        self.longest = page_tokens * (
+            num_pages - 1 if window is None else pages_per_slot)
+
+    def grow(self, slot: int, held: SlotPages, need: int,
+             cursor: int) -> None:
+        """Grow the slot's view to ``need`` logical pages. Under a window,
+        first give back the pages wholly behind the window of the next row
+        the slot's programs run (``cursor``: every program dispatched so far
+        took COPIES of the tables, and the device runs them first); their
+        entries point at the garbage page and the kernel's walk starts
+        behind them. Raises ``OutOfPagesError`` as ``take`` does."""
+        if self.window is not None:
+            front = held.fill - len(held.pages)
+            n = min(max(cursor - self.window + 1, 0)
+                    // self.arena.page_tokens - front, len(held.pages))
+            if n > 0:
+                self.arena.free([held.pages.popleft() for _ in range(n)])
+                self.read[slot, front:front + n] = 0
+                self.write[slot, front:front + n] = 0
+                self.released += n
+                m_window_pages_released.inc(n)
+                flight.instant(F_WINDOW_RELEASE, n)
+        missing = need - held.fill
+        if missing <= 0:
+            return
+        pages = self.take(missing)
+        self.read[slot, held.fill:need] = pages
+        self.write[slot, held.fill:need] = pages
+        held.pages.extend(pages)
+        self.filled += missing
+        held.fill = need
+
+    def take(self, n: int) -> List[int]:
+        """``n`` pages of the arena, the prefix cache evicting LRU
+        unreferenced nodes for them under pressure; or ``OutOfPagesError``
+        (its message is a failed sequence's) and nothing allocated."""
+        arena = self.arena
+        if arena.free_pages < n and self.radix is not None:
+            self.radix.evict(n - arena.free_pages)
+        if arena.free_pages < n:
+            said = (f"need {n} more, {arena.free_pages} free of "
+                    f"{arena.usable_pages}")
+            raise OutOfPagesError(
+                f"kv arena out of pages ({said}; nothing evictable)"
+                if self.window is None else
+                f"window kv arena out of pages ({said})")
+        return arena.alloc(n)
+
+    def splice(self, slot: int, held: SlotPages, pages: List[int]) -> None:
+        """Begin a fresh view with shared ``pages`` of the prefix cache:
+        read entries only (shared pages are immutable: the write entries
+        stay on the garbage page), and nothing the sequence must free."""
+        self.read[slot, :len(pages)] = pages
+        self.filled += len(pages)
+        held.fill = len(pages)
+
+    def share(self, slot: int, held: SlotPages, adopted: set) -> None:
+        """The prefix cache adopted pages of the slot: the sequence no
+        longer frees them and nothing writes them again."""
+        held.pages = deque(p for p in held.pages if p not in adopted)
+        row = self.write[slot]
+        for j in range(held.fill):
+            if int(row[j]) in adopted:
+                row[j] = 0
+
+    def free(self, slot: int, held: SlotPages) -> None:
+        """A sequence leaves ``slot``: its pages back to the arena, its
+        rows cleared (an inactive slot's write lands on the garbage page)."""
+        self.arena.free(held.pages)
+        held.pages.clear()
+        self.filled -= held.fill
+        held.fill = 0
+        self.read[slot, :] = 0
+        self.write[slot, :] = 0
+
+
+def build_pools(cfg, *, slots: int, page_tokens: int, pages_per_slot: int,
+                num_pages: int, prefill_chunk: int) -> Tuple[PagePool, ...]:
+    """A model's pools, from its layers' kinds and the scheduler's sizes:
+    none where no layer holds a page, else one a distinct
+    ``decode.pool_of(kind)``, first the pool that keeps everything, of
+    ``num_pages`` (``kv_pages`` means this pool). A pool with a window is
+    sized by what a slot can hold of it at once — the window behind a
+    chunk's first row, the chunk, and a page for where the two begin inside
+    one — times ``slots``, plus the garbage page: no option sets it."""
+    from ray_tpu.models.decode import pool_of
+    from ray_tpu.models.transformer import STATE_KINDS
+
+    pools: Dict[str, PagePool] = {}
+    for kind in cfg.kinds:
+        if kind in STATE_KINDS or pool_of(kind) in pools:
+            continue
+        window, pages = cfg.window(kind), num_pages
+        if window is not None:
+            pages = 1 + slots * min(
+                pages_per_slot,
+                -(-(window + prefill_chunk) // page_tokens) + 1)
+        pools[pool_of(kind)] = PagePool(pool_of(kind), pages, page_tokens,
+                                        slots, pages_per_slot, window)
+    return tuple(sorted(pools.values(),
+                        key=lambda pool: pool.window is not None))
+
+
+def pool_tables(pools: Tuple[PagePool, ...], slot: Optional[int] = None):
+    """(read, write) page tables for a program, of all slots or of one, as
+    COPIES (the host frees and hands out pages while a program that took
+    them is in flight): (None, None) for a model that holds no page (nothing
+    is uploaded), two arrays for a model of one pool, else each of the two a
+    dict by the pool's name (``decode.pool_tables`` picks a layer's)."""
+    if not pools:
+        return None, None
+    rows = slice(None) if slot is None else slot
+    pairs = [(pool.read[rows].copy(), pool.write[rows].copy())
+             for pool in pools]
+    if len(pools) == 1:
+        return pairs[0]
+    return tuple({pool.name: pair[i] for pool, pair in zip(pools, pairs)}
+                 for i in range(2))
+
+
+def pool_stats(pools: Tuple[PagePool, ...]) -> Dict[str, int]:
+    """What ``stats()`` shows of a model's pools: the first pool's arena
+    under the keys every scheduler has (for a model that holds no page the
+    reserved page alone and a true 0 elsewhere); where there are two, each
+    one's pages by its label; what a pool with a window released."""
+    if not pools:
+        return dict(num_pages=1, usable_pages=0, pages_in_use=0, pages_free=0,
+                    pages_allocated_total=0, pages_freed_total=0,
+                    peak_pages_in_use=0)
+    out = pools[0].arena.stats()
+    for pool in pools:
+        if len(pools) > 1:
+            arena = pool.arena.stats()
+            out["kv_pages_in_use_" + pool.label] = arena["pages_in_use"]
+            out["kv_peak_pages_in_use_" + pool.label] = arena[
+                "peak_pages_in_use"]
+        if pool.window is not None:
+            out["window_pages_released"] = pool.released
+    return out
+
+
+# why a model's sequences cannot be continued from pages alone -> what would
+# need that -> the message that refuses it
+_REFUSALS = {"state": {
+    "prefix_cache": (
+        "prefix_cache=True cannot serve a model with layers that keep a "
+        "state a slot ('lightning-attn', 'power-retention'): their state at "
+        "a prefix's end is not kept"),
+    "drafter": (
+        "speculative decoding cannot serve a model with layers that keep a "
+        "state a slot ('lightning-attn', 'power-retention'): a rejected "
+        "draft would have to rewind their states"),
+    "export": (
+        "a model with layers that keep a state a slot exports no prefix: "
+        "pages alone, if it holds any, do not continue a sequence"),
+}, "window": {
+    "prefix_cache": (
+        "prefix_cache=True cannot serve a model with 'sliding_attention' "
+        "layers: a spliced prefix would need their last window of it kept "
+        "too, and they release it"),
+    "drafter": (
+        "speculative decoding cannot serve a model with 'sliding_attention' "
+        "layers: a rejected draft would need the pages released behind it "
+        "back"),
+    "export": (
+        "a model with 'sliding_attention' layers exports no prefix: the full "
+        "layers' pages alone do not continue a sequence, and the window "
+        "layers' are released behind the window"),
+}}
+
+
+def cannot_continue(cfg, pools: Tuple[PagePool, ...]
+                    ) -> Optional[Dict[str, str]]:
+    """Why a sequence of this model cannot be continued from pages alone, as
+    the message for each thing that would need it ('prefix_cache',
+    'drafter', 'export'): a layer keeps a state a slot (what a token leaves
+    in it cannot be cut at a page boundary or rewound, and no snapshot is
+    kept), or a pool releases pages behind a window. None where it can."""
+    if cfg.recurrent:
+        return _REFUSALS["state"]
+    if any(pool.window is not None for pool in pools):
+        return _REFUSALS["window"]
+    return None
 
 
 class _RadixNode:

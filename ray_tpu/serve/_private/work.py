@@ -1,0 +1,219 @@
+"""What the scheduler's programs do, counted on the host by layer kind.
+
+``Work`` is built once from the model's config and the scheduler's sizes;
+the scheduler tells it of every attention-bearing program call
+(``record``), hands it what an expert model's programs return beside ids
+and caches (``routed``) and lets it sample the pools a turn (``sample``);
+``stats()`` is the kinds' share of ``scheduler_stats()``. Host-side mirror
+arithmetic on cursors and shapes, no device readback on the hot loop: a
+program's device time is read from a profiler trace, by its name.
+
+A kind's counters are said once, in ``_KINDS``: the function that counts one
+call of the kind's layers (a layer-call: one layer in one program run) and
+the keys it owns, which a model shows if and only if it has layers of the
+kind; ``attn_*`` always (they count pages: a true 0 for a model without).
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Dict, List, Optional
+
+import numpy as np
+
+from ray_tpu._private.metrics import Counter
+from ray_tpu.models.transformer import (LINEAR, RETENTION, SLIDING, SPARSE,
+                                        STATE_KINDS, state_shapes)
+from ray_tpu.ops.paged_attention import streamed_tokens, tile_sizes
+
+_m_attn_bytes = Counter(
+    "ray_tpu_serve_attn_bytes_moved_total",
+    "KV-cache bytes the paged attention lane streamed per program call "
+    "(host-side mirror arithmetic, labelled by implementation: whole "
+    "blocks of pages up to each sequence's cursor)")
+
+
+def _linear(work, n, layers, qk, cursors, real):
+    """Layer-calls of the chunked scan; live rows x layers of the one-row
+    update."""
+    n["linear_step_rows" if qk == 1 else "linear_chunk_calls"] += (
+        layers * len(cursors))
+
+
+def _retention(work, n, layers, qk, cursors, real):
+    """As ``_linear``, and the real tokens the chunk calls carried."""
+    calls = layers * len(cursors)
+    if qk == 1:
+        n["retention_step_rows"] += calls
+    else:
+        n["retention_chunk_calls"] += calls
+        n["retention_chunk_tokens"] += calls * real
+
+
+def _sparse(work, n, layers, qk, cursors, real):
+    """Block-selected layers: what each query attends is a function of its
+    position alone (``SparseSizes.attended_tokens``). Query rows x layers
+    (real tokens of a chunk, live rows of a step), those at or under
+    ``dense_len``, the tokens of the blocks they attended over the tokens of
+    their contexts, and of both a step's share. Returns (attended, fetched)
+    a layer as the paged kernel's are counted: a step streams each row's
+    chosen blocks once a K/V group, in whole kernel blocks of the compacted
+    table; a chunk (one row) streams its slot's context up to each tile of
+    32 queries, which attend their mean choice of it."""
+    cfg, sizes = work.cfg, work.cfg.sparse
+    t = np.asarray(cursors)[:, None] + np.arange(real)  # [rows, real]
+    att = sizes.attended_tokens(t)
+    attended, context = int(att.sum()), int((t + 1).sum())
+    n["sparse_rows"] += layers * t.size
+    n["sparse_rows_dense"] += layers * int((t + 1 <= sizes.dense_len).sum())
+    n["sparse_tokens_attended"] += layers * attended
+    n["sparse_tokens_context"] += layers * context
+    if qk == 1:
+        n["sparse_step_tokens_attended"] += layers * attended
+        n["sparse_step_tokens_context"] += layers * context
+        block = work.sparse_step_block
+        return (cfg.kv_heads * attended,
+                cfg.kv_heads * int((-(-att // block)).sum()) * block)
+    starts = np.arange(0, real, 32)
+    ends = np.minimum(starts + 32, real)
+    return (sum(int(att[0, lo:hi].mean()) for lo, hi in zip(starts, ends)),
+            int((-(-(t[0, 0] + ends) // 512)).sum()) * 512)
+
+
+def _window(work, n, layers, qk, cursors, real):
+    """Window layers beside full ones: what the mask admits, by kind of
+    layer and by work, summed over rows and layers — the keys a decode row
+    reads (``*_step_keys``), the (query, key) pairs of a chunk's real rows
+    (``*_chunk_pairs``)."""
+    t = np.asarray(cursors)[:, None] + np.arange(real)  # [rows, real]
+    what = "step_keys" if qk == 1 else "chunk_pairs"
+    n["full_attn_" + what] += (work.paged_layers - layers) * int(
+        (t + 1).sum())
+    n["window_attn_" + what] += layers * int(
+        np.minimum(t + 1, work.cfg.sliding_window).sum())
+
+
+# kind -> (what counts one call of its layers, the keys it owns); the last
+# two of the window kind's are ``sample``'s
+_KINDS = {
+    LINEAR: (_linear, ("linear_chunk_calls", "linear_step_rows")),
+    RETENTION: (_retention, ("retention_chunk_calls",
+                             "retention_chunk_tokens", "retention_step_rows")),
+    SPARSE: (_sparse, ("sparse_rows", "sparse_rows_dense",
+                       "sparse_tokens_attended", "sparse_tokens_context",
+                       "sparse_step_tokens_attended",
+                       "sparse_step_tokens_context")),
+    SLIDING: (_window, ("window_attn_step_keys", "full_attn_step_keys",
+                        "window_attn_chunk_pairs", "full_attn_chunk_pairs",
+                        "window_tokens_held", "window_tokens_unreleased")),
+}
+_ATTN = ("attn_bytes_moved", "attn_tokens_attended", "attn_tokens_fetched")
+_EXPERTS = ("moe_live_rows", "moe_layer_calls", "moe_rows_routed",
+            "moe_experts_hit", "moe_max_expert_rows")
+
+
+class Work:
+    """The counters of one scheduler. ``lane``: the paged-attention
+    implementation that runs (``resolve_impl``'s answer); ``itemsize``: the
+    bytes of one element of the K/V pools."""
+
+    def __init__(self, cfg, *, slots: int, page_tokens: int,
+                 pages_per_slot: int, lane: str, itemsize: int):
+        self.cfg, self.lane = cfg, lane
+        self._page_tokens, self._pages_per_slot = page_tokens, pages_per_slot
+        kinds = cfg.kinds
+        self._kinds = [(_KINDS[kind][0], kinds.count(kind))
+                       for kind in _KINDS if kind in kinds]
+        self.paged_layers = sum(kind not in STATE_KINDS for kind in kinds)
+        self._window_layers = kinds.count(SLIDING)
+        # all kv heads of one token's K (or V) row
+        self._row_bytes = cfg.kv_heads * cfg.head_dim * itemsize
+        if SPARSE in kinds:
+            # tokens of one block of the step's kernel over a row's table
+            # of chosen pages (``sparse_attention._step_attention``)
+            sizes = cfg.sparse
+            self.sparse_step_block = page_tokens * tile_sizes(
+                1, cfg.num_heads // cfg.kv_heads, page_tokens,
+                sizes.max_chosen_blocks() * sizes.pages_per_block,
+                self._row_bytes)[0]
+        # a float32 state a slot a layer that keeps one, by kind
+        state_bytes = 4 * sum(
+            math.prod(shape) for kind in kinds if kind in STATE_KINDS
+            for shape in state_shapes(cfg, kind, slots).values())
+        # an expert model's programs are asked for the rows each expert
+        # received (``moe_info``), handed to ``routed``
+        self.counts_experts = cfg.mlp == "moe"
+        self._n = dict.fromkeys(
+            _ATTN + tuple(key for kind in _KINDS if kind in kinds
+                          for key in _KINDS[kind][1])
+            + (_EXPERTS if self.counts_experts else ()), 0)
+        if state_bytes:
+            self._n.update(state_slots=slots, state_bytes=state_bytes)
+
+    def record(self, qk: int, cursors: List[int], idle_rows: int = 0,
+               real: Optional[int] = None) -> None:
+        """One attention-bearing program call: a K = ``qk`` window for every
+        row of ``cursors`` (its attention cursor), ``idle_rows`` other rows
+        that attend nothing, ``real`` real tokens of the window (a chunk's;
+        default all). Every kind the model has counts its own; then what
+        the call streamed through the page tables: ``attn_tokens_attended``
+        over ``attn_tokens_fetched`` is the block fill share of the layers
+        without a window (per layer: every such layer repeats the same
+        fetches; where a kind chooses its blocks, as that kind says),
+        ``attn_bytes_moved`` the K and V rows read through the tables plus
+        the ``qk`` freshly written rows a slot, over every layer that holds
+        pages."""
+        n, real, streamed = self._n, qk if real is None else real, None
+        for count, layers in self._kinds:
+            streamed = count(self, n, layers, qk, cursors, real) or streamed
+        if not self.paged_layers:
+            return
+        row = self._row_bytes
+        dense = lambda window: streamed_tokens(
+            self.lane, qk, cursors, idle_rows,
+            self.cfg.num_heads // self.cfg.kv_heads, self._page_tokens,
+            self._pages_per_slot, row, window)
+        attended, fetched = streamed or dense(None)
+        n["attn_tokens_attended"] += attended
+        n["attn_tokens_fetched"] += fetched
+        written = (len(cursors) + idle_rows) * qk
+        windowed = self._window_layers
+        moved = 2 * (self.paged_layers - windowed) * row * (fetched + written)
+        if windowed:
+            moved += 2 * windowed * row * (
+                dense(self.cfg.sliding_window)[1] + written)
+        n["attn_bytes_moved"] += moved
+        _m_attn_bytes.inc(moved, labels={"lane": self.lane})
+
+    def routed(self, returned: tuple, live_rows: int,
+               step: bool = True) -> None:
+        """Add up one finished program's expert counts, the first of what it
+        ``returned`` beside ids and caches (call after a wait on that
+        program: the copy below then waits for nothing), beside the
+        ``live_rows`` the host handed it: ``moe_rows_routed`` == live rows x
+        top-k x layers exactly, or a row was dropped. A chunk's program that
+        took the step along tells the two groups' rows apart ([layers, 2,
+        experts]): each is a layer-call of its own, the step's only where a
+        row was live (``step``)."""
+        c = np.asarray(returned[0]["counts"])  # [layers, experts]
+        if c.ndim == 3:
+            c = c[:, :1 + step].reshape(-1, c.shape[-1])
+        n = self._n
+        n["moe_live_rows"] += live_rows
+        n["moe_layer_calls"] += c.shape[0]
+        n["moe_rows_routed"] += int(c.sum())
+        n["moe_experts_hit"] += int((c > 0).sum())
+        n["moe_max_expert_rows"] += int(c.max(axis=1).sum())
+
+    def sample(self, pools) -> None:
+        """A turn's sample, behind its releases and allocations: the tokens
+        a pool with a window holds, beside those it would hold of the same
+        sequences had nothing been released."""
+        for pool in pools:
+            if pool.window is not None:
+                T = pool.arena.page_tokens
+                self._n["window_tokens_held"] += T * pool.arena.pages_in_use
+                self._n["window_tokens_unreleased"] += T * pool.filled
+
+    def stats(self) -> Dict[str, int]:
+        return dict(self._n)

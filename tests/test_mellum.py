@@ -580,7 +580,7 @@ def test_the_scheduler_serves_both_pools_and_releases_behind_the_window():
             assert logits.max() - logits[tok] <= TOL * np.abs(want).max()
     a_slot = -(-(cfg.sliding_window + C) // T) + 1
     assert a_slot == 11
-    assert sched._window_arena.usable_pages == slots * a_slot
+    assert sched._pools[1].arena.usable_pages == slots * a_slot
     assert 0 < stats["kv_peak_pages_in_use_window"] <= slots * a_slot
     # the longest context alone is more pages than a window slot may hold
     assert stats["kv_peak_pages_in_use_full"] > -(-81 // T) > a_slot

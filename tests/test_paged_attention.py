@@ -580,25 +580,21 @@ class TestLaneResolution:
 
 
 class TestAttnCounters:
-    """``_record_attn`` adds up what ``streamed_tokens`` says each
+    """``Work.record`` adds up what ``streamed_tokens`` says each
     implementation fetches, in whole blocks of ``tile_sizes`` pages,
-    against what is attended."""
+    against what is attended; and every other kind of layer counts its own
+    (``work._KINDS``), under keys a model shows only if it has the kind."""
 
     @staticmethod
-    def _scheduler(lane):
-        import types
+    def _work(lane, cfg=None, **sizes):
+        from ray_tpu.models import llama_debug
+        from ray_tpu.serve._private.work import Work
 
-        from ray_tpu.serve._private.continuous import ContinuousScheduler
-
-        sch = object.__new__(ContinuousScheduler)  # the counters only
-        sch.cfg = types.SimpleNamespace(num_heads=32, kv_heads=8,
-                                        head_dim=128, num_layers=2)
-        sch.attn_lane = lane
-        sch.page_tokens, sch._pages_per_slot, sch._kv_itemsize = 16, 256, 2
-        sch._n_attn_bytes = sch._n_attn_attended = sch._n_attn_fetched = 0
-        sch._n_linear, sch._n_sparse, sch._n_paged = 0, 0, 2  # by kind
-        sch._n_retention = sch._n_window = 0
-        return sch
+        # Mistral-7B's heads over two layers, bf16 pools
+        cfg = cfg or llama_debug(embed_dim=4096, num_heads=32, num_kv_heads=8)
+        sizes = {"slots": 5, "page_tokens": 16, "pages_per_slot": 256,
+                 "itemsize": 2, **sizes}
+        return Work(cfg, lane=lane, **sizes)
 
     @pytest.mark.parametrize("lane,attended,fetched", [
         # blocks of 32 pages = 512 tokens: 1 + 2 + 2 of the live rows' own
@@ -608,23 +604,99 @@ class TestAttnCounters:
         ("reference", 11 + 601 + 1024, 5 * 2 * 512),
     ])
     def test_decode_step(self, lane, attended, fetched):
-        sch = self._scheduler(lane)
-        sch._record_attn(1, [10, 600, 1023], idle_rows=2)
-        assert sch._n_attn_attended == attended
-        assert sch._n_attn_fetched == fetched
+        work = self._work(lane)
+        work.record(1, [10, 600, 1023], idle_rows=2)
         # K and V, both layers, 2048 bytes a token row; + the 5 new rows
-        assert sch._n_attn_bytes == 2 * 2 * 2048 * (fetched + 5)
+        assert work.stats() == {
+            "attn_tokens_attended": attended, "attn_tokens_fetched": fetched,
+            "attn_bytes_moved": 2 * 2 * 2048 * (fetched + 5)}
 
     def test_prefill_chunk_streams_once_per_query_tile(self):
         """A 512-token chunk at G = 4 is four query tiles of 128 tokens;
         each streams the blocks up to its own last position."""
-        sch = self._scheduler("pallas")
-        sch._record_attn(512, [700])
-        assert sch._n_attn_attended == 828 + 956 + 1084 + 1212
-        assert sch._n_attn_fetched == (2 + 2 + 3 + 3) * 512
-        ref = self._scheduler("reference")
-        ref._record_attn(512, [700])
-        assert (ref._n_attn_attended, ref._n_attn_fetched) == (1212, 3 * 512)
+        got = {}
+        for lane in ("pallas", "reference"):
+            work = self._work(lane)
+            work.record(512, [700])
+            got[lane] = work.stats()
+        assert got["pallas"]["attn_tokens_attended"] == 828 + 956 + 1084 + 1212
+        assert got["pallas"]["attn_tokens_fetched"] == (2 + 2 + 3 + 3) * 512
+        assert (got["reference"]["attn_tokens_attended"],
+                got["reference"]["attn_tokens_fetched"]) == (1212, 3 * 512)
+
+    @pytest.mark.parametrize("kind", [
+        "lightning-attn", "power-retention", "minicpm4", "sliding_attention",
+        "experts"])
+    def test_a_kind_counts_its_own_calls(self, kind):
+        """A step over three live rows (cursors 10, 40, 99) beside one idle
+        one, then a chunk of 16 tokens, 13 of them real, at position 32: the
+        identities PERF.md 3 states in words, a kind a case."""
+        from ray_tpu.models import (brumby_debug, mellum_debug,
+                                    minicpm_sala_debug)
+
+        preset = {"power-retention": brumby_debug,
+                  "sliding_attention": mellum_debug,
+                  "experts": mellum_debug}.get(kind, minicpm_sala_debug)
+        cfg = preset()
+        layers = cfg.kinds.count(kind)
+        work = self._work("reference", cfg, slots=4, page_tokens=4,
+                          pages_per_slot=64, itemsize=4)
+        cursors = [10, 40, 99]
+        work.record(1, cursors, idle_rows=1)
+        work.record(16, [32], real=13)
+        got = work.stats()
+        chunk = np.arange(32, 32 + 13)
+        if kind == "lightning-attn":
+            assert got["linear_step_rows"] == layers * 3 == 18
+            assert got["linear_chunk_calls"] == layers
+            assert got["state_slots"] == 4 and got["state_bytes"] > 0
+        elif kind == "power-retention":
+            assert got["retention_step_rows"] == layers * 3
+            assert got["retention_chunk_calls"] == layers
+            assert got["retention_chunk_tokens"] == layers * 13
+            # no layer holds a page: the counts of pages read a true 0
+            assert (got["attn_tokens_fetched"], got["attn_bytes_moved"]) == (
+                0, 0)
+        elif kind == "minicpm4":
+            at = np.concatenate([cursors, chunk])
+            assert got["sparse_rows"] == layers * (3 + 13)
+            assert got["sparse_rows_dense"] == layers * int(
+                (at + 1 <= cfg.sparse.dense_len).sum())
+            assert got["sparse_tokens_context"] == layers * int(
+                (at + 1).sum())
+            assert got["sparse_step_tokens_context"] == layers * (11 + 41 + 100)
+            assert 0 < got["sparse_tokens_attended"] < got[
+                "sparse_tokens_context"]
+        elif kind == "sliding_attention":
+            w, full = cfg.sliding_window, len(cfg.kinds) - layers
+            assert got["window_attn_step_keys"] == layers * sum(
+                min(c + 1, w) for c in cursors)
+            assert got["full_attn_step_keys"] == full * sum(
+                c + 1 for c in cursors)
+            assert got["window_attn_chunk_pairs"] == layers * int(
+                np.minimum(chunk + 1, w).sum())
+            assert got["full_attn_chunk_pairs"] == full * int(
+                (chunk + 1).sum())
+        else:
+            # a chunk's program that took a step along: two groups a layer
+            counts = np.zeros((8, 2, cfg.moe_num_experts), np.int32)
+            counts[:, 0, :3], counts[:, 1, 5] = 13, 9
+            assert work.counts_experts
+            work.routed(({"counts": counts},), 16)
+            got = work.stats()
+            assert got["moe_rows_routed"] == counts.sum() == 8 * (39 + 9)
+            assert got["moe_layer_calls"] == 16
+            assert got["moe_experts_hit"] == 8 * 4
+            assert got["moe_max_expert_rows"] == 8 * (13 + 9)
+            assert got["moe_live_rows"] == 16
+        # a kind's keys are there if and only if the model has the kind
+        prefixes = {"lightning-attn": ("linear_", "state_", "sparse_"),
+                    "minicpm4": ("linear_", "state_", "sparse_"),
+                    "power-retention": ("retention_", "state_"),
+                    "sliding_attention": ("window_", "full_attn_", "moe_"),
+                    "experts": ("window_", "full_attn_", "moe_")}[kind]
+        assert all(key.startswith(("attn_",) + prefixes) for key in got), got
+        assert all(any(key.startswith(p) for key in got) for p in prefixes)
 
 
 # ------------------------------------------------------------- end to end

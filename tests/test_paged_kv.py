@@ -20,7 +20,9 @@ import numpy as np
 import pytest
 
 from ray_tpu.serve._private.paging import (OutOfPagesError, PageArena,
-                                           RadixCache)
+                                           PagePool, RadixCache, SlotPages,
+                                           build_pools, cannot_continue,
+                                           pool_stats, pool_tables)
 
 SLOTS = 4
 CHUNK = 8
@@ -69,6 +71,157 @@ class TestPageArena:
         assert st["pages_freed_total"] == 2
         assert st["pages_in_use"] == 2
         assert st["peak_pages_in_use"] == 4
+
+
+# ------------------------------------------------------------- the pools
+
+
+class TestPagePool:
+    """One pool as the scheduler drives it: a slot's view grown a
+    chunk and then a step at a time, and freed."""
+
+    T, C, W = 4, 16, 24  # page tokens, prefill chunk, window
+
+    def _walk(self, pool, slot, length):
+        """A sequence of ``length`` tokens through ``slot``: chunks of C,
+        then steps; yields (cursor, held) behind every growth."""
+        held = SlotPages()
+        cursor = 0
+        while cursor < length:
+            real = min(self.C, length - cursor) if cursor < 64 else 1
+            pool.grow(slot, held, -(-(cursor + real) // self.T), cursor)
+            yield cursor, held
+            cursor += real
+        pool.free(slot, held)
+
+    def test_a_window_bounds_what_a_slot_holds(self):
+        bound = -(-(self.W + self.C) // self.T) + 1
+        pool = PagePool("w", 1 + 2 * bound, self.T, slots=2,
+                        pages_per_slot=64, window=self.W)
+        other = SlotPages()
+        pool.grow(1, other, 3, 0)
+        for cursor, held in self._walk(pool, 0, 250):
+            assert len(held.pages) <= bound
+            view = pool.read[0]
+            first = max(cursor - self.W + 1, 0) // self.T
+            # released entries point at the garbage page, held ones do not,
+            # and every position the window lets in is on a held page
+            assert not view[:held.fill - len(held.pages)].any()
+            assert list(view[first:held.fill]) == list(held.pages)[
+                first - (held.fill - len(held.pages)):]
+            assert (pool.write[0] == view).all()
+            assert pool.arena.pages_in_use == len(held.pages) + 3
+            last = len(held.pages)
+        assert pool.released == -(-250 // self.T) - last > 40
+        # what was released and what was freed is back: the other slot's
+        # three pages are all the arena misses
+        assert pool.arena.pages_in_use == 3 and pool.filled == 3
+        assert not pool.read[0].any() and not pool.write[0].any()
+        assert pool.longest == 64 * self.T
+
+    def test_without_a_window_nothing_is_released(self):
+        pool = PagePool("full", 1 + 64, self.T, slots=2, pages_per_slot=64)
+        for cursor, held in self._walk(pool, 1, 250):
+            assert held.fill == len(held.pages) == pool.arena.pages_in_use
+            assert list(pool.read[1, :held.fill]) == list(held.pages)
+        assert pool.released == 0 and pool.arena.pages_in_use == 0
+        assert pool.longest == 64 * self.T
+        read, write = pool_tables((pool,), 1)
+        read[:] = 7  # copies: a program in flight keeps what it took
+        assert not pool.read.any() and read.shape == (64,)
+        assert pool_tables((pool,))[0].shape == (2, 64)
+
+    @pytest.mark.parametrize("window,said", [
+        (None, r"^kv arena out of pages \(need 2 more, 1 free of 4; "
+               r"nothing evictable\)$"),
+        (8, r"^window kv arena out of pages \(need 2 more, 1 free of 4\)$"),
+    ])
+    def test_out_of_pages_is_the_sequences_message(self, window, said):
+        pool = PagePool("p", 5, self.T, slots=2, pages_per_slot=8,
+                        window=window)
+        a, b = SlotPages(), SlotPages()
+        pool.grow(0, a, 3, 0)
+        with pytest.raises(OutOfPagesError, match=said):
+            pool.grow(1, b, 2, 0)
+        assert b.fill == 0 and pool.arena.free_pages == 1  # nothing granted
+        pool.free(0, a)
+        pool.grow(1, b, 2, 0)
+
+    def test_the_prefix_cache_is_asked_before_a_sequence_fails(self):
+        pool = PagePool("full", 5, self.T, slots=2, pages_per_slot=8)
+        pool.radix = RadixCache(pool.arena)
+        a = SlotPages()
+        pool.grow(0, a, 3, 0)
+        offered = [int(x) for x in pool.read[0, :2]]
+        dups, node = pool.radix.insert(list(range(8)), offered)
+        pool.share(0, a, set(offered) - set(dups))
+        assert len(a.pages) == 1 and not pool.write[0, :2].any()
+        pool.radix.release(node)
+        pool.free(0, a)
+        assert pool.arena.pages_in_use == 2  # the cache's
+        b = SlotPages()
+        pool.splice(1, b, offered)
+        assert b.fill == 2 and not b.pages and not pool.write[1].any()
+        pool.free(1, b)
+        pool.grow(0, SlotPages(), 4, 0)  # evicts the cached span
+        assert pool.arena.pages_in_use == 4
+
+    def test_a_models_pools_follow_its_layers(self):
+        from ray_tpu.models import brumby_debug, llama_debug, mellum_debug
+
+        sizes = dict(slots=3, page_tokens=4, pages_per_slot=32, num_pages=50,
+                     prefill_chunk=16)
+        none = build_pools(brumby_debug(), **sizes)
+        assert none == () and pool_tables(none) == (None, None)
+        assert pool_stats(none)["num_pages"] == 1
+        assert not any(v for k, v in pool_stats(none).items()
+                       if k != "num_pages")
+        assert cannot_continue(brumby_debug(), none)["export"].startswith(
+            "a model with layers that keep a state a slot exports no prefix")
+        (one,) = build_pools(llama_debug(), **sizes)
+        assert one.window is None and one.arena.num_pages == 50
+        assert [t.shape for t in pool_tables((one,), 2)] == [(32,), (32,)]
+        assert cannot_continue(llama_debug(), (one,)) is None
+        assert "window_pages_released" not in pool_stats((one,))
+        # the window layers come first in the model; their pool second
+        full, window = pools = build_pools(mellum_debug(), **sizes)
+        assert (full.window, window.window) == (None, 24)
+        assert window.arena.num_pages == 1 + 3 * 11
+        read, write = pool_tables(pools)
+        assert sorted(read) == sorted(write) == [
+            "attention", "sliding_attention"]
+        assert {"kv_pages_in_use_full", "kv_peak_pages_in_use_window",
+                "window_pages_released"} <= pool_stats(pools).keys()
+        assert "'sliding_attention'" in cannot_continue(
+            mellum_debug(), pools)["drafter"]
+
+
+def test_the_scheduler_names_no_layer_kind():
+    """The seam: what a model's layers hold, forbid and do is behind
+    ``paging`` and ``work``. ``continuous.py`` imports no kind and nothing
+    that counts one, reads no kind off a config, and keeps nothing under a
+    kind's name (the code as parsed: its docstrings may say what they like)."""
+    import ast
+    import inspect
+
+    from ray_tpu.serve._private import continuous
+
+    tree = ast.parse(inspect.getsource(continuous))
+    imported = {alias.name for node in ast.walk(tree)
+                if isinstance(node, (ast.Import, ast.ImportFrom))
+                for alias in node.names}
+    assert not imported & {
+        "ATTENTION", "SPARSE", "LINEAR", "RETENTION", "SLIDING",
+        "STATE_KINDS", "state_shapes", "pool_of", "tile_sizes",
+        "streamed_tokens"}
+    names = {node.attr if isinstance(node, ast.Attribute) else
+             node.id if isinstance(node, ast.Name) else node.arg
+             for node in ast.walk(tree)
+             if isinstance(node, (ast.Attribute, ast.Name, ast.arg))}
+    assert not names & {"kinds", "recurrent", "holds_pages"}
+    assert not [name for name in names if any(
+        part in name for part in ("_window", "sliding", "sparse", "linear",
+                                  "retention", "_n_moe"))]
 
 
 # ------------------------------------------------------------ radix tree
