@@ -66,6 +66,24 @@ weights from ``--seed``):
            routes given, under the limits of cells/mellum2_shortlong.json) on
            the float8 CONTROL (the reference's forward with every weight
            rounded to float8 e4m3), which has to come out NOT correct
+  keye     a @ray_tpu.remote(num_tpus=1) task runs the benchmark's
+           Keye-VL-2.0-30B-A3B configuration (published widths: hidden 2048,
+           32 query heads over 4 K/V heads of 128 with q/k norm a head, an
+           indexer of 16 heads of 64 that picks 2048 tokens a query, 128
+           experts of 768 top-8 renormalized; 5 layers, bf16) through the
+           paged programs: a prompt of 4113 tokens and one of 2065 (each
+           ends on a page's FIRST token, both past topk) whose chunks take
+           the first's decode row along, then 6 decode steps of both,
+           against perfbench/reference/keye.py GIVEN the system's own routes
+           AND the tokens its queries attended (inside the dense cells' 8% /
+           6%; every query attends min(t + 1, 2048) tokens; the share of
+           choices on which the float32 reference's own indexer parts from
+           the bf16 one is reported); every page no table names is FILLED
+           WITH NaN in K, V and the index keys before the first program and
+           still is after the last; then the benchmark's own comparison
+           (reference_check with the routes given, under the limits of
+           cells/keye_longctx.json) on the float8 CONTROL, which has to
+           come out NOT correct
   serve    serve.run(build_app(preset="gpt2_small")) answers 8 concurrent
            requests: six through the handle, one streamed, one over HTTP
 
@@ -1031,6 +1049,173 @@ def mellum_task(seed: int) -> dict:
     return {**out, **device_report()}
 
 
+def keye_task(seed: int, control: bool = True) -> dict:
+    """The Keye-width checks (ISSUE 51): the paged chunk, step and fused
+    turn in bf16 at the published widths (hidden 2048, 32 heads over 4 K/V
+    heads of 128, an indexer of 16 heads of 64 that picks 2048 tokens, 128
+    experts of 768; the benchmark's 5 layers) against the plain float32
+    reference GIVEN the system's own routes and the tokens its queries
+    attended. Two prompts that end on a page's first token (4113 = 257 x 16
+    + 1 tokens, past ``topk`` twice over, and 2065 = 129 x 16 + 1, just past
+    it), the second's chunks taking the first's decode row along; every
+    page no table names filled with NaN in K, V and the index keys, as a
+    released page would be. Then the timed programs at the cell's own
+    shapes, and (``control``) the float8 control through the harness's own
+    comparison under the limits of ``cells/keye_longctx.json``, which must
+    refuse it."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from perfbench.lib import configs, weights
+    from perfbench.lib import manifest as manifest_lib
+    from perfbench.reference import keye as ref
+    from perfbench.reference.olmoe import routing_margin
+    from ray_tpu.models.decode import (StepRows, init_paged_caches,
+                                       paged_decode_step,
+                                       paged_prefill_into_slot)
+
+    require_chip()
+    manifest = manifest_lib.load()
+    hp = manifest_lib.config(manifest, "keye_vl2_30b_a3b_l5")
+    cfg = configs.build_program_config(*configs.program_overrides(
+        hp, manifest_lib.read_json_from_bench("families", hp["model_type"])))
+    L, k, topk = cfg.num_layers, cfg.moe_top_k, cfg.indexer.topk
+    params = weights.make_params(cfg, seed)
+    S, C, T, P = 4, 512, 16, 272
+    caches = init_paged_caches(cfg, S * P + 1 + 64, T, P)
+    rng = np.random.default_rng(seed)
+    prompts = {0: rng.integers(1, cfg.vocab_size, 4113).tolist(),
+               2: rng.integers(1, cfg.vocab_size, 2065).tolist()}
+    tables = np.zeros((S, P), np.int32)
+    for s in prompts:
+        tables[s] = 1 + s * P + np.arange(P)
+    loose = np.setdiff1d(np.arange(1, S * P + 65), tables)
+    caches = [dataclasses.replace(c, **{
+        name: getattr(c, name).at[loose].set(jnp.nan)
+        for name in ("k", "v", "ik")}) for c in caches]
+    both = jnp.asarray(tables)
+
+    prefill = jax.jit(lambda *a: paged_prefill_into_slot(
+        cfg, *a, attn="pallas", moe_info=True, logits=True, selected=True),
+        donate_argnums=(6,))
+    step = jax.jit(lambda *a: paged_decode_step(
+        cfg, *a, attn="pallas", moe_info=True, logits=True, selected=True),
+        donate_argnums=(6,))
+    ids = jnp.zeros(S, jnp.int32)
+    got = {s: [] for s in prompts}
+    taken = {s: [] for s in prompts}
+    picked = {s: [] for s in prompts}
+    fed = {s: [] for s in prompts}
+    active = np.zeros(S, np.int32)
+    cursors = np.zeros(S, np.int32)
+    greedy = (np.zeros(S, np.float32), np.zeros(S, np.uint32))
+    rows_routed = live_rows = 0
+
+    def feed():
+        for s in np.flatnonzero(active):
+            fed[s].append(int(got[s][-1].argmax()))
+
+    for s, prompt in prompts.items():
+        for c0 in range(0, len(prompt), C):
+            chunk = prompt[c0:c0 + C]
+            real = len(chunk)
+            feed()
+            ids, caches, moe, logits, chosen = prefill(
+                params, jnp.asarray([chunk + [0] * (C - real)], jnp.int32),
+                np.int32(real), np.int32(c0), both[s], both[s], caches, ids,
+                np.int32(s if c0 + C >= len(prompt) else -1), np.float32(0),
+                np.uint32(0), StepRows(active.copy(), cursors.copy(), both,
+                                       both, *greedy))
+            routes = np.asarray(moe["routes"])[:, 0]
+            taken[s].append(routes[:, :real])
+            picked[s].append(np.asarray(chosen[0])[:, 0, :real])
+            rows_routed += int(np.asarray(moe["counts"]).sum())
+            live_rows += real + int(active.sum())
+            for row in np.flatnonzero(active):
+                got[row].append(np.asarray(logits[1 + row], np.float32))
+                taken[row].append(routes[:, C + row:C + row + 1])
+                picked[row].append(np.asarray(chosen[1])[:, row])
+            cursors = cursors + active
+            cursors[s] += real
+        got[s].append(np.asarray(logits[0], np.float32))
+        active[s] = 1
+    assert len(fed[0]) == 5 and not fed[2]  # slot 0 decoded beside 2's chunks
+    for _ in range(6):
+        feed()
+        ids, caches, moe, logits, chosen = step(
+            params, ids, jnp.asarray(active), cursors, both, both, caches,
+            *greedy)
+        cursors = cursors + active
+        rows_routed += int(np.asarray(moe["counts"]).sum())
+        live_rows += len(prompts)
+        for s in prompts:
+            got[s].append(np.asarray(logits[s], np.float32))
+            taken[s].append(np.asarray(moe["routes"])[:, s])
+            picked[s].append(np.asarray(chosen)[:, s])
+
+    def rel(got, want):
+        return {"max": float(np.abs(got - want).max() / np.abs(want).max()),
+                "rms": float(np.sqrt(((got - want) ** 2).mean()
+                                     / (want ** 2).mean()))}
+
+    errs, flip_share, margins, own_choice = {}, [], [], {}
+    for s, prompt in prompts.items():
+        tokens = jnp.asarray([prompt + fed[s]], jnp.int32)
+        n = tokens.shape[1]
+        routes = jnp.asarray(np.concatenate(taken[s], axis=1))[:, None]
+        choice = np.concatenate([c[..., :n] for c in picked[s]], 1)[:, None]
+        counts = choice.sum(-1)[:, 0]
+        if not (counts == np.minimum(np.arange(n) + 1, topk)).all():
+            raise RuntimeError("keye: a query did not attend min(t + 1, "
+                               f"topk) tokens: {counts[:, -8:]}")
+        want, probs, theirs = ref.forward_and_choices(params, tokens, hp,
+                                                      routes, choice)
+        errs[s] = rel(np.stack(got[s][:-1]), want[0][len(prompt) - 1:-1])
+        f, m = routing_margin(probs, routes)
+        flip_share.append(f)
+        margins.append(m)
+        # what the float32 reference's own indexer picks, given the same
+        # inputs layer by layer: the share of (query, token) choices on
+        # which bf16 scores and float32 ones part
+        _, _, own = ref.forward_and_choices(params, tokens, hp, routes)
+        own_choice[s] = float((own != choice).sum() / choice.sum())
+    poisoned = [bool(jnp.isnan(getattr(c, name)[loose]).all())
+                for c in caches for name in ("k", "v", "ik")]
+    out = {"given_err": errs, "flip_share": flip_share, "margin": margins,
+           "choice_differs_share": own_choice,
+           "rows_routed": rows_routed,
+           "live_rows_x_k_x_layers": live_rows * k * L,
+           "pages_poisoned": int(loose.size)}
+    bad = []
+    if not all(np.isfinite(g).all() for rows in got.values() for g in rows):
+        bad.append("a logit is not finite: a page no table names was read")
+    if not all(poisoned):
+        bad.append("a page no table names was written")
+    if rows_routed != live_rows * k * L:
+        bad.append("a row was dropped or a dead row counted")
+    if max(e["max"] for e in errs.values()) > 0.08 \
+            or max(e["rms"] for e in errs.values()) > 0.06:
+        bad.append("error given both choices above the dense cells' "
+                   "tolerance")
+    if max(margins) > 4e-3:
+        bad.append("an expert was taken that the reference scores far "
+                   "below its 8th")
+    if bad:
+        raise RuntimeError(f"keye: {bad}: {out}")
+    del caches
+    if control:
+        seen = out["float8_control"] = float8_control(
+            cfg, hp, params, seed, ref, "keye", "keye_longctx")
+        if seen["checks"]["reference_logits"] \
+                or seen["checks"]["reference_logits_given_choices"]:
+            raise RuntimeError("keye: the float8 control passes a "
+                               f"comparison that has to refuse it: {seen}")
+    return {**out, **device_report()}
+
+
 class Float8Control:
     """Stands where the replica stands in ``BenchLLMServer.reference_check``:
     the plain reference's own forward pass with every weight rounded to
@@ -1080,15 +1265,23 @@ class Float8Control:
 
 
 def mellum_float8_control(cfg, hp, params, seed: int) -> dict:
+    from perfbench.reference import mellum as ref
+
+    return float8_control(cfg, hp, params, seed, ref, "mellum",
+                          "mellum2_shortlong")
+
+
+def float8_control(cfg, hp, params, seed: int, ref, reference: str,
+                   cell_name: str) -> dict:
     """The cell's own comparison (``BenchLLMServer.reference_check`` with
-    the routes given too, then the limits of
-    ``cells/mellum2_shortlong.json`` as ``serve_cell.run`` applies them) on
-    the float8 control, which has to come out NOT correct: on the cell's
-    check prompt from ``seed``, fed the tokens the program's own greedy pass
-    answers it with (``prefill`` + ``decode_step``, what the check itself
-    drives). The control's tokens are its logits' best at each of those
-    positions. Returns the readings, the verdicts, and which limits the
-    control passes."""
+    the routes given too, then the limits of ``cells/<cell_name>.json`` as
+    ``serve_cell.run`` applies them) on the float8 control, which has to
+    come out NOT correct: on the cell's check prompt from ``seed``, fed the
+    tokens the program's own greedy pass answers it with (``prefill`` +
+    ``decode_step``, what the check itself drives). The control's tokens
+    are its logits' best at each of those positions. ``ref``: the family's
+    plain reference, ``reference`` its file's name. Returns the readings,
+    the verdicts, and which limits the control passes."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1096,11 +1289,9 @@ def mellum_float8_control(cfg, hp, params, seed: int) -> dict:
     from perfbench.lib import manifest as manifest_lib
     from perfbench.lib import traffic
     from perfbench.lib.serve_app import BenchLLMServer
-    from perfbench.reference import mellum as ref
     from ray_tpu.models.decode import decode_step, init_caches, prefill
 
-    cell = manifest_lib.read_json(manifest_lib.load(), "cells",
-                                  "mellum2_shortlong")
+    cell = manifest_lib.read_json(manifest_lib.load(), "cells", cell_name)
     tol = cell["check_tolerance"]
     n, new = int(cell["check_prompt_tokens"]), int(cell["check_new_tokens"])
     ids = traffic.rng_for(seed, 9).integers(1, cfg.vocab_size,
@@ -1116,7 +1307,8 @@ def mellum_float8_control(cfg, hp, params, seed: int) -> dict:
         served.append(int(logits[0].argmax()))
     del caches, logits
     control = Float8Control(cfg, params, ref, hp, served[:-1])
-    path = os.path.join(manifest_lib.BENCH_DIR, "reference", "mellum.py")
+    path = os.path.join(manifest_lib.BENCH_DIR, "reference",
+                        reference + ".py")
     check = BenchLLMServer.reference_check(control, ids, served, hp, path,
                                            given=True)
     # the harness read the PROGRAM's tokens' margin; the control's own
@@ -1311,6 +1503,15 @@ def mellum_phase(seed: int) -> None:
     emit("mellum", seconds=round(time.perf_counter() - t0, 1), **out)
 
 
+def keye_phase(seed: int) -> None:
+    import ray_tpu
+
+    t0 = time.perf_counter()
+    out = ray_tpu.get(
+        ray_tpu.remote(num_tpus=1)(keye_task).remote(seed), timeout=2400)
+    emit("keye", seconds=round(time.perf_counter() - t0, 1), **out)
+
+
 def serve_phase(seed: int) -> None:
     import ray_tpu
     import ray_tpu.serve as serve
@@ -1425,6 +1626,7 @@ def one_chip(seed: int) -> dict:
     sala_phase(seed)
     brumby_phase(seed)
     mellum_phase(seed)
+    keye_phase(seed)
     serve_phase(seed)
     return out["device"]
 

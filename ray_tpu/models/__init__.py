@@ -18,5 +18,6 @@ from ray_tpu.models.presets import (  # noqa: F401
     moe_debug,
     minicpm_sala_debug,
     brumby_debug,
+    keye_debug,
     mellum_debug,
 )

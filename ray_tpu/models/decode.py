@@ -18,12 +18,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.transformer import (ATTENTION, LINEAR, RETENTION,
+from ray_tpu.models.transformer import (ATTENTION, INDEXED, LINEAR,
+                                        OWN_PAGE_TOKENS, RETENTION,
                                         SLIDING, SPARSE, STATE_KINDS,
                                         STATE_MIXERS,
                                         TransformerConfig, _gated_out, _mlp,
                                         _norm, _qkv, _residual, embed,
-                                        final_hidden, forward, layer_params,
+                                        final_hidden, forward, indexed_mix,
+                                        indexed_project, layer_params,
                                         project, rope_table,
                                         sparse_mix, sparse_pool_pages,
                                         stacked_mlp, state_shapes,
@@ -130,6 +132,12 @@ def init_caches(cfg: TransformerConfig, batch: int, max_len: int,
                                       cfg.head_dim, dtype)
         if kind in STATE_KINDS:
             return init_state(cfg, kind, batch, zero)
+        if kind == INDEXED:
+            # a pool of its own too, the index keys in it beside K and V
+            return dataclasses.replace(IndexedPagedKVCache.zeros(
+                1 + batch * -(-max_len // OWN_PAGE_TOKENS), OWN_PAGE_TOKENS,
+                cfg.kv_heads, cfg.head_dim, cfg.indexer.indexer_head_dim,
+                dtype), length=zero)
         # a pool of its own: page 0 the garbage page, then a sequence's
         # pages in order, so its page table is the identity
         return dataclasses.replace(SparsePagedKVCache.zeros(
@@ -225,6 +233,10 @@ def init_slot_caches(cfg: TransformerConfig, slots: int, max_len: int,
         raise ValueError(
             f"slot arena max_len ({max_len}) exceeds cfg.max_seq_len "
             f"({cfg.max_seq_len})")
+    if set(cfg.kinds) - {ATTENTION, SLIDING}:
+        raise ValueError(
+            "the slot arena holds keys and values alone (the speculative "
+            f"drafter's cache): no model with layers of {cfg.layer_kinds}")
     dtype = dtype or cfg.dtype
     return [SlotKVCache.zeros(slots, max_len, cfg.kv_heads, cfg.head_dim,
                               dtype) for _ in range(cfg.num_layers)]
@@ -310,7 +322,11 @@ def slot_decode_step(cfg: TransformerConfig, params, tokens, active, caches):
 # forward, ``_paged_forward_inplace``: each layer writes the new tokens' k/v
 # straight into their pages through a WRITE table and attends THROUGH the
 # READ table (``ops.paged_attention``), so no contiguous view of a slot ever
-# exists and a step's cost follows the pages a sequence holds. ``attn``
+# exists and a step's cost follows the pages a sequence holds (the layer
+# kinds that keep more than keys and values in a page or beside it — pooled
+# key rows for 'minicpm4', an index key a token for 'indexed_attention', a
+# state a slot for 'lightning-attn' and 'power-retention', a pool of their
+# own for 'sliding_attention' — are said at ``init_paged_caches``). ``attn``
 # names the op's implementation ('reference' | 'pallas') and has no default:
 # ``ops.paged_attention.resolve_impl`` picks it from the platform and the
 # model's shapes. The tests' oracle is the sequential cache above
@@ -369,12 +385,39 @@ class SparsePagedKVCache:
             (num_pages, kv_heads * head_dim), jnp.float32))
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class IndexedPagedKVCache:
+    """An 'indexed_attention' layer's page pool: k and v as ``PagedKVCache``
+    holds them, and beside them ``ik`` [num_pages, page_tokens, Di], each
+    token's index key (what the layer's indexer scores), under the same
+    page table: a page that is spliced, shared or freed takes its index
+    keys along. The same layout is that layer's CONTIGUOUS cache
+    (``init_caches``), which then carries its ``length``; in the serving
+    pool the cursors are the caller's and it is None."""
+
+    k: Any
+    v: Any
+    ik: Any
+    length: Any = None
+
+    @classmethod
+    def zeros(cls, num_pages: int, page_tokens: int, kv_heads: int,
+              head_dim: int, index_dim: int,
+              dtype=jnp.bfloat16) -> "IndexedPagedKVCache":
+        pool = PagedKVCache.zeros(num_pages, page_tokens, kv_heads, head_dim,
+                                  dtype)
+        return cls(k=pool.k, v=pool.v, ik=jnp.zeros(
+            (num_pages, page_tokens, index_dim), dtype))
+
+
 def init_paged_caches(cfg: TransformerConfig, num_pages: int,
                       page_tokens: int, pages_per_slot: int,
                       dtype=None, slots: Optional[int] = None,
                       window_pages: Optional[int] = None) -> List[Any]:
     """The serving pool, a layer at a time and by the layer's kind: pages
-    for an attention layer (with pooled key rows for a 'minicpm4' one), a
+    for an attention layer (with pooled key rows for a 'minicpm4' one, with
+    an index key a token for an 'indexed_attention' one), a
     state a slot (``slots`` of them) for a layer of a kind in
     ``STATE_KINDS``. A model none of whose layers holds a page has no pool:
     ``num_pages`` may then be anything, and nothing is made of it. A
@@ -410,6 +453,10 @@ def init_paged_caches(cfg: TransformerConfig, num_pages: int,
     def one(kind):
         if kind in STATE_KINDS:
             return init_state(cfg, kind, slots)
+        if kind == INDEXED:
+            return IndexedPagedKVCache.zeros(
+                num_pages, page_tokens, cfg.kv_heads, cfg.head_dim,
+                cfg.indexer.indexer_head_dim, dtype)
         pool = SparsePagedKVCache if kind == SPARSE else PagedKVCache
         return pool.zeros(window_pages if kind == SLIDING else num_pages,
                           page_tokens, cfg.kv_heads, cfg.head_dim, dtype)
@@ -517,7 +564,10 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
     attends through the read tables via ``ops.paged_attention(impl=)``, a
     call a group; a 'minicpm4' layer writes the same way and recomputes the
     pooled rows of the pages written, then attends the blocks it chooses, a
-    call a group (``transformer.sparse_mix``); a
+    call a group (``transformer.sparse_mix``); an 'indexed_attention' layer
+    writes its index keys with the keys and values, then scores them,
+    picks and attends its tokens, a call a group
+    (``transformer.indexed_mix``); a
     'lightning-attn' or 'power-retention' layer (``transformer
     .STATE_MIXERS``) reads and writes its states instead, a kernel call a
     group (``_Rows`` says whose states a group's rows meet). Layer math
@@ -533,8 +583,8 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
     Positions on unallocated/shared pages redirect there the same way.
     ``valid`` marks the rows that carry a live token (not a slot without a
     sequence, not a chunk's padding): the expert layer routes the others
-    nowhere. ``taps``: a list that is given each 'minicpm4' layer's choice,
-    a group at a time (debug).
+    nowhere. ``taps``: a list that is given each 'minicpm4' or
+    'indexed_attention' layer's choice, a group at a time (debug).
     Returns (hidden, caches, moe): the rows after the last layer, BEFORE the
     final norm, [S, K, d] (several groups: [1, rows, d], group after
     group) — the caller norms and projects the rows it samples (``_head``);
@@ -593,6 +643,21 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
                 outs.append(o)
             a = finish(cfg, ap, h, batch(outs))
             new_caches.append(_STATES[kind](**state))
+        elif kind == INDEXED:
+            attending, new = indexed_project(cfg, ap, h, rope, positions)
+            pools = tuple(
+                write_pages(pool, made, pages[pool_of(kind)], offs)
+                for pool, made in zip((c.k, c.v, c.ik), new))
+            outs, chosen = zip(*(_unless_idle(g, several, lambda _: (
+                indexed_mix(cfg, rows, pools,
+                            pool_tables(g.read_tables, kind), g.positions,
+                            g.lengths, impl=impl), ()), ())[0]
+                for g, *rows in zip(groups, *map(split, attending))))
+            if taps is not None:
+                taps.extend(chosen)
+            a = jnp.einsum("bshk,hkd->bsd", batch(outs),
+                           ap["wo"].astype(cfg.dtype))
+            new_caches.append(IndexedPagedKVCache(*pools))
         else:
             q, k, v = _qkv(cfg, ap, h, None if kind == SPARSE else rope,
                            positions, kind)
@@ -652,7 +717,8 @@ def _paged_outputs(first, caches, moe, moe_info: bool, logits, taps=None,
     ids in ``first`` were sampled from where the caller asked for them
     (else None), and last what the 'minicpm4' layers chose (``taps``,
     stacked; ``groups`` > 1: a tuple, a stack a group of rows) where it
-    asked for that."""
+    asked for that (the same for the tokens the 'indexed_attention' layers
+    picked, bool [layers of the kind, rows, K, context])."""
     if taps is not None:
         taps = (jnp.stack(taps) if groups == 1 else tuple(
             jnp.stack(taps[g::groups]) for g in range(groups)))

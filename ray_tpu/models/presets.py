@@ -155,6 +155,34 @@ def mellum_debug(**overrides) -> TransformerConfig:
     return TransformerConfig(**kw)
 
 
+def keye_debug(**overrides) -> TransformerConfig:
+    """Tiny Keye-VL-2.0-shaped language model (Kwai-Keye/Keye-VL-2.0-30B-A3B:
+    every layer 'indexed_attention' — grouped K/V heads with an RMSNorm over
+    each head's q and k, RoPE in three position streams (``rope_scaling``'s
+    ``mrope_section``; text feeds the three alike), and a learned indexer
+    (``sa_config``) that picks the ``topk`` tokens a query attends from one
+    cached index key a token — under dropless top-k experts whose weights
+    are renormalized) for tests: 4 index heads of 16, a choice of 16 tokens.
+    The vision tower is not part of it. Every layer is of the kind, however
+    many ``num_layers`` says, unless ``layer_kinds`` is given."""
+    kw = dict(
+        vocab_size=256, num_layers=2, embed_dim=64, num_heads=4,
+        num_kv_heads=2, head_dim=32, mlp="moe", mlp_dim=48,
+        moe_num_experts=8, moe_top_k=3, moe_renormalize=True,
+        head_qk_norm=True, max_seq_len=256, norm="rmsnorm", pos="rope",
+        norm_eps=1e-6, rope_theta=10000.0, tie_embeddings=False,
+        dtype=jnp.float32,
+        sa_config={"indexer_num_heads": 4, "indexer_head_dim": 16,
+                   "indexer_num_kv_heads": 1, "topk": 16,
+                   "q_chunk_size": 8, "kv_chunk_size": 8},
+        rope_scaling={"mrope_section": [4, 6, 6], "rope_type": "default",
+                      "type": "default"},
+    )
+    kw.update(overrides)
+    kw.setdefault("layer_kinds", ("indexed_attention",) * kw["num_layers"])
+    return TransformerConfig(**kw)
+
+
 # ---------------------------------------------------------------------------
 # pipeline stage partition (MPMD train.PipelineTrainer shards)
 #

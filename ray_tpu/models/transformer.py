@@ -34,9 +34,18 @@ Config switches:
     over the last ``sliding_window`` positions, the query's own among them:
     the attention block as it stands, K/V heads and all, with a window in
     the mask; in the serving pool its pages are a pool of their own, of
-    which a slot holds those the window still covers). 'full_attention' is
+    which a slot holds those the window still covers) and
+    'indexed_attention' (the attention block with a learned INDEXER beside
+    it: ``sa_config``'s index heads score every token of the context from
+    one cached index key a token, and the query attends the ``topk`` best
+    TOKENS, every one while the context is no longer than that;
+    ops/indexed_attention.py; its pages hold the index key beside K and V,
+    in the full layers' pool). 'full_attention' is
     taken for 'attention', so a source's ``layer_types`` map straight onto
-    ``layer_kinds``. Each state kind is written once, state in and state out,
+    ``layer_kinds``. ``rope_scaling``'s ``mrope_section`` turns runs of
+    frequency pairs by a position stream each (temporal, height, width:
+    ``positions`` [3, B, S]; text positions [B, S] are all three). Each
+    state kind is written once, state in and state out,
     as three pieces — project the rows, mix one group of them, finish the
     rows (``STATE_MIXERS``, ``sparse_mix``) — that every forward calls: this
     one with or without caches (``state_mixer``, ``sparse_mixer``), and the
@@ -60,6 +69,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import attention
+from ray_tpu.ops.indexed_attention import IndexerSizes, indexed_attention
 from ray_tpu.ops.flash_attention import FLASH_LSE, FLASH_OUT
 from ray_tpu.ops.linear_attention import (linear_attention_chunk,
                                           linear_attention_step, slopes)
@@ -78,7 +88,8 @@ from ray_tpu.parallel.sharding import free_axes
 ATTENTION, SPARSE, LINEAR = "attention", "minicpm4", "lightning-attn"
 RETENTION = "power-retention"
 SLIDING = "sliding_attention"
-LAYER_KINDS = (ATTENTION, SPARSE, LINEAR, RETENTION, SLIDING)
+INDEXED = "indexed_attention"
+LAYER_KINDS = (ATTENTION, SPARSE, LINEAR, RETENTION, SLIDING, INDEXED)
 # what a source calls the kind this file calls 'attention': its name in
 # ``layer_kinds`` as given and in ``rope_parameters``
 FULL_ATTENTION = "full_attention"
@@ -117,6 +128,14 @@ class TransformerConfig:
     # 'sliding_attention': {...}} as the source's config has it (a dict is
     # taken and frozen); None: ``rope_theta`` for every layer that rotates
     rope_parameters: Any = None
+    # 'indexed_attention': the indexer's sizes (ops.indexed_attention
+    # .IndexerSizes' fields, a source's ``sa_config``; a dict is taken and
+    # frozen)
+    sa_config: Any = None
+    # a source's ``rope_scaling`` (a dict is taken and frozen), read for
+    # ``mrope_section`` alone: runs of frequency pairs, a position stream
+    # each
+    rope_scaling: Any = None
     # 'lightning-attn': head h decays by exp(-2^(-e (h + 1) / H)) a token
     linear_slope_exponent: float = 8.0
     # MiniCPM's scales: the embedding times scale_emb; every residual
@@ -173,6 +192,16 @@ class TransformerConfig:
             object.__setattr__(self, "rope_parameters", tuple(sorted(
                 (kind, tuple(sorted(rule.items())))
                 for kind, rule in self.rope_parameters.items())))
+        if isinstance(self.sa_config, dict):
+            object.__setattr__(self, "sa_config",
+                               tuple(sorted(self.sa_config.items())))
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(self, "rope_scaling", tuple(sorted(
+                (key, tuple(value) if isinstance(value, list) else value)
+                for key, value in self.rope_scaling.items())))
+        if INDEXED in self.kinds and self.sa_config is None:
+            raise ValueError("an 'indexed_attention' layer needs sa_config "
+                             "(the indexer's sizes)")
         if SLIDING in self.kinds and self.sliding_window < 1:
             raise ValueError("a 'sliding_attention' layer needs "
                              f"sliding_window >= 1, got {self.sliding_window}")
@@ -208,6 +237,14 @@ class TransformerConfig:
     @property
     def sparse(self) -> SparseSizes:
         return SparseSizes(**dict(self.sparse_config or ()))
+
+    @property
+    def indexer(self) -> IndexerSizes:
+        return IndexerSizes(**dict(self.sa_config or ()))
+
+    @property
+    def mrope_section(self) -> Optional[Tuple[int, ...]]:
+        return dict(self.rope_scaling or ()).get("mrope_section")
 
     @property
     def residual_scale(self) -> float:
@@ -276,6 +313,14 @@ def _block_params(cfg: TransformerConfig, key,
         p["attn"]["o_norm"] = jnp.ones((h * hd,), cfg.param_dtype)
     if kind == RETENTION:
         p["attn"]["wc"] = init(ks[7], (d, kvh))     # a log-gate a K/V head
+    if kind == INDEXED:
+        hi, di = cfg.indexer.indexer_num_heads, cfg.indexer.indexer_head_dim
+        ki = jax.random.split(ks[7], 3)
+        p["attn"].update(
+            wi_q=init(ki[0], (d, hi, di)), wi_k=init(ki[1], (d, di)),
+            wi_w=init(ki[2], (d, hi)),
+            ik_scale=jnp.ones((di,), cfg.param_dtype),
+            ik_bias=jnp.zeros((di,), cfg.param_dtype))
     if cfg.mlp == "moe":
         from ray_tpu.ops.moe import init_moe_params
 
@@ -375,6 +420,11 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
             block["attn"]["o_norm"] = L + (None,)
         if kind == RETENTION:
             block["attn"]["wc"] = L + ("embed", "kv")
+        if kind == INDEXED:
+            block["attn"].update(
+                wi_q=L + ("embed", None, None), wi_k=L + ("embed", None),
+                wi_w=L + ("embed", None), ik_scale=L + (None,),
+                ik_bias=L + (None,))
         if cfg.mlp == "moe":
             from ray_tpu.ops.moe import moe_logical_axes
 
@@ -483,8 +533,10 @@ def _qkv(cfg, p, x, rope, positions, kind=ATTENTION):
         if positions is None:
             positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None]
         rule = cfg.rope_rule(kind)
-        q = apply_rotary_at(q, positions, cfg.rope_theta, rule)
-        k = apply_rotary_at(k, positions, cfg.rope_theta, rule)
+        q = apply_rotary_at(q, positions, cfg.rope_theta, rule,
+                            cfg.mrope_section)
+        k = apply_rotary_at(k, positions, cfg.rope_theta, rule,
+                            cfg.mrope_section)
     elif rope is not None:
         cos, sin = rope
         q = apply_rotary(q, cos, sin, positions)
@@ -761,6 +813,83 @@ def sparse_pool_pages(cfg, tokens: int) -> int:
     return -(-tokens // cfg.sparse.block_size) * cfg.sparse.pages_per_block
 
 
+def index_project(cfg, p, x, positions):
+    """The indexer's three projections of the normalized x [B, S, d]: the
+    index queries qI [B, S, Hi, Di] and the token's one index key kI [B, S,
+    Di] (LayerNorm first), both rotated over the whole index head by the
+    layer's rule at ``positions``, and the heads' weights w [B, S, Hi]
+    float32, already times ``Hi^-1/2 Di^-1/2``."""
+    sizes = cfg.indexer
+    qi = jnp.einsum("bsd,dhk->bshk", x, p["wi_q"].astype(cfg.dtype))
+    ki = layer_norm(jnp.einsum("bsd,dk->bsk", x, p["wi_k"].astype(cfg.dtype)),
+                    p["ik_scale"], p["ik_bias"], cfg.norm_eps)
+    turn = lambda a: apply_rotary_at(a, positions, cfg.rope_theta,
+                                     cfg.rope_rule(INDEXED),
+                                     cfg.mrope_section)
+    w = jnp.einsum("bsd,dh->bsh", x, p["wi_w"].astype(cfg.dtype),
+                   preferred_element_type=jnp.float32)
+    scale = (sizes.indexer_num_heads * sizes.indexer_head_dim) ** -0.5
+    return turn(qi), turn(ki[:, :, None])[:, :, 0], w * scale
+
+
+def indexed_project(cfg, p, x, rope, positions):
+    """What an 'indexed_attention' layer makes of the normalized x [B, S,
+    d]: (the rows that attend — q [B, S, H, D], qI, w —, what they leave in
+    the pages — k, v [B, S, Hkv, D] and the index key [B, S, 1, Di], in the
+    order of the pools)."""
+    q, k, v = _qkv(cfg, p, x, rope, positions, INDEXED)
+    qi, ki, w = index_project(cfg, p, x, positions)
+    return (q, qi, w), (k, v, ki[:, :, None])
+
+
+def indexed_mix(cfg, rows, pools, read_tables, positions, lengths, *,
+                impl: str):
+    """'indexed_attention', one group, its keys, values and index keys
+    already in the pool: rows = (q [B, S, H, D], qI, w) at ``positions``
+    [B, S] attend the tokens their indexer picks. ``pools`` = (k [N, T, Hkv
+    * D], v, the index keys [N, T, Di]), a row's pages through
+    ``read_tables`` [B, P], ``lengths`` [B] as ``ops.paged_attention``
+    takes them. Returns (o [B, S, H, D], the choice, bool [B, S,
+    context])."""
+    return indexed_attention(*rows, *pools, read_tables, positions, lengths,
+                             cfg.indexer, impl=impl, return_selected=True)
+
+
+def indexed_mixer(cfg, p, x, rope, rope_positions, positions, lengths,
+                  pools, read_tables, write_tables, *, impl: str,
+                  taps: Optional[List] = None):
+    """'indexed_attention' over ONE group of rows: x [B, S, d] at
+    ``positions`` [B, S] of their sequences (``rope_positions``: what the
+    rotation sees, those or three streams of them) over a paged pool
+    (``indexed_mix`` says what the arguments are; a row's pages are written
+    through ``write_tables`` [B, P]). Keys, values and index keys are
+    written in one go, then the picked tokens attended. Returns (y, pools);
+    ``taps`` (a list) is given the choice."""
+    T = pools[0].shape[1]
+    rows, new = indexed_project(cfg, p, x, rope, rope_positions)
+    cells = written_pages(write_tables, positions, T), positions % T
+    pools = tuple(write_pages(pool, made, *cells)
+                  for pool, made in zip(pools, new))
+    o, selected = indexed_mix(cfg, rows, pools, read_tables, positions,
+                              lengths, impl=impl)
+    if taps is not None:
+        taps.append(selected)
+    return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(cfg.dtype)), pools
+
+
+# tokens of a page of the pool an 'indexed_attention' layer makes for itself
+# where nobody hands it one (no cache, a contiguous cache)
+OWN_PAGE_TOKENS = 16
+
+
+def _own_tables(pools, rows: int):
+    """The identity page tables [rows, P] of a pool a layer made for itself:
+    page 0 the garbage page, then each row's pages in order."""
+    P = (pools[0].shape[0] - 1) // rows
+    return (1 + jnp.arange(rows, dtype=jnp.int32)[:, None] * P
+            + jnp.arange(P, dtype=jnp.int32)[None])
+
+
 def _mixer(cfg, kind, p, x, rope, positions, sp_axis, cache, taps):
     """One layer's mixer over the normalized x -> (y, the cache after it).
     ``cache``: None, or what ``models.decode.init_caches`` makes for the
@@ -768,7 +897,30 @@ def _mixer(cfg, kind, p, x, rope, positions, sp_axis, cache, taps):
     whose page table is the identity)."""
     if kind in (ATTENTION, SLIDING):
         return _attn(cfg, p, x, rope, positions, sp_axis, cache, kind)
+    from ray_tpu.ops.paged_attention import resolve_impl
+
     B, S = x.shape[:2]
+    if kind == INDEXED:
+        # the rows' places in their sequences; ``positions`` (those, or
+        # three streams of them) is what the rotation sees
+        if cache is None:
+            n = 1 + B * -(-S // OWN_PAGE_TOKENS)
+            pools = tuple(
+                jnp.zeros((n, OWN_PAGE_TOKENS, width), cfg.dtype)
+                for width in (cfg.kv_heads * cfg.head_dim,) * 2
+                + (cfg.indexer.indexer_head_dim,))
+            length = jnp.zeros((), jnp.int32)
+        else:
+            pools, length = (cache.k, cache.v, cache.ik), cache.length
+        tables = _own_tables(pools, B)
+        seq = jnp.broadcast_to(length + jnp.arange(S, dtype=jnp.int32)[None],
+                               (B, S))
+        y, pools = indexed_mixer(
+            cfg, p, x, rope, seq if positions is None else positions, seq,
+            jnp.broadcast_to(length, (B,)), pools, tables, tables,
+            impl=resolve_impl(cfg), taps=taps)
+        return y, cache and dataclasses.replace(
+            cache, k=pools[0], v=pools[1], ik=pools[2], length=length + S)
     pos = jnp.broadcast_to(jnp.arange(S)[None] if positions is None
                            else positions, (B, S)).astype(jnp.int32)
     if kind in STATE_KINDS:
@@ -778,8 +930,6 @@ def _mixer(cfg, kind, p, x, rope, positions, sp_axis, cache, taps):
         y, state = state_mixer(cfg, kind, p, x, pos, state)
         return y, cache and dataclasses.replace(
             cache, **state, length=cache.length + S)
-    from ray_tpu.ops.paged_attention import resolve_impl
-
     if cache is None:
         n = 1 + B * sparse_pool_pages(cfg, S)
         rows = (n, cfg.sparse.kernel_stride, cfg.kv_heads * cfg.head_dim)
@@ -788,9 +938,7 @@ def _mixer(cfg, kind, p, x, rope, positions, sp_axis, cache, taps):
         length = jnp.zeros((), jnp.int32)
     else:
         pools, length = (cache.k, cache.v, cache.means), cache.length
-    P = (pools[0].shape[0] - 1) // B
-    tables = (1 + jnp.arange(B, dtype=jnp.int32)[:, None] * P
-              + jnp.arange(P, dtype=jnp.int32)[None])
+    tables = _own_tables(pools, B)
     y, pools = sparse_mixer(cfg, p, x, pos, jnp.broadcast_to(length, (B,)),
                             pools, tables, tables,
                             impl=resolve_impl(cfg), taps=taps)
@@ -897,9 +1045,10 @@ def project(cfg, params, x):
 def rope_table(cfg):
     """The (cos, sin) tables plain attention rotates by, None for a model
     none of whose layers does (a table is as long as the context)."""
-    if cfg.pos == "learned" or not {ATTENTION, SLIDING} & set(cfg.kinds):
+    if (cfg.pos == "learned"
+            or not {ATTENTION, SLIDING, INDEXED} & set(cfg.kinds)):
         return None
-    if cfg.rope_parameters is not None:
+    if cfg.rope_parameters is not None or cfg.rope_scaling is not None:
         return COMPUTED  # a rule a kind: angles from the positions
     return rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
 
@@ -920,7 +1069,9 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
     made on the same choices.
     return_selected (debug, without kv_caches): also return the blocks every
     query of every 'minicpm4' layer attended, bool [layers of the kind, B,
-    S, Hkv, NB], for the same reason.
+    S, Hkv, NB], or the tokens every query of every 'indexed_attention'
+    layer attended, bool [layers of the kind, B, S, context], for the same
+    reason.
 
     sp_axis: when running inside shard_map with sequence sharded over that
     axis, attention goes through the ring kernel and `positions` must be the
@@ -947,9 +1098,10 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
 
     if return_routes and (cfg.mlp != "moe" or kv_caches is not None):
         raise ValueError("return_routes needs mlp='moe' and no kv_caches")
-    if return_selected and (SPARSE not in kinds or kv_caches is not None):
-        raise ValueError("return_selected needs a 'minicpm4' layer and no "
-                         "kv_caches")
+    if return_selected and (not {SPARSE, INDEXED} & set(kinds)
+                            or kv_caches is not None):
+        raise ValueError("return_selected needs a 'minicpm4' or an "
+                         "'indexed_attention' layer and no kv_caches")
     new_caches = None
     aux_total = 0.0
     routes = None

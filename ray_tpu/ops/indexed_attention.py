@@ -1,0 +1,432 @@
+"""Token-selected sparse attention over the paged K/V pool (the
+DeepSeek-Sparse-Attention indexer of the ``indexed_attention`` mixer).
+
+A query attends ``topk`` single TOKENS of its context, picked by a learned
+scorer that keeps a cache of its own: beside keys and values a layer keeps
+one INDEX KEY a token (``index_dim`` values, one head), in pages of the same
+table. For the query at position ``t``, with its ``Hi`` index queries ``qI``
+and their weights ``w`` (already scaled):
+
+  1. ``index_scores``: ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])``
+     over the slot's paged index keys, float32 (the kernel ``index_score``;
+     key tiles wholly behind a tile of queries are skipped).
+  2. ``select``: every ``s <= t`` where ``t + 1 <= topk``, else the ``topk``
+     positions of largest ``I[t, s]``, ties to the lower ``s`` — EXACT, and
+     without a sort: the kernel ``indexed_select`` finds a row's ``topk``-th
+     largest score by counting passes over the row in fast memory (a binary
+     search over the 32 bits of the scores' order-preserving integer form),
+     then how many of the scores EQUAL to it belong, by index. What it
+     hands on is two numbers a query, the cut ``tau`` and the index bound
+     ``M``: ``s`` is chosen iff ``s <= t`` and (``I > tau`` or (``I == tau``
+     and ``s < M``)) — ``chosen`` — so no mask a (query, token) is ever
+     written.
+  3. attention over the choice. A decode step (one query a row) sorts its
+     chosen positions to the front, gathers their ``topk`` rows of K and V
+     out of the pages and runs the paged kernel over that compact run
+     (``indexed_step_attention``): it reads the chosen tokens and no other.
+     A chunk (many queries, each with its own choice) runs a flash-style
+     kernel over the slot's context that rebuilds the choice of a (query
+     tile, key tile) from the scores and the two numbers
+     (``indexed_chunk_attention``): its time follows the context, not the
+     choice (PERF.md 7).
+
+The kernels carry those names in a profiler trace and are interpreted off a
+TPU. What is chosen is returned on request (``return_selected``), bool [B,
+S, context]: a comparison with another implementation has to be made on the
+same choice, because top-k is discontinuous.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops._pallas import should_interpret
+from ray_tpu.ops.paged_attention import NEG_INF, paged_attention
+
+_KEY_TILE = 512      # context tokens of one grid cell of a kernel
+_SCORE_TOKENS = 128  # query tokens of one tile of the score kernel
+_SELECT_ROWS = 8     # query rows of one tile of the selection kernel
+_ATTN_ROWS = 2048    # query rows (tokens x heads) of one tile of the chunk
+_INT_MIN = -2 ** 31
+
+
+@dataclasses.dataclass(frozen=True)
+class IndexerSizes:
+    """A source's ``sa_config``. ``q_chunk_size`` / ``kv_chunk_size`` are
+    tile sizes of the published kernel and change no result: kept so the
+    dict maps whole, read by nothing."""
+
+    indexer_num_heads: int = 16
+    indexer_head_dim: int = 64
+    indexer_num_kv_heads: int = 1
+    topk: int = 2048
+    q_chunk_size: int = 512
+    kv_chunk_size: int = 512
+
+    def __post_init__(self):
+        if self.indexer_num_kv_heads != 1:
+            raise ValueError("the indexer keeps ONE index key a token: "
+                             f"indexer_num_kv_heads {self.indexer_num_kv_heads}")
+        if self.topk < 1 or self.indexer_head_dim % 2:
+            raise ValueError("topk >= 1 and an even indexer_head_dim, got "
+                             f"{self.topk}, {self.indexer_head_dim}")
+
+    def attended_tokens(self, t):
+        """How many tokens the query at position ``t`` (an int or a NumPy
+        array of them) attends: a function of the position alone (the
+        scheduler's counters mirror it)."""
+        return np.minimum(np.asarray(t) + 1, self.topk)
+
+
+def chunk_tokens(num_heads: int) -> int:
+    """Query tokens of one tile of the chunk's kernel, every head's row of
+    each among its ``_ATTN_ROWS`` (the scheduler's counters mirror it)."""
+    return max(_ATTN_ROWS // num_heads // 8 * 8, 8)
+
+
+def _context(tables, page_tokens: int):
+    """A row's table padded to whole key tiles (with the garbage page, whose
+    tokens lie behind every position of the row) and the tokens it spans."""
+    pages = (-(-tables.shape[1] * page_tokens // _KEY_TILE)
+             * _KEY_TILE // page_tokens)
+    tables = jnp.pad(tables, ((0, 0), (0, pages - tables.shape[1])))
+    return tables, pages * page_tokens
+
+
+def _tiles(positions, tokens: int):
+    """positions [B, S] padded to whole tiles of ``tokens`` rows (the edge
+    repeated) -> (padded [B, Sp], key tiles a query tile reaches [B, n])."""
+    B, S = positions.shape
+    n = -(-S // tokens)
+    pos = jnp.pad(positions.astype(jnp.int32),
+                  ((0, 0), (0, n * tokens - S)), mode="edge")
+    nkt = jnp.maximum(pos.reshape(B, n, tokens).max(axis=-1), 0) // _KEY_TILE
+    return pos, (nkt + 1).astype(jnp.int32)
+
+
+# ------------------------------------------------------------ index scores
+
+
+def _score_kernel(nkt_ref, q_ref, w_ref, k_ref, o_ref, *, heads, tokens):
+    b, qt, kt = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+    @pl.when(kt < nkt_ref[b, qt])
+    def _():
+        k = k_ref[...]
+        total = jnp.zeros(o_ref.shape, jnp.float32)
+        for j in range(heads):
+            s = lax.dot_general(q_ref[j * tokens:(j + 1) * tokens], k,
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            total += jnp.maximum(s, 0.0) * w_ref[:, j:j + 1]
+        o_ref[...] = total
+
+
+def index_scores(qi, w, ik_pool, tables, positions, interpret: bool):
+    """qi [B, S, Hi, Di], w [B, S, Hi] float32, the index keys' pool [N, T,
+    Di] through ``tables`` [B, P] -> I [B, S, context] float32 (``context``:
+    the table's tokens in whole key tiles). Entries behind a query's
+    position are not all computed: every reader masks by position."""
+    B, S, Hi, Di = qi.shape
+    tables, ctx = _context(tables, ik_pool.shape[1])
+    keys = ik_pool[tables].reshape(B, ctx, Di)
+    tokens = min(_SCORE_TOKENS, -(-S // 8) * 8)
+    pos, nkt = _tiles(positions, tokens)
+    n_qt, Sp = nkt.shape[1], pos.shape[1]
+    # rows of a tile stand head-major: row j * tokens + i = (head j, token i)
+    pad = ((0, 0), (0, Sp - S), (0, 0))
+    qr = jnp.pad(qi, pad + ((0, 0),)).reshape(
+        B, n_qt, tokens, Hi, Di).transpose(0, 1, 3, 2, 4).reshape(
+        B, n_qt, Hi * tokens, Di).astype(ik_pool.dtype)
+    wr = jnp.pad(w.astype(jnp.float32), pad).reshape(B, n_qt, tokens, Hi)
+    reach = lambda b, qt, kt, nkt: jnp.minimum(kt, nkt[b, qt] - 1)
+    out = pl.pallas_call(
+        functools.partial(_score_kernel, heads=Hi, tokens=tokens),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, n_qt, ctx // _KEY_TILE),
+            in_specs=[
+                pl.BlockSpec((None, None, Hi * tokens, Di),
+                             lambda b, qt, kt, nkt: (b, qt, 0, 0)),
+                pl.BlockSpec((None, None, tokens, Hi),
+                             lambda b, qt, kt, nkt: (b, qt, 0, 0)),
+                pl.BlockSpec((None, _KEY_TILE, Di),
+                             lambda b, qt, kt, nkt: (
+                                 b, reach(b, qt, kt, nkt), 0))],
+            out_specs=pl.BlockSpec(
+                (None, tokens, _KEY_TILE),
+                lambda b, qt, kt, nkt: (b, qt, reach(b, qt, kt, nkt)))),
+        out_shape=jax.ShapeDtypeStruct((B, Sp, ctx), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        name="index_score", interpret=interpret,
+    )(nkt, qr, wr, keys)
+    return out[:, :S]
+
+
+# --------------------------------------------------------------- selection
+
+
+def _select_kernel(s_ref, pos_ref, o_ref, key_scr, *, topk, index_bits):
+    s = s_ref[...] + 0.0                        # -0.0 is 0.0: one order
+    idx = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    bits = lax.bitcast_convert_type(s, jnp.int32)
+    # the integers order as the floats do; what lies behind the query is
+    # below every score
+    key_scr[...] = jnp.where(idx <= pos_ref[...], jnp.where(
+        bits >= 0, bits, bits ^ jnp.int32(0x7FFFFFFF)), jnp.int32(_INT_MIN))
+    count = lambda hit: jnp.sum(hit.astype(jnp.int32), axis=1, keepdims=True)
+
+    # the largest integer that at least ``topk`` keys reach, bit by bit
+    cut = jnp.where(count(key_scr[...] >= 0) >= topk, jnp.int32(0),
+                    jnp.int32(_INT_MIN))
+
+    def raise_cut(i, cut):
+        higher = cut | jnp.left_shift(jnp.int32(1), 30 - i)
+        return jnp.where(count(key_scr[...] >= higher) >= topk, higher, cut)
+
+    cut = lax.fori_loop(0, 31, raise_cut, cut)
+    # of the keys EQUAL to it, those before index ``bound`` fill the choice
+    need = topk - count(key_scr[...] > cut)
+
+    def raise_bound(i, bound):
+        higher = bound | jnp.left_shift(jnp.int32(1), index_bits - 1 - i)
+        fits = count(jnp.logical_and(key_scr[...] == cut,
+                                     idx < higher)) <= need
+        return jnp.where(fits, higher, bound)
+
+    # (no row of the tile has more keys on its cut than it needs, the
+    # usual case: every one of them belongs, and the passes are skipped)
+    bound = lax.cond(
+        jnp.any(count(key_scr[...] == cut) > need),
+        lambda: lax.fori_loop(0, index_bits, raise_bound,
+                              jnp.zeros_like(cut)),
+        lambda: jnp.full_like(cut, (1 << index_bits) - 1))
+    tau = lax.bitcast_convert_type(
+        jnp.where(cut >= 0, cut, cut ^ jnp.int32(0x7FFFFFFF)), jnp.float32)
+    tau = jnp.where(cut == jnp.int32(_INT_MIN), -jnp.inf, tau)
+    lane = lax.broadcasted_iota(jnp.int32, o_ref.shape, 1)
+    o_ref[...] = jnp.where(lane == 0, tau, jnp.where(
+        lane == 1, bound.astype(jnp.float32), 0.0))
+
+
+def select(scores, positions, topk: int, interpret: bool):
+    """scores [B, S, context] float32, positions [B, S] -> (tau [B, S]
+    float32, bound [B, S] int32): the two numbers ``chosen`` reads."""
+    B, S, ctx = scores.shape
+    rows = B * S
+    padded = -(-rows // _SELECT_ROWS) * _SELECT_ROWS
+    flat = jnp.pad(scores.reshape(rows, ctx), ((0, padded - rows), (0, 0)))
+    pos = jnp.pad(positions.reshape(rows, 1).astype(jnp.int32),
+                  ((0, padded - rows), (0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_select_kernel, topk=topk,
+                          index_bits=max(ctx.bit_length(), 1)),
+        grid=(padded // _SELECT_ROWS,),
+        in_specs=[pl.BlockSpec((_SELECT_ROWS, ctx), lambda r: (r, 0)),
+                  pl.BlockSpec((_SELECT_ROWS, 1), lambda r: (r, 0))],
+        out_specs=pl.BlockSpec((_SELECT_ROWS, 128), lambda r: (r, 0)),
+        out_shape=jax.ShapeDtypeStruct((padded, 128), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((_SELECT_ROWS, ctx), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=96 << 20),
+        name="indexed_select", interpret=interpret,
+    )(flat, pos)
+    return (out[:rows, 0].reshape(B, S),
+            out[:rows, 1].astype(jnp.int32).reshape(B, S))
+
+
+def chosen(scores, positions, tau, bound, first=0):
+    """THE rule of the choice, wherever it is rebuilt: scores [..., rows,
+    keys] of the keys ``first`` .. against the rows' positions, cuts and
+    bounds [..., rows, 1] -> bool."""
+    scores = scores + 0.0
+    idx = first + lax.broadcasted_iota(jnp.int32, scores.shape,
+                                       scores.ndim - 1)
+    return jnp.logical_and(idx <= positions, jnp.logical_or(
+        scores > tau, jnp.logical_and(scores == tau, idx < bound)))
+
+
+# --------------------------------------------------------- chunk attention
+
+
+def _chunk_kernel(nkt_ref, q_ref, k_ref, v_ref, s_ref, cut_ref, o_ref,
+                  m_scr, l_scr, acc_scr, *, kv_heads, group, tokens,
+                  head_dim, sm_scale):
+    """One (row, query tile, key tile) cell, every head: the tile's choice
+    is rebuilt once from the index scores and serves all heads."""
+    b, qt, kt = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+    @pl.when(kt == 0)
+    def _():
+        m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    @pl.when(kt < nkt_ref[b, qt])
+    def _():
+        cut = cut_ref[...]                                 # [tokens, 128]
+        pos = cut[:, 0:1].astype(jnp.int32)
+        take = chosen(s_ref[...], pos, cut[:, 1:2],
+                      cut[:, 2:3].astype(jnp.int32), first=kt * _KEY_TILE)
+        bias = jnp.where(take, 0.0, NEG_INF).astype(jnp.float32)
+        bias = jnp.concatenate([bias] * group, axis=0)    # [G * tokens, tk]
+        R = group * tokens
+        for h in range(kv_heads):
+            rows = slice(h * R, (h + 1) * R)
+            lanes = slice(h * head_dim, (h + 1) * head_dim)
+            s = lax.dot_general(q_ref[rows], k_ref[:, lanes],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            s = s * sm_scale + bias
+            m = m_scr[rows]
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            pr = jnp.exp(s - m_new)
+            l_scr[rows] = l_scr[rows] * alpha + jnp.sum(pr, axis=1,
+                                                        keepdims=True)
+            acc_scr[rows] = acc_scr[rows] * alpha + lax.dot_general(
+                pr.astype(v_ref.dtype), v_ref[:, lanes],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[rows] = m_new
+
+    @pl.when(kt == pl.num_programs(2) - 1)
+    def _():
+        l = l_scr[...]
+        o_ref[...] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+
+
+def _chunk_attention(q, k_pool, v_pool, tables, positions, scores, tau,
+                     bound, interpret: bool):
+    """q [B, S, H, D] at ``positions`` [B, S] over each row's own context
+    (its pages, gathered into one run), every query over its own choice."""
+    B, S, H, D = q.shape
+    T = k_pool.shape[1]
+    Hkv = k_pool.shape[2] // D
+    G = H // Hkv
+    tables, ctx = _context(tables, T)
+    view = lambda pool: pool[tables].reshape(B, ctx, Hkv * D)
+    tokens = min(chunk_tokens(H), -(-S // 8) * 8)
+    pos, nkt = _tiles(positions, tokens)
+    n_qt, Sp = nkt.shape[1], pos.shape[1]
+    R = H * tokens
+    # rows of a tile stand head-major: row n * tokens + i = (head n, token i)
+    qr = jnp.pad(q, ((0, 0), (0, Sp - S), (0, 0), (0, 0))).reshape(
+        B, n_qt, tokens, H, D).transpose(0, 1, 3, 2, 4).reshape(
+        B, n_qt, R, D).astype(k_pool.dtype)
+    edge = lambda a: jnp.pad(a, ((0, 0), (0, Sp - S)), mode="edge")
+    cut = jnp.stack([pos.astype(jnp.float32), edge(tau),
+                     edge(bound).astype(jnp.float32)], axis=-1)
+    cut = jnp.pad(cut, ((0, 0), (0, 0), (0, 125)))        # [B, Sp, 128]
+    sc = jnp.pad(scores, ((0, 0), (0, Sp - S), (0, 0)))
+    cell = lambda b, qt, kt, nkt: (b, qt, 0, 0)
+    reach = lambda b, qt, kt, nkt: jnp.minimum(kt, nkt[b, qt] - 1)
+    keys = lambda b, qt, kt, nkt: (b, reach(b, qt, kt, nkt), 0)
+    out = pl.pallas_call(
+        functools.partial(_chunk_kernel, kv_heads=Hkv, group=G,
+                          tokens=tokens, head_dim=D,
+                          sm_scale=1.0 / math.sqrt(D)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, n_qt, ctx // _KEY_TILE),
+            in_specs=[
+                pl.BlockSpec((None, None, R, D), cell),
+                pl.BlockSpec((None, _KEY_TILE, Hkv * D), keys),
+                pl.BlockSpec((None, _KEY_TILE, Hkv * D), keys),
+                pl.BlockSpec((None, tokens, _KEY_TILE),
+                             lambda b, qt, kt, nkt: (
+                                 b, qt, reach(b, qt, kt, nkt))),
+                pl.BlockSpec((None, tokens, 128),
+                             lambda b, qt, kt, nkt: (b, qt, 0))],
+            out_specs=pl.BlockSpec((None, None, R, D), cell),
+            scratch_shapes=[pltpu.VMEM((R, 1), jnp.float32),
+                            pltpu.VMEM((R, 1), jnp.float32),
+                            pltpu.VMEM((R, D), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, n_qt, R, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=96 << 20),
+        name="indexed_chunk_attention", interpret=interpret,
+    )(nkt, qr, view(k_pool), view(v_pool), sc, cut)
+    out = out.reshape(B, n_qt, H, tokens, D).transpose(0, 1, 3, 2, 4)
+    return out.reshape(B, Sp, H, D)[:, :S]
+
+
+# ---------------------------------------------------------- step attention
+
+
+def _step_attention(q, k_pool, v_pool, tables, live, take, topk: int,
+                    impl: str):
+    """One query a row: the chosen positions sorted to the front, their rows
+    of K and V gathered out of the pages into a run of ``topk`` tokens a
+    row, and the paged kernel over that run, whose causal mask cuts behind
+    the last chosen token."""
+    B = q.shape[0]
+    T, HD = k_pool.shape[1:]
+    ctx = take.shape[-1]
+    width = -(-min(topk, ctx) // T) * T
+    idx = jnp.arange(ctx, dtype=jnp.int32)
+    order = jnp.sort(jnp.where(take, idx, ctx + idx), axis=-1)[:, :width]
+    held = order < ctx
+    order = jnp.where(held, order, 0)
+    pages = jnp.take_along_axis(tables, jnp.minimum(
+        order // T, tables.shape[1] - 1), axis=1)
+    rows = jnp.where(held, pages * T + order % T, 0)       # [B, width]
+    run = lambda pool: pool.reshape(-1, HD)[rows].reshape(
+        B * width // T, T, HD)
+    own = jnp.arange(B * width // T, dtype=jnp.int32).reshape(B, width // T)
+    lengths = jnp.where(live, held.sum(axis=-1, dtype=jnp.int32) - 1, -1)
+    return paged_attention(q, run(k_pool), run(v_pool), own, lengths,
+                           impl=impl, name="indexed_step_attention")
+
+
+# ------------------------------------------------------------------ the op
+
+
+def indexed_attention(q, qi, w, k_pool, v_pool, ik_pool, tables, positions,
+                      lengths, sizes: IndexerSizes, *, impl: str,
+                      return_selected: bool = False):
+    """Attention of q [B, S, H, D] at ``positions`` [B, S] over the tokens
+    each query's indexer picks, through its row's page table. qi [B, S, Hi,
+    Di] and w [B, S, Hi] float32: the index queries and their weights;
+    k_pool/v_pool: [N, T, Hkv * D]; ik_pool: [N, T, Di], the index keys (all
+    three already written for the rows' own tokens); tables: [B, P];
+    lengths: [B], as ``paged_attention`` takes them (a row whose window lies
+    before position 0 attends nothing). ``impl``: what the paged kernel runs
+    as for a step ('reference' | 'pallas'). Returns [B, S, H, D], and with
+    ``return_selected`` the choice, bool [B, S, context]."""
+    return _attention(q, qi, w, k_pool, v_pool, ik_pool, tables, positions,
+                      lengths, sizes, impl, return_selected,
+                      should_interpret())
+
+
+# jitted as the kernels' own wrappers are: a model's layers and a program's
+# groups of one shape are traced and lowered ONCE
+@functools.partial(jax.jit, static_argnames=(
+    "sizes", "impl", "return_selected", "interpret"))
+def _attention(q, qi, w, k_pool, v_pool, ik_pool, tables, positions, lengths,
+               sizes, impl, return_selected, interpret):
+    S = q.shape[1]
+    scores = index_scores(qi, w, ik_pool, tables, positions, interpret)
+    tau, bound = select(scores, positions, sizes.topk, interpret)
+    take = None
+    if S == 1 or return_selected:
+        take = chosen(scores, positions[..., None], tau[..., None],
+                      bound[..., None])
+    if S == 1:
+        o = _step_attention(q, k_pool, v_pool, tables, lengths + 1 > 0,
+                            take[:, 0], sizes.topk, impl)
+    else:
+        o = _chunk_attention(q, k_pool, v_pool, tables, positions, scores,
+                             tau, bound, interpret)
+    return (o, take) if return_selected else o

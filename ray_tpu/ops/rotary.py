@@ -42,20 +42,44 @@ def _rotate(x, cos, sin):
 
 
 def apply_rotary_at(x, positions, theta: float = 10000.0,
-                    rule: Optional[Mapping[str, Any]] = None):
+                    rule: Optional[Mapping[str, Any]] = None,
+                    sections: Optional[Tuple[int, ...]] = None):
     """Rotate q or k by angles computed from ``positions`` [..., seq] as
     they come — no table, so a model's context length costs nothing. x:
     [..., seq, heads, head_dim]; the same half-rotation layout. ``rule``:
     one entry of a source's ``rope_parameters`` (``rule_frequencies``), in
-    place of ``theta``."""
+    place of ``theta``. ``sections`` (a source's ``mrope_section``): the
+    frequency pairs in runs, each run turned by a position stream of its
+    own — ``positions`` [streams, ..., seq], one stream a run (temporal,
+    height, width); positions of x's own rank [..., seq] are every stream
+    at once, and the rule is plain RoPE. A head of fewer pairs than the
+    sections add up to (an index head) takes the runs in the same
+    proportion (``stream_of_pairs``)."""
     d = x.shape[-1]
     if rule is not None:
         inv_freq, factor = rule_frequencies(d, rule)
+    else:
+        inv_freq, factor = 1.0 / (theta ** (
+            jnp.arange(0, d, 2, dtype=jnp.float32) / d)), 1.0
+    if sections is not None and positions.ndim == x.ndim - 1:
+        # pair i's position is its run's stream's: [..., seq, pairs]
+        by_pair = jnp.moveaxis(positions, 0, -1)[
+            ..., stream_of_pairs(sections, d // 2)]
+        angles = by_pair.astype(jnp.float32) * inv_freq
+    else:
         angles = positions[..., None].astype(jnp.float32) * inv_freq
-        return _rotate(x, jnp.cos(angles) * factor, jnp.sin(angles) * factor)
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = positions[..., None].astype(jnp.float32) * inv_freq
-    return _rotate(x, jnp.cos(angles), jnp.sin(angles))
+    if factor == 1.0:
+        return _rotate(x, jnp.cos(angles), jnp.sin(angles))
+    return _rotate(x, jnp.cos(angles) * factor, jnp.sin(angles) * factor)
+
+
+def stream_of_pairs(sections, pairs: int) -> np.ndarray:
+    """The position stream [pairs] int that turns each frequency pair under
+    ``sections`` (runs of pairs, a stream each, that add up to a head's
+    pairs): pair i of a head of ``pairs`` pairs stands where pair ``i *
+    sum(sections) / pairs`` of the full head stands."""
+    full = np.repeat(np.arange(len(sections)), sections)
+    return full[np.arange(pairs) * len(full) // pairs]
 
 
 ROPE_TYPES = ("default", "yarn")

@@ -22,6 +22,11 @@ rows of pages ``j`` and ``j + 1``). For the query at position ``t``:
      context with the choice as a mask a (query, block): its time follows
      the context, not the choice (PERF.md 7).
 
+The sixth layer kind, 'indexed_attention', selects single TOKENS by a
+learned scorer with a cache of its own and shares none of this file's
+choice: ``ops/indexed_attention.py`` (its chunk runs a masked flash kernel
+over the slot's context as this one's does, a mask a (query, token) there).
+
 The kernels carry those two names in a profiler trace and are interpreted
 off a TPU. What is chosen is returned on request (``return_selected``): a
 comparison with another implementation has to be made on the same choice,

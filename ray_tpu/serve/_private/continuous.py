@@ -419,12 +419,12 @@ class ContinuousScheduler:
             self.page_tokens, self._pages_per_slot = self.arena_len, 1
         use_prefix = (conf.serve_prefix_cache if prefix_cache is None
                       else bool(prefix_cache))
-        self._refused = cannot_continue(cfg, self._pools)
-        if self._refused:
-            if prefix_cache:
-                raise ValueError(self._refused["prefix_cache"])
-            if drafter is not None:
-                raise ValueError(self._refused["drafter"])
+        self._refused = cannot_continue(cfg, self._pools) or {}
+        if prefix_cache and "prefix_cache" in self._refused:
+            raise ValueError(self._refused["prefix_cache"])
+        if drafter is not None and "drafter" in self._refused:
+            raise ValueError(self._refused["drafter"])
+        if "prefix_cache" in self._refused:
             use_prefix = False  # the configured default cannot apply
         # the prefix cache is the first pool's: it splices and adopts that
         # pool's pages, and the pool asks it for pages before it fails
@@ -1229,7 +1229,7 @@ class ContinuousScheduler:
         scheduler thread (sole owner of the tree and the donated caches),
         so this enqueues a command and waits. The matched node is pinned
         only for the duration of the gather."""
-        if self._refused:
+        if "export" in self._refused:
             raise ValueError(self._refused["export"])
         if self._radix is None:
             return {"matched_len": 0, "page_tokens": self.page_tokens,

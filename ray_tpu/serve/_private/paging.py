@@ -374,20 +374,35 @@ _REFUSALS = {"state": {
         "a model with 'sliding_attention' layers exports no prefix: the full "
         "layers' pages alone do not continue a sequence, and the window "
         "layers' are released behind the window"),
+}, "index": {
+    "drafter": (
+        "speculative decoding cannot serve a model with 'indexed_attention' "
+        "layers: the drafter's slot arena holds keys and values alone, and "
+        "a verify window's rows have not been held against the reference "
+        "for the kind"),
+    "export": (
+        "a model with 'indexed_attention' layers exports no prefix: an "
+        "exported page carries its keys and values and not its index keys"),
 }}
 
 
 def cannot_continue(cfg, pools: Tuple[PagePool, ...]
                     ) -> Optional[Dict[str, str]]:
     """Why a sequence of this model cannot be continued from pages alone, as
-    the message for each thing that would need it ('prefix_cache',
-    'drafter', 'export'): a layer keeps a state a slot (what a token leaves
-    in it cannot be cut at a page boundary or rewound, and no snapshot is
-    kept), or a pool releases pages behind a window. None where it can."""
+    the message for each thing that would need it and cannot have it (of
+    'prefix_cache', 'drafter', 'export'): a layer keeps a state a slot (what
+    a token leaves in it cannot be cut at a page boundary or rewound, and no
+    snapshot is kept), or a pool releases pages behind a window. An
+    'indexed_attention' layer's index keys lie in its pages, under the same
+    table: a spliced radix prefix brings them along, so the prefix cache
+    serves it (tests/test_keye.py); what leaves the pool as keys and values
+    alone does not. None where nothing is forbidden."""
     if cfg.recurrent:
         return _REFUSALS["state"]
     if any(pool.window is not None for pool in pools):
         return _REFUSALS["window"]
+    if "indexed_attention" in cfg.kinds:
+        return _REFUSALS["index"]
     return None
 
 

@@ -11,7 +11,8 @@ program's device time is read from a profiler trace, by its name.
 A kind's counters are said once, in ``_KINDS``: the function that counts one
 call of the kind's layers (a layer-call: one layer in one program run) and
 the keys it owns, which a model shows if and only if it has layers of the
-kind; ``attn_*`` always (they count pages: a true 0 for a model without).
+kind ('lightning-attn', 'power-retention', 'minicpm4', 'indexed_attention',
+'sliding_attention'; plain 'attention' is counted by ``attn_*`` alone); ``attn_*`` always (they count pages: a true 0 for a model without).
 """
 
 from __future__ import annotations
@@ -22,8 +23,9 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ray_tpu._private.metrics import Counter
-from ray_tpu.models.transformer import (LINEAR, RETENTION, SLIDING, SPARSE,
-                                        STATE_KINDS, state_shapes)
+from ray_tpu.models.transformer import (INDEXED, LINEAR, RETENTION, SLIDING,
+                                        SPARSE, STATE_KINDS, state_shapes)
+from ray_tpu.ops.indexed_attention import chunk_tokens
 from ray_tpu.ops.paged_attention import streamed_tokens, tile_sizes
 
 _m_attn_bytes = Counter(
@@ -93,6 +95,35 @@ def _window(work, n, layers, qk, cursors, real):
         np.minimum(t + 1, work.cfg.sliding_window).sum())
 
 
+def _indexed(work, n, layers, qk, cursors, real):
+    """Token-selected layers: query rows x layers' contexts, each scored
+    whole by the indexer (``indexed_tokens_scored``: index keys read and
+    dotted with every index head) and attended as far as ``topk``
+    (``IndexerSizes.attended_tokens``, a function of the position alone),
+    and of the three a step's share. Returns (attended, fetched) a layer as
+    the paged kernel's are counted: a step reads its rows' chosen tokens
+    once, gathered into a run of their own (every K/V head shares the
+    choice); a chunk (one row) streams its slot's context up to each tile
+    of queries in whole key tiles of 512, which attend their mean choice of
+    it."""
+    sizes = work.cfg.indexer
+    t = np.asarray(cursors)[:, None] + np.arange(real)  # [rows, real]
+    attended = int(sizes.attended_tokens(t).sum())
+    context = int((t + 1).sum())
+    n["indexed_tokens_scored"] += layers * context
+    n["indexed_tokens_attended"] += layers * attended
+    n["indexed_tokens_context"] += layers * context
+    if qk == 1:
+        n["indexed_step_tokens_attended"] += layers * attended
+        n["indexed_step_tokens_context"] += layers * context
+        return attended, attended
+    starts = np.arange(0, real, work.indexed_chunk_tokens)
+    ends = np.minimum(starts + work.indexed_chunk_tokens, real)
+    att = sizes.attended_tokens(t[0])
+    return (sum(int(att[lo:hi].mean()) for lo, hi in zip(starts, ends)),
+            int((-(-(t[0, 0] + ends) // 512)).sum()) * 512)
+
+
 # kind -> (what counts one call of its layers, the keys it owns); the last
 # two of the window kind's are ``sample``'s
 _KINDS = {
@@ -103,6 +134,10 @@ _KINDS = {
                        "sparse_tokens_attended", "sparse_tokens_context",
                        "sparse_step_tokens_attended",
                        "sparse_step_tokens_context")),
+    INDEXED: (_indexed, ("indexed_tokens_scored", "indexed_tokens_attended",
+                         "indexed_tokens_context",
+                         "indexed_step_tokens_attended",
+                         "indexed_step_tokens_context")),
     SLIDING: (_window, ("window_attn_step_keys", "full_attn_step_keys",
                         "window_attn_chunk_pairs", "full_attn_chunk_pairs",
                         "window_tokens_held", "window_tokens_unreleased")),
@@ -136,6 +171,8 @@ class Work:
                 1, cfg.num_heads // cfg.kv_heads, page_tokens,
                 sizes.max_chosen_blocks() * sizes.pages_per_block,
                 self._row_bytes)[0]
+        if INDEXED in kinds:
+            self.indexed_chunk_tokens = chunk_tokens(cfg.num_heads)
         # a float32 state a slot a layer that keeps one, by kind
         state_bytes = 4 * sum(
             math.prod(shape) for kind in kinds if kind in STATE_KINDS

@@ -1,0 +1,610 @@
+"""The 'indexed_attention' kind (Keye-VL-2.0's language model: a learned
+indexer picks the tokens a query attends, its keys cached a token beside K
+and V) against the plain reference, ``perfbench/reference/keye.py``: float32
+on the CPU at a toy size whose ``topk`` (16) is smaller than the contexts.
+
+The system is held to the reference GIVEN both its discrete choices (the
+experts' routes, the tokens attended) at 1e-4 of the largest logit, through
+every forward: without a cache, the contiguous cache (prefill then decode),
+and the paged chunk, step and fused turn. Named faults in the reference are
+refused by the same comparison; the selection itself is held EXACTLY, a
+planted tie included; a context of at most ``topk`` tokens equals the
+'attention' kind on the same weights.
+"""
+
+from __future__ import annotations
+
+import asyncio
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from perfbench.reference import keye as ref  # noqa: E402
+from ray_tpu.models.decode import (StepRows, decode_step,  # noqa: E402
+                                   init_caches, init_paged_caches,
+                                   init_slot_caches, paged_decode_step,
+                                   paged_prefill_into_slot, prefill)
+from ray_tpu.models.presets import keye_debug  # noqa: E402
+from ray_tpu.models.transformer import (INDEXED, LAYER_KINDS,  # noqa: E402
+                                        forward, init_params)
+from ray_tpu.ops import indexed_attention as ia  # noqa: E402
+from ray_tpu.ops.rotary import apply_rotary_at  # noqa: E402
+
+TOL = 1e-4
+
+
+def hp_of(cfg):
+    """The reference's configuration object, keyed as the source keys it."""
+    return {"num_hidden_layers": cfg.num_layers, "head_dim": cfg.head_dim,
+            "rms_norm_eps": cfg.norm_eps, "rope_theta": cfg.rope_theta,
+            "rope_scaling": {"mrope_section": list(cfg.mrope_section)},
+            "sa_config": dataclasses.asdict(cfg.indexer),
+            "num_experts_per_tok": cfg.moe_top_k,
+            "norm_topk_prob": cfg.moe_renormalize}
+
+
+def seeded(cfg, seed=0):
+    """Weights with every norm's scale and the index key's bias away from
+    their trivial values, and an indexer that speaks up (its projections
+    times 8: the seeded 0.02 leaves every score near 0)."""
+    params = init_params(cfg, jax.random.PRNGKey(seed))
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
+
+    def stir(path, leaf):
+        name = jax.tree_util.keystr(path)
+        if any(n in name for n in ("scale", "q_norm", "k_norm", "ik_bias")):
+            return leaf + 0.3 * jax.random.normal(next(keys), leaf.shape)
+        if "wi_" in name:
+            return leaf * 8.0
+        return leaf
+
+    return jax.tree_util.tree_map_with_path(stir, params)
+
+
+def rel(got, want):
+    return float(np.abs(np.asarray(got, np.float32) - want).max()
+                 / np.abs(want).max())
+
+
+@pytest.fixture(scope="module")
+def toy():
+    cfg = keye_debug()
+    params = seeded(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(7), (2, 72), 0,
+                                cfg.vocab_size)
+    with jax.default_matmul_precision("highest"):
+        logits, routes = forward(cfg, params, tokens, return_routes=True)
+        _, selected = forward(cfg, params, tokens, return_selected=True)
+    return {"cfg": cfg, "params": params, "tokens": tokens,
+            "logits": np.asarray(logits), "routes": np.asarray(routes),
+            "selected": np.asarray(selected)}
+
+
+def test_the_preset_has_what_the_architecture_forces():
+    cfg = keye_debug(num_layers=3)
+    assert INDEXED in LAYER_KINDS and cfg.kinds == (INDEXED,) * 3
+    assert cfg.mlp == "moe" and cfg.moe_renormalize and cfg.head_qk_norm
+    assert cfg.kv_heads < cfg.num_heads and cfg.period == 1
+    assert sum(cfg.mrope_section) == cfg.head_dim // 2
+    assert cfg.indexer.topk == 16 and cfg.holds_pages and not cfg.recurrent
+    attn = init_params(cfg, jax.random.PRNGKey(0))["blocks"]["attn"]
+    sizes = cfg.indexer
+    assert attn["wi_q"].shape == (3, cfg.embed_dim, sizes.indexer_num_heads,
+                                  sizes.indexer_head_dim)
+    assert attn["wi_k"].shape == (3, cfg.embed_dim, sizes.indexer_head_dim)
+    assert attn["wi_w"].shape == (3, cfg.embed_dim, sizes.indexer_num_heads)
+    with pytest.raises(ValueError, match="needs sa_config"):
+        keye_debug(sa_config=None)
+    with pytest.raises(ValueError, match="slot arena holds keys and values"):
+        init_slot_caches(cfg, 2, 32)
+
+
+# ------------------------------------------------ three position streams
+
+
+def test_three_equal_streams_are_plain_rope_and_unequal_ones_the_rule():
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 9, 3, 32))
+    pos = jnp.arange(9)[None] + jnp.asarray([[0], [5]])
+    plain = apply_rotary_at(x, pos, 1e4)
+    three = jnp.stack([pos, pos, pos])
+    np.testing.assert_array_equal(
+        apply_rotary_at(x, pos, 1e4, None, (4, 6, 6)), plain)
+    np.testing.assert_allclose(
+        apply_rotary_at(x, three, 1e4, None, (4, 6, 6)), plain, atol=1e-6)
+    streams = jnp.stack([pos, 2 * pos + 1, 40 - pos])
+    got = apply_rotary_at(x, streams, 1e4, None, (4, 6, 6))
+    want = ref.rotate(x, streams.astype(jnp.float32), 1e4, [4, 6, 6], 32)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    assert np.abs(np.asarray(got - plain)).max() > 0.1
+    # an index head of half the pairs takes the streams in proportion
+    half = apply_rotary_at(x[..., :16], streams, 1e4, None, (4, 6, 6))
+    np.testing.assert_allclose(half, ref.rotate(
+        x[..., :16], streams.astype(jnp.float32), 1e4, [4, 6, 6], 32),
+        atol=1e-5)
+
+
+# ------------------------------------------------------------ selection
+
+
+def _scores(seed, rows=24, keys=600):
+    """Index scores as the reference computes them, with ties planted: two
+    pairs of identical index keys, and a run of keys scored 0 by every
+    head (a ReLU's favourite tie) that is the best of every other query."""
+    k = jax.random.split(jax.random.PRNGKey(seed), 3)
+    qi = jax.random.normal(k[0], (1, rows, 4, 16))
+    w = jax.random.normal(k[1], (1, rows, 4))
+    ki = jax.random.normal(k[2], (1, keys, 16))
+    ki = ki.at[0, 9].set(ki[0, 5]).at[0, 300].set(ki[0, 17])
+    ki = ki.at[0, 40:60].set(0.0)
+    # every other query weighs all its heads down: its scores are <= 0 and
+    # the run of zeros is its best
+    w = w.at[0, ::2].set(-jnp.abs(w[0, ::2]))
+    return qi, w, ki
+
+
+@pytest.mark.parametrize("topk", [1, 16, 64, 599, 600, 2048])
+def test_the_selection_is_the_references_ties_and_all(topk):
+    qi, w, ki = _scores(3)
+    rows, keys = qi.shape[1], ki.shape[1]
+    with jax.default_matmul_precision("highest"):
+        scores = ref.score_block(qi, w, ki)
+    positions = (jnp.arange(rows, dtype=jnp.int32) * 25 + 20)[None]
+    want = np.stack([np.asarray(ref.select_block(
+        scores[:, r:r + 1], int(positions[0, r]), topk))[0, 0]
+        for r in range(rows)])
+    pad = jnp.pad(scores, ((0, 0), (0, 0), (0, 1024 - keys)),
+                  constant_values=jnp.nan)  # what nobody computed
+    tau, bound = ia.select(pad, positions, topk, True)
+    got = np.asarray(ia.chosen(pad, positions[..., None], tau[..., None],
+                               bound[..., None]))[0, :, :keys]
+    np.testing.assert_array_equal(got, want)
+    counts = np.minimum(np.asarray(positions[0]) + 1, topk)
+    np.testing.assert_array_equal(got.sum(-1), counts)
+    if topk == 16:
+        # the planted ties were live: some row's cut falls on a tie
+        tied = [(np.asarray(scores)[0, r] == float(tau[0, r])).sum()
+                for r in range(rows)]
+        assert max(tied) > 1
+
+
+def test_the_score_kernel_is_the_references_scores():
+    qi, w, ki = _scores(5, rows=40, keys=200)
+    T, P = 8, 26
+    pool = jnp.zeros((1 + P, T, 16)).at[1:].set(
+        jnp.pad(ki[0], ((0, P * T - 200), (0, 0))).reshape(P, T, 16))
+    tables = 1 + jnp.arange(P, dtype=jnp.int32)[None]
+    positions = (160 + jnp.arange(40, dtype=jnp.int32))[None]
+    with jax.default_matmul_precision("highest"):
+        got = ia.index_scores(qi, w, pool, tables, positions, True)
+        want = ref.score_block(qi, w, ki)
+    assert got.shape == (1, 40, 512)
+    np.testing.assert_allclose(got[0, :, :200], want[0], atol=1e-5)
+
+
+# ------------------------------------------------- the uncached forward
+
+
+def test_forward_logits_match_the_reference_given_both_choices(toy):
+    """With the layers kept apart here; the module's ``toy`` runs them
+    stacked under ``scan`` and is held the same way first."""
+    assert rel(toy["logits"], ref.forward(
+        toy["params"], toy["tokens"], hp_of(toy["cfg"]), toy["routes"],
+        toy["selected"])) <= TOL
+    cfg = dataclasses.replace(toy["cfg"], scan_layers=False)
+    params = seeded(cfg)
+    with jax.default_matmul_precision("highest"):
+        logits, routes = forward(cfg, params, toy["tokens"],
+                                 return_routes=True)
+        again, selected = forward(cfg, params, toy["tokens"],
+                                  return_selected=True)
+    np.testing.assert_allclose(again, logits, atol=1e-5)
+    assert selected.shape[:3] == (cfg.num_layers, 2, 72)
+    want = ref.forward(params, toy["tokens"], hp_of(cfg), np.asarray(routes),
+                       np.asarray(selected))
+    assert rel(logits, want) <= TOL
+    # every query past topk attends exactly topk tokens, none ahead of it
+    count = np.asarray(selected).sum(-1)
+    np.testing.assert_array_equal(
+        count[0, 0], np.minimum(np.arange(72) + 1, cfg.indexer.topk))
+    ahead = np.triu(np.ones((72, 72), bool), 1)
+    assert not (np.asarray(selected)[..., :72] & ahead).any()
+
+
+def test_the_selection_is_the_references_own(toy):
+    """No choice handed over: the reference's indexer picks the same tokens
+    from its own float32 scores, in every layer."""
+    _, _, taken = ref.forward_and_choices(
+        toy["params"], toy["tokens"], hp_of(toy["cfg"]), toy["routes"])
+    np.testing.assert_array_equal(taken, toy["selected"][..., :72])
+
+
+def test_three_unequal_streams_reach_the_reference(toy):
+    cfg, params, tokens = toy["cfg"], toy["params"], toy["tokens"]
+    base = jnp.broadcast_to(jnp.arange(72)[None], (2, 72))
+    streams = jnp.stack([base, base // 3, 71 - base % 7])
+    with jax.default_matmul_precision("highest"):
+        logits, routes = forward(cfg, params, tokens, positions=streams,
+                                 return_routes=True)
+        _, selected = forward(cfg, params, tokens, positions=streams,
+                              return_selected=True)
+    want = ref.forward(params, tokens, hp_of(cfg), np.asarray(routes),
+                       np.asarray(selected), streams)
+    assert rel(logits, want) <= TOL
+    assert rel(toy["logits"], want) > 100 * TOL  # the streams matter
+
+
+# ---------------------------------------------------------- named faults
+
+
+def _no_norm(m):
+    m.setattr(ref, "layer_norm", lambda x, scale, bias, eps: x)
+
+
+def _no_relu(m):
+    m.setattr(ref, "relu", lambda x: x)
+
+
+def _a_heads_weight_dropped(m):
+    true = ref.head_weights
+    m.setattr(ref, "head_weights",
+              lambda *a: true(*a).at[..., 0].set(0.0))
+
+
+def _topk_off_by_one(m):
+    m.setattr(ref, "topk_of", lambda hp: int(hp["sa_config"]["topk"]) - 1)
+
+
+def _selection_by_block(m):
+    """Blocks of 4 tokens by their best score, the 4 best blocks."""
+    def by_block(scores, first, topk):
+        b, r, s = scores.shape
+        t = first + jnp.arange(r)[:, None]
+        seen = jnp.arange(s)[None, :] <= t
+        masked = jnp.where(seen[None], scores, -jnp.inf)
+        blocks = jnp.pad(masked, ((0, 0), (0, 0), (0, -s % 4)),
+                         constant_values=-jnp.inf).reshape(b, r, -1, 4)
+        best = jax.lax.top_k(blocks.max(-1), min(topk // 4,
+                                                 blocks.shape[2]))[1]
+        taken = jnp.zeros(blocks.shape[:3], bool).at[
+            jnp.arange(b)[:, None, None], jnp.arange(r)[None, :, None],
+            best].set(True)
+        return jnp.logical_and(jnp.repeat(taken, 4, axis=-1)[..., :s],
+                               seen[None])
+    m.setattr(ref, "select_block", by_block)
+
+
+def _bf16_scores(m):
+    true = ref.score_block
+    low = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)
+    m.setattr(ref, "score_block",
+              lambda qi, w, ki: low(true(low(qi), low(w), low(ki))))
+
+
+def _experts_not_renormalized(m):
+    true = ref.token_weights
+    m.setattr(ref, "token_weights",
+              lambda p, r, top_k, renorm: true(p, r, top_k, False))
+
+
+INDEXER_FAULTS = [_no_norm, _no_relu, _a_heads_weight_dropped,
+                  _topk_off_by_one, _selection_by_block, _bf16_scores]
+
+
+@pytest.mark.parametrize("fault", [None] + INDEXER_FAULTS,
+                         ids=lambda f: f.__name__ if f else "sound")
+def test_a_fault_in_the_indexer_is_refused(toy, monkeypatch, fault):
+    """Given the routes and NOT the tokens: the reference picks its own, so
+    whatever its indexer does wrong moves its choice and its logits."""
+    if fault:
+        fault(monkeypatch)
+    want = ref.forward(toy["params"], toy["tokens"], hp_of(toy["cfg"]),
+                       toy["routes"])
+    err = rel(toy["logits"], want)
+    assert (err > TOL) if fault else (err <= TOL), err
+
+
+def test_experts_left_unrenormalized_are_refused(toy, monkeypatch):
+    _experts_not_renormalized(monkeypatch)
+    want = ref.forward(toy["params"], toy["tokens"], hp_of(toy["cfg"]),
+                       toy["routes"], toy["selected"])
+    assert rel(toy["logits"], want) > TOL
+
+
+def test_one_position_stream_for_three_is_refused(toy, monkeypatch):
+    cfg, params, tokens = toy["cfg"], toy["params"], toy["tokens"]
+    base = jnp.broadcast_to(jnp.arange(72)[None], (2, 72))
+    streams = jnp.stack([base, base // 3, 71 - base % 7])
+    with jax.default_matmul_precision("highest"):
+        logits, routes = forward(cfg, params, tokens, positions=streams,
+                                 return_routes=True)
+        _, selected = forward(cfg, params, tokens, positions=streams,
+                              return_selected=True)
+    true = ref.stream_positions
+    monkeypatch.setattr(ref, "stream_positions", lambda p, b, s: jnp.stack(
+        [true(p, b, s)[0]] * 3))
+    want = ref.forward(params, tokens, hp_of(cfg), np.asarray(routes),
+                       np.asarray(selected), streams)
+    assert rel(logits, want) > TOL
+
+
+# ------------------------------------- contexts of at most topk tokens
+
+
+def test_a_context_within_topk_is_the_attention_kind(toy):
+    cfg, params = toy["cfg"], toy["params"]
+    dense = keye_debug(layer_kinds=("attention",) * cfg.num_layers)
+    tokens = toy["tokens"][:, :cfg.indexer.topk]
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(forward(cfg, params, tokens),
+                                   forward(dense, params, tokens), atol=2e-6)
+        # and through the contiguous cache: a prompt, then a step
+        ours, theirs = (init_caches(c, 2, 16) for c in (cfg, dense))
+        a, ours = prefill(cfg, params, tokens[:, :15], ours)
+        b, theirs = prefill(dense, params, tokens[:, :15], theirs)
+        np.testing.assert_allclose(a, b, atol=2e-6)
+        a, _ = decode_step(cfg, params, tokens[:, 15:], ours)
+        b, _ = decode_step(dense, params, tokens[:, 15:], theirs)
+        np.testing.assert_allclose(a, b, atol=2e-6)
+        # one token more and the two part
+        longer = toy["tokens"][:, :40]
+        assert np.abs(np.asarray(forward(cfg, params, longer)
+                                 - forward(dense, params, longer))).max() > 1e-3
+
+
+# ------------------------------------------------ the contiguous cache
+
+
+@pytest.mark.parametrize("n", [9, 41])
+def test_prefill_and_decode_step_match_the_reference(toy, n):
+    """The cached forward hands no choice over; at float32 it makes the
+    uncached forward's, which the reference is given."""
+    cfg, params, tokens = toy["cfg"], toy["params"], toy["tokens"]
+    want = ref.forward(params, tokens, hp_of(cfg), toy["routes"],
+                       toy["selected"])
+    with jax.default_matmul_precision("highest"):
+        caches = init_caches(cfg, 2, 72)
+        logits, caches = prefill(cfg, params, tokens[:, :n], caches)
+        got = [logits]
+        for t in range(n, 72):
+            logits, caches = decode_step(cfg, params, tokens[:, t:t + 1],
+                                         caches)
+            got.append(logits)
+    assert rel(jnp.stack(got, 1), want[:, n - 1:]) <= TOL
+
+
+# ------------------------------------------------------ the paged programs
+
+
+@pytest.fixture(scope="module", params=["pallas"])
+def paged_run(request):
+    """Two prompts through the paged programs. Slot 1 takes a 53-token
+    prompt in chunks of 16 (past topk, over three chunk boundaries, ending
+    inside a chunk); slot 2 then a 33-token prompt (a page's first token
+    last) whose chunks take slot 1's decode row along (the fused turn);
+    then plain steps of both. Slots 0 and 3 hold no sequence, and every page
+    no table names is FILLED WITH NaN in every layer's three arrays, as a
+    released page would be: whatever read one would show."""
+    impl = request.param
+    cfg = keye_debug()
+    params = seeded(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(9), (2, 80), 0,
+                                cfg.vocab_size)
+    C, slots, T, P, n = 16, 4, 4, 24, {1: 53, 2: 33}
+    row = {1: 0, 2: 1}
+    tables = np.zeros((slots, P), np.int32)
+    for s in n:
+        tables[s] = 1 + s * P + np.arange(P)
+    caches = init_paged_caches(cfg, slots * P + 1 + 8, T, P)
+    named = np.unique(tables)
+    poisoned = np.setdiff1d(np.arange(slots * P + 9), named)
+    caches = [dataclasses.replace(c, **{
+        name: getattr(c, name).at[poisoned].set(jnp.nan)
+        for name in ("k", "v", "ik")}) for c in caches]
+    got = {s: [] for s in n}
+    routes = {s: [] for s in n}
+    picked = {s: [] for s in n}
+    cursor = {1: 0, 2: 0}
+    both = jnp.asarray(tables)
+
+    def step_rows(live):
+        active = np.zeros(slots, np.int32)
+        cursors = np.zeros(slots, np.int32)
+        for s in live:
+            active[s], cursors[s] = 1, cursor[s]
+        return StepRows(active, cursors, both, both,
+                        np.zeros(slots, np.float32),
+                        np.zeros(slots, np.uint32))
+
+    def ids_for(live):
+        ids = np.zeros(slots, np.int32)
+        for s in live:
+            ids[s] = tokens[row[s], cursor[s]]
+        return jnp.asarray(ids)
+
+    with jax.default_matmul_precision("highest"):
+        for s, live in ((1, []), (2, [1])):
+            prompt = np.asarray(tokens[row[s], :n[s]])
+            for c0 in range(0, n[s], C):
+                real = min(C, n[s] - c0)
+                padded = np.zeros((1, C), np.int32)
+                padded[0, :real] = prompt[c0:c0 + real]
+                _, caches, moe, logits, chosen = paged_prefill_into_slot(
+                    cfg, params, jnp.asarray(padded), real, np.int32(c0),
+                    both[s], both[s], caches, ids_for(live), np.int32(-1),
+                    np.float32(0), np.uint32(0), step_rows(live),
+                    attn=impl, moe_info=True, logits=True, selected=True)
+                r = np.asarray(moe["routes"])[:, 0]
+                routes[s].append(r[:, :real])
+                picked[s].append(np.asarray(chosen[0])[:, 0, :real])
+                cursor[s] = c0 + real
+                for other in live:
+                    got[other].append(logits[1 + other])
+                    routes[other].append(r[:, C + other][:, None])
+                    picked[other].append(np.asarray(chosen[1])[:, other])
+                    cursor[other] += 1
+            got[s].append(logits[0])
+        for _ in range(6):
+            live = [1, 2]
+            rows = step_rows(live)
+            _, caches, moe, logits, chosen = paged_decode_step(
+                cfg, params, ids_for(live), rows.active, rows.cursors,
+                rows.read_tables, rows.write_tables, caches,
+                rows.temperature, rows.seeds, attn=impl, moe_info=True,
+                logits=True, selected=True)
+            for s in live:
+                got[s].append(logits[s])
+                routes[s].append(np.asarray(moe["routes"])[:, s])
+                picked[s].append(np.asarray(chosen)[:, s])
+                cursor[s] += 1
+    return {"cfg": cfg, "params": params, "tokens": tokens, "got": got,
+            "routes": routes, "picked": picked, "n": n, "row": row,
+            "cursor": cursor, "caches": caches, "poisoned": poisoned}
+
+
+@pytest.mark.parametrize("slot", [1, 2])
+def test_paged_chunks_steps_and_fused_turns_match_the_reference(paged_run,
+                                                                slot):
+    run = paged_run
+    cfg, n, end = run["cfg"], run["n"][slot], run["cursor"][slot]
+    seq = run["tokens"][run["row"][slot]][None, :end]
+    routes = np.concatenate(run["routes"][slot], 1)[:, None]
+    # a program's choice spans its table's context: the sequence's part
+    picked = np.concatenate(
+        [p[..., :end] for p in run["picked"][slot]], 1)[:, None]
+    assert routes.shape[2] == end == picked.shape[2]
+    np.testing.assert_array_equal(
+        picked.sum(-1)[0, 0], np.minimum(np.arange(end) + 1,
+                                         cfg.indexer.topk))
+    got = jnp.stack(run["got"][slot])
+    assert np.isfinite(np.asarray(got)).all()
+    want = ref.forward(run["params"], seq, hp_of(cfg), routes, picked)[0]
+    assert rel(got, want[n - 1:]) <= TOL
+
+
+def test_the_paged_programs_left_the_poisoned_pages_alone(paged_run):
+    for c in paged_run["caches"]:
+        for pool in (c.k, c.v, c.ik):
+            assert np.isnan(np.asarray(pool[paged_run["poisoned"][1:]])).all()
+
+
+# ------------------------------------------------------------ the scheduler
+
+
+def serve(sched, prompts, new):
+    async def one(prompt):
+        queue = asyncio.Queue()
+        sched.submit(prompt, max_new_tokens=new, temperature=0.0,
+                     loop=asyncio.get_running_loop(), queue=queue)
+        out = []
+        while True:
+            kind, value, _ = await queue.get()
+            if kind == "tok":
+                out.append(value)
+            elif kind == "end":
+                return out
+            else:
+                raise RuntimeError(f"{kind}: {value}")
+
+    async def drive():
+        return await asyncio.gather(*(one(p) for p in prompts))
+
+    with jax.default_matmul_precision("highest"):
+        return asyncio.run(drive())
+
+
+def near_the_references_best(cfg, params, prompt, out):
+    seq = jnp.asarray([prompt + out[:-1]], jnp.int32)
+    want = ref.forward(params, seq, hp_of(cfg))[0][len(prompt) - 1:]
+    return all(logits.max() - logits[tok] <= 1e-3 * np.abs(want).max()
+               for logits, tok in zip(want, out))
+
+
+def test_the_scheduler_serves_the_kind_and_counts_its_work():
+    from ray_tpu.serve._private.continuous import ContinuousScheduler
+
+    cfg = keye_debug()
+    params = seeded(cfg)
+    tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(9), (4, 80), 0,
+                                           cfg.vocab_size))
+    new = 8
+    sched = ContinuousScheduler(cfg, params, slots=3, prefill_chunk=16,
+                                arena_len=96, page_tokens=4,
+                                prefix_cache=False, attn="reference")
+    prompts = [tokens[i, :n].tolist() for i, n in enumerate((70, 9, 33, 24))]
+    try:
+        served = serve(sched, prompts, new)
+        stats = sched.stats()
+        assert sched.compiled_programs() == 2
+    finally:
+        sched.shutdown()
+    for prompt, out in zip(prompts, served):
+        assert len(out) == new
+        assert near_the_references_best(cfg, params, prompt, out)
+    # every query row of a sequence, prompt and answer but the last token
+    rows = [c for p in prompts for c in range(len(p) + new - 1)]
+    steps = [len(p) + i for p in prompts for i in range(new - 1)]
+    L, k = cfg.num_layers, cfg.indexer.topk
+    assert stats["indexed_tokens_context"] == L * sum(c + 1 for c in rows)
+    assert stats["indexed_tokens_scored"] == stats["indexed_tokens_context"]
+    assert stats["indexed_tokens_attended"] == L * sum(
+        min(c + 1, k) for c in rows)
+    assert stats["indexed_step_tokens_attended"] == L * sum(
+        min(c + 1, k) for c in steps)
+    assert stats["indexed_step_tokens_context"] == L * sum(
+        c + 1 for c in steps)
+    assert stats["fused_turns"] > 0 and stats["pages_in_use"] == 0
+    assert "sparse_rows" not in stats  # another kind's
+
+
+def test_a_spliced_prefix_brings_its_index_keys_along():
+    """The prefix cache serves the kind: the second request splices the
+    first's pages — K, V and the index keys under one table — and answers
+    as a scheduler without the cache does."""
+    from ray_tpu.serve._private.continuous import ContinuousScheduler
+
+    cfg = keye_debug()
+    params = seeded(cfg)
+    tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(4), (96,), 0,
+                                           cfg.vocab_size)).tolist()
+    first, second = tokens[:64], tokens[:48] + tokens[70:90]
+    kw = dict(slots=2, prefill_chunk=16, arena_len=96, page_tokens=4,
+              attn="reference")
+    answers = {}
+    for cached in (True, False):
+        sched = ContinuousScheduler(cfg, params, prefix_cache=cached, **kw)
+        try:
+            answers[cached] = [serve(sched, [p], 6)[0]
+                               for p in (first, second)]
+            stats = sched.stats()
+        finally:
+            sched.shutdown()
+        if cached:
+            assert stats["prefix_hits"] == 1
+            assert stats["prefix_hit_tokens"] >= 44
+    assert answers[True] == answers[False]
+    assert near_the_references_best(cfg, params, second, answers[True][1])
+
+
+def test_the_scheduler_refuses_what_the_kind_cannot_have():
+    from ray_tpu.serve._private.continuous import ContinuousScheduler
+
+    cfg = keye_debug()
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    kw = dict(slots=2, prefill_chunk=16, arena_len=64, page_tokens=4,
+              attn="reference")
+    with pytest.raises(ValueError, match="speculative decoding cannot serve "
+                                         "a model with 'indexed_attention'"):
+        ContinuousScheduler(cfg, params, drafter=object(), **kw)
+    sched = ContinuousScheduler(cfg, params, prefix_cache=True, **kw)
+    try:
+        with pytest.raises(ValueError, match="not its index keys"):
+            sched.export_prefix([1, 2, 3, 4])
+    finally:
+        sched.shutdown()

@@ -880,3 +880,79 @@ def test_brumby_serve_programs_compile_and_fit(v5e):
         total = _fits(compiled)
         # the programs' own memory leaves room for the reference check
         assert total < 14.6e9, f"{name}: {total / 1e9:.1f} GB"
+
+
+def test_keye_serve_programs_compile_and_fit(v5e):
+    """The benchmark's Keye-VL-2.0-30B-A3B configuration (published widths:
+    hidden 2048, 32 query heads over 4 K/V heads of 128, an indexer of 16
+    heads of 64, 128 experts of 768; 5 layers, bf16) under its cell's
+    deployment (8 slots of 49664 tokens): the prefill chunk with the step's
+    rows along and the decode step, the indexer's kernels once a layer and
+    group of rows — scores, the counting selection, the chunk's masked
+    attention or the step's paged kernel over its gathered run — and the
+    experts' kernel once a layer; 7.50 GB of weights and the 4.32 GB pool
+    (K, V and the index key a token) beside the programs' own memory on one
+    16 GB chip."""
+    from ray_tpu.ops.paged_attention import resolve_impl
+
+    cfg, held, programs = _cell_programs(v5e, "keye_vl2_30b_a3b_l5",
+                                         "keye_longctx")
+    assert (cfg.embed_dim, cfg.head_dim, cfg.hidden_dim) == (2048, 128, 768)
+    assert cfg.num_heads // cfg.kv_heads == 8 and cfg.period == 1
+    assert cfg.indexer.topk == 2048 and cfg.mrope_section == (16, 24, 24)
+    lane = resolve_impl(cfg)
+    assert lane == "pallas"
+    assert 11.7e9 < held < 11.9e9
+    calls = {"prefill": {"index_score": 10, "indexed_select": 10,
+                         "indexed_chunk_attention": 5,
+                         "indexed_step_attention": 5,
+                         "moe_grouped_matmul": 5},
+             "decode": {"index_score": 5, "indexed_select": 5,
+                        "indexed_step_attention": 5,
+                        "moe_grouped_matmul": 5}}
+    for name, (program, args) in programs.items():
+        compiled = jax.jit(
+            functools.partial(program, cfg, attn=lane, moe_info=True),
+            donate_argnums=(6,)).lower(*args).compile()
+        assert _kernel_calls(compiled) == calls[name], name
+        total = _fits(compiled)
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert total < 13.2e9, f"{name}: {total / 1e9:.1f} GB"
+        assert temp < 1.3e9, f"{name}: {temp / 1e6:.0f} MB of temporaries"
+
+
+def test_keye_check_programs_fit_beside_the_pool(v5e):
+    """The largest program ``reference_check`` runs in the replica beside
+    the weights and the pool, on the cell's 8704-token check prompt and the
+    32 tokens served behind it: the cell states limits GIVEN the routes, so
+    the uncached whole-sequence ``forward`` up to whole tiles (the cached
+    prefill of the prompt is the same kernels over fewer rows, with a pool
+    of the layer's own of 114 MB). It does not go through
+    ``[32, S, S]`` scores: the kind's chunk kernel takes any number of
+    rows. The forward's 2.76 GB are its [8832, 151936] bf16 logits, which the
+    harness slices behind the program: why the configuration holds 5 layers
+    and not 6 (13.94 GB held would leave them 0.2 GB of slack)."""
+    from perfbench.lib import manifest as manifest_lib
+    from perfbench.lib.serve_app import GIVEN_PAD
+    from ray_tpu.models.transformer import forward
+
+    cfg, held, programs = _cell_programs(v5e, "keye_vl2_30b_a3b_l5",
+                                         "keye_longctx")
+    cell = manifest_lib.read_json(manifest_lib.load(), "cells",
+                                  "keye_longctx")
+    assert {"given_logit_err", "given_logit_rms_err"} <= set(
+        cell["check_tolerance"])
+    prompt, new = cell["check_prompt_tokens"], cell["check_new_tokens"]
+    first, n = prompt - 1, prompt - 1 + new
+    params = programs["decode"][1][0]
+    chip = params["embed"]["table"].sharding
+
+    def run(params, tokens):
+        logits, routes = forward(cfg, params, tokens, return_routes=True)
+        return logits[0, first:n].astype(jnp.float32), routes
+
+    compiled = jax.jit(run).lower(
+        params, _on(chip, (1, n + -n % GIVEN_PAD), jnp.int32)).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2.9e9, f"forward: {temp / 1e9:.2f} GB of temporaries"
+    assert held + temp < 14.8e9
