@@ -80,10 +80,12 @@ weights from ``--seed``):
            choices on which the float32 reference's own indexer parts from
            the bf16 one is reported); every page no table names is FILLED
            WITH NaN in K, V and the index keys before the first program and
-           still is after the last; then the benchmark's own comparison
-           (reference_check with the routes given, under the limits of
-           cells/keye_longctx.json) on the float8 CONTROL, which has to
-           come out NOT correct
+           still is after the last; then indexed_select alone on a 512
+           chunk's rows at 2k, 16k, 38k and 49k of a 49,664-lane table:
+           its milliseconds, the passes a tile ran, its choice a sort's;
+           then the benchmark's own comparison (reference_check with the
+           routes given, under the limits of cells/keye_longctx.json) on
+           the float8 CONTROL, which has to come out NOT correct
   serve    serve.run(build_app(preset="gpt2_small")) answers 8 concurrent
            requests: six through the handle, one streamed, one over HTTP
 
@@ -1059,8 +1061,9 @@ def keye_task(seed: int, control: bool = True) -> dict:
     + 1 tokens, past ``topk`` twice over, and 2065 = 129 x 16 + 1, just past
     it), the second's chunks taking the first's decode row along; every
     page no table names filled with NaN in K, V and the index keys, as a
-    released page would be. Then the timed programs at the cell's own
-    shapes, and (``control``) the float8 control through the harness's own
+    released page would be. Then the selection alone at the cell's chunk
+    (``select_timing``), and (``control``) the float8 control through the
+    harness's own
     comparison under the limits of ``cells/keye_longctx.json``, which must
     refuse it."""
     import dataclasses
@@ -1206,6 +1209,7 @@ def keye_task(seed: int, control: bool = True) -> dict:
     if bad:
         raise RuntimeError(f"keye: {bad}: {out}")
     del caches
+    out["select"] = select_timing(seed, topk, ref)
     if control:
         seen = out["float8_control"] = float8_control(
             cfg, hp, params, seed, ref, "keye", "keye_longctx")
@@ -1214,6 +1218,49 @@ def keye_task(seed: int, control: bool = True) -> dict:
             raise RuntimeError("keye: the float8 control passes a "
                                f"comparison that has to refuse it: {seen}")
     return {**out, **device_report()}
+
+
+def select_timing(seed: int, topk: int, ref) -> dict:
+    """``indexed_select`` alone (ISSUE 53) at the cell's chunk: 512 rows
+    that end at 2k, 16k, 38k and 49k of a 49,664-lane table of seeded
+    scores, every lane behind a row's position NaN (nobody computed it);
+    four such chunks a call, so that the device is timed and not the
+    dispatch. By last position: the milliseconds a chunk, the fewest and
+    most passes a tile of 8 rows ran (33 run every bit, 50 with a tied
+    cut's passes, 2 a tile that takes every token) — and the choice is a
+    sort's."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import indexed_attention as ia
+
+    lanes, rows, chunks, out = 49664, 512, 4, {}
+    scores = jax.random.normal(jax.random.PRNGKey(seed),
+                               (chunks, rows, lanes), jnp.float32)
+    run = jax.jit(lambda s, p: ia.select(s, p, topk, False, passes=True))
+    for end in (2048, 16384, 38912, lanes):
+        pos = jnp.broadcast_to(jnp.arange(end - rows, end, dtype=jnp.int32),
+                               (chunks, rows))
+        seen = jnp.where(jnp.arange(lanes) <= pos[..., None], scores, jnp.nan)
+        tau, bound, passes = jax.block_until_ready(run(seen, pos))
+        t0 = time.perf_counter()
+        for _ in range(20):
+            last = run(seen, pos)
+        jax.block_until_ready(last)
+        ms = (time.perf_counter() - t0) / 20 / chunks * 1e3
+        got = ia.chosen(seen, pos[..., None], tau[..., None],
+                        bound[..., None])
+        want = ref.select_block(scores, end - rows, topk)
+        if not bool((got == want).all()):
+            raise RuntimeError(f"keye: the selection at {end} is not a "
+                               f"sort's: {int((got != want).sum())} choices")
+        passes = np.asarray(passes)[:, ::ia.SELECT_ROWS]
+        out[str(end)] = {"ms": round(ms, 4), "passes": [
+            int(passes.min()), int(passes.max())]}
+    return out
 
 
 class Float8Control:

@@ -15,11 +15,14 @@ and their weights ``w`` (already scaled):
      without a sort: the kernel ``indexed_select`` finds a row's ``topk``-th
      largest score by counting passes over the row in fast memory (a binary
      search over the 32 bits of the scores' order-preserving integer form),
-     then how many of the scores EQUAL to it belong, by index. What it
-     hands on is two numbers a query, the cut ``tau`` and the index bound
-     ``M``: ``s`` is chosen iff ``s <= t`` and (``I > tau`` or (``I == tau``
-     and ``s < M``)) — ``chosen`` — so no mask a (query, token) is ever
-     written.
+     then how many of the scores EQUAL to it belong, by index. A tile of
+     rows copies in and walks the lanes up to its own last position only
+     (``select_lanes``), and its passes stop once every row's count is
+     EXACTLY ``topk``: the choice is decided, and one pass more reads the
+     cut off it. What it hands on is two numbers a query, the cut ``tau``
+     and the index bound ``M``: ``s`` is chosen iff ``s <= t`` and (``I >
+     tau`` or (``I == tau`` and ``s < M``)) — ``chosen`` — so no mask a
+     (query, token) is ever written.
   3. attention over the choice. A decode step (one query a row) sorts its
      chosen positions to the front, gathers their ``topk`` rows of K and V
      out of the pages and runs the paged kernel over that compact run
@@ -54,7 +57,10 @@ from ray_tpu.ops.paged_attention import NEG_INF, paged_attention
 
 _KEY_TILE = 512      # context tokens of one grid cell of a kernel
 _SCORE_TOKENS = 128  # query tokens of one tile of the score kernel
-_SELECT_ROWS = 8     # query rows of one tile of the selection kernel
+SELECT_ROWS = 8      # query rows of one tile of the selection kernel
+_SELECT_LANES = 4096  # context tokens of one segment of its passes' walk,
+_FOLD_LANES = 1024    # which a pass folds into so many before it sums them
+_LOOK_AFTER, _LOOK_EVERY = 20, 4  # passes before a tile asks: all decided?
 _ATTN_ROWS = 2048    # query rows (tokens x heads) of one tile of the chunk
 _INT_MIN = -2 ** 31
 
@@ -93,13 +99,19 @@ def chunk_tokens(num_heads: int) -> int:
     return max(_ATTN_ROWS // num_heads // 8 * 8, 8)
 
 
+def context_tokens(pages: int, page_tokens: int) -> int:
+    """The tokens a table of ``pages`` spans in whole key tiles: the lanes
+    of a row of index scores (the scheduler's counters mirror it)."""
+    return -(-pages * page_tokens // _KEY_TILE) * _KEY_TILE
+
+
 def _context(tables, page_tokens: int):
     """A row's table padded to whole key tiles (with the garbage page, whose
     tokens lie behind every position of the row) and the tokens it spans."""
-    pages = (-(-tables.shape[1] * page_tokens // _KEY_TILE)
-             * _KEY_TILE // page_tokens)
-    tables = jnp.pad(tables, ((0, 0), (0, pages - tables.shape[1])))
-    return tables, pages * page_tokens
+    ctx = context_tokens(tables.shape[1], page_tokens)
+    tables = jnp.pad(tables, ((0, 0), (
+        0, ctx // page_tokens - tables.shape[1])))
+    return tables, ctx
 
 
 def _tiles(positions, tokens: int):
@@ -176,73 +188,213 @@ def index_scores(qi, w, ik_pool, tables, positions, interpret: bool):
 # --------------------------------------------------------------- selection
 
 
-def _select_kernel(s_ref, pos_ref, o_ref, key_scr, *, topk, index_bits):
-    s = s_ref[...] + 0.0                        # -0.0 is 0.0: one order
-    idx = lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    bits = lax.bitcast_convert_type(s, jnp.int32)
+def _segment(context: int) -> int:
+    """Lanes of one segment of the selection's walk over such a table."""
+    return min(_SELECT_LANES, context)
+
+
+def select_lanes(positions, context: int, xp=jnp):
+    """positions [rows] of the selection's query rows, in the order it takes
+    them -> the lanes each tile of ``SELECT_ROWS`` of them walks, [tiles]:
+    whole segments up to the tile's last position, the table's at most. A
+    function of the positions alone: ``select`` prefetches it and the
+    scheduler's counters mirror it (``xp``: NumPy there)."""
+    tiles = -(-positions.shape[0] // SELECT_ROWS)
+    pos = xp.pad(positions, (0, tiles * SELECT_ROWS - positions.shape[0]))
+    last = xp.maximum(pos.reshape(tiles, SELECT_ROWS).max(axis=-1), 0)
+    W = _segment(context)
+    return W * xp.minimum(last // W + 1, -(-context // W))
+
+
+def _select_kernel(lanes_ref, s_ref, pos_ref, o_ref, s_buf, key_scr, sems, *,
+                   topk, index_bits):
+    """One tile of rows. Its scores stay in HBM; the segments of lanes up
+    to the tile's last position (``lanes_ref``) are copied in — the NEXT
+    tile's while this one counts — made into keys, and every pass is a loop
+    over those segments: what lies behind the reach is never read."""
+    r = pl.program_id(0)
+    n_seg, rows, W = key_scr.shape
+    ctx = s_ref.shape[1]
+    tail = ctx - (n_seg - 1) * W  # lanes of the table's last segment
+    first = lambda c: pl.multiple_of(c * W, W)
+
+    def copies(tile, buf, wait=False):
+        """Start (or wait for) the copies of ``tile``'s live segments."""
+        def one(c, width):
+            cp = pltpu.make_async_copy(
+                s_ref.at[pl.ds(pl.multiple_of(tile * rows, rows), rows),
+                         pl.ds(first(c), width)],
+                s_buf.at[buf, c, :, pl.ds(0, width)], sems.at[buf])
+            cp.wait() if wait else cp.start()
+
+        def segment(c, _):
+            if tail == W:
+                one(c, W)
+            else:
+                pl.when(c < n_seg - 1)(lambda: one(c, W))
+                pl.when(c == n_seg - 1)(lambda: one(c, tail))
+            return 0
+
+        lax.fori_loop(0, lanes_ref[tile] // W, segment, 0)
+
+    buf = lax.rem(r, 2)
+    pl.when(r == 0)(lambda: copies(0, 0))
+    pl.when(r + 1 < pl.num_programs(0))(lambda: copies(r + 1, 1 - buf))
+    copies(r, buf, wait=True)
+    reach = lanes_ref[r] // W
+    pos = pos_ref[...]
+    # a pass folds a segment's lanes into ``A`` of them, a few wide ops a
+    # segment: the kernel's body is traced in every process (PERF.md 2)
+    A = math.gcd(W, _FOLD_LANES)
+    blocks = [slice(j * A, (j + 1) * A) for j in range(W // A)]
+
+    def walk(segment, start):
+        """``segment(c, acc)`` over the live segments, from [rows, A] of
+        ``start``."""
+        return lax.fori_loop(0, reach, segment,
+                             jnp.full((rows, A), start, jnp.int32))
+
+    def sweep(op, start, value):
+        """One pass over the live lanes: ``value(keys, first lane)`` of every
+        [rows, A] block of keys, folded by ``op`` -> [rows, A]."""
+        return walk(lambda c, acc: functools.reduce(op, [
+            value(key_scr[c, :, b], first(c) + b.start) for b in blocks],
+            acc), start)
+
+    count = lambda hit: jnp.sum(sweep(
+        jnp.add, 0, lambda key, at: hit(key, at).astype(jnp.int32)),
+        axis=1, keepdims=True)
+
     # the integers order as the floats do; what lies behind the query is
-    # below every score
-    key_scr[...] = jnp.where(idx <= pos_ref[...], jnp.where(
-        bits >= 0, bits, bits ^ jnp.int32(0x7FFFFFFF)), jnp.int32(_INT_MIN))
-    count = lambda hit: jnp.sum(hit.astype(jnp.int32), axis=1, keepdims=True)
+    # below every score. The pass that makes them counts the keys >= 0
+    def make_keys(c, acc):
+        s = s_buf[buf, c]
+        s = jnp.where(s == 0.0, 0.0, s)         # -0.0 is 0.0: one order
+        idx = first(c) + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        bits = lax.bitcast_convert_type(s, jnp.int32)
+        key = jnp.where(idx <= pos, jnp.where(
+            bits >= 0, bits, bits ^ jnp.int32(0x7FFFFFFF)),
+            jnp.int32(_INT_MIN))
+        key_scr[c] = key
+        return functools.reduce(jnp.add, [
+            (key[:, b] >= 0).astype(jnp.int32) for b in blocks], acc)
 
-    # the largest integer that at least ``topk`` keys reach, bit by bit
-    cut = jnp.where(count(key_scr[...] >= 0) >= topk, jnp.int32(0),
-                    jnp.int32(_INT_MIN))
+    have = jnp.sum(walk(make_keys, 0), axis=1, keepdims=True)
+    # the largest integer that at least ``topk`` keys reach, bit by bit;
+    # ``have``: how many reach it, once a count has said (-1 before)
+    cut = jnp.where(have >= topk, jnp.int32(0), jnp.int32(_INT_MIN))
+    have = jnp.where(have >= topk, have, -1)
+    # a row is decided when EXACTLY ``topk`` keys reach its cut (they are
+    # its choice, and no lower bit can change it) or it has fewer tokens
+    # than ``topk`` (it takes them all); a tile stops when all its rows are
+    few = pos + 1 < topk
+    open_rows = lambda have: jnp.any(jnp.logical_not(
+        jnp.logical_or(have == topk, few))).astype(jnp.int32)
 
-    def raise_cut(i, cut):
-        higher = cut | jnp.left_shift(jnp.int32(1), 30 - i)
-        return jnp.where(count(key_scr[...] >= higher) >= topk, higher, cut)
+    def raise_cut(i, state, bit):
+        cut, have = state
+        higher = cut | jnp.left_shift(jnp.int32(1), bit - i)
+        reached = count(lambda key, at: key >= higher)
+        return (jnp.where(reached >= topk, higher, cut),
+                jnp.where(reached >= topk, reached, have))
 
-    cut = lax.fori_loop(0, 31, raise_cut, cut)
-    # of the keys EQUAL to it, those before index ``bound`` fill the choice
-    need = topk - count(key_scr[...] > cut)
+    def passes_then_look(state):
+        """To ask whether every row is decided costs a few hundred cycles
+        (a vector's verdict has to reach the scalar side), and between the
+        2048th and 2049th largest of 17k-49k scores the first bit that
+        differs lies past the 20th: ``_LOOK_AFTER`` passes, a look, then
+        one every ``_LOOK_EVERY`` (PERF.md 6, PR 53)."""
+        bit, cut, have, _ = state
+        n = jnp.minimum(jnp.where(bit == 30, _LOOK_AFTER, _LOOK_EVERY),
+                        bit + 1)
+        cut, have = lax.fori_loop(
+            0, n, functools.partial(raise_cut, bit=bit), (cut, have))
+        return bit - n, cut, have, open_rows(have)
 
-    def raise_bound(i, bound):
-        higher = bound | jnp.left_shift(jnp.int32(1), index_bits - 1 - i)
-        fits = count(jnp.logical_and(key_scr[...] == cut,
-                                     idx < higher)) <= need
-        return jnp.where(fits, higher, bound)
+    bit, cut, have, _ = lax.while_loop(
+        lambda state: jnp.logical_and(state[0] >= 0, state[3] > 0),
+        passes_then_look, (jnp.int32(30), cut, have, open_rows(have)))
+    # the least key of a decided row's choice IS its ``topk``-th largest:
+    # the cut the remaining passes would have come to
+    exact = have == topk
+    int_max = jnp.int32(2 ** 31 - 1)
+    cut = jnp.where(exact, jnp.min(sweep(
+        jnp.minimum, int_max,
+        lambda key, at: jnp.where(key >= cut, key, int_max)),
+        axis=1, keepdims=True), cut)
+    # of the keys EQUAL to the cut, those before index ``bound`` fill the
+    # choice. Passes only where a row went through every bit and still has
+    # more than ``topk`` keys at or above its cut: more lie ON the cut than
+    # it has room for (of any other row every one belongs, and the passes
+    # come to every bit set)
+    full = jnp.full_like(cut, (1 << index_bits) - 1)
+    tied = jnp.logical_and(jnp.logical_not(exact), jnp.logical_not(few))
 
-    # (no row of the tile has more keys on its cut than it needs, the
-    # usual case: every one of them belongs, and the passes are skipped)
-    bound = lax.cond(
-        jnp.any(count(key_scr[...] == cut) > need),
-        lambda: lax.fori_loop(0, index_bits, raise_bound,
-                              jnp.zeros_like(cut)),
-        lambda: jnp.full_like(cut, (1 << index_bits) - 1))
+    def ties():
+        on_cut = count(lambda key, at: key == cut)
+        need = topk - (have - on_cut)
+
+        def raise_bound(i, bound):
+            higher = bound | jnp.left_shift(jnp.int32(1), index_bits - 1 - i)
+            fits = count(lambda key, at: jnp.logical_and(
+                key == cut, at + lax.broadcasted_iota(
+                    jnp.int32, key.shape, 1) < higher)) <= need
+            return jnp.where(fits, higher, bound)
+
+        return lax.fori_loop(0, index_bits, raise_bound, jnp.zeros_like(cut))
+
+    ran_ties = jnp.any(tied)
+    bound = lax.cond(ran_ties, ties, lambda: full)
+    # a row of fewer tokens than ``topk`` takes them all: its cut lies
+    # below every score, and its bound where passes over the table's dead
+    # lanes, all tied down there, came to
+    bound = jnp.where(few, topk if ctx > topk else full, bound)
     tau = lax.bitcast_convert_type(
         jnp.where(cut >= 0, cut, cut ^ jnp.int32(0x7FFFFFFF)), jnp.float32)
     tau = jnp.where(cut == jnp.int32(_INT_MIN), -jnp.inf, tau)
+    # the keys, the cuts, the least, the ties
+    passes = (32 - bit + jnp.where(ran_ties, 1 + index_bits, 0)).astype(
+        jnp.float32)
     lane = lax.broadcasted_iota(jnp.int32, o_ref.shape, 1)
     o_ref[...] = jnp.where(lane == 0, tau, jnp.where(
-        lane == 1, bound.astype(jnp.float32), 0.0))
+        lane == 1, bound.astype(jnp.float32), jnp.where(
+            lane == 2, passes, 0.0)))
 
 
-def select(scores, positions, topk: int, interpret: bool):
+def select(scores, positions, topk: int, interpret: bool,
+           passes: bool = False):
     """scores [B, S, context] float32, positions [B, S] -> (tau [B, S]
-    float32, bound [B, S] int32): the two numbers ``chosen`` reads."""
+    float32, bound [B, S] int32): the two numbers ``chosen`` reads; with
+    ``passes`` also the passes over its live lanes each row's tile ran, [B,
+    S] int32 (tests and ``chip_smoke.py`` ask)."""
     B, S, ctx = scores.shape
     rows = B * S
-    padded = -(-rows // _SELECT_ROWS) * _SELECT_ROWS
+    padded = -(-rows // SELECT_ROWS) * SELECT_ROWS
     flat = jnp.pad(scores.reshape(rows, ctx), ((0, padded - rows), (0, 0)))
-    pos = jnp.pad(positions.reshape(rows, 1).astype(jnp.int32),
-                  ((0, padded - rows), (0, 0)))
+    pos = positions.reshape(rows).astype(jnp.int32)
+    W = _segment(ctx)
+    n_seg = -(-ctx // W)
+    tile = lambda r, lanes: (r, 0)
     out = pl.pallas_call(
         functools.partial(_select_kernel, topk=topk,
                           index_bits=max(ctx.bit_length(), 1)),
-        grid=(padded // _SELECT_ROWS,),
-        in_specs=[pl.BlockSpec((_SELECT_ROWS, ctx), lambda r: (r, 0)),
-                  pl.BlockSpec((_SELECT_ROWS, 1), lambda r: (r, 0))],
-        out_specs=pl.BlockSpec((_SELECT_ROWS, 128), lambda r: (r, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(padded // SELECT_ROWS,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec((SELECT_ROWS, 1), tile)],
+            out_specs=pl.BlockSpec((SELECT_ROWS, 128), tile),
+            scratch_shapes=[
+                pltpu.VMEM((2, n_seg, SELECT_ROWS, W), jnp.float32),
+                pltpu.VMEM((n_seg, SELECT_ROWS, W), jnp.int32),
+                pltpu.SemaphoreType.DMA((2,))]),
         out_shape=jax.ShapeDtypeStruct((padded, 128), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((_SELECT_ROWS, ctx), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",), vmem_limit_bytes=96 << 20),
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=32 << 20),
         name="indexed_select", interpret=interpret,
-    )(flat, pos)
-    return (out[:rows, 0].reshape(B, S),
-            out[:rows, 1].astype(jnp.int32).reshape(B, S))
+    )(select_lanes(pos, ctx), flat,
+      jnp.pad(pos, (0, padded - rows))[:, None])
+    tau, *counts = (out[:rows, i].reshape(B, S) for i in range(3))
+    return (tau, *(c.astype(jnp.int32) for c in counts[:1 + passes]))
 
 
 def chosen(scores, positions, tau, bound, first=0):
@@ -418,7 +570,10 @@ def _attention(q, qi, w, k_pool, v_pool, ik_pool, tables, positions, lengths,
                sizes, impl, return_selected, interpret):
     S = q.shape[1]
     scores = index_scores(qi, w, ik_pool, tables, positions, interpret)
-    tau, bound = select(scores, positions, sizes.topk, interpret)
+    # a row that attends nothing asks nothing of the selection: it stands
+    # at position 0 there, so no tile walks a table for its sake
+    tau, bound = select(scores, jnp.where((lengths + S > 0)[:, None],
+                                          positions, 0), sizes.topk, interpret)
     take = None
     if S == 1 or return_selected:
         take = chosen(scores, positions[..., None], tau[..., None],

@@ -25,7 +25,8 @@ import numpy as np
 from ray_tpu._private.metrics import Counter
 from ray_tpu.models.transformer import (INDEXED, LINEAR, RETENTION, SLIDING,
                                         SPARSE, STATE_KINDS, state_shapes)
-from ray_tpu.ops.indexed_attention import chunk_tokens
+from ray_tpu.ops.indexed_attention import (SELECT_ROWS, chunk_tokens,
+                                           context_tokens, select_lanes)
 from ray_tpu.ops.paged_attention import streamed_tokens, tile_sizes
 
 _m_attn_bytes = Counter(
@@ -107,6 +108,16 @@ def _indexed(work, n, layers, qk, cursors, real):
     of queries in whole key tiles of 512, which attend their mean choice of
     it."""
     sizes = work.cfg.indexer
+    # the selection's kernel: a chunk's rows are all ``qk`` of its tokens;
+    # a step's are the slots, the live ones at their cursors and the others
+    # at 0 — one tile of them, so the largest cursor is its reach (of more
+    # slots than a tile holds, every tile is counted at that reach)
+    rows = np.arange(qk) + cursors[0] if qk > 1 else np.full(
+        work.slots, max(cursors, default=0))
+    lanes = select_lanes(rows, work.indexed_context, np)  # [tiles]
+    n["indexed_select_lanes"] += layers * SELECT_ROWS * int(lanes.sum())
+    n["indexed_select_lanes_table"] += (
+        layers * SELECT_ROWS * len(lanes) * work.indexed_context)
     t = np.asarray(cursors)[:, None] + np.arange(real)  # [rows, real]
     attended = int(sizes.attended_tokens(t).sum())
     context = int((t + 1).sum())
@@ -137,7 +148,9 @@ _KINDS = {
     INDEXED: (_indexed, ("indexed_tokens_scored", "indexed_tokens_attended",
                          "indexed_tokens_context",
                          "indexed_step_tokens_attended",
-                         "indexed_step_tokens_context")),
+                         "indexed_step_tokens_context",
+                         "indexed_select_lanes",
+                         "indexed_select_lanes_table")),
     SLIDING: (_window, ("window_attn_step_keys", "full_attn_step_keys",
                         "window_attn_chunk_pairs", "full_attn_chunk_pairs",
                         "window_tokens_held", "window_tokens_unreleased")),
@@ -173,6 +186,9 @@ class Work:
                 self._row_bytes)[0]
         if INDEXED in kinds:
             self.indexed_chunk_tokens = chunk_tokens(cfg.num_heads)
+            self.slots = slots
+            self.indexed_context = context_tokens(pages_per_slot,
+                                                  page_tokens)
         # a float32 state a slot a layer that keeps one, by kind
         state_bytes = 4 * sum(
             math.prod(shape) for kind in kinds if kind in STATE_KINDS

@@ -174,6 +174,112 @@ def test_the_selection_is_the_references_ties_and_all(topk):
         assert max(tied) > 1
 
 
+def _plain_cut(keys, t, topk, lanes):
+    """The two numbers of one row as the kernel before ISSUE 53 came to
+    them, written plainly (a sort): ``keys`` float32 [lanes of the table],
+    the query at ``t``. ``tau``: the ``topk``-th largest of the keys at or
+    before ``t`` (-inf where there are fewer than ``topk``: what lies behind
+    the query ties below every score); ``bound``: where more keys lie ON the
+    cut than the choice has room for, the index before which they belong,
+    else every bit of an index set."""
+    full = (1 << lanes.bit_length()) - 1
+    live = keys[:t + 1] + np.float32(0.0)
+    if t + 1 < topk:
+        return -np.inf, topk if lanes > topk else full
+    tau = np.sort(live)[::-1][topk - 1]
+    need = topk - int((live > tau).sum())
+    on = np.flatnonzero(live == tau)
+    return tau, int(on[need]) if len(on) > need else full
+
+
+def _select_case(name):
+    """-> (scores [rows, lanes] float32, positions [rows], topk): what the
+    selection's loops over a tile's live segments can get wrong."""
+    W = ia._SELECT_LANES
+    lanes = 3 * W + 512  # a table whose last segment is a short one
+    rng = np.random.default_rng(sum(map(ord, name)))
+    rows, topk = 16, 64
+    scores = rng.standard_normal((rows, lanes)).astype(np.float32)
+    positions = rng.integers(topk, lanes, rows)
+    if name == "segment_edges":
+        positions = np.array([511, 512, 513, W - 1, W, W + 1, 2 * W - 1,
+                              2 * W, 2 * W + 1, 3 * W - 1, 3 * W, 3 * W + 1,
+                              lanes - 2, lanes - 1, 0, 1])
+    elif name == "a_steps_tile":  # 8 rows at contexts from 0 to the end
+        positions = np.array([0, lanes - 1, 5, W, topk - 1, 3 * W + 7, 700,
+                              2 * W - 1] * 2)
+    elif name == "around_topk":  # t + 1 below, at and above topk
+        positions = np.array([topk - 3, topk - 2, topk - 1, topk, topk + 1,
+                              0, 1, 2 * topk] * 2)
+    elif name == "a_reach_of_topk_lanes":
+        topk = W
+        positions = np.array([W - 2, W - 1, W, W + 1, 0, 5, 2 * W - 1,
+                              2 * W] * 2)
+    elif name == "topk_past_the_table":
+        topk = lanes + 5
+    elif name == "ties_across_a_segments_edge":
+        # the cut falls on a run of equal scores that straddles an edge
+        scores = np.where(scores > 1.0, scores, np.float32(0.25))
+        scores[:, W - 40:W + 40] = 1.0
+        positions = np.array([W + 3, W + 39, 2 * W, lanes - 1] * 4)
+    elif name == "all_equal":
+        scores[:] = 0.5
+    elif name == "signed_zeros":
+        scores = np.where(rng.random(scores.shape) < 0.5, -0.0,
+                          0.0).astype(np.float32)
+        scores[:, ::7] = rng.standard_normal(scores[:, ::7].shape)
+    elif name == "negative_only":
+        scores = -np.abs(scores) - 1
+    elif name == "coarse":  # many ties everywhere
+        scores = np.round(scores * 4) / 4
+    else:
+        assert name == "random", name
+    return scores, positions, topk
+
+
+@pytest.mark.parametrize("name", [
+    "random", "segment_edges", "a_steps_tile", "around_topk",
+    "a_reach_of_topk_lanes", "topk_past_the_table",
+    "ties_across_a_segments_edge", "all_equal", "signed_zeros",
+    "negative_only", "coarse"])
+@pytest.mark.parametrize("behind", [np.nan, np.inf])
+def test_the_selection_walks_a_tiles_own_context(name, behind):
+    """ISSUE 53: the passes walk the segments up to a tile's last position
+    and stop when every row's choice is decided — and come to the same
+    choice, the same cut and the same bound as passes over the whole table.
+    Every lane behind a row's position holds ``behind``: nobody computed
+    it, and the kernel must not let it count."""
+    scores, positions, topk = _select_case(name)
+    rows, lanes = scores.shape
+    idx = np.arange(lanes)
+    dirty = np.where(idx[None] <= positions[:, None], scores,
+                     np.float32(behind))
+    pos = jnp.asarray(positions, jnp.int32)[None]
+    tau, bound, passes = ia.select(jnp.asarray(dirty)[None], pos, topk, True,
+                                   passes=True)
+    want = [_plain_cut(scores[r], int(positions[r]), topk, lanes)
+            for r in range(rows)]
+    np.testing.assert_array_equal(np.asarray(tau)[0],
+                                  np.float32([w[0] for w in want]))
+    np.testing.assert_array_equal(np.asarray(bound)[0],
+                                  [w[1] for w in want])
+    got = np.asarray(ia.chosen(jnp.asarray(dirty)[None], pos[..., None],
+                               tau[..., None], bound[..., None]))[0]
+    plain = scores + np.float32(0.0)  # -0.0 orders as 0.0: ties by index
+    taken = np.stack([np.asarray(ref.select_block(
+        jnp.asarray(plain[None, r:r + 1]), int(positions[r]), topk))[0, 0]
+        for r in range(rows)])
+    np.testing.assert_array_equal(got, taken)
+    # a tile whose rows' cuts fall on no tie stops early; one whose rows
+    # all take every token runs the pass that makes the keys and one more
+    passes = np.asarray(passes)[0]
+    assert (passes[:8] == passes[0]).all() and (passes[8:] == passes[8]).all()
+    if name == "random":
+        assert passes.max() < 34
+    if name == "topk_past_the_table":
+        assert (passes == 2).all()
+
+
 def test_the_score_kernel_is_the_references_scores():
     qi, w, ki = _scores(5, rows=40, keys=200)
     T, P = 8, 26
@@ -561,6 +667,48 @@ def test_the_scheduler_serves_the_kind_and_counts_its_work():
         c + 1 for c in steps)
     assert stats["fused_turns"] > 0 and stats["pages_in_use"] == 0
     assert "sparse_rows" not in stats  # another kind's
+    # the selection's walk: a toy table of one segment is walked whole, a
+    # tile of 8 rows a call: a chunk's 16 rows are two, the 3 slots one,
+    # in a plain step and in every chunk's program, rows live or not
+    calls = 3 * stats["prefill_chunks"] + (
+        stats["decode_steps"] - stats["fused_turns"])
+    lanes = ia.context_tokens(24, 4)
+    assert stats["indexed_select_lanes_table"] == L * 8 * lanes * calls
+    assert stats["indexed_select_lanes"] == stats["indexed_select_lanes_table"]
+
+
+@pytest.mark.parametrize("qk,cursors,idle", [
+    (512, [0], 0), (512, [16384], 0), (512, [49152], 0),
+    (1, [20000, 45000, 17000, 30000, 41000], 3), (1, [2047], 7), (1, [], 8)])
+def test_the_counters_mirror_the_selections_walk(qk, cursors, idle):
+    """``indexed_select_lanes``: rows x lanes the kernel's passes walk, from
+    the function ``select`` prefetches its reach from, over the positions
+    the program hands it (a chunk's tokens; the slots, a row that is not
+    live at 0); ``indexed_select_lanes_table``: rows x the table's lanes,
+    what passes over the whole table walked. At the cell's sizes."""
+    from ray_tpu.models import llama_debug
+    from ray_tpu.serve._private.work import Work
+
+    sizes = dict(slots=8, page_tokens=16, pages_per_slot=3104, itemsize=2,
+                 lane="reference")
+    cfg = keye_debug()
+    work = Work(cfg, **sizes)
+    work.record(qk, cursors, idle, real=qk - 12 if qk > 1 else None)
+    got = work.stats()
+    positions = (cursors[0] + np.arange(qk) if qk > 1
+                 else np.array(cursors + [0] * idle))
+    rows = -(-len(positions) // 8) * 8
+    walked = np.asarray(ia.select_lanes(jnp.asarray(positions), 49664))
+    assert got["indexed_select_lanes"] == cfg.num_layers * 8 * walked.sum()
+    assert got["indexed_select_lanes_table"] == cfg.num_layers * rows * 49664
+    assert 0 < got["indexed_select_lanes"] <= cfg.num_layers * rows * (
+        49664 + ia._SELECT_LANES)
+    if qk == 1:  # the step's one tile walks up to its last live row
+        W = ia._SELECT_LANES
+        assert walked.tolist() == [(max(cursors, default=0) // W + 1) * W]
+    other = Work(llama_debug(), **sizes)
+    other.record(qk, cursors, idle)
+    assert not [key for key in other.stats() if key.startswith("indexed_")]
 
 
 def test_a_spliced_prefix_brings_its_index_keys_along():

@@ -921,6 +921,25 @@ def test_keye_serve_programs_compile_and_fit(v5e):
         assert temp < 1.3e9, f"{name}: {temp / 1e6:.0f} MB of temporaries"
 
 
+@pytest.mark.parametrize("rows", [(1, 512), (8, 1)])
+def test_keye_selection_compiles_at_the_cells_shapes(v5e, rows):
+    """ISSUE 53: ``indexed_select`` alone at the cell's two shapes — a 512
+    chunk's rows and the 8 slots' step, over a table of 49,664 lanes whose
+    last segment is a short one, ``topk`` 2048: the scores stay in HBM (no
+    temporary of the table's width), the live segments are copied in by
+    hand and the passes loop over them under a ``while``."""
+    from ray_tpu.ops import indexed_attention as ia
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    compiled = jax.jit(
+        lambda scores, positions: ia.select(scores, positions, 2048, False,
+                                            passes=True)).lower(
+        _on(chip, (*rows, 49664), jnp.float32),
+        _on(chip, rows, jnp.int32)).compile()
+    assert _kernel_calls(compiled) == {"indexed_select": 1}
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 def test_keye_check_programs_fit_beside_the_pool(v5e):
     """The largest program ``reference_check`` runs in the replica beside
     the weights and the pool, on the cell's 8704-token check prompt and the
