@@ -90,8 +90,10 @@ weights from ``--seed``):
            requests: six through the handle, one streamed, one over HTTP
 
 ``--chips 4`` runs instead, and only, what exists across chips: one worker
-holding four chips (fsdp=2 x tp=2) against the same job on one device, and
-four one-chip actors alive together.
+holding four chips (fsdp=2 x tp=2) against the same job on one device, the
+benchmark's four-chip training step with the compiler options that hide its
+``tp`` reduces against the same step without them (loss and gradient norm of
+two steps), and four one-chip actors alive together.
 
 This process never imports JAX: a chip belongs to one process at a time, and
 each phase's worker has exited (or been killed) before the next one starts.
@@ -207,6 +209,67 @@ def train_loop(config: dict) -> None:
     train.report({"losses": losses, "step_s": step_s,
                   "compile_s": round(compile_s, 2),
                   "mesh": dict(mesh.shape) if mesh is not None else None,
+                  **device_report()})
+
+
+OVERLAP_CELL = "mistral7b_train_4chip"
+
+
+def overlap_loop(config: dict) -> None:
+    """JaxTrainer loop on four chips: the step of the benchmark's four-chip
+    training cell (its configuration, mesh, optimizer, batch and traffic) as
+    ``make_train_step`` compiles it, with ``training.OVERLAP_REDUCES``, and
+    the same step without them, two steps each from the same seeded state
+    on the same batches, one after the other (two states do not fit). The
+    options are the chip compiler's private ones and a sibling of theirs
+    compiles another gradient (PERF.md 6, PR 54): this is what would see
+    it. Reports each step's loss and gradient norm, and how many of the
+    loop's ``tp`` reduces each compiled text holds and hides."""
+    from unittest import mock
+
+    import jax
+
+    from perfbench.lib import configs, traffic
+    from perfbench.lib import manifest as manifest_lib
+    from ray_tpu import train
+    from ray_tpu.models import training
+    from ray_tpu.parallel.mesh import (MeshSpec, build_mesh,
+                                       collective_census, data_sharding)
+
+    require_chip()
+    manifest = manifest_lib.load()
+    entry = manifest_lib.workload(manifest, OVERLAP_CELL)
+    cell = manifest_lib.read_json(manifest, "cells", OVERLAP_CELL)
+    mix = manifest_lib.read_json(manifest, "traffic", entry["traffic"])
+    hp = manifest_lib.config(manifest, entry["config"])
+    cfg = configs.build_program_config(*configs.program_overrides(
+        hp, manifest_lib.read_json_from_bench("families", hp["model_type"])))
+    mesh = build_mesh(MeshSpec.of(**cell["mesh"]))
+    ocfg = training.OptimizerConfig(**cell["optimizer"])
+    batches = [{"tokens": jax.device_put(tokens, data_sharding(mesh))}
+               for tokens, _ in zip(traffic.token_batches(
+                   mix, config["seed"], int(cell["batch"]), cfg.vocab_size),
+                   range(2))]
+
+    def two_steps(options: bool) -> dict:
+        state, tx = training.init_train_state(
+            cfg, ocfg, jax.random.PRNGKey(config["seed"]), mesh)
+        t0 = time.perf_counter()
+        with mock.patch.dict(training.OVERLAP_REDUCES,
+                             clear=not options):
+            compiled = training.make_train_step(cfg, tx, mesh).lower(
+                state, batches[0]).compile()
+        out = {"compile_s": round(time.perf_counter() - t0, 1),
+               "loss": [], "grad_norm": []}
+        for batch in batches:
+            state, metrics = compiled(state, batch)
+            out["loss"].append(float(metrics["loss"]))
+            out["grad_norm"].append(float(metrics["grad_norm"]))
+        out["tp_reduces"] = collective_census(compiled.as_text(), mesh)[
+            ("loop", "all-reduce", ("tp",))]
+        return out
+
+    train.report({"with": two_steps(True), "without": two_steps(False),
                   **device_report()})
 
 
@@ -1479,20 +1542,25 @@ class ChipProbe:
 # the driver's phases
 
 
-def fit(name: str, seed: int, steps: int, mesh, chips: int) -> dict:
+def on_chips(name: str, loop, config: dict, chips: int) -> dict:
+    """What ``loop`` reports from one JaxTrainer worker holding ``chips``."""
     from ray_tpu.air.config import ScalingConfig
     from ray_tpu.train import JaxTrainer
 
-    t0 = time.perf_counter()
     result = JaxTrainer(
-        train_loop,
-        train_loop_config={"seed": seed, "steps": steps, "mesh": mesh},
+        loop, train_loop_config=config,
         scaling_config=ScalingConfig(num_workers=1, use_tpu=True,
                                      tpus_per_worker=chips),
     ).fit()
     if result.error is not None:
         raise RuntimeError(f"{name}: training failed: {result.error}")
-    out = dict(result.metrics)
+    return dict(result.metrics)
+
+
+def fit(name: str, seed: int, steps: int, mesh, chips: int) -> dict:
+    t0 = time.perf_counter()
+    out = on_chips(name, train_loop,
+                   {"seed": seed, "steps": steps, "mesh": mesh}, chips)
     losses = out["losses"]
     if not all(math.isfinite(x) for x in losses):
         raise RuntimeError(f"{name}: non-finite loss in {losses}")
@@ -1678,6 +1746,34 @@ def one_chip(seed: int) -> dict:
     return out["device"]
 
 
+def overlap_parity(seed: int) -> None:
+    """The four-chip cell's step with ``training.OVERLAP_REDUCES`` against
+    the same step without: the first two steps' loss within 1e-5 of each
+    other and gradient norm within 1e-4 (the landed options read both equal
+    to seven digits; the option that is NOT taken read the loss 9e-4 and the
+    norm 0.4 away), twice the reduces at the same bytes either way, and
+    some of them hidden only with the options."""
+    t0 = time.perf_counter()
+    out = on_chips("overlap_parity", overlap_loop, {"seed": seed}, chips=4)
+    on, off = out["with"], out["without"]
+    rel = {key: [abs(a - b) / abs(b) for a, b in zip(on[key], off[key])]
+           for key in ("loss", "grad_norm")}
+    emit("overlap_parity", seconds=round(time.perf_counter() - t0, 1),
+         rel_diff=rel, **out)
+    if not all(math.isfinite(x) for x in on["loss"] + on["grad_norm"]):
+        raise RuntimeError(f"overlap_parity: a step is not finite: {on}")
+    if max(rel["loss"]) > 1e-5 or max(rel["grad_norm"]) > 1e-4:
+        raise RuntimeError(f"overlap_parity: the options compile another "
+                           f"step: {rel}")
+    hid = [side["tp_reduces"]["hidden"] for side in (on, off)]
+    if (on["tp_reduces"]["bytes"] != off["tp_reduces"]["bytes"]
+            or on["tp_reduces"]["calls"] != off["tp_reduces"]["calls"]
+            or not hid[0] > hid[1] == 0):
+        raise RuntimeError(f"overlap_parity: the reduces are not the same, "
+                           f"or none is hidden: {on['tp_reduces']} with, "
+                           f"{off['tp_reduces']} without")
+
+
 def four_chips(seed: int) -> dict:
     import ray_tpu
 
@@ -1693,6 +1789,7 @@ def four_chips(seed: int) -> dict:
     if max(rel) > 1e-2:
         raise RuntimeError(f"sharded and one-device losses differ: {rel}")
     emit("loss_parity", max_rel_diff=max(rel), rel_diff=rel)
+    overlap_parity(seed)
 
     t0 = time.perf_counter()
     probes = [ray_tpu.remote(num_tpus=1)(ChipProbe).remote()

@@ -83,7 +83,7 @@ from ray_tpu.ops.rotary import (apply_rotary, apply_rotary_at,
                                 rope_frequencies)
 from ray_tpu.ops.sparse_attention import (SparseSizes, sparse_attention,
                                           update_page_means)
-from ray_tpu.parallel.sharding import free_axes
+from ray_tpu.parallel.sharding import free_axes, free_parts
 
 ATTENTION, SPARSE, LINEAR = "attention", "minicpm4", "lightning-attn"
 RETENTION = "power-retention"
@@ -632,13 +632,21 @@ def _attn(cfg, p, x, rope, positions, sp_axis, kv_cache=None,
         o = ring_attention_local(q, k, v, sp_axis, causal=True)
         new_cache = None
     else:
-        o = attention(q, k, v, causal=True, impl=cfg.attn_impl
-                      if cfg.attn_impl != "ring" else "auto")
+        o = _plain_attention(cfg, q, k, v)
         new_cache = None
+    return _attn_out(cfg, p, o, named=kv_cache is None), new_cache
+
+
+def _plain_attention(cfg, q, k, v):
+    return attention(q, k, v, causal=True, impl=cfg.attn_impl
+                     if cfg.attn_impl != "ring" else "auto")
+
+
+def _attn_out(cfg, p, o, named=True):
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(cfg.dtype))
-    if kv_cache is None and free_axes("heads", p["wo"].shape[0]) is not None:
+    if named and free_axes("heads", p["wo"].shape[0]) is not None:
         out = checkpoint_name(out, ATTN_OUT)
-    return out, new_cache
+    return out
 
 
 # The mixers that are no plain attention, each written once and in three
@@ -998,11 +1006,56 @@ def _block(cfg, p, x, rope, positions, sp_axis, kv_cache=None, mlp=None,
     mixer. ``taps``: a list that is given what a mixer chose (debug)."""
     a, new_cache = _mixer(cfg, kind, p["attn"], _norm(cfg, p["ln1"], x),
                           rope, positions, sp_axis, kv_cache, taps)
+    return _after_mixer(cfg, p, x, a, new_cache, mlp)
+
+
+def _after_mixer(cfg, p, x, a, new_cache=None, mlp=None):
+    """The rest of a block, given what its mixer made of x: ``_block``'s
+    results."""
     x = _residual(cfg, x, a)
     mlp_p, layer = mlp or (p["mlp"], None)
     m, aux, moe = _mlp(cfg, mlp_p, _norm(cfg, p["ln2"], x), layer=layer)
     x = _residual(cfg, x, m)
     return x, new_cache, aux, moe
+
+
+# the matrices of a block's mixer and mlp: what their products cast to
+# ``cfg.dtype`` where they use it (a leaf that is not named here keeps its
+# type, and costs two streams a second gather, never a digit)
+_MATRICES = frozenset({"wq", "wk", "wv", "wo", "wg", "wc", "wi_q", "wi_k",
+                       "wi_w", "w_gate", "w_up", "w_down", "w_in", "w_out"})
+
+
+def _block_streams(cfg, p, hs, rope, positions, sp_axis, kind=ATTENTION):
+    """``_block`` on each stream of the residual (``streams``), one
+    ``_block``'s results a stream. Two streams take every matrix as the
+    products do, ``cfg.dtype``, from ONE cast (a stream's own
+    ``astype(cfg.dtype)`` is then nothing): the matrix is gathered once for
+    both, and its gradient is the two products' sum in that type before it
+    is reduced over ``fsdp`` — else two gathers and two reduces a matrix.
+    And they meet in the attention kernel, which takes both streams' rows
+    as the ONE call a layer it was (its batching, and what a trace counts
+    by its name, are a whole chip's rows): the products on either side of
+    it, whose reduces are the ones to hide, stay a stream's own."""
+    if len(hs) == 1:
+        return [_block(cfg, p, hs[0], rope, positions, sp_axis, kind=kind)]
+    p = {**p, **{part: {name: w.astype(cfg.dtype) if name in _MATRICES else w
+                        for name, w in p[part].items()}
+                 for part in ("attn", "mlp")}}
+    normed = [_norm(cfg, p["ln1"], h) for h in hs]
+    if (kind != ATTENTION or cfg.window(kind) is not None
+            or cfg.attn_impl == "ring" and sp_axis is not None):
+        mixed = [_mixer(cfg, kind, p["attn"], x, rope, positions, sp_axis,
+                        None, None)[0] for x in normed]
+    else:
+        # goes, with ``_plain_attention`` and ``_attn_out``, once a trace's
+        # reader takes a kernel call's rows from the event: a call a
+        # stream, the branch above, is the faster form (ROADMAP S5 (1b))
+        q, k, v = (_whole(rows) for rows in zip(*(
+            _qkv(cfg, p["attn"], x, rope, positions, kind) for x in normed)))
+        mixed = [_attn_out(cfg, p["attn"], o)
+                 for o in _halves(_plain_attention(cfg, q, k, v))]
+    return [_after_mixer(cfg, p, h, a) for h, a in zip(hs, mixed)]
 
 
 def _residual_layout(x):
@@ -1018,6 +1071,40 @@ def _residual_layout(x):
         return x
     return jax.lax.with_sharding_constraint(
         x, jax.sharding.PartitionSpec(batch, None, None))
+
+
+def hides_reduces(cfg) -> bool:
+    """Whether a block under the mesh in scope has a reduce that a second
+    stream can hide: it reduces over ``tp`` (``heads`` has an axis there),
+    and it has no experts — the router's auxiliary loss and the experts'
+    groups are statistics of the whole batch, not of a row."""
+    return cfg.mlp != "moe" and free_axes("heads", cfg.num_heads) is not None
+
+
+def streams(cfg, rows: int) -> int:
+    """How many streams the layer scan carries a batch of ``rows`` as under
+    the mesh in scope: two where ``hides_reduces`` and every batch group's
+    rows are even — the halves share nothing but the weights, so one half's
+    reduce can lie under the other half's matmuls — else one."""
+    even = rows % (2 * free_parts("batch", rows)) == 0
+    return 2 if hides_reduces(cfg) and even else 1
+
+
+def _halves(x):
+    """x [B, ...] -> the two halves of every batch group's rows, [B / 2,
+    ...] each. NOT ``x[:B // 2]`` and the rest: those are a group (an
+    ``fsdp`` rank's rows) each, and every activation would cross ``fsdp``
+    to be spread again."""
+    parts = x.reshape(free_parts("batch", x.shape[0]), 2, -1, *x.shape[1:])
+    return tuple(parts[:, i].reshape(-1, *x.shape[1:]) for i in range(2))
+
+
+def _whole(halves):
+    """``_halves`` undone: the rows back in the batch's order."""
+    rows, rest = sum(h.shape[0] for h in halves), halves[0].shape[1:]
+    groups = free_parts("batch", rows)
+    parts = jnp.stack([h.reshape(groups, -1, *rest) for h in halves], axis=1)
+    return parts.reshape(rows, *rest)
 
 
 def embed(cfg, params, tokens):
@@ -1087,14 +1174,14 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
     rope = rope_table(cfg)
     kinds = cfg.kinds
 
-    block_fn = lambda kind: functools.partial(_block, kind=kind)
+    remat = lambda fn: fn
     if cfg.remat and kv_caches is None and not return_selected:
         policy = remat_policy(cfg.remat_policy)
         # ``COMPUTED`` is a name, not an array: static like the config
         static = (0, 3, 5) if rope is COMPUTED else (0, 5)
-        block_fn = lambda kind: jax.checkpoint(
-            functools.partial(_block, kind=kind), static_argnums=static,
-            policy=policy)
+        remat = lambda fn: jax.checkpoint(fn, static_argnums=static,
+                                          policy=policy)
+    block_fn = lambda kind: remat(functools.partial(_block, kind=kind))
 
     if return_routes and (cfg.mlp != "moe" or kv_caches is not None):
         raise ValueError("return_routes needs mlp='moe' and no kv_caches")
@@ -1108,21 +1195,32 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
     taps = [] if return_selected else None
     if cfg.scan_layers and kv_caches is None and not return_selected:
         period = cfg.period
-        fns = [block_fn(kind) for kind in kinds[:period]]
+        fns = [remat(functools.partial(_block_streams, kind=kind))
+               for kind in kinds[:period]]
+        # the carry: the residual as one stream, or as the two halves of
+        # every chip's rows (``streams``; positions a row would have to be
+        # halved with it: none does)
+        split = (streams(cfg, x.shape[0]) == 2 and not return_routes
+                 and (positions is None or positions.ndim == 1))
+        carried = (tuple(_residual_layout(h) for h in _halves(x))
+                   if split else (x,))
 
         def body(carry, layer_params):
-            h, aux_acc = carry
+            hs, aux_acc = carry
             chosen = []
             for j, fn in enumerate(fns):
-                h, _, aux, moe = fn(
-                    cfg, layer_params if period == 1
-                    else layer_params[f"p{j}"], h, rope, positions, sp_axis)
-                aux_acc = aux_acc + aux
-                chosen.append(moe["routes"] if return_routes else None)
-            return (_residual_layout(h), aux_acc), (
+                outs = fn(cfg, layer_params if period == 1
+                          else layer_params[f"p{j}"], hs, rope, positions,
+                          sp_axis)
+                hs = tuple(h for h, _, _, _ in outs)
+                for _, _, aux, _ in outs:
+                    aux_acc = aux_acc + aux
+                chosen.append(outs[0][3]["routes"] if return_routes else None)
+            return (tuple(_residual_layout(h) for h in hs), aux_acc), (
                 jnp.stack(chosen) if return_routes else None)
-        (x, aux_total), routes = jax.lax.scan(body, (x, 0.0),
-                                              params["blocks"])
+        (carried, aux_total), routes = jax.lax.scan(body, (carried, 0.0),
+                                                    params["blocks"])
+        x = _residual_layout(_whole(carried)) if split else carried[0]
         if return_routes:  # [steps, layers a step, ...] -> a layer a row
             routes = routes.reshape(-1, *routes.shape[2:])
     else:

@@ -18,8 +18,13 @@ Canonical axis names (used by sharding rules and the trainer):
                ``forward`` states where the residual lives (batch over
                ``dp``/``fsdp``, the hidden dimension whole) and the fused
                cross-entropy where a chunk's rows do, so no activation is
-               reduced over ``fsdp``; ``collective_census`` below counts
-               what a compiled step holds, by op and axis
+               reduced over ``fsdp``. Each of the four is two reduces of
+               half the bytes where ``forward`` carries a chip's rows as
+               two streams (``transformer.streams``), so that one stream's
+               reduce can run under the other's matmuls
+               (``training.OVERLAP_REDUCES``); ``collective_census`` below
+               counts what a compiled step holds, by op and axis, and how
+               many of them the compiler scheduled matmuls under
   * ``sp``   — sequence/context parallelism (ring attention over this axis)
   * ``ep``   — expert parallelism for MoE layers
   * ``pp``   — pipeline stages (usually over DCN between slices)
@@ -137,6 +142,16 @@ _ARRAY = re.compile(r"(\w+)\[([\d,]*)\]")
 _CALLED = re.compile(
     r"(?:body|condition|calls|to_apply|true_computation|false_computation)"
     r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
+# any instruction: its name, and the op that stands before its operands
+_ANY = re.compile(r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*"
+                  r"(?:\(.*?\)|\S+)\s+(?P<op>[\w\-]+)\((?P<args>[^)]*)")
+_TARGET = re.compile(r'custom_call_target="([^"]*)"')
+# the chip compiler's asynchronous form: a fusion whose computation holds
+# the collective and a custom call of the first name starts it, fusions of
+# the work it lies under carry it on (each repeats the instruction), and one
+# whose computation holds the second name ends it
+_ASYNC_START, _ASYNC_DONE = "AsyncCollectiveStart", "AsyncCollectiveDone"
+_KERNEL = "tpu_custom_call"
 
 
 def _group_axes(line: str, op: str, shape: Dict[str, int]) -> Tuple[str, ...]:
@@ -173,7 +188,7 @@ def _group_axes(line: str, op: str, shape: Dict[str, int]) -> Tuple[str, ...]:
 
 
 def collectives(hlo_text: str, mesh) -> List[Dict[str, object]]:
-    """Every collective instruction of a compiled program's text
+    """Every collective of a compiled program's text
     (``compiled.as_text()``), one row each: ``op`` (``all-reduce-scatter``
     for an all-reduce inside the chip compiler's fusion of that name);
     ``axes``, the mesh axes its groups span; ``shapes`` and ``bytes`` of
@@ -182,13 +197,22 @@ def collectives(hlo_text: str, mesh) -> List[Dict[str, object]]:
     layer scan's, forward or backward, or the cross-entropy's chunks') or
     only the entry does; and ``op_name``, the source op it serves, as the
     compiler's metadata has it (``transpose(`` the backward pass,
-    ``rematted_computation`` a recompute). It counts INSTRUCTIONS: the chip's
-    compiler writes a layer's weight gathers three to five times in a body
-    it has pipelined, so their bytes are an upper bound there; a reduce of
-    an activation stands once. Text in, rows out: nothing is compiled or
+    ``rematted_computation`` a recompute). A collective the compiler made
+    asynchronous — ``<op>-start`` and ``<op>-done``, or the chip compiler's
+    fused form (``_ASYNC_START``), which is counted ONCE however many
+    fusions carry it on — also has ``between``: how many instructions that
+    multiply are scheduled between its start and its end in that text (a
+    fusion that holds a convolution or a dot, either of them bare, a Pallas
+    kernel's call). 0 there, or no such field: whatever needs the result
+    waits for the interconnect. Text in, rows out: nothing is compiled or
     run here."""
     shape = dict(mesh.shape)
     computation, rows, homes, calls, bodies = None, [], [], {}, set()
+    # a computation's instructions in the order they are scheduled, (name,
+    # op, the computation it calls, a custom call's target, operands); the
+    # computations that hold a matmul; the role of one that starts or ends
+    # the chip compiler's asynchronous form
+    order, multiplies, role = {}, set(), {}
     for line in hlo_text.splitlines():
         head = _COMPUTATION.match(line)
         if head:
@@ -198,6 +222,23 @@ def collectives(hlo_text: str, mesh) -> List[Dict[str, object]]:
             names = [one] if one else re.findall(r"[\w.\-]+", many)
             calls.setdefault(computation, set()).update(names)
         bodies.update(re.findall(r"\bbody=%?([\w.\-]+)", line))
+        any_ = _ANY.match(line)
+        if any_:
+            called = re.search(r"\bcalls=%?([\w.\-]+)", line)
+            target = _TARGET.search(line)
+            target = target.group(1) if target else ""
+            order.setdefault(computation, []).append((
+                any_.group("name"), any_.group("op"),
+                called.group(1) if called else None, target,
+                re.findall(r"%([\w.\-]+)", any_.group("args"))))
+            if any_.group("op") in ("convolution", "dot"):
+                multiplies.add(computation)
+            if target.startswith("AsyncCollective"):
+                if target not in (_ASYNC_START, _ASYNC_DONE):
+                    raise ValueError(f"{computation}: an asynchronous "
+                                     f"collective's {target!r} is no start "
+                                     f"and no end that is known here")
+                role[computation] = target
         found = _INSTRUCTION.match(line)
         if not found:
             continue
@@ -215,12 +256,17 @@ def collectives(hlo_text: str, mesh) -> List[Dict[str, object]]:
             arrays = [a for a in arrays if a[1] or op == "all-gather"]
             arrays = arrays[len(arrays) // 2:]
         name = re.search(r'op_name="([^"]*)"', line)
+        channel = re.search(r"channel_id=(\d+)", line)
         rows.append({
             "op": op, "axes": _group_axes(line, op, shape),
             "shapes": [dims for _, dims in arrays],
             "bytes": sum(_DTYPE_BYTES[dtype] * math.prod(dims)
                          for dtype, dims in arrays),
-            "op_name": name.group(1) if name else ""})
+            "op_name": name.group(1) if name else "",
+            # where it stands in its computation, whether it is a
+            # ``-start``, and the channel a fused chain's parts share
+            "_at": (len(order[computation]) - 1, bool(found.group("start")),
+                    channel.group(1) if channel else None)})
         homes.append(computation)
     in_loop, stack = set(), list(bodies)
     while stack:
@@ -228,18 +274,68 @@ def collectives(hlo_text: str, mesh) -> List[Dict[str, object]]:
         if name not in in_loop:
             in_loop.add(name)
             stack.extend(calls.get(name, ()))
+    # the instructions that call a computation, to find where an
+    # asynchronous fusion stands in its caller's schedule
+    callers = {called: (comp, i) for comp, instrs in order.items()
+               for i, (_, _, called, _, _) in enumerate(instrs) if called}
+    channels = {home: row["_at"][2] for row, home in zip(rows, homes)}
+
+    started = {channels[home] for home, target in role.items()
+               if target == _ASYNC_START}
+
+    def between(comp, start, ends) -> Optional[int]:
+        """Instructions that multiply after ``order[comp][start]`` and
+        before the first one that ``ends(instruction)``; None without one."""
+        count = 0
+        for instr in order[comp][start + 1:]:
+            _, op, called, target, _ = instr
+            if ends(instr):
+                return count
+            count += (op in ("convolution", "dot")
+                      or op == "fusion" and called in multiplies
+                      or op == "custom-call" and target == _KERNEL)
+        return None
+
+    kept = []
     for row, home in zip(rows, homes):
+        at, start, channel = row.pop("_at")
         row["loop"] = home in in_loop
-    return rows
+        if home in role or home.startswith("async_collective_fusion"):
+            # the fused form is the chip compiler's own and nowhere written
+            # down: text that is not the chain known here (a start, fusions
+            # of one channel that carry it on, an end) is refused, not read
+            # as a collective with nothing under it
+            if role.get(home) != _ASYNC_START:
+                if channel not in started:
+                    raise ValueError(f"{home}: carries on or ends a "
+                                     f"collective of channel {channel}, "
+                                     f"which no {_ASYNC_START} starts")
+                continue  # the same collective, carried on or ended
+            row["between"] = between(
+                *callers[home], lambda instr: role.get(instr[2]) == _ASYNC_DONE
+                and channels.get(instr[2]) == channel)
+            if row["between"] is None:
+                raise ValueError(f"{home}: no {_ASYNC_DONE} of channel "
+                                 f"{channel} follows its start")
+        elif start:
+            name = order[home][at][0]
+            row["between"] = between(
+                home, at, lambda instr: instr[1].endswith("-done")
+                and instr[4][:1] == [name]) or 0  # no end: not a pair
+        kept.append(row)
+    return kept
 
 
 def collective_census(hlo_text: str, mesh) -> Dict[tuple, Dict[str, int]]:
-    """``collectives`` added up: ``(where, op, axes) -> {"calls", "bytes"}``,
-    ``where`` "loop" (the scan bodies: a layer-step) or "entry"."""
+    """``collectives`` added up: ``(where, op, axes) -> {"calls", "bytes",
+    "hidden"}``, ``where`` "loop" (the scan bodies: a layer-step) or
+    "entry"; ``hidden`` the calls with matmuls scheduled ``between`` their
+    start and their end."""
     census: Dict[tuple, Dict[str, int]] = {}
     for row in collectives(hlo_text, mesh):
         key = ("loop" if row["loop"] else "entry", row["op"], row["axes"])
-        tally = census.setdefault(key, {"calls": 0, "bytes": 0})
+        tally = census.setdefault(key, {"calls": 0, "bytes": 0, "hidden": 0})
         tally["calls"] += 1
         tally["bytes"] += row["bytes"]
+        tally["hidden"] += row.get("between", 0) > 0
     return census

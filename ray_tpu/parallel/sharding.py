@@ -56,6 +56,17 @@ def free_axes(logical: str, *dims: int):
     return names if len(names) > 1 else names[0]
 
 
+def free_parts(logical: str, *dims: int) -> int:
+    """Into how many parts ``free_axes(logical, *dims)`` splits a dimension:
+    the product of its axes' sizes, 1 where it gives None."""
+    import jax
+
+    names = free_axes(logical, *dims)
+    shape = jax.sharding.get_abstract_mesh().shape
+    return math.prod(shape[a] for a in (
+        (names,) if isinstance(names, str) else names or ()))
+
+
 @dataclasses.dataclass
 class ShardingRules:
     rules: Dict[str, Optional[Union[str, Tuple[str, ...]]]] = dataclasses.field(
