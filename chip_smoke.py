@@ -86,6 +86,26 @@ weights from ``--seed``):
            then the benchmark's own comparison (reference_check with the
            routes given, under the limits of cells/keye_longctx.json) on
            the float8 CONTROL, which has to come out NOT correct
+  glm      a @ray_tpu.remote(num_tpus=1) task runs the benchmark's
+           GLM-4.7-Flash configuration (published widths: hidden 2048, 20
+           heads of 192 + 64 q/k and 256 v values over a latent of 512 and
+           one shared rotated key, a dense layer then 64 experts of 1536
+           top-4 sigmoid chosen by score + bias beside a shared one; 1 + 5
+           layers, bf16) through the paged programs, which attend the
+           latents ABSORBED: a prompt of 4113 tokens and one of 2065 (each
+           ends on a page's FIRST token) whose chunks take the first's
+           decode row along, a third sequence that SPLICES the first's
+           leading 2048 tokens (read table only, as the radix cache hands
+           them) and goes on alone, then 6 decode steps of all three,
+           against perfbench/reference/glm_moe_lite.py (UNABSORBED) GIVEN
+           the system's own routes (inside the dense cells' 8% / 6%); every
+           page no table names is FILLED WITH NaN before the first program
+           and still is after the last; then a 512 chunk's attention alone
+           at 8k, 20k and 55k, absorbed against expanded, and the step's
+           kernel; then the benchmark's own comparison (reference_check
+           with the routes given, under the limits of
+           cells/glm47_flash_longdocs.json) on the float8 CONTROL, which
+           has to come out NOT correct
   serve    serve.run(build_app(preset="gpt2_small")) answers 8 concurrent
            requests: six through the handle, one streamed, one over HTTP
 
@@ -1283,6 +1303,257 @@ def keye_task(seed: int, control: bool = True) -> dict:
     return {**out, **device_report()}
 
 
+def glm_task(seed: int, control: bool = True) -> dict:
+    """The GLM-4.7-Flash-width checks (ISSUE 55): the paged chunk, step and
+    fused turn in bf16 at the published widths (hidden 2048, 20 heads of 192
+    + 64 q/k and 256 v values over a latent of 512 and one shared rotated
+    key, a dense layer of 10240 then 64 experts of 1536 top-4 sigmoid beside
+    a shared one; the benchmark's 1 + 5 layers) — which attend the latents
+    ABSORBED — against the plain float32 reference, which rebuilds every
+    key and value, GIVEN the system's own routes. Two prompts that end on a
+    page's first token (4113 = 257 x 16 + 1 tokens and 2065 = 129 x 16 + 1),
+    the second's chunks taking the first's decode row along; then a third
+    sequence that SPLICES the first's leading 2048 tokens as the radix cache
+    would (their pages in its read table and not in its write table) and
+    goes on with a tail of its own; every page no table names filled with
+    NaN, as a released page would be. Then the chunk's attention alone,
+    absorbed against expanded (``latent_timing``), and (``control``) the
+    float8 control through the harness's own comparison under the limits of
+    ``cells/glm47_flash_longdocs.json``, which must refuse it."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from perfbench.lib import configs, weights
+    from perfbench.lib import manifest as manifest_lib
+    from perfbench.reference import glm_moe_lite as ref
+    from ray_tpu.models.decode import (StepRows, init_paged_caches,
+                                       paged_decode_step,
+                                       paged_prefill_into_slot)
+
+    require_chip()
+    manifest = manifest_lib.load()
+    hp = manifest_lib.config(manifest, "glm47_flash_l6")
+    cfg = configs.build_program_config(*configs.program_overrides(
+        hp, manifest_lib.read_json_from_bench("families", hp["model_type"])))
+    L, k = cfg.expert_layers, cfg.moe_top_k
+    params = weights.make_params(cfg, seed)
+    S, C, T, P, shared = 4, 512, 16, 272, 2048
+    caches = init_paged_caches(cfg, S * P + 1 + 64, T, P)
+    rng = np.random.default_rng(seed)
+    prompts = {0: rng.integers(1, cfg.vocab_size, 4113).tolist(),
+               2: rng.integers(1, cfg.vocab_size, 2065).tolist()}
+    # the third sequence: slot 0's first 2048 tokens, then 600 of its own
+    prompts[3] = prompts[0][:shared] + rng.integers(
+        1, cfg.vocab_size, 600).tolist()
+    read = np.zeros((S, P), np.int32)
+    for s in prompts:
+        read[s] = 1 + s * P + np.arange(P)
+    write = read.copy()
+    read[3, :shared // T] = read[0, :shared // T]   # spliced: read only
+    write[3, :shared // T] = 0
+    loose = np.setdiff1d(np.arange(1, S * P + 65), np.union1d(read, write))
+    caches = [dataclasses.replace(c, ckr=c.ckr.at[loose].set(jnp.nan))
+              for c in caches]
+    reads, writes = jnp.asarray(read), jnp.asarray(write)
+
+    prefill = jax.jit(lambda *a: paged_prefill_into_slot(
+        cfg, *a, attn="pallas", moe_info=True, logits=True),
+        donate_argnums=(6,))
+    step = jax.jit(lambda *a: paged_decode_step(
+        cfg, *a, attn="pallas", moe_info=True, logits=True),
+        donate_argnums=(6,))
+    ids = jnp.zeros(S, jnp.int32)
+    got = {s: [] for s in prompts}
+    taken = {s: [] for s in prompts}
+    fed = {s: [] for s in prompts}
+    active = np.zeros(S, np.int32)
+    cursors = np.zeros(S, np.int32)
+    greedy = (np.zeros(S, np.float32), np.zeros(S, np.uint32))
+    rows_routed = live_rows = 0
+
+    def feed():
+        for s in np.flatnonzero(active):
+            fed[s].append(int(got[s][-1].argmax()))
+
+    for s, prompt in prompts.items():
+        start = shared if s == 3 else 0   # the spliced tokens are resident
+        for c0 in range(start, len(prompt), C):
+            chunk = prompt[c0:c0 + C]
+            real = len(chunk)
+            feed()
+            ids, caches, moe, logits = prefill(
+                params, jnp.asarray([chunk + [0] * (C - real)], jnp.int32),
+                np.int32(real), np.int32(c0), reads[s], writes[s], caches,
+                ids, np.int32(s if c0 + C >= len(prompt) else -1),
+                np.float32(0), np.uint32(0),
+                StepRows(active.copy(), cursors.copy(), reads, writes,
+                         *greedy))
+            routes = np.asarray(moe["routes"])[:, 0]
+            taken[s].append(routes[:, :real])
+            rows_routed += int(np.asarray(moe["counts"]).sum())
+            live_rows += real + int(active.sum())
+            for row in np.flatnonzero(active):
+                got[row].append(np.asarray(logits[1 + row], np.float32))
+                taken[row].append(routes[:, C + row:C + row + 1])
+            cursors = cursors + active
+            cursors[s] = c0 + real
+        got[s].append(np.asarray(logits[0], np.float32))
+        active[s] = 1
+    assert len(fed[0]) == 7 and len(fed[2]) == 2 and not fed[3]
+    for _ in range(6):
+        feed()
+        ids, caches, moe, logits = step(
+            params, ids, jnp.asarray(active), cursors, reads, writes, caches,
+            *greedy)
+        cursors = cursors + active
+        rows_routed += int(np.asarray(moe["counts"]).sum())
+        live_rows += len(prompts)
+        for s in prompts:
+            got[s].append(np.asarray(logits[s], np.float32))
+            taken[s].append(np.asarray(moe["routes"])[:, s])
+
+    def rel(got, want):
+        return {"max": float(np.abs(got - want).max() / np.abs(want).max()),
+                "rms": float(np.sqrt(((got - want) ** 2).mean()
+                                     / (want ** 2).mean()))}
+
+    errs, flips = {}, {}
+    for s, prompt in prompts.items():
+        tokens = jnp.asarray([prompt + fed[s]], jnp.int32)
+        first = len(prompt) - 1
+        routes = np.concatenate(taken[s], axis=1)[:, None]
+        if s == 3:
+            # the spliced tokens' routes were slot 0's, position by position
+            routes = np.concatenate(
+                [np.concatenate(taken[0], axis=1)[:, None, :shared], routes],
+                axis=2)
+        want, scores = ref.forward_and_router(params, tokens, hp,
+                                              jnp.asarray(routes))
+        errs[s] = rel(np.stack(got[s][:-1]), want[0][first:-1])
+        # the share of rows whose biased top 4 in float32 is not the
+        # program's in bf16
+        bias = np.stack([np.asarray(
+            params["blocks"]["body"]["mlp"]["e_bias"][i], np.float32)
+            for i in range(L)])[:, None, None]
+        own = np.sort(np.asarray(jax.lax.top_k(
+            np.asarray(scores) + bias, k)[1]), -1)
+        flips[s] = float((own != np.sort(routes, -1)).any(-1).mean())
+    poisoned = [bool(jnp.isnan(c.ckr[loose]).all()) for c in caches]
+    out = {"given_err": errs, "flip_share": flips,
+           "rows_routed": rows_routed,
+           "live_rows_x_k_x_layers": live_rows * k * L,
+           "pages_poisoned": int(loose.size)}
+    bad = []
+    if not all(np.isfinite(g).all() for rows in got.values() for g in rows):
+        bad.append("a logit is not finite: a page no table names was read")
+    if not all(poisoned):
+        bad.append("a page no table names was written")
+    if rows_routed != live_rows * k * L:
+        bad.append("a row was dropped or a dead row counted")
+    if max(e["max"] for e in errs.values()) > 0.08 \
+            or max(e["rms"] for e in errs.values()) > 0.06:
+        bad.append("error given the routes above the dense cells' "
+                   "tolerance")
+    if bad:
+        raise RuntimeError(f"glm: {bad}: {out}")
+    del caches
+    out["latent_timing"] = latent_timing(cfg, params, seed)
+    if control:
+        seen = out["float8_control"] = float8_control(
+            cfg, hp, params, seed, ref, "glm_moe_lite",
+            "glm47_flash_longdocs")
+        if seen["checks"]["reference_logits"] \
+                and seen["checks"]["reference_logits_given_choices"]:
+            raise RuntimeError("glm: the float8 control passes both "
+                               f"comparisons of logits: {seen}")
+    return {**out, **device_report()}
+
+
+def latent_timing(cfg, params, seed: int) -> dict:
+    """A 512 chunk's latent attention alone, one layer, at contexts of 8k,
+    20k and 55k of one slot's table (the cell's 4128 pages): ABSORBED (what
+    the program runs: the latents attended as they lie, ``2 (576 + 512)``
+    operations a (query, key, head) pair) against EXPANDED (keys and values
+    rebuilt from the slot's latents — ``2 x 512 x 8960`` operations a
+    context token, 17.9 KB of temporaries a token — then the paged kernel
+    over them at heads of 256, ``2 x 512`` a pair), each in milliseconds a
+    call, four calls timed behind a warm-up. And the step's kernel over 8
+    rows at those contexts."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models.transformer import (latent_finish, latent_mix,
+                                            layer_params)
+    from ray_tpu.ops.latent_attention import pool_width
+    from ray_tpu.ops.paged_attention import paged_attention
+
+    H, nope, rope, rank, dv = (cfg.num_heads, cfg.latent_nope_dim,
+                               cfg.latent_rope_dim, cfg.latent_kv_rank,
+                               cfg.latent_v_dim)
+    T, P, C = 16, 4128, 512
+    p = layer_params(cfg, params, 1)["attn"]
+    keys = jax.random.split(jax.random.PRNGKey(seed & 0x7FFFFFFF), 4)
+    pool = jax.random.normal(keys[0], (8 * P + 1, T, pool_width(rank, rope)),
+                             jnp.bfloat16)
+    tables = 1 + jnp.arange(8 * P, dtype=jnp.int32).reshape(8, P)
+
+    def rows(k0, n, K):
+        return (jax.random.normal(keys[k0], (n, K, H, nope), jnp.bfloat16),
+                jax.random.normal(keys[k0 + 1], (n, K, H, rope),
+                                  jnp.bfloat16))
+
+    # the pool is an argument: closed over, its 1.3 GB would be a constant
+    # of the program
+    @jax.jit
+    def absorbed(pool, q, cursor, tables):
+        return latent_finish(cfg, p, latent_mix(
+            cfg, p, q, (pool,), tables, cursor, impl="pallas"))
+
+    @jax.jit
+    def expanded(pool, q, cursor, tables):
+        ckr = pool[tables[0]]                              # [P, T, width]
+        kv = jnp.einsum("ptr,rhk->pthk", ckr[..., :rank],
+                        p["wkv_b"].astype(jnp.bfloat16))
+        kr = jnp.broadcast_to(ckr[..., None, rank:rank + rope],
+                              kv.shape[:3] + (rope,))
+        k = jnp.concatenate([kv[..., :nope], kr], -1).reshape(P, T, -1)
+        v = kv[..., nope:].reshape(P, T, -1)
+        o = paged_attention(jnp.concatenate(q, -1), k, v,
+                            jnp.arange(P, dtype=jnp.int32)[None], cursor,
+                            impl="pallas")
+        return jnp.einsum("bshv,hvd->bsd", o, p["wo"].astype(jnp.bfloat16))
+
+    def ms(fn, *args):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(4):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return round((time.perf_counter() - t0) / 4 * 1e3, 3)
+
+    out = {}
+    for context in (8192, 20480, 55296):
+        cursor = jnp.asarray([context - C], jnp.int32)
+        q = rows(1, 1, C)
+        a, e = (fn(pool, q, cursor, tables[:1])
+                for fn in (absorbed, expanded))
+        off = float(jnp.abs(a.astype(jnp.float32) - e.astype(jnp.float32)
+                            ).max() / jnp.abs(e.astype(jnp.float32)).max())
+        out[str(context)] = {
+            "chunk_absorbed_ms": ms(absorbed, pool, q, cursor, tables[:1]),
+            "chunk_expanded_ms": ms(expanded, pool, q, cursor, tables[:1]),
+            "absorbed_off_expanded": off,
+            "step_8_rows_ms": ms(absorbed, pool, rows(1, 8, 1), jnp.full(
+                (8,), context, jnp.int32), tables)}
+    return out
+
+
 def select_timing(seed: int, topk: int, ref) -> dict:
     """``indexed_select`` alone (ISSUE 53) at the cell's chunk: 512 rows
     that end at 2k, 16k, 38k and 49k of a 49,664-lane table of seeded
@@ -1627,6 +1898,15 @@ def keye_phase(seed: int) -> None:
     emit("keye", seconds=round(time.perf_counter() - t0, 1), **out)
 
 
+def glm_phase(seed: int) -> None:
+    import ray_tpu
+
+    t0 = time.perf_counter()
+    out = ray_tpu.get(
+        ray_tpu.remote(num_tpus=1)(glm_task).remote(seed), timeout=2400)
+    emit("glm", seconds=round(time.perf_counter() - t0, 1), **out)
+
+
 def serve_phase(seed: int) -> None:
     import ray_tpu
     import ray_tpu.serve as serve
@@ -1742,6 +2022,7 @@ def one_chip(seed: int) -> dict:
     brumby_phase(seed)
     mellum_phase(seed)
     keye_phase(seed)
+    glm_phase(seed)
     serve_phase(seed)
     return out["device"]
 

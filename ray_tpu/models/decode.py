@@ -18,18 +18,21 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.transformer import (ATTENTION, INDEXED, LINEAR,
+from ray_tpu.models.transformer import (ATTENTION, INDEXED, LATENT, LINEAR,
                                         OWN_PAGE_TOKENS, RETENTION,
                                         SLIDING, SPARSE, STATE_KINDS,
                                         STATE_MIXERS,
                                         TransformerConfig, _gated_out, _mlp,
                                         _norm, _qkv, _residual, embed,
                                         final_hidden, forward, indexed_mix,
-                                        indexed_project, layer_params,
+                                        indexed_project, latent_finish,
+                                        latent_mix, latent_project,
+                                        layer_params,
                                         project, rope_table,
                                         sparse_mix, sparse_pool_pages,
                                         stacked_mlp, state_shapes,
                                         write_pages, written_pages)
+from ray_tpu.ops.latent_attention import pool_width
 from ray_tpu.ops.paged_attention import paged_attention
 from ray_tpu.ops.sparse_attention import check_pool, update_page_means
 
@@ -138,6 +141,11 @@ def init_caches(cfg: TransformerConfig, batch: int, max_len: int,
                 1 + batch * -(-max_len // OWN_PAGE_TOKENS), OWN_PAGE_TOKENS,
                 cfg.kv_heads, cfg.head_dim, cfg.indexer.indexer_head_dim,
                 dtype), length=zero)
+        if kind == LATENT:
+            # a pool of its own of latent-and-key rows, no K or V
+            return dataclasses.replace(LatentPagedKVCache.zeros(
+                1 + batch * -(-max_len // OWN_PAGE_TOKENS), OWN_PAGE_TOKENS,
+                cfg.latent_kv_rank, cfg.latent_rope_dim, dtype), length=zero)
         # a pool of its own: page 0 the garbage page, then a sequence's
         # pages in order, so its page table is the identity
         return dataclasses.replace(SparsePagedKVCache.zeros(
@@ -325,6 +333,7 @@ def slot_decode_step(cfg: TransformerConfig, params, tokens, active, caches):
 # exists and a step's cost follows the pages a sequence holds (the layer
 # kinds that keep more than keys and values in a page or beside it — pooled
 # key rows for 'minicpm4', an index key a token for 'indexed_attention', a
+# latent and a rotated key and NO keys or values for 'latent_attention', a
 # state a slot for 'lightning-attn' and 'power-retention', a pool of their
 # own for 'sliding_attention' — are said at ``init_paged_caches``). ``attn``
 # names the op's implementation ('reference' | 'pallas') and has no default:
@@ -411,13 +420,44 @@ class IndexedPagedKVCache:
             (num_pages, page_tokens, index_dim), dtype))
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class LatentPagedKVCache:
+    """A 'latent_attention' layer's page pool: ``ckr`` [num_pages,
+    page_tokens, width], a row a token — its latent after its norm (rank
+    values), the one rotated key its heads share (rope values), and zeros up
+    to whole 128-lane tiles (``ops.latent_attention.join``: 512 + 64 -> 640;
+    the chip holds a 576-lane row, or a 64-lane one beside a 512-lane one,
+    in as many) — no keys, no values: a cached forward attends the latents
+    themselves. It lies under the page table every page-holding kind uses,
+    so a page is spliced, shared or freed as theirs are. ``k`` names it for
+    whoever asks a pool for its page size. The same layout is that layer's
+    CONTIGUOUS cache (``init_caches``), which then carries its ``length``;
+    in the serving pool the cursors are the caller's and it is None."""
+
+    ckr: Any
+    length: Any = None
+
+    @property
+    def k(self):
+        return self.ckr
+
+    @classmethod
+    def zeros(cls, num_pages: int, page_tokens: int, rank: int, rope: int,
+              dtype=jnp.bfloat16) -> "LatentPagedKVCache":
+        return cls(ckr=jnp.zeros(
+            (num_pages, page_tokens, pool_width(rank, rope)), dtype))
+
+
 def init_paged_caches(cfg: TransformerConfig, num_pages: int,
                       page_tokens: int, pages_per_slot: int,
                       dtype=None, slots: Optional[int] = None,
                       window_pages: Optional[int] = None) -> List[Any]:
     """The serving pool, a layer at a time and by the layer's kind: pages
     for an attention layer (with pooled key rows for a 'minicpm4' one, with
-    an index key a token for an 'indexed_attention' one), a
+    an index key a token for an 'indexed_attention' one; a row of a latent
+    and a rotated key a token, and nothing else, for a 'latent_attention'
+    one), a
     state a slot (``slots`` of them) for a layer of a kind in
     ``STATE_KINDS``. A model none of whose layers holds a page has no pool:
     ``num_pages`` may then be anything, and nothing is made of it. A
@@ -457,6 +497,10 @@ def init_paged_caches(cfg: TransformerConfig, num_pages: int,
             return IndexedPagedKVCache.zeros(
                 num_pages, page_tokens, cfg.kv_heads, cfg.head_dim,
                 cfg.indexer.indexer_head_dim, dtype)
+        if kind == LATENT:
+            return LatentPagedKVCache.zeros(
+                num_pages, page_tokens, cfg.latent_kv_rank,
+                cfg.latent_rope_dim, dtype)
         pool = SparsePagedKVCache if kind == SPARSE else PagedKVCache
         return pool.zeros(window_pages if kind == SLIDING else num_pages,
                           page_tokens, cfg.kv_heads, cfg.head_dim, dtype)
@@ -567,7 +611,10 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
     call a group (``transformer.sparse_mix``); an 'indexed_attention' layer
     writes its index keys with the keys and values, then scores them,
     picks and attends its tokens, a call a group
-    (``transformer.indexed_mix``); a
+    (``transformer.indexed_mix``); a 'latent_attention' layer writes a
+    latent and a rotated key a token and attends the latents, the keys' and
+    values' up-projections absorbed, a call a group
+    (``transformer.latent_mix``); a
     'lightning-attn' or 'power-retention' layer (``transformer
     .STATE_MIXERS``) reads and writes its states instead, a kernel call a
     group (``_Rows`` says whose states a group's rows meet). Layer math
@@ -589,7 +636,8 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
     final norm, [S, K, d] (several groups: [1, rows, d], group after
     group) — the caller norms and projects the rows it samples (``_head``);
     moe is None for a dense model, else ``{"counts": [L, E], "routes": [L,
-    *rows, k]}`` — the rows each layer's experts received (they sum to valid
+    *rows, k]}`` over the L EXPERT layers (leading dense layers choose
+    nothing) — the rows each layer's experts received (they sum to valid
     rows x k a layer: no row is dropped; several groups: [L, groups, E], the
     rows each GROUP sent them) and the experts each row chose."""
     several = len(groups) > 1
@@ -658,6 +706,18 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
             a = jnp.einsum("bshk,hkd->bsd", batch(outs),
                            ap["wo"].astype(cfg.dtype))
             new_caches.append(IndexedPagedKVCache(*pools))
+        elif kind == LATENT:
+            attending, (row,), _ = latent_project(cfg, ap, h, positions)
+            pools = (write_pages(c.ckr, row, pages[pool_of(kind)], offs),)
+            # a call a group, the step's under one name and the chunk's
+            # under another (``latent_mix``); absorbed either way
+            outs = [_unless_idle(g, several, lambda _: (
+                latent_mix(cfg, ap, rows, pools,
+                           pool_tables(g.read_tables, kind), g.lengths,
+                           impl=impl), ()), ())[0]
+                for g, *rows in zip(groups, *map(split, attending))]
+            a = latent_finish(cfg, ap, batch(outs))
+            new_caches.append(LatentPagedKVCache(*pools))
         else:
             q, k, v = _qkv(cfg, ap, h, None if kind == SPARSE else rope,
                            positions, kind)
@@ -687,7 +747,8 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
                 new_caches.append(PagedKVCache(k=ck, v=cv))
         x = _residual(cfg, x, a)
         mlp_p, layer = stacked_mlp(cfg, params, p, i)
-        m, _, moe = _mlp(cfg, mlp_p, _norm(cfg, p["ln2"], x), valid, layer)
+        m, _, moe = _mlp(cfg, mlp_p, _norm(cfg, p["ln2"], x), valid, layer,
+                         cfg.mlp_of(i))
         x = _residual(cfg, x, m)
         if moe is not None and several:
             # the experts saw one batch; who sent them which rows is still
@@ -697,9 +758,10 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
                     live[..., None], routes, moe["counts"].shape[0])].add(
                         1, mode="drop")
                 for live, routes in zip(split(valid), split(moe["routes"]))])
-        moe_layers.append(moe)
+        if moe is not None:  # a leading dense layer has none
+            moe_layers.append(moe)
     moe = (jax.tree.map(lambda *a: jnp.stack(a), *moe_layers)
-           if cfg.mlp == "moe" else None)
+           if moe_layers else None)
     return x, new_caches, moe
 
 

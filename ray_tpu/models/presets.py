@@ -183,6 +183,33 @@ def keye_debug(**overrides) -> TransformerConfig:
     return TransformerConfig(**kw)
 
 
+def glm_moe_lite_debug(**overrides) -> TransformerConfig:
+    """Tiny GLM-4.7-Flash-shaped config (zai-org/GLM-4.7-Flash,
+    ``glm4_moe_lite``: every layer 'latent_attention' — the query through a
+    low-rank bottleneck with a norm of its own, keys and values from one
+    normed latent a token plus one rotated key the heads share, heads of
+    unequal q/k and v parts — under a leading dense SwiGLU layer and then
+    expert layers whose router scores by sigmoid, chooses by score + bias,
+    renormalizes and scales the weights, beside one shared expert) for
+    tests: 4 heads of 24 + 8 q/k values and 32 v values over a latent of 32,
+    8 experts of 48 top-3 times 1.8. The multi-token-prediction block is
+    not part of it. Every layer is of the kind, however many ``num_layers``
+    says, unless ``layer_kinds`` is given."""
+    kw = dict(
+        vocab_size=256, num_layers=3, embed_dim=64, num_heads=4,
+        latent_q_rank=48, latent_kv_rank=32, latent_nope_dim=24,
+        latent_rope_dim=8, latent_v_dim=32, mlp="moe", mlp_dim=48,
+        moe_num_experts=8, moe_top_k=3, moe_renormalize=True,
+        moe_scoring="sigmoid", moe_routed_scale=1.8, moe_shared_experts=1,
+        moe_dense_layers=1, dense_mlp_dim=96, max_seq_len=256,
+        norm="rmsnorm", pos="rope", norm_eps=1e-5, rope_theta=10000.0,
+        tie_embeddings=False, dtype=jnp.float32,
+    )
+    kw.update(overrides)
+    kw.setdefault("layer_kinds", ("latent_attention",) * kw["num_layers"])
+    return TransformerConfig(**kw)
+
+
 # ---------------------------------------------------------------------------
 # pipeline stage partition (MPMD train.PipelineTrainer shards)
 #

@@ -10,7 +10,17 @@ Config switches:
   * norm: 'rmsnorm' (LLaMA) | 'layernorm' (GPT-2)
   * pos:  'rope' (LLaMA) | 'learned' (GPT-2)
   * mlp:  'swiglu' (LLaMA) | 'gelu' (GPT-2) | 'moe' (SwiGLU experts,
-          dropless top-k routing over grouped matmuls, ops/moe.py)
+          dropless top-k routing over grouped matmuls, ops/moe.py). The
+          feed-forward is a pattern over the depth too: the first
+          ``moe_dense_layers`` layers of an expert model are plain SwiGLU of
+          ``dense_mlp_dim`` (a source's ``first_k_dense_replace``), the
+          expert layers follow; ``cfg.mlp_of(i)`` is the ONE place that
+          says which layer has which, and the leading layers are kept apart
+          from the scanned ones (``params["blocks"]["lead"]`` and
+          ``["body"]``). ``moe_scoring`` ('softmax' | 'sigmoid', the latter
+          chosen by score + bias), ``moe_routed_scale`` and
+          ``moe_shared_experts`` are the router's rule and the dense expert
+          every token takes beside its routed ones.
   * GQA via num_kv_heads; tied embeddings via tie_embeddings; head_dim a
     field where it is not embed_dim // num_heads.
   * rope_parameters: a RoPE rule a kind of layer, keyed as the source keys
@@ -40,7 +50,15 @@ Config switches:
     one cached index key a token, and the query attends the ``topk`` best
     TOKENS, every one while the context is no longer than that;
     ops/indexed_attention.py; its pages hold the index key beside K and V,
-    in the full layers' pool). 'full_attention' is
+    in the full layers' pool) and 'latent_attention' (multi-head LATENT
+    attention: q through a low-rank bottleneck with a norm of its own, keys
+    and values rebuilt from ONE latent of ``latent_kv_rank`` values a token
+    plus ONE rotated key of ``latent_rope_dim`` shared by the heads; what a
+    token leaves in the cache is the latent after its norm and that key
+    after RoPE, and a cached forward attends the latents themselves, the
+    key's and the value's up-projection absorbed into the query and the
+    output; ops/latent_attention.py; its pages, in the full layers' pool,
+    hold the two joined in one row a token). 'full_attention' is
     taken for 'attention', so a source's ``layer_types`` map straight onto
     ``layer_kinds``. ``rope_scaling``'s ``mrope_section`` turns runs of
     frequency pairs by a position stream each (temporal, height, width:
@@ -70,6 +88,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import attention
 from ray_tpu.ops.indexed_attention import IndexerSizes, indexed_attention
+from ray_tpu.ops.latent_attention import (join as latent_row,
+                                          latent_attention)
 from ray_tpu.ops.flash_attention import FLASH_LSE, FLASH_OUT
 from ray_tpu.ops.linear_attention import (linear_attention_chunk,
                                           linear_attention_step, slopes)
@@ -89,7 +109,8 @@ ATTENTION, SPARSE, LINEAR = "attention", "minicpm4", "lightning-attn"
 RETENTION = "power-retention"
 SLIDING = "sliding_attention"
 INDEXED = "indexed_attention"
-LAYER_KINDS = (ATTENTION, SPARSE, LINEAR, RETENTION, SLIDING, INDEXED)
+LATENT = "latent_attention"
+LAYER_KINDS = (ATTENTION, SPARSE, LINEAR, RETENTION, SLIDING, INDEXED, LATENT)
 # what a source calls the kind this file calls 'attention': its name in
 # ``layer_kinds`` as given and in ``rope_parameters``
 FULL_ATTENTION = "full_attention"
@@ -112,6 +133,18 @@ class TransformerConfig:
     moe_top_k: int = 2
     moe_renormalize: bool = True              # False: OLMoE (norm_topk_prob)
     moe_aux_weight: float = 0.01
+    # the router's rule (ops.moe.route): 'softmax', or 'sigmoid' scores
+    # chosen by score + a bias an expert and weighed without it, the weights
+    # then times moe_routed_scale
+    moe_scoring: str = "softmax"
+    moe_routed_scale: float = 1.0
+    # dense experts every token takes beside its routed ones, mlp_dim wide
+    # each (one SwiGLU of their joined width)
+    moe_shared_experts: int = 0
+    # the first so many layers of an expert model are plain SwiGLU of
+    # dense_mlp_dim (a source's first_k_dense_replace / intermediate_size)
+    moe_dense_layers: int = 0
+    dense_mlp_dim: Optional[int] = None
     # RMSNorm over ALL H*D (resp. Hkv*D) projected values of q and k, before
     # the split into heads and before RoPE (OLMoE's q_norm / k_norm)
     qk_norm: bool = False
@@ -132,6 +165,14 @@ class TransformerConfig:
     # .IndexerSizes' fields, a source's ``sa_config``; a dict is taken and
     # frozen)
     sa_config: Any = None
+    # 'latent_attention': the ranks of the query's and the keys-and-values'
+    # bottlenecks, a head's unrotated and rotated q/k values and its v
+    # values (head_dim is the two q/k parts together)
+    latent_q_rank: int = 0
+    latent_kv_rank: int = 0
+    latent_nope_dim: int = 0
+    latent_rope_dim: int = 0
+    latent_v_dim: int = 0
     # a source's ``rope_scaling`` (a dict is taken and frozen), read for
     # ``mrope_section`` alone: runs of frequency pairs, a position stream
     # each
@@ -205,6 +246,25 @@ class TransformerConfig:
         if SLIDING in self.kinds and self.sliding_window < 1:
             raise ValueError("a 'sliding_attention' layer needs "
                              f"sliding_window >= 1, got {self.sliding_window}")
+        if LATENT in self.kinds:
+            sizes = (self.latent_q_rank, self.latent_kv_rank,
+                     self.latent_nope_dim, self.latent_rope_dim,
+                     self.latent_v_dim)
+            if min(sizes) < 1 or self.latent_rope_dim % 2:
+                raise ValueError(
+                    "a 'latent_attention' layer needs latent_q_rank, "
+                    "latent_kv_rank, latent_nope_dim, latent_rope_dim (even) "
+                    f"and latent_v_dim, got {sizes}")
+            object.__setattr__(self, "head_dim", self.latent_nope_dim
+                               + self.latent_rope_dim)
+        if self.moe_dense_layers and not (
+                self.mlp == "moe"
+                and 0 < self.moe_dense_layers < self.num_layers
+                and len(set(self.kinds[:self.moe_dense_layers])) == 1):
+            raise ValueError(
+                "moe_dense_layers counts the leading dense layers of an "
+                "expert model (mlp='moe'), of one mixer kind and fewer than "
+                f"num_layers, got {self.moe_dense_layers}")
 
     @property
     def kv_heads(self) -> int:
@@ -215,10 +275,34 @@ class TransformerConfig:
         return self.layer_kinds or (ATTENTION,) * self.num_layers
 
     @property
+    def lead_layers(self) -> int:
+        """The leading layers whose feed-forward is not the model's
+        ``mlp``: stacked apart from the rest and applied before the scan."""
+        return self.moe_dense_layers
+
+    def mlp_of(self, i: int) -> str:
+        """Which feed-forward layer ``i`` has: THE definition, read by the
+        parameters, their axes and every forward."""
+        return "swiglu" if i < self.moe_dense_layers else self.mlp
+
+    def mlp_width(self, ff: str) -> int:
+        """The hidden width of a feed-forward of kind ``ff`` of this model:
+        an expert's, or a leading dense layer's."""
+        if ff != self.mlp and self.dense_mlp_dim:
+            return self.dense_mlp_dim
+        return self.hidden_dim
+
+    @property
+    def expert_layers(self) -> int:
+        return (self.num_layers - self.moe_dense_layers
+                if self.mlp == "moe" else 0)
+
+    @property
     def period(self) -> int:
         """The pattern's period: the layers one scan step applies when
-        layers are stacked (1 for a model of one kind)."""
-        kinds = self.kinds
+        layers are stacked (1 for a model of one kind), over the layers
+        behind the leading ones."""
+        kinds = self.kinds[self.lead_layers:]
         return next(p for p in range(1, len(kinds) + 1)
                     if len(kinds) % p == 0
                     and all(k == kinds[i % p] for i, k in enumerate(kinds)))
@@ -281,10 +365,12 @@ class TransformerConfig:
 # params
 
 
-def _block_params(cfg: TransformerConfig, key,
-                  kind: str = ATTENTION) -> Dict[str, Any]:
+def _block_params(cfg: TransformerConfig, key, kind: str = ATTENTION,
+                  ff: Optional[str] = None) -> Dict[str, Any]:
+    """``ff``: the layer's feed-forward (``cfg.mlp_of``); None: ``cfg.mlp``."""
+    ff = ff or cfg.mlp
     d, h, kvh, hd, f = (cfg.embed_dim, cfg.num_heads, cfg.kv_heads,
-                        cfg.head_dim, cfg.hidden_dim)
+                        cfg.head_dim, cfg.mlp_width(ff))
     if kind == LINEAR:
         kvh = h  # a key and a value head for every query head
     ks = jax.random.split(key, 8)
@@ -292,7 +378,8 @@ def _block_params(cfg: TransformerConfig, key,
     out_init = jax.nn.initializers.normal(
         0.02 / math.sqrt(2 * cfg.num_layers), cfg.param_dtype)
     p: Dict[str, Any] = {
-        "attn": {
+        "attn": _latent_params(cfg, ks, init, out_init) if kind == LATENT
+        else {
             "wq": init(ks[0], (d, h, hd)),
             "wk": init(ks[1], (d, kvh, hd)),
             "wv": init(ks[2], (d, kvh, hd)),
@@ -321,14 +408,16 @@ def _block_params(cfg: TransformerConfig, key,
             wi_w=init(ki[2], (d, hi)),
             ik_scale=jnp.ones((di,), cfg.param_dtype),
             ik_bias=jnp.zeros((di,), cfg.param_dtype))
-    if cfg.mlp == "moe":
-        from ray_tpu.ops.moe import init_moe_params
+    if ff == "moe":
+        from ray_tpu.ops.moe import SIGMOID, init_moe_params
 
         if cfg.moe_num_experts < 2:
             raise ValueError("mlp='moe' needs moe_num_experts >= 2")
-        p["mlp"] = init_moe_params(ks[4], d, f, cfg.moe_num_experts,
-                                   cfg.param_dtype)
-    elif cfg.mlp == "swiglu":
+        p["mlp"] = init_moe_params(
+            ks[4], d, f, cfg.moe_num_experts, cfg.param_dtype,
+            choice_bias=cfg.moe_scoring == SIGMOID,
+            shared_dim=cfg.moe_shared_experts * f)
+    elif ff == "swiglu":
         p["mlp"] = {
             "w_gate": init(ks[4], (d, f)),
             "w_up": init(ks[5], (d, f)),
@@ -342,6 +431,27 @@ def _block_params(cfg: TransformerConfig, key,
             "b_out": jnp.zeros((d,), cfg.param_dtype),
         }
     return p
+
+
+def _latent_params(cfg: TransformerConfig, ks, init, out_init):
+    """A 'latent_attention' layer's mixer: the query's bottleneck and its
+    norm, the projection to the latent and (behind it) the one rotated key
+    every head shares, the latent's norm, the up-projection to a head's
+    unrotated key values and (behind them) its values, and ``wo``."""
+    d, h = cfg.embed_dim, cfg.num_heads
+    rq, rkv, nope, rot, dv = (
+        cfg.latent_q_rank, cfg.latent_kv_rank, cfg.latent_nope_dim,
+        cfg.latent_rope_dim, cfg.latent_v_dim)
+    kl = jax.random.split(ks[7], 4)
+    return {
+        "wq_a": init(kl[0], (d, rq)),
+        "q_a_norm": jnp.ones((rq,), cfg.param_dtype),
+        "wq_b": init(kl[1], (rq, h, nope + rot)),
+        "wkv_a": init(kl[2], (d, rkv + rot)),
+        "kv_norm": jnp.ones((rkv,), cfg.param_dtype),
+        "wkv_b": init(kl[3], (rkv, h, nope + dv)),
+        "wo": out_init(ks[3], (h, dv, d)),
+    }
 
 
 def _norm_params(cfg: TransformerConfig, dim: int):
@@ -364,30 +474,45 @@ def init_params(cfg: TransformerConfig, key) -> Dict[str, Any]:
     if not cfg.tie_embeddings:
         params["lm_head"] = {
             "kernel": init(keys[2], (cfg.embed_dim, cfg.vocab_size))}
-    blocks = [_block_params(cfg, keys[3 + i], kind)
+    blocks = [_block_params(cfg, keys[3 + i], kind, cfg.mlp_of(i))
               for i, kind in enumerate(cfg.kinds)]
     stack = lambda layers: jax.tree.map(
         lambda *xs: jnp.stack(xs, axis=0), *layers)
-    if cfg.scan_layers and cfg.period == 1:
-        params["blocks"] = stack(blocks)
-    elif cfg.scan_layers:
+    lead = cfg.lead_layers
+    if not cfg.scan_layers:
+        params["blocks"] = {str(i): b for i, b in enumerate(blocks)}
+        return params
+    if cfg.period == 1:
+        body = stack(blocks[lead:])
+    else:
         # layers of unequal kinds have unequal shapes: stacked by their
         # place in the pattern's period, which is the scan's unit
-        params["blocks"] = {f"p{j}": stack(blocks[j::cfg.period])
-                            for j in range(cfg.period)}
-    else:
-        params["blocks"] = {str(i): b for i, b in enumerate(blocks)}
+        body = {f"p{j}": stack(blocks[lead + j::cfg.period])
+                for j in range(cfg.period)}
+    # leading layers of another feed-forward have shapes of their own too:
+    # a stack before the scanned ones
+    params["blocks"] = ({"lead": stack(blocks[:lead]), "body": body}
+                        if lead else body)
     return params
+
+
+def body_params(cfg: TransformerConfig, params):
+    """The stacked layers behind the leading ones (``scan_layers``): all of
+    ``params["blocks"]`` for a model without leading layers."""
+    return params["blocks"]["body"] if cfg.lead_layers else params["blocks"]
 
 
 def layer_params(cfg: TransformerConfig, params, i: int):
     """Layer ``i``'s weights, whichever way ``init_params`` laid them out."""
     if not cfg.scan_layers:
         return params["blocks"][str(i)]
+    if i < cfg.lead_layers:
+        return jax.tree.map(lambda a: a[i], params["blocks"]["lead"])
+    i -= cfg.lead_layers
     if cfg.period == 1:
-        return jax.tree.map(lambda a: a[i], params["blocks"])
+        return jax.tree.map(lambda a: a[i], body_params(cfg, params))
     return jax.tree.map(lambda a: a[i // cfg.period],
-                        params["blocks"][f"p{i % cfg.period}"])
+                        body_params(cfg, params)[f"p{i % cfg.period}"])
 
 
 def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
@@ -399,7 +524,7 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
             return {"scale": L + ("embed_notp",)}
         return {"scale": L + ("embed_notp",), "bias": L + ("embed_notp",)}
 
-    def block_axes(kind):
+    def block_axes(kind, ff=cfg.mlp):
         kv = "heads" if kind == LINEAR else "kv"
         block = {
             "attn": {
@@ -425,11 +550,20 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
                 wi_q=L + ("embed", None, None), wi_k=L + ("embed", None),
                 wi_w=L + ("embed", None), ik_scale=L + (None,),
                 ik_bias=L + (None,))
-        if cfg.mlp == "moe":
-            from ray_tpu.ops.moe import moe_logical_axes
+        if kind == LATENT:
+            block["attn"] = {
+                "wq_a": L + ("embed", None), "q_a_norm": L + (None,),
+                "wq_b": L + (None, "heads", "head_dim"),
+                "wkv_a": L + ("embed", None), "kv_norm": L + (None,),
+                "wkv_b": L + (None, "heads", "head_dim"),
+                "wo": L + ("heads", "head_dim", "embed")}
+        if ff == "moe":
+            from ray_tpu.ops.moe import SIGMOID, moe_logical_axes
 
-            block["mlp"] = {k: L + v for k, v in moe_logical_axes().items()}
-        elif cfg.mlp == "swiglu":
+            block["mlp"] = {k: L + v for k, v in moe_logical_axes(
+                cfg.moe_scoring == SIGMOID,
+                bool(cfg.moe_shared_experts)).items()}
+        elif ff == "swiglu":
             block["mlp"] = {"w_gate": L + ("embed", "mlp"),
                             "w_up": L + ("embed", "mlp"),
                             "w_down": L + ("mlp", "embed")}
@@ -440,13 +574,18 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
                             "b_out": L + ("embed_notp",)}
         return block
 
-    kinds = cfg.kinds
-    if cfg.scan_layers and cfg.period == 1:
-        blocks = block_axes(kinds[0])
-    elif cfg.scan_layers:
-        blocks = {f"p{j}": block_axes(kinds[j]) for j in range(cfg.period)}
+    kinds, lead = cfg.kinds, cfg.lead_layers
+    if not cfg.scan_layers:
+        blocks = {str(i): block_axes(kind, cfg.mlp_of(i))
+                  for i, kind in enumerate(kinds)}
+    elif cfg.period == 1:
+        blocks = block_axes(kinds[lead])
     else:
-        blocks = {str(i): block_axes(kind) for i, kind in enumerate(kinds)}
+        blocks = {f"p{j}": block_axes(kinds[lead + j])
+                  for j in range(cfg.period)}
+    if cfg.scan_layers and lead:
+        blocks = {"lead": block_axes(kinds[0], cfg.mlp_of(0)),
+                  "body": blocks}
     axes: Dict[str, Any] = {
         "embed": {"table": ("vocab", "embed")},
         "final_norm": {"scale": ("embed_notp",)} if cfg.norm == "rmsnorm"
@@ -885,8 +1024,99 @@ def indexed_mixer(cfg, p, x, rope, rope_positions, positions, lengths,
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(cfg.dtype)), pools
 
 
-# tokens of a page of the pool an 'indexed_attention' layer makes for itself
-# where nobody hands it one (no cache, a contiguous cache)
+def latent_project(cfg, p, x, positions):
+    """What a 'latent_attention' layer makes of the normalized x [B, S, d]
+    at ``positions`` [B, S]: (the rows that attend — q's unrotated part [B,
+    S, H, nope] and its rotated part [B, S, H, rope] —, what they leave in
+    the cache — the latent after its norm and the one key after RoPE, joined
+    into the pool's row [B, S, 1, width] (``ops.latent_attention.join``) —,
+    and the two as they are, [B, S, rank] and [B, S, 1, rope], for a forward
+    without a cache)."""
+    nope, rank = cfg.latent_nope_dim, cfg.latent_kv_rank
+    turn = lambda a: apply_rotary_at(a, positions, cfg.rope_theta)
+    cq = rms_norm(jnp.einsum("bsd,dr->bsr", x, p["wq_a"].astype(cfg.dtype)),
+                  p["q_a_norm"], cfg.norm_eps)
+    q = jnp.einsum("bsr,rhk->bshk", cq, p["wq_b"].astype(cfg.dtype))
+    ckr = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"].astype(cfg.dtype))
+    c = rms_norm(ckr[..., :rank], p["kv_norm"], cfg.norm_eps)
+    kr = turn(ckr[..., None, rank:])
+    return ((q[..., :nope], turn(q[..., nope:])),
+            (latent_row(c[:, :, None], kr),), (c, kr))
+
+
+def latent_absorb(cfg, p, q_nope):
+    """The key's up-projection folded into the query: q_nope [B, S, H, nope]
+    -> [B, S, H, rank], whose dot with a latent is q_nope's with the key
+    that latent expands to."""
+    wk = p["wkv_b"][..., :cfg.latent_nope_dim].astype(cfg.dtype)
+    return jnp.einsum("bshk,rhk->bshr", q_nope, wk)
+
+
+def latent_mix(cfg, p, rows, pools, read_tables, lengths, *, impl: str):
+    """'latent_attention', one group, its latents and rotated keys already
+    in the pool: rows = (q_nope, q_rope) [B, S, H, .] at positions
+    ``lengths[b]`` on attend the LATENTS of their context, absorbed.
+    ``pools`` = (the tokens' rows [N, T, width],), a row's pages through
+    ``read_tables`` [B, P], ``lengths`` [B] as ``ops.paged_attention`` takes
+    them. Returns o' [B, S, H, rank]: the heads' mixes of latents, which
+    ``latent_finish`` expands. The scale is that of the unabsorbed head,
+    1/sqrt(nope + rope)."""
+    q_nope, q_rope = rows
+    return latent_attention(
+        latent_absorb(cfg, p, q_nope), q_rope, *pools, read_tables, lengths,
+        sm_scale=cfg.head_dim ** -0.5, impl=impl,
+        name=("latent_step_attention" if q_nope.shape[1] == 1
+              else "latent_chunk_attention"))
+
+
+def latent_finish(cfg, p, o):
+    """o' [B, S, H, rank] -> y [B, S, d]: the value's up-projection, then
+    ``wo``."""
+    wv = p["wkv_b"][..., cfg.latent_nope_dim:].astype(cfg.dtype)
+    o = jnp.einsum("bshr,rhv->bshv", o.astype(cfg.dtype), wv)
+    return jnp.einsum("bshv,hvd->bsd", o, p["wo"].astype(cfg.dtype))
+
+
+def latent_expanded(cfg, p, rows, made):
+    """The layer WITHOUT a cache, unabsorbed: every token's keys and values
+    rebuilt from its latent, the shared rotated key behind each head's own,
+    and plain causal attention over heads of nope + rope (the values are as
+    wide when ``latent_v_dim`` is: the flash kernel's shape; else the
+    reference's) -> y [B, S, d]."""
+    (q_nope, q_rope), (c, kr) = rows, made
+    kv = jnp.einsum("bsr,rhk->bshk", c, p["wkv_b"].astype(cfg.dtype))
+    k = jnp.concatenate([kv[..., :cfg.latent_nope_dim], jnp.broadcast_to(
+        kr, kr.shape[:2] + (cfg.num_heads, kr.shape[-1]))], axis=-1)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    v = kv[..., cfg.latent_nope_dim:]
+    if v.shape[-1] == q.shape[-1]:
+        o = _plain_attention(cfg, q, k, v)
+    else:
+        o = attention(q, k, jnp.pad(v, ((0, 0),) * 3 + (
+            (0, q.shape[-1] - v.shape[-1]),)), causal=True,
+            impl="reference")[..., :v.shape[-1]]
+    return jnp.einsum("bshv,hvd->bsd", o, p["wo"].astype(cfg.dtype))
+
+
+def latent_mixer(cfg, p, x, positions, lengths, pools, read_tables,
+                 write_tables, *, impl: str):
+    """'latent_attention' over ONE group of rows: x [B, S, d] at
+    ``positions`` [B, S] over a paged pool (``latent_mix`` says what the
+    arguments are; a row's pages are written through ``write_tables`` [B,
+    P]). The latents and rotated keys are written, then attended. Returns
+    (y, pools)."""
+    T = pools[0].shape[1]
+    rows, new, _ = latent_project(cfg, p, x, positions)
+    cells = written_pages(write_tables, positions, T), positions % T
+    pools = tuple(write_pages(pool, made, *cells)
+                  for pool, made in zip(pools, new))
+    o = latent_mix(cfg, p, rows, pools, read_tables, lengths, impl=impl)
+    return latent_finish(cfg, p, o), pools
+
+
+# tokens of a page of the pool an 'indexed_attention' or 'latent_attention'
+# layer makes for itself where nobody hands it one (no cache, a contiguous
+# cache)
 OWN_PAGE_TOKENS = 16
 
 
@@ -931,6 +1161,16 @@ def _mixer(cfg, kind, p, x, rope, positions, sp_axis, cache, taps):
             cache, k=pools[0], v=pools[1], ik=pools[2], length=length + S)
     pos = jnp.broadcast_to(jnp.arange(S)[None] if positions is None
                            else positions, (B, S)).astype(jnp.int32)
+    if kind == LATENT:
+        if cache is None:
+            rows, _, made = latent_project(cfg, p, x, pos)
+            return latent_expanded(cfg, p, rows, made), None
+        tables = _own_tables((cache.ckr,), B)
+        y, (ckr,) = latent_mixer(
+            cfg, p, x, pos, jnp.broadcast_to(cache.length, (B,)),
+            (cache.ckr,), tables, tables, impl=resolve_impl(cfg))
+        return y, dataclasses.replace(cache, ckr=ckr,
+                                      length=cache.length + S)
     if kind in STATE_KINDS:
         state = ({name: jnp.zeros(shape, jnp.float32) for name, shape
                   in state_shapes(cfg, kind, B).items()} if cache is None
@@ -958,22 +1198,28 @@ def _residual(cfg, x, y):
     return x + y * cfg.residual_scale if cfg.scale_depth else x + y
 
 
-def _mlp(cfg, p, x, valid=None, layer=None):
-    """Returns (y, aux_loss, moe): aux is 0 and moe None except for MoE
-    routing, where moe is ``{"counts": [E], "routes": [B, S, k]}`` (rows
-    each expert received; the experts each row chose). ``valid``: bool
-    [B, S], rows that are not live (the expert layer routes them nowhere; a
-    dense mlp takes no notice). ``layer`` (experts only): ``p`` is every
-    layer's weights stacked and this is the one to apply (``stacked_mlp``)."""
-    if cfg.mlp == "moe":
+def _mlp(cfg, p, x, valid=None, layer=None, ff=None):
+    """A layer's feed-forward, ``ff`` of ``cfg.mlp_of`` (None: ``cfg.mlp``,
+    the model's own). Returns (y, aux_loss, moe): aux is 0 and moe None
+    except for an expert layer, whose router's rule is the config's
+    (``moe_scoring``, ``moe_renormalize``, ``moe_routed_scale``:
+    ``ops.moe.route``) and whose moe is ``{"counts": [E], "routes": [B, S,
+    k]}`` (rows each expert received; the experts each row chose).
+    ``valid``: bool [B, S], rows that are not live (the expert layer routes
+    them nowhere; a dense mlp takes no notice). ``layer`` (experts only):
+    ``p`` is every layer's weights stacked and this is the one to apply
+    (``stacked_mlp``)."""
+    ff = ff or cfg.mlp
+    if ff == "moe":
         from ray_tpu.ops.moe import moe_layer
 
         y, aux, counts, routes = moe_layer(
             p, x, num_experts=cfg.moe_num_experts, top_k=cfg.moe_top_k,
             renormalize=cfg.moe_renormalize, dtype=cfg.dtype, valid=valid,
-            layer=layer)
+            layer=layer, scoring=cfg.moe_scoring,
+            routed_scale=cfg.moe_routed_scale)
         return y, aux, {"counts": counts, "routes": routes}
-    if cfg.mlp == "swiglu":
+    if ff == "swiglu":
         gate = jnp.einsum("bsd,df->bsf", x, p["w_gate"].astype(cfg.dtype))
         up = jnp.einsum("bsd,df->bsf", x, p["w_up"].astype(cfg.dtype))
         return jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
@@ -990,31 +1236,34 @@ def stacked_mlp(cfg, params, layer_params, i):
     scan the whole stack that holds the layer and its index there — the
     grouped matmuls cannot fuse the slice as a dense matmul does, and would
     copy the layer's experts."""
-    if cfg.mlp == "moe" and cfg.scan_layers and cfg.period == 1:
-        return params["blocks"]["mlp"], i
-    if cfg.mlp == "moe" and cfg.scan_layers:
-        # a pattern of kinds: a stack a place in the period (``init_params``)
-        return (params["blocks"][f"p{i % cfg.period}"]["mlp"],
-                i // cfg.period)
-    return layer_params["mlp"], None
+    if cfg.mlp_of(i) != "moe" or not cfg.scan_layers:
+        return layer_params["mlp"], None
+    i -= cfg.lead_layers
+    if cfg.period == 1:
+        return body_params(cfg, params)["mlp"], i
+    # a pattern of kinds: a stack a place in the period (``init_params``)
+    return (body_params(cfg, params)[f"p{i % cfg.period}"]["mlp"],
+            i // cfg.period)
 
 
 def _block(cfg, p, x, rope, positions, sp_axis, kv_cache=None, mlp=None,
-           kind=ATTENTION, taps=None):
+           kind=ATTENTION, taps=None, ff=None):
     """``mlp``: ``stacked_mlp``'s pair where the caller walks stacked
     layers one by one; None for ``(p["mlp"], None)``. ``kind``: the layer's
-    mixer. ``taps``: a list that is given what a mixer chose (debug)."""
+    mixer; ``ff``: its feed-forward (``cfg.mlp_of``; None: ``cfg.mlp``).
+    ``taps``: a list that is given what a mixer chose (debug)."""
     a, new_cache = _mixer(cfg, kind, p["attn"], _norm(cfg, p["ln1"], x),
                           rope, positions, sp_axis, kv_cache, taps)
-    return _after_mixer(cfg, p, x, a, new_cache, mlp)
+    return _after_mixer(cfg, p, x, a, new_cache, mlp, ff)
 
 
-def _after_mixer(cfg, p, x, a, new_cache=None, mlp=None):
+def _after_mixer(cfg, p, x, a, new_cache=None, mlp=None, ff=None):
     """The rest of a block, given what its mixer made of x: ``_block``'s
     results."""
     x = _residual(cfg, x, a)
     mlp_p, layer = mlp or (p["mlp"], None)
-    m, aux, moe = _mlp(cfg, mlp_p, _norm(cfg, p["ln2"], x), layer=layer)
+    m, aux, moe = _mlp(cfg, mlp_p, _norm(cfg, p["ln2"], x), layer=layer,
+                       ff=ff)
     x = _residual(cfg, x, m)
     return x, new_cache, aux, moe
 
@@ -1026,7 +1275,8 @@ _MATRICES = frozenset({"wq", "wk", "wv", "wo", "wg", "wc", "wi_q", "wi_k",
                        "wi_w", "w_gate", "w_up", "w_down", "w_in", "w_out"})
 
 
-def _block_streams(cfg, p, hs, rope, positions, sp_axis, kind=ATTENTION):
+def _block_streams(cfg, p, hs, rope, positions, sp_axis, kind=ATTENTION,
+                   ff=None):
     """``_block`` on each stream of the residual (``streams``), one
     ``_block``'s results a stream. Two streams take every matrix as the
     products do, ``cfg.dtype``, from ONE cast (a stream's own
@@ -1038,7 +1288,8 @@ def _block_streams(cfg, p, hs, rope, positions, sp_axis, kind=ATTENTION):
     by its name, are a whole chip's rows): the products on either side of
     it, whose reduces are the ones to hide, stay a stream's own."""
     if len(hs) == 1:
-        return [_block(cfg, p, hs[0], rope, positions, sp_axis, kind=kind)]
+        return [_block(cfg, p, hs[0], rope, positions, sp_axis, kind=kind,
+                       ff=ff)]
     p = {**p, **{part: {name: w.astype(cfg.dtype) if name in _MATRICES else w
                         for name, w in p[part].items()}
                  for part in ("attn", "mlp")}}
@@ -1055,7 +1306,7 @@ def _block_streams(cfg, p, hs, rope, positions, sp_axis, kind=ATTENTION):
             _qkv(cfg, p["attn"], x, rope, positions, kind) for x in normed)))
         mixed = [_attn_out(cfg, p["attn"], o)
                  for o in _halves(_plain_attention(cfg, q, k, v))]
-    return [_after_mixer(cfg, p, h, a) for h, a in zip(hs, mixed)]
+    return [_after_mixer(cfg, p, h, a, ff=ff) for h, a in zip(hs, mixed)]
 
 
 def _residual_layout(x):
@@ -1151,7 +1402,8 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
     any) — used by the fused-CE loss path and by ``decode.prefill``, which
     projects the last position alone.
     return_routes (debug, mlp='moe' without kv_caches): also return the
-    experts every token chose in every layer, int32 [L, B, S, k] — top-k is
+    experts every token chose in every EXPERT layer (the leading dense ones
+    choose nothing), int32 [L, B, S, k] — top-k is
     discontinuous, so a comparison with another implementation has to be
     made on the same choices.
     return_selected (debug, without kv_caches): also return the blocks every
@@ -1181,7 +1433,8 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
         static = (0, 3, 5) if rope is COMPUTED else (0, 5)
         remat = lambda fn: jax.checkpoint(fn, static_argnums=static,
                                           policy=policy)
-    block_fn = lambda kind: remat(functools.partial(_block, kind=kind))
+    block_fn = lambda i: remat(functools.partial(
+        _block, kind=kinds[i], ff=cfg.mlp_of(i)))
 
     if return_routes and (cfg.mlp != "moe" or kv_caches is not None):
         raise ValueError("return_routes needs mlp='moe' and no kv_caches")
@@ -1194,9 +1447,10 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
     routes = None
     taps = [] if return_selected else None
     if cfg.scan_layers and kv_caches is None and not return_selected:
-        period = cfg.period
-        fns = [remat(functools.partial(_block_streams, kind=kind))
-               for kind in kinds[:period]]
+        period, lead = cfg.period, cfg.lead_layers
+        stream_fn = lambda i: remat(functools.partial(
+            _block_streams, kind=kinds[i], ff=cfg.mlp_of(i)))
+        fns = [stream_fn(lead + j) for j in range(period)]
         # the carry: the residual as one stream, or as the two halves of
         # every chip's rows (``streams``; positions a row would have to be
         # halved with it: none does)
@@ -1204,6 +1458,13 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
                  and (positions is None or positions.ndim == 1))
         carried = (tuple(_residual_layout(h) for h in _halves(x))
                    if split else (x,))
+        # the leading layers, one by one (a handful: a scan of their own
+        # would be a second program for a layer or two); they choose
+        # nothing, so ``routes`` holds the scanned layers' alone
+        for i in range(lead):
+            outs = stream_fn(i)(cfg, layer_params(cfg, params, i), carried,
+                                rope, positions, sp_axis)
+            carried = tuple(_residual_layout(h) for h, _, _, _ in outs)
 
         def body(carry, layer_params):
             hs, aux_acc = carry
@@ -1218,8 +1479,8 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
                 chosen.append(outs[0][3]["routes"] if return_routes else None)
             return (tuple(_residual_layout(h) for h in hs), aux_acc), (
                 jnp.stack(chosen) if return_routes else None)
-        (carried, aux_total), routes = jax.lax.scan(body, (carried, 0.0),
-                                                    params["blocks"])
+        (carried, aux_total), routes = jax.lax.scan(
+            body, (carried, 0.0), body_params(cfg, params))
         x = _residual_layout(_whole(carried)) if split else carried[0]
         if return_routes:  # [steps, layers a step, ...] -> a layer a row
             routes = routes.reshape(-1, *routes.shape[2:])
@@ -1232,14 +1493,15 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
                 x, c, aux, moe = _block(
                     cfg, layer_p, x, rope, positions, sp_axis,
                     kv_caches[i] if kv_caches is not None else None,
-                    stacked_mlp(cfg, params, layer_p, i), kind, taps)
+                    stacked_mlp(cfg, params, layer_p, i), kind, taps,
+                    cfg.mlp_of(i))
             else:
-                x, c, aux, moe = block_fn(kind)(cfg, layer_p, x, rope,
-                                                positions, sp_axis)
+                x, c, aux, moe = block_fn(i)(cfg, layer_p, x, rope,
+                                             positions, sp_axis)
             aux_total = aux_total + aux
             if new_caches is not None:
                 new_caches.append(c)
-            if return_routes:
+            if return_routes and moe is not None:
                 per_layer.append(moe["routes"])
         if return_routes:
             routes = jnp.stack(per_layer)

@@ -1,12 +1,15 @@
 """Mixture-of-Experts layer: dropless top-k routing over grouped matmuls.
 
-One routing for training and serving (OLMoE / Mixtral style, the
-reference has no MoE at all — SURVEY §5): the router scores every row in
-float32, each row takes its top-k experts, the (row, expert) pairs are
-sorted by expert and the three SwiGLU matmuls run as GROUPED matmuls over
-the ragged per-expert groups, then the pairs are un-sorted and summed
-under the router's weights. No capacity, so no row is ever dropped, and
-nothing of size [rows, experts, capacity] exists.
+One routing for training and serving (the reference has no MoE at all —
+SURVEY §5): the router scores every row in float32 by one of two rules
+(``SCORINGS``: a softmax over the experts, OLMoE / Mixtral style, or a
+sigmoid an expert with a bias that corrects the CHOICE and not the weight,
+DeepSeek-V3 style), each row takes its top-k experts, the (row, expert)
+pairs are sorted by expert and the three SwiGLU matmuls run as GROUPED
+matmuls over the ragged per-expert groups, then the pairs are un-sorted and
+summed under the router's weights; a SHARED expert, where the layer has one,
+is a dense SwiGLU every row takes beside its routed sum. No capacity, so no
+row is ever dropped, and nothing of size [rows, experts, capacity] exists.
 
 Which call runs which grouped matmul (two paths below the sort, because
 the needs conflict; the input says which, no option does):
@@ -54,26 +57,57 @@ from jax.experimental.pallas import tpu as pltpu
 from ray_tpu.ops._pallas import should_interpret
 
 
+SOFTMAX, SIGMOID = "softmax", "sigmoid"
+SCORINGS = (SOFTMAX, SIGMOID)
+# what a seeded choice bias is drawn with: sigmoid scores of seeded weights
+# lie tenths apart, so a bias of this spread moves a measurable share of the
+# rows' top-k (a bias of zeros could be dropped and no test would tell)
+_BIAS_STD = 0.1
+
+
 def init_moe_params(key, embed_dim: int, hidden_dim: int, num_experts: int,
-                    param_dtype=jnp.float32) -> Dict[str, Any]:
-    """SwiGLU experts: router [d,E] + per-expert gate/up [E,d,f], down [E,f,d]."""
+                    param_dtype=jnp.float32, *, choice_bias: bool = False,
+                    shared_dim: int = 0) -> Dict[str, Any]:
+    """SwiGLU experts: router [d,E] + per-expert gate/up [E,d,f], down
+    [E,f,d]. ``choice_bias``: ``e_bias`` [E] float32 beside them, what a
+    sigmoid router adds to its scores to CHOOSE (never to weigh).
+    ``shared_dim``: the width of the shared expert (its experts' widths
+    joined; 0: none), ``ws_gate``/``ws_up`` [d,fs] and ``ws_down`` [fs,d]."""
     ks = jax.random.split(key, 4)
+    # what the other two bring is drawn from keys of their own: the four
+    # above are what a model without them has always been made from
+    more = jax.random.split(jax.random.fold_in(key, 4), 4)
     init = jax.nn.initializers.normal(0.02, param_dtype)
-    return {
+    p = {
         "w_router": init(ks[0], (embed_dim, num_experts)),
         "w_gate": init(ks[1], (num_experts, embed_dim, hidden_dim)),
         "w_up": init(ks[2], (num_experts, embed_dim, hidden_dim)),
         "w_down": init(ks[3], (num_experts, hidden_dim, embed_dim)),
     }
+    if choice_bias:
+        p["e_bias"] = _BIAS_STD * jax.random.normal(
+            more[0], (num_experts,), jnp.float32)
+    if shared_dim:
+        p.update(ws_gate=init(more[1], (embed_dim, shared_dim)),
+                 ws_up=init(more[2], (embed_dim, shared_dim)),
+                 ws_down=init(more[3], (shared_dim, embed_dim)))
+    return p
 
 
-def moe_logical_axes() -> Dict[str, Tuple[Optional[str], ...]]:
-    return {
+def moe_logical_axes(choice_bias: bool = False, shared: bool = False
+                     ) -> Dict[str, Tuple[Optional[str], ...]]:
+    axes = {
         "w_router": ("embed", None),
         "w_gate": ("expert", "embed", "mlp"),
         "w_up": ("expert", "embed", "mlp"),
         "w_down": ("expert", "mlp", "embed"),
     }
+    if choice_bias:
+        axes["e_bias"] = (None,)
+    if shared:
+        axes.update(ws_gate=("embed", "mlp"), ws_up=("embed", "mlp"),
+                    ws_down=("mlp", "embed"))
+    return axes
 
 # ------------------------------------------- the serving path's experts
 
@@ -247,13 +281,52 @@ def expert_mlp(xs, w_gate, w_up, w_down, counts, first_group, tiles=None):
     return out[:pairs]
 
 
+def route(logits, top_k: int, renormalize: bool, scoring: str = SOFTMAX,
+          bias=None, routed_scale: float = 1.0):
+    """Router logits [N, E] float32 -> (scores [N, E], the k experts a row
+    takes [N, k] best first, their weights [N, k]), float32.
+
+    'softmax': scores are the softmax over the experts, the choice their k
+    largest, the weights those (divided by their sum with ``renormalize``).
+    'sigmoid': scores are a sigmoid an expert; the choice is the k largest
+    of ``scores + bias`` ([E] float32: the bias corrects who is chosen) and
+    the weights the UNBIASED scores of the chosen, divided by their sum plus
+    1e-20 with ``renormalize``. Either way times ``routed_scale``. Ties go
+    to the lower index (``jax.lax.top_k``)."""
+    if scoring == SOFTMAX:
+        scores = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(scores, top_k)  # [N, k]
+        if renormalize:
+            gate_vals = gate_vals / jnp.maximum(
+                gate_vals.sum(-1, keepdims=True), 1e-9)
+    elif scoring == SIGMOID:
+        scores = jax.nn.sigmoid(logits)
+        chosen_by = scores if bias is None else scores + bias.astype(
+            jnp.float32)
+        gate_idx = jax.lax.top_k(chosen_by, top_k)[1]
+        gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
+        if renormalize:
+            gate_vals = gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-20)
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}; expected one "
+                         f"of {SCORINGS}")
+    if routed_scale != 1.0:
+        gate_vals = gate_vals * routed_scale
+    return scores, gate_idx, gate_vals
+
+
 def moe_layer(p: Dict[str, Any], x, *, num_experts: int, top_k: int = 2,
               renormalize: bool = True, dtype=jnp.bfloat16, valid=None,
-              layer: Optional[int] = None):
+              layer: Optional[int] = None, scoring: str = SOFTMAX,
+              routed_scale: float = 1.0):
     """x: [B, S, d] -> (y [B, S, d], aux_loss, counts [E], routes [B, S, k]).
 
-    ``renormalize``: divide the k router probabilities by their sum
-    (Mixtral, GShard); OLMoE weights by the raw probabilities.
+    ``scoring``, ``renormalize``, ``routed_scale``: the router's rule
+    (``route``): softmax probabilities taken as they are (OLMoE) or divided
+    by their sum (Mixtral, GShard), or sigmoid scores chosen by ``score +
+    p["e_bias"]`` and weighed without it (DeepSeek-V3's ``noaux_tc`` with one
+    group). Where ``p`` holds a shared expert (``ws_gate``, ``ws_up``,
+    ``ws_down``) every live row takes it beside its routed sum, unweighted.
     ``valid``: optional bool [B, S]; a row marked False is routed nowhere.
     ``counts``: int32 rows each expert received (valid rows only; they sum
     to valid rows x k: the dropless witness). ``routes``: the experts each
@@ -266,20 +339,21 @@ def moe_layer(p: Dict[str, Any], x, *, num_experts: int, top_k: int = 2,
     the three products are ``jax.lax.ragged_dot`` (module docstring).
 
     aux_loss is the Switch load-balancing loss over the valid rows
-    (E * sum_e fraction_of_rows_whose_first_choice_is_e * mean_prob_e); add
+    (E * sum_e fraction_of_rows_whose_first_choice_is_e * mean_prob_e, a
+    sigmoid router's scores divided by their sum over the experts); add
     it to the task loss scaled by ~1e-2.
     """
     b, s, d = x.shape
     n = b * s
     xt = x.reshape(n, d).astype(dtype)
-    w_router = p["w_router"] if layer is None else p["w_router"][layer]
-    logits = jnp.einsum("nd,de->ne", xt, w_router.astype(dtype),
+    own = (lambda a: a) if layer is None else (lambda a: a[layer])
+    logits = jnp.einsum("nd,de->ne", xt, own(p["w_router"]).astype(dtype),
                         preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)  # float32 [N, E]
-    gate_vals, gate_idx = jax.lax.top_k(probs, top_k)  # [N, k]
-    if renormalize:
-        gate_vals = gate_vals / jnp.maximum(
-            gate_vals.sum(-1, keepdims=True), 1e-9)
+    probs, gate_idx, gate_vals = route(
+        logits, top_k, renormalize, scoring,
+        own(p["e_bias"]) if "e_bias" in p else None, routed_scale)
+    if scoring != SOFTMAX:  # the auxiliary loss wants a distribution
+        probs = probs / probs.sum(-1, keepdims=True)
     live = (jnp.ones((n,), bool) if valid is None
             else valid.reshape(n).astype(bool))
 
@@ -307,6 +381,14 @@ def moe_layer(p: Dict[str, Any], x, *, num_experts: int, top_k: int = 2,
     y = jnp.einsum("nkd,nk->nd",
                    jnp.where(live[:, None, None], out, 0).astype(jnp.float32),
                    gate_vals).astype(dtype)
+    if "ws_gate" in p:
+        # the shared expert: a dense SwiGLU of every row (a layer's slice of
+        # a dense matrix fuses into the product; a row that is not live
+        # comes out as zeros here too)
+        ws_gate, ws_up, ws_down = (own(p[k]).astype(dtype)
+                                   for k in ("ws_gate", "ws_up", "ws_down"))
+        shared = (jax.nn.silu(xt @ ws_gate) * (xt @ ws_up)) @ ws_down
+        y = y + jnp.where(live[:, None], shared, 0).astype(dtype)
 
     # Switch aux loss: encourage uniform routing
     rows = jnp.maximum(live.sum(), 1).astype(jnp.float32)

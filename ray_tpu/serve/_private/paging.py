@@ -383,6 +383,16 @@ _REFUSALS = {"state": {
     "export": (
         "a model with 'indexed_attention' layers exports no prefix: an "
         "exported page carries its keys and values and not its index keys"),
+}, "latent": {
+    "drafter": (
+        "speculative decoding cannot serve a model with 'latent_attention' "
+        "layers: the drafter's slot arena holds keys and values alone, and "
+        "a verify window's rows have not been held against the reference "
+        "for the kind"),
+    "export": (
+        "a model with 'latent_attention' layers exports no prefix: its "
+        "pages hold latents and rotated keys, and what is exported is keys "
+        "and values"),
 }}
 
 
@@ -396,13 +406,19 @@ def cannot_continue(cfg, pools: Tuple[PagePool, ...]
     'indexed_attention' layer's index keys lie in its pages, under the same
     table: a spliced radix prefix brings them along, so the prefix cache
     serves it (tests/test_keye.py); what leaves the pool as keys and values
-    alone does not. None where nothing is forbidden."""
+    alone does not. A 'latent_attention' layer's pages hold a latent and a
+    rotated key a token and nothing else: the prefix cache splices them as
+    it splices keys and values (tests/test_glm_moe_lite.py), and the two
+    that count on keys and values are refused. Plain 'attention' and
+    'minicpm4' forbid nothing: None."""
     if cfg.recurrent:
         return _REFUSALS["state"]
     if any(pool.window is not None for pool in pools):
         return _REFUSALS["window"]
     if "indexed_attention" in cfg.kinds:
         return _REFUSALS["index"]
+    if "latent_attention" in cfg.kinds:
+        return _REFUSALS["latent"]
     return None
 
 

@@ -12,7 +12,9 @@ A kind's counters are said once, in ``_KINDS``: the function that counts one
 call of the kind's layers (a layer-call: one layer in one program run) and
 the keys it owns, which a model shows if and only if it has layers of the
 kind ('lightning-attn', 'power-retention', 'minicpm4', 'indexed_attention',
-'sliding_attention'; plain 'attention' is counted by ``attn_*`` alone); ``attn_*`` always (they count pages: a true 0 for a model without).
+'sliding_attention', 'latent_attention'; plain 'attention' is counted by
+``attn_*`` alone); ``attn_*`` always (they count pages: a true 0 for a model
+without). What a token leaves in a page, by kind, is ``token_bytes``.
 """
 
 from __future__ import annotations
@@ -23,10 +25,12 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ray_tpu._private.metrics import Counter
-from ray_tpu.models.transformer import (INDEXED, LINEAR, RETENTION, SLIDING,
-                                        SPARSE, STATE_KINDS, state_shapes)
+from ray_tpu.models.transformer import (ATTENTION, INDEXED, LATENT, LINEAR,
+                                        RETENTION, SLIDING, SPARSE,
+                                        STATE_KINDS, state_shapes)
 from ray_tpu.ops.indexed_attention import (SELECT_ROWS, chunk_tokens,
                                            context_tokens, select_lanes)
+from ray_tpu.ops.latent_attention import pool_width
 from ray_tpu.ops.paged_attention import streamed_tokens, tile_sizes
 
 _m_attn_bytes = Counter(
@@ -135,6 +139,41 @@ def _indexed(work, n, layers, qk, cursors, real):
             int((-(-(t[0, 0] + ends) // 512)).sum()) * 512)
 
 
+def _latent(work, n, layers, qk, cursors, real):
+    """Latent layers: what a step's rows and a chunk's queries attend, by
+    position alone, summed over rows and layers — the latents of their
+    contexts (``latent_tokens_context``), of which a step's share
+    (``latent_step_tokens_context``) and a chunk's (query, key) pairs
+    (``latent_chunk_pairs``: every head of a pair costs ``2 (rank + rope) + 2
+    rank`` operations), and the bytes the least reading moves
+    (``latent_bytes_moved``: a step's row its whole context, a chunk its
+    context once, and each its own tokens written, ``rank + rope`` values a
+    token: the pool's padding is not work). What the kernel streams
+    in whole blocks is counted by ``attn_*`` as for every paged kind."""
+    t = np.asarray(cursors)[:, None] + np.arange(real)  # [rows, real]
+    context = int((t + 1).sum())
+    n["latent_tokens_context"] += layers * context
+    if qk == 1:
+        n["latent_step_tokens_context"] += layers * context
+        read = context
+    else:
+        n["latent_chunk_pairs"] += layers * context
+        read = int(t[:, -1].sum()) + len(cursors)
+    n["latent_bytes_moved"] += layers * (read + t.size) * work.itemsize * (
+        work.cfg.latent_kv_rank + work.cfg.latent_rope_dim)
+
+
+def token_bytes(cfg, kind: str, itemsize: int) -> int:
+    """Bytes the attended rows of one token take in a page of a layer of
+    ``kind``: K and V of all K/V heads, or for 'latent_attention' the one
+    row of a latent and a rotated key (``ops.latent_attention.join``, its
+    padding to whole lane tiles held and moved too) that is both."""
+    if kind == LATENT:
+        return pool_width(cfg.latent_kv_rank,
+                          cfg.latent_rope_dim) * itemsize
+    return 2 * cfg.kv_heads * cfg.head_dim * itemsize
+
+
 # kind -> (what counts one call of its layers, the keys it owns); the last
 # two of the window kind's are ``sample``'s
 _KINDS = {
@@ -151,6 +190,8 @@ _KINDS = {
                          "indexed_step_tokens_context",
                          "indexed_select_lanes",
                          "indexed_select_lanes_table")),
+    LATENT: (_latent, ("latent_tokens_context", "latent_step_tokens_context",
+                       "latent_chunk_pairs", "latent_bytes_moved")),
     SLIDING: (_window, ("window_attn_step_keys", "full_attn_step_keys",
                         "window_attn_chunk_pairs", "full_attn_chunk_pairs",
                         "window_tokens_held", "window_tokens_unreleased")),
@@ -158,6 +199,9 @@ _KINDS = {
 _ATTN = ("attn_bytes_moved", "attn_tokens_attended", "attn_tokens_fetched")
 _EXPERTS = ("moe_live_rows", "moe_layer_calls", "moe_rows_routed",
             "moe_experts_hit", "moe_max_expert_rows")
+# beside them where the expert layers have a shared expert: the rows it took
+# (every live row of every expert layer-call, times the shared experts)
+_SHARED = "moe_shared_rows"
 
 
 class Work:
@@ -167,15 +211,20 @@ class Work:
 
     def __init__(self, cfg, *, slots: int, page_tokens: int,
                  pages_per_slot: int, lane: str, itemsize: int):
-        self.cfg, self.lane = cfg, lane
+        self.cfg, self.lane, self.itemsize = cfg, lane, itemsize
         self._page_tokens, self._pages_per_slot = page_tokens, pages_per_slot
         kinds = cfg.kinds
         self._kinds = [(_KINDS[kind][0], kinds.count(kind))
                        for kind in _KINDS if kind in kinds]
         self.paged_layers = sum(kind not in STATE_KINDS for kind in kinds)
         self._window_layers = kinds.count(SLIDING)
-        # all kv heads of one token's K (or V) row
-        self._row_bytes = cfg.kv_heads * cfg.head_dim * itemsize
+        # all kv heads of one token's K (or V) row, and the query rows
+        # that share it; a latent layer's one row a token, in two halves for
+        # the same arithmetic, shared by every head
+        latent = LATENT in kinds
+        self._row_bytes = token_bytes(cfg, LATENT if latent else ATTENTION,
+                                      itemsize) // 2
+        self._group = cfg.num_heads // (1 if latent else cfg.kv_heads)
         if SPARSE in kinds:
             # tokens of one block of the step's kernel over a row's table
             # of chosen pages (``sparse_attention._step_attention``)
@@ -199,7 +248,8 @@ class Work:
         self._n = dict.fromkeys(
             _ATTN + tuple(key for kind in _KINDS if kind in kinds
                           for key in _KINDS[kind][1])
-            + (_EXPERTS if self.counts_experts else ()), 0)
+            + (_EXPERTS if self.counts_experts else ())
+            + ((_SHARED,) if cfg.moe_shared_experts else ()), 0)
         if state_bytes:
             self._n.update(state_slots=slots, state_bytes=state_bytes)
 
@@ -224,8 +274,8 @@ class Work:
         row = self._row_bytes
         dense = lambda window: streamed_tokens(
             self.lane, qk, cursors, idle_rows,
-            self.cfg.num_heads // self.cfg.kv_heads, self._page_tokens,
-            self._pages_per_slot, row, window)
+            self._group, self._page_tokens, self._pages_per_slot, row,
+            window)
         attended, fetched = streamed or dense(None)
         n["attn_tokens_attended"] += attended
         n["attn_tokens_fetched"] += fetched
@@ -257,6 +307,9 @@ class Work:
         n["moe_rows_routed"] += int(c.sum())
         n["moe_experts_hit"] += int((c > 0).sum())
         n["moe_max_expert_rows"] += int(c.max(axis=1).sum())
+        if _SHARED in n:
+            n[_SHARED] += (self.cfg.moe_shared_experts * int(c.sum())
+                           // self.cfg.moe_top_k)
 
     def sample(self, pools) -> None:
         """A turn's sample, behind its releases and allocations: the tokens

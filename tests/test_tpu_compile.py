@@ -1018,3 +1018,95 @@ def test_keye_check_programs_fit_beside_the_pool(v5e):
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 2.9e9, f"forward: {temp / 1e9:.2f} GB of temporaries"
     assert held + temp < 14.8e9
+
+
+def test_glm_serve_programs_compile_and_fit(v5e):
+    """The benchmark's GLM-4.7-Flash configuration (published widths: hidden
+    2048, 20 heads of 192 + 64 q/k and 256 v values over a latent of 512 and
+    one shared rotated key of 64, a dense layer of 10240 then 64 experts of
+    1536 top-4 beside a shared one; 1 + 5 layers, bf16) under its cell's
+    deployment (8 slots of 66048 tokens, 32769 pages): the prefill chunk with
+    the step's rows along and the decode step, the latent kernel once a layer
+    and group of rows under the step's name or the chunk's, the experts'
+    kernel once an EXPERT layer (the dense layer has none); 7.79 GB of
+    weights and the 4.03 GB pool (rows of 640 lanes: 512 + 64 + padding)
+    beside the programs' own memory on one 16 GB chip."""
+    from ray_tpu.ops.paged_attention import resolve_impl
+
+    cfg, held, programs = _cell_programs(v5e, "glm47_flash_l6",
+                                         "glm47_flash_longdocs")
+    assert (cfg.embed_dim, cfg.head_dim, cfg.hidden_dim) == (2048, 256, 1536)
+    assert (cfg.lead_layers, cfg.expert_layers, cfg.period) == (1, 5, 1)
+    assert cfg.mlp_width("swiglu") == 10240
+    assert cfg.moe_scoring == "sigmoid" and cfg.moe_routed_scale == 1.8
+    lane = resolve_impl(cfg)
+    assert lane == "pallas"
+    assert 11.7e9 < held < 11.9e9
+    calls = {"prefill": {"latent_chunk_attention": 6,
+                         "latent_step_attention": 6,
+                         "moe_grouped_matmul": 5},
+             "decode": {"latent_step_attention": 6,
+                        "moe_grouped_matmul": 5}}
+    for name, (program, args) in programs.items():
+        compiled = jax.jit(
+            functools.partial(program, cfg, attn=lane, moe_info=True),
+            donate_argnums=(6,)).lower(*args).compile()
+        assert _kernel_calls(compiled) == calls[name], name
+        total = _fits(compiled)
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert total < 12.6e9, f"{name}: {total / 1e9:.1f} GB"
+        assert temp < 0.6e9, f"{name}: {temp / 1e6:.0f} MB of temporaries"
+
+
+@pytest.mark.parametrize("S,K", [(8, 1), (1, 512), (1, 4113)],
+                         ids=["step_8slots", "chunk_k512", "check_prefill"])
+def test_latent_attention_compiles_at_the_cells_shapes(v5e, S, K):
+    """``ops.latent_attention`` alone at GLM-4.7-Flash's sizes: 20 heads over
+    rows of 640 lanes (a latent of 512, a shared key of 64, padding), pages
+    of 16 through a table of 4128 — the 8 slots' step, a 512 chunk, and the
+    check prompt's cached prefill in one call."""
+    from ray_tpu.ops.latent_attention import latent_attention, pool_width
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    name = "latent_step_attention" if K == 1 else "latent_chunk_attention"
+    compiled = jax.jit(functools.partial(
+        latent_attention, sm_scale=1 / 16, impl="pallas", name=name)).lower(
+        _on(chip, (S, K, 20, 512)), _on(chip, (S, K, 20, 64)),
+        _on(chip, (32769, 16, pool_width(512, 64))),
+        _on(chip, (S, 4128), jnp.int32), _on(chip, (S,), jnp.int32)).compile()
+    assert _kernel_calls(compiled) == {name: 1}
+
+
+def test_glm_check_programs_fit_beside_the_pool(v5e):
+    """The largest program ``reference_check`` runs in the replica beside
+    the weights and the pool, on the cell's 4113-token check prompt and the
+    32 tokens served behind it: the cell states limits GIVEN the routes, so
+    the uncached whole-sequence ``forward`` (unabsorbed, through the flash
+    kernel at heads of 256) up to whole tiles; its [4224, 154880] bf16 logits
+    are 1.31 GB."""
+    from perfbench.lib import manifest as manifest_lib
+    from perfbench.lib.serve_app import GIVEN_PAD
+    from ray_tpu.models.transformer import forward
+
+    cfg, held, programs = _cell_programs(v5e, "glm47_flash_l6",
+                                         "glm47_flash_longdocs")
+    cell = manifest_lib.read_json(manifest_lib.load(), "cells",
+                                  "glm47_flash_longdocs")
+    assert {"given_logit_err", "given_logit_rms_err"} <= set(
+        cell["check_tolerance"])
+    prompt, new = cell["check_prompt_tokens"], cell["check_new_tokens"]
+    first, n = prompt - 1, prompt - 1 + new
+    params = programs["decode"][1][0]
+    chip = params["embed"]["table"].sharding
+
+    def run(params, tokens):
+        logits, routes = forward(cfg, params, tokens, return_routes=True)
+        return logits[0, first:n].astype(jnp.float32), routes
+
+    compiled = jax.jit(run).lower(
+        params, _on(chip, (1, n + -n % GIVEN_PAD), jnp.int32)).compile()
+    assert "flash_attention_fwd" in " ".join(_kernel_names(
+        compiled.as_text()))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2.2e9, f"forward: {temp / 1e9:.2f} GB of temporaries"
+    assert held + temp < 14.2e9
