@@ -1481,7 +1481,11 @@ def latent_timing(cfg, params, seed: int) -> dict:
     context token, 17.9 KB of temporaries a token — then the paged kernel
     over them at heads of 256, ``2 x 512`` a pair), each in milliseconds a
     call, four calls timed behind a warm-up. And the step's kernel over 8
-    rows at those contexts."""
+    rows at those contexts. The absorbed path twice (ISSUE 56): the slots'
+    pages IN ORDER in the pool, as nothing but this script lays them, and
+    PERMUTED over the pool's 33,024 pages (seeded), as an arena that has
+    served a while hands them out — ``*_permuted_ms``: what a block's 32
+    page copies cost when they land 20 KB apart and not in one run."""
     import time
 
     import jax
@@ -1502,6 +1506,8 @@ def latent_timing(cfg, params, seed: int) -> dict:
     pool = jax.random.normal(keys[0], (8 * P + 1, T, pool_width(rank, rope)),
                              jnp.bfloat16)
     tables = 1 + jnp.arange(8 * P, dtype=jnp.int32).reshape(8, P)
+    permuted = jnp.asarray(1 + np.random.default_rng(seed).permutation(
+        8 * P).reshape(8, P), jnp.int32)
 
     def rows(k0, n, K):
         return (jax.random.normal(keys[k0], (n, K, H, nope), jnp.bfloat16),
@@ -1545,12 +1551,15 @@ def latent_timing(cfg, params, seed: int) -> dict:
                 for fn in (absorbed, expanded))
         off = float(jnp.abs(a.astype(jnp.float32) - e.astype(jnp.float32)
                             ).max() / jnp.abs(e.astype(jnp.float32)).max())
+        steps = rows(1, 8, 1), jnp.full((8,), context, jnp.int32)
         out[str(context)] = {
             "chunk_absorbed_ms": ms(absorbed, pool, q, cursor, tables[:1]),
+            "chunk_absorbed_permuted_ms": ms(absorbed, pool, q, cursor,
+                                             permuted[:1]),
             "chunk_expanded_ms": ms(expanded, pool, q, cursor, tables[:1]),
             "absorbed_off_expanded": off,
-            "step_8_rows_ms": ms(absorbed, pool, rows(1, 8, 1), jnp.full(
-                (8,), context, jnp.int32), tables)}
+            "step_8_rows_ms": ms(absorbed, pool, *steps, tables),
+            "step_8_rows_permuted_ms": ms(absorbed, pool, *steps, permuted)}
     return out
 
 

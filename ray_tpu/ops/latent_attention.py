@@ -24,15 +24,27 @@ and a V pool).
 
   * ``latent_attention(..., impl='pallas')`` — one Pallas TPU kernel for the
     decode step (K = 1: a slot's H query rows) and the prefill chunk (K > 1:
-    tiles of query tokens x H rows), one grid cell a (slot, query tile). The
-    walk is ``ops.paged_attention``'s: page tables and cursors in SMEM, the
-    pool in HBM, a block of pages copied page by page into one of two VMEM
-    buffers while the block before it is multiplied (the query is padded
-    with zeros to the row's width, so the scores are one product), only the
-    blocks up to the tile's last position, the slots that attend something
-    first in the grid and the next cell's first block started behind a
-    cell's last. Tile sizes are that module's ``tile_sizes`` over the row. It runs under the ``name`` the caller gives (a trace tells the
-    step's kernel from the chunk's).
+    query tokens x H rows), one grid cell a (slot, cell of query tokens).
+    The walk is ``ops.paged_attention``'s: page tables and cursors in SMEM,
+    the pool in HBM, a block of pages copied page by page into one of two
+    VMEM buffers while the block before it is multiplied (the query is
+    padded with zeros to the row's width, so the scores are one product),
+    only the blocks up to the cell's last position, the slots that attend
+    something first in the grid and the next cell's first block started
+    behind a cell's last. A slot's context is walked ONCE A CELL, and a
+    cell holds as many of the call's query tokens as VMEM does
+    (``latent_tiles``: all 512 of a chunk at GLM's sizes, where the paged
+    kernel's rule made 8 tiles of 64 and each walked the context again): a
+    block is copied in once and meets every row of the cell, ``_SUB_ROWS``
+    at a time and ``_IN_FLIGHT`` such groups side by side, so that one
+    group's softmax lies under another's products (the matrix unit waits
+    for a group's softmax between its two products: alone that chain, not
+    the copies or the mask, was the tile's time — PERF.md 6, PR 56). A
+    block that ends at or before the cell's first query position is seen by
+    every row and takes no mask; only the blocks that reach into the cell's
+    own tokens (one or two of a chunk's hundred) build one. It runs under
+    the ``name`` the caller gives (a trace tells the step's kernel from the
+    chunk's).
   * ``latent_attention(..., impl='reference')`` — plain ``jax.numpy``: the
     slots' pages gathered through the tables, dense masked scores, a
     float32 softmax. What serves off a TPU, and what the kernel is held
@@ -57,6 +69,8 @@ the v5e's 240: the step is bound by the memory, and its 20 query rows fill
 from __future__ import annotations
 
 import functools
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -85,13 +99,45 @@ def join(c, kr):
         [c, kr, jnp.zeros(c.shape[:-1] + (pad,), c.dtype)], axis=-1)
 
 
+# What a cell's blocks and scratch may take of VMEM (bytes, as ``latent_tiles``
+# counts them). v5e has 128 MiB of VMEM a core, of which a kernel gets 16 MiB
+# unless it asks; Mosaic is given the cell's own count and half again.
+_CELL_VMEM = 96 << 20
+_VMEM_LIMIT = 120 << 20
+# groups of ``_SUB_ROWS`` rows a turn of a cell's inner loop takes through a
+# block side by side (see ``_latent_kernel``): 2 reads 7.72 ms where 1 reads
+# 8.84 and 4 reads 7.63 (a 512 chunk at 55k alone; my chip run, PR 56)
+_IN_FLIGHT = 2
+
+
 def latent_tiles(qk: int, heads: int, page_tokens: int, pages_per_slot: int,
                  width: int, itemsize: int):
-    """(pages a block, query tokens a tile) of a K = ``qk`` call: the paged
-    kernel's rule (``ops.paged_attention.tile_sizes``) for one row of
-    ``width`` lanes a token that ``heads`` query rows share."""
-    return tile_sizes(qk, heads, page_tokens, pages_per_slot,
-                      width * itemsize // 2)
+    """(pages a block, query tokens a cell) of a K = ``qk`` call. The block
+    is the paged kernel's (``ops.paged_attention.tile_sizes`` for one row of
+    ``width`` lanes a token). The cell is this kernel's own: ONE row a
+    token serves all ``heads`` query rows, so a cell of the paged kernel's
+    ``_MAX_Q_ROWS`` would hold a ``heads``-th of its tokens and walk the
+    context that much more often. A cell holds all of the call's tokens if
+    ``_CELL_VMEM`` does, else the most whole matmuls of ``_SUB_ROWS`` rows
+    that fit, the call's tokens spread evenly over its cells. From static
+    shapes alone. Counted a query row: the query's and the output's
+    pipelined blocks (two of each; the output at the row's whole width, an
+    upper bound), the float32 accumulator likewise, m and l a lane tile
+    each; beside the two block buffers and the score tiles of the matmuls
+    in flight."""
+    pages, _ = tile_sizes(qk, heads, page_tokens, pages_per_slot,
+                          width * itemsize // 2)
+    if qk * heads <= _SUB_ROWS:
+        return pages, qk
+    block = pages * page_tokens
+    row = 4 * width * itemsize + 4 * width + 2 * 4 * _LANES
+    fixed = (2 * block * width * itemsize
+             + 4 * _IN_FLIGHT * _SUB_ROWS * block * 4)
+    step = _SUB_ROWS // math.gcd(heads, _SUB_ROWS)  # tokens of whole matmuls
+    wanted = -(-qk // step)
+    most = max((_CELL_VMEM - fixed) // (row * step * heads), 1)
+    cells = -(-wanted // most)
+    return pages, -(-wanted // cells) * step
 
 
 def latent_attention(q_c, q_r, pool, tables, lengths, *, sm_scale: float,
@@ -153,18 +199,23 @@ def _latent_kernel(lengths_ref, tables_ref,     # scalar prefetch (SMEM)
                    m_scr, l_scr, acc_scr,       # [R, 1|1|rank] f32 VMEM
                    *, page_tokens, pages, qk, q_tile, heads, rank,
                    sm_scale):
-    """One (slot, query tile) cell: R = q_tile * heads query rows over the
-    slot's blocks 0 .. the tile's last position. The grid's order, the two
-    buffers handed from cell to cell and the walk are
+    """One (slot, cell) of the grid: R = q_tile * heads query rows over the
+    slot's blocks 0 .. the cell's last position, each block copied in once
+    and met by the cell's rows ``_SUB_ROWS`` at a time. The grid's order,
+    the two buffers handed from cell to cell and the walk are
     ``ops.paged_attention._paged_kernel``'s, for ONE row a token: the
     block's rows are the scores' second operand and, in their first ``rank``
-    lanes, the values."""
+    lanes, the values. The blocks that end at or before the cell's first
+    position come first and take no mask; a row's position is made only in
+    the blocks behind them. ``sm_scale`` is 1 where the caller folded it
+    into the query."""
     c, t = pl.program_id(0), pl.program_id(1)
     n_tiles, n_live = pl.num_programs(1), n_live_ref[0]
     s = order_ref[c]
     T, B, R = page_tokens, pages, q_tile * heads
     BT = B * T
     RS = R if R <= _SUB_ROWS else _SUB_ROWS
+    G = _IN_FLIGHT if R // RS % _IN_FLIGHT == 0 else 1
     P = tables_ref.shape[1]
 
     def block_copies(s, b, buf, wait=False):
@@ -183,6 +234,14 @@ def _latent_kernel(lengths_ref, tables_ref,     # scalar prefetch (SMEM)
                 sems.at[buf])
             cp.wait() if wait else cp.start()
 
+    def turns(n, rows, fn):
+        """``fn(r0)`` at the cell's first ``n`` multiples of ``rows``."""
+        if R == RS:   # the step's one group: no loop, as it was
+            fn(0)
+        else:
+            lax.fori_loop(
+                0, n, lambda i, _: fn(pl.multiple_of(i * rows, rows)), None)
+
     @pl.when(jnp.logical_and(c == 0, t == 0))
     def _():
         first_buf[0] = 0
@@ -192,15 +251,25 @@ def _latent_kernel(lengths_ref, tables_ref,     # scalar prefetch (SMEM)
             block_copies(s, 0, 0)
 
     base = first_buf[0]
-    upto = lengths_ref[s] + jnp.minimum((t + 1) * q_tile, qk)
-    nb = jnp.clip(lax.div(upto + BT - 1, jnp.int32(BT)), 1, -(-P // B))
+    first = lengths_ref[s] + t * q_tile          # the cell's first position
+    real = jnp.minimum(q_tile, qk - t * q_tile)  # its tokens of the call's
+    nb = jnp.clip(lax.div(first + real + BT - 1, jnp.int32(BT)), 1,
+                  -(-P // B))
     nb = jnp.where(c < n_live, nb, 0)
+    # blocks whose last position, (b + 1) * BT - 1, every row may see
+    n_free = jnp.clip(lax.div(first + 1, jnp.int32(BT)), 0, nb)
+    # turns of the inner loop, G groups each, that hold one of its rows
+    n_turns = lax.div(real * heads + G * RS - 1, jnp.int32(G * RS))
     c_next = jnp.where(t + 1 == n_tiles, c + 1, c)
     s_next = order_ref[jnp.minimum(c_next, pl.num_programs(0) - 1)]
 
-    m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
-    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
-    acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+    def clear(r0):
+        rows = pl.ds(r0, RS)
+        m_scr[rows] = jnp.full((RS, 1), NEG_INF, jnp.float32)
+        l_scr[rows] = jnp.zeros((RS, 1), jnp.float32)
+        acc_scr[rows] = jnp.zeros((RS, rank), jnp.float32)
+
+    turns(R // RS, RS, clear)
 
     def body(b, _):
         buf = lax.rem(base + b, 2)
@@ -212,41 +281,62 @@ def _latent_kernel(lengths_ref, tables_ref,     # scalar prefetch (SMEM)
                          jnp.where(more, b + 1, 0), 1 - buf)
 
         block_copies(s, b, buf, wait=True)
-        kpos = b * BT + lax.broadcasted_iota(jnp.int32, (1, BT), 1)
 
-        def update(r0):
-            """Rows [r0, r0 + RS) against this block."""
-            rows = pl.ds(r0, RS)
-            s_ = lax.dot_general(
-                q_ref[0, 0, rows], buf_ref[buf], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            latents = buf_ref[buf, :, :rank]
-            # row r = i * heads + h is query token i of the tile
-            row_pos = lengths_ref[s] + t * q_tile + (
-                r0 + lax.broadcasted_iota(jnp.int32, (RS, 1), 0)) // heads
-            s_ = jnp.where(kpos <= row_pos, s_ * sm_scale, NEG_INF)
-            m = m_scr[rows]
-            m_new = jnp.maximum(m, jnp.max(s_, axis=-1, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            pr = jnp.exp(s_ - m_new)
-            l_scr[rows] = (l_scr[rows] * alpha
-                           + jnp.sum(pr, axis=-1, keepdims=True))
-            acc_scr[rows] = acc_scr[rows] * alpha + lax.dot_general(
+        def update(r0, masked):
+            """Rows [r0, r0 + G * RS) against this block, G groups of RS
+            side by side: a group is a chain (scores, softmax, product with
+            the latents) whose matrix unit waits for its softmax, and the
+            groups' chains stand in ONE basic block, phase by phase, so one
+            group's softmax lies under another's products."""
+            rows = [pl.ds(r0 + g * RS, RS) for g in range(G)]
+            keys, latents = buf_ref[buf], buf_ref[buf, :, :rank]
+            ss = [lax.dot_general(
+                q_ref[0, 0, r], keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) for r in rows]
+            if sm_scale != 1.0:
+                ss = [s_ * sm_scale for s_ in ss]
+            if masked:
+                kpos = b * BT + lax.broadcasted_iota(jnp.int32, (1, BT), 1)
+                # row r = i * heads + h is query token i of the cell
+                row = lax.broadcasted_iota(jnp.int32, (RS, 1), 0)
+                ss = [jnp.where(
+                    kpos <= first + (r0 + g * RS + row) // heads, s_, NEG_INF)
+                    for g, s_ in enumerate(ss)]
+            ms = [m_scr[r] for r in rows]
+            m_news = [jnp.maximum(m, jnp.max(s_, axis=-1, keepdims=True))
+                      for m, s_ in zip(ms, ss)]
+            alphas = [jnp.exp(m - m_new) for m, m_new in zip(ms, m_news)]
+            prs = [jnp.exp(s_ - m_new) for s_, m_new in zip(ss, m_news)]
+            ls = [l_scr[r] * alpha + jnp.sum(pr, axis=-1, keepdims=True)
+                  for r, alpha, pr in zip(rows, alphas, prs)]
+            accs = [acc_scr[r] * alpha + lax.dot_general(
                 pr.astype(latents.dtype), latents, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            m_scr[rows] = m_new
+                for r, alpha, pr in zip(rows, alphas, prs)]
+            for r, m_new, l, acc in zip(rows, m_news, ls, accs):
+                m_scr[r], l_scr[r], acc_scr[r] = m_new, l, acc
 
-        if R == RS:
-            update(0)
+        if R == RS:   # the step: a mask of ``heads`` rows is not worth a path
+            update(0, True)
         else:
-            lax.fori_loop(0, R // RS, lambda i, _: update(
-                pl.multiple_of(i * RS, RS)), None)
+            @pl.when(b < n_free)
+            def _():
+                turns(n_turns, G * RS, functools.partial(update, masked=False))
+
+            @pl.when(b >= n_free)
+            def _():
+                turns(n_turns, G * RS, functools.partial(update, masked=True))
 
     lax.fori_loop(0, nb, body, None)
     first_buf[0] = lax.rem(base + nb, 2)
-    l = l_scr[...]
-    l = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+
+    def finish(r0):
+        rows = pl.ds(r0, RS)
+        l = l_scr[rows]
+        l = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0, 0, rows] = (acc_scr[rows] / l).astype(o_ref.dtype)
+
+    turns(R // RS, RS, finish)
 
 
 # jitted on its own: a program calls the op once a layer with the same
@@ -259,8 +349,11 @@ def _latent_pallas(q_c, q_r, pool, tables, lengths, sm_scale, interpret,
     B, q_tile = latent_tiles(K, H, T, P, W, pool.dtype.itemsize)
     n_tiles = -(-K // q_tile)
     R = q_tile * H
-    # tile-major rows [S, tiles, R, width]: row i * H + h, a pool row's lanes
-    q = jnp.pad(join(q_c, q_r).astype(pool.dtype),
+    q = join(q_c, q_r)
+    if K > 1:   # once a call, not once a score: the step's rows are as few
+        q, sm_scale = q * sm_scale, 1.0
+    # cell-major rows [S, cells, R, width]: row i * H + h, a pool row's lanes
+    q = jnp.pad(q.astype(pool.dtype),
                 ((0, 0), (0, n_tiles * q_tile - K), (0, 0), (0, 0)))
     q = q.reshape(S, n_tiles, R, W)
     kernel = functools.partial(_latent_kernel, page_tokens=T, pages=B, qk=K,
@@ -274,7 +367,8 @@ def _latent_pallas(q_c, q_r, pool, tables, lengths, sm_scale, interpret,
     vmem = (sum(_vmem_bytes(a.shape, a.dtype) for a in bufs + stats)
             + 2 * (_vmem_bytes(blocks[0], pool.dtype)
                    + _vmem_bytes(blocks[1], q_c.dtype))
-            + 4 * _vmem_bytes((min(R, _SUB_ROWS), B * T), jnp.float32))
+            + 4 * _IN_FLIGHT * _vmem_bytes((min(R, _SUB_ROWS), B * T),
+                                           jnp.float32))
 
     def cell(c, t, lengths_ref, tables_ref, order_ref, n_live_ref):
         return order_ref[c], t, 0, 0
@@ -296,7 +390,7 @@ def _latent_pallas(q_c, q_r, pool, tables, lengths, sm_scale, interpret,
         out_shape=jax.ShapeDtypeStruct((S, n_tiles, R, rank), q_c.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=vmem + vmem // 2),
+            vmem_limit_bytes=min(vmem + vmem // 2, _VMEM_LIMIT)),
         name=name,
         interpret=interpret,
     )(lengths.astype(jnp.int32), tables.astype(jnp.int32), order,

@@ -217,7 +217,8 @@ def tile_sizes(qk: int, group: int, page_tokens: int, pages_per_slot: int,
 
 def streamed_tokens(impl: str, qk: int, cursors: List[int], idle_rows: int,
                     group: int, page_tokens: int, pages_per_slot: int,
-                    row_bytes: int, window: Optional[int] = None
+                    row_bytes: int, window: Optional[int] = None,
+                    tiles: Optional[Tuple[int, int]] = None
                     ) -> Tuple[int, int]:
     """(attended, fetched) token positions of one ``[S, K = qk]`` call, per
     layer: ``tile_sizes``' twin on the host, for counters. ``cursors``: the
@@ -229,9 +230,11 @@ def streamed_tokens(impl: str, qk: int, cursors: List[int], idle_rows: int,
     Attended (the positions a row, or a query tile of the kernel, may
     attend) over fetched is the block fill share. Under a ``window`` both
     start at the block that holds the first position the window (of the
-    row, or of the tile's first row) lets in."""
-    pages, q_tile = tile_sizes(qk, group, page_tokens, pages_per_slot,
-                               row_bytes, window)
+    row, or of the tile's first row) lets in. ``tiles``: the (pages a
+    block, query tokens a tile) of a kernel with a rule of its own
+    (``ops.latent_attention.latent_tiles``); default ``tile_sizes``'."""
+    pages, q_tile = tiles or tile_sizes(qk, group, page_tokens,
+                                        pages_per_slot, row_bytes, window)
     block = pages * page_tokens
 
     def blocks(upto: int) -> int:

@@ -30,7 +30,7 @@ from ray_tpu.models.transformer import (ATTENTION, INDEXED, LATENT, LINEAR,
                                         STATE_KINDS, state_shapes)
 from ray_tpu.ops.indexed_attention import (SELECT_ROWS, chunk_tokens,
                                            context_tokens, select_lanes)
-from ray_tpu.ops.latent_attention import pool_width
+from ray_tpu.ops.latent_attention import latent_tiles, pool_width
 from ray_tpu.ops.paged_attention import streamed_tokens, tile_sizes
 
 _m_attn_bytes = Counter(
@@ -149,7 +149,10 @@ def _latent(work, n, layers, qk, cursors, real):
     (``latent_bytes_moved``: a step's row its whole context, a chunk its
     context once, and each its own tokens written, ``rank + rope`` values a
     token: the pool's padding is not work). What the kernel streams
-    in whole blocks is counted by ``attn_*`` as for every paged kind."""
+    in whole blocks is counted by ``attn_*`` as for every paged kind, a
+    slot's blocks once a CELL of the kernel's own rule (``Work.record``
+    hands ``streamed_tokens`` the kind's ``latent_tiles``: all of a chunk's
+    queries where the paged kernel's rule counts a 64-token tile)."""
     t = np.asarray(cursors)[:, None] + np.arange(real)  # [rows, real]
     context = int((t + 1).sum())
     n["latent_tokens_context"] += layers * context
@@ -225,6 +228,7 @@ class Work:
         self._row_bytes = token_bytes(cfg, LATENT if latent else ATTENTION,
                                       itemsize) // 2
         self._group = cfg.num_heads // (1 if latent else cfg.kv_heads)
+        self._latent = latent
         if SPARSE in kinds:
             # tokens of one block of the step's kernel over a row's table
             # of chosen pages (``sparse_attention._step_attention``)
@@ -253,6 +257,17 @@ class Work:
         if state_bytes:
             self._n.update(state_slots=slots, state_bytes=state_bytes)
 
+    def _tiles(self, qk: int):
+        """The latent kernel's own (pages a block, query tokens a cell) of a
+        K = ``qk`` call; None for every other kind: ``tile_sizes``'."""
+        if not self._latent:
+            return None
+        cfg = self.cfg
+        return latent_tiles(
+            qk, cfg.num_heads, self._page_tokens, self._pages_per_slot,
+            pool_width(cfg.latent_kv_rank, cfg.latent_rope_dim),
+            self.itemsize)
+
     def record(self, qk: int, cursors: List[int], idle_rows: int = 0,
                real: Optional[int] = None) -> None:
         """One attention-bearing program call: a K = ``qk`` window for every
@@ -275,7 +290,7 @@ class Work:
         dense = lambda window: streamed_tokens(
             self.lane, qk, cursors, idle_rows,
             self._group, self._page_tokens, self._pages_per_slot, row,
-            window)
+            window, self._tiles(qk))
         attended, fetched = streamed or dense(None)
         n["attn_tokens_attended"] += attended
         n["attn_tokens_fetched"] += fetched

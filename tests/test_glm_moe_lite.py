@@ -347,22 +347,8 @@ def test_the_kernel_is_the_plain_path(S, K, H, lengths):
     """``impl='pallas'`` (interpreted here) against the ``jax.numpy`` path:
     pages through a shuffled table, the pages no table names filled with
     NaN."""
-    rank, rope, T, P = 32, 8, 8, 16
-    keys = jax.random.split(jax.random.PRNGKey(5), 4)
-    n_pages = 1 + S * P + 5
-    c = jax.random.normal(keys[0], (n_pages, T, rank), jnp.float32)
-    kr = jax.random.normal(keys[1], (n_pages, T, rope), jnp.float32)
-    perm = np.random.RandomState(0).permutation(np.arange(1, 1 + S * P))
-    tables = perm.reshape(S, P).astype(np.int32)
-    for s, n in enumerate(lengths):   # unallocated entries: the garbage page
-        tables[s, max(-(-(n + K) // T), 0):] = 0
-    poisoned = np.setdiff1d(np.arange(1, n_pages), np.unique(tables))
-    pool = join(c, kr).at[poisoned].set(jnp.nan)
-    assert pool.shape == (n_pages, T, 128)
-    q_c = jax.random.normal(keys[2], (S, K, H, rank), jnp.float32)
-    q_r = jax.random.normal(keys[3], (S, K, H, rope), jnp.float32)
-    args = (q_c, q_r, pool, jnp.asarray(tables),
-            jnp.asarray(lengths, jnp.int32))
+    args, _ = _kernel_case(S, K, H, lengths, 16)
+    assert args[2].shape == (1 + S * 16 + 5, 8, 128)
     run = lambda impl: jax.jit(functools.partial(
         latent_attention, sm_scale=0.17, impl=impl))(*args)
     with jax.default_matmul_precision("highest"):
@@ -376,11 +362,203 @@ def test_the_kernel_is_the_plain_path(S, K, H, lengths):
         latent_attention(*args, sm_scale=0.17, impl="flash")
 
 
+def _kernel_case(S, K, H, lengths, P, sm_scale=0.17, T=8):
+    """Seeded queries and a pool of [1 + S * P + 5] pages of ``T`` tokens
+    (rank 32, rope 8: rows of 128 lanes) through a shuffled table whose
+    entries past a slot's allocation name the garbage page; every page no
+    table names is NaN, as a released one would be."""
+    rank, rope = 32, 8
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    n_pages = 1 + S * P + 5
+    c = jax.random.normal(keys[0], (n_pages, T, rank), jnp.float32)
+    kr = jax.random.normal(keys[1], (n_pages, T, rope), jnp.float32)
+    perm = np.random.RandomState(0).permutation(np.arange(1, 1 + S * P))
+    tables = perm.reshape(S, P).astype(np.int32)
+    for s, n in enumerate(lengths):
+        tables[s, max(-(-(n + K) // T), 0):] = 0
+    poisoned = np.setdiff1d(np.arange(1, n_pages), np.unique(tables))
+    pool = join(c, kr).at[poisoned].set(jnp.nan)
+    q_c = jax.random.normal(keys[2], (S, K, H, rank), jnp.float32)
+    q_r = jax.random.normal(keys[3], (S, K, H, rope), jnp.float32)
+    return (q_c, q_r, pool, jnp.asarray(tables),
+            jnp.asarray(lengths, jnp.int32)), poisoned
+
+
+# a table of 160 pages of 8: blocks of 512 tokens, the third cut short by
+# the table's end; 20 heads, so 64 tokens are five matmuls of 256 rows
+@pytest.mark.parametrize("K,cursor,cell_vmem", [
+    (70, 0, None),        # cold: the one block reaches into the cell
+    (70, 300, None),      # the chunk's first position mid-block
+    (70, 510, None),      # its second token is the block's last: no block
+    #                       is free, and block 0 unmasked would show row 0
+    #                       the chunk's own next token
+    (70, 511, None),      # on a block's last token: block 0 is free
+    (70, 512, None),      # on a block's first token
+    (70, 1090, None),     # two free blocks, the table's short last one
+    (200, 400, 16 << 20),  # K no multiple of the cell: two cells of 128
+    #                        tokens, the second's last groups without a row
+    (330, 0, 16 << 20),   # three cells, cold: a cell's free blocks are
+    #                       the tokens of the cells before it
+], ids=["cold", "mid_block", "no_block_free", "block_last", "block_first",
+        "table_end", "cells_k200", "cells_cold"])
+def test_a_cell_walks_its_blocks_once_and_masks_where_it_must(
+        monkeypatch, K, cursor, cell_vmem):
+    """One cell holds several ``_SUB_ROWS`` groups (70 tokens x 20 heads:
+    128 tokens a cell, ten groups, two in flight), and where the cell's
+    VMEM is made small, several cells a call: against ``_latent_reference``
+    at every place a chunk's first position can lie in a block. The first
+    masked block is exactly the first that needs a mask: one block earlier
+    and a row would be refused a token it may see, one later and it would
+    attend the chunk's own later tokens or the garbage page's."""
+    from ray_tpu.ops import latent_attention as la
+
+    H, P = 20, 160
+    if cell_vmem is not None:
+        monkeypatch.setattr(la, "_CELL_VMEM", cell_vmem)
+    assert la.latent_tiles(K, H, 8, P, 128, 4) == (64, 128)
+    args, poisoned = _kernel_case(1, K, H, [cursor], P)
+    with jax.default_matmul_precision("highest"):
+        want = la._latent_reference(*args, 0.17)
+        # the jitted wrapper read ``_CELL_VMEM`` when it was traced
+        got = la._latent_pallas.__wrapped__(*args, 0.17, True)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    assert np.isnan(np.asarray(args[2])[poisoned]).all()
+
+
+def _step_as_it_was(q_c, q_r, pool, tables, lengths, sm_scale):
+    """K = 1 through the kernel AS PR 55 WROTE IT (its body and its call,
+    cut to the one tile of H rows a slot the step is), interpreted: what
+    ``test_the_step_is_bitwise_what_it_was`` holds the kernel to."""
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ray_tpu.ops.paged_attention import NEG_INF, tile_sizes
+
+    S, _, H, rank = q_c.shape
+    T, W, P = pool.shape[1], pool.shape[2], tables.shape[1]
+    B, _ = tile_sizes(1, H, T, P, W * pool.dtype.itemsize // 2)
+    BT = B * T
+
+    def kernel(lengths_ref, tables_ref, order_ref, n_live_ref, q_ref,
+               pool_ref, o_ref, buf_ref, sems, first_buf, m_scr, l_scr,
+               acc_scr):
+        c, n_live = pl.program_id(0), n_live_ref[0]
+        s = order_ref[c]
+
+        def block_copies(s, b, buf, wait=False):
+            for i in range(B):
+                j = b * B + i
+                pid = 0 if wait else jnp.where(
+                    j < P, tables_ref[s, jnp.minimum(j, P - 1)], 0)
+                cp = pltpu.make_async_copy(
+                    pool_ref.at[pid], buf_ref.at[buf, pl.ds(i * T, T)],
+                    sems.at[buf])
+                cp.wait() if wait else cp.start()
+
+        @pl.when(c == 0)
+        def _():
+            first_buf[0] = 0
+
+            @pl.when(n_live > 0)
+            def _():
+                block_copies(s, 0, 0)
+
+        base = first_buf[0]
+        nb = jnp.clip(lax.div(lengths_ref[s] + 1 + BT - 1, jnp.int32(BT)),
+                      1, -(-P // B))
+        nb = jnp.where(c < n_live, nb, 0)
+        s_next = order_ref[jnp.minimum(c + 1, pl.num_programs(0) - 1)]
+        m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+        def body(b, _):
+            buf = lax.rem(base + b, 2)
+            more = b + 1 < nb
+
+            @pl.when(jnp.logical_or(more, c + 1 < n_live))
+            def _():
+                block_copies(jnp.where(more, s, s_next),
+                             jnp.where(more, b + 1, 0), 1 - buf)
+
+            block_copies(s, b, buf, wait=True)
+            kpos = b * BT + lax.broadcasted_iota(jnp.int32, (1, BT), 1)
+            s_ = lax.dot_general(
+                q_ref[0, 0], buf_ref[buf], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            latents = buf_ref[buf, :, :rank]
+            row_pos = lengths_ref[s] + lax.broadcasted_iota(
+                jnp.int32, (H, 1), 0) // H
+            s_ = jnp.where(kpos <= row_pos, s_ * sm_scale, NEG_INF)
+            m = m_scr[...]
+            m_new = jnp.maximum(m, jnp.max(s_, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            pr = jnp.exp(s_ - m_new)
+            l_scr[...] = (l_scr[...] * alpha
+                          + jnp.sum(pr, axis=-1, keepdims=True))
+            acc_scr[...] = acc_scr[...] * alpha + lax.dot_general(
+                pr.astype(latents.dtype), latents, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[...] = m_new
+
+        lax.fori_loop(0, nb, body, None)
+        first_buf[0] = lax.rem(base + nb, 2)
+        l = l_scr[...]
+        o_ref[0, 0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+
+    live = lengths + 1 > 0
+    order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
+    cell = lambda c, *refs: (refs[2][c], 0, 0, 0)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(S,),
+            in_specs=[pl.BlockSpec((1, 1, H, W), cell),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, 1, H, rank), cell),
+            scratch_shapes=[pltpu.VMEM((2, BT, W), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((1,), jnp.int32)] + [
+                pltpu.VMEM((H, n), jnp.float32) for n in (1, 1, rank)]),
+        out_shape=jax.ShapeDtypeStruct((S, 1, H, rank), q_c.dtype),
+        interpret=True,
+    )(lengths, tables, order, jnp.sum(live, dtype=jnp.int32)[None],
+      join(q_c, q_r).astype(pool.dtype), pool)
+    return out
+
+
+def test_the_step_is_bitwise_what_it_was():
+    """K = 1 is one cell of H rows a slot, every block masked, the scale on
+    the scores: PR 55's arithmetic, bit for bit (a scale that is no power
+    of two: folded into the query it would show), slots idle, at their
+    first token and past the table's short last block."""
+    args, _ = _kernel_case(4, 1, 4, [37, -1, 0, 1200], 160)
+    assert latent_tiles(1, 4, 8, 160, 128, 4) == (64, 1)
+    with jax.default_matmul_precision("highest"):
+        got = latent_attention(*args, sm_scale=0.17, impl="pallas")
+        want = jax.jit(functools.partial(_step_as_it_was, sm_scale=0.17))(
+            *args)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert np.asarray(got[0]).any() and not np.asarray(got[1]).any()
+
+
 def test_the_tiles_are_the_paged_kernels_rule():
-    # the cell's shapes: 512 tokens a block either way, a chunk's 512
-    # queries in tiles of 64 (1,280 rows, five matmuls of 256)
+    # the cell's shapes: 512 tokens a block, the paged kernel's rule; the
+    # step one cell of a slot's 20 rows, as that rule has it too
     assert latent_tiles(1, 20, 16, 4128, 640, 2) == (32, 1)
-    assert latent_tiles(512, 20, 16, 4128, 640, 2) == (32, 64)
+    # a chunk's 512 queries are ONE cell (10,240 rows, forty matmuls of 256
+    # a block; the paged rule's 64 tokens made eight cells, each a walk of
+    # the slot's context), and the check prompt's 4,113 nine cells of 512
+    assert latent_tiles(512, 20, 16, 4128, 640, 2) == (32, 512)
+    assert latent_tiles(4113, 20, 16, 4128, 640, 2) == (32, 512)
+    # whole matmuls of 256 rows (64 tokens of 20 heads); under that one
+    assert latent_tiles(70, 20, 16, 4128, 640, 2) == (32, 128)
+    assert latent_tiles(12, 20, 16, 4128, 640, 2) == (32, 12)
+    # a cell that VMEM cannot hold whole: the tokens spread evenly
+    assert latent_tiles(8192, 20, 16, 4128, 640, 2) == (32, 512)
+    assert latent_tiles(600, 20, 16, 4128, 640, 2) == (32, 320)
 
 
 # ------------------------------------------------------ the paged programs
@@ -564,6 +742,11 @@ def test_the_scheduler_serves_the_kind_and_counts_its_work():
         sum(c + 1 for c in steps) + sum(chunk_ends) + len(rows))
     assert stats["fused_turns"] > 0 and stats["pages_in_use"] == 0
     assert "indexed_tokens_context" not in stats  # another kind's
+    # the reference lane fetches every row's longest context in whole
+    # blocks whatever the tiles: the fill share is what it was (the
+    # kernel's lane, once a cell:
+    # ``test_a_latent_chunk_fetches_its_blocks_once_a_cell``)
+    assert 0 < stats["attn_tokens_attended"] <= stats["attn_tokens_fetched"]
     # the experts: every live row of every EXPERT layer-call (the dense
     # layer routes nothing) took top-k experts and the shared one
     assert stats["moe_rows_routed"] == (
@@ -588,6 +771,50 @@ def test_another_models_counters_are_what_they_were():
     assert stats["attn_tokens_attended"] == 101 + 201
     assert stats["attn_bytes_moved"] == 3 * 256 * (
         stats["attn_tokens_fetched"] + 8)
+
+
+# a step of two live rows beside six idle, a chunk of 300 real tokens behind
+# a prefix of 1024 and a cold chunk, through the kernel's lane at pages of 16
+# under a table of 128: (attended, fetched, bytes) as PR 55's tree counted
+@pytest.mark.parametrize("preset,was", [
+    ("moe_debug", (3886, 5120, 1574912)),
+    ("mellum_debug", (3886, 5120, 6578176)),          # window beside full
+    ("minicpm_sala_debug", (2492, 24576, 6555648)),   # block-selected
+    ("keye_debug", (63, 2080, 1593344)),              # token-selected
+    ("glm_moe_lite_debug", (2350, 3072, 3151872)),    # PR 55: 6958, 9216
+], ids=["plain", "window", "sparse", "indexed", "latent"])
+def test_a_latent_chunk_fetches_its_blocks_once_a_cell(preset, was):
+    """``Work.record`` hands ``streamed_tokens`` the latent kernel's own
+    tiles and no other kind's: every other kind counts what it counted, and
+    a latent chunk the blocks up to its end ONCE (PR 55 counted them once a
+    tile of the paged kernel's rule: 128 tokens of the toy's 4 heads, four
+    tiles a chunk; at GLM's 20 heads eight)."""
+    from ray_tpu.models import presets
+    from ray_tpu.ops.paged_attention import streamed_tokens
+    from ray_tpu.serve._private.work import Work
+
+    work = Work(getattr(presets, preset)(), slots=8, page_tokens=16,
+                pages_per_slot=128, itemsize=2, lane="pallas")
+    work.record(1, [100, 200], 6)
+    work.record(512, [1024], 0, 300)
+    work.record(512, [0], 0)
+    stats = work.stats()
+    assert tuple(stats["attn_" + key] for key in (
+        "tokens_attended", "tokens_fetched", "bytes_moved")) == was
+    if preset != "glm_moe_lite_debug":
+        return
+    # the three calls: two rows' blocks of 512, then 1536 and 512 tokens
+    assert was[1] == 2 * 512 + 1536 + 512
+    # at the cell's sizes: a 512 chunk at 44,544 of a 4128-page table
+    at = ("pallas", 512, [44544], 0, 20, 16, 4128, 640)
+    tiles = latent_tiles(512, 20, 16, 4128, 640, 2)
+    assert streamed_tokens(*at) == (358656, 360448)      # eight tiles
+    assert streamed_tokens(*at, None, tiles) == (45056, 45056)
+    assert streamed_tokens(*at, None, (32, 64)) == streamed_tokens(*at)
+    # and the step is one cell a slot under either rule
+    step = ("pallas", 1, [44544, 100], 6, 20, 16, 4128, 640)
+    assert streamed_tokens(*step) == streamed_tokens(
+        *step, None, latent_tiles(1, 20, 16, 4128, 640, 2)) == (44646, 45568)
 
 
 def test_a_spliced_prefix_continues_to_the_same_logits():
