@@ -226,9 +226,13 @@ def train_loop(config: dict) -> None:
         jax.block_until_ready(metrics["loss"])
         step_s.append(round(time.perf_counter() - t0, 4))
         losses.append(float(metrics["loss"]))
+    from ray_tpu._private import compile_cache
+
     train.report({"losses": losses, "step_s": step_s,
                   "compile_s": round(compile_s, 2),
                   "mesh": dict(mesh.shape) if mesh is not None else None,
+                  # what this worker traced, lowered and compiled or loaded
+                  "compiles": compile_cache.summary(),
                   **device_report()})
 
 
@@ -2125,6 +2129,14 @@ def main() -> None:
                                            "chip_smoke_logs"),
                         dirs_exist_ok=True)
     emit("cache", dir=cache_dir, entries=compile_cache.entries(cache_dir))
+    # this process's own compile record: it drives and never imports JAX, so
+    # ``watching`` is false and every total 0 (a worker's is in its phase's
+    # line, ``compiles``, and behind profile_actor(..., kind="compiles"))
+    from ray_tpu._private import profiling
+
+    own = profiling.collect("compiles")
+    emit("compiles", **{k: v for k, v in own.items() if k != "jit_programs"},
+         programs=len(own["jit_programs"]))
     if device["platform"] != "tpu" or device["count"] != args.chips:
         raise SystemExit(f"chip_smoke: worker saw {device}")
     print(json.dumps({"ok": True, "device": device}))

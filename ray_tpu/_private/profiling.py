@@ -13,8 +13,12 @@ HBM?"), which a generic sampling profiler can't see:
   live trace)
 - ``device``: per-device live jax.Array count/bytes + committed-array
   breakdown by shape/dtype (top HBM holders)
+- ``compiles``: the process's compile record (``compile_cache.record()``):
+  every jitted program's trace, lower and compile-or-cache-load seconds and
+  its persistent-cache hits and misses, by function name, and their totals
+  ("which step recompiled, and did the cache serve it?")
 
-All three return plain dicts, routed driver -> supervisor -> worker by
+All four return plain dicts, routed driver -> supervisor -> worker by
 ``ray_tpu.util.state.profile_worker`` / ``profile_actor``.
 """
 
@@ -37,8 +41,10 @@ def collect(kind: str, limit: int = 20) -> Dict[str, Any]:
         return collect_memory(limit)
     if kind == "device":
         return collect_device(limit)
+    if kind == "compiles":
+        return collect_compiles()
     raise ValueError(f"unknown profile kind {kind!r} "
-                     "(expected stack|memory|device)")
+                     "(expected stack|memory|device|compiles)")
 
 
 def collect_stacks() -> Dict[str, Any]:
@@ -115,3 +121,14 @@ def collect_device(limit: int = 20) -> Dict[str, Any]:
     top = sorted(by_shape.values(), key=lambda a: -a["bytes"])[:limit]
     return {"pid": os.getpid(), "jax_initialized": True,
             "devices": per_device, "top_arrays": top}
+
+
+def collect_compiles() -> Dict[str, Any]:
+    """The compile record of this process. ``watching`` is False where
+    nothing listens: a process that has not imported JAX (this call does not
+    make it), or one whose program never called ``compile_cache.watch()``;
+    a worker asked before its first jitted program starts listening here."""
+    from ray_tpu._private import compile_cache
+
+    return {"pid": os.getpid(), "jax_initialized": "jax" in sys.modules,
+            "watching": compile_cache.watch(), **compile_cache.record()}

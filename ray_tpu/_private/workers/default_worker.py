@@ -884,8 +884,9 @@ def main() -> None:
     core.server.register("cancel", executor.cancel)
 
     async def profile(body):
-        """Live in-process profiling (stacks / memory / device HBM);
-        ref dashboard reporter_agent.py:391 py-spy attach."""
+        """Live in-process profiling (stacks / memory / device HBM / the
+        compile record); ref dashboard reporter_agent.py:391 py-spy
+        attach."""
         from ray_tpu._private import profiling
 
         return profiling.collect(body.get("kind", "stack"),

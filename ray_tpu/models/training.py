@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from ray_tpu._private import compile_cache
 from ray_tpu.models.transformer import (TransformerConfig, forward,
                                         init_params, logical_axes, loss_fn,
                                         streams)
@@ -64,6 +65,7 @@ def init_train_state(cfg: TransformerConfig, ocfg: OptimizerConfig, key,
 
     Uses jit-with-out-shardings so big models materialize directly as shards
     (no host-side full copy of each leaf)."""
+    compile_cache.watch()  # a trainer's first jitted program is often here
     tx = make_optimizer(ocfg)
 
     def _init(k):
@@ -148,6 +150,7 @@ def make_train_step(cfg: TransformerConfig, tx, mesh=None,
     log_grad_norm=False drops the grad_norm metric, saving one full pass
     over the gradients (~0.5 GB of HBM reads for a 124M-param model) —
     clipping inside `tx` still sees the norm either way."""
+    compile_cache.watch()
     # the default is ``loss_fn`` on the batch's tokens, of which ``streams``
     # can say how it is carried; a caller's own loss may run anything
     own_loss = loss is None

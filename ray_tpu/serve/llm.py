@@ -60,6 +60,18 @@ def _byte_detokenize(ids: List[int]) -> str:
     return bytes(int(i) % 256 for i in ids).decode("utf-8", errors="replace")
 
 
+# A replica's build as leaf phases of one clock (``flight.PhaseClock``, as
+# the scheduler thread's time is): the program's imports and the config; the
+# loader and ``device_put``; the drafter, the scheduler and its pools. A
+# phase ends where its last call RETURNS: device work it left running
+# (``device_put``, a loader that fills its arrays on the device) belongs to
+# whoever next waits for it, in the build or after it.
+BUILD_PHASES = ("setup.config", "setup.weights", "setup.scheduler")
+_B_CONFIG, _B_WEIGHTS, _B_SCHEDULER = range(3)
+# a phase's seconds in scheduler_stats(): setup_config_s, ...
+BUILD_KEYS = tuple(p.replace(".", "_") + "_s" for p in BUILD_PHASES)
+
+
 class LLMServerImpl:
     """One model replica: owns the weights and the continuous-batching
     scheduler (``serve/_private/continuous.py``). Weights are shared per
@@ -87,6 +99,13 @@ class LLMServerImpl:
                  attn: Optional[str] = None):
         import jax
 
+        from ray_tpu._private import compile_cache, flight
+
+        compile_cache.watch()  # every program this process jits, by name
+        # stopped on the way out; a build that raises leaves no replica
+        self._build_clock = build = flight.PhaseClock(BUILD_PHASES)
+        build.switch(_B_CONFIG)
+
         from ray_tpu.models import presets
         from ray_tpu.models.decode import decode_step, prefill
         from ray_tpu.models.transformer import init_params
@@ -106,6 +125,7 @@ class LLMServerImpl:
         self._stream_tokens = 0
 
         # ---- weights: one arena copy per node (ISSUE 9 tentpole) ----
+        build.switch(_B_WEIGHTS)
         from ray_tpu.serve._private import weights as _weights
 
         def load():
@@ -146,6 +166,7 @@ class LLMServerImpl:
         self.params = jax.device_put(host)
         del host
 
+        build.switch(_B_SCHEDULER)
         self._tokenize = tokenize or partial(
             _byte_tokenize, vocab_size=self.cfg.vocab_size)
         self._detokenize = detokenize or _byte_detokenize
@@ -169,6 +190,7 @@ class LLMServerImpl:
             prefix_cache=prefix_cache, drafter=drafter_obj,
             spec_k=spec_k, migration_budget=migration_budget,
             attn=attn)
+        build.stop()
 
     def _build_drafter(self, drafter: Optional[str], slots, arena_len,
                        _weights):
@@ -307,7 +329,24 @@ class LLMServerImpl:
     # ------------------------------------------------------ introspection
 
     def scheduler_stats(self) -> Dict[str, Any]:
+        """The scheduler's ``stats()`` plus what only the replica knows:
+        ``stream_lag_s`` / ``stream_tokens`` (the hand-off to the event
+        loop), ``stream_reports`` / ``stream_items_reported`` (the way out
+        of the worker), ``platform`` / ``device_kind`` / ``device_count`` /
+        ``peak_bytes_in_use`` (where the model really runs),
+        ``setup_config_s`` / ``setup_weights_s`` / ``setup_scheduler_s``
+        (the build by phase, ``BUILD_KEYS``), and this PROCESS's compile
+        record (``_private/compile_cache.py``): ``jit_compile_events``,
+        ``jit_trace_s``, ``jit_lower_s``, ``jit_compile_s`` (compile or
+        cache load), ``jit_cache_hits``, ``jit_cache_misses``,
+        ``jit_cache_saved_s``, and ``jit_programs``, function name -> row.
+        Two snapshots' difference of ``jit_compile_events`` is what was
+        compiled between them; the rows whose ``n`` rose say which."""
         out = self._sched.stats()
+        from ray_tpu._private import compile_cache
+
+        out.update(compile_cache.record())
+        out.update(zip(BUILD_KEYS, self._build_clock.seconds()))
         out["stream_lag_s"] = self._stream_lag_ns / 1e9
         out["stream_tokens"] = self._stream_tokens
         # how this worker's streams left it: items over reports is what one

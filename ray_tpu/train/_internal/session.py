@@ -73,9 +73,21 @@ class _TrainSession:
     # ------------------------------------------------------------- lifecycle
 
     def start(self) -> None:
+        from ray_tpu._private import compile_cache
+
+        # listens from here on where the backend's start-up has imported JAX
+        # already; a loop that imports it itself is heard from its first
+        # ``init_train_state`` / ``make_train_step`` on
+        compile_cache.watch()
+
         def _run():
             try:
                 ret = self._train_fn()
+                if compile_cache.watch():
+                    # once, when the loop ends: what this worker compiled
+                    # (the live call is profile_actor(..., kind="compiles"))
+                    logger.info("rank %d %s", self.world_rank,
+                                compile_cache.summary())
                 self._queue.put(TrainingReport(kind="done", final_return=ret))
             except BaseException as e:  # surfaced to the driver, then re-raised
                 logger.error("train fn failed on rank %d:\n%s",

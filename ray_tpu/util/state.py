@@ -285,7 +285,10 @@ def profile_worker(node_id_hex: str, worker_id_hex: str,
     `dashboard/modules/reporter/reporter_agent.py:391`; collectors in
     `_private/profiling.py`). Kinds: "stack" (all thread stacks),
     "memory" (RSS + tracemalloc top sites), "device" (live jax.Array
-    HBM breakdown — the TPU question generic profilers can't answer)."""
+    HBM breakdown — the TPU question generic profilers can't answer),
+    "compiles" (every jitted program's trace / lower / compile-or-cache-load
+    seconds and persistent-cache hits and misses by function name, with
+    totals: set-up's seconds by program, and which step recompiled)."""
     return _supervisor_call(node_id_hex, "worker_profile",
                             {"worker_id_hex": worker_id_hex,
                              "kind": kind, "limit": limit})
@@ -293,7 +296,9 @@ def profile_worker(node_id_hex: str, worker_id_hex: str,
 
 def profile_actor(name_or_id: str, kind: str = "stack",
                   limit: int = 20) -> Dict[str, Any]:
-    """Profile the worker currently hosting an actor (by name or id)."""
+    """Profile the worker currently hosting an actor (by name or id); the
+    kinds are ``profile_worker``'s. ``kind="compiles"`` is how a trainer's
+    worker or a replica is asked what it compiled, and when."""
     for rec in _call("actor_list"):
         if rec["actor_id_hex"] == name_or_id or rec["name"] == name_or_id:
             if rec["state"] != "ALIVE":
