@@ -42,7 +42,9 @@ weights from ``--seed``):
            mixer power retention, bf16, no page anywhere): the two kernels
            in float32 at the published head shape (40 query heads on 8
            states) against the attention form token against token (tight:
-           1e-3 of the largest output); then a prompt of 2177 tokens through
+           1e-3 of the largest output); the chunk kernel ALONE at the
+           cell's shape (one row, 512 tokens, bf16, a warm state), timed:
+           ``retention_chunk_ms``; then a prompt of 2177 tokens through
            the paged prefill chunks (the last one padded) and 6 decode steps
            beside a second live row, against perfbench/reference/brumby.py
            (inside the cell's limits); a slot without a sequence keeps a
@@ -803,6 +805,34 @@ def sala_task(seed: int) -> dict:
     return {**out, **device_report()}
 
 
+def retention_chunk_ms(cfg, seed: int, calls: int = 20) -> float:
+    """The retention chunk kernel ALONE at the cell's shape — one row, a
+    512-token chunk, every query head over its K/V heads, bf16, on the state
+    a first chunk left — in milliseconds a call, ``calls`` timed behind a
+    warm-up (the call's glue around the kernel is in it: the rows cut into
+    blocks, the gates' sums)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import power_retention as pr
+
+    H, G, D, C = cfg.num_heads, cfg.kv_heads, cfg.head_dim, 512
+    ks = jax.random.split(jax.random.key(seed & 0x7FFFFFFF), 4)
+    q, k, v = (jax.random.normal(key, (1, C, heads, D), jnp.bfloat16)
+               for key, heads in zip(ks, (H, G, G)))
+    gate = jax.nn.log_sigmoid(jax.random.normal(ks[3], (1, C, G)) + 2.0)
+    run = jax.jit(lambda *a: pr.power_retention_chunk(*a, C))
+    state = run(q, k, v, gate, *(
+        jnp.zeros(shape, jnp.float32)
+        for shape in pr.state_shapes(1, G, D).values()))[1:]
+    jax.block_until_ready(run(q, k, v, gate, *state))
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = run(q, k, v, gate, *state)
+    jax.block_until_ready(out)
+    return round((time.perf_counter() - t0) / calls * 1e3, 4)
+
+
 def brumby_task(seed: int) -> dict:
     """The Brumby-width checks (ISSUE 43): the two retention kernels in
     float32 against the attention form, then the system in bf16 — paged
@@ -863,6 +893,7 @@ def brumby_task(seed: int) -> dict:
         stepped.append(np.asarray(o1[0]))
     kernel_err["steps_behind_it"] = rel(np.stack(stepped), want[head - 7:])
     del q, k, v, o, s, z, zero
+    chunk_ms = retention_chunk_ms(cfg, seed)
     # ---- the paged programs, bf16, against the reference
     params = weights.make_params(cfg, seed)
     S, C, slot, other, steps = 4, 512, 2, 1, 6
@@ -931,7 +962,8 @@ def brumby_task(seed: int) -> dict:
             "margin": max(float((w.max() - w[t]) / np.abs(want).max())
                           for w, t in zip(want, fed[row]))}
         del want
-    out = {"kernel_err_f32": kernel_err, "paged_err": err,
+    out = {"kernel_err_f32": kernel_err, "retention_chunk_ms": chunk_ms,
+           "paged_err": err,
            "idle_slot_state": idle_states, "fused_turn": turn,
            "ids_on_device": [int(served_on_device[r]) for r in prompts],
            "chunk_ms": [round(1e3 * x, 1) for x in chunk_s],

@@ -11,28 +11,53 @@ pairs ``a <= b``: 8256 for ``D`` = 128), so the same function is a recurrence,
     S_t = exp(c_t) S_(t-1) + phi(k_t) v_t^T        z_t = exp(c_t) z_(t-1) + phi(k_t)
     y_t = phi(q_t)^T S_t / D / (phi(q_t) . z_t / D + eps)
 
-and ``H / G`` query heads read the one state of their K/V head. Here
-``phi(q)`` holds ``q_a q_b`` and ``phi(k)`` holds ``k_a k_b``, doubled where
-``a < b`` (the pair stands for ``(b, a)`` too); ``sqrt(2)`` on both sides is
-the same product, and 2 is exact in every float type. The state is kept
-value-major and in tiles, ``s [G, tiles, D, lanes]`` and ``z [G, tiles, 1,
-lanes]``: the pairs lie along the lanes, padded to whole 128-lane rows (8320
-for ``D`` = 128, never the 16384 of the square), a tile is a block of them
-that a kernel takes by its leading index (``layout``). Two kernels:
+and ``H / G`` query heads read the one state of their K/V head.
+
+The pairs are laid out by FOLDING the triangle. Every unordered pair ``{a,
+b}`` of ``D`` values is ``(a, (a + r) mod D)`` for exactly one ``r`` in ``0 ..
+D / 2 - 1`` (``r = 0``: the squares), or twice for ``r = D / 2``: lane ``r D
++ a`` of a head's pairs holds the pair ``(a, (a + r) mod D)``, ``r = 0 .. D /
+2``, so row ``r`` of ``phi(u)`` is ``u`` times ``u`` rolled by ``r`` and no
+value has to be picked out by a matrix. That is ``D (D / 2 + 1)`` lanes: 8320
+for ``D`` = 128, exactly 65 rows of 128 lanes with none of them padding
+(never the 16384 of the square). ``phi(q)`` holds ``q_a q_b`` and ``phi(k)``
+holds ``k_a k_b`` times the lane's weight (``_weights``): 1 on ``r = 0`` (a
+square), 2 on ``r = 1 .. D / 2 - 1`` (the pair stands for ``(b, a)`` too;
+``sqrt(2)`` on both sides is the same product, and 2 is exact in every float
+type) and 1 on ``r = D / 2``, where each pair stands twice. The state is
+kept value-major and in tiles, ``s [G, tiles, D, lanes]`` and ``z [G, tiles,
+1, lanes]``: the pairs lie along the lanes, padded with pairs that stay zero
+to whole 128-lane rows, a tile is a block of them that a kernel takes by its
+leading index (``layout``). Nothing outside this module knows the order of
+the lanes (``layout`` and ``state_shapes`` are all a holder reads), and a
+state lives only inside the process that wrote it — a replica's slots
+(``decode.RetentionState``; the scheduler refuses to export a prefix of such
+a model), never a file or a wire — which is what lets the order change with
+the build: a state an older build wrote (pairs ``a <= b`` in ``triu`` order)
+is NOT readable by this one. Two kernels:
 
   * ``power_retention_step`` — one token a row: a K/V head's state and
     normaliser are read once and written once, in place, for all its query
     heads together, on the vector unit; a row that is not ``active`` gets
     its state back bitwise. ``phi`` of the row's q and k is made outside
-    (two selections and a product: 5% of the bytes the state is).
+    (``_phi``: two selections over the fold and a product: 5% of the bytes
+    the state is). The kernel reads pairs and state lane by lane and is
+    indifferent to their order.
   * ``power_retention_chunk`` — ``S`` tokens in blocks: inside a block the
     attention form (squared scores, the gates' running sum as decay, causal),
     across blocks ``phi(Q) S_in`` and ``S_out = decay S_in + phi(K)^T V``,
-    with ``phi`` made in the kernel a tile at a time (two selection matmuls
-    and a product); the state rides in VMEM from block to block, so a chunk
-    reads and writes it once whatever its length. ``real_len`` tokens are
-    real and the rest trailing padding, which neither decays the state nor
-    adds to it.
+    with ``phi`` made in the kernel a tile at a time; the state rides in
+    VMEM from block to block, so a chunk reads and writes it once whatever
+    its length. ``real_len`` tokens are real and the rest trailing padding,
+    which neither decays the state nor adds to it. Which way a tile's pairs
+    are made hangs on the head's width alone. A head of whole lane rows
+    (``D % 128 == 0`` with a tile whole rows of the fold: 128, the
+    published width) ROTATES: the block's rows times themselves under
+    ``pltpu.roll`` along the lanes, on the rotate and vector units, and no
+    matrix work. A narrower head (16, 32, 64: the debug preset and the
+    tests; a lane rotation cannot turn 16 values inside a 128-lane row)
+    SELECTS over the same fold: two 0/1 matrices (``_selectors``) pick each
+    pair's values, as every width did before the fold.
 
 Both are Pallas kernels under those names (what a profiler trace shows as
 the op), interpreted off a TPU (``ops/_pallas.py``). Matmul operands take the
@@ -67,9 +92,13 @@ F32 = jnp.float32
 
 
 def layout(head_dim: int) -> Tuple[int, int]:
-    """(tiles, lanes a tile) of a head's pairs: ``D (D + 1) / 2`` of them,
-    padded with pairs that stay zero to whole 128-lane rows."""
-    rows = -(-(head_dim * (head_dim + 1) // 2) // _LANES)
+    """(tiles, lanes a tile) of a head's pairs: the ``D (D / 2 + 1)`` lanes
+    of the folded triangle, padded with pairs that stay zero to whole
+    128-lane rows."""
+    if head_dim % 2:
+        raise ValueError(f"power retention folds a head of an even size, "
+                         f"not {head_dim}")
+    rows = -(-(head_dim * (head_dim // 2 + 1)) // _LANES)
     per = max(n for n in range(1, _TILE_LANES + 1) if rows % n == 0)
     return rows // per, per * _LANES
 
@@ -82,34 +111,54 @@ def state_shapes(rows: int, kv_heads: int, head_dim: int) -> Dict[str, tuple]:
             "z": (rows, kv_heads, tiles, 1, lanes)}
 
 
+def _fold_index(head_dim: int):
+    """``(a, b)`` of every lane of the fold, in order: lane ``r D + a``
+    holds the pair ``(a, (a + r) mod D)``, ``r = 0 .. D / 2``."""
+    r, a = np.divmod(np.arange(head_dim * (head_dim // 2 + 1)), head_dim)
+    return a, (a + r) % head_dim
+
+
+@functools.lru_cache(maxsize=None)
+def _weights(head_dim: int):
+    """``w [tiles, 1, lanes]``: a lane's weight on the key side. 1 on ``r =
+    0`` (a value's square) and on ``r = D / 2`` (where each pair stands
+    twice), 2 between (the pair stands for ``(b, a)`` too), 0 on padding."""
+    tiles, lanes = layout(head_dim)
+    r = np.arange(head_dim * (head_dim // 2 + 1)) // head_dim
+    w = np.zeros((tiles * lanes,), np.float32)
+    w[:r.size] = np.where((r == 0) | (r == head_dim // 2), 1.0, 2.0)
+    return w.reshape(tiles, 1, lanes)
+
+
 @functools.lru_cache(maxsize=None)
 def _selectors(head_dim: int):
-    """``(A, B [tiles, D, lanes], w [tiles, 1, lanes])``: pair ``r`` is
-    ``(a, b)``, ``a <= b``; ``u A`` holds ``u_a`` there, ``u B`` holds
-    ``u_b``, and ``w`` is the pair's weight on the key side (1 where ``a =
-    b``, 2 where ``a < b``, 0 for the padding, where A and B are 0 too)."""
+    """``(A, B [tiles, D, lanes])``: ``u A`` holds ``u_a`` at every lane of
+    the fold, ``u B`` holds ``u_b``; both are 0 on the padding. What a row's
+    pairs are made with where nothing can rotate it: the step's q and k
+    (``_phi``) and the chunk kernel's heads narrower than a lane row."""
     tiles, lanes = layout(head_dim)
-    a, b = np.triu_indices(head_dim)
-    sel_a = np.zeros((head_dim, tiles * lanes), np.float32)
-    sel_b = np.zeros_like(sel_a)
-    w = np.zeros((tiles * lanes,), np.float32)
-    r = np.arange(a.size)
-    sel_a[a, r], sel_b[b, r] = 1.0, 1.0
-    w[r] = np.where(a == b, 1.0, 2.0)
-    cut = lambda m: np.ascontiguousarray(
-        m.reshape(m.shape[0], tiles, lanes).transpose(1, 0, 2))
-    return cut(sel_a), cut(sel_b), w.reshape(tiles, 1, lanes)
+    picks = []
+    for index in _fold_index(head_dim):
+        m = np.zeros((head_dim, tiles * lanes), np.float32)
+        m[index, np.arange(index.size)] = 1.0
+        picks.append(np.ascontiguousarray(
+            m.reshape(head_dim, tiles, lanes).transpose(1, 0, 2)))
+    return tuple(picks)
 
 
 def _phi(u):
     """u [..., D] -> the products of its pairs, float32 [..., tiles, lanes]
-    (unweighted). A selection picks single values, so it is exact in u's
-    own type."""
-    sel_a, sel_b, _ = _selectors(u.shape[-1])
+    (unweighted), for the step's one row a sequence. A selection picks
+    single values, so it is exact in u's own type. (Row ``r`` of the fold is
+    ``u`` times ``u`` rolled by ``r`` here too, but XLA has no cheap form of
+    it: 65 ``jnp.roll`` stacked run a step 0.1 ms faster and compile 7 s a
+    program slower, a gather or a reshape of ``u`` laid end to end compile
+    like this and run a step 0.9-1.3 ms slower — ``PERF.md`` 6, PR 58.)"""
     precision = lax.Precision.HIGHEST if u.dtype == F32 else None
     pick = lambda m: jnp.einsum("...d,tdr->...tr", u, jnp.asarray(m, u.dtype),
                                 precision=precision,
                                 preferred_element_type=F32)
+    sel_a, sel_b = _selectors(u.shape[-1])
     return pick(sel_a) * pick(sel_b)
 
 
@@ -158,7 +207,7 @@ def _step(q, k, v, log_gate, s, z, active, interpret):
     pq = (_phi(q) / D).reshape(B, G, rep, tiles, lanes).transpose(
         0, 1, 3, 2, 4)
     pq = jnp.pad(pq, ((0, 0),) * 3 + ((0, pad), (0, 0)))
-    pk = (_phi(k) * _selectors(D)[2][:, 0])[:, :, :, None]
+    pk = (_phi(k) * _weights(D)[:, 0])[:, :, :, None]
     cell = lambda b, g, *_: (b, g, 0, 0, 0)
     whole = lambda rows: pl.BlockSpec((None, None, tiles, rows, lanes), cell)
     column = pl.BlockSpec((None, None, D, 1), lambda b, g, *_: (b, g, 0, 0))
@@ -191,9 +240,10 @@ def power_retention_step(q, k, v, log_gate, s, z, active):
     return _step(q, k, v, log_gate, s, z, active, should_interpret())
 
 
-def _chunk_kernel(len_ref, x_ref, v_ref, vdt_ref, col_ref, row_ref, a_ref,
-                  b_ref, w_ref, s_in_ref, z_in_ref, o_ref, s_out_ref,
-                  z_out_ref, s_scr, z_scr, num_scr, den_scr, *, block, rep):
+def _chunk_kernel(len_ref, *refs, block, rep):
+    (*select, x_ref, v_ref, vdt_ref, col_ref, row_ref, w_ref, s_in_ref,
+     z_in_ref, o_ref, s_out_ref, z_out_ref, s_scr, z_scr, num_scr,
+     den_scr) = refs
     c = pl.program_id(2)
 
     @pl.when(c == 0)
@@ -201,7 +251,7 @@ def _chunk_kernel(len_ref, x_ref, v_ref, vdt_ref, col_ref, row_ref, a_ref,
         s_scr[...] = s_in_ref[...]
         z_scr[...] = z_in_ref[...]
 
-    tiles, d, _ = s_scr.shape
+    tiles, d, lanes = s_scr.shape
     x, v, vdt = x_ref[...], v_ref[...], vdt_ref[...]
     mx = x.dtype
     # float32 operands are multiplied as float32 (a TPU's default would
@@ -218,10 +268,29 @@ def _chunk_kernel(len_ref, x_ref, v_ref, vdt_ref, col_ref, row_ref, a_ref,
     through = jnp.exp(jnp.min(row, axis=1, keepdims=True))  # the whole block
     num_scr[...] = jnp.zeros(num_scr.shape, F32)
     den_scr[...] = jnp.zeros(den_scr.shape, F32)
+    if select:
+        # a head narrower than a lane row: two selections pick each pair's
+        # values out of it
+        a_ref, b_ref = select
+        pairs = lambda t: dot(x, a_ref[t]) * dot(x, b_ref[t])
+    else:
+        # a head of whole lane rows: row r of the fold is x times x rolled
+        # by r along the lanes, on the rotate and vector units
+        xf = x.astype(F32)
+        turn = lambda u, r: pltpu.roll(u, (d - r) % d, 1)
+        if mx.itemsize == 2:
+            # a rotation moves 32-bit lanes: two rows of x go as one
+            packed = pltpu.bitcast(x, jnp.uint32)
+            rolled = lambda r: pltpu.bitcast(turn(packed, r), mx).astype(F32)
+        else:
+            rolled = functools.partial(turn, xf)
+        pairs = lambda t: jnp.concatenate(
+            [xf * rolled(t * (lanes // d) + i) for i in range(lanes // d)],
+            axis=1)
 
     def tile(t, _):
         # the pairs of every row of x, the query heads' and then the key's
-        phi = dot(x, a_ref[t]) * dot(x, b_ref[t])         # [rows, lanes]
+        phi = pairs(t)                                    # [rows, lanes]
         s, z = s_scr[t], z_scr[t]
         pq = phi[:q_rows]
         num_scr[...] += rows_t(pq.astype(mx), s.astype(mx))
@@ -293,7 +362,10 @@ def _chunk(q, k, v, log_gate, s, z, real_len, interpret):
         (vb.astype(F32) * to_end[..., None]).swapaxes(-1, -2),
         to_end[..., None, :], jnp.zeros((B, G, nb, _AUG - 1, block), F32)],
         axis=3).astype(q.dtype)
-    sel_a, sel_b, w = _selectors(D)
+    # a head of whole lane rows, a tile whole rows of the fold, makes its
+    # pairs by rotation in the kernel
+    select = [] if D % _LANES == 0 and lanes % D == 0 else [
+        jnp.asarray(m, q.dtype) for m in _selectors(D)]
     at = lambda b, g, c, *_: (b, g, c, 0, 0)
     tokens = lambda rows, cols: pl.BlockSpec((None, None, None, rows, cols),
                                              at)
@@ -305,10 +377,10 @@ def _chunk(q, k, v, log_gate, s, z, real_len, interpret):
         functools.partial(_chunk_kernel, block=block, rep=rep),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(B, G, nb),
-            in_specs=[tokens((rep + 1) * block, D), tokens(block, D),
-                      tokens(D + _AUG, block), tokens(block, 1),
-                      tokens(1, block), fixed(D), fixed(D), fixed(1),
-                      state(D), state(1)],
+            in_specs=[fixed(D)] * len(select) + [
+                tokens((rep + 1) * block, D), tokens(block, D),
+                tokens(D + _AUG, block), tokens(block, 1), tokens(1, block),
+                fixed(1), state(D), state(1)],
             out_specs=[tokens(rep * block, D), state(D), state(1)],
             scratch_shapes=[pltpu.VMEM((tiles, D, lanes), F32),
                             pltpu.VMEM((tiles, 1, lanes), F32),
@@ -321,10 +393,9 @@ def _chunk(q, k, v, log_gate, s, z, real_len, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM),
         name="power_retention_chunk", interpret=interpret,
-    )(jnp.reshape(real_len, (1,)).astype(jnp.int32),
+    )(jnp.reshape(real_len, (1,)).astype(jnp.int32), *select,
       jnp.concatenate([qb, kb], axis=3), vb, vdt, cum[..., None],
-      cum[..., None, :], jnp.asarray(sel_a, q.dtype),
-      jnp.asarray(sel_b, q.dtype), jnp.asarray(w), s, z)
+      cum[..., None, :], jnp.asarray(_weights(D)), s, z)
     o = o.reshape(B, G, nb, rep, block, D).transpose(0, 2, 4, 1, 3, 5)
     return o.reshape(B, nb * block, H, D)[:, :S], s, z
 

@@ -89,6 +89,68 @@ def test_the_state_is_the_symmetric_half_and_its_shape_has_one_source():
         transformer.state_shapes(cfg, transformer.ATTENTION, 1)
 
 
+@pytest.mark.parametrize("D", [16, 32, 64, 128])
+def test_the_fold_holds_every_pair_once_by_weight(D):
+    """The layout alone, no kernel: lane ``r D + a`` is the pair ``(a, (a +
+    r) mod D)``, ``r = 0 .. D / 2``. Every pair of two values weighs 2 on
+    the key side in all (once at 2, or twice at 1 where ``r = D / 2``), every
+    square 1, the padding 0 with zero pairs, and the weighted product of two
+    heads' pairs is ``(q . k)^2`` exactly."""
+    tiles, lanes = pr.layout(D)
+    half, fold = D * (D + 1) // 2, D * (D // 2 + 1)
+    assert half <= fold <= tiles * lanes < half + 128 and lanes % 128 == 0
+    if D == 128:
+        assert (tiles, lanes) == (13, 640) and fold == tiles * lanes
+    a, b = pr._fold_index(D)
+    w = pr._weights(D).reshape(-1)
+    assert a.size == fold and not w[fold:].any()
+    total = np.zeros((D, D))
+    np.add.at(total, (np.minimum(a, b), np.maximum(a, b)), w[:fold])
+    assert np.array_equal(total, np.triu(2 * np.ones((D, D))) - np.eye(D))
+    assert set(w[:D]) == {1.0} and set(w[D * (D // 2):fold]) == {1.0}
+    rng = np.random.default_rng(D)
+    q, k = rng.integers(-3, 4, (2, 5, D)).astype(np.float32)
+    pq, pk = (np.asarray(pr._phi(jnp.asarray(u))).reshape(5, -1)
+              for u in (q, k))
+    assert np.array_equal(pq[:, :fold], q[:, a] * q[:, b])
+    assert not pq[:, fold:].any() and not pk[:, fold:].any()
+    assert np.array_equal((pq * w * pk).sum(-1), (q * k).sum(-1) ** 2)
+    for m, index in zip(pr._selectors(D), (a, b)):   # what _phi picks by
+        m = m.transpose(1, 0, 2).reshape(D, -1)
+        assert np.array_equal(q @ m[:, :fold], q[:, index])
+        assert not m[:, fold:].any()
+
+
+@pytest.mark.parametrize("D,selectors", [(128, 0), (32, 2)],
+                         ids=["d128_rotates", "d32_selects"])
+def test_a_head_of_whole_lane_rows_takes_no_selection_operand(D, selectors):
+    """Nothing runs: the chunk kernel's call, read from the jaxpr. At the
+    published head size no operand has a selector's shape ``[tiles, D,
+    lanes]``; a narrower head takes two."""
+    G, S = 1, 128
+    tiles, lanes = pr.layout(D)
+    shape = jax.ShapeDtypeStruct
+    rows = lambda heads: shape((1, S, heads, D), jnp.bfloat16)
+    state = [shape(s, jnp.float32)
+             for s in pr.state_shapes(1, G, D).values()]
+    jaxpr = jax.make_jaxpr(pr.power_retention_chunk)(
+        rows(5), rows(G), rows(G), shape((1, S, G), jnp.float32), *state,
+        shape((), jnp.int32))
+
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    call, = calls(jaxpr.jaxpr)
+    assert call.params["name"] == "power_retention_chunk"
+    shapes = [v.aval.shape for v in call.invars]
+    assert shapes.count((tiles, D, lanes)) == selectors
+    assert (tiles, 1, lanes) in shapes                 # the weights stay
+
+
 def test_the_uncached_forward_matches_the_reference(toy):
     cfg, params, tokens, want = toy
     assert rel_err(forward(cfg, params, tokens), want) < TOL
@@ -254,9 +316,9 @@ def faulty(monkeypatch, fault):
         monkeypatch.setattr(transformer, "power_retention_chunk", regrouped)
     if fault == "sqrt2_dropped":
         # a pair a < b counted once, not for (b, a) too
-        real = pr._selectors
-        monkeypatch.setattr(pr, "_selectors", lambda d: real(d)[:2] + (
-            np.minimum(real(d)[2], 1.0),))
+        real = pr._weights
+        monkeypatch.setattr(pr, "_weights",
+                            lambda d: np.minimum(real(d), 1.0))
         jax.clear_caches()  # the kernels' programs were traced with the 2
 
 
