@@ -1508,6 +1508,284 @@ def glm_task(seed: int, control: bool = True) -> dict:
     return {**out, **device_report()}
 
 
+def ssm_timing(cfg, seed: int, rows: int, calls: int = 20) -> dict:
+    """The two state-space kernels ALONE at the cell's shapes, in
+    milliseconds a call behind a warm-up: the step over ``rows`` slots'
+    states of one layer (in place), the chunked scan of one row's 512
+    tokens; and the step's bytes (a row's scan state read and written) over
+    its time, as a share of the memory's bandwidth."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import ssm
+
+    sizes = cfg.ssm
+    H, P, G, N = sizes.heads, sizes.head_dim, sizes.groups, sizes.state
+    ks = jax.random.split(jax.random.key(seed & 0x7FFFFFFF), 6)
+    normal = jax.random.normal
+    a = -jnp.exp(jax.random.uniform(ks[4], (H,), minval=0.0, maxval=2.5))
+    d = jnp.ones((H,), jnp.float32)
+
+    def ms(fn, state, *args):
+        out = fn(*args, state)
+        jax.block_until_ready(out)
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args, out[1])
+        jax.block_until_ready(out)
+        return round((time.perf_counter() - t0) / calls * 1e3, 4)
+
+    step = jax.jit(lambda x, dt, bm, cm, active, state: ssm.ssd_step(
+        x, dt, a, bm, cm, d, state, active, sizes, "pallas"),
+        donate_argnums=(5,))
+    step_ms = ms(
+        step, jnp.zeros(ssm.state_shapes(rows, sizes)["ssm"], jnp.float32),
+        normal(ks[0], (rows, H, P), jnp.bfloat16),
+        jax.nn.softplus(normal(ks[1], (rows, H)) - 2.0),
+        normal(ks[2], (rows, G, N), jnp.bfloat16),
+        normal(ks[3], (rows, G, N), jnp.bfloat16),
+        jnp.ones((rows,), jnp.int32))
+    chunk = jax.jit(lambda x, dt, bm, cm, state: ssm.ssd_chunk(
+        x, dt, a, bm, cm, d, state, sizes, jnp.int32(500), "pallas"))
+    chunk_ms = ms(
+        chunk, jnp.zeros(ssm.state_shapes(1, sizes)["ssm"], jnp.float32),
+        normal(ks[0], (1, 512, H, P), jnp.bfloat16),
+        jax.nn.softplus(normal(ks[1], (1, 512, H)) - 2.0),
+        normal(ks[2], (1, 512, G, N), jnp.bfloat16),
+        normal(ks[3], (1, 512, G, N), jnp.bfloat16))
+    moved = rows * 2 * H * P * N * 4
+    return {"rows": rows, "step_ms": step_ms, "chunk_ms": chunk_ms,
+            "step_bandwidth_share": round(moved / (step_ms * 1e-3) / 819e9,
+                                          3)}
+
+
+def nemotron_task(seed: int, control: bool = True) -> dict:
+    """The Nemotron-3-Nano-width checks (ISSUE 59). The two state-space
+    kernels in float32 at the published head sizes against their
+    ``jax.numpy`` form, across a block boundary with padding and from a
+    state that is not zero (1e-3, as the other state kinds' kernels), and
+    alone at the cell's shapes, timed (``ssm_timing``). Then the system in
+    bf16 at the benchmark's configuration (nine layers MEMEM*EME, 64 of 128
+    ``relu^2`` experts held, half the vocabulary): a 1100-token prompt
+    (eight blocks of 128 and 76 tokens; two chunks of 512 and 76) and a
+    300-token one whose chunk takes the first's decode row along, through
+    the paged chunks, fused turns and 6 steps on states and pages, against
+    ``perfbench/reference/nemotron_h.py`` GIVEN the system's routes; two
+    slots hold no sequence and their states must come back bitwise, every
+    page no table names is filled with NaN. Then (``control``) the float8
+    control through the harness's own comparison under the limits of
+    ``cells/nemotron3_nano_reason.json``, which must refuse it."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from perfbench.lib import configs, weights
+    from perfbench.lib import manifest as manifest_lib
+    from perfbench.reference import nemotron_h as ref
+    from ray_tpu.models.decode import (StepRows, init_paged_caches,
+                                       paged_decode_step,
+                                       paged_prefill_into_slot)
+    from ray_tpu.models.transformer import MAMBA
+    from ray_tpu.ops import ssm
+
+    require_chip()
+    manifest = manifest_lib.load()
+    hp = manifest_lib.config(manifest, "nemotron3_nano_30b_a3b_l9")
+    cell = manifest_lib.read_json(manifest, "cells", "nemotron3_nano_reason")
+    cfg = configs.build_program_config(*configs.program_overrides(
+        hp, manifest_lib.read_json_from_bench("families", hp["model_type"])))
+
+    def rel(got, want):
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        return {"max": float(np.abs(got - want).max() / np.abs(want).max()),
+                "rms": float(np.sqrt(((got - want) ** 2).mean()
+                                     / (want ** 2).mean()))}
+
+    # ---- the kernels, float32, against their jax.numpy form
+    sizes = cfg.ssm
+    H, P, G, N = sizes.heads, sizes.head_dim, sizes.groups, sizes.state
+    ks = jax.random.split(jax.random.key(seed & 0x7FFFFFFF), 8)
+    n, real = 600, 555
+    case = dict(
+        x=jax.random.normal(ks[0], (2, n, H, P)),
+        bm=jax.random.normal(ks[1], (2, n, G, N)) * 0.3,
+        cm=jax.random.normal(ks[2], (2, n, G, N)) * 0.3,
+        dt=jax.nn.softplus(jax.random.normal(ks[3], (2, n, H)) - 2.0),
+        a=-jnp.exp(jax.random.uniform(ks[4], (H,), minval=0.0, maxval=2.5)),
+        d=jax.random.normal(ks[5], (H,)),
+        state=jax.random.normal(ks[6], ssm.state_shapes(2, sizes)["ssm"]))
+
+    def chunk(impl):
+        with jax.default_matmul_precision("highest"):
+            return ssm.ssd_chunk(case["x"], case["dt"], case["a"],
+                                 case["bm"], case["cm"], case["d"],
+                                 case["state"], sizes, jnp.int32(real), impl)
+
+    def step(impl):
+        with jax.default_matmul_precision("highest"):
+            return ssm.ssd_step(
+                case["x"][:, 0], case["dt"][:, 0], case["a"],
+                case["bm"][:, 0], case["cm"][:, 0], case["d"], case["state"],
+                jnp.asarray([1, 0], jnp.int32), sizes, impl)
+
+    (ky, ks_), (ry, rs) = chunk("pallas"), chunk("reference")
+    (sy, ss), (qy, qs) = step("pallas"), step("reference")
+    kernels = {"chunk_y": rel(ky[:, :real], ry[:, :real])["max"],
+               "chunk_state": rel(ks_, rs)["max"],
+               "step_y": rel(sy[:1], qy[:1])["max"],
+               "step_state": rel(ss, qs)["max"],
+               "idle_row_bitwise": bool(
+                   (np.asarray(ss[1]) == np.asarray(case["state"][1])).all())}
+    if max(v for k, v in kernels.items() if k != "idle_row_bitwise") > 1e-3 \
+            or not kernels["idle_row_bitwise"]:
+        raise RuntimeError(f"nemotron: the state-space kernels: {kernels}")
+    del case, ky, ks_, ry, rs, ss, qs
+    out = {"kernels": kernels,
+           "ssm_timing": ssm_timing(cfg, seed,
+                                    int(cell["deployment"]["slots"]))}
+
+    # ---- the system, bf16, paged chunks, fused turns and steps
+    L, k = cfg.expert_layers, cfg.moe_top_k
+    params = weights.make_params(cfg, seed)
+    S, C, T, Pg = 4, 512, 16, 96
+    caches = init_paged_caches(cfg, S * Pg + 1 + 64, T, Pg, slots=S)
+    rng = np.random.default_rng(seed)
+    prompts = {0: rng.integers(1, cfg.vocab_size, 1100).tolist(),
+               2: rng.integers(1, cfg.vocab_size, 300).tolist()}
+    tables = np.zeros((S, Pg), np.int32)
+    for s in prompts:
+        tables[s] = 1 + s * Pg + np.arange(Pg)
+    loose = np.setdiff1d(np.arange(1, S * Pg + 65), tables)
+    idle = jnp.asarray([1, 3])
+
+    def spoil(c, kind):
+        if kind == MAMBA:   # slots without a sequence: must stay bitwise
+            return dataclasses.replace(c, conv=c.conv.at[idle].set(7.0),
+                                       ssm=c.ssm.at[idle].set(7.0))
+        if c is None:
+            return c
+        return dataclasses.replace(c, k=c.k.at[loose].set(jnp.nan),
+                                   v=c.v.at[loose].set(jnp.nan))
+
+    caches = [spoil(c, kind) for c, kind in zip(caches, cfg.kinds)]
+    both = jnp.asarray(tables)
+    prefill = jax.jit(lambda *a: paged_prefill_into_slot(
+        cfg, *a, attn="pallas", moe_info=True, logits=True),
+        donate_argnums=(6,))
+    step = jax.jit(lambda *a: paged_decode_step(
+        cfg, *a, attn="pallas", moe_info=True, logits=True),
+        donate_argnums=(6,))
+    ids = jnp.zeros(S, jnp.int32)
+    got = {s: [] for s in prompts}
+    taken = {s: [] for s in prompts}
+    fed = {s: [] for s in prompts}
+    active = np.zeros(S, np.int32)
+    cursors = np.zeros(S, np.int32)
+    greedy = (np.zeros(S, np.float32), np.zeros(S, np.uint32))
+    held = left_out = live_rows = 0
+
+    def feed():
+        for s in np.flatnonzero(active):
+            fed[s].append(int(got[s][-1].argmax()))
+
+    def count(moe):
+        return (int(np.asarray(moe["counts"]).sum()),
+                int(np.asarray(moe["left_out"]).sum()))
+
+    for s, prompt in prompts.items():
+        for c0 in range(0, len(prompt), C):
+            part = prompt[c0:c0 + C]
+            feed()
+            ids, caches, moe, logits = prefill(
+                params, jnp.asarray([part + [0] * (C - len(part))],
+                                    jnp.int32),
+                np.int32(len(part)), np.int32(c0), both[s], both[s], caches,
+                ids, np.int32(s if c0 + C >= len(prompt) else -1),
+                np.float32(0), np.uint32(0),
+                StepRows(active.copy(), cursors.copy(), both, both, *greedy),
+                np.int32(s))
+            routes = np.asarray(moe["routes"])[:, 0]
+            taken[s].append(routes[:, :len(part)])
+            here, away = count(moe)
+            held, left_out = held + here, left_out + away
+            live_rows += len(part) + int(active.sum())
+            for row in np.flatnonzero(active):
+                got[row].append(np.asarray(logits[1 + row], np.float32))
+                taken[row].append(routes[:, C + row:C + row + 1])
+            cursors = cursors + active
+            cursors[s] = c0 + len(part)
+        got[s].append(np.asarray(logits[0], np.float32))
+        active[s] = 1
+    for _ in range(6):
+        feed()
+        ids, caches, moe, logits = step(
+            params, ids, jnp.asarray(active), cursors, both, both, caches,
+            *greedy)
+        cursors = cursors + active
+        here, away = count(moe)
+        held, left_out = held + here, left_out + away
+        live_rows += len(prompts)
+        for s in prompts:
+            got[s].append(np.asarray(logits[s], np.float32))
+            taken[s].append(np.asarray(moe["routes"])[:, s])
+
+    errs, flips = {}, {}
+    for s, prompt in prompts.items():
+        tokens = jnp.asarray([prompt + fed[s]], jnp.int32)
+        first = len(prompt) - 1
+        routes = np.concatenate(taken[s], axis=1)[:, None]
+        want, scores = ref.forward_and_router(params, tokens, hp,
+                                              jnp.asarray(routes))
+        errs[s] = rel(np.stack(got[s][:-1]), want[0][first:-1])
+        # the share of rows whose biased top 6 in float32 is not the
+        # program's in bf16
+        bias = np.stack([np.asarray(
+            params["blocks"][f"p{i}"]["mlp"]["e_bias"][0], np.float32)
+            for i, symbol in enumerate(cfg.layer_pattern) if symbol == "E"]
+        )[:, None, None]
+        own = np.sort(np.asarray(jax.lax.top_k(
+            np.asarray(scores) + bias, k)[1]), -1)
+        flips[s] = float((own != np.sort(routes, -1)).any(-1).mean())
+    states_kept = all(
+        bool((np.asarray(state)[np.asarray(idle)] == 7.0).all())
+        for c, kind in zip(caches, cfg.kinds) if kind == MAMBA
+        for state in (c.conv, c.ssm))
+    poisoned = all(bool(jnp.isnan(c.k[loose]).all()) for c, kind in zip(
+        caches, cfg.kinds) if kind == "attention")
+    out.update(given_err=errs, flip_share=flips, routes_held=held,
+               routes_left_out=left_out,
+               live_rows_x_k_x_layers=live_rows * k * L)
+    bad = []
+    if not all(np.isfinite(g).all() for rows in got.values() for g in rows):
+        bad.append("a logit is not finite: a page no table names was read")
+    if not states_kept:
+        bad.append("an idle slot's state was touched")
+    if not poisoned:
+        bad.append("a page no table names was written")
+    if held + left_out != live_rows * k * L:
+        bad.append("a row was dropped or a dead row counted")
+    if not 0.35 < held / (held + left_out) < 0.65:
+        bad.append("the held experts' share of the routes is far from a half")
+    if max(e["max"] for e in errs.values()) > 0.08 \
+            or max(e["rms"] for e in errs.values()) > 0.06:
+        bad.append("error given the routes above the dense cells' "
+                   "tolerance")
+    if bad:
+        raise RuntimeError(f"nemotron: {bad}: {out}")
+    del caches
+    if control:
+        seen = out["float8_control"] = float8_control(
+            cfg, hp, params, seed, ref, "nemotron_h",
+            "nemotron3_nano_reason")
+        if seen["checks"]["reference_logits"] \
+                and seen["checks"]["reference_logits_given_choices"]:
+            raise RuntimeError("nemotron: the float8 control passes both "
+                               f"comparisons of logits: {seen}")
+    return {**out, **device_report()}
+
+
 def latent_timing(cfg, params, seed: int) -> dict:
     """A 512 chunk's latent attention alone, one layer, at contexts of 8k,
     20k and 55k of one slot's table (the cell's 4128 pages): ABSORBED (what
@@ -1952,6 +2230,15 @@ def glm_phase(seed: int) -> None:
     emit("glm", seconds=round(time.perf_counter() - t0, 1), **out)
 
 
+def nemotron_phase(seed: int) -> None:
+    import ray_tpu
+
+    t0 = time.perf_counter()
+    out = ray_tpu.get(
+        ray_tpu.remote(num_tpus=1)(nemotron_task).remote(seed), timeout=2400)
+    emit("nemotron", seconds=round(time.perf_counter() - t0, 1), **out)
+
+
 def serve_phase(seed: int) -> None:
     import ray_tpu
     import ray_tpu.serve as serve
@@ -2068,6 +2355,7 @@ def one_chip(seed: int) -> dict:
     mellum_phase(seed)
     keye_phase(seed)
     glm_phase(seed)
+    nemotron_phase(seed)
     serve_phase(seed)
     return out["device"]
 

@@ -21,4 +21,6 @@ from ray_tpu.models.presets import (  # noqa: F401
     glm_moe_lite_debug,
     keye_debug,
     mellum_debug,
+    nemotron_h,
+    nemotron_h_debug,
 )

@@ -19,12 +19,14 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models.transformer import (ATTENTION, INDEXED, LATENT, LINEAR,
-                                        OWN_PAGE_TOKENS, RETENTION,
+                                        MAMBA, NONE, OWN_PAGE_TOKENS,
+                                        RETENTION,
                                         SLIDING, SPARSE, STATE_KINDS,
                                         STATE_MIXERS,
                                         TransformerConfig, _gated_out, _mlp,
                                         _norm, _qkv, _residual, embed,
-                                        final_hidden, forward, indexed_mix,
+                                        final_hidden, forward, holds_page,
+                                        indexed_mix,
                                         indexed_project, latent_finish,
                                         latent_mix, latent_project,
                                         layer_params,
@@ -33,6 +35,7 @@ from ray_tpu.models.transformer import (ATTENTION, INDEXED, LATENT, LINEAR,
                                         stacked_mlp, state_shapes,
                                         write_pages, written_pages)
 from ray_tpu.ops.latent_attention import pool_width
+from ray_tpu.ops.moe import held_index
 from ray_tpu.ops.paged_attention import paged_attention
 from ray_tpu.ops.sparse_attention import check_pool, update_page_means
 
@@ -110,7 +113,23 @@ class RetentionState:
         return {"s": self.s, "z": self.z}
 
 
-_STATES = {LINEAR: LinearState, RETENTION: RetentionState}
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class MambaState:
+    """What a 'mamba2' layer keeps: the convolution's last inputs conv
+    [rows, taps - 1, width] and the scan's state ssm [rows, G, N, lanes],
+    float32 (``ops.ssm.state_shapes``), one row a sequence or a slot as
+    ``LinearState`` has it."""
+
+    conv: Any
+    ssm: Any
+    length: Any = None
+
+    def arrays(self):
+        return {"conv": self.conv, "ssm": self.ssm}
+
+
+_STATES = {LINEAR: LinearState, RETENTION: RetentionState, MAMBA: MambaState}
 
 
 def init_state(cfg: TransformerConfig, kind: str, rows: int, length=None):
@@ -123,11 +142,14 @@ def init_state(cfg: TransformerConfig, kind: str, rows: int, length=None):
 
 def init_caches(cfg: TransformerConfig, batch: int, max_len: int,
                 dtype=None) -> List[Any]:
-    """A contiguous cache a layer, by the layer's kind."""
+    """A contiguous cache a layer, by the layer's kind (None for a layer
+    without a mixer)."""
     dtype = dtype or cfg.dtype
     zero = jnp.zeros((), jnp.int32)
 
     def one(kind):
+        if kind == NONE:
+            return None
         if kind in (ATTENTION, SLIDING):
             # a window layer's contiguous cache keeps every position too:
             # the window is in the mask (the serving pool is what forgets)
@@ -156,11 +178,17 @@ def init_caches(cfg: TransformerConfig, batch: int, max_len: int,
     return [one(kind) for kind in cfg.kinds]
 
 
+def _length(caches):
+    """The tokens the contiguous caches hold: every layer that keeps one
+    counts the same."""
+    return next(c.length for c in caches if c is not None)
+
+
 def prefill(cfg: TransformerConfig, params, tokens, caches):
     """Run the prompt through the model, filling caches.
     Returns (logits_last [B, vocab], caches). The head sees the last
     position alone: a long prompt's logits are never made whole."""
-    positions = jnp.arange(tokens.shape[1])[None, :] + caches[0].length
+    positions = jnp.arange(tokens.shape[1])[None, :] + _length(caches)
     hidden, caches = forward(cfg, params, tokens, positions=positions,
                              kv_caches=caches, return_hidden=True)
     return project(cfg, params, hidden[:, -1:])[:, 0], caches
@@ -168,7 +196,7 @@ def prefill(cfg: TransformerConfig, params, tokens, caches):
 
 def decode_step(cfg: TransformerConfig, params, token, caches):
     """One token step. token: [B, 1]. Returns (logits [B, vocab], caches)."""
-    positions = caches[0].length + jnp.zeros((token.shape[0], 1), jnp.int32)
+    positions = _length(caches) + jnp.zeros((token.shape[0], 1), jnp.int32)
     logits, caches = forward(cfg, params, token, positions=positions,
                              kv_caches=caches)
     return logits[:, -1], caches
@@ -491,6 +519,8 @@ def init_paged_caches(cfg: TransformerConfig, num_pages: int,
     dtype = dtype or cfg.dtype
 
     def one(kind):
+        if kind == NONE:
+            return None
         if kind in STATE_KINDS:
             return init_state(cfg, kind, slots)
         if kind == INDEXED:
@@ -578,7 +608,7 @@ def _unless_idle(group: _Rows, several: bool, call, kept):
                                    made), kept), kept)
 
 
-def _mix_states(cfg, mix, group: _Rows, rows, state, several: bool):
+def _mix_states(cfg, mix, p, group: _Rows, rows, state, several: bool):
     """One group's rows (``rows``: what the kind's projection made of them)
     through a state layer's ``state`` ({name: [slots, ...]}, every slot's)
     -> (o, state). A chunk's group cuts its slot's states out and puts them
@@ -586,11 +616,12 @@ def _mix_states(cfg, mix, group: _Rows, rows, state, several: bool):
     if group.slot is None:
         return _unless_idle(
             group, several,
-            lambda state: mix(cfg, rows, state, active=group.active), state)
+            lambda state: mix(cfg, p, rows, state, active=group.active),
+            state)
     own = {n: jnp.where(group.positions[0, 0] == 0, 0.0,
                         lax.dynamic_slice_in_dim(s, group.slot, 1, axis=0))
            for n, s in state.items()}
-    o, own = mix(cfg, rows, own, real_len=group.real_len)
+    o, own = mix(cfg, p, rows, own, real_len=group.real_len)
     return o, {n: lax.dynamic_update_slice_in_dim(state[n], s, group.slot,
                                                   axis=0)
                for n, s in own.items()}
@@ -666,86 +697,93 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
     rope = rope_table(cfg)
     if cfg.holds_pages:
         T = next(c.k.shape[1] for c, kind in zip(caches, cfg.kinds)
-                 if kind not in STATE_KINDS)
+                 if holds_page(kind))
         # the pages the rows land on, a pool at a time (one, but for a
         # model with window layers)
         pages = {pool: batch([written_pages(pool_tables(g.write_tables, pool),
                                             g.positions, T) for g in groups])
                  for pool in dict.fromkeys(
                      pool_of(kind) for kind in cfg.kinds
-                     if kind not in STATE_KINDS)}
+                     if holds_page(kind))}
         offs = positions % T
     new_caches = []
     moe_layers = []
     for i, kind in enumerate(cfg.kinds):
         p = layer_params(cfg, params, i)
         c = caches[i]
-        ap = p["attn"]
-        h = _norm(cfg, p["ln1"], x)
-        if kind in STATE_KINDS:
-            project, mix, finish = STATE_MIXERS[kind]
-            state, outs = c.arrays(), []
-            for g, *rows in zip(groups, *map(
-                    split, project(cfg, ap, h, positions))):
-                o, state = _mix_states(cfg, mix, g, rows, state, several)
-                outs.append(o)
-            a = finish(cfg, ap, h, batch(outs))
-            new_caches.append(_STATES[kind](**state))
-        elif kind == INDEXED:
-            attending, new = indexed_project(cfg, ap, h, rope, positions)
-            pools = tuple(
-                write_pages(pool, made, pages[pool_of(kind)], offs)
-                for pool, made in zip((c.k, c.v, c.ik), new))
-            outs, chosen = zip(*(_unless_idle(g, several, lambda _: (
-                indexed_mix(cfg, rows, pools,
-                            pool_tables(g.read_tables, kind), g.positions,
-                            g.lengths, impl=impl), ()), ())[0]
-                for g, *rows in zip(groups, *map(split, attending))))
-            if taps is not None:
-                taps.extend(chosen)
-            a = jnp.einsum("bshk,hkd->bsd", batch(outs),
-                           ap["wo"].astype(cfg.dtype))
-            new_caches.append(IndexedPagedKVCache(*pools))
-        elif kind == LATENT:
-            attending, (row,), _ = latent_project(cfg, ap, h, positions)
-            pools = (write_pages(c.ckr, row, pages[pool_of(kind)], offs),)
-            # a call a group, the step's under one name and the chunk's
-            # under another (``latent_mix``); absorbed either way
-            outs = [_unless_idle(g, several, lambda _: (
-                latent_mix(cfg, ap, rows, pools,
-                           pool_tables(g.read_tables, kind), g.lengths,
-                           impl=impl), ()), ())[0]
-                for g, *rows in zip(groups, *map(split, attending))]
-            a = latent_finish(cfg, ap, batch(outs))
-            new_caches.append(LatentPagedKVCache(*pools))
+        if kind == NONE:  # a layer that is a feed-forward alone
+            new_caches.append(None)
         else:
-            q, k, v = _qkv(cfg, ap, h, None if kind == SPARSE else rope,
-                           positions, kind)
-            ck = write_pages(c.k, k, pages[pool_of(kind)], offs)
-            cv = write_pages(c.v, v, pages[pool_of(kind)], offs)
-            if kind == SPARSE:
-                pools = (ck, cv, update_page_means(c.means, ck, *(
-                    (g.write_tables, g.positions) for g in groups)))
+            ap = p["attn"]
+            h = _norm(cfg, p["ln1"], x)
+            if kind in STATE_KINDS:
+                project, mix, finish = STATE_MIXERS[kind]
+                state, outs = c.arrays(), []
+                for g, *rows in zip(groups, *map(
+                        split, project(cfg, ap, h, positions))):
+                    o, state = _mix_states(cfg, mix, ap, g, rows, state,
+                                           several)
+                    outs.append(o)
+                a = finish(cfg, ap, h, batch(outs))
+                new_caches.append(_STATES[kind](**state))
+            elif kind == INDEXED:
+                attending, new = indexed_project(cfg, ap, h, rope, positions)
+                pools = tuple(
+                    write_pages(pool, made, pages[pool_of(kind)], offs)
+                    for pool, made in zip((c.k, c.v, c.ik), new))
                 outs, chosen = zip(*(_unless_idle(g, several, lambda _: (
-                    sparse_mix(cfg, q_g, pools, g.read_tables, g.positions,
-                               g.lengths, impl=impl), ()), ())[0]
-                    for g, q_g in zip(groups, split(q))))
+                    indexed_mix(cfg, rows, pools,
+                                pool_tables(g.read_tables, kind), g.positions,
+                                g.lengths, impl=impl), ()), ())[0]
+                    for g, *rows in zip(groups, *map(split, attending))))
                 if taps is not None:
                     taps.extend(chosen)
-                a = _gated_out(cfg, ap, h, batch(outs))
-                new_caches.append(SparsePagedKVCache(*pools))
+                a = jnp.einsum("bshk,hkd->bsd", batch(outs),
+                               ap["wo"].astype(cfg.dtype))
+                new_caches.append(IndexedPagedKVCache(*pools))
+            elif kind == LATENT:
+                attending, (row,), _ = latent_project(cfg, ap, h, positions)
+                pools = (write_pages(c.ckr, row, pages[pool_of(kind)], offs),)
+                # a call a group, the step's under one name and the chunk's
+                # under another (``latent_mix``); absorbed either way
+                outs = [_unless_idle(g, several, lambda _: (
+                    latent_mix(cfg, ap, rows, pools,
+                               pool_tables(g.read_tables, kind), g.lengths,
+                               impl=impl), ()), ())[0]
+                    for g, *rows in zip(groups, *map(split, attending))]
+                a = latent_finish(cfg, ap, batch(outs))
+                new_caches.append(LatentPagedKVCache(*pools))
             else:
-                # a window layer's call has a name of its own, so a trace
-                # tells its kernel from the full layers'
-                o = batch([paged_attention(
-                    q_g, ck, cv, pool_tables(g.read_tables, kind), g.lengths,
-                    impl=impl, window=cfg.window(kind),
-                    name=("window_attention" if kind == SLIDING
-                          else "paged_attention"))
-                    for g, q_g in zip(groups, split(q))])
-                a = jnp.einsum("bshk,hkd->bsd", o, ap["wo"].astype(cfg.dtype))
-                new_caches.append(PagedKVCache(k=ck, v=cv))
-        x = _residual(cfg, x, a)
+                q, k, v = _qkv(cfg, ap, h, None if kind == SPARSE else rope,
+                               positions, kind)
+                ck = write_pages(c.k, k, pages[pool_of(kind)], offs)
+                cv = write_pages(c.v, v, pages[pool_of(kind)], offs)
+                if kind == SPARSE:
+                    pools = (ck, cv, update_page_means(c.means, ck, *(
+                        (g.write_tables, g.positions) for g in groups)))
+                    outs, chosen = zip(*(_unless_idle(g, several, lambda _: (
+                        sparse_mix(cfg, q_g, pools, g.read_tables, g.positions,
+                                   g.lengths, impl=impl), ()), ())[0]
+                        for g, q_g in zip(groups, split(q))))
+                    if taps is not None:
+                        taps.extend(chosen)
+                    a = _gated_out(cfg, ap, h, batch(outs))
+                    new_caches.append(SparsePagedKVCache(*pools))
+                else:
+                    # a window layer's call has a name of its own, so a trace
+                    # tells its kernel from the full layers'
+                    o = batch([paged_attention(
+                        q_g, ck, cv, pool_tables(g.read_tables, kind),
+                        g.lengths, impl=impl, window=cfg.window(kind),
+                        name=("window_attention" if kind == SLIDING
+                              else "paged_attention"))
+                        for g, q_g in zip(groups, split(q))])
+                    a = jnp.einsum("bshk,hkd->bsd", o,
+                                   ap["wo"].astype(cfg.dtype))
+                    new_caches.append(PagedKVCache(k=ck, v=cv))
+            x = _residual(cfg, x, a)
+        if cfg.mlp_of(i) == NONE:  # a layer that is a mixer alone
+            continue
         mlp_p, layer = stacked_mlp(cfg, params, p, i)
         m, _, moe = _mlp(cfg, mlp_p, _norm(cfg, p["ln2"], x), valid, layer,
                          cfg.mlp_of(i))
@@ -753,9 +791,10 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
         if moe is not None and several:
             # the experts saw one batch; who sent them which rows is still
             # told a group, as if each group had been a program of its own
+            # (of the experts held here, where the layer is a chip's share)
             moe["counts"] = jnp.stack([
-                jnp.zeros_like(moe["counts"]).at[jnp.where(
-                    live[..., None], routes, moe["counts"].shape[0])].add(
+                jnp.zeros_like(moe["counts"]).at[held_index(
+                    routes, live, cfg.moe_num_experts, cfg.held)].add(
                         1, mode="drop")
                 for live, routes in zip(split(valid), split(moe["routes"]))])
         if moe is not None:  # a leading dense layer has none

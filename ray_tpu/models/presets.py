@@ -210,6 +210,55 @@ def glm_moe_lite_debug(**overrides) -> TransformerConfig:
     return TransformerConfig(**kw)
 
 
+def nemotron_h(**overrides) -> TransformerConfig:
+    """NVIDIA-Nemotron-3-Nano-30B-A3B (``model_type: nemotron_h``) at its
+    published sizes: 52 layers that are ONE sublayer each, as its
+    ``hybrid_override_pattern`` lists them — 23 Mamba-2 mixers ('M': 64
+    heads of 64 over 8 groups, a state of 128 a head, a convolution of 4
+    taps, blocks of 128), 6 attention mixers ('*': 32 query and 2 K/V heads
+    of 128 over a hidden size of 2688, no positional rotation) and 23
+    expert feed-forwards ('E': 128 two-matrix ``relu^2`` experts of 1856,
+    the sigmoid router with a bias in the choice, top 6 renormalized times
+    2.5, beside one shared expert of 3712) — 31.58B parameters. What one
+    chip holds of it (fewer layers, a share of the experts and of the
+    vocabulary) is the caller's ``overrides``."""
+    kw = dict(
+        vocab_size=131072, num_layers=52,
+        layer_pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME",
+        embed_dim=2688, num_heads=32, num_kv_heads=2, head_dim=128,
+        ssm_num_heads=64, ssm_head_dim=64, ssm_groups=8, ssm_state_dim=128,
+        ssm_conv_kernel=4, ssm_chunk=128, mlp="moe", mlp_dim=1856,
+        moe_num_experts=128, moe_top_k=6, moe_renormalize=True,
+        moe_scoring="sigmoid", moe_routed_scale=2.5, moe_shared_experts=1,
+        moe_shared_dim=3712, moe_activation="relu2", max_seq_len=262144,
+        norm="rmsnorm", pos="none", norm_eps=1e-5, tie_embeddings=False,
+    )
+    kw.update(overrides)
+    return TransformerConfig(**kw)
+
+
+def nemotron_h_debug(**overrides) -> TransformerConfig:
+    """Tiny Nemotron-H-shaped config (``nemotron_h``'s mechanisms, for
+    tests): seven layers 'MEM*EME', so a mixer directly before a mixer
+    ('M*'), a mixer before a feed-forward ('ME') and attention before one
+    ('*E') all occur; 8 state-space heads of 8 over 2 groups with a state of
+    16 and blocks of 16 tokens, 4 query and 2 K/V heads of 16 without
+    rotation, 8 ``relu^2`` experts of 48 top 3 times 2.5 beside a shared one
+    of 96."""
+    kw = dict(
+        vocab_size=256, num_layers=7, layer_pattern="MEM*EME", embed_dim=64,
+        num_heads=4, num_kv_heads=2, head_dim=16, ssm_num_heads=8,
+        ssm_head_dim=8, ssm_groups=2, ssm_state_dim=16, ssm_conv_kernel=4,
+        ssm_chunk=16, mlp="moe", mlp_dim=48, moe_num_experts=8, moe_top_k=3,
+        moe_renormalize=True, moe_scoring="sigmoid", moe_routed_scale=2.5,
+        moe_shared_experts=1, moe_shared_dim=96, moe_activation="relu2",
+        max_seq_len=256, norm="rmsnorm", pos="none", norm_eps=1e-5,
+        tie_embeddings=False, dtype=jnp.float32,
+    )
+    kw.update(overrides)
+    return TransformerConfig(**kw)
+
+
 # ---------------------------------------------------------------------------
 # pipeline stage partition (MPMD train.PipelineTrainer shards)
 #

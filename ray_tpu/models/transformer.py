@@ -8,7 +8,9 @@ and XLA/GSPMD inserts all collectives.
 
 Config switches:
   * norm: 'rmsnorm' (LLaMA) | 'layernorm' (GPT-2)
-  * pos:  'rope' (LLaMA) | 'learned' (GPT-2)
+  * pos:  'rope' (LLaMA) | 'learned' (GPT-2) | 'none' (no positional
+          signal in attention at all: the state-space layers of a hybrid
+          carry the order)
   * mlp:  'swiglu' (LLaMA) | 'gelu' (GPT-2) | 'moe' (SwiGLU experts,
           dropless top-k routing over grouped matmuls, ops/moe.py). The
           feed-forward is a pattern over the depth too: the first
@@ -20,7 +22,21 @@ Config switches:
           ``["body"]``). ``moe_scoring`` ('softmax' | 'sigmoid', the latter
           chosen by score + bias), ``moe_routed_scale`` and
           ``moe_shared_experts`` are the router's rule and the dense expert
-          every token takes beside its routed ones.
+          every token takes beside its routed ones (``moe_shared_dim``: its
+          own width where that is not the experts'); ``moe_activation`` is
+          the expert's form ('swiglu', three matrices, or 'relu2', two:
+          ``W_down relu(x W_up)^2``); ``moe_held_first`` / ``moe_held_count``
+          say which experts THIS chip holds of a layer that is shared over
+          several: the router keeps its width, the layer computes its own
+          experts' part.
+  * layer_pattern: a source's string of SUBLAYERS ('M' a Mamba-2 mixer, '*'
+          an attention mixer, 'E' an expert feed-forward;
+          ``LAYER_SYMBOLS``): every layer is then ONE pre-norm sublayer, a
+          mixer or a feed-forward alone, and holds the parameters of what
+          it has and no other. ``cfg.kinds[i]`` and ``cfg.mlp_of(i)`` are
+          the one definition of what layer ``i`` has (``NONE``: it has no
+          such part), read by the parameters, their axes, the scan's period
+          and every forward.
   * GQA via num_kv_heads; tied embeddings via tie_embeddings; head_dim a
     field where it is not embed_dim // num_heads.
   * rope_parameters: a RoPE rule a kind of layer, keyed as the source keys
@@ -58,7 +74,13 @@ Config switches:
     after RoPE, and a cached forward attends the latents themselves, the
     key's and the value's up-projection absorbed into the query and the
     output; ops/latent_attention.py; its pages, in the full layers' pool,
-    hold the two joined in one row a token). 'full_attention' is
+    hold the two joined in one row a token) and 'mamba2' (Mamba-2's
+    state-space mixer: one in-projection to a gate, the joined x, B, C and
+    a step a head; a causal convolution of ``ssm_conv_kernel`` taps over
+    the joined three, a selective scan on a float32 state [N, P] a head
+    with B and C shared by a group's heads, the gate, an RMSNorm a group
+    and ``w_out``; two states a sequence, the convolution's last inputs
+    and the scan's; ops/ssm.py). 'full_attention' is
     taken for 'attention', so a source's ``layer_types`` map straight onto
     ``layer_kinds``. ``rope_scaling``'s ``mrope_section`` turns runs of
     frequency pairs by a position stream each (temporal, height, width:
@@ -103,6 +125,8 @@ from ray_tpu.ops.rotary import (apply_rotary, apply_rotary_at,
                                 rope_frequencies)
 from ray_tpu.ops.sparse_attention import (SparseSizes, sparse_attention,
                                           update_page_means)
+from ray_tpu.ops import ssm
+from ray_tpu.ops.ssm import SsmSizes
 from ray_tpu.parallel.sharding import free_axes, free_parts
 
 ATTENTION, SPARSE, LINEAR = "attention", "minicpm4", "lightning-attn"
@@ -110,13 +134,28 @@ RETENTION = "power-retention"
 SLIDING = "sliding_attention"
 INDEXED = "indexed_attention"
 LATENT = "latent_attention"
-LAYER_KINDS = (ATTENTION, SPARSE, LINEAR, RETENTION, SLIDING, INDEXED, LATENT)
+MAMBA = "mamba2"
+LAYER_KINDS = (ATTENTION, SPARSE, LINEAR, RETENTION, SLIDING, INDEXED, LATENT,
+               MAMBA)
+# what a layer that is ONE sublayer lacks: its kind where it has no mixer,
+# its ``mlp_of`` where it has no feed-forward
+NONE = "none"
+# a source's ``hybrid_override_pattern``, a symbol a layer -> (the layer's
+# mixer, its feed-forward)
+LAYER_SYMBOLS = {"M": (MAMBA, NONE), "*": (ATTENTION, NONE),
+                 "E": (NONE, "moe")}
 # what a source calls the kind this file calls 'attention': its name in
 # ``layer_kinds`` as given and in ``rope_parameters``
 FULL_ATTENTION = "full_attention"
 # the kinds that keep a fixed state a sequence and no keys or values: no
 # page of the serving pool is theirs
-STATE_KINDS = (LINEAR, RETENTION)
+STATE_KINDS = (LINEAR, RETENTION, MAMBA)
+
+
+def holds_page(kind: str) -> bool:
+    """Whether a layer of ``kind`` keeps something a token in the pages of
+    the serving pool (not a state a sequence, and not nothing at all)."""
+    return kind != NONE and kind not in STATE_KINDS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -145,6 +184,26 @@ class TransformerConfig:
     # dense_mlp_dim (a source's first_k_dense_replace / intermediate_size)
     moe_dense_layers: int = 0
     dense_mlp_dim: Optional[int] = None
+    # an expert's form (ops.moe.ACTIVATIONS): 'swiglu' or the two-matrix
+    # 'relu2'; the shared expert has the same form
+    moe_activation: str = "swiglu"
+    # the shared expert's own width (0: moe_shared_experts x mlp_dim)
+    moe_shared_dim: int = 0
+    # the experts this chip holds of every expert layer, ``count`` from
+    # ``first`` on (0: all moe_num_experts, which stays the router's width)
+    moe_held_first: int = 0
+    moe_held_count: int = 0
+    # every layer ONE sublayer, a symbol of LAYER_SYMBOLS each (a source's
+    # hybrid_override_pattern); None: every layer a mixer and a feed-forward
+    layer_pattern: Optional[str] = None
+    # 'mamba2': heads, a head's values, the groups that share B and C, the
+    # state's size, the convolution's taps, a block of the chunked scan
+    ssm_num_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state_dim: int = 0
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 128
     # RMSNorm over ALL H*D (resp. Hkv*D) projected values of q and k, before
     # the split into heads and before RoPE (OLMoE's q_norm / k_norm)
     qk_norm: bool = False
@@ -189,7 +248,7 @@ class TransformerConfig:
     dim_model_base: int = 0
     max_seq_len: int = 2048
     norm: str = "rmsnorm"                     # 'rmsnorm' | 'layernorm'
-    pos: str = "rope"                         # 'rope' | 'learned'
+    pos: str = "rope"                         # 'rope' | 'learned' | 'none'
     mlp: str = "swiglu"                       # 'swiglu' | 'gelu' | 'moe'
     rope_theta: float = 10000.0
     tie_embeddings: bool = True
@@ -218,10 +277,25 @@ class TransformerConfig:
         if self.head_dim is None:
             object.__setattr__(self, "head_dim",
                                self.embed_dim // self.num_heads)
+        pattern = self.layer_pattern
+        if pattern is not None:
+            kinds = tuple(LAYER_SYMBOLS.get(symbol, (None,))[0]
+                          for symbol in pattern)
+            # (``dataclasses.replace`` hands the derived kinds back in)
+            if (len(pattern) != self.num_layers or None in kinds
+                    or self.layer_kinds not in (None, kinds)
+                    or self.moe_dense_layers):
+                raise ValueError(
+                    f"layer_pattern must give one of {sorted(LAYER_SYMBOLS)} "
+                    f"for each of the {self.num_layers} layers, with "
+                    "layer_kinds and moe_dense_layers left alone, got "
+                    f"{pattern!r}")
+            object.__setattr__(self, "layer_kinds", kinds)
         if self.layer_kinds is not None:
             kinds = tuple(ATTENTION if kind == FULL_ATTENTION else kind
                           for kind in self.layer_kinds)
-            if len(kinds) != self.num_layers or set(kinds) - set(LAYER_KINDS):
+            if len(kinds) != self.num_layers or set(kinds) - set(
+                    LAYER_KINDS + ((NONE,) if pattern else ())):
                 raise ValueError(
                     f"layer_kinds must name one of {LAYER_KINDS} for each of "
                     f"the {self.num_layers} layers, got {kinds}")
@@ -257,6 +331,20 @@ class TransformerConfig:
                     f"and latent_v_dim, got {sizes}")
             object.__setattr__(self, "head_dim", self.latent_nope_dim
                                + self.latent_rope_dim)
+        if MAMBA in self.kinds and (
+                min(self.ssm_num_heads, self.ssm_head_dim, self.ssm_state_dim,
+                    self.ssm_groups) < 1
+                or self.ssm_num_heads % self.ssm_groups):
+            raise ValueError(
+                "a 'mamba2' layer needs ssm_num_heads (a multiple of "
+                "ssm_groups), ssm_head_dim and ssm_state_dim")
+        held = (self.moe_held_first, self.moe_held_count)
+        if held != (0, 0) and not (
+                0 <= held[0] and 0 < held[1]
+                and sum(held) <= self.moe_num_experts):
+            raise ValueError(
+                "moe_held_first / moe_held_count name a run of the "
+                f"{self.moe_num_experts} experts, got {held}")
         if self.moe_dense_layers and not (
                 self.mlp == "moe"
                 and 0 < self.moe_dense_layers < self.num_layers
@@ -283,6 +371,8 @@ class TransformerConfig:
     def mlp_of(self, i: int) -> str:
         """Which feed-forward layer ``i`` has: THE definition, read by the
         parameters, their axes and every forward."""
+        if self.layer_pattern is not None:
+            return LAYER_SYMBOLS[self.layer_pattern[i]][1]
         return "swiglu" if i < self.moe_dense_layers else self.mlp
 
     def mlp_width(self, ff: str) -> int:
@@ -294,15 +384,15 @@ class TransformerConfig:
 
     @property
     def expert_layers(self) -> int:
-        return (self.num_layers - self.moe_dense_layers
-                if self.mlp == "moe" else 0)
+        return sum(self.mlp_of(i) == "moe" for i in range(self.num_layers))
 
     @property
     def period(self) -> int:
         """The pattern's period: the layers one scan step applies when
         layers are stacked (1 for a model of one kind), over the layers
         behind the leading ones."""
-        kinds = self.kinds[self.lead_layers:]
+        kinds = [(kind, self.mlp_of(i)) for i, kind in enumerate(self.kinds)
+                 ][self.lead_layers:]
         return next(p for p in range(1, len(kinds) + 1)
                     if len(kinds) % p == 0
                     and all(k == kinds[i % p] for i, k in enumerate(kinds)))
@@ -316,7 +406,24 @@ class TransformerConfig:
     @property
     def holds_pages(self) -> bool:
         """Some layer keeps keys and values: the serving pool has pages."""
-        return any(kind not in STATE_KINDS for kind in self.kinds)
+        return any(holds_page(kind) for kind in self.kinds)
+
+    @property
+    def ssm(self) -> SsmSizes:
+        return SsmSizes(self.ssm_num_heads, self.ssm_head_dim,
+                        self.ssm_groups, self.ssm_state_dim,
+                        self.ssm_conv_kernel, self.ssm_chunk)
+
+    @property
+    def held(self) -> Optional[Tuple[int, int]]:
+        """(first, count) of the experts this chip holds; None: all."""
+        if not self.moe_held_count:
+            return None
+        return self.moe_held_first, self.moe_held_count
+
+    @property
+    def experts_held(self) -> int:
+        return self.moe_held_count or self.moe_num_experts
 
     @property
     def sparse(self) -> SparseSizes:
@@ -369,45 +476,19 @@ def _block_params(cfg: TransformerConfig, key, kind: str = ATTENTION,
                   ff: Optional[str] = None) -> Dict[str, Any]:
     """``ff``: the layer's feed-forward (``cfg.mlp_of``); None: ``cfg.mlp``."""
     ff = ff or cfg.mlp
-    d, h, kvh, hd, f = (cfg.embed_dim, cfg.num_heads, cfg.kv_heads,
-                        cfg.head_dim, cfg.mlp_width(ff))
-    if kind == LINEAR:
-        kvh = h  # a key and a value head for every query head
+    d, f = cfg.embed_dim, cfg.mlp_width(ff)
     ks = jax.random.split(key, 8)
     init = jax.nn.initializers.normal(0.02, cfg.param_dtype)
     out_init = jax.nn.initializers.normal(
         0.02 / math.sqrt(2 * cfg.num_layers), cfg.param_dtype)
-    p: Dict[str, Any] = {
-        "attn": _latent_params(cfg, ks, init, out_init) if kind == LATENT
-        else {
-            "wq": init(ks[0], (d, h, hd)),
-            "wk": init(ks[1], (d, kvh, hd)),
-            "wv": init(ks[2], (d, kvh, hd)),
-            "wo": out_init(ks[3], (h, hd, d)),
-        },
-        "ln1": _norm_params(cfg, d),
-        "ln2": _norm_params(cfg, d),
-    }
-    if cfg.qk_norm:
-        p["attn"]["q_norm"] = jnp.ones((h * hd,), cfg.param_dtype)
-        p["attn"]["k_norm"] = jnp.ones((kvh * hd,), cfg.param_dtype)
-    if cfg.head_qk_norm:
-        p["attn"]["q_norm"] = jnp.ones((hd,), cfg.param_dtype)
-        p["attn"]["k_norm"] = jnp.ones((hd,), cfg.param_dtype)
-    if kind in (SPARSE, LINEAR):
-        p["attn"]["wg"] = init(ks[7], (d, h, hd))   # the output gate
-    if kind == LINEAR:
-        p["attn"]["o_norm"] = jnp.ones((h * hd,), cfg.param_dtype)
-    if kind == RETENTION:
-        p["attn"]["wc"] = init(ks[7], (d, kvh))     # a log-gate a K/V head
-    if kind == INDEXED:
-        hi, di = cfg.indexer.indexer_num_heads, cfg.indexer.indexer_head_dim
-        ki = jax.random.split(ks[7], 3)
-        p["attn"].update(
-            wi_q=init(ki[0], (d, hi, di)), wi_k=init(ki[1], (d, di)),
-            wi_w=init(ki[2], (d, hi)),
-            ik_scale=jnp.ones((di,), cfg.param_dtype),
-            ik_bias=jnp.zeros((di,), cfg.param_dtype))
+    # a layer holds what it has: a mixer under its norm, a feed-forward
+    # under its own, or (every layer but a ``layer_pattern``'s) both
+    p: Dict[str, Any] = {}
+    if kind != NONE:
+        p["attn"] = _mixer_params(cfg, kind, ks, init, out_init)
+        p["ln1"] = _norm_params(cfg, d)
+    if ff != NONE:
+        p["ln2"] = _norm_params(cfg, d)
     if ff == "moe":
         from ray_tpu.ops.moe import SIGMOID, init_moe_params
 
@@ -416,14 +497,15 @@ def _block_params(cfg: TransformerConfig, key, kind: str = ATTENTION,
         p["mlp"] = init_moe_params(
             ks[4], d, f, cfg.moe_num_experts, cfg.param_dtype,
             choice_bias=cfg.moe_scoring == SIGMOID,
-            shared_dim=cfg.moe_shared_experts * f)
+            shared_dim=cfg.moe_shared_dim or cfg.moe_shared_experts * f,
+            activation=cfg.moe_activation, held=cfg.experts_held)
     elif ff == "swiglu":
         p["mlp"] = {
             "w_gate": init(ks[4], (d, f)),
             "w_up": init(ks[5], (d, f)),
             "w_down": out_init(ks[6], (f, d)),
         }
-    else:
+    elif ff != NONE:
         p["mlp"] = {
             "w_in": init(ks[4], (d, f)),
             "b_in": jnp.zeros((f,), cfg.param_dtype),
@@ -431,6 +513,81 @@ def _block_params(cfg: TransformerConfig, key, kind: str = ATTENTION,
             "b_out": jnp.zeros((d,), cfg.param_dtype),
         }
     return p
+
+
+def _mixer_params(cfg: TransformerConfig, kind: str, ks, init, out_init):
+    """A layer's mixer of ``kind`` from the layer's keys ``ks``."""
+    d, h, kvh, hd = (cfg.embed_dim, cfg.num_heads, cfg.kv_heads,
+                     cfg.head_dim)
+    if kind == LATENT:
+        return _latent_params(cfg, ks, init, out_init)
+    if kind == MAMBA:
+        return _mamba_params(cfg, ks, init, out_init)
+    if kind == LINEAR:
+        kvh = h  # a key and a value head for every query head
+    attn = {
+        "wq": init(ks[0], (d, h, hd)),
+        "wk": init(ks[1], (d, kvh, hd)),
+        "wv": init(ks[2], (d, kvh, hd)),
+        "wo": out_init(ks[3], (h, hd, d)),
+    }
+    if cfg.qk_norm:
+        attn["q_norm"] = jnp.ones((h * hd,), cfg.param_dtype)
+        attn["k_norm"] = jnp.ones((kvh * hd,), cfg.param_dtype)
+    if cfg.head_qk_norm:
+        attn["q_norm"] = jnp.ones((hd,), cfg.param_dtype)
+        attn["k_norm"] = jnp.ones((hd,), cfg.param_dtype)
+    if kind in (SPARSE, LINEAR):
+        attn["wg"] = init(ks[7], (d, h, hd))   # the output gate
+    if kind == LINEAR:
+        attn["o_norm"] = jnp.ones((h * hd,), cfg.param_dtype)
+    if kind == RETENTION:
+        attn["wc"] = init(ks[7], (d, kvh))     # a log-gate a K/V head
+    if kind == INDEXED:
+        hi, di = cfg.indexer.indexer_num_heads, cfg.indexer.indexer_head_dim
+        ki = jax.random.split(ks[7], 3)
+        attn.update(
+            wi_q=init(ki[0], (d, hi, di)), wi_k=init(ki[1], (d, di)),
+            wi_w=init(ki[2], (d, hi)),
+            ik_scale=jnp.ones((di,), cfg.param_dtype),
+            ik_bias=jnp.zeros((di,), cfg.param_dtype))
+    return attn
+
+
+# what a seeded 'mamba2' layer's step bias and decay are drawn between:
+# Mamba-2's own initialisation (softplus(dt_bias) log-uniform over a source's
+# time_step_min .. time_step_max, A uniform over 1 .. 16)
+_SSM_DT = (0.001, 0.1)
+_SSM_A = (1.0, 16.0)
+
+
+def _mamba_params(cfg: TransformerConfig, ks, init, out_init):
+    """A 'mamba2' layer's mixer: the in-projection to [gate | x B C | dt],
+    the convolution's taps and bias over the joined x, B, C (drawn as
+    PyTorch draws a Conv1d's: uniform within 1 / sqrt(taps); weights of the
+    projections' spread would leave the convolution's output, and with it
+    the whole scan, a hundredth of the gate's size), the step's bias, the
+    decay's log, the skip, the gated norm's scale and ``w_out``."""
+    d, sizes = cfg.embed_dim, cfg.ssm
+    inner, width, H = sizes.inner, sizes.conv_width, sizes.heads
+    km = jax.random.split(ks[7], 4)
+    bound = sizes.conv ** -0.5
+    uniform = lambda key, shape, lo, hi: jax.random.uniform(
+        key, shape, jnp.float32, lo, hi)
+    dt = jnp.exp(uniform(km[2], (H,), *map(math.log, _SSM_DT)))
+    return {
+        "w_in": init(ks[0], (d, inner + width + H)),
+        "conv_w": uniform(km[0], (sizes.conv, width), -bound, bound
+                          ).astype(cfg.param_dtype),
+        "conv_b": uniform(km[1], (width,), -bound, bound
+                          ).astype(cfg.param_dtype),
+        # the inverse of softplus, float32 as the source keeps them
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "a_log": jnp.log(uniform(km[3], (H,), *_SSM_A)),
+        "d_skip": jnp.ones((H,), jnp.float32),
+        "norm": jnp.ones((inner,), cfg.param_dtype),
+        "w_out": out_init(ks[3], (inner, d)),
+    }
 
 
 def _latent_params(cfg: TransformerConfig, ks, init, out_init):
@@ -524,50 +681,61 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
             return {"scale": L + ("embed_notp",)}
         return {"scale": L + ("embed_notp",), "bias": L + ("embed_notp",)}
 
-    def block_axes(kind, ff=cfg.mlp):
-        kv = "heads" if kind == LINEAR else "kv"
-        block = {
-            "attn": {
-                "wq": L + ("embed", "heads", "head_dim"),
-                "wk": L + ("embed", kv, "head_dim"),
-                "wv": L + ("embed", kv, "head_dim"),
-                "wo": L + ("heads", "head_dim", "embed"),
-            },
-            "ln1": norm_axes(),
-            "ln2": norm_axes(),
-        }
-        if cfg.qk_norm or cfg.head_qk_norm:
-            block["attn"]["q_norm"] = L + (None,)
-            block["attn"]["k_norm"] = L + (None,)
-        if kind in (SPARSE, LINEAR):
-            block["attn"]["wg"] = L + ("embed", "heads", "head_dim")
-        if kind == LINEAR:
-            block["attn"]["o_norm"] = L + (None,)
-        if kind == RETENTION:
-            block["attn"]["wc"] = L + ("embed", "kv")
-        if kind == INDEXED:
-            block["attn"].update(
-                wi_q=L + ("embed", None, None), wi_k=L + ("embed", None),
-                wi_w=L + ("embed", None), ik_scale=L + (None,),
-                ik_bias=L + (None,))
+    def mixer_axes(kind):
         if kind == LATENT:
-            block["attn"] = {
+            return {
                 "wq_a": L + ("embed", None), "q_a_norm": L + (None,),
                 "wq_b": L + (None, "heads", "head_dim"),
                 "wkv_a": L + ("embed", None), "kv_norm": L + (None,),
                 "wkv_b": L + (None, "heads", "head_dim"),
                 "wo": L + ("heads", "head_dim", "embed")}
+        if kind == MAMBA:
+            return {
+                "w_in": L + ("embed", None), "conv_w": L + (None, None),
+                "conv_b": L + (None,), "dt_bias": L + (None,),
+                "a_log": L + (None,), "d_skip": L + (None,),
+                "norm": L + (None,), "w_out": L + (None, "embed")}
+        kv = "heads" if kind == LINEAR else "kv"
+        attn = {
+            "wq": L + ("embed", "heads", "head_dim"),
+            "wk": L + ("embed", kv, "head_dim"),
+            "wv": L + ("embed", kv, "head_dim"),
+            "wo": L + ("heads", "head_dim", "embed"),
+        }
+        if cfg.qk_norm or cfg.head_qk_norm:
+            attn["q_norm"] = L + (None,)
+            attn["k_norm"] = L + (None,)
+        if kind in (SPARSE, LINEAR):
+            attn["wg"] = L + ("embed", "heads", "head_dim")
+        if kind == LINEAR:
+            attn["o_norm"] = L + (None,)
+        if kind == RETENTION:
+            attn["wc"] = L + ("embed", "kv")
+        if kind == INDEXED:
+            attn.update(
+                wi_q=L + ("embed", None, None), wi_k=L + ("embed", None),
+                wi_w=L + ("embed", None), ik_scale=L + (None,),
+                ik_bias=L + (None,))
+        return attn
+
+    def block_axes(kind, ff=cfg.mlp):
+        block = {}
+        if kind != NONE:
+            block.update(attn=mixer_axes(kind), ln1=norm_axes())
+        if ff != NONE:
+            block["ln2"] = norm_axes()
         if ff == "moe":
             from ray_tpu.ops.moe import SIGMOID, moe_logical_axes
 
             block["mlp"] = {k: L + v for k, v in moe_logical_axes(
                 cfg.moe_scoring == SIGMOID,
-                bool(cfg.moe_shared_experts)).items()}
+                bool(cfg.moe_shared_experts),
+                cfg.moe_activation).items()}
         elif ff == "swiglu":
             block["mlp"] = {"w_gate": L + ("embed", "mlp"),
                             "w_up": L + ("embed", "mlp"),
                             "w_down": L + ("mlp", "embed")}
-        else:
+        elif ff != NONE:
             block["mlp"] = {"w_in": L + ("embed", "mlp"),
                             "b_in": L + ("mlp",),
                             "w_out": L + ("mlp", "embed"),
@@ -579,9 +747,9 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
         blocks = {str(i): block_axes(kind, cfg.mlp_of(i))
                   for i, kind in enumerate(kinds)}
     elif cfg.period == 1:
-        blocks = block_axes(kinds[lead])
+        blocks = block_axes(kinds[lead], cfg.mlp_of(lead))
     else:
-        blocks = {f"p{j}": block_axes(kinds[lead + j])
+        blocks = {f"p{j}": block_axes(kinds[lead + j], cfg.mlp_of(lead + j))
                   for j in range(cfg.period)}
     if cfg.scan_layers and lead:
         blocks = {"lead": block_axes(kinds[0], cfg.mlp_of(0)),
@@ -811,6 +979,8 @@ def state_shapes(cfg, kind: str, rows: int) -> Dict[str, tuple]:
     if kind == RETENTION:
         return power_retention.state_shapes(rows, cfg.kv_heads,
                                             cfg.head_dim)
+    if kind == MAMBA:
+        return ssm.state_shapes(rows, cfg.ssm)
     raise ValueError(f"a {kind!r} layer keeps no state")
 
 
@@ -825,7 +995,7 @@ def _linear_project(cfg, p, x, positions):
     return _qkv(cfg, p, x, COMPUTED, positions)
 
 
-def _linear_mix(cfg, rows, state, *, real_len=None, active=None):
+def _linear_mix(cfg, p, rows, state, *, real_len=None, active=None):
     """'lightning-attn', one group: q, k, v [B, S, H, D] on the state
     ``{"s": [B, H, D, D]}`` float32 -> (o [B, S, H, D] float32, state)."""
     q, k, v = rows
@@ -859,7 +1029,7 @@ def _retention_project(cfg, p, x, positions):
     return q, k, v, gate
 
 
-def _retention_mix(cfg, rows, state, *, real_len=None, active=None):
+def _retention_mix(cfg, p, rows, state, *, real_len=None, active=None):
     """'power-retention', one group: q [B, S, H, D], k, v [B, S, Hkv, D]
     and the log-gate on the state the dict ``state_shapes`` describes (``s``
     and the normaliser ``z``, a K/V head each, float32) -> (o [B, S, H, D],
@@ -882,12 +1052,69 @@ def _retention_finish(cfg, p, x, o):
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(cfg.dtype))
 
 
+def _mamba_project(cfg, p, x, positions):
+    """The in-projection, split: the gate z [B, S, inner], the joined x, B,
+    C before their convolution [B, S, inner + 2 G N] and the steps before
+    their bias and softplus [B, S, H]. (The convolution needs the inputs a
+    sequence carries, so it is the mix's.)"""
+    sizes = cfg.ssm
+    zxd = jnp.einsum("bsd,dk->bsk", x, p["w_in"].astype(cfg.dtype))
+    inner, width = sizes.inner, sizes.conv_width
+    return zxd[..., :inner], zxd[..., inner:inner + width], zxd[
+        ..., inner + width:]
+
+
+def _mamba_mix(cfg, p, rows, state, *, real_len=None, active=None):
+    """'mamba2', one group: the gate, the joined x, B, C and the steps on
+    the two states ``state_shapes`` describes ({"conv", "ssm"}, float32):
+    the convolution over the inputs the sequence carries and its silu, the
+    split, ``dt = softplus(dt + dt_bias)``, the scan (a chunk's or a
+    step's, ``ops.ssm``) and the gate, ``y silu(z)`` -> (o [B, S, inner]
+    float32, state). The norm behind the gate is the finish's."""
+    z, xbc, dt = rows
+    sizes, f32 = cfg.ssm, jnp.float32
+    B, S = z.shape[:2]
+    H, P, G, N = sizes.heads, sizes.head_dim, sizes.groups, sizes.state
+    xbc, conv = ssm.causal_conv(xbc, state["conv"], p["conv_w"], p["conv_b"],
+                                real_len=real_len, active=active)
+    xbc = xbc.astype(cfg.dtype)
+    x = xbc[..., :sizes.inner].reshape(B, S, H, P)
+    bm, cm = (xbc[..., sizes.inner + j * G * N:sizes.inner + (j + 1) * G * N]
+              .reshape(B, S, G, N) for j in range(2))
+    dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
+    a, d = -jnp.exp(p["a_log"].astype(f32)), p["d_skip"].astype(f32)
+    impl = "reference" if cfg.attn_impl == "reference" else None
+    if S == 1:
+        y, s = ssm.ssd_step(
+            x[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0], d, state["ssm"],
+            jnp.ones((B,), jnp.int32) if active is None else active, sizes,
+            impl)
+        y = y[:, None]
+    else:
+        y, s = ssm.ssd_chunk(x, dt, a, bm, cm, d, state["ssm"], sizes,
+                             real_len, impl)
+    return (y.reshape(B, S, -1) * jax.nn.silu(z.astype(f32)),
+            {"conv": conv, "ssm": s})
+
+
+def _mamba_finish(cfg, p, x, o):
+    """The gated values through an RMSNorm a GROUP (the gate came first),
+    then ``w_out``."""
+    B, S = o.shape[:2]
+    G = cfg.ssm.groups
+    o = rms_norm(o.reshape(B, S, G, -1), p["norm"].reshape(G, -1),
+                 cfg.norm_eps).reshape(B, S, -1)
+    return jnp.einsum("bsk,kd->bsd", o.astype(cfg.dtype),
+                      p["w_out"].astype(cfg.dtype))
+
+
 # kind -> (project(cfg, p, x, positions) -> the rows' arrays [B, S, ...],
-#          mix(cfg, rows, state, *, real_len, active) -> (o, state),
+#          mix(cfg, p, rows, state, *, real_len, active) -> (o, state),
 #          finish(cfg, p, x, o) -> y [B, S, d])
 STATE_MIXERS = {
     LINEAR: (_linear_project, _linear_mix, _linear_finish),
     RETENTION: (_retention_project, _retention_mix, _retention_finish),
+    MAMBA: (_mamba_project, _mamba_mix, _mamba_finish),
 }
 
 
@@ -900,7 +1127,7 @@ def state_mixer(cfg, kind, p, x, positions, state):
     which keep their state bitwise, and its chunks trailing padding past
     ``real_len``.)"""
     project, mix, finish = STATE_MIXERS[kind]
-    o, state = mix(cfg, project(cfg, p, x, positions), state)
+    o, state = mix(cfg, p, project(cfg, p, x, positions), state)
     return finish(cfg, p, x, o), state
 
 
@@ -1217,8 +1444,19 @@ def _mlp(cfg, p, x, valid=None, layer=None, ff=None):
             p, x, num_experts=cfg.moe_num_experts, top_k=cfg.moe_top_k,
             renormalize=cfg.moe_renormalize, dtype=cfg.dtype, valid=valid,
             layer=layer, scoring=cfg.moe_scoring,
-            routed_scale=cfg.moe_routed_scale)
-        return y, aux, {"counts": counts, "routes": routes}
+            routed_scale=cfg.moe_routed_scale, held=cfg.held,
+            activation=cfg.moe_activation)
+        moe = {"counts": counts, "routes": routes}
+        if cfg.held:
+            # the live rows' choices that fell on experts held elsewhere,
+            # counted on the device: with ``counts`` they are every choice
+            # made (live rows x top-k exactly, or a row was dropped)
+            first, held = cfg.held
+            away = (routes < first) | (routes >= first + held)
+            if valid is not None:
+                away &= valid[..., None]
+            moe["left_out"] = away.sum().astype(jnp.int32)
+        return y, aux, moe
     if ff == "swiglu":
         gate = jnp.einsum("bsd,df->bsf", x, p["w_gate"].astype(cfg.dtype))
         up = jnp.einsum("bsd,df->bsf", x, p["w_up"].astype(cfg.dtype))
@@ -1236,6 +1474,8 @@ def stacked_mlp(cfg, params, layer_params, i):
     scan the whole stack that holds the layer and its index there — the
     grouped matmuls cannot fuse the slice as a dense matmul does, and would
     copy the layer's experts."""
+    if cfg.mlp_of(i) == NONE:
+        return None  # a layer that is a mixer alone
     if cfg.mlp_of(i) != "moe" or not cfg.scan_layers:
         return layer_params["mlp"], None
     i -= cfg.lead_layers
@@ -1252,15 +1492,21 @@ def _block(cfg, p, x, rope, positions, sp_axis, kv_cache=None, mlp=None,
     layers one by one; None for ``(p["mlp"], None)``. ``kind``: the layer's
     mixer; ``ff``: its feed-forward (``cfg.mlp_of``; None: ``cfg.mlp``).
     ``taps``: a list that is given what a mixer chose (debug)."""
+    if kind == NONE:  # a layer that is a feed-forward alone
+        return _after_mixer(cfg, p, x, None, kv_cache, mlp, ff)
     a, new_cache = _mixer(cfg, kind, p["attn"], _norm(cfg, p["ln1"], x),
                           rope, positions, sp_axis, kv_cache, taps)
     return _after_mixer(cfg, p, x, a, new_cache, mlp, ff)
 
 
 def _after_mixer(cfg, p, x, a, new_cache=None, mlp=None, ff=None):
-    """The rest of a block, given what its mixer made of x: ``_block``'s
-    results."""
-    x = _residual(cfg, x, a)
+    """The rest of a block, given what its mixer made of x (None: the
+    layer has none): ``_block``'s results. A layer without a feed-forward
+    (``ff`` is ``NONE``) ends behind its mixer."""
+    if a is not None:
+        x = _residual(cfg, x, a)
+    if ff == NONE:
+        return x, new_cache, 0.0, None
     mlp_p, layer = mlp or (p["mlp"], None)
     m, aux, moe = _mlp(cfg, mlp_p, _norm(cfg, p["ln2"], x), layer=layer,
                        ff=ff)
@@ -1272,7 +1518,8 @@ def _after_mixer(cfg, p, x, a, new_cache=None, mlp=None, ff=None):
 # ``cfg.dtype`` where they use it (a leaf that is not named here keeps its
 # type, and costs two streams a second gather, never a digit)
 _MATRICES = frozenset({"wq", "wk", "wv", "wo", "wg", "wc", "wi_q", "wi_k",
-                       "wi_w", "w_gate", "w_up", "w_down", "w_in", "w_out"})
+                       "wi_w", "w_gate", "w_up", "w_up_t", "w_down", "w_in",
+                       "w_out"})
 
 
 def _block_streams(cfg, p, hs, rope, positions, sp_axis, kind=ATTENTION,
@@ -1292,7 +1539,9 @@ def _block_streams(cfg, p, hs, rope, positions, sp_axis, kind=ATTENTION,
                        ff=ff)]
     p = {**p, **{part: {name: w.astype(cfg.dtype) if name in _MATRICES else w
                         for name, w in p[part].items()}
-                 for part in ("attn", "mlp")}}
+                 for part in ("attn", "mlp") if part in p}}
+    if kind == NONE:
+        return [_after_mixer(cfg, p, h, None, ff=ff) for h in hs]
     normed = [_norm(cfg, p["ln1"], h) for h in hs]
     if (kind != ATTENTION or cfg.window(kind) is not None
             or cfg.attn_impl == "ring" and sp_axis is not None):
@@ -1383,7 +1632,7 @@ def project(cfg, params, x):
 def rope_table(cfg):
     """The (cos, sin) tables plain attention rotates by, None for a model
     none of whose layers does (a table is as long as the context)."""
-    if (cfg.pos == "learned"
+    if (cfg.pos in ("learned", "none")
             or not {ATTENTION, SLIDING, INDEXED} & set(cfg.kinds)):
         return None
     if cfg.rope_parameters is not None or cfg.rope_scaling is not None:
@@ -1476,7 +1725,8 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
                 hs = tuple(h for h, _, _, _ in outs)
                 for _, _, aux, _ in outs:
                     aux_acc = aux_acc + aux
-                chosen.append(outs[0][3]["routes"] if return_routes else None)
+                if return_routes and outs[0][3] is not None:
+                    chosen.append(outs[0][3]["routes"])
             return (tuple(_residual_layout(h) for h in hs), aux_acc), (
                 jnp.stack(chosen) if return_routes else None)
         (carried, aux_total), routes = jax.lax.scan(

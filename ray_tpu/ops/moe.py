@@ -59,6 +59,10 @@ from ray_tpu.ops._pallas import should_interpret
 
 SOFTMAX, SIGMOID = "softmax", "sigmoid"
 SCORINGS = (SOFTMAX, SIGMOID)
+# an expert's form: ``W_down (silu(x W_gate) * (x W_up))``, three matrices,
+# or ``W_down relu(x W_up)^2``, two
+SWIGLU, RELU2 = "swiglu", "relu2"
+ACTIVATIONS = (SWIGLU, RELU2)
 # what a seeded choice bias is drawn with: sigmoid scores of seeded weights
 # lie tenths apart, so a bias of this spread moves a measurable share of the
 # rows' top-k (a bias of zeros could be dropped and no test would tell)
@@ -67,46 +71,69 @@ _BIAS_STD = 0.1
 
 def init_moe_params(key, embed_dim: int, hidden_dim: int, num_experts: int,
                     param_dtype=jnp.float32, *, choice_bias: bool = False,
-                    shared_dim: int = 0) -> Dict[str, Any]:
+                    shared_dim: int = 0, activation: str = SWIGLU,
+                    held: Optional[int] = None) -> Dict[str, Any]:
     """SwiGLU experts: router [d,E] + per-expert gate/up [E,d,f], down
     [E,f,d]. ``choice_bias``: ``e_bias`` [E] float32 beside them, what a
     sigmoid router adds to its scores to CHOOSE (never to weigh).
     ``shared_dim``: the width of the shared expert (its experts' widths
-    joined; 0: none), ``ws_gate``/``ws_up`` [d,fs] and ``ws_down`` [fs,d]."""
+    joined; 0: none), ``ws_gate``/``ws_up`` [d,fs] and ``ws_down`` [fs,d].
+    ``activation`` 'relu2': no gate, the experts' nor the shared one's, and
+    the experts' up-projection is kept HIDDEN-MAJOR, ``w_up_t`` [E,f,d]: a
+    width that is no whole number of 128-lane rows (1856) would otherwise be
+    the minor axis, which the chip lays out transposed and copies back for
+    the kernel on every call (639 MB a layer, compiled for the chip, PR 59).
+    ``held``: the experts whose weights are made, where that is fewer than
+    the ``num_experts`` the router scores (the chip's share of the layer)."""
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"unknown expert activation {activation!r}; "
+                         f"expected one of {ACTIVATIONS}")
+    held = num_experts if held is None else held
     ks = jax.random.split(key, 4)
     # what the other two bring is drawn from keys of their own: the four
     # above are what a model without them has always been made from
     more = jax.random.split(jax.random.fold_in(key, 4), 4)
     init = jax.nn.initializers.normal(0.02, param_dtype)
+    gated = activation == SWIGLU
     p = {
         "w_router": init(ks[0], (embed_dim, num_experts)),
-        "w_gate": init(ks[1], (num_experts, embed_dim, hidden_dim)),
-        "w_up": init(ks[2], (num_experts, embed_dim, hidden_dim)),
-        "w_down": init(ks[3], (num_experts, hidden_dim, embed_dim)),
+        "w_down": init(ks[3], (held, hidden_dim, embed_dim)),
     }
+    if gated:
+        p["w_gate"] = init(ks[1], (held, embed_dim, hidden_dim))
+        p["w_up"] = init(ks[2], (held, embed_dim, hidden_dim))
+    else:
+        p["w_up_t"] = init(ks[2], (held, hidden_dim, embed_dim))
     if choice_bias:
         p["e_bias"] = _BIAS_STD * jax.random.normal(
             more[0], (num_experts,), jnp.float32)
     if shared_dim:
-        p.update(ws_gate=init(more[1], (embed_dim, shared_dim)),
-                 ws_up=init(more[2], (embed_dim, shared_dim)),
+        p.update(ws_up=init(more[2], (embed_dim, shared_dim)),
                  ws_down=init(more[3], (shared_dim, embed_dim)))
+        if gated:
+            p["ws_gate"] = init(more[1], (embed_dim, shared_dim))
     return p
 
 
-def moe_logical_axes(choice_bias: bool = False, shared: bool = False
+def moe_logical_axes(choice_bias: bool = False, shared: bool = False,
+                     activation: str = SWIGLU
                      ) -> Dict[str, Tuple[Optional[str], ...]]:
+    gated = activation == SWIGLU
     axes = {
         "w_router": ("embed", None),
-        "w_gate": ("expert", "embed", "mlp"),
-        "w_up": ("expert", "embed", "mlp"),
         "w_down": ("expert", "mlp", "embed"),
     }
+    if gated:
+        axes.update(w_gate=("expert", "embed", "mlp"),
+                    w_up=("expert", "embed", "mlp"))
+    else:
+        axes["w_up_t"] = ("expert", "mlp", "embed")
     if choice_bias:
         axes["e_bias"] = (None,)
     if shared:
-        axes.update(ws_gate=("embed", "mlp"), ws_up=("embed", "mlp"),
-                    ws_down=("mlp", "embed"))
+        axes.update(ws_up=("embed", "mlp"), ws_down=("mlp", "embed"))
+        if gated:
+            axes["ws_gate"] = ("embed", "mlp")
     return axes
 
 # ------------------------------------------- the serving path's experts
@@ -123,17 +150,18 @@ class Tiles(NamedTuple):
     cols: int  # columns of the experts' hidden width a grid cell takes
 
 
-def _vmem_bytes(t: Tiles, d: int, itemsize: int) -> int:
-    """VMEM a grid cell holds: the row tile, the three weight tiles and
-    the out tile twice (two buffers each), the float32 products before
-    they are rounded (gate, up, down) and the down product's accumulator."""
-    blocks = 2 * t.rows * d + 3 * d * t.cols
+def _vmem_bytes(t: Tiles, d: int, itemsize: int, matrices: int = 3) -> int:
+    """VMEM a grid cell holds: the row tile, the weight tiles (three of a
+    SwiGLU expert, two of a 'relu2' one) and the out tile twice (two buffers
+    each), the float32 products before they are rounded (gate, up, down) and
+    the down product's accumulator."""
+    blocks = 2 * t.rows * d + matrices * d * t.cols
     return 2 * blocks * itemsize + (2 * t.rows * t.cols
                                     + 2 * t.rows * d) * 4
 
 
 def tile_sizes(pairs: int, groups: int, d: int, f: int, itemsize: int,
-               vmem_bytes: int = _VMEM_BUDGET) -> Tiles:
+               vmem_bytes: int = _VMEM_BUDGET, matrices: int = 3) -> Tiles:
     """The kernel's tiles, from static shapes alone.
 
     rows: ``_GROUPS_A_TILE`` groups of mean size, as a power of two between
@@ -155,7 +183,8 @@ def tile_sizes(pairs: int, groups: int, d: int, f: int, itemsize: int,
     lanes = f // _LANES if f % _LANES == 0 else 0
     for m in range(lanes, 0, -1):
         t = Tiles(rows, m * _LANES)
-        if lanes % m == 0 and _vmem_bytes(t, d, itemsize) <= vmem_bytes:
+        if lanes % m == 0 and _vmem_bytes(t, d, itemsize,
+                                          matrices) <= vmem_bytes:
             return t
     return Tiles(rows, f if not lanes else _LANES)
 
@@ -183,19 +212,27 @@ def _visits(counts, first_group, n_tiles: int, rows: int):
 
 
 def _expert_kernel(group_ref, tile_ref, start_ref, end_ref, total_ref,
-                   x_ref, gate_ref, up_ref, down_ref, o_ref, *acc, rows):
+                   x_ref, *refs, rows, gated=True):
     """One visit (and one column tile of the hidden width): the row tile
-    through the group's SwiGLU, stored into the rows of the tile that are
-    the group's. The out block stays in VMEM while consecutive visits share
-    its tile, and goes back to HBM once."""
+    through the group's SwiGLU (``gated``: gate, up, down) or its
+    ``relu(up)^2`` (up, down: no third matrix is read), stored into the rows
+    of the tile that are the group's. The out block stays in VMEM while
+    consecutive visits share its tile, and goes back to HBM once."""
     i, j = pl.program_id(0), pl.program_id(1)
+    up_ref, down_ref, o_ref, *acc = refs[gated:]
 
     @pl.when(i < total_ref[0])
     def _():
         x = x_ref[...]
-        gate = jnp.dot(x, gate_ref[...], preferred_element_type=jnp.float32)
-        up = jnp.dot(x, up_ref[...], preferred_element_type=jnp.float32)
-        hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+        if gated:
+            gate = jnp.dot(x, refs[0][...],
+                           preferred_element_type=jnp.float32)
+            up = jnp.dot(x, up_ref[...], preferred_element_type=jnp.float32)
+            hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+        else:  # up is hidden-major, [cols, d]: x up^T
+            up = lax.dot_general(x, up_ref[...], (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            hidden = jnp.square(jnp.maximum(up, 0.0)).astype(x.dtype)
         y = jnp.dot(hidden, down_ref[...],
                     preferred_element_type=jnp.float32)
 
@@ -229,7 +266,9 @@ def _expert_kernel(group_ref, tile_ref, start_ref, end_ref, total_ref,
 # layer (first_group is an argument, not a constant)
 @functools.partial(jax.jit, static_argnames=("tiles",))
 def expert_mlp(xs, w_gate, w_up, w_down, counts, first_group, tiles=None):
-    """``silu(xs @ gate_g) * (xs @ up_g) @ down_g`` for every row's group.
+    """``silu(xs @ gate_g) * (xs @ up_g) @ down_g`` for every row's group;
+    with ``w_gate`` None ``relu(xs @ up_g^T)^2 @ down_g``, the two-matrix
+    expert, whose ``w_up`` is hidden-major, ``[.., f, d]`` as ``w_down`` is.
 
     xs: [pairs, d], sorted by group; counts: [G] int32 rows of each group;
     the weights are a STACK ``[>= first_group + G, d, f]`` (gate, up) and
@@ -237,8 +276,11 @@ def expert_mlp(xs, w_gate, w_up, w_down, counts, first_group, tiles=None):
     (layer x experts; an int32 scalar). Rows past the last group are
     undefined. ``tiles``: ``tile_sizes``' unless a test names its own."""
     pairs, d = xs.shape
-    f = w_gate.shape[2]
-    t = tiles or tile_sizes(pairs, counts.shape[0], d, f, xs.dtype.itemsize)
+    f = w_down.shape[1]
+    gated = w_gate is not None
+    matrices = 2 + gated
+    t = tiles or tile_sizes(pairs, counts.shape[0], d, f, xs.dtype.itemsize,
+                            matrices=matrices)
     if f % t.cols:
         raise ValueError(f"{t} does not divide the hidden width {f}")
     n_tiles, f_tiles = -(-pairs // t.rows), f // t.cols
@@ -256,28 +298,26 @@ def expert_mlp(xs, w_gate, w_up, w_down, counts, first_group, tiles=None):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(visits),
         grid=(visits[0].shape[0], f_tiles),
-        in_specs=[
-            pl.BlockSpec((t.rows, d), rows_map),
-            pl.BlockSpec((None, d, t.cols), into_hidden),
-            pl.BlockSpec((None, d, t.cols), into_hidden),
-            pl.BlockSpec((None, t.cols, d),
-                         lambda i, j, group, *_: (group[i], j, 0)),
-        ],
+        in_specs=[pl.BlockSpec((t.rows, d), rows_map)]
+        + [pl.BlockSpec((None, d, t.cols), into_hidden)] * (2 * gated)
+        + [pl.BlockSpec((None, t.cols, d),
+                        lambda i, j, group, *_: (group[i], j, 0))]
+        * (2 - gated),
         out_specs=pl.BlockSpec((t.rows, d), rows_map),
         scratch_shapes=([pltpu.VMEM((t.rows, d), jnp.float32)]
                         if f_tiles > 1 else []),
     )
     out = pl.pallas_call(
-        functools.partial(_expert_kernel, rows=t.rows),
+        functools.partial(_expert_kernel, rows=t.rows, gated=gated),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_tiles * t.rows, d), xs.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=_vmem_bytes(t, d, xs.dtype.itemsize) * 5 // 4
-            + (4 << 20)),
+            vmem_limit_bytes=_vmem_bytes(t, d, xs.dtype.itemsize, matrices)
+            * 5 // 4 + (4 << 20)),
         name="moe_grouped_matmul",
         interpret=should_interpret(),
-    )(*visits, xs, w_gate, w_up, w_down)
+    )(*visits, xs, *((w_gate,) if gated else ()), w_up, w_down)
     return out[:pairs]
 
 
@@ -315,10 +355,26 @@ def route(logits, top_k: int, renormalize: bool, scoring: str = SOFTMAX,
     return scores, gate_idx, gate_vals
 
 
+def held_index(experts, live, num_experts: int,
+               held: Optional[Tuple[int, int]]):
+    """The group each (row, choice) pair sorts into: ``experts`` [..., k]
+    (chosen over all ``num_experts``), ``live`` [...] -> the expert's index
+    among the G experts held here (``held`` = (first, G); None: all of
+    them), and G — behind every group — for a pair of a row that is not
+    live or of an expert this chip does not hold."""
+    if held is None:
+        return jnp.where(live[..., None], experts, num_experts)
+    first, groups = held
+    mine = live[..., None] & (experts >= first) & (experts < first + groups)
+    return jnp.where(mine, experts - first, groups)
+
+
 def moe_layer(p: Dict[str, Any], x, *, num_experts: int, top_k: int = 2,
               renormalize: bool = True, dtype=jnp.bfloat16, valid=None,
               layer: Optional[int] = None, scoring: str = SOFTMAX,
-              routed_scale: float = 1.0):
+              routed_scale: float = 1.0,
+              held: Optional[Tuple[int, int]] = None,
+              activation: str = SWIGLU):
     """x: [B, S, d] -> (y [B, S, d], aux_loss, counts [E], routes [B, S, k]).
 
     ``scoring``, ``renormalize``, ``routed_scale``: the router's rule
@@ -327,6 +383,17 @@ def moe_layer(p: Dict[str, Any], x, *, num_experts: int, top_k: int = 2,
     p["e_bias"]`` and weighed without it (DeepSeek-V3's ``noaux_tc`` with one
     group). Where ``p`` holds a shared expert (``ws_gate``, ``ws_up``,
     ``ws_down``) every live row takes it beside its routed sum, unweighted.
+    ``activation``: the experts' form and the shared one's (``ACTIVATIONS``;
+    'relu2' has no gate matrix and none is read).
+    ``held`` = (first, count): ``p`` holds the weights of ``count`` of the
+    ``num_experts`` experts, from ``first`` on — the chip's share of a layer
+    whose experts lie over several. The router scores ALL experts and each
+    row's weights are normalised over all its ``top_k`` choices; the pairs
+    that chose an expert held elsewhere are dropped before the grouped
+    products (they sort behind every group: no row of a tile, no visit, no
+    count) and y is this chip's experts' part of the routed sum, plus the
+    shared expert. What the other chips would add is left out, and nothing
+    stands in for them. ``counts`` is then over the ``count`` held experts.
     ``valid``: optional bool [B, S]; a row marked False is routed nowhere.
     ``counts``: int32 rows each expert received (valid rows only; they sum
     to valid rows x k: the dropless witness). ``routes``: the experts each
@@ -358,36 +425,48 @@ def moe_layer(p: Dict[str, Any], x, *, num_experts: int, top_k: int = 2,
             else valid.reshape(n).astype(bool))
 
     # (row, choice) pairs sorted by expert; pairs of rows that are not live
-    # carry the expert id E, so they sort behind every group
-    pair_expert = jnp.where(live[:, None], gate_idx, num_experts).reshape(-1)
+    # (and of experts held elsewhere) carry the id behind the last group, so
+    # they sort behind every group
+    groups = held[1] if held else num_experts
+    pair_group = held_index(gate_idx, live, num_experts, held)  # [N, k]
+    pair_expert = pair_group.reshape(-1)
     order = jnp.argsort(pair_expert, stable=True)
-    counts = jnp.zeros((num_experts,), jnp.int32).at[pair_expert].add(
+    counts = jnp.zeros((groups,), jnp.int32).at[pair_expert].add(
         1, mode="drop")
     xs = xt[order // top_k]  # [N*k, d]
-    w_gate, w_up, w_down = (p[k].astype(dtype)
-                            for k in ("w_gate", "w_up", "w_down"))
-    if layer is None:
+    gated = activation == SWIGLU
+    w_gate, w_up, w_down = (p[k].astype(dtype) if k in p else None for k in (
+        "w_gate", "w_up" if gated else "w_up_t", "w_down"))
+    if layer is None and gated:
         gate = jax.lax.ragged_dot(xs, w_gate, counts)
         up = jax.lax.ragged_dot(xs, w_up, counts)
         out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_down, counts)
+    elif layer is None:
+        up = jax.lax.ragged_dot(xs, w_up.swapaxes(1, 2), counts)
+        out = jax.lax.ragged_dot(jnp.square(jax.nn.relu(up)), w_down, counts)
     else:  # [layers, experts, ...] seen as layers x experts groups
-        out = expert_mlp(xs, *(a.reshape(-1, *a.shape[2:])
+        out = expert_mlp(xs, *(a if a is None else a.reshape(-1, *a.shape[2:])
                                for a in (w_gate, w_up, w_down)),
-                         counts, layer * num_experts)
+                         counts, layer * groups)
     # back to (row, choice) order; the weighted sum over a row's k experts
     # accumulates in float32. Pairs past the last group hold nothing
     # defined: they are masked, not multiplied by 0
     out = out[jnp.argsort(order)].reshape(n, top_k, d)
+    kept = (live[:, None, None] if held is None
+            else (pair_group < groups)[:, :, None])
     y = jnp.einsum("nkd,nk->nd",
-                   jnp.where(live[:, None, None], out, 0).astype(jnp.float32),
+                   jnp.where(kept, out, 0).astype(jnp.float32),
                    gate_vals).astype(dtype)
-    if "ws_gate" in p:
-        # the shared expert: a dense SwiGLU of every row (a layer's slice of
+    if "ws_up" in p:
+        # the shared expert: a dense expert of every row (a layer's slice of
         # a dense matrix fuses into the product; a row that is not live
         # comes out as zeros here too)
-        ws_gate, ws_up, ws_down = (own(p[k]).astype(dtype)
-                                   for k in ("ws_gate", "ws_up", "ws_down"))
-        shared = (jax.nn.silu(xt @ ws_gate) * (xt @ ws_up)) @ ws_down
+        ws_gate, ws_up, ws_down = (
+            own(p[k]).astype(dtype) if k in p else None
+            for k in ("ws_gate", "ws_up", "ws_down"))
+        hidden = (jax.nn.silu(xt @ ws_gate) * (xt @ ws_up) if gated
+                  else jnp.square(jax.nn.relu(xt @ ws_up)))
+        shared = hidden @ ws_down
         y = y + jnp.where(live[:, None], shared, 0).astype(dtype)
 
     # Switch aux loss: encourage uniform routing

@@ -292,11 +292,11 @@ def build_pools(cfg, *, slots: int, page_tokens: int, pages_per_slot: int,
     chunk's first row, the chunk, and a page for where the two begin inside
     one — times ``slots``, plus the garbage page: no option sets it."""
     from ray_tpu.models.decode import pool_of
-    from ray_tpu.models.transformer import STATE_KINDS
+    from ray_tpu.models.transformer import holds_page
 
     pools: Dict[str, PagePool] = {}
     for kind in cfg.kinds:
-        if kind in STATE_KINDS or pool_of(kind) in pools:
+        if not holds_page(kind) or pool_of(kind) in pools:
             continue
         window, pages = cfg.window(kind), num_pages
         if window is not None:

@@ -11,8 +11,9 @@ program's device time is read from a profiler trace, by its name.
 A kind's counters are said once, in ``_KINDS``: the function that counts one
 call of the kind's layers (a layer-call: one layer in one program run) and
 the keys it owns, which a model shows if and only if it has layers of the
-kind ('lightning-attn', 'power-retention', 'minicpm4', 'indexed_attention',
-'sliding_attention', 'latent_attention'; plain 'attention' is counted by
+kind ('lightning-attn', 'power-retention', 'mamba2', 'minicpm4',
+'indexed_attention', 'sliding_attention', 'latent_attention'; plain
+'attention' is counted by
 ``attn_*`` alone); ``attn_*`` always (they count pages: a true 0 for a model
 without). What a token leaves in a page, by kind, is ``token_bytes``.
 """
@@ -26,8 +27,8 @@ import numpy as np
 
 from ray_tpu._private.metrics import Counter
 from ray_tpu.models.transformer import (ATTENTION, INDEXED, LATENT, LINEAR,
-                                        RETENTION, SLIDING, SPARSE,
-                                        STATE_KINDS, state_shapes)
+                                        MAMBA, RETENTION, SLIDING, SPARSE,
+                                        STATE_KINDS, holds_page, state_shapes)
 from ray_tpu.ops.indexed_attention import (SELECT_ROWS, chunk_tokens,
                                            context_tokens, select_lanes)
 from ray_tpu.ops.latent_attention import latent_tiles, pool_width
@@ -55,6 +56,20 @@ def _retention(work, n, layers, qk, cursors, real):
     else:
         n["retention_chunk_calls"] += calls
         n["retention_chunk_tokens"] += calls * real
+
+
+def _ssm(work, n, layers, qk, cursors, real):
+    """As ``_retention``, and the bytes the least handling of the states
+    moves: a live row's two states (the convolution's inputs and the scan's
+    state) of a layer read once and written once, a step's row or a chunk's
+    slot alike."""
+    calls = layers * len(cursors)
+    if qk == 1:
+        n["ssm_step_rows"] += calls
+    else:
+        n["ssm_chunk_calls"] += calls
+        n["ssm_chunk_tokens"] += calls * real
+    n["ssm_state_bytes_moved"] += 2 * calls * work.ssm_row_bytes
 
 
 def _sparse(work, n, layers, qk, cursors, real):
@@ -183,6 +198,8 @@ _KINDS = {
     LINEAR: (_linear, ("linear_chunk_calls", "linear_step_rows")),
     RETENTION: (_retention, ("retention_chunk_calls",
                              "retention_chunk_tokens", "retention_step_rows")),
+    MAMBA: (_ssm, ("ssm_chunk_calls", "ssm_chunk_tokens", "ssm_step_rows",
+                   "ssm_state_bytes_moved")),
     SPARSE: (_sparse, ("sparse_rows", "sparse_rows_dense",
                        "sparse_tokens_attended", "sparse_tokens_context",
                        "sparse_step_tokens_attended",
@@ -205,6 +222,9 @@ _EXPERTS = ("moe_live_rows", "moe_layer_calls", "moe_rows_routed",
 # beside them where the expert layers have a shared expert: the rows it took
 # (every live row of every expert layer-call, times the shared experts)
 _SHARED = "moe_shared_rows"
+# and where the layer is a chip's share of its experts (``cfg.held``): every
+# choice the live rows made, of which ``moe_rows_routed`` landed here
+_CHOSEN = "moe_routes_chosen"
 
 
 class Work:
@@ -219,7 +239,7 @@ class Work:
         kinds = cfg.kinds
         self._kinds = [(_KINDS[kind][0], kinds.count(kind))
                        for kind in _KINDS if kind in kinds]
-        self.paged_layers = sum(kind not in STATE_KINDS for kind in kinds)
+        self.paged_layers = sum(holds_page(kind) for kind in kinds)
         self._window_layers = kinds.count(SLIDING)
         # all kv heads of one token's K (or V) row, and the query rows
         # that share it; a latent layer's one row a token, in two halves for
@@ -242,6 +262,11 @@ class Work:
             self.slots = slots
             self.indexed_context = context_tokens(pages_per_slot,
                                                   page_tokens)
+        if MAMBA in kinds:
+            # one row's two float32 states of ONE layer
+            self.ssm_row_bytes = 4 * sum(
+                math.prod(shape) for shape in state_shapes(
+                    cfg, MAMBA, 1).values())
         # a float32 state a slot a layer that keeps one, by kind
         state_bytes = 4 * sum(
             math.prod(shape) for kind in kinds if kind in STATE_KINDS
@@ -253,7 +278,8 @@ class Work:
             _ATTN + tuple(key for kind in _KINDS if kind in kinds
                           for key in _KINDS[kind][1])
             + (_EXPERTS if self.counts_experts else ())
-            + ((_SHARED,) if cfg.moe_shared_experts else ()), 0)
+            + ((_SHARED,) if cfg.moe_shared_experts else ())
+            + ((_CHOSEN,) if cfg.held else ()), 0)
         if state_bytes:
             self._n.update(state_slots=slots, state_bytes=state_bytes)
 
@@ -309,7 +335,11 @@ class Work:
         ``returned`` beside ids and caches (call after a wait on that
         program: the copy below then waits for nothing), beside the
         ``live_rows`` the host handed it: ``moe_rows_routed`` == live rows x
-        top-k x layers exactly, or a row was dropped. A chunk's program that
+        top-k x layers exactly, or a row was dropped — where the layer is a
+        chip's share of its experts (``cfg.held``) that holds of
+        ``moe_routes_chosen``, the routes that landed here plus those the
+        device counted as left out, and ``moe_rows_routed`` and the counts
+        behind it are over the experts held. A chunk's program that
         took the step along tells the two groups' rows apart ([layers, 2,
         experts]): each is a layer-call of its own, the step's only where a
         row was live (``step``)."""
@@ -322,8 +352,12 @@ class Work:
         n["moe_rows_routed"] += int(c.sum())
         n["moe_experts_hit"] += int((c > 0).sum())
         n["moe_max_expert_rows"] += int(c.max(axis=1).sum())
-        if _SHARED in n:
-            n[_SHARED] += (self.cfg.moe_shared_experts * int(c.sum())
+        chosen = int(c.sum())
+        if _CHOSEN in n:
+            chosen += int(np.asarray(returned[0]["left_out"]).sum())
+            n[_CHOSEN] += chosen
+        if _SHARED in n:  # every live row of every layer-call took it
+            n[_SHARED] += (self.cfg.moe_shared_experts * chosen
                            // self.cfg.moe_top_k)
 
     def sample(self, pools) -> None:

@@ -1110,3 +1110,40 @@ def test_glm_check_programs_fit_beside_the_pool(v5e):
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 2.2e9, f"forward: {temp / 1e9:.2f} GB of temporaries"
     assert held + temp < 14.2e9
+
+
+@pytest.mark.parametrize("rows,tokens", [(128, 1), (1, 512), (1, 1152)],
+                         ids=["step_128slots", "chunk_512", "check_prefill"])
+def test_state_space_kernels_compile_at_the_published_sizes(v5e, rows,
+                                                             tokens):
+    """``ops.ssm`` alone at Nemotron-3-Nano's sizes (64 heads of 64 over 8
+    groups, a state of 128, float32 states [rows, 8, 128, 512]): a step over
+    128 slots (ISSUE 59's widest batch), a 512 chunk, and the check prompt's
+    cached prefill in one call of the chunked scan."""
+    from ray_tpu.ops import ssm
+
+    sizes = ssm.SsmSizes(64, 64, 8, 128, 4, 128)
+    chip = SingleDeviceSharding(v5e.devices[0])
+    f32 = functools.partial(_on, chip, dtype=jnp.float32)
+    state = f32(ssm.state_shapes(rows, sizes)["ssm"])
+    if tokens == 1:
+        fn, name = functools.partial(ssm.ssd_step, sizes=sizes,
+                                     impl="pallas"), ssm.STEP_KERNEL
+        args = (_on(chip, (rows, 64, 64)), f32((rows, 64)), f32((64,)),
+                _on(chip, (rows, 8, 128)), _on(chip, (rows, 8, 128)),
+                f32((64,)), state, _on(chip, (rows,), jnp.int32))
+    else:
+        fn, name = (lambda *a: ssm.ssd_chunk(*a[:-1], sizes, a[-1],
+                                             "pallas")), ssm.CHUNK_KERNEL
+        args = (_on(chip, (rows, tokens, 64, 64)), f32((rows, tokens, 64)),
+                f32((64,)), _on(chip, (rows, tokens, 8, 128)),
+                _on(chip, (rows, tokens, 8, 128)), f32((64,)), state,
+                _on(chip, (), jnp.int32))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert _kernel_calls(compiled) == {name: 1}
+    # sizes the kernels do not take are refused, never handed to a
+    # ``jax.numpy`` form in their place; that form runs by name alone
+    toy = ssm.SsmSizes(8, 8, 2, 16, 4, 16)
+    with pytest.raises(ValueError, match="do not take"):
+        ssm.use_kernel(toy)
+    assert not ssm.use_kernel(toy, "reference") and ssm.use_kernel(sizes)
