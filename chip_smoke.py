@@ -85,6 +85,9 @@ weights from ``--seed``):
            still is after the last; then indexed_select alone on a 512
            chunk's rows at 2k, 16k, 38k and 49k of a 49,664-lane table:
            its milliseconds, the passes a tile ran, its choice a sort's;
+           then index_score and indexed_chunk_attention alone at 8k, 20k
+           and 49k of the cell's table, pages in order and permuted over
+           the pool (indexed_timing);
            then the benchmark's own comparison (reference_check with the
            routes given, under the limits of cells/keye_longctx.json) on
            the float8 CONTROL, which has to come out NOT correct
@@ -1181,7 +1184,8 @@ def keye_task(seed: int, control: bool = True) -> dict:
     it), the second's chunks taking the first's decode row along; every
     page no table names filled with NaN in K, V and the index keys, as a
     released page would be. Then the selection alone at the cell's chunk
-    (``select_timing``), and (``control``) the float8 control through the
+    (``select_timing``), the two kernels that read a context alone
+    (``indexed_timing``), and (``control``) the float8 control through the
     harness's own
     comparison under the limits of ``cells/keye_longctx.json``, which must
     refuse it."""
@@ -1329,6 +1333,7 @@ def keye_task(seed: int, control: bool = True) -> dict:
         raise RuntimeError(f"keye: {bad}: {out}")
     del caches
     out["select"] = select_timing(seed, topk, ref)
+    out["indexed_timing"] = indexed_timing(seed, topk)
     if control:
         seen = out["float8_control"] = float8_control(
             cfg, hp, params, seed, ref, "keye", "keye_longctx")
@@ -1917,6 +1922,81 @@ def select_timing(seed: int, topk: int, ref) -> dict:
         passes = np.asarray(passes)[:, ::ia.SELECT_ROWS]
         out[str(end)] = {"ms": round(ms, 4), "passes": [
             int(passes.min()), int(passes.max())]}
+    return out
+
+
+def indexed_timing(seed: int, topk: int) -> dict:
+    """The indexed mixer's two kernels that read a slot's CONTEXT, alone at
+    the cell's shapes (ISSUE 60; pools of 24,833 pages of 16, tables of
+    3,104): ``index_score`` for a 512 chunk's rows and for the step's 8 rows
+    (16 index heads of 64 over the index keys' rows), and
+    ``indexed_chunk_attention`` for the chunk (32 heads over 4 K/V heads of
+    128, the choice from the kernels' own scores and selection), with the
+    queries ending at 8k, 20k and 49k of the table, milliseconds a call (four
+    calls a program, so the device is timed and not the dispatch). Each
+    twice: the slots' pages IN ORDER in the pool and PERMUTED over it
+    (seeded), as an arena that has served a while hands them out —
+    ``*_permuted_ms``. What a call's time holds is whatever stands between
+    the pools and the kernel: a gather of the context, or the kernel's own
+    page copies."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import indexed_attention as ia
+
+    T, P, slots, C, reps = 16, 3104, 8, 512, 4
+    H, G, D, Hi, Di = 32, 4, 128, 16, 64
+    key = jax.random.split(jax.random.PRNGKey(seed & 0x7FFFFFFF), 8)
+    pools = [jax.random.normal(k, (1 + slots * P, T, width), jnp.bfloat16)
+             for k, width in zip(key, (G * D, G * D, Di))]
+    pools[2] = ia.index_row(pools[2])
+    tables = 1 + jnp.arange(slots * P, dtype=jnp.int32).reshape(slots, P)
+    permuted = jnp.asarray(1 + np.random.default_rng(seed).permutation(
+        slots * P).reshape(slots, P), jnp.int32)
+
+    # the pools are arguments: closed over, they would be constants
+    @jax.jit
+    def score(qi, w, ik_pool, tables, positions):
+        return [ia.index_scores(qi * (1 + i), w, ik_pool, tables, positions,
+                                False) for i in range(reps)]
+
+    @jax.jit
+    def attend(q, k_pool, v_pool, tables, positions, scores, tau, bound):
+        return [ia._chunk_attention(q * (1 + i), k_pool, v_pool, tables,
+                                    positions, scores, tau, bound, False)
+                for i in range(reps)]
+
+    def ms(fn, *args, calls=8):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return round((time.perf_counter() - t0) / calls / reps * 1e3, 4)
+
+    out = {}
+    for context in (8192, 20480, 49152):
+        pos = jnp.arange(context - C, context, dtype=jnp.int32)[None]
+        step = jnp.full((slots, 1), context - 1, jnp.int32)
+        rows = lambda k, n, K: (
+            jax.random.normal(key[k], (n, K, Hi, Di), jnp.bfloat16),
+            jax.random.normal(key[k + 1], (n, K, Hi), jnp.float32))
+        q = jax.random.normal(key[7], (1, C, H, D), jnp.bfloat16)
+        here = out[str(context)] = {}
+        for name, table in (("", tables), ("_permuted", permuted)):
+            scores = score(*rows(3, 1, C), pools[2], table[:1], pos)[0]
+            cut = ia.select(scores, pos, topk, False)
+            here.update({
+                f"score_chunk{name}_ms": ms(score, *rows(3, 1, C), pools[2],
+                                            table[:1], pos),
+                f"score_step_8_rows{name}_ms": ms(
+                    score, *rows(5, slots, 1), pools[2], table, step,
+                    calls=20),
+                f"attention_chunk{name}_ms": ms(
+                    attend, q, *pools[:2], table[:1], pos, scores, *cut)})
     return out
 
 

@@ -34,6 +34,7 @@ from ray_tpu.models.transformer import (ATTENTION, INDEXED, LATENT, LINEAR,
                                         sparse_mix, sparse_pool_pages,
                                         stacked_mlp, state_shapes,
                                         write_pages, written_pages)
+from ray_tpu.ops.indexed_attention import index_width
 from ray_tpu.ops.latent_attention import pool_width
 from ray_tpu.ops.moe import held_index
 from ray_tpu.ops.paged_attention import paged_attention
@@ -426,10 +427,13 @@ class SparsePagedKVCache:
 @dataclasses.dataclass
 class IndexedPagedKVCache:
     """An 'indexed_attention' layer's page pool: k and v as ``PagedKVCache``
-    holds them, and beside them ``ik`` [num_pages, page_tokens, Di], each
-    token's index key (what the layer's indexer scores), under the same
-    page table: a page that is spliced, shared or freed takes its index
-    keys along. The same layout is that layer's CONTIGUOUS cache
+    holds them, and beside them ``ik`` [num_pages, page_tokens, W], each
+    token's index key (what the layer's indexer scores) and zeros up to
+    whole 128-lane tiles (``ops.indexed_attention.index_row``: 64 -> 128;
+    the chip holds a 64-lane row in as many, and writes one only by laying
+    the whole pool out anew), under the same page table: a page that is
+    spliced, shared or freed takes its index keys along. The same layout
+    is that layer's CONTIGUOUS cache
     (``init_caches``), which then carries its ``length``; in the serving
     pool the cursors are the caller's and it is None."""
 
@@ -445,7 +449,7 @@ class IndexedPagedKVCache:
         pool = PagedKVCache.zeros(num_pages, page_tokens, kv_heads, head_dim,
                                   dtype)
         return cls(k=pool.k, v=pool.v, ik=jnp.zeros(
-            (num_pages, page_tokens, index_dim), dtype))
+            (num_pages, page_tokens, index_width(index_dim)), dtype))
 
 
 @jax.tree_util.register_dataclass

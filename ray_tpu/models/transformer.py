@@ -109,7 +109,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import attention
-from ray_tpu.ops.indexed_attention import IndexerSizes, indexed_attention
+from ray_tpu.ops.indexed_attention import (IndexerSizes, index_row,
+                                           index_width, indexed_attention)
 from ray_tpu.ops.latent_attention import (join as latent_row,
                                           latent_attention)
 from ray_tpu.ops.flash_attention import FLASH_LSE, FLASH_OUT
@@ -1209,11 +1210,12 @@ def index_project(cfg, p, x, positions):
 def indexed_project(cfg, p, x, rope, positions):
     """What an 'indexed_attention' layer makes of the normalized x [B, S,
     d]: (the rows that attend — q [B, S, H, D], qI, w —, what they leave in
-    the pages — k, v [B, S, Hkv, D] and the index key [B, S, 1, Di], in the
-    order of the pools)."""
+    the pages — k, v [B, S, Hkv, D] and the index key as the pool holds it
+    [B, S, 1, W] (``ops.indexed_attention.index_row``), in the order of the
+    pools)."""
     q, k, v = _qkv(cfg, p, x, rope, positions, INDEXED)
     qi, ki, w = index_project(cfg, p, x, positions)
-    return (q, qi, w), (k, v, ki[:, :, None])
+    return (q, qi, w), (k, v, index_row(ki)[:, :, None])
 
 
 def indexed_mix(cfg, rows, pools, read_tables, positions, lengths, *,
@@ -1221,7 +1223,7 @@ def indexed_mix(cfg, rows, pools, read_tables, positions, lengths, *,
     """'indexed_attention', one group, its keys, values and index keys
     already in the pool: rows = (q [B, S, H, D], qI, w) at ``positions``
     [B, S] attend the tokens their indexer picks. ``pools`` = (k [N, T, Hkv
-    * D], v, the index keys [N, T, Di]), a row's pages through
+    * D], v, the index keys [N, T, W]), a row's pages through
     ``read_tables`` [B, P], ``lengths`` [B] as ``ops.paged_attention``
     takes them. Returns (o [B, S, H, D], the choice, bool [B, S,
     context])."""
@@ -1373,7 +1375,7 @@ def _mixer(cfg, kind, p, x, rope, positions, sp_axis, cache, taps):
             pools = tuple(
                 jnp.zeros((n, OWN_PAGE_TOKENS, width), cfg.dtype)
                 for width in (cfg.kv_heads * cfg.head_dim,) * 2
-                + (cfg.indexer.indexer_head_dim,))
+                + (index_width(cfg.indexer.indexer_head_dim),))
             length = jnp.zeros((), jnp.int32)
         else:
             pools, length = (cache.k, cache.v, cache.ik), cache.length

@@ -4,8 +4,11 @@ DeepSeek-Sparse-Attention indexer of the ``indexed_attention`` mixer).
 A query attends ``topk`` single TOKENS of its context, picked by a learned
 scorer that keeps a cache of its own: beside keys and values a layer keeps
 one INDEX KEY a token (``index_dim`` values, one head), in pages of the same
-table. For the query at position ``t``, with its ``Hi`` index queries ``qI``
-and their weights ``w`` (already scaled):
+table, a row of whole 128-lane tiles a token (``index_row``: the key, then
+zeros, which change no product — a row the chip holds without padding and
+writes in place, where a 64-lane row made every write a relayout of the
+whole pool). No program copies a pool. For the query at position ``t``, with
+its ``Hi`` index queries ``qI`` and their weights ``w`` (already scaled):
 
   1. ``index_scores``: ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])``
      over the slot's paged index keys, float32 (the kernel ``index_score``;
@@ -31,7 +34,11 @@ and their weights ``w`` (already scaled):
      kernel over the slot's context that rebuilds the choice of a (query
      tile, key tile) from the scores and the two numbers
      (``indexed_chunk_attention``): its time follows the context, not the
-     choice (PERF.md 7).
+     choice (PERF.md 7). It takes the K and V pools and the page table: a
+     key tile's pages are copied into fast memory by the kernel, the next
+     tile's while this one is computed on, once a CELL of query tiles
+     (``cell_tokens``), each of which meets the tile while it is there. No
+     gathered copy of a slot's K and V exists.
 
 The kernels carry those names in a profiler trace and are interpreted off a
 TPU. What is chosen is returned on request (``return_selected``), bool [B,
@@ -53,7 +60,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops._pallas import should_interpret
-from ray_tpu.ops.paged_attention import NEG_INF, paged_attention
+from ray_tpu.ops.paged_attention import _LANES, NEG_INF, paged_attention
 
 _KEY_TILE = 512      # context tokens of one grid cell of a kernel
 _SCORE_TOKENS = 128  # query tokens of one tile of the score kernel
@@ -61,7 +68,8 @@ SELECT_ROWS = 8      # query rows of one tile of the selection kernel
 _SELECT_LANES = 4096  # context tokens of one segment of its passes' walk,
 _FOLD_LANES = 1024    # which a pass folds into so many before it sums them
 _LOOK_AFTER, _LOOK_EVERY = 20, 4  # passes before a tile asks: all decided?
-_ATTN_ROWS = 2048    # query rows (tokens x heads) of one tile of the chunk
+_ATTN_ROWS = 2048    # query rows (tokens x heads) of one tile of the chunk,
+_CELL_TILES = 8      # of which so many meet a key tile while it is in VMEM
 _INT_MIN = -2 ** 31
 
 
@@ -95,8 +103,26 @@ class IndexerSizes:
 
 def chunk_tokens(num_heads: int) -> int:
     """Query tokens of one tile of the chunk's kernel, every head's row of
-    each among its ``_ATTN_ROWS`` (the scheduler's counters mirror it)."""
+    each among its ``_ATTN_ROWS``."""
     return max(_ATTN_ROWS // num_heads // 8 * 8, 8)
+
+
+def cell_tokens(num_heads: int) -> int:
+    """Query tokens of one cell of the chunk's kernel: the tiles a key tile
+    meets for one copy of its pages (the scheduler's counters mirror it)."""
+    return _CELL_TILES * chunk_tokens(num_heads)
+
+
+def index_width(index_dim: int) -> int:
+    """Lanes of a token's index key in the pool: whole 128-lane tiles."""
+    return -(-index_dim // _LANES) * _LANES
+
+
+def index_row(x):
+    """An index key (or query) x [..., Di] as the pool holds it: zeros up to
+    whole lane tiles, which change no product."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [
+        (0, index_width(x.shape[-1]) - x.shape[-1])])
 
 
 def context_tokens(pages: int, page_tokens: int) -> int:
@@ -108,21 +134,27 @@ def context_tokens(pages: int, page_tokens: int) -> int:
 def _context(tables, page_tokens: int):
     """A row's table padded to whole key tiles (with the garbage page, whose
     tokens lie behind every position of the row) and the tokens it spans."""
+    if _KEY_TILE % page_tokens:
+        raise ValueError(f"a key tile of {_KEY_TILE} tokens is whole pages: "
+                         f"page_tokens {page_tokens}")
     ctx = context_tokens(tables.shape[1], page_tokens)
-    tables = jnp.pad(tables, ((0, 0), (
+    tables = jnp.pad(tables.astype(jnp.int32), ((0, 0), (
         0, ctx // page_tokens - tables.shape[1])))
     return tables, ctx
 
 
-def _tiles(positions, tokens: int):
-    """positions [B, S] padded to whole tiles of ``tokens`` rows (the edge
-    repeated) -> (padded [B, Sp], key tiles a query tile reaches [B, n])."""
+def _tiles(positions, tokens: int, cell: int = 1):
+    """positions [B, S] padded to whole cells of ``cell`` tiles of ``tokens``
+    rows (the edge repeated) -> (padded [B, Sp], the key tiles each query
+    tile reaches [B, n]: at least one, and none for a tile that is padding
+    alone)."""
     B, S = positions.shape
-    n = -(-S // tokens)
+    n = -(-S // (tokens * cell)) * cell
     pos = jnp.pad(positions.astype(jnp.int32),
                   ((0, 0), (0, n * tokens - S)), mode="edge")
     nkt = jnp.maximum(pos.reshape(B, n, tokens).max(axis=-1), 0) // _KEY_TILE
-    return pos, (nkt + 1).astype(jnp.int32)
+    real = jnp.arange(n, dtype=jnp.int32) * tokens < S
+    return pos, jnp.where(real, nkt + 1, 0).astype(jnp.int32)
 
 
 # ------------------------------------------------------------ index scores
@@ -145,20 +177,26 @@ def _score_kernel(nkt_ref, q_ref, w_ref, k_ref, o_ref, *, heads, tokens):
 
 def index_scores(qi, w, ik_pool, tables, positions, interpret: bool):
     """qi [B, S, Hi, Di], w [B, S, Hi] float32, the index keys' pool [N, T,
-    Di] through ``tables`` [B, P] -> I [B, S, context] float32 (``context``:
-    the table's tokens in whole key tiles). Entries behind a query's
-    position are not all computed: every reader masks by position."""
-    B, S, Hi, Di = qi.shape
+    W] (``index_row``'s rows) through ``tables`` [B, P] -> I [B, S, context]
+    float32 (``context``: the table's tokens in whole key tiles). Entries
+    behind a query's position are not all computed: every reader masks by
+    position. The rows' index keys are gathered over the table's width for
+    the kernel, 256 B a token in whole lane tiles: a kernel that copies
+    these thin pages (4 KB) in itself, as the chunk's attention does its
+    K and V, lost to the gather at the chunk's shape and at the step's
+    (PERF.md 6, PR 60)."""
+    B, S, Hi, _ = qi.shape
+    W = ik_pool.shape[2]
     tables, ctx = _context(tables, ik_pool.shape[1])
-    keys = ik_pool[tables].reshape(B, ctx, Di)
+    keys = ik_pool[tables].reshape(B, ctx, W)
     tokens = min(_SCORE_TOKENS, -(-S // 8) * 8)
     pos, nkt = _tiles(positions, tokens)
     n_qt, Sp = nkt.shape[1], pos.shape[1]
     # rows of a tile stand head-major: row j * tokens + i = (head j, token i)
     pad = ((0, 0), (0, Sp - S), (0, 0))
-    qr = jnp.pad(qi, pad + ((0, 0),)).reshape(
-        B, n_qt, tokens, Hi, Di).transpose(0, 1, 3, 2, 4).reshape(
-        B, n_qt, Hi * tokens, Di).astype(ik_pool.dtype)
+    qr = jnp.pad(qi, pad + ((0, W - qi.shape[3]),)).reshape(
+        B, n_qt, tokens, Hi, W).transpose(0, 1, 3, 2, 4).reshape(
+        B, n_qt, Hi * tokens, W).astype(ik_pool.dtype)
     wr = jnp.pad(w.astype(jnp.float32), pad).reshape(B, n_qt, tokens, Hi)
     reach = lambda b, qt, kt, nkt: jnp.minimum(kt, nkt[b, qt] - 1)
     out = pl.pallas_call(
@@ -166,11 +204,11 @@ def index_scores(qi, w, ik_pool, tables, positions, interpret: bool):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(B, n_qt, ctx // _KEY_TILE),
             in_specs=[
-                pl.BlockSpec((None, None, Hi * tokens, Di),
+                pl.BlockSpec((None, None, Hi * tokens, W),
                              lambda b, qt, kt, nkt: (b, qt, 0, 0)),
                 pl.BlockSpec((None, None, tokens, Hi),
                              lambda b, qt, kt, nkt: (b, qt, 0, 0)),
-                pl.BlockSpec((None, _KEY_TILE, Di),
+                pl.BlockSpec((None, _KEY_TILE, W),
                              lambda b, qt, kt, nkt: (
                                  b, reach(b, qt, kt, nkt), 0))],
             out_specs=pl.BlockSpec(
@@ -411,24 +449,75 @@ def chosen(scores, positions, tau, bound, first=0):
 # --------------------------------------------------------- chunk attention
 
 
-def _chunk_kernel(nkt_ref, q_ref, k_ref, v_ref, s_ref, cut_ref, o_ref,
+def _tile_copies(tables_ref, pools, bufs, sems, b, kt, slot, wait=False):
+    """Start (or wait for) the copies of row ``b``'s key tile ``kt``: its
+    pages, out of each pool [N, T, W] into ``slot`` of the pool's buffer [2,
+    _KEY_TILE, W]."""
+    T = pools[0].shape[1]
+    per_tile = _KEY_TILE // T
+
+    def page(i, _):
+        # a wait reads the sizes only
+        pid = 0 if wait else tables_ref[b, kt * per_tile + i]
+        for pool, buf in zip(pools, bufs):
+            cp = pltpu.make_async_copy(
+                pool.at[pid], buf.at[slot, pl.ds(pl.multiple_of(i * T, T), T)],
+                sems.at[slot])
+            cp.wait() if wait else cp.start()
+        return 0
+
+    lax.fori_loop(0, per_tile, page, 0)
+
+
+def _chunk_kernel(reach_ref, tables_ref, nkt_ref, q_ref, k_pool, v_pool,
+                  s_ref, cut_ref, o_ref, k_buf, v_buf, sems, turn,
                   m_scr, l_scr, acc_scr, *, kv_heads, group, tokens,
                   head_dim, sm_scale):
-    """One (row, query tile, key tile) cell, every head: the tile's choice
-    is rebuilt once from the index scores and serves all heads."""
-    b, qt, kt = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    """One (row b, cell c of query tiles, key tile kt) step. The rows'
+    contexts are read out of their pages through the tables [B, P] (scalar
+    prefetch): cell (b, c) meets the key tiles 0 .. ``reach_ref[b, c]`` - 1,
+    at least one, each copied page by page into one of two buffers while the
+    step before it computes — the same cell's next tile, else the first of
+    the next cell or row; ``turn`` (SMEM [1]) hands the buffer from step to
+    step, so the grid is walked in order. A tile in VMEM meets every query
+    tile of the cell that reaches it, every head; a (query tile, key
+    tile)'s choice is rebuilt once from the index scores and serves all
+    heads."""
+    b, c, kt = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    n_b, n_c = pl.num_programs(0), pl.num_programs(1)
+    n_tiles = q_ref.shape[0]
+    copies = functools.partial(_tile_copies, tables_ref, (k_pool, v_pool),
+                               (k_buf, v_buf), sems)
+
+    def tiles(fn):
+        """``fn(j)`` for every query tile of the cell."""
+        def one(j, carry):
+            fn(j)
+            return carry
+
+        if n_tiles == 1:
+            fn(0)
+        else:
+            lax.fori_loop(0, n_tiles, one, None)
+
+    def clear(j):
+        m_scr[j] = jnp.full(m_scr.shape[1:], NEG_INF, jnp.float32)
+        l_scr[j] = jnp.zeros(l_scr.shape[1:], jnp.float32)
+        acc_scr[j] = jnp.zeros(acc_scr.shape[1:], jnp.float32)
 
     @pl.when(kt == 0)
     def _():
-        m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
-        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
-        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+        tiles(clear)
 
-    @pl.when(kt < nkt_ref[b, qt])
-    def _():
-        cut = cut_ref[...]                                 # [tokens, 128]
+        @pl.when(jnp.logical_and(b == 0, c == 0))
+        def _():
+            turn[0] = 0
+            copies(0, 0, 0)
+
+    def meet(j, slot):
+        cut = cut_ref[j]                                   # [tokens, 128]
         pos = cut[:, 0:1].astype(jnp.int32)
-        take = chosen(s_ref[...], pos, cut[:, 1:2],
+        take = chosen(s_ref[j], pos, cut[:, 1:2],
                       cut[:, 2:3].astype(jnp.int32), first=kt * _KEY_TILE)
         bias = jnp.where(take, 0.0, NEG_INF).astype(jnp.float32)
         bias = jnp.concatenate([bias] * group, axis=0)    # [G * tokens, tk]
@@ -436,41 +525,57 @@ def _chunk_kernel(nkt_ref, q_ref, k_ref, v_ref, s_ref, cut_ref, o_ref,
         for h in range(kv_heads):
             rows = slice(h * R, (h + 1) * R)
             lanes = slice(h * head_dim, (h + 1) * head_dim)
-            s = lax.dot_general(q_ref[rows], k_ref[:, lanes],
+            s = lax.dot_general(q_ref[j, rows], k_buf[slot, :, lanes],
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
             s = s * sm_scale + bias
-            m = m_scr[rows]
+            m = m_scr[j, rows]
             m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m - m_new)
             pr = jnp.exp(s - m_new)
-            l_scr[rows] = l_scr[rows] * alpha + jnp.sum(pr, axis=1,
-                                                        keepdims=True)
-            acc_scr[rows] = acc_scr[rows] * alpha + lax.dot_general(
-                pr.astype(v_ref.dtype), v_ref[:, lanes],
+            l_scr[j, rows] = l_scr[j, rows] * alpha + jnp.sum(
+                pr, axis=1, keepdims=True)
+            acc_scr[j, rows] = acc_scr[j, rows] * alpha + lax.dot_general(
+                pr.astype(v_buf.dtype), v_buf[slot, :, lanes],
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            m_scr[rows] = m_new
+            m_scr[j, rows] = m_new
 
-    @pl.when(kt == pl.num_programs(2) - 1)
+    @pl.when(kt < reach_ref[b, c])
     def _():
-        l = l_scr[...]
-        o_ref[...] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+        slot = turn[0]
+        more = kt + 1 < reach_ref[b, c]
+        same_row = jnp.logical_or(more, c + 1 < n_c)
+
+        @pl.when(jnp.logical_or(same_row, b + 1 < n_b))
+        def _():
+            copies(jnp.where(same_row, b, b + 1), jnp.where(more, kt + 1, 0),
+                   1 - slot)
+
+        copies(b, kt, slot, wait=True)
+        tiles(lambda j: pl.when(kt < nkt_ref[b, c * n_tiles + j])(
+            lambda: meet(j, slot)))
+        turn[0] = 1 - slot
+
+    def finish(j):
+        l = l_scr[j]
+        o_ref[j] = (acc_scr[j] / jnp.where(l == 0.0, 1.0, l)).astype(
             o_ref.dtype)
+
+    pl.when(kt == pl.num_programs(2) - 1)(lambda: tiles(finish))
 
 
 def _chunk_attention(q, k_pool, v_pool, tables, positions, scores, tau,
                      bound, interpret: bool):
-    """q [B, S, H, D] at ``positions`` [B, S] over each row's own context
-    (its pages, gathered into one run), every query over its own choice."""
+    """q [B, S, H, D] at ``positions`` [B, S] over each row's own context,
+    read out of its pages through ``tables``, every query over its own
+    choice."""
     B, S, H, D = q.shape
-    T = k_pool.shape[1]
     Hkv = k_pool.shape[2] // D
-    G = H // Hkv
-    tables, ctx = _context(tables, T)
-    view = lambda pool: pool[tables].reshape(B, ctx, Hkv * D)
+    tables, ctx = _context(tables, k_pool.shape[1])
     tokens = min(chunk_tokens(H), -(-S // 8) * 8)
-    pos, nkt = _tiles(positions, tokens)
+    C = min(_CELL_TILES, -(-S // tokens))                  # tiles a cell
+    pos, nkt = _tiles(positions, tokens, C)
     n_qt, Sp = nkt.shape[1], pos.shape[1]
     R = H * tokens
     # rows of a tile stand head-major: row n * tokens + i = (head n, token i)
@@ -482,34 +587,37 @@ def _chunk_attention(q, k_pool, v_pool, tables, positions, scores, tau,
                      edge(bound).astype(jnp.float32)], axis=-1)
     cut = jnp.pad(cut, ((0, 0), (0, 0), (0, 125)))        # [B, Sp, 128]
     sc = jnp.pad(scores, ((0, 0), (0, Sp - S), (0, 0)))
-    cell = lambda b, qt, kt, nkt: (b, qt, 0, 0)
-    reach = lambda b, qt, kt, nkt: jnp.minimum(kt, nkt[b, qt] - 1)
-    keys = lambda b, qt, kt, nkt: (b, reach(b, qt, kt, nkt), 0)
+    reach = nkt.reshape(B, n_qt // C, C).max(axis=-1)
+    cell = lambda b, c, kt, *_: (b, c, 0, 0)
     out = pl.pallas_call(
-        functools.partial(_chunk_kernel, kv_heads=Hkv, group=G,
+        functools.partial(_chunk_kernel, kv_heads=Hkv, group=H // Hkv,
                           tokens=tokens, head_dim=D,
                           sm_scale=1.0 / math.sqrt(D)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(B, n_qt, ctx // _KEY_TILE),
+            num_scalar_prefetch=3, grid=(B, n_qt // C, ctx // _KEY_TILE),
             in_specs=[
-                pl.BlockSpec((None, None, R, D), cell),
-                pl.BlockSpec((None, _KEY_TILE, Hkv * D), keys),
-                pl.BlockSpec((None, _KEY_TILE, Hkv * D), keys),
-                pl.BlockSpec((None, tokens, _KEY_TILE),
-                             lambda b, qt, kt, nkt: (
-                                 b, qt, reach(b, qt, kt, nkt))),
-                pl.BlockSpec((None, tokens, 128),
-                             lambda b, qt, kt, nkt: (b, qt, 0))],
-            out_specs=pl.BlockSpec((None, None, R, D), cell),
-            scratch_shapes=[pltpu.VMEM((R, 1), jnp.float32),
-                            pltpu.VMEM((R, 1), jnp.float32),
-                            pltpu.VMEM((R, D), jnp.float32)]),
+                pl.BlockSpec((None, C, R, D), cell),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec((None, C, tokens, _KEY_TILE),
+                             lambda b, c, kt, reach, *_: (
+                                 b, c, 0, jnp.minimum(kt, reach[b, c] - 1))),
+                pl.BlockSpec((None, C, tokens, 128), cell)],
+            out_specs=pl.BlockSpec((None, C, R, D), cell),
+            scratch_shapes=[
+                pltpu.VMEM((2, _KEY_TILE, Hkv * D), k_pool.dtype),
+                pltpu.VMEM((2, _KEY_TILE, Hkv * D), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((C, R, 1), jnp.float32),
+                pltpu.VMEM((C, R, 1), jnp.float32),
+                pltpu.VMEM((C, R, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, n_qt, R, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",) * 3,
             vmem_limit_bytes=96 << 20),
         name="indexed_chunk_attention", interpret=interpret,
-    )(nkt, qr, view(k_pool), view(v_pool), sc, cut)
+    )(reach, tables, nkt, qr, k_pool, v_pool,
+      sc.reshape(B, n_qt, tokens, ctx), cut.reshape(B, n_qt, tokens, 128))
     out = out.reshape(B, n_qt, H, tokens, D).transpose(0, 1, 3, 2, 4)
     return out.reshape(B, Sp, H, D)[:, :S]
 
@@ -551,8 +659,9 @@ def indexed_attention(q, qi, w, k_pool, v_pool, ik_pool, tables, positions,
     """Attention of q [B, S, H, D] at ``positions`` [B, S] over the tokens
     each query's indexer picks, through its row's page table. qi [B, S, Hi,
     Di] and w [B, S, Hi] float32: the index queries and their weights;
-    k_pool/v_pool: [N, T, Hkv * D]; ik_pool: [N, T, Di], the index keys (all
-    three already written for the rows' own tokens); tables: [B, P];
+    k_pool/v_pool: [N, T, Hkv * D]; ik_pool: [N, T, W], the index keys in
+    ``index_row``'s rows (all three already written for the rows' own
+    tokens); tables: [B, P];
     lengths: [B], as ``paged_attention`` takes them (a row whose window lies
     before position 0 attends nothing). ``impl``: what the paged kernel runs
     as for a step ('reference' | 'pallas'). Returns [B, S, H, D], and with

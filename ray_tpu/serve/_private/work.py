@@ -29,7 +29,7 @@ from ray_tpu._private.metrics import Counter
 from ray_tpu.models.transformer import (ATTENTION, INDEXED, LATENT, LINEAR,
                                         MAMBA, RETENTION, SLIDING, SPARSE,
                                         STATE_KINDS, holds_page, state_shapes)
-from ray_tpu.ops.indexed_attention import (SELECT_ROWS, chunk_tokens,
+from ray_tpu.ops.indexed_attention import (SELECT_ROWS, cell_tokens,
                                            context_tokens, select_lanes)
 from ray_tpu.ops.latent_attention import latent_tiles, pool_width
 from ray_tpu.ops.paged_attention import streamed_tokens, tile_sizes
@@ -123,9 +123,10 @@ def _indexed(work, n, layers, qk, cursors, real):
     and of the three a step's share. Returns (attended, fetched) a layer as
     the paged kernel's are counted: a step reads its rows' chosen tokens
     once, gathered into a run of their own (every K/V head shares the
-    choice); a chunk (one row) streams its slot's context up to each tile
-    of queries in whole key tiles of 512, which attend their mean choice of
-    it."""
+    choice); a chunk (one row) streams its slot's context up to each CELL
+    of query tiles (``ops.indexed_attention.cell_tokens``: a key tile is
+    copied in once a cell) in whole key tiles of 512, which attend their
+    mean choice of it."""
     sizes = work.cfg.indexer
     # the selection's kernel: a chunk's rows are all ``qk`` of its tokens;
     # a step's are the slots, the live ones at their cursors and the others
@@ -147,8 +148,8 @@ def _indexed(work, n, layers, qk, cursors, real):
         n["indexed_step_tokens_attended"] += layers * attended
         n["indexed_step_tokens_context"] += layers * context
         return attended, attended
-    starts = np.arange(0, real, work.indexed_chunk_tokens)
-    ends = np.minimum(starts + work.indexed_chunk_tokens, real)
+    starts = np.arange(0, real, work.indexed_cell_tokens)
+    ends = np.minimum(starts + work.indexed_cell_tokens, real)
     att = sizes.attended_tokens(t[0])
     return (sum(int(att[lo:hi].mean()) for lo, hi in zip(starts, ends)),
             int((-(-(t[0, 0] + ends) // 512)).sum()) * 512)
@@ -258,7 +259,7 @@ class Work:
                 sizes.max_chosen_blocks() * sizes.pages_per_block,
                 self._row_bytes)[0]
         if INDEXED in kinds:
-            self.indexed_chunk_tokens = chunk_tokens(cfg.num_heads)
+            self.indexed_cell_tokens = cell_tokens(cfg.num_heads)
             self.slots = slots
             self.indexed_context = context_tokens(pages_per_slot,
                                                   page_tokens)

@@ -283,8 +283,9 @@ def test_the_selection_walks_a_tiles_own_context(name, behind):
 def test_the_score_kernel_is_the_references_scores():
     qi, w, ki = _scores(5, rows=40, keys=200)
     T, P = 8, 26
-    pool = jnp.zeros((1 + P, T, 16)).at[1:].set(
-        jnp.pad(ki[0], ((0, P * T - 200), (0, 0))).reshape(P, T, 16))
+    pool = jnp.zeros((1 + P, T, ia.index_width(16))).at[1:].set(
+        jnp.pad(ia.index_row(ki[0]), ((0, P * T - 200), (0, 0))).reshape(
+            P, T, -1))
     tables = 1 + jnp.arange(P, dtype=jnp.int32)[None]
     positions = (160 + jnp.arange(40, dtype=jnp.int32))[None]
     with jax.default_matmul_precision("highest"):
@@ -292,6 +293,133 @@ def test_the_score_kernel_is_the_references_scores():
         want = ref.score_block(qi, w, ki)
     assert got.shape == (1, 40, 512)
     np.testing.assert_allclose(got[0, :, :200], want[0], atol=1e-5)
+
+
+# ------------------------------------- a context out of its scattered pages
+
+
+def _scattered(seed, B, T=8, P=80, G=2, D=16, Di=16):
+    """``B`` slots' contexts in pools whose pages lie SCATTERED (a seeded
+    permutation) and IN ORDER: random keys, values and index keys of ``P *
+    T`` tokens a slot, through tables [B, P]. The garbage page (0, which
+    pads a table to whole key tiles) holds finite junk; every page no table
+    names is NaN. Returns (the contiguous k, v [B, P * T, G, D] and ki [B,
+    P * T, Di], {"scattered" | "in_order": (pools, tables)})."""
+    key = jax.random.split(jax.random.PRNGKey(seed), 4)
+    k = jax.random.normal(key[0], (B, P * T, G, D))
+    v = jax.random.normal(key[1], (B, P * T, G, D))
+    ki = jax.random.normal(key[2], (B, P * T, Di))
+    N = 1 + B * P + 7
+    layouts = {}
+    for name, order in (
+            ("scattered", np.random.default_rng(seed).permutation(N - 1)),
+            ("in_order", np.arange(N - 1))):
+        tables = 1 + order[:B * P].reshape(B, P).astype(np.int32)
+        pools = []
+        for rows in (k.reshape(B, P * T, -1), v.reshape(B, P * T, -1),
+                     ia.index_row(ki)):
+            pool = jnp.full((N, T, rows.shape[-1]), jnp.nan).at[0].set(
+                1e3 * jax.random.normal(key[3], (T, rows.shape[-1])))
+            pools.append(pool.at[tables].set(
+                rows.reshape(B, P, T, rows.shape[-1])))
+        layouts[name] = (pools, jnp.asarray(tables))
+    return k, v, ki, layouts
+
+
+@pytest.mark.parametrize("tiles", [
+    {}, {"_ATTN_ROWS": 32, "_CELL_TILES": 2, "_SCORE_TOKENS": 16}],
+    ids=["one_tile", "cells_of_two_tiles"])
+def test_the_kernels_read_a_context_through_a_permuted_table(monkeypatch,
+                                                              tiles):
+    """ISSUE 60: ``index_score`` and ``indexed_chunk_attention`` take the
+    pools and the table, and copy a key tile's pages in themselves. Two
+    slots whose pages lie scattered over the pool, 40 queries each that end
+    at 600 and at 530 of 640 tokens a table (across the edge of the first
+    key tile, 512, and page edges; the second tile's tail is the garbage
+    page), float32: the scores of every token a query sees and the attention
+    over the kernel's own choice against their ``jax.numpy`` forms at 1e-6.
+    Once with every query in ONE tile, once in tiles of 8 tokens, two a cell
+    (five tiles: the third cell's second tile is padding alone) and score
+    tiles of 16."""
+    for name, value in tiles.items():
+        monkeypatch.setattr(ia, name, value)
+    H, topk = 4, 16
+    ends = (600, 530)
+    k, v, ki, layouts = _scattered(11, len(ends))
+    pools, tables = layouts["scattered"]
+    key = jax.random.split(jax.random.PRNGKey(12), 3)
+    q = jax.random.normal(key[0], (2, 40, H, 16))
+    qi = jax.random.normal(key[1], (2, 40, 16, 16))
+    w = jax.random.normal(key[2], (2, 40, 16))
+    positions = jnp.asarray([np.arange(e - 40, e) for e in ends], jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        scores = ia.index_scores(qi, w, pools[2], tables, positions, True)
+        assert scores.shape == (2, 40, 1024)
+        want = ref.score_block(qi, w, ki)
+        seen = np.arange(640)[None, None] <= np.asarray(positions)[..., None]
+        assert rel(np.where(seen, scores[..., :640], 0.0),
+                   np.where(seen, want, 0.0)) <= 1e-6
+        tau, bound = ia.select(scores, positions, topk, True)
+        take = ia.chosen(scores, positions[..., None], tau[..., None],
+                         bound[..., None])
+        assert (np.asarray(take).sum(-1) == topk).all()
+        got = ia._chunk_attention(q, *pools[:2], tables, positions, scores,
+                                  tau, bound, True)
+        assert np.isfinite(np.asarray(got)).all()
+        assert rel(got, np.asarray(ref.attend_block(
+            q, k, v, take[..., :640]))) <= 1e-6
+
+
+@pytest.mark.parametrize("rows", [40, 1], ids=["chunk", "step"])
+def test_the_choice_through_a_permuted_table_is_the_choice_in_order(rows):
+    """The op whole, three slots at 600, 530 and 77 tokens: pages scattered
+    over the pool or one run in order, the choice (``return_selected``) is
+    the same to the token and the output to the bit — where a page lies
+    changes no product."""
+    ends = (600, 530, 77)
+    _, _, _, layouts = _scattered(21, len(ends))
+    key = jax.random.split(jax.random.PRNGKey(22), 3)
+    q = jax.random.normal(key[0], (3, rows, 4, 16))
+    qi = jax.random.normal(key[1], (3, rows, 16, 16))
+    w = jax.random.normal(key[2], (3, rows, 16))
+    positions = jnp.asarray([np.arange(e - rows, e) for e in ends], jnp.int32)
+    sizes = ia.IndexerSizes(indexer_num_heads=16, indexer_head_dim=16,
+                            topk=16)
+    with jax.default_matmul_precision("highest"):
+        (a, chose_a), (b, chose_b) = (ia.indexed_attention(
+            q, qi, w, *pools, tables, positions, positions[:, 0], sizes,
+            impl="pallas", return_selected=True)
+            for pools, tables in layouts.values())
+    assert (np.asarray(chose_a).sum(-1) == 16).all()
+    np.testing.assert_array_equal(chose_a, chose_b)
+    np.testing.assert_array_equal(a, b)
+
+
+def test_the_contiguous_cache_and_the_pool_hold_one_row(toy):
+    """An index key lies in a row of whole lane tiles, the key and then
+    zeros, in the serving pool and in the contiguous cache alike: the same
+    prompt through ``prefill`` and through a paged chunk leaves the same
+    rows."""
+    cfg, params, tokens = toy["cfg"], toy["params"], toy["tokens"]
+    Di = cfg.indexer.indexer_head_dim
+    W = ia.index_width(Di)
+    assert (W, ia.index_width(64), ia.index_width(129)) == (128, 128, 256)
+    n, T, P = 16, 4, 8
+    own = init_caches(cfg, 1, n)
+    pool = init_paged_caches(cfg, 1 + P, T, P)
+    assert all(c.ik.shape[1:] == (16, W) for c in own)
+    assert all(c.ik.shape == (1 + P, T, W) for c in pool)
+    table = 1 + jnp.arange(P, dtype=jnp.int32)[::-1]
+    with jax.default_matmul_precision("highest"):
+        _, own = prefill(cfg, params, tokens[:1, :n], own)
+        _, pool, *_ = paged_prefill_into_slot(
+            cfg, params, tokens[:1, :n], n, np.int32(0), table, table, pool,
+            jnp.zeros((1,), jnp.int32), np.int32(-1), np.float32(0),
+            np.uint32(0), None, attn="pallas")
+    for a, b in zip(own, pool):
+        rows = np.asarray(b.ik[table[:n // T]]).reshape(n, W)
+        np.testing.assert_allclose(np.asarray(a.ik[1])[:n], rows, atol=1e-6)
+        assert np.abs(rows[:, :Di]).min() > 0 and not rows[:, Di:].any()
 
 
 # ------------------------------------------------- the uncached forward

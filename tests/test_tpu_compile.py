@@ -925,6 +925,27 @@ def test_brumby_serve_programs_compile_and_fit(v5e):
         assert total < 14.6e9, f"{name}: {total / 1e9:.1f} GB"
 
 
+_KEYE_COMPILED = {}
+
+
+def _keye_compiled(v5e):
+    """``keye_longctx``'s two serving programs compiled once for the tests
+    that read them: (cfg, bytes held, {name: (compiled, arguments)})."""
+    from ray_tpu.ops.paged_attention import resolve_impl
+
+    if not _KEYE_COMPILED:
+        cfg, held, programs = _cell_programs(v5e, "keye_vl2_30b_a3b_l5",
+                                             "keye_longctx")
+        assert resolve_impl(cfg) == "pallas"
+        _KEYE_COMPILED.update(cfg=cfg, held=held, programs={
+            name: (jax.jit(
+                functools.partial(program, cfg, attn="pallas", moe_info=True),
+                donate_argnums=(6,)).lower(*args).compile(), args)
+            for name, (program, args) in programs.items()})
+    return (_KEYE_COMPILED["cfg"], _KEYE_COMPILED["held"],
+            _KEYE_COMPILED["programs"])
+
+
 def test_keye_serve_programs_compile_and_fit(v5e):
     """The benchmark's Keye-VL-2.0-30B-A3B configuration (published widths:
     hidden 2048, 32 query heads over 4 K/V heads of 128, an indexer of 16
@@ -933,19 +954,15 @@ def test_keye_serve_programs_compile_and_fit(v5e):
     rows along and the decode step, the indexer's kernels once a layer and
     group of rows — scores, the counting selection, the chunk's masked
     attention or the step's paged kernel over its gathered run — and the
-    experts' kernel once a layer; 7.50 GB of weights and the 4.32 GB pool
-    (K, V and the index key a token) beside the programs' own memory on one
-    16 GB chip."""
-    from ray_tpu.ops.paged_attention import resolve_impl
-
-    cfg, held, programs = _cell_programs(v5e, "keye_vl2_30b_a3b_l5",
-                                         "keye_longctx")
+    experts' kernel once a layer; 7.50 GB of weights and the 4.58 GB pool
+    (K, V and the index key a token, in a row of 128 lanes: what the chip
+    held for its 64 before) beside the programs' own memory on one 16 GB
+    chip."""
+    cfg, held, programs = _keye_compiled(v5e)
     assert (cfg.embed_dim, cfg.head_dim, cfg.hidden_dim) == (2048, 128, 768)
     assert cfg.num_heads // cfg.kv_heads == 8 and cfg.period == 1
     assert cfg.indexer.topk == 2048 and cfg.mrope_section == (16, 24, 24)
-    lane = resolve_impl(cfg)
-    assert lane == "pallas"
-    assert 11.7e9 < held < 11.9e9
+    assert 11.9e9 < held < 12.2e9
     calls = {"prefill": {"index_score": 10, "indexed_select": 10,
                          "indexed_chunk_attention": 5,
                          "indexed_step_attention": 5,
@@ -953,15 +970,56 @@ def test_keye_serve_programs_compile_and_fit(v5e):
              "decode": {"index_score": 5, "indexed_select": 5,
                         "indexed_step_attention": 5,
                         "moe_grouped_matmul": 5}}
-    for name, (program, args) in programs.items():
-        compiled = jax.jit(
-            functools.partial(program, cfg, attn=lane, moe_info=True),
-            donate_argnums=(6,)).lower(*args).compile()
+    for name, (compiled, _) in programs.items():
         assert _kernel_calls(compiled) == calls[name], name
         total = _fits(compiled)
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert total < 13.2e9, f"{name}: {total / 1e9:.1f} GB"
-        assert temp < 1.3e9, f"{name}: {temp / 1e6:.0f} MB of temporaries"
+        assert temp < 0.3e9, f"{name}: {temp / 1e6:.0f} MB of temporaries"
+
+
+def test_keye_programs_read_their_pools_in_place(v5e):
+    """ISSUE 60, the change's counter — bytes of whole-pool copies a turn,
+    1.53 GB before it, 0 after: at the cell's shapes neither program's
+    compiled text holds a ``copy`` of a pool's shape (a 64-lane index-key
+    row made the write of a layer's keys two relayouts of its whole pool,
+    153 MB each), nor a ``gather`` of a slot's K or V out of its pages over
+    the table's 3,104: the chunk's kernel walks the table itself. The index
+    keys' gather stays, over rows of whole lane tiles (the chip's timing
+    kept it: PERF.md 6, PR 60). The scatters of the new index keys, one a
+    layer, take the donated pool as it came — in the chunk's program, which
+    is every turn of the cell; for the PLAIN step the compiler still
+    prefetches index-key pools into its fast memory in slices for that
+    gather and copies them back (its own doing, and the parent's too:
+    PERF.md 7) — and all fifteen pools are aliased."""
+    cfg, _, programs = _keye_compiled(v5e)
+    names = {"float32": "f32", "bfloat16": "bf16"}
+    for name, (compiled, args) in programs.items():
+        text = compiled.as_text()
+        pools = jax.tree.leaves(args[6])
+        held = {(names[a.dtype.name], ",".join(map(str, a.shape)))
+                for a in pools}
+        copied = held & _copied_shapes(compiled)
+        assert not copied, f"{name}: whole-pool copies of {copied}"
+        assert held == {("bf16", "24833,16,512"), ("bf16", "24833,16,128")}
+        # nor does the compiler move a pool through its fast memory in
+        # slices and back (its own prefetch for the gather), but in the
+        # plain step, where it still takes index-key pools that way
+        moved = set(re.findall(r"(?:slice|copy)-start\(%caches_\d+__(\w+?)[.\d]*\)",
+                               text))
+        assert moved <= ({"ik"} if name == "decode" else set()), (name, moved)
+        contexts = set(re.findall(
+            r"= bf16\[(?:\d+,)?3104,16,(\d+)\]\S* gather\(", text))
+        assert contexts == {"128"}, f"{name}: gathers of contexts {contexts}"
+        written = re.findall(
+            r"= bf16\[24833,16,128\]\S* fusion\(%([\w-]+?)[.\d]*, [^\n]*/scatter\"",
+            text)
+        assert len(written) == cfg.num_layers, (name, written)
+        if name == "prefill":  # (the plain step's prefetched ones apart)
+            assert sorted(written) == [
+                f"caches_{i}__ik" for i in range(cfg.num_layers)], written
+        assert compiled.memory_analysis().alias_size_in_bytes == sum(
+            a.size * a.dtype.itemsize for a in pools)
 
 
 @pytest.mark.parametrize("rows", [(1, 512), (8, 1)])
@@ -993,7 +1051,9 @@ def test_keye_check_programs_fit_beside_the_pool(v5e):
     ``[32, S, S]`` scores: the kind's chunk kernel takes any number of
     rows. The forward's 2.76 GB are its [8832, 151936] bf16 logits, which the
     harness slices behind the program: why the configuration holds 5 layers
-    and not 6 (13.94 GB held would leave them 0.2 GB of slack)."""
+    and not 6 (13.94 GB held would leave them 0.2 GB of slack). ``held``
+    counts an index key at the 128 lanes of its row since PR 60 (0.25 GB
+    more than the 64 it counted before, which the chip held in 128 too)."""
     from perfbench.lib import manifest as manifest_lib
     from perfbench.lib.serve_app import GIVEN_PAD
     from ray_tpu.models.transformer import forward
@@ -1017,7 +1077,7 @@ def test_keye_check_programs_fit_beside_the_pool(v5e):
         params, _on(chip, (1, n + -n % GIVEN_PAD), jnp.int32)).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 2.9e9, f"forward: {temp / 1e9:.2f} GB of temporaries"
-    assert held + temp < 14.8e9
+    assert held + temp < 15.1e9
 
 
 def test_glm_serve_programs_compile_and_fit(v5e):
