@@ -1513,6 +1513,257 @@ def glm_task(seed: int, control: bool = True) -> dict:
     return {**out, **device_report()}
 
 
+def deepseek_task(seed: int, control: bool = True) -> dict:
+    """The DeepSeek-V3.2-Exp-width checks (ISSUE 61): the paged chunk, step
+    and fused turn in bf16 at the published widths (hidden 7168, 128 heads
+    over a latent of 512 and one shared rotated key of 64, an indexer of 64
+    heads of 128 that picks 2048 latents, a dense layer of 18432 then 16
+    HELD of 256 experts of 2048 top-8 in 8 groups beside a shared one; the
+    benchmark's 1 + 4 layers, an eighth of the vocabulary) — which attend
+    the PICKED latents absorbed, gathered through the page table — against
+    the plain float32 reference, which rebuilds every key and value, GIVEN
+    the system's own routes and the tokens its queries attended. Two
+    prompts that end on a page's first token (4113 = 257 x 16 + 1 tokens,
+    past ``topk`` twice over, and 2065 = 129 x 16 + 1, just past it), the
+    second's chunks taking the first's decode row along; then a third
+    sequence that SPLICES the first's leading 2048 tokens as the radix cache
+    would (their pages — latents AND index keys — in its read table and not
+    in its write table) and goes on with a tail of its own, past ``topk``;
+    every page no table names filled with NaN in both arrays, as a released
+    page would be. Then the picked attention alone at the cell's shapes
+    (``picked_timing``), and (``control``) the float8 control through the
+    harness's own comparison under the limits of
+    ``cells/deepseek_v32_longdocs.json``, which must refuse it."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from perfbench.lib import configs, weights
+    from perfbench.lib import manifest as manifest_lib
+    from perfbench.reference import deepseek_v32 as ref
+    from ray_tpu.models.decode import (StepRows, init_paged_caches,
+                                       paged_decode_step,
+                                       paged_prefill_into_slot)
+
+    require_chip()
+    manifest = manifest_lib.load()
+    hp = manifest_lib.config(manifest, "deepseek_v32_exp_l5")
+    cfg = configs.build_program_config(*configs.program_overrides(
+        hp, manifest_lib.read_json_from_bench("families", hp["model_type"])))
+    L, k, topk = cfg.expert_layers, cfg.moe_top_k, cfg.indexer.topk
+    params = weights.make_params(cfg, seed)
+    S, C, T, P, shared = 4, 512, 16, 272, 2048
+    caches = init_paged_caches(cfg, S * P + 1 + 64, T, P)
+    rng = np.random.default_rng(seed)
+    prompts = {0: rng.integers(1, cfg.vocab_size, 4113).tolist(),
+               2: rng.integers(1, cfg.vocab_size, 2065).tolist()}
+    prompts[3] = prompts[0][:shared] + rng.integers(
+        1, cfg.vocab_size, 600).tolist()
+    read = np.zeros((S, P), np.int32)
+    for s in prompts:
+        read[s] = 1 + s * P + np.arange(P)
+    write = read.copy()
+    read[3, :shared // T] = read[0, :shared // T]   # spliced: read only
+    write[3, :shared // T] = 0
+    loose = np.setdiff1d(np.arange(1, S * P + 65), np.union1d(read, write))
+    caches = [dataclasses.replace(c, ckr=c.ckr.at[loose].set(jnp.nan),
+                                  ik=c.ik.at[loose].set(jnp.nan))
+              for c in caches]
+    reads, writes = jnp.asarray(read), jnp.asarray(write)
+
+    prefill = jax.jit(lambda *a: paged_prefill_into_slot(
+        cfg, *a, attn="pallas", moe_info=True, logits=True, selected=True),
+        donate_argnums=(6,))
+    step = jax.jit(lambda *a: paged_decode_step(
+        cfg, *a, attn="pallas", moe_info=True, logits=True, selected=True),
+        donate_argnums=(6,))
+    ids = jnp.zeros(S, jnp.int32)
+    got = {s: [] for s in prompts}
+    taken = {s: [] for s in prompts}
+    picked = {s: [] for s in prompts}
+    fed = {s: [] for s in prompts}
+    active = np.zeros(S, np.int32)
+    cursors = np.zeros(S, np.int32)
+    greedy = (np.zeros(S, np.float32), np.zeros(S, np.uint32))
+    routes_chosen = held_routes = live_rows = 0
+
+    def feed():
+        for s in np.flatnonzero(active):
+            fed[s].append(int(got[s][-1].argmax()))
+
+    def count(moe):
+        nonlocal routes_chosen, held_routes
+        landed = int(np.asarray(moe["counts"]).sum())
+        held_routes += landed
+        routes_chosen += landed + int(np.asarray(moe["left_out"]).sum())
+
+    for s, prompt in prompts.items():
+        start = shared if s == 3 else 0   # the spliced tokens are resident
+        for c0 in range(start, len(prompt), C):
+            chunk = prompt[c0:c0 + C]
+            real = len(chunk)
+            feed()
+            ids, caches, moe, logits, chosen = prefill(
+                params, jnp.asarray([chunk + [0] * (C - real)], jnp.int32),
+                np.int32(real), np.int32(c0), reads[s], writes[s], caches,
+                ids, np.int32(s if c0 + C >= len(prompt) else -1),
+                np.float32(0), np.uint32(0),
+                StepRows(active.copy(), cursors.copy(), reads, writes,
+                         *greedy))
+            routes = np.asarray(moe["routes"])[:, 0]
+            taken[s].append(routes[:, :real])
+            picked[s].append(np.asarray(chosen[0])[:, 0, :real])
+            count(moe)
+            live_rows += real + int(active.sum())
+            for row in np.flatnonzero(active):
+                got[row].append(np.asarray(logits[1 + row], np.float32))
+                taken[row].append(routes[:, C + row:C + row + 1])
+                picked[row].append(np.asarray(chosen[1])[:, row])
+            cursors = cursors + active
+            cursors[s] = c0 + real
+        got[s].append(np.asarray(logits[0], np.float32))
+        active[s] = 1
+    for _ in range(6):
+        feed()
+        ids, caches, moe, logits, chosen = step(
+            params, ids, jnp.asarray(active), cursors, reads, writes, caches,
+            *greedy)
+        cursors = cursors + active
+        count(moe)
+        live_rows += len(prompts)
+        for s in prompts:
+            got[s].append(np.asarray(logits[s], np.float32))
+            taken[s].append(np.asarray(moe["routes"])[:, s])
+            picked[s].append(np.asarray(chosen)[:, s])
+
+    def rel(got, want):
+        return {"max": float(np.abs(got - want).max() / np.abs(want).max()),
+                "rms": float(np.sqrt(((got - want) ** 2).mean()
+                                     / (want ** 2).mean()))}
+
+    errs, flips, own_choice = {}, {}, {}
+    layers = len(cfg.kinds)
+    for s, prompt in prompts.items():
+        tokens = jnp.asarray([prompt + fed[s]], jnp.int32)
+        n, first = tokens.shape[1], len(prompt) - 1
+        routes = np.concatenate(taken[s], axis=1)[:, None]
+        choice = np.concatenate([c[..., :n] for c in picked[s]], 1)[:, None]
+        if s == 3:
+            # the spliced tokens' routes were slot 0's, position by
+            # position; within ``topk`` of the start a query attends every
+            # token before it
+            routes = np.concatenate(
+                [np.concatenate(taken[0], axis=1)[:, None, :shared], routes],
+                axis=2)
+            causal = np.tril(np.ones((shared, n), bool))
+            choice = np.concatenate([np.broadcast_to(
+                causal, (layers, 1, shared, n)), choice], axis=2)
+        counts = choice.sum(-1)[:, 0]
+        if not (counts == np.minimum(np.arange(n) + 1, topk)).all():
+            raise RuntimeError("deepseek: a query did not attend min(t + 1, "
+                               f"topk) tokens: {counts[:, -8:]}")
+        want = ref.forward(params, tokens, hp, jnp.asarray(routes), choice)
+        errs[s] = rel(np.stack(got[s][:-1]), want[0][first:-1])
+        # what the float32 reference's own router and indexer pick, given
+        # the same inputs layer by layer: the share of rows whose top 8 and
+        # of (query, token) choices on which bf16 and float32 part
+        _, _, own = ref.forward_and_choices(
+            params, tokens, hp, jnp.asarray(routes))
+        own_choice[s] = float((own != choice).sum() / choice.sum())
+        _, own_routes, _ = ref.forward_and_choices(
+            params, tokens, hp, None, choice)
+        flips[s] = float((np.sort(own_routes, -1)
+                          != np.sort(routes, -1)).any(-1).mean())
+    poisoned = [bool(jnp.isnan(pool[loose]).all())
+                for c in caches for pool in (c.ckr, c.ik)]
+    out = {"given_err": errs, "flip_share": flips,
+           "choice_differs_share": own_choice,
+           "routes_chosen": routes_chosen, "held_routes": held_routes,
+           "live_rows_x_k_x_layers": live_rows * k * L,
+           "pages_poisoned": int(loose.size)}
+    bad = []
+    if not all(np.isfinite(g).all() for rows in got.values() for g in rows):
+        bad.append("a logit is not finite: a page no table names was read")
+    if not all(poisoned):
+        bad.append("a page no table names was written")
+    if routes_chosen != live_rows * k * L:
+        bad.append("a row was dropped or a dead row counted")
+    if max(e["max"] for e in errs.values()) > 0.08 \
+            or max(e["rms"] for e in errs.values()) > 0.06:
+        bad.append("error given both choices above the dense cells' "
+                   "tolerance")
+    if bad:
+        raise RuntimeError(f"deepseek: {bad}: {out}")
+    del caches
+    out["picked_timing"] = picked_timing(seed)
+    if control:
+        seen = out["float8_control"] = float8_control(
+            cfg, hp, params, seed, ref, "deepseek_v32",
+            "deepseek_v32_longdocs")
+        if seen["checks"]["reference_logits"] \
+                and seen["checks"]["reference_logits_given_choices"]:
+            raise RuntimeError("deepseek: the float8 control passes both "
+                               f"comparisons of logits: {seen}")
+    return {**out, **device_report()}
+
+
+def picked_timing(seed: int, calls: int = 4) -> dict:
+    """The picked latent attention ALONE at the cell's shapes (128 heads
+    over rows of 640 lanes, 64 index heads of 128, 2048 picked, a table of
+    4128 pages PERMUTED over a pool of 33,024, seeded), one layer: a 512
+    chunk at contexts of 8k, 20k and 55k and the step's 8 rows, in
+    milliseconds a call behind a warm-up — the whole op, and of it the
+    indexer's two kernels (``index_score`` + ``indexed_select``) alone; the
+    rest is the choice's compaction, the gather and the attention kernel."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops.indexed_attention import (IndexerSizes, index_scores,
+                                               select)
+    from ray_tpu.ops.picked_latent_attention import picked_latent_attention
+
+    sizes = IndexerSizes(indexer_num_heads=64, indexer_head_dim=128,
+                         topk=2048)
+    N, T, P = 33024, 16, 4128
+    ks = jax.random.split(jax.random.key(seed & 0x7FFFFFFF), 8)
+    normal = lambda key, shape: jax.random.normal(key, shape, jnp.bfloat16)
+    pool, ik = normal(ks[0], (N, T, 640)), normal(ks[1], (N, T, 128))
+    rng = np.random.default_rng(seed)
+    tables = jnp.asarray(np.stack([rng.permutation(N - 1)[:P] + 1
+                                   for _ in range(8)]), jnp.int32)
+
+    def ms(fn, *args):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return round((time.perf_counter() - t0) / calls * 1e3, 3)
+
+    whole = jax.jit(lambda *a: picked_latent_attention(
+        *a, sizes, sm_scale=0.135, impl="pallas"))
+    indexer = jax.jit(lambda qi, w, ik, tables, pos: select(
+        index_scores(qi, w, ik, tables, pos, False), pos, 2048, False))
+    out = {}
+    for name, B, S, contexts in (("chunk", 1, 512, (8192, 20480, 56320)),
+                                 ("step", 8, 1, (20480, 56320))):
+        q_c, q_r = normal(ks[2], (B, S, 128, 512)), normal(
+            ks[3], (B, S, 128, 64))
+        qi = normal(ks[4], (B, S, 64, 128))
+        w = jax.random.normal(ks[5], (B, S, 64), jnp.float32)
+        for ctx in contexts:
+            lengths = jnp.full((B,), ctx - S, jnp.int32)
+            pos = lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
+            out[f"{name}_{ctx // 1024}k_ms"] = ms(
+                whole, q_c, q_r, qi, w, pool, ik, tables[:B], pos, lengths)
+            out[f"{name}_{ctx // 1024}k_indexer_ms"] = ms(
+                indexer, qi, w, ik, tables[:B], pos)
+    return out
+
+
 def ssm_timing(cfg, seed: int, rows: int, calls: int = 20) -> dict:
     """The two state-space kernels ALONE at the cell's shapes, in
     milliseconds a call behind a warm-up: the step over ``rows`` slots'
@@ -2310,6 +2561,15 @@ def glm_phase(seed: int) -> None:
     emit("glm", seconds=round(time.perf_counter() - t0, 1), **out)
 
 
+def deepseek_phase(seed: int) -> None:
+    import ray_tpu
+
+    t0 = time.perf_counter()
+    out = ray_tpu.get(
+        ray_tpu.remote(num_tpus=1)(deepseek_task).remote(seed), timeout=2400)
+    emit("deepseek", seconds=round(time.perf_counter() - t0, 1), **out)
+
+
 def nemotron_phase(seed: int) -> None:
     import ray_tpu
 
@@ -2436,6 +2696,7 @@ def one_chip(seed: int) -> dict:
     keye_phase(seed)
     glm_phase(seed)
     nemotron_phase(seed)
+    deepseek_phase(seed)
     serve_phase(seed)
     return out["device"]
 

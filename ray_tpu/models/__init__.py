@@ -18,6 +18,7 @@ from ray_tpu.models.presets import (  # noqa: F401
     moe_debug,
     minicpm_sala_debug,
     brumby_debug,
+    deepseek_v32_debug,
     glm_moe_lite_debug,
     keye_debug,
     mellum_debug,

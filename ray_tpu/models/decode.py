@@ -18,7 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.transformer import (ATTENTION, INDEXED, LATENT, LINEAR,
+from ray_tpu.models.transformer import (ATTENTION, INDEXED, INDEXED_LATENT,
+                                        LATENT, LINEAR,
                                         MAMBA, NONE, OWN_PAGE_TOKENS,
                                         RETENTION,
                                         SLIDING, SPARSE, STATE_KINDS,
@@ -26,7 +27,8 @@ from ray_tpu.models.transformer import (ATTENTION, INDEXED, LATENT, LINEAR,
                                         TransformerConfig, _gated_out, _mlp,
                                         _norm, _qkv, _residual, embed,
                                         final_hidden, forward, holds_page,
-                                        indexed_mix,
+                                        indexed_latent_mix,
+                                        indexed_latent_project, indexed_mix,
                                         indexed_project, latent_finish,
                                         latent_mix, latent_project,
                                         layer_params,
@@ -169,6 +171,12 @@ def init_caches(cfg: TransformerConfig, batch: int, max_len: int,
             return dataclasses.replace(LatentPagedKVCache.zeros(
                 1 + batch * -(-max_len // OWN_PAGE_TOKENS), OWN_PAGE_TOKENS,
                 cfg.latent_kv_rank, cfg.latent_rope_dim, dtype), length=zero)
+        if kind == INDEXED_LATENT:
+            # the same, the index keys' rows beside the latents'
+            return dataclasses.replace(IndexedLatentPagedKVCache.zeros(
+                1 + batch * -(-max_len // OWN_PAGE_TOKENS), OWN_PAGE_TOKENS,
+                cfg.latent_kv_rank, cfg.latent_rope_dim,
+                cfg.indexer.indexer_head_dim, dtype), length=zero)
         # a pool of its own: page 0 the garbage page, then a sequence's
         # pages in order, so its page table is the identity
         return dataclasses.replace(SparsePagedKVCache.zeros(
@@ -481,6 +489,37 @@ class LatentPagedKVCache:
             (num_pages, page_tokens, pool_width(rank, rope)), dtype))
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class IndexedLatentPagedKVCache:
+    """An 'indexed_latent_attention' layer's page pool: TWO arrays a page,
+    ``ckr`` as ``LatentPagedKVCache`` holds it (a token's latent and shared
+    rotated key in one row of whole lane tiles) and ``ik`` as
+    ``IndexedPagedKVCache`` does (the token's index key, whole lane tiles
+    too), under the one page table: a page that is spliced, shared or freed
+    takes its index keys along with its latents. ``k`` names the first for
+    whoever asks a pool for its page size. The same layout is that layer's
+    CONTIGUOUS cache (``init_caches``), which then carries its ``length``."""
+
+    ckr: Any
+    ik: Any
+    length: Any = None
+
+    @property
+    def k(self):
+        return self.ckr
+
+    @classmethod
+    def zeros(cls, num_pages: int, page_tokens: int, rank: int, rope: int,
+              index_dim: int, dtype=jnp.bfloat16
+              ) -> "IndexedLatentPagedKVCache":
+        return cls(
+            ckr=jnp.zeros((num_pages, page_tokens, pool_width(rank, rope)),
+                          dtype),
+            ik=jnp.zeros((num_pages, page_tokens, index_width(index_dim)),
+                         dtype))
+
+
 def init_paged_caches(cfg: TransformerConfig, num_pages: int,
                       page_tokens: int, pages_per_slot: int,
                       dtype=None, slots: Optional[int] = None,
@@ -489,7 +528,7 @@ def init_paged_caches(cfg: TransformerConfig, num_pages: int,
     for an attention layer (with pooled key rows for a 'minicpm4' one, with
     an index key a token for an 'indexed_attention' one; a row of a latent
     and a rotated key a token, and nothing else, for a 'latent_attention'
-    one), a
+    one, and both of those rows for an 'indexed_latent_attention' one), a
     state a slot (``slots`` of them) for a layer of a kind in
     ``STATE_KINDS``. A model none of whose layers holds a page has no pool:
     ``num_pages`` may then be anything, and nothing is made of it. A
@@ -535,6 +574,10 @@ def init_paged_caches(cfg: TransformerConfig, num_pages: int,
             return LatentPagedKVCache.zeros(
                 num_pages, page_tokens, cfg.latent_kv_rank,
                 cfg.latent_rope_dim, dtype)
+        if kind == INDEXED_LATENT:
+            return IndexedLatentPagedKVCache.zeros(
+                num_pages, page_tokens, cfg.latent_kv_rank,
+                cfg.latent_rope_dim, cfg.indexer.indexer_head_dim, dtype)
         pool = SparsePagedKVCache if kind == SPARSE else PagedKVCache
         return pool.zeros(window_pages if kind == SLIDING else num_pages,
                           page_tokens, cfg.kv_heads, cfg.head_dim, dtype)
@@ -649,7 +692,10 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
     (``transformer.indexed_mix``); a 'latent_attention' layer writes a
     latent and a rotated key a token and attends the latents, the keys' and
     values' up-projections absorbed, a call a group
-    (``transformer.latent_mix``); a
+    (``transformer.latent_mix``); an 'indexed_latent_attention' layer
+    writes that row and an index key's, then scores the index keys, picks
+    and attends the picked LATENTS, a call a group
+    (``transformer.indexed_latent_mix``); a
     'lightning-attn' or 'power-retention' layer (``transformer
     .STATE_MIXERS``) reads and writes its states instead, a kernel call a
     group (``_Rows`` says whose states a group's rows meet). Layer math
@@ -666,7 +712,8 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
     ``valid`` marks the rows that carry a live token (not a slot without a
     sequence, not a chunk's padding): the expert layer routes the others
     nowhere. ``taps``: a list that is given each 'minicpm4' or
-    'indexed_attention' layer's choice, a group at a time (debug).
+    'indexed_attention' or 'indexed_latent_attention' layer's choice, a
+    group at a time (debug).
     Returns (hidden, caches, moe): the rows after the last layer, BEFORE the
     final norm, [S, K, d] (several groups: [1, rows, d], group after
     group) — the caller norms and projects the rows it samples (``_head``);
@@ -757,6 +804,21 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
                     for g, *rows in zip(groups, *map(split, attending))]
                 a = latent_finish(cfg, ap, batch(outs))
                 new_caches.append(LatentPagedKVCache(*pools))
+            elif kind == INDEXED_LATENT:
+                attending, new = indexed_latent_project(cfg, ap, h, positions)
+                pools = tuple(
+                    write_pages(pool, made, pages[pool_of(kind)], offs)
+                    for pool, made in zip((c.ckr, c.ik), new))
+                outs, chosen = zip(*(_unless_idle(g, several, lambda _: (
+                    indexed_latent_mix(cfg, ap, rows, pools,
+                                       pool_tables(g.read_tables, kind),
+                                       g.positions, g.lengths, impl=impl),
+                    ()), ())[0]
+                    for g, *rows in zip(groups, *map(split, attending))))
+                if taps is not None:
+                    taps.extend(chosen)
+                a = latent_finish(cfg, ap, batch(outs))
+                new_caches.append(IndexedLatentPagedKVCache(*pools))
             else:
                 q, k, v = _qkv(cfg, ap, h, None if kind == SPARSE else rope,
                                positions, kind)

@@ -210,6 +210,40 @@ def glm_moe_lite_debug(**overrides) -> TransformerConfig:
     return TransformerConfig(**kw)
 
 
+def deepseek_v32_debug(**overrides) -> TransformerConfig:
+    """Tiny DeepSeek-V3.2-Exp-shaped config (``deepseek_v32``: every layer
+    'indexed_latent_attention' — latent attention whose queries attend the
+    ``index_topk`` latents a learned indexer picks, the index queries out of
+    the query's bottleneck, the first ``latent_rope_dim`` values of an index
+    head rotated, YaRN on every rotated part and in the softmax scale —
+    under a leading dense SwiGLU layer and then expert layers whose sigmoid
+    router chooses among the best ``moe_top_groups`` of ``moe_groups``
+    groups of experts, beside one shared expert) for tests: 4 heads of 24 +
+    8 q/k values and 16 v values over a latent of 32, 4 index heads of 16
+    that pick 24 tokens, 16 experts of 48 in 4 groups of which 2 stay, top 3
+    times 2.5. The multi-token-prediction block is not part of it. Every
+    layer is of the kind unless ``layer_kinds`` is given."""
+    kw = dict(
+        vocab_size=256, num_layers=3, embed_dim=64, num_heads=4,
+        latent_q_rank=48, latent_kv_rank=32, latent_nope_dim=24,
+        latent_rope_dim=8, latent_v_dim=16, index_num_heads=4,
+        index_head_dim=16, index_topk=24, mlp="moe", mlp_dim=48,
+        moe_num_experts=16, moe_top_k=3, moe_renormalize=True,
+        moe_scoring="sigmoid", moe_routed_scale=2.5, moe_shared_experts=1,
+        moe_groups=4, moe_top_groups=2, moe_dense_layers=1,
+        dense_mlp_dim=96, max_seq_len=256, norm="rmsnorm", pos="rope",
+        norm_eps=1e-6, rope_theta=10000.0, tie_embeddings=False,
+        dtype=jnp.float32,
+        rope_scaling={"type": "yarn", "factor": 40, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                      "original_max_position_embeddings": 32},
+    )
+    kw.update(overrides)
+    kw.setdefault("layer_kinds",
+                  ("indexed_latent_attention",) * kw["num_layers"])
+    return TransformerConfig(**kw)
+
+
 def nemotron_h(**overrides) -> TransformerConfig:
     """NVIDIA-Nemotron-3-Nano-30B-A3B (``model_type: nemotron_h``) at its
     published sizes: 52 layers that are ONE sublayer each, as its

@@ -74,7 +74,13 @@ Config switches:
     after RoPE, and a cached forward attends the latents themselves, the
     key's and the value's up-projection absorbed into the query and the
     output; ops/latent_attention.py; its pages, in the full layers' pool,
-    hold the two joined in one row a token) and 'mamba2' (Mamba-2's
+    hold the two joined in one row a token) and 'indexed_latent_attention'
+    (latent attention WITH an indexer: the index queries come from the
+    QUERY'S BOTTLENECK after its norm, a token leaves its latent row and
+    its index key's row in a page, the first ``latent_rope_dim`` values of
+    an index head are rotated and the rest not, and a query attends the
+    ``topk`` LATENTS its indexer picks, every one while the context is no
+    longer than that; ops/picked_latent_attention.py) and 'mamba2' (Mamba-2's
     state-space mixer: one in-projection to a gate, the joined x, B, C and
     a step a head; a causal convolution of ``ssm_conv_kernel`` taps over
     the joined three, a selective scan on a float32 state [N, P] a head
@@ -112,7 +118,8 @@ from ray_tpu.ops.attention import attention
 from ray_tpu.ops.indexed_attention import (IndexerSizes, index_row,
                                            index_width, indexed_attention)
 from ray_tpu.ops.latent_attention import (join as latent_row,
-                                          latent_attention)
+                                          latent_attention, pool_width)
+from ray_tpu.ops.picked_latent_attention import picked_latent_attention
 from ray_tpu.ops.flash_attention import FLASH_LSE, FLASH_OUT
 from ray_tpu.ops.linear_attention import (linear_attention_chunk,
                                           linear_attention_step, slopes)
@@ -123,7 +130,7 @@ from ray_tpu.ops.power_retention import (power_retention_chunk,
                                          power_retention_step)
 from ray_tpu.ops.ring_attention import ring_attention_local
 from ray_tpu.ops.rotary import (apply_rotary, apply_rotary_at,
-                                rope_frequencies)
+                                rope_frequencies, yarn_mscale)
 from ray_tpu.ops.sparse_attention import (SparseSizes, sparse_attention,
                                           update_page_means)
 from ray_tpu.ops import ssm
@@ -135,9 +142,12 @@ RETENTION = "power-retention"
 SLIDING = "sliding_attention"
 INDEXED = "indexed_attention"
 LATENT = "latent_attention"
+INDEXED_LATENT = "indexed_latent_attention"
 MAMBA = "mamba2"
 LAYER_KINDS = (ATTENTION, SPARSE, LINEAR, RETENTION, SLIDING, INDEXED, LATENT,
-               MAMBA)
+               INDEXED_LATENT, MAMBA)
+# the kinds whose sizes are the ``latent_*`` fields
+LATENT_KINDS = (LATENT, INDEXED_LATENT)
 # what a layer that is ONE sublayer lacks: its kind where it has no mixer,
 # its ``mlp_of`` where it has no feed-forward
 NONE = "none"
@@ -178,6 +188,11 @@ class TransformerConfig:
     # then times moe_routed_scale
     moe_scoring: str = "softmax"
     moe_routed_scale: float = 1.0
+    # group-limited choice (a source's n_group / topk_group): the experts in
+    # so many groups of consecutive indices, of which the best so many stay
+    # (1: every expert stands for choice)
+    moe_groups: int = 1
+    moe_top_groups: int = 1
     # dense experts every token takes beside its routed ones, mlp_dim wide
     # each (one SwiGLU of their joined width)
     moe_shared_experts: int = 0
@@ -225,7 +240,13 @@ class TransformerConfig:
     # .IndexerSizes' fields, a source's ``sa_config``; a dict is taken and
     # frozen)
     sa_config: Any = None
-    # 'latent_attention': the ranks of the query's and the keys-and-values'
+    # the same three sizes where a source lists them flat (index_n_heads,
+    # index_head_dim, index_topk): read where ``sa_config`` is None
+    index_num_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # 'latent_attention', 'indexed_latent_attention': the ranks of the
+    # query's and the keys-and-values'
     # bottlenecks, a head's unrotated and rotated q/k values and its v
     # values (head_dim is the two q/k parts together)
     latent_q_rank: int = 0
@@ -234,8 +255,9 @@ class TransformerConfig:
     latent_rope_dim: int = 0
     latent_v_dim: int = 0
     # a source's ``rope_scaling`` (a dict is taken and frozen), read for
-    # ``mrope_section`` alone: runs of frequency pairs, a position stream
-    # each
+    # ``mrope_section`` (runs of frequency pairs, a position stream each)
+    # and, by the latent kinds, for a ``type`` of 'yarn': the rule their
+    # rotated parts turn by and the softmax scale's factor (``latent_rope``)
     rope_scaling: Any = None
     # 'lightning-attn': head h decays by exp(-2^(-e (h + 1) / H)) a token
     linear_slope_exponent: float = 8.0
@@ -318,20 +340,31 @@ class TransformerConfig:
         if INDEXED in self.kinds and self.sa_config is None:
             raise ValueError("an 'indexed_attention' layer needs sa_config "
                              "(the indexer's sizes)")
+        if (INDEXED_LATENT in self.kinds and self.sa_config is None
+                and min(self.index_num_heads, self.index_head_dim,
+                        self.index_topk) < 1):
+            raise ValueError(
+                "an 'indexed_latent_attention' layer needs sa_config or "
+                "index_num_heads, index_head_dim and index_topk")
         if SLIDING in self.kinds and self.sliding_window < 1:
             raise ValueError("a 'sliding_attention' layer needs "
                              f"sliding_window >= 1, got {self.sliding_window}")
-        if LATENT in self.kinds:
+        if set(LATENT_KINDS) & set(self.kinds):
             sizes = (self.latent_q_rank, self.latent_kv_rank,
                      self.latent_nope_dim, self.latent_rope_dim,
                      self.latent_v_dim)
             if min(sizes) < 1 or self.latent_rope_dim % 2:
                 raise ValueError(
-                    "a 'latent_attention' layer needs latent_q_rank, "
+                    "a latent attention layer needs latent_q_rank, "
                     "latent_kv_rank, latent_nope_dim, latent_rope_dim (even) "
                     f"and latent_v_dim, got {sizes}")
             object.__setattr__(self, "head_dim", self.latent_nope_dim
                                + self.latent_rope_dim)
+            if (INDEXED_LATENT in self.kinds
+                    and self.indexer.indexer_head_dim < self.latent_rope_dim):
+                raise ValueError("an index head holds the rotated part: "
+                                 f"{self.indexer.indexer_head_dim} < "
+                                 f"{self.latent_rope_dim}")
         if MAMBA in self.kinds and (
                 min(self.ssm_num_heads, self.ssm_head_dim, self.ssm_state_dim,
                     self.ssm_groups) < 1
@@ -432,7 +465,32 @@ class TransformerConfig:
 
     @property
     def indexer(self) -> IndexerSizes:
+        if self.sa_config is None and self.index_topk:
+            return IndexerSizes(indexer_num_heads=self.index_num_heads,
+                                indexer_head_dim=self.index_head_dim,
+                                topk=self.index_topk)
         return IndexerSizes(**dict(self.sa_config or ()))
+
+    @property
+    def latent_rope(self):
+        """(the rule the latent kinds' rotated parts turn by, what their
+        softmax scale ``head_dim ** -0.5`` is multiplied by): (None, 1.0)
+        — ``rope_theta`` alone — unless ``rope_scaling`` is of ``type``
+        'yarn' (DeepSeek-V3's: cos and sin scaled by ``mscale`` over
+        ``mscale_all_dim``'s factor, 1 where the two are equal, and the
+        scores by the square of the latter's)."""
+        scaling = dict(self.rope_scaling or ())
+        if scaling.get("type", scaling.get("rope_type")) != "yarn":
+            return None, 1.0
+        factor = float(scaling["factor"])
+        every = yarn_mscale(factor, float(scaling.get("mscale_all_dim", 0)))
+        rule = {key: scaling[key] for key in (
+            "factor", "original_max_position_embeddings", "beta_fast",
+            "beta_slow") if key in scaling}
+        rule.update(rope_type="yarn", rope_theta=self.rope_theta,
+                    attention_factor=yarn_mscale(
+                        factor, float(scaling.get("mscale", 1))) / every)
+        return rule, every * every
 
     @property
     def mrope_section(self) -> Optional[Tuple[int, ...]]:
@@ -520,8 +578,9 @@ def _mixer_params(cfg: TransformerConfig, kind: str, ks, init, out_init):
     """A layer's mixer of ``kind`` from the layer's keys ``ks``."""
     d, h, kvh, hd = (cfg.embed_dim, cfg.num_heads, cfg.kv_heads,
                      cfg.head_dim)
-    if kind == LATENT:
-        return _latent_params(cfg, ks, init, out_init)
+    if kind in LATENT_KINDS:
+        return _latent_params(cfg, ks, init, out_init,
+                              indexer=kind == INDEXED_LATENT)
     if kind == MAMBA:
         return _mamba_params(cfg, ks, init, out_init)
     if kind == LINEAR:
@@ -591,17 +650,22 @@ def _mamba_params(cfg: TransformerConfig, ks, init, out_init):
     }
 
 
-def _latent_params(cfg: TransformerConfig, ks, init, out_init):
+def _latent_params(cfg: TransformerConfig, ks, init, out_init,
+                   indexer: bool = False):
     """A 'latent_attention' layer's mixer: the query's bottleneck and its
     norm, the projection to the latent and (behind it) the one rotated key
     every head shares, the latent's norm, the up-projection to a head's
-    unrotated key values and (behind them) its values, and ``wo``."""
+    unrotated key values and (behind them) its values, and ``wo``. With
+    ``indexer`` ('indexed_latent_attention') the indexer's beside them, from
+    keys of their own: the index queries' projection out of the QUERY'S
+    BOTTLENECK, the index key's and the heads' weights' out of the layer's
+    input, the key's LayerNorm."""
     d, h = cfg.embed_dim, cfg.num_heads
     rq, rkv, nope, rot, dv = (
         cfg.latent_q_rank, cfg.latent_kv_rank, cfg.latent_nope_dim,
         cfg.latent_rope_dim, cfg.latent_v_dim)
     kl = jax.random.split(ks[7], 4)
-    return {
+    attn = {
         "wq_a": init(kl[0], (d, rq)),
         "q_a_norm": jnp.ones((rq,), cfg.param_dtype),
         "wq_b": init(kl[1], (rq, h, nope + rot)),
@@ -610,6 +674,15 @@ def _latent_params(cfg: TransformerConfig, ks, init, out_init):
         "wkv_b": init(kl[3], (rkv, h, nope + dv)),
         "wo": out_init(ks[3], (h, dv, d)),
     }
+    if indexer:
+        hi, di = cfg.indexer.indexer_num_heads, cfg.indexer.indexer_head_dim
+        ki = jax.random.split(jax.random.fold_in(ks[7], 4), 3)
+        attn.update(
+            wi_q=init(ki[0], (rq, hi, di)), wi_k=init(ki[1], (d, di)),
+            wi_w=init(ki[2], (d, hi)),
+            ik_scale=jnp.ones((di,), cfg.param_dtype),
+            ik_bias=jnp.zeros((di,), cfg.param_dtype))
+    return attn
 
 
 def _norm_params(cfg: TransformerConfig, dim: int):
@@ -683,13 +756,19 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
         return {"scale": L + ("embed_notp",), "bias": L + ("embed_notp",)}
 
     def mixer_axes(kind):
-        if kind == LATENT:
-            return {
+        if kind in LATENT_KINDS:
+            attn = {
                 "wq_a": L + ("embed", None), "q_a_norm": L + (None,),
                 "wq_b": L + (None, "heads", "head_dim"),
                 "wkv_a": L + ("embed", None), "kv_norm": L + (None,),
                 "wkv_b": L + (None, "heads", "head_dim"),
                 "wo": L + ("heads", "head_dim", "embed")}
+            if kind == INDEXED_LATENT:
+                attn.update(
+                    wi_q=L + (None, None, None), wi_k=L + ("embed", None),
+                    wi_w=L + ("embed", None), ik_scale=L + (None,),
+                    ik_bias=L + (None,))
+            return attn
         if kind == MAMBA:
             return {
                 "w_in": L + ("embed", None), "conv_w": L + (None, None),
@@ -1253,6 +1332,28 @@ def indexed_mixer(cfg, p, x, rope, rope_positions, positions, lengths,
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(cfg.dtype)), pools
 
 
+def _latent_turn(cfg, positions):
+    """The rotation of a latent kind's rotated parts at ``positions``: by
+    ``rope_theta``, or by the rule ``cfg.latent_rope`` gives."""
+    return lambda a: apply_rotary_at(a, positions, cfg.rope_theta,
+                                     cfg.latent_rope[0])
+
+
+def _latent_rows(cfg, p, x, positions):
+    """``latent_project``'s three, and before them the query's bottleneck
+    after its norm, cq [B, S, q_rank] (what an indexer reads)."""
+    nope, rank = cfg.latent_nope_dim, cfg.latent_kv_rank
+    turn = _latent_turn(cfg, positions)
+    cq = rms_norm(jnp.einsum("bsd,dr->bsr", x, p["wq_a"].astype(cfg.dtype)),
+                  p["q_a_norm"], cfg.norm_eps)
+    q = jnp.einsum("bsr,rhk->bshk", cq, p["wq_b"].astype(cfg.dtype))
+    ckr = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"].astype(cfg.dtype))
+    c = rms_norm(ckr[..., :rank], p["kv_norm"], cfg.norm_eps)
+    kr = turn(ckr[..., None, rank:])
+    return (cq, (q[..., :nope], turn(q[..., nope:])),
+            (latent_row(c[:, :, None], kr),), (c, kr))
+
+
 def latent_project(cfg, p, x, positions):
     """What a 'latent_attention' layer makes of the normalized x [B, S, d]
     at ``positions`` [B, S]: (the rows that attend — q's unrotated part [B,
@@ -1261,16 +1362,7 @@ def latent_project(cfg, p, x, positions):
     into the pool's row [B, S, 1, width] (``ops.latent_attention.join``) —,
     and the two as they are, [B, S, rank] and [B, S, 1, rope], for a forward
     without a cache)."""
-    nope, rank = cfg.latent_nope_dim, cfg.latent_kv_rank
-    turn = lambda a: apply_rotary_at(a, positions, cfg.rope_theta)
-    cq = rms_norm(jnp.einsum("bsd,dr->bsr", x, p["wq_a"].astype(cfg.dtype)),
-                  p["q_a_norm"], cfg.norm_eps)
-    q = jnp.einsum("bsr,rhk->bshk", cq, p["wq_b"].astype(cfg.dtype))
-    ckr = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"].astype(cfg.dtype))
-    c = rms_norm(ckr[..., :rank], p["kv_norm"], cfg.norm_eps)
-    kr = turn(ckr[..., None, rank:])
-    return ((q[..., :nope], turn(q[..., nope:])),
-            (latent_row(c[:, :, None], kr),), (c, kr))
+    return _latent_rows(cfg, p, x, positions)[1:]
 
 
 def latent_absorb(cfg, p, q_nope):
@@ -1293,9 +1385,15 @@ def latent_mix(cfg, p, rows, pools, read_tables, lengths, *, impl: str):
     q_nope, q_rope = rows
     return latent_attention(
         latent_absorb(cfg, p, q_nope), q_rope, *pools, read_tables, lengths,
-        sm_scale=cfg.head_dim ** -0.5, impl=impl,
+        sm_scale=latent_scale(cfg), impl=impl,
         name=("latent_step_attention" if q_nope.shape[1] == 1
               else "latent_chunk_attention"))
+
+
+def latent_scale(cfg) -> float:
+    """The softmax scale of a latent kind: that of the unabsorbed head, times
+    the YaRN factor where ``rope_scaling`` gives one (``cfg.latent_rope``)."""
+    return cfg.head_dim ** -0.5 * cfg.latent_rope[1]
 
 
 def latent_finish(cfg, p, o):
@@ -1340,6 +1438,67 @@ def latent_mixer(cfg, p, x, positions, lengths, pools, read_tables,
     pools = tuple(write_pages(pool, made, *cells)
                   for pool, made in zip(pools, new))
     o = latent_mix(cfg, p, rows, pools, read_tables, lengths, impl=impl)
+    return latent_finish(cfg, p, o), pools
+
+
+def indexed_latent_project(cfg, p, x, positions):
+    """What an 'indexed_latent_attention' layer makes of the normalized x
+    [B, S, d] at ``positions`` [B, S]: (the rows that attend — q's unrotated
+    and rotated parts as ``latent_project`` makes them, the index queries qI
+    [B, S, Hi, Di] OUT OF THE QUERY'S BOTTLENECK cq and the heads' weights w
+    [B, S, Hi] float32, already times ``Hi^-1/2 Di^-1/2`` —, what they leave
+    in the pages — the latent's row and the index key's row [B, S, 1, W]
+    (LayerNorm first), in the order of the pools). The FIRST
+    ``latent_rope_dim`` values of every index head and of the index key
+    turn by the layer's rule, the rest do not."""
+    sizes, rot = cfg.indexer, cfg.latent_rope_dim
+    cq, rows, (row,), _ = _latent_rows(cfg, p, x, positions)
+    turn = _latent_turn(cfg, positions)
+    part = lambda a: jnp.concatenate([turn(a[..., :rot]), a[..., rot:]],
+                                     axis=-1)
+    qi = jnp.einsum("bsr,rhk->bshk", cq, p["wi_q"].astype(cfg.dtype))
+    ki = layer_norm(jnp.einsum("bsd,dk->bsk", x, p["wi_k"].astype(cfg.dtype)),
+                    p["ik_scale"], p["ik_bias"], cfg.norm_eps)
+    w = jnp.einsum("bsd,dh->bsh", x, p["wi_w"].astype(cfg.dtype),
+                   preferred_element_type=jnp.float32)
+    scale = (sizes.indexer_num_heads * sizes.indexer_head_dim) ** -0.5
+    return ((*rows, part(qi), w * scale),
+            (row, index_row(part(ki[:, :, None]))))
+
+
+def indexed_latent_mix(cfg, p, rows, pools, read_tables, positions, lengths,
+                       *, impl: str):
+    """'indexed_latent_attention', one group, its latents and index keys
+    already in the pools: rows = (q_nope, q_rope, qI, w) at ``positions``
+    [B, S] attend the LATENTS their indexer picks, absorbed. ``pools`` =
+    (the latent rows [N, T, width], the index keys [N, T, W]), a row's
+    pages through ``read_tables`` [B, P], ``lengths`` [B] as
+    ``ops.paged_attention`` takes them. Returns (o' [B, S, H, rank], which
+    ``latent_finish`` expands, the choice, bool [B, S, context])."""
+    q_nope, q_rope, qi, w = rows
+    return picked_latent_attention(
+        latent_absorb(cfg, p, q_nope), q_rope, qi, w, *pools, read_tables,
+        positions, lengths, cfg.indexer, sm_scale=latent_scale(cfg),
+        impl=impl, return_selected=True)
+
+
+def indexed_latent_mixer(cfg, p, x, positions, lengths, pools, read_tables,
+                         write_tables, *, impl: str,
+                         taps: Optional[List] = None):
+    """'indexed_latent_attention' over ONE group of rows: x [B, S, d] at
+    ``positions`` [B, S] over a paged pool (``indexed_latent_mix`` says what
+    the arguments are; a row's pages are written through ``write_tables``
+    [B, P]). Latent rows and index keys are written, then the picked latents
+    attended. Returns (y, pools); ``taps`` (a list) is given the choice."""
+    T = pools[0].shape[1]
+    rows, new = indexed_latent_project(cfg, p, x, positions)
+    cells = written_pages(write_tables, positions, T), positions % T
+    pools = tuple(write_pages(pool, made, *cells)
+                  for pool, made in zip(pools, new))
+    o, selected = indexed_latent_mix(cfg, p, rows, pools, read_tables,
+                                     positions, lengths, impl=impl)
+    if taps is not None:
+        taps.append(selected)
     return latent_finish(cfg, p, o), pools
 
 
@@ -1390,6 +1549,23 @@ def _mixer(cfg, kind, p, x, rope, positions, sp_axis, cache, taps):
             cache, k=pools[0], v=pools[1], ik=pools[2], length=length + S)
     pos = jnp.broadcast_to(jnp.arange(S)[None] if positions is None
                            else positions, (B, S)).astype(jnp.int32)
+    if kind == INDEXED_LATENT:
+        if cache is None:
+            n = 1 + B * -(-S // OWN_PAGE_TOKENS)
+            pools = tuple(
+                jnp.zeros((n, OWN_PAGE_TOKENS, width), cfg.dtype)
+                for width in (
+                    pool_width(cfg.latent_kv_rank, cfg.latent_rope_dim),
+                    index_width(cfg.indexer.indexer_head_dim)))
+            length = jnp.zeros((), jnp.int32)
+        else:
+            pools, length = (cache.ckr, cache.ik), cache.length
+        tables = _own_tables(pools, B)
+        y, pools = indexed_latent_mixer(
+            cfg, p, x, pos, jnp.broadcast_to(length, (B,)), pools, tables,
+            tables, impl=resolve_impl(cfg), taps=taps)
+        return y, cache and dataclasses.replace(
+            cache, ckr=pools[0], ik=pools[1], length=length + S)
     if kind == LATENT:
         if cache is None:
             rows, _, made = latent_project(cfg, p, x, pos)
@@ -1447,7 +1623,8 @@ def _mlp(cfg, p, x, valid=None, layer=None, ff=None):
             renormalize=cfg.moe_renormalize, dtype=cfg.dtype, valid=valid,
             layer=layer, scoring=cfg.moe_scoring,
             routed_scale=cfg.moe_routed_scale, held=cfg.held,
-            activation=cfg.moe_activation)
+            activation=cfg.moe_activation, expert_groups=cfg.moe_groups,
+            top_groups=cfg.moe_top_groups)
         moe = {"counts": counts, "routes": routes}
         if cfg.held:
             # the live rows' choices that fell on experts held elsewhere,
@@ -1659,9 +1836,9 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
     made on the same choices.
     return_selected (debug, without kv_caches): also return the blocks every
     query of every 'minicpm4' layer attended, bool [layers of the kind, B,
-    S, Hkv, NB], or the tokens every query of every 'indexed_attention'
-    layer attended, bool [layers of the kind, B, S, context], for the same
-    reason.
+    S, Hkv, NB], or the tokens every query of every 'indexed_attention' or
+    'indexed_latent_attention' layer attended, bool [layers of the kind, B,
+    S, context], for the same reason.
 
     sp_axis: when running inside shard_map with sequence sharded over that
     axis, attention goes through the ring kernel and `positions` must be the
@@ -1689,10 +1866,12 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
 
     if return_routes and (cfg.mlp != "moe" or kv_caches is not None):
         raise ValueError("return_routes needs mlp='moe' and no kv_caches")
-    if return_selected and (not {SPARSE, INDEXED} & set(kinds)
-                            or kv_caches is not None):
-        raise ValueError("return_selected needs a 'minicpm4' or an "
-                         "'indexed_attention' layer and no kv_caches")
+    if return_selected and (
+            not {SPARSE, INDEXED, INDEXED_LATENT} & set(kinds)
+            or kv_caches is not None):
+        raise ValueError(
+            "return_selected needs a 'minicpm4', an 'indexed_attention' or "
+            "an 'indexed_latent_attention' layer and no kv_caches")
     new_caches = None
     aux_total = 0.0
     routes = None

@@ -1,5 +1,6 @@
 """Token-selected sparse attention over the paged K/V pool (the
-DeepSeek-Sparse-Attention indexer of the ``indexed_attention`` mixer).
+DeepSeek-Sparse-Attention indexer of the ``indexed_attention`` mixer; its
+scoring and selection serve the latent pool's form too, see below).
 
 A query attends ``topk`` single TOKENS of its context, picked by a learned
 scorer that keeps a cache of its own: beside keys and values a layer keeps
@@ -40,6 +41,18 @@ its ``Hi`` index queries ``qI`` and their weights ``w`` (already scaled):
      (``cell_tokens``), each of which meets the tile while it is there. No
      gathered copy of a slot's K and V exists.
 
+SHARED WITH THE LATENT FORM (``ops.picked_latent_attention``, the
+'indexed_latent_attention' mixer: the same indexer inside multi-head latent
+attention, whose pages hold a latent row in place of K and V): steps 1 and 2
+as they stand — ``index_scores`` (the kernel ``index_score``), ``select``
+(``indexed_select``), ``chosen``, ``index_row`` / ``index_width`` and the
+index keys' pool — at whatever sizes ``IndexerSizes`` gives (64 heads of 128
+there, 16 of 64 here; how much of an index head is rotated is the LAYER's
+business, not these sizes'). NOT shared: step 3. ``indexed_chunk_attention``
+and ``indexed_step_attention`` read a K pool and a V pool by K/V head; the
+latent form compacts each query's choice, gathers its rows of ONE pool and
+attends them under all heads at once (``picked_latent_*_attention``).
+
 The kernels carry those names in a profiler trace and are interpreted off a
 TPU. What is chosen is returned on request (``return_selected``), bool [B,
 S, context]: a comparison with another implementation has to be made on the
@@ -75,9 +88,12 @@ _INT_MIN = -2 ** 31
 
 @dataclasses.dataclass(frozen=True)
 class IndexerSizes:
-    """A source's ``sa_config``. ``q_chunk_size`` / ``kv_chunk_size`` are
-    tile sizes of the published kernel and change no result: kept so the
-    dict maps whole, read by nothing."""
+    """A source's ``sa_config`` (or, for the latent form, its flat
+    ``index_n_heads`` / ``index_head_dim`` / ``index_topk``: the sizes say
+    nothing of what the indexer sits beside, a K/V pool or a latent one).
+    ``q_chunk_size`` / ``kv_chunk_size`` are tile sizes of the published
+    kernel and change no result: kept so the dict maps whole, read by
+    nothing."""
 
     indexer_num_heads: int = 16
     indexer_head_dim: int = 64

@@ -321,8 +321,27 @@ def expert_mlp(xs, w_gate, w_up, w_down, counts, first_group, tiles=None):
     return out[:pairs]
 
 
+def limit_to_groups(chosen_by, groups: int, top_groups: int):
+    """Group-limited routing (DeepSeek-V3's ``noaux_tc``): the experts in
+    ``groups`` runs of consecutive indices, a group's score the sum of its
+    TWO largest entries of ``chosen_by`` [N, E], the ``top_groups`` best
+    groups kept (ties to the lower group) -> ``chosen_by`` with every expert
+    of another group at -inf, so no top-k takes one."""
+    n, e = chosen_by.shape
+    if e % groups or e // groups < 2 or not 0 < top_groups <= groups:
+        raise ValueError(
+            f"{e} experts in {groups} groups of at least two, of which "
+            f"{top_groups} are kept")
+    best = jax.lax.top_k(chosen_by.reshape(n, groups, e // groups), 2)[0]
+    kept = jax.lax.top_k(best.sum(-1), top_groups)[1]            # [N, kept]
+    keep = (kept[..., None] == jnp.arange(groups)).any(axis=1)  # [N, groups]
+    return jnp.where(jnp.repeat(keep, e // groups, axis=1), chosen_by,
+                     -jnp.inf)
+
+
 def route(logits, top_k: int, renormalize: bool, scoring: str = SOFTMAX,
-          bias=None, routed_scale: float = 1.0):
+          bias=None, routed_scale: float = 1.0, groups: int = 1,
+          top_groups: int = 1):
     """Router logits [N, E] float32 -> (scores [N, E], the k experts a row
     takes [N, k] best first, their weights [N, k]), float32.
 
@@ -331,8 +350,11 @@ def route(logits, top_k: int, renormalize: bool, scoring: str = SOFTMAX,
     'sigmoid': scores are a sigmoid an expert; the choice is the k largest
     of ``scores + bias`` ([E] float32: the bias corrects who is chosen) and
     the weights the UNBIASED scores of the chosen, divided by their sum plus
-    1e-20 with ``renormalize``. Either way times ``routed_scale``. Ties go
-    to the lower index (``jax.lax.top_k``)."""
+    1e-20 with ``renormalize``; with ``groups`` > 1 the choice is made among
+    the experts of the ``top_groups`` best groups alone (``limit_to_groups``;
+    one group is the identity and adds nothing to the program). Either way
+    times ``routed_scale``. Ties go to the lower index
+    (``jax.lax.top_k``)."""
     if scoring == SOFTMAX:
         scores = jax.nn.softmax(logits, axis=-1)
         gate_vals, gate_idx = jax.lax.top_k(scores, top_k)  # [N, k]
@@ -343,6 +365,8 @@ def route(logits, top_k: int, renormalize: bool, scoring: str = SOFTMAX,
         scores = jax.nn.sigmoid(logits)
         chosen_by = scores if bias is None else scores + bias.astype(
             jnp.float32)
+        if groups > 1:
+            chosen_by = limit_to_groups(chosen_by, groups, top_groups)
         gate_idx = jax.lax.top_k(chosen_by, top_k)[1]
         gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
         if renormalize:
@@ -374,14 +398,17 @@ def moe_layer(p: Dict[str, Any], x, *, num_experts: int, top_k: int = 2,
               layer: Optional[int] = None, scoring: str = SOFTMAX,
               routed_scale: float = 1.0,
               held: Optional[Tuple[int, int]] = None,
-              activation: str = SWIGLU):
+              activation: str = SWIGLU, expert_groups: int = 1,
+              top_groups: int = 1):
     """x: [B, S, d] -> (y [B, S, d], aux_loss, counts [E], routes [B, S, k]).
 
     ``scoring``, ``renormalize``, ``routed_scale``: the router's rule
     (``route``): softmax probabilities taken as they are (OLMoE) or divided
     by their sum (Mixtral, GShard), or sigmoid scores chosen by ``score +
-    p["e_bias"]`` and weighed without it (DeepSeek-V3's ``noaux_tc`` with one
-    group). Where ``p`` holds a shared expert (``ws_gate``, ``ws_up``,
+    p["e_bias"]`` and weighed without it (DeepSeek-V3's ``noaux_tc``; among
+    the ``top_groups`` best of ``expert_groups`` groups of experts where there
+    is more than one group). Where ``p`` holds a shared expert (``ws_gate``,
+    ``ws_up``,
     ``ws_down``) every live row takes it beside its routed sum, unweighted.
     ``activation``: the experts' form and the shared one's (``ACTIVATIONS``;
     'relu2' has no gate matrix and none is read).
@@ -418,7 +445,8 @@ def moe_layer(p: Dict[str, Any], x, *, num_experts: int, top_k: int = 2,
                         preferred_element_type=jnp.float32)
     probs, gate_idx, gate_vals = route(
         logits, top_k, renormalize, scoring,
-        own(p["e_bias"]) if "e_bias" in p else None, routed_scale)
+        own(p["e_bias"]) if "e_bias" in p else None, routed_scale,
+        expert_groups, top_groups)
     if scoring != SOFTMAX:  # the auxiliary loss wants a distribution
         probs = probs / probs.sum(-1, keepdims=True)
     live = (jnp.ones((n,), bool) if valid is None

@@ -85,6 +85,12 @@ def stream_of_pairs(sections, pairs: int) -> np.ndarray:
 ROPE_TYPES = ("default", "yarn")
 
 
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's magnitude scale ``0.1 mscale ln(factor) + 1`` (1 for a factor
+    of at most 1): what cos and sin, or the scores, are multiplied by."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
 def rule_frequencies(head_dim: int,
                      rule: Mapping[str, Any]) -> Tuple[np.ndarray, float]:
     """(inverse frequencies [head_dim // 2] float32, the factor cos and sin

@@ -393,6 +393,16 @@ _REFUSALS = {"state": {
         "a model with 'latent_attention' layers exports no prefix: its "
         "pages hold latents and rotated keys, and what is exported is keys "
         "and values"),
+}, "indexed_latent": {
+    "drafter": (
+        "speculative decoding cannot serve a model with "
+        "'indexed_latent_attention' layers: the drafter's slot arena holds "
+        "keys and values alone, and a verify window's rows have not been "
+        "held against the reference for the kind"),
+    "export": (
+        "a model with 'indexed_latent_attention' layers exports no prefix: "
+        "its pages hold latents, rotated keys and index keys, and what is "
+        "exported is keys and values"),
 }}
 
 
@@ -409,7 +419,11 @@ def cannot_continue(cfg, pools: Tuple[PagePool, ...]
     alone does not. A 'latent_attention' layer's pages hold a latent and a
     rotated key a token and nothing else: the prefix cache splices them as
     it splices keys and values (tests/test_glm_moe_lite.py), and the two
-    that count on keys and values are refused. Plain 'attention' and
+    that count on keys and values are refused. An
+    'indexed_latent_attention' layer's pages hold that row AND the token's
+    index key under the one table: a spliced prefix brings both
+    (tests/test_deepseek_v32.py), the other two are refused as for both
+    parents. Plain 'attention' and
     'minicpm4' forbid nothing: None."""
     if cfg.recurrent:
         return _REFUSALS["state"]
@@ -419,6 +433,8 @@ def cannot_continue(cfg, pools: Tuple[PagePool, ...]
         return _REFUSALS["index"]
     if "latent_attention" in cfg.kinds:
         return _REFUSALS["latent"]
+    if "indexed_latent_attention" in cfg.kinds:
+        return _REFUSALS["indexed_latent"]
     return None
 
 

@@ -12,8 +12,8 @@ A kind's counters are said once, in ``_KINDS``: the function that counts one
 call of the kind's layers (a layer-call: one layer in one program run) and
 the keys it owns, which a model shows if and only if it has layers of the
 kind ('lightning-attn', 'power-retention', 'mamba2', 'minicpm4',
-'indexed_attention', 'sliding_attention', 'latent_attention'; plain
-'attention' is counted by
+'indexed_attention', 'sliding_attention', 'latent_attention',
+'indexed_latent_attention'; plain 'attention' is counted by
 ``attn_*`` alone); ``attn_*`` always (they count pages: a true 0 for a model
 without). What a token leaves in a page, by kind, is ``token_bytes``.
 """
@@ -26,8 +26,9 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ray_tpu._private.metrics import Counter
-from ray_tpu.models.transformer import (ATTENTION, INDEXED, LATENT, LINEAR,
-                                        MAMBA, RETENTION, SLIDING, SPARSE,
+from ray_tpu.models.transformer import (ATTENTION, INDEXED, INDEXED_LATENT,
+                                        LATENT, LATENT_KINDS, LINEAR, MAMBA,
+                                        RETENTION, SLIDING, SPARSE,
                                         STATE_KINDS, holds_page, state_shapes)
 from ray_tpu.ops.indexed_attention import (SELECT_ROWS, cell_tokens,
                                            context_tokens, select_lanes)
@@ -182,12 +183,46 @@ def _latent(work, n, layers, qk, cursors, real):
         work.cfg.latent_kv_rank + work.cfg.latent_rope_dim)
 
 
+def _picked(work, n, layers, qk, cursors, real):
+    """Latent layers with an indexer: the (query, key) pairs the index heads
+    score (``picked_index_pairs``: every query row its whole causal context,
+    every layer) of which a step's (``picked_step_index_pairs``), the pairs
+    attended (``picked_chosen_pairs``: ``min(t + 1, topk)`` a query, a
+    function of its position alone) of which a step's
+    (``picked_step_chosen_pairs``), the bytes the chosen latent rows are
+    (``picked_latent_bytes``: ``rank + rope`` values a chosen pair, read
+    once a query and serving every head) and the index keys' the scoring
+    reads at least (``picked_index_key_bytes``: a step's row its whole
+    context, a chunk its context once, ``index_head_dim`` values a token).
+    Returns (attended, fetched) a layer as the paged kernel's are counted:
+    the chosen rows, each fetched once."""
+    sizes, cfg = work.cfg.indexer, work.cfg
+    t = np.asarray(cursors)[:, None] + np.arange(real)  # [rows, real]
+    attended = int(sizes.attended_tokens(t).sum())
+    context = int((t + 1).sum())
+    n["picked_index_pairs"] += layers * context
+    n["picked_chosen_pairs"] += layers * attended
+    if qk == 1:
+        n["picked_step_index_pairs"] += layers * context
+        n["picked_step_chosen_pairs"] += layers * attended
+        scored = context
+    else:
+        scored = int(t[:, -1].sum()) + len(cursors)
+    n["picked_latent_bytes"] += layers * attended * work.itemsize * (
+        cfg.latent_kv_rank + cfg.latent_rope_dim)
+    n["picked_index_key_bytes"] += (layers * scored * work.itemsize
+                                    * sizes.indexer_head_dim)
+    return attended, attended
+
+
 def token_bytes(cfg, kind: str, itemsize: int) -> int:
     """Bytes the attended rows of one token take in a page of a layer of
-    ``kind``: K and V of all K/V heads, or for 'latent_attention' the one
+    ``kind``: K and V of all K/V heads, or for the latent kinds the one
     row of a latent and a rotated key (``ops.latent_attention.join``, its
-    padding to whole lane tiles held and moved too) that is both."""
-    if kind == LATENT:
+    padding to whole lane tiles held and moved too) that is both (an
+    'indexed_latent_attention' layer's index key, beside it, is not
+    attended)."""
+    if kind in LATENT_KINDS:
         return pool_width(cfg.latent_kv_rank,
                           cfg.latent_rope_dim) * itemsize
     return 2 * cfg.kv_heads * cfg.head_dim * itemsize
@@ -213,6 +248,12 @@ _KINDS = {
                          "indexed_select_lanes_table")),
     LATENT: (_latent, ("latent_tokens_context", "latent_step_tokens_context",
                        "latent_chunk_pairs", "latent_bytes_moved")),
+    INDEXED_LATENT: (_picked, ("picked_index_pairs",
+                               "picked_step_index_pairs",
+                               "picked_chosen_pairs",
+                               "picked_step_chosen_pairs",
+                               "picked_latent_bytes",
+                               "picked_index_key_bytes")),
     SLIDING: (_window, ("window_attn_step_keys", "full_attn_step_keys",
                         "window_attn_chunk_pairs", "full_attn_chunk_pairs",
                         "window_tokens_held", "window_tokens_unreleased")),
@@ -245,11 +286,13 @@ class Work:
         # all kv heads of one token's K (or V) row, and the query rows
         # that share it; a latent layer's one row a token, in two halves for
         # the same arithmetic, shared by every head
-        latent = LATENT in kinds
+        latent = bool(set(LATENT_KINDS) & set(kinds))
         self._row_bytes = token_bytes(cfg, LATENT if latent else ATTENTION,
                                       itemsize) // 2
         self._group = cfg.num_heads // (1 if latent else cfg.kv_heads)
-        self._latent = latent
+        # the dense latent kernel's own tiles (``_tiles``): not the picked
+        # form's, which counts its own fetches
+        self._latent = LATENT in kinds
         if SPARSE in kinds:
             # tokens of one block of the step's kernel over a row's table
             # of chosen pages (``sparse_attention._step_attention``)

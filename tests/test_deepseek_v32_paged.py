@@ -1,0 +1,396 @@
+"""The 'indexed_latent_attention' kind where it meets pages (ISSUE 61): the
+op ``ops.picked_latent_attention`` against plain latent attention and
+against a sort, the paged chunk, step and fused turn by the ``jax.numpy``
+path and by the kernel interpreted, two faults of the pool, the scheduler's
+counters, a spliced prefix and what the scheduler refuses — float32 on the
+CPU at a toy size against ``perfbench/reference/deepseek_v32.py``, selection
+and routes EQUAL. The model's own forwards, the named faults of the
+reference and the router are ``tests/test_deepseek_v32.py``'s; two files so
+that ``--dist loadfile`` spreads them.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from perfbench.reference import deepseek_v32 as ref  # noqa: E402
+from ray_tpu.models.decode import (StepRows, init_paged_caches,  # noqa: E402
+                                   init_slot_caches, paged_decode_step,
+                                   paged_prefill_into_slot)
+from ray_tpu.models.presets import deepseek_v32_debug  # noqa: E402
+from ray_tpu.models.transformer import (INDEXED_LATENT,  # noqa: E402
+                                        init_params)
+from ray_tpu.ops.indexed_attention import IndexerSizes, index_row  # noqa: E402
+from ray_tpu.ops.latent_attention import join, latent_attention  # noqa: E402
+from ray_tpu.ops.picked_latent_attention import (  # noqa: E402
+    picked_latent_attention, picked_rows)
+from tests.test_deepseek_v32 import (TOL, hp_of,  # noqa: E402
+                                     near_the_references_best, stirred)
+from tests.test_glm_moe_lite import rel, serve  # noqa: E402
+
+
+# ---------------------------------------------------------------- the op
+
+
+def _op_case(B, S, lengths, topk, H=4, rank=32, rope=8, Hi=4, Di=16, T=4,
+             P=20, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    N = B * P + 1
+    q_c = jax.random.normal(ks[0], (B, S, H, rank))
+    q_r = jax.random.normal(ks[1], (B, S, H, rope))
+    qi = jax.random.normal(ks[2], (B, S, Hi, Di))
+    w = jax.random.normal(ks[3], (B, S, Hi))
+    pool = join(jax.random.normal(ks[4], (N, T, rank)),
+                jax.random.normal(ks[5], (N, T, rope)))
+    ik = index_row(jax.random.normal(ks[6], (N, T, Di)))
+    tables = jnp.asarray(
+        1 + np.random.default_rng(seed).permutation(B * P).reshape(B, P),
+        jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    positions = lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
+    sizes = IndexerSizes(indexer_num_heads=Hi, indexer_head_dim=Di,
+                         topk=topk)
+    return (q_c, q_r, qi, w, pool, ik, tables, positions, lengths, sizes)
+
+
+@pytest.mark.parametrize("impl,B,S,lengths", [
+    ("pallas", 3, 1, [7, 30, -1]), ("pallas", 1, 24, [9]),
+    ("reference", 2, 70, [0, 5])])
+def test_a_context_within_topk_is_plain_latent_attention(impl, B, S,
+                                                         lengths):
+    """Rows whose context is no longer than ``topk`` attend all of it: the
+    op equals ``ops.latent_attention``, whatever the index scores."""
+    *args, sizes = _op_case(B, S, lengths, topk=96)
+    q_c, q_r, qi, w, pool, ik, tables, positions, lens = args
+    with jax.default_matmul_precision("highest"):
+        got, take = picked_latent_attention(
+            *args, sizes, sm_scale=0.17, impl=impl, return_selected=True)
+        want = latent_attention(q_c, q_r, pool, tables, lens, sm_scale=0.17,
+                                impl="reference")
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() <= 2e-6
+    seen = (np.arange(take.shape[-1])[None, None]
+            <= np.asarray(positions)[..., None])
+    live = (np.asarray(lens) + S > 0)[:, None, None]
+    assert (np.asarray(take) == (seen & live)).all()
+
+
+@pytest.mark.parametrize("impl", ["pallas"])
+def test_the_op_attends_what_a_sort_would_pick(impl):
+    """Past ``topk``: against dense scores, a stable sort and a masked
+    softmax over the same pool, ties and all (index scores quantised)."""
+    args = _op_case(2, 40, [30, 3], topk=16, seed=3)
+    q_c, q_r, qi, w, pool, ik, tables, positions, lens, sizes = args
+    qi, w = jnp.round(qi * 2) / 2, jnp.round(w * 2) / 2
+    ik = jnp.round(ik * 2) / 2
+    args = (q_c, q_r, qi, w, pool, ik, tables, positions, lens, sizes)
+    with jax.default_matmul_precision("highest"):
+        got, take = picked_latent_attention(
+            *args, sm_scale=0.17, impl=impl, return_selected=True)
+        ctx = take.shape[-1]
+        rows = jnp.pad(pool[tables].reshape(2, -1, pool.shape[-1]),
+                       ((0, 0), (0, ctx - 80), (0, 0)))
+        keys = jnp.pad(ik[tables].reshape(2, -1, ik.shape[-1]),
+                       ((0, 0), (0, ctx - 80), (0, 0)))[..., :16]
+        scores = jnp.einsum("bsh,bshn->bsn", w, jnp.maximum(
+            jnp.einsum("bshd,bnd->bshn", qi, keys), 0.0))
+        want_take = np.stack([np.asarray(ref.select_block(
+            scores[b:b + 1], int(lens[b]), 16))[0] for b in range(2)])
+        assert (np.asarray(take) == want_take).all()
+        q = jnp.concatenate([q_c, q_r], -1)
+        s = jnp.einsum("bshw,bnw->bshn", q, rows[..., :40]) * 0.17
+        p = jax.nn.softmax(jnp.where(want_take[:, :, None], s, -jnp.inf), -1)
+        want = jnp.einsum("bshn,bnr->bshr", p, rows[..., :32])
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() <= 5e-6
+    assert (want_take.sum(-1) == np.minimum(
+        np.asarray(positions) + 1, 16)).all()
+
+
+def test_picked_rows_is_the_choice_in_order():
+    rng = np.random.default_rng(1)
+    take = rng.random((9, 640)) < 0.15
+    take[3] = False
+    take[4] = False
+    take[4, :5] = True
+    take[5] = False
+    take[5, 639] = True
+    at, count = picked_rows(jnp.asarray(take), 128)
+    for r in range(9):
+        want = np.nonzero(take[r])[0][:128]
+        assert int(count[r]) == take[r].sum()
+        assert (np.asarray(at[r])[:len(want)] == want).all()
+        assert not np.asarray(at[r])[len(want):].any()
+
+
+# --------------------------------------------------------- the paged programs
+
+
+@pytest.fixture(scope="module", params=["reference", "pallas"])
+def paged_run(request):
+    """Two prompts through the paged programs. Slot 1 takes a 53-token
+    prompt in chunks of 16 (over three chunk boundaries, ending inside a
+    chunk, past ``topk`` 24); slot 2 then a 33-token prompt (a page's first
+    token last) whose chunks take slot 1's decode row along (the fused
+    turn); then plain steps of both. Slots 0 and 3 hold no sequence, and
+    every page no table names is FILLED WITH NaN in both arrays of every
+    layer's pool, as a released page would be: whatever read one would
+    show."""
+    impl = request.param
+    cfg = deepseek_v32_debug()
+    params = stirred(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(9), (2, 80), 0,
+                                cfg.vocab_size)
+    C, slots, T, P, n = 16, 4, 4, 24, {1: 53, 2: 33}
+    row = {1: 0, 2: 1}
+    tables = np.zeros((slots, P), np.int32)
+    for s in n:
+        tables[s] = 1 + s * P + np.arange(P)
+    caches = init_paged_caches(cfg, slots * P + 1 + 8, T, P)
+    named = np.unique(tables)
+    poisoned = np.setdiff1d(np.arange(slots * P + 9), named)
+    caches = [dataclasses.replace(c, ckr=c.ckr.at[poisoned].set(jnp.nan),
+                                  ik=c.ik.at[poisoned].set(jnp.nan))
+              for c in caches]
+    got = {s: [] for s in n}
+    routes = {s: [] for s in n}
+    picked = {s: [] for s in n}
+    cursor = {1: 0, 2: 0}
+    both = jnp.asarray(tables)
+
+    def step_rows(live):
+        active = np.zeros(slots, np.int32)
+        cursors = np.zeros(slots, np.int32)
+        for s in live:
+            active[s], cursors[s] = 1, cursor[s]
+        return StepRows(active, cursors, both, both,
+                        np.zeros(slots, np.float32),
+                        np.zeros(slots, np.uint32))
+
+    def ids_for(live):
+        ids = np.zeros(slots, np.int32)
+        for s in live:
+            ids[s] = tokens[row[s], cursor[s]]
+        return jnp.asarray(ids)
+
+    kw = dict(attn=impl, moe_info=True, logits=True, selected=True)
+    chunk = jax.jit(functools.partial(paged_prefill_into_slot, cfg, **kw))
+    step = jax.jit(functools.partial(paged_decode_step, cfg, **kw))
+    with jax.default_matmul_precision("highest"):
+        for s, live in ((1, []), (2, [1])):
+            prompt = np.asarray(tokens[row[s], :n[s]])
+            for c0 in range(0, n[s], C):
+                real = min(C, n[s] - c0)
+                padded = np.zeros((1, C), np.int32)
+                padded[0, :real] = prompt[c0:c0 + real]
+                _, caches, moe_info, logits, taps = chunk(
+                    params, jnp.asarray(padded), np.int32(real),
+                    np.int32(c0), both[s], both[s], caches, ids_for(live),
+                    np.int32(-1), np.float32(0), np.uint32(0),
+                    step_rows(live))
+                r = np.asarray(moe_info["routes"])[:, 0]
+                assert r.shape[0] == cfg.expert_layers
+                routes[s].append(r[:, :real])
+                picked[s].append(np.asarray(taps[0])[:, 0, :real])
+                cursor[s] = c0 + real
+                for other in live:
+                    got[other].append(logits[1 + other])
+                    routes[other].append(r[:, C + other][:, None])
+                    picked[other].append(np.asarray(taps[1])[:, other])
+                    cursor[other] += 1
+            got[s].append(logits[0])
+        for _ in range(6):
+            live = [1, 2]
+            rows = step_rows(live)
+            _, caches, moe_info, logits, taps = step(
+                params, ids_for(live), rows.active, rows.cursors,
+                rows.read_tables, rows.write_tables, caches,
+                rows.temperature, rows.seeds)
+            for s in live:
+                got[s].append(logits[s])
+                routes[s].append(np.asarray(moe_info["routes"])[:, s])
+                picked[s].append(np.asarray(taps)[:, s])
+                cursor[s] += 1
+    return {"cfg": cfg, "params": params, "tokens": tokens, "got": got,
+            "routes": routes, "picked": picked, "n": n, "row": row,
+            "cursor": cursor, "caches": caches, "poisoned": poisoned,
+            "tables": both, "impl": impl, "step": step}
+
+
+@pytest.mark.parametrize("slot", [1, 2])
+def test_paged_chunks_steps_and_fused_turns_match_the_reference(paged_run,
+                                                                slot):
+    run = paged_run
+    cfg, n, end = run["cfg"], run["n"][slot], run["cursor"][slot]
+    seq = run["tokens"][run["row"][slot]][None, :end]
+    routes = np.concatenate(run["routes"][slot], 1)[:, None]
+    picked = np.concatenate(run["picked"][slot], 1)[:, None]
+    assert routes.shape[2] == end == picked.shape[2]
+    got = jnp.stack(run["got"][slot])
+    assert np.isfinite(np.asarray(got)).all()
+    # the reference on ITS OWN selection and routes: both equal the
+    # program's, ties and all
+    want, took, taken = ref.forward_and_choices(run["params"], seq,
+                                                hp_of(cfg))
+    assert (took == routes).all()
+    assert (picked[..., :end] == taken).all() and not picked[
+        ..., end:].any()
+    assert rel(got, want[0][n - 1:]) <= TOL
+
+
+def test_the_paged_programs_left_the_poisoned_pages_alone(paged_run):
+    for c in paged_run["caches"]:
+        for pool in (c.ckr, c.ik):
+            assert np.isnan(np.asarray(
+                pool[paged_run["poisoned"][1:]])).all()
+
+
+@pytest.mark.parametrize("fault", ["an_index_key_never_written",
+                                   "a_released_page_read"])
+def test_a_fault_of_the_pool_is_refused(paged_run, fault):
+    """One more step of slot 1 on the pools the run left, once as they are
+    and once with the fault planted: index keys of a page the table names
+    zeroed (as if never written: the indexer picks other tokens), or that
+    page's latents poisoned as a released page is."""
+    run = paged_run
+    if run["impl"] != "reference":
+        pytest.skip("the pools' faults are read once, by the plain path")
+    cfg, slot = run["cfg"], 1
+    end = run["cursor"][slot]
+    seq = run["tokens"][run["row"][slot]][None, :end + 1]
+    want = ref.forward(run["params"], seq, hp_of(cfg))[0][end]
+    page = int(run["tables"][slot, 2])
+    caches = run["caches"]
+    if fault == "an_index_key_never_written":
+        # every page of the slot's context: the scores are then all alike
+        # and the choice is the first 24 tokens
+        pages = np.asarray(run["tables"][slot, :end // 4])
+        bad = [dataclasses.replace(c, ik=c.ik.at[pages].set(0.0))
+               for c in caches]
+    else:
+        bad = [dataclasses.replace(c, ckr=c.ckr.at[page].set(jnp.nan))
+               for c in caches]
+    active = np.zeros(4, np.int32)
+    cursors = np.zeros(4, np.int32)
+    active[slot], cursors[slot] = 1, end
+    ids = np.zeros(4, np.int32)
+    ids[slot] = run["tokens"][run["row"][slot], end]
+    errs = {}
+    with jax.default_matmul_precision("highest"):
+        for name, pools in (("sound", caches), (fault, bad)):
+            out = run["step"](
+                run["params"], jnp.asarray(ids), active, cursors,
+                run["tables"], run["tables"], list(pools),
+                np.zeros(4, np.float32), np.zeros(4, np.uint32))
+            logits = np.asarray(out[3])[slot]
+            errs[name] = (np.inf if not np.isfinite(logits).all()
+                          else rel(logits, want))
+    assert errs["sound"] <= TOL < errs[fault], errs
+
+
+# ------------------------------------------------------------ the scheduler
+
+
+def test_the_scheduler_serves_the_kind_and_counts_its_work():
+    from ray_tpu.serve._private.continuous import ContinuousScheduler
+    from ray_tpu.serve._private.work import token_bytes
+
+    cfg = deepseek_v32_debug(moe_held_count=8, num_layers=2)
+    params = stirred(cfg)
+    tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(9), (4, 80), 0,
+                                           cfg.vocab_size))
+    new = 8
+    sched = ContinuousScheduler(cfg, params, slots=3, prefill_chunk=16,
+                                arena_len=96, page_tokens=4,
+                                prefix_cache=False, attn="reference")
+    prompts = [tokens[i, :n].tolist() for i, n in enumerate((70, 9, 33, 24))]
+    try:
+        served = serve(sched, prompts, new)
+        stats = sched.stats()
+        assert sched.compiled_programs() == 2
+    finally:
+        sched.shutdown()
+    for prompt, out in zip(prompts, served):
+        assert len(out) == new
+        assert near_the_references_best(cfg, params, prompt, out)
+    L, topk = cfg.num_layers, 24
+    rows = [c for p in prompts for c in range(len(p) + new - 1)]
+    steps = [len(p) + i for p in prompts for i in range(new - 1)]
+    assert stats["picked_index_pairs"] == L * sum(c + 1 for c in rows)
+    assert stats["picked_step_index_pairs"] == L * sum(c + 1 for c in steps)
+    assert stats["picked_chosen_pairs"] == L * sum(
+        min(c + 1, topk) for c in rows)
+    assert stats["picked_step_chosen_pairs"] == L * sum(
+        min(c + 1, topk) for c in steps)
+    # a chosen row is 32 + 8 float32 values; an index key 16
+    assert stats["picked_latent_bytes"] == 160 * stats["picked_chosen_pairs"]
+    chunk_ends = [min(c0 + 16, len(p)) for p in prompts
+                  for c0 in range(0, len(p), 16)]
+    assert stats["picked_index_key_bytes"] == L * 64 * (
+        sum(c + 1 for c in steps) + sum(chunk_ends))
+    assert token_bytes(cfg, INDEXED_LATENT, 4) == 512
+    assert stats["fused_turns"] > 0 and stats["pages_in_use"] == 0
+    for other in ("latent_tokens_context", "indexed_tokens_context"):
+        assert other not in stats  # another kind's
+    assert stats["attn_tokens_attended"] == stats["attn_tokens_fetched"] > 0
+    # the experts: every live row of every EXPERT layer-call chose top-k of
+    # 16, of which the 8 held took theirs; the shared expert took every row
+    chosen = cfg.expert_layers * cfg.moe_top_k * len(rows)
+    assert stats["moe_routes_chosen"] == chosen
+    assert 0 < stats["moe_rows_routed"] < chosen
+    assert stats["moe_shared_rows"] == cfg.expert_layers * len(rows)
+
+
+def test_a_spliced_prefix_continues_to_the_same_logits():
+    """The prefix cache serves the kind: the second request splices the
+    first's pages — latents AND index keys under one table, a context past
+    ``topk`` — and answers what the reference, which knows no cache, ranks
+    best at every position."""
+    from ray_tpu.serve._private.continuous import ContinuousScheduler
+
+    cfg = deepseek_v32_debug(num_layers=2)
+    params = stirred(cfg)
+    tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(4), (96,), 0,
+                                           cfg.vocab_size)).tolist()
+    first, second = tokens[:64], tokens[:48] + tokens[70:90]
+    sched = ContinuousScheduler(cfg, params, prefix_cache=True, slots=2,
+                                prefill_chunk=16, arena_len=96,
+                                page_tokens=4, attn="reference")
+    try:
+        answers = [serve(sched, [p], 6)[0] for p in (first, second)]
+        stats = sched.stats()
+    finally:
+        sched.shutdown()
+    assert stats["prefix_hits"] == 1
+    assert stats["prefix_hit_tokens"] >= 44 > 24
+    for prompt, out in zip((first, second), answers):
+        assert near_the_references_best(cfg, params, prompt, out)
+
+
+def test_the_scheduler_refuses_what_the_kind_cannot_have():
+    from ray_tpu.serve._private.continuous import ContinuousScheduler
+
+    cfg = deepseek_v32_debug()
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    kw = dict(slots=2, prefill_chunk=16, arena_len=64, page_tokens=4,
+              attn="reference")
+    with pytest.raises(ValueError, match="speculative decoding cannot serve "
+                                         "a model with "
+                                         "'indexed_latent_attention'"):
+        ContinuousScheduler(cfg, params, drafter=object(), **kw)
+    with pytest.raises(ValueError, match="holds keys and values alone"):
+        init_slot_caches(cfg, 2, 64)
+    sched = ContinuousScheduler(cfg, params, prefix_cache=True, **kw)
+    try:
+        with pytest.raises(ValueError, match="rotated keys and index keys"):
+            sched.export_prefix([1, 2, 3, 4])
+    finally:
+        sched.shutdown()
