@@ -1714,16 +1714,20 @@ def picked_timing(seed: int, calls: int = 4) -> dict:
     over rows of 640 lanes, 64 index heads of 128, 2048 picked, a table of
     4128 pages PERMUTED over a pool of 33,024, seeded), one layer: a 512
     chunk at contexts of 8k, 20k and 55k and the step's 8 rows, in
-    milliseconds a call behind a warm-up — the whole op, and of it the
-    indexer's two kernels (``index_score`` + ``indexed_select``) alone; the
-    rest is the choice's compaction, the gather and the attention kernel."""
+    milliseconds a call behind a warm-up — the whole op, of it the
+    indexer's two kernels (``index_score`` + ``indexed_select``) alone, and
+    for the chunk the kernel alone that copies the slot's pages in, moves
+    each query's chosen rows together and attends them (``*_kernel_ms``,
+    since PR 62: 2048 sorted positions a query drawn below the context);
+    the rest is the choice's compaction, and for the step the gather."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from ray_tpu.ops.indexed_attention import (IndexerSizes, index_scores,
                                                select)
-    from ray_tpu.ops.picked_latent_attention import picked_latent_attention
+    from ray_tpu.ops.picked_latent_attention import (_attend_chunk,
+                                                     picked_latent_attention)
 
     sizes = IndexerSizes(indexer_num_heads=64, indexer_head_dim=128,
                          topk=2048)
@@ -1747,6 +1751,9 @@ def picked_timing(seed: int, calls: int = 4) -> dict:
         *a, sizes, sm_scale=0.135, impl="pallas"))
     indexer = jax.jit(lambda qi, w, ik, tables, pos: select(
         index_scores(qi, w, ik, tables, pos, False), pos, 2048, False))
+    kernel = jax.jit(lambda q, at, pool, tables, last: _attend_chunk(
+        q, at, jnp.full(at.shape[:2], 2048, jnp.int32), pool, tables, last,
+        P * T, 512, False, "picked_latent_chunk_attention"))
     out = {}
     for name, B, S, contexts in (("chunk", 1, 512, (8192, 20480, 56320)),
                                  ("step", 8, 1, (20480, 56320))):
@@ -1761,6 +1768,13 @@ def picked_timing(seed: int, calls: int = 4) -> dict:
                 whole, q_c, q_r, qi, w, pool, ik, tables[:B], pos, lengths)
             out[f"{name}_{ctx // 1024}k_indexer_ms"] = ms(
                 indexer, qi, w, ik, tables[:B], pos)
+            if S > 1:
+                at = np.stack([np.sort(rng.choice(ctx - S, 2048, False))
+                               for _ in range(S)])[None]
+                out[f"{name}_{ctx // 1024}k_kernel_ms"] = ms(
+                    kernel, normal(ks[6], (B, S, 128, 640)),
+                    jnp.asarray(at, jnp.int32), pool, tables[:B],
+                    pos[:, -1])
     return out
 
 

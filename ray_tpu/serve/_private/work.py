@@ -193,7 +193,13 @@ def _picked(work, n, layers, qk, cursors, real):
     (``picked_latent_bytes``: ``rank + rope`` values a chosen pair, read
     once a query and serving every head) and the index keys' the scoring
     reads at least (``picked_index_key_bytes``: a step's row its whole
-    context, a chunk its context once, ``index_head_dim`` values a token).
+    context, a chunk its context once, ``index_head_dim`` values a token),
+    and what the chunk's kernel does itself where it runs (the 'pallas'
+    lane; ``ops.picked_latent_attention``, step 3): the chosen rows it moved
+    out of a slot's context (``picked_rows_in_kernel``: a chunk's share of
+    ``picked_chosen_pairs``; a step's rows are gathered) and the context
+    tokens whose pages it copied in for that (``picked_context_tokens_
+    copied``: whole pages up to the chunk's last position, a layer).
     Returns (attended, fetched) a layer as the paged kernel's are counted:
     the chosen rows, each fetched once."""
     sizes, cfg = work.cfg.indexer, work.cfg
@@ -208,6 +214,12 @@ def _picked(work, n, layers, qk, cursors, real):
         scored = context
     else:
         scored = int(t[:, -1].sum()) + len(cursors)
+        if work.lane == "pallas":
+            T, P = work._page_tokens, work._pages_per_slot
+            last = np.asarray(cursors) + qk - 1
+            n["picked_rows_in_kernel"] += layers * attended
+            n["picked_context_tokens_copied"] += layers * T * int(
+                np.minimum(last // T + 1, P).sum())
     n["picked_latent_bytes"] += layers * attended * work.itemsize * (
         cfg.latent_kv_rank + cfg.latent_rope_dim)
     n["picked_index_key_bytes"] += (layers * scored * work.itemsize
@@ -253,7 +265,9 @@ _KINDS = {
                                "picked_chosen_pairs",
                                "picked_step_chosen_pairs",
                                "picked_latent_bytes",
-                               "picked_index_key_bytes")),
+                               "picked_index_key_bytes",
+                               "picked_rows_in_kernel",
+                               "picked_context_tokens_copied")),
     SLIDING: (_window, ("window_attn_step_keys", "full_attn_step_keys",
                         "window_attn_chunk_pairs", "full_attn_chunk_pairs",
                         "window_tokens_held", "window_tokens_unreleased")),

@@ -7,6 +7,7 @@ is already the suite's longest.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +20,19 @@ from tests.test_tpu_compile import (_cell_programs, _fits,  # noqa: F401
 
 
 PICKED_KERNELS = {"index_score": 5, "indexed_select": 5}
+
+
+def _run_gathers(text: str) -> list:
+    """The compiled text's gathers of a query's run out of the latent pool:
+    a result of [*, 2048, 640]."""
+    return re.findall(r"= \w+\[[\d,]*2048,640\][^\n]* gather\(", text)
+
+
+def _vmem_asked(text: str, name: str) -> list:
+    """The VMEM bytes each custom call named ``name`` asks Mosaic for."""
+    return [int(size) for size in re.findall(
+        rf"%{name}[.\d]* = [^\n]*scoped_memory_configs[^\n]*?\"size\":"
+        r"\"(\d+)\"", text)]
 
 
 def test_deepseek_serve_programs_compile_and_fit(v5e):
@@ -34,6 +48,7 @@ def test_deepseek_serve_programs_compile_and_fit(v5e):
     name or the chunk's, the experts' kernel once an EXPERT layer; 9.27 GB
     of weights and the 4.03 GB pool beside the programs' own memory on one
     16 GB chip."""
+    from ray_tpu.ops.latent_attention import _VMEM_LIMIT
     from ray_tpu.ops.paged_attention import resolve_impl
 
     cfg, held, programs = _cell_programs(v5e, "deepseek_v32_exp_l5",
@@ -58,6 +73,11 @@ def test_deepseek_serve_programs_compile_and_fit(v5e):
             functools.partial(program, cfg, attn=lane, moe_info=True),
             donate_argnums=(6,)).lower(*args).compile()
         assert _kernel_calls(compiled) == calls[name], name
+        # the step's rows are gathered, a layer; the chunk's never
+        text = compiled.as_text()
+        assert len(_run_gathers(text)) == 5, name
+        assert all(size <= _VMEM_LIMIT for size in _vmem_asked(
+            text, "picked_latent_chunk_attention"))
         total = _fits(compiled)
         temp = compiled.memory_analysis().temp_size_in_bytes
         print(name, total / 1e9, temp / 1e9)
@@ -65,13 +85,20 @@ def test_deepseek_serve_programs_compile_and_fit(v5e):
         assert temp < 1.2e9, f"{name}: {temp / 1e6:.0f} MB of temporaries"
 
 
-@pytest.mark.parametrize("S,K", [(8, 1), (1, 512)],
-                         ids=["step_8slots", "chunk_k512"])
-def test_picked_latent_attention_compiles_at_the_cells_shapes(v5e, S, K):
+@pytest.mark.parametrize("S,K,P", [(8, 1, 4128), (1, 512, 4128),
+                                   (1, 512, 10240)],
+                         ids=["step_8slots", "chunk_k512",
+                              "chunk_k512_at_the_models_limit"])
+def test_picked_latent_attention_compiles_at_the_cells_shapes(v5e, S, K, P):
     """``ops.picked_latent_attention`` alone at DeepSeek-V3.2-Exp's sizes:
     128 heads over rows of 640 lanes, 64 index heads of 128 over rows of 128
     lanes, 2048 picked, pages of 16 through a table of 4128 — the 8 slots'
-    step and a 512 chunk."""
+    step and a 512 chunk — and the chunk through a table of the model's own
+    163,840 positions, which the kernel walks in two segments. The chunk
+    brings its chosen rows together ITSELF (ISSUE 62): no gather makes a
+    run of [*, 2048, 640], and every call of the kernel asks Mosaic for
+    less VMEM than the limit."""
+    from ray_tpu.ops.latent_attention import _VMEM_LIMIT
     from ray_tpu.ops.indexed_attention import IndexerSizes
     from ray_tpu.ops.picked_latent_attention import picked_latent_attention
 
@@ -86,10 +113,15 @@ def test_picked_latent_attention_compiles_at_the_cells_shapes(v5e, S, K):
         _on(chip, (S, K, 128, 512)), _on(chip, (S, K, 128, 64)),
         _on(chip, (S, K, 64, 128)), _on(chip, (S, K, 64), jnp.float32),
         _on(chip, (32769, 16, 640)), _on(chip, (32769, 16, 128)),
-        _on(chip, (S, 4128), jnp.int32), _on(chip, (S, K), jnp.int32),
+        _on(chip, (S, P), jnp.int32), _on(chip, (S, K), jnp.int32),
         _on(chip, (S,), jnp.int32)).compile()
-    assert _kernel_calls(compiled) == {name: 1, "index_score": 1,
-                                       "indexed_select": 1}
+    assert _kernel_calls(compiled) == {name: 1 if P == 4128 else 2,
+                                       "index_score": 1, "indexed_select": 1}
+    text = compiled.as_text()
+    assert (K == 1) == bool(_run_gathers(text))
+    asked = _vmem_asked(text, name)
+    assert len(asked) == (1 if P == 4128 else 2)
+    assert all(size <= _VMEM_LIMIT for size in asked), asked
     temp = compiled.memory_analysis().temp_size_in_bytes
     print(name, temp / 1e9)
     assert temp < 0.9e9, f"{temp / 1e6:.0f} MB of temporaries"

@@ -33,7 +33,8 @@ from ray_tpu.models.transformer import (INDEXED_LATENT,  # noqa: E402
 from ray_tpu.ops.indexed_attention import IndexerSizes, index_row  # noqa: E402
 from ray_tpu.ops.latent_attention import join, latent_attention  # noqa: E402
 from ray_tpu.ops.picked_latent_attention import (  # noqa: E402
-    picked_latent_attention, picked_rows)
+    _attend_chunk, _attend_reference, _segments, picked_latent_attention,
+    picked_rows)
 from tests.test_deepseek_v32 import (TOL, hp_of,  # noqa: E402
                                      near_the_references_best, stirred)
 from tests.test_glm_moe_lite import rel, serve  # noqa: E402
@@ -65,7 +66,7 @@ def _op_case(B, S, lengths, topk, H=4, rank=32, rope=8, Hi=4, Di=16, T=4,
 
 @pytest.mark.parametrize("impl,B,S,lengths", [
     ("pallas", 3, 1, [7, 30, -1]), ("pallas", 1, 24, [9]),
-    ("reference", 2, 70, [0, 5])])
+    ("pallas", 3, 20, [3, -20, 40]), ("reference", 2, 70, [0, 5])])
 def test_a_context_within_topk_is_plain_latent_attention(impl, B, S,
                                                          lengths):
     """Rows whose context is no longer than ``topk`` attend all of it: the
@@ -129,6 +130,102 @@ def test_picked_rows_is_the_choice_in_order():
         assert int(count[r]) == take[r].sum()
         assert (np.asarray(at[r])[:len(want)] == want).all()
         assert not np.asarray(at[r])[len(want):].any()
+
+
+@pytest.mark.parametrize("case", ["one_segment", "two_segments", "bf16"])
+def test_the_chunk_kernel_is_the_reference_over_the_gathered_run(case):
+    """``_attend_chunk`` (the slot's pages copied into the kernel's scratch,
+    the chosen rows moved there) against ``_attend_reference`` over XLA's
+    gather of the same rows: three slots' chunks across pages of 4 through
+    permuted tables — one past ``topk`` from its first query, one that
+    starts at 0 (a choice shorter than the run), one that attends nothing
+    and returns zeros — every page behind a slot's reach filled with NaN,
+    the dead slot's whole table too. ``two_segments``: a budget that holds
+    half the context, so each query's choice is split where the segments
+    meet and the softmax merged behind the two calls."""
+    dtype = jnp.bfloat16 if case == "bf16" else jnp.float32
+    B, S, H, rank, rope, T, P, topk = 3, 24, 4, 32, 8, 4, 24, 40
+    lengths = np.asarray([70, 0, -S])
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    rng = np.random.default_rng(5)
+    tables = 1 + rng.permutation(B * P).reshape(B, P)
+    pool = join(jax.random.normal(ks[0], (B * P + 1, T, rank)),
+                jax.random.normal(ks[1], (B * P + 1, T, rope))).astype(dtype)
+    W, width, context = pool.shape[-1], 128, 128   # the table's 96 in lanes
+    last = np.where(lengths + S > 0, lengths + S - 1, -1)
+    reached = np.concatenate([[0]] + [tables[b, :last[b] // T + 1]
+                                      for b in range(B) if last[b] >= 0])
+    pool = pool.at[np.setdiff1d(np.arange(B * P + 1), reached)].set(jnp.nan)
+    take = np.zeros((B, S, context), bool)
+    for b in range(B):
+        for i in range(S):
+            t = lengths[b] + i
+            if t >= 0:
+                take[b, i, rng.permutation(t + 1)[:topk]] = True
+    at, count = picked_rows(jnp.asarray(take.reshape(B * S, context)), width)
+    q = jax.random.normal(ks[2], (B, S, H, W)).astype(dtype)
+    budget = 1 << 30
+    if case == "two_segments":
+        whole = _segments(context, T, width, H, W * 4, budget)[2]
+        budget = whole - context * W * 4 // 2
+        assert _segments(context, T, width, H, W * 4, budget)[:2] == (2, 64)
+    with jax.default_matmul_precision("highest"):
+        got = _attend_chunk(q, at.reshape(B, S, width), count.reshape(B, S),
+                            pool, jnp.asarray(tables, jnp.int32),
+                            jnp.asarray(last, jnp.int32), context, rank,
+                            True, "picked_latent_chunk_attention",
+                            budget=budget)
+        rows = np.where(np.arange(width)[None] < np.asarray(count)[:, None],
+                        tables[np.repeat(np.arange(B), S)[:, None],
+                               np.asarray(at) // T] * T + np.asarray(at) % T,
+                        0)
+        want = _attend_reference(q.reshape(B * S, H, W),
+                                 pool.reshape(-1, W)[rows], count, rank)
+    got = np.asarray(got, np.float32).reshape(B * S, H, rank)
+    assert np.isfinite(got).all() and not got[2 * S:].any()
+    assert (np.asarray(count)[:2 * S] == np.minimum(
+        (lengths[:2, None] + np.arange(S) + 1).reshape(-1), topk)).all()
+    tol = 2e-2 if case == "bf16" else 5e-6
+    assert np.abs(got - np.asarray(want, np.float32)).max() <= tol
+
+
+def test_the_segments_follow_the_budget_and_the_rows_bytes():
+    """From the shapes alone: the cell's context (66,048 rows of 1,280 B
+    under 128 heads) is ONE segment under the kernel's limit, the model's
+    own (163,840) is two of whole pages and tiles, and either way the
+    kernel asks for less than the limit."""
+    from ray_tpu.ops.latent_attention import _VMEM_LIMIT
+
+    budget = _VMEM_LIMIT - (8 << 20)
+    for context, segments in ((66_048, 1), (163_840, 2)):
+        n, rows, vmem = _segments(context, 16, 2048, 128, 1280, budget)
+        assert n == segments and n * rows >= context > (n - 1) * rows
+        assert rows % 16 == 0 and vmem <= budget
+
+
+def test_the_kernels_counters_follow_the_chunks_positions():
+    """``picked_rows_in_kernel`` and ``picked_context_tokens_copied`` by
+    position, beside ``picked_chosen_pairs``: a chunk's chosen rows, and
+    the whole pages up to its last position (its padding's too) a layer;
+    nothing for a step, and nothing where the kernel does not run."""
+    from ray_tpu.serve._private.work import Work
+
+    cfg = deepseek_v32_debug(num_layers=2)
+    L, topk = cfg.num_layers, cfg.indexer.topk
+    for lane in ("pallas", "reference"):
+        work = Work(cfg, slots=3, page_tokens=4, pages_per_slot=24,
+                    lane=lane, itemsize=2)
+        work.record(16, [30], real=10)     # positions 30 .. 39 of 30 .. 45
+        work.record(1, [50, 7], idle_rows=1)
+        work.record(16, [80], real=16)     # the last page is the table's
+        stats = work.stats()
+        chunks = L * (10 * topk + 16 * topk)
+        assert stats["picked_chosen_pairs"] == chunks + L * (topk + 8)
+        if lane == "reference":
+            chunks = 0
+        assert stats["picked_rows_in_kernel"] == chunks
+        assert stats["picked_context_tokens_copied"] == (
+            L * 4 * (12 + 24) if chunks else 0)
 
 
 # --------------------------------------------------------- the paged programs
@@ -336,6 +433,9 @@ def test_the_scheduler_serves_the_kind_and_counts_its_work():
                   for c0 in range(0, len(p), 16)]
     assert stats["picked_index_key_bytes"] == L * 64 * (
         sum(c + 1 for c in steps) + sum(chunk_ends))
+    # the 'reference' lane gathers: the chunk's kernel moved nothing
+    assert not stats["picked_rows_in_kernel"]
+    assert not stats["picked_context_tokens_copied"]
     assert token_bytes(cfg, INDEXED_LATENT, 4) == 512
     assert stats["fused_turns"] > 0 and stats["pages_in_use"] == 0
     for other in ("latent_tokens_context", "indexed_tokens_context"):
