@@ -80,6 +80,7 @@ from ray_tpu.data._internal.streaming import (_copy_batch, _np_concat,
                                               _np_rows, _np_slice, _np_take,
                                               _require_positive, epoch_order,
                                               epoch_batch_stream,
+                                              pipeline_rpc_calls,
                                               shuffle_rng,
                                               split_streamable_plan)
 
@@ -425,7 +426,7 @@ class _ExchangeProducerImpl:
         return {"np_bytes": np_b, "row_bytes": row_b}
 
     def run_loop(self, plan: _ProducerPlan) -> dict:
-        from ray_tpu._private import api, rpc
+        from ray_tpu._private import api
         from ray_tpu.data.block import block_to_batch
 
         core = api._core
@@ -452,7 +453,7 @@ class _ExchangeProducerImpl:
         sent = [0] * C  # per-edge messages committed (version 2n)
         edge = [f"{plan.rank}->{c}" for c in range(C)]
         total_rows = 0
-        prev_rpc = rpc._m_client_calls.total()
+        prev_rpc = pipeline_rpc_calls()
 
         def send(c: int, payload) -> None:
             sent[c] += 1
@@ -500,7 +501,7 @@ class _ExchangeProducerImpl:
                     rows += n
                     blocks += 1
                 total_rows += rows
-                now = rpc._m_client_calls.total()
+                now = pipeline_rpc_calls()
                 stats = {"role": "producer", "rank": plan.rank,
                          "epoch": epoch, "blocks": blocks, "rows": rows,
                          "rpc_calls": now - prev_rpc}
@@ -541,7 +542,7 @@ class _ExchangeConsumerImpl:
         return "ok"
 
     def run_loop(self, plan: _ConsumerPlan) -> dict:
-        from ray_tpu._private import api, rpc
+        from ray_tpu._private import api
 
         core = api._core
         if core is None:
@@ -565,7 +566,7 @@ class _ExchangeConsumerImpl:
         reads = [0] * R  # per-upstream message count
         m = 0  # downstream messages committed
         total_batches = 0
-        prev_rpc = rpc._m_client_calls.total()
+        prev_rpc = pipeline_rpc_calls()
         try:
             for epoch in range(1, plan.epochs + 1):
                 stage_stats: List[dict] = []
@@ -640,7 +641,7 @@ class _ExchangeConsumerImpl:
                     out.write(serialization.pack({"b": batch}), 2 * m)
                     batches += 1
                 total_batches += batches
-                now = rpc._m_client_calls.total()
+                now = pipeline_rpc_calls()
                 stage_stats.append({"role": "consumer", "rank": plan.rank,
                                     "epoch": epoch, "rows": rows_in,
                                     "batches": batches,
@@ -1037,9 +1038,8 @@ class ExchangeExecutor:
             self._consuming = [False] * self._C
 
     def _merged(self, copy: bool) -> Iterator[Dict[str, np.ndarray]]:
-        from ray_tpu._private import rpc
 
-        prev_rpc = rpc._m_client_calls.total()
+        prev_rpc = pipeline_rpc_calls()
         for epoch in range(1, self._epochs + 1):
             live = list(range(self._C))
             stage_reports: List[dict] = []
@@ -1079,7 +1079,7 @@ class ExchangeExecutor:
                         finally:
                             del msg, view
                             self._out_chs[c].ack(0, v)
-            now = rpc._m_client_calls.total()
+            now = pipeline_rpc_calls()
             wall = max(time.perf_counter() - (epoch_t0 or
                                               time.perf_counter()), 1e-9)
             mean_rows = max(sum(rows_per_consumer) / self._C, 1e-9)
@@ -1128,9 +1128,8 @@ class ExchangeExecutor:
 
     def _rank_epoch(self, c: int, epoch: int,
                     copy: bool) -> Iterator[Dict[str, np.ndarray]]:
-        from ray_tpu._private import rpc
 
-        prev_rpc = rpc._m_client_calls.total()
+        prev_rpc = pipeline_rpc_calls()
         batches = 0
         stall_s = 0.0
         epoch_t0 = None
@@ -1149,7 +1148,7 @@ class ExchangeExecutor:
                 rows = msg.get("rows", 0)
                 del msg, view
                 self._out_chs[c].ack(0, v)
-                now = rpc._m_client_calls.total()
+                now = pipeline_rpc_calls()
                 wall = max(time.perf_counter() - epoch_t0, 1e-9)
                 self._rank_epoch_stats[c].append({
                     "epoch": epoch, "batches": batches, "rows": rows,

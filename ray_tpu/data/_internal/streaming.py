@@ -157,6 +157,27 @@ def quiesce_driver_rpcs(timeout_s: float = 5.0) -> None:
         time.sleep(0.02)
 
 
+# outbound calls that are no part of a pipeline, by RPC method: the core
+# worker's flush of its task events to the controller (observability: every
+# 100th event a process records, whenever that falls), and its return of a
+# lease that idled out (a timer's doing, for a lease EARLIER task-path work
+# took: a pipeline that leased in a warm epoch would still show its
+# ``request_lease`` and ``push_task``, which stay counted)
+_NOT_THE_PIPELINES = ("task_events", "release_lease")
+
+
+def pipeline_rpc_calls() -> float:
+    """This process's outbound RPC calls so far, those ``_NOT_THE_PIPELINES``
+    names apart: what every per-epoch ``rpc_calls`` / ``consumer_rpc_calls``
+    delta is taken from (the zero-RPC proof is about the pipeline's own
+    control traffic)."""
+    from ray_tpu._private import rpc
+
+    calls = rpc._m_client_calls
+    return calls.total() - sum(calls.value({"method": method})
+                               for method in _NOT_THE_PIPELINES)
+
+
 # ------------------------------------------------------- epoch determinism
 
 
@@ -435,7 +456,7 @@ class _StreamReaderImpl:
                 "row_bytes": row_b}
 
     def run_loop(self, plan: _ReaderPlan) -> dict:
-        from ray_tpu._private import api, rpc
+        from ray_tpu._private import api
         from ray_tpu.data.block import block_to_batch
 
         core = api._core
@@ -457,7 +478,7 @@ class _StreamReaderImpl:
 
         n = 0  # messages committed (version 2n)
         total = 0
-        prev_rpc = rpc._m_client_calls.total()
+        prev_rpc = pipeline_rpc_calls()
         try:
             for epoch in range(1, plan.epochs + 1):
                 order = epoch_order(plan.num_tasks, plan.seed, epoch)
@@ -476,7 +497,7 @@ class _StreamReaderImpl:
                     _m_blocks.inc()
                     blocks += 1
                 total += blocks
-                now = rpc._m_client_calls.total()
+                now = pipeline_rpc_calls()
                 n += 1
                 out.write(serialization.pack({
                     "eof": epoch,
@@ -516,7 +537,7 @@ class _StreamTransformImpl:
         return "ok"
 
     def run_loop(self, plan: _TransformPlan) -> dict:
-        from ray_tpu._private import api, rpc
+        from ray_tpu._private import api
         from ray_tpu.data.block import block_to_batch
 
         core = api._core
@@ -539,7 +560,7 @@ class _StreamTransformImpl:
 
         n = 0
         blocks = 0
-        prev_rpc = rpc._m_client_calls.total()
+        prev_rpc = pipeline_rpc_calls()
         epochs_done = 0
         try:
             while True:
@@ -553,7 +574,7 @@ class _StreamTransformImpl:
                     stats = list(msg["stats"])
                     del msg, view
                     in_ch.ack(0, 2 * n)
-                    now = rpc._m_client_calls.total()
+                    now = pipeline_rpc_calls()
                     stats.append({"role": "transform", "epoch": epoch,
                                   "blocks": blocks,
                                   "rpc_calls": now - prev_rpc})
@@ -600,7 +621,7 @@ class _StreamBatcherImpl:
         return "ok"
 
     def run_loop(self, plan: _BatcherPlan) -> dict:
-        from ray_tpu._private import api, rpc
+        from ray_tpu._private import api
 
         core = api._core
         if core is None:
@@ -624,7 +645,7 @@ class _StreamBatcherImpl:
         reads = [0] * R  # per-upstream message count
         m = 0  # downstream messages committed
         total_batches = 0
-        prev_rpc = rpc._m_client_calls.total()
+        prev_rpc = pipeline_rpc_calls()
         try:
             for epoch in range(1, plan.epochs + 1):
                 stage_stats: List[dict] = []
@@ -668,7 +689,7 @@ class _StreamBatcherImpl:
                     _m_batches.inc()
                     batches += 1
                 total_batches += batches
-                now = rpc._m_client_calls.total()
+                now = pipeline_rpc_calls()
                 stage_stats.append({"role": "batcher", "epoch": epoch,
                                     "blocks": blocks_in,
                                     "batches": batches,
@@ -987,12 +1008,11 @@ class StreamingExecutor:
             self._consuming = False
 
     def _batches(self, copy: bool) -> Iterator[Dict[str, np.ndarray]]:
-        from ray_tpu._private import rpc
 
         epoch_t0 = None
         stall_s = 0.0
         batches = 0
-        prev_rpc = rpc._m_client_calls.total()
+        prev_rpc = pipeline_rpc_calls()
         while True:
             v = 2 * (self._m + 1)
             t0 = time.perf_counter()
@@ -1017,7 +1037,7 @@ class StreamingExecutor:
                 stats = list(msg["stats"])
                 del msg, view
                 self._out_ch.ack(0, v)
-                now = rpc._m_client_calls.total()
+                now = pipeline_rpc_calls()
                 wall = max(time.perf_counter() - epoch_t0, 1e-9)
                 self._epoch_stats.append({
                     "epoch": epoch, "batches": batches,

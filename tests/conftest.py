@@ -27,7 +27,19 @@ os.environ.setdefault("RAY_TPU_HEALTH_CHECK_PERIOD_MS", "200")
 os.environ.setdefault("RAY_TPU_HEALTH_CHECK_TIMEOUT_MS", "1000")
 os.environ.setdefault("RAY_TPU_HEALTH_CHECK_FAILURE_THRESHOLD", "3")
 
+import sys  # noqa: E402
+
 import pytest  # noqa: E402
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _harness_memos_go_with_the_file():
+    """What ``tests/model_harness.py`` compiled and seeded for one file's
+    tests is dropped behind them: a worker holds one file's programs."""
+    yield
+    harness = sys.modules.get("tests.model_harness")
+    if harness is not None:
+        harness.forget()
 
 
 @pytest.fixture(scope="module")

@@ -21,12 +21,14 @@ import pytest
 
 from perfbench.reference import brumby as ref
 from ray_tpu.models import forward, init_params, logical_axes, transformer
-from ray_tpu.models.decode import (RetentionState, decode_step, init_caches,
-                                   init_paged_caches, paged_decode_step,
+from ray_tpu.models.decode import (RetentionState, init_caches,
+                                   init_paged_caches,
                                    paged_prefill_into_slot,
-                                   paged_verify_step, prefill)
+                                   paged_verify_step)
 from ray_tpu.models.presets import brumby_debug
 from ray_tpu.ops import power_retention as pr
+from tests import model_harness as harness
+from tests.model_harness import rel as rel_err
 
 TOL = 1e-4
 
@@ -35,11 +37,6 @@ def hp_of(cfg):
     """The reference's view of a program config (the source's keys)."""
     return {"rms_norm_eps": cfg.norm_eps, "rope_theta": cfg.rope_theta,
             "retention_eps": pr.EPS, "num_hidden_layers": cfg.num_layers}
-
-
-def rel_err(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.abs(got - want).max() / np.abs(want).max())
 
 
 @pytest.fixture(scope="module")
@@ -346,24 +343,18 @@ def test_the_tolerance_refuses(toy, monkeypatch, fault):
 def test_prefill_and_decode_step_match_the_full_forward(toy):
     cfg, params, tokens, want = toy
     n, total = 120, tokens.shape[1]
-    caches = init_caches(cfg, 2, total)
-    logits, caches = jax.jit(prefill, static_argnums=0)(
-        cfg, params, tokens[:, :n], caches)
-    got = [logits]
-    step = jax.jit(decode_step, static_argnums=0)
-    for t in range(n, total - 1):
-        logits, caches = step(cfg, params, tokens[:, t:t + 1], caches)
-        got.append(logits)
-    assert rel_err(jnp.stack(got, 1), want[:, n - 1:-1]) < TOL
+    got = harness.cached_logits(cfg, params, tokens[:, :-1], n, length=total)
+    assert rel_err(got, want[:, n - 1:-1]) < TOL
 
 
 # ------------------------------------------------------ the paged programs
 
-PREFILL = jax.jit(paged_prefill_into_slot, static_argnums=0,
-                  static_argnames=("attn", "logits"))
-STEP = jax.jit(paged_decode_step, static_argnums=0,
-               static_argnames=("attn", "logits"))
 C = 32  # the chunk
+
+
+def programs(cfg):
+    """The chunk's program and the step's, the harness's."""
+    return harness.paged_programs(cfg, attn="reference", logits=True)
 
 
 def chunks_into_slot(cfg, params, caches, prompt, slot, slots):
@@ -375,10 +366,10 @@ def chunks_into_slot(cfg, params, caches, prompt, slot, slots):
         part = prompt[at:at + C]
         tokens = np.zeros((1, C), np.int32)
         tokens[0, :len(part)] = part
-        ids, caches, logits = PREFILL(
-            cfg, params, tokens, np.int32(len(part)), np.int32(at), None,
-            None, caches, ids, np.int32(slot), np.float32(0), np.uint32(0),
-            None, np.int32(slot), attn="reference", logits=True)
+        ids, caches, logits = programs(cfg)[0](
+            params, tokens, np.int32(len(part)), np.int32(at), None, None,
+            caches, ids, np.int32(slot), np.float32(0), np.uint32(0), None,
+            np.int32(slot))
     return ids, caches, logits
 
 
@@ -401,10 +392,10 @@ def test_the_paged_programs_match_the_reference_without_a_page(toy):
     cursors = np.asarray([0, n, 70], np.int32)
     for t in range(6):
         fed = jnp.asarray([0, row[n + t], other[70 + t]], jnp.int32)
-        _, caches, logits = STEP(
-            cfg, params, fed, active, jnp.asarray(cursors + t), None, None,
+        _, caches, logits = programs(cfg)[1](
+            params, fed, active, jnp.asarray(cursors + t), None, None,
             caches, jnp.zeros(slots, jnp.float32),
-            jnp.zeros(slots, jnp.uint32), attn="reference", logits=True)
+            jnp.zeros(slots, jnp.uint32))
         assert rel_err(logits[1], want[0, n + t]) < TOL
         assert rel_err(logits[2], want[1, 70 + t]) < TOL
     # the slot that took no part has its states bitwise
@@ -463,18 +454,6 @@ def test_what_such_a_model_refuses(toy):
 # ------------------------------------------------------------ the scheduler
 
 
-def greedy(cfg, params, prompt, n):
-    """The sequential path: ``prefill`` + ``decode_step``, argmax."""
-    caches = init_caches(cfg, 1, len(prompt) + n)
-    logits, caches = prefill(cfg, params, jnp.asarray([prompt]), caches)
-    out = []
-    for _ in range(n):
-        out.append(int(jnp.argmax(logits[0])))
-        logits, caches = decode_step(cfg, params, jnp.asarray([[out[-1]]]),
-                                     caches)
-    return out
-
-
 def test_the_scheduler_serves_a_model_without_pages(toy):
     """Five requests through three slots: the sequential greedy path's
     tokens (so a retired slot's state was taken as zero by the next), two
@@ -496,28 +475,18 @@ def test_the_scheduler_serves_a_model_without_pages(toy):
     sched._step._cache_size = step._cache_size
 
     async def main():
-        loop = asyncio.get_running_loop()
-
-        async def one(prompt):
-            queue = asyncio.Queue()
-            sched.submit(prompt, max_new_tokens=new, loop=loop, queue=queue)
-            tokens = []
-            while True:
-                kind, value, _ = await queue.get()
-                if kind != "tok":
-                    return tokens, kind, value
-                tokens.append(value)
-
-        return await asyncio.gather(*[one(p) for p in prompts])
+        return await asyncio.gather(*[harness.stream(sched, p, new)
+                                      for p in prompts])
 
     try:
         served = asyncio.run(main())
         st = sched.stats()
     finally:
         sched.shutdown()
-    for prompt, (tokens, kind, value) in zip(prompts, served):
-        assert (kind, value) == ("end", "length")
-        assert tokens == greedy(cfg, params, prompt, new)
+    for prompt, (tokens, end) in zip(prompts, served):
+        assert end == ("end", "length")
+        # the sequential path: argmax of ``decode_step`` a token at a time
+        assert tokens == harness.oracle(cfg, params, prompt, new)
     assert st["compiled_programs"] == 2
     # a chunk's program takes the live rows along: one program a turn
     assert st["fused_turns"] == sum(1 for n in carried if n) > 0

@@ -21,7 +21,6 @@ programs and the scheduler are ``tests/test_deepseek_v32_paged.py``'s.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import itertools
 import os
 import sys
@@ -34,8 +33,6 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from perfbench.reference import deepseek_v32 as ref  # noqa: E402
-from ray_tpu.models.decode import (decode_step, init_caches,  # noqa: E402
-                                   prefill)
 from ray_tpu.models.presets import (deepseek_v32_debug,  # noqa: E402
                                     glm_moe_lite_debug)
 from ray_tpu.models.transformer import (INDEXED_LATENT,  # noqa: E402
@@ -43,7 +40,9 @@ from ray_tpu.models.transformer import (INDEXED_LATENT,  # noqa: E402
                                         layer_params, logical_axes)
 from ray_tpu.ops import moe  # noqa: E402
 from ray_tpu.ops.indexed_attention import IndexerSizes  # noqa: E402
-from tests.test_glm_moe_lite import rel, seeded  # noqa: E402
+from tests import model_harness as harness  # noqa: E402
+from tests.model_harness import rel  # noqa: E402
+from tests.test_glm_moe_lite import seeded  # noqa: E402
 
 TOL = 1e-4
 
@@ -84,10 +83,8 @@ def stirred(cfg, seed=0):
 
 
 def near_the_references_best(cfg, params, prompt, out):
-    seq = jnp.asarray([prompt + out[:-1]], jnp.int32)
-    want = ref.forward(params, seq, hp_of(cfg))[0][len(prompt) - 1:]
-    return all(logits.max() - logits[tok] <= 1e-3 * np.abs(want).max()
-               for logits, tok in zip(want, out))
+    return harness.near_the_references_best(
+        lambda seq: ref.forward(params, seq, hp_of(cfg)), prompt, out)
 
 
 @pytest.fixture(scope="module")
@@ -348,13 +345,5 @@ def test_prefill_and_decode_step_match_the_reference(toy, n):
     cfg, params, tokens = toy["cfg"], toy["params"], toy["tokens"]
     want = ref.forward(params, tokens, hp_of(cfg), toy["routes"],
                        toy["selected"])
-    step = jax.jit(functools.partial(decode_step, cfg))
-    with jax.default_matmul_precision("highest"):
-        caches = init_caches(cfg, 2, 72)
-        logits, caches = jax.jit(functools.partial(prefill, cfg))(
-            params, tokens[:, :n], caches)
-        got = [logits]
-        for t in range(n, 72):
-            logits, caches = step(params, tokens[:, t:t + 1], caches)
-            got.append(logits)
-    assert rel(jnp.stack(got, 1), want[:, n - 1:]) <= TOL
+    assert rel(harness.cached_logits(cfg, params, tokens, n),
+               want[:, n - 1:]) <= TOL

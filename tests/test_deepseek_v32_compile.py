@@ -1,9 +1,9 @@
 """DeepSeek-V3.2-Exp's cell compiled for the chip, without the chip (ISSUE
 61): the cell's two serving programs and the picked latent attention alone
 at the published widths, for a described ``v5e``. The fixtures and helpers
-are ``tests/test_tpu_compile.py``'s; the tests stand in a file of their own
-because ``--dist loadfile`` hands a whole file to one worker, and that file
-is already the suite's longest.
+are ``tests/tpu_compile_harness.py``'s; the tests stand in a file of their
+own, as every model's do, because ``--dist loadfile`` hands a whole file to
+one worker.
 """
 
 import functools
@@ -14,9 +14,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from tests.test_tpu_compile import (_cell_programs, _fits,  # noqa: F401
-                                    _kernel_calls, _on, as_a_tpu_process,
-                                    v5e)
+from tests.tpu_compile_harness import (  # noqa: F401
+    as_a_tpu_process, cell_programs, fits, kernel_calls, on, v5e)
 
 
 PICKED_KERNELS = {"index_score": 5, "indexed_select": 5}
@@ -51,7 +50,7 @@ def test_deepseek_serve_programs_compile_and_fit(v5e):
     from ray_tpu.ops.latent_attention import _VMEM_LIMIT
     from ray_tpu.ops.paged_attention import resolve_impl
 
-    cfg, held, programs = _cell_programs(v5e, "deepseek_v32_exp_l5",
+    cfg, held, programs = cell_programs(v5e, "deepseek_v32_exp_l5",
                                          "deepseek_v32_longdocs")
     assert (cfg.embed_dim, cfg.head_dim, cfg.hidden_dim) == (7168, 192, 2048)
     assert (cfg.lead_layers, cfg.expert_layers, cfg.period) == (1, 4, 1)
@@ -72,13 +71,13 @@ def test_deepseek_serve_programs_compile_and_fit(v5e):
         compiled = jax.jit(
             functools.partial(program, cfg, attn=lane, moe_info=True),
             donate_argnums=(6,)).lower(*args).compile()
-        assert _kernel_calls(compiled) == calls[name], name
+        assert kernel_calls(compiled) == calls[name], name
         # the step's rows are gathered, a layer; the chunk's never
         text = compiled.as_text()
         assert len(_run_gathers(text)) == 5, name
         assert all(size <= _VMEM_LIMIT for size in _vmem_asked(
             text, "picked_latent_chunk_attention"))
-        total = _fits(compiled)
+        total = fits(compiled)
         temp = compiled.memory_analysis().temp_size_in_bytes
         print(name, total / 1e9, temp / 1e9)
         assert total < 14.6e9, f"{name}: {total / 1e9:.1f} GB"
@@ -110,12 +109,12 @@ def test_picked_latent_attention_compiles_at_the_cells_shapes(v5e, S, K, P):
     compiled = jax.jit(functools.partial(
         picked_latent_attention, sizes=sizes, sm_scale=0.135,
         impl="pallas")).lower(
-        _on(chip, (S, K, 128, 512)), _on(chip, (S, K, 128, 64)),
-        _on(chip, (S, K, 64, 128)), _on(chip, (S, K, 64), jnp.float32),
-        _on(chip, (32769, 16, 640)), _on(chip, (32769, 16, 128)),
-        _on(chip, (S, P), jnp.int32), _on(chip, (S, K), jnp.int32),
-        _on(chip, (S,), jnp.int32)).compile()
-    assert _kernel_calls(compiled) == {name: 1 if P == 4128 else 2,
+        on(chip, (S, K, 128, 512)), on(chip, (S, K, 128, 64)),
+        on(chip, (S, K, 64, 128)), on(chip, (S, K, 64), jnp.float32),
+        on(chip, (32769, 16, 640)), on(chip, (32769, 16, 128)),
+        on(chip, (S, P), jnp.int32), on(chip, (S, K), jnp.int32),
+        on(chip, (S,), jnp.int32)).compile()
+    assert kernel_calls(compiled) == {name: 1 if P == 4128 else 2,
                                        "index_score": 1, "indexed_select": 1}
     text = compiled.as_text()
     assert (K == 1) == bool(_run_gathers(text))

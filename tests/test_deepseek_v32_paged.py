@@ -12,7 +12,6 @@ that ``--dist loadfile`` spreads them.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import os
 import sys
 
@@ -24,9 +23,8 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from perfbench.reference import deepseek_v32 as ref  # noqa: E402
-from ray_tpu.models.decode import (StepRows, init_paged_caches,  # noqa: E402
-                                   init_slot_caches, paged_decode_step,
-                                   paged_prefill_into_slot)
+from ray_tpu.models.decode import (init_paged_caches,  # noqa: E402
+                                   init_slot_caches)
 from ray_tpu.models.presets import deepseek_v32_debug  # noqa: E402
 from ray_tpu.models.transformer import (INDEXED_LATENT,  # noqa: E402
                                         init_params)
@@ -35,9 +33,10 @@ from ray_tpu.ops.latent_attention import join, latent_attention  # noqa: E402
 from ray_tpu.ops.picked_latent_attention import (  # noqa: E402
     _attend_chunk, _attend_reference, _segments, picked_latent_attention,
     picked_rows)
+from tests import model_harness as harness  # noqa: E402
+from tests.model_harness import rel  # noqa: E402
 from tests.test_deepseek_v32 import (TOL, hp_of,  # noqa: E402
                                      near_the_references_best, stirred)
-from tests.test_glm_moe_lite import rel, serve  # noqa: E402
 
 
 # ---------------------------------------------------------------- the op
@@ -231,95 +230,28 @@ def test_the_kernels_counters_follow_the_chunks_positions():
 # --------------------------------------------------------- the paged programs
 
 
-@pytest.fixture(scope="module", params=["reference", "pallas"])
-def paged_run(request):
-    """Two prompts through the paged programs. Slot 1 takes a 53-token
-    prompt in chunks of 16 (over three chunk boundaries, ending inside a
-    chunk, past ``topk`` 24); slot 2 then a 33-token prompt (a page's first
-    token last) whose chunks take slot 1's decode row along (the fused
-    turn); then plain steps of both. Slots 0 and 3 hold no sequence, and
-    every page no table names is FILLED WITH NaN in both arrays of every
-    layer's pool, as a released page would be: whatever read one would
-    show."""
-    impl = request.param
+def _paged(request):
+    """Two prompts through the paged programs (``harness.paged_drive``).
+    Slot 1 takes a 53-token prompt in chunks of 16 (over three chunk
+    boundaries, ending inside a chunk, past ``topk`` 24); slot 2 then a
+    33-token prompt (a page's first token last) whose chunks take slot 1's
+    decode row along (the fused turn); then plain steps of both. Slots 0
+    and 3 hold no sequence, and every page no table names is FILLED WITH
+    NaN in both arrays of every layer's pool, as a released page would be:
+    whatever read one would show."""
     cfg = deepseek_v32_debug()
-    params = stirred(cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(9), (2, 80), 0,
-                                cfg.vocab_size)
-    C, slots, T, P, n = 16, 4, 4, 24, {1: 53, 2: 33}
-    row = {1: 0, 2: 1}
-    tables = np.zeros((slots, P), np.int32)
-    for s in n:
-        tables[s] = 1 + s * P + np.arange(P)
-    caches = init_paged_caches(cfg, slots * P + 1 + 8, T, P)
-    named = np.unique(tables)
-    poisoned = np.setdiff1d(np.arange(slots * P + 9), named)
-    caches = [dataclasses.replace(c, ckr=c.ckr.at[poisoned].set(jnp.nan),
-                                  ik=c.ik.at[poisoned].set(jnp.nan))
-              for c in caches]
-    got = {s: [] for s in n}
-    routes = {s: [] for s in n}
-    picked = {s: [] for s in n}
-    cursor = {1: 0, 2: 0}
-    both = jnp.asarray(tables)
+    slots, T, P = 4, 4, 24
+    return dict(
+        cfg=cfg, params=stirred(cfg), impl=request.param,
+        tokens=jax.random.randint(jax.random.PRNGKey(9), (2, 80), 0,
+                                  cfg.vocab_size),
+        caches=init_paged_caches(cfg, slots * P + 1 + 8, T, P),
+        tables=harness.slot_tables(slots, P, (1, 2)),
+        lengths={1: 53, 2: 33}, chunk=16, steps=6, moe_info=True,
+        selected=True)
 
-    def step_rows(live):
-        active = np.zeros(slots, np.int32)
-        cursors = np.zeros(slots, np.int32)
-        for s in live:
-            active[s], cursors[s] = 1, cursor[s]
-        return StepRows(active, cursors, both, both,
-                        np.zeros(slots, np.float32),
-                        np.zeros(slots, np.uint32))
 
-    def ids_for(live):
-        ids = np.zeros(slots, np.int32)
-        for s in live:
-            ids[s] = tokens[row[s], cursor[s]]
-        return jnp.asarray(ids)
-
-    kw = dict(attn=impl, moe_info=True, logits=True, selected=True)
-    chunk = jax.jit(functools.partial(paged_prefill_into_slot, cfg, **kw))
-    step = jax.jit(functools.partial(paged_decode_step, cfg, **kw))
-    with jax.default_matmul_precision("highest"):
-        for s, live in ((1, []), (2, [1])):
-            prompt = np.asarray(tokens[row[s], :n[s]])
-            for c0 in range(0, n[s], C):
-                real = min(C, n[s] - c0)
-                padded = np.zeros((1, C), np.int32)
-                padded[0, :real] = prompt[c0:c0 + real]
-                _, caches, moe_info, logits, taps = chunk(
-                    params, jnp.asarray(padded), np.int32(real),
-                    np.int32(c0), both[s], both[s], caches, ids_for(live),
-                    np.int32(-1), np.float32(0), np.uint32(0),
-                    step_rows(live))
-                r = np.asarray(moe_info["routes"])[:, 0]
-                assert r.shape[0] == cfg.expert_layers
-                routes[s].append(r[:, :real])
-                picked[s].append(np.asarray(taps[0])[:, 0, :real])
-                cursor[s] = c0 + real
-                for other in live:
-                    got[other].append(logits[1 + other])
-                    routes[other].append(r[:, C + other][:, None])
-                    picked[other].append(np.asarray(taps[1])[:, other])
-                    cursor[other] += 1
-            got[s].append(logits[0])
-        for _ in range(6):
-            live = [1, 2]
-            rows = step_rows(live)
-            _, caches, moe_info, logits, taps = step(
-                params, ids_for(live), rows.active, rows.cursors,
-                rows.read_tables, rows.write_tables, caches,
-                rows.temperature, rows.seeds)
-            for s in live:
-                got[s].append(logits[s])
-                routes[s].append(np.asarray(moe_info["routes"])[:, s])
-                picked[s].append(np.asarray(taps)[:, s])
-                cursor[s] += 1
-    return {"cfg": cfg, "params": params, "tokens": tokens, "got": got,
-            "routes": routes, "picked": picked, "n": n, "row": row,
-            "cursor": cursor, "caches": caches, "poisoned": poisoned,
-            "tables": both, "impl": impl, "step": step}
+paged_run = harness.paged_fixture(_paged, impls=["reference", "pallas"])
 
 
 @pytest.mark.parametrize("slot", [1, 2])
@@ -331,8 +263,9 @@ def test_paged_chunks_steps_and_fused_turns_match_the_reference(paged_run,
     routes = np.concatenate(run["routes"][slot], 1)[:, None]
     picked = np.concatenate(run["picked"][slot], 1)[:, None]
     assert routes.shape[2] == end == picked.shape[2]
-    got = jnp.stack(run["got"][slot])
-    assert np.isfinite(np.asarray(got)).all()
+    assert all(r["routes"].shape[0] == cfg.expert_layers
+               for r in run["info"])
+    got = harness.slot_logits(run, slot)
     # the reference on ITS OWN selection and routes: both equal the
     # program's, ties and all
     want, took, taken = ref.forward_and_choices(run["params"], seq,
@@ -344,10 +277,9 @@ def test_paged_chunks_steps_and_fused_turns_match_the_reference(paged_run,
 
 
 def test_the_paged_programs_left_the_poisoned_pages_alone(paged_run):
-    for c in paged_run["caches"]:
-        for pool in (c.ckr, c.ik):
-            assert np.isnan(np.asarray(
-                pool[paged_run["poisoned"][1:]])).all()
+    assert all(set(harness.pools(c)) == {"ckr", "ik"}
+               for c in paged_run["caches"])
+    harness.poisoned_pages_left_alone(paged_run)
 
 
 @pytest.mark.parametrize("fault", ["an_index_key_never_written",
@@ -397,7 +329,6 @@ def test_a_fault_of_the_pool_is_refused(paged_run, fault):
 
 
 def test_the_scheduler_serves_the_kind_and_counts_its_work():
-    from ray_tpu.serve._private.continuous import ContinuousScheduler
     from ray_tpu.serve._private.work import token_bytes
 
     cfg = deepseek_v32_debug(moe_held_count=8, num_layers=2)
@@ -405,16 +336,10 @@ def test_the_scheduler_serves_the_kind_and_counts_its_work():
     tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(9), (4, 80), 0,
                                            cfg.vocab_size))
     new = 8
-    sched = ContinuousScheduler(cfg, params, slots=3, prefill_chunk=16,
-                                arena_len=96, page_tokens=4,
-                                prefix_cache=False, attn="reference")
     prompts = [tokens[i, :n].tolist() for i, n in enumerate((70, 9, 33, 24))]
-    try:
-        served = serve(sched, prompts, new)
-        stats = sched.stats()
-        assert sched.compiled_programs() == 2
-    finally:
-        sched.shutdown()
+    served, stats = harness.served(
+        cfg, params, prompts, new, slots=3, prefill_chunk=16, arena_len=96,
+        page_tokens=4, prefix_cache=False)
     for prompt, out in zip(prompts, served):
         assert len(out) == new
         assert near_the_references_best(cfg, params, prompt, out)
@@ -454,21 +379,14 @@ def test_a_spliced_prefix_continues_to_the_same_logits():
     first's pages — latents AND index keys under one table, a context past
     ``topk`` — and answers what the reference, which knows no cache, ranks
     best at every position."""
-    from ray_tpu.serve._private.continuous import ContinuousScheduler
-
     cfg = deepseek_v32_debug(num_layers=2)
     params = stirred(cfg)
     tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(4), (96,), 0,
                                            cfg.vocab_size)).tolist()
     first, second = tokens[:64], tokens[:48] + tokens[70:90]
-    sched = ContinuousScheduler(cfg, params, prefix_cache=True, slots=2,
-                                prefill_chunk=16, arena_len=96,
-                                page_tokens=4, attn="reference")
-    try:
-        answers = [serve(sched, [p], 6)[0] for p in (first, second)]
-        stats = sched.stats()
-    finally:
-        sched.shutdown()
+    answers, stats = harness.served(
+        cfg, params, (first, second), 6, together=False, prefix_cache=True,
+        slots=2, prefill_chunk=16, arena_len=96, page_tokens=4)
     assert stats["prefix_hits"] == 1
     assert stats["prefix_hit_tokens"] >= 44 > 24
     for prompt, out in zip((first, second), answers):

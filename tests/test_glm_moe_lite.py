@@ -15,7 +15,6 @@ are refused by the same comparison.
 
 from __future__ import annotations
 
-import asyncio
 import dataclasses
 import functools
 import os
@@ -29,10 +28,8 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from perfbench.reference import glm_moe_lite as ref  # noqa: E402
-from ray_tpu.models.decode import (StepRows, decode_step,  # noqa: E402
-                                   init_caches, init_paged_caches,
-                                   init_slot_caches, paged_decode_step,
-                                   paged_prefill_into_slot, prefill)
+from ray_tpu.models.decode import (init_caches,  # noqa: E402
+                                   init_paged_caches, init_slot_caches)
 from ray_tpu.models.presets import (glm_moe_lite_debug,  # noqa: E402
                                     moe_debug)
 from ray_tpu.models.transformer import (LATENT, LAYER_KINDS,  # noqa: E402
@@ -41,6 +38,8 @@ from ray_tpu.models.transformer import (LATENT, LAYER_KINDS,  # noqa: E402
 from ray_tpu.ops import moe  # noqa: E402
 from ray_tpu.ops.latent_attention import (join, latent_attention,  # noqa: E402
                                           latent_tiles, pool_width)
+from tests import model_harness as harness  # noqa: E402
+from tests.model_harness import rel  # noqa: E402
 
 TOL = 1e-4
 
@@ -58,24 +57,11 @@ def hp_of(cfg):
             "n_shared_experts": cfg.moe_shared_experts}
 
 
-def seeded(cfg, seed=0):
-    """Weights with every norm's scale away from 1 (a norm left out, or one
-    scale taken for another, then shows)."""
-    params = init_params(cfg, jax.random.PRNGKey(seed))
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
+# weights with every norm's scale away from 1 (a norm left out, or one scale
+# taken for another, then shows)
+seeded = functools.partial(
+    harness.seeded, stir=("scale", "q_a_norm", "kv_norm"), by=0.3)
 
-    def stir(path, leaf):
-        name = jax.tree_util.keystr(path)
-        if any(n in name for n in ("scale", "q_a_norm", "kv_norm")):
-            return leaf + 0.3 * jax.random.normal(next(keys), leaf.shape)
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(stir, params)
-
-
-def rel(got, want):
-    return float(np.abs(np.asarray(got, np.float32) - want).max()
-                 / np.abs(want).max())
 
 
 @pytest.fixture(scope="module")
@@ -323,16 +309,8 @@ def test_prefill_and_decode_step_match_the_reference(toy, n):
     rebuilds keys and values."""
     cfg, params, tokens = toy["cfg"], toy["params"], toy["tokens"]
     want = ref.forward(params, tokens, hp_of(cfg), toy["routes"])
-    step = jax.jit(functools.partial(decode_step, cfg))
-    with jax.default_matmul_precision("highest"):
-        caches = init_caches(cfg, 2, 72)
-        logits, caches = jax.jit(functools.partial(prefill, cfg))(
-            params, tokens[:, :n], caches)
-        got = [logits]
-        for t in range(n, 72):
-            logits, caches = step(params, tokens[:, t:t + 1], caches)
-            got.append(logits)
-    assert rel(jnp.stack(got, 1), want[:, n - 1:]) <= TOL
+    assert rel(harness.cached_logits(cfg, params, tokens, n),
+               want[:, n - 1:]) <= TOL
 
 
 # ------------------------------------------------------------- the kernel
@@ -564,89 +542,27 @@ def test_the_tiles_are_the_paged_kernels_rule():
 # ------------------------------------------------------ the paged programs
 
 
-@pytest.fixture(scope="module", params=["reference", "pallas"])
-def paged_run(request):
-    """Two prompts through the paged programs. Slot 1 takes a 53-token
-    prompt in chunks of 16 (over three chunk boundaries, ending inside a
-    chunk); slot 2 then a 33-token prompt (a page's first token last) whose
-    chunks take slot 1's decode row along (the fused turn); then plain steps
-    of both. Slots 0 and 3 hold no sequence, and every page no table names
-    is FILLED WITH NaN in every layer's pool, as a released page would be:
-    whatever read one would show."""
-    impl = request.param
+def _paged(request):
+    """Two prompts through the paged programs (``harness.paged_drive``).
+    Slot 1 takes a 53-token prompt in chunks of 16 (over three chunk
+    boundaries, ending inside a chunk); slot 2 then a 33-token prompt (a
+    page's first token last) whose chunks take slot 1's decode row along
+    (the fused turn); then plain steps of both. Slots 0 and 3 hold no
+    sequence, and every page no table names is FILLED WITH NaN in every
+    layer's pool, as a released page would be: whatever read one would
+    show."""
     cfg = glm_moe_lite_debug()
-    params = seeded(cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(9), (2, 80), 0,
-                                cfg.vocab_size)
-    C, slots, T, P, n = 16, 4, 4, 24, {1: 53, 2: 33}
-    row = {1: 0, 2: 1}
-    tables = np.zeros((slots, P), np.int32)
-    for s in n:
-        tables[s] = 1 + s * P + np.arange(P)
-    caches = init_paged_caches(cfg, slots * P + 1 + 8, T, P)
-    named = np.unique(tables)
-    poisoned = np.setdiff1d(np.arange(slots * P + 9), named)
-    caches = [dataclasses.replace(c, ckr=c.ckr.at[poisoned].set(jnp.nan))
-              for c in caches]
-    got = {s: [] for s in n}
-    routes = {s: [] for s in n}
-    cursor = {1: 0, 2: 0}
-    both = jnp.asarray(tables)
+    slots, T, P = 4, 4, 24
+    return dict(
+        cfg=cfg, params=seeded(cfg), impl=request.param,
+        tokens=jax.random.randint(jax.random.PRNGKey(9), (2, 80), 0,
+                                  cfg.vocab_size),
+        caches=init_paged_caches(cfg, slots * P + 1 + 8, T, P),
+        tables=harness.slot_tables(slots, P, (1, 2)),
+        lengths={1: 53, 2: 33}, chunk=16, steps=6, moe_info=True)
 
-    def step_rows(live):
-        active = np.zeros(slots, np.int32)
-        cursors = np.zeros(slots, np.int32)
-        for s in live:
-            active[s], cursors[s] = 1, cursor[s]
-        return StepRows(active, cursors, both, both,
-                        np.zeros(slots, np.float32),
-                        np.zeros(slots, np.uint32))
 
-    def ids_for(live):
-        ids = np.zeros(slots, np.int32)
-        for s in live:
-            ids[s] = tokens[row[s], cursor[s]]
-        return jnp.asarray(ids)
-
-    # one compile a program: every chunk has the step's rows along
-    kw = dict(attn=impl, moe_info=True, logits=True)
-    chunk = jax.jit(functools.partial(paged_prefill_into_slot, cfg, **kw))
-    step = jax.jit(functools.partial(paged_decode_step, cfg, **kw))
-    with jax.default_matmul_precision("highest"):
-        for s, live in ((1, []), (2, [1])):
-            prompt = np.asarray(tokens[row[s], :n[s]])
-            for c0 in range(0, n[s], C):
-                real = min(C, n[s] - c0)
-                padded = np.zeros((1, C), np.int32)
-                padded[0, :real] = prompt[c0:c0 + real]
-                _, caches, moe, logits = chunk(
-                    params, jnp.asarray(padded), np.int32(real),
-                    np.int32(c0), both[s], both[s], caches, ids_for(live),
-                    np.int32(-1), np.float32(0), np.uint32(0),
-                    step_rows(live))
-                r = np.asarray(moe["routes"])[:, 0]
-                assert r.shape[0] == cfg.expert_layers
-                routes[s].append(r[:, :real])
-                cursor[s] = c0 + real
-                for other in live:
-                    got[other].append(logits[1 + other])
-                    routes[other].append(r[:, C + other][:, None])
-                    cursor[other] += 1
-            got[s].append(logits[0])
-        for _ in range(6):
-            live = [1, 2]
-            rows = step_rows(live)
-            _, caches, moe, logits = step(
-                params, ids_for(live), rows.active, rows.cursors,
-                rows.read_tables, rows.write_tables, caches,
-                rows.temperature, rows.seeds)
-            for s in live:
-                got[s].append(logits[s])
-                routes[s].append(np.asarray(moe["routes"])[:, s])
-                cursor[s] += 1
-    return {"cfg": cfg, "params": params, "tokens": tokens, "got": got,
-            "routes": routes, "n": n, "row": row, "cursor": cursor,
-            "caches": caches, "poisoned": poisoned}
+paged_run = harness.paged_fixture(_paged, impls=["reference", "pallas"])
 
 
 @pytest.mark.parametrize("slot", [1, 2])
@@ -657,51 +573,27 @@ def test_paged_chunks_steps_and_fused_turns_match_the_reference(paged_run,
     seq = run["tokens"][run["row"][slot]][None, :end]
     routes = np.concatenate(run["routes"][slot], 1)[:, None]
     assert routes.shape[2] == end
-    got = jnp.stack(run["got"][slot])
-    assert np.isfinite(np.asarray(got)).all()
+    assert all(r["routes"].shape[0] == cfg.expert_layers
+               for r in run["info"])
+    got = harness.slot_logits(run, slot)
     want = ref.forward(run["params"], seq, hp_of(cfg), routes)[0]
     assert rel(got, want[n - 1:]) <= TOL
 
 
 def test_the_paged_programs_left_the_poisoned_pages_alone(paged_run):
-    for c in paged_run["caches"]:
-        assert np.isnan(np.asarray(c.ckr[paged_run["poisoned"][1:]])).all()
+    assert all(set(harness.pools(c)) == {"ckr"} for c in paged_run["caches"])
+    harness.poisoned_pages_left_alone(paged_run)
 
 
 # ------------------------------------------------------------ the scheduler
 
 
-def serve(sched, prompts, new):
-    async def one(prompt):
-        queue = asyncio.Queue()
-        sched.submit(prompt, max_new_tokens=new, temperature=0.0,
-                     loop=asyncio.get_running_loop(), queue=queue)
-        out = []
-        while True:
-            kind, value, _ = await queue.get()
-            if kind == "tok":
-                out.append(value)
-            elif kind == "end":
-                return out
-            else:
-                raise RuntimeError(f"{kind}: {value}")
-
-    async def drive():
-        return await asyncio.gather(*(one(p) for p in prompts))
-
-    with jax.default_matmul_precision("highest"):
-        return asyncio.run(drive())
-
-
 def near_the_references_best(cfg, params, prompt, out):
-    seq = jnp.asarray([prompt + out[:-1]], jnp.int32)
-    want = ref.forward(params, seq, hp_of(cfg))[0][len(prompt) - 1:]
-    return all(logits.max() - logits[tok] <= 1e-3 * np.abs(want).max()
-               for logits, tok in zip(want, out))
+    return harness.near_the_references_best(
+        lambda seq: ref.forward(params, seq, hp_of(cfg)), prompt, out)
 
 
 def test_the_scheduler_serves_the_kind_and_counts_its_work():
-    from ray_tpu.serve._private.continuous import ContinuousScheduler
     from ray_tpu.serve._private.work import token_bytes
 
     cfg = glm_moe_lite_debug()
@@ -709,16 +601,10 @@ def test_the_scheduler_serves_the_kind_and_counts_its_work():
     tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(9), (4, 80), 0,
                                            cfg.vocab_size))
     new = 8
-    sched = ContinuousScheduler(cfg, params, slots=3, prefill_chunk=16,
-                                arena_len=96, page_tokens=4,
-                                prefix_cache=False, attn="reference")
     prompts = [tokens[i, :n].tolist() for i, n in enumerate((70, 9, 33, 24))]
-    try:
-        served = serve(sched, prompts, new)
-        stats = sched.stats()
-        assert sched.compiled_programs() == 2
-    finally:
-        sched.shutdown()
+    served, stats = harness.served(
+        cfg, params, prompts, new, slots=3, prefill_chunk=16, arena_len=96,
+        page_tokens=4, prefix_cache=False)
     for prompt, out in zip(prompts, served):
         assert len(out) == new
         assert near_the_references_best(cfg, params, prompt, out)
@@ -821,24 +707,17 @@ def test_a_spliced_prefix_continues_to_the_same_logits():
     """The prefix cache serves the kind: the second request splices the
     first's pages — latents and rotated keys under one table — and answers
     as a scheduler without the cache does."""
-    from ray_tpu.serve._private.continuous import ContinuousScheduler
-
     cfg = glm_moe_lite_debug()
     params = seeded(cfg)
     tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(4), (96,), 0,
                                            cfg.vocab_size)).tolist()
     first, second = tokens[:64], tokens[:48] + tokens[70:90]
-    kw = dict(slots=2, prefill_chunk=16, arena_len=96, page_tokens=4,
-              attn="reference")
     answers = {}
     for cached in (True, False):
-        sched = ContinuousScheduler(cfg, params, prefix_cache=cached, **kw)
-        try:
-            answers[cached] = [serve(sched, [p], 6)[0]
-                               for p in (first, second)]
-            stats = sched.stats()
-        finally:
-            sched.shutdown()
+        answers[cached], stats = harness.served(
+            cfg, params, (first, second), 6, together=False, slots=2,
+            prefill_chunk=16, arena_len=96, page_tokens=4,
+            prefix_cache=cached)
         if cached:
             assert stats["prefix_hits"] == 1
             assert stats["prefix_hit_tokens"] >= 44
@@ -876,11 +755,5 @@ def test_value_heads_of_another_width_than_the_keys():
                                 cfg.vocab_size)
     with jax.default_matmul_precision("highest"):
         want = forward(cfg, params, tokens)
-        logits, caches = prefill(cfg, params, tokens[:, :17],
-                                 init_caches(cfg, 1, 24))
-        got = [logits]
-        for t in range(17, 24):
-            logits, caches = decode_step(cfg, params, tokens[:, t:t + 1],
-                                         caches)
-            got.append(logits)
-    assert rel(jnp.stack(got, 1), np.asarray(want[:, 16:])) <= TOL
+    assert rel(harness.cached_logits(cfg, params, tokens, 17),
+               np.asarray(want[:, 16:])) <= TOL

@@ -14,8 +14,8 @@ planted tie included; a context of at most ``topk`` tokens equals the
 
 from __future__ import annotations
 
-import asyncio
 import dataclasses
+import functools
 import os
 import sys
 
@@ -27,15 +27,15 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from perfbench.reference import keye as ref  # noqa: E402
-from ray_tpu.models.decode import (StepRows, decode_step,  # noqa: E402
-                                   init_caches, init_paged_caches,
-                                   init_slot_caches, paged_decode_step,
-                                   paged_prefill_into_slot, prefill)
+from ray_tpu.models.decode import (init_caches,  # noqa: E402
+                                   init_paged_caches, init_slot_caches)
 from ray_tpu.models.presets import keye_debug  # noqa: E402
 from ray_tpu.models.transformer import (INDEXED, LAYER_KINDS,  # noqa: E402
-                                        forward, init_params)
+                                        init_params)
 from ray_tpu.ops import indexed_attention as ia  # noqa: E402
 from ray_tpu.ops.rotary import apply_rotary_at  # noqa: E402
+from tests import model_harness as harness  # noqa: E402
+from tests.model_harness import rel  # noqa: E402
 
 TOL = 1e-4
 
@@ -50,27 +50,12 @@ def hp_of(cfg):
             "norm_topk_prob": cfg.moe_renormalize}
 
 
-def seeded(cfg, seed=0):
-    """Weights with every norm's scale and the index key's bias away from
-    their trivial values, and an indexer that speaks up (its projections
-    times 8: the seeded 0.02 leaves every score near 0)."""
-    params = init_params(cfg, jax.random.PRNGKey(seed))
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
-
-    def stir(path, leaf):
-        name = jax.tree_util.keystr(path)
-        if any(n in name for n in ("scale", "q_norm", "k_norm", "ik_bias")):
-            return leaf + 0.3 * jax.random.normal(next(keys), leaf.shape)
-        if "wi_" in name:
-            return leaf * 8.0
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(stir, params)
-
-
-def rel(got, want):
-    return float(np.abs(np.asarray(got, np.float32) - want).max()
-                 / np.abs(want).max())
+# weights with every norm's scale and the index key's bias away from their
+# trivial values, and an indexer that speaks up (its projections times 8: the
+# seeded 0.02 leaves every score near 0)
+seeded = functools.partial(
+    harness.seeded, stir=("scale", "q_norm", "k_norm", "ik_bias"), by=0.3,
+    times={"wi_": 8.0})
 
 
 @pytest.fixture(scope="module")
@@ -80,8 +65,10 @@ def toy():
     tokens = jax.random.randint(jax.random.PRNGKey(7), (2, 72), 0,
                                 cfg.vocab_size)
     with jax.default_matmul_precision("highest"):
-        logits, routes = forward(cfg, params, tokens, return_routes=True)
-        _, selected = forward(cfg, params, tokens, return_selected=True)
+        logits, routes = harness.forward_program(cfg, return_routes=True)(
+            params, tokens)
+        _, selected = harness.forward_program(cfg, return_selected=True)(
+            params, tokens)
     return {"cfg": cfg, "params": params, "tokens": tokens,
             "logits": np.asarray(logits), "routes": np.asarray(routes),
             "selected": np.asarray(selected)}
@@ -411,11 +398,11 @@ def test_the_contiguous_cache_and_the_pool_hold_one_row(toy):
     assert all(c.ik.shape == (1 + P, T, W) for c in pool)
     table = 1 + jnp.arange(P, dtype=jnp.int32)[::-1]
     with jax.default_matmul_precision("highest"):
-        _, own = prefill(cfg, params, tokens[:1, :n], own)
-        _, pool, *_ = paged_prefill_into_slot(
-            cfg, params, tokens[:1, :n], n, np.int32(0), table, table, pool,
-            jnp.zeros((1,), jnp.int32), np.int32(-1), np.float32(0),
-            np.uint32(0), None, attn="pallas")
+        _, own = harness.cached_programs(cfg)[0](params, tokens[:1, :n], own)
+        _, pool, *_ = harness.paged_programs(cfg, attn="pallas")[0](
+            params, tokens[:1, :n], np.int32(n), np.int32(0), table, table,
+            pool, jnp.zeros((1,), jnp.int32), np.int32(-1), np.float32(0),
+            np.uint32(0), None)
     for a, b in zip(own, pool):
         rows = np.asarray(b.ik[table[:n // T]]).reshape(n, W)
         np.testing.assert_allclose(np.asarray(a.ik[1])[:n], rows, atol=1e-6)
@@ -434,10 +421,10 @@ def test_forward_logits_match_the_reference_given_both_choices(toy):
     cfg = dataclasses.replace(toy["cfg"], scan_layers=False)
     params = seeded(cfg)
     with jax.default_matmul_precision("highest"):
-        logits, routes = forward(cfg, params, toy["tokens"],
-                                 return_routes=True)
-        again, selected = forward(cfg, params, toy["tokens"],
-                                  return_selected=True)
+        logits, routes = harness.forward_program(cfg, return_routes=True)(
+            params, toy["tokens"])
+        again, selected = harness.forward_program(
+            cfg, return_selected=True)(params, toy["tokens"])
     np.testing.assert_allclose(again, logits, atol=1e-5)
     assert selected.shape[:3] == (cfg.num_layers, 2, 72)
     want = ref.forward(params, toy["tokens"], hp_of(cfg), np.asarray(routes),
@@ -464,10 +451,10 @@ def test_three_unequal_streams_reach_the_reference(toy):
     base = jnp.broadcast_to(jnp.arange(72)[None], (2, 72))
     streams = jnp.stack([base, base // 3, 71 - base % 7])
     with jax.default_matmul_precision("highest"):
-        logits, routes = forward(cfg, params, tokens, positions=streams,
-                                 return_routes=True)
-        _, selected = forward(cfg, params, tokens, positions=streams,
-                              return_selected=True)
+        logits, routes = harness.forward_program(cfg, return_routes=True)(
+            params, tokens, positions=streams)
+        _, selected = harness.forward_program(cfg, return_selected=True)(
+            params, tokens, positions=streams)
     want = ref.forward(params, tokens, hp_of(cfg), np.asarray(routes),
                        np.asarray(selected), streams)
     assert rel(logits, want) <= TOL
@@ -556,10 +543,10 @@ def test_one_position_stream_for_three_is_refused(toy, monkeypatch):
     base = jnp.broadcast_to(jnp.arange(72)[None], (2, 72))
     streams = jnp.stack([base, base // 3, 71 - base % 7])
     with jax.default_matmul_precision("highest"):
-        logits, routes = forward(cfg, params, tokens, positions=streams,
-                                 return_routes=True)
-        _, selected = forward(cfg, params, tokens, positions=streams,
-                              return_selected=True)
+        logits, routes = harness.forward_program(cfg, return_routes=True)(
+            params, tokens, positions=streams)
+        _, selected = harness.forward_program(cfg, return_selected=True)(
+            params, tokens, positions=streams)
     true = ref.stream_positions
     monkeypatch.setattr(ref, "stream_positions", lambda p, b, s: jnp.stack(
         [true(p, b, s)[0]] * 3))
@@ -575,21 +562,24 @@ def test_a_context_within_topk_is_the_attention_kind(toy):
     cfg, params = toy["cfg"], toy["params"]
     dense = keye_debug(layer_kinds=("attention",) * cfg.num_layers)
     tokens = toy["tokens"][:, :cfg.indexer.topk]
+    whole = {c: harness.forward_program(c) for c in (cfg, dense)}
+    (fill, step), (dense_fill, dense_step) = (
+        harness.cached_programs(c) for c in (cfg, dense))
     with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(forward(cfg, params, tokens),
-                                   forward(dense, params, tokens), atol=2e-6)
+        np.testing.assert_allclose(whole[cfg](params, tokens),
+                                   whole[dense](params, tokens), atol=2e-6)
         # and through the contiguous cache: a prompt, then a step
         ours, theirs = (init_caches(c, 2, 16) for c in (cfg, dense))
-        a, ours = prefill(cfg, params, tokens[:, :15], ours)
-        b, theirs = prefill(dense, params, tokens[:, :15], theirs)
+        a, ours = fill(params, tokens[:, :15], ours)
+        b, theirs = dense_fill(params, tokens[:, :15], theirs)
         np.testing.assert_allclose(a, b, atol=2e-6)
-        a, _ = decode_step(cfg, params, tokens[:, 15:], ours)
-        b, _ = decode_step(dense, params, tokens[:, 15:], theirs)
+        a, _ = step(params, tokens[:, 15:], ours)
+        b, _ = dense_step(params, tokens[:, 15:], theirs)
         np.testing.assert_allclose(a, b, atol=2e-6)
         # one token more and the two part
         longer = toy["tokens"][:, :40]
-        assert np.abs(np.asarray(forward(cfg, params, longer)
-                                 - forward(dense, params, longer))).max() > 1e-3
+        assert np.abs(np.asarray(whole[cfg](params, longer)
+                                 - whole[dense](params, longer))).max() > 1e-3
 
 
 # ------------------------------------------------ the contiguous cache
@@ -602,104 +592,35 @@ def test_prefill_and_decode_step_match_the_reference(toy, n):
     cfg, params, tokens = toy["cfg"], toy["params"], toy["tokens"]
     want = ref.forward(params, tokens, hp_of(cfg), toy["routes"],
                        toy["selected"])
-    with jax.default_matmul_precision("highest"):
-        caches = init_caches(cfg, 2, 72)
-        logits, caches = prefill(cfg, params, tokens[:, :n], caches)
-        got = [logits]
-        for t in range(n, 72):
-            logits, caches = decode_step(cfg, params, tokens[:, t:t + 1],
-                                         caches)
-            got.append(logits)
-    assert rel(jnp.stack(got, 1), want[:, n - 1:]) <= TOL
+    assert rel(harness.cached_logits(cfg, params, tokens, n),
+               want[:, n - 1:]) <= TOL
 
 
 # ------------------------------------------------------ the paged programs
 
 
-@pytest.fixture(scope="module", params=["pallas"])
-def paged_run(request):
-    """Two prompts through the paged programs. Slot 1 takes a 53-token
-    prompt in chunks of 16 (past topk, over three chunk boundaries, ending
-    inside a chunk); slot 2 then a 33-token prompt (a page's first token
-    last) whose chunks take slot 1's decode row along (the fused turn);
-    then plain steps of both. Slots 0 and 3 hold no sequence, and every page
-    no table names is FILLED WITH NaN in every layer's three arrays, as a
-    released page would be: whatever read one would show."""
-    impl = request.param
+def _paged(request):
+    """Two prompts through the paged programs (``harness.paged_drive``).
+    Slot 1 takes a 53-token prompt in chunks of 16 (past topk, over three
+    chunk boundaries, ending inside a chunk); slot 2 then a 33-token prompt
+    (a page's first token last) whose chunks take slot 1's decode row along
+    (the fused turn); then plain steps of both. Slots 0 and 3 hold no
+    sequence, and every page no table names is FILLED WITH NaN in every
+    layer's three arrays, as a released page would be: whatever read one
+    would show."""
     cfg = keye_debug()
-    params = seeded(cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(9), (2, 80), 0,
-                                cfg.vocab_size)
-    C, slots, T, P, n = 16, 4, 4, 24, {1: 53, 2: 33}
-    row = {1: 0, 2: 1}
-    tables = np.zeros((slots, P), np.int32)
-    for s in n:
-        tables[s] = 1 + s * P + np.arange(P)
-    caches = init_paged_caches(cfg, slots * P + 1 + 8, T, P)
-    named = np.unique(tables)
-    poisoned = np.setdiff1d(np.arange(slots * P + 9), named)
-    caches = [dataclasses.replace(c, **{
-        name: getattr(c, name).at[poisoned].set(jnp.nan)
-        for name in ("k", "v", "ik")}) for c in caches]
-    got = {s: [] for s in n}
-    routes = {s: [] for s in n}
-    picked = {s: [] for s in n}
-    cursor = {1: 0, 2: 0}
-    both = jnp.asarray(tables)
+    slots, T, P = 4, 4, 24
+    return dict(
+        cfg=cfg, params=seeded(cfg), impl=request.param,
+        tokens=jax.random.randint(jax.random.PRNGKey(9), (2, 80), 0,
+                                  cfg.vocab_size),
+        caches=init_paged_caches(cfg, slots * P + 1 + 8, T, P),
+        tables=harness.slot_tables(slots, P, (1, 2)),
+        lengths={1: 53, 2: 33}, chunk=16, steps=6, moe_info=True,
+        selected=True)
 
-    def step_rows(live):
-        active = np.zeros(slots, np.int32)
-        cursors = np.zeros(slots, np.int32)
-        for s in live:
-            active[s], cursors[s] = 1, cursor[s]
-        return StepRows(active, cursors, both, both,
-                        np.zeros(slots, np.float32),
-                        np.zeros(slots, np.uint32))
 
-    def ids_for(live):
-        ids = np.zeros(slots, np.int32)
-        for s in live:
-            ids[s] = tokens[row[s], cursor[s]]
-        return jnp.asarray(ids)
-
-    with jax.default_matmul_precision("highest"):
-        for s, live in ((1, []), (2, [1])):
-            prompt = np.asarray(tokens[row[s], :n[s]])
-            for c0 in range(0, n[s], C):
-                real = min(C, n[s] - c0)
-                padded = np.zeros((1, C), np.int32)
-                padded[0, :real] = prompt[c0:c0 + real]
-                _, caches, moe, logits, chosen = paged_prefill_into_slot(
-                    cfg, params, jnp.asarray(padded), real, np.int32(c0),
-                    both[s], both[s], caches, ids_for(live), np.int32(-1),
-                    np.float32(0), np.uint32(0), step_rows(live),
-                    attn=impl, moe_info=True, logits=True, selected=True)
-                r = np.asarray(moe["routes"])[:, 0]
-                routes[s].append(r[:, :real])
-                picked[s].append(np.asarray(chosen[0])[:, 0, :real])
-                cursor[s] = c0 + real
-                for other in live:
-                    got[other].append(logits[1 + other])
-                    routes[other].append(r[:, C + other][:, None])
-                    picked[other].append(np.asarray(chosen[1])[:, other])
-                    cursor[other] += 1
-            got[s].append(logits[0])
-        for _ in range(6):
-            live = [1, 2]
-            rows = step_rows(live)
-            _, caches, moe, logits, chosen = paged_decode_step(
-                cfg, params, ids_for(live), rows.active, rows.cursors,
-                rows.read_tables, rows.write_tables, caches,
-                rows.temperature, rows.seeds, attn=impl, moe_info=True,
-                logits=True, selected=True)
-            for s in live:
-                got[s].append(logits[s])
-                routes[s].append(np.asarray(moe["routes"])[:, s])
-                picked[s].append(np.asarray(chosen)[:, s])
-                cursor[s] += 1
-    return {"cfg": cfg, "params": params, "tokens": tokens, "got": got,
-            "routes": routes, "picked": picked, "n": n, "row": row,
-            "cursor": cursor, "caches": caches, "poisoned": poisoned}
+paged_run = harness.paged_fixture(_paged, impls=["pallas"])
 
 
 @pytest.mark.parametrize("slot", [1, 2])
@@ -716,68 +637,35 @@ def test_paged_chunks_steps_and_fused_turns_match_the_reference(paged_run,
     np.testing.assert_array_equal(
         picked.sum(-1)[0, 0], np.minimum(np.arange(end) + 1,
                                          cfg.indexer.topk))
-    got = jnp.stack(run["got"][slot])
-    assert np.isfinite(np.asarray(got)).all()
+    got = harness.slot_logits(run, slot)
     want = ref.forward(run["params"], seq, hp_of(cfg), routes, picked)[0]
     assert rel(got, want[n - 1:]) <= TOL
 
 
 def test_the_paged_programs_left_the_poisoned_pages_alone(paged_run):
-    for c in paged_run["caches"]:
-        for pool in (c.k, c.v, c.ik):
-            assert np.isnan(np.asarray(pool[paged_run["poisoned"][1:]])).all()
+    assert all(set(harness.pools(c)) == {"k", "v", "ik"}
+               for c in paged_run["caches"])
+    harness.poisoned_pages_left_alone(paged_run)
 
 
 # ------------------------------------------------------------ the scheduler
 
 
-def serve(sched, prompts, new):
-    async def one(prompt):
-        queue = asyncio.Queue()
-        sched.submit(prompt, max_new_tokens=new, temperature=0.0,
-                     loop=asyncio.get_running_loop(), queue=queue)
-        out = []
-        while True:
-            kind, value, _ = await queue.get()
-            if kind == "tok":
-                out.append(value)
-            elif kind == "end":
-                return out
-            else:
-                raise RuntimeError(f"{kind}: {value}")
-
-    async def drive():
-        return await asyncio.gather(*(one(p) for p in prompts))
-
-    with jax.default_matmul_precision("highest"):
-        return asyncio.run(drive())
-
-
 def near_the_references_best(cfg, params, prompt, out):
-    seq = jnp.asarray([prompt + out[:-1]], jnp.int32)
-    want = ref.forward(params, seq, hp_of(cfg))[0][len(prompt) - 1:]
-    return all(logits.max() - logits[tok] <= 1e-3 * np.abs(want).max()
-               for logits, tok in zip(want, out))
+    return harness.near_the_references_best(
+        lambda seq: ref.forward(params, seq, hp_of(cfg)), prompt, out)
 
 
 def test_the_scheduler_serves_the_kind_and_counts_its_work():
-    from ray_tpu.serve._private.continuous import ContinuousScheduler
-
     cfg = keye_debug()
     params = seeded(cfg)
     tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(9), (4, 80), 0,
                                            cfg.vocab_size))
     new = 8
-    sched = ContinuousScheduler(cfg, params, slots=3, prefill_chunk=16,
-                                arena_len=96, page_tokens=4,
-                                prefix_cache=False, attn="reference")
     prompts = [tokens[i, :n].tolist() for i, n in enumerate((70, 9, 33, 24))]
-    try:
-        served = serve(sched, prompts, new)
-        stats = sched.stats()
-        assert sched.compiled_programs() == 2
-    finally:
-        sched.shutdown()
+    served, stats = harness.served(
+        cfg, params, prompts, new, slots=3, prefill_chunk=16, arena_len=96,
+        page_tokens=4, prefix_cache=False)
     for prompt, out in zip(prompts, served):
         assert len(out) == new
         assert near_the_references_best(cfg, params, prompt, out)
@@ -843,24 +731,17 @@ def test_a_spliced_prefix_brings_its_index_keys_along():
     """The prefix cache serves the kind: the second request splices the
     first's pages — K, V and the index keys under one table — and answers
     as a scheduler without the cache does."""
-    from ray_tpu.serve._private.continuous import ContinuousScheduler
-
     cfg = keye_debug()
     params = seeded(cfg)
     tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(4), (96,), 0,
                                            cfg.vocab_size)).tolist()
     first, second = tokens[:64], tokens[:48] + tokens[70:90]
-    kw = dict(slots=2, prefill_chunk=16, arena_len=96, page_tokens=4,
-              attn="reference")
     answers = {}
     for cached in (True, False):
-        sched = ContinuousScheduler(cfg, params, prefix_cache=cached, **kw)
-        try:
-            answers[cached] = [serve(sched, [p], 6)[0]
-                               for p in (first, second)]
-            stats = sched.stats()
-        finally:
-            sched.shutdown()
+        answers[cached], stats = harness.served(
+            cfg, params, (first, second), 6, together=False, slots=2,
+            prefill_chunk=16, arena_len=96, page_tokens=4,
+            prefix_cache=cached)
         if cached:
             assert stats["prefix_hits"] == 1
             assert stats["prefix_hit_tokens"] >= 44

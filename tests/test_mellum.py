@@ -13,7 +13,6 @@ so contexts below, at and past the window and chunk boundaries are all
 within 80 tokens.
 """
 
-import asyncio
 import dataclasses
 import hashlib
 
@@ -25,7 +24,7 @@ import pytest
 from perfbench.reference import mellum as ref
 from ray_tpu.models import (forward, init_params, llama_debug, mellum_debug,
                             moe_debug)
-from ray_tpu.models.decode import (StepRows, decode_step, init_caches,
+from ray_tpu.models.decode import (StepRows, init_caches,
                                    init_paged_caches, paged_decode_step,
                                    paged_prefill_into_slot,
                                    paged_verify_step, prefill)
@@ -33,7 +32,11 @@ from ray_tpu.models.transformer import ATTENTION, SLIDING
 from ray_tpu.ops.paged_attention import (paged_attention, streamed_tokens,
                                          tile_sizes)
 from ray_tpu.ops.rotary import rule_frequencies
+from tests import model_harness as harness
+from tests.model_harness import rel as rel_err, serve
 
+# weights whose norm scales are not all ones
+seeded = harness.seeded
 TOL = 1e-4
 PUBLISHED_YARN = {
     "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
@@ -55,23 +58,6 @@ def hp_of(cfg):
                             for kind in cfg.kinds],
             "mlp_layer_types": ["sparse"] * cfg.num_layers}
 
-
-def seeded(cfg, seed=0):
-    """Seeded weights whose norm scales are not all ones."""
-    params = init_params(cfg, jax.random.PRNGKey(seed))
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
-
-    def jitter(path, a):
-        name = jax.tree_util.keystr(path)
-        if "norm" in name or "ln" in name:
-            return a + 0.2 * jax.random.normal(next(keys), a.shape, a.dtype)
-        return a
-    return jax.tree_util.tree_map_with_path(jitter, params)
-
-
-def rel_err(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.abs(got - want).max() / np.abs(want).max())
 
 
 def sys_forward(cfg, params, tokens):
@@ -241,15 +227,9 @@ def test_prefill_and_decode_step_match_the_reference(toy, n):
     cfg, params, tokens = toy
     tokens = tokens[:, :n + 9]
     want = ref.forward(params, tokens, hp_of(cfg))
-    with jax.default_matmul_precision("highest"):
-        caches = init_caches(cfg, tokens.shape[0], tokens.shape[1])
-        logits, caches = prefill(cfg, params, tokens[:, :n], caches)
-        got = [logits]
-        for t in range(n, tokens.shape[1] - 1):
-            logits, caches = decode_step(cfg, params, tokens[:, t:t + 1],
-                                         caches)
-            got.append(logits)
-    assert rel_err(jnp.stack(got, 1), want[:, n - 1:-1]) < TOL
+    got = harness.cached_logits(cfg, params, tokens[:, :-1], n,
+                                length=tokens.shape[1])
+    assert rel_err(got, want[:, n - 1:-1]) < TOL
 
 
 def test_a_long_cached_prefill_attends_in_blocks_of_query_rows(monkeypatch):
@@ -404,87 +384,29 @@ class Pager:
         return both, both
 
 
-@pytest.fixture(scope="module", params=["reference", "pallas"])
-def paged_run(request):
-    """Two prompts through the paged programs, a pool a kind. Slot 1 takes
-    a 53-token prompt in chunks of 16 (past the window, over three chunk
-    boundaries, ending inside a chunk); slot 2 then a 20-token prompt
-    (below the window) whose two chunks take slot 1's decode row along (the
-    fused turn); then plain steps of both, slot 2 crossing the window.
-    Slots 0 and 3 hold no sequence."""
-    impl = request.param
+def _paged(request):
+    """Two prompts through the paged programs (``harness.paged_drive``), a
+    pool a kind. Slot 1 takes a 53-token prompt in chunks of 16 (past the
+    window, over three chunk boundaries, ending inside a chunk); slot 2 then
+    a 20-token prompt (below the window) whose two chunks take slot 1's
+    decode row along (the fused turn); then plain steps of both, slot 2
+    crossing the window. Slots 0 and 3 hold no sequence. The pages are the
+    ``Pager``'s: what it releases it poisons, so the drive poisons none."""
     cfg = mellum_debug(num_layers=4)
-    params = seeded(cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(9), (2, 80), 0,
-                                cfg.vocab_size)
-    C, slots, T, P, n = 16, 4, 4, 64, {1: 53, 2: 20}
-    row = {1: 0, 2: 1}
-    pager = Pager(cfg, slots, T, P)
-    caches = init_paged_caches(cfg, slots * P + 1, T, P, window_pages=400)
-    got = {s: [] for s in n}
-    taken = {s: [] for s in n}
-    cursor = {1: 0, 2: 0}
-    full_pages = []
+    slots, T, P = 4, 4, 64
+    pager, full_pages = Pager(cfg, slots, T, P), []
+    return dict(
+        cfg=cfg, params=seeded(cfg), impl=request.param,
+        tokens=jax.random.randint(jax.random.PRNGKey(9), (2, 80), 0,
+                                  cfg.vocab_size),
+        caches=init_paged_caches(cfg, slots * P + 1, T, P, window_pages=400),
+        tables=pager.tables, ensure=pager.ensure, poisoned=False,
+        after=lambda: full_pages.append(int((pager.full > 0).sum())),
+        lengths={1: 53, 2: 20}, chunk=16, steps=8, moe_info=True,
+        keep={"pager": pager, "full_pages": full_pages})
 
-    def step_rows(live):
-        active = np.zeros(slots, np.int32)
-        cursors = np.zeros(slots, np.int32)
-        for s in live:
-            active[s], cursors[s] = 1, cursor[s]
-        return StepRows(active, cursors, *pager.tables(),
-                        np.zeros(slots, np.float32),
-                        np.zeros(slots, np.uint32))
 
-    def ids_for(live):
-        ids = np.zeros(slots, np.int32)
-        for s in live:
-            ids[s] = tokens[row[s], cursor[s]]
-        return jnp.asarray(ids)
-
-    with jax.default_matmul_precision("highest"):
-        for s, live in ((1, []), (2, [1])):
-            prompt = np.asarray(tokens[row[s], :n[s]])
-            for c0 in range(0, n[s], C):
-                real = min(C, n[s] - c0)
-                padded = np.zeros((1, C), np.int32)
-                padded[0, :real] = prompt[c0:c0 + real]
-                caches = pager.ensure(caches, s, c0, c0 + real)
-                for other in live:
-                    caches = pager.ensure(caches, other, cursor[other],
-                                          cursor[other] + 1)
-                read, write = pager.tables(s)
-                _, caches, moe, logits = paged_prefill_into_slot(
-                    cfg, params, jnp.asarray(padded), real, np.int32(c0),
-                    read, write, caches, ids_for(live), np.int32(-1),
-                    np.float32(0), np.uint32(0), step_rows(live),
-                    attn=impl, moe_info=True, logits=True)
-                routes = np.asarray(moe["routes"])[:, 0]
-                taken[s].append(routes[:, :real])
-                cursor[s] = c0 + real
-                for other in live:
-                    got[other].append(logits[1 + other])
-                    taken[other].append(routes[:, C + other][:, None])
-                    cursor[other] += 1
-                full_pages.append(int((pager.full > 0).sum()))
-            got[s].append(logits[0])
-        for _ in range(8):
-            live = [1, 2]
-            for s in live:
-                caches = pager.ensure(caches, s, cursor[s], cursor[s] + 1)
-            rows = step_rows(live)
-            _, caches, moe, logits = paged_decode_step(
-                cfg, params, ids_for(live), rows.active, rows.cursors,
-                rows.read_tables, rows.write_tables, caches,
-                rows.temperature, rows.seeds, attn=impl, moe_info=True,
-                logits=True)
-            for s in live:
-                got[s].append(logits[s])
-                taken[s].append(np.asarray(moe["routes"])[:, s])
-                cursor[s] += 1
-            full_pages.append(int((pager.full > 0).sum()))
-    return {"cfg": cfg, "params": params, "tokens": tokens, "got": got,
-            "taken": taken, "n": n, "row": row, "cursor": cursor,
-            "pager": pager, "full_pages": full_pages, "caches": caches}
+paged_run = harness.paged_fixture(_paged, impls=["reference", "pallas"])
 
 
 @pytest.mark.parametrize("slot", [1, 2])
@@ -493,10 +415,9 @@ def test_paged_chunks_steps_and_fused_turns_match_the_reference(paged_run,
     run = paged_run
     cfg, n, end = run["cfg"], run["n"][slot], run["cursor"][slot]
     seq = run["tokens"][run["row"][slot]][None, :end]
-    routes = np.concatenate(run["taken"][slot], 1)[:, None]
+    routes = np.concatenate(run["routes"][slot], 1)[:, None]
     assert routes.shape[2] == end
-    got = jnp.stack(run["got"][slot])
-    assert np.isfinite(np.asarray(got)).all()
+    got = harness.slot_logits(run, slot)
     want = ref.forward(run["params"], seq, hp_of(cfg), routes)[0]
     assert rel_err(got, want[n - 1:end]) < TOL
     own = ref.forward(run["params"], seq, hp_of(cfg))[0]
@@ -526,28 +447,6 @@ def test_the_paged_programs_refuse_what_they_cannot_run(toy):
 # --------------------------------------------------------- the scheduler
 
 
-def serve(sched, prompts, max_new):
-    async def one(prompt):
-        queue = asyncio.Queue()
-        sched.submit(prompt, max_new_tokens=max_new, temperature=0.0,
-                     loop=asyncio.get_running_loop(), queue=queue)
-        out = []
-        while True:
-            kind, value, _ = await queue.get()
-            if kind == "tok":
-                out.append(value)
-            elif kind == "end":
-                return out
-            else:
-                raise RuntimeError(f"{kind}: {value}")
-
-    async def drive():
-        return await asyncio.gather(*(one(p) for p in prompts))
-
-    with jax.default_matmul_precision("highest"):
-        return asyncio.run(drive())
-
-
 def test_the_scheduler_serves_both_pools_and_releases_behind_the_window():
     """Through ``ContinuousScheduler``: five prompts of 9 to 70 tokens over
     three slots, 12 new tokens each. Every served token is the reference's
@@ -561,11 +460,11 @@ def test_the_scheduler_serves_both_pools_and_releases_behind_the_window():
     tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(9), (5, 80), 0,
                                            cfg.vocab_size))
     T, C, slots, new = 4, 16, 3, 12
+    prompts = [tokens[i, :n].tolist()
+               for i, n in enumerate((70, 9, 33, 24, 57))]
     sched = ContinuousScheduler(cfg, params, slots=slots, prefill_chunk=C,
                                 arena_len=128, page_tokens=T,
                                 attn="reference")
-    prompts = [tokens[i, :n].tolist()
-               for i, n in enumerate((70, 9, 33, 24, 57))]
     try:
         served = serve(sched, prompts, new)
         stats = sched.stats()
@@ -574,10 +473,9 @@ def test_the_scheduler_serves_both_pools_and_releases_behind_the_window():
         sched.shutdown()
     for prompt, out in zip(prompts, served):
         assert len(out) == new
-        seq = jnp.asarray([prompt + out[:-1]], jnp.int32)
-        want = ref.forward(params, seq, hp_of(cfg))[0][len(prompt) - 1:]
-        for logits, tok in zip(want, out):
-            assert logits.max() - logits[tok] <= TOL * np.abs(want).max()
+        assert harness.near_the_references_best(
+            lambda seq: ref.forward(params, seq, hp_of(cfg)), prompt, out,
+            tol=TOL)
     a_slot = -(-(cfg.sliding_window + C) // T) + 1
     assert a_slot == 11
     assert sched._pools[1].arena.usable_pages == slots * a_slot

@@ -14,8 +14,8 @@ chosen blocks against a masked dense one), which reads about 3e-7 here; each
 fault below reads far more (asserted).
 """
 
-import asyncio
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -23,16 +23,17 @@ import numpy as np
 import pytest
 
 from perfbench.reference import minicpm_sala as ref
-from ray_tpu.models import forward, init_params, logical_axes
+from ray_tpu.models import forward, logical_axes
 from ray_tpu.models import transformer
-from ray_tpu.models.decode import (LinearState, decode_step, init_caches,
-                                   init_paged_caches, paged_decode_step,
+from ray_tpu.models.decode import (LinearState, init_paged_caches,
                                    paged_prefill_into_slot,
-                                   paged_verify_step, prefill)
+                                   paged_verify_step)
 from ray_tpu.models.presets import minicpm_sala_debug
 from ray_tpu.ops.linear_attention import (linear_attention_chunk,
                                           linear_attention_step, slopes)
 from ray_tpu.ops.sparse_attention import SparseSizes
+from tests import model_harness as harness
+from tests.model_harness import rel as rel_err, serve
 
 TOL = 1e-4
 T = 4        # page_tokens: the toy selection's kernel_stride
@@ -51,23 +52,9 @@ def hp_of(cfg):
             "sparse_config": dict(cfg.sparse_config)}
 
 
-def seeded(cfg, seed=0):
-    """Seeded weights whose norm scales are not all ones (so a norm that
-    is left out shows)."""
-    params = init_params(cfg, jax.random.PRNGKey(seed))
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 256))
-
-    def jitter(path, a):
-        name = jax.tree_util.keystr(path)
-        if "norm" in name or "ln" in name:
-            return a + 0.2 * jax.random.normal(next(keys), a.shape, a.dtype)
-        return a
-    return jax.tree_util.tree_map_with_path(jitter, params)
-
-
-def rel_err(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.abs(got - want).max() / np.abs(want).max())
+# seeded weights whose norm scales are not all ones (so a norm that is left
+# out shows)
+seeded = functools.partial(harness.seeded, keys=256)
 
 
 @pytest.fixture(scope="module")
@@ -153,15 +140,8 @@ def test_the_tolerance_refuses(toy, monkeypatch, fault):
 def test_prefill_and_decode_step_match_the_full_forward(toy):
     cfg, params, tokens, want = toy
     n, total = 120, tokens.shape[1]
-    caches = init_caches(cfg, 2, total)
-    logits, caches = jax.jit(prefill, static_argnums=0)(
-        cfg, params, tokens[:, :n], caches)
-    got = [logits]
-    step = jax.jit(decode_step, static_argnums=0)
-    for t in range(n, total - 1):
-        logits, caches = step(cfg, params, tokens[:, t:t + 1], caches)
-        got.append(logits)
-    assert rel_err(jnp.stack(got, 1), want[:, n - 1:-1]) < TOL
+    got = harness.cached_logits(cfg, params, tokens[:, :-1], n, length=total)
+    assert rel_err(got, want[:, n - 1:-1]) < TOL
 
 
 # ------------------------------------------------------ the paged programs
@@ -173,10 +153,10 @@ def paged_setup(cfg, slots):
     return caches, jnp.asarray(tables)
 
 
-PREFILL = jax.jit(paged_prefill_into_slot, static_argnums=0,
-                  static_argnames=("attn", "logits"))
-STEP = jax.jit(paged_decode_step, static_argnums=0,
-               static_argnames=("attn", "logits"))
+def programs(cfg):
+    """The chunk's program and the step's, the harness's (one compile each
+    for the drive and for the cases below)."""
+    return harness.paged_programs(cfg, attn="reference", logits=True)
 
 
 def chunks_into(cfg, params, prompt, slot, caches, tables, chunk, slots,
@@ -189,54 +169,37 @@ def chunks_into(cfg, params, prompt, slot, caches, tables, chunk, slots,
         part = prompt[c0:c0 + chunk]
         padded = np.zeros((1, chunk), np.int32)
         padded[0, :len(part)] = part
-        _, caches, logits = PREFILL(
-            cfg, params, jnp.asarray(padded), np.int32(len(part)),
-            np.int32(c0),
+        _, caches, logits = programs(cfg)[0](
+            params, jnp.asarray(padded), np.int32(len(part)), np.int32(c0),
             tables[slot], tables[slot], caches, jnp.zeros(slots, jnp.int32),
-            np.int32(-1), np.float32(0), np.uint32(0), None, np.int32(slot),
-            attn="reference", logits=True)
+            np.int32(-1), np.float32(0), np.uint32(0), None, np.int32(slot))
         if between is not None and c0 + chunk < len(prompt):
             caches = between(caches, c0 + len(part))
     return logits, caches
 
 
-@pytest.fixture(scope="module")
-def paged_run(toy):
-    """Two prompts through the paged programs: chunks of 32 into slots 1 and
-    2 of 4 (slots 0 and 3 hold no sequence), then decode steps."""
-    cfg, params, tokens, _ = toy
-    slots, n = 4, [101, 128]
-    caches, tables = paged_setup(cfg, slots)
-    got = {s: [] for s in (1, 2)}
-    for b, s in enumerate((1, 2)):
-        logits, caches = chunks_into(
-            cfg, params, np.asarray(tokens[b, :n[b]]), s, caches, tables, 32,
-            slots)
-        got[s].append(logits)
-    active = jnp.asarray([0, 1, 1, 0], jnp.int32)
-    cursors = np.asarray([0, n[0], n[1], 0], np.int32)
-    for i in range(12):
-        toks = np.zeros(slots, np.int32)
-        for b, s in enumerate((1, 2)):
-            toks[s] = tokens[b, n[b] + i]
-        ids, caches, logits = STEP(
-            cfg, params, jnp.asarray(toks), active, cursors + i, tables,
-            tables, caches, jnp.zeros(slots, jnp.float32),
-            jnp.zeros(slots, jnp.uint32), attn="reference", logits=True)
-        assert np.array_equal(
-            np.asarray(ids), np.where(np.asarray(active) > 0,
-                                      np.asarray(logits).argmax(-1), toks))
-        for s in (1, 2):
-            got[s].append(logits[s])
-    return {"got": got, "n": n, "caches": caches}
+def _paged(request):
+    """Two prompts through the paged programs (``harness.paged_drive``):
+    chunks of 32 into slots 1 and 2 of 4 (slots 0 and 3 hold no sequence),
+    each chunk ALONE (no step's rows: the fused turn is
+    ``tests/test_serve_fused_turn.py``'s), then decode steps. (Every page
+    but the garbage page is some slot's: there is none to poison.)"""
+    cfg, params, tokens, _ = request.getfixturevalue("toy")
+    caches, tables = paged_setup(cfg, 4)
+    return dict(cfg=cfg, params=params, tokens=tokens, caches=caches,
+                tables=np.asarray(tables), impl="reference", along=None,
+                poisoned=False, lengths={1: 101, 2: 128}, chunk=32, steps=12)
+
+
+paged_run = harness.paged_fixture(_paged)
 
 
 @pytest.mark.parametrize("b,slot", [(0, 1), (1, 2)])
 def test_paged_chunks_and_decode_match_the_full_forward(toy, paged_run, b,
                                                         slot):
     want = toy[3]
-    n = paged_run["n"][b]
-    got = jnp.stack(paged_run["got"][slot])
+    n = paged_run["n"][slot]
+    got = harness.slot_logits(paged_run, slot)
     assert rel_err(got, want[b, n - 1:n + 12]) < TOL
 
 
@@ -280,12 +243,11 @@ def test_a_step_between_two_chunks_leaves_the_prefilling_slot_alone(toy):
     def a_step(caches, cursor):
         before = [np.asarray(c.s[1]) for c in caches
                   if isinstance(c, LinearState)]
-        _, after = STEP(
-            cfg, params, jnp.asarray([tokens[1, 50], 0], jnp.int32),
+        _, after, _ = programs(cfg)[1](
+            params, jnp.asarray([tokens[1, 50], 0], jnp.int32),
             jnp.asarray([1, 0], jnp.int32),
             jnp.asarray([50, cursor], jnp.int32), tables, tables, caches,
-            jnp.zeros(slots, jnp.float32), jnp.zeros(slots, jnp.uint32),
-            attn="reference")
+            jnp.zeros(slots, jnp.float32), jnp.zeros(slots, jnp.uint32))
         now = [np.asarray(c.s[1]) for c in after
                if isinstance(c, LinearState)]
         assert all(np.array_equal(x, y) for x, y in zip(before, now))
@@ -336,27 +298,6 @@ def test_the_counters_mirror_what_a_position_attends():
 # ------------------------------------------------------------ the scheduler
 
 
-def serve(sched, prompts, new=6):
-    async def one(prompt):
-        queue = asyncio.Queue()
-        sched.submit(prompt, max_new_tokens=new, temperature=0.0,
-                     loop=asyncio.get_running_loop(), queue=queue)
-        out = []
-        while True:
-            kind, value, _ = await queue.get()
-            if kind == "tok":
-                out.append(value)
-            elif kind == "end":
-                return out
-            else:
-                raise RuntimeError(f"{kind}: {value}")
-
-    async def drive():
-        return await asyncio.gather(*(one(p) for p in prompts))
-
-    return asyncio.run(drive())
-
-
 def scheduler(cfg, params, slots):
     from ray_tpu.serve._private.continuous import ContinuousScheduler
 
@@ -383,16 +324,15 @@ def test_scheduler_serves_both_kinds_and_a_reused_slot_starts_from_zero(toy):
     sched._step = lambda *args: (plain.append(1), step(*args))[1]
     sched._step._cache_size = step._cache_size
     try:
-        served = serve(sched, prompts)
+        served = serve(sched, prompts, 6)
         stats = sched.stats()
     finally:
         sched.shutdown()
     for prompt, out in zip(prompts, served):
         assert len(out) == 6
-        seq = jnp.asarray([prompt + out[:-1]], jnp.int32)
-        want = ref.forward(params, seq, hp_of(cfg))[0][len(prompt) - 1:]
-        for logits, tok in zip(want, out):
-            assert logits.max() - logits[tok] <= TOL * np.abs(want).max()
+        assert harness.near_the_references_best(
+            lambda seq: ref.forward(params, seq, hp_of(cfg)), prompt, out,
+            tol=TOL)
     # layers of other kinds too: a chunk's program takes the live rows along
     assert stats["fused_turns"] == sum(1 for n in carried if n) > 0
     assert stats["fused_step_rows"] == sum(carried)
@@ -426,8 +366,8 @@ def test_scheduler_serves_both_kinds_and_a_reused_slot_starts_from_zero(toy):
         calls["in_admit"] += calls["n"] - before
     one._admit = watched
     try:
-        first = serve(one, prompts[:1])
-        again = serve(one, prompts[1:2])
+        first = serve(one, prompts[:1], 6)
+        again = serve(one, prompts[1:2], 6)
         stats = one.stats()
     finally:
         one.shutdown()

@@ -17,7 +17,6 @@ faults planted in the reference are refused by the same comparison.
 
 from __future__ import annotations
 
-import asyncio
 import dataclasses
 import functools
 import hashlib
@@ -33,15 +32,15 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from perfbench.reference import nemotron_h as ref  # noqa: E402
 from ray_tpu.models import presets  # noqa: E402
-from ray_tpu.models.decode import (StepRows, decode_step,  # noqa: E402
-                                   init_caches, init_paged_caches,
-                                   paged_decode_step,
-                                   paged_prefill_into_slot, prefill)
+from ray_tpu.models.decode import (init_caches,  # noqa: E402
+                                   init_paged_caches)
 from ray_tpu.models.transformer import (ATTENTION, MAMBA, NONE,  # noqa: E402
-                                        count_params, forward, init_params,
+                                        count_params, init_params,
                                         logical_axes, state_shapes)
 from ray_tpu.ops import moe, ssm  # noqa: E402
 from ray_tpu.ops.ssm import SsmSizes  # noqa: E402
+from tests import model_harness as harness  # noqa: E402
+from tests.model_harness import rel  # noqa: E402
 
 TOL = 1e-4
 # experts 2-5 of the 8, in five layers where 'M*', '*E', 'EM' and 'ME' all
@@ -66,40 +65,10 @@ def hp_of(cfg):
             "experts_held_first": cfg.moe_held_first}
 
 
-@functools.lru_cache(maxsize=None)
-def seeded(cfg, seed=0):
-    """Weights with every norm's scale and the skip away from 1 (a norm left
-    out, or one scale taken for another, then shows). Made in ONE compiled
-    program and once a configuration: op by op the toy's cost 8 s."""
-    def stir(path, leaf, key):
-        name = jax.tree_util.keystr(path)
-        if any(n in name for n in ("scale", "'norm'", "d_skip")):
-            return leaf + 0.3 * jax.random.normal(key, leaf.shape)
-        return leaf
-
-    def make():
-        params = init_params(cfg, jax.random.PRNGKey(seed))
-        keys = jax.random.split(jax.random.PRNGKey(seed + 1),
-                                len(jax.tree.leaves(params)))
-        return jax.tree_util.tree_map_with_path(
-            stir, params, jax.tree.unflatten(jax.tree.structure(params),
-                                             list(keys)))
-
-    return jax.jit(make)()
-
-
-@functools.lru_cache(maxsize=None)
-def programs(cfg):
-    """The uncached forward (with its routes), the contiguous cache's
-    prefill and its step, each compiled once a configuration."""
-    return (jax.jit(functools.partial(forward, cfg, return_routes=True)),
-            jax.jit(functools.partial(prefill, cfg)),
-            jax.jit(functools.partial(decode_step, cfg)))
-
-
-def rel(got, want):
-    return float(np.abs(np.asarray(got, np.float32) - want).max()
-                 / np.abs(want).max())
+# weights with every norm's scale and the skip away from 1 (a norm left out,
+# or one scale taken for another, then shows)
+seeded = functools.partial(
+    harness.seeded, stir=("scale", "'norm'", "d_skip"), by=0.3)
 
 
 PADDED = 80  # every sound reference call is one row of so many tokens
@@ -139,7 +108,8 @@ def toy():
     tokens = jax.random.randint(jax.random.PRNGKey(7), (2, 41), 0,
                                 cfg.vocab_size)
     with jax.default_matmul_precision("highest"):
-        logits, routes = programs(cfg)[0](params, tokens)
+        logits, routes = harness.forward_program(cfg, return_routes=True)(
+            params, tokens)
     routes = np.asarray(routes)
     return {"cfg": cfg, "params": params, "tokens": tokens,
             "logits": np.asarray(logits), "routes": routes,
@@ -354,15 +324,8 @@ def test_prefill_and_decode_step_match_the_reference(toy, n):
     cfg, params, tokens = toy["cfg"], toy["params"], toy["tokens"]
     caches = init_caches(cfg, 2, 48)
     assert [c is None for c in caches] == [k == NONE for k in cfg.kinds]
-    got = []
-    _, fill, step = programs(cfg)
-    with jax.default_matmul_precision("highest"):
-        logits, caches = fill(params, tokens[:, :n], caches)
-        got.append(logits)
-        for t in range(n, 41):
-            logits, caches = step(params, tokens[:, t:t + 1], caches)
-            got.append(logits)
-    assert rel(jnp.stack(got, 1), toy["want"][:, n - 1:]) <= TOL
+    got = harness.cached_logits(cfg, params, tokens[:, :41], n, length=48)
+    assert rel(got, toy["want"][:, n - 1:]) <= TOL
 
 
 # ------------------------------------------------------------ the two scans
@@ -505,24 +468,17 @@ def test_the_two_shares_add_up_to_the_uncut_layer():
 # ------------------------------------------------------ the paged programs
 
 
-@pytest.fixture(scope="module")
-def paged_run():
-    """Two prompts through the paged programs. Slot 1 takes a 53-token
-    prompt in chunks of 16 (over three chunk boundaries, ending inside a
-    chunk: the states are carried from chunk to chunk, the last one's
-    padding touches neither); slot 2 then a 33-token prompt whose chunks
-    take slot 1's decode row along (the fused turn); then plain steps of
-    both. Slots 0 and 3 hold no sequence; their states are filled with a
-    value that must come back bitwise."""
+def _paged(request):
+    """Two prompts through the paged programs (``harness.paged_drive``).
+    Slot 1 takes a 53-token prompt in chunks of 16 (over three chunk
+    boundaries, ending inside a chunk: the states are carried from chunk to
+    chunk, the last one's padding touches neither); slot 2 then a 33-token
+    prompt whose chunks take slot 1's decode row along (the fused turn);
+    then plain steps of both. Slots 0 and 3 hold no sequence; their states
+    are filled with a value that must come back bitwise, and every page no
+    table names is FILLED WITH NaN in the attention layer's pool."""
     cfg = presets.nemotron_h_debug(**HELD)
-    params = seeded(cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(9), (2, 80), 0,
-                                cfg.vocab_size)
-    C, slots, T, P, n = 16, 4, 4, 24, {1: 53, 2: 33}
-    row = {1: 0, 2: 1}
-    tables = np.zeros((slots, P), np.int32)
-    for s in n:
-        tables[s] = 1 + s * P + np.arange(P)
+    slots, T, P = 4, 4, 24
     caches = init_paged_caches(cfg, slots * P + 1, T, P, slots=slots)
     idle = jnp.asarray([0, 3])
     caches = [c if k != MAMBA else dataclasses.replace(
@@ -533,71 +489,15 @@ def paged_run():
     caches = [c if k != MAMBA else dataclasses.replace(
         c, conv=c.conv.at[2].set(3.0), ssm=c.ssm.at[2].set(3.0))
         for c, k in zip(caches, cfg.kinds)]
-    got = {s: [] for s in n}
-    routes = {s: [] for s in n}
-    left_out = 0
-    cursor = {1: 0, 2: 0}
-    both = jnp.asarray(tables)
+    return dict(
+        cfg=cfg, params=seeded(cfg), impl="reference", caches=caches,
+        tokens=jax.random.randint(jax.random.PRNGKey(9), (2, 80), 0,
+                                  cfg.vocab_size),
+        tables=harness.slot_tables(slots, P, (1, 2)),
+        lengths={1: 53, 2: 33}, chunk=16, steps=5, moe_info=True)
 
-    def step_rows(live):
-        active = np.zeros(slots, np.int32)
-        cursors = np.zeros(slots, np.int32)
-        for s in live:
-            active[s], cursors[s] = 1, cursor[s]
-        return StepRows(active, cursors, both, both,
-                        np.zeros(slots, np.float32),
-                        np.zeros(slots, np.uint32))
 
-    def ids_for(live):
-        ids = np.zeros(slots, np.int32)
-        for s in live:
-            ids[s] = tokens[row[s], cursor[s]]
-        return jnp.asarray(ids)
-
-    kw = dict(attn="reference", moe_info=True, logits=True)
-    chunk = jax.jit(functools.partial(paged_prefill_into_slot, cfg, **kw))
-    step = jax.jit(functools.partial(paged_decode_step, cfg, **kw))
-    held_rows = 0
-    with jax.default_matmul_precision("highest"):
-        for s, live in ((1, []), (2, [1])):
-            prompt = np.asarray(tokens[row[s], :n[s]])
-            for c0 in range(0, n[s], C):
-                real = min(C, n[s] - c0)
-                padded = np.zeros((1, C), np.int32)
-                padded[0, :real] = prompt[c0:c0 + real]
-                _, caches, info, logits = chunk(
-                    params, jnp.asarray(padded), np.int32(real),
-                    np.int32(c0), both[s], both[s], caches, ids_for(live),
-                    np.int32(-1), np.float32(0), np.uint32(0),
-                    step_rows(live), np.int32(s))
-                r = np.asarray(info["routes"])[:, 0]
-                assert r.shape[0] == cfg.expert_layers
-                assert info["counts"].shape == (2, 2, 4)  # held experts
-                held_rows += int(np.asarray(info["counts"]).sum())
-                left_out += int(np.asarray(info["left_out"]).sum())
-                routes[s].append(r[:, :real])
-                cursor[s] = c0 + real
-                for other in live:
-                    got[other].append(logits[1 + other])
-                    routes[other].append(r[:, C + other][:, None])
-                    cursor[other] += 1
-            got[s].append(logits[0])
-        for _ in range(5):
-            live = [1, 2]
-            rows = step_rows(live)
-            _, caches, info, logits = step(
-                params, ids_for(live), rows.active, rows.cursors,
-                rows.read_tables, rows.write_tables, caches,
-                rows.temperature, rows.seeds)
-            held_rows += int(np.asarray(info["counts"]).sum())
-            left_out += int(np.asarray(info["left_out"]).sum())
-            for s in live:
-                got[s].append(logits[s])
-                routes[s].append(np.asarray(info["routes"])[:, s])
-                cursor[s] += 1
-    return {"cfg": cfg, "params": params, "tokens": tokens, "got": got,
-            "routes": routes, "n": n, "row": row, "cursor": cursor,
-            "caches": caches, "held": held_rows, "left_out": left_out}
+paged_run = harness.paged_fixture(_paged)
 
 
 @pytest.mark.parametrize("slot", [1, 2])
@@ -608,7 +508,11 @@ def test_paged_chunks_steps_and_fused_turns_match_the_reference(paged_run,
     seq = run["tokens"][run["row"][slot]][None, :end]
     routes = np.concatenate(run["routes"][slot], 1)[:, None]
     assert routes.shape[2] == end
-    got = jnp.stack(run["got"][slot])
+    for i, info in enumerate(run["info"]):
+        assert info["routes"].shape[0] == cfg.expert_layers
+        if i < 4 + 3:  # the chunks' programs: held experts, a group of rows
+            assert info["counts"].shape == (2, 2, 4)
+    got = harness.slot_logits(run, slot)
     want = reference(run["params"], seq, hp_of(cfg), routes)[0]
     assert rel(got, want[n - 1:]) <= TOL
 
@@ -620,37 +524,17 @@ def test_the_paged_programs_left_the_idle_slots_states_bitwise(paged_run):
         if kind == MAMBA:
             for state in (c.conv, c.ssm):
                 assert (np.asarray(state)[[0, 3]] == 7.0).all()
+    harness.poisoned_pages_left_alone(paged_run)
     # every choice a live row made is counted, here or as left out
     rows = sum(paged_run["cursor"].values())
-    assert paged_run["held"] + paged_run["left_out"] == (
-        cfg.expert_layers * cfg.moe_top_k * rows)
-    assert 0 < paged_run["left_out"] < paged_run["held"] + paged_run[
-        "left_out"]
+    held, left_out = (sum(int(np.asarray(info[key]).sum())
+                          for info in paged_run["info"])
+                      for key in ("counts", "left_out"))
+    assert held + left_out == cfg.expert_layers * cfg.moe_top_k * rows
+    assert 0 < left_out < held + left_out
 
 
 # ------------------------------------------------------------ the scheduler
-
-
-def serve(sched, prompts, new):
-    async def one(prompt):
-        queue = asyncio.Queue()
-        sched.submit(prompt, max_new_tokens=new, temperature=0.0,
-                     loop=asyncio.get_running_loop(), queue=queue)
-        out = []
-        while True:
-            kind, value, _ = await queue.get()
-            if kind == "tok":
-                out.append(value)
-            elif kind == "end":
-                return out
-            else:
-                raise RuntimeError(f"{kind}: {value}")
-
-    async def drive():
-        return await asyncio.gather(*(one(p) for p in prompts))
-
-    with jax.default_matmul_precision("highest"):
-        return asyncio.run(drive())
 
 
 def test_the_scheduler_serves_the_kind_and_counts_its_work():
@@ -667,23 +551,15 @@ def test_the_scheduler_serves_the_kind_and_counts_its_work():
         ContinuousScheduler(cfg, params, slots=3, prefill_chunk=16,
                             arena_len=96, page_tokens=4, prefix_cache=True,
                             attn="reference")
-    sched = ContinuousScheduler(cfg, params, slots=3, prefill_chunk=16,
-                                arena_len=96, page_tokens=4,
-                                prefix_cache=False, attn="reference")
     prompts = [tokens[i, :n].tolist() for i, n in enumerate((70, 9, 33))]
-    try:
-        served = serve(sched, prompts, new)
-        stats = sched.stats()
-        assert sched.compiled_programs() == 2
-    finally:
-        sched.shutdown()
+    served, stats = harness.served(
+        cfg, params, prompts, new, slots=3, prefill_chunk=16, arena_len=96,
+        page_tokens=4, prefix_cache=False)
     hp = hp_of(cfg)
     for prompt, out in zip(prompts, served):
         assert len(out) == new
-        seq = jnp.asarray([prompt + out[:-1]], jnp.int32)
-        want = reference(params, seq, hp)[0][len(prompt) - 1:]
-        assert all(logits.max() - logits[tok] <= 1e-3 * np.abs(want).max()
-                   for logits, tok in zip(want, out))
+        assert harness.near_the_references_best(
+            lambda seq: reference(params, seq, hp), prompt, out)
     mamba, experts = cfg.kinds.count(MAMBA), cfg.expert_layers
     rows = sum(len(p) + new - 1 for p in prompts)
     steps = (new - 1) * len(prompts)

@@ -13,6 +13,7 @@ that are renormalized or a missing q/k norm far more (asserted below).
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -22,14 +23,14 @@ import pytest
 from perfbench.reference import olmoe as ref
 from ray_tpu.models import (forward, init_params, llama_debug, loss_fn,
                             moe_debug)
-from ray_tpu.models.decode import (StepRows, decode_step, init_caches,
-                                   init_paged_caches, paged_decode_step,
-                                   paged_prefill_into_slot,
-                                   paged_verify_step, prefill)
+from ray_tpu.models.decode import (init_paged_caches, paged_decode_step,
+                                   paged_verify_step)
 from ray_tpu.models.transformer import _qkv
 from ray_tpu.ops import moe as moe_ops
 from ray_tpu.ops.moe import Tiles, init_moe_params, moe_layer, tile_sizes
 from ray_tpu.ops.rotary import apply_rotary, rope_frequencies
+from tests import model_harness as harness
+from tests.model_harness import rel as rel_err
 
 TOL = 1e-4
 
@@ -43,23 +44,9 @@ def hp_of(cfg):
             "num_hidden_layers": cfg.num_layers}
 
 
-def seeded(cfg, seed=0):
-    """Seeded weights whose norm scales are not all ones (so a norm that
-    is left out shows)."""
-    params = init_params(cfg, jax.random.PRNGKey(seed))
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
-
-    def jitter(path, a):
-        name = jax.tree_util.keystr(path)
-        if "norm" in name or "ln" in name:
-            return a + 0.2 * jax.random.normal(next(keys), a.shape, a.dtype)
-        return a
-    return jax.tree_util.tree_map_with_path(jitter, params)
-
-
-def rel_err(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.abs(got - want).max() / np.abs(want).max())
+# seeded weights whose norm scales are not all ones (so a norm that is left
+# out shows)
+seeded = harness.seeded
 
 
 def sys_forward(cfg, params, tokens):
@@ -156,15 +143,9 @@ def test_prefill_and_decode_step_match_the_full_forward(toy):
     cfg, params, tokens = toy
     n = 30
     want = ref.forward(params, tokens, hp_of(cfg))
-    with jax.default_matmul_precision("highest"):
-        caches = init_caches(cfg, tokens.shape[0], tokens.shape[1])
-        logits, caches = prefill(cfg, params, tokens[:, :n], caches)
-        got = [logits]
-        for t in range(n, tokens.shape[1] - 1):
-            logits, caches = decode_step(cfg, params, tokens[:, t:t + 1],
-                                         caches)
-            got.append(logits)
-    assert rel_err(jnp.stack(got, 1), want[:, n - 1:-1]) < TOL
+    got = harness.cached_logits(cfg, params, tokens[:, :-1], n,
+                                length=tokens.shape[1])
+    assert rel_err(got, want[:, n - 1:-1]) < TOL
 
 
 def paged_setup(cfg, slots, T=8, P=16):
@@ -173,75 +154,34 @@ def paged_setup(cfg, slots, T=8, P=16):
     return caches, jnp.asarray(tables)
 
 
-@pytest.fixture(scope="module")
-def paged_run(toy):
+def _paged(request):
     """Two prompts through the paged programs in the in-place reference
-    lane: prefill chunks of 16 into slots 1 and 2 of 4 (slots 0 and 3 hold
-    no sequence), then decode steps, collecting logits, routes and counts."""
-    cfg, params, tokens = toy
-    C, slots, n = 16, 4, [21, 32]
-    caches, tables = paged_setup(cfg, slots)
-    got = {s: [] for s in (1, 2)}
-    taken = {s: [] for s in (1, 2)}
-    counted = live = 0
-    # the step's rows a chunk's program takes along: none decodes yet, so
-    # none is active, none is routed to an expert and none is counted
-    idle = StepRows(np.zeros(slots, np.int32), np.zeros(slots, np.int32),
-                    tables, tables, np.zeros(slots, np.float32),
-                    np.zeros(slots, np.uint32))
-    with jax.default_matmul_precision("highest"):
-        for b, s in enumerate((1, 2)):
-            prompt = np.asarray(tokens[b, :n[b]])
-            for c0 in range(0, n[b], C):
-                chunk = prompt[c0:c0 + C]
-                real = len(chunk)
-                padded = np.zeros((1, C), np.int32)
-                padded[0, :real] = chunk
-                _, caches, moe, logits = paged_prefill_into_slot(
-                    cfg, params, jnp.asarray(padded), real, np.int32(c0),
-                    tables[s], tables[s], caches,
-                    jnp.zeros(slots, jnp.int32), np.int32(-1),
-                    np.float32(0), np.uint32(0), idle, attn="reference",
-                    moe_info=True, logits=True)
-                assert moe["routes"].shape[2] == C + slots
-                taken[s].append(np.asarray(moe["routes"])[:, 0, :real])
-                counted += int(moe["counts"].sum())
-                live += real
-            got[s].append(logits[0])  # behind it the idle step rows'
-        active = jnp.asarray([0, 1, 1, 0], jnp.int32)
-        cursors = np.asarray([0, n[0], n[1], 0], np.int32)
-        for step in range(6):
-            toks = np.zeros(slots, np.int32)
-            for b, s in enumerate((1, 2)):
-                toks[s] = tokens[b, n[b] + step]
-            ids, caches, moe, logits = paged_decode_step(
-                cfg, params, jnp.asarray(toks), active, cursors + step,
-                tables, tables, caches, jnp.zeros(slots, jnp.float32),
-                jnp.zeros(slots, jnp.uint32), attn="reference",
-                moe_info=True, logits=True)
-            # an active row's id is its argmax, an idle row's its token
-            assert np.array_equal(
-                np.asarray(ids), np.where(np.asarray(active) > 0,
-                                          np.asarray(logits).argmax(-1),
-                                          toks))
-            counted += int(moe["counts"].sum())
-            live += 2
-            for s in (1, 2):
-                got[s].append(logits[s])
-                taken[s].append(np.asarray(moe["routes"])[:, s])
-    return {"got": got, "taken": taken, "counted": counted, "live": live,
-            "n": n, "caches": caches, "tables": tables}
+    lane (``harness.paged_drive``): prefill chunks of 16 into slots 1 and 2
+    of 4 (slots 0 and 3 hold no sequence), then decode steps, collecting
+    logits, routes and counts. The step's rows a chunk's program takes
+    along: none decodes yet, so none is active, none is routed to an expert
+    and none is counted. (Every page but the garbage page is some slot's:
+    there is none to poison.)"""
+    cfg, params, tokens = request.getfixturevalue("toy")
+    caches, tables = paged_setup(cfg, 4)
+    return dict(cfg=cfg, params=params, tokens=tokens, caches=caches,
+                tables=np.asarray(tables), impl="reference", along=False,
+                poisoned=False, lengths={1: 21, 2: 32}, chunk=16, steps=6,
+                moe_info=True)
+
+
+paged_run = harness.paged_fixture(_paged)
 
 
 @pytest.mark.parametrize("b,slot", [(0, 1), (1, 2)])
 def test_paged_chunks_and_decode_match_the_full_forward(toy, paged_run, b,
                                                         slot):
     cfg, params, tokens = toy
-    n = paged_run["n"][b]
+    n = paged_run["n"][slot]
     seq = tokens[b:b + 1, :n + 6]
-    routes = jnp.asarray(np.concatenate(paged_run["taken"][slot], 1))[:, None]
+    routes = jnp.asarray(np.concatenate(paged_run["routes"][slot], 1))[:, None]
     want = ref.forward(params, seq, hp_of(cfg), routes)[0]
-    got = jnp.stack(paged_run["got"][slot])
+    got = harness.slot_logits(paged_run, slot)
     assert rel_err(got, want[n - 1:n + 6]) < TOL
     assert rel_err(got, ref.forward(params, seq, hp_of(cfg))[0][n - 1:]) < TOL
 
@@ -250,8 +190,12 @@ def test_paged_programs_count_live_rows_only(toy, paged_run):
     """Rows of slots without a sequence and a chunk's padding reach no
     expert: the counts are live rows x k x layers, exactly."""
     cfg = toy[0]
-    assert paged_run["counted"] == (paged_run["live"] * cfg.moe_top_k
-                                    * cfg.num_layers)
+    chunks = paged_run["info"][:2 + 2]
+    assert all(info["routes"].shape[2] == 16 + 4 for info in chunks)
+    counted = sum(int(info["counts"].sum()) for info in paged_run["info"])
+    live = sum(paged_run["cursor"].values())
+    assert live == 21 + 32 + 2 * 6
+    assert counted == live * cfg.moe_top_k * cfg.num_layers
 
 
 def test_paged_verify_step_matches_the_full_forward(toy, paged_run):
@@ -261,15 +205,15 @@ def test_paged_verify_step_matches_the_full_forward(toy, paged_run):
     cfg, params, tokens = toy
     K, used = 4, np.asarray([0, 3, 4, 0], np.int32)
     window = np.zeros((4, K), np.int32)
-    starts = {1: paged_run["n"][0] + 6, 2: paged_run["n"][1] + 6}
+    starts = {s: paged_run["n"][s] + 6 for s in (1, 2)}
     for b, s in enumerate((1, 2)):
         window[s, :used[s]] = tokens[b, starts[s]:starts[s] + used[s]]
     with jax.default_matmul_precision("highest"):
-        logits, _, moe = paged_verify_step(
-            cfg, params, jnp.asarray(window), jnp.asarray(used),
+        logits, _, moe = jax.jit(functools.partial(
+            paged_verify_step, cfg, attn="reference", moe_info=True))(
+            params, jnp.asarray(window), jnp.asarray(used),
             np.asarray([0, starts[1], starts[2], 0], np.int32),
-            paged_run["tables"], paged_run["tables"], paged_run["caches"],
-            attn="reference", moe_info=True)
+            paged_run["tables"], paged_run["tables"], paged_run["caches"])
     assert int(moe["counts"].sum()) == 7 * cfg.moe_top_k * cfg.num_layers
     for b, s in enumerate((1, 2)):
         seq = tokens[b:b + 1, :starts[s] + used[s]]
@@ -294,48 +238,18 @@ def test_scheduler_serves_the_expert_model_and_drops_no_row(toy):
     slots idle, padded chunks): every served token is the reference's
     choice or within TOL of it, and the device's expert counts add up to
     live rows x k x layers."""
-    import asyncio
-
-    from ray_tpu.serve._private.continuous import ContinuousScheduler
-
     cfg, params, tokens = toy
-    sched = ContinuousScheduler(cfg, params, slots=4, prefill_chunk=16,
-                                arena_len=128, page_tokens=8, kv_pages=65,
-                                attn="reference")
     prompts = [np.asarray(tokens[0, :21]).tolist(),
                np.asarray(tokens[1, :37]).tolist(),
                np.asarray(tokens[0, 5:14]).tolist()]
-
-    async def one(prompt):
-        queue = asyncio.Queue()
-        sched.submit(prompt, max_new_tokens=5, temperature=0.0,
-                     loop=asyncio.get_running_loop(), queue=queue)
-        out = []
-        while True:
-            kind, value, _ = await queue.get()
-            if kind == "tok":
-                out.append(value)
-            elif kind == "end":
-                return out
-            else:
-                raise RuntimeError(f"{kind}: {value}")
-
-    async def drive():
-        return await asyncio.gather(*(one(p) for p in prompts))
-
-    try:
-        with jax.default_matmul_precision("highest"):
-            served = asyncio.run(drive())
-        stats = sched.stats()
-    finally:
-        sched.shutdown()
+    served, stats = harness.served(
+        cfg, params, prompts, 5, slots=4, prefill_chunk=16, arena_len=128,
+        page_tokens=8, kv_pages=65)
     for prompt, out in zip(prompts, served):
         assert len(out) == 5
-        seq = jnp.asarray([prompt + out[:-1]], jnp.int32)
-        want = np.asarray(ref.forward(params, seq, hp_of(cfg))[0])
-        want = want[len(prompt) - 1:]
-        for logits, tok in zip(want, out):
-            assert logits.max() - logits[tok] <= TOL * np.abs(want).max()
+        assert harness.near_the_references_best(
+            lambda seq: np.asarray(ref.forward(params, seq, hp_of(cfg))),
+            prompt, out, tol=TOL)
     live = sum(len(p) for p in prompts) + 4 * len(prompts)
     assert stats["moe_live_rows"] == live
     assert stats["moe_rows_routed"] == live * cfg.moe_top_k * cfg.num_layers

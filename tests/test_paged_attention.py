@@ -24,6 +24,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from tests import model_harness as harness
+
 SLOTS = 4
 CHUNK = 8
 NEW = 6
@@ -398,17 +400,9 @@ def _sequential_logits(cfg, params, ids, feed):
     a token of ``feed``, over a ``LayerKVCache`` of one sequence. Returns
     the logits after the prompt and after each fed token, [1 + len(feed),
     vocab]."""
-    from ray_tpu.models.decode import decode_step, init_caches, prefill
-
-    caches = init_caches(cfg, 1, cfg.max_seq_len, jnp.float32)
-    logits, caches = prefill(cfg, params, jnp.asarray([ids], jnp.int32),
-                             caches)
-    out = [np.asarray(logits[0])]
-    for t in feed:
-        logits, caches = decode_step(cfg, params,
-                                     jnp.asarray([[t]], jnp.int32), caches)
-        out.append(np.asarray(logits[0]))
-    return np.stack(out)
+    tokens = jnp.asarray([list(ids) + list(feed)], jnp.int32)
+    return np.asarray(harness.cached_logits(
+        cfg, params, tokens, len(ids), cfg.max_seq_len, jnp.float32)[0])
 
 
 PAGED_PROGRAMS = ("paged_prefill_into_slot", "paged_decode_step",
@@ -703,19 +697,7 @@ class TestAttnCounters:
 
 
 def _sequential_reference(srv, prompt, new_tokens=NEW):
-    from ray_tpu.models.decode import init_caches
-
-    ids = srv._tokenize(prompt)
-    toks = jnp.asarray([ids], jnp.int32)
-    caches = init_caches(srv.cfg, 1, len(ids) + new_tokens)
-    logits, caches = srv._prefill(srv.params, toks, caches)
-    out = []
-    for _ in range(new_tokens):
-        t = int(np.asarray(logits).argmax(-1)[0])
-        out.append(t)
-        logits, caches = srv._decode_step(
-            srv.params, jnp.asarray([[t]], jnp.int32), caches)
-    return srv._detokenize(out)
+    return harness.sequential_text(srv, prompt, new_tokens, length=64)
 
 
 class TestSchedulerLanes:

@@ -16,13 +16,13 @@ a later admit of the same prefix.
 import asyncio
 import time
 
-import numpy as np
 import pytest
 
 from ray_tpu.serve._private.paging import (OutOfPagesError, PageArena,
                                            PagePool, RadixCache, SlotPages,
                                            build_pools, cannot_continue,
                                            pool_stats, pool_tables)
+from tests.model_harness import sequential_text
 
 SLOTS = 4
 CHUNK = 8
@@ -357,21 +357,7 @@ def server():
 
 
 def _sequential_reference(srv, prompt: str, new_tokens: int = NEW):
-    import jax.numpy as jnp
-
-    from ray_tpu.models.decode import init_caches
-
-    ids = srv._tokenize(prompt)
-    toks = jnp.asarray([ids], jnp.int32)
-    caches = init_caches(srv.cfg, 1, len(ids) + new_tokens)
-    logits, caches = srv._prefill(srv.params, toks, caches)
-    out = []
-    for _ in range(new_tokens):
-        t = int(np.asarray(logits).argmax(-1)[0])
-        out.append(t)
-        logits, caches = srv._decode_step(
-            srv.params, jnp.asarray([[t]], jnp.int32), caches)
-    return srv._detokenize(out)
+    return sequential_text(srv, prompt, new_tokens)
 
 
 class TestPagedParity:

@@ -33,6 +33,8 @@ import numpy as np
 import pytest
 
 import ray_tpu
+from tests.test_train_pipeline import (_batch, _local_losses,
+                                       _store_pins)
 
 TP = 2
 
@@ -44,50 +46,6 @@ def _tp_cfg(num_layers=2):
     return presets.llama_debug(
         num_layers=num_layers, vocab_size=128, max_seq_len=32,
         embed_dim=32, num_heads=4, num_kv_heads=2, mlp_dim=64)
-
-
-def _batch(n=8, seq=16, seed=0):
-    rng = np.random.default_rng(seed)
-    return rng.integers(0, 128, (n, seq)).astype(np.int32)
-
-
-def _local_losses(cfg, batch, num_microbatches, steps, lr=0.05):
-    """Single-process fused reference: per-microbatch value_and_grad,
-    grads averaged over the SAME microbatch split, optax SGD."""
-    import jax
-    import optax
-
-    from ray_tpu.models.transformer import init_params, loss_fn
-
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    opt = optax.sgd(lr)
-    ost = opt.init(params)
-
-    def mb_loss(p, toks):
-        loss, _ = loss_fn(cfg, p, {"tokens": toks})
-        return loss
-
-    gfn = jax.jit(jax.value_and_grad(mb_loss))
-    mb = batch.shape[0] // num_microbatches
-    out = []
-    for _ in range(steps):
-        acc, losses = None, []
-        for m in range(num_microbatches):
-            loss, g = gfn(params, batch[m * mb:(m + 1) * mb])
-            losses.append(float(loss))
-            acc = g if acc is None else jax.tree.map(
-                lambda a, b: a + b, acc, g)
-        grads = jax.tree.map(lambda g: g / num_microbatches, acc)
-        upd, ost = opt.update(grads, ost, params)
-        params = optax.apply_updates(params, upd)
-        out.append(float(np.mean(losses)))
-    return out
-
-
-def _store_pins(core):
-    stats = core._run(core.clients.get(core.supervisor_addr).call(
-        "store_stats"))
-    return stats["pins_total"]
 
 
 def _assert_trees_equal(want, got, ctx=""):

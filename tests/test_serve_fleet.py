@@ -33,6 +33,7 @@ from ray_tpu.serve._private.paging import PageArena, RadixCache
 from ray_tpu.serve._private.speculative import (_softmax, accept_greedy,
                                                 accept_sample)
 from ray_tpu.serve.llm import LLMServerImpl
+from tests.model_harness import sequential_text as _sequential_reference
 
 SLOTS = 4
 CHUNK = 8
@@ -496,24 +497,6 @@ class TestMigrationSplice:
 
 
 # --------------------------------------------------- speculative decoding
-
-
-def _sequential_reference(srv, prompt, new_tokens):
-    import jax.numpy as jnp
-
-    from ray_tpu.models.decode import init_caches
-
-    ids = srv._tokenize(prompt)
-    toks = jnp.asarray([ids], jnp.int32)
-    caches = init_caches(srv.cfg, 1, len(ids) + new_tokens)
-    logits, caches = srv._prefill(srv.params, toks, caches)
-    out = []
-    for _ in range(new_tokens):
-        t = int(np.asarray(logits).argmax(-1)[0])
-        out.append(t)
-        logits, caches = srv._decode_step(
-            srv.params, jnp.asarray([[t]], jnp.int32), caches)
-    return srv._detokenize(out)
 
 
 def _assert_the_pool_keeps_no_cursor(srv):

@@ -23,12 +23,13 @@ import pytest
 
 from ray_tpu.models import (brumby_debug, llama_debug, minicpm_sala_debug,
                             moe_debug)
-from ray_tpu.models.decode import (StepRows, decode_step, init_caches,
-                                   init_paged_caches, paged_decode_step,
-                                   paged_prefill_into_slot, prefill)
+from ray_tpu.models.decode import (StepRows, init_paged_caches,
+                                   paged_decode_step,
+                                   paged_prefill_into_slot)
 from ray_tpu.models.transformer import (ATTENTION, LINEAR, RETENTION, SPARSE,
                                         STATE_KINDS, init_params)
 from ray_tpu.serve._private.continuous import ContinuousScheduler
+from tests import model_harness as harness
 
 PRESETS = {
     "llama_debug": llama_debug, "moe_debug": moe_debug,
@@ -56,15 +57,15 @@ def model(request):
 
 
 def _programs(cfg):
-    """The two programs; a model with 'minicpm4' layers also hands back the
-    blocks they chose (last of what a program returns)."""
+    """The two programs, compiled once a model; a model with 'minicpm4'
+    layers also hands back the blocks they chose (last of what a program
+    returns)."""
     kw = {"attn": "reference"}
     if cfg.mlp == "moe":
         kw["moe_info"] = True
     if SPARSE in cfg.kinds:
         kw["selected"] = True
-    return (jax.jit(partial(paged_prefill_into_slot, cfg, **kw)),
-            jax.jit(partial(paged_decode_step, cfg, **kw)))
+    return harness.paged_programs(cfg, **kw)
 
 
 def _rows(cfg, tables, slot=None):
@@ -80,10 +81,30 @@ def _prompt(n, start):
     return [(start + 7 * i) % 250 + 1 for i in range(n)]
 
 
+_STATES = {}
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _states_go_with_the_file():
+    yield
+    _STATES.clear()
+
+
 def _state(cfg, params, lengths):
     """A pool in which the slots of ``lengths`` hold a prompt each (filled
     by the chunk's program alone), their tables (a slot's pages in order,
-    never page 0), cursors and the ids vector with each one's first token."""
+    never page 0), cursors and the ids vector with each one's first token.
+    Filled once a (model, lengths): the arrays are immutable, the cursors a
+    copy."""
+    key = (cfg, tuple(sorted(lengths.items())))
+    if key not in _STATES:
+        _STATES[key] = (params, _filled(cfg, params, lengths))
+    held, (caches, tables, ids, cursors) = _STATES[key]
+    assert held is params
+    return caches, tables, ids, cursors.copy()
+
+
+def _filled(cfg, params, lengths):
     chunk, _ = _programs(cfg)
     caches = init_paged_caches(cfg, SLOTS * P + 1, T, P, jnp.float32,
                                slots=SLOTS)
@@ -324,38 +345,15 @@ class Watched(ContinuousScheduler):
 
 def _oracle(cfg, params, prompt, new=NEW_MAX):
     """The sequential cache, which knows no page, slot or turn: greedy
-    tokens after ``prompt``."""
-    caches = init_caches(cfg, 1, len(prompt) + new, jnp.float32)
-    logits, caches = jax.jit(partial(prefill, cfg))(
-        params, jnp.asarray([prompt], jnp.int32), caches)
-    step = jax.jit(partial(decode_step, cfg))
-    out = []
-    for _ in range(new):
-        out.append(int(np.asarray(logits)[0].argmax()))
-        logits, caches = step(params, jnp.asarray([[out[-1]]], jnp.int32),
-                              caches)
-    return out
+    tokens after ``prompt`` (one program and one cache length a model)."""
+    return harness.oracle(cfg, params, prompt, new, P * T, jnp.float32)
 
 
 def _upto_eos(stream, eos):
     return stream[:stream.index(eos) + 1] if eos in stream else stream
 
 
-async def _stream(sched, prompt, new, cancel_after=None, gate=None):
-    """One request's items until its end: (tokens, how it ended)."""
-    if gate is not None:
-        await gate.wait()
-    queue = asyncio.Queue()
-    seq = sched.submit(prompt, max_new_tokens=new,
-                       loop=asyncio.get_running_loop(), queue=queue)
-    out = []
-    while True:
-        kind, value, _ = await queue.get()
-        if kind != "tok":
-            return out, (kind, value)
-        out.append(value)
-        if cancel_after is not None and len(out) == cancel_after:
-            sched.cancel(seq)
+_stream = harness.stream
 
 
 # prompt lengths of one chunk and of several, budgets short and long: with

@@ -27,6 +27,7 @@ import ray_tpu
 from ray_tpu import serve
 from ray_tpu.serve.batching import _BatchQueue  # noqa: F401  (unit tests)
 from ray_tpu.serve.llm import LLMServerImpl, build_app
+from tests.model_harness import sequential_text as _sequential_reference
 
 SLOTS = 4
 CHUNK = 8
@@ -44,26 +45,6 @@ def server():
                         prefill_chunk=CHUNK, share_weights=False)
     yield srv
     srv.shutdown()
-
-
-def _sequential_reference(srv, prompt: str, new_tokens: int):
-    """The sequential single-request path: full-prompt prefill + one
-    decode_step per token on a dedicated cache, greedy sampling."""
-    import jax.numpy as jnp
-
-    from ray_tpu.models.decode import decode_step, init_caches, prefill
-
-    ids = srv._tokenize(prompt)
-    toks = jnp.asarray([ids], jnp.int32)
-    caches = init_caches(srv.cfg, 1, len(ids) + new_tokens)
-    logits, caches = srv._prefill(srv.params, toks, caches)
-    out = []
-    for _ in range(new_tokens):
-        t = int(np.asarray(logits).argmax(-1)[0])
-        out.append(t)
-        logits, caches = srv._decode_step(
-            srv.params, jnp.asarray([[t]], jnp.int32), caches)
-    return srv._detokenize(out)
 
 
 def _scheduler_on_a_stub(**kwargs):

@@ -17,10 +17,10 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import presets
-from ray_tpu.models.decode import (decode_step, init_caches, prefill,
-                                   sample_token)
+from ray_tpu.models.decode import sample_token
 from ray_tpu.models.transformer import init_params
 from ray_tpu.serve._private.continuous import ContinuousScheduler
+from tests import model_harness as harness
 
 CHUNK, PAGE = 8, 4
 MODELS = ("llama_debug", "moe_debug")
@@ -46,52 +46,21 @@ def _sched(model, **kw):
     return ContinuousScheduler(cfg, params, attn="reference", **kw)
 
 
-_ORACLE, _STEP = {}, {}
-
-
 def _oracle(model, prompt, new):
-    """Greedy tokens of the sequential cache (``prefill`` + ``decode_step``
-    on one sequence): no page, table, slot or step in flight."""
-    cfg, params = model
-    key = (cfg.mlp, tuple(prompt))
-    have = _ORACLE.get(key, [])
-    if len(have) >= new:
-        return have[:new]
-    step = _STEP.setdefault(cfg.mlp, jax.jit(partial(decode_step, cfg)))
-    caches = init_caches(cfg, 1, cfg.max_seq_len)
-    logits, caches = prefill(cfg, params, jnp.asarray([prompt], jnp.int32),
-                             caches)
-    out = []
-    for _ in range(new):
-        out.append(int(np.asarray(logits).argmax(-1)[0]))
-        logits, caches = step(params, jnp.asarray([[out[-1]]], jnp.int32),
-                              caches)
-    _ORACLE[key] = out
-    return out
+    """Greedy tokens of the sequential cache (``decode_step`` on one
+    sequence): no page, table, slot or step in flight."""
+    return harness.oracle(*model, prompt, new)
 
 
 def _serve(sched, requests, on_submit=None):
     """Submit ``requests`` (dicts: prompt, new, and optionally temperature,
     seed) together; returns [(tokens, how it ended)] in their order."""
-    async def one(i, r):
-        queue = asyncio.Queue()
-        seq = sched.submit(r["prompt"], max_new_tokens=r["new"],
-                           temperature=r.get("temperature", 0.0),
-                           seed=r.get("seed", 0),
-                           loop=asyncio.get_running_loop(), queue=queue)
-        if on_submit is not None:
-            on_submit(i, seq)
-        out = []
-        while True:
-            kind, value, _ = await queue.get()
-            if kind == "tok":
-                out.append(value)
-            else:
-                return out, (kind, value)
-
     async def drive():
-        return await asyncio.gather(*(one(i, r)
-                                      for i, r in enumerate(requests)))
+        return await asyncio.gather(*(harness.stream(
+            sched, r["prompt"], r["new"],
+            temperature=r.get("temperature", 0.0), seed=r.get("seed", 0),
+            submitted=on_submit and partial(on_submit, i))
+            for i, r in enumerate(requests)))
 
     return asyncio.run(drive())
 
