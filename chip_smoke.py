@@ -1531,7 +1531,8 @@ def deepseek_task(seed: int, control: bool = True) -> dict:
     in its write table) and goes on with a tail of its own, past ``topk``;
     every page no table names filled with NaN in both arrays, as a released
     page would be. Then the picked attention alone at the cell's shapes
-    (``picked_timing``), and (``control``) the float8 control through the
+    (``picked_timing``), the experts' kernel alone at the cell's shape
+    (``experts_timing``), and (``control``) the float8 control through the
     harness's own comparison under the limits of
     ``cells/deepseek_v32_longdocs.json``, which must refuse it."""
     import dataclasses
@@ -1698,6 +1699,7 @@ def deepseek_task(seed: int, control: bool = True) -> dict:
         raise RuntimeError(f"deepseek: {bad}: {out}")
     del caches
     out["picked_timing"] = picked_timing(seed)
+    out["experts_timing"] = experts_timing(seed)
     if control:
         seen = out["float8_control"] = float8_control(
             cfg, hp, params, seed, ref, "deepseek_v32",
@@ -1707,6 +1709,49 @@ def deepseek_task(seed: int, control: bool = True) -> dict:
             raise RuntimeError("deepseek: the float8 control passes both "
                                f"comparisons of logits: {seen}")
     return {**out, **device_report()}
+
+
+def experts_timing(seed: int, calls: int = 8) -> dict:
+    """The experts' kernel ``moe_grouped_matmul`` ALONE at the cell's shape
+    (since PR 64): a fused turn's 4,160 pairs over the 16 held experts of
+    7168 x 2048 of the SECOND of two stacked layers, bf16 — 88 MB an expert,
+    which ``tile_sizes`` walks in 8 column tiles under a grid of 48 visits
+    — in milliseconds a call behind a warm-up: with the ~270 pairs a
+    sixteenth of DeepSeek's experts is sent (the fullest group 78), with
+    every pair held, and with none; beside each the visits that carry rows
+    and their weights' bytes over the time (GB/s; the memory gives 819)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.moe import _visits, expert_mlp, tile_sizes
+
+    G, d, f, pairs = 16, 7168, 2048, 4160
+    t = tile_sizes(pairs, G, d, f, 2)
+    n_tiles = -(-pairs // t.rows)
+    ks = jax.random.split(jax.random.key(seed & 0x7FFFFFFF), 4)
+    xs = jax.random.normal(ks[0], (pairs, d), jnp.bfloat16)
+    w = [0.02 * jax.random.normal(key, shape, jnp.bfloat16)
+         for key, shape in zip(ks[1:], [(2 * G, d, f), (2 * G, d, f),
+                                        (2 * G, f, d)])]
+    kernel = jax.jit(lambda xs, wg, wu, wd, g: expert_mlp(
+        xs, wg, wu, wd, g, G))
+    out = {"tiles": list(t), "grid_visits": n_tiles + G - 1}
+    for name, counts in (
+            ("a_sixteenth_held", (78, 3, 0, 31, 12, 0, 22, 9, 41, 0, 17, 5,
+                                  26, 8, 14, 4)),
+            ("every_pair_held", (pairs // G,) * G), ("none_held", (0,) * G)):
+        g = jnp.asarray(counts, jnp.int32)
+        jax.block_until_ready(kernel(xs, *w, g))
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            got = kernel(xs, *w, g)
+        jax.block_until_ready(got)
+        ms = (time.perf_counter() - t0) / calls * 1e3
+        visits = int(_visits(g, jnp.int32(G), n_tiles, t.rows)[4][0])
+        out[name] = {"ms": round(ms, 3), "visits": visits,
+                     "visited_gb_s": round(visits * 3 * d * f * 2 / ms / 1e6,
+                                           1)}
+    return out
 
 
 def picked_timing(seed: int, calls: int = 4) -> dict:

@@ -28,11 +28,13 @@ the needs conflict; the input says which, no option does):
     sliced off the stack (805 MB copied a call at OLMoE's widths), an expert
     that received rows is read from HBM exactly once (consecutive row tiles
     of one expert keep its weights in VMEM, the pipeline fetches the next
-    visit's while this one's are multiplied) and an expert without rows is
-    never fetched. Gate and up read the row tile once, ``silu(gate) * up``
-    stays in VMEM and is rounded once, then down; operands in the model's
-    dtype, float32 accumulation. Row tiles follow the STATIC pair count
-    (``tile_sizes``). No backward: nothing trains on this path.
+    visit's while this one's are multiplied; once a visit where an expert
+    is too wide for VMEM and its hidden width is walked in column tiles) and
+    an expert without rows is never fetched. Gate and up read the row tile
+    once, ``silu(gate) * up`` stays in VMEM and is rounded once, then down;
+    operands in the model's dtype, float32 accumulation. Row tiles follow
+    the STATIC pair count (``tile_sizes``). No backward: nothing trains on
+    this path.
 
 Rows that are not live (a slot without a sequence, a chunk's padding) are
 marked by ``valid``: they sort behind every group, cost no expert a row,
@@ -50,6 +52,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -174,8 +177,11 @@ def tile_sizes(pairs: int, groups: int, d: int, f: int, itemsize: int,
     less than it up to 128 rows (measured on the v5e at OLMoE's widths,
     PERF.md 6). cols: all of the hidden width ``f`` where a whole expert
     fits ``vmem_bytes`` twice over (OLMoE: 12.6 MB, three contiguous
-    copies), else the widest whole-lane divisor of ``f`` that does; the
-    down product then accumulates over the column tiles."""
+    copies), else the widest whole-lane divisor of ``f`` that does (256 of
+    DeepSeek's 2048 at d 7168: 88 MB an expert); the down product then
+    accumulates over the column tiles, and the weights are read once a
+    VISIT, not once an expert: a group across two row tiles streams its
+    expert twice, a padding visit nothing (``_column``)."""
     sublanes = 8 * 4 // itemsize
     want = _GROUPS_A_TILE * max(pairs // max(groups, 1), 1)
     rows = max(sublanes, min(_MAX_ROWS, 1 << (want - 1).bit_length()))
@@ -193,10 +199,12 @@ def _visits(counts, first_group, n_tiles: int, rows: int):
     """The grid's walk: one VISIT for every (group, row tile) pair that
     shares a row, in the order of the rows. Consecutive groups share at
     most one tile, so there are at most ``n_tiles + G - 1``; the walk is
-    padded to that by repeating the last visit, which fetches nothing anew
-    and is not computed. Returns int32 arrays a visit (its group in the
-    weight stack, its row tile, the group's first row and its end) and the
-    number of visits."""
+    padded to that by repeating the last visit, which is not computed. What
+    a padding visit FETCHES is the weight blocks' index maps' to say
+    (``hidden_block``, ``down_block``): the same group and row tile is the
+    same block, and no copy, only where the hidden width is one column tile.
+    Returns int32 arrays a visit (its group in the weight stack, its row
+    tile, the group's first row and its end) and the number of visits."""
     ends = jnp.cumsum(counts)
     starts = ends - counts
     first = starts // rows
@@ -209,6 +217,49 @@ def _visits(counts, first_group, n_tiles: int, rows: int):
                     counts.shape[0] - 1)
     tile = jnp.clip(first[g] + i - (upto - n)[g], 0, n_tiles - 1)
     return first_group + g, tile, starts[g], ends[g], total[None]
+
+
+def walk_lengths(counts, pairs: int, d: int, f: int, itemsize: int,
+                 matrices: int = 3) -> Tuple[int, int]:
+    """``_visits``' arithmetic on the host, for counters: ``counts`` [calls,
+    G] (numpy), the rows each group received in each kernel call of
+    ``pairs`` static pairs -> (the visits that carry rows, summed over the
+    calls; the grid's visits a call, ``n_tiles + G - 1``), on
+    ``tile_sizes``' rows. Their difference is the walk's padding."""
+    rows = tile_sizes(pairs, counts.shape[-1], d, f, itemsize,
+                      matrices=matrices).rows
+    ends = np.cumsum(counts, axis=-1)
+    tiles = np.where(counts > 0,
+                     (ends - 1) // rows - (ends - counts) // rows + 1, 0)
+    return int(tiles.sum()), -(-pairs // rows) + counts.shape[-1] - 1
+
+
+def _column(i, j, total, f_tiles: int):
+    """The column tile whose weights grid step (visit ``i``, column tile
+    ``j``) holds. A visit that carries rows (``i < total``) walks the
+    ``f_tiles`` tiles of its expert. A PADDING visit's ``j`` runs over them
+    again, and a block index that changes is a copy whatever the kernel then
+    does with it (at DeepSeek's widths 11 MB a step, a whole 88 MB expert a
+    padding visit, two thirds of the walk on a sixteenth of the experts):
+    it stays on the last real step's tile, so from there to the end of the
+    grid no index changes and the pipeline issues no copy; a walk without a
+    real visit fetches its one opening block. One column tile: ``j`` is 0
+    throughout and the maps are what they were before there were several."""
+    if f_tiles == 1:
+        return j
+    return jnp.where(i < total[0], j, f_tiles - 1)
+
+
+def hidden_block(i, j, group, total, f_tiles: int):
+    """Index map of a ``[d, cols]`` tile of a ``[stack, d, f]`` matrix (gate,
+    up): the visit's group in the stack, and ``_column``."""
+    return group[i], 0, _column(i, j, total, f_tiles)
+
+
+def down_block(i, j, group, total, f_tiles: int):
+    """Index map of a ``[cols, d]`` tile of a hidden-major ``[stack, f, d]``
+    matrix (down; a 'relu2' expert's up)."""
+    return group[i], _column(i, j, total, f_tiles), 0
 
 
 def _expert_kernel(group_ref, tile_ref, start_ref, end_ref, total_ref,
@@ -292,17 +343,18 @@ def expert_mlp(xs, w_gate, w_up, w_down, counts, first_group, tiles=None):
     def rows_map(i, j, group, tile, *_):
         return tile[i], 0
 
-    def into_hidden(i, j, group, *_):  # gate and up: [d, cols] of a group
-        return group[i], 0, j
+    def into_hidden(i, j, group, tile, start, end, total):
+        return hidden_block(i, j, group, total, f_tiles)
+
+    def from_hidden(i, j, group, tile, start, end, total):
+        return down_block(i, j, group, total, f_tiles)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(visits),
         grid=(visits[0].shape[0], f_tiles),
         in_specs=[pl.BlockSpec((t.rows, d), rows_map)]
         + [pl.BlockSpec((None, d, t.cols), into_hidden)] * (2 * gated)
-        + [pl.BlockSpec((None, t.cols, d),
-                        lambda i, j, group, *_: (group[i], j, 0))]
-        * (2 - gated),
+        + [pl.BlockSpec((None, t.cols, d), from_hidden)] * (2 - gated),
         out_specs=pl.BlockSpec((t.rows, d), rows_map),
         scratch_shapes=([pltpu.VMEM((t.rows, d), jnp.float32)]
                         if f_tiles > 1 else []),

@@ -33,6 +33,7 @@ from ray_tpu.models.transformer import (ATTENTION, INDEXED, INDEXED_LATENT,
 from ray_tpu.ops.indexed_attention import (SELECT_ROWS, cell_tokens,
                                            context_tokens, select_lanes)
 from ray_tpu.ops.latent_attention import latent_tiles, pool_width
+from ray_tpu.ops.moe import SWIGLU, walk_lengths
 from ray_tpu.ops.paged_attention import streamed_tokens, tile_sizes
 
 _m_attn_bytes = Counter(
@@ -274,7 +275,11 @@ _KINDS = {
 }
 _ATTN = ("attn_bytes_moved", "attn_tokens_attended", "attn_tokens_fetched")
 _EXPERTS = ("moe_live_rows", "moe_layer_calls", "moe_rows_routed",
-            "moe_experts_hit", "moe_max_expert_rows")
+            "moe_experts_hit", "moe_max_expert_rows",
+            # the kernel's walk (``ops.moe._visits``): a program's row groups
+            # are ONE kernel call a layer; of its grid's visits those that
+            # carry rows, the rest padding
+            "moe_kernel_calls", "moe_visits", "moe_grid_visits")
 # beside them where the expert layers have a shared expert: the rows it took
 # (every live row of every expert layer-call, times the shared experts)
 _SHARED = "moe_shared_rows"
@@ -400,11 +405,24 @@ class Work:
         behind it are over the experts held. A chunk's program that
         took the step along tells the two groups' rows apart ([layers, 2,
         experts]): each is a layer-call of its own, the step's only where a
-        row was live (``step``)."""
+        row was live (``step``) — and both are ONE call of the experts'
+        kernel a layer (``moe_kernel_calls``), whose walk over the call's
+        static pairs (``routes``' shape) ``ops.moe.walk_lengths`` repeats:
+        ``moe_visits`` of ``moe_grid_visits`` carried rows."""
         c = np.asarray(returned[0]["counts"])  # [layers, experts]
+        n = self._n
+        cfg = self.cfg
+        # the experts saw the program's rows as one batch, whoever sent them
+        visits, grid = walk_lengths(
+            c if c.ndim == 2 else c.sum(axis=1),
+            math.prod(returned[0]["routes"].shape[1:]), cfg.embed_dim,
+            cfg.mlp_width("moe"), np.dtype(cfg.dtype).itemsize,
+            2 + (cfg.moe_activation == SWIGLU))
+        n["moe_kernel_calls"] += c.shape[0]
+        n["moe_visits"] += visits
+        n["moe_grid_visits"] += c.shape[0] * grid
         if c.ndim == 3:
             c = c[:, :1 + step].reshape(-1, c.shape[-1])
-        n = self._n
         n["moe_live_rows"] += live_rows
         n["moe_layer_calls"] += c.shape[0]
         n["moe_rows_routed"] += int(c.sum())

@@ -48,6 +48,7 @@ def test_deepseek_serve_programs_compile_and_fit(v5e):
     of weights and the 4.03 GB pool beside the programs' own memory on one
     16 GB chip."""
     from ray_tpu.ops.latent_attention import _VMEM_LIMIT
+    from ray_tpu.ops.moe import tile_sizes
     from ray_tpu.ops.paged_attention import resolve_impl
 
     cfg, held, programs = cell_programs(v5e, "deepseek_v32_exp_l5",
@@ -57,6 +58,11 @@ def test_deepseek_serve_programs_compile_and_fit(v5e):
     assert cfg.mlp_width("swiglu") == 18432
     assert (cfg.moe_groups, cfg.moe_top_groups, cfg.held) == (8, 4, (0, 16))
     assert cfg.indexer.topk == 2048 and cfg.latent_rope[1] > 1.87
+    # an expert of 88 MB does not fit VMEM: the one cell whose experts'
+    # kernel walks the hidden width in column tiles (ISSUE 64), a turn's
+    # (512 + 8) x 8 pairs and a step's 64 alike
+    assert [tile_sizes(pairs, 16, 7168, 2048, 2).cols
+            for pairs in (4160, 64)] == [256, 256]
     lane = resolve_impl(cfg)
     assert lane == "pallas"
     assert 13.2e9 < held < 13.4e9

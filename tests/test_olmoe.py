@@ -259,6 +259,16 @@ def test_scheduler_serves_the_expert_model_and_drops_no_row(toy):
                                             * cfg.moe_num_experts)
     assert stats["moe_max_expert_rows"] >= (stats["moe_rows_routed"]
                                             / cfg.moe_num_experts)
+    # the kernel's walk: a chunk's program that took rows along is two
+    # layer-calls a layer and ONE kernel call; a visit that carries rows is
+    # at least one an expert hit in the call, at most the grid's
+    assert stats["fused_turns"] > 0
+    assert stats["moe_kernel_calls"] == cfg.num_layers * (
+        stats["decode_steps"] + stats["prefill_chunks"]
+        - stats["fused_turns"])
+    assert 0 < stats["moe_visits"] <= stats["moe_grid_visits"]
+    assert stats["moe_grid_visits"] >= stats["moe_kernel_calls"] * (
+        cfg.moe_num_experts)
 
 
 # ------------------------------------------------------ the expert layer
@@ -400,35 +410,90 @@ def test_the_kernel_matches_ragged_dot_and_the_reference(case):
         assert rel_err(y16[0, :n_live], want) > 10 * TOL
 
 
-@pytest.mark.parametrize("dtype,tiles", [
-    (jnp.float32, None), (jnp.bfloat16, None),
-    (jnp.float32, Tiles(8, 128)), (jnp.bfloat16, Tiles(32, 128))],
-    ids=["f32", "bf16", "f32_column_tiles", "bf16_column_tiles"])
-def test_expert_mlp_is_ragged_dot_thrice(dtype, tiles):
-    """The op alone, on a stack with an offset; with the hidden width cut
-    into column tiles the down product accumulates in float32. Rows past
-    the last group are undefined and touch no live row."""
-    G, d, f, pairs, first = 8, 128, 256, 80, 8
+_HIT = (0, 10, 0, 33, 1, 0, 0, 5)
+# name -> (dtype, tiles, hidden width, activation, rows a group, the visits
+# that carry rows). d 128, so 128 columns a tile are 2 column tiles of 256
+# and 4 of 512; 80 pairs in row tiles of 8 are a grid of 10 + 8 - 1 visits
+MLP_CASES = {
+    "f32": (jnp.float32, None, 256, "swiglu", _HIT, None),
+    "bf16": (jnp.bfloat16, None, 256, "swiglu", _HIT, None),
+    "f32_column_tiles": (jnp.float32, Tiles(8, 128), 256, "swiglu", _HIT, 10),
+    "bf16_column_tiles": (jnp.bfloat16, Tiles(32, 128), 256, "swiglu", _HIT,
+                          5),
+    # ISSUE 64: a padding visit holds its weight block still
+    "2_column_tiles_most_visits_padding": (
+        jnp.float32, Tiles(8, 128), 256, "swiglu", (0, 3, 0, 0, 2, 0, 0, 0),
+        2),
+    "4_column_tiles_most_visits_padding": (
+        jnp.float32, Tiles(8, 128), 512, "swiglu", (0, 0, 0, 0, 0, 0, 4, 1),
+        2),
+    "relu2_2_column_tiles_most_visits_padding": (
+        jnp.float32, Tiles(8, 128), 256, "relu2", (2, 0, 0, 0, 0, 3, 0, 0),
+        2),
+    "relu2_4_column_tiles": (jnp.bfloat16, Tiles(32, 128), 512, "relu2",
+                             _HIT, 5),
+    "2_column_tiles_no_held_pair": (
+        jnp.float32, Tiles(8, 128), 256, "swiglu", (0,) * 8, 0),
+    "relu2_4_column_tiles_no_held_pair": (
+        jnp.float32, Tiles(8, 128), 512, "relu2", (0,) * 8, 0),
+    "4_column_tiles_one_group_takes_every_pair": (
+        jnp.float32, Tiles(8, 128), 512, "swiglu", (0, 0, 0, 80, 0, 0, 0, 0),
+        10),
+    "relu2_2_column_tiles_one_group_takes_every_pair": (
+        jnp.float32, Tiles(16, 128), 256, "relu2", (0,) * 7 + (80,), 5),
+    "4_column_tiles_a_group_across_two_row_tiles": (
+        jnp.float32, Tiles(32, 128), 512, "swiglu", (5, 40, 0, 0, 0, 0, 0, 3),
+        4),
+    "relu2_2_column_tiles_a_group_across_two_row_tiles": (
+        jnp.float32, Tiles(16, 128), 256, "relu2", (0, 12, 9, 0, 0, 0, 0, 0),
+        3),
+}
+
+
+@pytest.mark.parametrize("case", list(MLP_CASES))
+def test_expert_mlp_is_ragged_dot_thrice(case):
+    """The op alone, on a stack with an offset, a SwiGLU expert's three
+    products and a 'relu2' expert's two; with the hidden width cut into
+    column tiles the down product accumulates in float32, and what the
+    walk's padding visits fetch (ISSUE 64) changes no number: most visits
+    padding, every visit padding, none, a group across two row tiles. Rows
+    past the last group are undefined and touch no live row."""
+    dtype, tiles, f, activation, counts, visits = MLP_CASES[case]
+    G, d, pairs, first = 8, 128, 80, 8
     ks = jax.random.split(jax.random.PRNGKey(3), 4)
     xs = jax.random.normal(ks[0], (pairs, d), jnp.float32).astype(dtype)
+    gated = activation == "swiglu"
     w = [(0.1 * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
-         for key, shape in zip(ks[1:], [(3 * G, d, f), (3 * G, d, f),
-                                        (3 * G, f, d)])]
-    counts = jnp.asarray([0, 10, 0, 33, 1, 0, 0, 5], jnp.int32)
+         for key, shape in zip(ks[1:], [
+             (3 * G, d, f), (3 * G, d, f) if gated else (3 * G, f, d),
+             (3 * G, f, d)])]
+    if not gated:
+        w[0] = None
+    counts = jnp.asarray(counts, jnp.int32)
     live = int(counts.sum())
     with jax.default_matmul_precision("highest"):
         got = moe_ops.expert_mlp(xs, *w, counts, first, tiles=tiles)
-        sl = [a[first:first + G] for a in w]
-        hidden = jax.nn.silu(jax.lax.ragged_dot(xs, sl[0], counts)) * (
-            jax.lax.ragged_dot(xs, sl[1], counts))
-        want = jax.lax.ragged_dot(hidden, sl[2], counts)
+        gate, up, down = (a if a is None else a[first:first + G] for a in w)
+        hidden = (jax.nn.silu(jax.lax.ragged_dot(xs, gate, counts))
+                  * jax.lax.ragged_dot(xs, up, counts) if gated else
+                  jnp.square(jax.nn.relu(jax.lax.ragged_dot(
+                      xs, up.swapaxes(1, 2), counts))))
+        want = jax.lax.ragged_dot(hidden, down, counts)
         again = moe_ops.expert_mlp(xs.at[live:].set(jnp.nan), *w, counts,
                                    first, tiles=tiles)
     assert got.shape == (pairs, d) and got.dtype == dtype
+    if tiles is not None:
+        n_tiles = -(-pairs // tiles.rows)
+        assert f // tiles.cols in (2, 4)
+        assert visits < n_tiles + G - 1 and visits == int(moe_ops._visits(
+            counts, jnp.int32(first), n_tiles, tiles.rows)[4][0])
+    if not live:
+        return
     # bf16: the kernel rounds silu(gate) * up once where XLA rounds thrice
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     assert rel_err(got[:live], want[:live]) < tol
-    assert float(jnp.abs(want[:live].astype(jnp.float32)).max()) > 0.1
+    assert float(jnp.abs(want[:live].astype(jnp.float32)).max()) > (
+        0.1 if gated else 0.02)
     np.testing.assert_array_equal(np.asarray(again[:live], np.float32),
                                   np.asarray(got[:live], np.float32))
 
@@ -462,6 +527,97 @@ def test_the_walk_reads_every_hit_expert_once_and_no_other(seed):
     assert int((np.diff(visited) != 0).sum()) + 1 == (counts > 0).sum()
     assert (group[total:] == group[total - 1]).all()
     assert (tile[total:] == tile[total - 1]).all()
+
+
+# 270 of a fused turn's 4,160 pairs held on 16 of DeepSeek-V3.2's 256
+# experts, the fullest group 78 rows (``deepseek_v32_longdocs``' routing)
+_HELD_270 = (78, 3, 0, 31, 12, 0, 22, 9, 41, 0, 17, 5, 26, 8, 14, 4)
+# name -> (pairs, d, f, rows a group, the visits that carry rows)
+WALKS = {
+    "deepseek_a_sixteenth_held": (4160, 7168, 2048, _HELD_270, 15),
+    "deepseek_no_pair_held": (4160, 7168, 2048, (0,) * 16, 0),
+    "deepseek_every_pair_held": (4160, 7168, 2048, (260,) * 16, 48),
+    "olmoe_one_column_tile": (4096, 2048, 1024, (64,) * 60 + (0, 256, 0, 0),
+                              62),
+    "olmoe_no_row_live": (256, 2048, 1024, (0,) * 64, 0),
+}
+
+
+def _blocks_fetched(index_map, group, total, grid, f_tiles):
+    """The grid steps whose weight block is not the step's before: what the
+    pipeline copies, the opening block among them."""
+    steps = [tuple(int(b) for b in index_map(i, j, group, total, f_tiles))
+             for i in range(grid) for j in range(f_tiles)]
+    return 1 + sum(a != b for a, b in zip(steps, steps[1:]))
+
+
+@pytest.mark.parametrize("case", list(WALKS))
+def test_a_visit_without_rows_moves_no_weights(case):
+    """ISSUE 64, the walk counted on the host: the weight blocks' index maps
+    over the whole grid, at DeepSeek's shape (an expert of 7168 x 2048 does
+    not fit VMEM: 8 column tiles under a grid of 33 + 16 - 1 visits). A
+    visit that carries rows fetches its expert's 8 tiles; a padding visit
+    none, where the maps as they were (``j`` in every visit) fetch 8 x 48 =
+    384 whatever the counts; a walk without a real visit fetches its opening
+    block. At one column tile a block changes where the group does, as it
+    always did."""
+    pairs, d, f, counts, visits = WALKS[case]
+    t = tile_sizes(pairs, len(counts), d, f, 2)
+    n_tiles, f_tiles = -(-pairs // t.rows), f // t.cols
+    grid = n_tiles + len(counts) - 1
+    group, _, _, _, total = (np.asarray(a) for a in moe_ops._visits(
+        jnp.asarray(counts, jnp.int32), jnp.int32(32), n_tiles, t.rows))
+    assert int(total[0]) == visits and group.shape == (grid,)
+    as_they_were = {moe_ops.hidden_block: lambda i, j, g, *_: (g[i], 0, j),
+                    moe_ops.down_block: lambda i, j, g, *_: (g[i], j, 0)}
+    for index_map, before in as_they_were.items():
+        fetched = _blocks_fetched(index_map, group, total, grid, f_tiles)
+        was = _blocks_fetched(before, group, total, grid, f_tiles)
+        if case.startswith("deepseek"):
+            assert (t, grid, f_tiles) == (Tiles(128, 256), 48, 8)
+            assert fetched == max(visits * f_tiles, 1) and was == 384
+        else:
+            assert f_tiles == 1
+            runs = max(sum(c > 0 for c in counts), 1)
+            assert fetched == was == runs <= max(visits, 1)
+
+
+def test_the_walks_counters_repeat_the_kernels_arithmetic(toy):
+    """``Work.routed``'s ``moe_visits`` is ``_visits``' ``total`` on the same
+    counts and the same tiles, ``moe_grid_visits`` the grid the kernel is
+    given, and a chunk's program that took the step's rows along — two
+    groups of rows, two layer-calls a layer — is ONE kernel call a layer
+    over both groups' counts."""
+    from ray_tpu.serve._private.work import Work
+
+    cfg = toy[0]
+    L, E, k = cfg.num_layers, cfg.moe_num_experts, cfg.moe_top_k
+    rng = np.random.default_rng(0)
+    chunk, slots = 16, 4
+    counts = np.zeros((L, 2, E), np.int32)
+    for layer in range(L):
+        for group, live in enumerate((13, 3)):
+            for _ in range(live):
+                counts[layer, group, rng.permutation(E)[:k]] += 1
+    work = Work(cfg, slots=slots, page_tokens=8, pages_per_slot=16,
+                lane="reference", itemsize=4)
+    routes = np.zeros((L, 1, chunk + slots, k), np.int32)
+    work.routed(({"counts": counts, "routes": routes},), 16)
+    pairs = (chunk + slots) * k
+    rows = tile_sizes(pairs, E, cfg.embed_dim, cfg.mlp_width("moe"),
+                      jnp.dtype(cfg.dtype).itemsize).rows
+    n_tiles = -(-pairs // rows)
+    walks = [moe_ops._visits(jnp.asarray(c.sum(0)), jnp.int32(0), n_tiles,
+                             rows) for c in counts]
+    got = work.stats()
+    assert got["moe_layer_calls"] == 2 * L and got["moe_kernel_calls"] == L
+    assert got["moe_visits"] == sum(int(w[4][0]) for w in walks)
+    assert got["moe_grid_visits"] == sum(w[0].shape[0] for w in walks)
+    assert 0 < got["moe_visits"] <= got["moe_grid_visits"]
+    # a step's program: a group of rows, a kernel call a layer
+    work.routed(({"counts": counts[:, 1],
+                  "routes": routes[:, :slots, :1]},), 3)
+    assert work.stats()["moe_kernel_calls"] == 2 * L
 
 
 def test_tile_sizes_is_a_function_of_static_shapes():

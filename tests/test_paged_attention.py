@@ -676,8 +676,13 @@ class TestAttnCounters:
             counts = np.zeros((8, 2, cfg.moe_num_experts), np.int32)
             counts[:, 0, :3], counts[:, 1, 5] = 13, 9
             assert work.counts_experts
-            work.routed(({"counts": counts},), 16)
+            # the program's 16 + 4 static rows chose top-k experts each
+            routes = np.zeros((8, 1, 20, cfg.moe_top_k), np.int32)
+            work.routed(({"counts": counts, "routes": routes},), 16)
             got = work.stats()
+            # the two groups' rows are ONE kernel call a layer
+            assert got["moe_kernel_calls"] == 8
+            assert 8 * 4 <= got["moe_visits"] <= got["moe_grid_visits"]
             assert got["moe_rows_routed"] == counts.sum() == 8 * (39 + 9)
             assert got["moe_layer_calls"] == 16
             assert got["moe_experts_hit"] == 8 * 4
