@@ -111,6 +111,19 @@ weights from ``--seed``):
            with the routes given, under the limits of
            cells/glm47_flash_longdocs.json) on the float8 CONTROL, which
            has to come out NOT correct
+  ouro     a @ray_tpu.remote(num_tpus=1) task runs the benchmark's
+           Ouro-2.6B configuration WHOLE (published widths, all 48 layers
+           gone through 4 times, bf16) under its cell's deployment (8 slots,
+           353 pages x 192 pools: 14.2 GB held): over five seeds a prompt of
+           320 tokens and one of 200 whose chunk takes the first's decode
+           row along, then 4 steps of both, through the paged programs — ONE
+           layer's body under a loop, the kernel told which pool — against
+           perfbench/reference/ouro.py on logits; every exit pass the
+           reference's (the last); every page no table names NaN in all 192
+           pools before and after; and on each of the first THREE seeds' own
+           weights and check prompt the float8 CONTROL through the
+           benchmark's own comparison under cells/ouro_reason.json's limits,
+           which has to come out NOT correct every time
   serve    serve.run(build_app(preset="gpt2_small")) answers 8 concurrent
            requests: six through the handle, one streamed, one over HTTP
 
@@ -1823,6 +1836,165 @@ def picked_timing(seed: int, calls: int = 4) -> dict:
     return out
 
 
+def ouro_task(seed: int, control: bool = True, seeds: int = 5) -> dict:
+    """The Ouro-2.6B-width checks (ISSUE 65): the benchmark's configuration
+    WHOLE — published widths, all 48 layers, 4 passes, bf16 — under
+    ``ouro_reason``'s deployment (8 slots, chunks of 256, 353 pages x 192
+    pools of 16 tokens: weights and pools are the cell's 14.2 GB). For each
+    of ``seeds`` seeds (``seed``, ``seed + 1``, ...: weights and tokens): a
+    320-token prompt in slot 1 (two chunks, the second padded) and a
+    200-token one in slot 3 whose chunk takes slot 1's decode row along,
+    then 4 steps of both, through the PAGED programs (the looped forward,
+    the kernel told which pool) against ``perfbench/reference/ouro.py`` on
+    LOGITS, teacher-forced; every returned exit pass the reference's own
+    (the published threshold: the last pass); every page no table names is
+    filled with NaN first, in all 192 pools, and still is afterwards. The
+    worst readings over the seeds are what ``check_tolerance`` in
+    ``cells/ouro_reason.json`` is twice of. And (``control``) for each of
+    the first three seeds, on ITS weights and check prompt and with the
+    pools dropped, the float8 control goes through the harness's own
+    comparison under that cell's limits, which must refuse every one."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from perfbench.lib import configs, traffic, weights
+    from perfbench.lib import manifest as manifest_lib
+    from perfbench.reference import ouro as ref
+    from ray_tpu.models.decode import (StepRows, init_paged_caches,
+                                       paged_decode_step,
+                                       paged_prefill_into_slot)
+
+    require_chip()
+    manifest = manifest_lib.load()
+    hp = manifest_lib.config(manifest, "ouro_2_6b")
+    cell = manifest_lib.read_json(manifest, "cells", "ouro_reason")
+    cfg = configs.build_program_config(*configs.program_overrides(
+        hp, manifest_lib.read_json_from_bench("families", hp["model_type"])))
+    dep = cell["deployment"]
+    slots, C, T = dep["slots"], dep["prefill_chunk"], dep["page_tokens"]
+    P = dep["arena_len"] // T
+    n = {1: int(cell["check_prompt_tokens"]), 3: 200}
+    new = int(cell["check_new_tokens"])
+    both = np.zeros((slots, P), np.int32)
+    for s in n:
+        both[s] = 1 + s * P + np.arange(P)
+    bad = np.setdiff1d(np.arange(dep["kv_pages"]), np.unique(both))
+    kw = dict(attn="pallas", logits=True, loop_info=True)
+    prefill = jax.jit(functools.partial(paged_prefill_into_slot, cfg, **kw),
+                      donate_argnums=(6,))
+    step = jax.jit(functools.partial(paged_decode_step, cfg, **kw),
+                   donate_argnums=(6,))
+    # a page at a time, in place: one scatter of the 264 pages' 3 GB of NaN
+    # would be made whole first, beside 14 GB
+    spoil = jax.jit(lambda caches: jax.tree.map(lambda pool: jax.lax.fori_loop(
+        0, len(bad), lambda i, pool: jax.lax.dynamic_update_slice(
+            pool, jnp.full((1,) + pool.shape[1:], jnp.nan, pool.dtype),
+            (jnp.asarray(bad)[i], 0, 0, 0)), pool), caches), donate_argnums=0)
+    spoiled = jax.jit(lambda caches: jnp.stack([jax.lax.fori_loop(
+        1, len(bad), lambda i, still: still & jnp.isnan(
+            jax.lax.dynamic_index_in_dim(pool, jnp.asarray(bad)[i])).all(),
+        jnp.asarray(True)) for pool in jax.tree.leaves(caches)]))
+
+    def rel(got, want):
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        return {"max": float(np.abs(got - want).max() / np.abs(want).max()),
+                "rms": float(np.sqrt(((got - want) ** 2).mean()
+                                     / (want ** 2).mean()))}
+
+    def rows_of(live, cursor):
+        active, cursors = np.zeros(slots, np.int32), np.zeros(slots, np.int32)
+        for s in live:
+            active[s], cursors[s] = 1, cursor[s]
+        return StepRows(active, cursors, both, both,
+                        np.zeros(slots, np.float32),
+                        np.zeros(slots, np.uint32))
+
+    readings, controls, t_first = [], [], None
+    for own in range(seed, seed + seeds):
+        t0 = time.perf_counter()
+        params = weights.make_params(cfg, own)
+        tokens = traffic.rng_for(own, 9).integers(
+            1, cfg.vocab_size, size=(2, max(n.values()) + 2 + new))
+        row = {1: 0, 3: 1}
+        caches = spoil(init_paged_caches(cfg, dep["kv_pages"], T, P))
+        got, left = {s: [] for s in n}, {s: [] for s in n}
+        cursor = dict.fromkeys(n, 0)
+        for s, live in ((1, []), (3, [1])):
+            for c0 in range(0, n[s], C):
+                real = min(C, n[s] - c0)
+                padded = np.zeros((1, C), np.int32)
+                padded[0, :real] = tokens[row[s], c0:c0 + real]
+                ids = np.zeros(slots, np.int32)
+                for o in live:
+                    ids[o] = tokens[row[o], cursor[o]]
+                last = c0 + real == n[s]
+                _, caches, told, logits = prefill(
+                    params, padded, np.int32(real), np.int32(c0), both[s],
+                    both[s], caches, ids, np.int32(s if last else -1),
+                    np.float32(0), np.uint32(0), rows_of(live, cursor),
+                    np.int32(s))
+                cursor[s] = c0 + real
+                for o in live:
+                    got[o].append(logits[1 + o])
+                    left[o].append(int(told["exit_pass"][1 + o]))
+                    cursor[o] += 1
+            got[s].append(logits[0])
+            left[s].append(int(told["exit_pass"][0]))
+        for _ in range(new):
+            rows = rows_of(list(n), cursor)
+            fed = np.zeros(slots, np.int32)
+            for s in n:
+                fed[s] = tokens[row[s], cursor[s]]
+            _, caches, told, logits = step(
+                params, fed, rows.active, rows.cursors, both, both, caches,
+                rows.temperature, rows.seeds)
+            for s in n:
+                got[s].append(logits[s])
+                left[s].append(int(told["exit_pass"][s]))
+                cursor[s] += 1
+        clean = bool(np.asarray(spoiled(caches)).all())
+        del caches
+        t_first = t_first or round(time.perf_counter() - t0, 1)
+        want, exits, shares = ref.forward_and_exits(
+            params, jnp.asarray(tokens, jnp.int32), hp)
+        want, exits = np.asarray(want), np.asarray(exits)
+        one = {"seed": own, "poisoned_pages_left_alone": clean}
+        for s in n:
+            mine = np.asarray(jnp.stack(got[s]), np.float32)
+            first = n[s] - 1
+            one[f"slot{s}"] = rel(mine, want[row[s],
+                                             first:first + len(mine)])
+            one[f"slot{s}_finite"] = bool(np.isfinite(mine).all())
+            one[f"slot{s}_exits_are_the_references"] = left[s] == exits[
+                row[s], first:first + len(mine)].tolist()
+        one["largest_share_before_the_last_pass"] = float(
+            np.asarray(shares)[:-1].sum(0).max())
+        readings.append(one)
+        if control and len(controls) < 3:
+            # on THIS seed's weights and check prompt, the pools gone
+            controls.append(float8_control(cfg, hp, params, own, ref, "ouro",
+                                           "ouro_reason"))
+    out = {"readings": readings, "first_seed_s": t_first,
+           "worst": {k: max(r[f"slot{s}"][k] for r in readings for s in n)
+                     for k in ("max", "rms")},
+           "every_exit_the_last_pass": all(
+               r[f"slot{s}_exits_are_the_references"] for r in readings
+               for s in n)}
+    if not all(r["poisoned_pages_left_alone"] and r["slot1_finite"]
+               and r["slot3_finite"] for r in readings):
+        raise RuntimeError(f"ouro: a program read or wrote a page no table "
+                           f"names: {readings}")
+    if control:
+        out["float8_control"] = controls
+        if any(all(c["checks"].values()) for c in controls):
+            raise RuntimeError("ouro: a float8 control passes the cell's "
+                               f"limits: {controls}")
+    return out
+
+
 def ssm_timing(cfg, seed: int, rows: int, calls: int = 20) -> dict:
     """The two state-space kernels ALONE at the cell's shapes, in
     milliseconds a call behind a warm-up: the step over ``rows`` slots'
@@ -2638,6 +2810,15 @@ def nemotron_phase(seed: int) -> None:
     emit("nemotron", seconds=round(time.perf_counter() - t0, 1), **out)
 
 
+def ouro_phase(seed: int) -> None:
+    import ray_tpu
+
+    t0 = time.perf_counter()
+    out = ray_tpu.get(
+        ray_tpu.remote(num_tpus=1)(ouro_task).remote(seed), timeout=2400)
+    emit("ouro", seconds=round(time.perf_counter() - t0, 1), **out)
+
+
 def serve_phase(seed: int) -> None:
     import ray_tpu
     import ray_tpu.serve as serve
@@ -2756,6 +2937,7 @@ def one_chip(seed: int) -> dict:
     glm_phase(seed)
     nemotron_phase(seed)
     deepseek_phase(seed)
+    ouro_phase(seed)
     serve_phase(seed)
     return out["device"]
 

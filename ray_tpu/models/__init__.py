@@ -24,4 +24,6 @@ from ray_tpu.models.presets import (  # noqa: F401
     mellum_debug,
     nemotron_h,
     nemotron_h_debug,
+    ouro,
+    ouro_debug,
 )

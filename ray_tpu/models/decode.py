@@ -19,13 +19,15 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models.transformer import (ATTENTION, INDEXED, INDEXED_LATENT,
-                                        LATENT, LINEAR,
+                                        LATENT, LINEAR, LOOP_PASS,
                                         MAMBA, NONE, OWN_PAGE_TOKENS,
                                         RETENTION,
                                         SLIDING, SPARSE, STATE_KINDS,
                                         STATE_MIXERS,
-                                        TransformerConfig, _gated_out, _mlp,
-                                        _norm, _qkv, _residual, embed,
+                                        TransformerConfig, _after_mixer,
+                                        _gated_out, _mlp,
+                                        _norm, _qkv, _residual, body_params,
+                                        embed, exit_state,
                                         final_hidden, forward, holds_page,
                                         indexed_latent_mix,
                                         indexed_latent_project, indexed_mix,
@@ -46,7 +48,11 @@ from ray_tpu.ops.sparse_attention import check_pool, update_page_means
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class LayerKVCache:
-    """Fixed-capacity cache for one layer. k/v: [B, max_len, Hkv, D]."""
+    """Fixed-capacity cache for one layer. k/v: [B, max_len, Hkv, D] (a
+    looped model's: ``rows`` of them stacked in front, one a (pass, layer),
+    each HEADS-major, [rows, B, Hkv, max_len, D] — the order attention reads
+    a row in, so the loop that carries the stack lays it out once:
+    ``transformer._looped_cached``)."""
 
     k: Any
     v: Any
@@ -54,12 +60,13 @@ class LayerKVCache:
 
     @classmethod
     def zeros(cls, batch: int, max_len: int, kv_heads: int, head_dim: int,
-              dtype=jnp.bfloat16) -> "LayerKVCache":
-        return cls(
-            k=jnp.zeros((batch, max_len, kv_heads, head_dim), dtype),
-            v=jnp.zeros((batch, max_len, kv_heads, head_dim), dtype),
-            length=jnp.zeros((), jnp.int32),
-        )
+              dtype=jnp.bfloat16, rows: Optional[int] = None
+              ) -> "LayerKVCache":
+        shape = (batch, max_len, kv_heads, head_dim)
+        if rows is not None:
+            shape = (rows, batch, kv_heads, max_len, head_dim)
+        return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
+                   length=jnp.zeros((), jnp.int32))
 
     def update(self, k_new, v_new) -> Tuple["LayerKVCache", Any, Any]:
         """Append [B, S, Hkv, D] new keys/values; returns (new_cache, k_all,
@@ -148,7 +155,19 @@ def init_caches(cfg: TransformerConfig, batch: int, max_len: int,
     """A contiguous cache a layer, by the layer's kind (None for a layer
     without a mixer)."""
     dtype = dtype or cfg.dtype
-    zero = jnp.zeros((), jnp.int32)
+    # a cursor a layer, each an array of its own: the caches are DONATED to
+    # the programs that fill them, and no buffer can be given twice
+    zero = lambda: jnp.zeros((), jnp.int32)
+    if cfg.looped:
+        # ONE cache, a (pass, layer) a leading row of k and v: what the
+        # loop over passes and layers carries (``transformer
+        # ._looped_cached``). Whole tiles of positions (16 rows of bf16):
+        # short of them the chip's own layout of the array puts the heads
+        # behind the positions, and the loop lays the stack out anew
+        rows = cfg.loop_passes * cfg.num_layers
+        return [LayerKVCache.zeros(batch, -(-max_len // 16) * 16,
+                                   cfg.kv_heads, cfg.head_dim, dtype,
+                                   rows=rows)]
 
     def one(kind):
         if kind == NONE:
@@ -159,30 +178,30 @@ def init_caches(cfg: TransformerConfig, batch: int, max_len: int,
             return LayerKVCache.zeros(batch, max_len, cfg.kv_heads,
                                       cfg.head_dim, dtype)
         if kind in STATE_KINDS:
-            return init_state(cfg, kind, batch, zero)
+            return init_state(cfg, kind, batch, zero())
         if kind == INDEXED:
             # a pool of its own too, the index keys in it beside K and V
             return dataclasses.replace(IndexedPagedKVCache.zeros(
                 1 + batch * -(-max_len // OWN_PAGE_TOKENS), OWN_PAGE_TOKENS,
                 cfg.kv_heads, cfg.head_dim, cfg.indexer.indexer_head_dim,
-                dtype), length=zero)
+                dtype), length=zero())
         if kind == LATENT:
             # a pool of its own of latent-and-key rows, no K or V
             return dataclasses.replace(LatentPagedKVCache.zeros(
                 1 + batch * -(-max_len // OWN_PAGE_TOKENS), OWN_PAGE_TOKENS,
-                cfg.latent_kv_rank, cfg.latent_rope_dim, dtype), length=zero)
+                cfg.latent_kv_rank, cfg.latent_rope_dim, dtype), length=zero())
         if kind == INDEXED_LATENT:
             # the same, the index keys' rows beside the latents'
             return dataclasses.replace(IndexedLatentPagedKVCache.zeros(
                 1 + batch * -(-max_len // OWN_PAGE_TOKENS), OWN_PAGE_TOKENS,
                 cfg.latent_kv_rank, cfg.latent_rope_dim,
-                cfg.indexer.indexer_head_dim, dtype), length=zero)
+                cfg.indexer.indexer_head_dim, dtype), length=zero())
         # a pool of its own: page 0 the garbage page, then a sequence's
         # pages in order, so its page table is the identity
         return dataclasses.replace(SparsePagedKVCache.zeros(
             1 + batch * sparse_pool_pages(cfg, max_len),
             cfg.sparse.kernel_stride, cfg.kv_heads, cfg.head_dim, dtype),
-            length=zero)
+            length=zero())
 
     return [one(kind) for kind in cfg.kinds]
 
@@ -278,10 +297,11 @@ def init_slot_caches(cfg: TransformerConfig, slots: int, max_len: int,
         raise ValueError(
             f"slot arena max_len ({max_len}) exceeds cfg.max_seq_len "
             f"({cfg.max_seq_len})")
-    if set(cfg.kinds) - {ATTENTION, SLIDING}:
+    if set(cfg.kinds) - {ATTENTION, SLIDING} or cfg.looped:
         raise ValueError(
-            "the slot arena holds keys and values alone (the speculative "
-            f"drafter's cache): no model with layers of {cfg.layer_kinds}")
+            "the slot arena holds keys and values alone, once a layer (the "
+            "speculative drafter's cache): no model with layers of "
+            f"{cfg.layer_kinds} or loop_passes={cfg.loop_passes}")
     dtype = dtype or cfg.dtype
     return [SlotKVCache.zeros(slots, max_len, cfg.kv_heads, cfg.head_dim,
                               dtype) for _ in range(cfg.num_layers)]
@@ -405,6 +425,30 @@ class PagedKVCache:
             k=jnp.zeros((num_pages, page_tokens, kv_heads * head_dim), dtype),
             v=jnp.zeros((num_pages, page_tokens, kv_heads * head_dim), dtype),
         )
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class LoopPagedKVCache:
+    """A LOOPED model's page pools, all of them, as ONE pair: k/v
+    [num_pages, passes * layers, page_tokens, Hkv * D]. A page holds its
+    token span once a (pass, layer) — pool ``t * layers + l`` is what layer
+    l wrote in pass t — under the one page table: page ``p`` is the same
+    tokens in every pool, so the allocator, the prefix cache and whoever
+    copies pages in or out by their ids (``pool[ids]``, as of a pool alone)
+    know nothing of passes. The serving loop carries the pair and writes it
+    in place (``_paged_forward_loop``); a (pass, layer)'s page is as
+    contiguous a run of whole rows as a page of a pool alone."""
+
+    k: Any
+    v: Any
+
+    @classmethod
+    def zeros(cls, num_pages: int, pools: int, page_tokens: int,
+              kv_heads: int, head_dim: int,
+              dtype=jnp.bfloat16) -> "LoopPagedKVCache":
+        shape = (num_pages, pools, page_tokens, kv_heads * head_dim)
+        return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
 
 @jax.tree_util.register_dataclass
@@ -535,7 +579,9 @@ def init_paged_caches(cfg: TransformerConfig, num_pages: int,
     'sliding_attention' layer's pool has a page count of its own,
     ``window_pages`` (its page 0 the garbage page too): its pages go
     through tables of their own (``pool_of``), and how many a slot keeps is
-    the caller's business."""
+    the caller's business. A looped model's pools (a layer's, once a pass)
+    are ONE pair under the one table, a list of one
+    (``LoopPagedKVCache``)."""
     if page_tokens < 1:
         raise ValueError(f"page_tokens must be >= 1, got {page_tokens}")
     if num_pages < 2 and cfg.holds_pages:
@@ -549,6 +595,12 @@ def init_paged_caches(cfg: TransformerConfig, num_pages: int,
         raise ValueError(
             f"pages_per_slot * page_tokens ({pages_per_slot * page_tokens}) "
             f"exceeds cfg.max_seq_len ({cfg.max_seq_len})")
+    if cfg.output_norms and not cfg.looped:
+        # the norms behind the sublayers are in the looped forward's body
+        # (``_after_mixer``) and not in ``_paged_forward_inplace``'s
+        raise ValueError("output_norms without loop_passes > 1: the paged "
+                         "programs norm a sublayer's output in the looped "
+                         "forward alone")
     if SPARSE in cfg.kinds:
         check_pool(cfg.sparse, page_tokens, pages_per_slot)
     if cfg.recurrent and not slots:
@@ -560,6 +612,10 @@ def init_paged_caches(cfg: TransformerConfig, num_pages: int,
                          "init_paged_caches needs window_pages >= 2, got "
                          f"{window_pages}")
     dtype = dtype or cfg.dtype
+    if cfg.looped:
+        return [LoopPagedKVCache.zeros(
+            num_pages, cfg.loop_passes * cfg.num_layers, page_tokens,
+            cfg.kv_heads, cfg.head_dim, dtype)]
 
     def one(kind):
         if kind == NONE:
@@ -674,6 +730,119 @@ def _mix_states(cfg, mix, p, group: _Rows, rows, state, several: bool):
                for n, s in own.items()}
 
 
+def _one_batch(groups: List[_Rows]):
+    """(whether there are SEVERAL groups, ``batch``, ``split``): the groups'
+    rows as the one batch a paged forward takes through a layer's weights,
+    and back."""
+    several = len(groups) > 1
+    lead = (1, -1) if several else groups[0].tokens.shape
+
+    def batch(parts):
+        """The groups' rows as the one batch: a lone group as it stands."""
+        parts = [p.reshape(lead + p.shape[2:]) for p in parts]
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+    ends = list(itertools.accumulate(g.tokens.size for g in groups))
+
+    def split(rows):
+        """The batch's rows group by group, each in its group's shape."""
+        if not several:
+            return [rows]
+        return [rows[:, end - g.tokens.size:end].reshape(
+            g.tokens.shape + rows.shape[2:]) for g, end in zip(groups, ends)]
+
+    return several, batch, split
+
+
+def _embedded(cfg, params, tokens, positions):
+    x = embed(cfg, params, tokens)
+    if cfg.pos == "learned":
+        x = x + params["pos_embed"]["table"].astype(cfg.dtype)[positions]
+    return x
+
+
+def _paged_forward_loop(cfg: TransformerConfig, params, groups: List[_Rows],
+                        caches, impl, sampled):
+    """The serving forward of a LOOPED model (``cfg.loop_passes`` > 1): the
+    program is ONE layer's body under a loop over the pass and the layer,
+    whatever the depth. ``_paged_forward_inplace``'s rule holds inside the
+    body: the groups' rows go through the norms, ``_qkv``, ``wo``, the
+    output norm and the feed-forward as one batch (a fused turn reads the
+    weights once A PASS); their k/v are written through the write tables
+    into pool ``t * L + l`` of the stacked pair (``LoopPagedKVCache``), which
+    is the loop's carry and is written in place; and each group attends
+    THAT pool through its read table, the kernel told which (``ops
+    .paged_attention(pool_index=)``): the keys and values a query of pass t
+    attends are those pass t wrote. The stacked weights are what the loop
+    scans (``body_params``); the final norm closes every pass
+    (``final_hidden``) and its output enters the next.
+
+    ``sampled(x)`` cuts the rows the caller samples or scores out of a
+    pass's state. Returns (their state behind the final norm of EVERY pass,
+    [T, ...] — the caller's gate picks the pass: ``_sampled_logits`` —,
+    caches)."""
+    (pool,) = caches
+    _, batch, split = _one_batch(groups)
+    tokens = batch([g.tokens for g in groups])
+    positions = batch([g.positions for g in groups])
+    x = _embedded(cfg, params, tokens, positions)
+    rope = rope_table(cfg)
+    T = pool.k.shape[2]
+    pages = batch([written_pages(g.write_tables, g.positions, T)
+                   for g in groups])
+    offs = positions % T
+    L = cfg.num_layers
+
+    def layer(carry, xs):
+        x, ck, cv = carry
+        p, which = xs
+        q, k, v = _qkv(cfg, p["attn"], _norm(cfg, p["ln1"], x), rope,
+                       positions)
+        ck = write_pages(ck, k, pages, offs, which)
+        cv = write_pages(cv, v, pages, offs, which)
+        o = batch([paged_attention(q_g, ck, cv, g.read_tables, g.lengths,
+                                   impl=impl, pool_index=which)
+                   for g, q_g in zip(groups, split(q))])
+        a = jnp.einsum("bshk,hkd->bsd", o, p["attn"]["wo"].astype(cfg.dtype))
+        return (_after_mixer(cfg, p, x, a)[0], ck, cv), None
+
+    def one_pass(carry, t):
+        with jax.named_scope(LOOP_PASS):
+            (x, ck, cv), _ = lax.scan(
+                layer, carry, (body_params(cfg, params),
+                               t * L + jnp.arange(L, dtype=jnp.int32)))
+            x = final_hidden(cfg, params, x)
+        return (x, ck, cv), sampled(x)
+
+    (_, ck, cv), states = lax.scan(
+        one_pass, (x, pool.k, pool.v),
+        jnp.arange(cfg.loop_passes, dtype=jnp.int32))
+    return states, [LoopPagedKVCache(k=ck, v=cv)]
+
+
+def _sampled_logits(cfg: TransformerConfig, params, groups: List[_Rows],
+                    caches, impl, rows, live, taps):
+    """The paged forward and the head of a program that samples: ``rows(
+    hidden)`` cuts the rows it samples [B, S, d] out of the forward's, and
+    ``live()`` [B * S] says whose sample somebody takes (asked for by a
+    looped model alone). Returns (their logits [B, S, vocab], caches, what
+    the program can tell beside ids and caches):
+    an expert model's counts and routes (``_paged_forward_inplace``), and a
+    LOOPED model's ``{"exit_pass": [B * S] int32}`` — the logits are of the
+    pass each row's exit gate picks (``transformer.exit_state``, over every
+    pass's state of the sampled rows), and that pass is told, 0 for a row
+    that is not ``live``."""
+    if not cfg.looped:
+        hidden, caches, moe = _paged_forward_inplace(cfg, params, groups,
+                                                     caches, impl, taps=taps)
+        return _head(cfg, params, rows(hidden)), caches, moe
+    states, caches = _paged_forward_loop(cfg, params, groups, caches, impl,
+                                         rows)
+    x, exits = exit_state(cfg, params, states)
+    return project(cfg, params, x), caches, {
+        "exit_pass": jnp.where(live(), exits.reshape(-1), 0)}
+
+
 def _paged_forward_inplace(cfg: TransformerConfig, params,
                            groups: List[_Rows], caches, impl, *, taps=None):
     """The serving forward: one pass over the rows of ``groups``. ONE RULE
@@ -722,29 +891,11 @@ def _paged_forward_inplace(cfg: TransformerConfig, params,
     nothing) — the rows each layer's experts received (they sum to valid
     rows x k a layer: no row is dropped; several groups: [L, groups, E], the
     rows each GROUP sent them) and the experts each row chose."""
-    several = len(groups) > 1
-    lead = (1, -1) if several else groups[0].tokens.shape
-
-    def batch(parts):
-        """The groups' rows as the one batch: a lone group as it stands."""
-        parts = [p.reshape(lead + p.shape[2:]) for p in parts]
-        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
-
-    ends = list(itertools.accumulate(g.tokens.size for g in groups))
-
-    def split(rows):
-        """The batch's rows group by group, each in its group's shape."""
-        if not several:
-            return [rows]
-        return [rows[:, end - g.tokens.size:end].reshape(
-            g.tokens.shape + rows.shape[2:]) for g, end in zip(groups, ends)]
-
+    several, batch, split = _one_batch(groups)
     tokens = batch([g.tokens for g in groups])
     positions = batch([g.positions for g in groups])
     valid = batch([g.valid for g in groups])
-    x = embed(cfg, params, tokens)
-    if cfg.pos == "learned":
-        x = x + params["pos_embed"]["table"].astype(cfg.dtype)[positions]
+    x = _embedded(cfg, params, tokens, positions)
     rope = rope_table(cfg)
     if cfg.holds_pages:
         T = next(c.k.shape[1] for c, kind in zip(caches, cfg.kinds)
@@ -877,10 +1028,12 @@ def _head(cfg: TransformerConfig, params, hidden):
     return project(cfg, params, final_hidden(cfg, params, hidden))
 
 
-def _paged_outputs(first, caches, moe, moe_info: bool, logits, taps=None,
+def _paged_outputs(first, caches, told, logits, taps=None,
                    groups: int = 1):
-    """What a paged program returns: ``(first, caches)``, with ``moe_info``
-    the expert layers' counts and routes next, then the ``logits`` the
+    """What a paged program returns: ``(first, caches)``, then what it was
+    asked to tell beside them (``told``: with ``moe_info`` the expert
+    layers' counts and routes, with ``loop_info`` the sampled rows' exit
+    passes; None: nothing), then the ``logits`` the
     ids in ``first`` were sampled from where the caller asked for them
     (else None), and last what the 'minicpm4' layers chose (``taps``,
     stacked; ``groups`` > 1: a tuple, a stack a group of rows) where it
@@ -889,15 +1042,19 @@ def _paged_outputs(first, caches, moe, moe_info: bool, logits, taps=None,
     if taps is not None:
         taps = (jnp.stack(taps) if groups == 1 else tuple(
             jnp.stack(taps[g::groups]) for g in range(groups)))
-    return ((first, caches) + ((moe,) if moe_info else ())
+    return ((first, caches) + (() if told is None else (told,))
             + (() if logits is None else (logits,))
             + (() if taps is None else (taps,)))
 
 
-def _check_moe_info(cfg: TransformerConfig, moe_info: bool):
+def _check_info(cfg: TransformerConfig, moe_info: bool,
+                loop_info: bool = False):
     if moe_info and cfg.mlp != "moe":
         raise ValueError("moe_info needs mlp='moe': a dense model has no "
                          "expert layer to count")
+    if loop_info and not cfg.looped:
+        raise ValueError("loop_info needs loop_passes > 1: a model that goes "
+                         "through its stack once leaves at no pass")
 
 
 def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
@@ -906,7 +1063,7 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
                             temperature, seed, step: Optional[StepRows],
                             state_slot=None, *, attn: str,
                             moe_info: bool = False, logits: bool = False,
-                            selected: bool = False):
+                            selected: bool = False, loop_info: bool = False):
     """One prefill chunk into ONE slot, through its page table, and with it
     the decode step of the rows that are live (``step``): a turn that holds
     a chunk reads the weights once. tokens: [1, C] — the next C prompt
@@ -976,8 +1133,13 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
     compare them with an oracle; the scheduler never asks); with
     ``selected`` the blocks the 'minicpm4' layers chose, bool [layers of
     the kind, 1, C, Hkv, NB], last (with ``step`` a pair: the chunk's, and
-    the step rows' [layers of the kind, slots, 1, Hkv, NB])."""
-    _check_moe_info(cfg, moe_info)
+    the step rows' [layers of the kind, slots, 1, Hkv, NB]); with
+    ``loop_info`` (``loop_passes`` > 1) the third value is ``{"exit_pass":
+    [1 + slots] int32}`` (without ``step`` [1]): the pass each SAMPLED row
+    left at — the chunk's last real row where the chunk is the prompt's
+    last (``slot`` >= 0), then the step's live rows — and 0 for a row whose
+    sample nobody takes."""
+    _check_info(cfg, moe_info, loop_info)
     if cfg.recurrent and state_slot is None:
         raise ValueError(f"a model with {_state_kinds(cfg)} layers needs "
                          "state_slot: the slot whose states the chunk "
@@ -1002,18 +1164,23 @@ def paged_prefill_into_slot(cfg: TransformerConfig, params, tokens, real_len,
         sample = tuple(jnp.concatenate(pair) for pair in zip(sample, (
             jnp.where(live, step.temperature, 0.0), step.seeds,
             step.cursors + 1)))
-    hidden, new_caches, moe = _paged_forward_inplace(
-        cfg, params, groups, caches, attn, taps=taps)
     # the rows that are sampled: the chunk's last real one, then the step's
-    last = lax.dynamic_slice_in_dim(hidden, real_len - 1, 1, axis=1)
-    sampled_logits = _head(cfg, params, jnp.concatenate(
-        [last, hidden[:, C:]], axis=1))[0]
+    rows = lambda hidden: jnp.concatenate(
+        [lax.dynamic_slice_in_dim(hidden, real_len - 1, 1, axis=1),
+         hidden[:, C:]], axis=1)
+    # whose sample somebody takes: the chunk's, if it is its prompt's last
+    taken = lambda: jnp.concatenate(
+        [jnp.reshape(slot >= 0, (1,))] + ([] if step is None else [live]))
+    sampled_logits, new_caches, told = _sampled_logits(
+        cfg, params, groups, caches, attn, rows, taken, taps)
+    sampled_logits = sampled_logits[0]
     sampled = sample_token(sampled_logits, *sample)
     if step is not None:
         ids = jnp.where(live, sampled[1:], ids)
     ids = jnp.where(jnp.arange(ids.shape[0]) == slot, sampled[0], ids)
     shown = sampled_logits[0] if step is None else sampled_logits
-    return _paged_outputs(ids, new_caches, moe, moe_info,
+    return _paged_outputs(ids, new_caches,
+                          told if moe_info or loop_info else None,
                           shown if logits else None, taps, len(groups))
 
 
@@ -1021,7 +1188,8 @@ def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
                       cursors, read_tables, write_tables,
                       caches: List[Any], temperature, seeds, *,
                       attn: str, moe_info: bool = False,
-                      logits: bool = False, selected: bool = False):
+                      logits: bool = False, selected: bool = False,
+                      loop_info: bool = False):
     """One fixed-shape decode step over the whole arena, through page
     tables. tokens/active/cursors: [slots] int32; read_tables/write_tables:
     [slots, P] int32. Row s's token is written at ``pool[page, offset]`` of
@@ -1050,21 +1218,24 @@ def paged_decode_step(cfg: TransformerConfig, params, tokens, active,
     the ids were sampled from come next (tests compare them with an
     oracle; the scheduler never asks); with ``selected`` the blocks the
     'minicpm4' layers chose, bool [layers of the kind, slots, 1, Hkv, NB],
-    last."""
-    _check_moe_info(cfg, moe_info)
+    last; with ``loop_info`` (``loop_passes`` > 1) the third value is
+    ``{"exit_pass": [slots] int32}``, the pass each active row left at (0
+    for a row that is not active)."""
+    _check_info(cfg, moe_info, loop_info)
     taps = [] if selected else None
-    hidden, new_caches, moe = _paged_forward_inplace(
-        cfg, params, [_Rows(tokens[:, None], cursors[:, None],
-                            jnp.where(active > 0, cursors, -1), read_tables,
-                            write_tables, active[:, None] > 0,
-                            active=active)],
-        caches, attn, taps=taps)
-    all_logits = _head(cfg, params, hidden)[:, 0]
+    groups = [_Rows(tokens[:, None], cursors[:, None],
+                    jnp.where(active > 0, cursors, -1), read_tables,
+                    write_tables, active[:, None] > 0, active=active)]
+    all_logits, new_caches, told = _sampled_logits(
+        cfg, params, groups, caches, attn, lambda hidden: hidden,
+        lambda: active > 0, taps)
+    all_logits = all_logits[:, 0]
     sampled = sample_token(all_logits,
                            jnp.where(active > 0, temperature, 0.0), seeds,
                            cursors + 1)
     ids = jnp.where(active > 0, sampled, tokens)
-    return _paged_outputs(ids, new_caches, moe, moe_info,
+    return _paged_outputs(ids, new_caches,
+                          told if moe_info or loop_info else None,
                           all_logits if logits else None, taps)
 
 
@@ -1098,12 +1269,17 @@ def paged_verify_step(cfg: TransformerConfig, params, tokens, active,
     Returns (logits [slots, K, vocab], caches); with ``moe_info``
     (mlp='moe') a third value, the expert layers' ``{"counts": [L, E],
     "routes": [L, slots, K, k]}`` over the used rows."""
-    _check_moe_info(cfg, moe_info)
+    _check_info(cfg, moe_info)
     if cfg.recurrent:
         raise ValueError(
             f"paged_verify_step cannot run a model with {_state_kinds(cfg)} "
             "layers: a rejected draft would have to rewind their states, "
             "and no snapshot is kept")
+    if cfg.looped:
+        raise ValueError(
+            "paged_verify_step cannot run a model with loop_passes > 1: no "
+            "drafter proposes for one (its slot arena holds one (K, V) a "
+            "layer)")
     K = tokens.shape[1]
     steps = jnp.arange(K, dtype=jnp.int32)[None]
     hidden, new_caches, moe = _paged_forward_inplace(
@@ -1111,8 +1287,8 @@ def paged_verify_step(cfg: TransformerConfig, params, tokens, active,
                             jnp.where(active > 0, cursors, -K), read_tables,
                             write_tables, steps < active[:, None])],
         caches, attn)
-    return _paged_outputs(_head(cfg, params, hidden), new_caches, moe,
-                          moe_info, None)
+    return _paged_outputs(_head(cfg, params, hidden), new_caches,
+                          moe if moe_info else None, None)
 
 
 @partial(jax.jit, static_argnums=(0, 4, 5, 6))

@@ -244,6 +244,39 @@ def deepseek_v32_debug(**overrides) -> TransformerConfig:
     return TransformerConfig(**kw)
 
 
+def ouro(**overrides) -> TransformerConfig:
+    """Ouro-2.6B (ByteDance/Ouro-2.6B, ``model_type: ouro``; "Scaling Latent
+    Reasoning via Looped Language Models", arXiv:2510.25741) at its
+    published sizes: 48 Llama-shaped layers (16 heads of 128, no grouping,
+    SwiGLU of 5632) that every token goes through ``loop_passes`` = 4 times
+    with the same weights, a norm on each sublayer's OUTPUT beside the two
+    on their inputs, the final norm behind every pass and a learned gate
+    whose exit shares pick the pass the head projects (the published
+    threshold 1: the last unless a gate saturates)."""
+    kw = dict(
+        vocab_size=49152, num_layers=48, embed_dim=2048, num_heads=16,
+        num_kv_heads=16, head_dim=128, mlp_dim=5632, max_seq_len=65536,
+        loop_passes=4, exit_threshold=1.0, output_norms=True,
+        norm="rmsnorm", pos="rope", mlp="swiglu", rope_theta=1000000.0,
+        norm_eps=1e-6, tie_embeddings=False,
+    )
+    kw.update(overrides)
+    return TransformerConfig(**kw)
+
+
+def ouro_debug(**overrides) -> TransformerConfig:
+    """Tiny Ouro-shaped config (``ouro``'s mechanisms, for tests): three
+    layers gone through four times, norms behind the sublayers, the exit
+    gate."""
+    kw = dict(
+        vocab_size=256, num_layers=3, embed_dim=64, num_heads=4,
+        num_kv_heads=4, head_dim=16, mlp_dim=128, max_seq_len=128,
+        dtype=jnp.float32,
+    )
+    kw.update(overrides)
+    return ouro(**kw)
+
+
 def nemotron_h(**overrides) -> TransformerConfig:
     """NVIDIA-Nemotron-3-Nano-30B-A3B (``model_type: nemotron_h``) at its
     published sizes: 52 layers that are ONE sublayer each, as its
@@ -342,6 +375,11 @@ def _check_pipeline_cfg(cfg) -> None:
             "router's load-balancing aux loss would need summing across "
             "stages every microbatch. Use a dense mlp ('gelu'/'swiglu'), "
             "or train MoE configs with the SPMD expert-parallel path")
+    if cfg.looped:
+        raise ValueError(
+            "pipeline_stage_defs: cfg.loop_passes > 1 is unsupported — "
+            "every pass would cross every stage again, and the last "
+            "stage's final norm and exit gate close each of them")
     if cfg.layer_kinds and set(cfg.layer_kinds) != {"attention"}:
         raise ValueError(
             "pipeline_stage_defs: cfg.layer_kinds other than 'attention' "

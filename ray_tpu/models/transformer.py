@@ -101,6 +101,16 @@ Config switches:
   * scale_emb, scale_depth (over scale_depth_layers), dim_model_base: the
     MiniCPM scales of the embedding, of every residual branch and of the
     hidden state before the output head.
+  * loop_passes (a source's ``total_ut_steps``): the whole stack applied so
+    many times a token, the SAME weights every pass. The final norm closes
+    every pass and its output enters the next; a learned gate reads each
+    pass's normed state and ``exit_threshold`` picks whose state the head
+    projects (``exit_shares``, ``exit_pass``) — every pass is computed for
+    every token whatever the gate says. The keys and values a query of pass
+    t attends are those pass t wrote: a cache a (pass, layer). 1: the stack
+    once, no gate, today's model. output_norms: a norm on the mixer's and on
+    the feed-forward's OUTPUT before the residual add, beside the two on
+    their inputs (``ln1_out``, ``ln2_out``).
 """
 
 from __future__ import annotations
@@ -269,6 +279,14 @@ class TransformerConfig:
     scale_depth: float = 0.0
     scale_depth_layers: int = 0
     dim_model_base: int = 0
+    # the stack applied this many times a token (a source's total_ut_steps),
+    # the final norm behind every pass; 1: once, and no exit gate
+    loop_passes: int = 1
+    # a token leaves at the first pass whose exit shares add up to this (a
+    # source's early_exit_threshold), else at the last: ``exit_pass``
+    exit_threshold: float = 1.0
+    # a norm on each sublayer's OUTPUT before the residual add
+    output_norms: bool = False
     max_seq_len: int = 2048
     norm: str = "rmsnorm"                     # 'rmsnorm' | 'layernorm'
     pos: str = "rope"                         # 'rope' | 'learned' | 'none'
@@ -379,6 +397,15 @@ class TransformerConfig:
             raise ValueError(
                 "moe_held_first / moe_held_count name a run of the "
                 f"{self.moe_num_experts} experts, got {held}")
+        if self.loop_passes < 1 or self.loop_passes > 1 and (
+                set(self.kinds) != {ATTENTION} or self.mlp == "moe"
+                or pattern is not None or not self.scan_layers):
+            raise ValueError(
+                "loop_passes counts the times the stack is applied: >= 1, "
+                "and more than once only over stacked layers (scan_layers) "
+                "that are all 'attention' over a dense feed-forward, got "
+                f"{self.loop_passes} over {sorted(set(self.kinds))}, "
+                f"mlp={self.mlp!r}")
         if self.moe_dense_layers and not (
                 self.mlp == "moe"
                 and 0 < self.moe_dense_layers < self.num_layers
@@ -430,6 +457,11 @@ class TransformerConfig:
         return next(p for p in range(1, len(kinds) + 1)
                     if len(kinds) % p == 0
                     and all(k == kinds[i % p] for i, k in enumerate(kinds)))
+
+    @property
+    def looped(self) -> bool:
+        """The stack is applied more than once a token."""
+        return self.loop_passes > 1
 
     @property
     def recurrent(self) -> bool:
@@ -548,6 +580,20 @@ def _block_params(cfg: TransformerConfig, key, kind: str = ATTENTION,
         p["ln1"] = _norm_params(cfg, d)
     if ff != NONE:
         p["ln2"] = _norm_params(cfg, d)
+    for name in _output_norms(cfg, kind, ff):
+        # the norm SETS its branch's size, so its seeded scale says how far
+        # a pass moves the stream. At 1 the stream is 2 L unit vectors over
+        # an input of 1; at (2 L)^-1/2 they add up to the residual's own
+        # size: a seeded stack gone through several times then amplifies
+        # rounding for some seeds' weights (bf16 against float32 at the
+        # published sizes: 1.1% on most sequences, 7-27% on one in six,
+        # where weights rounded to float8 read 13-157%: no limit lies
+        # between). At 0.3 of it a pass moves the stream by a third and the
+        # passes contract: bf16 1.5-2.7% on every sequence read, float8
+        # 16-69% (PERF.md 6, PR 65)
+        p[name] = jax.tree.map(
+            lambda a: a * 0.3 * (2 * cfg.num_layers) ** -0.5,
+            _norm_params(cfg, d))
     if ff == "moe":
         from ray_tpu.ops.moe import SIGMOID, init_moe_params
 
@@ -572,6 +618,15 @@ def _block_params(cfg: TransformerConfig, key, kind: str = ATTENTION,
             "b_out": jnp.zeros((d,), cfg.param_dtype),
         }
     return p
+
+
+def _output_norms(cfg: TransformerConfig, kind: str, ff: str):
+    """The names of a layer's norms behind its sublayers (``output_norms``):
+    one for what it has of a mixer and a feed-forward."""
+    if not cfg.output_norms:
+        return ()
+    return (("ln1_out",) if kind != NONE else ()) + (
+        ("ln2_out",) if ff != NONE else ())
 
 
 def _mixer_params(cfg: TransformerConfig, kind: str, ks, init, out_init):
@@ -692,6 +747,9 @@ def _norm_params(cfg: TransformerConfig, dim: int):
             "bias": jnp.zeros((dim,), cfg.param_dtype)}
 
 
+_EXIT_GATE_KEY = 0x6174  # folded into the model's key for the exit gate
+
+
 def init_params(cfg: TransformerConfig, key) -> Dict[str, Any]:
     keys = jax.random.split(key, cfg.num_layers + 3)
     init = jax.nn.initializers.normal(0.02, cfg.param_dtype)
@@ -705,6 +763,23 @@ def init_params(cfg: TransformerConfig, key) -> Dict[str, Any]:
     if not cfg.tie_embeddings:
         params["lm_head"] = {
             "kernel": init(keys[2], (cfg.embed_dim, cfg.vocab_size))}
+    if cfg.looped:
+        # d + 1 values from a key of their own: no other weight moves
+        params["exit_gate"] = {
+            "w": init(jax.random.fold_in(key, _EXIT_GATE_KEY),
+                      (cfg.embed_dim,)),
+            "b": jnp.zeros((), cfg.param_dtype)}
+    if cfg.looped:
+        # one kind over one feed-forward (``__post_init__``): ONE layer's
+        # draws under ``vmap`` over the layers' keys. Drawn a layer at a
+        # time the published 48 layers are 48 copies of the draws in the
+        # program, two minutes of compile for the chip — more than a serving
+        # replica is given to start (``serve/_private/controller.py``:
+        # INIT_TIMEOUT_S), so a replica with a cold compile cache is
+        # replaced for ever (PERF.md 6, PR 65)
+        params["blocks"] = jax.vmap(lambda k: _block_params(
+            cfg, k, cfg.kinds[0], cfg.mlp_of(0)))(keys[3:])
+        return params
     blocks = [_block_params(cfg, keys[3 + i], kind, cfg.mlp_of(i))
               for i, kind in enumerate(cfg.kinds)]
     stack = lambda layers: jax.tree.map(
@@ -804,6 +879,8 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
             block.update(attn=mixer_axes(kind), ln1=norm_axes())
         if ff != NONE:
             block["ln2"] = norm_axes()
+        for name in _output_norms(cfg, kind, ff):
+            block[name] = norm_axes()
         if ff == "moe":
             from ray_tpu.ops.moe import SIGMOID, moe_logical_axes
 
@@ -844,6 +921,8 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
         axes["pos_embed"] = {"table": (None, "embed")}
     if not cfg.tie_embeddings:
         axes["lm_head"] = {"kernel": ("embed", "vocab")}
+    if cfg.looped:
+        axes["exit_gate"] = {"w": ("embed_notp",), "b": ()}
     return axes
 
 
@@ -1221,11 +1300,14 @@ def written_pages(write_tables, positions, page_tokens: int):
         axis=1)
 
 
-def write_pages(pool, rows, pages, offs):
+def write_pages(pool, rows, pages, offs, which=None):
     """rows [B, S, Hkv, D] into pool [N, T, Hkv * D] at (pages, offs) [B,
-    S]."""
-    return pool.at[pages, offs].set(
-        rows.reshape(*rows.shape[:2], -1).astype(pool.dtype))
+    S]; with ``which`` (a scalar) into pool ``which`` of a page's stack,
+    [N, pools, T, Hkv * D]."""
+    rows = rows.reshape(*rows.shape[:2], -1).astype(pool.dtype)
+    if which is None:
+        return pool.at[pages, offs].set(rows)
+    return pool.at[pages, which, offs].set(rows)
 
 
 def sparse_mix(cfg, q, pools, read_tables, positions, lengths, *, impl: str):
@@ -1683,14 +1765,21 @@ def _after_mixer(cfg, p, x, a, new_cache=None, mlp=None, ff=None):
     layer has none): ``_block``'s results. A layer without a feed-forward
     (``ff`` is ``NONE``) ends behind its mixer."""
     if a is not None:
-        x = _residual(cfg, x, a)
+        x = _residual(cfg, x, output_norm(cfg, p, "ln1_out", a))
     if ff == NONE:
         return x, new_cache, 0.0, None
     mlp_p, layer = mlp or (p["mlp"], None)
     m, aux, moe = _mlp(cfg, mlp_p, _norm(cfg, p["ln2"], x), layer=layer,
                        ff=ff)
-    x = _residual(cfg, x, m)
+    x = _residual(cfg, x, output_norm(cfg, p, "ln2_out", m))
     return x, new_cache, aux, moe
+
+
+def output_norm(cfg, p, name: str, y):
+    """A sublayer's output ``y`` through the layer's norm ``name`` where the
+    block has norms behind its sublayers (``cfg.output_norms``), else as it
+    is."""
+    return _norm(cfg, p[name], y) if cfg.output_norms else y
 
 
 # the matrices of a block's mixer and mlp: what their products cast to
@@ -1819,10 +1908,84 @@ def rope_table(cfg):
     return rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
 
 
+# what a trace calls a pass's body and the gate that reads the passes
+LOOP_PASS, LOOP_EXIT_GATE = "loop_pass", "loop_exit_gate"
+
+
+def exit_shares(cfg, params, states):
+    """states [T, ..., d], each pass's state behind the final norm -> p [T,
+    ...] float32, the share of a token that leaves at each pass: the gate
+    ``g_t = sigmoid(h_t . w + b)``, ``p_t = g_t prod_{j<t} (1 - g_j)`` for
+    t < T and ``p_T`` the rest."""
+    with jax.named_scope(LOOP_EXIT_GATE):
+        gate = params["exit_gate"]
+        g = jax.nn.sigmoid(jnp.einsum(
+            "t...d,d->t...", states.astype(jnp.float32),
+            gate["w"].astype(jnp.float32)) + gate["b"].astype(jnp.float32))
+        stayed = jnp.cumprod(1.0 - g, axis=0)  # prod_{j<=t} (1 - g_j)
+        before = jnp.concatenate([jnp.ones_like(stayed[:1]), stayed[:-1]])
+        return jnp.concatenate([(g * before)[:-1], before[-1:]])
+
+
+def exit_pass(cfg, shares):
+    """shares [T, ...] -> the pass a token leaves at, int32 [...] counted
+    from 1: the first t whose ``p_1 + ... + p_t`` reaches
+    ``cfg.exit_threshold``, else the last (the sums only grow, so that is
+    one more than the passes before the last that fall short)."""
+    short = jnp.cumsum(shares[:-1], axis=0) < cfg.exit_threshold
+    return 1 + jnp.sum(short, axis=0, dtype=jnp.int32)
+
+
+def exit_state(cfg, params, states):
+    """(the state [..., d] the head projects, the pass [...] it is of) out
+    of every pass's state [T, ..., d]."""
+    exits = exit_pass(cfg, exit_shares(cfg, params, states))
+    picked = jnp.take_along_axis(states, (exits - 1)[None, ..., None], axis=0)
+    return picked[0], exits
+
+
+def _looped_cached(cfg, params, x, rope, positions, caches):
+    """A looped stack over its CONTIGUOUS cache (``decode.init_caches``: ONE
+    cache whose k and v hold a (pass, layer) a leading row, heads-major,
+    [T * L, B, Hkv, max_len, D]): the layer scan inside a scan over passes,
+    the cache the loops' CARRY as the paged loop carries its pool — a
+    (pass, layer)'s row is cut out for its block and written back where it
+    lies, so the program holds the cache once (as xs and ys of the scans it
+    is laid out anew twice; positions-major, once more in the order
+    attention reads it). Returns (every pass's state behind the final norm
+    [T, B, S, d], the caches)."""
+    (cache,) = caches
+    L = cfg.num_layers
+    swap = lambda a: jnp.swapaxes(a, 1, 2)  # [B, Hkv, len, D] <-> the block's
+
+    def layer(carry, xs):
+        x, k, v = carry
+        p, row = xs
+        x, new, _, _ = _block(
+            cfg, p, x, rope, positions, None,
+            dataclasses.replace(cache, k=swap(k[row]), v=swap(v[row])))
+        k, v = k.at[row].set(swap(new.k)), v.at[row].set(swap(new.v))
+        return (x, k, v), None
+
+    def one_pass(carry, t):
+        with jax.named_scope(LOOP_PASS):
+            (x, k, v), _ = jax.lax.scan(
+                layer, carry, (body_params(cfg, params),
+                               t * L + jnp.arange(L)))
+            x = final_hidden(cfg, params, x)
+        return (x, k, v), x
+
+    (_, k, v), states = jax.lax.scan(one_pass, (x, cache.k, cache.v),
+                                     jnp.arange(cfg.loop_passes))
+    return states, [dataclasses.replace(cache, k=k, v=v,
+                                        length=cache.length + x.shape[1])]
+
+
 def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
             sp_axis: Optional[str] = None, kv_caches=None,
             return_aux: bool = False, return_hidden: bool = False,
-            return_routes: bool = False, return_selected: bool = False):
+            return_routes: bool = False, return_selected: bool = False,
+            return_exit_pass: bool = False):
     """tokens [B, S] int32 -> logits [B, S, vocab].
 
     return_hidden: skip the vocab projection and return the post-final-norm
@@ -1839,6 +2002,10 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
     S, Hkv, NB], or the tokens every query of every 'indexed_attention' or
     'indexed_latent_attention' layer attended, bool [layers of the kind, B,
     S, context], for the same reason.
+    return_exit_pass (debug, ``loop_passes`` > 1 without kv_caches): also
+    return the pass each position left at, int32 [B, S] counted from 1 —
+    the threshold is a step, so a comparison with another implementation
+    has to be made on the same exits.
 
     sp_axis: when running inside shard_map with sequence sharded over that
     axis, attention goes through the ring kernel and `positions` must be the
@@ -1872,11 +2039,18 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
         raise ValueError(
             "return_selected needs a 'minicpm4', an 'indexed_attention' or "
             "an 'indexed_latent_attention' layer and no kv_caches")
+    if return_exit_pass and (not cfg.looped or kv_caches is not None):
+        raise ValueError("return_exit_pass needs loop_passes > 1 and no "
+                         "kv_caches")
     new_caches = None
     aux_total = 0.0
     routes = None
+    states = None  # a looped stack: each pass's state behind the final norm
     taps = [] if return_selected else None
-    if cfg.scan_layers and kv_caches is None and not return_selected:
+    if cfg.looped and kv_caches is not None:
+        states, new_caches = _looped_cached(cfg, params, x, rope, positions,
+                                            kv_caches)
+    elif cfg.scan_layers and kv_caches is None and not return_selected:
         period, lead = cfg.period, cfg.lead_layers
         stream_fn = lambda i: remat(functools.partial(
             _block_streams, kind=kinds[i], ff=cfg.mlp_of(i)))
@@ -1885,6 +2059,7 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
         # every chip's rows (``streams``; positions a row would have to be
         # halved with it: none does)
         split = (streams(cfg, x.shape[0]) == 2 and not return_routes
+                 and not cfg.looped
                  and (positions is None or positions.ndim == 1))
         carried = (tuple(_residual_layout(h) for h in _halves(x))
                    if split else (x,))
@@ -1910,8 +2085,20 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
                     chosen.append(outs[0][3]["routes"])
             return (tuple(_residual_layout(h) for h in hs), aux_acc), (
                 jnp.stack(chosen) if return_routes else None)
-        (carried, aux_total), routes = jax.lax.scan(
-            body, (carried, 0.0), body_params(cfg, params))
+        if cfg.looped:
+            # the scan of the scan: the same stacked weights every pass, so
+            # their gradients add up over the passes by construction
+            def one_pass(carry, _):
+                with jax.named_scope(LOOP_PASS):
+                    ((x,), aux), _ = jax.lax.scan(
+                        body, carry, body_params(cfg, params))
+                    x = final_hidden(cfg, params, x)
+                return ((x,), aux), x
+            (carried, aux_total), states = jax.lax.scan(
+                one_pass, (carried, 0.0), None, length=cfg.loop_passes)
+        else:
+            (carried, aux_total), routes = jax.lax.scan(
+                body, (carried, 0.0), body_params(cfg, params))
         x = _residual_layout(_whole(carried)) if split else carried[0]
         if return_routes:  # [steps, layers a step, ...] -> a layer a row
             routes = routes.reshape(-1, *routes.shape[2:])
@@ -1937,12 +2124,17 @@ def forward(cfg: TransformerConfig, params, tokens, *, positions=None,
         if return_routes:
             routes = jnp.stack(per_layer)
 
-    x = final_hidden(cfg, params, x)
+    if cfg.looped:  # the final norm closed every pass
+        x, exits = exit_state(cfg, params, states)
+    else:
+        x = final_hidden(cfg, params, x)
     if return_hidden:
         return x, (new_caches if kv_caches is not None else aux_total)
     logits = project(cfg, params, x)
     if kv_caches is not None:
         return logits, new_caches
+    if return_exit_pass:
+        return logits, exits
     if return_routes:
         return logits, routes
     if return_selected:
@@ -1980,6 +2172,11 @@ def tp_block_shard_spec(cfg: TransformerConfig) -> Dict[str, Dict[str, int]]:
         raise ValueError(
             "tensor parallelism knows plain attention layers alone, not "
             f"cfg.layer_kinds={cfg.layer_kinds}")
+    if cfg.looped or cfg.output_norms:
+        raise ValueError(
+            "tensor parallelism knows the stack applied once, a norm before "
+            "each sublayer and none behind it, not cfg.loop_passes="
+            f"{cfg.loop_passes}, cfg.output_norms={cfg.output_norms}")
     spec: Dict[str, Dict[str, int]] = {
         "attn": {"wq": 1, "wk": 1, "wv": 1,   # (d, heads, hd) — heads
                  "wo": 0},                     # (heads, hd, d) — heads

@@ -72,6 +72,15 @@ block that holds a position it may attend (its own, if no other) rescales
 that by exp(-1e30 - m) = 0 exactly, as the online softmax does for any
 stale maximum. Without a window the traced program is what it was.
 
+A STACK of pools (``pool_index=``, a traced scalar; a looped model's serving
+forward, whose body is one layer under a loop): the pools are ``[N, pools, T,
+Hkv * D]``, a page holding its token span once a pool under the ONE table,
+and the call reads pool ``pool_index`` of every page where it lies — the
+kernel takes the index by scalar prefetch and its page copy's source is
+``pool.at[page, index]``, as contiguous a run of whole rows as a page of a
+pool alone; the reference slices the pool out. Without it the traced program
+is what it was.
+
 Decode is the K=1 case; the fixed-K verify window and the prefill chunk
 share the same kernel — each query row reduces over blocks in ascending
 order with a full-width mask, so per-row reduction order matches K
@@ -137,11 +146,13 @@ def resolve_impl(cfg, impl: Optional[str] = None) -> str:
 def paged_attention(q, k_pool, v_pool, tables, lengths, *,
                     sm_scale: Optional[float] = None, impl: str = "reference",
                     name: str = "paged_attention",
-                    window: Optional[int] = None):
+                    window: Optional[int] = None, pool_index=None):
     """Attention for q at positions [lengths[s], lengths[s] + K) of each slot.
 
     q: [S, K, H, D] queries (K = 1 decode, K > 1 verify/prefill window).
-    k_pool/v_pool: [N, T, Hkv * D] page pools (page 0 = garbage page).
+    k_pool/v_pool: [N, T, Hkv * D] page pools (page 0 = garbage page); with
+    ``pool_index`` (an int32 scalar, traced) [N, pools, T, Hkv * D], of
+    which pool ``pool_index`` is read in place.
     tables: [S, P] int32 page tables; lengths: [S] int32 slot cursors
     (``-K`` for a row without a live sequence: zeros, no page read).
     Returns [S, K, H, D] in q.dtype. ``name``: what the kernel is called in
@@ -163,17 +174,25 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
             f"slot axis mismatch: q {q.shape}, tables {tables.shape}, "
             f"lengths {lengths.shape}")
     H, D = q.shape[2:]
-    if (k_pool.ndim != 3 or k_pool.shape[2] % D != 0
-            or H % (k_pool.shape[2] // D) != 0):
+    if (k_pool.ndim != (3 if pool_index is None else 4)
+            or k_pool.shape[-1] % D != 0
+            or H % (k_pool.shape[-1] // D) != 0):
         raise ValueError(
             f"head mismatch: q {q.shape} vs pool {k_pool.shape} (pool is "
-            "[N, T, Hkv * D]; H must be a multiple of Hkv, D must match)")
+            "[N, T, Hkv * D], with pool_index [N, pools, T, Hkv * D]; H "
+            "must be a multiple of Hkv, D must match)")
     if window is not None and window < 1:
         raise ValueError(f"window must be >= 1, got {window}")
     if impl == "pallas":
+        which = (None if pool_index is None else
+                 jnp.reshape(pool_index, (1,)).astype(jnp.int32))
         return _paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
                                        sm_scale, should_interpret(), name,
-                                       window)
+                                       window, which)
+    if pool_index is not None:
+        k_pool, v_pool = (lax.dynamic_index_in_dim(
+            pool, pool_index, axis=1, keepdims=False)
+            for pool in (k_pool, v_pool))
     return _paged_attention_reference(q, k_pool, v_pool, tables, lengths,
                                       sm_scale, window)
 
@@ -273,12 +292,12 @@ def _vmem_bytes(shape, dtype) -> int:
 
 def _shapes(q, k_pool, tables, window=None):
     S, K, H, D = q.shape
-    T = k_pool.shape[1]
-    Hkv = k_pool.shape[2] // D
+    T = k_pool.shape[-2]
+    Hkv = k_pool.shape[-1] // D
     P = tables.shape[1]
     G = H // Hkv
     B, q_tile = tile_sizes(K, G, T, P,
-                           k_pool.shape[2] * k_pool.dtype.itemsize, window)
+                           k_pool.shape[-1] * k_pool.dtype.itemsize, window)
     return S, K, D, T, Hkv, P, G, B, q_tile
 
 
@@ -374,15 +393,8 @@ def pallas_shape_problem(kv_heads: int, head_dim: int) -> Optional[str]:
 
 def _paged_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
                   order_ref, n_live_ref,        # the same: see below
-                  q_ref,                        # [1, 1, Hkv, R, D] VMEM
-                  k_pool_ref, v_pool_ref,       # [N, T, Hkv*D] HBM/ANY
-                  o_ref,                        # [1, 1, Hkv, R, D] VMEM
-                  k_buf, v_buf,                 # [2, B*T, Hkv*D] VMEM
-                  sems,                         # DMA [2 buffers, k|v]
-                  first_buf,                    # SMEM [1]: see below
-                  m_scr, l_scr, acc_scr,        # [Hkv, R, 1|1|D] f32 VMEM
-                  *, page_tokens, pages, qk, q_tile, group, kv_heads,
-                  head_dim, sm_scale, window=None):
+                  *refs, page_tokens, pages, qk, q_tile, group, kv_heads,
+                  head_dim, sm_scale, window=None, stacked=False):
     """One (slot, query tile) cell: R = q_tile * group query rows of every
     kv head over the slot's blocks 0 .. the tile's last position.
 
@@ -397,7 +409,17 @@ def _paged_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
     nothing. Under a ``window`` a cell's walk starts at its FIRST block, the
     one that holds the first position its first row may attend
     (``first_block``), and that is the block the cell before it starts for
-    it."""
+    it. ``stacked``: a fifth scalar, ``which_ref`` [1], comes first of
+    ``refs``, and the pools are ``[N, pools, T, Hkv*D]``: a page's copy
+    reads pool ``which_ref[0]`` of it."""
+    which_ref, refs = (refs[0], refs[1:]) if stacked else (None, refs)
+    (q_ref,                        # [1, 1, Hkv, R, D] VMEM
+     k_pool_ref, v_pool_ref,       # [N, T, Hkv*D] HBM/ANY
+     o_ref,                        # [1, 1, Hkv, R, D] VMEM
+     k_buf, v_buf,                 # [2, B*T, Hkv*D] VMEM
+     sems,                         # DMA [2 buffers, k|v]
+     first_buf,                    # SMEM [1]: see below
+     m_scr, l_scr, acc_scr) = refs  # [Hkv, R, 1|1|D] f32 VMEM
     c, t = pl.program_id(0), pl.program_id(1)
     n_tiles, n_live = pl.num_programs(1), n_live_ref[0]
     s = order_ref[c]
@@ -422,7 +444,9 @@ def _paged_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
             rows = pl.ds(i * T, T)
             for kv, (pool, dst) in enumerate(((k_pool_ref, k_buf),
                                               (v_pool_ref, v_buf))):
-                cp = pltpu.make_async_copy(pool.at[pid], dst.at[buf, rows],
+                page = (pool.at[pid] if which_ref is None
+                        else pool.at[pid, which_ref[0]])
+                cp = pltpu.make_async_copy(page, dst.at[buf, rows],
                                            sems.at[buf, kv])
                 cp.wait() if wait else cp.start()
 
@@ -518,7 +542,11 @@ def _paged_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret", "name",
                                              "window"))
 def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths, sm_scale,
-                            interpret, name="paged_attention", window=None):
+                            interpret, name="paged_attention", window=None,
+                            which=None):
+    """``which``: int32 [1], the pool of a stack ``[N, pools, T, Hkv*D]``
+    the call reads (one more scalar-prefetch operand); None: a pool
+    alone."""
     S, K, D, T, Hkv, P, G, B, q_tile = _shapes(q, k_pool, tables, window)
     problem = None if interpret else pallas_shape_problem(Hkv, D)
     if problem:
@@ -533,7 +561,8 @@ def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths, sm_scale,
     qr = qr.reshape(S, n_tiles, Hkv, R, D)
     kernel = functools.partial(_paged_kernel, page_tokens=T, pages=B, qk=K,
                                q_tile=q_tile, group=G, kv_heads=Hkv,
-                               head_dim=D, sm_scale=sm_scale, window=window)
+                               head_dim=D, sm_scale=sm_scale, window=window,
+                               stacked=which is not None)
     block = (1, 1, Hkv, R, D)
     live = lengths + K > 0
     order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
@@ -547,11 +576,15 @@ def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths, sm_scale,
                    + _vmem_bytes(block, q.dtype))
             + 4 * _vmem_bytes((min(R, _SUB_ROWS), B * T), jnp.float32))
 
-    def cell(c, t, lengths_ref, tables_ref, order_ref, n_live_ref):
+    def cell(c, t, lengths_ref, tables_ref, order_ref, *_):
         return order_ref[c], t, 0, 0, 0
 
+    scalars = (lengths.astype(jnp.int32), tables.astype(jnp.int32), order,
+               jnp.sum(live, dtype=jnp.int32)[None])
+    if which is not None:
+        scalars += (which,)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=len(scalars),
         grid=(S, n_tiles),
         in_specs=[
             pl.BlockSpec(block, cell),
@@ -571,8 +604,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths, sm_scale,
             vmem_limit_bytes=vmem + vmem // 2),
         name=name,
         interpret=interpret,
-    )(lengths.astype(jnp.int32), tables.astype(jnp.int32), order,
-      jnp.sum(live, dtype=jnp.int32)[None], qr, k_pool, v_pool)
+    )(*scalars, qr, k_pool, v_pool)
     out = out.reshape(S, n_tiles, Hkv, q_tile, G, D).transpose(
         0, 1, 3, 2, 4, 5)
     return out.reshape(S, n_tiles * q_tile, Hkv * G, D)[:, :K]

@@ -436,9 +436,7 @@ class ContinuousScheduler:
             cfg, slots=self.slots, page_tokens=self.page_tokens,
             pages_per_slot=self._pages_per_slot, lane=self.attn_lane,
             itemsize=int(jax.numpy.dtype(cache_dtype or cfg.dtype).itemsize))
-        program_kw = {"attn": self.attn_lane}
-        if self._work.counts_experts:
-            program_kw["moe_info"] = True
+        program_kw = {"attn": self.attn_lane, **self._work.program_keywords}
         self._no_rows = _LiveRows(self.slots)  # for a chunk that takes none
         # donated caches: the pool mutates in place across iterations;
         # the tables are tiny per-call host->device uploads

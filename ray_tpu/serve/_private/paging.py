@@ -403,6 +403,11 @@ _REFUSALS = {"state": {
         "a model with 'indexed_latent_attention' layers exports no prefix: "
         "its pages hold latents, rotated keys and index keys, and what is "
         "exported is keys and values"),
+}, "loop": {
+    "drafter": (
+        "speculative decoding cannot serve a model with loop_passes > 1: "
+        "the drafter's slot arena holds one (K, V) a layer and token, and "
+        "a looped layer leaves one a PASS"),
 }}
 
 
@@ -424,7 +429,10 @@ def cannot_continue(cfg, pools: Tuple[PagePool, ...]
     index key under the one table: a spliced prefix brings both
     (tests/test_deepseek_v32.py), the other two are refused as for both
     parents. Plain 'attention' and
-    'minicpm4' forbid nothing: None."""
+    'minicpm4' forbid nothing: None. A LOOPED model (``loop_passes``
+    > 1) keeps keys and values alone, once a pass, all under the one table:
+    a page that is spliced or exported carries every pass's
+    (``decode.LoopPagedKVCache``), so only the drafter is refused."""
     if cfg.recurrent:
         return _REFUSALS["state"]
     if any(pool.window is not None for pool in pools):
@@ -435,6 +443,8 @@ def cannot_continue(cfg, pools: Tuple[PagePool, ...]
         return _REFUSALS["latent"]
     if "indexed_latent_attention" in cfg.kinds:
         return _REFUSALS["indexed_latent"]
+    if cfg.looped:
+        return _REFUSALS["loop"]
     return None
 
 

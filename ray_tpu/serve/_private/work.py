@@ -15,7 +15,9 @@ kind ('lightning-attn', 'power-retention', 'mamba2', 'minicpm4',
 'indexed_attention', 'sliding_attention', 'latent_attention',
 'indexed_latent_attention'; plain 'attention' is counted by
 ``attn_*`` alone); ``attn_*`` always (they count pages: a true 0 for a model
-without). What a token leaves in a page, by kind, is ``token_bytes``.
+without). What a token leaves in a page, by kind, is ``token_bytes``. A
+LOOPED model (``loop_passes`` > 1) shows ``loop_*`` beside them, and no
+other does: its ``attn_*`` and ``token_bytes`` count every pass's rows.
 """
 
 from __future__ import annotations
@@ -234,11 +236,12 @@ def token_bytes(cfg, kind: str, itemsize: int) -> int:
     row of a latent and a rotated key (``ops.latent_attention.join``, its
     padding to whole lane tiles held and moved too) that is both (an
     'indexed_latent_attention' layer's index key, beside it, is not
-    attended)."""
+    attended) — once a pass of a looped stack, each of which leaves its own
+    (``cfg.loop_passes``; 1: once)."""
     if kind in LATENT_KINDS:
         return pool_width(cfg.latent_kv_rank,
                           cfg.latent_rope_dim) * itemsize
-    return 2 * cfg.kv_heads * cfg.head_dim * itemsize
+    return 2 * cfg.kv_heads * cfg.head_dim * itemsize * cfg.loop_passes
 
 
 # kind -> (what counts one call of its layers, the keys it owns); the last
@@ -286,6 +289,11 @@ _SHARED = "moe_shared_rows"
 # and where the layer is a chip's share of its experts (``cfg.held``): every
 # choice the live rows made, of which ``moe_rows_routed`` landed here
 _CHOSEN = "moe_routes_chosen"
+# a looped model's: passes x program runs, layer applications (passes x
+# layers x program runs), and beside them the sampled rows by the pass they
+# left at, ``_EXIT`` + "1" .. the last pass
+_LOOP = ("loop_passes", "loop_layer_calls")
+_EXIT = "loop_exit_pass_"
 
 
 class Work:
@@ -300,14 +308,16 @@ class Work:
         kinds = cfg.kinds
         self._kinds = [(_KINDS[kind][0], kinds.count(kind))
                        for kind in _KINDS if kind in kinds]
-        self.paged_layers = sum(holds_page(kind) for kind in kinds)
+        # the pools a page holds rows in: a layer's, once a pass
+        self.paged_layers = cfg.loop_passes * sum(
+            holds_page(kind) for kind in kinds)
         self._window_layers = kinds.count(SLIDING)
         # all kv heads of one token's K (or V) row, and the query rows
         # that share it; a latent layer's one row a token, in two halves for
         # the same arithmetic, shared by every head
         latent = bool(set(LATENT_KINDS) & set(kinds))
         self._row_bytes = token_bytes(cfg, LATENT if latent else ATTENTION,
-                                      itemsize) // 2
+                                      itemsize) // (2 * cfg.loop_passes)
         self._group = cfg.num_heads // (1 if latent else cfg.kv_heads)
         # the dense latent kernel's own tiles (``_tiles``): not the picked
         # form's, which counts its own fetches
@@ -335,14 +345,20 @@ class Work:
             math.prod(shape) for kind in kinds if kind in STATE_KINDS
             for shape in state_shapes(cfg, kind, slots).values())
         # an expert model's programs are asked for the rows each expert
-        # received (``moe_info``), handed to ``routed``
+        # received (``moe_info``), a looped model's for the pass each
+        # sampled row left at (``loop_info``): handed to ``routed``
         self.counts_experts = cfg.mlp == "moe"
+        self.program_keywords = {
+            **({"moe_info": True} if self.counts_experts else {}),
+            **({"loop_info": True} if cfg.looped else {})}
         self._n = dict.fromkeys(
             _ATTN + tuple(key for kind in _KINDS if kind in kinds
                           for key in _KINDS[kind][1])
             + (_EXPERTS if self.counts_experts else ())
             + ((_SHARED,) if cfg.moe_shared_experts else ())
-            + ((_CHOSEN,) if cfg.held else ()), 0)
+            + ((_CHOSEN,) if cfg.held else ())
+            + ((_LOOP + tuple(_EXIT + str(t) for t in range(
+                1, cfg.loop_passes + 1))) if cfg.looped else ()), 0)
         if state_bytes:
             self._n.update(state_slots=slots, state_bytes=state_bytes)
 
@@ -408,10 +424,21 @@ class Work:
         row was live (``step``) — and both are ONE call of the experts'
         kernel a layer (``moe_kernel_calls``), whose walk over the call's
         static pairs (``routes``' shape) ``ops.moe.walk_lengths`` repeats:
-        ``moe_visits`` of ``moe_grid_visits`` carried rows."""
-        c = np.asarray(returned[0]["counts"])  # [layers, experts]
+        ``moe_visits`` of ``moe_grid_visits`` carried rows. A LOOPED model's
+        program returns the pass each sampled row left at instead (0: a row
+        nobody samples): one run of ``loop_passes`` passes over every
+        layer, its rows counted by exit pass."""
         n = self._n
         cfg = self.cfg
+        if cfg.looped:
+            exits = np.asarray(returned[0]["exit_pass"]).ravel()
+            n["loop_passes"] += cfg.loop_passes
+            n["loop_layer_calls"] += cfg.loop_passes * cfg.num_layers
+            for t, rows in enumerate(np.bincount(
+                    exits, minlength=cfg.loop_passes + 1)[1:], start=1):
+                n[_EXIT + str(t)] += int(rows)
+            return
+        c = np.asarray(returned[0]["counts"])  # [layers, experts]
         # the experts saw the program's rows as one batch, whoever sent them
         visits, grid = walk_lengths(
             c if c.ndim == 2 else c.sum(axis=1),

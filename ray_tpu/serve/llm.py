@@ -175,9 +175,13 @@ class LLMServerImpl:
         # custom tokenizers need explicit prompt_ids in the request
         self._byte_tok = tokenize is None
         # the sequential cache's two programs: the oracle a deployment
-        # checks its served tokens against (perfbench's reference check)
-        self._prefill = jax.jit(partial(prefill, self.cfg))
-        self._decode_step = jax.jit(partial(decode_step, self.cfg))
+        # checks its served tokens against (perfbench's reference check).
+        # The caches are donated: the check runs beside the pool, and a
+        # second cache of a looped model is half a gigabyte
+        self._prefill = jax.jit(partial(prefill, self.cfg),
+                                donate_argnums=(2,))
+        self._decode_step = jax.jit(partial(decode_step, self.cfg),
+                                    donate_argnums=(2,))
 
         from ray_tpu.serve._private.continuous import ContinuousScheduler
 

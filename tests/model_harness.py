@@ -180,12 +180,13 @@ def paged_drive(cfg, params, tokens, caches, tables, *, lengths, chunk,
     (``ensure(caches, slot, lo, hi) -> caches`` is then called for every
     slot about to write positions [lo, hi), ``after()`` behind every
     program). ``poisoned``: every page no table names is filled with NaN in
-    every pool first. ``kw``: the programs' keywords (``moe_info``,
-    ``selected``); logits are always asked for.
+    every pool first. ``kw``: the programs' keywords (``moe_info`` or
+    ``loop_info``, ``selected``); logits are always asked for.
 
     Returns what the programs said, a slot at a time: ``got`` (logits, from
     the prompt's last position on), ``routes`` and ``picked`` (where asked
-    for), ``cursor``; ``info`` (every program's ``moe_info``), the final
+    for), ``cursor``; ``info`` (what every program told beside ids and
+    caches: its ``moe_info`` or ``loop_info``), the final
     ``caches``, the ``poisoned`` pages, the jitted ``step``."""
     first, second = sorted(lengths)
     row = {first: 0, second: 1}
@@ -200,6 +201,7 @@ def paged_drive(cfg, params, tokens, caches, tables, *, lengths, chunk,
         caches = poison(caches, bad)
     run, step = paged_programs(cfg, attn=impl, logits=True, **kw)
     moe, taps = kw.get("moe_info", False), kw.get("selected", False)
+    told = moe or kw.get("loop_info", False)
     got, routes, picked = ({s: [] for s in lengths} for _ in range(3))
     cursor, info = dict.fromkeys(lengths, 0), []
 
@@ -221,7 +223,7 @@ def paged_drive(cfg, params, tokens, caches, tables, *, lengths, chunk,
     def said(out):
         """(ids, caches, moe_info, logits, taps) of a program's outputs."""
         out = list(out)
-        return (out[0], out[1], out.pop(2) if moe else None, out[2],
+        return (out[0], out[1], out.pop(2) if told else None, out[2],
                 out[3] if taps else None)
 
     def wrote(caches, spans):
